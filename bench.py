@@ -7,6 +7,9 @@ optimizer) on the Llama-architecture flagship at the largest per-chip batch
 that fits. vs_baseline compares against the north-star target of 45% MFU
 (BASELINE.md: ZeRO-3 Llama-2-7B on v5e-64 at >=45% MFU; single-chip MFU is
 the per-chip factor of that target).
+
+No TPU is a failure, not a fallback: the run raises before measuring
+anything, and any phase that fails ends the run with a non-zero exit.
 """
 
 import json
@@ -15,10 +18,8 @@ import time
 import numpy as np
 
 # the peak-FLOPS table lives with the accelerator (serving_bench shares
-# it for its MFU field); these aliases keep the historical bench surface
-from deepspeed_tpu.accelerator.tpu_accelerator import (PEAK_FLOPS_BY_KIND
-                                                       as PEAK_FLOPS,
-                                                       peak_flops)
+# it for its MFU field)
+from deepspeed_tpu.accelerator.tpu_accelerator import peak_flops
 
 
 def _measure(cfg, micro, gas, steps, warmup, n_dev, zero_stage=None,
@@ -66,55 +67,52 @@ def _measure(cfg, micro, gas, steps, warmup, n_dev, zero_stage=None,
         # phase breakdown (VERDICT r4 #1c): forward wall-clock via the
         # eval step on the same shapes; exposed-collective fraction from
         # the optimized HLO of the train step
-        try:
-            for _ in range(2):
-                engine.eval_batch(batch=batch)
-            t1 = time.perf_counter()
-            for _ in range(max(steps, 3)):
-                engine.eval_batch(batch=batch)
-            fwd = (time.perf_counter() - t1) / max(steps, 3)
-            from deepspeed_tpu.utils.xla_profile import (
-                grad_exchange_report_from_compiled,
-                overlap_report_from_compiled)
-            compiled = engine.lower_train_step(batch)
-            rep = overlap_report_from_compiled(compiled)
-            gx = grad_exchange_report_from_compiled(compiled)
-            # compiler-measured MFU (satellite of the flops profiler):
-            # XLA's own flop count for the compiled step over the
-            # measured wall time and the chip's peak — cross-checks the
-            # analytic model.flops_per_token MFU headline. cost_analysis
-            # reports the PER-DEVICE partitioned module's flops, so no
-            # further /n_dev — peak is also per chip
-            from deepspeed_tpu.telemetry.memory import cost_analysis_dict
-            ca = cost_analysis_dict(compiled)
-            step_flops = float(ca.get("flops", 0.0))
-            step_bytes = float(ca.get("bytes accessed", 0.0))
-            extra_phases = {
-                "cost_analysis_flops": step_flops,
-                "cost_analysis_bytes": step_bytes,
-                "mfu_cost_analysis": (
-                    round(step_flops / dt
-                          / peak_flops(jax.devices()[0]), 4)
-                    if step_flops else None),
-                "fwd_s": round(fwd, 4),
-                "fwd_frac": round(fwd / dt, 3),
-                "bwd_opt_s": round(dt - fwd, 4),
-                "async_pairs": rep.async_pairs,
-                "sync_collectives": rep.sync_collectives,
-                "exposed_collective_fraction": round(rep.exposed_fraction, 4),
-                # gradient-exchange regression metric (grad_overlap.py):
-                # share of grad collectives with no overlap window
-                "grad_exposed_collective_fraction":
-                    round(gx.exposed_fraction, 4),
-                "grad_overlap_mode": engine.grad_overlap_mode,
-            }
-            if engine.grad_bucket_plan is not None:
-                extra_phases["reduce_buckets"] = \
-                    engine.grad_bucket_plan.num_buckets
-                extra_phases["reduce_bucket_max_bytes"] = \
-                    engine.grad_bucket_plan.max_bucket_bytes
-        except Exception as exc:
-            extra_phases = {"error": repr(exc)[:150]}
+        for _ in range(2):
+            engine.eval_batch(batch=batch)
+        t1 = time.perf_counter()
+        for _ in range(max(steps, 3)):
+            engine.eval_batch(batch=batch)
+        fwd = (time.perf_counter() - t1) / max(steps, 3)
+        from deepspeed_tpu.utils.xla_profile import (
+            grad_exchange_report_from_compiled,
+            overlap_report_from_compiled)
+        compiled = engine.lower_train_step(batch)
+        rep = overlap_report_from_compiled(compiled)
+        gx = grad_exchange_report_from_compiled(compiled)
+        # compiler-measured MFU (satellite of the flops profiler):
+        # XLA's own flop count for the compiled step over the
+        # measured wall time and the chip's peak — cross-checks the
+        # analytic model.flops_per_token MFU headline. cost_analysis
+        # reports the PER-DEVICE partitioned module's flops, so no
+        # further /n_dev — peak is also per chip
+        from deepspeed_tpu.telemetry.memory import cost_analysis_dict
+        ca = cost_analysis_dict(compiled)
+        step_flops = float(ca.get("flops", 0.0))
+        step_bytes = float(ca.get("bytes accessed", 0.0))
+        extra_phases = {
+            "cost_analysis_flops": step_flops,
+            "cost_analysis_bytes": step_bytes,
+            "mfu_cost_analysis": (
+                round(step_flops / dt
+                      / peak_flops(jax.devices()[0]), 4)
+                if step_flops else None),
+            "fwd_s": round(fwd, 4),
+            "fwd_frac": round(fwd / dt, 3),
+            "bwd_opt_s": round(dt - fwd, 4),
+            "async_pairs": rep.async_pairs,
+            "sync_collectives": rep.sync_collectives,
+            "exposed_collective_fraction": round(rep.exposed_fraction, 4),
+            # gradient-exchange regression metric (grad_overlap.py):
+            # share of grad collectives with no overlap window
+            "grad_exposed_collective_fraction":
+                round(gx.exposed_fraction, 4),
+            "grad_overlap_mode": engine.grad_overlap_mode,
+        }
+        if engine.grad_bucket_plan is not None:
+            extra_phases["reduce_buckets"] = \
+                engine.grad_bucket_plan.num_buckets
+            extra_phases["reduce_bucket_max_bytes"] = \
+                engine.grad_bucket_plan.max_bucket_bytes
     tokens_per_step = gm * gas * seq
     tokens_per_sec = tokens_per_step / dt
     achieved = tokens_per_sec * model.flops_per_token(seq) / n_dev
@@ -124,7 +122,8 @@ def _measure(cfg, micro, gas, steps, warmup, n_dev, zero_stage=None,
         "step_time_s": round(dt, 4),
         "params_no_embed": model.num_params(include_embed=False),
         "devices": n_dev,
-        "device_kind": str(getattr(jax.devices()[0], "device_kind", "cpu")),
+        "platform": jax.devices()[0].platform,
+        "device_kind": jax.devices()[0].device_kind,
         "seq_len": seq,
         "micro_batch": micro,
         "attention": "flash" if cfg.use_flash
@@ -241,51 +240,32 @@ def main(argv=None):
     # collective-overlap XLA knobs (latency-hiding scheduler + async
     # collective fusion incl. reduce-scatter chaining for the bucketed
     # grad reduction) ride LIBTPU_INIT_ARGS — only the TPU runtime reads
-    # them (this jaxlib's XLA_FLAGS parser rejects them and would abort
-    # CPU runs). Must be set before the TPU client initializes.
-    from deepspeed_tpu.accelerator.tpu_accelerator import \
-        apply_collective_overlap_flags
+    # them. Must be set before the TPU client initializes.
+    from deepspeed_tpu.accelerator.tpu_accelerator import (
+        apply_collective_overlap_flags, require_tpu)
+    from deepspeed_tpu.utils.compile_cache import enable_compile_cache
     apply_collective_overlap_flags()
+    enable_compile_cache()
+    n_dev = len(require_tpu())      # raises, naming the platform it found
 
-    from __graft_entry__ import _ensure_jax_platform, _flagship_cfg
+    from __graft_entry__ import _flagship_cfg
 
-    backend = _ensure_jax_platform()
-
-    import jax
-    from deepspeed_tpu.models import TransformerConfig
-
-    n_dev = jax.device_count()
-    on_tpu = backend == "tpu" and jax.default_backend() == "tpu"
-    tpu_unreachable = False
-    if on_tpu:
-        base = _flagship_cfg()  # the shipped flagship, not a local copy
-        # mini-autotune: attention impl x micro-batch x remat-policy ladder;
-        # OOM configs are skipped, the best-MFU measurement is reported.
-        # save_dots_and_attn keeps matmul outputs AND the tagged attention
-        # output (the Pallas call is opaque to dot policies, so without the
-        # tag the flash forward re-runs in backward);
-        # dots_with_no_batch_dims_saveable keeps matmul outputs only;
-        # nothing_saveable is full per-layer recompute.
-        trials = build_trials(base)
-        steps, warmup = 10, 2
-    else:  # CPU smoke mode
-        base = TransformerConfig(vocab_size=256, hidden_size=128,
-                                 intermediate_size=256, num_layers=2,
-                                 num_heads=8, max_seq_len=128)
-        trials = [(base, 1, None)]
-        steps, warmup = 5, 2
-        if os.environ.get("DS_TPU_PLATFORM_FALLBACK") == "1":
-            # the platform probe found an accelerator plugin but its device
-            # init failed/hung, so _ensure_jax_platform pinned CPU: say so
-            # in the record instead of letting a CPU smoke number
-            # masquerade as the chip
-            tpu_unreachable = True
+    base = _flagship_cfg()  # the shipped flagship, not a local copy
+    # mini-autotune: attention impl x micro-batch x remat-policy ladder;
+    # configs that do not fit are skipped, the best-MFU measurement is
+    # reported. save_dots_and_attn keeps matmul outputs AND the tagged
+    # attention output (the Pallas call is opaque to dot policies, so
+    # without the tag the flash forward re-runs in backward);
+    # dots_with_no_batch_dims_saveable keeps matmul outputs only;
+    # nothing_saveable is full per-layer recompute.
+    trials = build_trials(base)
+    steps, warmup = 10, 2
 
     best = None
-    errors = []
+    oom = []
     # wall-clock budget for the trial ladder: cold compiles cost ~40s per
     # config; stop opening new trials when the budget is spent so the
-    # driver's bench window always gets a number + the zero-3 variant
+    # run always gets a number + the zero-3 variant
     budget_s = float(os.environ.get("DS_TPU_BENCH_BUDGET", "900"))
     t_start = time.perf_counter()
     skipped_trials = 0
@@ -296,181 +276,85 @@ def main(argv=None):
         try:
             mfu, detail = _measure(cfg, micro, 1, steps, warmup, n_dev,
                                    remat_policy=policy)
-        except Exception as exc:  # OOM or compile failure: try next config
-            errors.append(f"micro={micro} flash={cfg.use_flash} "
-                          f"remat={policy}: {repr(exc)[:200]}")
+        except Exception as exc:
+            # a ladder rung that does not fit the chip is the ladder's
+            # business; anything else is a broken program and ends the run
+            if "RESOURCE_EXHAUSTED" not in str(exc):
+                raise
+            oom.append(f"micro={micro} flash={cfg.use_flash} "
+                       f"remat={policy}")
             continue
         if best is None or mfu > best[0]:
             best = (mfu, detail, cfg, micro, policy)
 
     if best is None:
-        raise RuntimeError("all bench configs failed: " + " | ".join(errors))
+        raise RuntimeError("no bench config fits the chip: " + " | ".join(oom))
     mfu, detail, cfg, micro, policy = best
+    if oom:
+        detail["out_of_memory_trials"] = oom
     if skipped_trials:  # a truncated search must say so in the record
         detail["skipped_trials"] = skipped_trials
 
     # ZeRO-3 variant on the same (best) config: the sharding machinery runs
     # on the degenerate dp=1 mesh so regressions in the stage-3 path show up
-    # in every bench (round-2 Weak #2), plus the profiler trace artifact.
-    prof_dir = os.environ.get("DS_TPU_BENCH_PROFILE",
-                              "profiles/bench_trace" if on_tpu else "")
-    try:
-        # phase breakdown costs a second AOT compile + eval-step compiles
-        # (~80s cold on chip); only spend it if the trial ladder left room
-        phases_ok = (time.perf_counter() - t_start) < budget_s * 0.8
-        z3_mfu, z3_detail = _measure(cfg, micro, 1, max(steps // 2, 3),
-                                     warmup, n_dev, zero_stage=3,
-                                     remat_policy=policy,
-                                     profile_dir=prof_dir or None,
-                                     phases=phases_ok)
-        detail["zero3_mfu"] = round(z3_mfu * 100, 2)
-        detail["zero3_tokens_per_sec_per_chip"] = \
-            z3_detail["tokens_per_sec_per_chip"]
-        if "phase_breakdown" in z3_detail:
-            detail["zero3_phase_breakdown"] = z3_detail["phase_breakdown"]
-        elif not phases_ok:  # a truncated record must say so
-            detail["zero3_phase_breakdown"] = {"skipped": "budget"}
-        if prof_dir:
-            detail["profile_trace"] = prof_dir
-    except Exception as exc:
-        detail["zero3_error"] = repr(exc)[:200]
+    # in every bench, plus the profiler trace (profiles/ is gitignored).
+    prof_dir = os.environ.get("DS_TPU_BENCH_PROFILE", "profiles/bench_trace")
+    # phase breakdown costs a second AOT compile + eval-step compiles
+    # (~80s cold on chip); only spend it if the trial ladder left room
+    phases_ok = (time.perf_counter() - t_start) < budget_s * 0.8
+    z3_mfu, z3_detail = _measure(cfg, micro, 1, max(steps // 2, 3),
+                                 warmup, n_dev, zero_stage=3,
+                                 remat_policy=policy,
+                                 profile_dir=prof_dir or None,
+                                 phases=phases_ok)
+    detail["zero3_mfu"] = round(z3_mfu * 100, 2)
+    detail["zero3_tokens_per_sec_per_chip"] = \
+        z3_detail["tokens_per_sec_per_chip"]
+    if "phase_breakdown" in z3_detail:
+        detail["zero3_phase_breakdown"] = z3_detail["phase_breakdown"]
+    else:  # a truncated record must say so
+        detail["zero3_phase_breakdown"] = {"skipped": "budget"}
+    if prof_dir:
+        detail["profile_trace"] = prof_dir
 
-    # chip-free AOT dp8 proxy: gradient-reduction overlap, monolithic vs
-    # bucketed (benchmarks/aot_scale.grad_overlap_dp8 — the libtpu compiler
-    # runs on the CPU host, so this rides every bench). The bucketed
-    # exposed_collective_fraction is the tracked regression metric
-    # (acceptance bar <= 0.5, from 1.0 at the seed).
     if time.perf_counter() - t_start < budget_s:
-        try:
-            from deepspeed_tpu.benchmarks.aot_scale import grad_overlap_dp8
-            rec = grad_overlap_dp8(out_dir="artifacts")
-            detail["aot_grad_overlap_dp8"] = {
-                "exposed_collective_fraction":
-                    round(rec["exposed_collective_fraction"], 4),
-                "exposed_collective_fraction_monolithic":
-                    round(rec["exposed_collective_fraction_monolithic"], 4),
-                "exposed_collective_fraction_int8":
-                    round(rec["exposed_collective_fraction_int8"], 4),
-                "quant_wire_ratio": rec["quant_wire_ratio"],
-                "buckets": rec["bucketed"].get("bucket_plan", {}).get(
-                    "num_buckets"),
-                "median_overlap_window":
-                    rec["bucketed"].get("median_overlap_window"),
-            }
-        except Exception as exc:
-            detail["aot_grad_overlap_error"] = repr(exc)[:200]
-
-    if on_tpu and time.perf_counter() - t_start < budget_s:
         # larger proxy (~780M total / ~680M non-embed): closer to the 7B
         # target's arithmetic intensity (H=1536); recorded as evidence, the
         # headline stays on the standard flagship so rounds stay comparable
-        try:
-            big = large_proxy_cfg(base)
-            b_mfu, b_detail = _measure(big, 8, 1, max(steps // 2, 3),
-                                       warmup, n_dev, remat_policy=policy)
-            detail["large_proxy_mfu"] = round(b_mfu * 100, 2)
-            detail["large_proxy_params_no_embed"] = \
-                b_detail["params_no_embed"]
-        except Exception as exc:
-            detail["large_proxy_error"] = repr(exc)[:200]
+        big = large_proxy_cfg(base)
+        b_mfu, b_detail = _measure(big, 8, 1, max(steps // 2, 3),
+                                   warmup, n_dev, remat_policy=policy)
+        detail["large_proxy_mfu"] = round(b_mfu * 100, 2)
+        detail["large_proxy_params_no_embed"] = \
+            b_detail["params_no_embed"]
 
-    if on_tpu:
-        # on-chip flash parity evidence in every bench record (round-2
-        # Weak #9: parity was previously interpret-mode-on-CPU only)
-        try:
-            from deepspeed_tpu.ops.attention_autotune import (
-                decode_parity_check, parity_check)
-            detail["flash_parity"] = parity_check(
-                heads=cfg.num_heads, kv_heads=cfg.kv_heads,
-                head_dim=cfg.head_dim, seq=512)
-            detail["decode_parity"] = decode_parity_check(
-                heads=cfg.num_heads, kv_heads=cfg.kv_heads,
-                head_dim=cfg.head_dim)
-        except Exception as exc:
-            detail["flash_parity_error"] = repr(exc)[:150]
+    # on-chip flash parity evidence in every bench record
+    from deepspeed_tpu.ops.attention_autotune import (decode_parity_check,
+                                                      parity_check)
+    detail["flash_parity"] = parity_check(
+        heads=cfg.num_heads, kv_heads=cfg.kv_heads,
+        head_dim=cfg.head_dim, seq=512)
+    detail["decode_parity"] = decode_parity_check(
+        heads=cfg.num_heads, kv_heads=cfg.kv_heads,
+        head_dim=cfg.head_dim)
 
-    if tpu_unreachable:
-        detail["tpu_unreachable"] = True
-        detail["note"] = ("JAX_PLATFORMS requested a TPU but device init "
-                          "failed or hung; this is a CPU smoke number, not "
-                          "a chip measurement")
-        # a chip window EARLIER in the round may have captured a real
-        # measurement (scripts/chip_probe_loop.sh -> chip_window*.sh);
-        # surface the newest-by-mtime one, labeled with its capture
-        # time so a carried-over file from a previous round is
-        # distinguishable from this round's evidence
-        import glob
-        import pathlib
-        here = pathlib.Path(__file__).parent
-        for cand in sorted(glob.glob(str(here / "BENCH_*_early.json")),
-                           key=os.path.getmtime, reverse=True):
-            try:
-                early = json.load(open(cand))
-                if "TPU" in str(early.get("detail", {}).get(
-                        "device_kind", "")):
-                    detail["latest_chip_capture"] = {
-                        "file": pathlib.Path(cand).name,
-                        "captured_at": time.strftime(
-                            "%Y-%m-%dT%H:%M:%SZ", time.gmtime(
-                                os.path.getmtime(cand))),
-                        "value": early["value"],
-                        "zero3_mfu": early["detail"].get("zero3_mfu"),
-                        "device_kind": early["detail"]["device_kind"],
-                    }
-                    break
-            except Exception:
-                continue
-        # the chip-free scale proofs (AOT-compiled against real v5e
-        # topologies with the local libtpu compiler; see
-        # benchmarks/aot_scale.py) still hold — surface the committed
-        # artifact numbers so the record carries the round's perf evidence
-        art = here / "artifacts"
-        try:
-            fit = json.load(open(art / "flagship_7b_v5e64.json"))
-            detail["aot_7b_v5e64_fit"] = {
-                k: {"peak_gib_per_chip": v["peak_gib_per_chip"],
-                    "fits_hbm": v["fits_hbm"]}
-                for k, v in fit.items()
-                if isinstance(v, dict) and "peak_gib_per_chip" in v}
-        except Exception:
-            pass
-        try:
-            ov = json.load(open(art / "overlap_dp8.json"))
-            u = ov.get("stage3_unrolled", {})
-            detail["aot_zero3_overlap_dp8"] = {
-                "async_chains": u.get("async_chains"),
-                "param_gather_exposed_fraction":
-                    u.get("param_gather_exposed_fraction"),
-                "exposed_bytes_fraction": u.get("exposed_bytes_fraction")}
-        except Exception:
-            pass
-    try:
-        # pin the exact compiler configuration to the perf record so a
-        # number is attributable to a jax/jaxlib/libtpu + flag set
-        from deepspeed_tpu.env_report import compiler_fingerprint
-        detail["compiler_config"] = compiler_fingerprint()
-    except Exception:
-        pass
-    try:
-        # black-box summary: the flight recorder ran through the whole
-        # bench (train_step events per batch), and any anomaly verdict
-        # (NaN/spike/stall) belongs in the record next to the number
-        from deepspeed_tpu.telemetry import anomaly, get_recorder
-        detail["flight_recorder"] = get_recorder().stats()
-        verdicts = anomaly.recent()
-        if verdicts:
-            detail["anomalies"] = [
-                {"kind": v["kind"], "summary": v["summary"]}
-                for v in verdicts]
-    except Exception:
-        pass
+    # pin the exact compiler configuration to the perf record so a
+    # number is attributable to a jax/jaxlib/libtpu + flag set
+    from deepspeed_tpu.env_report import compiler_fingerprint
+    detail["compiler_config"] = compiler_fingerprint()
+    # black-box summary: the flight recorder ran through the whole
+    # bench (train_step events per batch), and any anomaly verdict
+    # (NaN/spike/stall) belongs in the record next to the number
+    from deepspeed_tpu.telemetry import anomaly, get_recorder
+    detail["flight_recorder"] = get_recorder().stats()
+    verdicts = anomaly.recent()
+    if verdicts:
+        detail["anomalies"] = [
+            {"kind": v["kind"], "summary": v["summary"]}
+            for v in verdicts]
     if args.trace_out:
-        try:
-            from deepspeed_tpu.telemetry import timeline
-            detail["trace_out"] = timeline.write_chrome_trace(
-                args.trace_out)
-        except Exception as exc:
-            detail["trace_out_error"] = repr(exc)[:150]
+        from deepspeed_tpu.telemetry import timeline
+        detail["trace_out"] = timeline.write_chrome_trace(args.trace_out)
     result = {
         "metric": "train_mfu_llama_flagship",
         "value": round(mfu * 100, 2),
@@ -482,14 +366,4 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
-    try:
-        main()
-    except Exception as exc:  # never crash: an rc!=0 bench records nothing
-        import traceback
-
-        traceback.print_exc()
-        print(json.dumps({
-            "metric": "train_mfu_llama_flagship", "value": 0.0,
-            "unit": "% MFU", "vs_baseline": 0.0,
-            "error": repr(exc)[:500],
-        }))
+    main()

@@ -56,7 +56,11 @@ def initialize(args=None,
         raise ValueError("a config (dict or json path) is required")
 
     comm.init_distributed(distributed_port=distributed_port)
-    ds_config = DeepSpeedConfig(config)
+    # an explicit topology (e.g. one chip of a four-chip host) sets the
+    # world the batch triple is checked against, not jax.device_count()
+    ds_config = DeepSpeedConfig(
+        config, world_size=(topology.mesh.size if topology is not None
+                            else None))
 
     dataloader = None
     if training_data is not None:

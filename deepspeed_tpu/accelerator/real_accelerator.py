@@ -23,14 +23,9 @@ def _detect() -> str:
             raise ValueError(
                 f"DS_ACCELERATOR={override!r} not in {SUPPORTED}")
         return override
-    try:
-        import jax
+    import jax
 
-        if jax.default_backend() == "tpu":
-            return "tpu"
-    except Exception:
-        pass
-    return "cpu"
+    return "tpu" if jax.default_backend() == "tpu" else "cpu"
 
 
 def get_accelerator() -> DeepSpeedAccelerator:

@@ -41,23 +41,51 @@ COLLECTIVE_OVERLAP_COMPILER_OPTIONS: Dict[str, str] = {
 
 # bf16 peak matmul FLOPS per chip by device_kind substring — the MFU
 # denominator for bench.py / serving_bench (model-flops utilization =
-# achieved flops/s over this peak)
+# achieved flops/s over this peak). Sources: Google Cloud TPU
+# documentation, per-chip bf16 peaks of "TPU v5e", "TPU v5p", "TPU v4".
 PEAK_FLOPS_BY_KIND: Dict[str, float] = {
-    "TPU v5 lite": 197e12,   # v5e bf16 peak per chip
+    "TPU v5 lite": 197e12,   # v5e
     "TPU v5": 459e12,        # v5p
     "TPU v4": 275e12,
-    "cpu": 1e12,             # nominal, for smoke runs
 }
 
 
 def peak_flops(device) -> float:
     """Peak bf16 FLOPS of ``device`` (a jax.Device), by device_kind
-    substring; unknown kinds fall back to the nominal CPU figure."""
-    kind = getattr(device, "device_kind", "cpu")
+    substring. A device that is not in the table is an error, not a
+    default: a utilization against a made-up peak is not a number."""
+    kind = str(getattr(device, "device_kind", None))
     for key, val in PEAK_FLOPS_BY_KIND.items():
-        if key.lower() in str(kind).lower():
+        if key.lower() in kind.lower():
             return val
-    return PEAK_FLOPS_BY_KIND["cpu"]
+    raise ValueError(
+        f"no peak-FLOPS entry for device_kind {kind!r}; known kinds: "
+        f"{sorted(PEAK_FLOPS_BY_KIND)} (add the chip, with its source, to "
+        f"PEAK_FLOPS_BY_KIND)")
+
+
+def require_tpu(min_devices: int = 1):
+    """The TPU devices JAX sees, or an error naming what it saw instead.
+
+    For programs whose output is a statement about the chip (bench.py,
+    chip_smoke.py, scripts/): no TPU is a failure, never a CPU fallback.
+    Runs in-process — the caller becomes the one process that holds the
+    chip, so it must not start a child that needs it."""
+    import jax
+
+    devices = jax.devices()
+    platform = devices[0].platform
+    if platform != "tpu":
+        raise RuntimeError(
+            f"this program needs a TPU and JAX found none: "
+            f"jax.devices()[0].platform == {platform!r} "
+            f"(device_kind {devices[0].device_kind!r}, JAX_PLATFORMS="
+            f"{os.environ.get('JAX_PLATFORMS', '')!r})")
+    if len(devices) < min_devices:
+        raise RuntimeError(
+            f"this program needs {min_devices} TPU devices and JAX found "
+            f"{len(devices)} ({devices[0].device_kind})")
+    return devices
 
 
 def collective_overlap_init_args(existing: str = "") -> str:

@@ -28,9 +28,9 @@ def _collective_fn(op: str, axis: str):
     if op == "reduce_scatter":
         return lambda x: jax.lax.psum_scatter(x, axis, tiled=True)
     if op == "all_to_all":
-        from ..comm.quantized import _one_axis_size
+        from ..comm.quantized import _axis_size
         return lambda x: jax.lax.all_to_all(
-            x.reshape(_one_axis_size(axis), -1), axis, 0, 0,
+            x.reshape(_axis_size(axis), -1), axis, 0, 0,
             tiled=False).reshape(-1)
     raise ValueError(f"unknown op {op}")
 

@@ -339,8 +339,8 @@ def axis_rank(axis_name) -> jnp.ndarray:
 
 
 def axis_size(axis_name) -> int:
-    from .quantized import _one_axis_size
-    return _one_axis_size(axis_name)
+    from .quantized import _axis_size
+    return _axis_size(axis_name)
 
 
 # dispatch helpers mirroring reference comm.py:315/:246

@@ -24,19 +24,13 @@ from typing import Tuple, Union
 import jax
 import jax.numpy as jnp
 
+from .quantized import _axis_size
+
 AxisNames = Union[str, Tuple[str, ...]]
 
 
 def _axes_tuple(axes: AxisNames) -> Tuple[str, ...]:
     return (axes,) if isinstance(axes, str) else tuple(axes)
-
-
-def _axis_size(axes: AxisNames):
-    from .quantized import _one_axis_size
-    size = 1
-    for a in _axes_tuple(axes):
-        size = size * _one_axis_size(a)
-    return size
 
 
 def _sign_compress(x: jnp.ndarray):

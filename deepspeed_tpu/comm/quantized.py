@@ -45,34 +45,18 @@ def shard_map_unchecked(f, mesh, in_specs, out_specs, axis_names=None):
     """
     if isinstance(axis_names, str):
         axis_names = (axis_names,)
-    manual = frozenset(axis_names) if axis_names else None
-    try:
-        from jax import shard_map as sm
-        kw = {"axis_names": manual} if manual else {}
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=False, **kw)
-    except (ImportError, TypeError):  # older jax: auto= is the complement
-        from jax.experimental.shard_map import shard_map as sm
-        kw = ({"auto": frozenset(mesh.axis_names) - manual} if manual else {})
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False, **kw)
-
-
-def _one_axis_size(a: str) -> int:
-    """Static axis size inside shard_map. ``jax.lax.axis_size`` only exists
-    on newer jax; on older releases ``psum`` of a unit literal
-    constant-folds to the axis size as a plain Python int."""
-    if hasattr(jax.lax, "axis_size"):
-        return int(jax.lax.axis_size(a))
-    return int(jax.lax.psum(1, a))
+    kw = {"axis_names": frozenset(axis_names)} if axis_names else {}
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False, **kw)
 
 
 def _axis_size(axes: AxisNames) -> int:
+    """Static size of the named axes inside shard_map."""
     if isinstance(axes, str):
         axes = (axes,)
     size = 1
     for a in axes:
-        size = size * _one_axis_size(a)
+        size = size * int(jax.lax.axis_size(a))
     return size
 
 
@@ -150,7 +134,7 @@ def reduce_scatter_leaf(grad: jnp.ndarray, dim: int, axes: AxisNames,
         axes = (axes,)
     out = grad
     for a in axes:
-        if _one_axis_size(a) == 1:
+        if _axis_size(a) == 1:
             continue
         out = jax.lax.psum_scatter(out, a, scatter_dimension=dim, tiled=True)
     if mean:

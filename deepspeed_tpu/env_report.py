@@ -76,61 +76,18 @@ def compiler_config_report():
     ]
 
 
-def hardware_report(probe_timeout: int = 30):
-    """Device inventory. Device init runs in a SUBPROCESS with a timeout:
-    a diagnostic tool must never hang on exactly the broken-accelerator
-    machine it exists to diagnose (an unreachable TPU plugin blocks
-    jax.devices() indefinitely)."""
-    import json
-    import subprocess
+def hardware_report():
+    """Device inventory, in-process: this process becomes the one that
+    holds the chip while it lives, which is what a report about the chip
+    should be (a probe child would be refused the chip by a parent that
+    already held it)."""
+    import jax
 
-    probe = (
-        # the env var alone does not override a registered accelerator
-        # plugin (see tests/conftest.py); the probe must pin via jax.config
-        "import json, os, jax;"
-        "jp = os.environ.get('JAX_PLATFORMS');"
-        "_ = jp and jax.config.update('jax_platforms', jp);"
-        "d = jax.devices();"
-        "print(json.dumps({'backend': jax.default_backend(),"
-        " 'count': len(d),"
-        " 'kind': str(getattr(d[0], 'device_kind', '?')),"
-        " 'processes': jax.process_count()}))")
-    rows = []
-    import os
-
-    env = dict(os.environ)
-    try:  # propagate an in-process platform pin (jax.config) to the probe
-        import jax
-
-        jp = jax.config.jax_platforms
-        if jp:
-            env["JAX_PLATFORMS"] = jp
-    except Exception:
-        pass
-    try:
-        r = subprocess.run([sys.executable, "-c", probe],
-                           capture_output=True, text=True,
-                           timeout=probe_timeout, env=env)
-        if r.returncode != 0 or not r.stdout.strip():
-            # surface the probe's real failure (e.g. missing jax, plugin
-            # crash), not a parse error — this is a diagnostic tool
-            err = (r.stderr or "").strip().splitlines()
-            rows.append(("jax devices",
-                         f"probe failed rc={r.returncode}: "
-                         f"{err[-1] if err else 'no output'}"))
-            return rows
-        info = json.loads(r.stdout.strip().splitlines()[-1])
-        rows.append(("backend", info["backend"]))
-        rows.append(("device count", str(info["count"])))
-        rows.append(("device kind", info["kind"]))
-        rows.append(("process count", str(info["processes"])))
-    except subprocess.TimeoutExpired:
-        rows.append(("jax devices",
-                     f"UNREACHABLE: device init hung >{probe_timeout}s "
-                     f"(accelerator plugin present but not responding)"))
-    except Exception as e:  # report must never crash
-        rows.append(("jax devices", f"error: {e}"))
-    return rows
+    devices = jax.devices()
+    return [("backend", jax.default_backend()),
+            ("device count", str(len(devices))),
+            ("device kind", str(devices[0].device_kind)),
+            ("process count", str(jax.process_count()))]
 
 
 def main(hide_operator_status=False, hide_errors_and_warnings=False):

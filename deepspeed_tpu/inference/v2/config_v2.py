@@ -85,8 +85,8 @@ class RaggedInferenceEngineConfig:
     # int8 KV-cache pool (~0.5x bf16 bytes -> ~2x tokens, i.e. ~2x
     # concurrent sequences at a fixed pool budget): writes quantize
     # against a running per-(block, kv-head) absmax, reads dequantize.
-    # Serves through the SAME Pallas decode/ragged kernels as bf16 — the
-    # quant kernel variants stream int8 pages + scale rows and
+    # Serves through the SAME Pallas decode/ragged kernels as bf16 — they
+    # stream the int8 pages, read the row's scales from SMEM and
     # dequantize in VMEM — so fused decode windows, the ragged unified
     # program and the SplitFuse fast path all keep their compiled shape.
     kv_quant: bool = False

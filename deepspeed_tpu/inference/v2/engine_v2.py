@@ -42,6 +42,7 @@ from ...telemetry import trace, watchdog
 from ...utils.bucketing import ceil_bucket, pow2_bucket
 from ...utils.logging import log_dist
 from .config_v2 import RaggedInferenceEngineConfig
+from .kernels.ragged_attention import kernel_variant
 from .paged_model import (init_lora_bank, init_paged_kv_cache,
                           paged_continue, paged_decode, paged_decode_window,
                           paged_prefill, paged_ragged_step,
@@ -270,16 +271,21 @@ class InferenceEngineV2:
         # Pallas kernels only at tp=1: a bare pallas_call is not
         # GSPMD-partitionable, so sharded-param (tp>1) serving keeps the
         # jnp paths, which the partitioner splits over the head axis (same
-        # gate as the v1 decode kernel, models/transformer.py). kv_quant
-        # no longer gates the decode/ragged kernels: the quant kernel
-        # variants stream the int8 pages + per-(block, head) scale rows
-        # and dequantize in VMEM (kernels/paged_attention.py,
-        # kernels/ragged_attention.py), so 2x KV capacity keeps the whole
-        # Pallas fast path — fused decode windows and the ragged family
-        # included
+        # gate as the v1 decode kernel, models/transformer.py). The
+        # kernels carry no alibi bias; the jnp paths add the
+        # softmax-invariant row. int8 kv_quant pools ride the same kernels
+        # (scales dequantize in VMEM). WHICH kernel variant serves the
+        # pool is a static function of its geometry
+        # (kernels/ragged_attention.kernel_variant, pinned against the
+        # Mosaic compiler by tests/unit/ops/test_kernels_lower_tpu.py) —
+        # resolved here once, readable as ``attention_impl`` and in
+        # /statusz (health.attention_impl), never by trying a compile
         use_kernel = (config.use_paged_kernel and tp == 1 and ep == 1
-                      and cfg.positional != "alibi")  # kernels carry no
-        # alibi bias; the jnp paths add the softmax-invariant row
+                      and cfg.positional != "alibi")
+        self.attention_impl = (
+            "pallas:" + kernel_variant(cfg.head_dim, cfg.kv_heads,
+                                       bool(config.kv_quant))
+            if use_kernel else "jnp:gather")
         topo = self.topology if ep > 1 else None
         # load_draft_model builds jits after __init__; it reuses the
         # same kernel gate and topology the serving programs resolved
@@ -391,7 +397,7 @@ class InferenceEngineV2:
         # bucket) — put() and the SplitFuse scheduler route here instead
         # of sequencing the prefill/continue/decode families. The ragged
         # kernel shares the decode kernel's gates (no alibi, tp=ep=1;
-        # int8 kv_quant pools ride the quant kernel variants); gated-off
+        # int8 kv_quant pools ride the same kernels); gated-off
         # configs serve through the jnp ragged fallback inside the same
         # unified program.
         self.ragged_enabled = self._resolve_ragged_mode(
@@ -442,7 +448,7 @@ class InferenceEngineV2:
         log_dist(
             f"ragged inference engine: blocks={sm.num_blocks}x"
             f"{sm.block_size} max_seqs={sm.max_tracked_sequences} tp={tp}"
-            f" ep={ep}",
+            f" ep={ep} attention={self.attention_impl}",
             ranks=[0])
 
     # ------------------------------------------------------------------

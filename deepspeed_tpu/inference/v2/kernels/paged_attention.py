@@ -1,4 +1,4 @@
-"""Paged (blocked-KV) decode attention — Pallas TPU kernel.
+"""Paged (blocked-KV) decode attention.
 
 TPU-native equivalent of the reference's blocked flash attention for ragged
 decode (inference/v2/kernels/ragged_ops/blocked_flash/ + the CUDA paged-KV
@@ -8,388 +8,30 @@ streaming each KV block from HBM into VMEM exactly once — no
 paged_model.py does materialize it, which is why this kernel is the
 serving hot path).
 
-Two implementations:
-
-* ``paged_attention`` (grid ``(N,)``, manual DMA) — the serving path. The
-  K/V pools stay HBM-resident (``memory_space=ANY``); the kernel walks
-  only the pages a sequence has actually filled (``ceil(len/bs)``, a
-  dynamic ``fori_loop`` bound) with double-buffered ``make_async_copy``,
-  so DMA traffic scales with real context length, not table width. The
-  r05 chip capture showed why this matters: the BlockSpec-pipelined
-  variant streams every one of the table's ``MB`` slots per sequence
-  (the copy happens regardless of the in-kernel ``pl.when`` skip), which
-  at prompt 128 in a 1024-token table wasted >80% of the bandwidth.
-* ``paged_attention_pipelined`` (grid ``(N, MB)``) — the original
-  BlockSpec-indexed variant, kept as the comparison point and for
-  interpret-mode parity tests on CPU.
-
-Each page step loads the block's K/V for ALL kv heads at once — the
-(block_size, kv_heads, head_dim) tile equals the array's trailing dims,
-which is what the Mosaic lowering requires (blocks must tile to (8, 128)
-or cover the dimension; a per-head (1, bs, 1, hd) block does not, and
-fails to lower on real TPU even though interpret mode accepts it — r05
-chip capture). GQA is a static Python loop over kv heads inside the
-kernel (kv_heads is a compile-time constant), each head updating its own
-rows of the flat (nh, ...) softmax scratch; position masking handles the
-partial last page.
+Decode is the ragged kernel (``ragged_attention.py``) with one token per
+row: the token -> row map is the identity and ``lengths`` is per
+sequence. One kernel family, one variant table
+(:func:`~.ragged_attention.kernel_variant`), one set of numerics.
 """
 
-import functools
+from typing import Optional
 
-import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-NEG_INF = -1e30
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
-def _dequant_tile(tile, srow, io_dtype):
-    """int8 page tile (bs, kvh, hd) x per-(block, head) scale row (kvh,)
-    -> fp32, routed through the pool's serving dtype so the in-kernel
-    dequant is the SAME arithmetic as paged_model._kv_read's gather
-    dequant (bit-identical at fp32 io; one rounding at bf16). This is
-    what lets int8 KV serve through the kernels instead of falling back
-    to the materializing gather path."""
-    deq = tile.astype(jnp.float32) * srow[None, :, None]
-    return deq.astype(io_dtype).astype(jnp.float32)
-
-
-def _page_update(q_ref, k_all, v_all, j, length, acc_sc, m_sc, l_sc,
-                 *, bs, scale, kvh, group):
-    """One page's online-softmax update, all kv heads (shared by both
-    kernels so their numerics cannot diverge). k_all/v_all are the
-    page's (bs, kvh, hd) tiles already in fp32; GQA is a static Python
-    loop (kvh is a compile-time constant), each head updating its own
-    rows of the flat (kvh*group, ...) scratch."""
-    pos = j * bs + jax.lax.broadcasted_iota(jnp.int32, (group, bs), 1)
-    for h in range(kvh):                              # static unroll (GQA)
-        rows = slice(h * group, (h + 1) * group)
-        q = q_ref[0, h].astype(jnp.float32)           # (group, hd)
-        k = k_all[:, h, :]                            # (bs, hd)
-        v = v_all[:, h, :]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        s = s * scale
-        s = jnp.where(pos < length, s, NEG_INF)
-        m_prev = m_sc[rows, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        l_sc[rows] = jnp.broadcast_to(
-            l_sc[rows, :1] * corr + jnp.sum(p, axis=1, keepdims=True),
-            (group, l_sc.shape[1]))
-        acc_sc[rows] = acc_sc[rows] * corr + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_sc[rows] = jnp.broadcast_to(m_new, (group, m_sc.shape[1]))
-
-
-def _finalize(o_ref, acc_sc, l_sc, *, kvh, group):
-    """Write acc/l to the output block (shared by both kernels)."""
-    for h in range(kvh):                              # static unroll
-        rows = slice(h * group, (h + 1) * group)
-        l = l_sc[rows, :1]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, h] = (acc_sc[rows] / l_safe).astype(o_ref.dtype)
-
-
-def _kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-            acc_sc, m_sc, l_sc, *, bs, n_pages, scale, kvh, group):
-    n = pl.program_id(0)
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _init():
-        acc_sc[:] = jnp.zeros_like(acc_sc)
-        m_sc[:] = jnp.full_like(m_sc, NEG_INF)
-        l_sc[:] = jnp.zeros_like(l_sc)
-
-    length = len_ref[n]
-
-    @pl.when(j * bs < length)
-    def _body():
-        _page_update(q_ref, k_ref[0].astype(jnp.float32),
-                     v_ref[0].astype(jnp.float32), j, length,
-                     acc_sc, m_sc, l_sc,
-                     bs=bs, scale=scale, kvh=kvh, group=group)
-
-    @pl.when(j == n_pages - 1)
-    def _finish():
-        _finalize(o_ref, acc_sc, l_sc, kvh=kvh, group=group)
-
-
-def _dma_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
-                k_sc, v_sc, acc_sc, m_sc, l_sc, sem,
-                *, bs, scale, kvh, group):
-    """Grid (N,): per sequence, double-buffered manual DMA over its USED
-    pages only. k_sc/v_sc are (2, bs, kvh, hd) VMEM slots; sem is a
-    (2, 2) DMA semaphore array (slot x {k, v})."""
-    n = pl.program_id(0)
-    length = len_ref[n]
-    n_pages = (length + bs - 1) // bs
-
-    acc_sc[:] = jnp.zeros_like(acc_sc)
-    m_sc[:] = jnp.full_like(m_sc, NEG_INF)
-    l_sc[:] = jnp.zeros_like(l_sc)
-
-    def k_dma(slot, j):
-        return pltpu.make_async_copy(
-            k_hbm.at[bt_ref[n, j]], k_sc.at[slot], sem.at[slot, 0])
-
-    def v_dma(slot, j):
-        return pltpu.make_async_copy(
-            v_hbm.at[bt_ref[n, j]], v_sc.at[slot], sem.at[slot, 1])
-
-    @pl.when(n_pages > 0)
-    def _start():
-        k_dma(0, 0).start()
-        v_dma(0, 0).start()
-
-    def body(j, _):
-        slot = jax.lax.rem(j, 2)
-        nxt = jax.lax.rem(j + 1, 2)
-
-        @pl.when(j + 1 < n_pages)
-        def _prefetch():
-            k_dma(nxt, j + 1).start()
-            v_dma(nxt, j + 1).start()
-
-        k_dma(slot, j).wait()
-        v_dma(slot, j).wait()
-        _page_update(q_ref, k_sc[slot].astype(jnp.float32),
-                     v_sc[slot].astype(jnp.float32), j, length,
-                     acc_sc, m_sc, l_sc,
-                     bs=bs, scale=scale, kvh=kvh, group=group)
-        return 0
-
-    jax.lax.fori_loop(0, n_pages, body, 0)
-
-    _finalize(o_ref, acc_sc, l_sc, kvh=kvh, group=group)
-
-
-def _dma_kernel_quant(bt_ref, len_ref, q_ref, k_hbm, v_hbm, ks_hbm, vs_hbm,
-                      o_ref, k_sc, v_sc, ks_sc, vs_sc, acc_sc, m_sc, l_sc,
-                      sem, *, bs, scale, kvh, group, io_dtype):
-    """Quantized-pool variant of ``_dma_kernel``: per walked page, the
-    int8 K/V tiles AND their (kvh,) per-block scale rows stream from HBM
-    (the scale copy is ~kvh*4 bytes riding the same double-buffer slots),
-    and the tile dequantizes in VMEM before the shared online-softmax
-    update. sem is (2, 4): slot x {k, v, ks, vs}."""
-    n = pl.program_id(0)
-    length = len_ref[n]
-    n_pages = (length + bs - 1) // bs
-
-    acc_sc[:] = jnp.zeros_like(acc_sc)
-    m_sc[:] = jnp.full_like(m_sc, NEG_INF)
-    l_sc[:] = jnp.zeros_like(l_sc)
-
-    def dmas(slot, j):
-        page = bt_ref[n, j]
-        return (pltpu.make_async_copy(k_hbm.at[page], k_sc.at[slot],
-                                      sem.at[slot, 0]),
-                pltpu.make_async_copy(v_hbm.at[page], v_sc.at[slot],
-                                      sem.at[slot, 1]),
-                pltpu.make_async_copy(ks_hbm.at[page], ks_sc.at[slot],
-                                      sem.at[slot, 2]),
-                pltpu.make_async_copy(vs_hbm.at[page], vs_sc.at[slot],
-                                      sem.at[slot, 3]))
-
-    @pl.when(n_pages > 0)
-    def _start():
-        for d in dmas(0, 0):
-            d.start()
-
-    def body(j, _):
-        slot = jax.lax.rem(j, 2)
-        nxt = jax.lax.rem(j + 1, 2)
-
-        @pl.when(j + 1 < n_pages)
-        def _prefetch():
-            for d in dmas(nxt, j + 1):
-                d.start()
-
-        for d in dmas(slot, j):
-            d.wait()
-        _page_update(q_ref,
-                     _dequant_tile(k_sc[slot], ks_sc[slot], io_dtype),
-                     _dequant_tile(v_sc[slot], vs_sc[slot], io_dtype),
-                     j, length, acc_sc, m_sc, l_sc,
-                     bs=bs, scale=scale, kvh=kvh, group=group)
-        return 0
-
-    jax.lax.fori_loop(0, n_pages, body, 0)
-
-    _finalize(o_ref, acc_sc, l_sc, kvh=kvh, group=group)
-
-
-def _kernel_quant(bt_ref, len_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
-                  o_ref, acc_sc, m_sc, l_sc, *, bs, n_pages, scale, kvh,
-                  group, io_dtype):
-    """Quantized-pool variant of ``_kernel``: the BlockSpec pipeline also
-    streams each page's (1, kvh) scale rows; dequant happens in VMEM
-    before the shared update."""
-    n = pl.program_id(0)
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _init():
-        acc_sc[:] = jnp.zeros_like(acc_sc)
-        m_sc[:] = jnp.full_like(m_sc, NEG_INF)
-        l_sc[:] = jnp.zeros_like(l_sc)
-
-    length = len_ref[n]
-
-    @pl.when(j * bs < length)
-    def _body():
-        _page_update(q_ref,
-                     _dequant_tile(k_ref[0], ks_ref[0], io_dtype),
-                     _dequant_tile(v_ref[0], vs_ref[0], io_dtype),
-                     j, length, acc_sc, m_sc, l_sc,
-                     bs=bs, scale=scale, kvh=kvh, group=group)
-
-    @pl.when(j == n_pages - 1)
-    def _finish():
-        _finalize(o_ref, acc_sc, l_sc, kvh=kvh, group=group)
+from .ragged_attention import ragged_attention
 
 
 def paged_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
                     v_cache: jnp.ndarray, block_tables: jnp.ndarray,
                     lengths: jnp.ndarray,
                     k_scale: jnp.ndarray = None,
-                    v_scale: jnp.ndarray = None) -> jnp.ndarray:
-    """Manual-DMA paged decode attention (serving hot path).
-
-    q [N, nh, hd]; k/v_cache [nb, bs, kvh, hd]; block_tables [N, MB]
-    int32; lengths [N] (valid tokens incl. the current one). For the
-    int8 ``kv_quant`` pool, ``k_scale``/``v_scale`` [nb, kvh] are the
-    per-(block, head) dequant scales and the kernel dequantizes each
-    streamed tile in VMEM — int8 KV stays on the kernel fast path.
+                    v_scale: jnp.ndarray = None,
+                    variant: Optional[str] = None) -> jnp.ndarray:
+    """q [N, nh, hd]; k/v_cache [nb, bs, kvh, hd]; block_tables [N, MB]
+    int32; lengths [N] (valid tokens incl. the current one);
+    ``k_scale``/``v_scale`` [nb, kvh] for the int8 ``kv_quant`` pool.
     Returns [N, nh, hd]."""
-    if _interpret():
-        # interpret mode does not reliably simulate the manual
-        # DMA/semaphore protocol (observed to wedge on CPU); the
-        # BlockSpec-pipelined variant is numerically identical and keeps
-        # CPU tests meaningful. The DMA path is chip-verified instead
-        # (scripts/paged_kernel_chip.py -> artifacts/r05/paged_kernel_chip.json).
-        return paged_attention_pipelined(q, k_cache, v_cache,
-                                         block_tables, lengths,
-                                         k_scale=k_scale, v_scale=v_scale)
-    N, nh, hd = q.shape
-    nb, bs, kvh, _ = k_cache.shape
-    group = nh // kvh
-    scale = 1.0 / (hd ** 0.5)
-    q4 = q.reshape(N, kvh, group, hd)
-    quant = k_scale is not None
-
-    if quant:
-        kernel = functools.partial(_dma_kernel_quant, bs=bs, scale=scale,
-                                   kvh=kvh, group=group, io_dtype=q.dtype)
-        extra_in = [pl.BlockSpec(memory_space=pltpu.ANY),   # K scales
-                    pl.BlockSpec(memory_space=pltpu.ANY)]   # V scales
-        extra_scratch = [pltpu.VMEM((2, kvh), jnp.float32),
-                         pltpu.VMEM((2, kvh), jnp.float32)]
-        sem = pltpu.SemaphoreType.DMA((2, 4))
-        operands = (q4, k_cache, v_cache, k_scale.astype(jnp.float32),
-                    v_scale.astype(jnp.float32))
-    else:
-        kernel = functools.partial(_dma_kernel, bs=bs, scale=scale,
-                                   kvh=kvh, group=group)
-        extra_in, extra_scratch = [], []
-        sem = pltpu.SemaphoreType.DMA((2, 2))
-        operands = (q4, k_cache, v_cache)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(N,),
-        in_specs=[
-            pl.BlockSpec((1, kvh, group, hd), lambda n, bt, ln: (n, 0, 0, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),     # K pool stays in HBM
-            pl.BlockSpec(memory_space=pltpu.ANY),     # V pool stays in HBM
-        ] + extra_in,
-        out_specs=pl.BlockSpec((1, kvh, group, hd),
-                               lambda n, bt, ln: (n, 0, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((2, bs, kvh, hd), k_cache.dtype),
-            pltpu.VMEM((2, bs, kvh, hd), v_cache.dtype),
-        ] + extra_scratch + [
-            pltpu.VMEM((kvh * group, hd), jnp.float32),
-            pltpu.VMEM((kvh * group, 128), jnp.float32),
-            pltpu.VMEM((kvh * group, 128), jnp.float32),
-            sem,
-        ],
-    )
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((N, kvh, group, hd), q.dtype),
-        # never interpret: the early return above already routed interpret
-        # mode to the pipelined variant (the DMA protocol wedges there)
-        interpret=False,
-    )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32),
-      *operands)
-    return out.reshape(N, nh, hd)
-
-
-def paged_attention_pipelined(q: jnp.ndarray, k_cache: jnp.ndarray,
-                              v_cache: jnp.ndarray,
-                              block_tables: jnp.ndarray,
-                              lengths: jnp.ndarray,
-                              k_scale: jnp.ndarray = None,
-                              v_scale: jnp.ndarray = None) -> jnp.ndarray:
-    """BlockSpec-pipelined variant (streams all MB table slots; kept for
-    comparison + interpret-mode coverage). Same signature as
-    paged_attention."""
-    N, nh, hd = q.shape
-    nb, bs, kvh, _ = k_cache.shape
-    MB = block_tables.shape[1]
-    group = nh // kvh
-    scale = 1.0 / (hd ** 0.5)
-    q4 = q.reshape(N, kvh, group, hd)
-    quant = k_scale is not None
-
-    if quant:
-        kernel = functools.partial(_kernel_quant, bs=bs, n_pages=MB,
-                                   scale=scale, kvh=kvh, group=group,
-                                   io_dtype=q.dtype)
-        extra_in = [pl.BlockSpec((1, kvh),
-                                 lambda n, j, bt, ln: (bt[n, j], 0)),
-                    pl.BlockSpec((1, kvh),
-                                 lambda n, j, bt, ln: (bt[n, j], 0))]
-        operands = (q4, k_cache, v_cache, k_scale.astype(jnp.float32),
-                    v_scale.astype(jnp.float32))
-    else:
-        kernel = functools.partial(_kernel, bs=bs, n_pages=MB, scale=scale,
-                                   kvh=kvh, group=group)
-        extra_in = []
-        operands = (q4, k_cache, v_cache)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(N, MB),
-        in_specs=[
-            pl.BlockSpec((1, kvh, group, hd),
-                         lambda n, j, bt, ln: (n, 0, 0, 0)),
-            pl.BlockSpec((1, bs, kvh, hd),
-                         lambda n, j, bt, ln: (bt[n, j], 0, 0, 0)),
-            pl.BlockSpec((1, bs, kvh, hd),
-                         lambda n, j, bt, ln: (bt[n, j], 0, 0, 0)),
-        ] + extra_in,
-        out_specs=pl.BlockSpec((1, kvh, group, hd),
-                               lambda n, j, bt, ln: (n, 0, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((kvh * group, hd), jnp.float32),
-            pltpu.VMEM((kvh * group, 128), jnp.float32),
-            pltpu.VMEM((kvh * group, 128), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((N, kvh, group, hd), q.dtype),
-        interpret=_interpret(),
-    )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32),
-      *operands)
-    return out.reshape(N, nh, hd)
+    return ragged_attention(
+        q, k_cache, v_cache, jnp.arange(q.shape[0], dtype=jnp.int32),
+        lengths, block_tables, k_scale=k_scale, v_scale=v_scale,
+        variant=variant)

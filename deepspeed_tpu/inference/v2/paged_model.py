@@ -51,10 +51,10 @@ def init_paged_kv_cache(cfg: TransformerConfig, num_blocks: int,
     ~2x the tokens. Writes quantize against a running per-block absmax
     (requantizing the block's earlier content when the scale grows);
     reads dequantize. The per-block granularity is what lets the Pallas
-    decode/ragged kernels dequantize IN-KERNEL: one (kvh,) scale row per
-    streamed page tile, so int8 KV serves through the same one-program
-    kernel family as bf16 (kernels/paged_attention.py ragged_attention.py
-    quant variants). Scales init to 0 = "nothing written"."""
+    decode/ragged kernels dequantize IN-KERNEL: one scale per (streamed
+    page, kv head), so int8 KV serves through the same one-program
+    kernel family as bf16 (kernels/ragged_attention.py). Scales init to
+    0 = "nothing written"."""
     assert cfg.is_causal and cfg.norm_scheme == "pre", \
         "paged serving requires a causal pre-LN model (the MLM/post-LN " \
         "encoder family does not decode)"
@@ -117,7 +117,7 @@ def _cache_dict(kc, vc, ksc, vsc):
 def _kv_read(kc, ksc, l, table, dtype):
     """Gather pages [*, bs, kvh, hd], dequantizing when scales exist
     (per-block scale row broadcast over the page's slot and head-dim
-    axes — the same multiply the kernels' quant variants run per tile,
+    axes — the same multiply the kernels run per head slice of a page,
     so kernel and gather dequant agree bit-for-bit at fp32)."""
     pages = kc[l][table]
     if ksc is None:

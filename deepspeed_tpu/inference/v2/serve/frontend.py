@@ -463,6 +463,10 @@ class ServingEngine:
             # blue/green rollout signal (serve/weights.py): the router
             # converges the fleet onto one target version off this field
             "weight_version": self.weight_version,
+            # which attention path the engine resolved at construction
+            # ("pallas:dma" | "pallas:pipelined" | "jnp:gather")
+            "attention_impl": getattr(self.scheduler.engine,
+                                      "attention_impl", None),
             # spill-aware placement signal (ragged/spill.py): the bloom
             # summary of this replica's spilled digests rides every
             # heartbeat, so the router can place a returning

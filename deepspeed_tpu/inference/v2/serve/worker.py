@@ -1,5 +1,10 @@
 """Replica worker process: one ServingEngine behind the HTTP API.
 
+One worker per chip, by design — a chip belongs to one process at a time.
+The process that SPAWNS workers must therefore stay off JAX: a parent that
+has touched ``jax.devices()`` holds the chip, and the worker then fails or
+hangs at start-up.
+
 ``python -m deepspeed_tpu.inference.v2.serve.worker`` hosts ONE
 in-process :class:`~.replica.Replica` (engine + serving runtime) behind
 the serve/api.py surface plus the worker-only endpoints the remote
@@ -705,9 +710,6 @@ def main(argv=None) -> int:
     p.add_argument("--jax-platform", default=None,
                    help="force a jax platform (e.g. 'cpu' for the "
                         "chip-free smoke; default: whatever jax picks)")
-    p.add_argument("--compile-cache", default=None,
-                   help="persistent XLA compilation cache dir "
-                        "(default: $DS_TPU_COMPILE_CACHE if set)")
     p.add_argument("--resume-linger-s", type=float, default=2.0,
                    help="seconds a request stays resumable (KV held) "
                         "after a bare client connection loss before it "
@@ -725,13 +727,8 @@ def main(argv=None) -> int:
     import jax
     if args.jax_platform:
         jax.config.update("jax_platforms", args.jax_platform)
-    cache = args.compile_cache or os.environ.get("DS_TPU_COMPILE_CACHE")
-    if cache:
-        os.makedirs(cache, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", cache)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          0.5)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    from ....utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.spec:
         with open(args.spec) as fh:
             spec = json.load(fh)

@@ -179,8 +179,8 @@ def moe_layer_manual(x, gate_w, expert_params_local, expert_fn,
     routing), which is what makes this legal inside the compiled pipeline.
     """
     B, S, H = x.shape
-    from ..comm.quantized import _one_axis_size
-    ep = _one_axis_size(ep_axis)
+    from ..comm.quantized import _axis_size
+    ep = _axis_size(ep_axis)
     xt = x.reshape(B * S, H)
     E = gate_w.shape[-1]
     assert E % ep == 0, f"num_experts {E} not divisible by ep {ep}"

@@ -6,8 +6,8 @@ constant from one autotune run. This module provides the measured versions:
 
   * ``parity_check``     — runs the Pallas kernel AND the jnp reference on
     the current backend (the real chip when present) and returns the max
-    abs/rel error, fwd and grads. bench.py records it every round, so each
-    BENCH_r*.json carries on-chip parity evidence.
+    abs/rel error, fwd and grads. chip_smoke.py (phase K) and bench.py
+    run it on the chip.
   * ``measure_crossover`` — times flash vs XLA attention (fwd+bwd) at a
     ladder of sequence lengths for a given head geometry and returns the
     smallest S where flash wins (the measured value for

@@ -28,10 +28,7 @@ from ...utils.logging import logger
 def _cost_analysis(fn: Callable, *args, **kwargs) -> Dict[str, float]:
     """XLA cost analysis of fn(*args): {'flops': ..., 'bytes accessed': ...}."""
     compiled = jax.jit(fn).lower(*args, **kwargs).compile()
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):  # older jax returns [dict]
-        ca = ca[0] if ca else {}
-    return dict(ca or {})
+    return dict(compiled.cost_analysis() or {})
 
 
 def params_count(params) -> int:

@@ -602,19 +602,6 @@ def overlap_blockers(engine, forced: bool) -> List[Tuple[str, str]]:
     return out
 
 
-def partial_manual_supported() -> bool:
-    """Partial-manual shard_map (manual dp axes, auto tp/sp axes) needs the
-    jax>=0.5 shard_map: the legacy experimental fallback's ``auto=`` path
-    makes this jaxlib's SPMD partitioner hard-CHECK-fail (process abort,
-    ``IsManualSubgroup``) on any collective under remaining auto axes —
-    reject BEFORE compile, a Python error beats a SIGABRT."""
-    try:
-        from jax import shard_map  # noqa: F401
-        return True
-    except ImportError:
-        return False
-
-
 def resolve_overlap_mode(engine, use_zeropp: bool) -> str:
     """'bucketed' | 'off' for this engine build.
 
@@ -862,13 +849,6 @@ def make_overlapped_grad_fn(engine, zpp_w: bool, zpp_g: bool):
     # manual over the DP axes only, and specs mention only those (GSPMD
     # keeps the "model"/"seq"-axis collectives inside model.apply)
     tp = (topo.axis_size("model") > 1 or topo.axis_size("seq") > 1)
-    if tp and not partial_manual_supported():
-        raise NotImplementedError(
-            "tensor/sequence parallelism x the manual gradient program "
-            "(qwZ/qgZ/bucketed reduction) needs partial-manual shard_map "
-            "(jax >= 0.5); this jax's fallback aborts the process in the "
-            "SPMD partitioner. Disable zero_quantized_weights/gradients "
-            "and overlap_grad_reduce for tp/sp runs on this jax.")
     inline_last = not tp
     manual = tuple(axes)
 

@@ -11,8 +11,8 @@ resident training) and moves only the STORAGE to the host:
   * fp32 master weights and optimizer moments live in host memory —
     as ``memory_kind="pinned_host"`` jax arrays where this runtime
     supports committing them there (:func:`pinned_host_supported`), and
-    as plain numpy staging buffers otherwise (the jax-0.4.37 CPU image
-    tier-1 runs on takes this fallback);
+    as plain numpy staging buffers otherwise (jax 0.9 places them on the
+    CPU backend too, so tier-1 runs the pinned path);
   * the update streams BUCKET by BUCKET: leaf-aligned groups capped at
     ``zero_optimization.stage3_prefetch_bucket_size`` elements (the
     same knob that sizes the reference's stage-3 prefetch), so HBM
@@ -57,9 +57,9 @@ _PINNED_SUPPORT: Optional[bool] = None
 
 def pinned_host_supported() -> bool:
     """Can this runtime COMMIT an array to a ``pinned_host`` memory
-    space? Probed once per process: jax-0.4.37 on the CPU backend
-    parses the memory kind but fails placement, which is exactly the
-    case the numpy staging fallback exists for."""
+    space? Probed once per process: a backend that parses the memory
+    kind but fails placement is exactly the case the numpy staging
+    fallback exists for."""
     global _PINNED_SUPPORT
     if _PINNED_SUPPORT is None:
         try:

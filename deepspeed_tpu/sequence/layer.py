@@ -32,37 +32,6 @@ def seq_all_to_all(x, axis_name: str, scatter_dim: int, gather_dim: int):
                           concat_axis=gather_dim, tiled=True)
 
 
-def _axis_bound(name: str) -> bool:
-    """Older jax: an axis name resolves in the tracing axis env exactly
-    when an enclosing shard_map (or pmap) binds it as manual."""
-    try:
-        jax.core.axis_frame(name)
-        return True
-    except Exception:
-        return False
-
-
-def _inside_manual_region(mesh=None) -> bool:
-    """True when tracing inside an enclosing FULLY-manual shard_map (every
-    mesh axis manual — e.g. the pipeline program or the bucketed gradient
-    program on a pure-dp mesh). Partial-manual regions (manual dp, auto
-    tp/sp) return False: the nested attention shard_map over the auto axes
-    stays legal and required."""
-    try:
-        amesh = jax.sharding.get_abstract_mesh()
-        types = getattr(amesh, "axis_types", ())
-        return bool(amesh.shape) and bool(types) and all(
-            "Manual" in str(t) for t in types)
-    except AttributeError:
-        pass  # jax<0.5: no abstract-mesh introspection; probe the axis env
-    except Exception:
-        return False
-    if mesh is None:
-        return False
-    names = getattr(mesh, "axis_names", ())
-    return bool(names) and all(_axis_bound(n) for n in names)
-
-
 def _inner_attention(q, k, v, causal, use_flash, block_q, block_kv, sp_size,
                      impl="ulysses", scale=None):
     """Runs on local shards inside shard_map. q/k/v: [B_l, H_l, S_l, D]."""
@@ -108,14 +77,21 @@ def sharded_attention(q, k, v, topo: Optional[MeshTopology], causal: bool = True
     repartition, reference sequence/layer.py) or "ring" (blockwise ring
     attention, ring_attention.py).
     """
-    if topo is None or _inside_manual_region(topo.mesh):
-        # already under a fully-manual shard_map (the pipeline region or
-        # the bucketed gradient program): arrays are local shards, call
-        # the kernel directly
+    # the mesh axes an enclosing shard_map already made manual (none
+    # outside any shard_map)
+    context_mesh = jax.sharding.get_abstract_mesh()
+    manual = frozenset(context_mesh.manual_axes)
+    if topo is None or manual >= frozenset(topo.mesh.axis_names):
+        # no mesh, or already under a FULLY-manual shard_map (the pipeline
+        # region or the bucketed gradient program on a pure-dp mesh):
+        # arrays are local shards, call the kernel directly
         return _inner_attention(q, k, v, causal, use_flash, block_q, block_kv,
                                 1, scale=scale)
     sp = topo.axis_size(SEQ_AXIS)
-    dp_axes = topo.batch_axes
+    # inside a partial-manual region (manual dp, auto tp/sp — the bucketed
+    # gradient program) the batch is already local over the manual axes;
+    # the nested shard_map maps only what is still auto
+    dp_axes = tuple(a for a in topo.batch_axes if a not in manual)
     dp_total = 1
     for a in dp_axes:
         dp_total *= topo.axis_size(a)
@@ -130,11 +106,20 @@ def sharded_attention(q, k, v, topo: Optional[MeshTopology], causal: bool = True
     fn = partial(_inner_attention, causal=causal, use_flash=use_flash,
                  block_q=block_q, block_kv=block_kv, sp_size=sp, impl=impl,
                  scale=scale)
+    if manual:
+        # a nested shard_map must be handed the CONTEXT's abstract mesh
+        # (its axis types record which axes are already manual), not the
+        # concrete all-auto topo.mesh
+        mesh = context_mesh
+        axis_names = frozenset(mesh.axis_names) - manual
+    else:
+        mesh, axis_names = topo.mesh, None
     # replication checking off: pallas_call outputs don't carry vma metadata
     from ..comm.quantized import shard_map_unchecked
-    return shard_map_unchecked(fn, mesh=topo.mesh,
+    return shard_map_unchecked(fn, mesh=mesh,
                                in_specs=(qkv_spec, qkv_spec, qkv_spec),
-                               out_specs=qkv_spec)(q, k, v)
+                               out_specs=qkv_spec,
+                               axis_names=axis_names)(q, k, v)
 
 
 def ulysses_attention(q, k, v, causal: bool = True, use_flash: bool = True,

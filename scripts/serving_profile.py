@@ -41,8 +41,8 @@ def main():
     ap.add_argument("--out", default="artifacts/r05/serving_profile.json")
     args = ap.parse_args()
 
-    from __graft_entry__ import _ensure_jax_platform
-    _ensure_jax_platform()
+    from deepspeed_tpu.accelerator.tpu_accelerator import require_tpu
+    require_tpu()
     import jax
     import jax.numpy as jnp
 

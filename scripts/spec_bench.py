@@ -17,12 +17,9 @@ sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent.parent))
 
 
 def main():
-    from __graft_entry__ import _ensure_jax_platform
-    _ensure_jax_platform()
+    from deepspeed_tpu.accelerator.tpu_accelerator import require_tpu
+    require_tpu()
     import jax
-    if jax.default_backend() != "tpu":
-        print(json.dumps({"error": "needs the chip"}))
-        return 1
 
     from deepspeed_tpu.benchmarks.serving_bench import build_model
     from deepspeed_tpu.inference.v2.engine_v2 import InferenceEngineV2

@@ -216,7 +216,7 @@ def test_worker_subprocess_spawn_drain_kill(tmp_path):
     env["JAX_PLATFORMS"] = "cpu"
     # ISOLATED compile cache: a worker SIGKILLed on a failure path must
     # never be able to poison the shared suite cache
-    env["DS_TPU_COMPILE_CACHE"] = str(tmp_path / "xla-cache")
+    env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "xla-cache")
     # the spawn helper owns the handshake: ready-line wait under an
     # explicit timeout, stderr surfaced if the worker dies first
     proc, info = spawn_worker(

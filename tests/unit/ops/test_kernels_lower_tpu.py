@@ -1,0 +1,240 @@
+"""Chip-free Mosaic lowering: every Pallas kernel on the two main paths,
+AOT-compiled for a v5e with the local libtpu as a host compiler.
+
+``jax.experimental.topologies`` describes a ``v5e:2x2`` slice with no
+device attached; a jit lowered against one of its devices runs the real
+TPU pipeline, Mosaic included (~3 s for the topology, ~1 s per kernel).
+What this pins, so that the table in the code and the compiler cannot
+drift apart:
+
+* for each (head_dim, kv_heads, KV dtype) row and each paged-attention
+  variant, either the kernel compiles or the engine's static gate
+  (``kernel_variant``) does not select that variant for that row;
+* int8 KV compiles in the variant that serves it;
+* flash fwd+bwd compile at the head dims the trainer uses;
+* the whole serving programs (ragged step, fused decode window with
+  greedy and sampled picks, prefill) compile at OPT-1.3B's geometry — the
+  one the manual-DMA kernel refuses.
+
+This is the pre-check that costs no chip time: run it before
+``chip_smoke.py``. It says nothing about speed or numerics; phase K of
+chip_smoke.py compares the same kernels with their references on the chip.
+"""
+
+import dataclasses
+import itertools
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from deepspeed_tpu.inference.v2.kernels.ragged_attention import (
+    VARIANTS, kernel_variant, ragged_attention)
+
+
+def _topologies_available():
+    try:
+        from jax.experimental import topologies
+        topologies.get_topology_desc("v5e:2x2", platform="tpu")
+        return True
+    except Exception:
+        return False
+
+
+pytestmark = pytest.mark.skipif(
+    not _topologies_available(),
+    reason="libtpu topology descriptions unavailable on this host")
+
+HEAD_DIMS = (64, 96, 128, 256)
+KV_HEADS = (1, 4, 8, 12, 32)
+# the tier-1 dozen: the two geometries chip_smoke.py serves, the published
+# shapes finding 1 named (GPT-2/OPT-125M 12x64, Falcon-7B MQA, Phi 96-wide,
+# Gemma 256-wide), and the edges of the DMA rule (kv_heads 4, 12)
+TIER1_ROWS = (
+    (64, 32, False), (64, 32, True), (64, 12, False), (64, 1, False),
+    (96, 32, False), (128, 8, False), (128, 8, True), (128, 4, True),
+    (128, 12, False), (128, 1, True), (256, 8, False), (256, 32, True),
+)
+ALL_ROWS = tuple(itertools.product(HEAD_DIMS, KV_HEADS, (False, True)))
+
+
+@pytest.fixture(scope="module")
+def tpu_sharding():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    desc = topologies.get_topology_desc("v5e:2x2", platform="tpu")
+    return SingleDeviceSharding(desc.devices[0])
+
+
+@pytest.fixture(autouse=True)
+def _trace_for_tpu(monkeypatch):
+    """Every kernel module asks ``jax.default_backend()`` whether to run
+    in interpret mode; the programs here are compiled FOR the TPU from a
+    CPU host, so answer for the target."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def _compile_error(fn, *args):
+    """None if ``fn`` compiles for the arguments' device, else the
+    compiler's message."""
+    try:
+        jax.jit(fn).lower(*args).compile()
+    except Exception as e:  # noqa: BLE001 — Mosaic raises several types
+        return str(e)
+    return None
+
+
+def _paged_args(sds, hd, kvh, quant):
+    T, R, nb, bs, MB = 8, 4, 64, 16, 8
+    nh = kvh if kvh >= 12 else 32
+    pool = sds((nb, bs, kvh, hd), jnp.int8 if quant else jnp.bfloat16)
+    args = [sds((T, nh, hd), jnp.bfloat16), pool, pool,
+            sds((T,), jnp.int32), sds((T,), jnp.int32),
+            sds((R, MB), jnp.int32)]
+    if quant:
+        args += [sds((nb, kvh), jnp.float32)] * 2
+    return args
+
+
+def _check_rows(rows, sharding, variants):
+    """For each (row, variant): it compiles, or the gate does not select
+    it. A variant the gate does not select is only compiled when the
+    caller asks for it (the slow full table does, to show where the gate
+    is conservative)."""
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    failures = []
+    for hd, kvh, quant in rows:
+        chosen = kernel_variant(hd, kvh, quant)
+        for variant in variants or (chosen,):
+            err = _compile_error(
+                lambda *a, _v=variant: ragged_attention(
+                    *a[:6], k_scale=a[6] if quant else None,
+                    v_scale=a[7] if quant else None, variant=_v),
+                *_paged_args(sds, hd, kvh, quant))
+            if err is not None and chosen == variant:
+                failures.append(
+                    f"hd={hd} kvh={kvh} {'int8' if quant else 'bf16'}: "
+                    f"the gate selects {variant!r} and Mosaic refuses it: "
+                    f"{err[:300]}")
+    assert not failures, "\n".join(failures)
+
+
+def test_gate_matches_compiler_tier1_rows(tpu_sharding):
+    _check_rows(TIER1_ROWS, tpu_sharding, variants=None)
+
+
+@pytest.mark.slow   # 80 compiles; the tier-1 dozen above is its sibling
+def test_gate_matches_compiler_full_table(tpu_sharding):
+    _check_rows(ALL_ROWS, tpu_sharding, variants=VARIANTS)
+
+
+def test_gate_uses_dma_where_the_issue_found_it_compiles():
+    """The gate is a table, not 'always pipelined': lane-dense pools keep
+    the manual-DMA variant (traffic scales with context, not table
+    width), the refused geometries do not."""
+    for hd, kvh in ((128, 8), (128, 4), (128, 16), (256, 16), (128, 32)):
+        assert kernel_variant(hd, kvh, False) == "dma"
+        assert kernel_variant(hd, kvh, True) == "dma"
+    for hd, kvh in ((64, 32), (64, 12), (80, 32), (96, 32), (128, 1),
+                    (128, 12)):
+        assert kernel_variant(hd, kvh, False) == "pipelined"
+        assert kernel_variant(hd, kvh, True) == "pipelined"
+
+
+@pytest.mark.parametrize("hd,kv_heads", [(64, 4), (128, 2)])
+def test_flash_fwd_bwd_compile(tpu_sharding, hd, kv_heads):
+    from deepspeed_tpu.ops.flash_attention import flash_attention
+
+    def sds(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16,
+                                    sharding=tpu_sharding)
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, causal=True)
+                       .astype(jnp.float32) ** 2)
+
+    q, kv = sds((1, 4, 2048, hd)), sds((1, kv_heads, 2048, hd))
+    err = _compile_error(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
+    assert err is None, err
+
+
+# ---------------------------------------------------------------------------
+# whole serving programs at OPT-1.3B's geometry (depth cut to 2)
+# ---------------------------------------------------------------------------
+def _serving_case(sharding, kv_quant):
+    from deepspeed_tpu.inference.v2.paged_model import init_paged_kv_cache
+    from deepspeed_tpu.models import TransformerLM
+    from deepspeed_tpu.models.transformer import opt_1_3b
+
+    cfg = dataclasses.replace(opt_1_3b(), num_layers=2)
+
+    def on_tpu(x, dtype=None):
+        return jax.ShapeDtypeStruct(x.shape, dtype or x.dtype,
+                                    sharding=sharding)
+
+    params = jax.tree.map(
+        lambda x: on_tpu(x, jnp.bfloat16),
+        jax.eval_shape(TransformerLM(cfg).init_params,
+                       jax.random.PRNGKey(0)))
+    cache = jax.tree.map(on_tpu, jax.eval_shape(
+        lambda: init_paged_kv_cache(cfg, 129, 16, jnp.bfloat16,
+                                    kv_quant=kv_quant)))
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=sharding)
+
+    return cfg, params, cache, i32
+
+
+@pytest.mark.parametrize("kv_quant", [False, True])
+def test_opt_ragged_step_compiles(tpu_sharding, kv_quant):
+    from deepspeed_tpu.inference.v2.paged_model import paged_ragged_step
+    cfg, params, cache, i32 = _serving_case(tpu_sharding, kv_quant)
+    T, R, MB = 64, 8, 16
+    err = _compile_error(
+        lambda p, ids, rows, pos, ln, wb, wo, bt, li, c: paged_ragged_step(
+            cfg, p, ids, rows, pos, ln, wb, wo, bt, li, c, 16,
+            use_kernel=True),
+        params, i32(T), i32(T), i32(T), i32(T), i32(T), i32(T), i32(R, MB),
+        i32(R), cache)
+    assert err is None, err
+
+
+@pytest.mark.slow   # siblings: the ragged-step rows above, same kernel
+@pytest.mark.parametrize("kv_quant,sampled", [(False, False), (True, True)])
+def test_opt_decode_window_compiles(tpu_sharding, kv_quant, sampled):
+    """The fused K-step window with the greedy pick and with the
+    per-row-keyed sampler (sampling.py) inside the device loop."""
+    from deepspeed_tpu.inference.v2.paged_model import paged_decode_window
+    cfg, params, cache, i32 = _serving_case(tpu_sharding, kv_quant)
+    N, MB = 8, 16
+
+    def f32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32,
+                                    sharding=tpu_sharding)
+
+    def window(p, t, pos, bt, c, sl, eos, seeds, g0, temp, topp, topk):
+        kw = (dict(rng=jax.random.PRNGKey(0), row_seeds=seeds, gen_idx0=g0,
+                   temp=temp, topp=topp, topk=topk) if sampled else {})
+        return paged_decode_window(cfg, p, t, pos, bt, c, sl, eos, 16, 8,
+                                   use_kernel=True, **kw)
+
+    err = _compile_error(window, params, i32(N), i32(N), i32(N, MB), cache,
+                         i32(N), i32(N), i32(N), i32(N), f32(N), f32(N),
+                         i32(N))
+    assert err is None, err
+
+
+@pytest.mark.slow   # the flash kernel it uses is pinned tier-1 above
+def test_opt_prefill_compiles(tpu_sharding):
+    from deepspeed_tpu.inference.v2.paged_model import paged_prefill
+    cfg, params, cache, i32 = _serving_case(tpu_sharding, False)
+    C = 256
+    err = _compile_error(
+        lambda p, ids, n, c, b, o: paged_prefill(cfg, p, ids, n, c, b, o,
+                                                 use_kernel=True),
+        params, i32(1, C), i32(), cache, i32(C), i32(C))
+    assert err is None, err

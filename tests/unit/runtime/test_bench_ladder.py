@@ -1,5 +1,7 @@
 """The bench mini-autotune ladder only ever CONSTRUCTS on a real chip;
-this pins its shape off-chip so edits can't silently break the autotune."""
+this pins its shape off-chip so edits can't silently break the autotune,
+and pins what the ladder may skip: a rung that does not fit the chip,
+nothing else."""
 
 import sys
 
@@ -89,3 +91,44 @@ def test_indivisible_gqa_pair_fails_at_config_time():
                              num_heads=8, num_kv_heads=8, max_seq_len=128)
     with pytest.raises(ValueError, match="GQA requires"):
         dataclasses.replace(base, hidden_size=768, num_heads=12)
+
+
+def _bench_on_a_fake_chip(monkeypatch, measure):
+    """bench.main() up to its first measurement, with the chip check and
+    the measurement replaced."""
+    sys.path.insert(0, ".")
+    import bench
+    from deepspeed_tpu.accelerator import tpu_accelerator
+    monkeypatch.setenv("LIBTPU_INIT_ARGS", "")     # main() exports flags
+    monkeypatch.setattr(tpu_accelerator, "require_tpu", lambda: [object()])
+    monkeypatch.setattr(bench, "_measure", measure)
+    return bench
+
+
+def test_ladder_skips_only_out_of_memory(monkeypatch, capsys):
+    def oom(*a, **k):
+        raise RuntimeError("RESOURCE_EXHAUSTED: Ran out of memory in hbm")
+
+    bench = _bench_on_a_fake_chip(monkeypatch, oom)
+    with pytest.raises(RuntimeError, match="no bench config fits"):
+        bench.main([])
+
+    def broken(*a, **k):
+        raise ValueError("Mosaic failed to compile TPU kernel")
+
+    bench = _bench_on_a_fake_chip(monkeypatch, broken)
+    with pytest.raises(ValueError, match="Mosaic failed"):
+        bench.main([])
+    assert capsys.readouterr().out == ""      # no number either way
+
+
+def test_bench_carries_no_cpu_route():
+    """What ISSUE 22 took out of bench.py stays out: the CPU smoke mode,
+    the scraped old captures, the mid-run AOT call that re-pinned the
+    platform and wrote into artifacts/, and the exit-0 wrapper."""
+    import pathlib
+    src = pathlib.Path("bench.py").read_text()
+    for gone in ("_ensure_jax_platform", "tpu_unreachable",
+                 "latest_chip_capture", "grad_overlap_dp8", "artifacts",
+                 "CPU smoke", '"value": 0.0'):
+        assert gone not in src, gone

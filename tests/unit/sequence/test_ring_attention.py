@@ -109,7 +109,7 @@ def test_ring_chunked_matches_dense(causal):
     to [H, qb, kb] — the enabler for the 1M-token proof
     (artifacts/longcontext_1m_v5e64.json)."""
     from functools import partial
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from deepspeed_tpu.sequence.ring_attention import ring_attention
 
     topo = MeshTopology(TopologyConfig(seq=4))
@@ -118,7 +118,7 @@ def test_ring_chunked_matches_dense(causal):
     fn = shard_map(
         partial(ring_attention, causal=causal, q_chunk=8, kv_chunk=16),
         mesh=topo.mesh, in_specs=(spec, spec, spec), out_specs=spec,
-        check_rep=False)
+        check_vma=False)
     out = fn(q, k, v)
     ref = mha_reference(q, k, v, causal=causal)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
@@ -127,7 +127,7 @@ def test_ring_chunked_matches_dense(causal):
 
 def test_ring_chunked_grads_match_dense():
     from functools import partial
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from deepspeed_tpu.sequence.ring_attention import ring_attention
 
     topo = MeshTopology(TopologyConfig(seq=4))
@@ -136,7 +136,7 @@ def test_ring_chunked_grads_match_dense():
     fn = shard_map(partial(ring_attention, causal=True, q_chunk=8,
                            kv_chunk=8),
                    mesh=topo.mesh, in_specs=(spec, spec, spec),
-                   out_specs=spec, check_rep=False)
+                   out_specs=spec, check_vma=False)
     g = jax.grad(lambda q, k, v: jnp.sum(fn(q, k, v) ** 2),
                  argnums=(0, 1, 2))(q, k, v)
     g_ref = jax.grad(
