@@ -437,6 +437,7 @@ def make_zero3_gather(dim: int, axes: AxisNames, fwd_quantized: bool,
     Must run inside shard_map over `axes`.
     """
 
+    @jax.named_scope("param_gather")
     def _gather_impl(shard):
         if fwd_quantized:
             return quantized_all_gather(shard, dim, axes, block=block,
@@ -452,6 +453,7 @@ def make_zero3_gather(dim: int, axes: AxisNames, fwd_quantized: bool,
     def fwd(shard):
         return _gather_impl(shard), None
 
+    @jax.named_scope("grad_reduce")
     def bwd(_, cot):
         if bwd_quantized:
             g = all_to_all_quant_reduce(cot, dim, axes, block=block, bits=bits,

@@ -604,16 +604,38 @@ class TransformerLM:
         return checkpoint_name(o, "attn_out")
 
     def _layer(self, x, lp, cos, sin):
+        """One block, in two named scopes (``attention``, then ``mlp`` or
+        ``moe``): what the step's phase metrics and XProf group by."""
+        cfg = self.cfg
+        if cfg.parallel_residual:
+            with jax.named_scope("attention"):
+                hn, attn_out = self._attention_sublayer(x, lp, cos, sin)
+            with jax.named_scope("mlp"):
+                # Falcon block: both sublayers read the normed input and
+                # the residual adds once; NeoX (parallel_norms) norms
+                # separately
+                hn2 = (self._norm(x, lp["mlp_norm"], lp.get("mlp_norm_b"))
+                       if cfg.parallel_norms else hn)
+                return (x + attn_out + dense_mlp(cfg, lp, hn2),
+                        jnp.zeros((), jnp.float32))
+        with jax.named_scope("attention"):
+            _, attn_out = self._attention_sublayer(x, lp, cos, sin)
+            x = x + attn_out
+            if cfg.norm_scheme == "post":
+                x = self._norm(x, lp["attn_norm"], lp.get("attn_norm_b"))
+        with jax.named_scope("moe" if cfg.moe_num_experts > 0 else "mlp"):
+            return self._mlp_sublayer(x, lp)
+
+    def _attention_sublayer(self, x, lp, cos, sin):
+        """(normed input, attention output before the residual add)."""
         cfg = self.cfg
         B, S, H = x.shape
         nh, nkv, hd = cfg.num_heads, cfg.kv_heads, cfg.head_dim
-        post = cfg.norm_scheme == "post"
-
         # post-LN (original BERT; reference kernel pre_layer_norm=False):
         # the sublayer reads the raw residual stream and the norm lands
         # AFTER the residual add
-        hn = x if post else self._norm(x, lp["attn_norm"],
-                                       lp.get("attn_norm_b"))
+        hn = x if cfg.norm_scheme == "post" else self._norm(
+            x, lp["attn_norm"], lp.get("attn_norm_b"))
         q, k, v = qkv_proj(lp, hn)
         q = q.reshape(B, S, nh, hd).transpose(0, 2, 1, 3)
         k = k.reshape(B, S, nkv, hd).transpose(0, 2, 1, 3)
@@ -623,17 +645,11 @@ class TransformerLM:
             k = apply_rotary(k, cos, sin)
         o = self._attention(q, k, v)
         o = o.transpose(0, 2, 1, 3).reshape(B, S, nh * hd)
-        if cfg.parallel_residual:
-            # Falcon block: both sublayers read the normed input and the
-            # residual adds once; NeoX (parallel_norms) norms separately
-            hn2 = (self._norm(x, lp["mlp_norm"], lp.get("mlp_norm_b"))
-                   if cfg.parallel_norms else hn)
-            return (x + out_proj(lp, o) + dense_mlp(cfg, lp, hn2),
-                    jnp.zeros((), jnp.float32))
-        x = x + out_proj(lp, o)
-        if post:
-            x = self._norm(x, lp["attn_norm"], lp.get("attn_norm_b"))
+        return hn, out_proj(lp, o)
 
+    def _mlp_sublayer(self, x, lp):
+        cfg = self.cfg
+        post = cfg.norm_scheme == "post"
         hn = x if post else self._norm(x, lp["mlp_norm"],
                                        lp.get("mlp_norm_b"))
         aux = jnp.zeros((), jnp.float32)
@@ -707,23 +723,24 @@ class TransformerLM:
 
     def forward_hidden(self, params, input_ids):
         cfg = self.cfg
-        x = params["embed"][input_ids]                    # [B, S, H] gather
-        if cfg.embed_scale != 1.0:
-            x = x * jnp.asarray(cfg.embed_scale, x.dtype)
-        if cfg.positional == "learned":
-            x = x + params["pos_embed"][: input_ids.shape[1]][None]
-        if "embed_ln_w" in params:
-            # BERT-family embedding LayerNorm (applied to the summed
-            # word+position embeddings; HF bert.embeddings.LayerNorm)
-            x = layer_norm(x, params["embed_ln_w"], params.get("embed_ln_b"),
-                           cfg.norm_eps)
-        S = input_ids.shape[1]
-        if cfg.positional == "rope":
-            cos, sin = _rope_tables(cfg, S)
-            cos = cos.astype(x.dtype)
-            sin = sin.astype(x.dtype)
-        else:
-            cos = sin = jnp.zeros((S, 1), x.dtype)
+        with jax.named_scope("embed"):
+            x = params["embed"][input_ids]                # [B, S, H] gather
+            if cfg.embed_scale != 1.0:
+                x = x * jnp.asarray(cfg.embed_scale, x.dtype)
+            if cfg.positional == "learned":
+                x = x + params["pos_embed"][: input_ids.shape[1]][None]
+            if "embed_ln_w" in params:
+                # BERT-family embedding LayerNorm (applied to the summed
+                # word+position embeddings; HF bert.embeddings.LayerNorm)
+                x = layer_norm(x, params["embed_ln_w"],
+                               params.get("embed_ln_b"), cfg.norm_eps)
+            S = input_ids.shape[1]
+            if cfg.positional == "rope":
+                cos, sin = _rope_tables(cfg, S)
+                cos = cos.astype(x.dtype)
+                sin = sin.astype(x.dtype)
+            else:
+                cos = sin = jnp.zeros((S, 1), x.dtype)
 
         body = self._layer
         if getattr(self, "stream_params_from_host", False):
@@ -755,11 +772,14 @@ class TransformerLM:
 
         unroll = max(self.cfg.scan_unroll,
                      getattr(self, "scan_unroll_hint", 1))
-        x, aux = jax.lax.scan(scan_fn, x, params["layers"], unroll=unroll)
+        with jax.named_scope("layers"):
+            x, aux = jax.lax.scan(scan_fn, x, params["layers"],
+                                  unroll=unroll)
         if cfg.norm_scheme == "pre":
             # post-LN has no final norm: the last layer's output LN is it
-            x = self._norm(x, params["final_norm"],
-                           params.get("final_norm_b"))
+            with jax.named_scope("loss_head"):
+                x = self._norm(x, params["final_norm"],
+                               params.get("final_norm_b"))
         return x, jnp.mean(aux)
 
     def _head_inputs(self, params, x):
@@ -1025,24 +1045,25 @@ class TransformerLM:
             # loss at the masked positions against the original tokens. A
             # missing loss_mask is always a caller error for MLM: defaulting
             # to all-ones would make ~85% of the loss a trivial copy task
-            labels = batch["labels"]
             assert mask is not None, \
                 "objective='mlm' requires batch['loss_mask'] (1 at masked " \
                 "positions)"
-            x, head, bias = self._head_inputs(params, x)
-            total, count = _chunked_ce_loss(x, labels,
-                                            mask.astype(jnp.float32), head,
-                                            self.cfg.loss_chunk, bias=bias)
-        else:
-            head = (params["embed"].T if self.cfg.tie_embeddings
-                    else params["lm_head"])
-            mask = (mask[:, 1:].astype(jnp.float32) if mask is not None
-                    else jnp.ones(ids[:, 1:].shape, jnp.float32))
-            total, count = _chunked_ce_loss(x[:, :-1], ids[:, 1:], mask,
-                                            head, self.cfg.loss_chunk)
-        loss = total / jnp.maximum(count, 1.0)
-        if self.cfg.moe_num_experts > 0:
-            loss = loss + self.cfg.moe_aux_loss_coef * aux
+        with jax.named_scope("loss_head"):
+            if self.cfg.objective == "mlm":
+                x, head, bias = self._head_inputs(params, x)
+                total, count = _chunked_ce_loss(
+                    x, batch["labels"], mask.astype(jnp.float32), head,
+                    self.cfg.loss_chunk, bias=bias)
+            else:
+                head = (params["embed"].T if self.cfg.tie_embeddings
+                        else params["lm_head"])
+                mask = (mask[:, 1:].astype(jnp.float32) if mask is not None
+                        else jnp.ones(ids[:, 1:].shape, jnp.float32))
+                total, count = _chunked_ce_loss(x[:, :-1], ids[:, 1:], mask,
+                                                head, self.cfg.loss_chunk)
+            loss = total / jnp.maximum(count, 1.0)
+            if self.cfg.moe_num_experts > 0:
+                loss = loss + self.cfg.moe_aux_loss_coef * aux
         return loss
 
     def _apply_ppo(self, params, batch):

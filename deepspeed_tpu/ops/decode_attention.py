@@ -119,6 +119,7 @@ def dense_decode_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, kvh, group, hd), q.dtype),
+        name="decode_attention",
         interpret=_interpret(),
     )(lengths.astype(jnp.int32), q4, k_cache, v_cache)
     return out.reshape(B, nh, hd)

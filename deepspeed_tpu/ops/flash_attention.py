@@ -143,6 +143,7 @@ def _flash_fwd(q, k, v, scale, causal, bq, bk):
         ],
         out_shape=out_shape,
         cost_estimate=_cost(bh, sq, skv, d, causal, n_dots=2),
+        name="flash_attention_fwd",
         interpret=_interpret(),
     )(q, k, v)
     return o, lse
@@ -266,6 +267,7 @@ def _flash_bwd(res, g, scale, causal, bq, bk):
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         cost_estimate=_cost(bh, sq, skv, d, causal, n_dots=3),
+        name="flash_attention_bwd_dq",
         interpret=_interpret(),
     )(q, k, v, do, lse, delta)
 
@@ -307,6 +309,7 @@ def _flash_bwd(res, g, scale, causal, bq, bk):
             jax.ShapeDtypeStruct((bhk, skv, d), v.dtype),
         ],
         cost_estimate=_cost(bh, sq, skv, d, causal, n_dots=5),
+        name="flash_attention_bwd_dkv",
         interpret=_interpret(),
     )(q, k, v, do, lse, delta)
     return dq, dk, dv

@@ -45,6 +45,7 @@ def rms_norm_pallas(x, weight, eps: float = 1e-6, block_rows: int = 256):
         ],
         out_specs=pl.BlockSpec((br, h), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, h), x.dtype),
+        name="rms_norm",
         interpret=_interpret(),
     )(xf, weight)
     return out.reshape(orig_shape)

@@ -62,6 +62,7 @@ def quantize_blocks_pallas(blocks: jnp.ndarray, bits: int = 8
                    pl.BlockSpec((R, 1), lambda i: (i, 0))],
         out_shape=[jax.ShapeDtypeStruct((nb, block), jnp.int8),
                    jax.ShapeDtypeStruct((nb, 1), jnp.float32)],
+        name="quantize_blocks",
         interpret=_interpret(),
     )(blocks)
     return q, s
@@ -80,6 +81,7 @@ def dequantize_blocks_pallas(q: jnp.ndarray, scale: jnp.ndarray,
                   pl.BlockSpec((R, 1), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((R, block), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((nb, block), out_dtype),
+        name="dequantize_blocks",
         interpret=_interpret(),
     )(q, scale)
 

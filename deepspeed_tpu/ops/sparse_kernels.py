@@ -178,6 +178,7 @@ def _sparse_fwd(q, k, v, kv_idx, kv_valid, scale, causal, block, nheads):
             jax.ShapeDtypeStruct(q.shape, q.dtype),
             jax.ShapeDtypeStruct((bh, s, 1), jnp.float32),
         ],
+        name="sparse_flash_attention_fwd",
         interpret=_interpret(),
     )(kv_idx, kv_valid, q, k, v)
     return o, lse
@@ -287,6 +288,7 @@ def _sparse_bwd(res, g, scale, causal, block, nheads):
             scratch_shapes=[pltpu.VMEM((block, d), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        name="sparse_flash_attention_bwd_dq",
         interpret=_interpret(),
     )(kv_idx, kv_valid, q, k, v, do, lse, delta)
 
@@ -321,6 +323,7 @@ def _sparse_bwd(res, g, scale, causal, block, nheads):
             jax.ShapeDtypeStruct(k.shape, k.dtype),
             jax.ShapeDtypeStruct(v.shape, v.dtype),
         ],
+        name="sparse_flash_attention_bwd_dkv",
         interpret=_interpret(),
     )(q_idx, q_valid, q, k, v, do, lse, delta)
     return dq, dk, dv

@@ -51,6 +51,22 @@ def _split_loss_aux(out):
     return out, {}
 
 
+def _cast_params(dtype):
+    """Master -> compute-dtype cast, as a function with a name: the
+    device's "XLA Modules" line calls a program after its function."""
+    def cast_params(p):
+        return jax.tree.map(lambda x: x.astype(dtype), p)
+    return cast_params
+
+
+def stack_grad_leaf_sqnorms(*xs):
+    return jnp.stack(xs)
+
+
+def accumulate_grads(a, b):
+    return jax.tree.map(jnp.add, a, b)
+
+
 def per_leaf_sqnorms(tree):
     """Per-leaf sums of squares (fp32), in ``jax.tree.leaves`` order —
     the sub-expressions :func:`global_norm` sums. Anomaly attribution
@@ -413,12 +429,9 @@ class DeepSpeedTpuEngine:
         self._tm_step_time = reg.histogram(
             "training_step_seconds", "train_batch wall time", unit="s")
         # comm-overlap series (grad_overlap.py): bucket geometry is known
-        # at build time; the exposed fraction is measured from the compiled
-        # HLO whenever the step is AOT-lowered (lower_train_step)
-        self._tm_comm_exposed = reg.gauge(
-            "training_comm_exposed_fraction",
-            "fraction of grad-reduce collectives in the compiled train "
-            "step with no overlap window (from HLO scheduling analysis)")
+        # at build time. How much of the collectives' time is exposed is a
+        # time, read from a device trace (the benchmark's
+        # collective_exposed.train), not from the compiler's schedule
         self._tm_bucket_bytes = reg.gauge(
             "training_reduce_bucket_bytes",
             "largest gradient-reduction bucket", unit="bytes")
@@ -735,7 +748,7 @@ class DeepSpeedTpuEngine:
         # (mixing memory kinds in one jit's out_shardings trips the SPMD
         # partitioner's side-effect-op replication check)
         cast = jax.jit(
-            lambda p: jax.tree.map(lambda x: x.astype(self.compute_dtype), p),
+            _cast_params(self.compute_dtype),
             out_shardings=self.zero_plan.param_sharding)
         self.params = cast(self.master_params) if self.has_master else self.master_params
         if self.param_offload and self.params is not None:
@@ -837,8 +850,7 @@ class DeepSpeedTpuEngine:
                               out_shardings=self.zero_plan.master_sharding)
         master_dev = init_master(rng)
         cast = jax.jit(
-            lambda p: jax.tree.map(
-                lambda x: x.astype(self.compute_dtype), p),
+            _cast_params(self.compute_dtype),
             out_shardings=self.zero_plan.param_sharding)
         self.params = cast(master_dev)
         leaves_dev, self._param_treedef = jax.tree_util.tree_flatten(
@@ -1048,7 +1060,13 @@ class DeepSpeedTpuEngine:
 
         def train_step(params, master, opt_state, scale_state, step, rng,
                        batch, qstate):
-            lr = lr_fn(step)
+            # the named scopes of this step (embed, layers, attention, mlp,
+            # loss_head in the model; grad_reduce, grad_clip, optimizer
+            # here; param_gather in comm/quantized.py) are metadata on
+            # the compiled instructions: utils/xla_profile.scope_map reads
+            # them back, XProf groups by them. They change no instruction.
+            with jax.named_scope("optimizer"):
+                lr = lr_fn(step)
             scale = scale_state["loss_scale"] if fp16 else jnp.asarray(1.0, jnp.float32)
             new_qstate = qstate
 
@@ -1059,8 +1077,10 @@ class DeepSpeedTpuEngine:
                 loss, grads = self.model.loss_and_grads(params, batch,
                                                         rng=sub)
                 loss = loss.astype(jnp.float32)
-                grads = jax.tree.map(lambda g: g.astype(jnp.float32), grads)
-                grads = constrain(grads, grad_sh)
+                with jax.named_scope("grad_reduce"):
+                    grads = jax.tree.map(lambda g: g.astype(jnp.float32),
+                                         grads)
+                    grads = constrain(grads, grad_sh)
                 inv = jnp.asarray(1.0, jnp.float32)
             elif pipeline_mode:
                 # the pipeline consumes all microbatches in one compiled
@@ -1075,8 +1095,10 @@ class DeepSpeedTpuEngine:
 
                 (_, loss), grads = jax.value_and_grad(
                     loss_fn, has_aux=True)(params)
-                grads = jax.tree.map(lambda g: g.astype(jnp.float32), grads)
-                grads = constrain(grads, grad_sh)
+                with jax.named_scope("grad_reduce"):
+                    grads = jax.tree.map(lambda g: g.astype(jnp.float32),
+                                         grads)
+                    grads = constrain(grads, grad_sh)
                 inv = 1.0 / scale
             elif use_manual:
                 rng, sub = jax.random.split(rng)
@@ -1085,7 +1107,8 @@ class DeepSpeedTpuEngine:
                         params, sub, batch, scale, qstate)
                 else:
                     grads, loss = manual_grad_fn(params, sub, batch, scale)
-                grads = constrain(grads, grad_sh)
+                with jax.named_scope("grad_reduce"):
+                    grads = constrain(grads, grad_sh)
                 inv = 1.0 / (gas * scale)
             else:
                 def micro_fn(carry, micro):
@@ -1094,29 +1117,35 @@ class DeepSpeedTpuEngine:
                     (scaled, (loss, _aux)), grads = jax.value_and_grad(
                         self._loss_fn, has_aux=True)(params, micro, sub, scale,
                                                      step)
-                    grads = jax.tree.map(lambda a, g: a + g.astype(jnp.float32),
-                                         grads_acc, grads)
-                    grads = constrain(grads, grad_sh)
+                    # accumulation across micro-batches and the sharding
+                    # constraint GSPMD turns into the reduction
+                    with jax.named_scope("grad_reduce"):
+                        grads = jax.tree.map(
+                            lambda a, g: a + g.astype(jnp.float32),
+                            grads_acc, grads)
+                        grads = constrain(grads, grad_sh)
                     return (grads, rng), loss
 
-                grads0 = jax.tree.map(
-                    lambda p: jnp.zeros(p.shape, jnp.float32), params)
-                grads0 = constrain(grads0, grad_sh)
+                with jax.named_scope("grad_reduce"):
+                    grads0 = jax.tree.map(
+                        lambda p: jnp.zeros(p.shape, jnp.float32), params)
+                    grads0 = constrain(grads0, grad_sh)
                 (grads, rng), losses = jax.lax.scan(micro_fn, (grads0, rng), batch)
                 loss = jnp.mean(losses)
                 inv = 1.0 / (gas * scale)
-            if grad_attribution:
-                # the per-leaf squared norms are the global norm's own
-                # sub-expressions (CSE'd, so exporting them is free) and
-                # deliberately not gated on `finite`: the non-finite
-                # step is exactly the one whose per-bucket norms name
-                # the culprit parameter buckets
-                grads, finite, gnorm, leaf_sq = unscale_clip_check(
-                    grads, inv, clip, fp16, frozen_mask,
-                    with_leaf_sqnorms=True)
-            else:
-                grads, finite, gnorm = unscale_clip_check(
-                    grads, inv, clip, fp16, frozen_mask)
+            with jax.named_scope("grad_clip"):
+                if grad_attribution:
+                    # the per-leaf squared norms are the global norm's own
+                    # sub-expressions (CSE'd, so exporting them is free)
+                    # and deliberately not gated on `finite`: the
+                    # non-finite step is exactly the one whose per-bucket
+                    # norms name the culprit parameter buckets
+                    grads, finite, gnorm, leaf_sq = unscale_clip_check(
+                        grads, inv, clip, fp16, frozen_mask,
+                        with_leaf_sqnorms=True)
+                else:
+                    grads, finite, gnorm = unscale_clip_check(
+                        grads, inv, clip, fp16, frozen_mask)
             if use_qr:
                 # a skipped (non-finite) step's grads are garbage and so
                 # are their transport errors — the EF residual must not
@@ -1125,30 +1154,33 @@ class DeepSpeedTpuEngine:
                     lambda n, o: jnp.where(finite, n, o), new_qstate,
                     qstate)
             target = master if has_master else params
-            new_target, new_opt, new_step = apply_update_with_skip(
-                optimizer, target, grads, opt_state, step, lr, finite,
-                frozen_mask)
+            # update, master -> compute cast and loss scale: one scope
+            with jax.named_scope("optimizer"):
+                new_target, new_opt, new_step = apply_update_with_skip(
+                    optimizer, target, grads, opt_state, step, lr, finite,
+                    frozen_mask)
 
-            if has_master:
-                new_master = new_target
-                new_params = jax.tree.map(
-                    lambda x: x.astype(compute_dtype), new_master)
-                new_params = constrain(new_params, param_sh)
-                if po_constrain:
-                    # out_shardings are None under offload_param: pin
-                    # master/opt in-step so placements cannot drift
-                    new_master = constrain(new_master, master_sh_c)
-                    new_opt = constrain(new_opt, opt_sh_c)
-            else:
-                new_master = None
-                new_params = constrain(new_target, param_sh)
-                if po_constrain:
-                    new_opt = constrain(new_opt, opt_sh_c)
+                if has_master:
+                    new_master = new_target
+                    new_params = jax.tree.map(
+                        lambda x: x.astype(compute_dtype), new_master)
+                    new_params = constrain(new_params, param_sh)
+                    if po_constrain:
+                        # out_shardings are None under offload_param: pin
+                        # master/opt in-step so placements cannot drift
+                        new_master = constrain(new_master, master_sh_c)
+                        new_opt = constrain(new_opt, opt_sh_c)
+                else:
+                    new_master = None
+                    new_params = constrain(new_target, param_sh)
+                    if po_constrain:
+                        new_opt = constrain(new_opt, opt_sh_c)
 
-            if fp16:
-                new_scale_state = update_scale(scale_state, finite, scale_cfg)
-            else:
-                new_scale_state = scale_state
+                if fp16:
+                    new_scale_state = update_scale(scale_state, finite,
+                                                   scale_cfg)
+                else:
+                    new_scale_state = scale_state
             metrics = {
                 "loss": loss,
                 "grad_norm": gnorm,
@@ -1261,14 +1293,17 @@ class DeepSpeedTpuEngine:
                 loss, grads = self.model.loss_and_grads(params, batch,
                                                         rng=sub)
                 loss = loss.astype(jnp.float32)
-                grads = jax.tree.map(lambda g: g.astype(jnp.float32), grads)
-                grads = constrain(grads, grad_sh)
-                gnorm = global_norm(grads)
-                if clip and clip > 0:
-                    factor = jnp.minimum(1.0, clip / (gnorm + 1e-6))
-                    grads = jax.tree.map(lambda g: g * factor, grads)
-                grads = jax.tree.map(lambda g: g.astype(transfer_dtype),
-                                     grads)
+                with jax.named_scope("grad_reduce"):
+                    grads = jax.tree.map(lambda g: g.astype(jnp.float32),
+                                         grads)
+                    grads = constrain(grads, grad_sh)
+                with jax.named_scope("grad_clip"):
+                    gnorm = global_norm(grads)
+                    if clip and clip > 0:
+                        factor = jnp.minimum(1.0, clip / (gnorm + 1e-6))
+                        grads = jax.tree.map(lambda g: g * factor, grads)
+                    grads = jax.tree.map(
+                        lambda g: g.astype(transfer_dtype), grads)
                 metrics = {"loss": loss, "grad_norm": gnorm,
                            "skipped": jnp.asarray(0, jnp.int32)}
                 return grads, scale_state, rng, metrics
@@ -1279,20 +1314,30 @@ class DeepSpeedTpuEngine:
                 (_, (loss, _aux)), grads = jax.value_and_grad(
                     self._loss_fn, has_aux=True)(params, micro, sub, scale,
                                                  step)
-                grads = jax.tree.map(lambda a, g: a + g.astype(jnp.float32),
-                                     grads_acc, grads)
-                grads = constrain(grads, grad_sh)
+                with jax.named_scope("grad_reduce"):
+                    grads = jax.tree.map(
+                        lambda a, g: a + g.astype(jnp.float32),
+                        grads_acc, grads)
+                    grads = constrain(grads, grad_sh)
                 return (grads, rng), loss
 
-            grads0 = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params)
-            grads0 = constrain(grads0, grad_sh)
+            with jax.named_scope("grad_reduce"):
+                grads0 = jax.tree.map(
+                    lambda p: jnp.zeros(p.shape, jnp.float32), params)
+                grads0 = constrain(grads0, grad_sh)
             (grads, rng), losses = jax.lax.scan(micro_fn, (grads0, rng), batch)
             loss = jnp.mean(losses)
-            grads, finite, gnorm = unscale_clip_check(
-                grads, 1.0 / (gas * scale), clip, fp16)
-            grads = jax.tree.map(lambda g: g.astype(transfer_dtype), grads)
-            new_scale_state = (update_scale(scale_state, finite, scale_cfg)
-                               if fp16 else scale_state)
+            with jax.named_scope("grad_clip"):
+                grads, finite, gnorm = unscale_clip_check(
+                    grads, 1.0 / (gas * scale), clip, fp16)
+                grads = jax.tree.map(lambda g: g.astype(transfer_dtype),
+                                     grads)
+            # the update itself runs on the host; what is left of the
+            # optimizer on the device is the loss scale
+            with jax.named_scope("optimizer"):
+                new_scale_state = (update_scale(scale_state, finite,
+                                                scale_cfg)
+                                   if fp16 else scale_state)
             metrics = {"loss": loss, "grad_norm": gnorm,
                        "skipped": (~finite).astype(jnp.int32)}
             if fp16:
@@ -1371,13 +1416,15 @@ class DeepSpeedTpuEngine:
                 tree, sh)
 
         def grad_step(params, scale_state, step, rng, batch):
-            lr = lr_fn(step)
+            with jax.named_scope("optimizer"):
+                lr = lr_fn(step)
             scale = (scale_state["loss_scale"] if fp16
                      else jnp.asarray(1.0, jnp.float32))
             if use_manual:
                 rng, sub = jax.random.split(rng)
                 grads, loss = manual_grad_fn(params, sub, batch, scale)
-                grads = constrain(grads, grad_sh)
+                with jax.named_scope("grad_reduce"):
+                    grads = constrain(grads, grad_sh)
                 inv = 1.0 / (gas * scale)
             else:
                 def micro_fn(carry, micro):
@@ -1386,27 +1433,34 @@ class DeepSpeedTpuEngine:
                     (scaled, (loss, _aux)), grads = jax.value_and_grad(
                         self._loss_fn, has_aux=True)(params, micro, sub,
                                                      scale, step)
-                    grads = jax.tree.map(
-                        lambda a, g: a + g.astype(jnp.float32),
-                        grads_acc, grads)
-                    grads = constrain(grads, grad_sh)
+                    with jax.named_scope("grad_reduce"):
+                        grads = jax.tree.map(
+                            lambda a, g: a + g.astype(jnp.float32),
+                            grads_acc, grads)
+                        grads = constrain(grads, grad_sh)
                     return (grads, rng), loss
 
-                grads0 = jax.tree.map(
-                    lambda p: jnp.zeros(p.shape, jnp.float32), params)
-                grads0 = constrain(grads0, grad_sh)
+                with jax.named_scope("grad_reduce"):
+                    grads0 = jax.tree.map(
+                        lambda p: jnp.zeros(p.shape, jnp.float32), params)
+                    grads0 = constrain(grads0, grad_sh)
                 (grads, rng), losses = jax.lax.scan(micro_fn,
                                                     (grads0, rng), batch)
                 loss = jnp.mean(losses)
                 inv = 1.0 / (gas * scale)
-            if grad_attribution:
-                grads, finite, gnorm, leaf_sq = unscale_clip_check(
-                    grads, inv, clip, fp16, with_leaf_sqnorms=True)
-            else:
-                grads, finite, gnorm = unscale_clip_check(
-                    grads, inv, clip, fp16)
-            new_scale_state = (update_scale(scale_state, finite, scale_cfg)
-                               if fp16 else scale_state)
+            with jax.named_scope("grad_clip"):
+                if grad_attribution:
+                    grads, finite, gnorm, leaf_sq = unscale_clip_check(
+                        grads, inv, clip, fp16, with_leaf_sqnorms=True)
+                else:
+                    grads, finite, gnorm = unscale_clip_check(
+                        grads, inv, clip, fp16)
+            # the streamed bucket update (runtime/offload.py) carries the
+            # scope of the update itself
+            with jax.named_scope("optimizer"):
+                new_scale_state = (update_scale(scale_state, finite,
+                                                scale_cfg)
+                                   if fp16 else scale_state)
             metrics = {"loss": loss, "grad_norm": gnorm, "lr": lr,
                        "skipped": (~finite).astype(jnp.int32)}
             if fp16:
@@ -1626,16 +1680,22 @@ class DeepSpeedTpuEngine:
         t0 = time.perf_counter()
         compiled = (lowered.compile(compiler_options=compiler_options)
                     if compiler_options else lowered.compile())
-        self._record_comm_overlap(compiled)
-        self._record_train_forensics(compiled, time.perf_counter() - t0)
+        # with options of its own, ``compiled`` is not what train_batch
+        # dispatches: the scope map has to name the instructions of the
+        # program that runs, which is this lowering compiled as jit does
+        self._record_train_forensics(
+            compiled, time.perf_counter() - t0,
+            dispatched=lowered.compile if compiler_options else None)
         return compiled
 
-    def _record_train_forensics(self, compiled, compile_s: float):
+    def _record_train_forensics(self, compiled, compile_s: float,
+                                dispatched=None):
         """Feed the performance-forensics subsystem from an AOT-compiled
         train step: the compile event (watchdog counters) and the
         program's device-memory/cost analysis plus the big long-lived
-        buffers (telemetry/memory.py gauges + oom_report). Best-effort —
-        forensics must never break AOT analysis."""
+        buffers (telemetry/memory.py gauges + oom_report; the record
+        keeps the executable for ``memory.scopes("train_step")``).
+        Best-effort — forensics must never break AOT analysis."""
         if not getattr(self, "telemetry_enabled", False):
             return
         try:
@@ -1643,7 +1703,8 @@ class DeepSpeedTpuEngine:
             from ..telemetry import watchdog
             watchdog.record_compile("train_step", compile_s,
                                     analysis=True)
-            ds_memory.record_memory_analysis("train_step", compiled)
+            ds_memory.record_memory_analysis("train_step", compiled,
+                                             dispatched=dispatched)
             ds_memory.record_buffer(
                 "train_params", ds_memory.tree_bytes(self.params))
             if self.opt_state is not None:
@@ -1651,20 +1712,6 @@ class DeepSpeedTpuEngine:
                     "optimizer_state", ds_memory.tree_bytes(self.opt_state))
         except Exception as e:  # pragma: no cover - diagnostics only
             logger.debug(f"train-step forensics skipped: {e}")
-
-    def _record_comm_overlap(self, compiled):
-        """Feed ``training_comm_exposed_fraction`` from the compiled step's
-        HLO scheduling (TPU: async-collective-fusion chains; CPU backend:
-        start/done pairs). Best-effort — analysis must never break AOT."""
-        if not getattr(self, "telemetry_enabled", False):
-            return
-        try:
-            from ..utils.xla_profile import grad_exchange_report_from_compiled
-            rep = grad_exchange_report_from_compiled(compiled)
-            if rep.total:
-                self._tm_comm_exposed.set(float(rep.exposed_fraction))
-        except Exception as e:  # pragma: no cover - diagnostics only
-            logger.debug(f"comm overlap analysis skipped: {e}")
 
     def train_batch(self, data_iter=None, batch=None):
         """Run one full (micro*gas) training batch; returns scalar loss.
@@ -1728,6 +1775,16 @@ class DeepSpeedTpuEngine:
         if stall is not None:
             stall.beat("train_step")
             stall.set_active("train_step", False)
+        # everything the host does once the loss is here, in one span: with
+        # train_data and train_step, all of train_batch is inside a span
+        with trace.span("train_bookkeeping", step=self.global_steps):
+            self._train_bookkeeping(metrics, loss, dev_batch)
+        return loss
+
+    def _train_bookkeeping(self, metrics, loss: float, dev_batch) -> None:
+        """What ``train_batch`` does on the host once the loss is here:
+        skip count and scheduler, logging, registry, flight recorder and
+        anomaly check, ``_last_metrics``."""
         # Host bookkeeping mirrors the device counter: the compiled step
         # leaves ``_step_arr`` un-advanced on fp16 overflow, so the host
         # step count and the LR schedule must hold too (reference skips the
@@ -1775,7 +1832,6 @@ class DeepSpeedTpuEngine:
         self._record_flight_and_anomaly(metrics, loss, skipped,
                                         leaf_sqnorms)
         self._last_metrics = {k: float(v) for k, v in metrics.items()}
-        return loss
 
     def _record_flight_and_anomaly(self, metrics, loss: float,
                                    skipped: int, leaf_sqnorms) -> None:
@@ -1800,7 +1856,7 @@ class DeepSpeedTpuEngine:
             if leaf_sqnorms:
                 if self._leaf_stack_fn is None:
                     self._leaf_stack_fn = jax.jit(
-                        lambda *xs: jnp.stack(xs))
+                        stack_grad_leaf_sqnorms)
                 leaf_sqnorms = np.asarray(
                     self._leaf_stack_fn(*leaf_sqnorms), dtype=np.float64)
             else:
@@ -1882,8 +1938,8 @@ class DeepSpeedTpuEngine:
         if self._grad_buffer is None:
             self._grad_buffer = g
         else:
-            self._grad_buffer = jax.jit(
-                lambda a, b: jax.tree.map(jnp.add, a, b))(self._grad_buffer, g)
+            self._grad_buffer = jax.jit(accumulate_grads)(
+                self._grad_buffer, g)
         self.micro_steps += 1
 
     def step(self):
@@ -1909,22 +1965,23 @@ class DeepSpeedTpuEngine:
             def apply(params, master, opt_state, scale_state, step, grads):
                 scale = (scale_state["loss_scale"] if fp16
                          else jnp.asarray(1.0, jnp.float32))
-                grads, finite, _gnorm = unscale_clip_check(
-                    grads, 1.0 / (gas * scale), clip, fp16, frozen_mask)
+                with jax.named_scope("grad_clip"):
+                    grads, finite, _gnorm = unscale_clip_check(
+                        grads, 1.0 / (gas * scale), clip, fp16, frozen_mask)
                 target = master if has_master else params
-                new_target, new_opt, new_step = apply_update_with_skip(
-                    optimizer, target, grads, opt_state, step, lr_fn(step),
-                    finite, frozen_mask)
-                new_scale_state = (update_scale(scale_state, finite, scale_cfg)
-                                   if fp16 else scale_state)
-                skipped = (~finite).astype(jnp.int32)
-                if has_master:
-                    new_params = jax.tree.map(
+                with jax.named_scope("optimizer"):
+                    new_target, new_opt, new_step = apply_update_with_skip(
+                        optimizer, target, grads, opt_state, step,
+                        lr_fn(step), finite, frozen_mask)
+                    new_scale_state = (update_scale(scale_state, finite,
+                                                    scale_cfg)
+                                       if fp16 else scale_state)
+                    skipped = (~finite).astype(jnp.int32)
+                    new_params = (jax.tree.map(
                         lambda x: x.astype(compute_dtype), new_target)
-                    return (new_params, new_target, new_opt, new_scale_state,
-                            new_step, skipped)
-                return (new_target, None, new_opt, new_scale_state, new_step,
-                        skipped)
+                        if has_master else new_target)
+                return (new_params, new_target if has_master else None,
+                        new_opt, new_scale_state, new_step, skipped)
 
             self._apply_jit = jax.jit(
                 apply,
@@ -2271,8 +2328,7 @@ class DeepSpeedTpuEngine:
             self.master_params = jax.tree.map(
                 lambda a, s: jax.device_put(np.asarray(a, np.float32), s.sharding),
                 host_tree, self.master_params)
-            cast = jax.jit(lambda p: jax.tree.map(
-                lambda x: x.astype(self.compute_dtype), p),
+            cast = jax.jit(_cast_params(self.compute_dtype),
                 out_shardings=self.zero_plan.param_sharding)
             self.params = cast(self.master_params)
             self._relocate_params_to_storage()

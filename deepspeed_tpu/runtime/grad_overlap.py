@@ -777,12 +777,14 @@ def make_overlapped_grad_fn(engine, zpp_w: bool, zpp_g: bool):
             sub = jax.random.fold_in(sub, linear_index())
             (_, loss), g = jax.value_and_grad(
                 apply_model, has_aux=True)(params_l, micro, sub)
-            grads_acc = jax.tree.map(
-                lambda a, x: a + x.astype(jnp.float32), grads_acc, g)
+            with jax.named_scope("grad_reduce"):
+                grads_acc = jax.tree.map(
+                    lambda a, x: a + x.astype(jnp.float32), grads_acc, g)
             return grads_acc, rng, loss
 
-        grads0 = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32),
-                              params_l)
+        with jax.named_scope("grad_reduce"):
+            grads0 = jax.tree.map(
+                lambda p: jnp.zeros(p.shape, jnp.float32), params_l)
 
         def scan_fn(carry, micro):
             acc, rng = carry
@@ -814,26 +816,28 @@ def make_overlapped_grad_fn(engine, zpp_w: bool, zpp_g: bool):
                                               batch_l)
 
         flat, treedef = jax.tree_util.tree_flatten(acc)
-        if use_qr:
-            # local residual rows ride shard_map with a leading sharded
-            # dim of 1 (global dim0 = world); squeeze in, unsqueeze out
-            qin = {k: {kk: a[0] for kk, a in v.items()}
-                   for k, v in qstate.items()}
-            flat, qerr = apply_bucketed_reduction(
-                flat, plan, gd_flat, axes, cross_group_axes, world,
-                cross_world, axis_sizes=axis_sizes, quantized=zpp_g,
-                ring=not tp, quant_reduce=qr_mode,
-                quant_reduce_block=qr_block,
-                quant_reduce_groups=qr_groups, qstate=qin,
-                loss_scale=scale)
-            qout = {k: {kk: a[None] for kk, a in v.items()}
-                    for k, v in qerr.items()}
-        else:
-            flat = apply_bucketed_reduction(
-                flat, plan, gd_flat, axes, cross_group_axes, world,
-                cross_world, axis_sizes=axis_sizes, quantized=zpp_g,
-                ring=not tp)
-            qout = qstate
+        with jax.named_scope("grad_reduce"):
+            if use_qr:
+                # local residual rows ride shard_map with a leading
+                # sharded dim of 1 (global dim0 = world); squeeze in,
+                # unsqueeze out
+                qin = {k: {kk: a[0] for kk, a in v.items()}
+                       for k, v in qstate.items()}
+                flat, qerr = apply_bucketed_reduction(
+                    flat, plan, gd_flat, axes, cross_group_axes, world,
+                    cross_world, axis_sizes=axis_sizes, quantized=zpp_g,
+                    ring=not tp, quant_reduce=qr_mode,
+                    quant_reduce_block=qr_block,
+                    quant_reduce_groups=qr_groups, qstate=qin,
+                    loss_scale=scale)
+                qout = {k: {kk: a[None] for kk, a in v.items()}
+                        for k, v in qerr.items()}
+            else:
+                flat = apply_bucketed_reduction(
+                    flat, plan, gd_flat, axes, cross_group_axes, world,
+                    cross_world, axis_sizes=axis_sizes, quantized=zpp_g,
+                    ring=not tp)
+                qout = qstate
         grads = jax.tree_util.tree_unflatten(treedef, flat)
         loss = jax.lax.pmean(jnp.mean(losses), axes)
         return grads, loss, qout

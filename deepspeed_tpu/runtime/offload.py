@@ -245,11 +245,12 @@ class TieredOptimizerOffload:
             # apply_update_with_skip (finite=True — skipped steps never
             # reach the streaming update; the host gates on the grad
             # program's `skipped` flag instead)
-            lr = lr_fn(step)
-            new_master, new_state, _ = apply_update_with_skip(
-                opt, masters, grads, states, step, lr,
-                jnp.asarray(True))
-            new_params = [m.astype(out_dtype) for m in new_master]
+            with jax.named_scope("optimizer"):
+                lr = lr_fn(step)
+                new_master, new_state, _ = apply_update_with_skip(
+                    opt, masters, grads, states, step, lr,
+                    jnp.asarray(True))
+                new_params = [m.astype(out_dtype) for m in new_master]
             return new_master, new_state, new_params
 
         fn = jax.jit(update, donate_argnums=(0, 1))

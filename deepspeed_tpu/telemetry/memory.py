@@ -12,7 +12,9 @@ Two record kinds:
     AOT-compiled program's memory stats (and its cost analysis: flops /
     bytes accessed, the MFU inputs) into
     ``xla_program_{peak,argument,temp,output}_bytes{program=...}``
-    gauges. ``runtime.engine.lower_train_step`` records the train step;
+    gauges. ``runtime.engine.lower_train_step`` records the train step,
+    and the record keeps the executable, so that :func:`scopes` can say
+    later which phase of the step each of its instructions belongs to;
     ``InferenceEngineV2.memory_report()`` AOT-lowers the decode/prefill
     programs at representative bucket shapes (no chip needed — the
     compiler runs on the host).
@@ -25,13 +27,17 @@ read after a RESOURCE_EXHAUSTED (docs/PROFILING.md, "Triaging OOMs").
 """
 
 import threading
-from typing import Any, Dict, Optional
+from typing import Any, Callable, Dict, Optional
 
 from .registry import get_registry
 
 _lock = threading.Lock()
 _programs: Dict[str, Dict[str, Any]] = {}
 _buffers: Dict[str, int] = {}
+# per program: a zero-argument callable that returns the ``Compiled`` the
+# program runs as, and the scope map built from it on first request
+_executables: Dict[str, Callable[[], Any]] = {}
+_scopes: Dict[str, Dict[str, str]] = {}
 
 _MEM_FIELDS = ("argument_size_in_bytes", "output_size_in_bytes",
                "temp_size_in_bytes", "alias_size_in_bytes",
@@ -67,9 +73,17 @@ def cost_analysis_dict(compiled) -> Dict[str, float]:
     return dict(ca or {})
 
 
-def record_memory_analysis(program: str, compiled) -> Dict[str, Any]:
+def record_memory_analysis(program: str, compiled,
+                           dispatched: Optional[Callable[[], Any]] = None
+                           ) -> Dict[str, Any]:
     """Extract ``compiled.memory_analysis()`` (+ ``cost_analysis()``)
-    into gauges and the program table; returns the record."""
+    into gauges and the program table; returns the record.
+
+    The executable itself is kept for :func:`scopes` (a reference, not
+    its text: nothing is printed or parsed here). Where ``compiled`` was
+    built with other options than the program is dispatched with,
+    ``dispatched`` returns the executable that really runs; it is called
+    on the first :func:`scopes` request, not here."""
     ma = compiled.memory_analysis()
     rec: Dict[str, Any] = {k: int(getattr(ma, k)) for k in _MEM_FIELDS
                            if hasattr(ma, k)}
@@ -93,7 +107,24 @@ def record_memory_analysis(program: str, compiled) -> Dict[str, Any]:
         rec.get("output_size_in_bytes", 0))
     with _lock:
         _programs[program] = dict(rec)
+        _executables[program] = dispatched or (lambda: compiled)
+        _scopes.pop(program, None)
     return rec
+
+
+def scopes(program: str) -> Optional[Dict[str, str]]:
+    """``{instruction name: op_name}`` of a recorded program
+    (``utils.xla_profile.scope_map`` of the executable it runs as), or
+    None for a program never recorded. Built on the first request and
+    kept; outlives the engine that recorded it."""
+    with _lock:
+        got, executable = _scopes.get(program), _executables.get(program)
+    if got is None and executable is not None:
+        from ..utils.xla_profile import scope_map
+        got = scope_map(executable())
+        with _lock:
+            _scopes[program] = got
+    return got
 
 
 def tree_bytes(tree) -> int:
@@ -134,6 +165,8 @@ def reset() -> None:
     with _lock:
         _programs.clear()
         _buffers.clear()
+        _executables.clear()
+        _scopes.clear()
 
 
 def oom_report(top: int = 5) -> Dict[str, Any]:

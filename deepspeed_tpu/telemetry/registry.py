@@ -50,6 +50,9 @@ def _format_value(v: float) -> str:
     if v == -_INF:
         return "-Inf"
     f = float(v)
+    if f != f:
+        # a NaN loss lands in a gauge; the exposition format spells it so
+        return "NaN"
     return repr(int(f)) if f == int(f) else repr(f)
 
 

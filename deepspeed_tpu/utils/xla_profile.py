@@ -16,6 +16,15 @@ stream): is ZeRO communication overlapped with compute?
    counts async pairs per collective kind and the instruction distance
    between start and done — a device-independent, committable measurement
    of how much latency hiding the compiled program actually has.
+
+3. ``scope_map(compiled)`` / ``scope_phase(op_name)``: which part of the
+   training step an instruction of the compiled program belongs to. A
+   device trace names an operation by its HLO instruction (``fusion.491``);
+   the compiled program's text still carries, per instruction, the
+   ``jax.named_scope`` and autodiff path it was traced under
+   (``op_name``). The map joins the two, so a trace can be summed by
+   phase: forward, recomputed forward, backward, loss head, optimizer,
+   gradient reduction, parameter gather.
 """
 
 import re
@@ -23,6 +32,100 @@ from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional
 
 import jax
+
+# ---------------------------------------------------------------------------
+# scope map: instruction name -> op_name, and op_name -> phase of the step
+# ---------------------------------------------------------------------------
+_HLO_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s*\(.*\{\s*$")
+_HLO_INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(.*)$")
+_HLO_OP_NAME = re.compile(r'\bop_name="([^"]*)"')
+_HLO_REFERENCE = re.compile(r"%([\w.\-]+)")
+# a fusion's body and a reducer run as part of the instruction that calls
+# them; these opcodes run as no device operation at all
+_HLO_CALLS_INNER = re.compile(
+    r"\sfusion\(.*\bcalls=%?([\w.\-]+)|\bto_apply=%?([\w.\-]+)")
+_HLO_NEVER_RUNS = re.compile(
+    r"\s(?:parameter|constant|get-tuple-element|tuple|bitcast)\(")
+
+# the fixed scope names of the step (runtime/engine.py's step builders,
+# models/transformer.py, comm/quantized.py, runtime/grad_overlap.py) that
+# name a phase by themselves, whatever autodiff wrapped them in
+_PHASE_OF_SCOPE = {"loss_head": "loss_head", "optimizer": "optimizer",
+                   "grad_clip": "optimizer", "grad_reduce": "grad_reduce",
+                   "param_gather": "param_gather"}
+_MODEL_SCOPES = ("embed", "layers", "attention", "mlp", "moe")
+_SCOPE_WORD = re.compile(
+    r"\b(" + "|".join(list(_PHASE_OF_SCOPE) + list(_MODEL_SCOPES)) + r")\b")
+PHASES = ("forward", "recompute", "backward", "loss_head", "optimizer",
+          "grad_reduce", "param_gather", "other")
+
+
+def scope_map(compiled) -> Dict[str, str]:
+    """``{instruction name: op_name}`` of a jax ``Compiled``, from the
+    optimized program's text: the instructions a device trace can show
+    as operations of their own. That is every computation but fusion
+    bodies and reducers — entry, loop bodies and conditions, branches —
+    less the opcodes that only name a value; instruction names are unique
+    in a module. A fusion carries its root's ``op_name``. What the
+    compiler added itself (an async copy into fast memory, the ``-start``
+    half of a collective, a layout conversion) carries none: it is given
+    that of the instruction it feeds or, failing that, of the one that
+    feeds it. Parses the whole text: call it when a reader asks, not on a
+    path that is timed."""
+    rows, inner, computation = [], set(), None
+    for line in compiled.as_text().splitlines():
+        m = _HLO_COMPUTATION.match(line)
+        if m:
+            computation = m.group(1)
+            continue
+        for called in _HLO_CALLS_INNER.findall(line):
+            inner.update(c for c in called if c)
+        m = _HLO_INSTRUCTION.match(line)
+        if m and not _HLO_NEVER_RUNS.search(line):
+            rows.append((computation, m.group(1), m.group(2)))
+    # (a computation is printed before the instruction that calls it)
+    named: Dict[str, str] = {}
+    unnamed, users = [], {}
+    for computation, name, rest in rows:
+        if computation in inner:
+            continue
+        op_name = _HLO_OP_NAME.search(rest)
+        operands = _HLO_REFERENCE.findall(rest)
+        for operand in operands:
+            users.setdefault(operand, []).append(name)
+        if op_name:
+            named[name] = op_name.group(1)
+        else:
+            unnamed.append((name, operands))
+    for _ in range(3):      # a copy that feeds a copy: chains are short
+        for name, operands in unnamed:
+            near = [n for n in users.get(name, []) + operands if n in named]
+            if name not in named and near:
+                named[name] = named[near[0]]
+    return named
+
+
+def scope_phase(op_name: str) -> str:
+    """The phase of the training step an ``op_name`` belongs to, one of
+    ``PHASES``. A phase scope (``loss_head``, ``optimizer`` with
+    ``grad_clip``, ``grad_reduce``, ``param_gather``) wins over the
+    autodiff wrapper round it, and the innermost of several wins; the
+    rest is told apart by autodiff's own marks: ``rematted_computation``
+    is the forward run again under activation checkpointing,
+    ``transpose(`` otherwise the backward, ``jvp(`` without it (or a
+    model scope with neither, in a forward-only program) the forward."""
+    words = _SCOPE_WORD.findall(op_name)
+    for word in reversed(words):
+        if word in _PHASE_OF_SCOPE:
+            return _PHASE_OF_SCOPE[word]
+    if "rematted_computation" in op_name:
+        return "recompute"
+    if "transpose(" in op_name:
+        return "backward"
+    if "jvp(" in op_name or words:
+        return "forward"
+    return "other"
+
 
 # async-pair HLO opcodes emitted by the latency-hiding scheduler
 _ASYNC_KINDS = ("all-gather", "all-reduce", "reduce-scatter",

@@ -235,10 +235,14 @@ def test_bucket_telemetry_gauges():
         gm = eng.micro_batch_size * eng.ds_config.dp_world_size
         b = random_batches(1, gm * eng.gas, HIDDEN)[0]
         gb = {k: v.reshape(eng.gas, gm, HIDDEN) for k, v in b.items()}
-        eng.lower_train_step(gb)  # populates the exposed-fraction gauge
-        exposed = eng.telemetry.gauge(
-            "training_comm_exposed_fraction", "").value
-        assert 0.0 <= exposed <= 1.0
+        # what the compiler scheduled is a report of its own, asked for
+        # directly; it is no longer published under the name of a time
+        from deepspeed_tpu.utils.xla_profile import \
+            grad_exchange_report_from_compiled
+        rep = grad_exchange_report_from_compiled(eng.lower_train_step(gb))
+        assert rep.total >= 0 and 0.0 <= rep.exposed_fraction <= 1.0
+        assert "training_comm_exposed_fraction" not in \
+            eng.telemetry.render_prometheus()
     finally:
         set_registry(prev)
 

@@ -99,6 +99,16 @@ def test_render_prometheus_scalars(reg):
     assert 'depth{queue="prefill"} 7' in text
 
 
+def test_render_prometheus_survives_a_nan_gauge(reg):
+    """A poisoned step leaves ``training_loss`` NaN; the scrape that
+    follows must still render (it raised ``cannot convert float NaN to
+    integer``, for every later scrape of the process)."""
+    reg.gauge("training_loss").set(float("nan"))
+    reg.gauge("depth").set(2)
+    text = reg.render_prometheus()
+    assert "training_loss NaN" in text and "depth 2" in text
+
+
 def test_render_prometheus_histogram_cumulative(reg):
     h = reg.histogram("lat_seconds", buckets=(0.1, 1.0))
     for v in (0.05, 0.5, 5.0):

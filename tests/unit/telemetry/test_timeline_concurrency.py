@@ -126,9 +126,16 @@ def test_concurrent_writers_with_wraparound():
             for e in obj["traceEvents"] if e["ph"] == "M"}
     assert "asyncio-frontend" in meta
     assert len(set(meta.values())) == len(meta)
-    # the newest fully-recorded lifeline in the window is unbroken
+    # the newest fully-recorded lifeline in the window is unbroken. The
+    # ring drops oldest first, so a lifeline is whole in it when its first
+    # record and its last are: the other writer can push a lifeline's
+    # first record out before its last is written (a thread switch between
+    # two of its four records lets thousands of spans in), and did, once
+    # in three whole runs of the suite
+    first = {s["attrs"]["uid"] for s in spans
+             if s["name"] == "request_queue"}
     uids = [s["attrs"]["uid"] for s in spans
-            if s["name"] == "request" and "attrs" in s]
+            if s["name"] == "request" and s["attrs"]["uid"] in first]
     assert uids, "no complete request span retained"
     life = timeline.request_lifeline(max(uids))
     for phase in timeline.REQUEST_PHASES:
