@@ -144,9 +144,12 @@ def phase_kernels(sizes):
         kernel_variant, ragged_attention)
     from deepspeed_tpu.ops.attention_autotune import parity_check
 
-    for hd in (64, 128):
-        rep = parity_check(batch=1, heads=4, kv_heads=2,
-                           seq=sizes["flash_seq"], head_dim=hd)
+    # the last row is over the 4,096 rows of K/V one grid step keeps
+    # resident: the chunked walk and its clamped index maps
+    for hd, seq in ((64, sizes["flash_seq"]), (128, sizes["flash_seq"]),
+                    (64, sizes["flash_seq_chunked"])):
+        rep = parity_check(batch=1, heads=4, kv_heads=2, seq=seq,
+                           head_dim=hd)
         print(f"  flash fwd+bwd hd={hd} seq={rep['seq']}: "
               f"out={rep['out_rel_err']:.2e} dq={rep['dq_rel_err']:.2e} "
               f"dk={rep['dk_rel_err']:.2e} dv={rep['dv_rel_err']:.2e}",
@@ -461,7 +464,7 @@ def sizes_for(rehearse):
     from deepspeed_tpu.models.transformer import opt_125m, opt_1_3b
     if not rehearse:
         return dict(
-            flash_seq=2048,
+            flash_seq=2048, flash_seq_chunked=8192,
             train_cfg_1=opt_125m(), micro_1=8,
             train_cfg_4=opt_1_3b(), micro_4=2,
             serve_cfg=opt_1_3b(),
@@ -475,7 +478,7 @@ def sizes_for(rehearse):
         opt_125m(), vocab_size=512, hidden_size=128, intermediate_size=512,
         num_layers=2, num_heads=4, max_seq_len=256, flash_min_seq=256)
     return dict(
-        flash_seq=256,
+        flash_seq=256, flash_seq_chunked=512,
         train_cfg_1=toy, micro_1=2, train_cfg_4=toy, micro_4=2,
         serve_cfg=toy, prompt_lens=(4, 9, 17, 30, 41, 60, 90, 150),
         new_tokens=8, kv_tokens=1024, generate_tokens=512,

@@ -8,6 +8,9 @@ constant from one autotune run. This module provides the measured versions:
     the current backend (the real chip when present) and returns the max
     abs/rel error, fwd and grads. chip_smoke.py (phase K) and bench.py
     run it on the chip.
+  * ``time_kernels``     — times the forward, dq and dk/dv kernels each
+    alone for one geometry (scripts/flash_kernel_table.py prints the
+    table of them; ``flash_attention._auto_blocks`` is read off it).
   * ``measure_crossover`` — times flash vs XLA attention (fwd+bwd) at a
     ladder of sequence lengths for a given head geometry and returns the
     smallest S where flash wins (the measured value for
@@ -26,6 +29,7 @@ from typing import Dict, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
+from . import flash_attention as _fa
 from .flash_attention import flash_attention, mha_reference
 
 
@@ -116,6 +120,45 @@ def _time_step(fn, args, steps: int = 5, warmup: int = 2) -> float:
         out = fn(*args)
     jax.block_until_ready(out)
     return (time.perf_counter() - t0) / steps
+
+
+def time_kernels(batch: int, heads: int, kv_heads: int, seq: int,
+                 head_dim: int, kv_seq: Optional[int] = None,
+                 causal: bool = True, dtype=jnp.bfloat16,
+                 block_q: Optional[int] = None,
+                 block_kv: Optional[int] = None,
+                 steps: int = 10) -> Dict[str, float]:
+    """Seconds a call of each flash kernel takes alone on the CURRENT
+    backend: ``fwd``, ``dq`` and ``dkv`` (XLA drops the kernel whose
+    result a jit does not return; dq and dkv each include the small
+    ``sum(dO * O)`` reduction both read). ``block_q``/``block_kv`` None =
+    the kernels' own table."""
+    kv_seq = kv_seq or seq
+    q, _, _ = _inputs(batch, heads, kv_heads, seq, head_dim, dtype)
+    _, k, v = _inputs(batch, heads, kv_heads, kv_seq, head_dim, dtype, seed=1)
+    scale, blocks = _fa._plan(q.shape, k.shape, causal, None, block_q,
+                              block_kv)
+    q = q.reshape(batch * heads, seq, head_dim)
+    k = k.reshape(batch * kv_heads, kv_seq, head_dim)
+    v = v.reshape(batch * kv_heads, kv_seq, head_dim)
+
+    fwd = jax.jit(lambda q, k, v: _fa._flash_fwd(q, k, v, scale, causal,
+                                                 *blocks[0]))
+    o, lse = fwd(q, k, v)
+    do = jax.random.normal(jax.random.PRNGKey(3), o.shape, o.dtype)
+
+    def bwd(*res_and_do):
+        return _fa._flash_bwd(res_and_do[:5], res_and_do[5], scale, causal,
+                              blocks)
+
+    res = (q, k, v, o, lse, do)
+    return {
+        "fwd": _time_step(fwd, (q, k, v), steps),
+        "dq": _time_step(jax.jit(lambda *a: bwd(*a)[0]), res, steps),
+        "dkv": _time_step(jax.jit(lambda *a: bwd(*a)[1:]), res, steps),
+        "blocks": blocks,
+        "backend": jax.default_backend(),
+    }
 
 
 def measure_crossover(batch: int = 1, heads: int = 16, kv_heads: int = 16,

@@ -4,18 +4,45 @@ TPU-native replacement for the reference's fused attention kernels
 (csrc/transformer/ds_transformer_cuda.cpp softmax path, and the inference
 attention kernels in csrc/transformer/inference). Implements the
 memory-efficient online-softmax algorithm (never materializes the [S, S]
-score matrix) as three Mosaic kernels:
+score matrix) as three Mosaic kernels that share one block schedule:
 
-  * forward:  grid (BH, Sq/bq, Skv/bk), running (m, l, acc) in VMEM scratch —
-    the kv grid axis is innermost and TPU grids execute sequentially, so the
-    scratch carries across kv steps.
-  * backward dq: same grid, accumulates dq over kv blocks.
-  * backward dk/dv: grid (BH, Skv/bk, Sq/bq), accumulates dk, dv over q blocks.
+  * forward and backward dq: grid (BH, Sq/bq, kv chunks). A chunk is the
+    run of K/V rows one grid step keeps in VMEM — the whole sequence
+    when it fits ``_RESIDENT_BYTES``, which is one chunk and one K/V fetch a
+    head. Inside a step a ``lax.fori_loop`` walks the chunk in (bq, bk)
+    blocks and stops at the causal bound, so blocks above the diagonal
+    are neither scheduled nor fetched: with more than one chunk the
+    index map clamps to the last live chunk, which names the block
+    already resident and Pallas elides the copy.
+  * backward dk/dv: grid (BHkv, Skv/bk, GQA group x q chunks), the same
+    walk transposed — q and dO are the resident operands, the loop
+    starts at the first live q block, and the scores are computed as
+    ``k·qT`` so that ``dv = pT·dO`` and ``dk = dsT·q`` are plain
+    matmuls and the group reduction stays in VMEM scratch.
+
+The forward's walk is split in two loops over the same integers: blocks
+the diagonal crosses run the masked body, blocks wholly below it run a body
+without compare or select. The backward kernels mask every block they
+visit (the split measured nothing there on the v5e). ``block_schedule``
+counts the visited and the diagonal's blocks from the bounds the kernels
+use (``_kv_bounds``/``_q_bounds``) and is published as the gauges
+``flash_blocks_{grid,live,masked}``.
+
+Row statistics are lane-dense: lse and delta are ``[BH, 1, Sq]`` float32
+(the sequence is the minor dimension; no 128x lane padding in HBM). The
+forward keeps its running max and sum lane-replicated ``(bq, 128)`` in
+scratch, as the update needs them; dq turns its lse/delta rows into
+columns once per q block; dk/dv broadcasts them as rows against the
+transposed tile. The softmax scale multiplies the float32 scores; dq and
+dk take it on the float32 accumulator.
 
 Supports causal masking (bottom-right aligned for sq != skv, matching the
-usual decode convention; fully-masked blocks are skipped via pl.when) and
-grouped-query attention (kv-head indexing in the BlockSpec index map). f32
-accumulation on the MXU (preferred_element_type) with bf16 inputs.
+usual decode convention; fully-masked rows give zeros) and grouped-query
+attention (kv-head indexing in the BlockSpec index map). f32 accumulation
+on the MXU (preferred_element_type) with bf16 inputs. Blocks come from
+``_auto_blocks``, measured on the v5e per kernel; ``block_q``/``block_kv``
+override it for all three. A q block is the lane dimension of the lse and
+delta blocks, so it is a multiple of 128 or the whole of sq (``_plan``).
 
 On non-TPU backends (the CPU test mesh) kernels run in interpret mode;
 parity is tested against the jnp reference in tests/unit/ops.
@@ -23,29 +50,29 @@ parity is tested against the jnp reference in tests/unit/ops.
 
 import functools
 import math
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..telemetry import registry as _registry
+
 NEG_INF = -1e30
+_LANES = 128
+# VMEM the resident operands of one grid step (K and V; in dk/dv q and dO)
+# may take, their double buffers included. 4 MiB holds 4096 rows of bf16
+# at head widths up to 128; the scoped default on a v5e is 16 MiB and the
+# score tiles need the rest.
+_RESIDENT_BYTES = 4 * 2 ** 20
+
+_NT = (((1,), (1,)), ((), ()))      # a · bT
+_NN = (((1,), (0,)), ((), ()))      # a · b
 
 
 def _interpret() -> bool:
     return jax.default_backend() != "tpu"
-
-
-def _cost(bh, sq, skv, d, causal, n_dots):
-    """CostEstimate so XLA's scheduler can overlap collectives with the
-    kernel (the pallas body is opaque to XLA's own cost analysis)."""
-    frac = 0.5 if causal else 1.0
-    return pl.CostEstimate(
-        flops=int(n_dots * 2 * bh * sq * skv * d * frac),
-        bytes_accessed=int(2 * bh * (sq + skv) * d * 2 * n_dots),
-        transcendentals=int(bh * sq * skv * frac),
-    )
 
 
 def _pick_block(s: int, target: int) -> int:
@@ -56,93 +83,294 @@ def _pick_block(s: int, target: int) -> int:
     return max(b, 1)
 
 
+def _chunk_rows(s: int, block: int, d: int, itemsize: int) -> int:
+    """Rows of a [s, d] operand pair one grid step keeps resident: all of
+    s when two such operands, double-buffered, fit ``_RESIDENT_BYTES``,
+    else the largest divisor of s that does and is a multiple of block."""
+    row_bytes = 2 * 2 * max(d, _LANES) * itemsize
+    for n in range(1, s // block + 1):
+        rows, rem = divmod(s, n)
+        if (rem == 0 and rows % block == 0
+                and rows * row_bytes <= _RESIDENT_BYTES):
+            return rows
+    return block
+
+
+# ---------------------------------------------------------------------------
+# Block schedule: which (q block, kv block) pairs a kernel visits
+# ---------------------------------------------------------------------------
+
+def _clip(x, lo, hi):
+    if all(isinstance(a, int) for a in (x, lo, hi)):
+        return min(max(x, lo), hi)
+    return jnp.clip(x, lo, hi)
+
+
+def _kv_bounds(i, c, *, bq, bk, ck, off, causal):
+    """For q block i and kv chunk c: (n_full, n_live) in blocks of the
+    chunk. Blocks [0, n_full) lie wholly on or below the diagonal, blocks
+    [n_full, n_live) are crossed by it, the rest are dead. Works on
+    Python ints (``block_schedule``) and on traced ones (the kernels)."""
+    n = ck // bk
+    if not causal:
+        return n, n
+    first_q = i * bq + off - c * ck          # relative to the chunk's start
+    n_live = _clip((first_q + bq - 1) // bk + 1, 0, n)
+    n_full = _clip((first_q + 1) // bk, 0, n_live)
+    return n_full, n_live
+
+
+def _q_bounds(j, c, *, bq, bk, cq, off, causal):
+    """For kv block j and q chunk c: (i_lo, i_full) in blocks of the
+    chunk. q blocks [i_lo, i_full) are crossed by the diagonal, blocks
+    [i_full, cq // bq) lie wholly below it, blocks before i_lo are dead."""
+    n = cq // bq
+    if not causal:
+        return 0, 0
+    first_k = j * bk - off - c * cq          # first row that sees block j
+    i_lo = _clip(first_k // bq, 0, n)
+    i_full = _clip(-((-(first_k + bk - 1)) // bq), i_lo, n)
+    return i_lo, i_full
+
+
+def _last_live_chunk(i, *, bq, ck, n_chunks, off):
+    """The kv chunk holding q block i's last visible column (clamped)."""
+    return _clip((i * bq + bq - 1 + off) // ck, 0, n_chunks - 1)
+
+
+def _first_live_chunk(j, *, bk, cq, n_chunks, off):
+    """The q chunk holding the first row that sees kv block j (clamped)."""
+    return _clip((j * bk - off) // cq, 0, n_chunks - 1)
+
+
+class BlockSchedule(NamedTuple):
+    grid: int       # grid steps a head
+    live: int       # (bq, bk) block pairs a kernel body visits
+    masked: int     # of those, the ones the diagonal crosses
+    fetched: int    # chunk fetches a head (steps naming one chunk share one)
+
+
+def block_schedule(sq: int, skv: int, bq: int, bk: int, causal: bool, *,
+                   chunk: Optional[int] = None,
+                   kv_major: bool = False) -> BlockSchedule:
+    """What one head costs in grid steps, visited blocks and fetches.
+
+    ``chunk`` is the rows of the inner operand a grid step keeps resident
+    (default: one block, i.e. every block is its own grid step and fetch);
+    ``kv_major`` counts the dk/dv walk (kv blocks outside, q inside)."""
+    off = skv - sq
+    n_outer, inner, block = ((skv // bk, sq, bq) if kv_major
+                             else (sq // bq, skv, bk))
+    chunk = chunk or block
+    n_chunks = inner // chunk
+    live = masked = fetched = 0
+    resident = None                 # the chunk the previous grid step named
+    for o in range(n_outer):
+        for c in range(n_chunks):
+            if kv_major:
+                i_lo, i_full = _q_bounds(o, c, bq=bq, bk=bk, cq=chunk,
+                                         off=off, causal=causal)
+                live += chunk // bq - i_lo
+                masked += i_full - i_lo
+                named = max(c, _first_live_chunk(
+                    o, bk=bk, cq=chunk, n_chunks=n_chunks, off=off))
+            else:
+                n_full, n_live = _kv_bounds(o, c, bq=bq, bk=bk, ck=chunk,
+                                            off=off, causal=causal)
+                live += n_live
+                masked += n_live - n_full
+                named = min(c, _last_live_chunk(
+                    o, bq=bq, ck=chunk, n_chunks=n_chunks, off=off))
+            named = named if causal else c
+            fetched += named != resident
+            resident = named
+    return BlockSchedule(n_outer * n_chunks, live, masked, fetched)
+
+
+def _publish(kernel: str, sq: int, skv: int, d: int, sched: BlockSchedule,
+             masked: int):
+    """Gauges of a kernel's schedule, set while its call is traced;
+    ``masked`` is the visited blocks that run the kernel's masked body."""
+    reg = _registry.get_registry()
+    labels = ("kernel", "geometry")
+    at = dict(kernel=kernel, geometry=f"{sq}x{skv}x{d}")
+    reg.gauge("flash_blocks_grid", "grid steps a head of a flash kernel",
+              labelnames=labels).labels(**at).set(sched.grid)
+    reg.gauge("flash_blocks_live", "(bq, bk) blocks a head's walk visits",
+              labelnames=labels).labels(**at).set(sched.live)
+    reg.gauge("flash_blocks_masked", "visited blocks that build a mask",
+              labelnames=labels).labels(**at).set(masked)
+
+
+def _cost(bh, d, itemsize, sched: BlockSchedule, bq, bk, n_dots, rows_moved):
+    """CostEstimate so XLA's scheduler can overlap collectives with the
+    kernel (the pallas body is opaque to XLA's own cost analysis): the
+    matmuls of the blocks the walk visits, and every operand row once."""
+    area = sched.live * bq * bk
+    return pl.CostEstimate(
+        flops=int(n_dots * 2 * bh * area * d),
+        bytes_accessed=int(bh * rows_moved * d * itemsize),
+        transcendentals=int(bh * area),
+    )
+
+
+# ---------------------------------------------------------------------------
+# In-kernel helpers
+# ---------------------------------------------------------------------------
+
+def _lanes(x, n):
+    """A lane-replicated (rows, 128) statistic as (rows, n)."""
+    if n <= _LANES:
+        return x if n == _LANES else x[:, :n]
+    reps = pl.cdiv(n, _LANES)
+    x = jnp.tile(x, (1, reps))
+    return x if reps * _LANES == n else x[:, :n]
+
+
+def _walk(lo, hi, body):
+    """body(j) for j in [lo, hi); state lives in scratch refs."""
+    def step(j, carry):
+        body(j)
+        return carry
+    jax.lax.fori_loop(lo, hi, step, 0)
+
+
+def _kv_chunk_map(group, causal, bq, ck, n_chunks, off):
+    """Index map of the resident K/V chunk for grid (head, q block, chunk):
+    a dead step names the last live chunk, which is already in VMEM."""
+    def kv_map(b, i, c):
+        if causal and n_chunks > 1:
+            c = jnp.minimum(c, _last_live_chunk(i, bq=bq, ck=ck,
+                                                n_chunks=n_chunks, off=off))
+        return b // group, c, 0
+    return kv_map
+
+
+def _rel(shape, q_axis):
+    """Position of a tile's q index minus its kv index, before the block's
+    own offset: an element is visible iff this is >= that offset."""
+    return (jax.lax.broadcasted_iota(jnp.int32, shape, q_axis)
+            - jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis))
+
+
+def _dot(a, b, dims):
+    # keep dots in the input dtype (bf16 runs the MXU at full rate; f32
+    # matmul is ~8x slower) with f32 accumulation
+    return jax.lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
+
+
 # ---------------------------------------------------------------------------
 # Forward kernel
 # ---------------------------------------------------------------------------
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
-                acc_sc, m_sc, l_sc, *, scale, causal, bq, bk, n_kv, off):
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_sc, m_sc, l_sc,
+                *, scale, causal, bq, bk, ck, n_chunks, off):
     i = pl.program_id(1)  # q block
-    j = pl.program_id(2)  # kv block
+    c = pl.program_id(2)  # kv chunk
+    d = q_ref.shape[-1]
 
-    @pl.when(j == 0)
+    @pl.when(c == 0)
     def _init():
         acc_sc[:] = jnp.zeros_like(acc_sc)
         m_sc[:] = jnp.full_like(m_sc, NEG_INF)
         l_sc[:] = jnp.zeros_like(l_sc)
 
-    # causal: skip blocks entirely above the (bottom-right aligned) diagonal
-    run = True
+    q = q_ref[0]                                     # (bq, d)
+    n_full, n_live = _kv_bounds(i, c, bq=bq, bk=bk, ck=ck, off=off,
+                                causal=causal)
     if causal:
-        run = j * bk <= (i + 1) * bq - 1 + off
+        rel = _rel((bq, bk), q_axis=0)
 
-    @pl.when(run)
-    def _body():
-        # keep dots in the input dtype (bf16 runs the MXU at full rate; f32
-        # matmul is ~8x slower) with f32 accumulation
-        q = q_ref[0]                                 # (bq, d)
-        k = k_ref[0]                                 # (bk, d)
-        v = v_ref[0]                                 # (bk, d)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        if causal:
-            q_pos = off + i * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-            k_pos = j * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-            s = jnp.where(q_pos >= k_pos, s, NEG_INF)
-        m_prev = m_sc[:, :1]                         # (bq, 1)
-        m_cur = jnp.max(s, axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
+    def block(j, masked):
+        rows = pl.ds(pl.multiple_of(j * bk, bk), bk)
+        k = k_ref[0, rows, :]                        # (bk, d)
+        v = v_ref[0, rows, :]
+        s = _dot(q, k, _NT) * scale                  # (bq, bk) f32
+        if masked:
+            s = jnp.where(rel >= c * ck + j * bk - i * bq - off, s, NEG_INF)
+        m_prev = m_sc[:]                             # (bq, 128)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         # rows fully masked so far have m_new == NEG_INF; exp(s - m_new)
         # would be exp(0) = 1 garbage — substitute 0 so exp(NEG_INF) == 0
-        m_safe = jnp.where(m_new <= NEG_INF * 0.5, 0.0, m_new)
-        p = jnp.exp(s - m_safe)                      # (bq, bk) f32
-        corr = jnp.exp(m_prev - m_new)               # (bq, 1)
-        l_new = l_sc[:, :1] * corr + jnp.sum(p, axis=1, keepdims=True)
-        acc_sc[:] = acc_sc[:] * corr + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_sc[:] = jnp.broadcast_to(m_new, m_sc.shape)
-        l_sc[:] = jnp.broadcast_to(l_new, l_sc.shape)
+        m_safe = (jnp.where(m_new <= NEG_INF * 0.5, 0.0, m_new)
+                  if masked else m_new)
+        p = jnp.exp(s - _lanes(m_safe, bk))          # (bq, bk) f32
+        corr = jnp.exp(m_prev - m_new)               # (bq, 128)
+        l_sc[:] = l_sc[:] * corr + jnp.sum(p, axis=1, keepdims=True)
+        acc_sc[:] = acc_sc[:] * _lanes(corr, d) + _dot(p.astype(v.dtype), v,
+                                                       _NN)
+        m_sc[:] = m_new
 
-    @pl.when(j == n_kv - 1)
+    _walk(0, n_full, lambda j: block(j, False))
+    if causal:
+        _walk(n_full, n_live, lambda j: block(j, True))
+
+    @pl.when(c == n_chunks - 1)
     def _finish():
-        l = l_sc[:, :1]
+        l = l_sc[:]
         l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_sc[:] / l_safe).astype(o_ref.dtype)
-        lse_ref[0] = m_sc[:, :1] + jnp.log(l_safe)
+        o_ref[0] = (acc_sc[:] / _lanes(l_safe, d)).astype(o_ref.dtype)
+        lse_ref[0] = _col_to_row(m_sc[:] + jnp.log(l_safe))
+
+
+def _col_to_row(x):
+    """A lane-replicated (rows, 128) statistic as one lane-dense (1, rows)
+    row: per group of 128 rows, the diagonal of the square the group
+    spans, summed over sublanes."""
+    pieces = []
+    for lo in range(0, x.shape[0], _LANES):
+        n = min(_LANES, x.shape[0] - lo)
+        eye = (jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
+               == jax.lax.broadcasted_iota(jnp.int32, (n, n), 1))
+        pieces.append(jnp.sum(jnp.where(eye, x[lo:lo + n, :n], 0.0), axis=0,
+                              keepdims=True))
+    return pieces[0] if len(pieces) == 1 else jnp.concatenate(pieces, axis=1)
+
+
+def _row_to_col(ref):
+    """A (1, 1, rows) block of a lane-dense statistic as a lane-replicated
+    (rows, 128) column."""
+    col = ref[0, 0][:, None]
+    return jnp.broadcast_to(col, (col.shape[0], _LANES))
 
 
 def _flash_fwd(q, k, v, scale, causal, bq, bk):
     bh, sq, d = q.shape
     bhk, skv, _ = k.shape
     group = bh // bhk
-    n_q, n_kv = pl.cdiv(sq, bq), pl.cdiv(skv, bk)
+    off = skv - sq
+    ck = _chunk_rows(skv, bk, d, k.dtype.itemsize)
+    n_q, n_chunks = sq // bq, skv // ck
+    sched = block_schedule(sq, skv, bq, bk, causal, chunk=ck)
+    _publish("fwd", sq, skv, d, sched, sched.masked)
 
-    kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
-                               bq=bq, bk=bk, n_kv=n_kv, off=skv - sq)
-    out_shape = [
-        jax.ShapeDtypeStruct(q.shape, q.dtype),
-        jax.ShapeDtypeStruct((bh, sq, 1), jnp.float32),
-    ]
+    kv_map = _kv_chunk_map(group, causal, bq, ck, n_chunks, off)
+    kernel = functools.partial(
+        _fwd_kernel, scale=scale, causal=causal, bq=bq, bk=bk, ck=ck,
+        n_chunks=n_chunks, off=off)
     o, lse = pl.pallas_call(
         kernel,
-        grid=(bh, n_q, n_kv),
+        grid=(bh, n_q, n_chunks),
         in_specs=[
-            pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bk, d), lambda b, i, j, g=group: (b // g, j, 0)),
-            pl.BlockSpec((1, bk, d), lambda b, i, j, g=group: (b // g, j, 0)),
+            pl.BlockSpec((1, bq, d), lambda b, i, c: (b, i, 0)),
+            pl.BlockSpec((1, ck, d), kv_map),
+            pl.BlockSpec((1, ck, d), kv_map),
         ],
         out_specs=[
-            pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, bq, d), lambda b, i, c: (b, i, 0)),
+            pl.BlockSpec((1, 1, bq), lambda b, i, c: (b, 0, i)),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, d), jnp.float32),
-            pltpu.VMEM((bq, 128), jnp.float32),
-            pltpu.VMEM((bq, 128), jnp.float32),
+            pltpu.VMEM((bq, _LANES), jnp.float32),
+            pltpu.VMEM((bq, _LANES), jnp.float32),
         ],
-        out_shape=out_shape,
-        cost_estimate=_cost(bh, sq, skv, d, causal, n_dots=2),
+        out_shape=[
+            jax.ShapeDtypeStruct(q.shape, q.dtype),
+            jax.ShapeDtypeStruct((bh, 1, sq), jnp.float32),
+        ],
+        cost_estimate=_cost(bh, d, q.dtype.itemsize, sched, bq, bk, n_dots=2,
+                            rows_moved=2 * sq + 2 * skv // group),
         name="flash_attention_fwd",
         interpret=_interpret(),
     )(q, k, v)
@@ -154,152 +382,160 @@ def _flash_fwd(q, k, v, scale, causal, bq, bk):
 # ---------------------------------------------------------------------------
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-                   dq_sc, *, scale, causal, bq, bk, n_kv, off):
+                   dq_sc, *, scale, causal, bq, bk, ck, n_chunks, off):
     i = pl.program_id(1)
-    j = pl.program_id(2)
+    c = pl.program_id(2)
 
-    @pl.when(j == 0)
+    @pl.when(c == 0)
     def _init():
         dq_sc[:] = jnp.zeros_like(dq_sc)
 
-    run = True
+    q = q_ref[0]
+    do = do_ref[0]
+    lse = _row_to_col(lse_ref)                       # (bq, 128)
+    # fully-masked rows carry lse == NEG_INF; exp(s - lse) would be 1
+    lse = jnp.where(lse <= NEG_INF * 0.5, 0.0, lse)
+    delta = _row_to_col(delta_ref)
+    _, n_live = _kv_bounds(i, c, bq=bq, bk=bk, ck=ck, off=off, causal=causal)
     if causal:
-        run = j * bk <= (i + 1) * bq - 1 + off
+        rel = _rel((bq, bk), q_axis=0)
 
-    @pl.when(run)
-    def _body():
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        do = do_ref[0]
-        lse = lse_ref[0]                             # (bq, 1)
-        delta = delta_ref[0]                         # (bq, 1)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
+    def block(j):
+        rows = pl.ds(pl.multiple_of(j * bk, bk), bk)
+        k = k_ref[0, rows, :]
+        v = v_ref[0, rows, :]
+        s = _dot(q, k, _NT) * scale
         if causal:
-            q_pos = off + i * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-            k_pos = j * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-            s = jnp.where(q_pos >= k_pos, s, NEG_INF)
-        # fully-masked rows carry lse == NEG_INF; exp(s - lse) would be 1
-        lse_safe = jnp.where(lse <= NEG_INF * 0.5, 0.0, lse)
-        p = jnp.exp(s - lse_safe)                    # (bq, bk) f32
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta) * scale).astype(k.dtype)
-        dq_sc[:] += jax.lax.dot_general(ds, k, (((1,), (0,)), ((), ())),
-                                        preferred_element_type=jnp.float32)
+            s = jnp.where(rel >= c * ck + j * bk - i * bq - off, s, NEG_INF)
+        p = jnp.exp(s - _lanes(lse, bk))             # (bq, bk) f32
+        dp = _dot(do, v, _NT)
+        ds = (p * (dp - _lanes(delta, bk))).astype(k.dtype)
+        dq_sc[:] += _dot(ds, k, _NN)
 
-    @pl.when(j == n_kv - 1)
+    _walk(0, n_live, block)
+
+    @pl.when(c == n_chunks - 1)
     def _finish():
-        dq_ref[0] = dq_sc[:].astype(dq_ref.dtype)
+        dq_ref[0] = (dq_sc[:] * scale).astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     dk_ref, dv_ref, dk_sc, dv_sc,
-                    *, scale, causal, bq, bk, n_q, n_inner, off):
+                    *, scale, causal, bq, bk, cq, n_chunks, n_inner, off):
     j = pl.program_id(1)   # kv block (outer)
-    e = pl.program_id(2)   # inner: q-heads of the GQA group x q blocks
-    i = e % n_q            # q block within the head
+    e = pl.program_id(2)   # inner: q-heads of the GQA group x q chunks
+    c = e % n_chunks       # q chunk within the head
 
     @pl.when(e == 0)
     def _init():
         dk_sc[:] = jnp.zeros_like(dk_sc)
         dv_sc[:] = jnp.zeros_like(dv_sc)
 
-    run = True
+    k = k_ref[0]                                     # (bk, d)
+    v = v_ref[0]
+    i_lo, _ = _q_bounds(j, c, bq=bq, bk=bk, cq=cq, off=off, causal=causal)
     if causal:
-        run = (i + 1) * bq - 1 + off >= j * bk
+        # transposed tile: rows are kv positions, lanes are q positions
+        rel = _rel((bk, bq), q_axis=1)
 
-    @pl.when(run)
-    def _body():
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        do = do_ref[0]
-        lse = lse_ref[0]
-        delta = delta_ref[0]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
+    def block(i):
+        start = pl.multiple_of(i * bq, bq)
+        q = q_ref[0, pl.ds(start, bq), :]            # (bq, d)
+        do = do_ref[0, pl.ds(start, bq), :]
+        lse = lse_ref[0, :, pl.ds(start, bq)]        # (1, bq)
+        delta = delta_ref[0, :, pl.ds(start, bq)]
+        st = _dot(k, q, _NT) * scale                 # (bk, bq) f32
         if causal:
-            q_pos = off + i * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-            k_pos = j * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-            s = jnp.where(q_pos >= k_pos, s, NEG_INF)
-        lse_safe = jnp.where(lse <= NEG_INF * 0.5, 0.0, lse)
-        p = jnp.exp(s - lse_safe)                    # (bq, bk) f32
-        pc = p.astype(do.dtype)
-        dv_sc[:] += jax.lax.dot_general(pc, do, (((0,), (0,)), ((), ())),
-                                        preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta) * scale).astype(q.dtype)  # (bq, bk)
-        dk_sc[:] += jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ())),
-                                        preferred_element_type=jnp.float32)
+            st = jnp.where(rel >= j * bk - off - c * cq - i * bq, st,
+                           NEG_INF)
+        lse = jnp.where(lse <= NEG_INF * 0.5, 0.0, lse)
+        pt = jnp.exp(st - lse)                       # (bk, bq) f32
+        dv_sc[:] += _dot(pt.astype(do.dtype), do, _NN)
+        dpt = _dot(v, do, _NT)
+        dst = (pt * (dpt - delta)).astype(q.dtype)
+        dk_sc[:] += _dot(dst, q, _NN)
+
+    _walk(i_lo, cq // bq, block)
 
     @pl.when(e == n_inner - 1)
     def _finish():
-        dk_ref[0] = dk_sc[:].astype(dk_ref.dtype)
+        dk_ref[0] = (dk_sc[:] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_sc[:].astype(dv_ref.dtype)
 
 
-def _flash_bwd(res, g, scale, causal, bq, bk):
+def _flash_bwd(res, g, scale, causal, blocks):
     q, k, v, o, lse = res
     do = g
     bh, sq, d = q.shape
     bhk, skv, _ = k.shape
     group = bh // bhk
-    n_q, n_kv = pl.cdiv(sq, bq), pl.cdiv(skv, bk)
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1,
-                    keepdims=True)
+    off = skv - sq
+    itemsize = q.dtype.itemsize
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
+                    axis=-1)[:, None, :]             # [bh, 1, sq]
 
+    bq, bk = blocks[1]
+    ck = _chunk_rows(skv, bk, d, itemsize)
+    n_q, n_chunks = sq // bq, skv // ck
+    sched = block_schedule(sq, skv, bq, bk, causal, chunk=ck)
+    # the backward kernels mask every block they visit: splitting their
+    # walks bought nothing on the chip (PERF.md, PR 26)
+    _publish("dq", sq, skv, d, sched, sched.live if causal else 0)
+
+    kv_map = _kv_chunk_map(group, causal, bq, ck, n_chunks, off)
+    q_spec = pl.BlockSpec((1, bq, d), lambda b, i, c: (b, i, 0))
+    row_spec = pl.BlockSpec((1, 1, bq), lambda b, i, c: (b, 0, i))
     dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
-                          bq=bq, bk=bk, n_kv=n_kv, off=skv - sq),
-        grid=(bh, n_q, n_kv),
-        in_specs=[
-            pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bk, d), lambda b, i, j, g_=group: (b // g_, j, 0)),
-            pl.BlockSpec((1, bk, d), lambda b, i, j, g_=group: (b // g_, j, 0)),
-            pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
+        functools.partial(_bwd_dq_kernel, scale=scale, causal=causal, bq=bq,
+                          bk=bk, ck=ck, n_chunks=n_chunks, off=off),
+        grid=(bh, n_q, n_chunks),
+        in_specs=[q_spec, pl.BlockSpec((1, ck, d), kv_map),
+                  pl.BlockSpec((1, ck, d), kv_map), q_spec, row_spec,
+                  row_spec],
+        out_specs=q_spec,
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        cost_estimate=_cost(bh, sq, skv, d, causal, n_dots=3),
+        cost_estimate=_cost(bh, d, itemsize, sched, bq, bk, n_dots=3,
+                            rows_moved=3 * sq + 2 * skv // group),
         name="flash_attention_bwd_dq",
         interpret=_interpret(),
     )(q, k, v, do, lse, delta)
 
-    # dk/dv: grid over kv heads; the inner axis walks every q block of every
+    # dk/dv: grid over kv heads; the inner axis walks every q chunk of every
     # q-head in the GQA group, accumulating in VMEM scratch — the group
     # reduction happens in-register instead of a second [bh, skv, d] HBM pass.
-    n_inner = group * n_q
+    bq, bk = blocks[2]
+    cq = _chunk_rows(sq, bq, d, itemsize)
+    n_kv, n_chunks = skv // bk, sq // cq
+    n_inner = group * n_chunks
+    sched = block_schedule(sq, skv, bq, bk, causal, chunk=cq, kv_major=True)
+    _publish("dkv", sq, skv, d, sched, sched.live if causal else 0)
+
+    def q_chunk(b, j, e):
+        c = e % n_chunks
+        if causal and n_chunks > 1:
+            c = jnp.maximum(c, _first_live_chunk(j, bk=bk, cq=cq,
+                                                 n_chunks=n_chunks, off=off))
+        return b * group + e // n_chunks, c
+
+    def q_map(b, j, e):
+        return (*q_chunk(b, j, e), 0)
+
+    def row_map(b, j, e):
+        head, c = q_chunk(b, j, e)
+        return head, 0, c
+
+    kv_spec = pl.BlockSpec((1, bk, d), lambda b, j, e: (b, j, 0))
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
-                          bq=bq, bk=bk, n_q=n_q, n_inner=n_inner,
-                          off=skv - sq),
+                          bq=bq, bk=bk, cq=cq, n_chunks=n_chunks,
+                          n_inner=n_inner, off=off),
         grid=(bhk, n_kv, n_inner),
-        in_specs=[
-            pl.BlockSpec((1, bq, d),
-                         lambda b, j, e, g_=group, nq=n_q:
-                         (b * g_ + e // nq, e % nq, 0)),
-            pl.BlockSpec((1, bk, d), lambda b, j, e: (b, j, 0)),
-            pl.BlockSpec((1, bk, d), lambda b, j, e: (b, j, 0)),
-            pl.BlockSpec((1, bq, d),
-                         lambda b, j, e, g_=group, nq=n_q:
-                         (b * g_ + e // nq, e % nq, 0)),
-            pl.BlockSpec((1, bq, 1),
-                         lambda b, j, e, g_=group, nq=n_q:
-                         (b * g_ + e // nq, e % nq, 0)),
-            pl.BlockSpec((1, bq, 1),
-                         lambda b, j, e, g_=group, nq=n_q:
-                         (b * g_ + e // nq, e % nq, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, bk, d), lambda b, j, e: (b, j, 0)),
-            pl.BlockSpec((1, bk, d), lambda b, j, e: (b, j, 0)),
-        ],
+        in_specs=[pl.BlockSpec((1, cq, d), q_map), kv_spec, kv_spec,
+                  pl.BlockSpec((1, cq, d), q_map),
+                  pl.BlockSpec((1, 1, cq), row_map),
+                  pl.BlockSpec((1, 1, cq), row_map)],
+        out_specs=[kv_spec, kv_spec],
         scratch_shapes=[
             pltpu.VMEM((bk, d), jnp.float32),
             pltpu.VMEM((bk, d), jnp.float32),
@@ -308,7 +544,8 @@ def _flash_bwd(res, g, scale, causal, bq, bk):
             jax.ShapeDtypeStruct((bhk, skv, d), k.dtype),
             jax.ShapeDtypeStruct((bhk, skv, d), v.dtype),
         ],
-        cost_estimate=_cost(bh, sq, skv, d, causal, n_dots=5),
+        cost_estimate=_cost(bh, d, itemsize, sched, bq, bk, n_dots=4,
+                            rows_moved=2 * sq + 4 * skv // group),
         name="flash_attention_bwd_dkv",
         interpret=_interpret(),
     )(q, k, v, do, lse, delta)
@@ -319,22 +556,53 @@ def _flash_bwd(res, g, scale, causal, bq, bk):
 # Public API
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash_core(q, k, v, scale, causal, bq, bk):
-    o, _ = _flash_fwd(q, k, v, scale, causal, bq, bk)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _flash_core(q, k, v, scale, causal, blocks):
+    o, _ = _flash_fwd(q, k, v, scale, causal, *blocks[0])
     return o
 
 
-def _flash_core_fwd(q, k, v, scale, causal, bq, bk):
-    o, lse = _flash_fwd(q, k, v, scale, causal, bq, bk)
+def _flash_core_fwd(q, k, v, scale, causal, blocks):
+    o, lse = _flash_fwd(q, k, v, scale, causal, *blocks[0])
     return o, (q, k, v, o, lse)
 
 
-def _flash_core_bwd(scale, causal, bq, bk, res, g):
-    return _flash_bwd(res, g, scale, causal, bq, bk)
+def _flash_core_bwd(scale, causal, blocks, res, g):
+    return _flash_bwd(res, g, scale, causal, blocks)
 
 
 _flash_core.defvjp(_flash_core_fwd, _flash_core_bwd)
+
+
+def _auto_blocks(causal):
+    """(block_q, block_kv) targets for the forward, dq and dk/dv kernels,
+    read off scripts/flash_kernel_table.py --sweep on a v5e (PERF.md, PR
+    26): block_q 128-1024 x block_kv 128-2048, each kernel alone. Causal
+    calls took 512 x 512 in all three kernels at every shape swept (head
+    width 64 and 128, GQA group 1 and 4, sq == skv at 2048 and 4096, sq
+    1024 under skv 2048), so the diagonal is the table's one key; with
+    none to waste work on, non-causal calls took the whole 2048 rows of
+    K/V a block (4 / 16 / 6 % over 512 x 512)."""
+    return ((512, 512),) * 3 if causal else ((512, 2048),) * 3
+
+
+def _plan(q_shape, k_shape, causal, scale, block_q, block_kv):
+    """(scale, ((bq, bk) for forward, dq, dk/dv)) of a call."""
+    _, h, sq, d = q_shape
+    _, hk, skv, _ = k_shape
+    assert h % hk == 0, f"GQA requires h({h}) % hk({hk}) == 0"
+    scale = scale if scale is not None else 1.0 / math.sqrt(d)
+    blocks = tuple(
+        (_pick_block(sq, block_q or tq), _pick_block(skv, block_kv or tk))
+        for tq, tk in _auto_blocks(causal))
+    for bq, _ in blocks:
+        if bq % _LANES and bq != sq:
+            raise ValueError(
+                f"flash attention's q block is the lane dimension of its "
+                f"lse and delta blocks: it has to be a multiple of {_LANES} "
+                f"or all of sq, and sq={sq} with block_q={block_q} gives "
+                f"{bq}")
+    return scale, blocks
 
 
 def flash_attention(q, k, v, causal: bool = True, scale: Optional[float] = None,
@@ -343,23 +611,15 @@ def flash_attention(q, k, v, causal: bool = True, scale: Optional[float] = None,
     """Flash attention over [batch, num_heads, seq, head_dim] inputs.
 
     k/v may have fewer heads (GQA); num_heads % num_kv_heads == 0.
-    block_q/block_kv None (or 0) = auto: 256/512 capped to the seq lens —
-    large blocks amortize the online-softmax bookkeeping and keep the MXU
-    fed; VMEM cost at d<=128 is well under budget.
+    block_q/block_kv None (or 0) = auto: ``_auto_blocks``, capped to the
+    seq lens; a value overrides all three kernels'.
     """
     b, h, sq, d = q.shape
     _, hk, skv, _ = k.shape
-    assert h % hk == 0, f"GQA requires h({h}) % hk({hk}) == 0"
-    scale = scale if scale is not None else 1.0 / math.sqrt(d)
-    bq = _pick_block(sq, block_q or 256)
-    bk = _pick_block(skv, block_kv or 512)
-    assert sq % bq == 0 and skv % bk == 0, \
-        f"seq lengths ({sq},{skv}) must be multiples of block sizes ({bq},{bk})"
-    qf = q.reshape(b * h, sq, d)
-    kf = k.reshape(b * hk, skv, d)
-    vf = v.reshape(b * hk, skv, d)
+    scale, blocks = _plan(q.shape, k.shape, causal, scale, block_q, block_kv)
     # fold batch into the head axis keeping kv-head grouping contiguous
-    o = _flash_core(qf, kf, vf, scale, causal, bq, bk)
+    o = _flash_core(q.reshape(b * h, sq, d), k.reshape(b * hk, skv, d),
+                    v.reshape(b * hk, skv, d), scale, causal, blocks)
     return o.reshape(b, h, sq, d)
 
 

@@ -137,28 +137,50 @@ def test_gate_uses_dma_where_the_issue_found_it_compiles():
         assert kernel_variant(hd, kvh, True) == "pipelined"
 
 
-@pytest.mark.parametrize("hd,kv_heads", [(64, 4), (128, 2)])
-def test_flash_fwd_bwd_compile(tpu_sharding, hd, kv_heads):
+@pytest.mark.parametrize("hd,kv_heads,causal,seq", [
+    (64, 4, True, 2048), (128, 2, True, 2048),
+    (128, 2, False, 2048),          # non-causal takes 512 x 2048 blocks
+    # over 4,096 rows K/V no longer fit one chunk: the gridded walk, whose
+    # index maps clamp traced chunk indices, with a GQA group in dk/dv
+    (64, 2, True, 8192), (128, 2, True, 8192),
+])
+def test_flash_fwd_bwd_compile(tpu_sharding, hd, kv_heads, causal, seq):
+    from deepspeed_tpu.ops import flash_attention as fa
     from deepspeed_tpu.ops.flash_attention import flash_attention
+
+    assert seq // fa._chunk_rows(seq, 512, hd, 2) == max(1, seq // 4096)
 
     def sds(shape):
         return jax.ShapeDtypeStruct(shape, jnp.bfloat16,
                                     sharding=tpu_sharding)
 
     def loss(q, k, v):
-        return jnp.sum(flash_attention(q, k, v, causal=True)
+        return jnp.sum(flash_attention(q, k, v, causal=causal)
                        .astype(jnp.float32) ** 2)
 
-    q, kv = sds((1, 4, 2048, hd)), sds((1, kv_heads, 2048, hd))
+    q, kv = sds((1, 4, seq, hd)), sds((1, kv_heads, seq, hd))
     err = _compile_error(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
     assert err is None, err
 
 
-def test_flash_kernels_are_named_in_the_compiled_program(tpu_sharding):
-    """A device trace shows a Mosaic call under its instruction's name:
+_CELL_GEOMETRIES = {                   # [batch a chip, heads, seq, head_dim]
+    "opt-125m.train-dense": (32, 12, 2048, 64),
+    "opt-1.3b.zero3-dp4": (4, 32, 2048, 64),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(_CELL_GEOMETRIES))
+def test_flash_at_the_cells_geometry(tpu_sharding, cell):
+    """The benchmark's two cells, forward and backward, as the v5e's
+    compiler sees them.
+
+    A device trace shows a Mosaic call under its instruction's name:
     ``pallas_call(name=...)`` reaches it (unnamed, the three were
     ``checkpoint.20``, ``closed_call.8``, whatever jaxpr was round them),
-    and the benchmark's per-kernel shares find them by it."""
+    and the benchmark's per-kernel shares find them by it. And the row
+    statistics stay lane-dense: a ``f32[bh, sq, 1]`` operand is tiled
+    T(8,128), i.e. padded 128x in HBM (402 MB each for lse and delta on
+    the dense cell) and moved as 128 KB blocks."""
     import re
     from deepspeed_tpu.ops.flash_attention import flash_attention
 
@@ -167,17 +189,25 @@ def test_flash_kernels_are_named_in_the_compiled_program(tpu_sharding):
             o = flash_attention(q, k, v, causal=True)
         return jnp.sum(o.astype(jnp.float32) ** 2)
 
-    x = jax.ShapeDtypeStruct((1, 4, 2048, 64), jnp.bfloat16,
+    b, h, s, d = _CELL_GEOMETRIES[cell]
+    x = jax.ShapeDtypeStruct((b, h, s, d), jnp.bfloat16,
                              sharding=tpu_sharding)
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         x, x, x).compile().as_text()
-    kernels = re.findall(
-        r'%([\w.\-]+) = [^\n]*custom_call_target="tpu_custom_call"', text)
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    kernels = [re.match(r"\s*(?:ROOT )?%([\w.\-]+) = ", c).group(1)
+               for c in calls]
     assert len(kernels) == 3, kernels
     for name in ("flash_attention_fwd", "flash_attention_bwd_dq",
                  "flash_attention_bwd_dkv"):
         assert [k for k in kernels if re.search(
             rf"(?<!sparse_){name}[_.0-9]*$", k)], (name, kernels)
+    for call in calls:
+        types = call.split("metadata=")[0]     # result and operand types
+        assert not re.search(r"f32\[[\d,]*,1\]", types), types
+    assert f"f32[{b * h},1,{s}]" in "".join(calls)
+    assert not re.search(rf"f32\[{b * h},{s},1\]", text)
 
 
 # ---------------------------------------------------------------------------

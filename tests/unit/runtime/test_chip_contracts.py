@@ -30,6 +30,18 @@ def test_chip_smoke_without_tpu_fails_naming_the_platform():
     assert '"ok"' not in out.stdout and "PASS" not in out.stdout
 
 
+def test_flash_kernel_table_without_tpu_prints_no_table():
+    """Its rows are read into PERF.md and ``_auto_blocks`` as chip times;
+    on the CPU they would be the Pallas interpreter's."""
+    out = subprocess.run(
+        [sys.executable, "scripts/flash_kernel_table.py", "--only",
+         "head128"], cwd=REPO, env=dict(os.environ, JAX_PLATFORMS="cpu"),
+        capture_output=True, text=True, timeout=120)
+    assert out.returncode != 0
+    assert "platform == 'cpu'" in out.stderr
+    assert "ours_ms" not in out.stdout and "backend" not in out.stdout
+
+
 def test_chip_smoke_rehearsal_passes_and_labels_itself():
     """One phase keeps this inside the tier-1 budget; the slow sibling
     below rehearses all five."""
