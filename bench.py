@@ -130,7 +130,7 @@ def _measure(cfg, micro, gas, steps, warmup, n_dev, zero_stage=None,
                      and seq >= cfg.flash_min_seq else "xla",
         "attn_blocks": [cfg.attn_block_q, cfg.attn_block_kv],
         "loss_chunk": cfg.loss_chunk,
-        "remat_policy": remat_policy or "nothing_saveable",
+        "remat_policy": engine.remat_policy[0],
         "zero_stage": config["zero_optimization"]["stage"],
         "global_batch_tokens": tokens_per_step,
     }

@@ -560,6 +560,14 @@ class TransformerLM:
             return rms_norm(x, w, self.cfg.norm_eps)
         return layer_norm(x, w, b, self.cfg.norm_eps)
 
+    def _flash_at(self, seq: int) -> bool:
+        """XLA fused attention for short sequences, Pallas flash once the
+        S^2 score tensor dominates (see flash_min_seq rationale); the ALiBi
+        branch is always XLA's."""
+        cfg = self.cfg
+        return (cfg.use_flash and cfg.positional != "alibi"
+                and seq >= cfg.flash_min_seq)
+
     def _attention(self, q, k, v):
         cfg = self.cfg
         from ..sequence.layer import sharded_attention
@@ -590,18 +598,18 @@ class TransformerLM:
             o = jnp.einsum("bhqk,bhkd->bhqd", probs, v)
             return checkpoint_name(o, "attn_out")
 
-        # policy: XLA fused attention for short sequences, Pallas flash once
-        # the S^2 score tensor dominates (see flash_min_seq rationale)
-        use_flash = cfg.use_flash and q.shape[2] >= cfg.flash_min_seq
-        o = sharded_attention(q, k, v, self.topology, causal=cfg.is_causal,
-                              use_flash=use_flash,
-                              block_q=cfg.attn_block_q,
-                              block_kv=cfg.attn_block_kv,
-                              impl=cfg.seq_parallel_impl)
-        # tag for selective remat (save_attn / save_dots_and_attn policies,
-        # runtime/activation_checkpointing): saving o skips the attention
-        # forward re-run in backward — the most expensive recompute at long S
-        return checkpoint_name(o, "attn_out")
+        use_flash = self._flash_at(q.shape[2])
+        # the output is named "attn_out" for selective remat where it is
+        # made (sequence/layer._inner_attention for the XLA and ring paths;
+        # the flash kernel names o and its row statistics itself, as
+        # "attn_out" and "attn_lse"): a policy that saves those names
+        # (save_attn, the engine's default when memory allows) leaves no
+        # attention forward to re-run in the backward
+        return sharded_attention(q, k, v, self.topology,
+                                 causal=cfg.is_causal, use_flash=use_flash,
+                                 block_q=cfg.attn_block_q,
+                                 block_kv=cfg.attn_block_kv,
+                                 impl=cfg.seq_parallel_impl)
 
     def _layer(self, x, lp, cos, sin):
         """One block, in two named scopes (``attention``, then ``mlp`` or
@@ -1270,6 +1278,30 @@ class TransformerLM:
         if "lm_head_b" in params:
             logits = logits + params["lm_head_b"].astype(jnp.float32)
         return logits, {"k": new_k, "v": new_v}
+
+    def activation_save_sets(self, batch, micro_batch_size: int,
+                             itemsize: int):
+        """What ``activation_checkpointing.policy: auto`` may keep of a
+        layer for the backward, smallest first: (policy name, bytes a
+        device over all layers of one micro-batch). ``batch`` gives the
+        sequence length, ``micro_batch_size`` the sequences a data-parallel
+        rank holds, ``itemsize`` the activations' bytes an element. Empty
+        where there is no layer checkpoint to steer (``remat`` off, or the
+        pipeline program, which checkpoints by stage).
+
+        One set: the attention output and, from the flash kernel, float32
+        row statistics a head. The MLP's pre-activation was measured as a
+        second one and is not offered: 1.7 % on one chip at 90 % of its
+        memory, a loss of 2.2 % under ZeRO-3 (PERF.md, PR 27)."""
+        cfg, topo = self.cfg, self.topology
+        axis = topo.axis_size if topo is not None else (lambda a: 1)
+        if not cfg.remat or axis("pipe") > 1 or "input_ids" not in batch:
+            return []
+        seq = batch["input_ids"].shape[-1]
+        # heads are split over "model", the sequence over "seq"
+        tokens = micro_batch_size * seq // (axis("model") * axis("seq"))
+        return [("save_attn", cfg.num_layers * tokens * cfg.num_heads * (
+            cfg.head_dim * itemsize + (4 if self._flash_at(seq) else 0)))]
 
     def flops_per_token(self, seq_len: Optional[int] = None) -> float:
         """6*N_active + attention flops per token (for MFU accounting)."""

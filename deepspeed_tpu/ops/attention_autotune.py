@@ -138,18 +138,17 @@ def time_kernels(batch: int, heads: int, kv_heads: int, seq: int,
     _, k, v = _inputs(batch, heads, kv_heads, kv_seq, head_dim, dtype, seed=1)
     scale, blocks = _fa._plan(q.shape, k.shape, causal, None, block_q,
                               block_kv)
-    q = q.reshape(batch * heads, seq, head_dim)
-    k = k.reshape(batch * kv_heads, kv_seq, head_dim)
-    v = v.reshape(batch * kv_heads, kv_seq, head_dim)
+    q, k, v = _fa._fold(q), _fa._fold(k), _fa._fold(v)
 
     fwd = jax.jit(lambda q, k, v: _fa._flash_fwd(q, k, v, scale, causal,
                                                  *blocks[0]))
     o, lse = fwd(q, k, v)
     do = jax.random.normal(jax.random.PRNGKey(3), o.shape, o.dtype)
 
-    def bwd(*res_and_do):
-        return _fa._flash_bwd(res_and_do[:5], res_and_do[5], scale, causal,
-                              blocks)
+    def bwd(q, k, v, o, lse, do):
+        return _fa._flash_bwd(q, k, v, do, lse,
+                              _fa._row_dots(do, o)[:, None, :], scale,
+                              causal, blocks)
 
     res = (q, k, v, o, lse, do)
     return {

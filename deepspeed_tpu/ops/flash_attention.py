@@ -54,6 +54,7 @@ from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -463,16 +464,20 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_ref[0] = dv_sc[:].astype(dv_ref.dtype)
 
 
-def _flash_bwd(res, g, scale, causal, blocks):
-    q, k, v, o, lse = res
-    do = g
+def _row_dots(do, o):
+    """delta = rowsum(dO * O) in float32 over the last axis: what the two
+    backward kernels read of the forward's output."""
+    return jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
+
+
+def _flash_bwd(q, k, v, do, lse, delta, scale, causal, blocks):
+    """dq, dk, dv of folded ``[bh, s, d]`` operands; ``lse`` and ``delta``
+    are ``[bh, 1, sq]`` float32."""
     bh, sq, d = q.shape
     bhk, skv, _ = k.shape
     group = bh // bhk
     off = skv - sq
     itemsize = q.dtype.itemsize
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
-                    axis=-1)[:, None, :]             # [bh, 1, sq]
 
     bq, bk = blocks[1]
     ck = _chunk_rows(skv, bk, d, itemsize)
@@ -556,19 +561,66 @@ def _flash_bwd(res, g, scale, causal, blocks):
 # Public API
 # ---------------------------------------------------------------------------
 
+def _fold(x):
+    """[b, h, s, d] -> [b*h, s, d]: batch folded into the head axis, the
+    kernels' grid axis (kv-head grouping stays contiguous)."""
+    b, h, s, d = x.shape
+    return x.reshape(b * h, s, d)
+
+
+def _heads_to_tokens(o, b):
+    """[b*h, s, d] -> [b, s, h*d]: the layout the output projection reads,
+    and the one HBM holds without padding (a minor dimension of 64 is
+    padded to the 128 lanes: twice the bytes)."""
+    bh, s, d = o.shape
+    return o.reshape(b, bh // b, s, d).transpose(0, 2, 1, 3).reshape(
+        b, s, bh // b * d)
+
+
+def _tokens_to_heads(o, d):
+    """[b, s, h*d] -> [b, h, s, d]."""
+    b, s, hd = o.shape
+    return o.reshape(b, s, hd // d, d).transpose(0, 2, 1, 3)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def _flash_core(q, k, v, scale, causal, blocks):
-    o, _ = _flash_fwd(q, k, v, scale, causal, *blocks[0])
-    return o
+    o, _ = _flash_fwd(_fold(q), _fold(k), _fold(v), scale, causal,
+                      *blocks[0])
+    return o.reshape(q.shape)
 
 
 def _flash_core_fwd(q, k, v, scale, causal, blocks):
-    o, lse = _flash_fwd(q, k, v, scale, causal, *blocks[0])
-    return o, (q, k, v, o, lse)
+    b, _, _, d = q.shape
+    o, lse = _flash_fwd(_fold(q), _fold(k), _fold(v), scale, causal,
+                        *blocks[0])
+    # The two residuals only the kernel can make are named, so that a
+    # checkpoint policy can keep them (runtime/activation_checkpointing:
+    # ATTN_NAMES, the save_attn policy): with both saved, nothing in the
+    # recomputed layer reads the forward pallas_call and it is dropped as
+    # dead code. o is kept as [b, s, h*d], which HBM does not pad (lse's
+    # [bh, 1, sq] is tiled T(1,128): no padding either). The output is
+    # derived from the named o, so that what follows it in a recomputed
+    # layer reads the saved array too. q, k and v are cheap to make again
+    # and stay unnamed.
+    o = checkpoint_name(_heads_to_tokens(o, b), "attn_out")
+    lse = checkpoint_name(lse, "attn_lse")
+    return _tokens_to_heads(o, d), (q, k, v, o, lse)
 
 
 def _flash_core_bwd(scale, causal, blocks, res, g):
-    return _flash_bwd(res, g, scale, causal, blocks)
+    q, k, v, o, lse = res
+    b, h, sq, d = q.shape
+    # the kernels read o only through delta: taken in the layout o is kept
+    # in, against dO in the same one (under the model this undoes the
+    # transpose that made g, and XLA drops the pair), so o never goes
+    # back to the kernels' padded [bh, s, d]
+    do_tok = _heads_to_tokens(_fold(g), b)
+    delta = _row_dots(do_tok.reshape(b, sq, h, d), o.reshape(b, sq, h, d))
+    dq, dk, dv = _flash_bwd(
+        _fold(q), _fold(k), _fold(v), _fold(g), lse,
+        delta.transpose(0, 2, 1).reshape(b * h, 1, sq), scale, causal, blocks)
+    return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
 
 
 _flash_core.defvjp(_flash_core_fwd, _flash_core_bwd)
@@ -614,13 +666,8 @@ def flash_attention(q, k, v, causal: bool = True, scale: Optional[float] = None,
     block_q/block_kv None (or 0) = auto: ``_auto_blocks``, capped to the
     seq lens; a value overrides all three kernels'.
     """
-    b, h, sq, d = q.shape
-    _, hk, skv, _ = k.shape
     scale, blocks = _plan(q.shape, k.shape, causal, scale, block_q, block_kv)
-    # fold batch into the head axis keeping kv-head grouping contiguous
-    o = _flash_core(q.reshape(b * h, sq, d), k.reshape(b * hk, skv, d),
-                    v.reshape(b * hk, skv, d), scale, causal, blocks)
-    return o.reshape(b, h, sq, d)
+    return _flash_core(q, k, v, scale, causal, blocks)
 
 
 def mha_reference(q, k, v, causal: bool = True, scale: Optional[float] = None):

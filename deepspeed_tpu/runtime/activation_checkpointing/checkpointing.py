@@ -22,11 +22,30 @@ of that collapses into ``jax.checkpoint``:
 ``activation_checkpointing`` from the JSON config), plus a TPU-native
 ``policy`` knob naming any jax.checkpoint_policies entry for selective
 checkpointing (e.g. "dots_saveable" to keep matmul outputs).
+
+The default ``policy`` is ``"auto"``: the engine picks, once, when it first
+sees a batch's shapes, the richest of the model's save sets that fits the
+memory its placed state leaves free (``choose_policy``). Anything that
+traces a model without an engine's choice gets ``nothing_saveable``.
 """
 
-from typing import Any, Callable, Dict, Optional
+from typing import Any, Callable, Dict, Optional, Sequence, Tuple
 
 import jax
+
+AUTO = "auto"
+NOTHING = "nothing_saveable"
+# what the model names for a policy to keep (jax.ad_checkpoint
+# .checkpoint_name): the attention output, once, where it is made, and the
+# flash kernel's row statistics, which its backward reads beside it
+# (ops/flash_attention._flash_core_fwd)
+ATTN_NAMES = ("attn_out", "attn_lse")
+# the share of the memory left free by the placed state that saved
+# activations may take; the rest is the step's own working set (gradients,
+# layer-boundary activations, one layer's recompute, the loss head). The
+# benchmark's cells take 8 % and 7 % for save_attn and peak at 46 % and
+# 54 % of the chip (PERF.md, PR 27)
+SAVE_SHARE = 0.25
 
 _config: Dict[str, Any] = {
     "partition_activations": False,
@@ -35,7 +54,7 @@ _config: Dict[str, Any] = {
     "number_checkpoints": None,
     "synchronize_checkpoint_boundary": False,
     "profile": False,
-    "policy": "nothing_saveable",
+    "policy": AUTO,
 }
 _configured = False
 
@@ -46,23 +65,45 @@ def _resolve_policy(name: str, cpu_checkpointing: bool = False):
         # keeping them in HBM (reference checkpoint_in_cpu / copy_to_main_memory)
         return jax.checkpoint_policies.offload_dot_with_no_batch_dims(
             "device", "pinned_host")
+    if name == AUTO:
+        # no engine has chosen (yet)
+        return jax.checkpoint_policies.nothing_saveable
     if name == "save_attn":
-        # keep attention outputs (tagged checkpoint_name("attn_out") in the
-        # model): dots_with_no_batch_dims skips them (attention einsums have
-        # batch dims, and the Pallas flash call is opaque to dot policies),
-        # so without the tag the whole attention fwd re-runs in backward
-        return jax.checkpoint_policies.save_only_these_names("attn_out")
+        # keep what the attention backward reads and only the attention
+        # forward can make: its output and, on the flash path, the row
+        # statistics. dots_with_no_batch_dims skips them (attention einsums
+        # have batch dims, and the Pallas call is opaque to dot policies);
+        # with both saved the recomputed layer holds no attention forward
+        return jax.checkpoint_policies.save_only_these_names(*ATTN_NAMES)
     if name == "save_dots_and_attn":
         return jax.checkpoint_policies.save_from_both_policies(
             jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
-            jax.checkpoint_policies.save_only_these_names("attn_out"))
+            jax.checkpoint_policies.save_only_these_names(*ATTN_NAMES))
     policy = getattr(jax.checkpoint_policies, name, None)
     if policy is None:
         raise ValueError(
             f"unknown activation-checkpointing policy '{name}'; options: "
-            f"save_attn, save_dots_and_attn, "
+            f"{AUTO}, save_attn, save_dots_and_attn, "
             f"{[p for p in dir(jax.checkpoint_policies) if not p.startswith('_')]}")
     return policy
+
+
+def choose_policy(bytes_limit: int, state_bytes: int,
+                  save_sets: Sequence[Tuple[str, int]]) -> Tuple[str, int]:
+    """(policy name, bytes it keeps a device) for ``policy: auto``.
+
+    ``save_sets`` is the model's ordered list, smallest first, of (policy
+    name, bytes a device the policy keeps over all layers of a step); the
+    richest one within ``SAVE_SHARE`` of what ``state_bytes`` (parameters,
+    master weights, optimizer state as placed) leaves of ``bytes_limit``
+    wins. A chip the state fills, or a device that reports no limit, gets
+    ``nothing_saveable``. Nothing is compiled or traced to decide."""
+    budget = SAVE_SHARE * max(int(bytes_limit or 0) - int(state_bytes), 0)
+    chosen = (NOTHING, 0)
+    for name, nbytes in save_sets:
+        if 0 < nbytes <= budget:
+            chosen = (name, int(nbytes))
+    return chosen
 
 
 def configure(mpu_=None, deepspeed_config=None, partition_activations=None,
@@ -183,6 +224,6 @@ def reset():
                    contiguous_memory_optimization=False,
                    number_checkpoints=None,
                    synchronize_checkpoint_boundary=False, profile=False,
-                   policy="nothing_saveable")
+                   policy=AUTO)
     _configured = False
     _RNG_TRACKER.reset()

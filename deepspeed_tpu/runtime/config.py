@@ -276,8 +276,10 @@ class ActivationCheckpointingConfig:
     number_checkpoints: Optional[int] = None
     synchronize_checkpoint_boundary: bool = False
     profile: bool = False
-    # TPU-native: remat policy name passed to jax.checkpoint
-    policy: str = "nothing_saveable"
+    # TPU-native: remat policy name passed to jax.checkpoint. "auto": the
+    # engine keeps what the chip's free memory allows (checkpointing.py
+    # choose_policy); any other name is obeyed as written
+    policy: str = "auto"
 
 
 @dataclass

@@ -322,6 +322,14 @@ class DeepSpeedTpuEngine:
         # _configure_checkpointing -> checkpointing.configure)
         from .activation_checkpointing import checkpointing as ds_ckpt
         ds_ckpt.configure(deepspeed_config=self.config)
+        # (policy name, bytes it keeps a device) once settled: a policy the
+        # config names is obeyed here; "auto" waits for the first batch's
+        # shapes (_settle_remat_policy)
+        ac_policy = self.config.activation_checkpointing.policy
+        self.remat_policy = (None if ac_policy == ds_ckpt.AUTO
+                             else (ac_policy, None))
+        # armed while a set that "auto" chose has yet to compile once
+        self._remat_fallback = False
 
         # --- compression (QAT/pruning) spec, applied inside the loss
         # (reference compression/compress.py init_compression rewrites
@@ -879,6 +887,103 @@ class DeepSpeedTpuEngine:
     # ------------------------------------------------------------------
     # Compiled train step
     # ------------------------------------------------------------------
+    def _device_bytes_limit(self) -> int:
+        """What one device's allocator may hold (0 where the backend does
+        not say, as the CPU's does not)."""
+        try:
+            stats = self.mesh.devices.flat[0].memory_stats() or {}
+        except Exception:
+            stats = {}
+        return int(stats.get("bytes_limit", 0))
+
+    def _placed_state_bytes(self) -> int:
+        """Bytes a device holds of parameters, master weights and optimizer
+        state as placed (shards, not global shapes; what sits in host
+        memory is not the chip's)."""
+        total = 0
+        for x in jax.tree.leaves((self.params, self.master_params,
+                                  self.opt_state)):
+            sh = getattr(x, "sharding", None)
+            if getattr(sh, "memory_kind", None) == "pinned_host":
+                continue
+            shape = sh.shard_shape(x.shape) if sh is not None else x.shape
+            total += int(np.prod(shape)) * x.dtype.itemsize
+        return total
+
+    def _settle_remat_policy(self, dev_batch) -> None:
+        """``activation_checkpointing.policy: auto``, settled once, before
+        the step is first traced: the richest of the model's save sets
+        that fits what the placed state leaves of the device's memory
+        (checkpointing.choose_policy). From shapes alone: nothing is
+        compiled to find out."""
+        if self.remat_policy is not None:
+            return
+        from .activation_checkpointing import checkpointing as ds_ckpt
+        sets_fn = getattr(self.model, "activation_save_sets", None)
+        # the steps that keep state off the chip chose memory over speed,
+        # and build programs of their own: nothing_saveable, as ever
+        standard = not (self.offload_device or self.onebit_mode
+                        or self.param_offload or self.param_offload_nvme)
+        sets = sets_fn(dev_batch, self.micro_batch_size,
+                       jnp.dtype(self.compute_dtype).itemsize) \
+            if sets_fn is not None and standard else []
+        limit, state = self._device_bytes_limit(), self._placed_state_bytes()
+        self._set_remat_policy(*ds_ckpt.choose_policy(limit, state, sets))
+        self._remat_fallback = self.remat_policy[0] != ds_ckpt.NOTHING
+        log_dist(
+            f"activation checkpointing keeps {self.remat_policy[0]} "
+            f"({self.remat_policy[1] / 1e9:.2f} GB a device; state "
+            f"{state / 1e9:.2f} of {limit / 1e9:.2f} GB; offered: "
+            f"{[(n, round(b / 1e9, 2)) for n, b in sets]})", ranks=[0])
+
+    def _set_remat_policy(self, name: str, saved_bytes) -> None:
+        from .activation_checkpointing import checkpointing as ds_ckpt
+        self.remat_policy = (name, saved_bytes)
+        ds_ckpt.configure(policy=name)
+        if getattr(self, "telemetry_enabled", False):
+            # one series a process: the last engine's choice reads 1
+            g = self.telemetry.gauge(
+                "remat_policy", "activation-checkpointing policy of the "
+                "train step (1 on the one in force)", labelnames=("chosen",))
+            for _, series in g.series():
+                series.set(0)
+            g.labels(chosen=name).set(1)
+            self.telemetry.gauge(
+                "remat_saved_bytes", "bytes a device the policy keeps of "
+                "the layers for the backward (from shapes; 0 for a policy "
+                "the config named)", unit="bytes").set(saved_bytes or 0)
+
+    def _dispatch_train_step(self, dev_batch):
+        """The compiled step; its first call compiles it. If the save set
+        that ``policy: auto`` chose does not fit after all, the compiler
+        says so before anything runs: fall back to ``nothing_saveable``,
+        once, and say so."""
+        def call():
+            return self._train_step(
+                self.params, self.master_params, self.opt_state,
+                self.scale_state, self._step_arr, self._model_rng,
+                dev_batch, self.quant_reduce_state)
+
+        if not self._remat_fallback:
+            return call()
+        self._remat_fallback = False
+        try:
+            return call()
+        except jax.errors.JaxRuntimeError as e:
+            donated = any(x.is_deleted() for x in jax.tree.leaves(
+                (self.params, self.master_params, self.opt_state)))
+            if "RESOURCE_EXHAUSTED" not in str(e) or donated:
+                raise
+            from .activation_checkpointing import checkpointing as ds_ckpt
+            chosen, saved = self.remat_policy
+            logger.warning(
+                f"the train step did not fit with {chosen} "
+                f"({saved / 1e9:.2f} GB of saved activations a device): "
+                f"compiling it again with {ds_ckpt.NOTHING}")
+            self._set_remat_policy(ds_ckpt.NOTHING, 0)
+            self._build_train_step()
+            return call()
+
     def _loss_fn(self, params, micro_batch, rng, scale, step=None):
         if self.compression_spec is not None and step is not None:
             params = self.compression_spec.apply(params, step)
@@ -1634,14 +1739,9 @@ class DeepSpeedTpuEngine:
     # ------------------------------------------------------------------
     # Public API (reference surface)
     # ------------------------------------------------------------------
-    def lower_train_step(self, batch, compiler_options=None):
-        """AOT-compile the train step for analysis (HLO text, overlap
-        report, cost) without executing it. Returns the jax Compiled.
-
-        TPU targets get the collective-overlap compiler options by default
-        (the AOT compile-only client does not read LIBTPU_INIT_ARGS, and
-        reduce-scatter async-fusion is off without them — the bucketed
-        reduction would measure as fully exposed for want of a flag)."""
+    def _lower_train_step(self, batch):
+        """The train step lowered for ``batch`` (jax's Lowered): traced
+        under the settled checkpoint policy, nothing compiled."""
         if self.offload_device or self.onebit_mode or self.param_offload_nvme:
             raise NotImplementedError(
                 "lower_train_step supports the standard jitted step only "
@@ -1661,6 +1761,21 @@ class DeepSpeedTpuEngine:
             dev_batch = jax.tree.map(prep, batch)
         else:
             dev_batch = self._shard_batch(batch)
+        self._settle_remat_policy(dev_batch)
+        return self._train_step.lower(
+            self.params, self.master_params, self.opt_state,
+            self.scale_state, self._step_arr, self._model_rng, dev_batch,
+            self.quant_reduce_state)
+
+    def lower_train_step(self, batch, compiler_options=None):
+        """AOT-compile the train step for analysis (HLO text, overlap
+        report, cost) without executing it. Returns the jax Compiled.
+
+        TPU targets get the collective-overlap compiler options by default
+        (the AOT compile-only client does not read LIBTPU_INIT_ARGS, and
+        reduce-scatter async-fusion is off without them — the bucketed
+        reduction would measure as fully exposed for want of a flag)."""
+        lowered = self._lower_train_step(batch)
         if compiler_options is None:
             try:
                 on_tpu = self.mesh.devices.flat[0].platform == "tpu"
@@ -1673,10 +1788,6 @@ class DeepSpeedTpuEngine:
                 from ..accelerator.tpu_accelerator import \
                     COLLECTIVE_OVERLAP_COMPILER_OPTIONS
                 compiler_options = dict(COLLECTIVE_OVERLAP_COMPILER_OPTIONS)
-        lowered = self._train_step.lower(
-            self.params, self.master_params, self.opt_state,
-            self.scale_state, self._step_arr, self._model_rng, dev_batch,
-            self.quant_reduce_state)
         t0 = time.perf_counter()
         compiled = (lowered.compile(compiler_options=compiler_options)
                     if compiler_options else lowered.compile())
@@ -1747,6 +1858,7 @@ class DeepSpeedTpuEngine:
         # the host-side split of a training step's wall time
         with trace.span("train_data", step=self.global_steps):
             dev_batch = self._shard_batch(batch)
+        self._settle_remat_policy(dev_batch)
         # stall watchdog: armed only while a step is in flight — a hung
         # host sync (wedged collective, dead chip) is what it catches
         stall = self._ensure_stall_watchdog()
@@ -1763,10 +1875,8 @@ class DeepSpeedTpuEngine:
                 else:
                     (self.params, self.master_params, self.opt_state,
                      self.scale_state, self._step_arr, self._model_rng,
-                     metrics, self.quant_reduce_state) = self._train_step(
-                        self.params, self.master_params, self.opt_state,
-                        self.scale_state, self._step_arr, self._model_rng,
-                        dev_batch, self.quant_reduce_state)
+                     metrics, self.quant_reduce_state) = \
+                        self._dispatch_train_step(dev_batch)
                 self._relocate_params_to_storage()
             # the loss fetch blocks on the async-dispatched device step, so
             # it belongs inside the span/timer (XLA programs complete here)

@@ -20,6 +20,7 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
 from ..parallel.topology import MODEL_AXIS, SEQ_AXIS, MeshTopology
@@ -39,8 +40,9 @@ def _inner_attention(q, k, v, causal, use_flash, block_q, block_kv, sp_size,
 
     if sp_size > 1 and impl == "ring":
         from .ring_attention import ring_attention
-        return ring_attention(q, k, v, SEQ_AXIS, causal=causal, scale=scale,
-                              q_chunk=block_q, kv_chunk=block_kv)
+        return checkpoint_name(
+            ring_attention(q, k, v, SEQ_AXIS, causal=causal, scale=scale,
+                           q_chunk=block_q, kv_chunk=block_kv), "attn_out")
 
     if sp_size > 1:
         # Ulysses: heads -> heads/sp, seq/sp -> seq
@@ -53,12 +55,18 @@ def _inner_attention(q, k, v, causal, use_flash, block_q, block_kv, sp_size,
         k = seq_all_to_all(k, SEQ_AXIS, scatter_dim=1, gather_dim=2)
         v = seq_all_to_all(v, SEQ_AXIS, scatter_dim=1, gather_dim=2)
 
+    # the output carries the name "attn_out" for selective checkpointing
+    # (runtime/activation_checkpointing: save_attn) exactly once: the flash
+    # kernel names its own output and row statistics where its backward
+    # reads them (ops/flash_attention._flash_core_fwd), so a second name
+    # here would save the same array twice
     s = q.shape[2]
     if use_flash and s % 128 == 0 and k.shape[2] % 128 == 0:
         o = flash_attention(q, k, v, causal=causal, scale=scale,
                             block_q=block_q or None, block_kv=block_kv or None)
     else:
-        o = mha_reference(q, k, v, causal=causal, scale=scale)
+        o = checkpoint_name(mha_reference(q, k, v, causal=causal, scale=scale),
+                            "attn_out")
 
     if sp_size > 1:
         o = seq_all_to_all(o, SEQ_AXIS, scatter_dim=2, gather_dim=1)

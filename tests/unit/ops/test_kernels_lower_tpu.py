@@ -211,6 +211,64 @@ def test_flash_at_the_cells_geometry(tpu_sharding, cell):
 
 
 # ---------------------------------------------------------------------------
+# the train step on a chip its state fills: the program nothing_saveable gives
+# ---------------------------------------------------------------------------
+V5E_BYTES_LIMIT = 15.75 * 2 ** 30      # what a v5e chip's allocator reports
+
+
+@pytest.mark.parametrize("micro,want,fwd_calls", [
+    # 16 layers at OPT-1.3B's widths: 12.7 GB of state on one chip leave
+    # 4.2 GB, a quarter of which may go to saved activations (SAVE_SHARE)
+    (16, "nothing_saveable", 2),        # the flash set would be 2.2 GB
+    (4, "save_attn", 1),                # 0.55 GB: kept
+])
+def test_train_step_on_a_full_chip(tpu_sharding, monkeypatch, micro, want,
+                                   fwd_calls):
+    """``activation_checkpointing.policy: auto`` on a memory-full job
+    lowers the very program that ``nothing_saveable`` lowers (what every
+    job got before the policy was chosen from memory): two forward kernels
+    a layer. With a quarter of the batch the same model keeps the kernel's
+    output and row statistics, and the recomputed layer holds no forward."""
+    import numpy as np
+    from deepspeed_tpu.models import TransformerLM
+    from deepspeed_tpu.models.transformer import opt_1_3b
+    from deepspeed_tpu.parallel.topology import MeshTopology, TopologyConfig
+    from deepspeed_tpu.runtime.activation_checkpointing import checkpointing
+    from deepspeed_tpu.runtime.config import DeepSpeedConfig
+    from deepspeed_tpu.runtime.engine import DeepSpeedTpuEngine
+
+    monkeypatch.setattr(DeepSpeedTpuEngine, "_device_bytes_limit",
+                        lambda self: int(V5E_BYTES_LIMIT))
+    cfg = dataclasses.replace(opt_1_3b(), num_layers=16)
+    (device,) = tpu_sharding.device_set
+    batch = {"input_ids": np.zeros((1, micro, cfg.max_seq_len), np.int64)}
+
+    def lowered(policy):
+        ds = {"train_micro_batch_size_per_gpu": micro,
+              "optimizer": {"type": "adamw", "params": {"lr": 3e-4}},
+              "bf16": {"enabled": True}, "zero_optimization": {"stage": 0},
+              "steps_per_print": 10 ** 9}
+        if policy:
+            ds["activation_checkpointing"] = {"policy": policy}
+        engine = DeepSpeedTpuEngine(
+            TransformerLM(cfg), DeepSpeedConfig(ds, world_size=1),
+            topology=MeshTopology(TopologyConfig(), devices=[device]),
+            abstract_init=True)
+        text = engine._lower_train_step(batch).as_text()
+        return engine, text
+
+    try:
+        engine, text = lowered(None)
+        assert 12.6e9 < engine._placed_state_bytes() < 12.8e9
+        assert engine.remat_policy[0] == want
+        assert text.count('kernel_name = "flash_attention_fwd"') == fwd_calls
+        if want == "nothing_saveable":
+            assert text == lowered("nothing_saveable")[1]
+    finally:
+        checkpointing.reset()
+
+
+# ---------------------------------------------------------------------------
 # whole serving programs at OPT-1.3B's geometry (depth cut to 2)
 # ---------------------------------------------------------------------------
 def _serving_case(sharding, kv_quant):

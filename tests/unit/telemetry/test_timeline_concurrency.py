@@ -117,6 +117,16 @@ def test_concurrent_writers_with_wraparound():
         t.join()
     assert not errors, errors
 
+    # one lifeline more, with the writers gone: the frontend writer can end
+    # the race with a run of over 256 spans of its own, which leaves the
+    # window without a whole lifeline to check (it did, in one whole run of
+    # the suite in four, PR 27); the ring has wrapped under both writers by
+    # now all the same
+    trace.set_track("asyncio-frontend")
+    with trace.span("submit", uid=-1):
+        pass
+    trace.set_track(None)
+    _emit_lifeline(10 ** 9, 0.0)
     spans = trace.export()
     assert len(spans) == 256
     # both tracks present in the final window and mapped to distinct
