@@ -145,18 +145,24 @@ def phase_kernels(sizes):
     from deepspeed_tpu.ops.attention_autotune import parity_check
 
     # the last row is over the 4,096 rows of K/V one grid step keeps
-    # resident: the chunked walk and its clamped index maps
-    for hd, seq in ((64, sizes["flash_seq"]), (128, sizes["flash_seq"]),
-                    (64, sizes["flash_seq_chunked"])):
+    # resident: the chunked walk and its clamped index maps. At 2,048 rows
+    # the two heads' dQ fits VMEM and the backward is the one fused kernel
+    for hd, seq, fused in ((64, sizes["flash_seq"], True),
+                           (128, sizes["flash_seq"], True),
+                           (64, sizes["flash_seq_chunked"], False)):
         rep = parity_check(batch=1, heads=4, kv_heads=2, seq=seq,
                            head_dim=hd)
-        print(f"  flash fwd+bwd hd={hd} seq={rep['seq']}: "
+        print(f"  flash fwd+bwd hd={hd} seq={rep['seq']} "
+              f"{'+'.join(rep['kernels'])}: "
               f"out={rep['out_rel_err']:.2e} dq={rep['dq_rel_err']:.2e} "
               f"dk={rep['dk_rel_err']:.2e} dv={rep['dv_rel_err']:.2e}",
               flush=True)
         check(rep["out_rel_err"] < OUT_TOL, f"flash fwd hd={hd}: {rep}")
         check(max(rep["dq_rel_err"], rep["dk_rel_err"], rep["dv_rel_err"])
               < GRAD_TOL, f"flash bwd hd={hd}: {rep}")
+        if fused:
+            check(rep["kernels"] == ["bwd", "fwd"],
+                  f"flash hd={hd}: the fused backward did not run: {rep}")
 
     rng = np.random.default_rng(0)
     nb, bs, R, MB = 64, 16, 4, 8

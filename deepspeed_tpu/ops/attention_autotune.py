@@ -8,8 +8,8 @@ constant from one autotune run. This module provides the measured versions:
     the current backend (the real chip when present) and returns the max
     abs/rel error, fwd and grads. chip_smoke.py (phase K) and bench.py
     run it on the chip.
-  * ``time_kernels``     — times the forward, dq and dk/dv kernels each
-    alone for one geometry (scripts/flash_kernel_table.py prints the
+  * ``time_kernels``     — times the forward and the backward kernels
+    each alone for one geometry (scripts/flash_kernel_table.py prints the
     table of them; ``flash_attention._auto_blocks`` is read off it).
   * ``measure_crossover`` — times flash vs XLA attention (fwd+bwd) at a
     ladder of sequence lengths for a given head geometry and returns the
@@ -29,6 +29,7 @@ from typing import Dict, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
+from ..telemetry import registry as _registry
 from . import flash_attention as _fa
 from .flash_attention import flash_attention, mha_reference
 
@@ -42,12 +43,23 @@ def _inputs(batch: int, heads: int, kv_heads: int, seq: int, head_dim: int,
     return q, k, v
 
 
+def kernels_taken(seq: int, kv_seq: int, head_dim: int):
+    """Which flash kernels the calls traced at a geometry took: the
+    ``kernel`` labels of the gauge ``flash_blocks_live`` there (``fwd``;
+    ``bwd`` for the fused backward, ``dq`` and ``dkv`` for the pair)."""
+    family = _registry.get_registry().snapshot()["metrics"].get(
+        "flash_blocks_live", {"series": []})
+    return sorted(row["labels"]["kernel"] for row in family["series"]
+                  if row["labels"]["geometry"] == f"{seq}x{kv_seq}x{head_dim}")
+
+
 def parity_check(batch: int = 1, heads: int = 8, kv_heads: int = 4,
                  seq: int = 1024, head_dim: int = 64,
                  dtype=jnp.bfloat16) -> Dict[str, float]:
     """Max error of the flash kernel vs the jnp reference on the CURRENT
-    backend — fwd output and dq/dk/dv. Tolerances are the caller's call;
-    bf16 grad noise is ~1e-2."""
+    backend — fwd output and dq/dk/dv, and which kernels made them
+    (``kernels_taken``). Tolerances are the caller's call; bf16 grad noise
+    is ~1e-2."""
     q, k, v = _inputs(batch, heads, kv_heads, seq, head_dim, dtype)
 
     def loss_flash(q, k, v):
@@ -74,6 +86,7 @@ def parity_check(batch: int = 1, heads: int = 8, kv_heads: int = 4,
         "dq_rel_err": err(g_f[0], g_r[0]),
         "dk_rel_err": err(g_f[1], g_r[1]),
         "dv_rel_err": err(g_f[2], g_r[2]),
+        "kernels": kernels_taken(seq, seq, head_dim),
         "backend": jax.default_backend(),
         "seq": seq,
     }
@@ -129,15 +142,19 @@ def time_kernels(batch: int, heads: int, kv_heads: int, seq: int,
                  block_kv: Optional[int] = None,
                  steps: int = 10) -> Dict[str, float]:
     """Seconds a call of each flash kernel takes alone on the CURRENT
-    backend: ``fwd``, ``dq`` and ``dkv`` (XLA drops the kernel whose
-    result a jit does not return; dq and dkv each include the small
-    ``sum(dO * O)`` reduction both read). ``block_q``/``block_kv`` None =
-    the kernels' own table."""
+    backend: ``fwd`` and ``bwd`` (the whole backward in one jit: the fused
+    kernel where the shapes take it) and, where the backward is the pair,
+    ``dq`` and ``dkv`` as well (XLA drops the kernel whose result a jit
+    does not return). Every backward row includes the small ``sum(dO * O)``
+    reduction the kernels read. ``block_q``/``block_kv`` None = the
+    kernels' own table."""
     kv_seq = kv_seq or seq
     q, _, _ = _inputs(batch, heads, kv_heads, seq, head_dim, dtype)
     _, k, v = _inputs(batch, heads, kv_heads, kv_seq, head_dim, dtype, seed=1)
     scale, blocks = _fa._plan(q.shape, k.shape, causal, None, block_q,
                               block_kv)
+    fused = _fa._kv_major_plan(blocks, heads // kv_heads, seq, head_dim,
+                               q.dtype.itemsize)[0]
     q, k, v = _fa._fold(q), _fa._fold(k), _fa._fold(v)
 
     fwd = jax.jit(lambda q, k, v: _fa._flash_fwd(q, k, v, scale, causal,
@@ -151,13 +168,12 @@ def time_kernels(batch: int, heads: int, kv_heads: int, seq: int,
                               causal, blocks)
 
     res = (q, k, v, o, lse, do)
-    return {
-        "fwd": _time_step(fwd, (q, k, v), steps),
-        "dq": _time_step(jax.jit(lambda *a: bwd(*a)[0]), res, steps),
-        "dkv": _time_step(jax.jit(lambda *a: bwd(*a)[1:]), res, steps),
-        "blocks": blocks,
-        "backend": jax.default_backend(),
-    }
+    out = {"fwd": _time_step(fwd, (q, k, v), steps),
+           "bwd": _time_step(jax.jit(bwd), res, steps)}
+    if not fused:
+        out["dq"] = _time_step(jax.jit(lambda *a: bwd(*a)[0]), res, steps)
+        out["dkv"] = _time_step(jax.jit(lambda *a: bwd(*a)[1:]), res, steps)
+    return {**out, "blocks": blocks, "backend": jax.default_backend()}
 
 
 def measure_crossover(batch: int = 1, heads: int = 16, kv_heads: int = 16,

@@ -4,7 +4,9 @@ TPU-native replacement for the reference's fused attention kernels
 (csrc/transformer/ds_transformer_cuda.cpp softmax path, and the inference
 attention kernels in csrc/transformer/inference). Implements the
 memory-efficient online-softmax algorithm (never materializes the [S, S]
-score matrix) as three Mosaic kernels that share one block schedule:
+score matrix) as Mosaic kernels that share one block schedule: a forward
+and one backward, or, where a head's dQ does not fit VMEM, a forward and
+the backward as a pair (dq; dk/dv):
 
   * forward and backward dq: grid (BH, Sq/bq, kv chunks). A chunk is the
     run of K/V rows one grid step keeps in VMEM — the whole sequence
@@ -14,11 +16,20 @@ score matrix) as three Mosaic kernels that share one block schedule:
     are neither scheduled nor fetched: with more than one chunk the
     index map clamps to the last live chunk, which names the block
     already resident and Pallas elides the copy.
-  * backward dk/dv: grid (BHkv, Skv/bk, GQA group x q chunks), the same
-    walk transposed — q and dO are the resident operands, the loop
-    starts at the first live q block, and the scores are computed as
-    ``k·qT`` so that ``dv = pT·dO`` and ``dk = dsT·q`` are plain
-    matmuls and the group reduction stays in VMEM scratch.
+  * backward (dk/dv, and dq with them): grid (BHkv, Skv/bk, GQA group x q
+    chunks), the same walk transposed — q and dO are the resident
+    operands, the loop starts at the first live q block, and the scores
+    are computed as ``k·qT`` so that ``dv = pT·dO`` and ``dk = dsT·q`` are
+    plain matmuls and the group reduction stays in VMEM scratch. The fused
+    kernel (``flash_attention_bwd``) takes ``dq += ds·k`` from the same
+    tile: the scores, the exp and the mask are made once a block and five
+    matmuls do the work of the pair's seven. Its dQ is a float32
+    accumulator for every q row under the kv head, carried across the
+    kv-block axis and written out at the head's last kv block; it is the
+    backward wherever that fits (``_fused_bwd_fits``, from the call's
+    shapes: both benchmark cells, GQA 4 at width 128, 4,096 rows).
+    Otherwise ``flash_attention_bwd_dkv`` is the same kernel without dQ
+    and ``flash_attention_bwd_dq`` runs beside it.
 
 The forward's walk is split in two loops over the same integers: blocks
 the diagonal crosses run the masked body, blocks wholly below it run a body
@@ -26,22 +37,23 @@ without compare or select. The backward kernels mask every block they
 visit (the split measured nothing there on the v5e). ``block_schedule``
 counts the visited and the diagonal's blocks from the bounds the kernels
 use (``_kv_bounds``/``_q_bounds``) and is published as the gauges
-``flash_blocks_{grid,live,masked}``.
+``flash_blocks_{grid,live,masked}``, whose ``kernel`` label (``fwd``;
+``bwd``, or ``dq`` and ``dkv``) says which kernels a call took.
 
 Row statistics are lane-dense: lse and delta are ``[BH, 1, Sq]`` float32
 (the sequence is the minor dimension; no 128x lane padding in HBM). The
 forward keeps its running max and sum lane-replicated ``(bq, 128)`` in
 scratch, as the update needs them; dq turns its lse/delta rows into
-columns once per q block; dk/dv broadcasts them as rows against the
-transposed tile. The softmax scale multiplies the float32 scores; dq and
-dk take it on the float32 accumulator.
+columns once per q block; the kv-major backward broadcasts them as rows
+against the transposed tile. The softmax scale multiplies the float32
+scores; dq and dk take it on the float32 accumulator.
 
 Supports causal masking (bottom-right aligned for sq != skv, matching the
 usual decode convention; fully-masked rows give zeros) and grouped-query
 attention (kv-head indexing in the BlockSpec index map). f32 accumulation
 on the MXU (preferred_element_type) with bf16 inputs. Blocks come from
 ``_auto_blocks``, measured on the v5e per kernel; ``block_q``/``block_kv``
-override it for all three. A q block is the lane dimension of the lse and
+override it for all of them. A q block is the lane dimension of the lse and
 delta blocks, so it is a multiple of 128 or the whole of sq (``_plan``).
 
 On non-TPU backends (the CPU test mesh) kernels run in interpret mode;
@@ -62,14 +74,19 @@ from ..telemetry import registry as _registry
 
 NEG_INF = -1e30
 _LANES = 128
-# VMEM the resident operands of one grid step (K and V; in dk/dv q and dO)
+# VMEM the resident operands of one grid step (K and V; kv-major q and dO)
 # may take, their double buffers included. 4 MiB holds 4096 rows of bf16
 # at head widths up to 128; the scoped default on a v5e is 16 MiB and the
 # score tiles need the rest.
 _RESIDENT_BYTES = 4 * 2 ** 20
+# VMEM one fused backward call may plan on (``_fused_bwd_fits``). The v5e's
+# scoped default is 16 MiB, and where the compiler draws the line moved by
+# 1 MiB with the size of the program round the call: two are left.
+_FUSED_BWD_BYTES = 14 * 2 ** 20
 
 _NT = (((1,), (1,)), ((), ()))      # a · bT
 _NN = (((1,), (0,)), ((), ()))      # a · b
+_TN = (((0,), (0,)), ((), ()))      # aT · b
 
 
 def _interpret() -> bool:
@@ -420,17 +437,34 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         dq_ref[0] = (dq_sc[:] * scale).astype(dq_ref.dtype)
 
 
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    dk_ref, dv_ref, dk_sc, dv_sc,
-                    *, scale, causal, bq, bk, cq, n_chunks, n_inner, off):
+def _bwd_kv_major_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                         *refs, scale, causal, bq, bk, cq, n_kv, n_chunks,
+                         n_inner, off, fused):
+    """dK and dV of one kv block: the walk over the q rows that see it.
+    ``fused`` takes dQ on the same walk, from the tile it already holds:
+    ``dq_sc`` is the float32 dQ of every q row under this kv head (GQA
+    group x sq), carried across the kv-block axis."""
+    if fused:
+        dq_ref, dk_ref, dv_ref, dq_sc, dk_sc, dv_sc = refs
+    else:
+        dk_ref, dv_ref, dk_sc, dv_sc = refs
     j = pl.program_id(1)   # kv block (outer)
     e = pl.program_id(2)   # inner: q-heads of the GQA group x q chunks
     c = e % n_chunks       # q chunk within the head
+    acc = pl.multiple_of(e * cq, cq)     # this step's first row of dq_sc
 
     @pl.when(e == 0)
     def _init():
         dk_sc[:] = jnp.zeros_like(dk_sc)
         dv_sc[:] = jnp.zeros_like(dv_sc)
+
+    if fused:
+        @pl.when(j == 0)
+        def _init_dq():
+            # every row, not those the first kv block's walk visits: rows
+            # that see no key (sq > skv) are reached by no walk
+            dq_sc[pl.ds(acc, cq), :] = jnp.zeros((cq, dq_sc.shape[1]),
+                                                 dq_sc.dtype)
 
     k = k_ref[0]                                     # (bk, d)
     v = v_ref[0]
@@ -455,6 +489,11 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dpt = _dot(v, do, _NT)
         dst = (pt * (dpt - delta)).astype(q.dtype)
         dk_sc[:] += _dot(dst, q, _NN)
+        if fused:
+            # Mosaic places the transpose; on the v5e it costs nothing
+            # (PERF.md, PR 28: equal to an explicit dst.T and to none)
+            rows = pl.ds(pl.multiple_of(acc + start, bq), bq)
+            dq_sc[rows, :] += _dot(dst, k, _TN)
 
     _walk(i_lo, cq // bq, block)
 
@@ -463,28 +502,125 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_ref[0] = (dk_sc[:] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_sc[:].astype(dv_ref.dtype)
 
+    if fused:
+        @pl.when(j == n_kv - 1)
+        def _finish_dq():
+            dq_ref[0] = (dq_sc[pl.ds(acc, cq), :] * scale).astype(
+                dq_ref.dtype)
+
 
 def _row_dots(do, o):
-    """delta = rowsum(dO * O) in float32 over the last axis: what the two
-    backward kernels read of the forward's output."""
+    """delta = rowsum(dO * O) in float32 over the last axis: what the
+    backward reads of the forward's output."""
     return jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
+
+
+def _fused_bwd_fits(group, sq, cq, bq, bk, d, itemsize):
+    """Whether taking dQ on the dK/dV walk fits VMEM: a float32 dQ row for
+    every q row under a kv head (GQA group x sq) and the double-buffered
+    output chunk, beside what the walk holds anyway (the q and dO chunks;
+    the k, v, dk and dv blocks with their accumulators) and two score
+    tiles. Rows are padded to the lanes. Compiled for the v5e at group 1
+    to 14, sq 2048 to 8192, widths 64 and 128 and eight block shapes, the
+    kernel was refused from ``held + 1.5 tiles`` = 15 to 16 MiB on."""
+    held = (group * sq * 4 + (2 + 4) * cq * itemsize
+            + bk * (8 * itemsize + 8)) * max(d, _LANES)
+    return held + 2 * bq * bk * 4 <= _FUSED_BWD_BYTES
+
+
+def _kv_major_plan(blocks, group, sq, d, itemsize):
+    """(fused, bq, bk, rows of q and dO a step keeps) of the kv-major
+    backward: the fused kernel at its own blocks where it fits, else dk/dv
+    at theirs, with dq apart."""
+    for fused, (bq, bk) in ((True, blocks[3]), (False, blocks[2])):
+        cq = _chunk_rows(sq, bq, d, itemsize)
+        if not fused or _fused_bwd_fits(group, sq, cq, bq, bk, d, itemsize):
+            return fused, bq, bk, cq
 
 
 def _flash_bwd(q, k, v, do, lse, delta, scale, causal, blocks):
     """dq, dk, dv of folded ``[bh, s, d]`` operands; ``lse`` and ``delta``
-    are ``[bh, 1, sq]`` float32."""
+    are ``[bh, 1, sq]`` float32. One kernel where the head's dQ fits VMEM
+    (``_fused_bwd_fits``), else dq and dk/dv apart."""
     bh, sq, d = q.shape
     bhk, skv, _ = k.shape
     group = bh // bhk
     off = skv - sq
     itemsize = q.dtype.itemsize
 
+    # dk/dv: grid over kv heads; the inner axis walks every q chunk of every
+    # q-head in the GQA group, accumulating in VMEM scratch — the group
+    # reduction happens in-register instead of a second [bh, skv, d] HBM pass.
+    fused, bq, bk, cq = _kv_major_plan(blocks, group, sq, d, itemsize)
+    n_kv, n_chunks = skv // bk, sq // cq
+    n_inner = group * n_chunks
+    sched = block_schedule(sq, skv, bq, bk, causal, chunk=cq, kv_major=True)
+    # the backward kernels mask every block they visit: splitting their
+    # walks bought nothing on the chip (PERF.md, PR 26)
+    _publish("bwd" if fused else "dkv", sq, skv, d, sched,
+             sched.live if causal else 0)
+
+    def q_chunk(b, j, e):
+        c = e % n_chunks
+        if causal and n_chunks > 1:
+            c = jnp.maximum(c, _first_live_chunk(j, bk=bk, cq=cq,
+                                                 n_chunks=n_chunks, off=off))
+        return b * group + e // n_chunks, c
+
+    def q_map(b, j, e):
+        return (*q_chunk(b, j, e), 0)
+
+    def row_map(b, j, e):
+        head, c = q_chunk(b, j, e)
+        return head, 0, c
+
+    def dq_map(b, j, e):
+        # written at a head's last kv block; until then every step names
+        # the block that step will write first, and nothing is copied out
+        last = j == n_kv - 1
+        return (b * group + jnp.where(last, e // n_chunks, 0),
+                jnp.where(last, e % n_chunks, 0), 0)
+
+    kv_spec = pl.BlockSpec((1, bk, d), lambda b, j, e: (b, j, 0))
+    out_specs = [kv_spec, kv_spec]
+    out_shape = [jax.ShapeDtypeStruct((bhk, skv, d), k.dtype),
+                 jax.ShapeDtypeStruct((bhk, skv, d), v.dtype)]
+    scratch = [pltpu.VMEM((bk, d), jnp.float32)] * 2
+    if fused:
+        out_specs.insert(0, pl.BlockSpec((1, cq, d), dq_map))
+        out_shape.insert(0, jax.ShapeDtypeStruct(q.shape, q.dtype))
+        scratch.insert(0, pltpu.VMEM((group * sq, d), jnp.float32))
+    outs = pl.pallas_call(
+        functools.partial(_bwd_kv_major_kernel, scale=scale, causal=causal,
+                          bq=bq, bk=bk, cq=cq, n_kv=n_kv, n_chunks=n_chunks,
+                          n_inner=n_inner, off=off, fused=fused),
+        grid=(bhk, n_kv, n_inner),
+        in_specs=[pl.BlockSpec((1, cq, d), q_map), kv_spec, kv_spec,
+                  pl.BlockSpec((1, cq, d), q_map),
+                  pl.BlockSpec((1, 1, cq), row_map),
+                  pl.BlockSpec((1, 1, cq), row_map)],
+        out_specs=out_specs,
+        scratch_shapes=scratch,
+        out_shape=out_shape,
+        cost_estimate=_cost(bh, d, itemsize, sched, bq, bk,
+                            n_dots=5 if fused else 4,
+                            rows_moved=(3 if fused else 2) * sq
+                            + 4 * skv // group),
+        # dq, dk and dv take the place of q, k and v, each block written
+        # after its last read: with what XLA pads a [bh, s, 64] array to,
+        # three arrays fewer at the step's peak
+        input_output_aliases={0: 0, 1: 1, 2: 2} if fused else {},
+        name="flash_attention_bwd" if fused else "flash_attention_bwd_dkv",
+        interpret=_interpret(),
+    )(q, k, v, do, lse, delta)
+    if fused:
+        return tuple(outs)
+    dk, dv = outs
+
     bq, bk = blocks[1]
     ck = _chunk_rows(skv, bk, d, itemsize)
     n_q, n_chunks = sq // bq, skv // ck
     sched = block_schedule(sq, skv, bq, bk, causal, chunk=ck)
-    # the backward kernels mask every block they visit: splitting their
-    # walks bought nothing on the chip (PERF.md, PR 26)
     _publish("dq", sq, skv, d, sched, sched.live if causal else 0)
 
     kv_map = _kv_chunk_map(group, causal, bq, ck, n_chunks, off)
@@ -503,55 +639,6 @@ def _flash_bwd(q, k, v, do, lse, delta, scale, causal, blocks):
         cost_estimate=_cost(bh, d, itemsize, sched, bq, bk, n_dots=3,
                             rows_moved=3 * sq + 2 * skv // group),
         name="flash_attention_bwd_dq",
-        interpret=_interpret(),
-    )(q, k, v, do, lse, delta)
-
-    # dk/dv: grid over kv heads; the inner axis walks every q chunk of every
-    # q-head in the GQA group, accumulating in VMEM scratch — the group
-    # reduction happens in-register instead of a second [bh, skv, d] HBM pass.
-    bq, bk = blocks[2]
-    cq = _chunk_rows(sq, bq, d, itemsize)
-    n_kv, n_chunks = skv // bk, sq // cq
-    n_inner = group * n_chunks
-    sched = block_schedule(sq, skv, bq, bk, causal, chunk=cq, kv_major=True)
-    _publish("dkv", sq, skv, d, sched, sched.live if causal else 0)
-
-    def q_chunk(b, j, e):
-        c = e % n_chunks
-        if causal and n_chunks > 1:
-            c = jnp.maximum(c, _first_live_chunk(j, bk=bk, cq=cq,
-                                                 n_chunks=n_chunks, off=off))
-        return b * group + e // n_chunks, c
-
-    def q_map(b, j, e):
-        return (*q_chunk(b, j, e), 0)
-
-    def row_map(b, j, e):
-        head, c = q_chunk(b, j, e)
-        return head, 0, c
-
-    kv_spec = pl.BlockSpec((1, bk, d), lambda b, j, e: (b, j, 0))
-    dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
-                          bq=bq, bk=bk, cq=cq, n_chunks=n_chunks,
-                          n_inner=n_inner, off=off),
-        grid=(bhk, n_kv, n_inner),
-        in_specs=[pl.BlockSpec((1, cq, d), q_map), kv_spec, kv_spec,
-                  pl.BlockSpec((1, cq, d), q_map),
-                  pl.BlockSpec((1, 1, cq), row_map),
-                  pl.BlockSpec((1, 1, cq), row_map)],
-        out_specs=[kv_spec, kv_spec],
-        scratch_shapes=[
-            pltpu.VMEM((bk, d), jnp.float32),
-            pltpu.VMEM((bk, d), jnp.float32),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bhk, skv, d), k.dtype),
-            jax.ShapeDtypeStruct((bhk, skv, d), v.dtype),
-        ],
-        cost_estimate=_cost(bh, d, itemsize, sched, bq, bk, n_dots=4,
-                            rows_moved=2 * sq + 4 * skv // group),
-        name="flash_attention_bwd_dkv",
         interpret=_interpret(),
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
@@ -627,19 +714,23 @@ _flash_core.defvjp(_flash_core_fwd, _flash_core_bwd)
 
 
 def _auto_blocks(causal):
-    """(block_q, block_kv) targets for the forward, dq and dk/dv kernels,
-    read off scripts/flash_kernel_table.py --sweep on a v5e (PERF.md, PR
-    26): block_q 128-1024 x block_kv 128-2048, each kernel alone. Causal
-    calls took 512 x 512 in all three kernels at every shape swept (head
-    width 64 and 128, GQA group 1 and 4, sq == skv at 2048 and 4096, sq
-    1024 under skv 2048), so the diagonal is the table's one key; with
-    none to waste work on, non-causal calls took the whole 2048 rows of
-    K/V a block (4 / 16 / 6 % over 512 x 512)."""
-    return ((512, 512),) * 3 if causal else ((512, 2048),) * 3
+    """(block_q, block_kv) targets for the forward, dq, dk/dv and fused
+    backward kernels, read off scripts/flash_kernel_table.py --sweep on a
+    v5e (PERF.md, PR 26 and PR 28): block_q 128-1024 x block_kv 128-2048,
+    each kernel alone. Causal calls took 512 x 512 in every kernel at
+    every shape swept (head width 64 and 128, GQA group 1 and 4, sq ==
+    skv at 2048 and 4096, sq 1024 under skv 2048), so the diagonal is the
+    table's one key; with none to waste work on, non-causal calls took the
+    whole 2048 rows of K/V a block (4 / 16 / 6 % over 512 x 512) in the
+    first three, and 1024 in the fused backward, whose VMEM the 4 MiB score
+    tiles of 512 x 2048 would take."""
+    return (((512, 512),) * 4 if causal
+            else ((512, 2048),) * 3 + ((512, 1024),))
 
 
 def _plan(q_shape, k_shape, causal, scale, block_q, block_kv):
-    """(scale, ((bq, bk) for forward, dq, dk/dv)) of a call."""
+    """(scale, ((bq, bk) for forward, dq, dk/dv, fused backward)) of a
+    call."""
     _, h, sq, d = q_shape
     _, hk, skv, _ = k_shape
     assert h % hk == 0, f"GQA requires h({h}) % hk({hk}) == 0"
@@ -664,7 +755,7 @@ def flash_attention(q, k, v, causal: bool = True, scale: Optional[float] = None,
 
     k/v may have fewer heads (GQA); num_heads % num_kv_heads == 0.
     block_q/block_kv None (or 0) = auto: ``_auto_blocks``, capped to the
-    seq lens; a value overrides all three kernels'.
+    seq lens; a value overrides every kernel's.
     """
     scale, blocks = _plan(q.shape, k.shape, causal, scale, block_q, block_kv)
     return _flash_core(q, k, v, scale, causal, blocks)

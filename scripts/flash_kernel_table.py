@@ -3,14 +3,17 @@
 
 Prints, for the benchmark cells' two geometries and for the other shapes
 that run the same kernels (head width 128, GQA, non-causal, sq != skv),
-the milliseconds a call of the forward, dq and dk/dv kernels takes alone
-(``attention_autotune.time_kernels``), beside the forward and
+the milliseconds a call of the forward and of the backward takes alone
+(``attention_autotune.time_kernels``: ``bwd`` is the whole backward, the
+fused kernel where the shapes take it; where they do, ``pair_ms`` is the
+dq + dk/dv pair forced on the same operands), beside the forward and
 forward+backward times of the two flash kernels the installed jax ships
-(``pallas.ops.tpu.flash_attention`` and ``splash_attention``) as
-yardsticks. ``--sweep`` adds one row per (block_q, block_kv) candidate;
-``flash_attention._auto_blocks`` is read off that. It needs the chip: without
-one it exits naming the platform it found (``time_kernels`` itself runs on
-any backend, for its unit test).
+(``pallas.ops.tpu.flash_attention``, and ``splash_attention`` with its
+two-kernel and with its fused backward, which sums dQ partials through
+HBM) as yardsticks. ``--sweep`` adds one row per (block_q, block_kv)
+candidate; ``flash_attention._auto_blocks`` is read off that. It needs the
+chip: without one it exits naming the platform it found (``time_kernels``
+itself runs on any backend, for its unit test).
 
     python scripts/flash_kernel_table.py [--sweep] [--steps 10] [--only NAME]
 """
@@ -26,6 +29,7 @@ sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent.parent))
 import jax
 import jax.numpy as jnp
 
+from deepspeed_tpu.ops import flash_attention as fa
 from deepspeed_tpu.ops.attention_autotune import (_inputs, _time_step,
                                                   time_kernels)
 
@@ -71,12 +75,14 @@ def _library(name, batch, heads, kv_heads, seq, head_dim, kv_seq, causal,
             splash_attention_kernel as sk, splash_attention_mask as sm)
         one = (sm.CausalMask((seq, seq)) if causal
                else sm.FullMask((seq, seq)))
+        dq = ({"use_fused_bwd_kernel": True} if name == "jax_splash_fused"
+              else {"block_q_dq": blk, "block_kv_dq": blk})
         kernel = sk.make_splash_mha(
             sm.MultiHeadMask([one] * heads), head_shards=1, q_seq_shards=1,
             block_sizes=sk.BlockSizes(
                 block_q=blk, block_kv=blk, block_kv_compute=blk,
                 block_q_dkv=blk, block_kv_dkv=blk, block_kv_dkv_compute=blk,
-                block_q_dq=blk, block_kv_dq=blk))
+                **dq))
 
         def attn(q, k, v):
             return jax.vmap(kernel)(q * scale, k, v)
@@ -99,6 +105,15 @@ def _ms(x):
             for k, v in x.items()}
 
 
+def _pair(**kw):
+    """``time_kernels`` with the fused backward refused: the pair."""
+    budget, fa._FUSED_BWD_BYTES = fa._FUSED_BWD_BYTES, 0
+    try:
+        return time_kernels(**kw)
+    finally:
+        fa._FUSED_BWD_BYTES = budget
+
+
 def main(argv=None):
     p = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     p.add_argument("--sweep", action="store_true")
@@ -116,10 +131,13 @@ def main(argv=None):
         b, h, hk, s, d, skv, causal = GEOMETRIES[name]
         row = {"geometry": name, "shape": [b, h, hk, s, skv, d],
                "causal": causal}
-        row["ours_ms"] = _ms(time_kernels(b, h, hk, s, d, kv_seq=skv,
-                                          causal=causal, steps=args.steps))
+        kw = dict(batch=b, heads=h, kv_heads=hk, seq=s, head_dim=d,
+                  kv_seq=skv, causal=causal, steps=args.steps)
+        row["ours_ms"] = _ms(time_kernels(**kw))
+        if "dq" not in row["ours_ms"]:
+            row["pair_ms"] = _ms(_pair(**kw))
         if not args.no_library:
-            for lib in ("jax_flash", "jax_splash"):
+            for lib in ("jax_flash", "jax_splash", "jax_splash_fused"):
                 row[f"{lib}_ms"] = _ms(_library(lib, b, h, hk, s, d, skv,
                                                 causal, args.steps))
         print(json.dumps(row), flush=True)
@@ -128,9 +146,7 @@ def main(argv=None):
                 if bq > s or bk > skv:
                     continue
                 try:
-                    t = _ms(time_kernels(b, h, hk, s, d, kv_seq=skv,
-                                         causal=causal, block_q=bq,
-                                         block_kv=bk, steps=args.steps))
+                    t = _ms(time_kernels(**kw, block_q=bq, block_kv=bk))
                 except Exception as e:  # noqa: BLE001 — e.g. out of VMEM
                     t = {"refused": f"{type(e).__name__}: {str(e)[:120]}"}
                 print(json.dumps({"geometry": name, "block_q": bq,

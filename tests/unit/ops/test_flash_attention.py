@@ -123,12 +123,34 @@ def _grads(fn, q, k, v):
         q, k, v)
 
 
-def _check_fwd_bwd(q, k, v, causal=True, **blocks):
+FUSED, PAIR = {"fwd", "bwd"}, {"fwd", "dq", "dkv"}
+
+
+def _traced(fn):
+    """(fn(), snapshot of the gauges its trace set), in a registry of its
+    own: the ``kernel`` label says which kernels a call took."""
+    from deepspeed_tpu.telemetry import registry
+    old = registry.set_registry(registry.MetricsRegistry())
+    try:
+        return fn(), registry.get_registry().snapshot()["metrics"]
+    finally:
+        registry.set_registry(old)
+
+
+def _kernels(metrics):
+    return {row["labels"]["kernel"]
+            for row in metrics["flash_blocks_live"]["series"]}
+
+
+def _check_fwd_bwd(q, k, v, causal=True, kernels=FUSED, **blocks):
+    """Forward and backward against the reference, through whichever
+    backward the shapes take: ``kernels`` is the set they must take."""
     out = flash_attention(q, k, v, causal=causal, **blocks)
     ref = mha_reference(q, k, v, causal=causal)
     np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
-    g1 = _grads(lambda *a: flash_attention(*a, causal=causal, **blocks),
-                q, k, v)
+    g1, metrics = _traced(lambda: _grads(
+        lambda *a: flash_attention(*a, causal=causal, **blocks), q, k, v))
+    assert _kernels(metrics) == kernels
     g2 = _grads(lambda *a: mha_reference(*a, causal=causal), q, k, v)
     for a, b, name in zip(g1, g2, "qkv"):
         np.testing.assert_allclose(a, b, atol=5e-4, rtol=1e-3,
@@ -187,28 +209,31 @@ def test_block_schedule_at_the_cells_geometry():
         16, 32, 0, 16)
 
 
-def test_schedule_gauges_are_published_per_traced_geometry():
-    from deepspeed_tpu.telemetry import registry
-    old = registry.set_registry(registry.MetricsRegistry())
-    try:
-        q, k, v = rand_qkv(b=1, h=1, s=256, d=64)
-        _grads(lambda *a: flash_attention(*a, block_q=128, block_kv=64),
-               q, k, v)
-        snap = registry.get_registry().snapshot()
-    finally:
-        registry.set_registry(old)
+@pytest.mark.parametrize("kernels", [FUSED, PAIR], ids=["fused", "pair"])
+def test_schedule_gauges_are_published_per_traced_geometry(monkeypatch,
+                                                           kernels):
+    """The ``kernel`` label is what says which backward a call took:
+    ``bwd`` for the fused kernel, ``dq`` and ``dkv`` for the pair."""
+    if kernels == PAIR:
+        monkeypatch.setattr(fa, "_FUSED_BWD_BYTES", 0)
+    q, k, v = rand_qkv(b=1, h=1, s=256, d=64)
+    _, metrics = _traced(lambda: _grads(
+        lambda *a: flash_attention(*a, block_q=128, block_kv=64), q, k, v))
+    assert _kernels(metrics) == kernels
     want = block_schedule(256, 256, 128, 64, True, chunk=256)
     assert (want.live, want.masked) == (6, 4)
-    for kernel in ("fwd", "dq", "dkv"):
+    for kernel in kernels:
         read = {name: row["value"]
                 for name in ("flash_blocks_grid", "flash_blocks_live",
                              "flash_blocks_masked")
-                for row in snap["metrics"][name]["series"]
+                for row in metrics[name]["series"]
                 if row["labels"] == {"kernel": kernel,
                                      "geometry": "256x256x64"}}
-        # the forward masks the diagonal's blocks alone, dq and dk/dv
-        # every block they visit
-        assert read == {"flash_blocks_grid": 4 if kernel == "dkv" else 2,
+        # the forward masks the diagonal's blocks alone, the backward
+        # kernels every block they visit; the kv-major walks (dk/dv, the
+        # fused backward) take a grid step a kv block
+        assert read == {"flash_blocks_grid": 4 if kernel in ("dkv", "bwd")
+                        else 2,
                         "flash_blocks_live": want.live,
                         "flash_blocks_masked": want.masked
                         if kernel == "fwd" else want.live}, (kernel, read)
@@ -248,6 +273,66 @@ def test_flash_cross_length_offsets(monkeypatch, resident_bytes, sq, skv):
     if sq > skv:
         out = flash_attention(q, k, v, block_q=128, block_kv=128)
         assert not np.asarray(out[:, :, :sq - skv]).any()
+
+
+@pytest.mark.parametrize("h,hk,sq,skv,d,causal,resident_bytes", [
+    (2, 2, 256, 256, 64, True, 4 * 2 ** 20),
+    (2, 2, 256, 256, 64, False, 4 * 2 ** 20),
+    (2, 2, 256, 256, 128, True, 4 * 2 ** 20),
+    (4, 1, 256, 256, 64, True, 4 * 2 ** 20),     # GQA: four heads' dQ live
+    (4, 2, 384, 384, 128, True, 1),              # GQA over gridded q chunks
+    (2, 2, 384, 384, 64, True, 1),               # one block a chunk
+    (2, 2, 384, 384, 64, False, 1),
+    (2, 2, 128, 384, 64, True, 4 * 2 ** 20),     # off > 0
+    (2, 2, 384, 128, 64, True, 4 * 2 ** 20),     # 256 rows see no key
+    (2, 2, 384, 128, 64, True, 1),
+])
+def test_fused_backward_matches_the_pair(monkeypatch, h, hk, sq, skv, d,
+                                         causal, resident_bytes):
+    """One kernel or two, the same operands give the same dq, dk and dv to
+    bf16 rounding; the budget ``_FUSED_BWD_BYTES`` is what chooses. Rows that
+    see no key (sq > skv) are visited by no walk and come out zero from both."""
+    monkeypatch.setattr(fa, "_RESIDENT_BYTES", resident_bytes)
+    q, _, _ = rand_qkv(b=1, h=h, s=sq, d=d, dtype=jnp.bfloat16)
+    _, k, v = rand_qkv(b=1, h=hk, s=skv, d=d, dtype=jnp.bfloat16, seed=1)
+
+    def grads():
+        return _grads(lambda *a: flash_attention(
+            *a, causal=causal, block_q=128, block_kv=128).astype(jnp.float32),
+            q, k, v)
+
+    fused, metrics = _traced(grads)
+    assert _kernels(metrics) == FUSED
+    monkeypatch.setattr(fa, "_FUSED_BWD_BYTES", 0)
+    pair, metrics = _traced(grads)
+    assert _kernels(metrics) == PAIR
+    for a, b, name in zip(fused, pair, "qkv"):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        assert a.dtype == b.dtype and a.shape == b.shape
+        np.testing.assert_allclose(
+            a, b, rtol=2 ** -7, atol=2 ** -8 * np.abs(b).max(),
+            err_msg=f"d{name} mismatch")
+    if causal and sq > skv:
+        assert not np.asarray(fused[0][:, :, :sq - skv], np.float32).any()
+        assert np.asarray(fused[0][:, :, sq - skv:], np.float32).any()
+
+
+@pytest.mark.parametrize("group,sq,d,bq,bk,fused", [
+    (1, 2048, 64, 512, 512, True),        # both benchmark cells
+    (4, 2048, 128, 512, 512, True),       # GQA 4 at width 128
+    (1, 4096, 64, 512, 512, True),
+    (2, 8192, 64, 512, 512, False),       # 8 MiB of accumulators
+    (8, 32768, 128, 512, 512, False),     # 128 MiB: the whole of VMEM
+    (1, 2048, 64, 512, 1024, True),
+    (1, 2048, 64, 512, 2048, False),      # 4 MiB a score tile
+])
+def test_fused_backward_is_taken_where_dq_fits(group, sq, d, bq, bk, fused):
+    """Which backward runs is read off the call's shapes: the float32 dQ of
+    the q heads under one kv head and what the walk holds beside it, against
+    ``_FUSED_BWD_BYTES``; ``test_kernels_lower_tpu`` compiles both sides of
+    the line for the v5e."""
+    cq = fa._chunk_rows(sq, bq, d, 2)
+    assert fa._fused_bwd_fits(group, sq, cq, bq, bk, d, 2) == fused
 
 
 @pytest.mark.parametrize("d", [64, 128])
@@ -291,9 +376,17 @@ def test_q_block_is_lane_aligned_or_all_of_sq(sq, block_q, ok):
             fa._plan(shape, shape, True, None, block_q, None)
 
 
-def test_time_kernels_times_each_kernel_alone():
+@pytest.mark.parametrize("kernels", [("fwd", "bwd"),
+                                     ("fwd", "bwd", "dq", "dkv")],
+                         ids=["fused", "pair"])
+def test_time_kernels_times_each_kernel_alone(monkeypatch, kernels):
+    """``bwd`` is the whole backward; ``dq`` and ``dkv`` are there when
+    the pair is what the shapes take."""
     from deepspeed_tpu.ops.attention_autotune import time_kernels
+    if "dq" in kernels:
+        monkeypatch.setattr(fa, "_FUSED_BWD_BYTES", 0)
     t = time_kernels(1, 2, 1, 128, 8, dtype=jnp.float32, steps=1)
     assert t["backend"] == jax.default_backend()
-    assert all(t[k] > 0 for k in ("fwd", "dq", "dkv"))
-    assert len(t["blocks"]) == 3
+    assert {k for k, v in t.items() if isinstance(v, float)} == set(kernels)
+    assert all(t[k] > 0 for k in kernels)
+    assert len(t["blocks"]) == 4

@@ -23,6 +23,7 @@ chip_smoke.py compares the same kernels with their references on the chip.
 
 import dataclasses
 import itertools
+import re
 
 import pytest
 
@@ -137,14 +138,23 @@ def test_gate_uses_dma_where_the_issue_found_it_compiles():
         assert kernel_variant(hd, kvh, True) == "pipelined"
 
 
-@pytest.mark.parametrize("hd,kv_heads,causal,seq", [
-    (64, 4, True, 2048), (128, 2, True, 2048),
-    (128, 2, False, 2048),          # non-causal takes 512 x 2048 blocks
+@pytest.mark.parametrize("hd,kv_heads,causal,seq,fused", [
+    (64, 4, True, 2048, True), (128, 2, True, 2048, True),
+    (128, 1, True, 2048, True),     # GQA 4: four heads' dQ in VMEM
+    (64, 4, True, 4096, True),
+    # non-causal: 512 x 1024 blocks in the fused backward (2 MiB a score
+    # tile); with four heads' dQ beside them the pair, at 512 x 2048
+    (128, 2, False, 2048, True), (128, 1, False, 4096, False),
     # over 4,096 rows K/V no longer fit one chunk: the gridded walk, whose
-    # index maps clamp traced chunk indices, with a GQA group in dk/dv
-    (64, 2, True, 8192), (128, 2, True, 8192),
+    # index maps clamp traced chunk indices, with a GQA group in dk/dv;
+    # two heads' dQ is 8 MiB there, so dq and dk/dv run apart
+    (64, 2, True, 8192, False), (128, 2, True, 8192, False),
+    (64, 4, True, 8192, True),      # one head's dQ over two q chunks
 ])
-def test_flash_fwd_bwd_compile(tpu_sharding, hd, kv_heads, causal, seq):
+def test_flash_fwd_bwd_compile(tpu_sharding, hd, kv_heads, causal, seq, fused):
+    """Both backwards at the shapes that take them (``_fused_bwd_fits``):
+    the kernel that keeps dQ in VMEM has to fit the v5e's scoped limit
+    wherever the rule admits it."""
     from deepspeed_tpu.ops import flash_attention as fa
     from deepspeed_tpu.ops.flash_attention import flash_attention
 
@@ -159,8 +169,13 @@ def test_flash_fwd_bwd_compile(tpu_sharding, hd, kv_heads, causal, seq):
                        .astype(jnp.float32) ** 2)
 
     q, kv = sds((1, 4, seq, hd)), sds((1, kv_heads, seq, hd))
-    err = _compile_error(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
-    assert err is None, err
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, kv, kv).compile().as_text()
+    kernels = re.findall(
+        r"%[\w.\-]*?flash_attention_(\w+?)[_.0-9]* = [^\n]*tpu_custom_call",
+        text)
+    assert sorted(kernels) == (["bwd", "fwd"] if fused
+                               else ["bwd_dkv", "bwd_dq", "fwd"])
 
 
 _CELL_GEOMETRIES = {                   # [batch a chip, heads, seq, head_dim]
@@ -175,13 +190,12 @@ def test_flash_at_the_cells_geometry(tpu_sharding, cell):
     compiler sees them.
 
     A device trace shows a Mosaic call under its instruction's name:
-    ``pallas_call(name=...)`` reaches it (unnamed, the three were
+    ``pallas_call(name=...)`` reaches it (unnamed, they were
     ``checkpoint.20``, ``closed_call.8``, whatever jaxpr was round them),
     and the benchmark's per-kernel shares find them by it. And the row
     statistics stay lane-dense: a ``f32[bh, sq, 1]`` operand is tiled
     T(8,128), i.e. padded 128x in HBM (402 MB each for lse and delta on
     the dense cell) and moved as 128 KB blocks."""
-    import re
     from deepspeed_tpu.ops.flash_attention import flash_attention
 
     def loss(q, k, v):
@@ -198,9 +212,9 @@ def test_flash_at_the_cells_geometry(tpu_sharding, cell):
              if 'custom_call_target="tpu_custom_call"' in line]
     kernels = [re.match(r"\s*(?:ROOT )?%([\w.\-]+) = ", c).group(1)
                for c in calls]
-    assert len(kernels) == 3, kernels
-    for name in ("flash_attention_fwd", "flash_attention_bwd_dq",
-                 "flash_attention_bwd_dkv"):
+    # one backward kernel: dQ is taken on the dK/dV walk at these shapes
+    assert len(kernels) == 2, kernels
+    for name in ("flash_attention_fwd", "flash_attention_bwd"):
         assert [k for k in kernels if re.search(
             rf"(?<!sparse_){name}[_.0-9]*$", k)], (name, kernels)
     for call in calls:

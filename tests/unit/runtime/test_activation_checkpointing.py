@@ -267,7 +267,7 @@ def _kernel_calls(engine, batch):
         engine.scale_state, engine._step_arr, engine._model_rng, dev_batch,
         engine.quant_reduce_state).jaxpr)
     return {k: len(re.findall(rf"name=flash_attention_{k}\b", text))
-            for k in ("fwd", "bwd_dq", "bwd_dkv")}, text
+            for k in ("fwd", "bwd", "bwd_dq", "bwd_dkv")}, text
 
 
 @pytest.mark.parametrize("devices", [1, 4])
@@ -282,7 +282,8 @@ def test_flash_forward_runs_once_a_layer(monkeypatch, devices, policy,
     memory is there."""
     engine, batch = _flash_engine(monkeypatch, devices, policy)
     calls, text = _kernel_calls(engine, batch)
-    assert calls == {"fwd": fwd_calls, "bwd_dq": 1, "bwd_dkv": 1}
+    # one backward call a layer: the fused kernel, not the dq + dk/dv pair
+    assert calls == {"fwd": fwd_calls, "bwd": 1, "bwd_dq": 0, "bwd_dkv": 0}
     assert "shard_map" in text      # sharded_attention's, around the kernel
     assert engine.remat_policy[0] == (policy or "save_attn")
     # one name on a kernel call's output: a second would save it twice
