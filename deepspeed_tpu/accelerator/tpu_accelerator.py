@@ -40,7 +40,7 @@ COLLECTIVE_OVERLAP_COMPILER_OPTIONS: Dict[str, str] = {
 
 
 # bf16 peak matmul FLOPS per chip by device_kind substring — the MFU
-# denominator for bench.py / serving_bench (model-flops utilization =
+# denominator for serving_bench and the flops profiler (utilization =
 # achieved flops/s over this peak). Sources: Google Cloud TPU
 # documentation, per-chip bf16 peaks of "TPU v5e", "TPU v5p", "TPU v4".
 PEAK_FLOPS_BY_KIND: Dict[str, float] = {
@@ -67,8 +67,8 @@ def peak_flops(device) -> float:
 def require_tpu(min_devices: int = 1):
     """The TPU devices JAX sees, or an error naming what it saw instead.
 
-    For programs whose output is a statement about the chip (bench.py,
-    chip_smoke.py, scripts/): no TPU is a failure, never a CPU fallback.
+    For programs whose output is a statement about the chip
+    (chip_smoke.py, scripts/): no TPU is a failure, never a CPU fallback.
     Runs in-process — the caller becomes the one process that holds the
     chip, so it must not start a child that needs it."""
     import jax

@@ -14,8 +14,8 @@ knobs are scored on structural math over the replayed request mix
 Each knob's cost function is a proxy for its registered ``cost_signal``
 (runtime/tunables.py): the report ranks knobs by cost delta against the
 registry defaults, and ``improved_signals`` counts the distinct cost
-signals the tuned values improved — the perf gate pins it >= 1 on the
-recorded proxy workload (``autotune_offline_improved_signals``).
+signals the tuned values improved — tests/unit/autotuning/test_offline.py
+holds it >= 1 on the recorded proxy workload.
 
 The tuned output is a runtime config dict that ``DeepSpeedConfig``
 accepts verbatim: train knobs land in their native blocks

@@ -10,8 +10,8 @@ proofs ride on that:
    actual jitted train step for a v5e 8-chip slice at stage 0 vs stage 3 and
    measure how many parameter all-gathers the TPU backend covers with async
    collective fusion chains (its equivalent of the reference's dedicated
-   __allgather_stream, reference runtime/zero/stage3.py:1151). Artifact:
-   ``artifacts/overlap_dp8.json``.
+   __allgather_stream, reference runtime/zero/stage3.py:1151). Writes
+   ``<out>/overlap_dp8.json``.
 
 2. **The Llama-2-7B / v5e-64 north star fits** (VERDICT r4 Next #3): compile
    the real 7B config under ZeRO-3 (and ZeRO-3+hpZ) on a v5e:8x8 topology and
@@ -138,14 +138,14 @@ def grad_overlap_dp8(model_cfg=None, out_dir: Optional[str] = None,
     Compiles the engine's real train step twice on an 8-chip v5e topology —
     ``overlap_grad_reduce='off'`` (the seed behavior: GSPMD emits the
     reduction, in practice one fused collective after the full backward,
-    BENCH_r05 ``exposed_collective_fraction: 1.0``) vs ``'bucketed'``
+    a round-5 capture's ``exposed_collective_fraction: 1.0``) vs ``'bucketed'``
     (runtime/grad_overlap.py issues per-bucket collectives the TPU
     latency-hiding scheduler can float into the backward as async
     ppermute-ring hops). The headline regression metric is the bucketed
     variant's ``exposed_collective_fraction`` — the share of
     gradient-exchange collectives with no overlap window in the scheduled
-    HLO. Chip-free: the libtpu compiler runs on the CPU host. Artifact:
-    ``artifacts/grad_overlap_dp8.json``."""
+    HLO. Chip-free: the libtpu compiler runs on the CPU host. Writes
+    ``<out_dir>/grad_overlap_dp8.json`` when given an ``out_dir``."""
     from ..utils.xla_profile import (grad_exchange_report_from_compiled,
                                      tpu_overlap_report_from_compiled)
 

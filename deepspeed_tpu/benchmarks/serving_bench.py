@@ -756,8 +756,8 @@ def main(argv=None) -> int:
         from ..telemetry import timeline
         trace_out = timeline.write_chrome_trace(args.trace_out)
     # flight-recorder + anomaly summary (the black box ran through the
-    # whole bench): events per decode step is the same overhead number
-    # the perf gate pins, and TTFT percentiles come from the histogram's
+    # whole bench): events per decode step is the recorder's overhead
+    # in events, and TTFT percentiles come from the histogram's
     # quantile() — no raw-sample lists
     from ..telemetry import anomaly, get_recorder, get_registry
     reg = get_registry()

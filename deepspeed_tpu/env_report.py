@@ -47,8 +47,8 @@ def compiler_fingerprint():
     jax/jaxlib/libtpu versions plus the RESOLVED ``LIBTPU_INIT_ARGS``
     (the env merged with the collective-overlap defaults
     ``apply_collective_overlap_flags`` would export) and the overlap
-    flag list itself. A bench number without this dict is not
-    attributable to a compiler; bench.py embeds it in every record."""
+    flag list itself. A measured number without this dict is not
+    attributable to a compiler."""
     import os
 
     from .accelerator.tpu_accelerator import (

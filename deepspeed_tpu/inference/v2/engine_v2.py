@@ -1697,7 +1697,7 @@ class InferenceEngineV2:
         program a long sequence pays). Publishes peak/argument/temp
         bytes per program and returns ``{"programs", "buffers", "flops"
         per program}``. Runs chip-free: the compiler is a host library,
-        so OOM forensics and the perf gate never need a TPU.
+        so OOM forensics and the tests never need a TPU.
 
         Analysis compiles are NOT watchdog events — they never run on
         the serving path."""
@@ -1747,7 +1747,7 @@ class InferenceEngineV2:
             # decode row per batch slot, full table width (the
             # worst-case ragged program a long sequence pays). The
             # analyzed bucket geometry rides along in the record so
-            # consumers (perf_gate's per-token normalization) read the
+            # consumers (a per-token normalization) read the
             # bucket this analysis actually compiled
             TB = pow2_bucket(self.config.prefill_bucket + N,
                              sm.config.max_ragged_batch_size)

@@ -30,8 +30,8 @@ through the plane's seeded RNG (the ``load_bench --chaos`` mode).
 Read-op counting starts at the NDJSON body (the HTTP response head is
 never counted), so ``skip=K`` means "after K body lines".
 
-Install per replica (``RemoteReplica(faults=plane)``) in tests and the
-perf gate, or per fleet via ``load_bench --chaos SEED``. Every firing
+Install per replica (``RemoteReplica(faults=plane)``) in tests, or per
+fleet via ``load_bench --chaos SEED``. Every firing
 increments ``chaos_faults_injected_total{kind}`` and the plane's
 ``injected`` counter dict, so a chaos run can assert its schedule
 actually executed.

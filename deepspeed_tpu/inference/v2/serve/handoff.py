@@ -40,8 +40,8 @@ content in the prefix index (a partial block must never be reused as a
 cached prefix). Because each ``apply`` is one small scatter executed
 between the serving loop's scheduler steps, the transfer overlaps the
 decode replica's running batch instead of stalling it — the
-``handoff_chunk_*`` metrics and the perf gate's
-``handoff_decode_stall_fraction`` pin that overlap.
+``handoff_chunk_*`` metrics and tests/unit/inference/test_remote_serving.py
+hold that overlap.
 """
 
 import io

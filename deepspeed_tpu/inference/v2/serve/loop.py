@@ -82,12 +82,12 @@ class ServingLoop:
         # batch; drain waits for them and hard-stop aborts them
         self._restores: Dict[int, object] = {}
         # scheduler steps completed since start — the overlap evidence
-        # the chunked-handoff tests and perf gate read
+        # the chunked-handoff tests read
         self.steps_done = 0
         # weight updates currently STAGING host-side (frontend.py
         # WeightUpdate): staging never blocks the loop — steps taken
-        # while >= 1 update stages are the publish/decode overlap the
-        # perf gate's weight_publish_decode_stall_fraction pins at 0
+        # while >= 1 update stages are the publish/decode overlap
+        # (weight_update_overlap_steps_total; test_hybrid_serving.py)
         self.weight_staging = 0
         from ....telemetry import get_registry
         reg = get_registry()

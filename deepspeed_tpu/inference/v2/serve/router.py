@@ -100,7 +100,7 @@ def _relabel_exposition(text: str, label: str, value: str) -> str:
 class RouterConfig:
     # 'affinity' — prefix-digest affinity with consistent-hash fallback
     # (the default); 'hash' — consistent hash only; 'round_robin' — the
-    # random-placement baseline the perf gate pins affinity against
+    # random-placement baseline the tests hold affinity against
     placement: str = "affinity"
     # digest -> replica map bound (LRU): memory ceiling for the
     # affinity index, NOT correctness — evicted digests just fall back
@@ -795,8 +795,8 @@ class ReplicaRouter:
         KV actually lives. 'spill' means no replica holds the prefix
         HOT at that depth but one's advertised spill-tier bloom claims
         it — restoring spilled KV beats recomputing it (a bloom false
-        positive silently recomputes). Exposed for the perf gate's
-        dispatch-overhead probe."""
+        positive silently recomputes). Public so that a test can ask
+        where a prompt would go without sending it."""
         routable = self._routable()
         if not routable:
             return None, [], "none"

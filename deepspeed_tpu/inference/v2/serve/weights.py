@@ -9,8 +9,8 @@ every new leaf is ``device_put`` onto the OLD leaf's sharding with the
 OLD leaf's dtype, so the swapped tree presents the exact executable
 signature (shape x dtype x sharding) every compiled serving program was
 keyed on. Steady-state recompiles across a swap are zero *by
-construction* — and pinned by the recompile watchdog in the perf gate
-(``hot_swap_steady_recompiles``) and the parity tests.
+construction* — and held by the recompile watchdog in
+``test_steady_state_recompiles[hot_swap]`` and the parity tests.
 
 Payload layout (``chunk_weight_leaves``): one HEADER chunk carrying the
 version, the leaf manifest (names / shapes / dtypes) and per-chunk

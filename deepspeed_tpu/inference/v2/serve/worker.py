@@ -581,7 +581,7 @@ def _rng_state_from_wire(state):
 
 class ReplicaWorker:
     """One replica + its WorkerAPI, runnable in-process (the loopback
-    tests and the perf gate) or as the __main__ process."""
+    tests) or as the __main__ process."""
 
     def __init__(self, engine, serving_config: Optional[ServingConfig]
                  = None, name: str = "worker0",

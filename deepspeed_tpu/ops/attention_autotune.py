@@ -6,8 +6,8 @@ constant from one autotune run. This module provides the measured versions:
 
   * ``parity_check``     — runs the Pallas kernel AND the jnp reference on
     the current backend (the real chip when present) and returns the max
-    abs/rel error, fwd and grads. chip_smoke.py (phase K) and bench.py
-    run it on the chip.
+    abs/rel error, fwd and grads. chip_smoke.py (phase K) runs it on
+    the chip.
   * ``time_kernels``     — times the forward and the backward kernels
     each alone for one geometry (scripts/flash_kernel_table.py prints the
     table of them; ``flash_attention._auto_blocks`` is read off it).
@@ -98,8 +98,8 @@ def decode_parity_check(batch: int = 4, heads: int = 8, kv_heads: int = 4,
     """Max error of the dense-cache decode kernel (ops/decode_attention,
     the v1 inference hot path) vs the repeat+einsum reference on the
     CURRENT backend. cache_len deliberately defaults to a non-power-of-two
-    (masked tail block). Recorded by bench.py so every round's BENCH JSON
-    carries on-chip evidence for the default-on decode kernel."""
+    (masked tail block): on-chip evidence for the default-on decode
+    kernel, for whoever runs it there."""
     from .decode_attention import dense_decode_attention
 
     ks = jax.random.split(jax.random.PRNGKey(2), 4)

@@ -11,8 +11,8 @@ arrays. :meth:`PPOLearner.pack` bridges them:
 * samples pack into ``gas * global_micro`` rows with the sequence
   axis pow2-bucketed (``utils/bucketing.pow2_bucket``, capped at the
   model's ``max_seq_len``) — the learner step compiles ONCE per
-  bucket and then never again (zero steady-state recompiles, pinned
-  by the perf gate's ``learner_step_steady_recompiles``),
+  bucket and then never again (zero steady-state recompiles, held by
+  ``test_steady_state_recompiles[learner_step]``),
 * the packed batch carries ``ppo_*`` keys, which routes
   ``model.apply`` to the clipped-PPO + reference-KL objective
   (models/transformer.py ``_apply_ppo``) — the KL term REUSES the

@@ -1912,8 +1912,8 @@ class DeepSpeedTpuEngine:
         if getattr(self.config, "wall_clock_breakdown", False) and \
                 self._batches_seen % self.config.steps_per_print == 0:
             # one fused jitted step: fwd/bwd/opt split isn't separable at
-            # runtime (bench.py's zero3 phase_breakdown reports it from
-            # the eval step + HLO); the wall-clock series here mirrors the
+            # runtime (the benchmark's traced run splits it by scope:
+            # benchmark/tracing.py); the wall-clock series here mirrors the
             # reference's step timing logs (engine.py:2180-2190)
             dur = self.tput_timer.last_duration or 0.0
             log_dist(

@@ -2,8 +2,8 @@
 
 The seed engine let GSPMD insert the data-parallel gradient reduction
 wherever it liked — in practice one monolithic all-reduce/reduce-scatter
-AFTER the full backward, fully exposed (BENCH_r05:
-``exposed_collective_fraction: 1.0`` while the ZeRO-3 param gathers are 97%
+AFTER the full backward, fully exposed (a round-5 chip capture read
+``exposed_collective_fraction: 1.0`` while the ZeRO-3 param gathers were 97%
 overlapped). DeepCompile (arXiv:2504.09983) shows compiler-scheduled overlap
 of exactly this collective is the dominant lever for distributed training
 step time; the reference runtime buys the same overlap by hand with
@@ -362,8 +362,8 @@ def ring_wire_bytes(plan: GradBucketPlan, world: int,
     """Per-device bytes the bucket ring transports ship per step
     (world-1 hops per phase; ALL_REDUCE buckets pay reduce-scatter AND
     all-gather phases; vjp/CROSS_GROUP leaves are excluded — they do not
-    ride the ring). The fp32/quantized ratio of this number is the
-    perf-gate's wire-compression pin."""
+    ride the ring). The fp32/quantized ratio of this number is what
+    test_quantized_reduce.py holds at >= 3.5x."""
     if world <= 1:
         return 0
     hops = world - 1

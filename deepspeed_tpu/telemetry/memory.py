@@ -65,8 +65,8 @@ def _gauges():
 
 def cost_analysis_dict(compiled) -> Dict[str, float]:
     """``compiled.cost_analysis()`` normalized to a plain dict (older
-    jax returns ``[dict]``) — the ONE copy of this shim; bench.py and
-    the perf gate share it."""
+    jax returns ``[dict]``) — the ONE copy of this shim; every reader
+    of a compiled program's cost shares it."""
     ca = compiled.cost_analysis()
     if isinstance(ca, (list, tuple)):
         ca = ca[0] if ca else {}

@@ -22,9 +22,9 @@ Producers (docs/TELEMETRY.md § Flight recorder):
   * anomaly detectors append their verdicts as ``anomaly`` events.
 
 Cost: one dict build, one approximate size estimate, one locked deque
-append — single-digit microseconds. ``scripts/perf_gate.py`` gates
-``recorder_ns_per_event`` so the black box can never become the hot
-path. Post-mortem bundles (:mod:`.postmortem`) snapshot the last-N
+append — single-digit microseconds on a CPU host, measured once by
+hand; nothing gates it (a host wall clock is not this system's speed:
+PERF.md). Post-mortem bundles (:mod:`.postmortem`) snapshot the last-N
 events; ``events()`` serves them live.
 
 Like the metrics registry, there is one process default

@@ -21,8 +21,8 @@ merges them into ONE Chrome trace with a process row per lane, and
 request across router dispatch, prefill, KV handoff and decode — the
 distributed-tracing surface (docs/PROFILING.md § Distributed tracing).
 
-Surfaces: ``bench.py --trace-out`` and ``serving_bench --trace-out``
-write the file after a run (``--router`` writes the stitched fleet
+Surfaces: ``serving_bench --trace-out`` writes the file after a run
+(``--router`` writes the stitched fleet
 form); the serving API exposes ``GET /debug/timeline[?uid=N][&trace=ID]``
 live (docs/PROFILING.md).
 """

@@ -2,7 +2,7 @@
 
 Nothing else in the tree touches ``jax_compilation_cache_dir`` (a tier-1
 test greps for it). Entry points that compile — tests/conftest.py,
-chip_smoke.py, bench.py, benchmarks/aot_scale.py, serve/worker.py — call
+chip_smoke.py, benchmark/run.py, benchmarks/aot_scale.py, serve/worker.py — call
 :func:`enable_compile_cache` once, before their first compile.
 
 Where the cache lives:

@@ -36,13 +36,9 @@ _DOC_ROW_RE = re.compile(
 
 def registered_metrics(root: pathlib.Path = REPO) -> Set[str]:
     """Metric names registered with literal strings anywhere in the
-    package (plus bench.py, which registers read-side families)."""
+    package."""
     names: Set[str] = set()
-    files = list((root / "deepspeed_tpu").rglob("*.py"))
-    files.append(root / "bench.py")
-    for p in files:
-        if not p.exists():
-            continue
+    for p in (root / "deepspeed_tpu").rglob("*.py"):
         names.update(_REGISTER_RE.findall(p.read_text()))
     return names
 

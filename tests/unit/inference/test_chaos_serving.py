@@ -148,6 +148,33 @@ def test_reconnect_bit_identical_and_corruption_typed(model_and_params):
                         "steady-state recompiles"
 
 
+# -- retry amplification is what the schedule asks for, no more ----------
+def test_one_reset_a_probe_costs_two_attempts(model_and_params):
+    """Every other dial of /healthz is reset: each forced probe fails
+    once and succeeds on its one retry. Two attempts a probe exactly; a
+    retry storm (attempts racing to max_attempts) shows here."""
+    model, params = model_and_params
+    fam = get_registry().family_total
+
+    async def run():
+        plane = FaultPlane()
+        worker, replica = await _worker(model, params, "amp0", plane)
+        try:
+            await replica.refresh(force=True)         # first dial, clean
+            plane.script(FaultSpec(kind="reset", op="connect",
+                                   target="/healthz", skip=0, every=2,
+                                   times=None))
+            before = fam("remote_call_attempts_total")
+            for _ in range(8):
+                await replica.refresh(force=True)
+            return fam("remote_call_attempts_total") - before
+        finally:
+            plane.clear()
+            await worker.stop()
+
+    assert asyncio.run(run()) == 16
+
+
 # -- probe timeout: suspected (route around, streams keep) vs dead ------
 def test_probe_timeout_suspected_not_dead_then_breaker_exhaustion(
         model_and_params):

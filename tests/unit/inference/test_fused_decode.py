@@ -149,6 +149,23 @@ def test_fused_host_syncs_leq_one_per_window(tiny):
         "inference_decode_window_size").value == K
 
 
+def test_decode_host_syncs_per_token(tiny):
+    """Exactly, on a warm engine: two rows of 16 new tokens are 15
+    decoded tokens a row after the prefill's own, in windows of 8: two
+    [N, K] transfers for 30 tokens, 1/15 of a sync a token."""
+    from deepspeed_tpu.telemetry import get_registry
+    model, params = tiny
+    eng = _engine(model, params, 8, num_blocks=65)
+    prompts = [[2, 4, 6, 8], [3, 5, 7]]
+    eng.generate(prompts, max_new_tokens=16)
+    reg = get_registry()
+    syncs = reg.family_total("inference_decode_host_syncs_total")
+    toks = reg.family_total("inference_decode_tokens_total")
+    eng.generate(prompts, max_new_tokens=16, uids=[10, 11])
+    assert reg.family_total("inference_decode_host_syncs_total") - syncs == 2
+    assert reg.family_total("inference_decode_tokens_total") - toks == 30
+
+
 def test_per_token_fallback_still_selectable(tiny):
     """decode_window=1 keeps the per-token hot loop (no fused dispatch):
     the acceptance fallback knob."""

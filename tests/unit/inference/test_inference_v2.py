@@ -210,8 +210,8 @@ def test_generate_order_preserved_with_early_eos(tiny_model):
         assert len(o) == len(p) + 4
 
 
-# slow tier: a full serving_bench sweep; its invariants are pinned by
-# the perf gate's structural metrics
+# slow tier: a full serving_bench sweep, run by nothing; its invariants
+# are held by test_fused_decode.py and test_program_counts.py
 @pytest.mark.slow
 def test_serving_bench_smoke():
     """The serving benchmark runs end-to-end and emits the JSON line

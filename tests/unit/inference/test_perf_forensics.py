@@ -85,7 +85,6 @@ def test_watchdog_matches_bucket_cache_behavior(tiny, _fresh):
     assert all(e["signature"] for e in watchdog.events())
 
 
-@pytest.mark.slow  # gate twin: steady_state_recompiles=0 pinned in perf_baseline.json every gate run
 def test_zero_steady_state_recompiles_on_fused_path(tiny, _fresh):
     """The acceptance bar: after warmup passes over the workload's
     buckets, steady-state serving compiles NOTHING — repeat traffic and
@@ -93,7 +92,7 @@ def test_zero_steady_state_recompiles_on_fused_path(tiny, _fresh):
     replays each bucket twice: a bucket's first call compiles against
     the fresh (unsharded) KV pool and its repeat against the donated
     sharded cache, a one-time respecialization steady state must not
-    see (the bench/gate warmup discipline)."""
+    see."""
     model, params = tiny
     eng = _engine(model, params, window=8)
     prompts = [[2, 4, 6, 8], [3, 5, 7]]
@@ -101,6 +100,7 @@ def test_zero_steady_state_recompiles_on_fused_path(tiny, _fresh):
     eng.generate(prompts[:1], max_new_tokens=12, uids=[5])  # bucket 1
     eng.generate(prompts, max_new_tokens=12, uids=[6, 7])   # 2nd warm
     eng.generate(prompts[:1], max_new_tokens=12, uids=[8])
+    events = _fresh.family_total("xla_compile_events_total")
     watchdog.mark_steady(True)
     try:
         eng.generate(prompts, max_new_tokens=12, uids=[10, 11])
@@ -108,6 +108,8 @@ def test_zero_steady_state_recompiles_on_fused_path(tiny, _fresh):
     finally:
         watchdog.mark_steady(False)
     assert _steady_total(_fresh) == 0
+    # nor a compile of any other kind: the event count stood still
+    assert _fresh.family_total("xla_compile_events_total") == events
     # and a genuinely new bucket AT steady state is loudly counted
     watchdog.mark_steady(True)
     try:

@@ -149,9 +149,6 @@ def _run_placement(model, params, prompts, placement):
     return asyncio.run(run())
 
 
-# slow tier: the affinity-vs-random hit-rate sweep is pinned numerically
-# by the perf gate (router_affinity_hit_gain); placement units stay here
-@pytest.mark.slow
 def test_prefix_affinity_beats_random_placement(model_and_params):
     model, params = model_and_params
     prompts = _shared_prefix_workload()

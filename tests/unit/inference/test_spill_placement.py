@@ -295,6 +295,7 @@ def test_session_resurrection_restores_on_failover_target(tiny, tmp_path):
             ("router_session_resurrections_total",
              "router_resurrected_requests_total",
              "kv_spill_adopted_blocks_total",
+             "kv_restore_blocks_total",
              "router_requeued_total")}
     release = threading.Event()
 
@@ -339,6 +340,10 @@ def test_session_resurrection_restores_on_failover_target(tiny, tmp_path):
         - base["router_resurrected_requests_total"] >= 1
     assert fam("kv_spill_adopted_blocks_total") \
         - base["kv_spill_adopted_blocks_total"] >= 3
+    # and restored there, not recomputed (replica0 is wedged: every
+    # restore since the baseline is the survivor's)
+    assert fam("kv_restore_blocks_total") \
+        - base["kv_restore_blocks_total"] >= 3
     assert fam("router_requeued_total") \
         - base["router_requeued_total"] >= 1
     # the dead replica's namespace was adopted (moved), not clobbered:
@@ -350,9 +355,9 @@ def test_session_resurrection_restores_on_failover_target(tiny, tmp_path):
 # composition: spill + router + autoscaler + chaos over loopback workers
 # ---------------------------------------------------------------------------
 # slow: tier-1 siblings are the placement/FP/resurrection tests above
-# (each composed subsystem pinned individually); the full composition
-# also runs as the slow city sweep below and is perf-gate pinned
-# (spill_placement_* / session_resurrection_recompute_avoided).
+# (each composed subsystem pinned individually, and the restore's
+# steady state in test_program_counts.py); the full composition also
+# runs as the slow city sweep below. Both are run by nothing.
 @pytest.mark.slow
 def test_composition_spill_router_autoscaler_chaos(tiny, tmp_path):
     """The tier-1 twin of the city-scale sweep: a seeded fault

@@ -159,6 +159,26 @@ def test_mlm_rejects_generation():
         TransformerLM(cfg).init_kv_cache(1, 16)
 
 
+def test_indivisible_gqa_pair_fails_at_config_time():
+    """An indivisible (num_heads, num_kv_heads) pair must fail when the
+    config is BUILT, with the valid choices in the message — not
+    mid-capture inside flash_attention on a live chip."""
+    import dataclasses
+
+    with pytest.raises(ValueError, match=r"num_kv_heads.*\[1, 2, 3, 4"):
+        TransformerConfig(vocab_size=128, hidden_size=768,
+                          intermediate_size=1536, num_layers=2,
+                          num_heads=12, num_kv_heads=8, max_seq_len=128)
+    # dataclasses.replace() re-runs validation: replace() setting
+    # num_heads without num_kv_heads raises at once instead of compiling
+    # toward an assert (a round-5 chip window was lost to that)
+    base = TransformerConfig(vocab_size=128, hidden_size=512,
+                             intermediate_size=1024, num_layers=2,
+                             num_heads=8, num_kv_heads=8, max_seq_len=128)
+    with pytest.raises(ValueError, match="GQA requires"):
+        dataclasses.replace(base, hidden_size=768, num_heads=12)
+
+
 def test_mlm_config_and_batch_guards():
     from deepspeed_tpu.models import TransformerConfig, TransformerLM
 

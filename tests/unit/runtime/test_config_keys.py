@@ -86,7 +86,6 @@ def _package_source_without_config():
         if p.name == "config.py" and p.parent.name == "runtime":
             continue
         out.append(p.read_text())
-    out.append((REPO / "bench.py").read_text())
     out.append((REPO / "__graft_entry__.py").read_text())
     return "\n".join(out)
 

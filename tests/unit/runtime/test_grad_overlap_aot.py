@@ -15,30 +15,24 @@ from deepspeed_tpu.benchmarks import aot_scale
 from deepspeed_tpu.models import TransformerConfig
 
 
-def _topologies_available():
-    try:
-        from jax.experimental import topologies
-        topologies.get_topology_desc("v5e:2x4", platform="tpu")
-        return True
-    except Exception:
-        return False
-
-
-pytestmark = [
-    pytest.mark.skipif(
-        not _topologies_available(),
-        reason="libtpu topology descriptions unavailable on this host"),
-    # perf-gate twins: train_grad_exposed_collective_fraction /
-    # train_quant_reduce_wire_ratio pin the same AOT overlap structure
-    # every gate run; tier-1 sibling: test_overlap.py sharded-grad report
-    pytest.mark.slow,
-]
+# slow, and run by nothing (the driver runs -m 'not slow'; ROADMAP D3). With
+# the libtpu of this container the report finds no gradient collective at
+# all in the bucketed step (total 0, so "exposed 0.0" is 0 of 0) and the two
+# tests that count async ops fail, on PR 28's tree as on this one (PR 30).
+pytestmark = pytest.mark.slow
 
 
 @pytest.fixture(scope="module")
 def dp8_record():
-    # compact proxy: 2 unrolled layers keep the tier-1 compile budget low
-    # while still exercising layer-sliced buckets
+    # the topology is described (and libtpu loaded) here, never while
+    # the file is imported: every xdist worker imports every test file
+    try:
+        from jax.experimental import topologies
+        topologies.get_topology_desc("v5e:2x4", platform="tpu")
+    except Exception as e:  # noqa: BLE001 — whatever libtpu raises
+        pytest.skip(f"no v5e:2x4 topology can be described here: {e}")
+    # compact proxy: 2 unrolled layers keep the compile short while
+    # still exercising layer-sliced buckets
     cfg = TransformerConfig(vocab_size=1024, hidden_size=256,
                             intermediate_size=512, num_layers=2,
                             num_heads=4, max_seq_len=128, use_flash=False,

@@ -87,15 +87,3 @@ def test_zero3_overlap_comm_unrolls_layer_scan():
     np.testing.assert_allclose(losses[True], losses[False], rtol=1e-6)
 
 
-@pytest.mark.slow  # tier-1 sibling: test_overlap_report_on_sharded_grad; gate twin: train_grad_exposed_collective_fraction
-def test_chip_evidence_overlap_section(tmp_path):
-    """The chip-evidence collector's overlap section runs end-to-end
-    (engine.lower_train_step -> HLO analysis) and writes its JSON."""
-    import json
-    from deepspeed_tpu.benchmarks import chip_evidence
-
-    rc = chip_evidence.main(["--out", str(tmp_path), "--skip-serving",
-                             "--skip-flash"])
-    assert rc == 0
-    rec = json.load(open(tmp_path / "overlap.json"))
-    assert "exposed_fraction" in rec and "async_pairs" in rec

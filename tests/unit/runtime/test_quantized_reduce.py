@@ -160,7 +160,6 @@ def test_int8_ring_tracks_fp32_across_stages(stage, gas):
     assert eng_q._train_step._cache_size() == 1
 
 
-@pytest.mark.slow  # tier-1 sibling: test_int8_ring_tracks_fp32_across_stages; gate twin: train_quant_reduce_wire_ratio
 def test_int8_ring_vs_int8_a2a_reference():
     """Stage 2: the ring transport vs the ZeRO++ qgZ int8 all-to-all —
     two quantized exchanges of the same gradients agree within combined

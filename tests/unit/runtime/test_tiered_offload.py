@@ -92,6 +92,36 @@ def test_prefetch_buckets_honor_knob_and_overlap_gauges():
     assert reg.counter("offload_d2h_bytes_total").value >= state_bytes
 
 
+def test_tiered_offload_update_programs():
+    """The streamed update compiles one executable a bucket signature and
+    then no more: a 2-layer transformer under a 16 KiB prefetch bucket has
+    six signatures, at the first step and at the third."""
+    from deepspeed_tpu.models import TransformerConfig, TransformerLM
+    cfg = TransformerConfig(vocab_size=128, hidden_size=64,
+                            intermediate_size=128, num_layers=2,
+                            num_heads=4, num_kv_heads=2, max_seq_len=64,
+                            remat=False, use_flash=False)
+    eng, _, _, _ = deepspeed_tpu.initialize(
+        model=TransformerLM(cfg),
+        config={"train_micro_batch_size_per_gpu": 1,
+                "bf16": {"enabled": True},
+                "optimizer": {"type": "adamw", "params": {"lr": 1e-3}},
+                "zero_optimization": {
+                    "stage": 2,
+                    "offload_optimizer": {"device": "cpu",
+                                          "pin_memory": True},
+                    "stage3_prefetch_bucket_size": 1 << 14},
+                "steps_per_print": 10 ** 9})
+    ids = np.random.default_rng(0).integers(
+        0, cfg.vocab_size, (1, 8, 32), dtype=np.int64)
+    programs = []
+    for _ in range(3):
+        eng.train_batch(batch={"input_ids": ids})
+        programs.append(len(eng.host_opt._update_fns))
+    eng.destroy()
+    assert programs == [6, 6, 6]
+
+
 def test_tiered_checkpoint_roundtrip(tmp_path):
     cfg = _cfg(2, 1, tiered=True)
     engine, _ = _train(cfg, steps=3)
