@@ -32,6 +32,7 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
 from ..ops.norms import layer_norm, rms_norm
+from ..telemetry import registry as _registry
 
 
 @dataclass(frozen=True)
@@ -307,58 +308,126 @@ def lora_target_leaves(cfg: TransformerConfig):
             "layers/wv": (h, cfg.kv_heads * hd)}
 
 
-def _chunked_ce_loss(x, targets, mask, head, chunk: int, bias=None):
-    """Cross-entropy without materializing [B, S, V] logits: scan over
-    sequence chunks, each chunk's logits+logsumexp rematerialized in the
-    backward (jax.checkpoint). Peak memory drops from O(S*V) to O(chunk*V),
-    which is what lets large micro-batches fit on one chip — the role the
-    reference's fused CUDA softmax-xent kernels play.
-    Returns (sum of masked nll, sum of mask)."""
-    B, S, H = x.shape
+def _sequence_chunks(chunk: int, *arrays):
+    """``[B, S, ...]`` arrays as ``[n_chunks, B, chunk, ...]`` scan inputs,
+    zero-padded to a whole number of chunks; ``chunk`` 0 is one chunk."""
+    S = arrays[0].shape[1]
     chunk = min(chunk, S) if chunk and chunk > 0 else S
     pad = (-S) % chunk
-    if pad:
-        x = jnp.pad(x, ((0, 0), (0, pad), (0, 0)))
-        targets = jnp.pad(targets, ((0, 0), (0, pad)))
-        mask = jnp.pad(mask, ((0, 0), (0, pad)))
-    n_chunks = x.shape[1] // chunk
-    xc = x.reshape(B, n_chunks, chunk, H).swapaxes(0, 1)
-    tc = targets.reshape(B, n_chunks, chunk).swapaxes(0, 1)
-    mc = mask.reshape(B, n_chunks, chunk).swapaxes(0, 1)
 
-    @jax.checkpoint
-    def chunk_nll(x_c, t_c, m_c):
-        logits = (x_c @ head.astype(x_c.dtype)).astype(jnp.float32)
-        if bias is not None:
-            logits = logits + bias.astype(jnp.float32)
-        lse = jax.scipy.special.logsumexp(logits, axis=-1)
-        tgt = jnp.take_along_axis(logits, t_c[..., None], axis=-1)[..., 0]
-        return jnp.sum((lse - tgt) * m_c)
+    def split(a):
+        if pad:
+            a = jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
+        return a.reshape(a.shape[0], -1, chunk, *a.shape[2:]).swapaxes(0, 1)
+
+    return tuple(split(a) for a in arrays)
+
+
+def _ce_walk(x, head, bias, targets, mask, chunk: int, with_grads: bool):
+    """The scan behind :func:`_chunked_ce_loss`: the masked nll sum and,
+    ``with_grads``, its gradients for a unit cotangent ``(dx, dhead,
+    dbias)`` in float32, formed from each chunk's logits in the iteration
+    that made them."""
+    _registry.get_registry().gauge(
+        "loss_head_logit_matmuls", "vocabulary-wide matmuls a chunk of the "
+        "loss head runs, set while it is traced", labelnames=("mode",)
+    ).labels(mode="grad" if with_grads else "eval").set(
+        3 if with_grads else 1)
+    B, S, H = x.shape
+    f32 = jnp.float32
+    head_c = head.astype(x.dtype)
+    bias32 = None if bias is None else bias.astype(f32)
 
     def body(carry, inputs):
-        total = carry
+        total, unit = carry
         x_c, t_c, m_c = inputs
-        return total + chunk_nll(x_c, t_c, m_c), None
+        logits = (x_c @ head_c).astype(f32)
+        if bias is not None:
+            logits = logits + bias32
+        lse = jax.scipy.special.logsumexp(logits, axis=-1)
+        # the target's logit from its own column of the head, accumulated
+        # in float32 as the matmul accumulates it: a gather from the logits
+        # wants them written out in float32 first, and the compute-dtype
+        # copy that the other passes read has been rounded
+        tgt = jnp.einsum("bch,hbc->bc", x_c,
+                         jnp.take(head_c, t_c, axis=1, mode="clip"),
+                         preferred_element_type=f32)
+        if bias is not None:
+            tgt = tgt + bias32[t_c]
+        total = total + jnp.sum((lse - tgt) * m_c)
+        if not with_grads:
+            return (total, unit), None
+        dhead, dbias = unit
+        hit = jax.lax.broadcasted_iota(
+            t_c.dtype, logits.shape, logits.ndim - 1) == t_c[..., None]
+        dlogits = (jnp.exp(logits - lse[..., None]) - hit) * m_c[..., None]
+        if bias is not None:
+            dbias = dbias + jnp.sum(dlogits, axis=(0, 1))
+        # cast where autodiff casts the logits' cotangent: at the matmul
+        dlogits = dlogits.astype(x.dtype)
+        dx_c = jnp.einsum("bcv,hv->bch", dlogits, head_c,
+                          preferred_element_type=f32)
+        dhead = dhead + jnp.einsum("bch,bcv->hv", x_c, dlogits,
+                                   preferred_element_type=f32)
+        return (total, (dhead, dbias)), dx_c
 
-    total, _ = jax.lax.scan(body, jnp.zeros((), jnp.float32), (xc, tc, mc))
-    return total, jnp.sum(mask)
+    unit = (jnp.zeros(head.shape, f32),
+            None if bias is None else jnp.zeros(bias.shape, f32)) \
+        if with_grads else None
+    (total, unit), dx = jax.lax.scan(
+        body, (jnp.zeros((), f32), unit),
+        _sequence_chunks(chunk, x, targets, mask))
+    if not with_grads:
+        return total, None
+    return total, (dx.swapaxes(0, 1).reshape(B, -1, H)[:, :S], *unit)
+
+
+def _chunked_ce_loss(x, targets, mask, head, chunk: int, bias=None):
+    """Cross-entropy without materializing [B, S, V] logits: a scan over
+    sequence chunks of ``chunk`` positions, so peak memory is O(chunk*V)
+    and not O(S*V), which is what lets large micro-batches fit on one
+    chip — the role the reference's fused CUDA softmax-xent kernels play.
+
+    The loss is a scalar, so a chunk's logits' gradient is known the
+    moment its softmax is, up to the scalar that arrives later. Under
+    differentiation the forward walk therefore makes each chunk's logits
+    once and forms ``(softmax - onehot) * mask`` and from it ``dx`` and
+    ``dhead`` (``dbias``) on the spot; those three are all that is kept
+    for the backward, in float32 and for a unit cotangent, and the
+    backward only scales them by the cotangent of the total (``1/count``
+    times any fp16 loss scale) before casting to the primals' dtypes.
+    Nothing is recomputed: three vocabulary-wide matmuls a chunk. Not
+    differentiated (``eval``), the walk makes the loss alone: one matmul,
+    nothing kept. ``targets`` and ``mask`` get no gradient.
+    Returns (sum of masked nll, sum of mask)."""
+    dtypes = jax.tree.map(lambda p: p.dtype, (x, head, bias))
+
+    @jax.custom_vjp
+    def total_nll(x, head, bias, targets, mask):
+        return _ce_walk(x, head, bias, targets, mask, chunk, False)[0]
+
+    def fwd(x, head, bias, targets, mask):
+        return _ce_walk(x, head, bias, targets, mask, chunk, True)
+
+    def bwd(unit_grads, g):
+        scaled = jax.tree.map(
+            lambda d, dtype: (d * g.astype(jnp.float32)).astype(dtype),
+            unit_grads, dtypes)
+        return (*scaled, None, None)
+
+    total_nll.defvjp(fwd, bwd)
+    return total_nll(x, head, bias, targets, mask), jnp.sum(mask)
 
 
 def _chunked_token_logprobs(x, targets, head, chunk: int):
     """Per-token ``log softmax(x @ head)[target]`` [B, S] without
-    materializing [B, S, V] logits — the same sequence-chunked scan +
-    rematerialization as :func:`_chunked_ce_loss`, returning the
-    per-position values instead of their masked sum (the PPO ratio and
-    KL terms need each token's logprob, not an aggregate)."""
-    B, S, H = x.shape
-    chunk = min(chunk, S) if chunk and chunk > 0 else S
-    pad = (-S) % chunk
-    if pad:
-        x = jnp.pad(x, ((0, 0), (0, pad), (0, 0)))
-        targets = jnp.pad(targets, ((0, 0), (0, pad)))
-    n_chunks = x.shape[1] // chunk
-    xc = x.reshape(B, n_chunks, chunk, H).swapaxes(0, 1)
-    tc = targets.reshape(B, n_chunks, chunk).swapaxes(0, 1)
+    materializing [B, S, V] logits, over the same sequence chunks as
+    :func:`_chunked_ce_loss` (the PPO ratio and KL terms need each
+    token's logprob, not an aggregate). Its cotangent is a value per
+    token and not one scalar, so a chunk's gradient is not known on the
+    forward walk: the logits are made again in the backward
+    (``jax.checkpoint``) rather than kept."""
+    B, S, _ = x.shape
 
     @jax.checkpoint
     def chunk_lp(x_c, t_c):
@@ -370,7 +439,7 @@ def _chunked_token_logprobs(x, targets, head, chunk: int):
     def body(carry, inputs):
         return carry, chunk_lp(*inputs)
 
-    _, lps = jax.lax.scan(body, None, (xc, tc))
+    _, lps = jax.lax.scan(body, None, _sequence_chunks(chunk, x, targets))
     return lps.swapaxes(0, 1).reshape(B, -1)[:, :S]
 
 

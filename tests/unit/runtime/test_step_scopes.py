@@ -107,6 +107,9 @@ def test_every_phase_of_the_compiled_step_is_named(builder):
         # update itself runs on the host)
         assert phases[phase] > 0, (phase, phases)
     assert set(phases) <= set(PHASES)
+    # the loss head keeps its gradients and makes nothing again
+    assert not [op for op in mapped.values()
+                if "loss_head" in op and "rematted_computation" in op]
     # what no scope and no autodiff mark names: the micro-batch slice, the
     # zeros a scan starts from, and the split of the rng key. The split is
     # a dozen scalar instructions of jax's own (``jit(_threefry_split)``)
@@ -151,6 +154,16 @@ def test_the_map_holds_what_a_trace_can_show():
      "loss_head"),
     ("jit(train_step)/transpose(jvp(loss_head))/while/body/checkpoint/"
      "rematted_computation/reduce_max", "loss_head"),
+    # the cross-entropy's own paths: its forward walk holds the two
+    # matmuls of its gradients, its backward only their scaling
+    ("jit(train_step)/while/body/closed_call/jvp(loss_head)/while/body/"
+     "closed_call/bch,bcv->hv/dot_general", "loss_head"),
+    ("jit(train_step)/while/body/closed_call/jvp(loss_head)/while/body/"
+     "closed_call/bcv,hv->bch/dot_general", "loss_head"),
+    ("jit(train_step)/while/body/closed_call/jvp(loss_head)/while/body/"
+     "closed_call/jit(_take)/gather", "loss_head"),
+    ("jit(train_step)/while/body/closed_call/transpose(jvp(loss_head))/mul",
+     "loss_head"),
     ("jit(train_step)/transpose(jvp(moe))/grad_reduce/psum_scatter",
      "grad_reduce"),
     # ... and of two, the inner one
