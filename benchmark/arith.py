@@ -7,6 +7,7 @@ yardstick stays put while the program moves.
 """
 
 import json
+import statistics
 from pathlib import Path
 
 ROOT = Path(__file__).resolve().parent
@@ -18,6 +19,29 @@ def rate(amount, seconds):
         raise ValueError(f"a rate needs a window longer than 0 s, got "
                          f"{seconds}")
     return amount / seconds
+
+
+def steady_rate(step_seconds, block, amount_per_step):
+    """The rate of the MEDIAN block of a window of whole steps that took
+    ``step_seconds`` each, ``amount_per_step`` of work a step: a steadier
+    statistic to stand BESIDE the rate over all the work and all the
+    time, never in its place.
+
+    The steps are grouped into consecutive blocks of ``block`` (one pass
+    over the runner's distinct batches) and the ragged last block is
+    dropped. A stall of the host spoils the block it falls in and the
+    median does not see it, which is why this is no end-to-end reading:
+    a user's tokens a second pay for every stall. What the program pays
+    every few steps (a recompile, a periodic sync, a path that
+    alternates) is inside every block and is counted, which the median
+    step would hide. With fewer than two whole blocks there is no median
+    to take: None."""
+    whole = len(step_seconds) // block
+    if whole < 2:
+        return None
+    blocks = [sum(step_seconds[i * block:(i + 1) * block])
+              for i in range(whole)]
+    return rate(block * amount_per_step, statistics.median(blocks))
 
 
 def peaks(device_kind):

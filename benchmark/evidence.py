@@ -13,7 +13,31 @@ from . import tracing
 # the program's span names the reduction knows: mirrored into the
 # profiler's trace by trace.enable_xla_annotations(True)
 PROGRAM_SPANS = ("train_step", "train_data", "train_device_dispatch",
-                 "train_host_sync")
+                 "train_host_sync", "train_bookkeeping")
+KERNEL_MARK = ":tpu_custom_call"
+
+
+def scope_map():
+    """``{instruction name: op_name}`` of the step that ran
+    (``deepspeed_tpu.telemetry.memory.scopes("train_step")``: the
+    ``jax.named_scope`` and autodiff path each instruction was traced
+    under), and the program's classifier of an ``op_name`` into a phase
+    (``deepspeed_tpu.utils.xla_profile.scope_phase``); (None, None)
+    where the program offers none. The v5e's op events carry no
+    ``op_name`` of their own, so this join is where a scope comes from."""
+    try:
+        from deepspeed_tpu.telemetry import memory
+        from deepspeed_tpu.utils.xla_profile import scope_phase
+    except ImportError:
+        return None, None
+    scopes = getattr(memory, "scopes", None)
+    return (scopes("train_step") if scopes is not None else None), scope_phase
+
+
+def instruction(name):
+    """The instruction's name as the scope map has it: without the mark
+    ``tracing.short_name`` puts after a Mosaic kernel's."""
+    return name[:-len(KERNEL_MARK)] if name.endswith(KERNEL_MARK) else name
 
 
 def program_bytes(compiled):
@@ -48,6 +72,19 @@ class Context:
     t_process_start: float
     log: Callable[[str], None]
     scratch: Path
+    # where set-up's seconds go, by part, in order: printed with the
+    # run's detail and judged by nothing
+    setup_parts: Dict[str, float] = field(default_factory=dict)
+
+    def __post_init__(self):
+        self._part_from = self.t_process_start
+
+    def part(self, name, at=None):
+        """Close a part of set-up: it took the seconds since the last one
+        closed, or since the process started."""
+        at = at or time.perf_counter()
+        self.setup_parts[name] = at - self._part_from
+        self._part_from = at
 
     @property
     def fields(self):
@@ -73,6 +110,8 @@ class Evidence:
     slice_steps: int = 0                     # training steps traced
     tokens_per_step: int = 0
     step_tok_s: Optional[float] = None       # of the median step, a chip
+    # seconds of each step that finished inside the window, in order
+    step_seconds: List[float] = field(default_factory=list)
     # what the cell's largest program needs on one chip (program_bytes)
     memory_peak_bytes: Optional[int] = None
 
@@ -86,10 +125,25 @@ class Evidence:
         return tracing.busy_and_window(self.events)
 
     def breakdown(self):
+        """The ledger's ``device_ops`` and ``idle_gaps``. An operation's
+        name is followed by its phase and the last two scopes of its
+        ``op_name`` (``fusion.492 backward:mlp/dot_general``), so that
+        the line says what a fusion is; the name alone where the program
+        offers no map or the map lacks it."""
         planes = tracing.device_planes(self.events)
         gaps = tracing.program_gaps(self.events, planes[0])
+        mapped, scope_phase = scope_map()
+
+        def label(name):
+            op_name = (mapped or {}).get(instruction(name))
+            if not op_name:
+                return name
+            tail = "/".join(op_name.split("/")[-2:])
+            return f"{name} {scope_phase(op_name)}:{tail}"[:96]
+
         return {
-            "device_ops": [[n, s] for n, s in tracing.top_ops(self.events)],
+            "device_ops": [[label(n), s]
+                           for n, s in tracing.top_ops(self.events)],
             "idle_gaps": [[n, s] for n, s in tracing.attribute_gaps(
                 gaps, self.host_spans())]}
 

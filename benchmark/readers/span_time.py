@@ -8,11 +8,7 @@ host clock). With ``train_data``, ``train_device_dispatch`` and
 A span belongs to the step its ``step`` attribute names, or its parent
 span's. params: ``spans`` (list), ``skip_steps`` (the warm-up steps the
 runner makes before the window). A program that lacks one of the spans
-gives None.
-
-No cell lists a metric of this reader yet: ``host_ms.train`` waits in
-``tests/benchmark/fixtures/proposed_layer_metrics.json`` (PERF.md, Open
-questions)."""
+gives None."""
 
 import statistics
 from collections import defaultdict

@@ -8,7 +8,12 @@ Everything that belongs to one cell, configuration, traffic mix or
 per-layer metric is a file found by name (``workloads/<cell>.json``,
 ``configs/<config>.json``, ``traffic/<mix>.json``,
 ``layer_metrics/<metric>.json``, ``runners/<runner>.py``,
-``readers/<reader>.py``); this file holds no list of them.
+``readers/<reader>.py``); this file holds no list of them. Which metrics
+a cell reports is said once, in ``BENCHMARK.json`` (``reported_by``): a
+later PR adds a metric as an entry there and a spec file here, and gives
+a new cell the metrics that are there by adding the cell's name to their
+``workloads`` lists in ``BENCHMARK.json``; no file under this directory
+is edited for either.
 
 Without a TPU, or with fewer chips than the cell asks for, it exits
 non-zero naming what JAX found, and prints no result: there is no CPU
@@ -67,6 +72,27 @@ def load_cell(name, rehearse=False):
     return cell, config, traffic
 
 
+def reported_by(manifest, cell_name, kind):
+    """Names of the ``kind`` (``end_to_end`` or ``per_layer``) metrics
+    the cell reports, in ``BENCHMARK.json``'s order: those that list it
+    under ``workloads``. An end-to-end metric without the list is every
+    cell's (``setup_s``). A per-layer metric always has the list, and a
+    cell in it that does not report the metric's ``moves`` is an error,
+    not a metric silently left out."""
+    e2e = [m["name"] for m in manifest["end_to_end"]
+           if cell_name in m.get("workloads", [cell_name])]
+    if kind == "end_to_end":
+        return e2e
+    mine = [m for m in manifest["per_layer"] if cell_name in m["workloads"]]
+    for m in mine:
+        if m["moves"] not in e2e:
+            raise SystemExit(
+                f"benchmark: BENCHMARK.json lists {cell_name} under "
+                f"{m['name']}, which moves {m['moves']}, and the cell "
+                f"reports only {e2e}")
+    return [m["name"] for m in mine]
+
+
 def require_tpu(devices, chips):
     """The chips the cell asks for, or an error naming what JAX found
     (deepspeed_tpu.accelerator.require_tpu's behaviour, kept here so that
@@ -120,13 +146,13 @@ def log(msg):
           file=sys.stderr, flush=True)
 
 
-def layer_metrics(cell, evidence, rehearse=False):
-    """The cell's per-layer metrics, each by its own reader. A reader
+def layer_metrics(names, evidence, rehearse=False):
+    """The per-layer metrics ``names``, each by its own reader. A reader
     that finds nothing to read returns None and the metric is left out.
     A rehearsal runs the readers for their control flow; the CPU has no
     published peak, and a reader that asks for one is skipped there."""
     out = {}
-    for name in cell["per_layer"]:
+    for name in names:
         spec = load_json("layer_metrics", name)
         reader = importlib.import_module(
             f"benchmark.readers.{spec['reader']}")
@@ -160,6 +186,7 @@ def main(argv=None):
             os.environ["XLA_FLAGS"] = (
                 flags + " --xla_force_host_platform_device_count=4")
     cell, config, traffic = load_cell(args.workload, args.rehearse)
+    manifest = json.loads((REPO / "BENCHMARK.json").read_text())
     if args.seconds is None:
         if not args.rehearse:
             raise SystemExit("benchmark: --seconds is required")
@@ -176,7 +203,9 @@ def main(argv=None):
     # jax.devices() below); the trainer's entry points set them too
     apply_collective_overlap_flags()
     import jax
+    t_imported = time.perf_counter()
     devices = jax.devices()
+    t_client = time.perf_counter()
     if not args.rehearse:
         require_tpu(devices, cell["chips"])
     elif len(devices) < cell["chips"]:
@@ -197,6 +226,8 @@ def main(argv=None):
         rehearse=args.rehearse, devices=devices, clock=clock,
         t_process_start=T_PROCESS_START, log=log,
         scratch=REPO / ".bench_scratch" / args.workload)
+    ctx.part("import_jax", at=t_imported)
+    ctx.part("tpu_client", at=t_client)
     runner = importlib.import_module(
         f"benchmark.runners.{traffic['runner']}")
     result = runner.run(ctx)
@@ -210,7 +241,9 @@ def main(argv=None):
             raise SystemExit(f"rehearsal failed its checks: "
                              f"{result.correct_detail}")
         if args.trace:
-            got = layer_metrics(cell, result.evidence, rehearse=True)
+            got = layer_metrics(
+                reported_by(manifest, args.workload, "per_layer"),
+                result.evidence, rehearse=True)
             log(f"readers ran (values are not results): {sorted(got)}")
         print(REHEARSAL_BANNER, flush=True)
         return 0
@@ -223,15 +256,17 @@ def main(argv=None):
             "failed": int(result.failed)}
     if args.trace:
         ev = result.evidence
-        line["metrics"] = layer_metrics(cell, ev)
+        line["metrics"] = layer_metrics(
+            reported_by(manifest, args.workload, "per_layer"), ev)
         device["busy_s"], device["window_s"] = ev.busy_and_window()
         line["breakdown"] = ev.breakdown()
     else:
-        spec = {m["name"]: m for m in json.loads(
-            (REPO / "BENCHMARK.json").read_text())["end_to_end"]}
+        spec = {m["name"]: m for m in manifest["end_to_end"]}
         line["metrics"] = {
-            k: {"value": float(v), "unit": spec[k]["unit"]}
-            for k, v in result.end_to_end.items() if k in cell["end_to_end"]}
+            k: {"value": float(result.end_to_end[k]),
+                "unit": spec[k]["unit"]}
+            for k in reported_by(manifest, args.workload, "end_to_end")
+            if k in result.end_to_end}
     line["device"] = device
     line["detail"] = result.correct_detail
     print(json.dumps(line), flush=True)

@@ -1,10 +1,24 @@
 """Training: ``deepspeed_tpu.initialize()`` -> ``train_batch`` on seeded
 token batches that differ per step and were made before the window.
 
-``train_tok_s`` is tokens consumed by optimizer steps that FINISHED
-inside the window, over the time those steps took x chips, on the host
-clock. A step is closed by its loss reaching the host (``train_batch``
-returns a float, which blocks on the device step).
+``train_tok_s`` is all the work over all the time: the tokens of the
+optimizer steps that FINISHED inside the window over the seconds from
+the window's opening to the last of them, on the host clock, per chip. A
+step is closed by its loss reaching the host (``train_batch`` returns a
+float, which blocks on the device step). A stall of the host, a late
+recompile, a checkpoint: whatever costs the user a second costs this
+rate that second.
+
+Beside it, judged by nothing, the run's detail says what a window spent
+beyond ``steps`` median steps (``stall_s``), in which step
+(``slowest_step``: its index and its seconds over the median; on the
+one-chip machine a quiet run in four stalls one step by 0.06 to 0.15 s,
+and it is not the collector, which takes 0.5 ms a window; my chip runs,
+PR 32), and the rate of the median pass over the ``distinct_batches``
+seeded batches (``steady_tok_s``, ``arith.steady_rate``; the per-layer
+metric ``steady_tok_s.train`` in a traced run): a window mean below it
+by more than the cell's spread was stalled, and ``stall_s`` says by how
+much.
 """
 
 import statistics
@@ -52,6 +66,7 @@ def run(ctx):
     from deepspeed_tpu.models import TransformerLM
 
     cell, tr = ctx.cell, ctx.traffic
+    ctx.part("import_program")
     cfg = ctx.model_config()
     topo = None
     if len(ctx.devices) != jax.device_count():
@@ -66,8 +81,10 @@ def run(ctx):
     ctx.log(f"engine up: zero stage "
             f"{cell['deepspeed']['zero_optimization']['stage']}, micro "
             f"{engine.micro_batch_size} x dp {engine.ds_config.dp_world_size}")
+    ctx.part("engine_and_weights")
     batches, check, distinct = make_batches(ctx, engine, cfg.vocab_size)
     tokens_per_step = int(np.prod(batches[0]["input_ids"].shape))
+    ctx.part("batches")
 
     # reference first: the float32 loss of the initial weights on the
     # check sequences, before any step moves them
@@ -75,11 +92,13 @@ def run(ctx):
         else engine.params
     ref_loss = float(np.mean([reference.next_token_loss(
         master, ctx.fields, seq) for seq in distinct]))
+    ctx.part("reference_loss")
     # warm-up: two steps. The first is on the check batch and gives the
     # program's first-step loss; both compile or load what the window runs
     first_loss = float(engine.train_batch(batch=check))
     losses = [first_loss, float(engine.train_batch(batch=batches[-1]))]
     jax.block_until_ready(engine.params)
+    ctx.part("warm_steps")
     loss_err = abs(first_loss - ref_loss)
     ctx.log(f"first-step loss {first_loss:.5f} vs reference "
             f"{ref_loss:.5f} (|d| {loss_err:.2e})")
@@ -87,11 +106,15 @@ def run(ctx):
     # what the step program needs on a chip, from the program's own
     # analysis entry point (evidence.program_bytes says why this source)
     step_bytes = program_bytes(engine.lower_train_step(check))
-    ctx.log(f"step program: {step_bytes / 1e9:.3f} GB a chip (compiler)")
+    ctx.part("memory_analysis")
+    ctx.log(f"step program: {step_bytes / 1e9:.3f} GB a chip (compiler); "
+            "set-up by part, s: "
+            + ", ".join(f"{k} {v:.2f}" for k, v in ctx.setup_parts.items()))
 
     slicer = TraceSlice(ctx) if ctx.trace else None
     ctx.clock.mark()
     setup_s = ctx.setup_seconds()
+    ctx.log("window open")
     t0 = time.perf_counter()
     close = t0 + ctx.seconds
     steps = slice_steps = 0
@@ -120,20 +143,25 @@ def run(ctx):
         slicer.stop()       # a window too short for the whole slice
     compiles = ctx.clock.since_mark()
     chips = len(ctx.devices)
-    # over the time the window's whole steps took: with ~35 steps a
-    # window, dividing by --seconds would move the rate in steps of 3 %
-    # as the count of finished steps flips
-    rate = arith.rate(steps * tokens_per_step, t_last - t0) / chips \
-        if steps else None
-    # the rate of the window's median step, for mfu.train: it is read in
-    # the traced run, where the profiler's start and stop stall the steps
-    # round the slice by seconds (in the untraced run the two rates agree)
-    step_rate = arith.rate(tokens_per_step, statistics.median(step_s)) \
-        / chips if steps else None
+    # all the work over all the time; beside it the rate of the median
+    # pass and what the window spent beyond its steps' median, and where
+    rate = steady = stall_s = median_step = step_rate = slowest = None
+    if steps:
+        rate = arith.rate(steps * tokens_per_step, t_last - t0) / chips
+        steady = arith.steady_rate(step_s, len(batches), tokens_per_step)
+        median_step = statistics.median(step_s)
+        stall_s = (t_last - t0) - steps * median_step
+        at = max(range(steps), key=step_s.__getitem__)
+        slowest = [at, step_s[at] - median_step]
+        # the rate of the window's median step, for mfu.train: it is
+        # read in the traced run, where the profiler's start and stop
+        # stall the steps round the slice by seconds
+        step_rate = arith.rate(tokens_per_step, median_step) / chips
     finite = bool(np.all(np.isfinite(losses)))
     ev = Evidence(ctx=ctx, compiles_in_window=compiles,
                   slice_steps=slice_steps, tokens_per_step=tokens_per_step,
-                  step_tok_s=step_rate, memory_peak_bytes=step_bytes)
+                  step_tok_s=step_rate, step_seconds=step_s,
+                  memory_peak_bytes=step_bytes)
     if slicer is not None:
         ev.events = slicer.events()
     engine.destroy()
@@ -144,8 +172,10 @@ def run(ctx):
                         "reference_loss": ref_loss, "tolerance": LOSS_TOL,
                         "last_loss": losses[-1], "steps": steps,
                         "last_step_end_s": t_last - t0,
-                        "median_step_s": statistics.median(step_s)
-                        if steps else None,
+                        "median_step_s": median_step,
+                        "steady_tok_s": steady and steady / chips,
+                        "stall_s": stall_s, "slowest_step": slowest,
+                        "setup_parts_s": dict(ctx.setup_parts),
                         "compiles_in_window": compiles},
         end_to_end={k: v for k, v in (("train_tok_s", rate),
                                       ("setup_s", setup_s))

@@ -1,6 +1,7 @@
 """BENCHMARK.json and the files under benchmark/ say the same thing, in
-the characters and lengths the contract allows, and every per-layer
-metric moves an end-to-end metric that its cells report."""
+the characters and lengths the contract allows, every per-layer metric
+moves an end-to-end metric that its cells report, and which metrics a
+cell reports is said in one place."""
 
 import importlib
 import json
@@ -8,6 +9,8 @@ import re
 from pathlib import Path
 
 import pytest
+
+from benchmark.run import reported_by
 
 ROOT = Path("benchmark")
 BENCH = json.loads(Path("BENCHMARK.json").read_text())
@@ -125,8 +128,10 @@ def test_cells_mirror_their_files_and_the_reverse():
         traffic = load("traffic", f["traffic"])
         runner = ROOT / "runners" / f"{traffic['runner']}.py"
         assert runner.is_file(), runner
-        assert "setup_s" in f["end_to_end"] and len(f["end_to_end"]) >= 2
-        assert f["per_layer"], "a cell reports at least one layer metric"
+        e2e = reported_by(BENCH, w["name"], "end_to_end")
+        assert "setup_s" in e2e and len(e2e) >= 2
+        assert reported_by(BENCH, w["name"], "per_layer"), \
+            "a cell reports at least one layer metric"
     assert {w["traffic"] for w in BENCH["workloads"]} == \
         set(names("traffic"))
 
@@ -135,11 +140,46 @@ def reported_in(metric):
     return metric.get("workloads", CELLS)
 
 
-def test_metrics_mirror_the_cell_files():
-    for m in METRICS:
-        kind = "end_to_end" if "bound" in m else "per_layer"
-        want = [c for c in CELLS if m["name"] in load("workloads", c)[kind]]
-        assert reported_in(m) == want, m["name"]
+def test_which_metrics_a_cell_reports_is_said_once():
+    """``BENCHMARK.json`` says it (``run.reported_by``), the driver reads
+    it there, and a cell file that said it again could disagree."""
+    for cell in CELLS:
+        assert not {"end_to_end", "per_layer"} & set(load("workloads", cell))
+        for kind in ("end_to_end", "per_layer"):
+            assert reported_by(BENCH, cell, kind) == [
+                m["name"] for m in BENCH[kind] if cell in reported_in(m)]
+    assert all("workloads" in m for m in BENCH["per_layer"])
+
+
+def test_a_per_layer_metric_names_its_cells_and_they_report_its_moves():
+    bench = {
+        "end_to_end": [
+            {"name": "train_tok_s", "workloads": ["a.train"]},
+            {"name": "ttft_p95_ms", "workloads": ["a.serve"]},
+            {"name": "setup_s"}],
+        "per_layer": [
+            {"name": "mfu.train", "moves": "train_tok_s",
+             "workloads": ["a.train"]},
+            {"name": "prefix_hits", "moves": "ttft_p95_ms",
+             "workloads": ["a.serve"]}]}
+    assert reported_by(bench, "a.train", "end_to_end") == \
+        ["train_tok_s", "setup_s"]
+    assert reported_by(bench, "a.train", "per_layer") == ["mfu.train"]
+    assert reported_by(bench, "a.serve", "per_layer") == ["prefix_hits"]
+    assert reported_by(bench, "b.new", "end_to_end") == ["setup_s"]
+    assert reported_by(bench, "b.new", "per_layer") == []
+    # a cell listed under a metric whose ``moves`` it does not report is
+    # an error, and so is a per-layer metric that names no cells
+    bench["per_layer"].append({"name": "wrong", "moves": "ttft_p95_ms",
+                               "workloads": ["a.train"]})
+    with pytest.raises(SystemExit, match="wrong, which moves ttft_p95_ms"):
+        reported_by(bench, "a.train", "per_layer")
+    bench["per_layer"][-1] = {"name": "everywhere", "moves": "train_tok_s"}
+    with pytest.raises(KeyError):
+        reported_by(bench, "a.train", "per_layer")
+
+
+def test_metrics_mirror_their_spec_files():
     assert {m["name"] for m in BENCH["per_layer"]} == \
         set(names("layer_metrics"))
     for m in BENCH["per_layer"]:

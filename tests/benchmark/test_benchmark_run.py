@@ -84,9 +84,20 @@ def test_a_new_cell_is_data_files_only(tmp_path):
                 why="a cell added by a test: ZeRO-1 over two chips")
     cell["deepspeed"]["zero_optimization"] = {"stage": 1}
     cell["rehearse_traffic"]["seq_len"] = 128
-    cell["per_layer"].append("collective_ms.train")
     (bench / "workloads" / "opt-125m.train-seq1k.json").write_text(
         json.dumps(cell))
+    # entries in BENCHMARK.json say what the new cell reports: the cell,
+    # and its name in the lists of the metrics it takes
+    manifest = json.loads((tmp_path / "BENCHMARK.json").read_text())
+    manifest["workloads"].append({
+        "name": "opt-125m.train-seq1k", "config": "opt-125m",
+        "traffic": "train-seq1k", "chips": 2, "why": cell["why"]})
+    takes = {"train_tok_s", "compiles.train", "mfu.train", "host_ms.train",
+             "collective_ms.train"}
+    for m in manifest["end_to_end"] + manifest["per_layer"]:
+        if m["name"] in takes:
+            m["workloads"].append("opt-125m.train-seq1k")
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(manifest))
     (bench / "traffic" / "train-seq1k.json").write_text(json.dumps({
         "runner": "train", "seq_len": 1024, "distinct_batches": 4,
         "trace_steps": 2}))
@@ -94,6 +105,8 @@ def test_a_new_cell_is_data_files_only(tmp_path):
                 "--trace", "1"], cwd=tmp_path)
     assert_rehearsed(p)
     assert "micro 4 x dp 2" in p.stderr
+    ran = p.stderr.split("readers ran")[1]
+    assert "host_ms.train" in ran and "flash_share.train" not in ran
     for path, data in before.items():
         assert path.read_bytes() == data, f"{path} was edited"
 

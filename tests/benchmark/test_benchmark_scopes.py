@@ -1,14 +1,8 @@
 """The readers that join a device trace with what the program says about
 itself (``scope_time``: the compiled step's scope map; ``span_time``: the
-span ring) on hand-written events, map and ring with known answers; and
-the per-kernel patterns on the names the chip shows.
-
-No cell reports these metrics yet: ``run.py`` reports what a cell file's
-``per_layer`` lists, and only a ``benchmark`` PR may edit a cell file.
-``fixtures/proposed_layer_metrics.json`` holds each metric's spec and
-cells; ``lay_over`` switches them on in a copy of the benchmark, which is
-how their builder ran them on the chip and how the last test here
-rehearses them."""
+span ring) on hand-written events, map and ring with known answers; the
+per-kernel patterns on the names the chip shows; and both cells rehearsed
+with every reader their metrics name."""
 
 import json
 import os
@@ -23,35 +17,13 @@ import pytest
 
 from benchmark import tracing
 from benchmark.readers import op_time_share, scope_time, span_time
+from benchmark.run import reported_by
 from benchmark.tracing import Event
 
 REPO = Path(__file__).resolve().parents[2]
-PROPOSED = json.loads((Path(__file__).parent / "fixtures"
-                       / "proposed_layer_metrics.json").read_text())["metrics"]
-SPECS = {m["name"]: m["spec"] for m in PROPOSED}
-
-
-def lay_over(root):
-    """Switch the proposed metrics on in the checkout (or copy of
-    ``benchmark/`` and ``BENCHMARK.json``) at ``root``: a spec file each,
-    the name at the end of its cells' ``per_layer`` lists and an entry at
-    the end of ``BENCHMARK.json``'s."""
-    root = Path(root)
-    manifest = json.loads((root / "BENCHMARK.json").read_text())
-    for m in PROPOSED:
-        (root / "benchmark" / "layer_metrics" / f"{m['name']}.json") \
-            .write_text(json.dumps(m["spec"], indent=2) + "\n")
-        for cell in m["cells"]:
-            path = root / "benchmark" / "workloads" / f"{cell}.json"
-            data = json.loads(path.read_text())
-            data["per_layer"].append(m["name"])
-            path.write_text(json.dumps(data, indent=2) + "\n")
-        entry = {"name": m["name"]}
-        entry.update({k: m["spec"][k] for k in
-                      ("unit", "better", "source", "layer", "moves")})
-        entry["workloads"] = m["cells"]
-        manifest["per_layer"].append(entry)
-    (root / "BENCHMARK.json").write_text(json.dumps(manifest, indent=1) + "\n")
+MANIFEST = json.loads((REPO / "BENCHMARK.json").read_text())
+SPECS = {p.name[:-len(".json")]: json.loads(p.read_text())
+         for p in (REPO / "benchmark" / "layer_metrics").glob("*.json")}
 
 
 DEV, DEV1 = "/device:TPU:0", "/device:TPU:1"
@@ -70,6 +42,8 @@ SCOPES = {
     "all-gather.4": FWD + "attention/dot_general",
     "fusion.6": LAYERS + "transpose(jvp(loss_head))/while/body/mul",
     "all-gather-start.11": LAYERS + "jvp(loss_head)/dot_general",
+    "all-reduce.13": LAYERS + "jvp(loss_head)/while/body/bch,bcv->hv/"
+                              "dot_general",
     "fusion.7": "jit(train_step)/optimizer/add",
     "fusion.9": LAYERS + "jit(_threefry_split)/add",
     "reduce-scatter-start.10": BWD + "attention/dot_general",
@@ -132,14 +106,53 @@ def test_scope_time_on_the_hand_written_events(program_map, params, ms):
     assert got == (None if ms is None else pytest.approx(ms))
 
 
+# the loss head forms its gradients on the forward walk: what its
+# gradient matmuls exchange runs under jvp(loss_head) and only the einsum
+# name tells it from the logits matmul's gather
+HEAD = [Event(DEV, OPS, "fusion.1", 0.0, 0.4),
+        Event(DEV, ASYNC, "all-gather-start.11", 0.1, 0.2),
+        Event(DEV, OPS, "all-reduce.13", 0.4, 0.1)]
+MARKS = SPECS["collective_bwd_ms.train"]["params"]["transpose_marks"]
+
+
+@pytest.mark.parametrize("metric,marks,ms", [
+    ("collective_fwd_ms.train", MARKS, 100.0),
+    ("collective_bwd_ms.train", MARKS, 50.0),
+    # without the marks both are the forward's: what the ledger read
+    ("collective_fwd_ms.train", [], 150.0),
+    ("collective_bwd_ms.train", [], None),
+])
+def test_the_loss_heads_gradient_collectives_count_as_backward(
+        program_map, metric, marks, ms):
+    spec = SPECS[metric]
+    assert spec["reader"] == "scope_time" and spec["params"]["collectives"]
+    assert MARKS == ["bcv,hv->bch", "bch,bcv->hv"]
+    got = scope_time.read(evidence(HEAD),
+                          dict(spec["params"], transpose_marks=marks))
+    assert got == (None if ms is None else pytest.approx(ms))
+    # the plain phase sum does not split: the head is one phase
+    assert scope_time.read(evidence(HEAD), {"phases": ["loss_head"]}) == \
+        pytest.approx(50.0)
+
+
 def test_scope_coverage_counts_unknown_and_unnamed_against(program_map):
     # self times add up to the 2.2 s the device was busy; copy.8 and the
     # nested slice are unknown to the map and fusion.9 is in no phase
-    assert sum(s for _, s in scope_time.self_times(HAND, DEV)) == \
+    assert sum(s for _, s in tracing.self_times(HAND, DEV)) == \
         pytest.approx(tracing.busy_and_window(HAND)[0]) == \
         pytest.approx(2.2)
     assert scope_time.read(evidence(), {"coverage": True}) == \
         pytest.approx(100 * 1.99 / 2.2)
+
+
+def test_the_breakdown_says_what_a_fusion_is(program_map):
+    from benchmark.evidence import Evidence
+    ops = dict((n.split(" ")[0], n) for n, _ in
+               Evidence(ctx=None, events=HAND).breakdown()["device_ops"])
+    assert ops["fusion.1"] == "fusion.1 forward:mlp/dot_general"
+    assert ops["flash_attention_fwd.2:tpu_custom_call"].endswith(
+        " recompute:flash_attention_fwd/pallas_call")
+    assert ops["copy.8"] == "copy.8"            # in no map: the name alone
 
 
 def test_scope_time_is_the_mean_over_chips(program_map):
@@ -216,14 +229,17 @@ def test_host_time_without_the_span_reads_nothing(monkeypatch):
     assert span_time.read(None, HOST) is None
 
 
-# the names a v5e trace shows for the three flash kernels, in the dense
-# step and under ZeRO-3's shard_map (this PR's chip runs), and what the
-# patterns must leave alone
+# the names a v5e trace shows for the two flash kernels of a step, in the
+# dense step and under ZeRO-3's shard_map (my chip runs, PR 25 and 28),
+# and what the patterns must leave alone. The dq + dk/dv pair, which no
+# cell runs since the backward was fused, counts as backward
 KERNEL_NAMES = {
     "flash_fwd_share.train": ["flash_attention_fwd.13",
                               "flash_attention_fwd.2"],
-    "flash_dq_share.train": ["flash_attention_bwd_dq.10"],
-    "flash_dkv_share.train": ["flash_attention_bwd_dkv.10"],
+    "flash_bwd_share.train": ["flash_attention_bwd.10",
+                              "flash_attention_bwd.2",
+                              "flash_attention_bwd_dq.10",
+                              "flash_attention_bwd_dkv.10"],
 }
 STRANGERS = ["sparse_flash_attention_fwd.1", "ragged_attention_pipelined.4",
              "checkpoint.20", "rms_norm.3", "fusion.491"]
@@ -248,41 +264,52 @@ def test_each_flash_kernel_is_found_by_its_own_name(metric):
         pytest.approx(25.0)
 
 
-def test_the_proposed_metrics_are_whole_and_new():
-    manifest = json.loads((REPO / "BENCHMARK.json").read_text())
-    cells = [w["name"] for w in manifest["workloads"]]
-    e2e = {m["name"]: m.get("workloads", cells)
-           for m in manifest["end_to_end"]}
-    taken = {m["name"] for m in manifest["per_layer"]}
-    layers = {m["layer"] for m in manifest["per_layer"]}
-    assert len(SPECS) == len(PROPOSED) == 12
-    for m in PROPOSED:
-        spec = m["spec"]
-        assert m["name"] not in taken
-        assert not (REPO / "benchmark" / "layer_metrics"
-                    / f"{m['name']}.json").exists()
+def test_forward_and_backward_shares_make_up_the_flash_share():
+    events = [Event(DEV, OPS, n + ":tpu_custom_call", t, 0.1)
+              for t, n in enumerate(["flash_attention_fwd.13",
+                                     "flash_attention_bwd.10",
+                                     "flash_attention_fwd.14"])] + \
+        [Event(DEV, OPS, "fusion.1", 3.0, 0.7)]
+    read = {m: op_time_share.read(evidence(events), SPECS[m]["params"])
+            for m in ("flash_share.train", "flash_fwd_share.train",
+                      "flash_bwd_share.train")}
+    assert read["flash_fwd_share.train"] == pytest.approx(20.0)
+    assert read["flash_bwd_share.train"] == pytest.approx(10.0)
+    assert read["flash_share.train"] == pytest.approx(30.0)
+
+
+CELLS = [w["name"] for w in MANIFEST["workloads"]]
+
+
+def test_every_spec_is_in_a_cell_and_every_cells_metric_has_a_spec():
+    reported = {c: reported_by(MANIFEST, c, "per_layer") for c in CELLS}
+    everywhere = {n for names in reported.values() for n in names}
+    assert everywhere == set(SPECS)
+    for name, spec in SPECS.items():
         assert (REPO / "benchmark" / "readers"
-                / f"{spec['reader']}.py").is_file()
-        assert spec["layer"] in layers      # a layer the benchmark names
-        assert spec["better"] in ("lower", "higher")
-        assert spec["source"] in ("device_trace", "host_clock")
-        assert m["cells"] and set(m["cells"]) <= set(cells)
-        assert set(m["cells"]) <= set(e2e[spec["moves"]])
+                / f"{spec['reader']}.py").is_file(), name
+    # the collectives' metrics are the four-chip cell's alone
+    one_chip = [w["name"] for w in MANIFEST["workloads"] if w["chips"] == 1]
+    for cell in one_chip:
+        assert not [n for n in reported[cell] if n.startswith("collective")]
+    # the phase metrics this file tests are in every training cell
+    for name in ("forward_ms.train", "recompute_ms.train",
+                 "backward_ms.train", "loss_head_ms.train",
+                 "optimizer_ms.train", "steady_tok_s.train",
+                 "flash_fwd_share.train",
+                 "flash_bwd_share.train", "scope_coverage.train",
+                 "host_ms.train"):
+        assert all(name in reported[c] for c in CELLS), name
 
 
-@pytest.mark.parametrize("cell", ["opt-125m.train-dense",
-                                  "opt-1.3b.zero3-dp4"])
-def test_laid_over_a_copy_the_cell_rehearses_with_every_reader(
-        tmp_path, cell):
+@pytest.mark.parametrize("cell", CELLS)
+def test_the_cell_rehearses_with_every_reader(tmp_path, cell):
+    # in a copy: the trace goes to .bench_scratch/<cell> of the checkout,
+    # and another worker may be rehearsing the same cell in this one
     shutil.copytree(REPO / "benchmark", tmp_path / "benchmark",
                     ignore=shutil.ignore_patterns("__pycache__"))
     shutil.copy(REPO / "BENCHMARK.json", tmp_path / "BENCHMARK.json")
     os.symlink(REPO / "deepspeed_tpu", tmp_path / "deepspeed_tpu")
-    lay_over(tmp_path)
-    mine = [m["name"] for m in PROPOSED if cell in m["cells"]]
-    laid = json.loads((tmp_path / "benchmark" / "workloads"
-                       / f"{cell}.json").read_text())["per_layer"]
-    assert laid[-len(mine):] == mine
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     env.pop("BENCH_RUN", None)
     p = subprocess.run(
@@ -293,3 +320,6 @@ def test_laid_over_a_copy_the_cell_rehearses_with_every_reader(
     assert "correct True" in p.stderr and "readers ran" in p.stderr
     # the span ring is there on the CPU too; the device's planes are not
     assert "host_ms.train" in p.stderr.split("readers ran")[1]
+    ran = p.stderr.split("readers ran")[1]
+    assert "steady_tok_s.train" in ran and "'steady_tok_s': " in p.stderr
+    assert "'setup_parts_s': {'import_jax':" in p.stderr
