@@ -1,0 +1,325 @@
+"""The generation cell: its arithmetic on hand-made numbers, its roofline
+reader on a hand-made trace, the manifest rules for its entries, the
+cell rehearsed at toy widths, and its ``correct`` shown to pass as it
+stands and to fail with int8 keys and values, with a cache written one
+position off, and with one served token altered."""
+
+import json
+import types
+from pathlib import Path
+
+import numpy as np
+import pytest
+
+from benchmark import arith, arith_gen, control, tracing, weights
+from benchmark import run as harness
+from benchmark.readers import hbm_roofline
+from benchmark.run import reported_by
+from benchmark.runners import generate as gen
+
+from test_benchmark_run import assert_rehearsed, run_py
+
+REPO = Path(__file__).resolve().parents[2]
+BENCH = json.loads((REPO / "BENCHMARK.json").read_text())
+CELL = "opt-1.3b.rollout-256"
+GEN_METRICS = ["compiles.gen", "idle.gen", "peak_hbm.gen",
+               "ragged_share.gen", "ragged_roofline.gen"]
+OPT_1_3B = json.loads(
+    (REPO / "benchmark/configs/opt-1.3b.json").read_text())["fields"]
+KERNEL = "ragged_attention_pipelined.3:tpu_custom_call"
+
+
+# ---------------------------------------------------------------------------
+# arithmetic
+# ---------------------------------------------------------------------------
+def test_generated_tokens_leave_the_prompt_out_and_the_rate_is_per_chip():
+    outs = [np.arange(7), np.arange(7), np.arange(5)]    # prompts of 4
+    assert arith_gen.generated_tokens(outs, 4) == 3 + 3 + 1
+    # four calls of 16 x 256 new tokens, the last back at 38.4 s: the
+    # window's 40 s are not the divisor, the last call's end is
+    assert arith.rate(4 * 16 * 256, 38.4) / 1 == pytest.approx(426.6667)
+    with pytest.raises(ValueError):
+        arith.rate(4096, 0.0)
+
+
+def test_a_call_is_one_prefill_and_new_tokens_less_one_decode_steps():
+    rows = arith_gen.generate_call_rows(rows=2, prompt_len=4, new_tokens=3)
+    assert rows == [(4, 4), (4, 4), (1, 5), (1, 5), (1, 6), (1, 6)]
+    real = arith_gen.generate_call_rows(16, 256, 256)
+    assert len(real) == 16 * 256
+    assert real[-1] == (1, 511) and real[16] == (1, 257)
+
+
+def test_ragged_attention_bytes_of_the_cells_call_by_hand():
+    # OPT-1.3B: 32 heads of 64 in bf16: a cached position is 2 x 32 x 64
+    # x 2 = 8,192 B a layer, a token's q and o another 8,192
+    assert arith_gen.kv_bytes_per_token(OPT_1_3B) == 8192
+    prefill = 16 * (256 * 8192 + 256 * 8192)
+    decode = 16 * sum(c * 8192 + 8192 for c in range(257, 512))
+    want = 24 * (prefill + decode)
+    assert want == 310_442_459_136
+    got = arith_gen.ragged_attention_bytes(
+        OPT_1_3B, arith_gen.generate_call_rows(16, 256, 256))
+    assert got == want
+    # a row's keys are read once a launch however many tokens share them
+    one = arith_gen.ragged_attention_bytes(OPT_1_3B, [(256, 256)])
+    each = arith_gen.ragged_attention_bytes(OPT_1_3B, [(1, 256)] * 256)
+    assert each > 100 * one / 2
+    gqa = dict(OPT_1_3B, num_kv_heads=8)
+    assert arith_gen.kv_bytes_per_token(gqa) == 2048
+
+
+# ---------------------------------------------------------------------------
+# the roofline reader on a hand-made trace
+# ---------------------------------------------------------------------------
+def evidence(events, launch_rows, calls=1, kind="TPU v5 lite"):
+    ctx = types.SimpleNamespace(
+        fields=OPT_1_3B, devices=[types.SimpleNamespace(device_kind=kind)])
+    return types.SimpleNamespace(events=tracing.Events(events), ctx=ctx,
+                                 launch_rows=launch_rows, slice_steps=calls)
+
+
+def kernel_events(seconds_each, n, name=KERNEL):
+    plane = "/device:TPU:0"
+    ev = [tracing.Event(plane, tracing.OPS_LINE, "while.1", 0.0,
+                        n * 2.0 * seconds_each)]
+    for i in range(n):
+        ev.append(tracing.Event(plane, tracing.OPS_LINE, name,
+                                i * 2.0 * seconds_each, seconds_each))
+    return ev
+
+
+def test_hbm_roofline_is_bytes_over_bandwidth_over_the_kernels_self_time():
+    rows = arith_gen.generate_call_rows(16, 256, 256)
+    moved = arith_gen.ragged_attention_bytes(OPT_1_3B, rows)
+    ev = evidence(kernel_events(0.5, 10), rows)       # 5 s in the kernel
+    got = hbm_roofline.read(ev, {"pattern": "ragged_attention_"})
+    assert got == pytest.approx(100 * moved / 819e9 / 5.0)
+    assert 7.5 < got < 7.7
+    # two calls in the slice move twice the bytes
+    ev2 = evidence(kernel_events(0.5, 10), rows, calls=2)
+    assert hbm_roofline.read(ev2, {"pattern": "ragged_attention_"}) \
+        == pytest.approx(2 * got)
+    # the enclosing while is no kernel time: its self time is the gaps
+    spec = json.loads((REPO / "benchmark/layer_metrics"
+                       / "ragged_roofline.gen.json").read_text())
+    assert hbm_roofline.read(ev, spec["params"]) == pytest.approx(got)
+
+
+def test_hbm_roofline_reads_nothing_where_there_is_nothing_to_read():
+    rows = arith_gen.generate_call_rows(2, 4, 3)
+    params = {"pattern": "ragged_attention_"}
+    assert hbm_roofline.read(evidence([], rows), params) is None
+    assert hbm_roofline.read(evidence(kernel_events(0.5, 2), ()),
+                             params) is None
+    other = kernel_events(0.5, 2, name="flash_attention_fwd.3")
+    assert hbm_roofline.read(evidence(other, rows), params) is None
+    with pytest.raises(KeyError):           # no published peak, no share
+        hbm_roofline.read(evidence(kernel_events(0.5, 2), rows,
+                                   kind="cpu"), params)
+
+
+def test_the_share_pattern_takes_both_kernel_variants_and_nothing_else():
+    import re
+    spec = json.loads((REPO / "benchmark/layer_metrics"
+                       / "ragged_share.gen.json").read_text())
+    rx = re.compile(spec["params"]["pattern"])
+    assert rx.search("ragged_attention_pipelined.3:tpu_custom_call")
+    assert rx.search("ragged_attention_dma:tpu_custom_call")
+    assert not rx.search("flash_attention_fwd.3:tpu_custom_call")
+    assert not rx.search("ragged_attention_pipelined.3")    # no kernel
+
+
+# ---------------------------------------------------------------------------
+# the comparison's pieces on hand-made numbers
+# ---------------------------------------------------------------------------
+def test_logit_error_and_token_gaps_on_hand_made_logits():
+    ref = np.array([[4.0, 1.0, -2.0], [0.5, -8.0, 2.0]], np.float32)
+    got = ref + np.array([[0.0, 0.04, 0.0], [0.0, 0.0, -0.08]], np.float32)
+    assert gen.logit_error(got, ref) == pytest.approx(0.08 / 8.0)
+    # the served token is the best (0), the second (3 below a best of 4),
+    # and at the second position the best again
+    gaps = gen.token_gaps(np.stack([ref[0], ref[0], ref[1]]), [0, 1, 2])
+    assert gaps.tolist() == pytest.approx([0.0, 3.0 / 4.0, 0.0])
+
+
+def test_rows_are_drawn_from_the_seed_with_the_last_call_among_them():
+    a = gen.draw_rows(3300000001, calls=4, rows=16, n=4)
+    assert a == gen.draw_rows(3300000001, calls=4, rows=16, n=4)
+    assert a != gen.draw_rows(3300000002, calls=4, rows=16, n=4)
+    assert len(set(a)) == 4 and any(c == 3 for c, _ in a)
+    assert all(0 <= c < 4 and 0 <= r < 16 for c, r in a)
+    for seed in range(40):          # whichever rows the seed draws
+        assert any(c == 2 for c, _ in gen.draw_rows(seed, 3, 16, 4))
+    assert gen.draw_rows(5, calls=1, rows=2, n=4) in ([(0, 0), (0, 1)],
+                                                     [(0, 1), (0, 0)])
+    assert gen.draw_rows(5, calls=0, rows=2, n=4) == []
+
+
+def test_batches_and_weights_come_from_the_seed_large_ones_too():
+    tr = dict(rows=2, prompt_len=5, distinct_batches=3)
+    big = 2 ** 31 + 7                      # more than 32 signed bits hold
+    a, probe = gen.make_batches(tr, 512, big)
+    b, _ = gen.make_batches(tr, 512, big)
+    assert len(a) == 3 and probe.shape == (2, 5)
+    assert all((x == y).all() for x, y in zip(a, b))
+    assert not (a[0] == a[1]).all() and not (a[0] == probe).all()
+    c, _ = gen.make_batches(tr, 512, big + 1)
+    assert not (a[0] == c[0]).all()
+    fields = dict(OPT_1_3B, **harness.TOY_FIELDS)
+    w = weights.make(fields, big)
+    again = weights.make(fields, big)
+    low = weights.make(fields, 7)          # the same low 31 bits
+    assert str(w["embed"].dtype) == "bfloat16"
+    assert (w["layers"]["wq"] == again["layers"]["wq"]).all()
+    assert not (w["layers"]["wq"] == low["layers"]["wq"]).all()
+    # the program's layout, every leaf
+    from deepspeed_tpu.models import TransformerLM
+    from deepspeed_tpu.models.transformer import TransformerConfig
+    import jax
+    want = jax.eval_shape(
+        TransformerLM(TransformerConfig(**fields)).init_params,
+        jax.random.PRNGKey(0))
+    assert jax.tree.map(lambda x: x.shape, want) == \
+        jax.tree.map(lambda x: x.shape, w)
+    # two outlier channels a head of 64 in the key bias, none elsewhere
+    bk = np.abs(np.asarray(w["layers"]["b_k"], np.float32))
+    assert (bk[:, ::32] == weights.KEY_OUTLIER).all()
+    assert bk[:, 1:32].max() < 1.0
+    assert np.abs(np.asarray(w["layers"]["b_v"], np.float32)).max() < 1.0
+
+
+# ---------------------------------------------------------------------------
+# the manifest's rules for the new entries
+# ---------------------------------------------------------------------------
+def test_the_generation_cell_is_one_chip_and_the_four_chip_quota_stands():
+    cells = {w["name"]: w for w in BENCH["workloads"]}
+    assert cells[CELL]["chips"] == 1
+    assert cells[CELL]["config"] == "opt-1.3b"
+    assert [n for n, w in cells.items() if w["chips"] == 4] == \
+        ["opt-1.3b.zero3-dp4"]
+    assert {c["name"] for c in BENCH["configs"]} == {"opt-1.3b", "opt-125m"}
+    assert BENCH["run_seconds"] == 40
+
+
+def test_what_the_generation_cell_reports_and_what_the_others_do_not():
+    assert reported_by(BENCH, CELL, "end_to_end") == ["setup_s", "gen_tok_s"]
+    assert reported_by(BENCH, CELL, "per_layer") == GEN_METRICS
+    e2e = {m["name"]: m for m in BENCH["end_to_end"]}
+    assert e2e["gen_tok_s"]["workloads"] == [CELL]
+    assert e2e["gen_tok_s"]["unit"] == "tokens/s/chip"
+    assert e2e["gen_tok_s"]["better"] == "higher"
+    assert 0.01 <= e2e["gen_tok_s"]["bound"] <= 0.05
+    assert CELL not in e2e["train_tok_s"]["workloads"]
+    assert e2e["train_tok_s"]["bound"] == 0.01 and \
+        e2e["setup_s"]["bound"] == 0.1           # nothing loosened
+    for m in BENCH["per_layer"]:
+        if m["name"] in GEN_METRICS:
+            assert m["moves"] == "gen_tok_s" and m["workloads"] == [CELL]
+        else:
+            assert m["moves"] == "train_tok_s" and CELL not in m["workloads"]
+    for cell in ("opt-125m.train-dense", "opt-1.3b.zero3-dp4"):
+        assert "gen_tok_s" not in reported_by(BENCH, cell, "end_to_end")
+        assert not set(GEN_METRICS) & set(reported_by(BENCH, cell,
+                                                      "per_layer"))
+
+
+def test_the_cell_file_says_the_traffic_the_engine_and_the_limits():
+    cell = harness.load_json("workloads", CELL)
+    tr = harness.load_json("traffic", cell["traffic"])
+    assert (tr["rows"], tr["prompt_len"], tr["new_tokens"]) == (16, 256, 256)
+    assert tr["temperature"] == 0.0 and tr["runner"] == "generate"
+    assert "DeepSpeed-Chat" in tr["what"] and "assumed" in tr
+    sm = cell["engine"]["state_manager"]
+    assert sm["max_ragged_batch_size"] == tr["rows"] * tr["prompt_len"]
+    assert sm["max_tracked_sequences"] == tr["rows"]
+    # the pool: what the step needs, a spare block a row, the null block
+    need = tr["rows"] * (tr["prompt_len"] + tr["new_tokens"])
+    assert sm["num_blocks"] == need // sm["block_size"] + tr["rows"] + 1
+    assert not cell["engine"].get("kv_quant")
+    assert cell["control"] == {"engine": {"kv_quant": True}}
+    for overridden in sm:
+        assert overridden in cell["overrides"], overridden
+    for limits in (cell["limits"], cell["rehearse"]["limits"]):
+        assert set(limits) == {"logit_err", "token_gap"}
+        for lim in limits.values():
+            assert 0 < lim["limit"] < 1 and lim["from"]
+
+
+# ---------------------------------------------------------------------------
+# the cell at toy widths
+# ---------------------------------------------------------------------------
+def test_rehearse_the_generation_cell_with_its_readers():
+    p = run_py(["--workload", CELL, "--rehearse", "--trace", "1",
+                "--seed", str(2 ** 31 + 33)])
+    assert_rehearsed(p)
+    assert "readers ran" in p.stderr and "compiles.gen" in p.stderr
+    assert "compared: logit_err" in p.stderr
+    assert "compared: token_gap" in p.stderr
+
+
+@pytest.fixture(scope="module")
+def toy():
+    """``run_once(seed, ...)`` of the cell at toy widths in this process
+    (the harness's look for a chip skipped), one compile clock for all."""
+    import jax
+    clock = harness.CompileClock()
+    devices = jax.devices()
+
+    def run_once(seed, control_on=False, overlay=None):
+        return control.run_once(CELL, seed, 0.5, control_on, devices,
+                                clock, rehearse=True, overlay=overlay)
+    return run_once
+
+
+def compared(result):
+    return {k: v["value"] for k, v in
+            result.correct_detail["compared"].items()}
+
+
+@pytest.mark.parametrize("seed", [3300000101, 2 ** 31 + 3300000102])
+def test_correct_passes_as_the_cell_stands_and_fails_with_int8_kv(toy, seed):
+    sound, ctl = toy(seed), toy(seed, control_on=True)
+    assert sound.correct, compared(sound)
+    assert sound.failed == 0 and sound.attempted > 0
+    assert sound.correct_detail["compiles_in_window"] == 0
+    assert not ctl.correct, compared(ctl)
+    limits = harness.load_cell(CELL, rehearse=True)[0]["limits"]
+    over = [k for k, v in compared(ctl).items() if v > limits[k]["limit"]]
+    assert over, "the control has to fail a number, not a count of calls"
+
+
+def test_correct_fails_with_the_cache_written_one_position_off(
+        toy, monkeypatch):
+    from deepspeed_tpu.inference.v2 import paged_model
+    write = paged_model._kv_write
+
+    def one_off(kc, ksc, l, blocks, offs, k):
+        return write(kc, ksc, l, blocks, (offs + 1) % kc.shape[2], k)
+
+    monkeypatch.setattr(paged_model, "_kv_write", one_off)
+    broken = toy(3300000103)
+    assert not broken.correct, compared(broken)
+
+
+def test_correct_fails_with_one_served_token_altered(toy, monkeypatch):
+    """The rest of a run driven with the timed path broken underneath:
+    every call comes back whole, one token of one row in a hundred is
+    not the one the engine made."""
+    from deepspeed_tpu.inference.v2 import InferenceEngineV2
+    made = InferenceEngineV2.generate
+
+    def altered(self, prompts, max_new_tokens, **kw):
+        outs = made(self, prompts, max_new_tokens, **kw)
+        for row in outs:
+            row[len(prompts[0]) + 2] = (row[len(prompts[0]) + 2] + 1) % 512
+        return outs
+
+    monkeypatch.setattr(InferenceEngineV2, "generate", altered)
+    broken = toy(3300000104)
+    assert broken.failed == 0 and broken.attempted > 0
+    got = compared(broken)
+    limits = harness.load_cell(CELL, rehearse=True)[0]["limits"]
+    assert got["logit_err"] <= limits["logit_err"]["limit"]   # put() is whole
+    assert got["token_gap"] > limits["token_gap"]["limit"]
+    assert not broken.correct
