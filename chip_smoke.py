@@ -165,13 +165,16 @@ def phase_kernels(sizes):
                   f"flash hd={hd}: the fused backward did not run: {rep}")
 
     rng = np.random.default_rng(0)
-    nb, bs, R, MB = 64, 16, 4, 8
-    # ragged rows: a 10-token prefill chunk at positions 20..29, decode
-    # rows deep into / at the start of their tables, padding to T=16
-    positions = [range(20, 30), [100], [5], [127]]
+    nb, bs, R, MB = 80, 16, 4, 16
+    # ragged rows: a 150-token chunk at positions 20..169 (over a cached
+    # prefix, longer than one query tile of the tiled kernel, ending
+    # mid-page), decode rows deep into / at the start / at the end of
+    # their tables, padding to T=256
+    positions = [range(20, 170), [200], [5], [255]]
     rows = [r for r, ps in enumerate(positions) for _ in ps]
     lens = [p + 1 for ps in positions for p in ps]
-    pad = 16 - len(rows)
+    T = 256
+    pad = T - len(rows)
     rows, lens = rows + [0] * pad, lens + [0] * pad
     for nh, kvh, hd in ((32, 32, 64), (32, 8, 128)):
         for quant in (False, True):
@@ -189,14 +192,14 @@ def phase_kernels(sizes):
             (kc, ks), (vc, vs) = pool(), pool()
             tables = jnp.asarray(rng.permutation(np.arange(1, nb))
                                  [:R * MB].reshape(R, MB), jnp.int32)
-            q = jnp.asarray(rng.standard_normal((16, nh, hd)), jnp.bfloat16)
+            q = jnp.asarray(rng.standard_normal((T, nh, hd)), jnp.bfloat16)
             rows_a = jnp.asarray(rows, jnp.int32)
             lens_a = jnp.asarray(lens, jnp.int32)
             ragged = jax.jit(ragged_attention)(
                 q, kc, vc, rows_a, lens_a, tables, k_scale=ks, v_scale=vs)
             e_r = rel_err(ragged, ragged_reference(
                 q, kc, vc, rows_a, lens_a, tables, ks, vs))
-            dlen = jnp.asarray([1, 16, 77, 128], jnp.int32)
+            dlen = jnp.asarray([1, 16, 77, 256], jnp.int32)
             decode = jax.jit(paged_attention)(
                 q[:R], kc, vc, tables, dlen, k_scale=ks, v_scale=vs)
             e_d = rel_err(decode, ragged_reference(

@@ -35,43 +35,51 @@ Two variants, chosen STATICALLY from the pool geometry by
 :func:`kernel_variant` (the table ``tests/unit/ops/
 test_kernels_lower_tpu.py`` pins against the Mosaic compiler):
 
-* ``"dma"`` (grid ``(T,)``, manual DMA). The pools stay HBM-resident
-  (``memory_space=ANY``); each token walks only the pages its causal
-  bound covers (``ceil(length/bs)``, a dynamic ``fori_loop`` bound) with
-  double-buffered ``make_async_copy``, so DMA traffic scales with real
-  context length, not table width. Mosaic only accepts the page slice
-  ``pool[page]`` when the page's trailing ``(kv_heads, head_dim)`` dims
-  are aligned to the pool's HBM tiling, which limits this variant to
-  lane-dense geometries (see :func:`kernel_variant`).
-* ``"pipelined"`` (grid ``(T, MB)``, BlockSpec-indexed). Streams every
-  one of the table's ``MB`` slots per token (compute is skipped past the
-  causal bound, the copy is not), but the pipeline emitter pads
-  unaligned pages itself, so it compiles at every geometry. It is also
-  the variant interpret mode runs off-chip (the manual DMA/semaphore
-  protocol wedges under interpret).
+* ``"tiled"`` — the page walk goes per ROW. Grid ``(T / tq,)``: a step
+  owns a tile of up to 128 flat tokens and walks the rows whose tokens
+  lie in it (decode: 16 rows of one token; prefill: one row a tile). A
+  row's pages arrive in chunks of ``_CHUNK_POSITIONS`` positions by
+  ``make_async_copy`` out of its block table, double-buffered across
+  chunks AND rows, up to the causal bound of the row's last token in the
+  tile (a dynamic bound: no page past it is copied or visited), so a
+  prefill chunk's keys are read once a tile and not once a token. The
+  pool is presented lane-dense, ``(nb * bs, kvh * hd)`` (a reshape in
+  the wrapper), so a page is ``bs`` rows that move their own bytes, and
+  a 128-lane block of it is two 64-wide heads or one head a multiple of
+  128 wide.
+  A block's query rows — tile tokens x GQA group x heads of the block,
+  each zero outside its own head's lanes — go through the matrix unit
+  together on the pool's bf16 with float32 accumulation
+  (:func:`_tile_update`); tokens of other rows and positions past a
+  token's own bound are masked, which is all in-tile causality is.
+  Serves every geometry :func:`tiled_geometry` accepts.
+* ``"pipelined"`` (grid ``(T, MB)``, BlockSpec-indexed): one step a
+  token and table slot, all of a page's heads by a static loop
+  (:func:`_page_update`). Streams every slot (compute is skipped past
+  the causal bound, the copy is not), but the pipeline emitter pads
+  unaligned pages itself, so it compiles where a page row is not whole
+  128-lane blocks of whole heads (a lone 64-wide head, 80- and 96-wide
+  heads, toy widths).
 
-Each page step loads the block's K/V for ALL kv heads at once — the
-(block_size, kv_heads, head_dim) tile equals the array's trailing dims,
-which is what the Mosaic lowering requires. GQA is a static Python loop
-over kv heads inside the kernel, each head updating its own rows of the
-flat (nh, ...) softmax scratch; position masking handles the partial
-last page.
+Off the TPU each variant runs under its interpreter when asked for by
+name (the tiled one under ``pltpu.InterpretParams``: DMAs, semaphores and
+all), so tier-1 holds both to the gather reference chip-free; the
+engine's programs default to the pipelined one there, whose interpreter
+is several times the faster.
 
 int8 ``kv_quant`` pools: the per-(block, kv-head) scales are gathered by
 the row's block table OUTSIDE the kernel (``scale[block_tables]``,
-``R*MB*kvh`` floats) and delivered as one ``MB*kvh`` SMEM block per row
-— a ``(1, kvh)`` slice of the ``[nb, kvh]`` scale array is not a legal
-Mosaic block or DMA slice at any geometry, and a scalar read from SMEM
-is the one operand Mosaic broadcasts over a whole (bs, hd) tile. The
-kernel dequantizes each head's page slice in VMEM.
+``R*MB*kvh`` floats) and reach SMEM — pipelined: one ``MB*kvh`` block a
+row; tiled: a chunk's slice by DMA beside its pages — because a scalar
+read from SMEM is the one operand Mosaic broadcasts over a whole tile.
+Both dequantize in VMEM through the pool's serving dtype, the arithmetic
+of ``paged_model._kv_read``.
 
-Design note — token-grid vs query-tiling: this kernel walks pages per
-TOKEN, which makes decode rows optimal but re-streams a prefill chunk's
-shared prefix once per chunk token (O(chunk * ctx / bs) page loads
-instead of O(ctx / bs) per q-tile). The published RPA kernel tiles
-queries per row to amortize that; doing the same here means (q-tile,
-page) grid cells with per-row tile maps. The SplitFuse chunk budget
-bounds the waste meanwhile.
+What the tiled variant wants of the pool (PERF.md section 7): the layer
+stored as ``[nb, bs, kvh * hd]``. At head width 64 the pool's
+``(kvh, hd)`` tiles pad 64 lanes to 128, no view of them is a bitcast,
+and XLA makes the wrapper's reshape in a pass of its own after the
+layer's slice; every other formulation tried on the chip cost more.
 """
 
 import functools
@@ -82,9 +90,20 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ....utils.bucketing import pow2_bucket
+
 NEG_INF = -1e30
 
-VARIANTS = ("dma", "pipelined")
+VARIANTS = ("tiled", "pipelined")
+
+# the tiled kernel's VMEM: two slots of K and V chunks, the query and
+# output tiles twice, the float32 state — 12 MB at OPT-1.3B's geometry,
+# over the 16 MB the compiler grants by default once a group is wider
+_TILED_VMEM_BYTES = 64 * 2 ** 20
+
+
+# positions of a row fetched and computed at a time (tuned on the chip)
+_CHUNK_POSITIONS = 512
 
 
 def _interpret() -> bool:
@@ -92,21 +111,14 @@ def _interpret() -> bool:
 
 
 def kernel_variant(head_dim: int, kv_heads: int, kv_quant: bool) -> str:
-    """Which variant serves a ``[nb, bs, kv_heads, head_dim]`` pool on
-    the TPU: ``"dma"`` where Mosaic accepts the manual page slice,
-    ``"pipelined"`` everywhere else. Decided from static config only —
-    never by trying a compile — and pinned row by row against the real
-    compiler in tests/unit/ops/test_kernels_lower_tpu.py.
-
-    The DMA slice needs the page's trailing dims aligned to the pool's
-    HBM tiling: ``head_dim`` a multiple of the 128 lanes, and
-    ``kv_heads`` a whole number of sublane tiles — XLA tiles that dim by
-    8 rows, or by 4 when it is exactly 4. bf16 and int8 pools follow the
-    same rule (kv_heads 1 and 2 tile differently per dtype and are left
-    to the pipelined variant)."""
-    if head_dim % 128 == 0 and (kv_heads % 8 == 0 or kv_heads == 4):
-        return "dma"
-    return "pipelined"
+    """Which variant serves a ``[nb, bs, kv_heads, head_dim]`` pool:
+    ``"tiled"`` wherever a page row splits into whole 128-lane blocks of
+    whole heads (:func:`tiled_geometry`), ``"pipelined"`` everywhere
+    else. Decided from static config only — never by trying a compile —
+    and pinned row by row against the real compiler in
+    tests/unit/ops/test_kernels_lower_tpu.py; bf16 and int8 pools follow
+    the same rule."""
+    return "tiled" if tiled_geometry(head_dim, kv_heads) else "pipelined"
 
 
 def _page_update(q_ref, k_tile, v_tile, ks, vs, j, length, acc_sc, m_sc,
@@ -199,55 +211,294 @@ def _pipelined_kernel(row_ref, len_ref, bt_ref, q_ref, k_ref, v_ref, *rest,
                   group=static["group"])
 
 
-def _dma_kernel(row_ref, len_ref, bt_ref, q_ref, k_hbm, v_hbm, *rest,
-                quant, **static):
-    """Grid (T,): per token, double-buffered manual DMA over the pages
-    its causal bound covers, out of its row's table. k_sc/v_sc are
-    (2, bs, kvh, hd) VMEM slots; sem is a (2, 2) DMA semaphore array
-    (slot x {k, v})."""
-    (ks_ref, vs_ref), rest = (rest[:2], rest[2:]) if quant \
-        else ((None, None), rest)
-    o_ref, k_sc, v_sc, acc_sc, m_sc, l_sc, sem = rest
-    bs = static["bs"]
-    t = pl.program_id(0)
-    row = row_ref[t]
-    length = len_ref[t]
-    n_pages = (length + bs - 1) // bs
+def tiled_geometry(head_dim: int, kv_heads: int):
+    """``(lane-block width, kv heads a block)`` of the tiled variant, or
+    None where a page's ``kv_heads * head_dim`` row does not split into
+    whole 128-lane blocks of whole heads. A block is one head (width a
+    multiple of 128) or the ``128 // head_dim`` heads that share 128
+    lanes."""
+    if (kv_heads * head_dim) % 128:
+        return None
+    if head_dim % 128 == 0:
+        return head_dim, 1
+    if 128 % head_dim == 0:
+        return 128, 128 // head_dim
+    return None
+
+
+def _tile_update(q, k, v, visible, acc_sc, m_sc, l_sc, b, *, scale):
+    """One lane block's online-softmax update over one KV chunk: q
+    ``(M, bw)`` (the rows of the block's query heads, each zero outside
+    its own kv head's lanes), k/v ``(P, bw)`` as stored (bf16 on the
+    chip: the products are exact in the float32 accumulator), visible
+    ``(M, P)`` which positions each row may attend (none for a token of
+    another row).
+    Max, sum and accumulator stay float32; p is rounded to the pool's
+    dtype for the second product, as ops/flash_attention.py does. A
+    masked score is 2 * NEG_INF under a running max that starts at
+    NEG_INF, so a row with nothing to attend yet adds exp(NEG_INF) = 0
+    and keeps its state."""
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * scale
+    s = jnp.where(visible, s, 2 * NEG_INF)
+    m_prev = m_sc[b, :, :1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    p = jnp.exp(s - m_new)
+    corr = jnp.exp(m_prev - m_new)
+    l_sc[b] = jnp.broadcast_to(
+        l_sc[b, :, :1] * corr + jnp.sum(p, axis=1, keepdims=True),
+        l_sc.shape[1:])
+    acc_sc[b] = acc_sc[b] * corr + jax.lax.dot_general(
+        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    m_sc[b] = jnp.broadcast_to(m_new, m_sc.shape[1:])
+
+
+def _tiled_kernel(len_ref, bt_ref, first_ref, last_ref, lo_ref, hi_ref,
+                  q_ref, tl_ref, k_hbm, v_hbm, *rest, quant, bs, scale, kvh,
+                  hd, hpb, group, tq, cp, io_dtype):
+    """Grid (T / tq,): one step a tile of ``tq`` flat tokens. The tile
+    walks the rows that own its tokens (``lo_ref``/``hi_ref``), a row's
+    pages in chunks of ``cp`` up to the causal bound of the row's last
+    token in the tile — no page past it is copied or visited. A chunk's
+    pages go by ``make_async_copy`` from the HBM pool into one of two
+    VMEM slots; the next chunk (of this row or the next) is in flight
+    while this one is computed. The pools are ``(nb * bs, F)``, a page
+    ``bs`` rows of ``F = kvh * hd`` lanes; k_buf/v_buf are (2, cp, bs,
+    F); sem is (2, 2) = slot x {k, v}, one wait a page."""
+    if quant:
+        ks_hbm, vs_hbm, o_ref, k_buf, v_buf, ks_buf, vs_buf, acc_sc, m_sc, \
+            l_sc, sem, ssem = rest
+    else:
+        o_ref, k_buf, v_buf, acc_sc, m_sc, l_sc, sem = rest
+    nblk, rpb = q_ref.shape[:2]
+    bw = q_ref.shape[3]
+    M, P, T = rpb * tq, cp * bs, len_ref.shape[0]
+    sw = ks_buf.shape[0] // 2 if quant else 0   # a slot of scales, words
+    t0 = pl.program_id(0) * tq
+    lo, hi = lo_ref[pl.program_id(0)], hi_ref[pl.program_id(0)]
 
     _init_scratch(acc_sc, m_sc, l_sc)
 
-    def k_dma(slot, j):
-        return pltpu.make_async_copy(
-            k_hbm.at[bt_ref[row, j]], k_sc.at[slot], sem.at[slot, 0])
+    @pl.when(pl.program_id(0) == 0)
+    def _zero():
+        # a stale value page is multiplied by p = 0: it must be finite
+        v_buf[...] = jnp.zeros_like(v_buf)
 
-    def v_dma(slot, j):
-        return pltpu.make_async_copy(
-            v_hbm.at[bt_ref[row, j]], v_sc.at[slot], sem.at[slot, 1])
+    def bounds(r):
+        """Row r's tokens in this tile [first, last] and the causal bound
+        of the last of them (0: the row has no token here)."""
+        r = jnp.minimum(r, first_ref.shape[0] - 1)   # hi + 1 is asked too
+        first = jnp.maximum(first_ref[r], t0)
+        last = jnp.minimum(last_ref[r], t0 + tq - 1)
+        kv = jnp.where(first <= last, len_ref[jnp.clip(last, 0, T - 1)], 0)
+        return first, last, kv
 
-    @pl.when(n_pages > 0)
-    def _start():
-        k_dma(0, 0).start()
-        v_dma(0, 0).start()
+    def n_chunks(r):
+        return (bounds(r)[2] + P - 1) // P
 
-    def body(j, _):
-        slot = jax.lax.rem(j, 2)
-        nxt = jax.lax.rem(j + 1, 2)
+    def next_row(r):
+        return jax.lax.while_loop(
+            lambda r: (r <= hi) & (n_chunks(r) == 0), lambda r: r + 1, r)
 
-        @pl.when(j + 1 < n_pages)
+    def pages(r, c):
+        return jnp.minimum((bounds(r)[2] + bs - 1) // bs - c * cp, cp)
+
+    def copies(r, c, slot, j):
+        page = pl.ds(bt_ref[r, c * cp + j] * bs, bs)   # its rows of the pool
+        return (pltpu.make_async_copy(k_hbm.at[page], k_buf.at[slot, j],
+                                      sem.at[slot, 0]),
+                pltpu.make_async_copy(v_hbm.at[page], v_buf.at[slot, j],
+                                      sem.at[slot, 1]))
+
+    def scale_copies(r, c, slot):
+        src = pl.ds(pl.multiple_of(
+            (r * (bt_ref.shape[1] // cp) + c) * sw, sw), sw)
+        dst = pl.ds(pl.multiple_of(slot * sw, sw), sw)
+        return (pltpu.make_async_copy(ks_hbm.at[src], ks_buf.at[dst],
+                                      ssem.at[slot, 0]),
+                pltpu.make_async_copy(vs_hbm.at[src], vs_buf.at[dst],
+                                      ssem.at[slot, 1]))
+
+    def each_copy(r, c, slot, act):
+        """``act`` (start or wait) on every copy of chunk c of row r."""
+        def one(j, _):
+            for dma in copies(r, c, slot, j):
+                act(dma)
+            return 0
+        jax.lax.fori_loop(0, pages(r, c), one, 0)
+        if quant:
+            for dma in scale_copies(r, c, slot):
+                act(dma)
+
+    def start(r, c, slot):
+        each_copy(r, c, slot, lambda dma: dma.start())
+
+    def wait(r, c, slot):
+        each_copy(r, c, slot, lambda dma: dma.wait())
+
+    def dequant(x, s_buf, slot, b):
+        """The block's (cp, bs, bw) slice of an int8 chunk through the
+        pool's serving dtype: paged_model._kv_read's arithmetic, a scale
+        a page and head splat from SMEM."""
+        lane = jax.lax.broadcasted_iota(jnp.int32, (bs, bw), 1)
+        rows = []
+        for j in range(cp):
+            at = slot * sw + j * kvh + b * hpb
+            sc = jnp.full((bs, bw), s_buf[at], jnp.float32)
+            for i in range(1, hpb):
+                sc = jnp.where(lane >= i * hd, s_buf[at + i], sc)
+            rows.append(sc)
+        return (x.astype(jnp.float32).reshape(P, bw)
+                * jnp.concatenate(rows, axis=0)).astype(io_dtype)
+
+    def compute(r, c, slot):
+        first, last, _ = bounds(r)
+        tok = t0 + jax.lax.broadcasted_iota(jnp.int32, (tq, 1), 0)
+        eff = jnp.where((tok >= first) & (tok <= last), tl_ref[...], 0)
+        eff = jnp.concatenate([eff] * rpb, axis=0)            # (M, 1)
+        visible = c * P + jax.lax.broadcasted_iota(
+            jnp.int32, (M, P), 1) < eff
+        for b in range(nblk):                                 # static
+            lanes = slice(b * bw, (b + 1) * bw)
+            k = k_buf[slot, :, :, lanes]
+            v = v_buf[slot, :, :, lanes]
+            if quant:
+                k = dequant(k, ks_buf, slot, b)
+                v = dequant(v, vs_buf, slot, b)
+            _tile_update(q_ref[b].reshape(M, bw), k.reshape(P, bw),
+                         v.reshape(P, bw), visible, acc_sc, m_sc, l_sc, b,
+                         scale=scale)
+
+    r0 = next_row(lo)
+
+    @pl.when(r0 <= hi)
+    def _first():
+        start(r0, 0, 0)
+
+    def step(state):
+        r, c, slot = state
+        last = c + 1 >= n_chunks(r)
+        nr = jax.lax.cond(last, lambda: next_row(r + 1), lambda: r)
+        nc = jnp.where(last, 0, c + 1)
+
+        @pl.when(nr <= hi)
         def _prefetch():
-            k_dma(nxt, j + 1).start()
-            v_dma(nxt, j + 1).start()
+            start(nr, nc, 1 - slot)
 
-        k_dma(slot, j).wait()
-        v_dma(slot, j).wait()
-        ks, vs = _scale_rows(ks_ref, vs_ref, j, static["kvh"])
-        _page_update(q_ref, k_sc[slot], v_sc[slot], ks, vs, j, length,
-                     acc_sc, m_sc, l_sc, **static)
-        return 0
+        wait(r, c, slot)
+        compute(r, c, slot)
+        return nr, nc, 1 - slot
 
-    jax.lax.fori_loop(0, n_pages, body, 0)
+    jax.lax.while_loop(lambda state: state[0] <= hi, step,
+                       (r0, jnp.int32(0), jnp.int32(0)))
 
-    _finalize(o_ref, acc_sc, l_sc, kvh=static["kvh"], group=static["group"])
+    lane = jax.lax.broadcasted_iota(jnp.int32, (group * tq, bw), 1)
+    for b in range(nblk):                                     # static
+        l = l_sc[b, :, :1]
+        a = (acc_sc[b] / jnp.where(l == 0.0, 1.0, l)).reshape(
+            hpb, group * tq, bw)
+        out = a[0]
+        for i in range(1, hpb):        # head i's lanes from head i's rows
+            out = jnp.where(lane >= i * hd, a[i], out)
+        o_ref[b] = out.reshape(group, tq, bw).astype(o_ref.dtype)
+
+
+def _tiled_call(q, k_cache, v_cache, row_ids, lengths, block_tables,
+                k_scale, v_scale, interpret):
+    """Lay the operands out for :func:`_tiled_kernel` and undo it: the
+    per-row descriptor (first and last flat token, from ``row_ids`` and
+    ``lengths``: a row's tokens are contiguous in pack order), a tile's
+    first and last row, queries as ``[block, row of the block, T, bw]``
+    and the pool lane-dense and two-dimensional, ``[nb * bs, kvh * hd]``:
+    that shape XLA reaches from the layer's slice with ONE native
+    reshape, ``[nb, bs, kvh * hd]`` with two transposing copies (PERF.md
+    section 6)."""
+    T0, nh, hd = q.shape
+    nb, bs, kvh, _ = k_cache.shape
+    R, MB = block_tables.shape
+    group = nh // kvh
+    bw, hpb = tiled_geometry(hd, kvh)
+    nblk, rpb, F = kvh // hpb, hpb * group, kvh * hd
+    quant = k_scale is not None
+    # a tile: a power of two of 16 to 128 tokens, at most 512 query rows
+    # a lane block where that leaves 16
+    tq = max(16, min(pow2_bucket(T0, 128),
+                     1 << (max(512 // rpb, 1).bit_length() - 1)))
+    T = -(-T0 // tq) * tq
+    cp = max(1, min(MB, _CHUNK_POSITIONS // bs))   # pages a chunk
+    if MB % cp:
+        block_tables = jnp.pad(block_tables, ((0, 0), (0, cp - MB % cp)))
+        MB = block_tables.shape[1]
+    if T != T0:
+        q = jnp.pad(q, ((0, T - T0), (0, 0), (0, 0)))
+        row_ids = jnp.pad(row_ids, (0, T - T0))
+        lengths = jnp.pad(lengths, (0, T - T0))
+
+    tok = jnp.arange(T, dtype=jnp.int32)
+    valid = lengths > 0
+    mine = (row_ids[None, :] == jnp.arange(R, dtype=jnp.int32)[:, None]) \
+        & valid[None, :]                                        # [R, T]
+    row_first = jnp.min(jnp.where(mine, tok, T), axis=1)
+    row_last = jnp.max(jnp.where(mine, tok, -1), axis=1)
+    tile_lo = jnp.min(jnp.where(valid, row_ids, R).reshape(-1, tq), axis=1)
+    tile_hi = jnp.max(jnp.where(valid, row_ids, -1).reshape(-1, tq), axis=1)
+
+    qx = q.reshape(T, nblk, hpb, group, hd).transpose(1, 2, 3, 0, 4)
+    if hpb > 1:       # a query row is zero outside its own head's lanes
+        own = jnp.eye(hpb, dtype=bool)[None, :, None, None, :, None]
+        qx = jnp.where(own, qx[:, :, :, :, None, :], 0)
+    qx = qx.reshape(nblk, rpb, T, bw)
+
+    def tile(i, *_):
+        return (0, 0, i, 0)
+
+    pool_spec = pl.BlockSpec(memory_space=pl.ANY)       # pool stays in HBM
+    in_specs = [pl.BlockSpec((nblk, rpb, tq, bw), tile),
+                pl.BlockSpec((tq, 1), lambda i, *_: (i, 0)),
+                pool_spec, pool_spec]
+    operands = [qx, lengths.reshape(T, 1), k_cache.reshape(nb * bs, F),
+                v_cache.reshape(nb * bs, F)]
+    M = rpb * tq
+    scratch = [pltpu.VMEM((2, cp, bs, F), k_cache.dtype),
+               pltpu.VMEM((2, cp, bs, F), v_cache.dtype)]
+    sems = [pltpu.SemaphoreType.DMA((2, 2))]
+    if quant:
+        # a chunk's scales, [cp, kvh] padded to a whole 1,024-word tile, go
+        # to SMEM beside its pages: one flat array, a slice a chunk
+        w = -(-cp * kvh // 1024) * 1024
+        for sc in (k_scale, v_scale):
+            sc = sc.astype(jnp.float32)[block_tables].reshape(
+                R * MB // cp, cp * kvh)
+            in_specs.append(pool_spec)
+            operands.append(jnp.pad(sc, ((0, 0), (0, w - cp * kvh)))
+                            .reshape(-1))
+        scratch += [pltpu.SMEM((2 * w,), jnp.float32)] * 2
+        sems.append(pltpu.SemaphoreType.DMA((2, 2)))
+    scratch += [pltpu.VMEM((nblk, M, bw), jnp.float32),
+                pltpu.VMEM((nblk, M, 128), jnp.float32),
+                pltpu.VMEM((nblk, M, 128), jnp.float32)]
+    kernel = functools.partial(
+        _tiled_kernel, quant=quant, bs=bs, scale=1.0 / (hd ** 0.5), kvh=kvh,
+        hd=hd, hpb=hpb, group=group, tq=tq, cp=cp, io_dtype=q.dtype)
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=6,
+            grid=(T // tq,),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((nblk, group, tq, bw), tile),
+            scratch_shapes=scratch + sems),
+        out_shape=jax.ShapeDtypeStruct((nblk, group, T, bw), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_TILED_VMEM_BYTES),
+        interpret=pltpu.InterpretParams() if interpret else False,
+        name="ragged_attention_tiled",
+    )(lengths, block_tables, row_first, row_last, tile_lo, tile_hi,
+      *operands)
+    out = out.reshape(nblk, group, T, hpb, hd).transpose(2, 0, 3, 1, 4)
+    return out.reshape(T, nh, hd)[:T0]
 
 
 def ragged_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
@@ -266,8 +517,9 @@ def ragged_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
     dequant scales — the kernel dequantizes in VMEM, so quantized KV
     serves through the SAME one-program ragged family. ``variant``
     defaults to :func:`kernel_variant`'s static choice for the pool
-    geometry; off-TPU the pipelined variant runs in interpret mode
-    whatever was asked. Returns [T, nh, hd]."""
+    geometry; off-TPU the default is the pipelined variant in interpret
+    mode, and ``variant="tiled"`` runs the tiled one under the TPU
+    interpreter, DMAs and semaphores included. Returns [T, nh, hd]."""
     T, nh, hd = q.shape
     nb, bs, kvh, _ = k_cache.shape
     MB = block_tables.shape[1]
@@ -275,13 +527,20 @@ def ragged_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
     quant = k_scale is not None
     interpret = _interpret()
     if variant is None:
-        variant = kernel_variant(hd, kvh, quant)
-    if interpret:
-        variant = "pipelined"
+        # off the TPU the engine's programs take the pipelined variant:
+        # its interpreter is several times faster than the TPU
+        # interpreter the tiled one needs, which only a test asks for
+        variant = "pipelined" if interpret else kernel_variant(hd, kvh, quant)
     if variant not in VARIANTS:
         raise ValueError(f"variant must be one of {VARIANTS}, got {variant!r}")
-    dma = variant == "dma"
+    row_ids = row_ids.astype(jnp.int32)
+    lengths = lengths.astype(jnp.int32)
     block_tables = block_tables.astype(jnp.int32)
+    if variant == "tiled":
+        if tiled_geometry(hd, kvh) is None:
+            raise ValueError(f"no tiled variant for {kvh} kv heads of {hd}")
+        return _tiled_call(q, k_cache, v_cache, row_ids, lengths,
+                           block_tables, k_scale, v_scale, interpret)
     q4 = q.reshape(T, kvh, group, hd)
     static = dict(bs=bs, scale=1.0 / (hd ** 0.5), kvh=kvh, group=group,
                   io_dtype=q.dtype)
@@ -296,21 +555,10 @@ def ragged_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
     def page(t, j, row, ln, bt):
         return (bt[row[t], j], 0, 0, 0)
 
+    in_specs = [pl.BlockSpec((1, kvh, group, hd), tok),
+                pl.BlockSpec((1, bs, kvh, hd), page),
+                pl.BlockSpec((1, bs, kvh, hd), page)]
     operands = [q4, k_cache, v_cache]
-    if dma:
-        kernel = functools.partial(_dma_kernel, quant=quant, **static)
-        grid = (T,)
-        pool_spec = pl.BlockSpec(memory_space=pl.ANY)   # pool stays in HBM
-        scratch = [pltpu.VMEM((2, bs, kvh, hd), k_cache.dtype),
-                   pltpu.VMEM((2, bs, kvh, hd), v_cache.dtype)]
-        sems = [pltpu.SemaphoreType.DMA((2, 2))]
-    else:
-        kernel = functools.partial(_pipelined_kernel, quant=quant,
-                                   n_pages=MB, **static)
-        grid = (T, MB)
-        pool_spec = pl.BlockSpec((1, bs, kvh, hd), page)
-        scratch, sems = [], []
-    in_specs = [pl.BlockSpec((1, kvh, group, hd), tok), pool_spec, pool_spec]
     if quant:
         R = block_tables.shape[0]
         for sc in (k_scale, v_scale):
@@ -319,20 +567,20 @@ def ragged_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
             operands.append(sc.astype(jnp.float32)[block_tables]
                             .reshape(R, 1, MB * kvh))
     out = pl.pallas_call(
-        kernel,
+        functools.partial(_pipelined_kernel, quant=quant, n_pages=MB,
+                          **static),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
-            grid=grid,
+            grid=(T, MB),
             in_specs=in_specs,
             out_specs=pl.BlockSpec((1, kvh, group, hd), tok),
-            scratch_shapes=scratch + [
+            scratch_shapes=[
                 pltpu.VMEM((kvh * group, hd), jnp.float32),
                 pltpu.VMEM((kvh * group, 128), jnp.float32),
                 pltpu.VMEM((kvh * group, 128), jnp.float32),
-            ] + sems),
+            ]),
         out_shape=jax.ShapeDtypeStruct((T, kvh, group, hd), q.dtype),
         interpret=interpret,
-        name=f"ragged_attention_{variant}",
-    )(row_ids.astype(jnp.int32), lengths.astype(jnp.int32), block_tables,
-      *operands)
+        name="ragged_attention_pipelined",
+    )(row_ids, lengths, block_tables, *operands)
     return out.reshape(T, nh, hd)

@@ -464,7 +464,7 @@ class ServingEngine:
             # converges the fleet onto one target version off this field
             "weight_version": self.weight_version,
             # which attention path the engine resolved at construction
-            # ("pallas:dma" | "pallas:pipelined" | "jnp:gather")
+            # ("pallas:tiled" | "pallas:pipelined" | "jnp:gather")
             "attention_impl": getattr(self.scheduler.engine,
                                       "attention_impl", None),
             # spill-aware placement signal (ragged/spill.py): the bloom

@@ -17,6 +17,8 @@ engine_v2.step_ragged + the SplitFuse scheduler's RaggedBatch emission):
   (the CI-visible rollback guarantee).
 """
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -110,6 +112,116 @@ def test_ragged_kernel_pure_decode_matches_decode_kernel():
     decode = np.asarray(paged_attention(q, k_cache, v_cache, tables,
                                         lengths))
     np.testing.assert_array_equal(ragged, decode)
+
+
+# ---------------------------------------------------------------------------
+# the tiled variant (the TPU's kernel wherever a page row is lane-dense),
+# whole, asked for by name under the TPU interpreter: DMAs, semaphores,
+# the chunked walk (off the TPU the engine's default is the pipelined one)
+# ---------------------------------------------------------------------------
+def _gather_reference(q, kc, vc, rows, lens, tables, ks=None, vs=None):
+    """The jnp gather path's arithmetic (paged_model._kv_read's dequant,
+    masked float32 softmax) over each row's pages; padding gives zeros."""
+    T, nh, hd = q.shape
+    _, bs, kvh, _ = kc.shape
+    R, MB = tables.shape
+
+    def pages(c, s):
+        p = c[tables]                                # [R, MB, bs, kvh, hd]
+        if s is not None:
+            p = (p.astype(jnp.float32)
+                 * s[tables][:, :, None, :, None]).astype(q.dtype)
+        p = p.reshape(R, MB * bs, kvh, hd)[rows]     # [T, ctx, kvh, hd]
+        return jnp.repeat(p, nh // kvh, axis=2).astype(jnp.float32)
+
+    s = jnp.einsum("thd,tchd->thc", q.astype(jnp.float32),
+                   pages(kc, ks)) / (hd ** 0.5)
+    mask = jnp.arange(MB * bs)[None, :] < lens[:, None]
+    p = jax.nn.softmax(jnp.where(mask[:, None, :], s, -1e30), axis=-1)
+    out = jnp.einsum("thc,tchd->thd", p, pages(vc, vs))
+    return np.asarray(jnp.where((lens > 0)[:, None, None], out, 0.0))
+
+
+def _tiled_case(kvh, nh, pool, seed=0):
+    """A mixed batch at head width 64 over a pool whose pages are out of
+    order: a 150-token prefill chunk (longer than one query tile), a
+    40-token continuation over a cached prefix of 600 (its context ends
+    mid-page and in its second chunk of pages), three decode rows (one
+    at the table's last position), padding tokens, three padding rows."""
+    rng = np.random.default_rng(seed)
+    nb, bs, hd, R, MB, T = 128, 16, 64, 8, 64, 256
+    positions = [range(150), range(600, 640), [77], [5], [1023]]
+    rows = [r for r, ps in enumerate(positions) for _ in ps]
+    lens = [p + 1 for ps in positions for p in ps]
+    pad = T - len(rows)
+    tables = np.zeros((R, MB), np.int32)
+    free = iter(rng.permutation(np.arange(1, nb)))
+    for r, ps in enumerate(positions):
+        for j in range(max(ps) // bs + 1):
+            tables[r, j] = next(free)
+    io = jnp.float32 if pool == "int8" else jnp.bfloat16
+
+    def one():
+        if pool == "int8":
+            return (jnp.asarray(rng.integers(-127, 128, (nb, bs, kvh, hd)),
+                                jnp.int8),
+                    jnp.asarray(rng.uniform(0.005, 0.03, (nb, kvh)),
+                                jnp.float32))
+        return jnp.asarray(rng.standard_normal((nb, bs, kvh, hd)), io), None
+
+    (kc, ks), (vc, vs) = one(), one()
+    q = jnp.asarray(rng.standard_normal((T, nh, hd)), io)
+    return dict(q=q, kc=kc, vc=vc, ks=ks, vs=vs, pad=pad,
+                rows=jnp.asarray(rows + [0] * pad, jnp.int32),
+                lens=jnp.asarray(lens + [0] * pad, jnp.int32),
+                tables=jnp.asarray(tables))
+
+
+TILED_CASES = [(2, 8, "bf16"), (4, 4, "bf16"), (2, 8, "int8"),
+               (4, 4, "int8")]
+
+
+@pytest.mark.parametrize("kvh,nh,pool", TILED_CASES)
+def test_tiled_kernel_matches_gather_reference(kvh, nh, pool):
+    from deepspeed_tpu.inference.v2.kernels.ragged_attention import \
+        kernel_variant
+    assert kernel_variant(64, kvh, pool == "int8") == "tiled"
+    c = _tiled_case(kvh, nh, pool)
+    out = np.asarray(jax.jit(functools.partial(
+        ragged_attention, variant="tiled"))(
+        c["q"], c["kc"], c["vc"], c["rows"], c["lens"], c["tables"],
+        k_scale=c["ks"], v_scale=c["vs"]), np.float32)
+    ref = _gather_reference(c["q"], c["kc"], c["vc"], c["rows"], c["lens"],
+                            c["tables"], c["ks"], c["vs"])
+    assert np.isfinite(out).all()
+    # bf16: the output's own rounding and p's before p.v; int8 pools are
+    # served in float32 here, where only the summation order differs
+    tol = 2e-2 if pool == "bf16" else 2e-5
+    np.testing.assert_allclose(out, ref, rtol=tol, atol=tol)
+    assert (out[-c["pad"]:] == 0.0).all()
+
+
+@pytest.mark.parametrize("kvh,nh,pool", TILED_CASES)
+def test_tiled_pure_decode_is_the_decode_kernel(kvh, nh, pool):
+    """One token a row through ``paged_attention()`` and through
+    ``ragged_attention()``: bit-equal, and both the reference's."""
+    c = _tiled_case(kvh, nh, pool, seed=1)
+    lens = jnp.asarray([150, 640, 78, 6, 1024, 0, 17, 513], jnp.int32)
+    tables = c["tables"].at[6, :2].set(jnp.asarray([3, 1])) \
+        .at[7, :33].set(jnp.arange(40, 73, dtype=jnp.int32))
+    q = c["q"][:8]
+    kw = dict(k_scale=c["ks"], v_scale=c["vs"], variant="tiled")
+    ragged = np.asarray(jax.jit(functools.partial(ragged_attention, **kw))(
+        q, c["kc"], c["vc"], jnp.arange(8, dtype=jnp.int32), lens, tables),
+        np.float32)
+    decode = np.asarray(jax.jit(functools.partial(paged_attention, **kw))(
+        q, c["kc"], c["vc"], tables, lens), np.float32)
+    np.testing.assert_array_equal(ragged, decode)
+    ref = _gather_reference(q, c["kc"], c["vc"],
+                            jnp.arange(8, dtype=jnp.int32), lens, tables,
+                            c["ks"], c["vs"])
+    tol = 2e-2 if pool == "bf16" else 2e-5
+    np.testing.assert_allclose(decode, ref, rtol=tol, atol=tol)
 
 
 # ---------------------------------------------------------------------------
