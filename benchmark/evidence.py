@@ -2,6 +2,8 @@
 ``Result`` it returns, and the ``Evidence`` the per-layer readers read.
 """
 
+import functools
+import importlib
 import shutil
 import time
 from dataclasses import dataclass, field
@@ -58,8 +60,60 @@ def program_bytes(compiled):
                + m.generated_code_size_in_bytes)
 
 
+# What a configuration's modules must offer, whichever block they are
+# of. A configuration's file names them under ``"reference"`` and
+# ``"weights"``: modules under ``benchmark/``, found by name as runners
+# and readers are (absent: ``reference`` and ``weights``, the OPT
+# block's).
+#
+# * ``reference.logits(params, fields, ids) -> [S, vocab]`` float32 of
+#   one sequence ``ids`` [S], every matmul under
+#   ``jax.default_matmul_precision("highest")``;
+#   ``reference.next_token_loss(params, fields, ids) -> float``, the mean
+#   next-token cross-entropy of that sequence;
+#   ``reference.check_supported(fields)`` raises where ``fields``
+#   describe another block than the module implements. It imports
+#   nothing of the program and reads ``params`` in the program's layout.
+# * ``weights.make(fields, seed[, dtype])``: the whole tree in the
+#   program's layout, on the device, in one jitted call of which the seed
+#   (any whole number, over 2**31 too) is an argument; ``dtype`` left out
+#   is the type the configuration is served in.
+CONTRACT = {"reference": ("logits", "next_token_loss", "check_supported"),
+            "weights": ("make",)}
+
+
+def config_module(config, key, config_name):
+    """The module that configuration ``config_name``'s file ``config``
+    names under ``key`` (``"reference"`` or ``"weights"``), held to
+    ``CONTRACT``. A module that is not there, or lacks what a runner
+    calls, is an error naming the configuration: there is no falling
+    back to another block's."""
+    name = config.get(key, key)
+    try:
+        module = importlib.import_module(f"benchmark.{name}")
+    except ImportError as e:
+        raise SystemExit(
+            f"benchmark: configuration {config_name} names {key} "
+            f"{name!r} and benchmark/{name.replace('.', '/')}.py does "
+            f"not import ({e})")
+    lacks = [f for f in CONTRACT[key] if not callable(
+        getattr(module, f, None))]
+    if lacks:
+        raise SystemExit(
+            f"benchmark: configuration {config_name}'s {key} module "
+            f"benchmark.{name} lacks {', '.join(lacks)} "
+            f"(benchmark/evidence.py says what it must offer)")
+    return module
+
+
 @dataclass
 class Context:
+    """What a runner is given. ``config`` is the configuration's file:
+    ``fields`` (the ``TransformerConfig`` it runs as; at ``--rehearse``
+    with the file's ``toy_fields`` laid on), and by name the modules
+    that hold its block: ``reference`` and ``weights`` (``CONTRACT``),
+    which a runner reaches through ``ctx.reference`` and
+    ``ctx.weights`` and imports neither by name."""
     cell: Dict[str, Any]
     config: Dict[str, Any]
     traffic: Dict[str, Any]
@@ -89,6 +143,18 @@ class Context:
     @property
     def fields(self):
         return self.config["fields"]
+
+    @functools.cached_property
+    def reference(self):
+        """The configuration's plain reference, which has to describe
+        the fields it is asked about."""
+        module = config_module(self.config, "reference", self.cell["config"])
+        module.check_supported(self.fields)
+        return module
+
+    @functools.cached_property
+    def weights(self):
+        return config_module(self.config, "weights", self.cell["config"])
 
     def model_config(self):
         """``TransformerConfig(**fields)``: a later model of the same

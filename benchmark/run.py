@@ -15,6 +15,18 @@ a new cell the metrics that are there by adding the cell's name to their
 ``workloads`` lists in ``BENCHMARK.json``; no file under this directory
 is edited for either.
 
+A configuration of another block than OPT's is files too. Its
+``configs/<config>.json`` names, beside ``fields``, the modules under
+this directory that hold the block: ``"reference"`` (its plain float32
+reference) and ``"weights"`` (its seeded weights), which the runners
+reach through the ``Context`` (``evidence.CONTRACT`` says what each
+must offer; absent, ``reference.py`` and ``weights.py``, the OPT
+block's); and ``"toy_fields"``, the widths ``--rehearse`` lays on its
+``fields`` (absent, ``TOY_FIELDS``). How far it is cut from its source
+it says under ``"published_as"``, ``"reduced"`` and ``"cuts"``, which
+``manifest.py`` holds to the floors of a cut: depth, experts held,
+vocabulary, a side module left out, and never a width.
+
 Without a TPU, or with fewer chips than the cell asks for, it exits
 non-zero naming what JAX found, and prints no result: there is no CPU
 fallback. ``--rehearse`` runs the same control flow at toy widths on
@@ -37,7 +49,8 @@ HERE = Path(__file__).resolve().parent
 REPO = HERE.parent
 REHEARSAL_BANNER = "REHEARSAL (cpu) -- toy widths, not a chip result"
 # the OPT block at toy widths for --rehearse: every width shrinks, the
-# block and the control flow stay
+# block and the control flow stay. The default of a configuration's own
+# ``toy_fields`` (``load_cell``), and read nowhere else
 TOY_FIELDS = dict(vocab_size=512, hidden_size=128, intermediate_size=512,
                   num_layers=2, num_heads=4, max_seq_len=256,
                   flash_min_seq=256)
@@ -61,14 +74,16 @@ def merge(base, over):
 
 def load_cell(name, rehearse=False):
     """(cell, config, traffic) for a cell name, rehearsal overrides laid
-    on where asked."""
+    on where asked: the cell's ``rehearse``, its ``rehearse_traffic``,
+    and on the configuration's ``fields`` its own ``toy_fields``."""
     cell = load_json("workloads", name)
     config = load_json("configs", cell["config"])
     traffic = load_json("traffic", cell["traffic"])
     if rehearse:
         cell = merge(cell, cell.get("rehearse", {}))
         traffic = merge(traffic, cell.get("rehearse_traffic", {}))
-        config = merge(config, {"fields": TOY_FIELDS})
+        config = merge(config, {"fields": config.get("toy_fields",
+                                                     TOY_FIELDS)})
     return cell, config, traffic
 
 

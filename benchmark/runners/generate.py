@@ -44,7 +44,7 @@ import time
 
 import numpy as np
 
-from .. import arith, arith_gen, reference, tracing, weights
+from .. import arith, arith_gen, tracing
 from ..evidence import Evidence, Result, TraceSlice, program_bytes
 
 # the program's spans a generation step opens (telemetry/trace.py),
@@ -189,7 +189,8 @@ def compare(ctx, probe_batch, probe_logits, finished):
     """The numbers ``correct`` rests on, each beside its limit.
     ``finished`` is [(batch, outs)] of the calls that finished inside
     the window. Makes the weights again from the seed (the benchmark's
-    own), runs the reference a row at a time."""
+    own), runs the configuration's reference a row at a time."""
+    reference, weights = ctx.reference, ctx.weights
     tr, limits = ctx.traffic, ctx.cell["limits"]
     n_check, plen = tr["check_rows"], tr["prompt_len"]
     params = weights.make(ctx.fields, ctx.seed)
@@ -219,6 +220,8 @@ def run(ctx):
     from deepspeed_tpu.telemetry import trace
 
     cell, tr = ctx.cell, ctx.traffic
+    # the configuration's own modules, found before set-up is spent
+    weights, _ = ctx.weights, ctx.reference
     ctx.part("import_program")
     cfg = ctx.model_config()
     params = weights.make(ctx.fields, ctx.seed, cell["engine"]["dtype"])

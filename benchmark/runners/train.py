@@ -26,7 +26,7 @@ import time
 
 import numpy as np
 
-from .. import arith, reference
+from .. import arith
 from ..evidence import Evidence, Result, TraceSlice, program_bytes
 
 # first-step loss, program (bf16 compute, chunked cross-entropy, flash
@@ -66,6 +66,7 @@ def run(ctx):
     from deepspeed_tpu.models import TransformerLM
 
     cell, tr = ctx.cell, ctx.traffic
+    reference = ctx.reference       # found before set-up is spent
     ctx.part("import_program")
     cfg = ctx.model_config()
     topo = None

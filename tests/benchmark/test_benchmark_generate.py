@@ -11,8 +11,9 @@ from pathlib import Path
 import numpy as np
 import pytest
 
-from benchmark import arith, arith_gen, control, tracing, weights
+from benchmark import arith, arith_gen, control, tracing
 from benchmark import run as harness
+from benchmark.evidence import config_module
 from benchmark.readers import hbm_roofline
 from benchmark.run import reported_by
 from benchmark.runners import generate as gen
@@ -166,7 +167,12 @@ def test_batches_and_weights_come_from_the_seed_large_ones_too():
     assert not (a[0] == a[1]).all() and not (a[0] == probe).all()
     c, _ = gen.make_batches(tr, 512, big + 1)
     assert not (a[0] == c[0]).all()
-    fields = dict(OPT_1_3B, **harness.TOY_FIELDS)
+    # the weights module the cell's configuration resolves, on the
+    # fields a rehearsal runs it at
+    config = harness.load_cell(CELL, rehearse=True)[1]
+    fields = config["fields"]
+    assert fields["hidden_size"] == 128 and fields["norm"] == "layernorm"
+    weights = config_module(config, "weights", "opt-1.3b")
     w = weights.make(fields, big)
     again = weights.make(fields, big)
     low = weights.make(fields, 7)          # the same low 31 bits
@@ -196,9 +202,12 @@ def test_the_generation_cell_is_one_chip_and_the_four_chip_quota_stands():
     cells = {w["name"]: w for w in BENCH["workloads"]}
     assert cells[CELL]["chips"] == 1
     assert cells[CELL]["config"] == "opt-1.3b"
-    assert [n for n, w in cells.items() if w["chips"] == 4] == \
-        ["opt-1.3b.zero3-dp4"]
-    assert {c["name"] for c in BENCH["configs"]} == {"opt-1.3b", "opt-125m"}
+    assert cells["opt-1.3b.zero3-dp4"]["chips"] == 4
+    assert cells["opt-125m.train-dense"]["chips"] == 1
+    # whatever cells a later PR adds, a quarter of them at most ask for
+    # four chips (``manifest.top_level`` holds the quota)
+    four = [n for n, w in cells.items() if w["chips"] == 4]
+    assert len(four) <= max(1, len(cells) // 4)
     assert BENCH["run_seconds"] == 40
 
 
@@ -206,22 +215,26 @@ def test_what_the_generation_cell_reports_and_what_the_others_do_not():
     assert reported_by(BENCH, CELL, "end_to_end") == ["setup_s", "gen_tok_s"]
     assert reported_by(BENCH, CELL, "per_layer") == GEN_METRICS
     e2e = {m["name"]: m for m in BENCH["end_to_end"]}
-    assert e2e["gen_tok_s"]["workloads"] == [CELL]
+    assert CELL in e2e["gen_tok_s"]["workloads"]
     assert e2e["gen_tok_s"]["unit"] == "tokens/s/chip"
     assert e2e["gen_tok_s"]["better"] == "higher"
-    assert 0.01 <= e2e["gen_tok_s"]["bound"] <= 0.05
     assert CELL not in e2e["train_tok_s"]["workloads"]
-    assert e2e["train_tok_s"]["bound"] == 0.01 and \
-        e2e["setup_s"]["bound"] == 0.1           # nothing loosened
+    # gen_tok_s: 0.02 since the check of PR 35 read the cell's runs
+    # spread by 0.17 % and 0.89 % of the median in two sets of one tree
+    # (PERF.md section 2: the machine pauses and the chip slows for
+    # seconds at a time, and a generate() call feels both)
+    assert (e2e["gen_tok_s"]["bound"], e2e["train_tok_s"]["bound"],
+            e2e["setup_s"]["bound"]) == (0.02, 0.01, 0.1)
     for m in BENCH["per_layer"]:
         if m["name"] in GEN_METRICS:
-            assert m["moves"] == "gen_tok_s" and m["workloads"] == [CELL]
-        else:
-            assert m["moves"] == "train_tok_s" and CELL not in m["workloads"]
-    for cell in ("opt-125m.train-dense", "opt-1.3b.zero3-dp4"):
-        assert "gen_tok_s" not in reported_by(BENCH, cell, "end_to_end")
-        assert not set(GEN_METRICS) & set(reported_by(BENCH, cell,
-                                                      "per_layer"))
+            assert m["moves"] == "gen_tok_s" and CELL in m["workloads"]
+    # no training cell reports a generation metric or gen_tok_s
+    gen_names = {"gen_tok_s"} | {m["name"] for m in BENCH["per_layer"]
+                                 if m["moves"] == "gen_tok_s"}
+    for cell in e2e["train_tok_s"]["workloads"]:
+        assert not gen_names & set(
+            reported_by(BENCH, cell, "end_to_end")
+            + reported_by(BENCH, cell, "per_layer")), cell
 
 
 def test_the_cell_file_says_the_traffic_the_engine_and_the_limits():

@@ -1,154 +1,70 @@
 """BENCHMARK.json and the files under benchmark/ say the same thing, in
 the characters and lengths the contract allows, every per-layer metric
-moves an end-to-end metric that its cells report, and which metrics a
-cell reports is said in one place."""
+moves an end-to-end metric that its cells report, which metrics a cell
+reports is said in one place, and a configuration is held to its source
+by what it may cut. The rules are ``benchmark/manifest.py``'s, functions
+of a root: here they are called on this repository, and in
+``test_benchmark_run.py`` on a copy that a configuration was added to."""
 
 import importlib
 import json
-import re
+import shutil
 from pathlib import Path
 
 import pytest
 
-from benchmark.run import reported_by
+from benchmark import manifest
+from benchmark.run import merge, reported_by
 
-ROOT = Path("benchmark")
-BENCH = json.loads(Path("BENCHMARK.json").read_text())
-NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
-UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
-SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
-
-
-def load(kind, name):
-    return json.loads((ROOT / kind / f"{name}.json").read_text())
-
-
-def names(kind):
-    return sorted(p.name[:-len(".json")]
-                  for p in (ROOT / kind).glob("*.json"))
-
-
-CELLS = [w["name"] for w in BENCH["workloads"]]
-METRICS = BENCH["end_to_end"] + BENCH["per_layer"]
+ROOT = Path(__file__).resolve().parents[2]
+BENCH = manifest.read(ROOT)
+FIXTURE = Path(__file__).resolve().parent / "fixtures" / "new_configuration"
 
 
 def test_top_level_keys_and_limits():
-    assert set(BENCH) == {"command", "paths", "run_seconds", "configs",
-                          "workloads", "end_to_end", "per_layer"}
-    assert BENCH["paths"] == ["benchmark", "tests/benchmark"]
-    assert BENCH["command"] == ["python3", "benchmark/run.py"]
-    assert isinstance(BENCH["run_seconds"], int)
-    assert 1 <= BENCH["run_seconds"] <= 51
-    assert len(Path("BENCHMARK.json").read_bytes()) <= 64 * 1024
-    assert 1 <= len(BENCH["workloads"]) <= 24
-    four = [w for w in BENCH["workloads"] if w["chips"] == 4]
-    assert len(four) <= max(1, len(BENCH["workloads"]) // 4)
-    setup = [m for m in BENCH["end_to_end"] if m["name"] == "setup_s"]
-    assert len(setup) == 1 and setup[0]["bound"] <= 0.1
-    assert all(0.01 <= m["bound"] <= 0.1 for m in BENCH["end_to_end"])
+    manifest.top_level(ROOT)
 
 
-@pytest.mark.parametrize("entry", BENCH["configs"] + BENCH["workloads"]
-                         + METRICS, ids=lambda e: e["name"])
-def test_names_units_and_lines(entry):
-    assert NAME.match(entry["name"]), entry["name"]
-    for key in ("config", "traffic", "moves"):
-        if key in entry:
-            assert NAME.match(entry[key]), entry[key]
-    if "unit" in entry:
-        assert UNIT.match(entry["unit"]), entry["unit"]
-        assert entry["better"] in ("lower", "higher")
-        assert entry["source"] in SOURCES
-    for key in ("why", "layer", "source"):
-        if key in entry:
-            v = entry[key]
-            assert 1 <= len(v) <= 200 and "\n" not in v and "\t" not in v
-    allowed = {"name", "source", "file", "reduced", "why"} \
-        if "file" in entry else \
-        {"name", "config", "traffic", "chips", "why"} \
-        if "traffic" in entry else \
-        {"name", "unit", "better", "bound", "source", "workloads"} \
-        if "bound" in entry else \
-        {"name", "unit", "better", "source", "layer", "moves", "workloads"}
-    assert set(entry) <= allowed, set(entry) - allowed
+@pytest.mark.parametrize("kind,entry", manifest.entries(BENCH),
+                         ids=lambda v: v["name"] if isinstance(v, dict)
+                         else v)
+def test_names_units_and_lines(kind, entry):
+    manifest.entry(kind, entry)
+    for bad in ({"note": "one more key"}, {"name": "two words"},
+                {"name": "a/b"}, {"why": "two\nlines"}, {"why": ""}):
+        with pytest.raises(manifest.Refused):
+            manifest.entry(kind, dict(entry, **bad))
 
 
 def test_no_two_entries_share_a_name():
-    for group in (BENCH["configs"], BENCH["workloads"], METRICS):
-        ns = [e["name"] for e in group]
-        assert len(ns) == len(set(ns))
-    pairs = [(w["config"], w["traffic"]) for w in BENCH["workloads"]]
-    assert len(pairs) == len(set(pairs))
+    manifest.unique_names(ROOT)
 
 
 def test_every_file_under_benchmark_is_named_and_loads():
-    for p in ROOT.rglob("*"):
-        if "__pycache__" in p.parts or p.suffix == ".pyc":
-            continue
-        assert re.match(r"^[A-Za-z0-9_.\-/]+$", str(p)), p
-        if p.suffix == ".json":
-            json.loads(p.read_text())
-    for mod in sorted((ROOT / "readers").glob("*.py")) + \
-            sorted((ROOT / "runners").glob("*.py")):
-        if mod.stem != "__init__":
-            importlib.import_module(
-                f"benchmark.{mod.parent.name}.{mod.stem}")
-    for mod in ("arith", "tracing", "reference", "evidence", "run"):
-        importlib.import_module(f"benchmark.{mod}")
+    modules = manifest.files(ROOT)
+    assert {"benchmark.run", "benchmark.manifest", "benchmark.control",
+            "benchmark.runners.generate"} <= set(modules)
+    for mod in modules:         # every one, whatever a later PR adds
+        importlib.import_module(mod)
 
 
 def test_configs_mirror_their_files():
-    assert {c["name"] for c in BENCH["configs"]} == set(names("configs"))
-    assert {c["name"] for c in BENCH["configs"]} == \
-        {w["config"] for w in BENCH["workloads"]}
-    from deepspeed_tpu.models.transformer import TransformerConfig
+    manifest.configs(ROOT)
+    # the configurations that are there are not cut, name no module and
+    # bring no toy widths: they take every default
     for c in BENCH["configs"]:
-        f = json.loads(Path(c["file"]).read_text())
-        assert c["file"] == f"benchmark/configs/{c['name']}.json"
-        assert f["source"] == c["source"] and f["reduced"] == c["reduced"]
-        cfg = TransformerConfig(**f["fields"])      # a file, no code
-        pub = f["published"]
-        # no width differs from the published config
-        assert cfg.hidden_size == pub["hidden_size"]
-        assert cfg.intermediate_size == pub["ffn_dim"]
-        assert cfg.num_heads == pub["num_attention_heads"]
-        assert cfg.num_layers == pub["num_hidden_layers"]
-        assert cfg.vocab_size == pub["vocab_size"]
-        assert cfg.max_seq_len == pub["max_position_embeddings"]
+        f = manifest.load(ROOT, "configs", c["name"])
+        assert c["reduced"] == [] and not {
+            "cuts", "published_as", "reference", "weights",
+            "toy_fields"} & set(f), c["name"]
 
 
 def test_cells_mirror_their_files_and_the_reverse():
-    assert CELLS == sorted(CELLS, key=CELLS.index)
-    assert set(CELLS) == set(names("workloads"))
-    for w in BENCH["workloads"]:
-        f = load("workloads", w["name"])
-        assert w["name"] == f"{w['config']}.{w['traffic']}"
-        for key in ("config", "traffic", "chips", "why"):
-            assert f[key] == w[key], (w["name"], key)
-        traffic = load("traffic", f["traffic"])
-        runner = ROOT / "runners" / f"{traffic['runner']}.py"
-        assert runner.is_file(), runner
-        e2e = reported_by(BENCH, w["name"], "end_to_end")
-        assert "setup_s" in e2e and len(e2e) >= 2
-        assert reported_by(BENCH, w["name"], "per_layer"), \
-            "a cell reports at least one layer metric"
-    assert {w["traffic"] for w in BENCH["workloads"]} == \
-        set(names("traffic"))
-
-
-def reported_in(metric):
-    return metric.get("workloads", CELLS)
+    manifest.cell_files(ROOT)
 
 
 def test_which_metrics_a_cell_reports_is_said_once():
-    """``BENCHMARK.json`` says it (``run.reported_by``), the driver reads
-    it there, and a cell file that said it again could disagree."""
-    for cell in CELLS:
-        assert not {"end_to_end", "per_layer"} & set(load("workloads", cell))
-        for kind in ("end_to_end", "per_layer"):
-            assert reported_by(BENCH, cell, kind) == [
-                m["name"] for m in BENCH[kind] if cell in reported_in(m)]
-    assert all("workloads" in m for m in BENCH["per_layer"])
+    manifest.reported_once(ROOT)
 
 
 def test_a_per_layer_metric_names_its_cells_and_they_report_its_moves():
@@ -180,29 +96,140 @@ def test_a_per_layer_metric_names_its_cells_and_they_report_its_moves():
 
 
 def test_metrics_mirror_their_spec_files():
-    assert {m["name"] for m in BENCH["per_layer"]} == \
-        set(names("layer_metrics"))
-    for m in BENCH["per_layer"]:
-        f = load("layer_metrics", m["name"])
-        for key in ("unit", "better", "source", "layer", "moves"):
-            assert f[key] == m[key], (m["name"], key)
-        assert (ROOT / "readers" / f"{f['reader']}.py").is_file()
-    assert all(m["source"] == "host_clock" for m in BENCH["end_to_end"])
+    manifest.metric_files(ROOT)
 
 
 def test_every_moves_is_reported_wherever_the_metric_is():
-    e2e = {m["name"]: m for m in BENCH["end_to_end"]}
-    for m in BENCH["per_layer"]:
-        assert m["moves"] in e2e and m["moves"] != "setup_s", m["name"]
-        for cell in reported_in(m):
-            assert cell in reported_in(e2e[m["moves"]]), (m["name"], cell)
+    manifest.moves(ROOT)
 
 
 def test_one_layer_one_spelling_and_perf_md_lists_it():
-    layers = {m["layer"] for m in BENCH["per_layer"]}
-    perf = Path("PERF.md").read_text()
-    for layer in layers:
-        assert layer in perf, f"PERF.md's list of layers lacks {layer!r}"
-    for m in BENCH["per_layer"]:
-        if m["name"].endswith("_roofline"):
-            assert m["unit"] == "%"
+    manifest.layers(ROOT)
+
+
+# ---------------------------------------------------------------------------
+# what a configuration may cut from its source, and how far
+# ---------------------------------------------------------------------------
+DEPLOYED = "the layers left out lie on the pipeline's further stages"
+
+
+def depth(here, lead=0, period=1, **more):
+    return {"reduced": ["num_hidden_layers"], "fields": {"num_layers": here},
+            "cuts": {"num_hidden_layers": {**dict(
+                kind="depth", published=16, here=here, leading_dense=lead,
+                period=period, deployment=DEPLOYED), **more}}}
+
+
+def experts(here, chips, published=64):
+    """The fixture with an expert layer: ``published`` routed experts,
+    ``here`` of them held where ``chips`` chips share a layer."""
+    return {"reduced": ["num_hidden_layers", "n_routed_experts"],
+            "published": {"n_routed_experts": published},
+            "published_as": {"moe_num_experts": "n_routed_experts"},
+            "fields": {"moe_num_experts": here},
+            "cuts": {"n_routed_experts": dict(
+                kind="experts", published=published, here=here,
+                shared_over_chips=chips, deployment=f"a layer's experts "
+                f"over {chips} chips")}}
+
+
+def vocabulary(here):
+    return {"reduced": ["num_hidden_layers", "vocab_size"],
+            "fields": {"vocab_size": here},
+            "cuts": {"vocab_size": dict(
+                kind="vocabulary", published=32000, here=here,
+                deployment="the head and the embedding over eight chips")}}
+
+
+def module(**said):
+    """A published side module that is not loaded: no field of the
+    program stands for it."""
+    return {"reduced": ["num_hidden_layers", "num_nextn_predict_layers"],
+            "published": {"num_nextn_predict_layers": 1},
+            "cuts": {"num_nextn_predict_layers": dict(
+                kind="module", published=1, here=0, deployment="a server "
+                "that does not draft from the prediction head", **said)}}
+
+
+LEFT_OUT = dict(left_out="the multi-token-prediction module",
+                why="a fifth expert layer that no served token passes")
+CUTS = {
+    # what stands, as the fixture has it and as the guide's examples do
+    "as_the_fixture_stands": ({}, None),
+    "one_leading_dense_and_four": (depth(5, lead=1), None),
+    "a_whole_period_of_six": (depth(7, lead=1, period=6), None),
+    "eight_experts_of_64_over_8_chips": (experts(8, 8), None),
+    "an_eighth_of_the_vocabulary": (vocabulary(4000), None),
+    "a_side_module_left_out": (module(**LEFT_OUT), None),
+    # what is refused
+    "reduced_with_no_cuts_entry": ({"cuts": None}, "cuts explains"),
+    "a_cuts_entry_not_in_reduced": (
+        {"cuts": {"vocab_size": depth(4)["cuts"]["num_hidden_layers"]}},
+        "cuts explains"),
+    "a_kind_outside_the_four": (depth(4, kind="heads"), "a cut is one of"),
+    "three_layers": (depth(3), "3 layers are under the 0 leading dense "
+                     "and 4 that follow"),
+    "four_layers_one_of_them_dense": (depth(4, lead=1), "are under"),
+    "less_than_a_period": (depth(6, lead=1, period=6), "a whole period"),
+    "depth_without_its_pattern": (depth(4, period=None),
+                                  "states leading_dense and period"),
+    "four_experts_held": (experts(4, 16), "4 experts held are under 8"),
+    "experts_that_do_not_add_up": (experts(8, 4), "are not the source's"),
+    "experts_over_unstated_chips": (experts(8, None),
+                                    "states shared_over_chips"),
+    "under_an_eighth_of_the_vocabulary": (vocabulary(3999),
+                                          "under an eighth"),
+    "a_module_without_a_reason": (module(left_out="the head"),
+                                  "says what .* and why"),
+    "no_deployment": (depth(4, deployment=""), "what deployment"),
+    "a_cut_that_says_other_numbers": (depth(4, published=12),
+                                      "the cut says 12 -> 4"),
+    "a_cut_that_cuts_nothing": (
+        {"published": {"num_hidden_layers": 4},
+         "cuts": {"num_hidden_layers": {"published": 4}}}, "cuts nothing"),
+    "a_width_that_differs": ({"fields": {"intermediate_size": 2816}},
+                             "reduced does not list it"),
+    "a_width_listed_as_a_cut": (
+        {"reduced": ["num_hidden_layers", "intermediate_size"],
+         "fields": {"intermediate_size": 2816},
+         "cuts": {"intermediate_size": dict(
+             kind="depth", published=5632, here=2816, leading_dense=0,
+             period=1, deployment=DEPLOYED)}}, "is a width, and never cut"),
+    "fewer_heads": ({"fields": {"num_heads": 16}},
+                    "reduced does not list it"),
+    "a_map_without_the_vocabulary": (
+        {"published_as": {"vocab_size": None}}, "published_as maps"),
+    "a_reference_that_is_not_there": ({"reference": "reference_lost"},
+                                      "reference names"),
+    "toy_fields_the_program_does_not_have": (
+        {"toy_fields": {"hidden": 64}}, "unexpected keyword"),
+}
+
+
+def drop_nones(d):
+    return {k: drop_nones(v) if isinstance(v, dict) else v
+            for k, v in d.items() if v is not None}
+
+
+@pytest.mark.parametrize("case", CUTS)
+def test_a_cut_is_held_to_its_floor(tmp_path, case):
+    """``manifest.config`` on the fixture configuration with ``change``
+    laid on (a None takes the key out): the floors of the
+    ``model-configs`` guide's section 4, each from both sides."""
+    change, refused = CUTS[case]
+    shutil.copytree(FIXTURE, tmp_path / "benchmark")
+    path = tmp_path / "benchmark" / "configs" / "rope-gqa.json"
+    f = drop_nones(merge(manifest.load(tmp_path, "configs", "rope-gqa"),
+                         change))
+    f.setdefault("cuts", {})
+    path.write_text(json.dumps(f))
+    entry = {"name": "rope-gqa", "file": "benchmark/configs/rope-gqa.json",
+             "source": f["source"], "reduced": f["reduced"]}
+    if refused is None:
+        manifest.config(tmp_path, entry)
+    elif refused == "unexpected keyword":
+        with pytest.raises(TypeError, match=refused):
+            manifest.config(tmp_path, entry)
+    else:
+        with pytest.raises(manifest.Refused, match=refused):
+            manifest.config(tmp_path, entry)
