@@ -38,6 +38,8 @@ class InferenceEngine:
 
     def __init__(self, model, config: DeepSpeedInferenceConfig, params=None):
         self.module = self.model = model
+        if hasattr(getattr(model, "cfg", None), "refuse_served_only"):
+            model.cfg.refuse_served_only("the v1 inference engine")
         self.config = config
         self.dtype = DTYPES[config.dtype]
         tp = config.tensor_parallel.tp_size

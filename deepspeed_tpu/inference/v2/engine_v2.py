@@ -42,7 +42,7 @@ from ...telemetry import trace, watchdog
 from ...utils.bucketing import ceil_bucket, pow2_bucket
 from ...utils.logging import log_dist
 from .config_v2 import RaggedInferenceEngineConfig
-from .kernels.ragged_attention import kernel_variant
+from .kernels.ragged_attention import LATENT, kernel_variant
 from .paged_model import (init_lora_bank, init_paged_kv_cache,
                           paged_continue, paged_decode, paged_decode_window,
                           paged_prefill, paged_ragged_step,
@@ -145,9 +145,14 @@ class InferenceEngineV2:
             # serving uses the k-generic sorted-token grouped GEMM
             # (dropless_topk_dispatch) with renormalized top-k weights —
             # the Mixtral/Qwen-MoE/DBRX convention — so any k serves.
-            assert cfg.moe_top_k <= 2, \
-                f"expert-parallel serving is top-1/top-2 only " \
-                f"(got moe_top_k={cfg.moe_top_k}); serve top-k>2 at ep=1"
+            assert cfg.moe_top_k <= 2 and cfg.served_only is None, \
+                f"expert-parallel serving is top-1/top-2 only and " \
+                f"routes as training does (softmax scores, no selection " \
+                f"bias, scale or shared expert; got moe_top_k=" \
+                f"{cfg.moe_top_k}, {cfg.served_only}); serve top-k>2 " \
+                f"and the deployed expert layer at ep=1"
+        if cfg.attention == "mla":
+            self._refuse_for_latent(config)
         sm = config.state_manager
         if sm.max_seq_len > cfg.max_seq_len:
             sm.max_seq_len = cfg.max_seq_len
@@ -175,7 +180,15 @@ class InferenceEngineV2:
             lambda s: NamedSharding(self.mesh, s), specs,
             is_leaf=lambda x: isinstance(x, P)) if specs is not None else None)
 
-        if params is not None:
+        if params is not None and tp * ep == 1 and all(
+                isinstance(x, jax.Array) and x.dtype == self.dtype
+                for x in jax.tree.leaves(params)):
+            # already what the cast below would give: taken as they are
+            # (an equivalent sharding on the one device moves no bytes).
+            # A copy of an 11 GB tree does not fit beside it on a chip
+            self.params = (params if self.param_sharding is None else
+                           jax.device_put(params, self.param_sharding))
+        elif params is not None:
             cast = jax.jit(lambda p: jax.tree.map(
                 lambda x: jnp.asarray(x, self.dtype), p),
                 out_shardings=self.param_sharding)
@@ -282,9 +295,12 @@ class InferenceEngineV2:
         # /statusz (health.attention_impl), never by trying a compile
         use_kernel = (config.use_paged_kernel and tp == 1 and ep == 1
                       and cfg.positional != "alibi")
+        # a latent pool (attention='mla') has one kernel, whatever its
+        # widths: kernels/ragged_attention.latent_attention
         self.attention_impl = (
-            "pallas:" + kernel_variant(cfg.head_dim, cfg.kv_heads,
-                                       bool(config.kv_quant))
+            "pallas:" + (LATENT if cfg.attention == "mla" else
+                         kernel_variant(cfg.head_dim, cfg.kv_heads,
+                                        bool(config.kv_quant)))
             if use_kernel else "jnp:gather")
         topo = self.topology if ep > 1 else None
         # load_draft_model builds jits after __init__; it reuses the
@@ -310,11 +326,10 @@ class InferenceEngineV2:
             # greedy variant for the generate() hot loop: argmax on device
             # so the per-token host transfer is [N] int32, not [N, vocab]
             # (the reference's sampler also runs device-side)
-            logits, c = paged_decode(cfg, p, t, pos, bt, c, a,
-                                     sm.block_size,
-                                     use_kernel=use_kernel,
-                                     topo=topo, lora=lb, adapter_ids=aid)
-            return jnp.argmax(logits, axis=-1).astype(jnp.int32), c
+            logits, *moe, c = paged_decode(
+                cfg, p, t, pos, bt, c, a, sm.block_size,
+                use_kernel=use_kernel, topo=topo, lora=lb, adapter_ids=aid)
+            return (jnp.argmax(logits, axis=-1).astype(jnp.int32), *moe, c)
 
         self._decode_tok_jit = watchdog.watch(
             "decode_greedy", jax.jit(_decode_tok, donate_argnums=(4,)))
@@ -326,13 +341,12 @@ class InferenceEngineV2:
             # Per-ROW keys (stable row seed + generated-token index) so
             # the stream matches the fused window path bit-for-bit
             from .sampling import fold_in_rows, sample_tokens_rowwise
-            logits, c = paged_decode(cfg, p, t, pos, bt, c, a,
-                                     sm.block_size,
-                                     use_kernel=use_kernel,
-                                     topo=topo, lora=lb, adapter_ids=aid)
+            logits, *moe, c = paged_decode(
+                cfg, p, t, pos, bt, c, a, sm.block_size,
+                use_kernel=use_kernel, topo=topo, lora=lb, adapter_ids=aid)
             keys = fold_in_rows(rng, seeds, gidx)
-            return sample_tokens_rowwise(logits, keys, temp, topp,
-                                         topk), c
+            return (sample_tokens_rowwise(logits, keys, temp, topp, topk),
+                    *moe, c)
 
         self._decode_sample_jit = watchdog.watch(
             "decode_sample", jax.jit(_decode_sample, donate_argnums=(4,)))
@@ -431,16 +445,18 @@ class InferenceEngineV2:
             # the capacity win, as a live gauge: pool bytes the int8
             # layout frees vs the same (num_blocks x block_size) pool at
             # the serving dtype
-            unquant = 2 * (cfg.num_layers * sm.num_blocks * sm.block_size
-                           * cfg.kv_heads * cfg.head_dim
-                           * jnp.dtype(self.dtype).itemsize)
-            quant = sum(int(np.prod(v.shape)) * v.dtype.itemsize
-                        for v in self.kv_cache.values())
+            pool = jax.eval_shape(lambda: init_paged_kv_cache(
+                cfg, sm.num_blocks, sm.block_size, self.dtype))
+            unquant, quant = (
+                sum(int(np.prod(v.shape)) * v.dtype.itemsize
+                    for v in c.values())
+                for c in (pool, self.kv_cache))
             self._m_kv_quant_saved.set(max(unquant - quant, 0))
         try:  # HBM accounting (telemetry/memory.py): the two big
             # long-lived buffers every decode program references
-            ds_memory.record_buffer("kv_pool",
-                                    ds_memory.tree_bytes(self.kv_cache))
+            ds_memory.record_buffer(
+                "latent_pool" if cfg.attention == "mla" else "kv_pool",
+                ds_memory.tree_bytes(self.kv_cache))
             ds_memory.record_buffer("params",
                                     ds_memory.tree_bytes(self.params))
         except Exception:  # accounting must never block serving
@@ -451,12 +467,68 @@ class InferenceEngineV2:
             f" ep={ep} attention={self.attention_impl}",
             ranks=[0])
 
+    @staticmethod
+    def _refuse_for_latent(config):
+        """What this engine does not do for an attention='mla' model,
+        said at construction rather than run wrong."""
+        sm = config.state_manager
+        refused = {
+            "tensor_parallel_size > 1 (the latent projections and the "
+            "kernel are written for one device)":
+                config.tensor_parallel_size > 1,
+            "expert_parallel_size > 1 (the expert layer as deployed is "
+            "served at ep = 1)": config.expert_parallel_size > 1,
+            "quant_bits (the quantiser does not know the two stacks, and "
+            "the expert stack is read whole)": bool(config.quant_bits),
+            "max_lora_adapters (LoRA targets wq / wv, which the latent "
+            "projections replace)": config.max_lora_adapters > 0,
+            "enable_prefix_caching (the prefix index has not been shown "
+            "to share latent blocks)": sm.enable_prefix_caching,
+            "enable_kv_spill (the spill tier moves k / v leaves)":
+                sm.enable_kv_spill,
+            "ragged_attention 'off' (the stitched prefill / continue "
+            "programs have no latent form)":
+                config.ragged_attention == "off"}
+        bad = [what for what, on in refused.items() if on]
+        if bad:
+            raise NotImplementedError(
+                "attention='mla' is served without: " + "; ".join(bad))
+
     # ------------------------------------------------------------------
     # Telemetry (unified registry, telemetry/registry.py)
     # ------------------------------------------------------------------
+    def _note_moe(self, program: str, stats=None) -> None:
+        """What the launch's expert layers routed (the latent programs'
+        middle output, ``paged_model._latent_step``, fetched with the
+        launch's own result) into the registry's counters."""
+        if stats is None:
+            return
+        launches, rows, touched, share = (float(v) for v in stats)
+        self._m_moe_launches.labels(program=program).inc(launches)
+        self._m_moe_rows.labels(program=program).inc(rows)
+        self._m_moe_touched.labels(program=program).inc(touched)
+        self._m_moe_share.labels(program=program).set(share)
+
     def _init_telemetry(self):
         from ...telemetry import get_registry
         reg = get_registry()
+        self._m_moe_launches = reg.counter(
+            "moe_launches_total",
+            "expert-layer passes run by the latent block's programs (a "
+            "ragged step: one an expert layer; a decode window: one an "
+            "expert layer and step)", labelnames=("program",))
+        self._m_moe_rows = reg.counter(
+            "moe_routed_rows_total",
+            "rows routed to experts (valid tokens x top-k), summed over "
+            "expert layers and launches", labelnames=("program",))
+        self._m_moe_touched = reg.counter(
+            "moe_experts_touched_total",
+            "distinct experts with at least one row, summed over expert "
+            "layers and launches", labelnames=("program",))
+        self._m_moe_share = reg.gauge(
+            "moe_fullest_expert_share",
+            "the fullest expert's share of an expert layer's rows, the "
+            "largest of the last launch", labelnames=("program",))
         self._m_prefill_tokens = reg.counter(
             "inference_prefill_tokens_total",
             "prompt tokens run through prefill/continuation passes")
@@ -1024,6 +1096,10 @@ class InferenceEngineV2:
         init (tests); production passes the trained draft weights."""
         dcfg = model.cfg
         cfg = self.model.cfg
+        if "mla" in (cfg.attention, dcfg.attention):
+            raise NotImplementedError(
+                "draft-model speculation with an attention='mla' target "
+                "or draft: the verify pass has no latent form")
         if dcfg.vocab_size != cfg.vocab_size:
             raise DraftModelMismatchError(
                 f"draft vocab_size {dcfg.vocab_size} != target "
@@ -1315,11 +1391,13 @@ class InferenceEngineV2:
             aid = (self._pad_i32(active.shape[0],
                                  [self._adapter_slot_of(u) for u in uids])
                    if lb is not None else None)
-            vals, self.kv_cache = jit_fn(
+            vals, *moe, self.kv_cache = jit_fn(
                 self.params, toks, pos, tables, self.kv_cache, active,
                 lb, aid)
-            vals = np.asarray(vals)  # blocks: the pass completes here
+            # blocks: the pass completes here
+            vals, moe = jax.device_get((vals, moe))
         self._m_host_syncs.inc()
+        self._note_moe("decode_step", *moe)
         dt = time.perf_counter() - t0
         self._m_decode_steps.inc()
         self._m_decode_tokens.inc(len(uids))
@@ -1406,11 +1484,13 @@ class InferenceEngineV2:
             aid = (self._pad_i32(N, [self._adapter_slot_of(u)
                                      for u in uids])
                    if lb is not None else None)
-            out, self.kv_cache = run(
+            out, *moe, self.kv_cache = run(
                 jnp.asarray(toks), jnp.asarray(pos), jnp.asarray(tables),
                 self._pad_i32(N, steps_left), jnp.asarray(eos), lb, aid)
-            out = np.asarray(out)   # ONE transfer for the whole window
+            # ONE transfer for the whole window
+            out, moe = jax.device_get((out, moe))
         self._m_host_syncs.inc()
+        self._note_moe("decode_window", *moe)
         dt = time.perf_counter() - t0
         log_tokens = sm.config.enable_prefix_caching
         emitted: Dict[int, List[int]] = {}
@@ -1541,7 +1621,7 @@ class InferenceEngineV2:
                         tokens=rb.total_tokens,
                         uids=[u for u, _ in entries],
                         **self._trace_attrs(u for u, _ in entries)):
-            logits, self.kv_cache = self._ragged_jit(
+            logits, *moe, self.kv_cache = self._ragged_jit(
                 self.params, jnp.asarray(rb.ids),
                 jnp.asarray(rb.row_ids), jnp.asarray(rb.positions),
                 jnp.asarray(rb.lengths), jnp.asarray(rb.write_blocks),
@@ -1551,7 +1631,9 @@ class InferenceEngineV2:
                 self.lora_bank,
                 (jnp.asarray(rb.adapter_slots)
                  if self.lora_bank is not None else None))
-            logits = np.asarray(logits)  # blocks: the pass completes here
+            # blocks: the pass completes here
+            logits, moe = jax.device_get((logits, moe))
+        self._note_moe("ragged_step", *moe)
         dt = time.perf_counter() - t0
         log_tokens = sm.config.enable_prefix_caching
         for uid, toks in entries:
@@ -1786,6 +1868,10 @@ class InferenceEngineV2:
         assert not (speculative and sampling), \
             "speculative decoding is greedy-only (draft verification " \
             "compares argmax)"
+        if speculative and self.model.cfg.attention == "mla":
+            raise NotImplementedError(
+                "speculative decoding of an attention='mla' model: the "
+                "verify pass has no latent form")
         # each generate() call is an independent request batch: spec
         # cold-streaks (and draft indexes) from earlier calls must not
         # leak into this one

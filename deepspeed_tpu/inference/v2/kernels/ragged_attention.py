@@ -95,6 +95,8 @@ from ....utils.bucketing import pow2_bucket
 NEG_INF = -1e30
 
 VARIANTS = ("tiled", "pipelined")
+# the latent pool's one variant, as ``engine.attention_impl`` names it
+LATENT = "latent"
 
 # the tiled kernel's VMEM: two slots of K and V chunks, the query and
 # output tiles twice, the float32 state — 12 MB at OPT-1.3B's geometry,
@@ -216,7 +218,10 @@ def tiled_geometry(head_dim: int, kv_heads: int):
     None where a page's ``kv_heads * head_dim`` row does not split into
     whole 128-lane blocks of whole heads. A block is one head (width a
     multiple of 128) or the ``128 // head_dim`` heads that share 128
-    lanes."""
+    lanes. A LATENT pool (attention='mla') is not asked here: its page
+    row is one row that every head reads whole, whatever its width (576
+    is four and a half lane blocks), and :func:`latent_attention` serves
+    it."""
     if (kv_heads * head_dim) % 128:
         return None
     if head_dim % 128 == 0:
@@ -224,6 +229,94 @@ def tiled_geometry(head_dim: int, kv_heads: int):
     if 128 % head_dim == 0:
         return 128, 128 // head_dim
     return None
+
+
+def _visible(tl_ref, t0, first, last, c, tq, reps, P):
+    """``(reps * tq, P)``: which of chunk c's positions each query row
+    of the tile may attend. Query rows are ``reps`` copies of the tile's
+    tokens; a token outside [first, last] (another row's) sees nothing,
+    one inside sees the positions under its own causal bound
+    (``tl_ref``, (tq, 1))."""
+    tok = t0 + jax.lax.broadcasted_iota(jnp.int32, (tq, 1), 0)
+    eff = jnp.where((tok >= first) & (tok <= last), tl_ref[...], 0)
+    eff = jnp.concatenate([eff] * reps, axis=0)
+    return c * P + jax.lax.broadcasted_iota(
+        jnp.int32, (reps * tq, P), 1) < eff
+
+
+def _walk_rows(len_ref, bt_ref, first_ref, last_ref, lo, hi, *, t0, tq, bs,
+               cp, copies, compute, chunk_copies=None):
+    """The walk the tiled and the latent kernel share: the tile of flat
+    tokens [t0, t0 + tq) visits the rows ``lo..hi`` that own its tokens,
+    a row's pages in chunks of ``cp`` up to the causal bound of the
+    row's last token in the tile — no page past it is copied or
+    visited. ``copies(page, slot, j)`` are the DMAs that bring pool page
+    ``page`` to place j of VMEM slot ``slot`` (``chunk_copies(r, c,
+    slot)``: those a whole chunk needs besides); the next chunk, of this
+    row or the next, is in flight while ``compute(first, last, c,
+    slot)`` runs on this one ([first, last]: the row's tokens in the
+    tile)."""
+    P, T = cp * bs, len_ref.shape[0]
+
+    def bounds(r):
+        """Row r's tokens in this tile [first, last] and the causal bound
+        of the last of them (0: the row has no token here)."""
+        r = jnp.minimum(r, first_ref.shape[0] - 1)   # hi + 1 is asked too
+        first = jnp.maximum(first_ref[r], t0)
+        last = jnp.minimum(last_ref[r], t0 + tq - 1)
+        kv = jnp.where(first <= last, len_ref[jnp.clip(last, 0, T - 1)], 0)
+        return first, last, kv
+
+    def n_chunks(r):
+        return (bounds(r)[2] + P - 1) // P
+
+    def next_row(r):
+        return jax.lax.while_loop(
+            lambda r: (r <= hi) & (n_chunks(r) == 0), lambda r: r + 1, r)
+
+    def pages(r, c):
+        return jnp.minimum((bounds(r)[2] + bs - 1) // bs - c * cp, cp)
+
+    def each_copy(r, c, slot, act):
+        """``act`` (start or wait) on every copy of chunk c of row r."""
+        def one(j, _):
+            for dma in copies(bt_ref[r, c * cp + j], slot, j):
+                act(dma)
+            return 0
+        jax.lax.fori_loop(0, pages(r, c), one, 0)
+        if chunk_copies is not None:
+            for dma in chunk_copies(r, c, slot):
+                act(dma)
+
+    def start(r, c, slot):
+        each_copy(r, c, slot, lambda dma: dma.start())
+
+    def wait(r, c, slot):
+        each_copy(r, c, slot, lambda dma: dma.wait())
+
+    r0 = next_row(lo)
+
+    @pl.when(r0 <= hi)
+    def _first():
+        start(r0, 0, 0)
+
+    def step(state):
+        r, c, slot = state
+        last = c + 1 >= n_chunks(r)
+        nr = jax.lax.cond(last, lambda: next_row(r + 1), lambda: r)
+        nc = jnp.where(last, 0, c + 1)
+
+        @pl.when(nr <= hi)
+        def _prefetch():
+            start(nr, nc, 1 - slot)
+
+        wait(r, c, slot)
+        first, last_tok, _ = bounds(r)
+        compute(first, last_tok, c, slot)
+        return nr, nc, 1 - slot
+
+    jax.lax.while_loop(lambda state: state[0] <= hi, step,
+                       (r0, jnp.int32(0), jnp.int32(0)))
 
 
 def _tile_update(q, k, v, visible, acc_sc, m_sc, l_sc, b, *, scale):
@@ -273,7 +366,7 @@ def _tiled_kernel(len_ref, bt_ref, first_ref, last_ref, lo_ref, hi_ref,
         o_ref, k_buf, v_buf, acc_sc, m_sc, l_sc, sem = rest
     nblk, rpb = q_ref.shape[:2]
     bw = q_ref.shape[3]
-    M, P, T = rpb * tq, cp * bs, len_ref.shape[0]
+    M, P = rpb * tq, cp * bs
     sw = ks_buf.shape[0] // 2 if quant else 0   # a slot of scales, words
     t0 = pl.program_id(0) * tq
     lo, hi = lo_ref[pl.program_id(0)], hi_ref[pl.program_id(0)]
@@ -285,30 +378,11 @@ def _tiled_kernel(len_ref, bt_ref, first_ref, last_ref, lo_ref, hi_ref,
         # a stale value page is multiplied by p = 0: it must be finite
         v_buf[...] = jnp.zeros_like(v_buf)
 
-    def bounds(r):
-        """Row r's tokens in this tile [first, last] and the causal bound
-        of the last of them (0: the row has no token here)."""
-        r = jnp.minimum(r, first_ref.shape[0] - 1)   # hi + 1 is asked too
-        first = jnp.maximum(first_ref[r], t0)
-        last = jnp.minimum(last_ref[r], t0 + tq - 1)
-        kv = jnp.where(first <= last, len_ref[jnp.clip(last, 0, T - 1)], 0)
-        return first, last, kv
-
-    def n_chunks(r):
-        return (bounds(r)[2] + P - 1) // P
-
-    def next_row(r):
-        return jax.lax.while_loop(
-            lambda r: (r <= hi) & (n_chunks(r) == 0), lambda r: r + 1, r)
-
-    def pages(r, c):
-        return jnp.minimum((bounds(r)[2] + bs - 1) // bs - c * cp, cp)
-
-    def copies(r, c, slot, j):
-        page = pl.ds(bt_ref[r, c * cp + j] * bs, bs)   # its rows of the pool
-        return (pltpu.make_async_copy(k_hbm.at[page], k_buf.at[slot, j],
+    def copies(page, slot, j):
+        rows = pl.ds(page * bs, bs)                    # its rows of the pool
+        return (pltpu.make_async_copy(k_hbm.at[rows], k_buf.at[slot, j],
                                       sem.at[slot, 0]),
-                pltpu.make_async_copy(v_hbm.at[page], v_buf.at[slot, j],
+                pltpu.make_async_copy(v_hbm.at[rows], v_buf.at[slot, j],
                                       sem.at[slot, 1]))
 
     def scale_copies(r, c, slot):
@@ -319,23 +393,6 @@ def _tiled_kernel(len_ref, bt_ref, first_ref, last_ref, lo_ref, hi_ref,
                                       ssem.at[slot, 0]),
                 pltpu.make_async_copy(vs_hbm.at[src], vs_buf.at[dst],
                                       ssem.at[slot, 1]))
-
-    def each_copy(r, c, slot, act):
-        """``act`` (start or wait) on every copy of chunk c of row r."""
-        def one(j, _):
-            for dma in copies(r, c, slot, j):
-                act(dma)
-            return 0
-        jax.lax.fori_loop(0, pages(r, c), one, 0)
-        if quant:
-            for dma in scale_copies(r, c, slot):
-                act(dma)
-
-    def start(r, c, slot):
-        each_copy(r, c, slot, lambda dma: dma.start())
-
-    def wait(r, c, slot):
-        each_copy(r, c, slot, lambda dma: dma.wait())
 
     def dequant(x, s_buf, slot, b):
         """The block's (cp, bs, bw) slice of an int8 chunk through the
@@ -352,13 +409,8 @@ def _tiled_kernel(len_ref, bt_ref, first_ref, last_ref, lo_ref, hi_ref,
         return (x.astype(jnp.float32).reshape(P, bw)
                 * jnp.concatenate(rows, axis=0)).astype(io_dtype)
 
-    def compute(r, c, slot):
-        first, last, _ = bounds(r)
-        tok = t0 + jax.lax.broadcasted_iota(jnp.int32, (tq, 1), 0)
-        eff = jnp.where((tok >= first) & (tok <= last), tl_ref[...], 0)
-        eff = jnp.concatenate([eff] * rpb, axis=0)            # (M, 1)
-        visible = c * P + jax.lax.broadcasted_iota(
-            jnp.int32, (M, P), 1) < eff
+    def compute(first, last, c, slot):
+        visible = _visible(tl_ref, t0, first, last, c, tq, rpb, P)
         for b in range(nblk):                                 # static
             lanes = slice(b * bw, (b + 1) * bw)
             k = k_buf[slot, :, :, lanes]
@@ -370,28 +422,9 @@ def _tiled_kernel(len_ref, bt_ref, first_ref, last_ref, lo_ref, hi_ref,
                          v.reshape(P, bw), visible, acc_sc, m_sc, l_sc, b,
                          scale=scale)
 
-    r0 = next_row(lo)
-
-    @pl.when(r0 <= hi)
-    def _first():
-        start(r0, 0, 0)
-
-    def step(state):
-        r, c, slot = state
-        last = c + 1 >= n_chunks(r)
-        nr = jax.lax.cond(last, lambda: next_row(r + 1), lambda: r)
-        nc = jnp.where(last, 0, c + 1)
-
-        @pl.when(nr <= hi)
-        def _prefetch():
-            start(nr, nc, 1 - slot)
-
-        wait(r, c, slot)
-        compute(r, c, slot)
-        return nr, nc, 1 - slot
-
-    jax.lax.while_loop(lambda state: state[0] <= hi, step,
-                       (r0, jnp.int32(0), jnp.int32(0)))
+    _walk_rows(len_ref, bt_ref, first_ref, last_ref, lo, hi, t0=t0, tq=tq,
+               bs=bs, cp=cp, copies=copies, compute=compute,
+               chunk_copies=scale_copies if quant else None)
 
     lane = jax.lax.broadcasted_iota(jnp.int32, (group * tq, bw), 1)
     for b in range(nblk):                                     # static
@@ -402,6 +435,22 @@ def _tiled_kernel(len_ref, bt_ref, first_ref, last_ref, lo_ref, hi_ref,
         for i in range(1, hpb):        # head i's lanes from head i's rows
             out = jnp.where(lane >= i * hd, a[i], out)
         o_ref[b] = out.reshape(group, tq, bw).astype(o_ref.dtype)
+
+
+def _row_descriptors(row_ids, lengths, R, tq):
+    """What :func:`_walk_rows` reads: each row's first and last flat
+    token (a row's tokens are contiguous in pack order; [R] each), and
+    each tile's first and last row ([T / tq] each). T is whole tiles."""
+    T = row_ids.shape[0]
+    tok = jnp.arange(T, dtype=jnp.int32)
+    valid = lengths > 0
+    mine = (row_ids[None, :] == jnp.arange(R, dtype=jnp.int32)[:, None]) \
+        & valid[None, :]                                        # [R, T]
+    row_first = jnp.min(jnp.where(mine, tok, T), axis=1)
+    row_last = jnp.max(jnp.where(mine, tok, -1), axis=1)
+    tile_lo = jnp.min(jnp.where(valid, row_ids, R).reshape(-1, tq), axis=1)
+    tile_hi = jnp.max(jnp.where(valid, row_ids, -1).reshape(-1, tq), axis=1)
+    return row_first, row_last, tile_lo, tile_hi
 
 
 def _tiled_call(q, k_cache, v_cache, row_ids, lengths, block_tables,
@@ -435,14 +484,8 @@ def _tiled_call(q, k_cache, v_cache, row_ids, lengths, block_tables,
         row_ids = jnp.pad(row_ids, (0, T - T0))
         lengths = jnp.pad(lengths, (0, T - T0))
 
-    tok = jnp.arange(T, dtype=jnp.int32)
-    valid = lengths > 0
-    mine = (row_ids[None, :] == jnp.arange(R, dtype=jnp.int32)[:, None]) \
-        & valid[None, :]                                        # [R, T]
-    row_first = jnp.min(jnp.where(mine, tok, T), axis=1)
-    row_last = jnp.max(jnp.where(mine, tok, -1), axis=1)
-    tile_lo = jnp.min(jnp.where(valid, row_ids, R).reshape(-1, tq), axis=1)
-    tile_hi = jnp.max(jnp.where(valid, row_ids, -1).reshape(-1, tq), axis=1)
+    row_first, row_last, tile_lo, tile_hi = _row_descriptors(
+        row_ids, lengths, R, tq)
 
     qx = q.reshape(T, nblk, hpb, group, hd).transpose(1, 2, 3, 0, 4)
     if hpb > 1:       # a query row is zero outside its own head's lanes
@@ -584,3 +627,145 @@ def ragged_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
         name="ragged_attention_pipelined",
     )(row_ids, lengths, block_tables, *operands)
     return out.reshape(T, nh, hd)
+
+
+# ---------------------------------------------------------------------------
+# Latent (MLA) pool
+# ---------------------------------------------------------------------------
+def _latent_kernel(layer_ref, len_ref, bt_ref, first_ref, last_ref, lo_ref,
+                   hi_ref, q_ref, tl_ref, pool_hbm, o_ref, buf, acc_sc,
+                   m_sc, l_sc, sem, *, bs, scale, dc, tq, cp):
+    """Grid (T / tq,), the tiled variant's walk (:func:`_walk_rows`)
+    over a latent pool ``[L, nb, bs, W]`` left whole in HBM, the layer a
+    prefetched scalar. All ``nh`` heads of the tile's tokens, ``(nh *
+    tq, W)`` query rows in the absorbed form (a head's query against the
+    latent's ``dc`` lanes, then its rotated part), go through the matrix
+    unit together against ONE row a position: a chunk's ``(P, W)`` rows
+    are the keys whole and, their first ``dc`` lanes, the values, so a
+    page is read once. buf is (2, cp, bs, W); sem is (2,), one wait a
+    page."""
+    nh, W = q_ref.shape[0], q_ref.shape[2]
+    M, P = nh * tq, cp * bs
+    t0 = pl.program_id(0) * tq
+    lo, hi = lo_ref[pl.program_id(0)], hi_ref[pl.program_id(0)]
+    layer = layer_ref[0]
+
+    _init_scratch(acc_sc, m_sc, l_sc)
+
+    @pl.when(pl.program_id(0) == 0)
+    def _zero():
+        # a stale page is multiplied by p = 0: it must be finite
+        buf[...] = jnp.zeros_like(buf)
+
+    def copies(page, slot, j):
+        return (pltpu.make_async_copy(pool_hbm.at[layer, page],
+                                      buf.at[slot, j], sem.at[slot]),)
+
+    def compute(first, last, c, slot):
+        visible = _visible(tl_ref, t0, first, last, c, tq, nh, P)
+        kv = buf[slot].reshape(P, W)
+        s = jax.lax.dot_general(q_ref[...].reshape(M, W), kv,
+                                (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        s = jnp.where(visible, s, 2 * NEG_INF)      # as _tile_update masks
+        m_prev = m_sc[:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        l_sc[...] = jnp.broadcast_to(
+            l_sc[:, :1] * corr + jnp.sum(p, axis=1, keepdims=True),
+            l_sc.shape)
+        acc_sc[...] = acc_sc[...] * corr + jax.lax.dot_general(
+            p.astype(kv.dtype), kv[:, :dc], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_sc[...] = jnp.broadcast_to(m_new, m_sc.shape)
+
+    _walk_rows(len_ref, bt_ref, first_ref, last_ref, lo, hi, t0=t0, tq=tq,
+               bs=bs, cp=cp, copies=copies, compute=compute)
+
+    l = l_sc[:, :1]
+    o_ref[...] = (acc_sc[...] / jnp.where(l == 0.0, 1.0, l)).reshape(
+        nh, tq, dc).astype(o_ref.dtype)
+
+
+def latent_attention_reference(q, pool, layer, row_ids, lengths,
+                               block_tables, *, dc: int, scale: float):
+    """:func:`latent_attention` by gathering: each row's pages once,
+    every token against its row's positions under its own bound, the
+    softmax in float32. The ``jnp:gather`` path of a latent pool and the
+    kernel's parity reference."""
+    R, MB = block_tables.shape
+    bs, W = pool.shape[2:]
+    rows = pool[layer][block_tables].reshape(R, MB * bs, W)[row_ids]
+    s = jnp.einsum("htw,tcw->htc", q, rows).astype(jnp.float32) * scale
+    seen = jnp.arange(MB * bs)[None, :] < lengths[:, None]       # [T, ctx]
+    p = jax.nn.softmax(jnp.where(seen[None], s, NEG_INF), axis=-1)
+    p = jnp.where(lengths[None, :, None] > 0, p, 0.0)   # padding: zeros
+    return jnp.einsum("htc,tcd->htd", p.astype(q.dtype), rows[..., :dc])
+
+
+def latent_attention(q, pool, layer, row_ids, lengths, block_tables, *,
+                     dc: int, scale: float,
+                     interpret: Optional[bool] = None):
+    """Ragged paged attention over a LATENT pool (attention='mla', the
+    absorbed form): every head's query attends ONE cached row a
+    position, whose first ``dc`` lanes are also the values.
+
+    q ``[nh, T, W]`` flat token buffer, head-major (a head's ``dc``-wide
+    query in the latent's space, then its rotated part; W = pool row);
+    pool ``[L, nb, bs, W]`` whole, ``layer`` a traced scalar; row_ids,
+    lengths [T] and block_tables [R, MB] as :func:`ragged_attention`
+    takes them. Returns ``[nh, T, dc]``, a head's output in the latent's
+    space. On a TPU the kernel (``ragged_attention_latent`` in a trace);
+    off it the gathering reference, and the kernel under the TPU
+    interpreter (DMAs, semaphores and all) only where ``interpret`` asks
+    for it: that interpreter is several times slower than the gather."""
+    nh, T0, W = q.shape
+    if interpret is None and _interpret():
+        return latent_attention_reference(q, pool, layer, row_ids, lengths,
+                                          block_tables, dc=dc, scale=scale)
+    row_ids = row_ids.astype(jnp.int32)
+    lengths = lengths.astype(jnp.int32)
+    block_tables = block_tables.astype(jnp.int32)
+    bs = pool.shape[2]
+    R, MB = block_tables.shape
+    # a tile: a power of two of 16 to 128 tokens, 512 query rows where
+    # that leaves 16
+    tq = max(16, min(pow2_bucket(T0, 128),
+                     1 << (max(512 // nh, 1).bit_length() - 1)))
+    T = -(-T0 // tq) * tq
+    cp = max(1, min(MB, _CHUNK_POSITIONS // bs))   # pages a chunk
+    if MB % cp:
+        block_tables = jnp.pad(block_tables, ((0, 0), (0, cp - MB % cp)))
+    if T != T0:
+        q = jnp.pad(q, ((0, 0), (0, T - T0), (0, 0)))
+        row_ids = jnp.pad(row_ids, (0, T - T0))
+        lengths = jnp.pad(lengths, (0, T - T0))
+    row_first, row_last, tile_lo, tile_hi = _row_descriptors(
+        row_ids, lengths, R, tq)
+    M = nh * tq
+    out = pl.pallas_call(
+        functools.partial(_latent_kernel, bs=bs, scale=scale, dc=dc, tq=tq,
+                          cp=cp),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=7,
+            grid=(T // tq,),
+            in_specs=[pl.BlockSpec((nh, tq, W), lambda i, *_: (0, i, 0)),
+                      pl.BlockSpec((tq, 1), lambda i, *_: (i, 0)),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((nh, tq, dc), lambda i, *_: (0, i, 0)),
+            scratch_shapes=[pltpu.VMEM((2, cp, bs, W), pool.dtype),
+                            pltpu.VMEM((M, dc), jnp.float32),
+                            pltpu.VMEM((M, 128), jnp.float32),
+                            pltpu.VMEM((M, 128), jnp.float32),
+                            pltpu.SemaphoreType.DMA((2,))]),
+        out_shape=jax.ShapeDtypeStruct((nh, T, dc), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_TILED_VMEM_BYTES),
+        interpret=pltpu.InterpretParams() if interpret or _interpret()
+        else False,
+        name="ragged_attention_latent",
+    )(jnp.asarray(layer, jnp.int32).reshape(1), lengths, block_tables,
+      row_first, row_last, tile_lo, tile_hi, q, lengths.reshape(T, 1), pool)
+    return out[:, :T0]

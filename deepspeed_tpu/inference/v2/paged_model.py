@@ -58,6 +58,9 @@ def init_paged_kv_cache(cfg: TransformerConfig, num_blocks: int,
     assert cfg.is_causal and cfg.norm_scheme == "pre", \
         "paged serving requires a causal pre-LN model (the MLM/post-LN " \
         "encoder family does not decode)"
+    if cfg.attention == "mla":
+        return _init_latent_cache(cfg, num_blocks, block_size, dtype,
+                                  kv_quant)
     shape = (cfg.num_layers, num_blocks, block_size, cfg.kv_heads,
              cfg.head_dim)
     if kv_quant:
@@ -67,6 +70,37 @@ def init_paged_kv_cache(cfg: TransformerConfig, num_blocks: int,
                 "ks": jnp.zeros(sshape, jnp.float32),
                 "vs": jnp.zeros(sshape, jnp.float32)}
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+
+
+def latent_pool_row(cfg) -> int:
+    """Lanes of one position of the latent pool: ``cfg.latent_row``
+    rounded up to whole 128-lane blocks, the rest zeros."""
+    return -(-cfg.latent_row // 128) * 128
+
+
+def _init_latent_cache(cfg, num_blocks, block_size, dtype, kv_quant):
+    """The pool of an attention='mla' model: ONE leaf, ``latent``
+    ``[L, nb, bs, kv_lora_rank + qk_rope_head_dim]``: a cached position
+    holds a layer's normed latent and, behind it, the rotated key part
+    all heads share (DeepSeek-V3: 512 + 64 values, 1,152 B in bf16,
+    where 32 heads of keys and values would be 8,192). The row is stored
+    padded with zeros to whole 128-lane blocks (``latent_pool_row``: 640
+    lanes for 576): the TPU's tiles pad it so in HBM whatever the shape
+    says, and Mosaic copies no slice of a page that is not whole lane
+    blocks (it refused the 576-wide one). The leaf's shape
+    is a function of the attention kind alone (every layer here has the
+    same kind); the layer leads, so that the kernel takes the whole pool
+    and a layer index (``kernels/ragged_attention.latent_attention``).
+
+    ``kv_quant``: see below."""
+    shape = (cfg.num_layers, num_blocks, block_size, latent_pool_row(cfg))
+    if kv_quant:
+        # int8 rows, one float32 scale a cached position (its row's
+        # absmax / 127): half the pool's bytes. A launch dequantises the
+        # layer it attends into a transient copy (``_latent_rows``)
+        return {"latent": jnp.zeros(shape, jnp.int8),
+                "latent_scale": jnp.zeros(shape[:3], jnp.float32)}
+    return {"latent": jnp.zeros(shape, dtype)}
 
 
 def _kv_write(kc, ksc, l, blocks, offs, k):
@@ -216,24 +250,24 @@ def _mlp(cfg, lp, x, topo=None):
 def _moe_mlp(cfg, lp, x, topo=None):
     """Routed-expert MLP for serving (reference v2 serves Mixtral-class
     MoE, inference/v2/model_implementations/): dropless sorted-token
-    grouped GEMM via jax.lax.ragged_dot — no [T,E,C] capacity tensor, no
-    token drops (dropping tokens at inference corrupts outputs), ep=1.
+    grouped GEMM — no [T,E,C] capacity tensor, no token drops (dropping
+    tokens at inference corrupts outputs), ep=1.
 
-    Routing matches the training graph so serving is parity-testable
-    against the same weights: top-1 uses the raw gate probability
-    (sharded_moe.top1gating g1); top-k>=2 renormalizes over the chosen
-    set (top2gating's g1/g2 normalization; for k>2 the same convention
-    is the Mixtral/Qwen-MoE/DBRX one — serving-only, training gates are
-    top-1/top-2).
+    Routing is ``sharded_moe.topk_routing`` on float32 router logits.
+    By default it matches the training graph, so that serving is
+    parity-testable against the same weights: softmax scores, top-1 its
+    raw gate probability (top1gating g1), top-k >= 2 renormalised over
+    the chosen set (top2gating's g1/g2; for k > 2 the Mixtral/Qwen-MoE/
+    DBRX convention, serving-only). The configuration may instead ask
+    for the layer as DeepSeek-V3-class checkpoints deploy it: sigmoid
+    scores (``moe_scoring``), a bias that chooses and does not weigh
+    (``moe_selection_bias``), the chosen weights scaled
+    (``moe_routed_scale``), and always-on shared experts added beside
+    the routed ones (``moe_shared_experts``), any k.
     """
-    from ...moe.sharded_moe import dropless_topk_dispatch
-
     orig_shape = x.shape
     H = orig_shape[-1]
     xt = x.reshape(-1, H)
-    gate_w = lp["moe_gate_w"]
-    E = gate_w.shape[-1]
-    k = cfg.moe_top_k
     if topo is not None and topo.axis_size("expert") > 1:
         # expert-parallel serving: experts live sharded over the "expert"
         # axis, so the ragged grouped GEMM (device-local experts) cannot
@@ -249,17 +283,12 @@ def _moe_mlp(cfg, lp, x, topo=None):
             return (jax.nn.silu(xe @ g_) * (xe @ u_)) @ d_
 
         out3, _aux = moe_layer_dropless_ep(
-            xt[None], gate_w, (lp["e_gate"], lp["e_up"], lp["e_down"]),
-            expert_fn, topo, top_k=k)
+            xt[None], lp["moe_gate_w"],
+            (lp["e_gate"], lp["e_up"], lp["e_down"]),
+            expert_fn, topo, top_k=cfg.moe_top_k)
         out = out3[0]
     else:
-        logits = xt.astype(jnp.float32) @ gate_w.astype(jnp.float32)
-        gates = jax.nn.softmax(logits, axis=-1)
-        topv, topi = jax.lax.top_k(gates, k)                # [T, k]
-        if k > 1:
-            topv = topv / jnp.sum(topv, axis=-1, keepdims=True)
-        experts = (lp["e_gate"], lp["e_up"], lp["e_down"])
-        out = dropless_topk_dispatch(xt, topi, topv, experts, E)
+        out, _ = _moe_routed(cfg, lp, xt)
     if cfg.moe_use_residual:
         from ...moe.sharded_moe import residual_moe_combine
         dense = (jax.nn.silu(xt @ lp["res_gate"])
@@ -267,6 +296,49 @@ def _moe_mlp(cfg, lp, x, topo=None):
         out = residual_moe_combine(xt, out, dense, lp["res_coef_w"],
                                    lp["res_coef_b"])
     return out.reshape(orig_shape)
+
+
+def _moe_routed(cfg, lp, xt, experts=None, stack_layer=None,
+                router_precision=None):
+    """The ep = 1 expert layer on flat tokens ``xt`` [T, H]: (what the
+    routed and the shared experts add, in the experts' type; the chosen
+    experts [T, k]). ``experts`` with ``stack_layer``: the scanned
+    stack's expert weights whole and this layer's index in it
+    (``sharded_moe.dropless_topk_dispatch``); else ``lp``'s own.
+    ``xt`` may be float32 beside bf16 experts: the router reads it as it
+    is, the experts its rounding. ``router_precision`` is the router
+    matmul's: the latent block's published router is float32 (``s =
+    sigmoid(x Wr)`` in float32) and on a TPU a float32 matmul runs in
+    bf16 passes unless asked otherwise, so ``_latent_step`` asks for
+    ``HIGHEST``; the per-head path's softmax router keeps the backend's
+    default (None), which is what its programs compiled to before."""
+    from ...moe.sharded_moe import (dropless_topk_dispatch, gmm_serves,
+                                    gmm_swiglu_experts, topk_routing)
+
+    with jax.named_scope("moe_router"):
+        gate_w = lp["moe_gate_w"]
+        # on a TPU a float32 matmul runs in bf16 passes unless asked
+        # otherwise; rounded scores flip which experts are chosen
+        logits = jnp.matmul(xt.astype(jnp.float32),
+                            gate_w.astype(jnp.float32),
+                            precision=router_precision)
+        topi, topv = topk_routing(
+            logits, cfg.moe_top_k, cfg.moe_scoring,
+            lp["moe_gate_bias"] if cfg.moe_selection_bias else None,
+            cfg.moe_norm_topk, cfg.moe_routed_scale)
+    xt = xt.astype(gate_w.dtype)
+    with jax.named_scope("moe_experts"):
+        if experts is None:
+            experts = (lp["e_gate"], lp["e_up"], lp["e_down"])
+        out = dropless_topk_dispatch(
+            xt, topi, topv, experts, gate_w.shape[-1],
+            gmm_swiglu_experts if gmm_serves(experts) else None,
+            stack_layer=stack_layer)
+    if cfg.moe_shared_experts:
+        with jax.named_scope("moe_shared_expert"):
+            out = out + (jax.nn.silu(xt @ lp["shared_gate"])
+                         * (xt @ lp["shared_up"])) @ lp["shared_down"]
+    return out, topi
 
 
 def _deq_nonlayer(params):
@@ -314,6 +386,205 @@ def _logits(cfg, params, x):
 
 
 # ---------------------------------------------------------------------------
+# The latent-attention block (attention='mla'): one step for every program
+# ---------------------------------------------------------------------------
+def _rotate_pairs(x, cos, sin, interleave):
+    """Rope on x [..., D] (D even, all of it rotates), cos/sin
+    broadcastable to [..., D/2]. ``interleave``: the pairs are lanes
+    (2i, 2i+1), the published DeepSeek layout; they are read by a
+    strided slice and the result is written half-split, evens' results
+    then odds'. Queries and the shared key part go through this one
+    function, so a score (a dot product over the pairs) is what the
+    pair-wise rotation in place gives; nothing else reads the rotated
+    lanes, so the permutation lives here, at trace time, and in no
+    weight. Otherwise the pairs are (i, i + D/2): ``_rotate``."""
+    if not interleave:
+        return _rotate(x, cos, sin)
+    x1, x2 = x[..., 0::2], x[..., 1::2]
+    return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos],
+                           axis=-1).astype(x.dtype)
+
+
+def _latent_write(pool, l, blocks, offs, row):
+    """The new tokens' rows [T, row] into layer ``l`` of the pool
+    (``{"latent"[, "latent_scale"]}``), padded with zeros to the pool's
+    lanes; into an int8 pool against each row's own absmax."""
+    lat = pool["latent"]
+    row = jnp.pad(row, ((0, 0), (0, lat.shape[-1] - row.shape[-1])))
+    if "latent_scale" not in pool:
+        return {"latent": lat.at[l, blocks, offs].set(row.astype(lat.dtype))}
+    rf = row.astype(jnp.float32)
+    scale = jnp.max(jnp.abs(rf), axis=-1) / 127.0
+    q = jnp.round(rf / jnp.where(scale > 0, scale, 1.0)[:, None])
+    return {"latent": lat.at[l, blocks, offs].set(q.astype(jnp.int8)),
+            "latent_scale": pool["latent_scale"].at[l, blocks, offs]
+            .set(scale)}
+
+
+def _latent_rows(pool, l, dtype):
+    """(pool, layer) for the attention of layer ``l``: the pool whole
+    and ``l``, or, of an int8 pool, that ONE layer dequantised through
+    the serving dtype as a pool of one layer (a transient 1 / L of the
+    pool at twice its bytes; an in-kernel dequant would save the pass:
+    ROADMAP M3)."""
+    if "latent_scale" not in pool:
+        return pool["latent"], l
+    rows = (pool["latent"][l].astype(jnp.float32)
+            * pool["latent_scale"][l][..., None]).astype(dtype)
+    return rows[None], jnp.int32(0)
+
+
+def _latent_attention_sublayer(cfg, lp, x, l, pool, cos, sin, row_ids,
+                               lengths, write_blocks, write_offsets,
+                               block_tables, use_kernel):
+    """Multi-head latent attention on flat tokens x [T, H], in the
+    ABSORBED form for prefill and decode alike: the new tokens' rows
+    (normed latent, rotated shared key part) go to layer ``l`` of the
+    pool, a head's query is carried into the latent's space by its slice
+    of ``wkv_b`` (``q_nope Wk^T``), attends the rows there
+    (``kernels/ragged_attention.latent_attention``) and its output
+    leaves that space by the value slice (``o_lat Wv``). The same
+    mathematics as expanding every cached position's keys and values
+    per head, which a long prefill would do more cheaply (ROADMAP M3).
+    Returns (what attention adds to x, pool)."""
+    from ...ops.norms import rms_norm
+    from .kernels.ragged_attention import (latent_attention,
+                                           latent_attention_reference)
+    T = x.shape[0]
+    nh, dc = cfg.num_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    hn = _norm(cfg, x, lp["attn_norm"]).astype(lp["wq_a"].dtype)
+    q = rms_norm(hn @ lp["wq_a"], lp["q_norm"], cfg.norm_eps) @ lp["wq_b"]
+    q = q.reshape(T, nh, dn + dr)
+    kv = hn @ lp["wkv_a"]                                   # [T, dc + dr]
+    ckv = rms_norm(kv[:, :dc], lp["kv_norm"], cfg.norm_eps)
+    k_rope = _rotate_pairs(kv[:, dc:], cos, sin, cfg.rope_interleave)
+    q_rope = _rotate_pairs(q[..., dn:], cos[:, None], sin[:, None],
+                           cfg.rope_interleave)
+    W = pool["latent"].shape[-1]
+    pool = _latent_write(pool, l, write_blocks, write_offsets,
+                         jnp.concatenate([ckv, k_rope], axis=-1))
+    rows, at = _latent_rows(pool, l, hn.dtype)
+    wkv_b = lp["wkv_b"].reshape(dc, nh, dn + dv)
+    q_lat = jnp.einsum("thd,chd->htc", q[..., :dn], wkv_b[..., :dn])
+    qx = jnp.concatenate([q_lat, q_rope.transpose(1, 0, 2)], axis=-1)
+    qx = jnp.pad(qx, ((0, 0), (0, 0), (0, W - dc - dr)))    # [nh, T, W]
+    scale = 1.0 / float(dn + dr) ** 0.5
+    if use_kernel:
+        o_lat = latent_attention(qx, rows, at, row_ids, lengths,
+                                 block_tables, dc=dc, scale=scale)
+    else:
+        o_lat = latent_attention_reference(qx, rows, at, row_ids, lengths,
+                                           block_tables, dc=dc, scale=scale)
+    o = jnp.einsum("htc,chd->thd", o_lat, wkv_b[..., dn:])
+    return o.reshape(T, nh * dv) @ lp["wo"], pool
+
+
+def _moe_stats(topi, valid, num_experts):
+    """What one expert layer routed in one launch, float32 [4]: 1 (a
+    launch of an expert layer), the routed rows (valid tokens x k), the
+    distinct experts with at least one row, and the fullest expert's
+    share of the rows."""
+    counts = jnp.zeros((num_experts,), jnp.float32).at[
+        topi.reshape(-1)].add(jnp.repeat(valid, topi.shape[-1])
+                              .astype(jnp.float32))
+    rows = jnp.sum(counts)
+    return jnp.stack([jnp.float32(1.0), rows, jnp.sum(counts > 0),
+                      jnp.max(counts) / jnp.maximum(rows, 1.0)])
+
+
+def _merge_moe_stats(a, b):
+    """Counts add; the fullest share is the larger."""
+    return jnp.concatenate([a[:3] + b[:3], jnp.maximum(a[3:], b[3:])])
+
+
+def _latent_step(cfg: TransformerConfig, params, ids, row_ids, pos, lengths,
+                 write_blocks, write_offsets, block_tables, cache,
+                 use_kernel=True):
+    """The whole block of an attention='mla' model on a flat token
+    buffer, the one forward behind ``paged_ragged_step`` and
+    ``paged_decode`` (a decode batch is the ragged layout with one token
+    a row): embedding, the LEADING stack (``lead_layers``: latent
+    attention + a dense gated MLP) scanned, then the expert stack
+    (``layers``: latent attention + the expert layer, or a dense MLP
+    where the model has no experts) scanned, both writing ONE pool
+    ``[L, nb, bs, row]`` at their own layer indices, then the final
+    norm. The stack's expert weights do not ride the scan: a layer
+    sliced out of them for the grouped-matmul kernel would be a copy of
+    all its experts, so the kernel takes the stack whole and the layer
+    by where its groups lie (``dropless_topk_dispatch``).
+
+    Returns (normed hidden states [T, H], what this step's expert layers
+    routed (``_moe_stats`` merged over them, valid tokens only; zeros
+    where the model has no experts), cache)."""
+    dtype = params["embed"].dtype
+    # the residual stream is float32 whatever the weights' type: every
+    # sub-layer reads its norm rounded to ``dtype`` and adds what it
+    # makes to the sum unrounded. Ten roundings of the stream itself
+    # move a router's scores, and a score that moves past a neighbour's
+    # swaps an expert: at published widths on the chip a bf16 stream
+    # doubled the logits' typical error against the float32 reference
+    # and swapped an expert on 7 seeds of 10 where this swaps on 4
+    # (PERF.md section 4)
+    x = params["embed"][ids].astype(jnp.float32)
+    if cfg.embed_scale != 1.0:
+        x = x * jnp.asarray(cfg.embed_scale, x.dtype)
+    cos, sin = _rope_at(cfg, pos)                # [T, qk_rope_head_dim / 2]
+    valid = lengths > 0
+    lead = cfg.moe_first_dense_layers
+    moe = cfg.moe_num_experts > 0
+    expert_keys = ("e_gate", "e_up", "e_down")
+
+    def stack(x, pool, stats, layers, first, routed):
+        experts = tuple(layers[k] for k in expert_keys) if routed else None
+        scanned = {k: v for k, v in layers.items()
+                   if not (routed and k in expert_keys)}
+        n = scanned["attn_norm"].shape[0]
+
+        def layer_fn(carry, inputs):
+            x, pool, stats = carry
+            lp, i = inputs
+            with jax.named_scope("mla_attention"):
+                a, pool = _latent_attention_sublayer(
+                    cfg, lp, x, first + i, pool, cos, sin, row_ids, lengths,
+                    write_blocks, write_offsets, block_tables, use_kernel)
+            x = x + a.astype(jnp.float32)
+            hn = _norm(cfg, x, lp["mlp_norm"])
+            if routed:
+                out, topi = _moe_routed(
+                    cfg, lp, hn, experts, i,
+                    router_precision=jax.lax.Precision.HIGHEST)
+                stats = _merge_moe_stats(
+                    stats, _moe_stats(topi, valid, cfg.moe_num_experts))
+            else:
+                from ...models.transformer import gate_act
+                with jax.named_scope("dense_mlp"):
+                    hn = hn.astype(dtype)
+                    out = (gate_act(cfg)(hn @ lp["w_gate"])
+                           * (hn @ lp["w_up"])) @ lp["w_down"]
+            return (x + out.astype(jnp.float32), pool, stats), None
+
+        (x, pool, stats), _ = jax.lax.scan(
+            layer_fn, (x, pool, stats), (scanned, jnp.arange(n)))
+        return x, pool, stats
+
+    pool, stats = cache, jnp.zeros((4,), jnp.float32)
+    if lead:
+        x, pool, stats = stack(x, pool, stats, params["lead_layers"], 0,
+                               False)
+    x, pool, stats = stack(x, pool, stats, params["layers"], lead, moe)
+    return _norm(cfg, x, params["final_norm"]).astype(dtype), stats, pool
+
+
+def _refuse_latent(cfg, program):
+    if cfg.attention == "mla":
+        raise NotImplementedError(
+            f"{program} has no latent-attention form: an attention='mla' "
+            f"model is served through the ragged step and the decode "
+            f"programs (ragged_attention 'auto' or 'on', no speculation)")
+
+
+# ---------------------------------------------------------------------------
 # Prefill
 # ---------------------------------------------------------------------------
 def paged_prefill(cfg: TransformerConfig, params, ids: jnp.ndarray,
@@ -332,6 +603,7 @@ def paged_prefill(cfg: TransformerConfig, params, ids: jnp.ndarray,
     positions AFTER every valid query, so causal masking excludes them and
     no explicit valid mask is needed; K/V still scatter into the cache
     blocks in the same pass."""
+    _refuse_latent(cfg, "paged_prefill")
     C = ids.shape[1]
     nh, nkv, hd = cfg.num_heads, cfg.kv_heads, cfg.head_dim
     # shape gates only: off-TPU the kernel runs in interpret mode (slow but
@@ -435,6 +707,7 @@ def paged_continue(cfg: TransformerConfig, params, ids: jnp.ndarray,
     (cache block, slot), padding -> null block; block_table [MB] is the
     sequence's full table. Returns (last-token logits [V], cache).
     """
+    _refuse_latent(cfg, "paged_continue")
     C = ids.shape[1]
     MB = block_table.shape[0]
     ctx = MB * block_size
@@ -525,8 +798,19 @@ def paged_decode(cfg: TransformerConfig, params, toks: jnp.ndarray,
     returns ([N, V] logits, cache). Inactive rows write to the null block
     and produce garbage logits (masked by the caller). ``use_kernel`` runs
     the Pallas paged-attention kernel (kernels/paged_attention.py) instead
-    of the materializing gather fallback."""
+    of the materializing gather fallback. An attention='mla' model
+    returns (logits, what its expert layers routed, cache): see
+    ``_latent_step``."""
     N, MB = block_tables.shape
+    if cfg.attention == "mla":
+        # the ragged layout with one token a row (_latent_step)
+        blk = jnp.take_along_axis(
+            block_tables, (pos // block_size)[:, None], axis=1)[:, 0]
+        x, stats, cache = _latent_step(
+            cfg, params, toks, jnp.arange(N, dtype=jnp.int32), pos,
+            jnp.where(active, pos + 1, 0), jnp.where(active, blk, 0),
+            pos % block_size, block_tables, cache, use_kernel=use_kernel)
+        return _logits(cfg, params, x), stats, cache
     nh, nkv, hd = cfg.num_heads, cfg.kv_heads, cfg.head_dim
     ctx = MB * block_size
     params = _deq_nonlayer(params)
@@ -625,7 +909,9 @@ def paged_ragged_step(cfg: TransformerConfig, params, ids: jnp.ndarray,
     [RB, MBw] and ``last_index`` [RB] (flat index of each row's last
     valid token). Replaces the separate paged_prefill / paged_continue /
     paged_decode dispatches for everything the scheduler composes into a
-    step. Returns ([RB, V] last-token logits per row, cache).
+    step. Returns ([RB, V] last-token logits per row, cache); an
+    attention='mla' model returns (logits, what its expert layers
+    routed, cache): see ``_latent_step``.
 
     The new tokens' K/V scatter into the pool inside the scanned layer
     body (padding tokens land in the null block), then every token
@@ -636,6 +922,11 @@ def paged_ragged_step(cfg: TransformerConfig, params, ids: jnp.ndarray,
     through attention, which is row-local by construction."""
     T = ids.shape[0]
     RB, MBw = block_tables.shape
+    if cfg.attention == "mla":
+        x, stats, cache = _latent_step(
+            cfg, params, ids, row_ids, pos, lengths, write_blocks,
+            write_offsets, block_tables, cache, use_kernel=use_kernel)
+        return _logits(cfg, params, x[last_index]), stats, cache
     ctx = MBw * block_size
     nh, nkv, hd = cfg.num_heads, cfg.kv_heads, cfg.head_dim
     params = _deq_nonlayer(params)
@@ -764,17 +1055,20 @@ def paged_decode_window(cfg: TransformerConfig, params, toks: jnp.ndarray,
     streams are bit-identical under a fixed seed.
 
     Returns (tokens [N, window] int32 with -1 in steps a row did not
-    take, cache). Emitted tokens form a prefix of each row.
+    take, cache). Emitted tokens form a prefix of each row. An
+    attention='mla' model returns (tokens, what its expert layers routed
+    over the window's steps, cache), as ``paged_decode`` does.
     """
     N = toks.shape[0]
     sampled = rng is not None
 
     def body(state):
-        s, toks, pos, active, out, cache = state
-        logits, cache = paged_decode(cfg, params, toks, pos, block_tables,
-                                     cache, active, block_size,
-                                     use_kernel=use_kernel, topo=topo,
-                                     lora=lora, adapter_ids=adapter_ids)
+        s, toks, pos, active, out, moe, cache = state
+        logits, *routed, cache = paged_decode(
+            cfg, params, toks, pos, block_tables, cache, active, block_size,
+            use_kernel=use_kernel, topo=topo, lora=lora,
+            adapter_ids=adapter_ids)
+        moe = [_merge_moe_stats(a, b) for a, b in zip(moe, routed)]
         if sampled:
             from .sampling import fold_in_rows, sample_tokens_rowwise
             keys = fold_in_rows(rng, row_seeds, gen_idx0 + s)
@@ -786,16 +1080,18 @@ def paged_decode_window(cfg: TransformerConfig, params, toks: jnp.ndarray,
         pos = jnp.where(active, pos + 1, pos)
         toks = jnp.where(active, nxt, toks)
         active = active & (nxt != eos_ids) & (s + 1 < steps_left)
-        return s + 1, toks, pos, active, out, cache
+        return s + 1, toks, pos, active, out, moe, cache
 
     def cond(state):
-        s, _, _, active, _, _ = state
-        return (s < window) & jnp.any(active)
+        return (state[0] < window) & jnp.any(state[3])
 
+    # what the window's expert layers routed, merged over its steps: one
+    # more output where paged_decode has it (attention='mla'), none else
+    moe = [jnp.zeros((4,), jnp.float32)] * (cfg.attention == "mla")
     state = (jnp.asarray(0, jnp.int32), toks, pos, steps_left > 0,
-             jnp.full((N, window), -1, jnp.int32), cache)
-    _, _, _, _, out, cache = jax.lax.while_loop(cond, body, state)
-    return out, cache
+             jnp.full((N, window), -1, jnp.int32), moe, cache)
+    _, _, _, _, out, moe, cache = jax.lax.while_loop(cond, body, state)
+    return (out, *moe, cache)
 
 
 # ---------------------------------------------------------------------------
@@ -819,6 +1115,7 @@ def _paged_verify(cfg: TransformerConfig, params, fed: jnp.ndarray,
     ids[:, j] is the target's next token AFTER seeing fed[:, :j+1], which
     is exactly what the plain loop would emit at that step — the accept
     rule compares ids[:, :S-1] against fed[:, 1:]."""
+    _refuse_latent(cfg, "the speculative verify pass")
     N, S = fed.shape
     MB = block_tables.shape[1]
     ctx = MB * block_size

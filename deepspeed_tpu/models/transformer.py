@@ -112,6 +112,38 @@ class TransformerConfig:
     # cannot silently diverge. Use deepspeed_tpu.moe.layer.MoE for noisy
     # gating today.
     moe_noisy_gate_policy: Optional[str] = None
+    # the expert layer as DeepSeek-V3-class checkpoints deploy it (served
+    # only, inference/v2/paged_model.py): an expert's width apart from
+    # the dense MLP's (None: intermediate_size); how many LEADING layers
+    # keep a dense MLP (they form the ``lead_layers`` stack of the
+    # parameter tree, the expert layers ``layers``); always-on shared
+    # experts beside the routed ones; how a router logit becomes a score
+    # ("softmax" | "sigmoid"); a per-expert bias added to the scores for
+    # SELECTION only (``moe_gate_bias``); whether the chosen k > 1
+    # weights are normalised over the chosen set; and what they are then
+    # scaled by
+    moe_intermediate_size: Optional[int] = None
+    moe_first_dense_layers: int = 0
+    moe_shared_experts: int = 0
+    moe_scoring: str = "softmax"
+    moe_selection_bias: bool = False
+    moe_norm_topk: bool = True
+    moe_routed_scale: float = 1.0
+    # attention kind: "mha" (per-head keys and values; GQA/MQA by
+    # num_kv_heads) or "mla" (multi-head latent attention, served only:
+    # queries through a q_lora_rank bottleneck, keys and values expanded
+    # from ONE kv_lora_rank-wide normed latent a token, plus one
+    # qk_rope_head_dim-wide rotated key part shared by all heads; a head
+    # is qk_nope_head_dim + qk_rope_head_dim wide for scores and
+    # v_head_dim for values). rope_interleave: the rotation pairs lanes
+    # (2i, 2i+1), the published DeepSeek layout, and not (i, i + half)
+    attention: str = "mha"
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    rope_interleave: bool = False
 
     # training objective: "causal_lm" (next-token, causal attention) or
     # "mlm" (BERT-family masked-LM: bidirectional attention, loss at the
@@ -156,6 +188,38 @@ class TransformerConfig:
                 f"{self.norm_scheme!r}")
         if self.norm_scheme == "post" and self.moe_num_experts > 0:
             raise NotImplementedError("post-LN + MoE is not supported")
+        if self.attention not in ("mha", "mla"):
+            raise ValueError(
+                f"attention must be 'mha' or 'mla', got {self.attention!r}")
+        if self.attention == "mla":
+            sizes = (self.q_lora_rank, self.kv_lora_rank,
+                     self.qk_nope_head_dim, self.qk_rope_head_dim,
+                     self.v_head_dim)
+            if min(sizes) <= 0 or self.qk_rope_head_dim % 2:
+                raise ValueError(
+                    f"attention='mla' needs q_lora_rank, kv_lora_rank, "
+                    f"qk_nope_head_dim, an even qk_rope_head_dim and "
+                    f"v_head_dim, got {sizes}")
+            if (self.positional != "rope" or self.norm != "rmsnorm"
+                    or self.attn_bias or not self.is_causal
+                    or self.norm_scheme != "pre" or self.parallel_residual
+                    or not self.is_gated_mlp):
+                raise NotImplementedError(
+                    "attention='mla' is the DeepSeek-V3 block: rope, "
+                    "RMSNorm, pre-norm, causal, a gated MLP, no biases")
+        if self.moe_scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"moe_scoring must be 'softmax' or 'sigmoid', "
+                             f"got {self.moe_scoring!r}")
+        if not 0 <= self.moe_first_dense_layers < max(self.num_layers, 1):
+            raise ValueError(
+                f"moe_first_dense_layers={self.moe_first_dense_layers} "
+                f"leaves no expert layer of {self.num_layers}")
+        if self.moe_first_dense_layers and (self.moe_num_experts == 0
+                                            or self.attention != "mla"):
+            # the two-stack scan lives in paged_model._latent_step
+            raise NotImplementedError(
+                "leading dense layers (moe_first_dense_layers) are served "
+                "for an MoE model with attention='mla' only")
         if self.moe_noisy_gate_policy is not None:
             # RSample needs an rng threaded through the scanned layer body,
             # which neither the GSPMD nor the manual-pipeline MoE branch
@@ -177,6 +241,37 @@ class TransformerConfig:
     @property
     def head_dim(self) -> int:
         return self.head_dim_override or self.hidden_size // self.num_heads
+
+    @property
+    def served_only(self) -> Optional[str]:
+        """What of this configuration only ``InferenceEngineV2``'s paged
+        programs implement (None: nothing); the trainer, the v1 engine
+        and this module's own forwards refuse it by this line."""
+        what = [name for name, on in (
+            ("attention='mla'", self.attention == "mla"),
+            ("moe_scoring='sigmoid'", self.moe_scoring != "softmax"),
+            ("moe_selection_bias", self.moe_selection_bias),
+            ("moe_shared_experts", self.moe_shared_experts > 0),
+            ("moe_routed_scale", self.moe_routed_scale != 1.0),
+            ("moe_norm_topk=False", not self.moe_norm_topk)) if on]
+        return ", ".join(what) or None
+
+    def refuse_served_only(self, who: str):
+        if self.served_only:
+            raise NotImplementedError(
+                f"{who} does not implement {self.served_only}: this "
+                f"block is served by InferenceEngineV2 only")
+
+    @property
+    def expert_size(self) -> int:
+        """Width of one routed (or shared) expert."""
+        return self.moe_intermediate_size or self.intermediate_size
+
+    @property
+    def latent_row(self) -> int:
+        """What one cached position holds a layer under attention='mla':
+        the normed latent and the rotated shared key part."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
 
     @property
     def is_gated_mlp(self) -> bool:
@@ -202,8 +297,11 @@ def alibi_slopes(nh: int) -> jnp.ndarray:
 
 
 def rotary_dims(cfg: TransformerConfig) -> int:
-    """How many leading head dims rotate (rotary_pct < 1: NeoX/Phi).
+    """How many leading head dims rotate (rotary_pct < 1: NeoX/Phi;
+    attention='mla': all of the query's and the shared key's rope part).
     Always even."""
+    if cfg.attention == "mla":
+        return cfg.qk_rope_head_dim
     rot = int(cfg.head_dim * cfg.rotary_pct)
     return rot - (rot % 2)
 
@@ -483,6 +581,8 @@ class TransformerLM:
         def init(key, shape, scale=std):
             return (jax.random.normal(key, shape, jnp.float32) * scale).astype(dt)
 
+        if cfg.attention == "mla":
+            return self._init_latent_params(rng)
         layer = {
             "attn_norm": jnp.ones((L, h), dt),
             "wq": init(k[0], (L, h, nh * hd)),
@@ -493,10 +593,13 @@ class TransformerLM:
         }
         if cfg.moe_num_experts > 0:
             E = cfg.moe_num_experts
+            ffn = cfg.expert_size
             layer["moe_gate_w"] = init(k[4], (L, h, E))
             layer["e_gate"] = init(k[8], (L, E, h, ffn))
             layer["e_up"] = init(k[10], (L, E, h, ffn))
             layer["e_down"] = init(k[11], (L, E, ffn, h), out_std)
+            layer.update(self._init_deployed_router(k[12], L, init, out_std))
+            ffn = cfg.intermediate_size
             if cfg.moe_use_residual:
                 layer["res_gate"] = init(k[12], (L, h, ffn))
                 layer["res_up"] = init(k[13], (L, h, ffn))
@@ -552,9 +655,90 @@ class TransformerLM:
             params["lm_head_b"] = jnp.zeros((v,), dt)
         return params
 
+    def _init_deployed_router(self, key, L, init, out_std):
+        """The leaves the deployed expert layer adds to ``L`` expert
+        layers: the selection bias and the shared experts (one SwiGLU
+        ``moe_shared_experts`` experts wide)."""
+        cfg, dt = self.cfg, jnp.float32
+        h, fs = cfg.hidden_size, cfg.moe_shared_experts * cfg.expert_size
+        out = {}
+        if cfg.moe_selection_bias:
+            out["moe_gate_bias"] = jnp.zeros((L, cfg.moe_num_experts), dt)
+        if fs:
+            ks = jax.random.split(key, 3)
+            out["shared_gate"] = init(ks[0], (L, h, fs))
+            out["shared_up"] = init(ks[1], (L, h, fs))
+            out["shared_down"] = init(ks[2], (L, fs, h), out_std)
+        return out
+
+    def _init_latent_params(self, rng):
+        """The tree of an attention='mla' model: every layer the latent
+        attention's leaves; ``lead_layers`` [moe_first_dense_layers, ...]
+        with a dense gated MLP apart from ``layers`` [the rest, ...],
+        the scanned expert stack (every layer, where the model has no
+        experts or no leading dense layer)."""
+        cfg, dt = self.cfg, jnp.float32
+        h, v, nh = cfg.hidden_size, cfg.vocab_size, cfg.num_heads
+        L, std = cfg.num_layers, 0.02
+        out_std = std / math.sqrt(2 * L)
+
+        def init(key, shape, scale=std):
+            return (jax.random.normal(key, shape, jnp.float32)
+                    * scale).astype(dt)
+
+        def attention(key, n):
+            ks = jax.random.split(key, 5)
+            qk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+            return {
+                "attn_norm": jnp.ones((n, h), dt),
+                "wq_a": init(ks[0], (n, h, cfg.q_lora_rank)),
+                "q_norm": jnp.ones((n, cfg.q_lora_rank), dt),
+                "wq_b": init(ks[1], (n, cfg.q_lora_rank, nh * qk)),
+                "wkv_a": init(ks[2], (n, h, cfg.latent_row)),
+                "kv_norm": jnp.ones((n, cfg.kv_lora_rank), dt),
+                "wkv_b": init(ks[3], (n, cfg.kv_lora_rank, nh * (
+                    cfg.qk_nope_head_dim + cfg.v_head_dim))),
+                "wo": init(ks[4], (n, nh * cfg.v_head_dim, h), out_std),
+                "mlp_norm": jnp.ones((n, h), dt)}
+
+        def dense(key, n):
+            ks, ffn = jax.random.split(key, 3), cfg.intermediate_size
+            return {"w_gate": init(ks[0], (n, h, ffn)),
+                    "w_up": init(ks[1], (n, h, ffn)),
+                    "w_down": init(ks[2], (n, ffn, h), out_std)}
+
+        def experts(key, n):
+            ks, E, f = jax.random.split(key, 5), cfg.moe_num_experts, \
+                cfg.expert_size
+            return {"moe_gate_w": init(ks[0], (n, h, E)),
+                    "e_gate": init(ks[1], (n, E, h, f)),
+                    "e_up": init(ks[2], (n, E, h, f)),
+                    "e_down": init(ks[3], (n, E, f, h), out_std),
+                    **self._init_deployed_router(ks[4], n, init, out_std)}
+
+        k = jax.random.split(rng, 7)
+        lead = cfg.moe_first_dense_layers
+        params = {"embed": init(k[0], (v, h)),
+                  "final_norm": jnp.ones((h,), dt),
+                  "layers": {**attention(k[1], L - lead),
+                             **(experts(k[2], L - lead)
+                                if cfg.moe_num_experts else
+                                dense(k[2], L - lead))}}
+        if lead:
+            params["lead_layers"] = {**attention(k[3], lead),
+                                     **dense(k[4], lead)}
+        if not cfg.tie_embeddings:
+            params["lm_head"] = init(k[5], (h, v))
+        return params
+
     # -- sharding (TP over "model", PP over "pipe"; ZeRO composes on top) --
     def param_partition_specs(self, topo) -> Dict[str, Any]:
         cfg = self.cfg
+        if cfg.attention == "mla":
+            # served at tp = ep = 1 only (engine_v2 refuses the rest):
+            # every leaf whole on its device
+            shapes = jax.eval_shape(self.init_params, jax.random.PRNGKey(0))
+            return jax.tree.map(lambda x: P(*([None] * x.ndim)), shapes)
         tp = topo.axis_size("model") if "model" in topo.sizes else 1
         pp = topo.axis_size("pipe") if "pipe" in topo.sizes else 1
         pipe = "pipe" if pp > 1 else None
@@ -573,6 +757,11 @@ class TransformerLM:
             layer["e_gate"] = P(pipe, ep, None, "model" if tp > 1 else None)
             layer["e_up"] = P(pipe, ep, None, "model" if tp > 1 else None)
             layer["e_down"] = P(pipe, ep, "model" if tp > 1 else None, None)
+            if cfg.moe_selection_bias:
+                layer["moe_gate_bias"] = P(pipe, None)
+            if cfg.moe_shared_experts:
+                layer["shared_gate"] = layer["shared_up"] = col
+                layer["shared_down"] = row
             if cfg.moe_use_residual:
                 layer["res_gate"] = col
                 layer["res_up"] = col
@@ -800,6 +989,7 @@ class TransformerLM:
 
     def forward_hidden(self, params, input_ids):
         cfg = self.cfg
+        cfg.refuse_served_only("TransformerLM's training forward")
         with jax.named_scope("embed"):
             x = params["embed"][input_ids]                # [B, S, H] gather
             if cfg.embed_scale != 1.0:
@@ -1315,6 +1505,7 @@ class TransformerLM:
         Returns (logits [B, S, V], new_cache). Used for both prefill
         (start_pos=0, S=prompt) and decode (S=1)."""
         cfg = self.cfg
+        cfg.refuse_served_only("TransformerLM.forward_cached")
         max_len = cache["k"].shape[3]
         S = input_ids.shape[1]
         x = params["embed"][input_ids].astype(cache["k"].dtype)

@@ -219,18 +219,101 @@ def ragged_swiglu_experts(expert_params, xs, group_sizes):
     return jax.lax.ragged_dot(jax.nn.silu(g) * u, wd, group_sizes)
 
 
+# the most an expert's [K, N] weight may hold for the grouped-matmul
+# kernel to take it as ONE tile (twice that is resident: the next
+# expert's arrives while this one is used)
+_GMM_WHOLE_EXPERT_BYTES = 4 * 2 ** 20
+_GMM_ROWS = 128
+
+
+def gmm_swiglu_experts(expert_params, xs, group_sizes):
+    """``ragged_swiglu_experts`` through the Pallas grouped matmul JAX
+    ships (``jax.experimental.pallas.ops.tpu.megablox``), a row tile of
+    128 against an expert's WHOLE [K, N] weight: a launch then streams
+    each touched expert's weights once and nothing else, where XLA's own
+    ``ragged_dot`` kernel took 2.5 times as long at 256 experts of
+    2048 x 768 and two rows an expert (9.03 ms against 3.66 a layer; at
+    65,536 rows 17.0 against 9.15; PERF.md section 6, PR 36). Serving
+    only (no caller differentiates it), on a TPU, where
+    :func:`gmm_serves` says the weights fit. A trace shows the kernels as
+    ``gmm.N`` (the name of JAX's jitted function around them)."""
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
+    wg, wu, wd = expert_params
+    m = xs.shape[0]
+    pad = (-m) % _GMM_ROWS          # rows past the last group: not computed
+    if pad:
+        xs = jnp.pad(xs, ((0, pad), (0, 0)))
+
+    def mm(x, w):
+        return gmm(x, w, group_sizes, preferred_element_type=x.dtype,
+                   tiling=(_GMM_ROWS, w.shape[1], w.shape[2]))
+
+    return mm(jax.nn.silu(mm(xs, wg)) * mm(xs, wu), wd)[:m]
+
+
+def gmm_serves(expert_params) -> bool:
+    """Whether :func:`gmm_swiglu_experts` takes these experts: on a TPU,
+    every weight's two widths whole 128-lane blocks, one expert's weight
+    small enough to be a tile."""
+    if jax.default_backend() != "tpu":
+        return False
+    return all(w.shape[-1] % 128 == 0 and w.shape[-2] % 128 == 0
+               and w.shape[-1] * w.shape[-2] * w.dtype.itemsize
+               <= _GMM_WHOLE_EXPERT_BYTES for w in expert_params)
+
+
+def topk_routing(logits, k: int, scoring: str = "softmax", bias=None,
+                 normalize: bool = True, scale: float = 1.0):
+    """Router logits [T, E] (float32) -> (chosen experts [T, k], their
+    weights [T, k]), as served. ``scoring`` makes a logit a score
+    (softmax over the experts, or an independent sigmoid); ``bias`` [E]
+    is added to the scores to CHOOSE the top k and is no part of a
+    weight (DeepSeek-V3's ``noaux_tc``: the bias balances load without a
+    loss term; its group limit with one group of experts is the identity
+    and is not written here); the k > 1 chosen weights are normalised
+    over the chosen set where ``normalize`` (top-1 keeps its raw score:
+    top1gating's g1), then scaled."""
+    scores = (jax.nn.softmax(logits, axis=-1) if scoring == "softmax"
+              else jax.nn.sigmoid(logits))
+    if bias is None:
+        topv, topi = jax.lax.top_k(scores, k)
+    else:
+        _, topi = jax.lax.top_k(scores + bias.astype(scores.dtype), k)
+        topv = jnp.take_along_axis(scores, topi, axis=-1)
+    if k > 1 and normalize:
+        total = jnp.sum(topv, axis=-1, keepdims=True)
+        # the published code's guard; a softmax's chosen never sum to 0
+        topv = topv / (total + 1e-20 if scoring == "sigmoid" else total)
+    if scale != 1.0:
+        topv = topv * scale
+    return topi, topv
+
+
 def dropless_topk_dispatch(xt, topi, topv, expert_params, num_experts: int,
-                           ragged_expert_fn=None):
+                           ragged_expert_fn=None, stack_layer=None):
     """Sorted-token grouped-GEMM core shared by the training dropless MoE
     and the v2 serving path (_moe_mlp): route every (token, choice) row to
     its expert with one argsort + `jax.lax.ragged_dot`, unsort, and weight
-    by the gate value. xt: [T, H]; topi/topv: [T, k]. Returns [T, H]."""
+    by the gate value. xt: [T, H]; topi/topv: [T, k]. Returns [T, H].
+
+    ``stack_layer`` (a traced scalar): ``expert_params`` are a whole
+    scanned stack's, [L, E, ...], and the layer is chosen by WHERE its
+    groups lie among L * E (every other group is empty), so that the
+    grouped matmul reads the stack in place: a layer sliced out of the
+    stack for a custom call is a copy of all its experts."""
     T, H = xt.shape
     k = topi.shape[-1]
     idx = topi.reshape(-1)                       # [T*k], token-major
     order = jnp.argsort(idx)                     # stable
     xs = xt[order // k]                          # row t*k+j <-> (token t, j)
     group_sizes = jnp.bincount(idx, length=num_experts).astype(jnp.int32)
+    if stack_layer is not None:
+        L = expert_params[0].shape[0]
+        group_sizes = jax.lax.dynamic_update_slice(
+            jnp.zeros((L * num_experts,), jnp.int32), group_sizes,
+            (stack_layer * num_experts,))
+        expert_params = tuple(w.reshape(-1, *w.shape[2:])
+                              for w in expert_params)
     fn = ragged_expert_fn or ragged_swiglu_experts
     ys = fn(expert_params, xs, group_sizes)      # [T*k, H]
     ys = jnp.zeros_like(ys).at[order].set(ys)    # unsort

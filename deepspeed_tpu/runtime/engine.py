@@ -162,6 +162,8 @@ class DeepSpeedTpuEngine:
         # lower_train_step is usable on such an engine.
         self._abstract_init = abstract_init
         self.model = model
+        if hasattr(getattr(model, "cfg", None), "refuse_served_only"):
+            model.cfg.refuse_served_only("the trainer (runtime/engine.py)")
         self.ds_config = config
         self.config = config.cfg
         self.topology = topology or build_topology(config)

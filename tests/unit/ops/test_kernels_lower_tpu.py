@@ -394,3 +394,100 @@ def test_opt_prefill_compiles(tpu_sharding):
                                                  use_kernel=True),
         params, i32(1, C), i32(), cache, i32(C), i32(C))
     assert err is None, err
+
+
+# ---------------------------------------------------------------------------
+# the latent (MLA) pool: joyai-llm-flash.rollout-64x256's geometry
+# ---------------------------------------------------------------------------
+# its two launches: the 8,192-token prefill of 64 rows and a decode step
+# of 64 rows, over 1,601 blocks of 16 in 5 layers; (tokens, rows, table)
+LATENT_LAUNCHES = {"prefill": (8192, 64, 32), "decode": (64, 64, 32)}
+# benchmark/layer_metrics' patterns for latent_*.gen and experts_*.gen
+LATENT_PATTERN = re.compile(r"ragged_attention_latent[_.0-9]*$")
+GMM_PATTERN = re.compile(r"^gmm[_.0-9]*$")
+
+
+def _latent_args(sharding, T, R, MB, row):
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+    return (sds((32, T, row), jnp.bfloat16),
+            sds((5, 1601, 16, row), jnp.bfloat16), sds((), jnp.int32),
+            sds((T,), jnp.int32), sds((T,), jnp.int32),
+            sds((R, MB), jnp.int32))
+
+
+@pytest.mark.parametrize("launch", sorted(LATENT_LAUNCHES))
+def test_latent_kernel_at_the_cells_launch_shapes(tpu_sharding, launch):
+    """32 heads against one 640-lane row (512 + 64, padded) at the
+    cell's shapes: the kernel compiles, under the name the benchmark's
+    readers find."""
+    from deepspeed_tpu.inference.v2.kernels.ragged_attention import \
+        latent_attention
+    text = jax.jit(lambda *a: latent_attention(
+        *a, dc=512, scale=192 ** -0.5)).lower(*_latent_args(
+            tpu_sharding, *LATENT_LAUNCHES[launch], 640)).compile().as_text()
+    kernels = re.findall(r"%([\w.\-]+) = [^\n]*tpu_custom_call", text)
+    assert len(kernels) == 1 and LATENT_PATTERN.search(kernels[0]), kernels
+
+
+def test_a_latent_row_that_is_not_whole_lane_blocks_is_refused(tpu_sharding):
+    """Why ``paged_model.latent_pool_row`` pads 576 lanes to 640: Mosaic
+    copies no page whose row is not whole 128-lane blocks."""
+    from deepspeed_tpu.inference.v2.kernels.ragged_attention import \
+        latent_attention
+    err = _compile_error(
+        lambda *a: latent_attention(*a, dc=512, scale=192 ** -0.5),
+        *_latent_args(tpu_sharding, *LATENT_LAUNCHES["decode"], 576))
+    assert err is not None and "aligned to tiling" in err, err
+
+
+def test_latent_ragged_step_compiles_with_its_experts_in_place(tpu_sharding):
+    """The whole ragged step at the published widths, depth cut to the
+    leading dense layer and ONE expert layer of 256 experts: it compiles
+    for the chip, the latent kernel runs in both stacks and the grouped
+    matmul three times under names a trace finds, and no instruction
+    holds a copy of a layer's experts (the kernel reads the stack where
+    it lies; a sliced layer would be a 1.2 GB copy a launch)."""
+    import json
+    from pathlib import Path
+    from deepspeed_tpu.inference.v2.paged_model import (init_paged_kv_cache,
+                                                        paged_ragged_step)
+    from deepspeed_tpu.models import TransformerLM
+    from deepspeed_tpu.models.transformer import TransformerConfig
+
+    fields = json.loads((Path(__file__).resolve().parents[3]
+                         / "benchmark/configs/joyai-llm-flash.json"
+                         ).read_text())["fields"]
+    cfg = TransformerConfig(**{**fields, "num_layers": 2})
+
+    def on_tpu(x, dtype=None):
+        return jax.ShapeDtypeStruct(x.shape, dtype or x.dtype,
+                                    sharding=tpu_sharding)
+
+    params = jax.tree.map(
+        lambda x: on_tpu(x, jnp.bfloat16),
+        jax.eval_shape(TransformerLM(cfg).init_params,
+                       jax.random.PRNGKey(0)))
+    cache = jax.tree.map(on_tpu, jax.eval_shape(
+        lambda: init_paged_kv_cache(cfg, 129, 16, jnp.bfloat16)))
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=tpu_sharding)
+
+    T, R, MB = 512, 8, 16
+    compiled = jax.jit(
+        lambda p, ids, rows, pos, ln, wb, wo, bt, li, c: paged_ragged_step(
+            cfg, p, ids, rows, pos, ln, wb, wo, bt, li, c, 16,
+            use_kernel=True), donate_argnums=(9,)).lower(
+        params, i32(T), i32(T), i32(T), i32(T), i32(T), i32(T), i32(R, MB),
+        i32(R), cache).compile()
+    text = compiled.as_text()
+    kernels = re.findall(r"%([\w.\-]+) = [^\n]*tpu_custom_call", text)
+    assert sum(bool(LATENT_PATTERN.search(k)) for k in kernels) == 2, kernels
+    assert sum(bool(GMM_PATTERN.search(k)) for k in kernels) == 3, kernels
+    made = re.findall(r"%[\w.\-]+ = bf16\[(?:1,)?256,(?:2048,768|768,2048)\]"
+                      r"\S* (\w[\w\-]*)\(", text)
+    assert set(made) <= {"parameter", "bitcast", "get-tuple-element"}, made
+    # the experts are arguments read in place: the program's temporaries
+    # are far under one expert matrix stack (0.4 GB)
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.2e9
