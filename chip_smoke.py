@@ -99,7 +99,8 @@ def free_device_memory():
 # K — kernels
 # ---------------------------------------------------------------------------
 def ragged_reference(q, kc, vc, rows, lens, tables, ks=None, vs=None):
-    """Dense jax.numpy reference for the paged kernels: gather each row's
+    """Dense jax.numpy reference for the paged kernels over ONE layer,
+    kc/vc ``[nb, bs, kvh, hd]``: gather each row's
     pages (dequantizing like paged_model._kv_read), mask to each token's
     causal bound, plain softmax in fp32. Padding tokens give zeros."""
     import jax
@@ -190,18 +191,31 @@ def phase_kernels(sizes):
                     (nb, bs, kvh, hd)), jnp.bfloat16), None
 
             (kc, ks), (vc, vs) = pool(), pool()
+
+            def stored(c):
+                """The pool as the engine stores it, [L, nb, bs, kvh *
+                hd], whole, ``c`` its layer 1 of 3; the kernel takes the
+                layer as a scalar and may read no other (NaN; int8:
+                -128)."""
+                return jnp.full((3, nb, bs, kvh * hd),
+                                -128 if quant else jnp.nan,
+                                c.dtype).at[1].set(c.reshape(nb, bs, -1))
+
+            kp, vp = stored(kc), stored(vc)
             tables = jnp.asarray(rng.permutation(np.arange(1, nb))
                                  [:R * MB].reshape(R, MB), jnp.int32)
             q = jnp.asarray(rng.standard_normal((T, nh, hd)), jnp.bfloat16)
             rows_a = jnp.asarray(rows, jnp.int32)
             lens_a = jnp.asarray(lens, jnp.int32)
             ragged = jax.jit(ragged_attention)(
-                q, kc, vc, rows_a, lens_a, tables, k_scale=ks, v_scale=vs)
+                q, kp, vp, jnp.int32(1), rows_a, lens_a, tables,
+                k_scale=ks, v_scale=vs)
             e_r = rel_err(ragged, ragged_reference(
                 q, kc, vc, rows_a, lens_a, tables, ks, vs))
             dlen = jnp.asarray([1, 16, 77, 256], jnp.int32)
             decode = jax.jit(paged_attention)(
-                q[:R], kc, vc, tables, dlen, k_scale=ks, v_scale=vs)
+                q[:R], kp, vp, jnp.int32(1), tables, dlen,
+                k_scale=ks, v_scale=vs)
             e_d = rel_err(decode, ragged_reference(
                 q[:R], kc, vc, jnp.arange(R, dtype=jnp.int32), dlen,
                 tables, ks, vs))

@@ -22,16 +22,17 @@ from .ragged_attention import ragged_attention
 
 
 def paged_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
-                    v_cache: jnp.ndarray, block_tables: jnp.ndarray,
+                    v_cache: jnp.ndarray, layer, block_tables: jnp.ndarray,
                     lengths: jnp.ndarray,
                     k_scale: jnp.ndarray = None,
                     v_scale: jnp.ndarray = None,
                     variant: Optional[str] = None) -> jnp.ndarray:
-    """q [N, nh, hd]; k/v_cache [nb, bs, kvh, hd]; block_tables [N, MB]
-    int32; lengths [N] (valid tokens incl. the current one);
-    ``k_scale``/``v_scale`` [nb, kvh] for the int8 ``kv_quant`` pool.
-    Returns [N, nh, hd]."""
+    """q [N, nh, hd]; k/v_cache the pool's leaves whole,
+    [L, nb, bs, kvh * hd], and the ``layer`` to attend; block_tables
+    [N, MB] int32; lengths [N] (valid tokens incl. the current one);
+    ``k_scale``/``v_scale`` [nb, kvh], the layer's, for the int8
+    ``kv_quant`` pool. Returns [N, nh, hd]."""
     return ragged_attention(
-        q, k_cache, v_cache, jnp.arange(q.shape[0], dtype=jnp.int32),
+        q, k_cache, v_cache, layer, jnp.arange(q.shape[0], dtype=jnp.int32),
         lengths, block_tables, k_scale=k_scale, v_scale=v_scale,
         variant=variant)

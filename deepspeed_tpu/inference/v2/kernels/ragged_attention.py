@@ -23,6 +23,10 @@ Descriptor layout (built by ``ragged.batch.RaggedBatch``):
   causal masking inside a chunk fall out of the same page walk decode
   rows use. 0 marks padding.
 * ``block_tables`` ``[R, MB]`` — each row's paged KV block table.
+* ``k_cache`` / ``v_cache`` ``[L, nb, bs, kvh * hd]`` and ``layer`` —
+  the pool's leaves as stored, WHOLE, and which layer to attend (a
+  traced scalar, prefetched): a page is ``pool[layer, block]``, ``bs``
+  lane-dense rows, and it is read where it lies.
 
 The KV append for the new tokens is the jnp scatter in the surrounding
 jitted layer body (``paged_model.paged_ragged_step``) — the same
@@ -43,10 +47,10 @@ test_kernels_lower_tpu.py`` pins against the Mosaic compiler):
   chunks AND rows, up to the causal bound of the row's last token in the
   tile (a dynamic bound: no page past it is copied or visited), so a
   prefill chunk's keys are read once a tile and not once a token. The
-  pool is presented lane-dense, ``(nb * bs, kvh * hd)`` (a reshape in
-  the wrapper), so a page is ``bs`` rows that move their own bytes, and
-  a 128-lane block of it is two 64-wide heads or one head a multiple of
-  128 wide.
+  pool stays in HBM (``memory_space=ANY``) and a page is copied by
+  ``k_hbm.at[layer, page]``: ``bs`` rows of ``kvh * hd`` lanes, whole
+  tiles that move their own bytes; a 128-lane block of it is two
+  64-wide heads or one head a multiple of 128 wide.
   A block's query rows — tile tokens x GQA group x heads of the block,
   each zero outside its own head's lanes — go through the matrix unit
   together on the pool's bf16 with float32 accumulation
@@ -54,7 +58,9 @@ test_kernels_lower_tpu.py`` pins against the Mosaic compiler):
   token's own bound are masked, which is all in-tile causality is.
   Serves every geometry :func:`tiled_geometry` accepts.
 * ``"pipelined"`` (grid ``(T, MB)``, BlockSpec-indexed): one step a
-  token and table slot, all of a page's heads by a static loop
+  token and table slot, the page a ``(1, 1, bs, kvh * hd)`` block of
+  the same stored leaf by the index map ``(layer, bt[row, j])``, its
+  heads cut by static lane slices in a static loop
   (:func:`_page_update`). Streams every slot (compute is skipped past
   the causal bound, the copy is not), but the pipeline emitter pads
   unaligned pages itself, so it compiles where a page row is not whole
@@ -75,11 +81,17 @@ read from SMEM is the one operand Mosaic broadcasts over a whole tile.
 Both dequantize in VMEM through the pool's serving dtype, the arithmetic
 of ``paged_model._kv_read``.
 
-What the tiled variant wants of the pool (PERF.md section 7): the layer
-stored as ``[nb, bs, kvh * hd]``. At head width 64 the pool's
-``(kvh, hd)`` tiles pad 64 lanes to 128, no view of them is a bitcast,
-and XLA makes the wrapper's reshape in a pass of its own after the
-layer's slice; every other formulation tried on the chip cost more.
+What the pool is, and why (``paged_model.init_paged_kv_cache``): a
+layer is stored ``[nb, bs, kvh * hd]``. With ``(kvh, hd)`` as the minor
+axes, bf16 tiles of ``(16, 128)`` padded a 64-wide head to 128 lanes, no
+lane-dense view of a layer was a bitcast, and every launch paid for the
+layer's slice, a reshape pass and four pool-sized relayout copies round
+the kernel (3.0 s of a 4.8 s ``generate()`` call at OPT-1.3B; PERF.md
+section 6, PR 34 and 37). Stored lane-dense the pool has one layout at
+every head width, a page is contiguous, and both variants and the
+latent kernel address it the same way: the whole leaf, the layer a
+scalar. ``tests/unit/ops/test_kernels_lower_tpu.py`` pins, by AOT, that
+the serving programs hold no slice, reshape or copy of a layer.
 """
 
 import functools
@@ -113,7 +125,8 @@ def _interpret() -> bool:
 
 
 def kernel_variant(head_dim: int, kv_heads: int, kv_quant: bool) -> str:
-    """Which variant serves a ``[nb, bs, kv_heads, head_dim]`` pool:
+    """Which variant serves a pool of ``kv_heads`` heads of ``head_dim``
+    (stored ``[L, nb, bs, kv_heads * head_dim]`` whichever it is):
     ``"tiled"`` wherever a page row splits into whole 128-lane blocks of
     whole heads (:func:`tiled_geometry`), ``"pipelined"`` everywhere
     else. Decided from static config only — never by trying a compile —
@@ -123,23 +136,26 @@ def kernel_variant(head_dim: int, kv_heads: int, kv_quant: bool) -> str:
     return "tiled" if tiled_geometry(head_dim, kv_heads) else "pipelined"
 
 
-def _page_update(q_ref, k_tile, v_tile, ks, vs, j, length, acc_sc, m_sc,
+def _page_update(q_ref, k_ref, v_ref, ks, vs, j, length, acc_sc, m_sc,
                  l_sc, *, bs, scale, kvh, group, io_dtype):
     """One page's online-softmax update, all kv heads (shared by both
-    variants so their numerics cannot diverge). k_tile/v_tile are the
-    page's (bs, kvh, hd) tiles as stored; ks/vs map a head index to the
-    page's dequant scale for an int8 pool (None otherwise). The int8
+    variants so their numerics cannot diverge). k_ref/v_ref are the
+    page's (1, 1, bs, kvh * hd) block as stored, a head its ``hd`` lanes
+    (a static slice); ks/vs map a head index to the page's dequant scale
+    for an int8 pool (None otherwise). The int8
     dequant routes through the pool's serving dtype so it is the SAME
     arithmetic as paged_model._kv_read's gather dequant (bit-identical
     at fp32 io; one rounding at bf16). GQA is a static Python loop (kvh
     is a compile-time constant), each head updating its own rows of the
     flat (kvh*group, ...) scratch."""
     pos = j * bs + jax.lax.broadcasted_iota(jnp.int32, (group, bs), 1)
+    hd = q_ref.shape[-1]
     for h in range(kvh):                              # static unroll (GQA)
         rows = slice(h * group, (h + 1) * group)
+        lanes = slice(h * hd, (h + 1) * hd)
         q = q_ref[0, h].astype(jnp.float32)           # (group, hd)
-        k = k_tile[:, h, :].astype(jnp.float32)       # (bs, hd)
-        v = v_tile[:, h, :].astype(jnp.float32)
+        k = k_ref[0, 0, :, lanes].astype(jnp.float32)  # (bs, hd)
+        v = v_ref[0, 0, :, lanes].astype(jnp.float32)
         if ks is not None:
             k = (k * ks(h)).astype(io_dtype).astype(jnp.float32)
             v = (v * vs(h)).astype(io_dtype).astype(jnp.float32)
@@ -185,10 +201,10 @@ def _scale_rows(ks_ref, vs_ref, j, kvh):
             lambda h: vs_ref[0, 0, j * kvh + h])
 
 
-def _pipelined_kernel(row_ref, len_ref, bt_ref, q_ref, k_ref, v_ref, *rest,
-                      quant, n_pages, **static):
-    """Grid (T, MB): token t streams page j of ITS row's table (index
-    map ``bt[row[t], j]``)."""
+def _pipelined_kernel(layer_ref, row_ref, len_ref, bt_ref, q_ref, k_ref,
+                      v_ref, *rest, quant, n_pages, **static):
+    """Grid (T, MB): token t streams page j of ITS row's table out of
+    the whole pool (index map ``(layer, bt[row[t], j])``)."""
     (ks_ref, vs_ref), rest = (rest[:2], rest[2:]) if quant \
         else ((None, None), rest)
     o_ref, acc_sc, m_sc, l_sc = rest
@@ -204,7 +220,7 @@ def _pipelined_kernel(row_ref, len_ref, bt_ref, q_ref, k_ref, v_ref, *rest,
     @pl.when(j * static["bs"] < length)
     def _body():
         ks, vs = _scale_rows(ks_ref, vs_ref, j, static["kvh"])
-        _page_update(q_ref, k_ref[0], v_ref[0], ks, vs, j, length,
+        _page_update(q_ref, k_ref, v_ref, ks, vs, j, length,
                      acc_sc, m_sc, l_sc, **static)
 
     @pl.when(j == n_pages - 1)
@@ -347,18 +363,20 @@ def _tile_update(q, k, v, visible, acc_sc, m_sc, l_sc, b, *, scale):
     m_sc[b] = jnp.broadcast_to(m_new, m_sc.shape[1:])
 
 
-def _tiled_kernel(len_ref, bt_ref, first_ref, last_ref, lo_ref, hi_ref,
-                  q_ref, tl_ref, k_hbm, v_hbm, *rest, quant, bs, scale, kvh,
-                  hd, hpb, group, tq, cp, io_dtype):
+def _tiled_kernel(layer_ref, len_ref, bt_ref, first_ref, last_ref, lo_ref,
+                  hi_ref, q_ref, tl_ref, k_hbm, v_hbm, *rest, quant, bs,
+                  scale, kvh, hd, hpb, group, tq, cp, io_dtype):
     """Grid (T / tq,): one step a tile of ``tq`` flat tokens. The tile
     walks the rows that own its tokens (``lo_ref``/``hi_ref``), a row's
     pages in chunks of ``cp`` up to the causal bound of the row's last
     token in the tile — no page past it is copied or visited. A chunk's
     pages go by ``make_async_copy`` from the HBM pool into one of two
     VMEM slots; the next chunk (of this row or the next) is in flight
-    while this one is computed. The pools are ``(nb * bs, F)``, a page
-    ``bs`` rows of ``F = kvh * hd`` lanes; k_buf/v_buf are (2, cp, bs,
-    F); sem is (2, 2) = slot x {k, v}, one wait a page."""
+    while this one is computed. The pools are the stored leaves whole,
+    ``(L, nb, bs, F)``, the layer a prefetched scalar: a page is
+    ``k_hbm.at[layer, page]``, ``bs`` rows of ``F = kvh * hd`` lanes,
+    whole tiles where it lies; k_buf/v_buf are (2, cp, bs, F); sem is
+    (2, 2) = slot x {k, v}, one wait a page."""
     if quant:
         ks_hbm, vs_hbm, o_ref, k_buf, v_buf, ks_buf, vs_buf, acc_sc, m_sc, \
             l_sc, sem, ssem = rest
@@ -370,6 +388,7 @@ def _tiled_kernel(len_ref, bt_ref, first_ref, last_ref, lo_ref, hi_ref,
     sw = ks_buf.shape[0] // 2 if quant else 0   # a slot of scales, words
     t0 = pl.program_id(0) * tq
     lo, hi = lo_ref[pl.program_id(0)], hi_ref[pl.program_id(0)]
+    layer = layer_ref[0]
 
     _init_scratch(acc_sc, m_sc, l_sc)
 
@@ -379,11 +398,10 @@ def _tiled_kernel(len_ref, bt_ref, first_ref, last_ref, lo_ref, hi_ref,
         v_buf[...] = jnp.zeros_like(v_buf)
 
     def copies(page, slot, j):
-        rows = pl.ds(page * bs, bs)                    # its rows of the pool
-        return (pltpu.make_async_copy(k_hbm.at[rows], k_buf.at[slot, j],
-                                      sem.at[slot, 0]),
-                pltpu.make_async_copy(v_hbm.at[rows], v_buf.at[slot, j],
-                                      sem.at[slot, 1]))
+        return (pltpu.make_async_copy(k_hbm.at[layer, page],
+                                      k_buf.at[slot, j], sem.at[slot, 0]),
+                pltpu.make_async_copy(v_hbm.at[layer, page],
+                                      v_buf.at[slot, j], sem.at[slot, 1]))
 
     def scale_copies(r, c, slot):
         src = pl.ds(pl.multiple_of(
@@ -453,22 +471,20 @@ def _row_descriptors(row_ids, lengths, R, tq):
     return row_first, row_last, tile_lo, tile_hi
 
 
-def _tiled_call(q, k_cache, v_cache, row_ids, lengths, block_tables,
+def _tiled_call(q, k_cache, v_cache, layer, row_ids, lengths, block_tables,
                 k_scale, v_scale, interpret):
     """Lay the operands out for :func:`_tiled_kernel` and undo it: the
     per-row descriptor (first and last flat token, from ``row_ids`` and
     ``lengths``: a row's tokens are contiguous in pack order), a tile's
-    first and last row, queries as ``[block, row of the block, T, bw]``
-    and the pool lane-dense and two-dimensional, ``[nb * bs, kvh * hd]``:
-    that shape XLA reaches from the layer's slice with ONE native
-    reshape, ``[nb, bs, kvh * hd]`` with two transposing copies (PERF.md
-    section 6)."""
+    first and last row, and queries as ``[block, row of the block, T,
+    bw]``. The pools go in as they are stored, whole."""
     T0, nh, hd = q.shape
-    nb, bs, kvh, _ = k_cache.shape
+    bs, F = k_cache.shape[2:]
+    kvh = F // hd
     R, MB = block_tables.shape
     group = nh // kvh
     bw, hpb = tiled_geometry(hd, kvh)
-    nblk, rpb, F = kvh // hpb, hpb * group, kvh * hd
+    nblk, rpb = kvh // hpb, hpb * group
     quant = k_scale is not None
     # a tile: a power of two of 16 to 128 tokens, at most 512 query rows
     # a lane block where that leaves 16
@@ -500,8 +516,7 @@ def _tiled_call(q, k_cache, v_cache, row_ids, lengths, block_tables,
     in_specs = [pl.BlockSpec((nblk, rpb, tq, bw), tile),
                 pl.BlockSpec((tq, 1), lambda i, *_: (i, 0)),
                 pool_spec, pool_spec]
-    operands = [qx, lengths.reshape(T, 1), k_cache.reshape(nb * bs, F),
-                v_cache.reshape(nb * bs, F)]
+    operands = [qx, lengths.reshape(T, 1), k_cache, v_cache]
     M = rpb * tq
     scratch = [pltpu.VMEM((2, cp, bs, F), k_cache.dtype),
                pltpu.VMEM((2, cp, bs, F), v_cache.dtype)]
@@ -527,7 +542,7 @@ def _tiled_call(q, k_cache, v_cache, row_ids, lengths, block_tables,
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=6,
+            num_scalar_prefetch=7,
             grid=(T // tq,),
             in_specs=in_specs,
             out_specs=pl.BlockSpec((nblk, group, tq, bw), tile),
@@ -538,14 +553,14 @@ def _tiled_call(q, k_cache, v_cache, row_ids, lengths, block_tables,
             vmem_limit_bytes=_TILED_VMEM_BYTES),
         interpret=pltpu.InterpretParams() if interpret else False,
         name="ragged_attention_tiled",
-    )(lengths, block_tables, row_first, row_last, tile_lo, tile_hi,
+    )(layer, lengths, block_tables, row_first, row_last, tile_lo, tile_hi,
       *operands)
     out = out.reshape(nblk, group, T, hpb, hd).transpose(2, 0, 3, 1, 4)
     return out.reshape(T, nh, hd)[:T0]
 
 
 def ragged_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
-                     v_cache: jnp.ndarray, row_ids: jnp.ndarray,
+                     v_cache: jnp.ndarray, layer, row_ids: jnp.ndarray,
                      lengths: jnp.ndarray,
                      block_tables: jnp.ndarray,
                      k_scale: jnp.ndarray = None,
@@ -553,18 +568,23 @@ def ragged_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
                      variant: Optional[str] = None) -> jnp.ndarray:
     """Ragged paged attention (serving hot path).
 
-    q [T, nh, hd] flat token buffer; k/v_cache [nb, bs, kvh, hd];
-    row_ids [T] token -> batch row; lengths [T] per-token causal bound
-    (0 = padding); block_tables [R, MB] int32. For the int8 ``kv_quant``
-    pool, ``k_scale``/``v_scale`` [nb, kvh] are the per-(block, head)
-    dequant scales — the kernel dequantizes in VMEM, so quantized KV
+    q [T, nh, hd] flat token buffer; k/v_cache the pool's leaves as
+    stored, WHOLE, [L, nb, bs, kvh * hd] (``paged_model.
+    init_paged_kv_cache``; the kv heads are the row's lanes over hd),
+    ``layer`` a traced scalar: a launch reads its layer's pages where
+    they lie and nothing cuts a layer out. row_ids [T] token -> batch
+    row; lengths [T] per-token causal bound (0 = padding); block_tables
+    [R, MB] int32. For the int8 ``kv_quant`` pool, ``k_scale``/
+    ``v_scale`` [nb, kvh] are the LAYER's per-(block, head) dequant
+    scales — the kernel dequantizes in VMEM, so quantized KV
     serves through the SAME one-program ragged family. ``variant``
     defaults to :func:`kernel_variant`'s static choice for the pool
     geometry; off-TPU the default is the pipelined variant in interpret
     mode, and ``variant="tiled"`` runs the tiled one under the TPU
     interpreter, DMAs and semaphores included. Returns [T, nh, hd]."""
     T, nh, hd = q.shape
-    nb, bs, kvh, _ = k_cache.shape
+    bs, F = k_cache.shape[2:]
+    kvh = F // hd
     MB = block_tables.shape[1]
     group = nh // kvh
     quant = k_scale is not None
@@ -579,28 +599,29 @@ def ragged_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
     row_ids = row_ids.astype(jnp.int32)
     lengths = lengths.astype(jnp.int32)
     block_tables = block_tables.astype(jnp.int32)
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
     if variant == "tiled":
         if tiled_geometry(hd, kvh) is None:
             raise ValueError(f"no tiled variant for {kvh} kv heads of {hd}")
-        return _tiled_call(q, k_cache, v_cache, row_ids, lengths,
+        return _tiled_call(q, k_cache, v_cache, layer, row_ids, lengths,
                            block_tables, k_scale, v_scale, interpret)
     q4 = q.reshape(T, kvh, group, hd)
     static = dict(bs=bs, scale=1.0 / (hd ** 0.5), kvh=kvh, group=group,
                   io_dtype=q.dtype)
 
-    # index maps see (grid indices..., row_ids, lengths, block_tables)
+    # index maps see (grid indices..., layer, row_ids, lengths, block_tables)
     def tok(t, *_):
         return (t, 0, 0, 0)
 
     def row_scales(t, *a):
         return (a[-3][t], 0, 0)
 
-    def page(t, j, row, ln, bt):
-        return (bt[row[t], j], 0, 0, 0)
+    def page(t, j, layer, row, ln, bt):
+        return (layer[0], bt[row[t], j], 0, 0)
 
     in_specs = [pl.BlockSpec((1, kvh, group, hd), tok),
-                pl.BlockSpec((1, bs, kvh, hd), page),
-                pl.BlockSpec((1, bs, kvh, hd), page)]
+                pl.BlockSpec((1, 1, bs, F), page),
+                pl.BlockSpec((1, 1, bs, F), page)]
     operands = [q4, k_cache, v_cache]
     if quant:
         R = block_tables.shape[0]
@@ -613,7 +634,7 @@ def ragged_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
         functools.partial(_pipelined_kernel, quant=quant, n_pages=MB,
                           **static),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=4,
             grid=(T, MB),
             in_specs=in_specs,
             out_specs=pl.BlockSpec((1, kvh, group, hd), tok),
@@ -625,7 +646,7 @@ def ragged_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
         out_shape=jax.ShapeDtypeStruct((T, kvh, group, hd), q.dtype),
         interpret=interpret,
         name="ragged_attention_pipelined",
-    )(row_ids, lengths, block_tables, *operands)
+    )(layer, row_ids, lengths, block_tables, *operands)
     return out.reshape(T, nh, hd)
 
 

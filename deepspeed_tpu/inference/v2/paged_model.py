@@ -15,7 +15,16 @@ Two entry points, both pure and jit-compiled by the engine:
     each sequence's next slot, attention over the sequence's block table
     (gathered pages), returns [N, V] logits.
 
-The KV pool is ``[L, num_blocks, block_size, kv_heads, head_dim]``; block 0
+The KV pool is ``[L, num_blocks, block_size, kv_heads * head_dim]``: a
+cached position is ONE lane-dense row, its kv heads side by side (head h
+the lanes ``[h * hd, (h + 1) * hd)``). The two minor axes ``(block_size,
+kv_heads * head_dim)`` are whole TPU tiles at every head width (``(kv_heads,
+head_dim) = (32, 64)`` as minor axes padded 64 lanes to 128, and every launch
+paid a slice, a reshape pass and relayout copies for it), a page is
+contiguous, and the attention kernels take the leaves WHOLE with the layer
+as a scalar and copy a page from where it lies (kernels/ragged_attention.py).
+One stored layout for every geometry and dtype; ``_kv_write`` and
+``_kv_read`` are the only code that knows it. Block 0
 is the null block (padding writes land there). Static shapes throughout:
 prompt lengths bucket to multiples of ``prefill_bucket`` and the decode
 batch pads to the next power-of-two bucket — each bucket compiles once
@@ -54,15 +63,19 @@ def init_paged_kv_cache(cfg: TransformerConfig, num_blocks: int,
     decode/ragged kernels dequantize IN-KERNEL: one scale per (streamed
     page, kv head), so int8 KV serves through the same one-program
     kernel family as bf16 (kernels/ragged_attention.py). Scales init to
-    0 = "nothing written"."""
+    0 = "nothing written".
+
+    ``k``/``v`` are ``[L, nb, bs, kv_heads * head_dim]`` for every
+    geometry and dtype (the module docstring says why); the int8 scales
+    ``[L, nb, kv_heads]``."""
     assert cfg.is_causal and cfg.norm_scheme == "pre", \
         "paged serving requires a causal pre-LN model (the MLM/post-LN " \
         "encoder family does not decode)"
     if cfg.attention == "mla":
         return _init_latent_cache(cfg, num_blocks, block_size, dtype,
                                   kv_quant)
-    shape = (cfg.num_layers, num_blocks, block_size, cfg.kv_heads,
-             cfg.head_dim)
+    shape = (cfg.num_layers, num_blocks, block_size,
+             cfg.kv_heads * cfg.head_dim)
     if kv_quant:
         sshape = (cfg.num_layers, num_blocks, cfg.kv_heads)
         return {"k": jnp.zeros(shape, jnp.int8),
@@ -104,18 +117,22 @@ def _init_latent_cache(cfg, num_blocks, block_size, dtype, kv_quant):
 
 
 def _kv_write(kc, ksc, l, blocks, offs, k):
-    """Scatter one write-set into the pool. Under kv_quant the pool is
+    """Scatter one write-set ``k`` [C, kvh, hd] into the pool, a token
+    one ``kvh * hd`` row. Under kv_quant the pool is
     int8 with per-(block, kv-head) scales: the block scale is a running
     absmax over everything written to the block, so a write whose
     magnitude exceeds the current scale first rescales the block's
     existing int8 content to the grown scale (deterministic
     round-to-nearest requant — grow-only, so earlier tokens only ever
-    lose up to half an LSB per growth), then quantizes the new tokens.
+    lose up to half an LSB per growth; a (block, head) ratio spread over
+    the head's ``hd`` lanes), then quantizes the new tokens.
     Duplicate block indices in one write-set (a prefill chunk spanning a
     block) scatter identical per-block values, so the duplicate-index
     writes stay deterministic; the final per-slot writes are unique."""
+    C, _, hd = k.shape
     if ksc is None:
-        return kc.at[l, blocks, offs].set(k.astype(kc.dtype)), None
+        return kc.at[l, blocks, offs].set(
+            k.astype(kc.dtype).reshape(C, -1)), None
     xf = k.astype(jnp.float32)                          # [C, kvh, hd]
     tok_scale = jnp.max(jnp.abs(xf), axis=-1) / 127.0   # [C, kvh]
     old = ksc[l]                                        # [nb, kvh]
@@ -123,9 +140,9 @@ def _kv_write(kc, ksc, l, blocks, offs, k):
 
     def _requant(c):
         ratio = jnp.where(new > 0, old / jnp.where(new > 0, new, 1.0), 0.0)
-        r_tok = ratio[blocks]                           # [C, kvh]
-        pages = c[l, blocks].astype(jnp.float32)        # [C, bs, kvh, hd]
-        pages = jnp.round(pages * r_tok[:, None, :, None])
+        r_tok = jnp.repeat(ratio[blocks], hd, axis=-1)  # [C, kvh * hd]
+        pages = c[l, blocks].astype(jnp.float32)        # [C, bs, kvh * hd]
+        pages = jnp.round(pages * r_tok[:, None, :])
         return c.at[l, blocks].set(pages.astype(jnp.int8))
 
     # steady-state decode almost never grows a block's absmax, so the
@@ -137,7 +154,7 @@ def _kv_write(kc, ksc, l, blocks, offs, k):
                       lambda c: c, kc)
     s_tok = jnp.where(new > 0, new, 1.0)[blocks]        # [C, kvh]
     q = jnp.clip(jnp.round(xf / s_tok[..., None]), -127, 127)
-    kc = kc.at[l, blocks, offs].set(q.astype(jnp.int8))
+    kc = kc.at[l, blocks, offs].set(q.astype(jnp.int8).reshape(C, -1))
     return kc, ksc.at[l].set(new)
 
 
@@ -148,12 +165,16 @@ def _cache_dict(kc, vc, ksc, vsc):
     return out
 
 
-def _kv_read(kc, ksc, l, table, dtype):
-    """Gather pages [*, bs, kvh, hd], dequantizing when scales exist
-    (per-block scale row broadcast over the page's slot and head-dim
-    axes — the same multiply the kernels run per head slice of a page,
-    so kernel and gather dequant agree bit-for-bit at fp32)."""
+def _kv_read(kc, ksc, l, table, kvh, dtype):
+    """Gather layer ``l``'s pages by ``table`` and cut a stored row into
+    its heads: [*table.shape, bs, kvh, hd], dequantizing when scales
+    exist (per-block scale row broadcast over the page's slot and
+    head-dim axes — the same multiply the kernels run per head slice of
+    a page, so kernel and gather dequant agree bit-for-bit at fp32).
+    The ``jnp:gather`` path (parity reference, tp > 1, alibi): under
+    GSPMD the reshape is where the merged lane axis splits by heads."""
     pages = kc[l][table]
+    pages = pages.reshape(pages.shape[:-1] + (kvh, -1))
     if ksc is None:
         return pages
     return (pages.astype(jnp.float32)
@@ -741,9 +762,9 @@ def paged_continue(cfg: TransformerConfig, params, ids: jnp.ndarray,
             k = _rotate(k, cos[:, None], sin[:, None])
         kc, ksc = _kv_write(kc, ksc, l, block_ids, offsets, k)
         vc, vsc = _kv_write(vc, vsc, l, block_ids, offsets, v)
-        kpages = _kv_read(kc, ksc, l, block_table,
+        kpages = _kv_read(kc, ksc, l, block_table, nkv,
                           x.dtype).reshape(ctx, nkv, hd)
-        vpages = _kv_read(vc, vsc, l, block_table,
+        vpages = _kv_read(vc, vsc, l, block_table, nkv,
                           x.dtype).reshape(ctx, nkv, hd)
         if nkv != nh:
             kpages = jnp.repeat(kpages, nh // nkv, axis=1)
@@ -847,14 +868,14 @@ def paged_decode(cfg: TransformerConfig, params, toks: jnp.ndarray,
         if use_kernel:
             from .kernels.paged_attention import paged_attention
             o = paged_attention(
-                q, kc[l], vc[l], block_tables, pos + 1,
+                q, kc, vc, l, block_tables, pos + 1,
                 k_scale=None if ksc is None else ksc[l],
                 v_scale=None if vsc is None else vsc[l]).reshape(N, nh * hd)
         else:
             # gather this sequence's pages: [N, MB, bs, nkv, hd] -> [N, ctx, ..]
-            kpages = _kv_read(kc, ksc, l, block_tables,
+            kpages = _kv_read(kc, ksc, l, block_tables, nkv,
                               x.dtype).reshape(N, ctx, nkv, hd)
-            vpages = _kv_read(vc, vsc, l, block_tables,
+            vpages = _kv_read(vc, vsc, l, block_tables, nkv,
                               x.dtype).reshape(N, ctx, nkv, hd)
             if nkv != nh:
                 kpages = jnp.repeat(kpages, nh // nkv, axis=2)
@@ -964,15 +985,15 @@ def paged_ragged_step(cfg: TransformerConfig, params, ids: jnp.ndarray,
         if use_kernel:
             from .kernels.ragged_attention import ragged_attention
             o = ragged_attention(
-                q, kc[l], vc[l], row_ids, lengths, block_tables,
+                q, kc, vc, l, row_ids, lengths, block_tables,
                 k_scale=None if ksc is None else ksc[l],
                 v_scale=None if vsc is None else vsc[l]).reshape(T, nh * hd)
         else:
             # gather each ROW's pages once, indirect per token: the
             # materializing fallback (parity reference + tp/alibi/quant)
-            kpages = _kv_read(kc, ksc, l, block_tables,
+            kpages = _kv_read(kc, ksc, l, block_tables, nkv,
                               x.dtype).reshape(RB, ctx, nkv, hd)
-            vpages = _kv_read(vc, vsc, l, block_tables,
+            vpages = _kv_read(vc, vsc, l, block_tables, nkv,
                               x.dtype).reshape(RB, ctx, nkv, hd)
             ktok = kpages[row_ids]                      # [T, ctx, nkv, hd]
             vtok = vpages[row_ids]
@@ -1166,15 +1187,15 @@ def _paged_verify(cfg: TransformerConfig, params, fed: jnp.ndarray,
         if use_kernel:
             from .kernels.ragged_attention import ragged_attention
             o = ragged_attention(
-                q.reshape(N * S, nh, hd), kc[l], vc[l], row_ids, lengths,
+                q.reshape(N * S, nh, hd), kc, vc, l, row_ids, lengths,
                 block_tables,
                 k_scale=None if ksc is None else ksc[l],
                 v_scale=None if vsc is None else vsc[l]
             ).reshape(N, S, nh * hd)
         else:
-            kpages = _kv_read(kc, ksc, l, block_tables,
+            kpages = _kv_read(kc, ksc, l, block_tables, nkv,
                               x.dtype).reshape(N, ctx, nkv, hd)
-            vpages = _kv_read(vc, vsc, l, block_tables,
+            vpages = _kv_read(vc, vsc, l, block_tables, nkv,
                               x.dtype).reshape(N, ctx, nkv, hd)
             if nkv != nh:
                 kpages = jnp.repeat(kpages, nh // nkv, axis=2)

@@ -385,6 +385,7 @@ class KVSpillTier:
             if set(chunk["kv"]) != set(self.engine.kv_cache):
                 raise ValueError("spill entry leaf set disagrees with "
                                  "the pool")
+            handoff.check_leaf_shapes(chunk["kv"], self.engine.kv_cache)
         except Exception as e:
             logger.warning(f"kv spill restore dropped a corrupt entry: {e}")
             self._m_dropped.inc()

@@ -72,6 +72,22 @@ def _scatter_blocks(leaf, idx, data):
     return leaf.at[:, idx].set(data)
 
 
+def check_leaf_shapes(kv: Dict, cache: Dict) -> None:
+    """Refuse content whose rows are not the pool's: a leaf ``[L, n,
+    ...]`` must carry the pool leaf's layers and its ``shape[2:]``. A
+    pack written by the five-axis per-head layout ``[L, n, bs, kvh,
+    hd]`` holds the same bytes as the stored ``[L, n, bs, kvh * hd]``
+    and is still refused, by shape, before anything is scattered."""
+    for key, leaf in cache.items():
+        got = tuple(np.shape(kv[key]))
+        if got[:1] + got[2:] != tuple(leaf.shape[:1] + leaf.shape[2:]):
+            raise ValueError(
+                f"kv leaf {key!r} of shape {got} does not fit the pool's "
+                f"{tuple(leaf.shape)}: layers and everything behind the "
+                f"block axis must match (the two replicas must share the "
+                f"KV layout)")
+
+
 def export_sequence(engine, uid: int, trace_ctx=None) -> Dict:
     """Snapshot ``uid``'s KV blocks and descriptor from ``engine`` into
     a host-side pack (plain numpy + ints). The sequence stays live on
@@ -318,6 +334,7 @@ class ChunkedRestore:
                 f"(corrupted in transfer)")
         if set(chunk["kv"]) != set(self.engine.kv_cache):
             raise ValueError("chunk leaf set disagrees with the pool")
+        check_leaf_shapes(chunk["kv"], self.engine.kv_cache)
         blocks = self.seq.blocks[i:j]
         nb = len(blocks)
         bucket = pow2_bucket(max(nb, 1),
@@ -374,6 +391,7 @@ def restore_sequence(engine, pack: Dict, uid: int) -> None:
             f"handoff pool-leaf mismatch: payload has "
             f"{sorted(pack['kv'])}, target pool has "
             f"{sorted(engine.kv_cache)} (kv_quant must match)")
+    check_leaf_shapes(pack["kv"], engine.kv_cache)
     nb = int(pack["n_blocks"])
     seq = sm.adopt_sequence(uid, nb, pack["seen_tokens"],
                             pack["token_log"])

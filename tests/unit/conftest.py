@@ -1,4 +1,4 @@
-"""Session-shared tiny-model fixtures.
+"""Session-shared tiny-model fixtures, and the paged pool as stored.
 
 Most inference/serving test modules build the SAME tiny transformer
 (vocab 128, hidden 64, 2 layers, 4/2 heads) with a module-scoped
@@ -38,3 +38,19 @@ def tiny_model_256():
 def tiny_model_128():
     """(model, params) for the max_seq_len=128 tiny serving model."""
     return _build_tiny(128)
+
+
+@pytest.fixture(scope="session")
+def stored_pool():
+    """``stored_pool(layer_kv, layers=1, at=0)``: the paged pool as
+    ``init_paged_kv_cache`` stores it, ``[L, nb, bs, kvh * hd]``, with
+    the ``[nb, bs, kvh, hd]`` array its layer ``at``. Every other layer
+    holds what no launch may read and no write ever makes: NaN (an int8
+    pool: -128)."""
+    def stored(layer_kv, layers=1, at=0):
+        nb, bs = layer_kv.shape[:2]
+        bad = -128 if layer_kv.dtype == jnp.int8 else jnp.nan
+        pool = jnp.full((layers, nb, bs, layer_kv[0, 0].size), bad,
+                        layer_kv.dtype)
+        return pool.at[at].set(layer_kv.reshape(nb, bs, -1))
+    return stored

@@ -56,13 +56,13 @@ def _engine(model, params, kernel=True, window=8, **kw):
 # kernel level
 # ---------------------------------------------------------------------------
 def _quant_pool(rng, nb, bs, kvh, hd):
-    """Random int8 pool + per-(block, head) scales."""
+    """Random int8 layer [nb, bs, kvh, hd] + per-(block, head) scales."""
     q = rng.integers(-127, 128, size=(nb, bs, kvh, hd)).astype(np.int8)
     s = rng.uniform(0.01, 0.2, size=(nb, kvh)).astype(np.float32)
     return jnp.asarray(q), jnp.asarray(s)
 
 
-def test_quant_ragged_kernel_matches_gather_dequant_reference():
+def test_quant_ragged_kernel_matches_gather_dequant_reference(stored_pool):
     from deepspeed_tpu.inference.v2.kernels.ragged_attention import \
         ragged_attention
 
@@ -81,8 +81,10 @@ def test_quant_ragged_kernel_matches_gather_dequant_reference():
     row_ids += [0] * pad
     lengths += [0] * pad
     q = jnp.asarray(rng.normal(size=(T, nh, hd)), jnp.float32)
+    # the pool as stored: [L, nb, bs, kvh * hd], layer 1 of two attended
+    kp, vp = stored_pool(kq, 2, 1), stored_pool(vq, 2, 1)
     out = np.asarray(ragged_attention(
-        q, kq, vq, jnp.asarray(row_ids, jnp.int32),
+        q, kp, vp, 1, jnp.asarray(row_ids, jnp.int32),
         jnp.asarray(lengths, jnp.int32), jnp.asarray(tables),
         k_scale=ks, v_scale=vs))
     # reference: dequantize like paged_model._kv_read, dense softmax
@@ -107,7 +109,7 @@ def test_quant_ragged_kernel_matches_gather_dequant_reference():
     np.testing.assert_allclose(out, ref, rtol=2e-5, atol=2e-5)
 
 
-def test_quant_ragged_pure_decode_matches_quant_decode_kernel():
+def test_quant_ragged_pure_decode_matches_quant_decode_kernel(stored_pool):
     from deepspeed_tpu.inference.v2.kernels.paged_attention import \
         paged_attention
     from deepspeed_tpu.inference.v2.kernels.ragged_attention import \
@@ -121,12 +123,95 @@ def test_quant_ragged_pure_decode_matches_quant_decode_kernel():
                                   np.int32))
     lengths = jnp.asarray([17, 30, 5, 32], jnp.int32)
     q = jnp.asarray(rng.normal(size=(4, nh, hd)), jnp.float32)
+    kp, vp = stored_pool(kq, 2, 1), stored_pool(vq, 2, 1)
     ragged = np.asarray(ragged_attention(
-        q, kq, vq, jnp.arange(4, dtype=jnp.int32), lengths, tables,
+        q, kp, vp, 1, jnp.arange(4, dtype=jnp.int32), lengths, tables,
         k_scale=ks, v_scale=vs))
-    decode = np.asarray(paged_attention(q, kq, vq, tables, lengths,
+    decode = np.asarray(paged_attention(q, kp, vp, 1, tables, lengths,
                                         k_scale=ks, v_scale=vs))
     np.testing.assert_array_equal(ragged, decode)
+
+
+# ---------------------------------------------------------------------------
+# the stored layout against the per-head one it replaced
+# ---------------------------------------------------------------------------
+def _per_head_write(kc, ksc, l, blocks, offs, k):
+    """``paged_model._kv_write`` as it was over a ``[L, nb, bs, kvh,
+    hd]`` pool (PR 36's tree), kept here as the semantics the stored
+    layout must reproduce bit for bit."""
+    if ksc is None:
+        return kc.at[l, blocks, offs].set(k.astype(kc.dtype)), None
+    xf = k.astype(jnp.float32)
+    tok_scale = jnp.max(jnp.abs(xf), axis=-1) / 127.0
+    old = ksc[l]
+    new = old.at[blocks].max(tok_scale)
+    ratio = jnp.where(new > 0, old / jnp.where(new > 0, new, 1.0), 0.0)
+    pages = jnp.round(kc[l, blocks].astype(jnp.float32)
+                      * ratio[blocks][:, None, :, None])
+    kc = jnp.where(jnp.any(tok_scale > old[blocks]),
+                   kc.at[l, blocks].set(pages.astype(jnp.int8)), kc)
+    s_tok = jnp.where(new > 0, new, 1.0)[blocks]
+    q = jnp.clip(jnp.round(xf / s_tok[..., None]), -127, 127)
+    return kc.at[l, blocks, offs].set(q.astype(jnp.int8)), \
+        ksc.at[l].set(new)
+
+
+def _per_head_read(kc, ksc, l, table, dtype):
+    pages = kc[l][table]
+    if ksc is None:
+        return pages
+    return (pages.astype(jnp.float32)
+            * ksc[l][table][..., None, :, None]).astype(dtype)
+
+
+@pytest.mark.parametrize("case", ["bf16", "int8-requant", "int8-steady"])
+def test_write_then_read_is_the_per_head_pools(case):
+    """Two write-sets into layer 1 of 3 through ``_kv_write`` and the
+    pages back through ``_kv_read``: the stored ``[L, nb, bs, kvh * hd]``
+    pool is the per-head pool's bytes reshaped, and the gathered pages
+    equal, bit for bit. int8: the second write either grows a block's
+    absmax (the requant branch rescales the block's earlier rows, a
+    (block, head) ratio over the head's lanes) or stays under it."""
+    from deepspeed_tpu.inference.v2 import paged_model as pm
+
+    L, nb, bs, kvh, hd, l = 3, 6, 16, 4, 8, 1
+    quant = case != "bf16"
+    dt = jnp.int8 if quant else jnp.bfloat16
+    rng = np.random.default_rng(7)
+    new = {"k": jnp.zeros((L, nb, bs, kvh * hd), dt)}
+    old = {"k": jnp.zeros((L, nb, bs, kvh, hd), dt)}
+    if quant:
+        new["ks"] = old["ks"] = jnp.zeros((L, nb, kvh), jnp.float32)
+    # a 20-token chunk over blocks 2 and 4, then one decode token in 4
+    writes = [(np.repeat([2, 4], [16, 4]), np.r_[0:16, 0:4], 1.0),
+              (np.array([4]), np.array([4]),
+               8.0 if case == "int8-requant" else 0.1)]
+    for blocks, offs, gain in writes:
+        x = jnp.asarray(rng.standard_normal((len(blocks), kvh, hd)) * gain,
+                        jnp.bfloat16)
+        grows = quant and bool(jnp.any(
+            jnp.max(jnp.abs(x.astype(jnp.float32)), -1) / 127.0
+            > new["ks"][l][blocks]))
+        b, o = jnp.asarray(blocks), jnp.asarray(offs)
+        new["k"], ks = jax.jit(pm._kv_write)(new["k"], new.get("ks"), l,
+                                            b, o, x)
+        old["k"], ks0 = jax.jit(_per_head_write)(old["k"], old.get("ks"), l,
+                                                 b, o, x)
+        if quant:
+            new["ks"], old["ks"] = ks, ks0
+    if quant:       # the second write took the branch the case names
+        assert grows == (case == "int8-requant")
+        np.testing.assert_array_equal(new["ks"], old["ks"])
+    np.testing.assert_array_equal(
+        np.asarray(new["k"]).reshape(old["k"].shape), np.asarray(old["k"]))
+    assert np.asarray(new["k"])[l, 4, 4].any()
+    assert not np.asarray(new["k"])[[0, 2]].any()      # the layer's alone
+    table = jnp.asarray([[2, 4, 0], [4, 0, 0]])
+    got = pm._kv_read(new["k"], new.get("ks"), l, table, kvh, jnp.bfloat16)
+    want = _per_head_read(old["k"], old.get("ks"), l, table, jnp.bfloat16)
+    assert got.shape == (2, 3, bs, kvh, hd)
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(want, np.float32))
 
 
 # ---------------------------------------------------------------------------
@@ -222,10 +307,11 @@ def test_quant_kernel_actually_runs_not_the_fallback(tiny, monkeypatch):
     seen = {}
     orig = rk.ragged_attention
 
-    def spy(q, kc, vc, rows, lens, bt, k_scale=None, v_scale=None):
+    def spy(q, kc, vc, layer, rows, lens, bt, k_scale=None, v_scale=None):
         seen["called"] = True
         seen["scales"] = k_scale is not None
-        return orig(q, kc, vc, rows, lens, bt, k_scale=k_scale,
+        seen["whole"] = kc.shape == eng.kv_cache["k"].shape
+        return orig(q, kc, vc, layer, rows, lens, bt, k_scale=k_scale,
                     v_scale=v_scale)
 
     monkeypatch.setattr(rk, "ragged_attention", spy)
@@ -233,6 +319,7 @@ def test_quant_kernel_actually_runs_not_the_fallback(tiny, monkeypatch):
     eng.put([1, 2], [list(range(3, 17)), [40]])
     assert seen.get("called") and seen.get("scales"), \
         "kv_quant must serve through the quant ragged kernel"
+    assert seen["whole"], "the kernel takes the pool whole, not a layer"
 
 
 def test_kv_pool_layout_and_capacity_gauge(tiny):
@@ -246,6 +333,9 @@ def test_kv_pool_layout_and_capacity_gauge(tiny):
         eng = _engine(model, params)
         L, nb, kvh = 2, 65, 2
         assert eng.kv_cache["k"].dtype == jnp.int8
+        # one stored layout: a position is one lane-dense row of its heads
+        assert eng.kv_cache["k"].shape == eng.kv_cache["v"].shape \
+            == (L, nb, 16, kvh * 16)
         assert eng.kv_cache["ks"].shape == (L, nb, kvh)
         assert eng.kv_cache["vs"].shape == (L, nb, kvh)
         from deepspeed_tpu.telemetry import get_registry
@@ -296,6 +386,57 @@ def test_handoff_roundtrip_quant_scales_bit_exact(tiny):
             dtype="float32", prefill_bucket=16), params=params)
     with pytest.raises(ValueError, match="pool-leaf mismatch"):
         handoff.restore_sequence(plain, back, uid=1)
+
+
+@pytest.mark.parametrize("kv_quant", [False, True], ids=["plain", "int8"])
+def test_a_pack_of_the_per_head_layout_is_refused_by_shape(tiny, kv_quant):
+    """A pack written when the pool was ``[L, nb, bs, kvh, hd]`` holds
+    the same bytes and no longer fits: both the whole-pack restore and
+    the chunked one refuse it with the two shapes in the message, adopt
+    nothing, and leave the pool as it was; the pack as exported today
+    still goes in."""
+    from deepspeed_tpu.inference.v2.serve import handoff
+
+    model, params = tiny
+
+    def build():
+        return InferenceEngineV2(
+            model, RaggedInferenceEngineConfig(
+                state_manager=DSStateManagerConfig(
+                    max_tracked_sequences=8, max_seq_len=128, num_blocks=65,
+                    block_size=16),
+                dtype="float32", prefill_bucket=16, kv_quant=kv_quant),
+            params=params)
+
+    src, dst = build(), build()
+    src.put([5], [np.arange(3, 40)])
+    pack = handoff.export_sequence(src, 5)
+    n = pack["n_blocks"]
+    assert pack["kv"]["k"].shape == (2, n, 16, 2 * 16)
+    stale = dict(pack, kv={
+        key: leaf.reshape(2, n, 16, 2, 16) if key in "kv" else leaf
+        for key, leaf in pack["kv"].items()})
+    before = {key: np.asarray(leaf) for key, leaf in dst.kv_cache.items()}
+    shapes = r"\(2, 3, 16, 2, 16\).*\(2, 65, 16, 32\)"
+    with pytest.raises(ValueError, match=shapes):
+        handoff.restore_sequence(
+            dst, handoff.deserialize(handoff.serialize(stale)), uid=9)
+    header, *chunks = handoff.chunk_pack(stale, chunk_blocks=2)
+    restore = handoff.ChunkedRestore(dst, 10, handoff.parse_header(header))
+    restore.begin()
+    with pytest.raises(ValueError, match=r"\(2, 2, 16, 2, 16\)"):
+        restore.apply(handoff.parse_chunk(chunks[0]))
+    restore.abort()
+    assert not {9, 10} & set(dst.state_manager.seqs)
+    for key, leaf in dst.kv_cache.items():
+        np.testing.assert_array_equal(np.asarray(leaf), before[key])
+    handoff.restore_sequence(
+        dst, handoff.deserialize(handoff.serialize(pack)), uid=11)
+    took, gave = (e.state_manager.seqs[u].blocks
+                  for e, u in ((dst, 11), (src, 5)))
+    for key in src.kv_cache:
+        np.testing.assert_array_equal(np.asarray(dst.kv_cache[key])[:, took],
+                                      np.asarray(src.kv_cache[key])[:, gave])
 
 
 def test_disaggregated_streams_parity_with_kv_quant(tiny):

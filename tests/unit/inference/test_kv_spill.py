@@ -170,6 +170,45 @@ def test_corrupt_spill_entry_degrades_to_recompute(tiny):
     assert not se.spill.has(victim)
 
 
+@pytest.mark.parametrize("kv_quant", [False, True], ids=["plain", "int8"])
+def test_an_entry_of_the_per_head_layout_is_dropped_by_shape(tiny, kv_quant):
+    """A spilled block comes back into the stored ``[L, nb, bs, kvh *
+    hd]`` leaves bit for bit. An entry written when a page was ``[bs,
+    kvh, hd]`` (a dead peer's disk tier from before the layout changed:
+    the same bytes, a sound crc) is refused by its shape and dropped,
+    the pool untouched: a recompute, never a scatter."""
+    from deepspeed_tpu.inference.v2.serve import handoff
+    model, params = tiny
+    rng = np.random.default_rng(5)
+    se = _engine(model, params, spill=True, num_blocks=11,
+                 kv_quant=kv_quant)
+    pA = list(map(int, rng.integers(1, 127, 50)))
+    se.generate([pA], max_new_tokens=4, uids=[1])
+    held = {key: np.asarray(leaf) for key, leaf in se.kv_cache.items()}
+    block = {d: se.state_manager._prefix[d]
+             for d in prefix_digest(pA[:48], 16)}
+    _pressure(se, rng, uid=2, tokens=150)          # evicts all three
+    sound, stale = [d for d in block if se.spill.has(d)][:2]
+    # the sound entry: every leaf of the block as it was before eviction
+    assert se.spill.restore_block(sound, 3)
+    for key, leaf in se.kv_cache.items():
+        assert leaf.shape[2:] == held[key].shape[2:]
+        np.testing.assert_array_equal(np.asarray(leaf)[:, 3],
+                                      held[key][:, block[sound]])
+    # the stale one: the same content, its pages cut into heads
+    chunk = handoff.parse_chunk(se.spill._host[stale])
+    kv = {key: (leaf.reshape(leaf.shape[:3] + (2, -1)) if key in "kv"
+                else leaf) for key, leaf in chunk["kv"].items()}
+    assert kv["k"].shape == (2, 1, 16, 2, 16)
+    se.spill._host[stale] = handoff._npz_chunk(
+        dict(chunk["descriptor"], crc32=handoff._chunk_crc(kv)), kv)
+    before = {key: np.asarray(leaf) for key, leaf in se.kv_cache.items()}
+    assert not se.spill.restore_block(stale, 4)
+    assert not se.spill.has(stale)
+    for key, leaf in se.kv_cache.items():
+        np.testing.assert_array_equal(np.asarray(leaf), before[key])
+
+
 def test_spill_restore_zero_steady_state_recompiles(tiny):
     """Restore rides the double-warmed donated-pool scatter: after one
     full spill->restore cycle warmed both executable signatures, a

@@ -29,7 +29,7 @@ def _reference(q, kc, vc, bt, lengths):
 
 
 @pytest.mark.parametrize("kvh,nh", [(4, 4), (2, 8)])
-def test_paged_attention_matches_gather(kvh, nh):
+def test_paged_attention_matches_gather(stored_pool, kvh, nh):
     N, hd, nb, bs, MB = 3, 64, 12, 16, 4
     rng = np.random.default_rng(0)
     q = jnp.asarray(rng.standard_normal((N, nh, hd)) * 0.3, jnp.float32)
@@ -43,7 +43,9 @@ def test_paged_attention_matches_gather(kvh, nh):
                   for _ in range(N)]), jnp.int32)
     lengths = jnp.asarray([5, 33, 64], jnp.int32)
 
-    out = paged_attention(q, kc, vc, bt, lengths)
+    # the pool as stored: [L, nb, bs, kvh * hd], layer 1 of two attended
+    out = paged_attention(q, stored_pool(kc, 2, 1), stored_pool(vc, 2, 1), 1,
+                          bt, lengths)
     ref = _reference(q, kc, vc, bt, lengths)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-5,
                                atol=2e-5)

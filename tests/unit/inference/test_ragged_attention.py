@@ -5,7 +5,9 @@ engine_v2.step_ragged + the SplitFuse scheduler's RaggedBatch emission):
 
 * the ragged kernel matches a dense reference for mixed rows, and is
   BIT-IDENTICAL to the decode kernel on pure-decode batches (shared
-  ``_page_update``);
+  ``_page_update``); both variants read the pool as it is stored,
+  ``[L, nb, bs, kvh * hd]`` whole with the layer a scalar, and touch no
+  other layer;
 * ragged vs stitched token streams are bit-identical — greedy and
   fixed-seed sampled — for prefill-only, decode-only and interleaved
   batches, through put() and through the scheduler (chip-free: the
@@ -67,7 +69,7 @@ def _reference_ragged(q, k_cache, v_cache, row_ids, lengths, tables):
     return out
 
 
-def test_ragged_kernel_matches_reference_mixed_rows():
+def test_ragged_kernel_matches_reference_mixed_rows(stored_pool):
     rng = np.random.default_rng(0)
     nb, bs, kvh, hd, nh = 9, 16, 2, 16, 4
     k_cache = jnp.asarray(rng.normal(size=(nb, bs, kvh, hd)), jnp.float32)
@@ -87,7 +89,8 @@ def test_ragged_kernel_matches_reference_mixed_rows():
     lengths += [0] * pad
     q = jnp.asarray(rng.normal(size=(T, nh, hd)), jnp.float32)
     out = np.asarray(ragged_attention(
-        q, k_cache, v_cache, jnp.asarray(row_ids, jnp.int32),
+        q, stored_pool(k_cache), stored_pool(v_cache), 0,
+        jnp.asarray(row_ids, jnp.int32),
         jnp.asarray(lengths, jnp.int32), jnp.asarray(tables)))
     ref = _reference_ragged(q, k_cache, v_cache, row_ids, lengths, tables)
     np.testing.assert_allclose(out, ref, rtol=2e-5, atol=2e-5)
@@ -95,7 +98,7 @@ def test_ragged_kernel_matches_reference_mixed_rows():
     assert (out[-pad:] == 0.0).all()
 
 
-def test_ragged_kernel_pure_decode_matches_decode_kernel():
+def test_ragged_kernel_pure_decode_matches_decode_kernel(stored_pool):
     """row per token, per-token lengths == the decode kernel's lengths:
     the shared page-walk math makes the outputs bit-identical."""
     rng = np.random.default_rng(1)
@@ -106,12 +109,12 @@ def test_ragged_kernel_pure_decode_matches_decode_kernel():
                                   np.int32))
     lengths = jnp.asarray([17, 30, 5, 32], jnp.int32)
     q = jnp.asarray(rng.normal(size=(4, nh, hd)), jnp.float32)
+    kp, vp = stored_pool(k_cache, 2, 1), stored_pool(v_cache, 2, 1)
     ragged = np.asarray(ragged_attention(
-        q, k_cache, v_cache, jnp.arange(4, dtype=jnp.int32), lengths,
-        tables))
-    decode = np.asarray(paged_attention(q, k_cache, v_cache, tables,
-                                        lengths))
+        q, kp, vp, 1, jnp.arange(4, dtype=jnp.int32), lengths, tables))
+    decode = np.asarray(paged_attention(q, kp, vp, 1, tables, lengths))
     np.testing.assert_array_equal(ragged, decode)
+    assert np.isfinite(ragged).all()
 
 
 # ---------------------------------------------------------------------------
@@ -182,15 +185,16 @@ TILED_CASES = [(2, 8, "bf16"), (4, 4, "bf16"), (2, 8, "int8"),
 
 
 @pytest.mark.parametrize("kvh,nh,pool", TILED_CASES)
-def test_tiled_kernel_matches_gather_reference(kvh, nh, pool):
+def test_tiled_kernel_matches_gather_reference(stored_pool, kvh, nh, pool):
     from deepspeed_tpu.inference.v2.kernels.ragged_attention import \
         kernel_variant
     assert kernel_variant(64, kvh, pool == "int8") == "tiled"
     c = _tiled_case(kvh, nh, pool)
     out = np.asarray(jax.jit(functools.partial(
         ragged_attention, variant="tiled"))(
-        c["q"], c["kc"], c["vc"], c["rows"], c["lens"], c["tables"],
-        k_scale=c["ks"], v_scale=c["vs"]), np.float32)
+        c["q"], stored_pool(c["kc"]), stored_pool(c["vc"]), 0, c["rows"],
+        c["lens"], c["tables"], k_scale=c["ks"], v_scale=c["vs"]),
+        np.float32)
     ref = _gather_reference(c["q"], c["kc"], c["vc"], c["rows"], c["lens"],
                             c["tables"], c["ks"], c["vs"])
     assert np.isfinite(out).all()
@@ -202,7 +206,7 @@ def test_tiled_kernel_matches_gather_reference(kvh, nh, pool):
 
 
 @pytest.mark.parametrize("kvh,nh,pool", TILED_CASES)
-def test_tiled_pure_decode_is_the_decode_kernel(kvh, nh, pool):
+def test_tiled_pure_decode_is_the_decode_kernel(stored_pool, kvh, nh, pool):
     """One token a row through ``paged_attention()`` and through
     ``ragged_attention()``: bit-equal, and both the reference's."""
     c = _tiled_case(kvh, nh, pool, seed=1)
@@ -211,17 +215,44 @@ def test_tiled_pure_decode_is_the_decode_kernel(kvh, nh, pool):
         .at[7, :33].set(jnp.arange(40, 73, dtype=jnp.int32))
     q = c["q"][:8]
     kw = dict(k_scale=c["ks"], v_scale=c["vs"], variant="tiled")
+    kp, vp = stored_pool(c["kc"], 2, 1), stored_pool(c["vc"], 2, 1)
     ragged = np.asarray(jax.jit(functools.partial(ragged_attention, **kw))(
-        q, c["kc"], c["vc"], jnp.arange(8, dtype=jnp.int32), lens, tables),
+        q, kp, vp, 1, jnp.arange(8, dtype=jnp.int32), lens, tables),
         np.float32)
     decode = np.asarray(jax.jit(functools.partial(paged_attention, **kw))(
-        q, c["kc"], c["vc"], tables, lens), np.float32)
+        q, kp, vp, 1, tables, lens), np.float32)
     np.testing.assert_array_equal(ragged, decode)
     ref = _gather_reference(q, c["kc"], c["vc"],
                             jnp.arange(8, dtype=jnp.int32), lens, tables,
                             c["ks"], c["vs"])
     tol = 2e-2 if pool == "bf16" else 2e-5
     np.testing.assert_allclose(decode, ref, rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("pool", ["bf16", "int8"])
+@pytest.mark.parametrize("variant", ["tiled", "pipelined"])
+def test_a_launch_reads_its_own_layer_of_the_whole_pool(stored_pool, variant,
+                                                        pool):
+    """Three layers, the other two NaN (int8: -128 under the attended
+    layer's scales): layer 1 through either variant is the gather
+    reference on layer 1 alone, finite everywhere, and the layer is a
+    traced scalar (one program for every layer)."""
+    c = _tiled_case(2, 8, pool, seed=2)
+    kp, vp = stored_pool(c["kc"], 3, 1), stored_pool(c["vc"], 3, 1)
+    run = jax.jit(functools.partial(
+        ragged_attention, variant=variant, k_scale=c["ks"], v_scale=c["vs"]))
+    out = np.asarray(run(c["q"], kp, vp, jnp.int32(1), c["rows"], c["lens"],
+                         c["tables"]), np.float32)
+    ref = _gather_reference(c["q"], c["kc"], c["vc"], c["rows"], c["lens"],
+                            c["tables"], c["ks"], c["vs"])
+    assert np.isfinite(out).all()
+    tol = 2e-2 if pool == "bf16" else 2e-5
+    np.testing.assert_allclose(out, ref, rtol=tol, atol=tol)
+    if pool == "bf16":      # another layer's pages are NaN: it read them
+        assert not np.isfinite(np.asarray(run(
+            c["q"], kp, vp, jnp.int32(2), c["rows"], c["lens"], c["tables"]),
+            np.float32)[:-c["pad"]]).any()
+    assert run._cache_size() == 1
 
 
 # ---------------------------------------------------------------------------

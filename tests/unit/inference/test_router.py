@@ -221,9 +221,12 @@ def test_dead_replica_heartbeat_expiry_requeues_queued_requests(
             diagnostics=DiagnosticsConfig(stall_min_deadline_s=0.05,
                                           stall_check_interval_s=0.02))
         replicas = build_replicas([eng0, eng1], cfg)
+        # the survivor compiles its first buckets inside this test (~0.5 s
+        # a bucket alone, over a second beside five other xdist workers):
+        # the heartbeat has to outlast that, the wedge outlasts both
         router = ReplicaRouter(
             replicas, RouterConfig(placement="round_robin",
-                                   heartbeat_timeout_s=1.0,
+                                   heartbeat_timeout_s=3.0,
                                    monitor_interval_s=0.0))
         await router.start()
         real_step = replicas[0].serving.scheduler.step

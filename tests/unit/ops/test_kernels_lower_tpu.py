@@ -15,7 +15,9 @@ drift apart:
 * the tiled paged-attention kernel compiles at the launch shapes of the
   benchmark's generation cell, under a name its trace pattern matches;
 * the whole serving programs (ragged step, fused decode window with
-  greedy and sampled picks, prefill) compile at OPT-1.3B's geometry.
+  greedy and sampled picks, prefill) compile at OPT-1.3B's geometry;
+* the generation cell's two programs, at its shapes, take the pool as
+  an argument and make no copy of it or of a layer of it.
 
 This is the pre-check that costs no chip time: run it before
 ``chip_smoke.py``. It says nothing about speed or numerics; phase K of
@@ -81,16 +83,25 @@ def _compile_error(fn, *args):
     return None
 
 
-def _paged_args(sds, hd, kvh, quant):
-    T, R, nb, bs, MB = 8, 4, 64, 16, 8
+def _paged_args(sds, hd, kvh, quant, T=8, R=4, MB=8, nb=64, layers=2):
+    """``ragged_attention``'s operands: queries, the two pool leaves as
+    stored (``[L, nb, 16, kvh * hd]``), the layer, the descriptors, and
+    an int8 pool's two scale rows of the layer."""
     nh = kvh if kvh >= 12 else 32
-    pool = sds((nb, bs, kvh, hd), jnp.int8 if quant else jnp.bfloat16)
-    args = [sds((T, nh, hd), jnp.bfloat16), pool, pool,
+    pool = sds((layers, nb, 16, kvh * hd),
+               jnp.int8 if quant else jnp.bfloat16)
+    args = [sds((T, nh, hd), jnp.bfloat16), pool, pool, sds((), jnp.int32),
             sds((T,), jnp.int32), sds((T,), jnp.int32),
             sds((R, MB), jnp.int32)]
     if quant:
         args += [sds((nb, kvh), jnp.float32)] * 2
     return args
+
+
+def _attend(quant, variant=None):
+    return lambda *a: ragged_attention(
+        *a[:7], k_scale=a[7] if quant else None,
+        v_scale=a[8] if quant else None, variant=variant)
 
 
 def _check_rows(rows, sharding, variants):
@@ -105,11 +116,8 @@ def _check_rows(rows, sharding, variants):
     for hd, kvh, quant in rows:
         chosen = kernel_variant(hd, kvh, quant)
         for variant in variants or (chosen,):
-            err = _compile_error(
-                lambda *a, _v=variant: ragged_attention(
-                    *a[:6], k_scale=a[6] if quant else None,
-                    v_scale=a[7] if quant else None, variant=_v),
-                *_paged_args(sds, hd, kvh, quant))
+            err = _compile_error(_attend(quant, variant),
+                                 *_paged_args(sds, hd, kvh, quant))
             if err is not None and chosen == variant:
                 failures.append(
                     f"hd={hd} kvh={kvh} {'int8' if quant else 'bf16'}: "
@@ -151,26 +159,27 @@ TRACE_PATTERN = re.compile(r"ragged_attention_[a-z]+[_.0-9]*$")
 @pytest.mark.parametrize("launch", sorted(CELL_LAUNCHES))
 def test_tiled_kernel_at_the_cells_launch_shapes(tpu_sharding, launch,
                                                  quant):
-    """32 heads of 64 at the cell's shapes: the kernel compiles, and the
-    compiled program calls it under a name the benchmark's readers find
-    (a name with a second word would read both metrics as null)."""
+    """32 heads of 64 at the cell's shapes, the whole pool of 24 layers
+    and the layer a scalar: the kernel compiles (an int8 page of 16
+    positions is half a (32, 128) tile and still copies), the compiled
+    program calls it under a name the benchmark's readers find (a name
+    with a second word would read both metrics as null), and nothing in
+    it is as large as a layer of the pool."""
     T, R, MB = CELL_LAUNCHES[launch]
 
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=tpu_sharding)
 
-    pool = sds((529, 16, 32, 64), jnp.int8 if quant else jnp.bfloat16)
-    args = [sds((T, 32, 64), jnp.bfloat16), pool, pool, sds((T,), jnp.int32),
-            sds((T,), jnp.int32), sds((R, MB), jnp.int32)]
-    if quant:
-        args += [sds((529, 32), jnp.float32)] * 2
     assert kernel_variant(64, 32, quant) == "tiled"
-    text = jax.jit(lambda *a: ragged_attention(
-        *a[:6], k_scale=a[6] if quant else None,
-        v_scale=a[7] if quant else None)).lower(*args).compile().as_text()
-    kernels = re.findall(r"%([\w.\-]+) = [^\n]*tpu_custom_call", text)
+    compiled = jax.jit(_attend(quant)).lower(*_paged_args(
+        sds, 64, 32, quant, T=T, R=R, MB=MB, nb=529, layers=24)).compile()
+    kernels = re.findall(r"%([\w.\-]+) = [^\n]*tpu_custom_call",
+                         compiled.as_text())
     assert len(kernels) == 1 and TRACE_PATTERN.search(kernels[0]), kernels
     assert kernels[0].startswith("ragged_attention_tiled")
+    # a layer of the pool is 17 MB int8 (35 bf16): queries and output of
+    # the 4,096-token launch are 2 x 0.5 MB, relaid once each way
+    assert compiled.memory_analysis().temp_size_in_bytes < 4e6
 
 
 @pytest.mark.parametrize("hd,kv_heads,causal,seq,fused", [
@@ -320,12 +329,12 @@ def test_train_step_on_a_full_chip(tpu_sharding, monkeypatch, micro, want,
 # ---------------------------------------------------------------------------
 # whole serving programs at OPT-1.3B's geometry (depth cut to 2)
 # ---------------------------------------------------------------------------
-def _serving_case(sharding, kv_quant):
+def _serving_case(sharding, kv_quant, layers=2, blocks=129):
     from deepspeed_tpu.inference.v2.paged_model import init_paged_kv_cache
     from deepspeed_tpu.models import TransformerLM
     from deepspeed_tpu.models.transformer import opt_1_3b
 
-    cfg = dataclasses.replace(opt_1_3b(), num_layers=2)
+    cfg = dataclasses.replace(opt_1_3b(), num_layers=layers)
 
     def on_tpu(x, dtype=None):
         return jax.ShapeDtypeStruct(x.shape, dtype or x.dtype,
@@ -336,7 +345,7 @@ def _serving_case(sharding, kv_quant):
         jax.eval_shape(TransformerLM(cfg).init_params,
                        jax.random.PRNGKey(0)))
     cache = jax.tree.map(on_tpu, jax.eval_shape(
-        lambda: init_paged_kv_cache(cfg, 129, 16, jnp.bfloat16,
+        lambda: init_paged_kv_cache(cfg, blocks, 16, jnp.bfloat16,
                                     kv_quant=kv_quant)))
 
     def i32(*shape):
@@ -382,6 +391,72 @@ def test_opt_decode_window_compiles(tpu_sharding, kv_quant, sampled):
                          i32(N), i32(N), i32(N), i32(N), f32(N), f32(N),
                          i32(N))
     assert err is None, err
+
+
+def _cell_program(program, cfg, params, cache, i32):
+    """(jitted program, its arguments) of opt-1.3b.rollout-256's two
+    launches as the engine builds them, the pool donated."""
+    from deepspeed_tpu.inference.v2.paged_model import (paged_decode_window,
+                                                        paged_ragged_step)
+    T, R, MB = CELL_LAUNCHES[program]
+    if program == "prefill":
+        return jax.jit(
+            lambda p, ids, rows, pos, ln, wb, wo, bt, li, c:
+            paged_ragged_step(cfg, p, ids, rows, pos, ln, wb, wo, bt, li, c,
+                              16, use_kernel=True), donate_argnums=(9,)), (
+            params, i32(T), i32(T), i32(T), i32(T), i32(T), i32(T),
+            i32(R, MB), i32(R), cache)
+    return jax.jit(
+        lambda p, t, pos, bt, c, sl, eos: paged_decode_window(
+            cfg, p, t, pos, bt, c, sl, eos, 16, 8, use_kernel=True),
+        donate_argnums=(4,)), (
+        params, i32(R), i32(R), i32(R, MB), cache, i32(R), i32(R))
+
+
+@pytest.mark.parametrize("layers", [
+    2, pytest.param(24, marks=pytest.mark.slow)])   # 24: the cell's depth
+@pytest.mark.parametrize("program", sorted(CELL_LAUNCHES))
+def test_the_cells_programs_read_the_pool_where_it_lies(tpu_sharding,
+                                                        program, layers):
+    """opt-1.3b.rollout-256's ragged step (4,096 tokens) and decode
+    window (16 rows, 8 steps) over its pool of 529 blocks of 16,
+    compiled under the chip's flags: the arguments are the weights and
+    the pool as stored (no padded entry layout), the donated pool is
+    aliased to the result, the temporaries are far under a pool, and no
+    instruction cuts, reshapes or copies a layer or a pool (PR 34's
+    tree did all three, 3.0 s of a 4.8 s call). The sibling of
+    test_latent_ragged_step_compiles_with_its_experts_in_place."""
+    from deepspeed_tpu.accelerator.tpu_accelerator import \
+        COLLECTIVE_OVERLAP_COMPILER_OPTIONS
+    cfg, params, cache, i32 = _serving_case(tpu_sharding, False, layers, 529)
+    fn, args = _cell_program(program, cfg, params, cache, i32)
+    compiled = fn.lower(*args).compile(
+        compiler_options=dict(COLLECTIVE_OVERLAP_COMPILER_OPTIONS))
+
+    def nbytes(tree):
+        return sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(tree))
+
+    pool = nbytes(cache)
+    assert pool == 2 * layers * 529 * 16 * 2048 * 2    # 1.66 GB at 24
+    mem = compiled.memory_analysis()
+    assert abs(mem.argument_size_in_bytes / (nbytes(params) + pool) - 1) \
+        < 0.02, mem
+    assert mem.alias_size_in_bytes >= pool, mem
+    assert mem.temp_size_in_bytes < 0.5e9, mem
+    text = compiled.as_text()
+    kernels = re.findall(r"%([\w.\-]+) = [^\n]*tpu_custom_call", text)
+    assert kernels and all(k.startswith("ragged_attention_tiled")
+                           for k in kernels), kernels
+    # every instruction whose result has the pool's blocks: [529, 16, ..]
+    # is a layer, [L, 529, 16, ..] a pool
+    made = re.findall(r"= \w+\[((?:\d+,)?529,16,[\d,]+)\]\S* ([\w\-]+)\(",
+                      text)
+    assert made, "the pattern no longer finds the pool in the program"
+    layer_sized = [m for m in made if m[0].startswith(("529,", "1,529,"))]
+    assert not layer_sized, layer_sized
+    assert {op for _, op in made} <= {
+        "parameter", "get-tuple-element", "bitcast", "scatter", "fusion",
+        "while", "tuple"}, sorted(set(made))
 
 
 @pytest.mark.slow   # the flash kernel it uses is pinned tier-1 above
