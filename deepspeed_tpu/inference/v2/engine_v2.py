@@ -50,6 +50,7 @@ from .paged_model import (init_lora_bank, init_paged_kv_cache,
 from .ragged import batch as ragged_batch
 from .ragged.blocked_allocator import NULL_BLOCK
 from .ragged.ragged_manager import DSStateManager
+from .sampling import greedy_tokens
 
 DTYPES = {"float32": jnp.float32, "float16": jnp.float16,
           "bfloat16": jnp.bfloat16}
@@ -315,12 +316,12 @@ class InferenceEngineV2:
         # disabled (an empty pytree — same compiled programs as before),
         # and they TRAIL the existing argument lists so every
         # donate_argnums index stays put
-        self._decode_jit = watchdog.watch("decode", jax.jit(
-            lambda p, t, pos, bt, c, a, lb, aid: paged_decode(
+        self._decode_jit = watchdog.watch_jit(
+            "decode", lambda p, t, pos, bt, c, a, lb, aid: paged_decode(
                 cfg, p, t, pos, bt, c, a, sm.block_size,
                 use_kernel=use_kernel, topo=topo, lora=lb,
                 adapter_ids=aid),
-            donate_argnums=(4,)))
+            donate_argnums=(4,))
 
         def _decode_tok(p, t, pos, bt, c, a, lb, aid):
             # greedy variant for the generate() hot loop: argmax on device
@@ -329,10 +330,10 @@ class InferenceEngineV2:
             logits, *moe, c = paged_decode(
                 cfg, p, t, pos, bt, c, a, sm.block_size,
                 use_kernel=use_kernel, topo=topo, lora=lb, adapter_ids=aid)
-            return (jnp.argmax(logits, axis=-1).astype(jnp.int32), *moe, c)
+            return (greedy_tokens(logits), *moe, c)
 
-        self._decode_tok_jit = watchdog.watch(
-            "decode_greedy", jax.jit(_decode_tok, donate_argnums=(4,)))
+        self._decode_tok_jit = watchdog.watch_jit(
+            "decode_greedy", _decode_tok, donate_argnums=(4,))
 
         def _decode_sample(p, t, pos, bt, c, a, rng, seeds, gidx, temp,
                            topp, topk, lb, aid):
@@ -348,8 +349,8 @@ class InferenceEngineV2:
             return (sample_tokens_rowwise(logits, keys, temp, topp, topk),
                     *moe, c)
 
-        self._decode_sample_jit = watchdog.watch(
-            "decode_sample", jax.jit(_decode_sample, donate_argnums=(4,)))
+        self._decode_sample_jit = watchdog.watch_jit(
+            "decode_sample", _decode_sample, donate_argnums=(4,))
         # fused multi-token decode window (the generate()/scheduler hot
         # path when decode_window > 1): K decode steps per dispatch, one
         # [N, K] int32 transfer per window. K is baked into the compiled
@@ -369,14 +370,16 @@ class InferenceEngineV2:
         self._fused_jit_cache: Dict[int, tuple] = {}
 
         def _build_fused_pair(K: int):
-            greedy = watchdog.watch("decode_window_greedy", jax.jit(
+            greedy = watchdog.watch_jit(
+                "decode_window_greedy",
                 lambda p, t, pos, bt, c, sl, eos, lb, aid, _K=K:
                 paged_decode_window(
                     cfg, p, t, pos, bt, c, sl, eos, sm.block_size,
                     _K, use_kernel=use_kernel,
                     topo=topo, lora=lb, adapter_ids=aid),
-                donate_argnums=(4,)))
-            sample = watchdog.watch("decode_window_sample", jax.jit(
+                donate_argnums=(4,))
+            sample = watchdog.watch_jit(
+                "decode_window_sample",
                 lambda p, t, pos, bt, c, sl, eos, rng, seeds, g0, temp, \
                 topp, topk, lb, aid, _K=K: paged_decode_window(
                     cfg, p, t, pos, bt, c, sl, eos, sm.block_size,
@@ -384,7 +387,7 @@ class InferenceEngineV2:
                     temp=temp, topp=topp, topk=topk,
                     use_kernel=use_kernel, topo=topo, lora=lb,
                     adapter_ids=aid),
-                donate_argnums=(4,)))
+                donate_argnums=(4,))
             return greedy, sample
 
         self._build_fused_pair = _build_fused_pair
@@ -394,17 +397,18 @@ class InferenceEngineV2:
         self._warmed_windows: set = set()
         self._fused_greedy_jit, self._fused_sample_jit = \
             self._fused_pair(self.decode_window)
-        self._prefill_jit = watchdog.watch("prefill", jax.jit(
-            lambda p, ids, n, c, b, o, lb, aid: paged_prefill(
+        self._prefill_jit = watchdog.watch_jit(
+            "prefill", lambda p, ids, n, c, b, o, lb, aid: paged_prefill(
                 cfg, p, ids, n, c, b, o,
                 use_kernel=use_kernel, topo=topo, lora=lb,
                 adapter_ids=aid),
-            donate_argnums=(3,)))
-        self._continue_jit = watchdog.watch("continue", jax.jit(
+            donate_argnums=(3,))
+        self._continue_jit = watchdog.watch_jit(
+            "continue",
             lambda p, ids, s, n, c, b, o, t, lb, aid: paged_continue(
                 cfg, p, ids, s, n, c, b, o, t, sm.block_size, topo=topo,
                 lora=lb, adapter_ids=aid),
-            donate_argnums=(4,)))
+            donate_argnums=(4,))
         # ragged unified step (ROADMAP item 1; kernels/ragged_attention.py
         # + ragged/batch.py): every mixed prefill+decode composition runs
         # as ONE program keyed by (token bucket, row bucket, table-width
@@ -416,13 +420,14 @@ class InferenceEngineV2:
         # unified program.
         self.ragged_enabled = self._resolve_ragged_mode(
             config.ragged_attention)
-        self._ragged_jit = watchdog.watch("ragged_step", jax.jit(
+        self._ragged_jit = watchdog.watch_jit(
+            "ragged_step",
             lambda p, ids, rows, pos, ln, wb, wo, bt, li, c, lb, aid:
             paged_ragged_step(
                 cfg, p, ids, rows, pos, ln, wb, wo, bt, li, c,
                 sm.block_size, use_kernel=use_kernel, topo=topo,
                 lora=lb, adapter_ids=aid),
-            donate_argnums=(9,)))
+            donate_argnums=(9,))
         # speculative verification: greedy ids for a static window of
         # fed positions from one fused continuation pass (prompt-lookup
         # decoding); one compiled program per window size
@@ -430,14 +435,14 @@ class InferenceEngineV2:
 
         def _spec_jit(window: int):
             if window not in self._continue_spec_jits:
-                self._continue_spec_jits[window] = watchdog.watch(
-                    f"spec_verify_w{window}", jax.jit(
-                        lambda p, ids, s, n, c, b, o, t, lb, aid:
-                        paged_continue(
-                            cfg, p, ids, s, n, c, b, o, t, sm.block_size,
-                            topo=topo, greedy_window=window, lora=lb,
-                            adapter_ids=aid),
-                        donate_argnums=(4,)))
+                self._continue_spec_jits[window] = watchdog.watch_jit(
+                    f"spec_verify_w{window}",
+                    lambda p, ids, s, n, c, b, o, t, lb, aid:
+                    paged_continue(
+                        cfg, p, ids, s, n, c, b, o, t, sm.block_size,
+                        topo=topo, greedy_window=window, lora=lb,
+                        adapter_ids=aid),
+                    donate_argnums=(4,))
             return self._continue_spec_jits[window]
 
         self._spec_jit = _spec_jit
@@ -1130,11 +1135,11 @@ class InferenceEngineV2:
         # (prefill, plain decode, n-gram rounds) before a uid's first
         # spec window
         bs = self.block_size
-        self._draft_continue_jit = watchdog.watch(
-            "draft_catchup", jax.jit(
-                lambda p, ids, s, n, c, b, o, t: paged_continue(
+        self._draft_continue_jit = watchdog.watch_jit(
+            "draft_catchup",
+            lambda p, ids, s, n, c, b, o, t: paged_continue(
                     dcfg, p, ids, s, n, c, b, o, t, bs, topo=None),
-                donate_argnums=(4,)))
+                donate_argnums=(4,))
         try:
             ds_memory.record_buffer(
                 "draft_params", ds_memory.tree_bytes(self.draft_params))
@@ -1158,14 +1163,14 @@ class InferenceEngineV2:
             dcfg = self._draft_cfg
             bs = self.block_size
             uk, topo = self._use_kernel, self._topo
-            self._spec_window_jits[key] = watchdog.watch(
-                "spec_decode_window", jax.jit(
-                    lambda p, dp, t, pos, bt, c, dc, sl, eos, lb, aid,
+            self._spec_window_jits[key] = watchdog.watch_jit(
+                "spec_decode_window",
+                lambda p, dp, t, pos, bt, c, dc, sl, eos, lb, aid,
                     _K=window, _k=spec_k: paged_spec_decode_window(
                         cfg, dcfg, p, dp, t, pos, bt, c, dc, sl, eos,
                         bs, _K, _k, use_kernel=uk, topo=topo,
                         lora=lb, adapter_ids=aid),
-                    donate_argnums=(5, 6)))
+                    donate_argnums=(5, 6))
         return self._spec_window_jits[key]
 
     def _draft_catchup(self, uid: int, row: List[int]) -> None:
@@ -1381,41 +1386,45 @@ class InferenceEngineV2:
     def _decode_common(self, uids: List[int], tokens: List[int], jit_fn,
                        extract) -> Dict[int, object]:
         sm = self.state_manager
-        t0 = time.perf_counter()
         with trace.span("decode_step", batch=len(uids),
                         uids=[int(u) for u in uids],
-                        **self._trace_attrs(uids)):
-            toks, pos, tables, active = self._build_decode_inputs(uids,
-                                                                  tokens)
-            lb = self.lora_bank
-            aid = (self._pad_i32(active.shape[0],
-                                 [self._adapter_slot_of(u) for u in uids])
-                   if lb is not None else None)
-            vals, *moe, self.kv_cache = jit_fn(
-                self.params, toks, pos, tables, self.kv_cache, active,
-                lb, aid)
-            # blocks: the pass completes here
-            vals, moe = jax.device_get((vals, moe))
-        self._m_host_syncs.inc()
-        self._note_moe("decode_step", *moe)
-        dt = time.perf_counter() - t0
-        self._m_decode_steps.inc()
-        self._m_decode_tokens.inc(len(uids))
-        self._m_decode_time.observe(dt)
-        if dt > 0:
-            self._m_decode_tput.set(len(uids) / dt)
-        flight.record("decode_step", batch=len(uids),
-                      dur_s=round(dt, 5))
-        self._warmed_windows.add(1)   # per-token path == window 1
-        log_tokens = sm.config.enable_prefix_caching
-        out = {}
-        for i, uid in enumerate(uids):
-            seq = sm.seqs[uid]
-            seq.seen_tokens += 1
-            if log_tokens:
-                seq.token_log.append(int(tokens[i]))
-            out[uid] = extract(vals, i)
-        self._update_pool_telemetry()
+                        **self._trace_attrs(uids)) as step:
+            with trace.span("step_assemble"):
+                toks, pos, tables, active = self._build_decode_inputs(
+                    uids, tokens)
+                lb = self.lora_bank
+                aid = (self._pad_i32(active.shape[0],
+                                     [self._adapter_slot_of(u)
+                                      for u in uids])
+                       if lb is not None else None)
+            with trace.span("step_dispatch"):
+                vals, *moe, self.kv_cache = jit_fn(
+                    self.params, toks, pos, tables, self.kv_cache, active,
+                    lb, aid)
+            with trace.span("step_fetch"):
+                # blocks: the pass completes here
+                vals, moe = jax.device_get((vals, moe))
+        with trace.span("step_bookkeeping"):
+            dt = step["duration_s"]
+            self._m_host_syncs.inc()
+            self._note_moe("decode_step", *moe)
+            self._m_decode_steps.inc()
+            self._m_decode_tokens.inc(len(uids))
+            self._m_decode_time.observe(dt)
+            if dt > 0:
+                self._m_decode_tput.set(len(uids) / dt)
+            flight.record("decode_step", batch=len(uids),
+                          dur_s=round(dt, 5))
+            self._warmed_windows.add(1)   # per-token path == window 1
+            log_tokens = sm.config.enable_prefix_caching
+            out = {}
+            for i, uid in enumerate(uids):
+                seq = sm.seqs[uid]
+                seq.seen_tokens += 1
+                if log_tokens:
+                    seq.token_log.append(int(tokens[i]))
+                out[uid] = extract(vals, i)
+            self._update_pool_telemetry()
         return out
 
     def _decode_batch(self, uids: List[int],
@@ -1467,55 +1476,61 @@ class InferenceEngineV2:
         the row's last emitted token is never fed/cached — the same
         invariant as the per-token loop)."""
         sm = self.state_manager
-        t0 = time.perf_counter()
         with trace.span("decode_window", batch=len(uids),
                         window=self.decode_window,
                         uids=[int(u) for u in uids],
-                        **self._trace_attrs(uids)):
-            # block pre-allocation contract: every block row i can write
-            # during its steps_left[i] steps is allocated HERE, so the
-            # device loop never needs the host mid-window (block-table
-            # advancement is position arithmetic over a complete table)
-            N, toks, pos, tables = self._assemble_decode_rows(
-                uids, tokens, steps_left)
-            eos = np.full(N, -1, np.int32)
-            eos[:len(uids)] = eos_ids
-            lb = self.lora_bank
-            aid = (self._pad_i32(N, [self._adapter_slot_of(u)
-                                     for u in uids])
-                   if lb is not None else None)
-            out, *moe, self.kv_cache = run(
-                jnp.asarray(toks), jnp.asarray(pos), jnp.asarray(tables),
-                self._pad_i32(N, steps_left), jnp.asarray(eos), lb, aid)
-            # ONE transfer for the whole window
-            out, moe = jax.device_get((out, moe))
-        self._m_host_syncs.inc()
-        self._note_moe("decode_window", *moe)
-        dt = time.perf_counter() - t0
-        log_tokens = sm.config.enable_prefix_caching
-        emitted: Dict[int, List[int]] = {}
-        total = 0
-        for i, uid in enumerate(uids):
-            row = out[i]
-            e = int((row >= 0).sum())   # active steps are a prefix
-            toks_out = [int(t) for t in row[:e]]
-            seq = sm.seqs[uid]
-            seq.seen_tokens += e        # e tokens were fed and cached
-            if log_tokens:
-                # fed tokens: the input token plus all but the last emit
-                seq.token_log.extend([int(tokens[i])] + toks_out[:-1])
-            emitted[uid] = toks_out
-            total += e
-        self._m_decode_steps.inc()
-        self._m_decode_tokens.inc(total)
-        self._m_decode_time.observe(dt)
-        self._m_fused_time.observe(dt)
-        if dt > 0:
-            self._m_decode_tput.set(total / dt)
-        flight.record("decode_window", batch=len(uids), tokens=total,
-                      window=self.decode_window, dur_s=round(dt, 5))
-        self._warmed_windows.add(self.decode_window)
-        self._update_pool_telemetry()
+                        **self._trace_attrs(uids)) as win:
+            with trace.span("window_assemble"):
+                # block pre-allocation contract: every block row i can
+                # write during its steps_left[i] steps is allocated HERE,
+                # so the device loop never needs the host mid-window
+                # (block-table advancement is position arithmetic over a
+                # complete table)
+                N, toks, pos, tables = self._assemble_decode_rows(
+                    uids, tokens, steps_left)
+                eos = np.full(N, -1, np.int32)
+                eos[:len(uids)] = eos_ids
+                lb = self.lora_bank
+                aid = (self._pad_i32(N, [self._adapter_slot_of(u)
+                                         for u in uids])
+                       if lb is not None else None)
+            with trace.span("window_dispatch"):
+                out, *moe, self.kv_cache = run(
+                    jnp.asarray(toks), jnp.asarray(pos),
+                    jnp.asarray(tables), self._pad_i32(N, steps_left),
+                    jnp.asarray(eos), lb, aid)
+            with trace.span("window_fetch"):
+                # ONE transfer for the whole window: the device wait
+                out, moe = jax.device_get((out, moe))
+        with trace.span("window_bookkeeping"):
+            dt = win["duration_s"]
+            self._m_host_syncs.inc()
+            self._note_moe("decode_window", *moe)
+            log_tokens = sm.config.enable_prefix_caching
+            emitted: Dict[int, List[int]] = {}
+            total = 0
+            for i, uid in enumerate(uids):
+                row = out[i]
+                e = int((row >= 0).sum())   # active steps are a prefix
+                toks_out = [int(t) for t in row[:e]]
+                seq = sm.seqs[uid]
+                seq.seen_tokens += e        # e tokens were fed and cached
+                if log_tokens:
+                    # fed tokens: the input token plus all but the last
+                    # emit
+                    seq.token_log.extend([int(tokens[i])] + toks_out[:-1])
+                emitted[uid] = toks_out
+                total += e
+            self._m_decode_steps.inc()
+            self._m_decode_tokens.inc(total)
+            self._m_decode_time.observe(dt)
+            self._m_fused_time.observe(dt)
+            if dt > 0:
+                self._m_decode_tput.set(total / dt)
+            flight.record("decode_window", batch=len(uids), tokens=total,
+                          window=self.decode_window, dur_s=round(dt, 5))
+            self._warmed_windows.add(self.decode_window)
+            self._update_pool_telemetry()
         return emitted
 
     def _decode_window_greedy(self, uids: List[int], tokens: List[int],
@@ -1582,81 +1597,88 @@ class InferenceEngineV2:
         decode program families. Same contract as put(): returns
         [len(batch_uids), vocab] last-token logits per entry."""
         sm = self.state_manager
-        entries = [(int(uid), np.atleast_1d(np.asarray(toks, np.int64)))
-                   for uid, toks in zip(batch_uids, batch_tokens)]
-        if not self.can_schedule([u for u, _ in entries],
-                                 [len(t) for _, t in entries]):
-            raise RuntimeError(
-                "batch not schedulable (KV blocks / sequence budget); "
-                "check can_schedule()/query() before put()")
-        for i, (uid, toks) in enumerate(entries):
-            if not sm.known_seq(uid) and len(toks) > 1:
-                # prefix caching: shared full blocks shorten the row to
-                # its unseen suffix (same as the stitched put()).
-                # Adapter-keyed: a LoRA row's v-projection KV differs
-                # from the base model's, so prefixes only share within
-                # one adapter identity (the NAME — stable across
-                # replicas, unlike engine-local slot ints)
-                _, n_reused = sm.match_prefix(
-                    uid, toks, adapter=self._uid_adapter.get(int(uid)))
-                if n_reused:
-                    entries[i] = (uid, toks[n_reused:])
-        # classify rows BEFORE packing mutates allocation state: a
-        # decode row is one token for a sequence with cached history
-        decode_rows = sum(
-            1 for uid, toks in entries
-            if len(toks) == 1 and sm.known_seq(uid)
-            and sm.seqs[uid].seen_tokens > 0)
-        if self.lora_bank is not None:
-            # stamp each row's adapter identity into its descriptor so
-            # the packer carries the per-row bank slots in the ragged
-            # layout (and flush-time prefix registration keys on it)
-            for uid, _ in entries:
-                seq = sm.get_or_create_sequence(uid)
-                seq.adapter = self._uid_adapter.get(int(uid))
-                seq.adapter_slot = self._adapter_slot_of(uid)
-        t0 = time.perf_counter()
-        rb = ragged_batch.pack(entries, sm)
+        with trace.span("ragged_pack") as packed:
+            entries = [(int(uid),
+                        np.atleast_1d(np.asarray(toks, np.int64)))
+                       for uid, toks in zip(batch_uids, batch_tokens)]
+            if not self.can_schedule([u for u, _ in entries],
+                                     [len(t) for _, t in entries]):
+                raise RuntimeError(
+                    "batch not schedulable (KV blocks / sequence budget); "
+                    "check can_schedule()/query() before put()")
+            for i, (uid, toks) in enumerate(entries):
+                if not sm.known_seq(uid) and len(toks) > 1:
+                    # prefix caching: shared full blocks shorten the row
+                    # to its unseen suffix (same as the stitched put()).
+                    # Adapter-keyed: a LoRA row's v-projection KV differs
+                    # from the base model's, so prefixes only share
+                    # within one adapter identity (the NAME — stable
+                    # across replicas, unlike engine-local slot ints)
+                    _, n_reused = sm.match_prefix(
+                        uid, toks, adapter=self._uid_adapter.get(int(uid)))
+                    if n_reused:
+                        entries[i] = (uid, toks[n_reused:])
+            # classify rows BEFORE packing mutates allocation state: a
+            # decode row is one token for a sequence with cached history
+            decode_rows = sum(
+                1 for uid, toks in entries
+                if len(toks) == 1 and sm.known_seq(uid)
+                and sm.seqs[uid].seen_tokens > 0)
+            if self.lora_bank is not None:
+                # stamp each row's adapter identity into its descriptor
+                # so the packer carries the per-row bank slots in the
+                # ragged layout (and flush-time prefix registration keys
+                # on it)
+                for uid, _ in entries:
+                    seq = sm.get_or_create_sequence(uid)
+                    seq.adapter = self._uid_adapter.get(int(uid))
+                    seq.adapter_slot = self._adapter_slot_of(uid)
+            rb = ragged_batch.pack(entries, sm)
         with trace.span("ragged_step", rows=len(entries),
                         tokens=rb.total_tokens,
                         uids=[u for u, _ in entries],
-                        **self._trace_attrs(u for u, _ in entries)):
-            logits, *moe, self.kv_cache = self._ragged_jit(
-                self.params, jnp.asarray(rb.ids),
-                jnp.asarray(rb.row_ids), jnp.asarray(rb.positions),
-                jnp.asarray(rb.lengths), jnp.asarray(rb.write_blocks),
-                jnp.asarray(rb.write_offsets),
-                jnp.asarray(rb.block_tables),
-                jnp.asarray(rb.last_index), self.kv_cache,
-                self.lora_bank,
-                (jnp.asarray(rb.adapter_slots)
-                 if self.lora_bank is not None else None))
-            # blocks: the pass completes here
-            logits, moe = jax.device_get((logits, moe))
-        self._note_moe("ragged_step", *moe)
-        dt = time.perf_counter() - t0
-        log_tokens = sm.config.enable_prefix_caching
-        for uid, toks in entries:
-            seq = sm.seqs[uid]
-            seq.seen_tokens += len(toks)
-            if log_tokens:
-                seq.token_log.extend(map(int, toks))
-        chunk_tokens = rb.total_tokens - decode_rows
-        self._m_ragged_steps.inc()
-        self._m_ragged_tokens.inc(rb.total_tokens)
-        self._m_ragged_prefill_rows.inc(len(entries) - decode_rows)
-        self._m_ragged_decode_rows.inc(decode_rows)
-        self._m_ragged_time.observe(dt)
-        self._m_ragged_pad.set(rb.pad_fraction)
-        self._m_ragged_host_syncs.inc()
-        # the family counters stay comparable across ragged/stitched:
-        # chunk tokens are prefill work wherever they run
-        if chunk_tokens:
-            self._m_prefill_tokens.inc(chunk_tokens)
-        flight.record("ragged_step", rows=len(entries),
-                      tokens=rb.total_tokens, bucket=rb.token_bucket,
-                      dur_s=round(dt, 5))
-        self._update_pool_telemetry()
+                        **self._trace_attrs(u for u, _ in entries)) as step:
+            with trace.span("ragged_dispatch"):
+                logits, *moe, self.kv_cache = self._ragged_jit(
+                    self.params, jnp.asarray(rb.ids),
+                    jnp.asarray(rb.row_ids), jnp.asarray(rb.positions),
+                    jnp.asarray(rb.lengths), jnp.asarray(rb.write_blocks),
+                    jnp.asarray(rb.write_offsets),
+                    jnp.asarray(rb.block_tables),
+                    jnp.asarray(rb.last_index), self.kv_cache,
+                    self.lora_bank,
+                    (jnp.asarray(rb.adapter_slots)
+                     if self.lora_bank is not None else None))
+            with trace.span("ragged_fetch"):
+                # blocks: the pass completes here
+                logits, moe = jax.device_get((logits, moe))
+        with trace.span("ragged_bookkeeping"):
+            # inference_ragged_step_seconds: the pack and the launch, to
+            # the logits' arrival (the two spans' own durations)
+            dt = packed["duration_s"] + step["duration_s"]
+            self._note_moe("ragged_step", *moe)
+            log_tokens = sm.config.enable_prefix_caching
+            for uid, toks in entries:
+                seq = sm.seqs[uid]
+                seq.seen_tokens += len(toks)
+                if log_tokens:
+                    seq.token_log.extend(map(int, toks))
+            chunk_tokens = rb.total_tokens - decode_rows
+            self._m_ragged_steps.inc()
+            self._m_ragged_tokens.inc(rb.total_tokens)
+            self._m_ragged_prefill_rows.inc(len(entries) - decode_rows)
+            self._m_ragged_decode_rows.inc(decode_rows)
+            self._m_ragged_time.observe(dt)
+            self._m_ragged_pad.set(rb.pad_fraction)
+            self._m_ragged_host_syncs.inc()
+            # the family counters stay comparable across ragged/stitched:
+            # chunk tokens are prefill work wherever they run
+            if chunk_tokens:
+                self._m_prefill_tokens.inc(chunk_tokens)
+            flight.record("ragged_step", rows=len(entries),
+                          tokens=rb.total_tokens, bucket=rb.token_bucket,
+                          dur_s=round(dt, 5))
+            self._update_pool_telemetry()
         return logits[:len(entries)]
 
     def put(self, batch_uids: Sequence[int],
@@ -1860,181 +1882,210 @@ class InferenceEngineV2:
         plain greedy either way. ``spec_mode`` overrides the configured
         chooser mode for this call ("auto"/"ngram"/"draft"). ``adapter``
         routes rows through a loaded LoRA adapter: a str applies to all
-        rows, a sequence gives one name (or None) per row."""
-        uids = list(uids) if uids is not None else list(range(len(prompts)))
-        outs: List[List[int]] = [list(map(int, p)) for p in prompts]
-        row_of = {uid: i for i, uid in enumerate(uids)}
-        sampling = temperature > 0.0
-        assert not (speculative and sampling), \
-            "speculative decoding is greedy-only (draft verification " \
-            "compares argmax)"
-        if speculative and self.model.cfg.attention == "mla":
-            raise NotImplementedError(
-                "speculative decoding of an attention='mla' model: the "
-                "verify pass has no latent form")
-        # each generate() call is an independent request batch: spec
-        # cold-streaks (and draft indexes) from earlier calls must not
-        # leak into this one
-        self._spec_miss_streak.clear()
-        self._draft_index.clear()
-        if adapter is not None:
-            names = ([adapter] * len(uids) if isinstance(adapter, str)
-                     else list(adapter))
-            if len(names) != len(uids):
-                raise ValueError(
-                    f"adapter list length {len(names)} != batch size "
-                    f"{len(uids)}")
-            for uid, name in zip(uids, names):
-                self.assign_adapter(uid, name)
-        if speculative:
-            if spec_mode not in (None, "auto", "ngram", "draft"):
-                raise ValueError(f"spec_mode must be auto|ngram|draft, "
-                                 f"got {spec_mode!r}")
-            if spec_mode == "draft" and self.draft_model is None:
-                raise ValueError("spec_mode='draft' requires a draft "
-                                 "model: call load_draft_model() first")
-            from .ngram_index import NGramIndex
-            for uid in uids:
-                # the request's routing decision is made ONCE, up front:
-                # the n-gram index over the prompt is the chooser's
-                # cheap repetitiveness prior, the per-mode accept-rate
-                # EMAs its learned history
-                idx = self._draft_index[uid] = NGramIndex(
-                    spec_ngram, self._SPEC_SCAN_WINDOW)
-                idx.sync(outs[row_of[uid]])
-                if spec_mode in ("ngram", "draft"):
-                    mode = spec_mode
-                else:
-                    mode = self.spec_chooser.choose(
-                        self.draft_model is not None,
-                        idx.has_candidate(spec_ngram))
-                self._spec_mode_of[int(uid)] = mode
-                self._m_spec_mode_requests.labels(mode=mode).inc()
-        base_rng = jax.random.PRNGKey(seed) if sampling else None
-        t_start = time.perf_counter()
-        # prompts go through put() (prefill); the continuation loop then
-        # stays in token space — argmax/sampler runs on device and only
-        # [N] int32s cross to host per step (put()'s [N, vocab] logits
-        # are the API for external schedulers, not the hot loop)
-        try:
-            logits = self.put(uids, prompts)
-            self._m_ttft.observe(time.perf_counter() - t_start)
-            if sampling:
-                from .sampling import fold_in_rows, sample_tokens_rowwise
-                # per-row keys (stable row seed + generated-token index):
-                # a row's stream depends only on its own draw history,
-                # so the per-token and fused-window paths sample the
-                # exact same tokens for a given seed
-                keys = fold_in_rows(base_rng,
-                                    jnp.arange(len(uids), dtype=jnp.int32),
-                                    jnp.zeros(len(uids), jnp.int32))
-                first = np.asarray(sample_tokens_rowwise(
-                    jnp.asarray(logits), keys,
-                    jnp.full((len(uids),), temperature, jnp.float32),
-                    jnp.full((len(uids),), top_p, jnp.float32),
-                    jnp.full((len(uids),), top_k, jnp.int32)))
-                cur = {uid: int(t) for uid, t in zip(uids, first)}
-            else:
-                cur = {uid: int(t) for uid, t in
-                       zip(uids, np.argmax(logits, axis=-1))}
-            live = set(uids)
-            prompt_lens = {uid: len(prompts[row_of[uid]]) for uid in uids}
-            row_seed = {uid: i for i, uid in enumerate(uids)}
-            window = 1 if speculative else self.decode_window
-            while max_new_tokens > 0:   # 0 -> prompt-only rows (no emit)
-                step_uids = []
-                for uid in uids:
-                    if uid not in live:
-                        continue
-                    tok = cur[uid]
-                    row = outs[row_of[uid]]
-                    row.append(tok)
-                    # per-uid budget (not a step counter): speculative
-                    # rounds and fused windows emit several tokens, so
-                    # sequences finish at different steps
-                    if ((eos_token_id is not None and tok == eos_token_id)
-                            or len(row) - prompt_lens[uid]
-                            >= max_new_tokens):
-                        live.discard(uid)
-                    else:
-                        step_uids.append(uid)
-                if not step_uids:
-                    break
-                # same guard put() applies: generating past max_seq_len
-                # (or a drained block pool) must raise, not silently
-                # overrun or crash inside table assembly
-                if not self.can_schedule(step_uids, [1] * len(step_uids)):
-                    raise RuntimeError(
-                        "generation not schedulable: prompt + generated "
-                        "tokens exceed max_seq_len or the free KV block "
-                        "pool; lower max_new_tokens or raise the limits")
-                # every step_uid is already tracked, so the batch can
-                # never exceed max_tracked_sequences — one call suffices
-                feed = [outs[row_of[u]][-1] for u in step_uids]
-                gen_count = [len(outs[row_of[u]]) - prompt_lens[u]
-                             for u in step_uids]
+        rows, a sequence gives one name (or None) per row.
+
+        A call is one ``generate`` span and no part of it runs outside a
+        leaf span under it (``train_batch``'s rule; docs/TELEMETRY.md,
+        "Span tracing"): ``gen_admit`` to the ``put()`` call, ``put()``'s
+        own leaves, ``gen_first_token`` (the host's pick over ``put()``'s
+        logits), then a decode window at a time ``gen_schedule`` (from
+        the last window's return to the next one's call) and the
+        window's own leaves, and ``gen_flush``. The leaves carry no
+        attrs: what a call was is on the root and on ``ragged_step`` /
+        ``decode_window``."""
+        with trace.span("generate", rows=len(prompts),
+                        max_new_tokens=int(max_new_tokens)):
+            with trace.span("gen_admit"):
+                uids = (list(uids) if uids is not None
+                        else list(range(len(prompts))))
+                outs: List[List[int]] = [list(map(int, p)) for p in prompts]
+                row_of = {uid: i for i, uid in enumerate(uids)}
+                sampling = temperature > 0.0
+                assert not (speculative and sampling), \
+                    "speculative decoding is greedy-only (draft " \
+                    "verification compares argmax)"
+                if speculative and self.model.cfg.attention == "mla":
+                    raise NotImplementedError(
+                        "speculative decoding of an attention='mla' model: "
+                        "the verify pass has no latent form")
+                # each generate() call is an independent request batch: spec
+                # cold-streaks (and draft indexes) from earlier calls must not
+                # leak into this one
+                self._spec_miss_streak.clear()
+                self._draft_index.clear()
+                if adapter is not None:
+                    names = ([adapter] * len(uids) if isinstance(adapter, str)
+                             else list(adapter))
+                    if len(names) != len(uids):
+                        raise ValueError(
+                            f"adapter list length {len(names)} != batch size "
+                            f"{len(uids)}")
+                    for uid, name in zip(uids, names):
+                        self.assign_adapter(uid, name)
                 if speculative:
-                    # per-request routing: draft-model rows take the
-                    # fused in-window path, the rest keep prompt-lookup
-                    draft_set = {u for u in step_uids
-                                 if self._spec_mode_of.get(int(u))
-                                 == "draft"}
-                    cur = {}
-                    if draft_set:
-                        cur.update(self._spec_window_round(
-                            [u for u in step_uids if u in draft_set],
-                            outs, row_of, prompt_lens, live,
-                            max_new_tokens, eos_token_id, spec_k))
-                    ngram_uids = [u for u in step_uids
-                                  if u not in draft_set]
-                    if ngram_uids:
-                        cur.update(self._speculative_round(
-                            ngram_uids, outs, row_of, prompt_lens, live,
-                            max_new_tokens, eos_token_id, spec_k,
-                            spec_ngram))
-                    continue
-                if window > 1:
-                    sl = self._window_steps_left(
-                        step_uids, [max_new_tokens - g for g in gen_count])
-                    eos = -1 if eos_token_id is None else int(eos_token_id)
+                    if spec_mode not in (None, "auto", "ngram", "draft"):
+                        raise ValueError("spec_mode must be auto|ngram|"
+                                         f"draft, got {spec_mode!r}")
+                    if spec_mode == "draft" and self.draft_model is None:
+                        raise ValueError(
+                            "spec_mode='draft' requires a draft model: call "
+                            "load_draft_model() first")
+                    from .ngram_index import NGramIndex
+                    for uid in uids:
+                        # the request's routing decision is made ONCE, up
+                        # front: the n-gram index over the prompt is the
+                        # chooser's cheap repetitiveness prior, the per-mode
+                        # accept-rate EMAs its learned history
+                        idx = self._draft_index[uid] = NGramIndex(
+                            spec_ngram, self._SPEC_SCAN_WINDOW)
+                        idx.sync(outs[row_of[uid]])
+                        if spec_mode in ("ngram", "draft"):
+                            mode = spec_mode
+                        else:
+                            mode = self.spec_chooser.choose(
+                                self.draft_model is not None,
+                                idx.has_candidate(spec_ngram))
+                        self._spec_mode_of[int(uid)] = mode
+                        self._m_spec_mode_requests.labels(mode=mode).inc()
+                base_rng = jax.random.PRNGKey(seed) if sampling else None
+            t_start = time.perf_counter()
+            # prompts go through put() (prefill); the continuation loop
+            # then stays in token space — argmax/sampler runs on device
+            # and only [N] int32s cross to host per step (put()'s [N,
+            # vocab] logits are the API for external schedulers, not the
+            # hot loop)
+            try:
+                logits = self.put(uids, prompts)
+                with trace.span("gen_first_token"):
+                    self._m_ttft.observe(time.perf_counter() - t_start)
                     if sampling:
+                        from .sampling import (fold_in_rows,
+                                               sample_tokens_rowwise)
+                        # per-row keys (stable row seed + generated-token
+                        # index): a row's stream depends only on its own
+                        # draw history, so the per-token and fused-window
+                        # paths sample the exact same tokens for a given
+                        # seed
+                        keys = fold_in_rows(
+                            base_rng, jnp.arange(len(uids), dtype=jnp.int32),
+                            jnp.zeros(len(uids), jnp.int32))
+                        first = np.asarray(sample_tokens_rowwise(
+                            jnp.asarray(logits), keys,
+                            jnp.full((len(uids),), temperature, jnp.float32),
+                            jnp.full((len(uids),), top_p, jnp.float32),
+                            jnp.full((len(uids),), top_k, jnp.int32)))
+                        cur = {uid: int(t) for uid, t in zip(uids, first)}
+                    else:
+                        cur = {uid: int(t) for uid, t in
+                               zip(uids, np.argmax(logits, axis=-1))}
+                    live = set(uids)
+                    prompt_lens = {uid: len(prompts[row_of[uid]])
+                                   for uid in uids}
+                    row_seed = {uid: i for i, uid in enumerate(uids)}
+                    window = 1 if speculative else self.decode_window
+                    eos = -1 if eos_token_id is None else int(eos_token_id)
+                    step_uids, em = [], None
+
+                while max_new_tokens > 0:   # 0 -> prompt-only rows (no emit)
+                    with trace.span("gen_schedule"):
+                        if em is not None:
+                            # the window that just returned: all but a
+                            # row's last emit are fed/cached already; the
+                            # host only re-applies the eos/budget cuts
+                            # (defensively — the device enforced them too)
+                            cur = {}
+                            for uid in step_uids:
+                                row = outs[row_of[uid]]
+                                toks_out = em[uid]
+                                full = prompt_lens[uid] + max_new_tokens
+                                for tok in toks_out[:-1]:
+                                    row.append(tok)
+                                    if tok == eos_token_id \
+                                            or len(row) >= full:
+                                        live.discard(uid)
+                                        break
+                                else:
+                                    cur[uid] = toks_out[-1]
+                            em = None
+                        step_uids = []
+                        for uid in uids:
+                            if uid not in live:
+                                continue
+                            tok = cur[uid]
+                            row = outs[row_of[uid]]
+                            row.append(tok)
+                            # per-uid budget (not a step counter):
+                            # speculative rounds and fused windows emit
+                            # several tokens, so sequences finish at
+                            # different steps
+                            if tok == eos_token_id or len(row) \
+                                    - prompt_lens[uid] >= max_new_tokens:
+                                live.discard(uid)
+                            else:
+                                step_uids.append(uid)
+                        if not step_uids:
+                            break
+                        # same guard put() applies: generating past
+                        # max_seq_len (or a drained block pool) must
+                        # raise, not silently overrun or crash inside
+                        # table assembly
+                        if not self.can_schedule(step_uids,
+                                                 [1] * len(step_uids)):
+                            raise RuntimeError(
+                                "generation not schedulable: prompt + "
+                                "generated tokens exceed max_seq_len or "
+                                "the free KV block pool; lower "
+                                "max_new_tokens or raise the limits")
+                        # every step_uid is already tracked, so the batch
+                        # can never exceed max_tracked_sequences — one
+                        # call suffices
+                        feed = [outs[row_of[u]][-1] for u in step_uids]
+                        gen_count = [len(outs[row_of[u]]) - prompt_lens[u]
+                                     for u in step_uids]
+                        if window > 1:
+                            sl = self._window_steps_left(
+                                step_uids,
+                                [max_new_tokens - g for g in gen_count])
+                    if speculative:
+                        # per-request routing: draft-model rows take the
+                        # fused in-window path, the rest keep prompt-lookup
+                        draft_set = {u for u in step_uids
+                                     if self._spec_mode_of.get(int(u))
+                                     == "draft"}
+                        cur = {}
+                        if draft_set:
+                            cur.update(self._spec_window_round(
+                                [u for u in step_uids if u in draft_set],
+                                outs, row_of, prompt_lens, live,
+                                max_new_tokens, eos_token_id, spec_k))
+                        ngram_uids = [u for u in step_uids
+                                      if u not in draft_set]
+                        if ngram_uids:
+                            cur.update(self._speculative_round(
+                                ngram_uids, outs, row_of, prompt_lens, live,
+                                max_new_tokens, eos_token_id, spec_k,
+                                spec_ngram))
+                    elif window > 1 and sampling:
                         em = self._decode_window_sample(
                             step_uids, feed, sl, [eos] * len(step_uids),
                             base_rng, [row_seed[u] for u in step_uids],
                             gen_count, temperature, top_p, top_k)
-                    else:
+                    elif window > 1:
                         em = self._decode_window_greedy(
                             step_uids, feed, sl, [eos] * len(step_uids))
-                    cur = {}
-                    for uid in step_uids:
-                        row = outs[row_of[uid]]
-                        toks_out = em[uid]
-                        finished = False
-                        # all but the last emit are fed/cached already;
-                        # the host only re-applies the eos/budget cuts
-                        # (defensively — the device enforced them too)
-                        for tok in toks_out[:-1]:
-                            row.append(tok)
-                            if ((eos_token_id is not None
-                                 and tok == eos_token_id)
-                                    or len(row) - prompt_lens[uid]
-                                    >= max_new_tokens):
-                                finished = True
-                                break
-                        if finished:
-                            live.discard(uid)
-                        else:
-                            cur[uid] = toks_out[-1]
-                elif sampling:
-                    cur = self._decode_batch_sample(
-                        step_uids, feed, base_rng,
-                        [row_seed[u] for u in step_uids], gen_count,
-                        temperature, top_p, top_k)
-                else:
-                    cur = self._decode_batch_greedy(step_uids, feed)
-        finally:
-            # flush even on the schedulability raise: a long-lived engine
-            # must not leak this call's KV blocks / sequence slots
-            for uid in uids:
-                self.flush(uid)
-        return [np.asarray(o) for o in outs]
+                    elif sampling:
+                        cur = self._decode_batch_sample(
+                            step_uids, feed, base_rng,
+                            [row_seed[u] for u in step_uids], gen_count,
+                            temperature, top_p, top_k)
+                    else:
+                        cur = self._decode_batch_greedy(step_uids, feed)
+            finally:
+                # flush even on the schedulability raise: a long-lived
+                # engine must not leak this call's KV blocks / sequence
+                # slots
+                with trace.span("gen_flush"):
+                    for uid in uids:
+                        self.flush(uid)
+                    rows = [np.asarray(o) for o in outs]
+        return rows

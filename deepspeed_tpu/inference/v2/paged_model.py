@@ -407,6 +407,118 @@ def _logits(cfg, params, x):
 
 
 # ---------------------------------------------------------------------------
+# Scopes: what a device trace can say about a serving program
+# ---------------------------------------------------------------------------
+# A device trace names an operation by its HLO instruction (``fusion.147``)
+# and the compiled program keeps, per instruction, the ``jax.named_scope``
+# path it was traced under. The helpers below are the one place the
+# serving programs open theirs, in the training model's words where they
+# exist (models/transformer.py): ``embed``, ``layers`` > ``attention`` >
+# {``qkv_proj``, ``kv_write``, ``attn_kernel``, ``out_proj``}, ``mlp``,
+# ``head`` (final norm and logits) and ``pick`` (sampling.py: the argmax
+# or the sampler). ``telemetry.memory.scopes(program)`` hands out the map
+# and ``utils.xla_profile.serve_phase`` reads a path as a phase. A scope
+# is metadata: it adds no instruction and moves no fusion (pinned by
+# tests/unit/inference/test_serving_scopes.py).
+def _embed(cfg, params, ids, pos):
+    """Token embeddings of ``ids`` at cache positions ``pos`` (the
+    learned table's rows, clipped: a bucket may round past
+    ``max_seq_len``), under scope ``embed``."""
+    with jax.named_scope("embed"):
+        x = params["embed"][ids]
+        if cfg.embed_scale != 1.0:
+            x = x * jnp.asarray(cfg.embed_scale, x.dtype)
+        x = _embed_ln(cfg, params, x)
+        if cfg.positional == "learned":
+            x = x + params["pos_embed"][
+                jnp.clip(pos, 0, cfg.max_seq_len - 1)]
+    return x
+
+
+def _qkv_heads(cfg, lp, hn, lead, cos, sin, lora_qv=None):
+    """q, k, v of the normed input as heads ``[*lead, heads, hd]``,
+    rotated where the model rotates, under scope ``qkv_proj``.
+    ``lora_qv(q, v) -> (q, v)`` adds a row's adapter to the flat
+    projections."""
+    nh, nkv, hd = cfg.num_heads, cfg.kv_heads, cfg.head_dim
+    with jax.named_scope("qkv_proj"):
+        q, k, v = qkv_proj(lp, hn)
+        if lora_qv is not None:
+            q, v = lora_qv(q, v)
+        q = q.reshape(*lead, nh, hd)
+        k = k.reshape(*lead, nkv, hd)
+        v = v.reshape(*lead, nkv, hd)
+        if cfg.positional == "rope":
+            q = _rotate(q, cos[..., None, :], sin[..., None, :])
+            k = _rotate(k, cos[..., None, :], sin[..., None, :])
+    return q, k, v
+
+
+def _kv_write_pair(kc, vc, ksc, vsc, l, blocks, offs, k, v):
+    """The write-set's keys and values into layer ``l`` of the pool,
+    under scope ``kv_write``."""
+    with jax.named_scope("kv_write"):
+        kc, ksc = _kv_write(kc, ksc, l, blocks, offs, k)
+        vc, vsc = _kv_write(vc, vsc, l, blocks, offs, v)
+    return kc, vc, ksc, vsc
+
+
+def _scan_layers(cfg, params, x, cache, lora, topo, attend):
+    """The per-head block's layer stack, scanned, for every program that
+    runs it (prefill, continue, decode, the ragged step, the speculative
+    verify). What differs between them is ``attend(lp, ll, l, hn, kc,
+    vc, ksc, vsc) -> (o, kc, vc, ksc, vsc)``: the program's projections
+    by its own shapes (``_qkv_heads``), its write-set
+    (``_kv_write_pair``) and its attention over the pool (scope
+    ``attn_kernel``: the Pallas call or the gather fallback). The norms,
+    the output projection, the residual adds and the MLP are the same
+    everywhere, and so are the scopes: ``layers`` > ``attention`` (the
+    norm, ``attend``, ``out_proj``, the add) and ``mlp``. Returns (x,
+    cache)."""
+
+    def layer_fn(carry, inputs):
+        x_in, kc, vc, ksc, vsc = carry
+        lp, l = inputs[0], inputs[1]
+        ll = inputs[2] if lora is not None else None
+        lp = _deq_layer(lp)
+        with jax.named_scope("attention"):
+            hn = _norm(cfg, x_in, lp["attn_norm"], lp.get("attn_norm_b"))
+            o, kc, vc, ksc, vsc = attend(lp, ll, l, hn, kc, vc, ksc, vsc)
+            with jax.named_scope("out_proj"):
+                a = out_proj(lp, o)
+            x = x_in + a
+        with jax.named_scope("mlp"):
+            if cfg.parallel_residual:
+                # Falcon block: attention and MLP both read the normed
+                # input of the layer and add to one stream (NeoX
+                # parallel_norms norms separately)
+                hn = (_norm(cfg, x_in, lp["mlp_norm"],
+                            lp.get("mlp_norm_b"))
+                      if cfg.parallel_norms else hn)
+            else:
+                hn = _norm(cfg, x, lp["mlp_norm"], lp.get("mlp_norm_b"))
+            x = x + _mlp(cfg, lp, hn, topo)
+        return (x, kc, vc, ksc, vsc), None
+
+    with jax.named_scope("layers"):
+        (x, kc, vc, ksc, vsc), _ = jax.lax.scan(
+            layer_fn, (x, cache["k"], cache["v"],
+                       cache.get("ks"), cache.get("vs")),
+            (params["layers"], jnp.arange(cfg.num_layers))
+            + ((lora,) if lora is not None else ()))
+    return x, _cache_dict(kc, vc, ksc, vsc)
+
+
+def _head(cfg, params, x, rows=None):
+    """The final norm over every position, ``rows(x)`` of the result
+    where only some feed the head, and their logits, under scope
+    ``head``."""
+    with jax.named_scope("head"):
+        x = _norm(cfg, x, params["final_norm"], params.get("final_norm_b"))
+        return _logits(cfg, params, x if rows is None else rows(x))
+
+
+# ---------------------------------------------------------------------------
 # The latent-attention block (attention='mla'): one step for every program
 # ---------------------------------------------------------------------------
 def _rotate_pairs(x, cos, sin, interleave):
@@ -483,20 +595,20 @@ def _latent_attention_sublayer(cfg, lp, x, l, pool, cos, sin, row_ids,
     q_rope = _rotate_pairs(q[..., dn:], cos[:, None], sin[:, None],
                            cfg.rope_interleave)
     W = pool["latent"].shape[-1]
-    pool = _latent_write(pool, l, write_blocks, write_offsets,
-                         jnp.concatenate([ckv, k_rope], axis=-1))
-    rows, at = _latent_rows(pool, l, hn.dtype)
+    with jax.named_scope("kv_write"):
+        pool = _latent_write(pool, l, write_blocks, write_offsets,
+                             jnp.concatenate([ckv, k_rope], axis=-1))
     wkv_b = lp["wkv_b"].reshape(dc, nh, dn + dv)
     q_lat = jnp.einsum("thd,chd->htc", q[..., :dn], wkv_b[..., :dn])
     qx = jnp.concatenate([q_lat, q_rope.transpose(1, 0, 2)], axis=-1)
     qx = jnp.pad(qx, ((0, 0), (0, 0), (0, W - dc - dr)))    # [nh, T, W]
     scale = 1.0 / float(dn + dr) ** 0.5
-    if use_kernel:
-        o_lat = latent_attention(qx, rows, at, row_ids, lengths,
-                                 block_tables, dc=dc, scale=scale)
-    else:
-        o_lat = latent_attention_reference(qx, rows, at, row_ids, lengths,
-                                           block_tables, dc=dc, scale=scale)
+    with jax.named_scope("attn_kernel"):
+        rows, at = _latent_rows(pool, l, hn.dtype)
+        attend = latent_attention if use_kernel \
+            else latent_attention_reference
+        o_lat = attend(qx, rows, at, row_ids, lengths, block_tables,
+                       dc=dc, scale=scale)
     o = jnp.einsum("htc,chd->thd", o_lat, wkv_b[..., dn:])
     return o.reshape(T, nh * dv) @ lp["wo"], pool
 
@@ -547,9 +659,10 @@ def _latent_step(cfg: TransformerConfig, params, ids, row_ids, pos, lengths,
     # doubled the logits' typical error against the float32 reference
     # and swapped an expert on 7 seeds of 10 where this swaps on 4
     # (PERF.md section 4)
-    x = params["embed"][ids].astype(jnp.float32)
-    if cfg.embed_scale != 1.0:
-        x = x * jnp.asarray(cfg.embed_scale, x.dtype)
+    with jax.named_scope("embed"):
+        x = params["embed"][ids].astype(jnp.float32)
+        if cfg.embed_scale != 1.0:
+            x = x * jnp.asarray(cfg.embed_scale, x.dtype)
     cos, sin = _rope_at(cfg, pos)                # [T, qk_rope_head_dim / 2]
     valid = lengths > 0
     lead = cfg.moe_first_dense_layers
@@ -569,24 +682,28 @@ def _latent_step(cfg: TransformerConfig, params, ids, row_ids, pos, lengths,
                 a, pool = _latent_attention_sublayer(
                     cfg, lp, x, first + i, pool, cos, sin, row_ids, lengths,
                     write_blocks, write_offsets, block_tables, use_kernel)
-            x = x + a.astype(jnp.float32)
-            hn = _norm(cfg, x, lp["mlp_norm"])
-            if routed:
-                out, topi = _moe_routed(
-                    cfg, lp, hn, experts, i,
-                    router_precision=jax.lax.Precision.HIGHEST)
-                stats = _merge_moe_stats(
-                    stats, _moe_stats(topi, valid, cfg.moe_num_experts))
-            else:
-                from ...models.transformer import gate_act
-                with jax.named_scope("dense_mlp"):
-                    hn = hn.astype(dtype)
-                    out = (gate_act(cfg)(hn @ lp["w_gate"])
-                           * (hn @ lp["w_up"])) @ lp["w_down"]
-            return (x + out.astype(jnp.float32), pool, stats), None
+                x = x + a.astype(jnp.float32)
+            with jax.named_scope("mlp"):
+                hn = _norm(cfg, x, lp["mlp_norm"])
+                if routed:
+                    out, topi = _moe_routed(
+                        cfg, lp, hn, experts, i,
+                        router_precision=jax.lax.Precision.HIGHEST)
+                    with jax.named_scope("moe_router"):
+                        stats = _merge_moe_stats(stats, _moe_stats(
+                            topi, valid, cfg.moe_num_experts))
+                else:
+                    from ...models.transformer import gate_act
+                    with jax.named_scope("dense_mlp"):
+                        hn = hn.astype(dtype)
+                        out = (gate_act(cfg)(hn @ lp["w_gate"])
+                               * (hn @ lp["w_up"])) @ lp["w_down"]
+                x = x + out.astype(jnp.float32)
+            return (x, pool, stats), None
 
-        (x, pool, stats), _ = jax.lax.scan(
-            layer_fn, (x, pool, stats), (scanned, jnp.arange(n)))
+        with jax.named_scope("layers"):
+            (x, pool, stats), _ = jax.lax.scan(
+                layer_fn, (x, pool, stats), (scanned, jnp.arange(n)))
         return x, pool, stats
 
     pool, stats = cache, jnp.zeros((4,), jnp.float32)
@@ -594,7 +711,9 @@ def _latent_step(cfg: TransformerConfig, params, ids, row_ids, pos, lengths,
         x, pool, stats = stack(x, pool, stats, params["lead_layers"], 0,
                                False)
     x, pool, stats = stack(x, pool, stats, params["layers"], lead, moe)
-    return _norm(cfg, x, params["final_norm"]).astype(dtype), stats, pool
+    with jax.named_scope("head"):
+        x = _norm(cfg, x, params["final_norm"]).astype(dtype)
+    return x, stats, pool
 
 
 def _refuse_latent(cfg, program):
@@ -632,76 +751,47 @@ def paged_prefill(cfg: TransformerConfig, params, ids: jnp.ndarray,
     flash_ok = (use_kernel and C % 128 == 0 and hd % 8 == 0
                 and cfg.positional != "alibi")
     params = _deq_nonlayer(params)
-    x = params["embed"][ids[0]]                                # [C, H]
-    if cfg.embed_scale != 1.0:
-        x = x * jnp.asarray(cfg.embed_scale, x.dtype)
-    x = _embed_ln(cfg, params, x)
-    if cfg.positional == "learned":
-        # the bucket C may round past max_seq_len; clip like paged_continue
-        x = x + params["pos_embed"][
-            jnp.clip(jnp.arange(C), 0, cfg.max_seq_len - 1)]
     pos = jnp.arange(C)
+    x = _embed(cfg, params, ids[0], pos)                       # [C, H]
     cos, sin = _rope_at(cfg, pos)                              # [C, half]
     valid = pos < prompt_len                                   # [C]
     causal = pos[:, None] >= pos[None, :]
     mask = causal & valid[None, :]                             # [C, C]
 
-    def layer_fn(carry, inputs):
-        x, kc, vc, ksc, vsc = carry
-        lp, l = inputs[0], inputs[1]
-        ll = inputs[2] if lora is not None else None
-        lp = _deq_layer(lp)
-        hn = _norm(cfg, x, lp["attn_norm"], lp.get("attn_norm_b"))
-        q, k, v = qkv_proj(lp, hn)
-        q, v = _lora_qv(ll, hn, adapter_ids, q, v)
-        q = q.reshape(C, nh, hd)
-        k = k.reshape(C, nkv, hd)
-        v = v.reshape(C, nkv, hd)
-        if cfg.positional == "rope":
-            q = _rotate(q, cos[:, None], sin[:, None])
-            k = _rotate(k, cos[:, None], sin[:, None])
-        kc, ksc = _kv_write(kc, ksc, l, block_ids, offsets, k)
-        vc, vsc = _kv_write(vc, vsc, l, block_ids, offsets, v)
-        if flash_ok:
-            from ...ops.flash_attention import flash_attention
+    def attend(lp, ll, l, hn, kc, vc, ksc, vsc):
+        q, k, v = _qkv_heads(
+            cfg, lp, hn, (C,), cos, sin,
+            lambda q, v: _lora_qv(ll, hn, adapter_ids, q, v))
+        kc, vc, ksc, vsc = _kv_write_pair(kc, vc, ksc, vsc, l, block_ids,
+                                          offsets, k, v)
+        with jax.named_scope("attn_kernel"):
+            if flash_ok:
+                from ...ops.flash_attention import flash_attention
 
-            o = flash_attention(
-                q.transpose(1, 0, 2)[None],      # [1, nh, C, hd]
-                k.transpose(1, 0, 2)[None],      # [1, nkv, C, hd]
-                v.transpose(1, 0, 2)[None],
-                causal=True)[0].transpose(1, 0, 2).reshape(C, nh * hd)
-        else:
-            kf, vf = k, v
-            if nkv != nh:
-                kf = jnp.repeat(kf, nh // nkv, axis=1)
-                vf = jnp.repeat(vf, nh // nkv, axis=1)
-            scores = jnp.einsum("qhd,khd->hqk", q, kf).astype(jnp.float32)
-            scores = scores / jnp.sqrt(jnp.asarray(hd, jnp.float32))
-            if cfg.positional == "alibi":
-                scores = scores + _alibi_row(cfg, pos)
-            scores = jnp.where(mask[None], scores, NEG_INF)
-            probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
-            o = jnp.einsum("hqk,khd->qhd", probs, vf).reshape(C, nh * hd)
-        if cfg.parallel_residual:
-            # Falcon block: attention and MLP both read the normed input;
-            # one residual add (NeoX parallel_norms norms separately)
-            hn2 = (_norm(cfg, x, lp["mlp_norm"], lp.get("mlp_norm_b"))
-                   if cfg.parallel_norms else hn)
-            x = x + out_proj(lp, o) + _mlp(cfg, lp, hn2, topo)
-            return (x, kc, vc, ksc, vsc), None
-        x = x + out_proj(lp, o)
-        hn = _norm(cfg, x, lp["mlp_norm"], lp.get("mlp_norm_b"))
-        x = x + _mlp(cfg, lp, hn, topo)
-        return (x, kc, vc, ksc, vsc), None
+                o = flash_attention(
+                    q.transpose(1, 0, 2)[None],      # [1, nh, C, hd]
+                    k.transpose(1, 0, 2)[None],      # [1, nkv, C, hd]
+                    v.transpose(1, 0, 2)[None],
+                    causal=True)[0].transpose(1, 0, 2).reshape(C, nh * hd)
+            else:
+                kf, vf = k, v
+                if nkv != nh:
+                    kf = jnp.repeat(kf, nh // nkv, axis=1)
+                    vf = jnp.repeat(vf, nh // nkv, axis=1)
+                scores = jnp.einsum("qhd,khd->hqk", q,
+                                    kf).astype(jnp.float32)
+                scores = scores / jnp.sqrt(jnp.asarray(hd, jnp.float32))
+                if cfg.positional == "alibi":
+                    scores = scores + _alibi_row(cfg, pos)
+                scores = jnp.where(mask[None], scores, NEG_INF)
+                probs = jax.nn.softmax(scores, axis=-1).astype(hn.dtype)
+                o = jnp.einsum("hqk,khd->qhd", probs,
+                               vf).reshape(C, nh * hd)
+        return o, kc, vc, ksc, vsc
 
-    (x, kc, vc, ksc, vsc), _ = jax.lax.scan(
-        layer_fn, (x, cache["k"], cache["v"],
-                   cache.get("ks"), cache.get("vs")),
-        (params["layers"], jnp.arange(cfg.num_layers))
-        + ((lora,) if lora is not None else ()))
-    x = _norm(cfg, x, params["final_norm"], params.get("final_norm_b"))
-    last = jnp.take(x, prompt_len - 1, axis=0)                  # [H]
-    return _logits(cfg, params, last), _cache_dict(kc, vc, ksc, vsc)
+    x, cache = _scan_layers(cfg, params, x, cache, lora, topo, attend)
+    return _head(cfg, params, x,
+                 lambda x: jnp.take(x, prompt_len - 1, axis=0)), cache
 
 
 # ---------------------------------------------------------------------------
@@ -734,76 +824,49 @@ def paged_continue(cfg: TransformerConfig, params, ids: jnp.ndarray,
     ctx = MB * block_size
     nh, nkv, hd = cfg.num_heads, cfg.kv_heads, cfg.head_dim
     params = _deq_nonlayer(params)
-    x = params["embed"][ids[0]]                                 # [C, H]
-    if cfg.embed_scale != 1.0:
-        x = x * jnp.asarray(cfg.embed_scale, x.dtype)
-    x = _embed_ln(cfg, params, x)
     pos = start_pos + jnp.arange(C)                             # [C]
-    if cfg.positional == "learned":
-        x = x + params["pos_embed"][jnp.clip(pos, 0, cfg.max_seq_len - 1)]
+    x = _embed(cfg, params, ids[0], pos)                        # [C, H]
     cos, sin = _rope_at(cfg, pos)
     ctx_pos = jnp.arange(ctx)
     # each chunk token sees cache positions up to and including itself
     mask = ctx_pos[None, :] <= pos[:, None]                     # [C, ctx]
 
-    def layer_fn(carry, inputs):
-        x, kc, vc, ksc, vsc = carry
-        lp, l = inputs[0], inputs[1]
-        ll = inputs[2] if lora is not None else None
-        lp = _deq_layer(lp)
-        hn = _norm(cfg, x, lp["attn_norm"], lp.get("attn_norm_b"))
-        q, k, v = qkv_proj(lp, hn)
-        q, v = _lora_qv(ll, hn, adapter_ids, q, v)
-        q = q.reshape(C, nh, hd)
-        k = k.reshape(C, nkv, hd)
-        v = v.reshape(C, nkv, hd)
-        if cfg.positional == "rope":
-            q = _rotate(q, cos[:, None], sin[:, None])
-            k = _rotate(k, cos[:, None], sin[:, None])
-        kc, ksc = _kv_write(kc, ksc, l, block_ids, offsets, k)
-        vc, vsc = _kv_write(vc, vsc, l, block_ids, offsets, v)
-        kpages = _kv_read(kc, ksc, l, block_table, nkv,
-                          x.dtype).reshape(ctx, nkv, hd)
-        vpages = _kv_read(vc, vsc, l, block_table, nkv,
-                          x.dtype).reshape(ctx, nkv, hd)
-        if nkv != nh:
-            kpages = jnp.repeat(kpages, nh // nkv, axis=1)
-            vpages = jnp.repeat(vpages, nh // nkv, axis=1)
-        scores = jnp.einsum("qhd,chd->hqc", q, kpages).astype(jnp.float32)
-        scores = scores / jnp.sqrt(jnp.asarray(hd, jnp.float32))
-        if cfg.positional == "alibi":
-            scores = scores + _alibi_row(cfg, ctx_pos)
-        scores = jnp.where(mask[None], scores, NEG_INF)
-        probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
-        o = jnp.einsum("hqc,chd->qhd", probs, vpages).reshape(C, nh * hd)
-        if cfg.parallel_residual:
-            # Falcon block: attention and MLP both read the normed input;
-            # one residual add (NeoX parallel_norms norms separately)
-            hn2 = (_norm(cfg, x, lp["mlp_norm"], lp.get("mlp_norm_b"))
-                   if cfg.parallel_norms else hn)
-            x = x + out_proj(lp, o) + _mlp(cfg, lp, hn2, topo)
-            return (x, kc, vc, ksc, vsc), None
-        x = x + out_proj(lp, o)
-        hn = _norm(cfg, x, lp["mlp_norm"], lp.get("mlp_norm_b"))
-        x = x + _mlp(cfg, lp, hn, topo)
-        return (x, kc, vc, ksc, vsc), None
+    def attend(lp, ll, l, hn, kc, vc, ksc, vsc):
+        q, k, v = _qkv_heads(
+            cfg, lp, hn, (C,), cos, sin,
+            lambda q, v: _lora_qv(ll, hn, adapter_ids, q, v))
+        kc, vc, ksc, vsc = _kv_write_pair(kc, vc, ksc, vsc, l, block_ids,
+                                          offsets, k, v)
+        with jax.named_scope("attn_kernel"):
+            kpages = _kv_read(kc, ksc, l, block_table, nkv,
+                              hn.dtype).reshape(ctx, nkv, hd)
+            vpages = _kv_read(vc, vsc, l, block_table, nkv,
+                              hn.dtype).reshape(ctx, nkv, hd)
+            if nkv != nh:
+                kpages = jnp.repeat(kpages, nh // nkv, axis=1)
+                vpages = jnp.repeat(vpages, nh // nkv, axis=1)
+            scores = jnp.einsum("qhd,chd->hqc", q,
+                                kpages).astype(jnp.float32)
+            scores = scores / jnp.sqrt(jnp.asarray(hd, jnp.float32))
+            if cfg.positional == "alibi":
+                scores = scores + _alibi_row(cfg, ctx_pos)
+            scores = jnp.where(mask[None], scores, NEG_INF)
+            probs = jax.nn.softmax(scores, axis=-1).astype(hn.dtype)
+            o = jnp.einsum("hqc,chd->qhd", probs,
+                           vpages).reshape(C, nh * hd)
+        return o, kc, vc, ksc, vsc
 
-    (x, kc, vc, ksc, vsc), _ = jax.lax.scan(
-        layer_fn, (x, cache["k"], cache["v"],
-                   cache.get("ks"), cache.get("vs")),
-        (params["layers"], jnp.arange(cfg.num_layers))
-        + ((lora,) if lora is not None else ()))
-    x = _norm(cfg, x, params["final_norm"], params.get("final_norm_b"))
+    x, cache = _scan_layers(cfg, params, x, cache, lora, topo, attend)
     if greedy_window:
         # speculative verification: greedy token ids for the first
         # ``greedy_window`` fed positions — the projection runs on the
         # sliced window (not the padded bucket) and only [window] int32
         # crosses to host, keeping the decode loop's transfer discipline
         from .sampling import greedy_tokens
-        ids_out = greedy_tokens(_logits(cfg, params, x[:greedy_window]))
-        return ids_out, _cache_dict(kc, vc, ksc, vsc)
-    last = jnp.take(x, n_new - 1, axis=0)
-    return _logits(cfg, params, last), _cache_dict(kc, vc, ksc, vsc)
+        return greedy_tokens(_head(cfg, params, x,
+                                   lambda x: x[:greedy_window])), cache
+    return _head(cfg, params, x,
+                 lambda x: jnp.take(x, n_new - 1, axis=0)), cache
 
 
 # ---------------------------------------------------------------------------
@@ -831,16 +894,12 @@ def paged_decode(cfg: TransformerConfig, params, toks: jnp.ndarray,
             cfg, params, toks, jnp.arange(N, dtype=jnp.int32), pos,
             jnp.where(active, pos + 1, 0), jnp.where(active, blk, 0),
             pos % block_size, block_tables, cache, use_kernel=use_kernel)
-        return _logits(cfg, params, x), stats, cache
+        with jax.named_scope("head"):
+            return _logits(cfg, params, x), stats, cache
     nh, nkv, hd = cfg.num_heads, cfg.kv_heads, cfg.head_dim
     ctx = MB * block_size
     params = _deq_nonlayer(params)
-    x = params["embed"][toks]                                   # [N, H]
-    if cfg.embed_scale != 1.0:
-        x = x * jnp.asarray(cfg.embed_scale, x.dtype)
-    x = _embed_ln(cfg, params, x)
-    if cfg.positional == "learned":
-        x = x + params["pos_embed"][jnp.clip(pos, 0, cfg.max_seq_len - 1)]
+    x = _embed(cfg, params, toks, pos)                          # [N, H]
     cos, sin = _rope_at(cfg, pos)                               # [N, half]
     blk = jnp.take_along_axis(block_tables,
                               (pos // block_size)[:, None], axis=1)[:, 0]
@@ -849,63 +908,43 @@ def paged_decode(cfg: TransformerConfig, params, toks: jnp.ndarray,
     ctx_pos = jnp.arange(ctx)
     attn_mask = ctx_pos[None, :] <= pos[:, None]                # [N, ctx]
 
-    def layer_fn(carry, inputs):
-        x, kc, vc, ksc, vsc = carry
-        lp, l = inputs[0], inputs[1]
-        ll = inputs[2] if lora is not None else None
-        lp = _deq_layer(lp)
-        hn = _norm(cfg, x, lp["attn_norm"], lp.get("attn_norm_b"))
-        q, k, v = qkv_proj(lp, hn)
-        q, v = _lora_qv(ll, hn, adapter_ids, q, v)
-        q = q.reshape(N, nh, hd)
-        k = k.reshape(N, nkv, hd)
-        v = v.reshape(N, nkv, hd)
-        if cfg.positional == "rope":
-            q = _rotate(q, cos[:, None], sin[:, None])
-            k = _rotate(k, cos[:, None], sin[:, None])
-        kc, ksc = _kv_write(kc, ksc, l, blk, off, k)
-        vc, vsc = _kv_write(vc, vsc, l, blk, off, v)
-        if use_kernel:
-            from .kernels.paged_attention import paged_attention
-            o = paged_attention(
-                q, kc, vc, l, block_tables, pos + 1,
-                k_scale=None if ksc is None else ksc[l],
-                v_scale=None if vsc is None else vsc[l]).reshape(N, nh * hd)
-        else:
-            # gather this sequence's pages: [N, MB, bs, nkv, hd] -> [N, ctx, ..]
-            kpages = _kv_read(kc, ksc, l, block_tables, nkv,
-                              x.dtype).reshape(N, ctx, nkv, hd)
-            vpages = _kv_read(vc, vsc, l, block_tables, nkv,
-                              x.dtype).reshape(N, ctx, nkv, hd)
-            if nkv != nh:
-                kpages = jnp.repeat(kpages, nh // nkv, axis=2)
-                vpages = jnp.repeat(vpages, nh // nkv, axis=2)
-            scores = jnp.einsum("nhd,nchd->nhc", q, kpages).astype(jnp.float32)
-            scores = scores / jnp.sqrt(jnp.asarray(hd, jnp.float32))
-            if cfg.positional == "alibi":
-                scores = scores + _alibi_row(cfg, ctx_pos)[None, :, 0, :]
-            scores = jnp.where(attn_mask[:, None, :], scores, NEG_INF)
-            probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
-            o = jnp.einsum("nhc,nchd->nhd", probs, vpages).reshape(N, nh * hd)
-        if cfg.parallel_residual:
-            # Falcon block: attention and MLP both read the normed input;
-            # one residual add (NeoX parallel_norms norms separately)
-            hn2 = (_norm(cfg, x, lp["mlp_norm"], lp.get("mlp_norm_b"))
-                   if cfg.parallel_norms else hn)
-            x = x + out_proj(lp, o) + _mlp(cfg, lp, hn2, topo)
-            return (x, kc, vc, ksc, vsc), None
-        x = x + out_proj(lp, o)
-        hn = _norm(cfg, x, lp["mlp_norm"], lp.get("mlp_norm_b"))
-        x = x + _mlp(cfg, lp, hn, topo)
-        return (x, kc, vc, ksc, vsc), None
+    def attend(lp, ll, l, hn, kc, vc, ksc, vsc):
+        q, k, v = _qkv_heads(
+            cfg, lp, hn, (N,), cos, sin,
+            lambda q, v: _lora_qv(ll, hn, adapter_ids, q, v))
+        kc, vc, ksc, vsc = _kv_write_pair(kc, vc, ksc, vsc, l, blk, off,
+                                          k, v)
+        with jax.named_scope("attn_kernel"):
+            if use_kernel:
+                from .kernels.paged_attention import paged_attention
+                o = paged_attention(
+                    q, kc, vc, l, block_tables, pos + 1,
+                    k_scale=None if ksc is None else ksc[l],
+                    v_scale=None if vsc is None else vsc[l]
+                ).reshape(N, nh * hd)
+            else:
+                # gather this sequence's pages:
+                # [N, MB, bs, nkv, hd] -> [N, ctx, ..]
+                kpages = _kv_read(kc, ksc, l, block_tables, nkv,
+                                  hn.dtype).reshape(N, ctx, nkv, hd)
+                vpages = _kv_read(vc, vsc, l, block_tables, nkv,
+                                  hn.dtype).reshape(N, ctx, nkv, hd)
+                if nkv != nh:
+                    kpages = jnp.repeat(kpages, nh // nkv, axis=2)
+                    vpages = jnp.repeat(vpages, nh // nkv, axis=2)
+                scores = jnp.einsum("nhd,nchd->nhc", q,
+                                    kpages).astype(jnp.float32)
+                scores = scores / jnp.sqrt(jnp.asarray(hd, jnp.float32))
+                if cfg.positional == "alibi":
+                    scores = scores + _alibi_row(cfg, ctx_pos)[None, :, 0, :]
+                scores = jnp.where(attn_mask[:, None, :], scores, NEG_INF)
+                probs = jax.nn.softmax(scores, axis=-1).astype(hn.dtype)
+                o = jnp.einsum("nhc,nchd->nhd", probs,
+                               vpages).reshape(N, nh * hd)
+        return o, kc, vc, ksc, vsc
 
-    (x, kc, vc, ksc, vsc), _ = jax.lax.scan(
-        layer_fn, (x, cache["k"], cache["v"],
-                   cache.get("ks"), cache.get("vs")),
-        (params["layers"], jnp.arange(cfg.num_layers))
-        + ((lora,) if lora is not None else ()))
-    x = _norm(cfg, x, params["final_norm"], params.get("final_norm_b"))
-    return _logits(cfg, params, x), _cache_dict(kc, vc, ksc, vsc)
+    x, cache = _scan_layers(cfg, params, x, cache, lora, topo, attend)
+    return _head(cfg, params, x), cache
 
 
 # ---------------------------------------------------------------------------
@@ -947,16 +986,12 @@ def paged_ragged_step(cfg: TransformerConfig, params, ids: jnp.ndarray,
         x, stats, cache = _latent_step(
             cfg, params, ids, row_ids, pos, lengths, write_blocks,
             write_offsets, block_tables, cache, use_kernel=use_kernel)
-        return _logits(cfg, params, x[last_index]), stats, cache
+        with jax.named_scope("head"):
+            return _logits(cfg, params, x[last_index]), stats, cache
     ctx = MBw * block_size
     nh, nkv, hd = cfg.num_heads, cfg.kv_heads, cfg.head_dim
     params = _deq_nonlayer(params)
-    x = params["embed"][ids]                                     # [T, H]
-    if cfg.embed_scale != 1.0:
-        x = x * jnp.asarray(cfg.embed_scale, x.dtype)
-    x = _embed_ln(cfg, params, x)
-    if cfg.positional == "learned":
-        x = x + params["pos_embed"][jnp.clip(pos, 0, cfg.max_seq_len - 1)]
+    x = _embed(cfg, params, ids, pos)                            # [T, H]
     cos, sin = _rope_at(cfg, pos)                                # [T, half]
     ctx_pos = jnp.arange(ctx)
     attn_mask = ctx_pos[None, :] < lengths[:, None]              # [T, ctx]
@@ -966,69 +1001,46 @@ def paged_ragged_step(cfg: TransformerConfig, params, ids: jnp.ndarray,
     # a plain [T] indexed read — padding rows carry slot 0 (base)
     tok_aid = adapter_ids[row_ids] if lora is not None else None
 
-    def layer_fn(carry, inputs):
-        x, kc, vc, ksc, vsc = carry
-        lp, l = inputs[0], inputs[1]
-        ll = inputs[2] if lora is not None else None
-        lp = _deq_layer(lp)
-        hn = _norm(cfg, x, lp["attn_norm"], lp.get("attn_norm_b"))
-        q, k, v = qkv_proj(lp, hn)
-        q, v = _lora_qv(ll, hn, tok_aid, q, v)
-        q = q.reshape(T, nh, hd)
-        k = k.reshape(T, nkv, hd)
-        v = v.reshape(T, nkv, hd)
-        if cfg.positional == "rope":
-            q = _rotate(q, cos[:, None], sin[:, None])
-            k = _rotate(k, cos[:, None], sin[:, None])
-        kc, ksc = _kv_write(kc, ksc, l, write_blocks, write_offsets, k)
-        vc, vsc = _kv_write(vc, vsc, l, write_blocks, write_offsets, v)
-        if use_kernel:
-            from .kernels.ragged_attention import ragged_attention
-            o = ragged_attention(
-                q, kc, vc, l, row_ids, lengths, block_tables,
-                k_scale=None if ksc is None else ksc[l],
-                v_scale=None if vsc is None else vsc[l]).reshape(T, nh * hd)
-        else:
-            # gather each ROW's pages once, indirect per token: the
-            # materializing fallback (parity reference + tp/alibi/quant)
-            kpages = _kv_read(kc, ksc, l, block_tables, nkv,
-                              x.dtype).reshape(RB, ctx, nkv, hd)
-            vpages = _kv_read(vc, vsc, l, block_tables, nkv,
-                              x.dtype).reshape(RB, ctx, nkv, hd)
-            ktok = kpages[row_ids]                      # [T, ctx, nkv, hd]
-            vtok = vpages[row_ids]
-            if nkv != nh:
-                ktok = jnp.repeat(ktok, nh // nkv, axis=2)
-                vtok = jnp.repeat(vtok, nh // nkv, axis=2)
-            scores = jnp.einsum("thd,tchd->thc", q,
-                                ktok).astype(jnp.float32)
-            scores = scores / jnp.sqrt(jnp.asarray(hd, jnp.float32))
-            if cfg.positional == "alibi":
-                scores = scores + _alibi_row(cfg, ctx_pos)[None, :, 0, :]
-            scores = jnp.where(attn_mask[:, None, :], scores, NEG_INF)
-            probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
-            o = jnp.einsum("thc,tchd->thd", probs,
-                           vtok).reshape(T, nh * hd)
-        if cfg.parallel_residual:
-            # Falcon block: attention and MLP both read the normed input;
-            # one residual add (NeoX parallel_norms norms separately)
-            hn2 = (_norm(cfg, x, lp["mlp_norm"], lp.get("mlp_norm_b"))
-                   if cfg.parallel_norms else hn)
-            x = x + out_proj(lp, o) + _mlp(cfg, lp, hn2, topo)
-            return (x, kc, vc, ksc, vsc), None
-        x = x + out_proj(lp, o)
-        hn = _norm(cfg, x, lp["mlp_norm"], lp.get("mlp_norm_b"))
-        x = x + _mlp(cfg, lp, hn, topo)
-        return (x, kc, vc, ksc, vsc), None
+    def attend(lp, ll, l, hn, kc, vc, ksc, vsc):
+        q, k, v = _qkv_heads(
+            cfg, lp, hn, (T,), cos, sin,
+            lambda q, v: _lora_qv(ll, hn, tok_aid, q, v))
+        kc, vc, ksc, vsc = _kv_write_pair(kc, vc, ksc, vsc, l,
+                                          write_blocks, write_offsets, k, v)
+        with jax.named_scope("attn_kernel"):
+            if use_kernel:
+                from .kernels.ragged_attention import ragged_attention
+                o = ragged_attention(
+                    q, kc, vc, l, row_ids, lengths, block_tables,
+                    k_scale=None if ksc is None else ksc[l],
+                    v_scale=None if vsc is None else vsc[l]
+                ).reshape(T, nh * hd)
+            else:
+                # gather each ROW's pages once, indirect per token: the
+                # materializing fallback (parity reference +
+                # tp/alibi/quant)
+                kpages = _kv_read(kc, ksc, l, block_tables, nkv,
+                                  hn.dtype).reshape(RB, ctx, nkv, hd)
+                vpages = _kv_read(vc, vsc, l, block_tables, nkv,
+                                  hn.dtype).reshape(RB, ctx, nkv, hd)
+                ktok = kpages[row_ids]                  # [T, ctx, nkv, hd]
+                vtok = vpages[row_ids]
+                if nkv != nh:
+                    ktok = jnp.repeat(ktok, nh // nkv, axis=2)
+                    vtok = jnp.repeat(vtok, nh // nkv, axis=2)
+                scores = jnp.einsum("thd,tchd->thc", q,
+                                    ktok).astype(jnp.float32)
+                scores = scores / jnp.sqrt(jnp.asarray(hd, jnp.float32))
+                if cfg.positional == "alibi":
+                    scores = scores + _alibi_row(cfg, ctx_pos)[None, :, 0, :]
+                scores = jnp.where(attn_mask[:, None, :], scores, NEG_INF)
+                probs = jax.nn.softmax(scores, axis=-1).astype(hn.dtype)
+                o = jnp.einsum("thc,tchd->thd", probs,
+                               vtok).reshape(T, nh * hd)
+        return o, kc, vc, ksc, vsc
 
-    (x, kc, vc, ksc, vsc), _ = jax.lax.scan(
-        layer_fn, (x, cache["k"], cache["v"],
-                   cache.get("ks"), cache.get("vs")),
-        (params["layers"], jnp.arange(cfg.num_layers))
-        + ((lora,) if lora is not None else ()))
-    x = _norm(cfg, x, params["final_norm"], params.get("final_norm_b"))
-    last = x[last_index]                                         # [RB, H]
-    return _logits(cfg, params, last), _cache_dict(kc, vc, ksc, vsc)
+    x, cache = _scan_layers(cfg, params, x, cache, lora, topo, attend)
+    return _head(cfg, params, x, lambda x: x[last_index]), cache  # [RB, V]
 
 
 # ---------------------------------------------------------------------------
@@ -1142,13 +1154,8 @@ def _paged_verify(cfg: TransformerConfig, params, fed: jnp.ndarray,
     ctx = MB * block_size
     nh, nkv, hd = cfg.num_heads, cfg.kv_heads, cfg.head_dim
     params = _deq_nonlayer(params)
-    x = params["embed"][fed]                                    # [N, S, H]
-    if cfg.embed_scale != 1.0:
-        x = x * jnp.asarray(cfg.embed_scale, x.dtype)
-    x = _embed_ln(cfg, params, x)
     posm = pos0[:, None] + jnp.arange(S)[None, :]               # [N, S]
-    if cfg.positional == "learned":
-        x = x + params["pos_embed"][jnp.clip(posm, 0, cfg.max_seq_len - 1)]
+    x = _embed(cfg, params, fed, posm)                          # [N, S, H]
     cos, sin = _rope_at(cfg, posm)                              # [N, S, half]
     blkm = jnp.take_along_axis(block_tables, posm // block_size, axis=1)
     blkm = jnp.where(active[:, None], blkm, 0).reshape(N * S)
@@ -1159,75 +1166,52 @@ def _paged_verify(cfg: TransformerConfig, params, fed: jnp.ndarray,
     row_ids = jnp.repeat(jnp.arange(N, dtype=jnp.int32), S)     # [N*S]
     lengths = jnp.where(active[:, None], posm + 1, 0).reshape(N * S)
 
-    def layer_fn(carry, inputs):
-        x, kc, vc, ksc, vsc = carry
-        lp, l = inputs[0], inputs[1]
-        ll = inputs[2] if lora is not None else None
-        lp = _deq_layer(lp)
-        hn = _norm(cfg, x, lp["attn_norm"], lp.get("attn_norm_b"))
-        q, k, v = qkv_proj(lp, hn)
-        if ll is not None:
+    def attend(lp, ll, l, hn, kc, vc, ksc, vsc):
+        def lora_qv(q, v):
+            if ll is None:
+                return q, v
             # bank gather broadcast over the S fed positions of each row
-            q = q + _lora_delta(ll["qa"], ll["qb"],
-                                hn.reshape(N * S, -1),
-                                jnp.repeat(adapter_ids, S)).reshape(q.shape)
-            v = v + _lora_delta(ll["va"], ll["vb"],
-                                hn.reshape(N * S, -1),
-                                jnp.repeat(adapter_ids, S)).reshape(v.shape)
-        q = q.reshape(N, S, nh, hd)
-        k = k.reshape(N, S, nkv, hd)
-        v = v.reshape(N, S, nkv, hd)
-        if cfg.positional == "rope":
-            q = _rotate(q, cos[..., None, :], sin[..., None, :])
-            k = _rotate(k, cos[..., None, :], sin[..., None, :])
-        kc, ksc = _kv_write(kc, ksc, l, blkm, offm,
-                            k.reshape(N * S, nkv, hd))
-        vc, vsc = _kv_write(vc, vsc, l, blkm, offm,
-                            v.reshape(N * S, nkv, hd))
-        if use_kernel:
-            from .kernels.ragged_attention import ragged_attention
-            o = ragged_attention(
-                q.reshape(N * S, nh, hd), kc, vc, l, row_ids, lengths,
-                block_tables,
-                k_scale=None if ksc is None else ksc[l],
-                v_scale=None if vsc is None else vsc[l]
-            ).reshape(N, S, nh * hd)
-        else:
-            kpages = _kv_read(kc, ksc, l, block_tables, nkv,
-                              x.dtype).reshape(N, ctx, nkv, hd)
-            vpages = _kv_read(vc, vsc, l, block_tables, nkv,
-                              x.dtype).reshape(N, ctx, nkv, hd)
-            if nkv != nh:
-                kpages = jnp.repeat(kpages, nh // nkv, axis=2)
-                vpages = jnp.repeat(vpages, nh // nkv, axis=2)
-            scores = jnp.einsum("nshd,nchd->nhsc", q,
-                                kpages).astype(jnp.float32)
-            scores = scores / jnp.sqrt(jnp.asarray(hd, jnp.float32))
-            if cfg.positional == "alibi":
-                scores = scores + _alibi_row(cfg, ctx_pos)[None]
-            scores = jnp.where(mask[:, None], scores, NEG_INF)
-            probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
-            o = jnp.einsum("nhsc,nchd->nshd", probs,
-                           vpages).reshape(N, S, nh * hd)
-        if cfg.parallel_residual:
-            hn2 = (_norm(cfg, x, lp["mlp_norm"], lp.get("mlp_norm_b"))
-                   if cfg.parallel_norms else hn)
-            x = x + out_proj(lp, o) + _mlp(cfg, lp, hn2, topo)
-            return (x, kc, vc, ksc, vsc), None
-        x = x + out_proj(lp, o)
-        hn = _norm(cfg, x, lp["mlp_norm"], lp.get("mlp_norm_b"))
-        x = x + _mlp(cfg, lp, hn, topo)
-        return (x, kc, vc, ksc, vsc), None
+            flat, aid = hn.reshape(N * S, -1), jnp.repeat(adapter_ids, S)
+            return (q + _lora_delta(ll["qa"], ll["qb"], flat,
+                                    aid).reshape(q.shape),
+                    v + _lora_delta(ll["va"], ll["vb"], flat,
+                                    aid).reshape(v.shape))
 
-    (x, kc, vc, ksc, vsc), _ = jax.lax.scan(
-        layer_fn, (x, cache["k"], cache["v"],
-                   cache.get("ks"), cache.get("vs")),
-        (params["layers"], jnp.arange(cfg.num_layers))
-        + ((lora,) if lora is not None else ()))
-    x = _norm(cfg, x, params["final_norm"], params.get("final_norm_b"))
+        q, k, v = _qkv_heads(cfg, lp, hn, (N, S), cos, sin, lora_qv)
+        kc, vc, ksc, vsc = _kv_write_pair(
+            kc, vc, ksc, vsc, l, blkm, offm,
+            k.reshape(N * S, nkv, hd), v.reshape(N * S, nkv, hd))
+        with jax.named_scope("attn_kernel"):
+            if use_kernel:
+                from .kernels.ragged_attention import ragged_attention
+                o = ragged_attention(
+                    q.reshape(N * S, nh, hd), kc, vc, l, row_ids, lengths,
+                    block_tables,
+                    k_scale=None if ksc is None else ksc[l],
+                    v_scale=None if vsc is None else vsc[l]
+                ).reshape(N, S, nh * hd)
+            else:
+                kpages = _kv_read(kc, ksc, l, block_tables, nkv,
+                                  hn.dtype).reshape(N, ctx, nkv, hd)
+                vpages = _kv_read(vc, vsc, l, block_tables, nkv,
+                                  hn.dtype).reshape(N, ctx, nkv, hd)
+                if nkv != nh:
+                    kpages = jnp.repeat(kpages, nh // nkv, axis=2)
+                    vpages = jnp.repeat(vpages, nh // nkv, axis=2)
+                scores = jnp.einsum("nshd,nchd->nhsc", q,
+                                    kpages).astype(jnp.float32)
+                scores = scores / jnp.sqrt(jnp.asarray(hd, jnp.float32))
+                if cfg.positional == "alibi":
+                    scores = scores + _alibi_row(cfg, ctx_pos)[None]
+                scores = jnp.where(mask[:, None], scores, NEG_INF)
+                probs = jax.nn.softmax(scores, axis=-1).astype(hn.dtype)
+                o = jnp.einsum("nhsc,nchd->nshd", probs,
+                               vpages).reshape(N, S, nh * hd)
+        return o, kc, vc, ksc, vsc
+
+    x, cache = _scan_layers(cfg, params, x, cache, lora, topo, attend)
     from .sampling import greedy_tokens
-    return greedy_tokens(_logits(cfg, params, x)), \
-        _cache_dict(kc, vc, ksc, vsc)
+    return greedy_tokens(_head(cfg, params, x)), cache
 
 
 def paged_spec_decode_window(cfg: TransformerConfig, dcfg: TransformerConfig,

@@ -85,7 +85,8 @@ def greedy_tokens(logits: jnp.ndarray) -> jnp.ndarray:
     shared by the decode hot loops and the in-window speculative verify,
     so the accept rule compares tokens produced by the same reduction
     order (the bit-identical-speculation contract leans on this)."""
-    return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    with jax.named_scope("pick"):
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
 
 def fold_in_rows(rng, row_seeds: jnp.ndarray,
@@ -95,8 +96,9 @@ def fold_in_rows(rng, row_seeds: jnp.ndarray,
     fused-window decode paths derive keys this way, which is what makes
     their sampled streams bit-identical (and invariant to how the batch
     is composed or padded)."""
-    return jax.vmap(lambda s, g: jax.random.fold_in(
-        jax.random.fold_in(rng, s), g))(row_seeds, gen_idx)
+    with jax.named_scope("pick"):
+        return jax.vmap(lambda s, g: jax.random.fold_in(
+            jax.random.fold_in(rng, s), g))(row_seeds, gen_idx)
 
 
 def sample_tokens_rowwise(logits: jnp.ndarray, keys: jnp.ndarray,
@@ -109,9 +111,10 @@ def sample_tokens_rowwise(logits: jnp.ndarray, keys: jnp.ndarray,
     a row's stream changes when the batch re-buckets — rowwise keys are
     what let the fused decode window keep EOS'd rows padded in place
     while matching the per-token path token-for-token."""
-    order, masked = _sorted_support(logits, temperature, top_p, top_k)
-    pick = jax.vmap(jax.random.categorical)(keys, masked)     # [N] sorted-idx
-    return _unsort_pick(logits, order, pick, temperature)
+    with jax.named_scope("pick"):
+        order, masked = _sorted_support(logits, temperature, top_p, top_k)
+        pick = jax.vmap(jax.random.categorical)(keys, masked)  # sorted-idx
+        return _unsort_pick(logits, order, pick, temperature)
 
 
 def host_sample(logits: np.ndarray, rng: np.random.Generator,

@@ -17,7 +17,10 @@ Two record kinds:
     later which phase of the step each of its instructions belongs to;
     ``InferenceEngineV2.memory_report()`` AOT-lowers the decode/prefill
     programs at representative bucket shapes (no chip needed — the
-    compiler runs on the host).
+    compiler runs on the host). A serving program offers its executable
+    without any of that (:func:`offer_executable`, from the watchdog's
+    proxy when the program compiles on its dispatch path), so
+    ``scopes("ragged_step")`` answers for the program that really ran.
   * **buffers** — :func:`record_buffer` publishes long-lived allocations
     the programs reference (KV pool, weights, optimizer state) as
     ``device_buffer_bytes{buffer=...}``.
@@ -27,7 +30,7 @@ read after a RESOURCE_EXHAUSTED (docs/PROFILING.md, "Triaging OOMs").
 """
 
 import threading
-from typing import Any, Callable, Dict, Optional
+from typing import Any, Callable, Dict, List, Optional
 
 from .registry import get_registry
 
@@ -38,6 +41,9 @@ _buffers: Dict[str, int] = {}
 # program runs as, and the scope map built from it on first request
 _executables: Dict[str, Callable[[], Any]] = {}
 _scopes: Dict[str, Dict[str, str]] = {}
+# per program: every executable it was offered as (offer_executable),
+# oldest first, each [thunk, its scope map once somebody asked]
+_offered: Dict[str, List[list]] = {}
 
 _MEM_FIELDS = ("argument_size_in_bytes", "output_size_in_bytes",
                "temp_size_in_bytes", "alias_size_in_bytes",
@@ -112,6 +118,50 @@ def record_memory_analysis(program: str, compiled,
     return rec
 
 
+def offer_executable(program: str, executable: Callable[[], Any]) -> None:
+    """Offer ``program``'s scope map at no cost now: ``executable``, a
+    zero-argument callable that returns the ``Compiled`` the program
+    runs as, is kept and called on the first :func:`scopes` request
+    (``telemetry.watchdog.WatchedFunction`` offers one over the abstract
+    signature of every call that compiled: lowering again then is a
+    compile-cache hit). A program compiled under several signatures
+    (bucket shapes) is several executables with instruction names of
+    their own: :func:`scopes` answers for the newest,
+    :func:`signatures_offered` says how many there were and
+    :func:`scopes_offered` gives every one's map, so that a reader of a
+    trace in which more than one of them ran trusts a name only where
+    the maps agree on it."""
+    with _lock:
+        _executables[program] = executable
+        _offered.setdefault(program, []).append([executable, None])
+        _scopes.pop(program, None)
+
+
+def signatures_offered(program: str) -> int:
+    """How many signatures ``program`` compiled under and offered."""
+    with _lock:
+        return len(_offered.get(program, ()))
+
+
+def scopes_offered(program: str) -> List[Dict[str, str]]:
+    """One scope map for each executable ``program`` was offered as,
+    oldest first (the last is ``scopes(program)``); each is built on the
+    first request and kept. A program recorded some other way has the
+    one map :func:`scopes` gives; one never recorded has none."""
+    from ..utils.xla_profile import scope_map
+    with _lock:
+        entries = list(_offered.get(program, ()))
+        newest = _executables.get(program)
+    for entry in entries:
+        if entry[1] is None:
+            entry[1] = (scopes(program) if entry[0] is newest
+                        else scope_map(entry[0]()))
+    if entries:
+        return [entry[1] for entry in entries]
+    only = scopes(program)
+    return [] if only is None else [only]
+
+
 def scopes(program: str) -> Optional[Dict[str, str]]:
     """``{instruction name: op_name}`` of a recorded program
     (``utils.xla_profile.scope_map`` of the executable it runs as), or
@@ -167,6 +217,7 @@ def reset() -> None:
         _buffers.clear()
         _executables.clear()
         _scopes.clear()
+        _offered.clear()
 
 
 def oom_report(top: int = 5) -> Dict[str, Any]:

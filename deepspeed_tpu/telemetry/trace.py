@@ -3,7 +3,9 @@
 ``with trace.span("decode_step"):`` records (name, start, duration,
 depth) into a bounded deque — overhead is two ``perf_counter`` calls and
 one locked append, so the serving hot path can stay instrumented in
-production.
+production. ``with trace.span(...) as sp:`` hands the record itself:
+``sp["duration_s"]`` is there once the block has closed, so a histogram
+beside a span observes the span's own duration and not a second clock.
 ``export()`` drains a copy for offline analysis; ``durations(name)``
 feeds assertions and benchmarks.
 
@@ -28,7 +30,11 @@ lane belong to no replica (single-engine serving, training).
 ``jax.profiler.TraceAnnotation`` so spans line up with device activity
 in a TensorBoard/XProf trace captured via
 ``deepspeed_tpu.utils.xla_profile.capture_trace`` (the hook is optional:
-absent/failed jax.profiler leaves spans host-only).
+absent/failed jax.profiler leaves spans host-only). A span recorded while
+it was mirrored carries ``annotated: True``: the same span exists on the
+profiler's clock under the same name, so pairing the two in order gives
+the offset between ``perf_counter`` and that clock, and with it every
+span of the ring a place on the device trace's time axis.
 """
 
 import itertools
@@ -38,7 +44,12 @@ from collections import deque
 from contextlib import contextmanager
 from typing import Dict, List, Optional
 
-_DEFAULT_CAPACITY = 4096
+# a generate() call records ~200 spans (its root, a ragged step and 32
+# decode windows with their leaves), a rollout cell finishes ~20 calls in
+# its window, and the benchmark's runner reads the whole window's
+# ``ragged_step`` / ``decode_window`` spans from the ring: 4,096 would
+# have dropped the window's first calls
+_DEFAULT_CAPACITY = 16384
 
 _lock = threading.Lock()
 _buffer: deque = deque(maxlen=_DEFAULT_CAPACITY)
@@ -101,18 +112,18 @@ def span(name: str, lane: Optional[str] = None, **attrs):
             annotation.__enter__()
         except Exception:
             annotation = None
-    start = time.perf_counter()
+    rec = {"name": name, "start": time.perf_counter(), "duration_s": None,
+           "depth": depth, "id": span_id, "parent": parent}
     try:
-        yield
+        yield rec
     finally:
-        dur = time.perf_counter() - start
+        rec["duration_s"] = time.perf_counter() - rec["start"]
         if annotation is not None:
             annotation.__exit__(None, None, None)
+            rec["annotated"] = True
         _local.depth = depth
         _local.span_id = parent
-        rec = {"name": name, "start": start, "duration_s": dur,
-               "depth": depth, "id": span_id, "parent": parent,
-               "track": current_track()}
+        rec["track"] = current_track()
         ln = lane if lane is not None else current_lane()
         if ln is not None:
             rec["lane"] = ln

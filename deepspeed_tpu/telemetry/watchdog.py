@@ -17,6 +17,13 @@ observable and enforceable:
     their warmup pass; serving can call it once traffic is warm.
   * :func:`record_compile` covers explicit compile points that don't go
     through a jit call (``engine.lower_train_step`` AOT compiles).
+  * :func:`watch_jit` is how a serving program is built: the function is
+    NAMED for the program before it is jitted, so the device trace's
+    "XLA Modules" line reads ``jit_<program>`` (a lambda would read
+    ``jit__lambda``, the same for every program), and on a compile event
+    the proxy offers ``telemetry.memory`` the executable's scope map
+    (``memory.scopes(program)``) as a thunk over the abstract signature:
+    nothing is lowered, compiled or parsed until a reader asks.
 
 Compile wall time comes from jax.monitoring's
 ``backend_compile_duration`` events accumulated on the calling thread
@@ -185,10 +192,28 @@ def summary() -> Dict[str, Dict[str, float]]:
     return out
 
 
+def _abstract(x):
+    """An array argument as its shape and type (and its sharding where
+    it spans devices: a single-device placement is the default and says
+    nothing); anything else as it is."""
+    if not (hasattr(x, "shape") and hasattr(x, "dtype")):
+        return x
+    import jax
+    sharding = getattr(x, "sharding", None)
+    if sharding is not None and len(sharding.device_set) < 2:
+        sharding = None
+    return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding)
+
+
 class WatchedFunction:
     """Transparent proxy over a jitted callable: forwards calls and
     attribute access (``.lower``, ``._cache_size`` keep working),
-    recording a compile event whenever the jit cache grows."""
+    recording a compile event whenever the jit cache grows. On that
+    event it also offers the program's scope map
+    (``memory.offer_executable``): the abstract signature of the call
+    that compiled and a thunk that lowers and compiles it again (a
+    cache hit) when ``memory.scopes(program)`` is first asked for. The
+    thunk holds the jitted function and shapes, no array."""
 
     def __init__(self, program: str, fn: Callable):
         self.program = program
@@ -217,7 +242,19 @@ class WatchedFunction:
                     else time.perf_counter() - t0,
                     signature=_signature(args, kwargs),
                     cached_programs=after)
+                self._offer_scopes(args, kwargs)
         return out
+
+    def _offer_scopes(self, args, kwargs) -> None:
+        try:
+            import jax
+            a, k = jax.tree.map(_abstract, (args, kwargs))
+            fn = self._fn
+            from . import memory
+            memory.offer_executable(
+                self.program, lambda: fn.lower(*a, **k).compile())
+        except Exception:   # a map nobody may ask for never blocks a call
+            pass
 
     def __getattr__(self, name):
         return getattr(self._fn, name)
@@ -232,3 +269,13 @@ def watch(program: str, fn: Callable) -> WatchedFunction:
     if isinstance(fn, WatchedFunction):
         return fn
     return WatchedFunction(program, fn)
+
+
+def watch_jit(program: str, fn: Callable, **jit_kwargs) -> WatchedFunction:
+    """``watch(program, jax.jit(fn, **jit_kwargs))`` with ``fn`` named
+    for the program first: jax calls the module it compiles
+    ``jit_<fn.__name__>``, which is what a device trace's "XLA Modules"
+    line shows for every launch, and a lambda's name says nothing."""
+    import jax
+    fn.__name__ = fn.__qualname__ = program
+    return watch(program, jax.jit(fn, **jit_kwargs))

@@ -24,7 +24,11 @@ stream): is ZeRO communication overlapped with compute?
    ``jax.named_scope`` and autodiff path it was traced under
    (``op_name``). The map joins the two, so a trace can be summed by
    phase: forward, recomputed forward, backward, loss head, optimizer,
-   gradient reduction, parameter gather.
+   gradient reduction, parameter gather. ``serve_phase(op_name)`` is the
+   same for a serving program (``telemetry.memory.scopes("ragged_step")``
+   and the decode programs'; the scopes of inference/v2/paged_model.py):
+   embedding, attention projections, the pool write, the attention
+   kernel, MLP, router, experts, head, pick.
 """
 
 import re
@@ -125,6 +129,35 @@ def scope_phase(op_name: str) -> str:
     if "jvp(" in op_name or words:
         return "forward"
     return "other"
+
+
+# the scope names of a serving program (inference/v2/paged_model.py and
+# sampling.py) and the phase each stands for. ``attention`` and
+# ``mla_attention`` are the projections, norms and residual add round
+# the two scopes nested in them that are phases of their own
+_SERVE_PHASE_OF_SCOPE = {
+    "embed": "embed", "attention": "attn_proj", "mla_attention": "attn_proj",
+    "qkv_proj": "attn_proj", "out_proj": "attn_proj",
+    "kv_write": "kv_write", "attn_kernel": "attn_kernel",
+    "mlp": "mlp", "dense_mlp": "mlp", "moe_shared_expert": "mlp",
+    "moe_router": "router", "moe_experts": "experts",
+    "head": "head", "pick": "pick"}
+_SERVE_SCOPE_WORD = re.compile(
+    r"\b(" + "|".join(sorted(_SERVE_PHASE_OF_SCOPE, key=len, reverse=True))
+    + r")\b")
+SERVE_PHASES = ("embed", "attn_proj", "kv_write", "attn_kernel", "mlp",
+                "router", "experts", "head", "pick", "other")
+
+
+def serve_phase(op_name: str) -> str:
+    """The phase of a serving program an ``op_name`` belongs to, one of
+    ``SERVE_PHASES``: the innermost scope word the path holds
+    (``layers/attention/kv_write/scatter`` is ``kv_write``,
+    ``layers/mlp/moe_router/dot_general`` is ``router``); ``other`` for a
+    path with none (``layers`` alone: the scan's own slicing and
+    counting)."""
+    words = _SERVE_SCOPE_WORD.findall(op_name)
+    return _SERVE_PHASE_OF_SCOPE[words[-1]] if words else "other"
 
 
 # async-pair HLO opcodes emitted by the latency-hiding scheduler

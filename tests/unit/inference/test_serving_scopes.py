@@ -1,0 +1,348 @@
+"""The generation step measured from inside, on the CPU toy engines (the
+per-head block and the latent one): a ``generate()`` call's leaf spans
+tile its root span; every serving jit is named for its program; a
+program's scope map is offered at no cost and holds every scope word;
+``serve_phase`` reads a path as a phase; and scopes are metadata."""
+
+import contextlib
+import json
+import re
+from pathlib import Path
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from benchmark import run as harness
+from benchmark import weights_joyai
+from deepspeed_tpu.inference.v2 import (InferenceEngineV2,
+                                        RaggedInferenceEngineConfig)
+from deepspeed_tpu.inference.v2.config_v2 import DSStateManagerConfig
+from deepspeed_tpu.models import TransformerConfig, TransformerLM
+from deepspeed_tpu.telemetry import memory, trace
+from deepspeed_tpu.telemetry.watchdog import WatchedFunction
+from deepspeed_tpu.utils.xla_profile import SERVE_PHASES, serve_phase
+
+REPO = Path(__file__).resolve().parents[3]
+JOYAI = json.loads(
+    (REPO / "benchmark/configs/joyai-llm-flash.json").read_text())
+TOY_LATENT = harness.merge(JOYAI["fields"], JOYAI["toy_fields"])
+NEW_TOKENS = 20
+
+# the leaves of a generate() call: what docs/TELEMETRY.md lists
+LEAVES = {"gen_admit", "ragged_pack", "ragged_dispatch", "ragged_fetch",
+          "ragged_bookkeeping", "gen_first_token", "gen_schedule",
+          "window_assemble", "window_dispatch", "window_fetch",
+          "window_bookkeeping", "gen_flush"}
+PER_TOKEN_LEAVES = {"step_assemble", "step_dispatch", "step_fetch",
+                    "step_bookkeeping"}
+# the scope words a program of each block must hold an instruction of
+PER_HEAD_WORDS = {"embed", "layers", "attention", "qkv_proj", "kv_write",
+                  "attn_kernel", "out_proj", "mlp", "head"}
+LATENT_WORDS = {"embed", "layers", "mla_attention", "kv_write",
+                "attn_kernel", "mlp", "moe_router", "moe_experts",
+                "moe_shared_expert", "dense_mlp", "head"}
+
+
+def _per_head(tiny, **kw):
+    model, params = tiny
+    return InferenceEngineV2(
+        model, RaggedInferenceEngineConfig(
+            state_manager=DSStateManagerConfig(
+                max_tracked_sequences=8, max_seq_len=128, num_blocks=65,
+                block_size=16), dtype="float32", **kw), params=params)
+
+
+def _latent(**kw):
+    return InferenceEngineV2(
+        TransformerLM(TransformerConfig(**TOY_LATENT)),
+        {"dtype": "float32", "use_paged_kernel": True, **kw,
+         "state_manager": {"max_tracked_sequences": 4,
+                           "max_ragged_batch_size": 64, "max_seq_len": 256,
+                           "block_size": 16, "num_blocks": 40}},
+        params=weights_joyai.make(TOY_LATENT, 7, "float32"))
+
+
+def _prompts(vocab, n=3, length=12):
+    rng = np.random.default_rng(0)
+    return [rng.integers(0, vocab, length) for _ in range(n)]
+
+
+@pytest.fixture(params=["per_head", "latent"])
+def engine(request, tiny_model_128):
+    """(engine, prompts, the scope words its programs must hold)."""
+    memory.reset()
+    if request.param == "latent":
+        eng = _latent()
+        return eng, _prompts(TOY_LATENT["vocab_size"]), LATENT_WORDS
+    eng = _per_head(tiny_model_128)
+    return eng, _prompts(eng.model.cfg.vocab_size), PER_HEAD_WORDS
+
+
+def _call_spans(eng, prompts, **kw):
+    """The spans of one warm ``generate()`` call: (root, the rest)."""
+    eng.generate(prompts, max_new_tokens=NEW_TOKENS, **kw)     # compiles
+    trace.clear()
+    eng.generate(prompts, max_new_tokens=NEW_TOKENS, **kw)
+    ring = trace.export()
+    roots = [s for s in ring if s["name"] == "generate"]
+    assert len(roots) == 1
+    return roots[0], [s for s in ring if s is not roots[0]]
+
+
+# ---------------------------------------------------------------------------
+# host: no part of generate() runs outside a span
+# ---------------------------------------------------------------------------
+def test_leaf_spans_tile_the_call_and_reach_its_root(engine):
+    eng, prompts, _ = engine
+    root, spans = _call_spans(eng, prompts)
+    assert root["parent"] is None
+    assert root["attrs"] == {"rows": len(prompts),
+                             "max_new_tokens": NEW_TOKENS}
+    by_id = {s["id"]: s for s in spans + [root]}
+    for s in spans:
+        at = s
+        while at["parent"] is not None:
+            at = by_id[at["parent"]]
+        assert at is root, s
+    leaves = [s for s in spans if s["name"] in LEAVES]
+    assert {s["name"] for s in leaves} == LEAVES
+    assert all("attrs" not in s for s in leaves)
+    # a leaf holds no span: what the leaves cover is counted once
+    assert not {s["parent"] for s in spans} & {s["id"] for s in leaves}
+    covered = sum(s["duration_s"] for s in leaves)
+    assert covered >= 0.99 * root["duration_s"], (covered, root)
+    assert covered <= root["duration_s"]
+
+
+def test_the_coarse_spans_keep_name_extent_and_attrs(engine):
+    eng, prompts, _ = engine
+    _, spans = _call_spans(eng, prompts)
+    step = [s for s in spans if s["name"] == "ragged_step"]
+    windows = [s for s in spans if s["name"] == "decode_window"]
+    assert len(step) == 1 and len(windows) == -(-(NEW_TOKENS - 1) // 8)
+    assert step[0]["attrs"] == {"rows": 3, "tokens": 36, "uids": [0, 1, 2]}
+    for w in windows:
+        assert w["attrs"] == {"batch": 3, "window": 8, "uids": [0, 1, 2]}
+    # the extent is the children's: uploads and launch, then the wait
+    for outer, names in ((step[0], ("ragged_dispatch", "ragged_fetch")),
+                         (windows[0], ("window_assemble", "window_dispatch",
+                                       "window_fetch"))):
+        inner = [s for s in spans if s["parent"] == outer["id"]]
+        assert tuple(s["name"] for s in sorted(
+            inner, key=lambda s: s["start"])) == names
+        assert sum(s["duration_s"] for s in inner) \
+            >= 0.98 * outer["duration_s"]
+    # one gen_schedule before every window, and one that ends the loop
+    assert sum(s["name"] == "gen_schedule" for s in spans) \
+        == len(windows) + 1
+
+
+def test_the_per_token_path_has_the_same_leaves(tiny_model_128):
+    eng = _per_head(tiny_model_128, decode_window=1)
+    root, spans = _call_spans(eng, _prompts(eng.model.cfg.vocab_size))
+    steps = [s for s in spans if s["name"] == "decode_step"]
+    assert len(steps) == NEW_TOKENS - 1
+    inner = [s["name"] for s in sorted(
+        (s for s in spans if s["parent"] == steps[0]["id"]),
+        key=lambda s: s["start"])]
+    assert inner == ["step_assemble", "step_dispatch", "step_fetch"]
+    leaves = [s for s in spans
+              if s["name"] in LEAVES | PER_TOKEN_LEAVES]
+    assert sum(s["duration_s"] for s in leaves) \
+        >= 0.99 * root["duration_s"]
+
+
+def test_a_span_hands_out_its_record_and_marks_a_mirrored_one():
+    trace.clear()
+    with trace.span("outer") as sp:
+        assert sp["duration_s"] is None
+    assert sp["duration_s"] >= 0 and "annotated" not in sp
+    trace.enable_xla_annotations(True)
+    try:
+        with trace.span("mirrored") as sp:
+            pass
+    finally:
+        trace.enable_xla_annotations(False)
+    assert sp["annotated"] is True
+    assert [s["name"] for s in trace.export()] == ["outer", "mirrored"]
+
+
+# ---------------------------------------------------------------------------
+# device: every program a name, every fusion a scope, the map on request
+# ---------------------------------------------------------------------------
+def test_every_serving_jit_is_named_for_its_program(engine):
+    eng, prompts, _ = engine
+    watched = {name: fn for name, fn in vars(eng).items()
+               if isinstance(fn, WatchedFunction)}
+    assert len(watched) >= 6
+    for fn in watched.values():
+        assert fn.__wrapped__.__name__ == fn.program, fn
+    eng.generate(prompts, max_new_tokens=NEW_TOKENS)
+    ran = {"ragged_step", "decode_window_greedy"}
+    assert ran <= set(memory._executables)
+    for program in ran:
+        head = memory._executables[program]().as_text().splitlines()[0]
+        assert head.startswith(f"HloModule jit_{program},"), head
+
+
+def test_the_scope_map_is_offered_free_and_holds_every_word(engine):
+    eng, prompts, words = engine
+    lowered = []
+    jax.monitoring.register_event_duration_secs_listener(
+        lambda name, *_a, **_k: lowered.append(name)
+        if name.endswith("jaxpr_to_mlir_module_duration") else None)
+    eng.generate(prompts, max_new_tokens=NEW_TOKENS)
+    eng.generate(prompts, max_new_tokens=NEW_TOKENS)
+    assert not memory._scopes           # nothing parsed, and from here on
+    del lowered[:]                      # nothing lowered, before the request
+    eng.generate(prompts, max_new_tokens=NEW_TOKENS)
+    assert not lowered and not memory._scopes
+    for program in ("ragged_step", "decode_window_greedy"):
+        assert memory.signatures_offered(program) >= 1
+        mapped = memory.scopes(program)
+        assert lowered                  # the request lowered it
+        assert mapped is memory.scopes(program)      # and keeps the map
+        seen = {w for op_name in mapped.values()
+                for w in re.findall(r"[a-z_]+", op_name)}
+        assert words <= seen, (program, words - seen)
+        phases = {serve_phase(v) for v in mapped.values()}
+        assert phases <= set(SERVE_PHASES)
+        assert {"embed", "attn_proj", "kv_write", "attn_kernel", "mlp",
+                "head"} <= phases, phases
+    # the map outlives the engine, and holds none of its arrays
+    del eng
+    assert memory.scopes("ragged_step")
+
+
+def test_the_newest_signature_answers_and_the_count_says_so():
+    memory.reset()
+    memory.offer_executable("p", lambda: 1 / 0)
+    memory.offer_executable("p", lambda: None)
+    assert memory.signatures_offered("p") == 2
+    assert memory.signatures_offered("never") == 0
+    assert memory.scopes("never") is None
+    assert memory.scopes_offered("never") == []
+
+
+def test_a_program_under_two_signatures_offers_a_map_for_each():
+    """What ``serve_scope_time`` reads where one call ran a program
+    under two bucket shapes: every signature's own map, oldest first,
+    built once each, the last being ``scopes(program)``."""
+    from deepspeed_tpu.telemetry.watchdog import watch_jit
+    memory.reset()
+
+    def body(x, y):
+        with jax.named_scope("mlp"):
+            x = x @ y
+        if x.shape[0] > 4:              # the wider bucket alone
+            with jax.named_scope("head"):
+                x = jnp.tanh(x).sum(axis=0, keepdims=True) + x
+        return x
+
+    fn = watch_jit("two_buckets", body)
+    fn(jnp.ones((4, 8)), jnp.ones((8, 8)))
+    fn(jnp.ones((16, 8)), jnp.ones((8, 8)))
+    fn(jnp.ones((4, 8)), jnp.ones((8, 8)))          # no compile: no offer
+    assert memory.signatures_offered("two_buckets") == 2
+    narrow, wide = memory.scopes_offered("two_buckets")
+    assert wide is memory.scopes("two_buckets")
+    assert memory.scopes_offered("two_buckets")[0] is narrow     # kept
+    phases = [{serve_phase(v) for v in m.values()} for m in (narrow, wide)]
+    assert "mlp" in phases[0] and "head" not in phases[0]
+    assert {"mlp", "head"} <= phases[1]
+    # a program recorded the other way has the one map
+    compiled = jax.jit(body).lower(jnp.ones((4, 8)),
+                                   jnp.ones((8, 8))).compile()
+    memory.record_memory_analysis("analysed", compiled)
+    assert memory.scopes_offered("analysed") == [memory.scopes("analysed")]
+
+
+PHASE_TABLE = [
+    ("jit(ragged_step)/embed/gather", "embed"),
+    ("jit(ragged_step)/layers/while/body/attention/reduce_sum",
+     "attn_proj"),
+    ("jit(ragged_step)/layers/while/body/attention/qkv_proj/dot_general",
+     "attn_proj"),
+    ("jit(decode_window_greedy)/while/body/layers/while/body/attention/"
+     "out_proj/dot_general", "attn_proj"),
+    ("jit(ragged_step)/layers/while/body/attention/kv_write/scatter",
+     "kv_write"),
+    ("jit(ragged_step)/layers/while/body/attention/attn_kernel/"
+     "ragged_attention_tiled/pallas_call", "attn_kernel"),
+    ("jit(ragged_step)/layers/while/body/mla_attention/dot_general",
+     "attn_proj"),
+    ("jit(ragged_step)/layers/while/body/mla_attention/kv_write/scatter",
+     "kv_write"),
+    ("jit(ragged_step)/layers/while/body/mla_attention/attn_kernel/"
+     "ragged_attention_latent/pallas_call", "attn_kernel"),
+    ("jit(ragged_step)/layers/while/body/closed_call/mlp/dot_general",
+     "mlp"),
+    ("jit(ragged_step)/layers/while/body/mlp/dense_mlp/mul", "mlp"),
+    ("jit(ragged_step)/layers/while/body/mlp/moe_shared_expert/dot_general",
+     "mlp"),
+    ("jit(ragged_step)/layers/while/body/mlp/moe_router/top_k", "router"),
+    ("jit(ragged_step)/layers/while/body/mlp/moe_experts/gmm/pallas_call",
+     "experts"),
+    ("jit(decode_window_greedy)/while/body/head/dot_general", "head"),
+    ("jit(decode_window_greedy)/while/body/pick/argmax", "pick"),
+    ("jit(decode_window_sample)/while/body/pick/jit(_threefry_split)/add",
+     "pick"),
+    ("jit(decode_window_greedy)/while/body/layers/while/body/dynamic_slice",
+     "other"),
+    ("jit(decode_window_greedy)/while/body/jit(take_along_axis)/gather",
+     "other"),
+    # a word inside another name is not the scope
+    ("jit(ragged_step)/ragged_attention_tiled/heads/mul", "other"),
+]
+
+
+@pytest.mark.parametrize("op_name,phase", PHASE_TABLE)
+def test_serve_phase(op_name, phase):
+    assert serve_phase(op_name) == phase
+
+
+def _instructions(text):
+    return len(re.findall(r"^\s*(?:ROOT\s+)?%?[\w.\-]+ = ", text, re.M))
+
+
+@pytest.mark.parametrize("block", ["per_head", "latent"])
+def test_scopes_are_metadata(block, tiny_model_128, monkeypatch):
+    """The toy decode window compiles to the same number of
+    instructions, fusions and loops with the scopes and without."""
+    from deepspeed_tpu.inference.v2.paged_model import (
+        init_paged_kv_cache, paged_decode_window)
+    if block == "latent":
+        cfg = TransformerConfig(**TOY_LATENT)
+        params = jax.eval_shape(
+            lambda: weights_joyai.make(TOY_LATENT, 7, "float32"))
+    else:
+        cfg = tiny_model_128[0].cfg
+        params = jax.eval_shape(lambda: tiny_model_128[1])
+    cache = jax.eval_shape(
+        lambda: init_paged_kv_cache(cfg, 9, 16, jnp.float32))
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
+
+    def lower_it():
+        def window(p, t, pos, bt, c, sl, eos):      # a new trace each time
+            return paged_decode_window(cfg, p, t, pos, bt, c, sl, eos,
+                                       16, 4)
+        return jax.jit(window).lower(params, i32(2), i32(2), i32(2, 4),
+                                     cache, i32(2), i32(2))
+
+    scoped = lower_it()
+    assert "attn_kernel" in scoped.as_text(debug_info=True)
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    bare = lower_it()
+    assert "attn_kernel" not in bare.as_text(debug_info=True)
+    # what the two lower to differs in locations alone (the persistent
+    # compile cache keys them alike, for the same reason) ...
+    assert scoped.as_text() == bare.as_text()
+    # ... and so does what they compile to
+    scoped, bare = scoped.compile().as_text(), bare.compile().as_text()
+    assert _instructions(scoped) == _instructions(bare) > 100
+    for opcode in (" fusion(", " while(", " custom-call("):
+        assert scoped.count(opcode) == bare.count(opcode)
