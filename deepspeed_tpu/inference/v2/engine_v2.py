@@ -28,6 +28,8 @@ with one [N, K] int32 transfer per window instead of a Python round-trip
 per token (docs/SERVING.md, "Fused multi-token decode").
 """
 
+import contextlib
+import dataclasses
 import time
 from typing import Dict, Iterable, List, Optional, Sequence
 
@@ -129,6 +131,22 @@ class SpecChooser:
         if self.rate["ngram"] is None and self.rate["draft"] is None:
             return "ngram" if ngram_hit else "draft"
         return self.current
+
+
+@dataclasses.dataclass
+class _Window:
+    """A fused decode window that was launched: what collecting it
+    needs, and the rows' state it hands to a window queued behind it."""
+    uids: List[int]
+    steps_left: List[int]
+    # the fed tokens: the host's list, or the window this one was queued
+    # behind (whose collect notes every row's last emit in ``last``)
+    fed: object
+    t0: float               # perf_counter at the start of the launch
+    out: object             # [N, K] tokens, still on the device
+    moe: list
+    state: tuple            # (token, position, alive), on the device
+    last: Optional[Dict[int, int]] = None
 
 
 class InferenceEngineV2:
@@ -356,7 +374,10 @@ class InferenceEngineV2:
         # [N, K] int32 transfer per window. K is baked into the compiled
         # program; batch rows pad to the same power-of-two buckets as the
         # per-token path, so the compile cache stays one program per
-        # (batch bucket, table-width bucket).
+        # (batch bucket, table-width bucket). The rows' state (t, pos,
+        # alive) comes from the host or from the window before
+        # (_launch_window): uploaded under the sharding the program
+        # returns it with, both are one signature
         self.decode_window = max(int(config.decode_window), 1)
         self._m_window_size.set(self.decode_window)
 
@@ -372,21 +393,21 @@ class InferenceEngineV2:
         def _build_fused_pair(K: int):
             greedy = watchdog.watch_jit(
                 "decode_window_greedy",
-                lambda p, t, pos, bt, c, sl, eos, lb, aid, _K=K:
+                lambda p, t, pos, bt, c, sl, eos, alive, lb, aid, _K=K:
                 paged_decode_window(
                     cfg, p, t, pos, bt, c, sl, eos, sm.block_size,
                     _K, use_kernel=use_kernel,
-                    topo=topo, lora=lb, adapter_ids=aid),
+                    topo=topo, lora=lb, adapter_ids=aid, alive=alive),
                 donate_argnums=(4,))
             sample = watchdog.watch_jit(
                 "decode_window_sample",
-                lambda p, t, pos, bt, c, sl, eos, rng, seeds, g0, temp, \
-                topp, topk, lb, aid, _K=K: paged_decode_window(
+                lambda p, t, pos, bt, c, sl, eos, alive, rng, seeds, g0, \
+                temp, topp, topk, lb, aid, _K=K: paged_decode_window(
                     cfg, p, t, pos, bt, c, sl, eos, sm.block_size,
                     _K, rng=rng, row_seeds=seeds, gen_idx0=g0,
                     temp=temp, topp=topp, topk=topk,
                     use_kernel=use_kernel, topo=topo, lora=lb,
-                    adapter_ids=aid),
+                    adapter_ids=aid, alive=alive),
                 donate_argnums=(4,))
             return greedy, sample
 
@@ -397,6 +418,7 @@ class InferenceEngineV2:
         self._warmed_windows: set = set()
         self._fused_greedy_jit, self._fused_sample_jit = \
             self._fused_pair(self.decode_window)
+        self._row_state_sharding = NamedSharding(self.mesh, P())
         self._prefill_jit = watchdog.watch_jit(
             "prefill", lambda p, ids, n, c, b, o, lb, aid: paged_prefill(
                 cfg, p, ids, n, c, b, o,
@@ -544,7 +566,9 @@ class InferenceEngineV2:
             "inference_decode_steps_total", "batched decode passes")
         self._m_decode_time = reg.histogram(
             "inference_decode_step_seconds",
-            "batched decode pass wall time", unit="s")
+            "batched decode pass wall time (a per-token step, or a fused "
+            "window from the start of its launch to its tokens on the "
+            "host)", unit="s")
         self._m_decode_tput = reg.gauge(
             "inference_decode_tokens_per_s",
             "last decode pass throughput (batch tokens / wall time)")
@@ -598,9 +622,17 @@ class InferenceEngineV2:
             "inference_decode_host_syncs_total",
             "device->host transfers made by the decode loop (one per "
             "per-token step, one per fused multi-step window)")
+        self._m_windows_ahead = reg.counter(
+            "inference_decode_windows_ahead_total",
+            "fused decode windows launched while the window before them "
+            "was still in flight (generate() launches ahead; a window "
+            "launched after its predecessor's tokens were fetched does "
+            "not count)")
         self._m_fused_time = reg.histogram(
             "inference_fused_window_seconds",
-            "fused multi-step decode window wall time", unit="s")
+            "fused multi-step decode window wall time, from the start of "
+            "its launch to its tokens on the host (under launch-ahead "
+            "that spans the fetch of the window before it)", unit="s")
         self._m_ragged_steps = reg.counter(
             "inference_ragged_steps_total",
             "unified ragged steps run (mixed prefill+decode, one "
@@ -1468,67 +1500,121 @@ class InferenceEngineV2:
             lambda v, i: int(v[i]))
 
     # -- fused multi-token decode window --------------------------------
-    def _decode_window_common(self, uids: List[int], tokens: List[int],
-                              steps_left: List[int], eos_ids: List[int],
-                              run) -> Dict[int, List[int]]:
-        """Run one fused window and fold the [N, K] result back into
-        host state. Returns {uid: emitted tokens} (1..steps_left[i] each;
-        the row's last emitted token is never fed/cached — the same
-        invariant as the per-token loop)."""
+    def _launch_window(self, uids: List[int], tokens: Optional[List[int]],
+                       steps_left: List[int], eos_ids: List[int],
+                       sampling=None,
+                       behind: Optional["_Window"] = None):
+        """Launch one fused window and return ``(window, span)`` without
+        waiting for it: the handle :meth:`_collect_window` takes, and
+        the ``decode_window`` span, still open (the caller's collect
+        closes it). ``sampling`` is None for the greedy program, else
+        ``(rng, row_seeds, gen_idx0, temperature, top_p, top_k)``.
+
+        ``behind`` is a window of the SAME rows in the same order that
+        is still in flight: this one takes the rows' state from it on
+        the device (``tokens`` is not read) and is queued behind it, so
+        the device goes from one to the next without the host. A row
+        the host knows dead has ``steps_left`` 0 there; one that dies
+        inside ``behind`` is masked by the state it hands on."""
         sm = self.state_manager
-        with trace.span("decode_window", batch=len(uids),
-                        window=self.decode_window,
-                        uids=[int(u) for u in uids],
-                        **self._trace_attrs(uids)) as win:
+        with contextlib.ExitStack() as stack:
+            stack.enter_context(trace.span(
+                "decode_window", batch=len(uids), window=self.decode_window,
+                ahead=int(behind is not None), uids=[int(u) for u in uids],
+                **self._trace_attrs(uids)))
+            t0 = time.perf_counter()
             with trace.span("window_assemble"):
                 # block pre-allocation contract: every block row i can
                 # write during its steps_left[i] steps is allocated HERE,
                 # so the device loop never needs the host mid-window
                 # (block-table advancement is position arithmetic over a
-                # complete table)
+                # complete table). Behind a window in flight the host's
+                # position is that window's start: its writes count too
+                writes = steps_left if behind is None else [
+                    b + s if s else 0
+                    for b, s in zip(behind.steps_left, steps_left)]
                 N, toks, pos, tables = self._assemble_decode_rows(
-                    uids, tokens, steps_left)
+                    uids, tokens or [0] * len(uids), writes)
                 eos = np.full(N, -1, np.int32)
                 eos[:len(uids)] = eos_ids
                 lb = self.lora_bank
                 aid = (self._pad_i32(N, [self._adapter_slot_of(u)
                                          for u in uids])
                        if lb is not None else None)
+                extra = ()
+                if sampling is not None:
+                    rng, row_seeds, gen_idx0, *knobs = sampling
+                    extra = (rng, *self._sampling_arrays(
+                        N, row_seeds, gen_idx0, *knobs))
+                jit_fn = (self._fused_greedy_jit if sampling is None
+                          else self._fused_sample_jit)
             with trace.span("window_dispatch"):
-                out, *moe, self.kv_cache = run(
-                    jnp.asarray(toks), jnp.asarray(pos),
-                    jnp.asarray(tables), self._pad_i32(N, steps_left),
-                    jnp.asarray(eos), lb, aid)
+                state = behind.state if behind is not None else \
+                    jax.device_put((toks, pos, np.ones(N, bool)),
+                                   self._row_state_sharding)
+                out, state, *moe, self.kv_cache = jit_fn(
+                    self.params, state[0], state[1], jnp.asarray(tables),
+                    self.kv_cache, self._pad_i32(N, steps_left),
+                    jnp.asarray(eos), state[2], *extra, lb, aid)
+                if behind is not None:
+                    self._m_windows_ahead.inc()
+                win = _Window(
+                    uids=list(uids), steps_left=list(steps_left),
+                    fed=behind if behind is not None else tokens,
+                    t0=t0, out=out, moe=moe, state=state)
+            return win, stack.pop_all()
+
+    def _collect_window(self, win: "_Window",
+                        span=None) -> Dict[int, List[int]]:
+        """Wait for a launched window's tokens and fold the [N, K]
+        result back into host state. ``span`` is the open
+        ``decode_window`` span the wait belongs to (the window's own, or
+        under launch-ahead the next one's; None: the wait stands alone).
+        Returns {uid: emitted tokens}: 1..steps_left[i] each for a row
+        that ran (the row's last emitted token is never fed/cached — the
+        same invariant as the per-token loop), none for a masked row."""
+        sm = self.state_manager
+        with span if span is not None else contextlib.nullcontext():
             with trace.span("window_fetch"):
                 # ONE transfer for the whole window: the device wait
-                out, moe = jax.device_get((out, moe))
+                out, moe = jax.device_get((win.out, win.moe))
+                dt = time.perf_counter() - win.t0
         with trace.span("window_bookkeeping"):
-            dt = win["duration_s"]
+            win.out = win.moe = win.state = None
             self._m_host_syncs.inc()
             self._note_moe("decode_window", *moe)
             log_tokens = sm.config.enable_prefix_caching
             emitted: Dict[int, List[int]] = {}
+            win.last = {}
             total = 0
-            for i, uid in enumerate(uids):
+            for i, uid in enumerate(win.uids):
                 row = out[i]
                 e = int((row >= 0).sum())   # active steps are a prefix
                 toks_out = [int(t) for t in row[:e]]
+                emitted[uid] = toks_out
+                if not e:
+                    continue
                 seq = sm.seqs[uid]
                 seq.seen_tokens += e        # e tokens were fed and cached
                 if log_tokens:
-                    # fed tokens: the input token plus all but the last
-                    # emit
-                    seq.token_log.extend([int(tokens[i])] + toks_out[:-1])
-                emitted[uid] = toks_out
+                    win.last[uid] = toks_out[-1]
+                    # fed tokens: the input token (the host's, or the
+                    # last emit of the window this one was queued
+                    # behind) plus all but the last emit
+                    fed = (win.fed.last[uid] if isinstance(win.fed, _Window)
+                           else int(win.fed[i]))
+                    seq.token_log.extend([fed] + toks_out[:-1])
                 total += e
+            win.fed = None
             self._m_decode_steps.inc()
             self._m_decode_tokens.inc(total)
             self._m_decode_time.observe(dt)
             self._m_fused_time.observe(dt)
             if dt > 0:
                 self._m_decode_tput.set(total / dt)
-            flight.record("decode_window", batch=len(uids), tokens=total,
-                          window=self.decode_window, dur_s=round(dt, 5))
+            flight.record("decode_window", batch=len(win.uids),
+                          tokens=total, window=self.decode_window,
+                          dur_s=round(dt, 5))
             self._warmed_windows.add(self.decode_window)
             self._update_pool_telemetry()
         return emitted
@@ -1536,28 +1622,14 @@ class InferenceEngineV2:
     def _decode_window_greedy(self, uids: List[int], tokens: List[int],
                               steps_left: List[int],
                               eos_ids: List[int]) -> Dict[int, List[int]]:
-        return self._decode_window_common(
-            uids, tokens, steps_left, eos_ids,
-            lambda t, pos, bt, sl, eos, lb, aid: self._fused_greedy_jit(
-                self.params, t, pos, bt, self.kv_cache, sl, eos, lb, aid))
-
-    def _decode_window_sample(self, uids: List[int], tokens: List[int],
-                              steps_left: List[int], eos_ids: List[int],
-                              rng, row_seeds: List[int],
-                              gen_idx0: List[int], temperature: float,
-                              top_p: float,
-                              top_k: int = 0) -> Dict[int, List[int]]:
-        seeds, g0, temp, topp, topk = self._sampling_arrays(
-            self._decode_bucket(len(uids)), row_seeds, gen_idx0,
-            temperature, top_p, top_k)
-        return self._decode_window_common(
-            uids, tokens, steps_left, eos_ids,
-            lambda t, pos, bt, sl, eos, lb, aid: self._fused_sample_jit(
-                self.params, t, pos, bt, self.kv_cache, sl, eos, rng,
-                seeds, g0, temp, topp, topk, lb, aid))
+        """One synchronous fused window: launched, then collected."""
+        return self._collect_window(*self._launch_window(
+            uids, tokens, steps_left, eos_ids))
 
     def _window_steps_left(self, step_uids: List[int],
-                           remaining: List[int]) -> List[int]:
+                           remaining: List[int],
+                           in_flight: Optional[List[int]] = None
+                           ) -> Optional[List[int]]:
         """Per-row step budgets for one window: the generation budget,
         the sequence-length room, and — when the KV pool is too tight for
         the full window everywhere — a halving cap so the window shrinks
@@ -1568,23 +1640,63 @@ class InferenceEngineV2:
         term — sum(lengths) <= max_ragged_batch_size — is the put()
         prefill cap (one pass over that many tokens); a window is K
         sequential steps of at most N tokens each, so a large decode
-        batch times K must not shrink the window against it."""
+        batch times K must not shrink the window against it.
+
+        ``in_flight[i]`` is the writes row i may still make in a window
+        launched and not collected: the budgets are then those of the
+        window AFTER it, from the host-known position plus those writes
+        (an upper bound: a row that stops early wrote less). There the
+        answer is None wherever the synchronous schedule would have had
+        to raise or to halve, and the caller collects first."""
         sm = self.state_manager
         K = self.decode_window
-        sl = [max(1, min(K, r,
-                         sm.config.max_seq_len
-                         - sm.seqs[u].seen_tokens))
-              for u, r in zip(step_uids, remaining)]
+        ahead = in_flight is not None
+        queued = in_flight if ahead else [0] * len(step_uids)
+        room = [sm.config.max_seq_len - sm.seqs[u].seen_tokens - q
+                for u, q in zip(step_uids, queued)]
+        if ahead and min(room) < 1:
+            return None
+        sl = [max(1, min(K, r, m)) for r, m in zip(remaining, room)]
 
         def blocks_ok(lengths):
-            need = sum(sm.seqs[u].blocks_needed(n, self.block_size)
-                       for u, n in zip(step_uids, lengths))
+            need = sum(sm.seqs[u].blocks_needed(q + n, self.block_size)
+                       for u, q, n in zip(step_uids, queued, lengths))
             return need <= sm.reclaimable_blocks()
 
         cap = K
         while cap > 1 and not blocks_ok([min(cap, s) for s in sl]):
+            if ahead:
+                return None
             cap //= 2
         return [min(cap, s) for s in sl]
+
+    def _window_steps_ahead(self, flying: "_Window", live,
+                            remaining: List[int]) -> Optional[List[int]]:
+        """``steps_left`` of the window to queue behind ``flying`` before
+        its tokens are fetched, or None where the host has to see them
+        first. ``live`` is the rows the host has not seen stop;
+        ``remaining[i]`` is row i's budget once ``flying`` has emitted
+        all its ``steps_left[i]``, which it does unless the row dies, and
+        a dead row is masked on the device. So the next window is known
+        wherever it has the same rows: none of them out of budget, not
+        so many seen dead that a smaller batch bucket would hold the
+        rest (the synchronous schedule recomposes), and both windows'
+        blocks in the pool (:meth:`_window_steps_left`). A row seen dead
+        stays in its place with no step."""
+        going = [i for i, u in enumerate(flying.uids) if u in live]
+        if not going or min(remaining[i] for i in going) < 1 \
+                or self._decode_bucket(len(going)) \
+                < self._decode_bucket(len(flying.uids)):
+            return None
+        sl = self._window_steps_left(
+            [flying.uids[i] for i in going], [remaining[i] for i in going],
+            in_flight=[flying.steps_left[i] for i in going])
+        if sl is None:
+            return None
+        out = [0] * len(flying.uids)
+        for i, s in zip(going, sl):
+            out[i] = s
+        return out
 
     # -- ragged unified step --------------------------------------------
     def step_ragged(self, batch_uids: Sequence[int],
@@ -1836,7 +1948,7 @@ class InferenceEngineV2:
         if self.decode_window > 1:
             compiled = self._fused_greedy_jit.lower(
                 params, toks, pos, tables, cache, i32(N), i32(N),
-                lb, aidN).compile()
+                jax.ShapeDtypeStruct((N,), jnp.bool_), lb, aidN).compile()
             programs["decode_window_greedy"] = \
                 ds_memory.record_memory_analysis("decode_window_greedy",
                                                  compiled)
@@ -1974,79 +2086,99 @@ class InferenceEngineV2:
                             jnp.full((len(uids),), temperature, jnp.float32),
                             jnp.full((len(uids),), top_p, jnp.float32),
                             jnp.full((len(uids),), top_k, jnp.int32)))
-                        cur = {uid: int(t) for uid, t in zip(uids, first)}
                     else:
-                        cur = {uid: int(t) for uid, t in
-                               zip(uids, np.argmax(logits, axis=-1))}
+                        first = np.argmax(logits, axis=-1)
+                    # what came back and is not in ``outs`` yet: the
+                    # prefill's pick here, then a window's tokens or a
+                    # step's one (the last of a row is the next one fed)
+                    em = {uid: [int(t)] for uid, t in zip(uids, first)}
                     live = set(uids)
                     prompt_lens = {uid: len(prompts[row_of[uid]])
                                    for uid in uids}
                     row_seed = {uid: i for i, uid in enumerate(uids)}
                     window = 1 if speculative else self.decode_window
                     eos = -1 if eos_token_id is None else int(eos_token_id)
-                    step_uids, em = [], None
+                    # the window launched and not collected yet (fused
+                    # windows only; speculative rounds need the host's
+                    # accept counts, a per-token step has no row state
+                    # to hand on)
+                    flying = None
 
                 while max_new_tokens > 0:   # 0 -> prompt-only rows (no emit)
                     with trace.span("gen_schedule"):
-                        if em is not None:
-                            # the window that just returned: all but a
-                            # row's last emit are fed/cached already; the
-                            # host only re-applies the eos/budget cuts
-                            # (defensively — the device enforced them too)
-                            cur = {}
-                            for uid in step_uids:
-                                row = outs[row_of[uid]]
-                                toks_out = em[uid]
-                                full = prompt_lens[uid] + max_new_tokens
-                                for tok in toks_out[:-1]:
-                                    row.append(tok)
-                                    if tok == eos_token_id \
-                                            or len(row) >= full:
-                                        live.discard(uid)
-                                        break
-                                else:
-                                    cur[uid] = toks_out[-1]
-                            em = None
-                        step_uids = []
-                        for uid in uids:
-                            if uid not in live:
-                                continue
-                            tok = cur[uid]
+                        # all but a row's last emit are fed/cached
+                        # already; the host only re-applies the eos/budget
+                        # cuts (defensively — the device enforced them
+                        # too). Per-uid budget (not a step counter):
+                        # speculative rounds and fused windows emit
+                        # several tokens, so sequences finish at
+                        # different steps
+                        for uid, toks_out in em.items():
                             row = outs[row_of[uid]]
-                            row.append(tok)
-                            # per-uid budget (not a step counter):
-                            # speculative rounds and fused windows emit
-                            # several tokens, so sequences finish at
-                            # different steps
-                            if tok == eos_token_id or len(row) \
-                                    - prompt_lens[uid] >= max_new_tokens:
-                                live.discard(uid)
-                            else:
-                                step_uids.append(uid)
-                        if not step_uids:
-                            break
-                        # same guard put() applies: generating past
-                        # max_seq_len (or a drained block pool) must
-                        # raise, not silently overrun or crash inside
-                        # table assembly
-                        if not self.can_schedule(step_uids,
-                                                 [1] * len(step_uids)):
-                            raise RuntimeError(
-                                "generation not schedulable: prompt + "
-                                "generated tokens exceed max_seq_len or "
-                                "the free KV block pool; lower "
-                                "max_new_tokens or raise the limits")
-                        # every step_uid is already tracked, so the batch
-                        # can never exceed max_tracked_sequences — one
-                        # call suffices
-                        feed = [outs[row_of[u]][-1] for u in step_uids]
-                        gen_count = [len(outs[row_of[u]]) - prompt_lens[u]
-                                     for u in step_uids]
-                        if window > 1:
-                            sl = self._window_steps_left(
-                                step_uids,
+                            full = prompt_lens[uid] + max_new_tokens
+                            for tok in toks_out:
+                                row.append(tok)
+                                if tok == eos_token_id or len(row) >= full:
+                                    live.discard(uid)
+                                    break
+                        em = {}
+                        sl = None
+                        if flying is not None:
+                            # a row in flight emits its steps_left unless
+                            # it dies, so the window after is known
+                            # before this one's tokens are
+                            gen_count = [g + s for g, s in
+                                         zip(gen_count, flying.steps_left)]
+                            sl = self._window_steps_ahead(
+                                flying, live,
                                 [max_new_tokens - g for g in gen_count])
-                    if speculative:
+                        else:
+                            step_uids = [u for u in uids if u in live]
+                            if not step_uids:
+                                break
+                            # same guard put() applies: generating past
+                            # max_seq_len (or a drained block pool) must
+                            # raise, not silently overrun or crash inside
+                            # table assembly
+                            if not self.can_schedule(step_uids,
+                                                     [1] * len(step_uids)):
+                                raise RuntimeError(
+                                    "generation not schedulable: prompt + "
+                                    "generated tokens exceed max_seq_len "
+                                    "or the free KV block pool; lower "
+                                    "max_new_tokens or raise the limits")
+                            # every step_uid is already tracked, so the
+                            # batch can never exceed
+                            # max_tracked_sequences — one call suffices
+                            feed = [outs[row_of[u]][-1] for u in step_uids]
+                            gen_count = [len(outs[row_of[u]])
+                                         - prompt_lens[u] for u in step_uids]
+                            if window > 1:
+                                sl = self._window_steps_left(
+                                    step_uids,
+                                    [max_new_tokens - g for g in gen_count])
+                    if flying is not None and sl is None:
+                        # the host has to see the window in flight first:
+                        # the schedule above starts over from its tokens
+                        em, flying = self._collect_window(flying), None
+                    elif window > 1:
+                        # launch, THEN wait for the window before: the
+                        # device goes from one to the next on its own
+                        nxt, span = self._launch_window(
+                            step_uids, feed, sl, [eos] * len(step_uids),
+                            (base_rng, [row_seed[u] for u in step_uids],
+                             gen_count, temperature, top_p, top_k)
+                            if sampling else None, behind=flying)
+                        if flying is not None:
+                            em = self._collect_window(flying, span)
+                        else:
+                            # nothing to wait for yet; the launch took
+                            # blocks, which the pool's gauges show now
+                            span.close()
+                            with trace.span("window_bookkeeping"):
+                                self._update_pool_telemetry()
+                        flying = nxt
+                    elif speculative:
                         # per-request routing: draft-model rows take the
                         # fused in-window path, the rest keep prompt-lookup
                         draft_set = {u for u in step_uids
@@ -2065,25 +2197,22 @@ class InferenceEngineV2:
                                 ngram_uids, outs, row_of, prompt_lens, live,
                                 max_new_tokens, eos_token_id, spec_k,
                                 spec_ngram))
-                    elif window > 1 and sampling:
-                        em = self._decode_window_sample(
-                            step_uids, feed, sl, [eos] * len(step_uids),
-                            base_rng, [row_seed[u] for u in step_uids],
-                            gen_count, temperature, top_p, top_k)
-                    elif window > 1:
-                        em = self._decode_window_greedy(
-                            step_uids, feed, sl, [eos] * len(step_uids))
+                        em = {u: [t] for u, t in cur.items()}
                     elif sampling:
-                        cur = self._decode_batch_sample(
+                        em = {u: [t] for u, t in self._decode_batch_sample(
                             step_uids, feed, base_rng,
                             [row_seed[u] for u in step_uids], gen_count,
-                            temperature, top_p, top_k)
+                            temperature, top_p, top_k).items()}
                     else:
-                        cur = self._decode_batch_greedy(step_uids, feed)
+                        em = {u: [t] for u, t in self._decode_batch_greedy(
+                            step_uids, feed).items()}
             finally:
                 # flush even on the schedulability raise: a long-lived
                 # engine must not leak this call's KV blocks / sequence
-                # slots
+                # slots. A window still in flight (an exception between
+                # its launch and its collect) is dropped: the pool it
+                # returns is already the engine's, and what is launched
+                # next runs after it
                 with trace.span("gen_flush"):
                     for uid in uids:
                         self.flush(uid)

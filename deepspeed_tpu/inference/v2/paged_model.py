@@ -1056,8 +1056,7 @@ def paged_decode_window(cfg: TransformerConfig, params, toks: jnp.ndarray,
                         temp: jnp.ndarray = None, topp: jnp.ndarray = None,
                         topk: jnp.ndarray = None,
                         use_kernel: bool = True, topo=None,
-                        lora=None, adapter_ids=None
-                        ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
+                        lora=None, adapter_ids=None, alive=None):
     """Up to ``window`` decode steps entirely on device — the answer to
     the dispatch-bound per-token loop (one Python round-trip + [N] int32
     transfer PER TOKEN). One ``lax.while_loop`` runs cache write, paged
@@ -1088,15 +1087,30 @@ def paged_decode_window(cfg: TransformerConfig, params, toks: jnp.ndarray,
     streams are bit-identical under a fixed seed.
 
     Returns (tokens [N, window] int32 with -1 in steps a row did not
-    take, cache). Emitted tokens form a prefix of each row. An
-    attention='mla' model returns (tokens, what its expert layers routed
-    over the window's steps, cache), as ``paged_decode`` does.
+    take, the rows' state, cache). Emitted tokens form a prefix of each
+    row. An attention='mla' model returns (tokens, the rows' state, what
+    its expert layers routed over the window's steps, cache), as
+    ``paged_decode`` does.
+
+    The rows' state is what the NEXT window of the same rows feeds, so
+    that it can be launched before this one's tokens reach the host:
+    (token [N], position [N], alive [N] bool). A row's token is the last
+    it emitted (not yet fed or cached) and its position the one that
+    token takes; ``alive`` is False once the row has emitted its EOS (a
+    row that only ran out of ``steps_left`` stays alive). Handed back as
+    ``toks`` / ``pos`` / ``alive``, a dead row rides the window as a row
+    out of steps does: no write but to the null block, -1 in every step.
+    ``gen_idx0`` of that window is the host's arithmetic (a row emits
+    ``steps_left`` tokens unless it dies, and a dead row's draw is never
+    read).
     """
     N = toks.shape[0]
     sampled = rng is not None
+    if alive is None:
+        alive = jnp.ones((N,), bool)
 
     def body(state):
-        s, toks, pos, active, out, moe, cache = state
+        s, toks, pos, active, alive, out, moe, cache = state
         logits, *routed, cache = paged_decode(
             cfg, params, toks, pos, block_tables, cache, active, block_size,
             use_kernel=use_kernel, topo=topo, lora=lora,
@@ -1112,8 +1126,9 @@ def paged_decode_window(cfg: TransformerConfig, params, toks: jnp.ndarray,
         out = out.at[:, s].set(jnp.where(active, nxt, -1))
         pos = jnp.where(active, pos + 1, pos)
         toks = jnp.where(active, nxt, toks)
-        active = active & (nxt != eos_ids) & (s + 1 < steps_left)
-        return s + 1, toks, pos, active, out, moe, cache
+        alive = alive & ~(active & (nxt == eos_ids))
+        active = active & alive & (s + 1 < steps_left)
+        return s + 1, toks, pos, active, alive, out, moe, cache
 
     def cond(state):
         return (state[0] < window) & jnp.any(state[3])
@@ -1121,10 +1136,12 @@ def paged_decode_window(cfg: TransformerConfig, params, toks: jnp.ndarray,
     # what the window's expert layers routed, merged over its steps: one
     # more output where paged_decode has it (attention='mla'), none else
     moe = [jnp.zeros((4,), jnp.float32)] * (cfg.attention == "mla")
-    state = (jnp.asarray(0, jnp.int32), toks, pos, steps_left > 0,
+    state = (jnp.asarray(0, jnp.int32), toks, pos,
+             (steps_left > 0) & alive, alive,
              jnp.full((N, window), -1, jnp.int32), moe, cache)
-    _, _, _, _, out, moe, cache = jax.lax.while_loop(cond, body, state)
-    return (out, *moe, cache)
+    _, toks, pos, _, alive, out, moe, cache = jax.lax.while_loop(
+        cond, body, state)
+    return (out, (toks, pos, alive), *moe, cache)
 
 
 # ---------------------------------------------------------------------------

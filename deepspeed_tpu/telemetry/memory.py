@@ -44,6 +44,12 @@ _scopes: Dict[str, Dict[str, str]] = {}
 # per program: every executable it was offered as (offer_executable),
 # oldest first, each [thunk, its scope map once somebody asked]
 _offered: Dict[str, List[list]] = {}
+# an offer holds the jitted function, and with it every executable it
+# loaded, for as long as it is kept: a program's newest signatures (a
+# serving process compiles a few; a test process builds hundreds of
+# engines, and XLA:CPU dies loading one more executable once thousands
+# are resident)
+_OFFERS_KEPT = 8
 
 _MEM_FIELDS = ("argument_size_in_bytes", "output_size_in_bytes",
                "temp_size_in_bytes", "alias_size_in_bytes",
@@ -133,7 +139,9 @@ def offer_executable(program: str, executable: Callable[[], Any]) -> None:
     the maps agree on it."""
     with _lock:
         _executables[program] = executable
-        _offered.setdefault(program, []).append([executable, None])
+        kept = _offered.setdefault(program, [])
+        kept.append([executable, None])
+        del kept[:-_OFFERS_KEPT]
         _scopes.pop(program, None)
 
 

@@ -94,6 +94,8 @@ def run_programs(block):
             cfg, params, i32([toks[15], toks[25]]), i32([15, 5]), table,
             cache, jnp.asarray([True, True]), BS, use_kernel=kernel)
         out[name] = logits
+    out.update(_windows(cfg, params, i32([toks[15], toks[25]]), i32([15, 5]),
+                        table, cache))
     cache = stepped
     logits, cache = pm._paged_verify(
         cfg, params, i32([toks[16:18], toks[26:28]]), i32([16, 6]), table,
@@ -103,6 +105,35 @@ def run_programs(block):
     out["pool"] = jnp.stack([jnp.abs(cache[kv]).sum(axis=(1, 2, 3))
                              for kv in ("k", "v")])
     return {k: np.asarray(v, np.float32).tolist() for k, v in out.items()}
+
+
+def _windows(cfg, params, toks, pos, table, cache):
+    """Not stored, compared among themselves (PR 39): the fused window
+    over the two decode rows from the cache the decode step read (three
+    steps for row A, whose EOS is the token it emits second; two for
+    row B), and the window after it fed twice: with the rows' state the
+    first handed on, on the device, and with what a host would make of
+    the first's tokens."""
+    def window(t, p, steps, eos, c, alive=None):
+        return pm.paged_decode_window(
+            cfg, params, t, p, table, c, jnp.asarray(steps, jnp.int32),
+            jnp.asarray(eos, jnp.int32), BS, 4, use_kernel=False,
+            alive=alive)
+
+    free, _, _ = window(toks, pos, [3, 2], [-1, -1], cache)
+    eos = [int(free[0, 1]), -1]
+    first, (t, p, alive), after = window(toks, pos, [3, 2], eos, cache)
+    device, (_, p2, alive2), _ = window(t, p, [2, 2], eos, after, alive)
+    # the host's reading of ``first``: row A is dead and leaves, row B
+    # feeds its last token at the position after those it fed
+    took = (np.asarray(first) >= 0).sum(axis=1)
+    host, _, _ = window(
+        jnp.asarray([0, int(first[1, took[1] - 1])], jnp.int32),
+        pos + jnp.asarray(took, jnp.int32), [0, 2], eos, after)
+    return {"window": first, "window_free": free, "window_pos": p,
+            "window_alive": alive, "window_next_device": device,
+            "window_next_host": host, "window_next_pos": p2,
+            "window_next_alive": alive2}
 
 
 @pytest.fixture(scope="module", params=sorted(BLOCKS))
@@ -118,3 +149,32 @@ def test_a_programs_logits_are_the_parents(ran, program):
     assert want.size and np.abs(want).max() > 1e-3     # a real reading
     np.testing.assert_allclose(np.asarray(got[program], np.float32), want,
                                rtol=2e-6, atol=2e-6)
+
+
+def test_the_window_hands_on_its_rows_state(ran):
+    """``paged_decode_window`` returns (tokens, (token, position, alive),
+    cache). Its first step is the pinned decode step; a row stopped by
+    its EOS is dead and one out of steps is alive; fed back on the
+    device the state gives the tokens a host-made window gives."""
+    block, got = ran
+    stored = json.loads(STORED.read_text())[block]
+    first = np.asarray(got["window"], np.int64)
+    np.testing.assert_array_equal(
+        first[:, 0], np.argmax(np.asarray(stored["decode"]), axis=-1))
+    free = np.asarray(got["window_free"], np.int64)
+    assert (free[0, :3] >= 0).all() and (free[1, :2] >= 0).all()
+    assert (free[0, 3:] == -1).all() and (free[1, 2:] == -1).all()
+    # row A stops where it first emits its EOS (the token of its second
+    # step), with steps left; row B runs out of steps
+    fed = list(free[0, :3]).index(free[0, 1]) + 1
+    np.testing.assert_array_equal(first[0],
+                                  list(free[0, :fed]) + [-1] * (4 - fed))
+    np.testing.assert_array_equal(first[1], free[1])
+    assert got["window_alive"] == [0.0, 1.0]
+    assert got["window_pos"] == [15 + fed, 5 + 2]
+    nxt = np.asarray(got["window_next_device"], np.int64)
+    np.testing.assert_array_equal(nxt,
+                                  np.asarray(got["window_next_host"]))
+    assert (nxt[0] == -1).all() and (nxt[1, :2] >= 0).all()
+    assert got["window_next_alive"] == [0.0, 1.0]
+    assert got["window_next_pos"] == [15 + fed, 5 + 4]

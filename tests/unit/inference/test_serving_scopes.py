@@ -124,20 +124,32 @@ def test_the_coarse_spans_keep_name_extent_and_attrs(engine):
     windows = [s for s in spans if s["name"] == "decode_window"]
     assert len(step) == 1 and len(windows) == -(-(NEW_TOKENS - 1) // 8)
     assert step[0]["attrs"] == {"rows": 3, "tokens": 36, "uids": [0, 1, 2]}
-    for w in windows:
-        assert w["attrs"] == {"batch": 3, "window": 8, "uids": [0, 1, 2]}
+    # a span a window launched; all but a call's first were queued
+    # behind the one before (generate() launches ahead)
+    for i, w in enumerate(windows):
+        assert w["attrs"] == {"batch": 3, "window": 8, "ahead": int(i > 0),
+                              "uids": [0, 1, 2]}
     # the extent is the children's: uploads and launch, then the wait
+    # (a window's: for the tokens of the window before it, so a call's
+    # first has none and its last is fetched under the root)
     for outer, names in ((step[0], ("ragged_dispatch", "ragged_fetch")),
-                         (windows[0], ("window_assemble", "window_dispatch",
+                         (windows[0], ("window_assemble", "window_dispatch")),
+                         (windows[1], ("window_assemble", "window_dispatch",
                                        "window_fetch"))):
         inner = [s for s in spans if s["parent"] == outer["id"]]
         assert tuple(s["name"] for s in sorted(
             inner, key=lambda s: s["start"])) == names
-        assert sum(s["duration_s"] for s in inner) \
+        # (a span with no wait in it is too short for that to say much)
+        assert outer is windows[0] or sum(s["duration_s"] for s in inner) \
             >= 0.98 * outer["duration_s"]
-    # one gen_schedule before every window, and one that ends the loop
+    fetches = [s for s in spans if s["name"] == "window_fetch"]
+    assert len(fetches) == len(windows)
+    assert [s["parent"] for s in fetches] \
+        == [w["id"] for w in windows[1:]] + [windows[0]["parent"]]
+    # one gen_schedule before every window, one that finds no window to
+    # queue behind the last, and one that ends the loop
     assert sum(s["name"] == "gen_schedule" for s in spans) \
-        == len(windows) + 1
+        == len(windows) + 2
 
 
 def test_the_per_token_path_has_the_same_leaves(tiny_model_128):

@@ -99,3 +99,30 @@ def test_tree_bytes_counts_pytrees():
     tree = {"a": jnp.ones((4, 4), jnp.float32),
             "b": [jnp.ones(10, jnp.int32)]}
     assert ds_memory.tree_bytes(tree) == 4 * 4 * 4 + 10 * 4
+
+
+def test_offers_keep_a_programs_newest_and_release_the_rest():
+    """An offer holds the jitted function, and with it every executable
+    it loaded: a program keeps its newest ``_OFFERS_KEPT`` and lets the
+    functions of older ones go (a test process builds engines by the
+    hundred; the benchmark's reader asks after the engine is deleted,
+    for the two signatures a program ran)."""
+    import gc
+    import weakref
+
+    class Fn:
+        pass
+
+    ds_memory.reset()
+    try:
+        kept = ds_memory._OFFERS_KEPT
+        fns = [Fn() for _ in range(kept + 3)]
+        refs = [weakref.ref(f) for f in fns]
+        for f in fns:
+            ds_memory.offer_executable("p", lambda f=f: f)
+        del fns, f
+        gc.collect()
+        assert ds_memory.signatures_offered("p") == kept
+        assert [r() is None for r in refs] == [True] * 3 + [False] * kept
+    finally:
+        ds_memory.reset()
