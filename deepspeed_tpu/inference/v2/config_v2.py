@@ -90,6 +90,12 @@ class RaggedInferenceEngineConfig:
     # dequantize in VMEM — so fused decode windows, the ragged unified
     # program and the SplitFuse fast path all keep their compiled shape.
     kv_quant: bool = False
+    # the type the recurrent state of a model with linear-attention
+    # layers is KEPT in between launches (the update itself runs in
+    # float32): "float32", or "bfloat16" at half the bytes, which rounds
+    # the state at every token and drifts from the recurrence (the
+    # benchmark's control for such a cell)
+    state_dtype: str = "float32"
     # fused multi-token decode: up to K decode steps run in ONE jitted
     # device loop (cache write, paged attention, sampling, EOS masking,
     # arithmetic block-table advance over pre-allocated blocks) with a
@@ -140,6 +146,10 @@ class RaggedInferenceEngineConfig:
             raise ValueError(
                 f"spec_mode must be 'auto', 'ngram' or 'draft', got "
                 f"{self.spec_mode!r}")
+        if self.state_dtype not in ("float32", "bfloat16"):
+            raise ValueError(
+                f"state_dtype must be 'float32' or 'bfloat16', got "
+                f"{self.state_dtype!r}")
         if self.max_lora_adapters < 0:
             raise ValueError("max_lora_adapters must be >= 0")
         if self.max_lora_adapters and self.lora_rank < 1:

@@ -171,7 +171,11 @@ class InferenceEngineV2:
                 f"{cfg.moe_top_k}, {cfg.served_only}); serve top-k>2 " \
                 f"and the deployed expert layer at ep=1"
         if cfg.attention == "mla":
-            self._refuse_for_latent(config)
+            self._refuse_for_latent(config, cfg)
+        if not cfg.has_state and config.state_dtype != "float32":
+            raise ValueError(
+                "state_dtype is for a model that keeps recurrent state "
+                "(linear-attention layers); this one keeps none")
         sm = config.state_manager
         if sm.max_seq_len > cfg.max_seq_len:
             sm.max_seq_len = cfg.max_seq_len
@@ -235,7 +239,12 @@ class InferenceEngineV2:
             self.params, self._qmeta = quantize_params(
                 self.params, bits=config.quant_bits)
 
-        self.state_manager = DSStateManager(sm)
+        # a model with linear-attention layers keeps recurrent state a
+        # sequence: a slot a tracked sequence, beside its blocks
+        self._has_state = cfg.has_state
+        self.state_manager = DSStateManager(
+            sm, state_slots=sm.max_tracked_sequences
+            if self._has_state else 0)
         # note: the fresh pool carries no sharding, while every program
         # returns the donated cache with an explicit NamedSharding — so
         # a bucket's FIRST call compiles against a different executable
@@ -244,9 +253,11 @@ class InferenceEngineV2:
         # the bucket set twice before watchdog.mark_steady(); committing
         # the pool sharded at init was tried and destabilizes unrelated
         # XLA-CPU executables later in the process (see PR 7 notes)
-        self.kv_cache = init_paged_kv_cache(cfg, sm.num_blocks,
-                                            sm.block_size, self.dtype,
-                                            kv_quant=config.kv_quant)
+        self.kv_cache = init_paged_kv_cache(
+            cfg, sm.num_blocks, sm.block_size, self.dtype,
+            kv_quant=config.kv_quant,
+            state_slots=self.state_manager.state_slots,
+            state_dtype=DTYPES[config.state_dtype])
         # cold-block KV spill tier (ragged/spill.py): installed on the
         # state manager so prefix eviction demotes content to host RAM
         # (+ optional disk) and match_prefix restores it between steps
@@ -333,28 +344,32 @@ class InferenceEngineV2:
         # bank and per-row adapter slots. Both are None when the bank is
         # disabled (an empty pytree — same compiled programs as before),
         # and they TRAIL the existing argument lists so every
-        # donate_argnums index stays put
+        # donate_argnums index stays put. The programs that run a model
+        # with recurrent state (decode, the fused window, the ragged
+        # step) take one more behind them, ``ss``: each row's state
+        # slot, None for every other model
         self._decode_jit = watchdog.watch_jit(
-            "decode", lambda p, t, pos, bt, c, a, lb, aid: paged_decode(
+            "decode", lambda p, t, pos, bt, c, a, lb, aid, ss: paged_decode(
                 cfg, p, t, pos, bt, c, a, sm.block_size,
                 use_kernel=use_kernel, topo=topo, lora=lb,
-                adapter_ids=aid),
+                adapter_ids=aid, state_slots=ss),
             donate_argnums=(4,))
 
-        def _decode_tok(p, t, pos, bt, c, a, lb, aid):
+        def _decode_tok(p, t, pos, bt, c, a, lb, aid, ss):
             # greedy variant for the generate() hot loop: argmax on device
             # so the per-token host transfer is [N] int32, not [N, vocab]
             # (the reference's sampler also runs device-side)
             logits, *moe, c = paged_decode(
                 cfg, p, t, pos, bt, c, a, sm.block_size,
-                use_kernel=use_kernel, topo=topo, lora=lb, adapter_ids=aid)
+                use_kernel=use_kernel, topo=topo, lora=lb, adapter_ids=aid,
+                state_slots=ss)
             return (greedy_tokens(logits), *moe, c)
 
         self._decode_tok_jit = watchdog.watch_jit(
             "decode_greedy", _decode_tok, donate_argnums=(4,))
 
         def _decode_sample(p, t, pos, bt, c, a, rng, seeds, gidx, temp,
-                           topp, topk, lb, aid):
+                           topp, topk, lb, aid, ss):
             # sampling variant (FastGen temperature/top-p/top-k): the
             # sampler runs device-side too, still an [N] int32 transfer.
             # Per-ROW keys (stable row seed + generated-token index) so
@@ -362,7 +377,8 @@ class InferenceEngineV2:
             from .sampling import fold_in_rows, sample_tokens_rowwise
             logits, *moe, c = paged_decode(
                 cfg, p, t, pos, bt, c, a, sm.block_size,
-                use_kernel=use_kernel, topo=topo, lora=lb, adapter_ids=aid)
+                use_kernel=use_kernel, topo=topo, lora=lb, adapter_ids=aid,
+                state_slots=ss)
             keys = fold_in_rows(rng, seeds, gidx)
             return (sample_tokens_rowwise(logits, keys, temp, topp, topk),
                     *moe, c)
@@ -393,21 +409,22 @@ class InferenceEngineV2:
         def _build_fused_pair(K: int):
             greedy = watchdog.watch_jit(
                 "decode_window_greedy",
-                lambda p, t, pos, bt, c, sl, eos, alive, lb, aid, _K=K:
+                lambda p, t, pos, bt, c, sl, eos, alive, lb, aid, ss, _K=K:
                 paged_decode_window(
                     cfg, p, t, pos, bt, c, sl, eos, sm.block_size,
                     _K, use_kernel=use_kernel,
-                    topo=topo, lora=lb, adapter_ids=aid, alive=alive),
+                    topo=topo, lora=lb, adapter_ids=aid, alive=alive,
+                    state_slots=ss),
                 donate_argnums=(4,))
             sample = watchdog.watch_jit(
                 "decode_window_sample",
                 lambda p, t, pos, bt, c, sl, eos, alive, rng, seeds, g0, \
-                temp, topp, topk, lb, aid, _K=K: paged_decode_window(
+                temp, topp, topk, lb, aid, ss, _K=K: paged_decode_window(
                     cfg, p, t, pos, bt, c, sl, eos, sm.block_size,
                     _K, rng=rng, row_seeds=seeds, gen_idx0=g0,
                     temp=temp, topp=topp, topk=topk,
                     use_kernel=use_kernel, topo=topo, lora=lb,
-                    adapter_ids=aid, alive=alive),
+                    adapter_ids=aid, alive=alive, state_slots=ss),
                 donate_argnums=(4,))
             return greedy, sample
 
@@ -444,11 +461,11 @@ class InferenceEngineV2:
             config.ragged_attention)
         self._ragged_jit = watchdog.watch_jit(
             "ragged_step",
-            lambda p, ids, rows, pos, ln, wb, wo, bt, li, c, lb, aid:
+            lambda p, ids, rows, pos, ln, wb, wo, bt, li, c, lb, aid, ss:
             paged_ragged_step(
                 cfg, p, ids, rows, pos, ln, wb, wo, bt, li, c,
                 sm.block_size, use_kernel=use_kernel, topo=topo,
-                lora=lb, adapter_ids=aid),
+                lora=lb, adapter_ids=aid, state_slots=ss),
             donate_argnums=(9,))
         # speculative verification: greedy ids for a static window of
         # fed positions from one fused continuation pass (prompt-lookup
@@ -486,6 +503,9 @@ class InferenceEngineV2:
                 ds_memory.tree_bytes(self.kv_cache))
             ds_memory.record_buffer("params",
                                     ds_memory.tree_bytes(self.params))
+            self._m_state_bytes.set(ds_memory.tree_bytes(
+                {k: v for k, v in self.kv_cache.items()
+                 if k.startswith("kda_")}))
         except Exception:  # accounting must never block serving
             pass
         log_dist(
@@ -495,10 +515,13 @@ class InferenceEngineV2:
             ranks=[0])
 
     @staticmethod
-    def _refuse_for_latent(config):
+    def _refuse_for_latent(config, cfg):
         """What this engine does not do for an attention='mla' model,
-        said at construction rather than run wrong."""
+        and (``cfg.has_state``) for one whose layer pattern keeps
+        recurrent state a sequence, said at construction rather than run
+        wrong."""
         sm = config.state_manager
+        state = cfg.has_state
         refused = {
             "tensor_parallel_size > 1 (the latent projections and the "
             "kernel are written for one device)":
@@ -510,23 +533,31 @@ class InferenceEngineV2:
             "max_lora_adapters (LoRA targets wq / wv, which the latent "
             "projections replace)": config.max_lora_adapters > 0,
             "enable_prefix_caching (the prefix index has not been shown "
-            "to share latent blocks)": sm.enable_prefix_caching,
-            "enable_kv_spill (the spill tier moves k / v leaves)":
+            "to share latent blocks" + (
+                ", and a shared block carries no recurrent state: a row "
+                "that skipped a prefix would start from the wrong state"
+                if state else "") + ")": sm.enable_prefix_caching,
+            "enable_kv_spill (the spill tier moves k / v leaves" + (
+                " and no state slot" if state else "") + ")":
                 sm.enable_kv_spill,
             "ragged_attention 'off' (the stitched prefill / continue "
             "programs have no latent form)":
-                config.ragged_attention == "off"}
+                config.ragged_attention == "off",
+            "kv_quant (the int8 latent pool has not been served beside "
+            "state leaves)": state and config.kv_quant}
         bad = [what for what, on in refused.items() if on]
         if bad:
             raise NotImplementedError(
-                "attention='mla' is served without: " + "; ".join(bad))
+                "attention='mla'" + (" with linear-attention layers"
+                                     if state else "")
+                + " is served without: " + "; ".join(bad))
 
     # ------------------------------------------------------------------
     # Telemetry (unified registry, telemetry/registry.py)
     # ------------------------------------------------------------------
     def _note_moe(self, program: str, stats=None) -> None:
         """What the launch's expert layers routed (the latent programs'
-        middle output, ``paged_model._latent_step``, fetched with the
+        middle output, ``paged_model._pattern_step``, fetched with the
         launch's own result) into the registry's counters."""
         if stats is None:
             return
@@ -583,6 +614,19 @@ class InferenceEngineV2:
             "high-water mark of inference_kv_pool_utilization")
         self._m_tracked = reg.gauge(
             "inference_tracked_sequences", "sequences with live KV state")
+        self._m_state_bytes = reg.gauge(
+            "inference_state_bytes",
+            "bytes of the recurrent-state leaves (linear-attention "
+            "layers: every slot of every such layer, the null slot "
+            "included); 0 for a model that keeps none", unit="bytes")
+        self._m_state_slots = reg.gauge(
+            "inference_state_slots_in_use",
+            "recurrent-state slots owned by tracked sequences")
+        self._m_state_rows = reg.counter(
+            "inference_state_rows_total",
+            "rows whose recurrent state a launch read and wrote, by "
+            "program (a fused window counts a row once a step it may "
+            "take)", labelnames=("program",))
         self._m_spec_drafted = reg.counter(
             "inference_spec_drafted_tokens_total",
             "speculative tokens drafted for verification")
@@ -685,6 +729,7 @@ class InferenceEngineV2:
         if util > self._m_kv_util_peak.value:
             self._m_kv_util_peak.set(util)
         self._m_tracked.set(sm.tracked_sequences())
+        self._m_state_slots.set(sm.state_slots_in_use())
 
     # ------------------------------------------------------------------
     # Ragged mode (config_v2.ragged_attention: auto | on | off)
@@ -1407,6 +1452,14 @@ class InferenceEngineV2:
         tables = tables[:, :self._pow2_bucket(used_pages, MB)]
         return N, toks, pos, tables
 
+    def _state_slots(self, uids: List[int], N: int):
+        """[N] int32: each row's slot of recurrent state, the null slot
+        for padding rows; None for a model that keeps none."""
+        if not self._has_state:
+            return None
+        seqs = self.state_manager.seqs
+        return self._pad_i32(N, [seqs[u].state_slot for u in uids])
+
     def _build_decode_inputs(self, uids: List[int], tokens: List[int]):
         N, toks, pos, tables = self._assemble_decode_rows(
             uids, tokens, [1] * len(uids))
@@ -1432,7 +1485,7 @@ class InferenceEngineV2:
             with trace.span("step_dispatch"):
                 vals, *moe, self.kv_cache = jit_fn(
                     self.params, toks, pos, tables, self.kv_cache, active,
-                    lb, aid)
+                    lb, aid, self._state_slots(uids, active.shape[0]))
             with trace.span("step_fetch"):
                 # blocks: the pass completes here
                 vals, moe = jax.device_get((vals, moe))
@@ -1440,6 +1493,9 @@ class InferenceEngineV2:
             dt = step["duration_s"]
             self._m_host_syncs.inc()
             self._note_moe("decode_step", *moe)
+            if self._has_state:
+                self._m_state_rows.labels(program="decode_step").inc(
+                    len(uids))
             self._m_decode_steps.inc()
             self._m_decode_tokens.inc(len(uids))
             self._m_decode_time.observe(dt)
@@ -1494,9 +1550,9 @@ class InferenceEngineV2:
             temperature, top_p, top_k)
         return self._decode_common(
             uids, tokens,
-            lambda p, t, pos, bt, c, a, lb, aid: self._decode_sample_jit(
+            lambda p, t, pos, bt, c, a, lb, aid, ss: self._decode_sample_jit(
                 p, t, pos, bt, c, a, rng, seeds, g0, temp, topp, topk,
-                lb, aid),
+                lb, aid, ss),
             lambda v, i: int(v[i]))
 
     # -- fused multi-token decode window --------------------------------
@@ -1555,7 +1611,8 @@ class InferenceEngineV2:
                 out, state, *moe, self.kv_cache = jit_fn(
                     self.params, state[0], state[1], jnp.asarray(tables),
                     self.kv_cache, self._pad_i32(N, steps_left),
-                    jnp.asarray(eos), state[2], *extra, lb, aid)
+                    jnp.asarray(eos), state[2], *extra, lb, aid,
+                    self._state_slots(uids, N))
                 if behind is not None:
                     self._m_windows_ahead.inc()
                 win = _Window(
@@ -1583,6 +1640,9 @@ class InferenceEngineV2:
             win.out = win.moe = win.state = None
             self._m_host_syncs.inc()
             self._note_moe("decode_window", *moe)
+            if self._has_state:
+                self._m_state_rows.labels(program="decode_window").inc(
+                    sum(win.steps_left))
             log_tokens = sm.config.enable_prefix_caching
             emitted: Dict[int, List[int]] = {}
             win.last = {}
@@ -1760,7 +1820,9 @@ class InferenceEngineV2:
                     jnp.asarray(rb.last_index), self.kv_cache,
                     self.lora_bank,
                     (jnp.asarray(rb.adapter_slots)
-                     if self.lora_bank is not None else None))
+                     if self.lora_bank is not None else None),
+                    (jnp.asarray(rb.state_slots)
+                     if self._has_state else None))
             with trace.span("ragged_fetch"):
                 # blocks: the pass completes here
                 logits, moe = jax.device_get((logits, moe))
@@ -1769,6 +1831,9 @@ class InferenceEngineV2:
             # the logits' arrival (the two spans' own durations)
             dt = packed["duration_s"] + step["duration_s"]
             self._note_moe("ragged_step", *moe)
+            if self._has_state:
+                self._m_state_rows.labels(program="ragged_step").inc(
+                    len(entries))
             log_tokens = sm.config.enable_prefix_caching
             for uid, toks in entries:
                 seq = sm.seqs[uid]
@@ -1888,6 +1953,24 @@ class InferenceEngineV2:
                 seen.append(tid)
         return {"trace_ids": seen} if seen else {}
 
+    def sequence_state(self, uid: int) -> Dict[str, np.ndarray]:
+        """The recurrent state a tracked sequence holds in its slot, on
+        the host, as the cache keeps it: ``kda_state`` ``[linear layers,
+        heads, d_k, d_v]`` and ``kda_conv`` ``[linear layers, taps - 1,
+        3 x heads x d_k]``, after every token fed so far. The read half
+        of a snapshot (what preemption and handoff of such a model would
+        carry: ROADMAP M5)."""
+        if not self._has_state:
+            raise ValueError("sequence_state: this model keeps no "
+                             "recurrent state (no linear-attention layer)")
+        sm = self.state_manager
+        if not sm.known_seq(uid):
+            raise KeyError(f"sequence_state: uid {uid} is not tracked")
+        slot = sm.seqs[uid].state_slot
+        return {name: np.asarray(leaf[:, slot])
+                for name, leaf in self.kv_cache.items()
+                if name.startswith("kda_")}
+
     def flush(self, uid: int) -> None:
         """Release a finished sequence's KV blocks (reference flush).
         Also forgets the uid's speculative cold-streak state: uids are
@@ -1939,16 +2022,18 @@ class InferenceEngineV2:
         aidN = i32(N) if self.lora_bank is not None else None
         aid0 = (jax.ShapeDtypeStruct((), jnp.int32)
                 if self.lora_bank is not None else None)
+        ssN = i32(N) if self._has_state else None
         programs: Dict[str, dict] = {}
         compiled = self._decode_tok_jit.lower(
             params, toks, pos, tables, cache,
-            jax.ShapeDtypeStruct((N,), jnp.bool_), lb, aidN).compile()
+            jax.ShapeDtypeStruct((N,), jnp.bool_), lb, aidN, ssN).compile()
         programs["decode_greedy"] = ds_memory.record_memory_analysis(
             "decode_greedy", compiled)
         if self.decode_window > 1:
             compiled = self._fused_greedy_jit.lower(
                 params, toks, pos, tables, cache, i32(N), i32(N),
-                jax.ShapeDtypeStruct((N,), jnp.bool_), lb, aidN).compile()
+                jax.ShapeDtypeStruct((N,), jnp.bool_), lb, aidN,
+                ssN).compile()
             programs["decode_window_greedy"] = \
                 ds_memory.record_memory_analysis("decode_window_greedy",
                                                  compiled)
@@ -1969,7 +2054,8 @@ class InferenceEngineV2:
                              sm.config.max_ragged_batch_size)
             compiled = self._ragged_jit.lower(
                 params, i32(TB), i32(TB), i32(TB), i32(TB), i32(TB),
-                i32(TB), i32(N, MB), i32(N), cache, lb, aidN).compile()
+                i32(TB), i32(N, MB), i32(N), cache, lb, aidN,
+                ssN).compile()
             programs["ragged_step"] = dict(
                 ds_memory.record_memory_analysis("ragged_step", compiled),
                 token_bucket=TB, row_bucket=N)
@@ -1983,7 +2069,8 @@ class InferenceEngineV2:
                  top_k: int = 0, seed: int = 0, speculative: bool = False,
                  spec_k: int = 4, spec_ngram: int = 3,
                  spec_mode: Optional[str] = None,
-                 adapter=None) -> List[np.ndarray]:
+                 adapter=None,
+                 keep_sequences: bool = False) -> List[np.ndarray]:
         """Greedy by default; temperature > 0 samples with nucleus top_p
         (FastGen's sampling surface), deterministic for a given seed.
         ``speculative`` turns on speculative decoding (greedy only):
@@ -2061,6 +2148,7 @@ class InferenceEngineV2:
                         self._m_spec_mode_requests.labels(mode=mode).inc()
                 base_rng = jax.random.PRNGKey(seed) if sampling else None
             t_start = time.perf_counter()
+            served = False
             # prompts go through put() (prefill); the continuation loop
             # then stays in token space — argmax/sampler runs on device
             # and only [N] int32s cross to host per step (put()'s [N,
@@ -2206,6 +2294,7 @@ class InferenceEngineV2:
                     else:
                         em = {u: [t] for u, t in self._decode_batch_greedy(
                             step_uids, feed).items()}
+                served = True
             finally:
                 # flush even on the schedulability raise: a long-lived
                 # engine must not leak this call's KV blocks / sequence
@@ -2214,7 +2303,8 @@ class InferenceEngineV2:
                 # returns is already the engine's, and what is launched
                 # next runs after it
                 with trace.span("gen_flush"):
-                    for uid in uids:
-                        self.flush(uid)
+                    if not (keep_sequences and served):
+                        for uid in uids:
+                            self.flush(uid)
                     rows = [np.asarray(o) for o in outs]
         return rows

@@ -41,7 +41,7 @@ where fusion cannot: the attention reads (paged_attention.py,
 ops/decode_attention.py, flash prefill).
 """
 
-from typing import Any, Dict, Tuple
+from typing import Any, Dict, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -52,8 +52,9 @@ NEG_INF = -1e30
 
 
 def init_paged_kv_cache(cfg: TransformerConfig, num_blocks: int,
-                        block_size: int, dtype,
-                        kv_quant: bool = False) -> Dict[str, jnp.ndarray]:
+                        block_size: int, dtype, kv_quant: bool = False,
+                        state_slots: int = 0, state_dtype=jnp.float32
+                        ) -> Dict[str, jnp.ndarray]:
     """``kv_quant`` stores the pool int8 with PER-BLOCK (page x kv-head)
     fp32 scales — ~0.5x the bf16 bytes (scale overhead 4/(bs*hd) per
     element instead of the old per-slot 4/hd), so the same HBM holds
@@ -67,13 +68,15 @@ def init_paged_kv_cache(cfg: TransformerConfig, num_blocks: int,
 
     ``k``/``v`` are ``[L, nb, bs, kv_heads * head_dim]`` for every
     geometry and dtype (the module docstring says why); the int8 scales
-    ``[L, nb, kv_heads]``."""
+    ``[L, nb, kv_heads]``. ``state_slots`` / ``state_dtype``: the
+    recurrent state of a model with linear-attention layers
+    (``_init_latent_cache``)."""
     assert cfg.is_causal and cfg.norm_scheme == "pre", \
         "paged serving requires a causal pre-LN model (the MLM/post-LN " \
         "encoder family does not decode)"
     if cfg.attention == "mla":
         return _init_latent_cache(cfg, num_blocks, block_size, dtype,
-                                  kv_quant)
+                                  kv_quant, state_slots, state_dtype)
     shape = (cfg.num_layers, num_blocks, block_size,
              cfg.kv_heads * cfg.head_dim)
     if kv_quant:
@@ -91,7 +94,8 @@ def latent_pool_row(cfg) -> int:
     return -(-cfg.latent_row // 128) * 128
 
 
-def _init_latent_cache(cfg, num_blocks, block_size, dtype, kv_quant):
+def _init_latent_cache(cfg, num_blocks, block_size, dtype, kv_quant,
+                       state_slots=0, state_dtype=jnp.float32):
     """The pool of an attention='mla' model: ONE leaf, ``latent``
     ``[L, nb, bs, kv_lora_rank + qk_rope_head_dim]``: a cached position
     holds a layer's normed latent and, behind it, the rotated key part
@@ -101,19 +105,39 @@ def _init_latent_cache(cfg, num_blocks, block_size, dtype, kv_quant):
     lanes for 576): the TPU's tiles pad it so in HBM whatever the shape
     says, and Mosaic copies no slice of a page that is not whole lane
     blocks (it refused the 576-wide one). The leaf's shape
-    is a function of the attention kind alone (every layer here has the
-    same kind); the layer leads, so that the kernel takes the whole pool
-    and a layer index (``kernels/ragged_attention.latent_attention``).
+    is a function of the attention kind alone; the layer leads, so that
+    the kernel takes the whole pool and a layer index
+    (``kernels/ragged_attention.latent_attention``).
+
+    A leaf a layer KIND: under a layer pattern (``cfg.layer_kinds``) the
+    pool has the LATENT layers only, in layer order, and the linear
+    layers' recurrent state lies beside it, indexed by a sequence's
+    state SLOT and not by block: ``kda_state`` ``[L_linear, slots + 1,
+    heads, d_k, d_v]`` and ``kda_conv`` ``[L_linear, slots + 1, taps - 1,
+    3 x heads x d_k]`` (the convolution's last inputs), both in
+    ``state_dtype`` (float32: a state rounded to bfloat16 at every token
+    drifts from the recurrence). Slot 0 is the null slot, as block 0 is
+    the null block: padded and masked rows read and write it.
 
     ``kv_quant``: see below."""
-    shape = (cfg.num_layers, num_blocks, block_size, latent_pool_row(cfg))
+    kinds = cfg.layer_kinds
+    shape = (kinds.count("mla"), num_blocks, block_size,
+             latent_pool_row(cfg))
+    state = {}
+    if "kda" in kinds:
+        n, d = kinds.count("kda"), cfg.linear_head_dim
+        state = {"kda_state": jnp.zeros(
+                     (n, state_slots + 1, cfg.num_heads, d, d), state_dtype),
+                 "kda_conv": jnp.zeros(
+                     (n, state_slots + 1, cfg.linear_conv_size - 1,
+                      3 * cfg.num_heads * d), state_dtype)}
     if kv_quant:
         # int8 rows, one float32 scale a cached position (its row's
         # absmax / 127): half the pool's bytes. A launch dequantises the
         # layer it attends into a transient copy (``_latent_rows``)
         return {"latent": jnp.zeros(shape, jnp.int8),
-                "latent_scale": jnp.zeros(shape[:3], jnp.float32)}
-    return {"latent": jnp.zeros(shape, dtype)}
+                "latent_scale": jnp.zeros(shape[:3], jnp.float32), **state}
+    return {"latent": jnp.zeros(shape, dtype), **state}
 
 
 def _kv_write(kc, ksc, l, blocks, offs, k):
@@ -319,6 +343,17 @@ def _moe_mlp(cfg, lp, x, topo=None):
     return out.reshape(orig_shape)
 
 
+# tokens of a launch an expert layer that holds a SHARE takes at a time
+_SHARE_TOKENS = 4096
+
+
+def _held_from(cfg):
+    """The first expert this tree holds where it holds a share of the
+    router's, else None (it holds them all)."""
+    return cfg.moe_experts_first \
+        if cfg.experts_held < cfg.moe_num_experts else None
+
+
 def _moe_routed(cfg, lp, xt, experts=None, stack_layer=None,
                 router_precision=None):
     """The ep = 1 expert layer on flat tokens ``xt`` [T, H]: (what the
@@ -330,7 +365,7 @@ def _moe_routed(cfg, lp, xt, experts=None, stack_layer=None,
     is, the experts its rounding. ``router_precision`` is the router
     matmul's: the latent block's published router is float32 (``s =
     sigmoid(x Wr)`` in float32) and on a TPU a float32 matmul runs in
-    bf16 passes unless asked otherwise, so ``_latent_step`` asks for
+    bf16 passes unless asked otherwise, so ``_pattern_step`` asks for
     ``HIGHEST``; the per-head path's softmax router keeps the backend's
     default (None), which is what its programs compiled to before."""
     from ...moe.sharded_moe import (dropless_topk_dispatch, gmm_serves,
@@ -343,18 +378,43 @@ def _moe_routed(cfg, lp, xt, experts=None, stack_layer=None,
         logits = jnp.matmul(xt.astype(jnp.float32),
                             gate_w.astype(jnp.float32),
                             precision=router_precision)
+        limit = dict(n_group=cfg.moe_n_group,
+                     topk_group=cfg.moe_topk_group) \
+            if cfg.moe_n_group > 1 else {}
         topi, topv = topk_routing(
             logits, cfg.moe_top_k, cfg.moe_scoring,
             lp["moe_gate_bias"] if cfg.moe_selection_bias else None,
-            cfg.moe_norm_topk, cfg.moe_routed_scale)
+            cfg.moe_norm_topk, cfg.moe_routed_scale, **limit)
     xt = xt.astype(gate_w.dtype)
     with jax.named_scope("moe_experts"):
         if experts is None:
             experts = (lp["e_gate"], lp["e_up"], lp["e_down"])
-        out = dropless_topk_dispatch(
-            xt, topi, topv, experts, gate_w.shape[-1],
-            gmm_swiglu_experts if gmm_serves(experts) else None,
-            stack_layer=stack_layer)
+        # the router scores every expert; this tree may hold a share of
+        # them (cfg.moe_experts_held from cfg.moe_experts_first), and a
+        # pick that is held elsewhere adds nothing here
+        def dispatch(xt, topi, topv):
+            return dropless_topk_dispatch(
+                xt, topi, topv, experts, cfg.experts_held,
+                gmm_swiglu_experts if gmm_serves(experts) else None,
+                stack_layer=stack_layer, held_from=_held_from(cfg))
+
+        T = xt.shape[0]
+        if _held_from(cfg) is not None and T > _SHARE_TOKENS:
+            # a share's launch sorts and gathers EVERY pick's row and
+            # computes the held ones (all of a token's picks may be
+            # held, so no smaller buffer is safe): a launch of any
+            # length over a run goes through in runs of tokens, so that
+            # the sorted rows of one run, not of the launch, are what is
+            # live (16,384 tokens x 8 picks x 2560 are 0.67 GB a
+            # buffer). The last run is padded with rows of zeros, which
+            # add nothing and are cut off
+            pad = -T % _SHARE_TOKENS
+            out = jax.lax.map(lambda a: dispatch(*a), tuple(
+                jnp.pad(a, ((0, pad), (0, 0))).reshape(
+                    -1, _SHARE_TOKENS, a.shape[-1])
+                for a in (xt, topi, topv))).reshape(T + pad, -1)[:T]
+        else:
+            out = dispatch(xt, topi, topv)
     if cfg.moe_shared_experts:
         with jax.named_scope("moe_shared_expert"):
             out = out + (jax.nn.silu(xt @ lp["shared_gate"])
@@ -545,11 +605,13 @@ def _latent_write(pool, l, blocks, offs, row):
     lat = pool["latent"]
     row = jnp.pad(row, ((0, 0), (0, lat.shape[-1] - row.shape[-1])))
     if "latent_scale" not in pool:
-        return {"latent": lat.at[l, blocks, offs].set(row.astype(lat.dtype))}
+        return {**pool,
+                "latent": lat.at[l, blocks, offs].set(row.astype(lat.dtype))}
     rf = row.astype(jnp.float32)
     scale = jnp.max(jnp.abs(rf), axis=-1) / 127.0
     q = jnp.round(rf / jnp.where(scale > 0, scale, 1.0)[:, None])
-    return {"latent": lat.at[l, blocks, offs].set(q.astype(jnp.int8)),
+    return {**pool,
+            "latent": lat.at[l, blocks, offs].set(q.astype(jnp.int8)),
             "latent_scale": pool["latent_scale"].at[l, blocks, offs]
             .set(scale)}
 
@@ -586,8 +648,9 @@ def _latent_attention_sublayer(cfg, lp, x, l, pool, cos, sin, row_ids,
     T = x.shape[0]
     nh, dc = cfg.num_heads, cfg.kv_lora_rank
     dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
-    hn = _norm(cfg, x, lp["attn_norm"]).astype(lp["wq_a"].dtype)
-    q = rms_norm(hn @ lp["wq_a"], lp["q_norm"], cfg.norm_eps) @ lp["wq_b"]
+    hn = _norm(cfg, x, lp["attn_norm"]).astype(lp["wkv_a"].dtype)
+    q = (rms_norm(hn @ lp["wq_a"], lp["q_norm"], cfg.norm_eps) @ lp["wq_b"]
+         if cfg.q_lora_rank else hn @ lp["wq"])
     q = q.reshape(T, nh, dn + dr)
     kv = hn @ lp["wkv_a"]                                   # [T, dc + dr]
     ckv = rms_norm(kv[:, :dc], lp["kv_norm"], cfg.norm_eps)
@@ -610,17 +673,26 @@ def _latent_attention_sublayer(cfg, lp, x, l, pool, cos, sin, row_ids,
         o_lat = attend(qx, rows, at, row_ids, lengths, block_tables,
                        dc=dc, scale=scale)
     o = jnp.einsum("htc,chd->thd", o_lat, wkv_b[..., dn:])
+    if cfg.attn_gate == "head":
+        o = o * jax.nn.sigmoid(hn @ lp["wg"])[..., None].astype(o.dtype)
     return o.reshape(T, nh * dv) @ lp["wo"], pool
 
 
-def _moe_stats(topi, valid, num_experts):
+def _moe_stats(topi, valid, num_experts, held_from=None):
     """What one expert layer routed in one launch, float32 [4]: 1 (a
     launch of an expert layer), the routed rows (valid tokens x k), the
     distinct experts with at least one row, and the fullest expert's
-    share of the rows."""
-    counts = jnp.zeros((num_experts,), jnp.float32).at[
-        topi.reshape(-1)].add(jnp.repeat(valid, topi.shape[-1])
-                              .astype(jnp.float32))
+    share of the rows. ``held_from``: the layer holds ``num_experts`` of
+    the router's from that index, and only rows routed to THEM and the
+    held experts they touch are counted (what its grouped matmuls
+    read and compute)."""
+    idx = topi.reshape(-1)
+    w = jnp.repeat(valid, topi.shape[-1]).astype(jnp.float32)
+    if held_from is not None:
+        idx = idx - held_from
+        w = w * ((idx >= 0) & (idx < num_experts))
+        idx = jnp.clip(idx, 0, num_experts - 1)
+    counts = jnp.zeros((num_experts,), jnp.float32).at[idx].add(w)
     rows = jnp.sum(counts)
     return jnp.stack([jnp.float32(1.0), rows, jnp.sum(counts > 0),
                       jnp.max(counts) / jnp.maximum(rows, 1.0)])
@@ -631,25 +703,171 @@ def _merge_moe_stats(a, b):
     return jnp.concatenate([a[:3] + b[:3], jnp.maximum(a[3:], b[3:])])
 
 
-def _latent_step(cfg: TransformerConfig, params, ids, row_ids, pos, lengths,
-                 write_blocks, write_offsets, block_tables, cache,
-                 use_kernel=True):
+class _StateRows(NamedTuple):
+    """Where the rows of a launch lie, for the layers that keep a state
+    a row: token -> row, each row's first flat token and token count
+    (rows are packed one after another in row order:
+    ``ragged/batch.pack``), its state slot (0, the null slot, for a row
+    with no token) and whether its first token here is the sequence's
+    first, so that it starts from zeros and not from what its slot
+    held. ``one_token``: every row has exactly one (a decode batch)."""
+    row_ids: Any
+    starts: Any
+    counts: Any
+    slots: Any
+    fresh: Any
+    one_token: bool
+
+
+def _state_rows(row_ids, pos, lengths, slots, rows, one_token):
+    valid = lengths > 0
+    if one_token:
+        counts = valid.astype(jnp.int32)
+        starts = jnp.arange(rows, dtype=jnp.int32)
+    else:
+        counts = jnp.zeros((rows,), jnp.int32).at[row_ids].add(
+            valid.astype(jnp.int32))
+        starts = jnp.cumsum(counts) - counts
+    first = pos[jnp.clip(starts, 0, pos.shape[0] - 1)]
+    return _StateRows(row_ids, starts, counts,
+                      jnp.where(counts > 0, slots, 0), first == 0,
+                      one_token)
+
+
+def _l2norm(x):
+    return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
+
+
+def _linear_attention_sublayer(cfg, lp, x, l, cache, rows: _StateRows,
+                               use_kernel=True):
+    """A linear-attention (KDA) mixer on flat tokens x [T, H]; ``l`` is
+    the layer's index among the linear layers (its state leaves'
+    leading axis). q, k and v pass the short causal convolution over the
+    row's own tokens and SiLU; a head's q and k are l2-normalised (q
+    times d_k^-1/2); the decay a head and key channel is
+    ``linear_decay_floor * sigmoid(exp(a_log) (wf x + dt_bias))`` and
+    the update strength ``sigmoid(wb x)``; the heads' outputs are
+    RMS-normed, gated a head and projected. The recurrence runs in
+    float32 from the row's slot (zeros for a row at its first token)
+    and its result goes back to the slot: a decode batch through the
+    one-token update (scope ``kda_state``: the kernel that reads the
+    slot where it lies, ``kda_state_update``, where ``use_kernel`` and
+    the widths allow, else gather, ``kda_step`` and scatter), every
+    other launch through
+    the chunked form, rows of any lengths (scope ``kda_chunk``):
+    ``kernels/linear_attention``. Returns (what the mixer adds to x,
+    cache)."""
+    from ...ops.norms import rms_norm
+    from .kernels import linear_attention as la
+    T = x.shape[0]
+    nh, d = cfg.num_heads, cfg.linear_head_dim
+    D, f32 = nh * d, jnp.float32
+    dt = lp["wq"].dtype
+    hn = _norm(cfg, x, lp["attn_norm"]).astype(dt)
+    with jax.named_scope("kda_proj"):
+        qkv = [hn @ lp[w] for w in ("wq", "wk", "wv")]
+        f, b = hn @ lp["wf"], hn @ lp["wb"]
+    slots = rows.slots
+    with jax.named_scope("kda_conv"):
+        held = cache["kda_conv"][l, slots]                 # [R, K - 1, 3D]
+        held = jnp.where(rows.fresh[:, None, None], 0, held)
+        mixed, kept = [], []
+        for i, part in enumerate(qkv):
+            taps = lp["conv"][:, i * D:(i + 1) * D]
+            past = held[..., i * D:(i + 1) * D]
+            y, past = la.causal_conv_step(part, taps, past, jax.nn.silu) \
+                if rows.one_token else la.causal_conv_rows(
+                    part, taps, past, rows.row_ids, rows.starts,
+                    rows.counts, jax.nn.silu)
+            mixed.append(y)
+            kept.append(past)
+        cache = {**cache, "kda_conv": cache["kda_conv"].at[l, slots].set(
+            jnp.concatenate(kept, axis=-1))}
+    rate = jnp.exp(lp["a_log"].astype(f32))[:, None]        # [nh, 1]
+    dt_bias = lp["dt_bias"].astype(f32).reshape(nh, d)
+
+    def prepare(q, k, v, f, b):
+        """float32 (q, k, v, g, beta) of tokens [..., D], heads split"""
+        q, k, v, f = (a.astype(f32).reshape(*a.shape[:-1], nh, d)
+                      for a in (q, k, v, f))
+        g = cfg.linear_decay_floor * jax.nn.sigmoid(rate * (f + dt_bias))
+        return (_l2norm(q) * d ** -0.5, _l2norm(k), v, g,
+                jax.nn.sigmoid(b.astype(f32)))
+
+    # the recurrence with its state's way out of the slot (zeros for a
+    # row at its first token) and back, one scope: what a roofline of it
+    # has to count
+    with jax.named_scope("kda_state" if rows.one_token else "kda_chunk"):
+        leaf = cache["kda_state"]
+        if rows.one_token and use_kernel and la.state_kernel_serves(leaf):
+            o, leaf = la.kda_state_update(leaf, l, slots, rows.fresh,
+                                          *prepare(*mixed, f, b))
+        elif rows.one_token:
+            state = jnp.where(rows.fresh[:, None, None, None], 0.0,
+                              leaf[l, slots].astype(f32))   # [N, nh, d, d]
+            o, state = la.kda_step(*prepare(*mixed, f, b), state)
+            leaf = leaf.at[l, slots].set(state.astype(leaf.dtype))
+        else:
+            o, leaf = la.kda_chunked(
+                (*mixed, f, b), prepare, leaf, l, slots, rows.fresh,
+                rows.starts, rows.counts, cfg.linear_decay_floor)
+        cache = {**cache, "kda_state": leaf}
+    with jax.named_scope("kda_out"):
+        o = rms_norm(o, lp["o_norm"], cfg.norm_eps)         # [T, nh, d]
+        if cfg.attn_gate == "head":
+            o = o * jax.nn.sigmoid((hn @ lp["wg"]).astype(f32))[..., None]
+        return o.astype(dt).reshape(T, D) @ lp["wo"], cache
+
+
+def _layer_runs(cfg):
+    """The layers as maximal runs of one (mixer kind, MLP kind):
+    [(kind, routed, first layer, layers)], ``kind`` one of
+    ``cfg.layer_kinds``' and ``routed`` whether the run's MLP is the
+    expert layer. One scan a run; the pattern is static."""
+    lead = cfg.moe_first_dense_layers
+    runs = []
+    for i, kind in enumerate(cfg.layer_kinds):
+        key = (kind, cfg.moe_num_experts > 0 and i >= lead, i < lead)
+        if runs and runs[-1][0] == key:
+            runs[-1][2] += 1
+        else:
+            runs.append([key, i, 1])
+    return [(k[0], k[1], first, n) for k, first, n in runs]
+
+
+def _pattern_step(cfg: TransformerConfig, params, ids, row_ids, pos, lengths,
+                  write_blocks, write_offsets, block_tables, cache,
+                  use_kernel=True, state_slots=None, one_token=False):
     """The whole block of an attention='mla' model on a flat token
     buffer, the one forward behind ``paged_ragged_step`` and
     ``paged_decode`` (a decode batch is the ragged layout with one token
-    a row): embedding, the LEADING stack (``lead_layers``: latent
-    attention + a dense gated MLP) scanned, then the expert stack
-    (``layers``: latent attention + the expert layer, or a dense MLP
-    where the model has no experts) scanned, both writing ONE pool
-    ``[L, nb, bs, row]`` at their own layer indices, then the final
-    norm. The stack's expert weights do not ride the scan: a layer
-    sliced out of them for the grouped-matmul kernel would be a copy of
-    all its experts, so the kernel takes the stack whole and the layer
-    by where its groups lie (``dropless_topk_dispatch``).
+    a row, ``one_token``): embedding, then the layers as RUNS of one
+    mixer kind and one MLP kind (``_layer_runs``), each run one scan,
+    then the final norm.
+
+    Without a layer pattern there are two runs, the model's two stacks:
+    ``lead_layers`` (latent attention + a dense gated MLP) and
+    ``layers`` (latent attention + the expert layer, or a dense MLP
+    where the model has no experts), both writing ONE pool
+    ``[L, nb, bs, row]`` at their own layer indices. Under a pattern
+    (``cfg.linear_attn_period``: linear-attention layers with one latent
+    layer a period) the parameter tree keeps a stack a mixer kind
+    (``kda_layers``, ``mla_layers``) beside the MLPs' two, and the cache
+    a leaf a kind: the latent pool ``[L_latent, nb, bs, row]`` indexed
+    by block, and the linear layers' state indexed by the row's SLOT
+    (``state_slots`` [rows]; ``_init_latent_cache``,
+    ``_linear_attention_sublayer``). A run indexes the stacks it reads
+    at its own layers inside the scan's body: a static slice of a stack
+    handed to the scan would be a copy of it.
+
+    The stack's expert weights never ride a scan: a layer sliced out of
+    them for the grouped-matmul kernel would be a copy of all its
+    experts, so the kernel takes the stack whole and the layer by where
+    its groups lie (``dropless_topk_dispatch``).
 
     Returns (normed hidden states [T, H], what this step's expert layers
-    routed (``_moe_stats`` merged over them, valid tokens only; zeros
-    where the model has no experts), cache)."""
+    routed (``_moe_stats`` merged over them, valid tokens only, held
+    experts only; zeros where the model has no experts), cache)."""
     dtype = params["embed"].dtype
     # the residual stream is float32 whatever the weights' type: every
     # sub-layer reads its norm rounded to ``dtype`` and adds what it
@@ -666,32 +884,54 @@ def _latent_step(cfg: TransformerConfig, params, ids, row_ids, pos, lengths,
     cos, sin = _rope_at(cfg, pos)                # [T, qk_rope_head_dim / 2]
     valid = lengths > 0
     lead = cfg.moe_first_dense_layers
-    moe = cfg.moe_num_experts > 0
+    kinds = cfg.layer_kinds
+    pattern = cfg.linear_attn_period > 0
     expert_keys = ("e_gate", "e_up", "e_down")
+    rows = _state_rows(row_ids, pos, lengths, state_slots,
+                       block_tables.shape[0], one_token) \
+        if cfg.has_state else None
 
-    def stack(x, pool, stats, layers, first, routed):
-        experts = tuple(layers[k] for k in expert_keys) if routed else None
-        scanned = {k: v for k, v in layers.items()
+    def stack(x, pool, stats, kind, routed, first, n):
+        led = first < lead
+        mlps = params["lead_layers" if led else "layers"]
+        f0 = first if led else first - lead     # the run's place in mlps
+        experts = tuple(mlps[k] for k in expert_keys) if routed else None
+        scanned = {k: v for k, v in mlps.items()
                    if not (routed and k in expert_keys)}
-        n = scanned["attn_norm"].shape[0]
+        # the run's place in its mixer's leaves, parameters and cache
+        m0 = kinds[:first].count(kind) if pattern else first
+        mixers = params[kind + "_layers"] if pattern else None
 
         def layer_fn(carry, inputs):
             x, pool, stats = carry
-            lp, i = inputs
-            with jax.named_scope("mla_attention"):
-                a, pool = _latent_attention_sublayer(
-                    cfg, lp, x, first + i, pool, cos, sin, row_ids, lengths,
-                    write_blocks, write_offsets, block_tables, use_kernel)
-                x = x + a.astype(jnp.float32)
+            if pattern:
+                i = inputs
+                lp = {**jax.tree.map(lambda a: a[m0 + i], mixers),
+                      **jax.tree.map(lambda a: a[f0 + i], scanned)}
+            else:
+                lp, i = inputs
+            if kind == "kda":
+                with jax.named_scope("linear_attention"):
+                    a, pool = _linear_attention_sublayer(
+                        cfg, lp, x, m0 + i, pool, rows, use_kernel)
+                    x = x + a.astype(jnp.float32)
+            else:
+                with jax.named_scope("mla_attention"):
+                    a, pool = _latent_attention_sublayer(
+                        cfg, lp, x, m0 + i, pool, cos, sin, row_ids,
+                        lengths, write_blocks, write_offsets, block_tables,
+                        use_kernel)
+                    x = x + a.astype(jnp.float32)
             with jax.named_scope("mlp"):
                 hn = _norm(cfg, x, lp["mlp_norm"])
                 if routed:
                     out, topi = _moe_routed(
-                        cfg, lp, hn, experts, i,
+                        cfg, lp, hn, experts, f0 + i,
                         router_precision=jax.lax.Precision.HIGHEST)
                     with jax.named_scope("moe_router"):
                         stats = _merge_moe_stats(stats, _moe_stats(
-                            topi, valid, cfg.moe_num_experts))
+                            topi, valid, cfg.experts_held,
+                            _held_from(cfg)))
                 else:
                     from ...models.transformer import gate_act
                     with jax.named_scope("dense_mlp"):
@@ -703,14 +943,13 @@ def _latent_step(cfg: TransformerConfig, params, ids, row_ids, pos, lengths,
 
         with jax.named_scope("layers"):
             (x, pool, stats), _ = jax.lax.scan(
-                layer_fn, (x, pool, stats), (scanned, jnp.arange(n)))
+                layer_fn, (x, pool, stats),
+                jnp.arange(n) if pattern else (scanned, jnp.arange(n)))
         return x, pool, stats
 
     pool, stats = cache, jnp.zeros((4,), jnp.float32)
-    if lead:
-        x, pool, stats = stack(x, pool, stats, params["lead_layers"], 0,
-                               False)
-    x, pool, stats = stack(x, pool, stats, params["layers"], lead, moe)
+    for kind, routed, first, n in _layer_runs(cfg):
+        x, pool, stats = stack(x, pool, stats, kind, routed, first, n)
     with jax.named_scope("head"):
         x = _norm(cfg, x, params["final_norm"]).astype(dtype)
     return x, stats, pool
@@ -876,7 +1115,7 @@ def paged_decode(cfg: TransformerConfig, params, toks: jnp.ndarray,
                  pos: jnp.ndarray, block_tables: jnp.ndarray,
                  cache: Dict[str, jnp.ndarray], active: jnp.ndarray,
                  block_size: int, use_kernel: bool = True, topo=None,
-                 lora=None, adapter_ids=None
+                 lora=None, adapter_ids=None, state_slots=None
                  ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
     """toks/pos/active [N]; block_tables [N, MB]. One token per sequence;
     returns ([N, V] logits, cache). Inactive rows write to the null block
@@ -884,16 +1123,19 @@ def paged_decode(cfg: TransformerConfig, params, toks: jnp.ndarray,
     the Pallas paged-attention kernel (kernels/paged_attention.py) instead
     of the materializing gather fallback. An attention='mla' model
     returns (logits, what its expert layers routed, cache): see
-    ``_latent_step``."""
+    ``_pattern_step``. ``state_slots`` [N]: each row's slot of recurrent
+    state, for a model whose layer pattern has linear-attention layers
+    (an inactive row reads and writes the null slot)."""
     N, MB = block_tables.shape
     if cfg.attention == "mla":
-        # the ragged layout with one token a row (_latent_step)
+        # the ragged layout with one token a row (_pattern_step)
         blk = jnp.take_along_axis(
             block_tables, (pos // block_size)[:, None], axis=1)[:, 0]
-        x, stats, cache = _latent_step(
+        x, stats, cache = _pattern_step(
             cfg, params, toks, jnp.arange(N, dtype=jnp.int32), pos,
             jnp.where(active, pos + 1, 0), jnp.where(active, blk, 0),
-            pos % block_size, block_tables, cache, use_kernel=use_kernel)
+            pos % block_size, block_tables, cache, use_kernel=use_kernel,
+            state_slots=state_slots, one_token=True)
         with jax.named_scope("head"):
             return _logits(cfg, params, x), stats, cache
     nh, nkv, hd = cfg.num_heads, cfg.kv_heads, cfg.head_dim
@@ -957,7 +1199,7 @@ def paged_ragged_step(cfg: TransformerConfig, params, ids: jnp.ndarray,
                       block_tables: jnp.ndarray, last_index: jnp.ndarray,
                       cache: Dict[str, jnp.ndarray], block_size: int,
                       use_kernel: bool = True, topo=None,
-                      lora=None, adapter_ids=None
+                      lora=None, adapter_ids=None, state_slots=None
                       ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
     """One compiled program for a MIXED batch (the Ragged Paged
     Attention layout, kernels/ragged_attention.py): prefill chunks,
@@ -971,7 +1213,10 @@ def paged_ragged_step(cfg: TransformerConfig, params, ids: jnp.ndarray,
     paged_decode dispatches for everything the scheduler composes into a
     step. Returns ([RB, V] last-token logits per row, cache); an
     attention='mla' model returns (logits, what its expert layers
-    routed, cache): see ``_latent_step``.
+    routed, cache): see ``_pattern_step``; ``state_slots`` [RB] is each
+    row's slot of recurrent state where its layer pattern has
+    linear-attention layers (rows packed one after another in row
+    order, as ``ragged/batch.pack`` lays them).
 
     The new tokens' K/V scatter into the pool inside the scanned layer
     body (padding tokens land in the null block), then every token
@@ -983,9 +1228,10 @@ def paged_ragged_step(cfg: TransformerConfig, params, ids: jnp.ndarray,
     T = ids.shape[0]
     RB, MBw = block_tables.shape
     if cfg.attention == "mla":
-        x, stats, cache = _latent_step(
+        x, stats, cache = _pattern_step(
             cfg, params, ids, row_ids, pos, lengths, write_blocks,
-            write_offsets, block_tables, cache, use_kernel=use_kernel)
+            write_offsets, block_tables, cache, use_kernel=use_kernel,
+            state_slots=state_slots)
         with jax.named_scope("head"):
             return _logits(cfg, params, x[last_index]), stats, cache
     ctx = MBw * block_size
@@ -1056,7 +1302,8 @@ def paged_decode_window(cfg: TransformerConfig, params, toks: jnp.ndarray,
                         temp: jnp.ndarray = None, topp: jnp.ndarray = None,
                         topk: jnp.ndarray = None,
                         use_kernel: bool = True, topo=None,
-                        lora=None, adapter_ids=None, alive=None):
+                        lora=None, adapter_ids=None, alive=None,
+                        state_slots=None):
     """Up to ``window`` decode steps entirely on device — the answer to
     the dispatch-bound per-token loop (one Python round-trip + [N] int32
     transfer PER TOKEN). One ``lax.while_loop`` runs cache write, paged
@@ -1102,7 +1349,10 @@ def paged_decode_window(cfg: TransformerConfig, params, toks: jnp.ndarray,
     out of steps does: no write but to the null block, -1 in every step.
     ``gen_idx0`` of that window is the host's arithmetic (a row emits
     ``steps_left`` tokens unless it dies, and a dead row's draw is never
-    read).
+    read). A row's RECURRENT state (``state_slots`` [N]: a model with
+    linear-attention layers) needs no place in that tuple: it lives in
+    the cache at the row's slot, and the cache is what one window hands
+    the next on the device.
     """
     N = toks.shape[0]
     sampled = rng is not None
@@ -1114,7 +1364,7 @@ def paged_decode_window(cfg: TransformerConfig, params, toks: jnp.ndarray,
         logits, *routed, cache = paged_decode(
             cfg, params, toks, pos, block_tables, cache, active, block_size,
             use_kernel=use_kernel, topo=topo, lora=lora,
-            adapter_ids=adapter_ids)
+            adapter_ids=adapter_ids, state_slots=state_slots)
         moe = [_merge_moe_stats(a, b) for a, b in zip(moe, routed)]
         if sampled:
             from .sampling import fold_in_rows, sample_tokens_rowwise

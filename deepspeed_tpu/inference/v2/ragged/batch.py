@@ -53,6 +53,7 @@ class RaggedBatch:
     block_tables: np.ndarray      # [RB, MBw] int32 (null-padded)
     last_index: np.ndarray        # [RB] int32 flat idx of row's last token
     adapter_slots: np.ndarray     # [RB] int32 LoRA bank slot (0 = base)
+    state_slots: np.ndarray       # [RB] int32 recurrent-state slot (0 = none)
 
     @property
     def total_tokens(self) -> int:
@@ -94,6 +95,7 @@ def pack(entries: Sequence[Tuple[int, np.ndarray]], state_manager
     tables = np.full((RB, sm.max_blocks_per_seq), NULL_BLOCK, np.int32)
     last_index = np.zeros(RB, np.int32)
     adapter_slots = np.zeros(RB, np.int32)
+    state_slots = np.zeros(RB, np.int32)
 
     cursor = 0
     used_pages = 1
@@ -115,6 +117,7 @@ def pack(entries: Sequence[Tuple[int, np.ndarray]], state_manager
         tables[r, :len(seq.blocks)] = seq_blocks
         last_index[r] = cursor + n - 1
         adapter_slots[r] = getattr(seq, "adapter_slot", 0)
+        state_slots[r] = seq.state_slot
         used_pages = max(used_pages, len(seq.blocks))
         cursor += n
         uids.append(int(uid))
@@ -129,4 +132,5 @@ def pack(entries: Sequence[Tuple[int, np.ndarray]], state_manager
                        positions=positions, lengths=lengths,
                        write_blocks=write_blocks,
                        write_offsets=write_offsets, block_tables=tables,
-                       last_index=last_index, adapter_slots=adapter_slots)
+                       last_index=last_index, adapter_slots=adapter_slots,
+                       state_slots=state_slots)

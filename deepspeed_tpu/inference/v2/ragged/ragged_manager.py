@@ -74,8 +74,22 @@ def prefix_digest(tokens, block_size: int,
 
 
 class DSStateManager:
-    def __init__(self, config: DSStateManagerConfig):
+    def __init__(self, config: DSStateManagerConfig, state_slots: int = 0):
+        """``state_slots`` > 0: the model keeps recurrent state a
+        sequence beside its blocks (linear-attention layers), in that
+        many slots of the cache's state leaves, numbered from 1 (slot 0
+        is the null slot). A tracked sequence owns one from its creation
+        to its flush; a slot is not cleared when it changes hands: a
+        sequence's first token starts from zeros in the program
+        (``paged_model._linear_attention_sublayer``)."""
         self.config = config
+        self.state_slots = int(state_slots)
+        if self.state_slots and \
+                self.state_slots < config.max_tracked_sequences:
+            raise ValueError(
+                f"{state_slots} state slots are fewer than the "
+                f"{config.max_tracked_sequences} sequences tracked")
+        self._free_slots = list(range(self.state_slots, 0, -1))
         self.block_size = config.block_size
         self.allocator = BlockedAllocator(config.num_blocks)
         self.seqs: Dict[int, DSSequenceDescriptor] = {}
@@ -262,8 +276,13 @@ class DSStateManager:
                 raise RuntimeError(
                     f"tracked-sequence limit "
                     f"{self.config.max_tracked_sequences} reached")
-            self.seqs[uid] = DSSequenceDescriptor(uid=uid)
+            self.seqs[uid] = DSSequenceDescriptor(
+                uid=uid, state_slot=self._free_slots.pop()
+                if self.state_slots else 0)
         return self.seqs[uid]
+
+    def state_slots_in_use(self) -> int:
+        return self.state_slots - len(self._free_slots)
 
     def can_schedule(self, uid: int, new_tokens: int) -> bool:
         seq = self.seqs.get(uid) or DSSequenceDescriptor(uid=uid)
@@ -298,6 +317,10 @@ class DSStateManager:
         the fed-token log the prefix index registers at flush. The
         caller scatters the handed-off KV content into the returned
         descriptor's blocks."""
+        if self.state_slots:
+            raise NotImplementedError(
+                "a sequence with recurrent state cannot be adopted: a "
+                "handoff carries KV blocks and no state slot")
         if uid in self.seqs:
             raise ValueError(
                 f"cannot adopt uid {uid}: sequence already tracked")
@@ -330,6 +353,8 @@ class DSStateManager:
         (prefix caching first indexes the full blocks for reuse)."""
         seq = self.seqs.pop(uid, None)
         if seq is not None:
+            if seq.state_slot:
+                self._free_slots.append(seq.state_slot)
             if self.config.enable_prefix_caching:
                 self._register_prefix(seq)
             self.allocator.free(seq.blocks)

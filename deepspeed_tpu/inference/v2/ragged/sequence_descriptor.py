@@ -24,6 +24,11 @@ class DSSequenceDescriptor:
     # Base-model sequences keep (None, 0) — slot 0 is the zero adapter.
     adapter: Optional[str] = None
     adapter_slot: int = 0
+    # the sequence's slot of recurrent state (a model with
+    # linear-attention layers: the manager hands one out with the
+    # sequence and takes it back at flush, beside its blocks); 0, the
+    # null slot, where the model keeps none
+    state_slot: int = 0
 
     def blocks_needed(self, new_tokens: int, block_size: int) -> int:
         total = self.seen_tokens + new_tokens

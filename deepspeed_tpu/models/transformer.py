@@ -144,6 +144,35 @@ class TransformerConfig:
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
     rope_interleave: bool = False
+    # a per-layer PATTERN of mixers (served only): with
+    # ``linear_attn_period`` p > 0 layer i keeps ``attention`` (which has
+    # to be "mla") where (i + 1) % p == 0 and is a LINEAR-attention layer
+    # otherwise (Kimi Delta Attention, arXiv:2510.26692: ``num_heads``
+    # heads of ``linear_head_dim`` keys and values, a causal depthwise
+    # convolution of ``linear_conv_size`` taps on q, k and v, a decay a
+    # head and key channel of ``linear_decay_floor`` * sigmoid(.), and a
+    # recurrent float32 state [linear_head_dim, linear_head_dim] a head
+    # in place of cached positions). ``attn_gate`` "head" multiplies each
+    # head's output by sigmoid of one projection of the layer's input
+    # before ``wo``, on both mixers. ``q_lora_rank`` 0 under "mla": the
+    # query is one projection, without the bottleneck and its norm
+    linear_attn_period: int = 0
+    linear_head_dim: int = 0
+    linear_conv_size: int = 4
+    linear_decay_floor: float = -5.0
+    attn_gate: str = "none"
+    # the group limit of the deployed router (DeepSeek-V3 noaux_tc): the
+    # experts form ``moe_n_group`` groups, a group scores the sum of its
+    # best two, and the top k are chosen inside the best
+    # ``moe_topk_group`` groups (1 / 1: no limit)
+    moe_n_group: int = 1
+    moe_topk_group: int = 1
+    # the chip's share of an expert layer (expert parallelism's cut, on
+    # one chip): the router scores all ``moe_num_experts``, this tree
+    # HOLDS ``moe_experts_held`` of them from index ``moe_experts_first``
+    # (0: all), and a pick that is held elsewhere adds nothing here
+    moe_experts_held: int = 0
+    moe_experts_first: int = 0
 
     # training objective: "causal_lm" (next-token, causal attention) or
     # "mlm" (BERT-family masked-LM: bidirectional attention, loss at the
@@ -192,14 +221,16 @@ class TransformerConfig:
             raise ValueError(
                 f"attention must be 'mha' or 'mla', got {self.attention!r}")
         if self.attention == "mla":
-            sizes = (self.q_lora_rank, self.kv_lora_rank,
-                     self.qk_nope_head_dim, self.qk_rope_head_dim,
-                     self.v_head_dim)
-            if min(sizes) <= 0 or self.qk_rope_head_dim % 2:
+            sizes = (self.kv_lora_rank, self.qk_nope_head_dim,
+                     self.qk_rope_head_dim, self.v_head_dim)
+            if min(sizes) <= 0 or self.qk_rope_head_dim % 2 \
+                    or self.q_lora_rank < 0:
                 raise ValueError(
-                    f"attention='mla' needs q_lora_rank, kv_lora_rank, "
+                    f"attention='mla' needs kv_lora_rank, "
                     f"qk_nope_head_dim, an even qk_rope_head_dim and "
-                    f"v_head_dim, got {sizes}")
+                    f"v_head_dim (and q_lora_rank, or 0 for a query "
+                    f"without the bottleneck), got "
+                    f"{(self.q_lora_rank, *sizes)}")
             if (self.positional != "rope" or self.norm != "rmsnorm"
                     or self.attn_bias or not self.is_causal
                     or self.norm_scheme != "pre" or self.parallel_residual
@@ -214,9 +245,47 @@ class TransformerConfig:
             raise ValueError(
                 f"moe_first_dense_layers={self.moe_first_dense_layers} "
                 f"leaves no expert layer of {self.num_layers}")
+        if self.attn_gate not in ("none", "head"):
+            raise ValueError(f"attn_gate must be 'none' or 'head', got "
+                             f"{self.attn_gate!r}")
+        if self.linear_attn_period < 0 or (
+                self.linear_attn_period
+                and (self.attention != "mla" or self.linear_head_dim <= 0
+                     or self.linear_conv_size < 2
+                     or self.linear_decay_floor >= 0)):
+            raise ValueError(
+                f"a layer pattern (linear_attn_period="
+                f"{self.linear_attn_period}) needs attention='mla' for "
+                f"the layers it leaves, linear_head_dim > 0, "
+                f"linear_conv_size >= 2 and linear_decay_floor < 0")
+        if self.attn_gate != "none" and self.attention != "mla":
+            raise NotImplementedError(
+                "attn_gate is served with attention='mla' (and the "
+                "linear layers of its pattern) only")
+        E, G = self.moe_num_experts, self.moe_n_group
+        if G < 1 or not 1 <= self.moe_topk_group <= G or (
+                G > 1 and (E % G or E // G < 2
+                           or self.moe_topk_group * (E // G)
+                           < self.moe_top_k)):
+            raise ValueError(
+                f"moe_n_group={G} / moe_topk_group={self.moe_topk_group} "
+                f"do not divide {E} experts into groups of two or more "
+                f"that hold the top {self.moe_top_k}")
+        if self.moe_experts_held < 0 or self.moe_experts_first < 0 \
+                or self.moe_experts_first + self.moe_experts_held > E:
+            raise ValueError(
+                f"moe_experts_held={self.moe_experts_held} from "
+                f"{self.moe_experts_first} lie outside {E} experts")
+        if (self.moe_experts_held or self.moe_experts_first) and (
+                self.attention != "mla"
+                or not 0 < self.moe_experts_held):
+            raise NotImplementedError(
+                "a share of the experts (moe_experts_held > 0 from "
+                "moe_experts_first) is served by the attention='mla' "
+                "block's expert layer only")
         if self.moe_first_dense_layers and (self.moe_num_experts == 0
                                             or self.attention != "mla"):
-            # the two-stack scan lives in paged_model._latent_step
+            # the runs' scans live in paged_model._pattern_step
             raise NotImplementedError(
                 "leading dense layers (moe_first_dense_layers) are served "
                 "for an MoE model with attention='mla' only")
@@ -249,6 +318,12 @@ class TransformerConfig:
         and this module's own forwards refuse it by this line."""
         what = [name for name, on in (
             ("attention='mla'", self.attention == "mla"),
+            ("linear_attn_period (linear-attention layers and their "
+             "recurrent state)", self.linear_attn_period > 0),
+            ("attn_gate", self.attn_gate != "none"),
+            ("moe_n_group", self.moe_n_group > 1),
+            ("moe_experts_held (a share of the experts)",
+             self.experts_held < self.moe_num_experts),
             ("moe_scoring='sigmoid'", self.moe_scoring != "softmax"),
             ("moe_selection_bias", self.moe_selection_bias),
             ("moe_shared_experts", self.moe_shared_experts > 0),
@@ -266,6 +341,24 @@ class TransformerConfig:
     def expert_size(self) -> int:
         """Width of one routed (or shared) expert."""
         return self.moe_intermediate_size or self.intermediate_size
+
+    @property
+    def experts_held(self) -> int:
+        """Routed experts whose weights this tree holds."""
+        return self.moe_experts_held or self.moe_num_experts
+
+    @property
+    def layer_kinds(self) -> tuple:
+        """The mixer of every layer, in order: "kda" (linear attention)
+        or ``attention``."""
+        p = self.linear_attn_period
+        return tuple("kda" if p and (i + 1) % p else self.attention
+                     for i in range(self.num_layers))
+
+    @property
+    def has_state(self) -> bool:
+        """Whether a sequence owns recurrent state beside its blocks."""
+        return "kda" in self.layer_kinds
 
     @property
     def latent_row(self) -> int:
@@ -676,7 +769,11 @@ class TransformerLM:
         attention's leaves; ``lead_layers`` [moe_first_dense_layers, ...]
         with a dense gated MLP apart from ``layers`` [the rest, ...],
         the scanned expert stack (every layer, where the model has no
-        experts or no leading dense layer)."""
+        experts or no leading dense layer). Under a layer pattern
+        (``linear_attn_period``) the mixers leave those two stacks for
+        one of their own a kind, ``kda_layers`` and ``mla_layers``, each
+        in layer order; an expert stack holds ``cfg.experts_held``
+        experts under a router of ``moe_num_experts``."""
         cfg, dt = self.cfg, jnp.float32
         h, v, nh = cfg.hidden_size, cfg.vocab_size, cfg.num_heads
         L, std = cfg.num_layers, 0.02
@@ -686,20 +783,53 @@ class TransformerLM:
             return (jax.random.normal(key, shape, jnp.float32)
                     * scale).astype(dt)
 
-        def attention(key, n):
+        def attention(key, n, mlp_norm=True):
+            # five keys as before the gate came: the leaves that were
+            # there are seeded as they were; the gate folds one in
             ks = jax.random.split(key, 5)
             qk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
-            return {
-                "attn_norm": jnp.ones((n, h), dt),
-                "wq_a": init(ks[0], (n, h, cfg.q_lora_rank)),
-                "q_norm": jnp.ones((n, cfg.q_lora_rank), dt),
-                "wq_b": init(ks[1], (n, cfg.q_lora_rank, nh * qk)),
+            query = {"wq_a": init(ks[0], (n, h, cfg.q_lora_rank)),
+                     "q_norm": jnp.ones((n, cfg.q_lora_rank), dt),
+                     "wq_b": init(ks[1], (n, cfg.q_lora_rank, nh * qk))} \
+                if cfg.q_lora_rank else {"wq": init(ks[0], (n, h, nh * qk))}
+            out = {
+                "attn_norm": jnp.ones((n, h), dt), **query,
                 "wkv_a": init(ks[2], (n, h, cfg.latent_row)),
                 "kv_norm": jnp.ones((n, cfg.kv_lora_rank), dt),
                 "wkv_b": init(ks[3], (n, cfg.kv_lora_rank, nh * (
                     cfg.qk_nope_head_dim + cfg.v_head_dim))),
-                "wo": init(ks[4], (n, nh * cfg.v_head_dim, h), out_std),
-                "mlp_norm": jnp.ones((n, h), dt)}
+                "wo": init(ks[4], (n, nh * cfg.v_head_dim, h), out_std)}
+            if cfg.attn_gate == "head":
+                out["wg"] = init(jax.random.fold_in(key, 5), (n, h, nh))
+            if mlp_norm:
+                out["mlp_norm"] = jnp.ones((n, h), dt)
+            return out
+
+        def linear(key, n):
+            """A linear-attention (KDA) mixer's leaves: q, k, v and the
+            decay's projection ``wf`` to ``nh * linear_head_dim``, the
+            update strength ``wb`` and the output gate ``wg`` one a
+            head, the depthwise taps ``conv`` [taps, q | k | v], the
+            decay's ``a_log`` a head and ``dt_bias`` a channel (float32
+            in the checkpoint), the heads' output norm ``o_norm``."""
+            ks = jax.random.split(key, 9)
+            d = nh * cfg.linear_head_dim
+            out = {"attn_norm": jnp.ones((n, h), dt),
+                   "wq": init(ks[0], (n, h, d)),
+                   "wk": init(ks[1], (n, h, d)),
+                   "wv": init(ks[2], (n, h, d)),
+                   "wf": init(ks[3], (n, h, d)),
+                   "wb": init(ks[4], (n, h, nh)),
+                   "conv": init(ks[5], (n, cfg.linear_conv_size, 3 * d),
+                                cfg.linear_conv_size ** -0.5),
+                   "a_log": jnp.log(jax.random.uniform(
+                       ks[6], (n, nh), dt, 1.0, 16.0)),
+                   "dt_bias": jnp.zeros((n, d), dt),
+                   "o_norm": jnp.ones((n, cfg.linear_head_dim), dt),
+                   "wo": init(ks[7], (n, d, h), out_std)}
+            if cfg.attn_gate == "head":
+                out["wg"] = init(ks[8], (n, h, nh))
+            return out
 
         def dense(key, n):
             ks, ffn = jax.random.split(key, 3), cfg.intermediate_size
@@ -710,25 +840,43 @@ class TransformerLM:
         def experts(key, n):
             ks, E, f = jax.random.split(key, 5), cfg.moe_num_experts, \
                 cfg.expert_size
+            held = cfg.experts_held
             return {"moe_gate_w": init(ks[0], (n, h, E)),
-                    "e_gate": init(ks[1], (n, E, h, f)),
-                    "e_up": init(ks[2], (n, E, h, f)),
-                    "e_down": init(ks[3], (n, E, f, h), out_std),
+                    "e_gate": init(ks[1], (n, held, h, f)),
+                    "e_up": init(ks[2], (n, held, h, f)),
+                    "e_down": init(ks[3], (n, held, f, h), out_std),
                     **self._init_deployed_router(ks[4], n, init, out_std)}
 
         k = jax.random.split(rng, 7)
         lead = cfg.moe_first_dense_layers
+        mlp = experts if cfg.moe_num_experts else dense
         params = {"embed": init(k[0], (v, h)),
-                  "final_norm": jnp.ones((h,), dt),
-                  "layers": {**attention(k[1], L - lead),
-                             **(experts(k[2], L - lead)
-                                if cfg.moe_num_experts else
-                                dense(k[2], L - lead))}}
+                  "final_norm": jnp.ones((h,), dt)}
+        if not cfg.tie_embeddings:
+            params["lm_head"] = init(k[5], (h, v))
+        if cfg.linear_attn_period:
+            # a stack a mixer kind (``cfg.layer_kinds``), and the MLPs
+            # apart: ``lead_layers`` / ``layers`` hold the norm and the
+            # MLP of the leading dense and of the other layers
+            kinds = cfg.layer_kinds
+            params["kda_layers"] = linear(jax.random.fold_in(rng, 7),
+                                          kinds.count("kda"))
+            if "mla" in kinds:
+                params["mla_layers"] = attention(
+                    jax.random.fold_in(rng, 8), kinds.count("mla"),
+                    mlp_norm=False)
+            params["layers"] = {"mlp_norm": jnp.ones((L - lead, h), dt),
+                                **mlp(k[2], L - lead)}
+            if lead:
+                params["lead_layers"] = {
+                    "mlp_norm": jnp.ones((lead, h), dt),
+                    **dense(k[4], lead)}
+            return params
+        params["layers"] = {**attention(k[1], L - lead),
+                            **mlp(k[2], L - lead)}
         if lead:
             params["lead_layers"] = {**attention(k[3], lead),
                                      **dense(k[4], lead)}
-        if not cfg.tie_embeddings:
-            params["lm_head"] = init(k[5], (h, v))
         return params
 
     # -- sharding (TP over "model", PP over "pipe"; ZeRO composes on top) --

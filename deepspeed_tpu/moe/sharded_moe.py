@@ -263,22 +263,37 @@ def gmm_serves(expert_params) -> bool:
 
 
 def topk_routing(logits, k: int, scoring: str = "softmax", bias=None,
-                 normalize: bool = True, scale: float = 1.0):
+                 normalize: bool = True, scale: float = 1.0,
+                 n_group: int = 1, topk_group: int = 1):
     """Router logits [T, E] (float32) -> (chosen experts [T, k], their
     weights [T, k]), as served. ``scoring`` makes a logit a score
     (softmax over the experts, or an independent sigmoid); ``bias`` [E]
     is added to the scores to CHOOSE the top k and is no part of a
     weight (DeepSeek-V3's ``noaux_tc``: the bias balances load without a
-    loss term; its group limit with one group of experts is the identity
-    and is not written here); the k > 1 chosen weights are normalised
-    over the chosen set where ``normalize`` (top-1 keeps its raw score:
-    top1gating's g1), then scaled."""
+    loss term). Its group limit: the experts form ``n_group`` groups of
+    consecutive indices, a group scores the sum of its best two (bias
+    included), and the top k are chosen inside the best ``topk_group``
+    groups (``n_group`` 1: one group, every expert stands). The k > 1
+    chosen weights are normalised over the chosen set where
+    ``normalize`` (top-1 keeps its raw score: top1gating's g1), then
+    scaled."""
     scores = (jax.nn.softmax(logits, axis=-1) if scoring == "softmax"
               else jax.nn.sigmoid(logits))
-    if bias is None:
+    plain = bias is None and n_group == 1    # the scores choose as they are
+    choose = scores if bias is None else scores + bias.astype(scores.dtype)
+    if n_group > 1:
+        T, E = choose.shape
+        grouped = choose.reshape(T, n_group, E // n_group)
+        best2, _ = jax.lax.top_k(grouped, 2)
+        _, kept = jax.lax.top_k(jnp.sum(best2, axis=-1), topk_group)
+        stands = jnp.zeros((T, n_group), bool).at[
+            jnp.arange(T)[:, None], kept].set(True)
+        choose = jnp.where(stands[:, :, None], grouped,
+                           -jnp.inf).reshape(T, E)
+    if plain:
         topv, topi = jax.lax.top_k(scores, k)
     else:
-        _, topi = jax.lax.top_k(scores + bias.astype(scores.dtype), k)
+        _, topi = jax.lax.top_k(choose, k)
         topv = jnp.take_along_axis(scores, topi, axis=-1)
     if k > 1 and normalize:
         total = jnp.sum(topv, axis=-1, keepdims=True)
@@ -290,7 +305,8 @@ def topk_routing(logits, k: int, scoring: str = "softmax", bias=None,
 
 
 def dropless_topk_dispatch(xt, topi, topv, expert_params, num_experts: int,
-                           ragged_expert_fn=None, stack_layer=None):
+                           ragged_expert_fn=None, stack_layer=None,
+                           held_from=None):
     """Sorted-token grouped-GEMM core shared by the training dropless MoE
     and the v2 serving path (_moe_mlp): route every (token, choice) row to
     its expert with one argsort + `jax.lax.ragged_dot`, unsort, and weight
@@ -300,12 +316,23 @@ def dropless_topk_dispatch(xt, topi, topv, expert_params, num_experts: int,
     scanned stack's, [L, E, ...], and the layer is chosen by WHERE its
     groups lie among L * E (every other group is empty), so that the
     grouped matmul reads the stack in place: a layer sliced out of the
-    stack for a custom call is a copy of all its experts."""
+    stack for a custom call is a copy of all its experts.
+
+    ``held_from`` (an int; None: ``expert_params`` hold every expert the
+    router scores): they hold ``num_experts`` of them, the router's
+    ``held_from`` .. (expert parallelism's share on one chip). A pick
+    outside them sorts behind every group, is computed by no expert and
+    adds nothing."""
     T, H = xt.shape
     k = topi.shape[-1]
     idx = topi.reshape(-1)                       # [T*k], token-major
+    if held_from is not None:
+        idx = idx - held_from
+        held = (idx >= 0) & (idx < num_experts)
+        idx = jnp.where(held, idx, num_experts)
     order = jnp.argsort(idx)                     # stable
     xs = xt[order // k]                          # row t*k+j <-> (token t, j)
+    # (a pick held elsewhere has index num_experts: counted in no group)
     group_sizes = jnp.bincount(idx, length=num_experts).astype(jnp.int32)
     if stack_layer is not None:
         L = expert_params[0].shape[0]
@@ -317,6 +344,9 @@ def dropless_topk_dispatch(xt, topi, topv, expert_params, num_experts: int,
     fn = ragged_expert_fn or ragged_swiglu_experts
     ys = fn(expert_params, xs, group_sizes)      # [T*k, H]
     ys = jnp.zeros_like(ys).at[order].set(ys)    # unsort
+    if held_from is not None:
+        # rows past the last group hold whatever the grouped matmul left
+        ys = jnp.where(held[:, None], ys, 0)
     return jnp.sum(ys.reshape(T, k, H) * topv[..., None].astype(ys.dtype),
                    axis=1)
 

@@ -141,12 +141,18 @@ _SERVE_PHASE_OF_SCOPE = {
     "kv_write": "kv_write", "attn_kernel": "attn_kernel",
     "mlp": "mlp", "dense_mlp": "mlp", "moe_shared_expert": "mlp",
     "moe_router": "router", "moe_experts": "experts",
-    "head": "head", "pick": "pick"}
+    "head": "head", "pick": "pick",
+    # a linear-attention layer: its projections, convolution, norm and
+    # gate are ``linear``; the recurrence is a phase a form
+    "linear_attention": "linear", "kda_proj": "linear",
+    "kda_conv": "linear", "kda_out": "linear",
+    "kda_chunk": "linear_chunk", "kda_state": "linear_state"}
 _SERVE_SCOPE_WORD = re.compile(
     r"\b(" + "|".join(sorted(_SERVE_PHASE_OF_SCOPE, key=len, reverse=True))
     + r")\b")
 SERVE_PHASES = ("embed", "attn_proj", "kv_write", "attn_kernel", "mlp",
-                "router", "experts", "head", "pick", "other")
+                "router", "experts", "head", "pick", "linear",
+                "linear_chunk", "linear_state", "other")
 
 
 def serve_phase(op_name: str) -> str:

@@ -1,0 +1,650 @@
+"""The ``bailing_hybrid`` block served: linear-attention (KDA) layers
+with a recurrent state a sequence beside one latent layer a period, a
+per-layer pattern of parameter stacks and cache leaves, a group-limited
+router and an expert layer that holds a SHARE of the experts, at toy
+widths on the CPU, against the benchmark's plain reference
+(``benchmark/reference_ling.py``: float32, a token at a time through the
+recurrence, no cache).
+
+Tolerances. A float32 engine differs from the reference by the order of
+its sums, the absorbed latent form and the chunked form of the
+recurrence (a triangular solve a chunk in place of 64 rank-one updates):
+2e-5 of the largest logit is twenty times what it reads (4e-7 to 7e-7).
+A bf16 engine rounds every activation to 8 bits: the other blocks' toy
+limit, 4e-2, on a seed whose routing the rounding does not flip (it
+reads 3e-3 to 1e-2). Two forms of one recurrence, both float32: 2e-5 of
+the largest output (they read 2e-6).
+"""
+
+import json
+from pathlib import Path
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from benchmark import reference_ling, weights_ling
+from benchmark import run as harness
+from deepspeed_tpu.inference.v2 import InferenceEngineV2, paged_model
+from deepspeed_tpu.inference.v2.kernels import linear_attention as la
+from deepspeed_tpu.inference.v2.paged_model import init_paged_kv_cache
+from deepspeed_tpu.inference.v2.ragged.ragged_manager import DSStateManager
+from deepspeed_tpu.inference.v2.config_v2 import DSStateManagerConfig
+from deepspeed_tpu.models import TransformerConfig, TransformerLM
+from deepspeed_tpu.moe.sharded_moe import topk_routing
+from deepspeed_tpu.telemetry import get_registry
+
+REPO = Path(__file__).resolve().parents[3]
+CONFIG = json.loads(
+    (REPO / "benchmark/configs/ling-3.0-flash.json").read_text())
+TOY = harness.merge(CONFIG["fields"], CONFIG["toy_fields"])
+F32_TIGHT, BF16_LIMIT = 2e-5, 4e-2
+SEED = 5
+
+
+def _engine(dtype="float32", fields=TOY, seqs=4, **engine):
+    cfg = TransformerConfig(**fields)
+    return InferenceEngineV2(TransformerLM(cfg), {
+        "dtype": dtype, "use_paged_kernel": True, "decode_window": 4,
+        **engine,
+        "state_manager": {"max_tracked_sequences": seqs,
+                          "max_ragged_batch_size": 256, "max_seq_len": 256,
+                          "block_size": 16, "num_blocks": 60}},
+        params=weights_ling.make(fields, SEED, dtype))
+
+
+def _prompts(lengths=(20, 70, 5), seed=0):
+    rng = np.random.default_rng(seed)
+    return [rng.integers(0, TOY["vocab_size"], n) for n in lengths]
+
+
+def _reference(prompt, fields=TOY):
+    return np.asarray(reference_ling.logits(
+        weights_ling.make(fields, SEED, "float32"), fields, prompt))
+
+
+def _err(got, want):
+    return float(np.abs(np.asarray(got, np.float32) - want).max()
+                 / np.abs(want).max())
+
+
+# ---------------------------------------------------------------------------
+# (a) the engine against the plain reference
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("dtype,limit", [("float32", F32_TIGHT),
+                                         ("bfloat16", BF16_LIMIT)])
+def test_put_logits_match_the_reference(dtype, limit):
+    """Rows of 20, 70 (two chunks of the chunked form) and 5 tokens in
+    one ragged step."""
+    eng = _engine(dtype)
+    assert eng.attention_impl == "pallas:latent" and eng._has_state
+    prompts = _prompts()
+    got = eng.put([0, 1, 2], prompts)
+    for i, p in enumerate(prompts):
+        assert _err(got[i], _reference(p)[-1]) <= limit, i
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_decode_through_pool_and_state_matches_the_reference(dtype):
+    """The ragged step leaves each row's state in its slot and its
+    latent rows in the pool; decode windows of 4 (launched one behind
+    the other: the state rides the cache) read and extend both. float32:
+    at EVERY generated position the engine's token is the reference's
+    best on the same prefix, so a state, a slot or a conv tap read wrong
+    shows. bf16: the served token's reference logit lies within the
+    bf16 limit of the best."""
+    eng = _engine(dtype)
+    prompts = _prompts()
+    outs = eng.generate(prompts, max_new_tokens=13, temperature=0.0,
+                        eos_token_id=None)
+    assert get_registry().family_total(
+        "inference_decode_windows_ahead_total") > 0
+    for prompt, out in zip(prompts, outs):
+        out = np.asarray(out)
+        assert len(out) == len(prompt) + 13
+        ref = _reference(out[:-1])[len(prompt) - 1:]
+        if dtype == "float32":
+            np.testing.assert_array_equal(out[len(prompt):], ref.argmax(-1))
+        else:
+            served = ref[np.arange(len(ref)), out[len(prompt):]]
+            gap = (ref.max(-1) - served) / np.abs(ref).max(-1)
+            assert gap.max() <= BF16_LIMIT
+
+
+def test_a_state_kept_in_bfloat16_is_the_lower_precision_control():
+    """``state_dtype`` bfloat16 rounds the state at every token: a
+    float32 engine with it reads far over the float32 limit after a few
+    dozen tokens, and the state leaves are half the bytes."""
+    prompts = _prompts((70,))
+    want = _reference(prompts[0])[-1]
+    sound, control = _engine("float32"), \
+        _engine("float32", state_dtype="bfloat16")
+    assert control.kv_cache["kda_state"].dtype == jnp.bfloat16
+    assert sound.kv_cache["kda_state"].dtype == jnp.float32
+    err = _err(control.put([0], prompts)[0], want)
+    assert err > 20 * F32_TIGHT, err
+    assert _err(sound.put([0], prompts)[0], want) <= F32_TIGHT
+
+
+def test_rows_in_one_step_are_the_rows_served_alone():
+    """Rows of unequal lengths in one ragged step, then a MIXED step (a
+    new prompt beside the first rows' decode tokens), give each row what
+    it gets served alone: rows mix nowhere, not in the convolution (a
+    row's taps stop at its first token), not in the chunks."""
+    prompts = _prompts((33, 64, 7))
+    late = _prompts((41,), seed=3)[0]
+    nxt = [11, 22, 33]
+    eng = _engine("float32")
+    first = eng.put([0, 1, 2], prompts)
+    mixed = eng.put([0, 1, 2, 3], [[t] for t in nxt] + [late])
+    for i, p in enumerate(prompts):
+        alone = _engine("float32")
+        a = alone.put([7], [p])
+        assert _err(first[i], np.asarray(a[0])) <= F32_TIGHT
+        b = alone.put([7], [[nxt[i]]])          # continues from its slot
+        assert _err(mixed[i], np.asarray(b[0])) <= F32_TIGHT
+    alone = _engine("float32")
+    assert _err(mixed[3], np.asarray(alone.put([9], [late])[0])) \
+        <= F32_TIGHT
+    # and against the reference: the decode token's logits
+    want = _reference(np.append(prompts[1], nxt[1]))[-1]
+    assert _err(mixed[1], want) <= F32_TIGHT
+
+
+def test_a_row_continues_from_its_slot():
+    """A prompt fed in two put()s of 50 and 37 tokens (the second starts
+    mid-chunk from the slot's state and the slot's last three conv
+    inputs) is the prompt fed at once."""
+    prompt = _prompts((87,))[0]
+    eng = _engine("float32")
+    eng.put([4], [prompt[:50]])
+    got = eng.put([4], [prompt[50:]])
+    assert _err(got[0], _reference(prompt)[-1]) <= F32_TIGHT
+    # one token at a time from the third on: the ragged step's rows of one
+    eng = _engine("float32")
+    eng.put([4], [prompt[:3]])
+    eng.put([4], [prompt[3:4]])
+    eng.put([4], [prompt[4:6]])
+    got = eng.put([4], [prompt[6:]])
+    assert _err(got[0], _reference(prompt)[-1]) <= F32_TIGHT
+
+
+def test_a_kept_sequence_holds_the_references_state():
+    """``generate(keep_sequences=True)`` leaves its rows tracked, every
+    token but the last fed; ``sequence_state`` reads a row's slot: the
+    leading linear layers' states are the reference's after the same
+    tokens, and the row goes on from there through ``put()``."""
+    eng = _engine("float32")
+    prompts = _prompts((20, 70))
+    outs = eng.generate(prompts, max_new_tokens=9, temperature=0.0,
+                        eos_token_id=None, keep_sequences=True)
+    assert eng.state_manager.state_slots_in_use() == 2
+    params = weights_ling.make(TOY, SEED, "float32")
+    for uid, out in enumerate(outs):
+        assert eng.query(uid)["seen_tokens"] == len(out) - 1
+        state = eng.sequence_state(uid)
+        assert state["kda_state"].shape == (7, 4, 16, 16)
+        assert state["kda_conv"].shape == (7, 3, 3 * 4 * 16)
+        want = np.asarray(reference_ling.leading_states(
+            params, TOY, out[:-1]))
+        assert want.shape == (3, 4, 16, 16)     # layers 0, 1 and 2
+        assert _err(state["kda_state"][:3], want) <= F32_TIGHT
+        # one token more than was fed: a different state
+        assert _err(state["kda_state"][:3], np.asarray(
+            reference_ling.leading_states(params, TOY, out))) > 1e-3
+    # the row continues from its slot with the token that was not fed
+    nxt = eng.put([0], [outs[0][-1:]])
+    assert _err(nxt[0], _reference(outs[0])[-1]) <= F32_TIGHT
+    for uid in (0, 1):
+        eng.flush(uid)
+    assert eng.state_manager.state_slots_in_use() == 0
+    with pytest.raises(KeyError, match="not tracked"):
+        eng.sequence_state(0)
+
+
+def test_a_call_that_raises_keeps_nothing():
+    eng = _engine("float32")
+    with pytest.raises(RuntimeError, match="not schedulable"):
+        eng.generate(_prompts((250,)), max_new_tokens=20, temperature=0.0,
+                     eos_token_id=None, keep_sequences=True)
+    assert eng.state_manager.state_slots_in_use() == 0
+
+
+def test_slots_are_freed_and_reused_without_leaking_state():
+    """Two tracked sequences at most: a slot changes hands at flush and
+    is NOT cleared; its next owner's first token starts from zeros."""
+    eng = _engine("float32", seqs=2)
+    sm = eng.state_manager
+    a, b, c = _prompts((40, 25, 31), seed=9)
+    eng.put([0, 1], [a, b])
+    slots = {sm.seqs[u].state_slot for u in (0, 1)}
+    assert slots == {1, 2} and sm.state_slots_in_use() == 2
+    with pytest.raises(RuntimeError, match="not schedulable"):
+        eng.put([2], [c])
+    eng.flush(0)
+    assert sm.state_slots_in_use() == 1
+    dirty = np.asarray(eng.kv_cache["kda_state"])
+    assert np.abs(dirty[:, 1:]).max() > 0       # what the old rows left
+    got = eng.put([2], [c])
+    assert sm.seqs[2].state_slot in slots
+    assert _err(got[0], _reference(c)[-1]) <= F32_TIGHT
+    eng.flush(1), eng.flush(2)
+    assert sm.state_slots_in_use() == 0
+    reg = get_registry()
+    assert reg.get("inference_state_slots_in_use").value == 0
+    assert reg.get("inference_state_bytes").value == sum(
+        v.size * v.dtype.itemsize for k, v in eng.kv_cache.items()
+        if k.startswith("kda_"))
+
+
+def test_the_generation_loop_reuses_slots_across_calls():
+    eng = _engine("float32")
+    prompts = _prompts((9, 17))
+    first = eng.generate(prompts, max_new_tokens=6, temperature=0.0,
+                         eos_token_id=None)
+    again = eng.generate(prompts, max_new_tokens=6, temperature=0.0,
+                         eos_token_id=None)
+    for x, y in zip(first, again):
+        np.testing.assert_array_equal(x, y)
+    assert eng.state_manager.state_slots_in_use() == 0
+
+
+# ---------------------------------------------------------------------------
+# (b) the two forms of the recurrence, and the convolution
+# ---------------------------------------------------------------------------
+def _kda_rows(lengths, nh=2, d=16, seed=0):
+    rng = np.random.default_rng(seed)
+    T = int(np.ceil((sum(lengths) + 5) / 64) * 64)
+
+    def rnd(*s):
+        return rng.standard_normal(s).astype(np.float32)
+
+    q, k = rnd(T, nh, d), rnd(T, nh, d)
+    q /= np.linalg.norm(q, axis=-1, keepdims=True)
+    k /= np.linalg.norm(k, axis=-1, keepdims=True)
+    g = -5.0 * np.asarray(jax.nn.sigmoid(3 * rnd(T, nh, d)))
+    beta = np.asarray(jax.nn.sigmoid(rnd(T, nh)))
+    starts = np.cumsum([0] + list(lengths[:-1])).astype(np.int32)
+    return (q, k, rnd(T, nh, d), g, beta), starts, \
+        np.asarray(lengths, np.int32)
+
+
+def test_the_chunked_form_is_the_one_token_form_over_a_row():
+    """Rows of 150 (three chunks, the last partial), 1, 70, 0 and 64
+    tokens from states that are not zero, decays down to the floor of -5
+    a token (64 of them would overflow ``exp(-G)``: the sub-block
+    factoring is what holds), in row blocks of two."""
+    tokens, starts, counts = _kda_rows((150, 1, 70, 0, 64, 3))
+    R = len(counts)
+    rng = np.random.default_rng(1)
+    leaf = rng.standard_normal((2, R + 1, 2, 16, 16)).astype(np.float32)
+    slots = jnp.arange(1, R + 1)
+    out, new = jax.jit(lambda *a: la.kda_chunked(
+        a[:5], lambda *t: t, a[5], 1, slots, jnp.zeros((R,), bool),
+        jnp.asarray(starts), jnp.asarray(counts), -5.0,
+        rows_a_step=2))(*tokens, leaf)
+    want_o = np.zeros_like(np.asarray(out))
+    for r, (s0, n) in enumerate(zip(starts, counts)):
+        state = jnp.asarray(leaf[1, r + 1][None])
+        for t in range(s0, s0 + n):
+            o, state = la.kda_step(*(a[t:t + 1] for a in tokens), state)
+            want_o[t] = o[0]
+        np.testing.assert_allclose(np.asarray(new)[1, r + 1], state[0],
+                                   atol=2e-5)
+    np.testing.assert_allclose(np.asarray(out), want_o, atol=2e-5)
+    np.testing.assert_array_equal(np.asarray(new)[0], leaf[0])  # its layer
+
+
+@pytest.mark.parametrize("kept", ["float32", "bfloat16"])
+def test_the_decode_kernel_is_the_one_token_form_in_place(kept):
+    """``kda_state_update`` under the TPU interpreter at the published
+    head width: rows at scattered slots of layer 1, two of them at their
+    first token (their slots' old content must not show), one at the
+    null slot; every other slot and layer comes back as it went in."""
+    rng = np.random.default_rng(0)
+    N, nh, d, L, S = 6, 16, 128, 2, 9
+
+    def rnd(*s):
+        return rng.standard_normal(s).astype(np.float32)
+
+    q, k, v = rnd(N, nh, d), rnd(N, nh, d), rnd(N, nh, d)
+    g = -5 * np.asarray(jax.nn.sigmoid(rnd(N, nh, d)))
+    beta = np.asarray(jax.nn.sigmoid(rnd(N, nh)))
+    leaf = jnp.asarray(rnd(L, S, nh, d, d), kept)
+    slots = np.array([3, 1, 7, 0, 5, 8])
+    fresh = np.array([0, 1, 0, 0, 0, 1], bool)
+    o, new = jax.jit(lambda *a: la.kda_state_update(*a, interpret=True))(
+        leaf, jnp.int32(1), jnp.asarray(slots), jnp.asarray(fresh),
+        q, k, v, g, beta)
+    assert new.dtype == leaf.dtype
+    old = np.asarray(leaf, np.float32)
+    start = np.where(fresh[:, None, None, None], 0, old[1, slots])
+    want_o, want_s = la.kda_step(q, k, v, g, beta, jnp.asarray(start))
+    tol = 1e-4 if kept == "float32" else 0.2    # a state rounded to 8 bits
+    np.testing.assert_allclose(o, want_o, atol=1e-4)
+    new = np.asarray(new, np.float32)
+    np.testing.assert_allclose(new[1, slots], want_s, atol=tol)
+    others = [i for i in range(S) if i not in slots]
+    np.testing.assert_array_equal(new[1, others], old[1, others])
+    np.testing.assert_array_equal(new[0], old[0])
+
+
+def test_a_fresh_row_starts_from_zeros_whatever_its_slot_holds():
+    tokens, starts, counts = _kda_rows((70, 20))
+    tokens = tuple(jnp.asarray(a) for a in tokens)
+    leaf = np.random.default_rng(2).standard_normal(
+        (1, 3, 2, 16, 16)).astype(np.float32)
+    run = jax.jit(lambda leaf, fresh: la.kda_chunked(
+        tokens, lambda *t: t, leaf, 0, jnp.asarray([1, 2]), fresh,
+        jnp.asarray(starts), jnp.asarray(counts), -5.0))
+    o_dirty, _ = run(leaf, jnp.asarray([True, False]))
+    o_clean, _ = run(leaf.copy() * np.asarray([1, 0, 1])[None, :, None,
+                                                        None, None],
+                     jnp.asarray([False, False]))
+    np.testing.assert_allclose(np.asarray(o_dirty), np.asarray(o_clean),
+                               atol=1e-6)
+
+
+def test_the_convolution_stops_at_a_rows_first_token():
+    """The flat form against the one-token form a row, rows of 150, 1,
+    2, 0 and 64 tokens, from conv states that are not zero."""
+    rng = np.random.default_rng(4)
+    lengths = (150, 1, 2, 0, 64)
+    T, D, K = 256, 6, 4
+    starts = np.cumsum([0] + list(lengths[:-1])).astype(np.int32)
+    x = rng.standard_normal((T, D)).astype(np.float32)
+    taps = rng.standard_normal((K, D)).astype(np.float32)
+    held = rng.standard_normal((len(lengths), K - 1, D)).astype(np.float32)
+    row_ids = np.zeros(T, np.int32)
+    for r, (s0, n) in enumerate(zip(starts, lengths)):
+        row_ids[s0:s0 + n] = r
+    y, new = la.causal_conv_rows(
+        jnp.asarray(x), jnp.asarray(taps), jnp.asarray(held),
+        jnp.asarray(row_ids), jnp.asarray(starts),
+        jnp.asarray(lengths, jnp.int32), jax.nn.silu)
+    for r, (s0, n) in enumerate(zip(starts, lengths)):
+        state = jnp.asarray(held[r:r + 1])
+        for t in range(s0, s0 + n):
+            yt, state = la.causal_conv_step(x[t:t + 1], taps, state,
+                                            jax.nn.silu)
+            np.testing.assert_allclose(y[t], yt[0], atol=1e-6)
+        np.testing.assert_allclose(new[r], state[0], atol=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# (c) the router's group limit and the share of the experts
+# ---------------------------------------------------------------------------
+def test_the_group_limit_against_a_plain_loop():
+    """16 experts in 4 groups, the best 2 groups kept, top 4: a token at
+    a time in plain Python."""
+    rng = np.random.default_rng(3)
+    logits = rng.normal(size=(40, 16)).astype(np.float32) * 1.5
+    bias = (rng.normal(size=(16,)) * 0.3).astype(np.float32)
+    chosen, weights = topk_routing(jnp.asarray(logits), 4, "sigmoid",
+                                   jnp.asarray(bias), True, 2.5,
+                                   n_group=4, topk_group=2)
+    scores = 1.0 / (1.0 + np.exp(-logits))
+    for t in range(40):
+        pick = scores[t] + bias
+        group = [np.sort(pick[g * 4:(g + 1) * 4])[-2:].sum()
+                 for g in range(4)]
+        kept = np.argsort(group)[-2:]
+        stands = [e for e in range(16) if e // 4 in kept]
+        want = sorted(stands, key=lambda e: -pick[e])[:4]
+        assert sorted(np.asarray(chosen)[t]) == sorted(want)
+        w = {int(e): float(v) for e, v in zip(np.asarray(chosen)[t],
+                                              np.asarray(weights)[t])}
+        total = sum(scores[t][e] for e in want)
+        for e in want:                          # weights: no bias
+            assert w[e] == pytest.approx(scores[t][e] / total * 2.5,
+                                         rel=1e-5)
+    # the reference's own routing is that loop too
+    ref_i, ref_w = reference_ling.route(
+        jnp.asarray(scores), jnp.asarray(bias),
+        dict(moe_n_group=4, moe_topk_group=2, moe_top_k=4,
+             moe_routed_scale=2.5))
+    np.testing.assert_array_equal(np.sort(ref_i, -1), np.sort(chosen, -1))
+    np.testing.assert_allclose(np.sort(ref_w, -1), np.sort(weights, -1),
+                               rtol=1e-6)
+
+
+def test_the_four_shares_add_up_to_the_uncut_layer():
+    """The guide's test of the cut: 16 experts over four chips of 4. Each
+    chip's expert layer (the program's, told which experts it holds)
+    routes over all 16 and computes its own experts' part and the shared
+    expert; the four parts, with the shared expert counted ONCE, are
+    what the uncut reference layer gives with all 16."""
+    fields = {**TOY, "moe_experts_held": 0, "moe_experts_first": 0}
+    whole = weights_ling.make(fields, SEED, "float32")["layers"]
+    assert whole["e_gate"].shape[1] == 16
+    rng = np.random.default_rng(0)
+    x = jnp.asarray(rng.standard_normal((37, TOY["hidden_size"])),
+                    jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        uncut = reference_ling._expert_mlp(x, whole, 1, fields) - x
+        small = {k: v[1] for k, v in whole.items()
+                 if k not in ("e_gate", "e_up", "e_down")}
+        hn = reference_ling._rms_norm(x, small["mlp_norm"],
+                                      fields["norm_eps"])
+        shared = reference_ling._swiglu(hn, small["shared_gate"],
+                                        small["shared_up"],
+                                        small["shared_down"])
+        parts, touched = [], 0
+        for chip in range(4):
+            cfg = TransformerConfig(**{**TOY, "moe_experts_held": 4,
+                                       "moe_experts_first": 4 * chip})
+            held = tuple(whole[k][:, 4 * chip:4 * chip + 4]
+                         for k in ("e_gate", "e_up", "e_down"))
+            out, topi = paged_model._moe_routed(
+                cfg, small, hn, held, 1,
+                router_precision=jax.lax.Precision.HIGHEST)
+            parts.append(out - shared)
+            stats = paged_model._moe_stats(
+                topi, jnp.ones((37,), bool), 4, 4 * chip)
+            touched += float(stats[1])
+            # the reference is given the same share
+            share = {**whole, **dict(zip(("e_gate", "e_up", "e_down"),
+                                         held))}
+            ref = reference_ling._expert_mlp(
+                x, share, 1, {**TOY, "moe_experts_held": 4,
+                              "moe_experts_first": 4 * chip}) - x
+            np.testing.assert_allclose(out, ref, atol=2e-5)
+        np.testing.assert_allclose(sum(parts) + shared, uncut, atol=2e-5)
+    assert touched == 37 * 4        # every pick is held by exactly one
+    assert max(float(jnp.abs(p).max()) for p in parts) > 0.01
+
+
+def test_a_share_takes_a_launch_of_any_length_in_runs(monkeypatch):
+    """A launch longer than a run goes through in runs, the last one
+    padded: 21 tokens in runs of 8 are the 21 tokens at once."""
+    cfg = TransformerConfig(**TOY)
+    lp = jax.tree.map(lambda a: a[1],
+                      weights_ling.make(TOY, SEED, "float32")["layers"])
+    x = jnp.asarray(np.random.default_rng(1).standard_normal(
+        (21, TOY["hidden_size"])), jnp.float32)
+    at_once, picks = paged_model._moe_routed(cfg, lp, x)
+    monkeypatch.setattr(paged_model, "_SHARE_TOKENS", 8)
+    in_runs, picks_runs = paged_model._moe_routed(cfg, lp, x)
+    np.testing.assert_array_equal(picks, picks_runs)
+    np.testing.assert_allclose(in_runs, at_once, atol=1e-6)
+    assert float(jnp.abs(at_once).max()) > 0.01
+
+
+def test_the_share_is_counted_over_held_experts_only():
+    topi = jnp.asarray([[0, 5, 6, 15], [4, 5, 7, 9]])
+    valid = jnp.asarray([True, True])
+    stats = np.asarray(paged_model._moe_stats(topi, valid, 4, 4))
+    assert list(stats[:3]) == [1.0, 5.0, 4.0]   # rows to 4..7; 4 touched
+    assert stats[3] == pytest.approx(2 / 5)     # expert 5 has two
+    whole = np.asarray(paged_model._moe_stats(topi, valid, 16))
+    assert list(whole[:3]) == [1.0, 8.0, 7.0]
+
+
+def test_the_engine_counts_held_rows():
+    reg = get_registry()
+    eng = _engine("float32")            # registers the families
+    before = reg.get("moe_routed_rows_total").labels(
+        program="ragged_step").value
+    rows0 = reg.get("inference_state_rows_total").labels(
+        program="ragged_step").value
+    eng.put([0, 1], _prompts((12, 8)))
+    routed = reg.get("moe_routed_rows_total").labels(
+        program="ragged_step").value - before
+    # 20 tokens x 4 picks x 6 expert layers, of which a share is held
+    assert 0 < routed < 20 * 4 * 6
+    assert reg.get("inference_state_rows_total").labels(
+        program="ragged_step").value - rows0 == 2
+
+
+# ---------------------------------------------------------------------------
+# (d) trees, leaves, runs
+# ---------------------------------------------------------------------------
+def test_the_tree_keeps_a_stack_a_layer_kind():
+    cfg = TransformerConfig(**TOY)
+    assert cfg.layer_kinds == ("kda",) * 5 + ("mla",) + ("kda",) * 2
+    assert cfg.has_state and cfg.experts_held == 4
+    tree = jax.eval_shape(TransformerLM(cfg).init_params,
+                          jax.random.PRNGKey(0))
+    assert sorted(tree) == ["embed", "final_norm", "kda_layers", "layers",
+                            "lead_layers", "lm_head", "mla_layers"]
+    assert tree["kda_layers"]["wq"].shape == (7, 64, 64)
+    assert tree["kda_layers"]["conv"].shape == (7, 4, 192)
+    assert tree["kda_layers"]["wg"].shape == (7, 64, 4)
+    assert tree["mla_layers"]["wq"].shape == (1, 64, 4 * 32)
+    assert "wq_a" not in tree["mla_layers"]
+    assert tree["layers"]["e_gate"].shape == (6, 4, 64, 32)
+    assert tree["layers"]["moe_gate_w"].shape == (6, 64, 16)
+    assert sorted(tree["lead_layers"]) == ["mlp_norm", "w_down", "w_gate",
+                                           "w_up"]
+    made = jax.eval_shape(lambda: weights_ling.make(TOY, 1, "float32"))
+    assert jax.tree.map(lambda a: a.shape, made) == jax.tree.map(
+        lambda a: a.shape, tree)
+    assert paged_model._layer_runs(cfg) == [
+        ("kda", False, 0, 2), ("kda", True, 2, 3), ("mla", True, 5, 1),
+        ("kda", True, 6, 2)]
+
+
+def test_the_cache_keeps_a_leaf_a_layer_kind():
+    cfg = TransformerConfig(**TOY)
+    cache = jax.eval_shape(lambda: init_paged_kv_cache(
+        cfg, 9, 16, jnp.bfloat16, state_slots=4))
+    assert cache["latent"].shape == (1, 9, 16, 128)      # latent layers
+    assert cache["kda_state"].shape == (7, 5, 4, 16, 16)  # by slot
+    assert cache["kda_conv"].shape == (7, 5, 3, 192)
+    assert cache["kda_state"].dtype == cache["kda_conv"].dtype \
+        == jnp.float32
+    assert cache["latent"].dtype == jnp.bfloat16
+
+
+def test_the_published_pattern_and_sizes():
+    """The configuration's fields: 8 layers, the latent one at 5; the
+    10.54 GB of the issue from the tree's shapes."""
+    cfg = TransformerConfig(**CONFIG["fields"])
+    assert cfg.layer_kinds == ("kda",) * 5 + ("mla",) + ("kda",) * 2
+    tree = jax.eval_shape(TransformerLM(cfg).init_params,
+                          jax.random.PRNGKey(0))
+    count = sum(int(np.prod(x.shape)) for x in jax.tree.leaves(tree))
+    assert 2 * count == pytest.approx(10.54e9, rel=1e-3)
+    cache = jax.eval_shape(lambda: init_paged_kv_cache(
+        cfg, 3201, 16, jnp.bfloat16, state_slots=128))
+    state = sum(int(np.prod(cache[k].shape)) * 4
+                for k in ("kda_state", "kda_conv"))
+    assert state / 129 == pytest.approx(15.0e6, rel=0.05)  # 15.0 MB a row
+
+
+def test_the_old_trees_are_what_they_were():
+    joyai = json.loads((REPO / "benchmark/configs/joyai-llm-flash.json")
+                       .read_text())
+    cfg = TransformerConfig(**harness.merge(joyai["fields"],
+                                            joyai["toy_fields"]))
+    assert not cfg.has_state and cfg.layer_kinds == ("mla",) * 3
+    assert paged_model._layer_runs(cfg) == [("mla", False, 0, 1),
+                                            ("mla", True, 1, 2)]
+    tree = jax.eval_shape(TransformerLM(cfg).init_params,
+                          jax.random.PRNGKey(0))
+    assert sorted(tree) == ["embed", "final_norm", "layers", "lead_layers",
+                            "lm_head"]
+    assert "wq_a" in tree["layers"] and "wg" not in tree["layers"]
+    cache = jax.eval_shape(lambda: init_paged_kv_cache(cfg, 5, 16,
+                                                       jnp.bfloat16))
+    assert set(cache) == {"latent"} and cache["latent"].shape[0] == 3
+    # and seeded as they were: the leaves that existed draw the keys
+    # they drew (sums read on the tree of commit 0c7ccb8, PRNGKey(3))
+    seeded = TransformerLM(cfg).init_params(jax.random.PRNGKey(3))
+    for path, want in ((("embed",), -1.3219291393684216),
+                       (("lm_head",), -3.9146002336599395),
+                       (("layers", "wq_a"), 0.6759318736699242),
+                       (("layers", "wo"), -0.8488620366340172),
+                       (("layers", "e_up"), -2.132007654832478),
+                       (("lead_layers", "wkv_b"), 1.00273535138831),
+                       (("lead_layers", "w_down"), 1.2854191246841415)):
+        leaf = seeded
+        for key in path:
+            leaf = leaf[key]
+        assert float(np.asarray(leaf, np.float64).sum()) \
+            == pytest.approx(want, abs=1e-6), path
+
+
+# ---------------------------------------------------------------------------
+# what is refused
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("engine,word", [
+    ({"tensor_parallel_size": 2}, "tensor_parallel_size"),
+    ({"expert_parallel_size": 2}, "expert-parallel"),
+    ({"quant_bits": 8}, "quant_bits"),
+    ({"max_lora_adapters": 2}, "max_lora_adapters"),
+    ({"ragged_attention": "off"}, "ragged_attention"),
+    ({"kv_quant": True}, "kv_quant"),
+    ({"state_manager": {"enable_prefix_caching": True}},
+     "no recurrent state"),
+    ({"state_manager": {"enable_prefix_caching": True,
+                        "enable_kv_spill": True}}, "no state slot")])
+def test_the_engine_refuses_at_construction(engine, word):
+    cfg = TransformerConfig(**TOY)
+    with pytest.raises((NotImplementedError, AssertionError), match=word):
+        InferenceEngineV2(TransformerLM(cfg), {"dtype": "float32", **engine})
+
+
+def test_what_else_is_refused():
+    cfg = TransformerConfig(**TOY)
+    eng = _engine("float32")
+    with pytest.raises(NotImplementedError, match="speculative"):
+        eng.generate(_prompts((4,)), max_new_tokens=2, speculative=True)
+    with pytest.raises(NotImplementedError, match="draft"):
+        eng.load_draft_model(TransformerLM(cfg))
+    with pytest.raises(NotImplementedError, match="state slot"):
+        eng.state_manager.adopt_sequence(5, 1, 3, [1, 2, 3])
+    with pytest.raises(NotImplementedError, match="linear_attn_period"):
+        TransformerLM(cfg).forward_hidden({}, jnp.zeros((1, 4), jnp.int32))
+    with pytest.raises(ValueError, match="state_dtype"):
+        InferenceEngineV2(TransformerLM(TransformerConfig(
+            vocab_size=64, hidden_size=32, intermediate_size=64,
+            num_layers=1, num_heads=4, max_seq_len=64)),
+            {"dtype": "float32", "state_dtype": "bfloat16"})
+    with pytest.raises(ValueError, match="state slots"):
+        DSStateManager(DSStateManagerConfig(max_tracked_sequences=8),
+                       state_slots=4)
+    plain = InferenceEngineV2(TransformerLM(TransformerConfig(
+        vocab_size=64, hidden_size=32, intermediate_size=64,
+        num_layers=1, num_heads=4, max_seq_len=64)), {"dtype": "float32"})
+    with pytest.raises(ValueError, match="keeps no recurrent state"):
+        plain.sequence_state(0)
+
+
+@pytest.mark.parametrize("change,word", [
+    ({"attention": "mha", "moe_first_dense_layers": 0,
+      "moe_experts_held": 0, "moe_experts_first": 0, "attn_gate": "none"},
+     "layer pattern"),
+    ({"linear_head_dim": 0}, "layer pattern"),
+    ({"linear_decay_floor": 0.5}, "layer pattern"),
+    ({"attn_gate": "channel"}, "attn_gate"),
+    ({"moe_n_group": 3}, "moe_n_group"),
+    ({"moe_topk_group": 5}, "moe_n_group"),
+    ({"moe_n_group": 8, "moe_topk_group": 1}, "moe_n_group"),
+    ({"moe_experts_first": 14}, "moe_experts_held"),
+    ({"q_lora_rank": -1}, "q_lora_rank")])
+def test_the_configuration_refuses_what_it_cannot_mean(change, word):
+    with pytest.raises((ValueError, NotImplementedError), match=word):
+        TransformerConfig(**{**TOY, **change})
