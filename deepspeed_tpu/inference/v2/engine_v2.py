@@ -44,6 +44,7 @@ from ...telemetry import trace, watchdog
 from ...utils.bucketing import ceil_bucket, pow2_bucket
 from ...utils.logging import log_dist
 from .config_v2 import RaggedInferenceEngineConfig
+from .kernels.linear_attention import chunk_kernel_serves
 from .kernels.ragged_attention import LATENT, kernel_variant
 from .paged_model import (init_lora_bank, init_paged_kv_cache,
                           paged_continue, paged_decode, paged_decode_window,
@@ -627,6 +628,12 @@ class InferenceEngineV2:
             "rows whose recurrent state a launch read and wrote, by "
             "program (a fused window counts a row once a step it may "
             "take)", labelnames=("program",))
+        self._m_chunk_kernel_steps = reg.counter(
+            "inference_linear_chunk_kernel_steps_total",
+            "ragged steps launched whose linear-attention layers ran "
+            "their chunked form as the kernel kda_chunk_fwd (0 for a "
+            "model without such layers, and where the backend or the "
+            "widths leave it to the XLA form)")
         self._m_spec_drafted = reg.counter(
             "inference_spec_drafted_tokens_total",
             "speculative tokens drafted for verification")
@@ -1834,6 +1841,9 @@ class InferenceEngineV2:
             if self._has_state:
                 self._m_state_rows.labels(program="ragged_step").inc(
                     len(entries))
+                if self._use_kernel and chunk_kernel_serves(
+                        self.kv_cache["kda_state"]):
+                    self._m_chunk_kernel_steps.inc()
             log_tokens = sm.config.enable_prefix_caching
             for uid, toks in entries:
                 seq = sm.seqs[uid]

@@ -10,12 +10,34 @@ value ``v``, log-decay ``g`` <= 0 a KEY CHANNEL and update strength
 
 * :func:`kda_step`: that update, one token a row (decode). Elementwise
   float32: a row's state read once and written once.
-* :func:`kda_chunked`: the same recurrence over a row's prompt tokens,
+* the CHUNKED form: the same recurrence over a row's prompt tokens,
   ``chunk`` (64) tokens at a time in matmuls, the state carried from
-  chunk to chunk. Rows of any lengths lie in the ragged step's flat
-  token buffer; step j of a ``while_loop`` takes chunk j of EVERY row
-  (gathered by ``starts`` / ``counts``), so the loop runs as many steps
-  as the longest row has chunks.
+  chunk to chunk. Rows of any lengths lie one after another in the
+  ragged step's flat token buffer.
+
+Each form has a Pallas kernel that works on the state leaf where it
+lies and an XLA implementation that is its reference in the tests:
+
+==========  ==========================  ================================
+form        kernel (a TPU, d_k and d_v  XLA (every other backend and
+            multiples of 128, heads in  width: the CPU tests, toy
+            whole grid steps)           widths)
+==========  ==========================  ================================
+one token   :func:`kda_state_update`    gather, :func:`kda_step`, scatter
+chunked     :func:`kda_chunk_fwd`       :func:`kda_chunked`
+==========  ==========================  ================================
+
+:func:`state_kernel_serves` / :func:`chunk_kernel_serves` say which
+runs, from the leaf's shape and ``jax.default_backend()`` alone: no
+option selects a form. :func:`kda_chunked` is a ``while_loop`` whose
+step j takes chunk j of a block of rows (gathered by ``starts`` /
+``counts``) from their slots and back. :func:`kda_chunk_fwd` is one
+launch a layer: a grid step takes one row and 8 of its heads, the
+heads' states come from the row's slot once, stay in VMEM over the row's
+chunks and go back once, and the tokens (the layer's bf16 projections,
+:func:`kda_inputs` run on them in VMEM) are copied from where they lie
+in the flat buffer and the outputs to where they go, a window of 64
+tokens at a time.
 
 The chunked form (the WY representation of the delta rule): with ``G``
 the cumulative log-decay inside a chunk and ``A[i, j] = sum_c k_i[c]
@@ -26,7 +48,11 @@ lower-triangular solve; then ``o = (Q exp(G)) S_0 + tril(QK) u`` and
 overflows float32 (64 tokens at the decay's floor of -5 reach e^320), so
 a pair (i, j) is factored round the cumulative decay at the start of i's
 SUB-block of ``sub`` (16) tokens: ``exp(G_i - R) <= 1`` and ``exp(R -
-G_j) <= e^(16 x 5)``, which float32 holds.
+G_j) <= e^(16 x 5)``, which float32 holds. :func:`kda_chunked` hands the
+solve to ``solve_triangular``; the kernel inverts ``I + diag(beta) A``
+on its 16 x 16 diagonal blocks by substitution on the VPU and takes the
+blocks below them through the MXU (:func:`_chunk_in_vmem`). Every
+product of either is float32 (``Precision.HIGHEST``).
 
 :func:`causal_conv_rows` / :func:`causal_conv_step` are the short
 depthwise convolution in front of q, k and v, over a row's own tokens,
@@ -131,6 +157,304 @@ def kda_state_update(leaf, layer, slots, fresh, q, k, v, g, beta,
       fresh.astype(jnp.int32), leaf, columns(jnp.exp(g)), columns(k),
       columns(beta[..., None] * k), columns(q), beta[..., None] * v)
     return o, so
+
+
+def l2norm(x):
+    return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
+
+
+def kda_inputs(q, k, v, f, b, rate, dt_bias, floor):
+    """The recurrence's float32 (q, k, v, g, beta) of a linear layer's
+    convolved q, k, v and its gate projections f, b, a head's width on
+    the last axis (b: a value a head): q and k l2-normalised (q times
+    d_k^-1/2), the decay ``floor * sigmoid(rate (f + dt_bias))`` a key
+    channel, the update strength ``sigmoid(b)``. Elementwise but for the
+    norms' sums over the last axis: the same lines run on ``[..., nh,
+    d]`` in XLA and on one head's ``[chunk, d]`` inside the kernel."""
+    q, k, v, f = (a.astype(jnp.float32) for a in (q, k, v, f))
+    g = floor * jax.nn.sigmoid(rate * (f + dt_bias))
+    return (l2norm(q) * q.shape[-1] ** -0.5, l2norm(k), v, g,
+            jax.nn.sigmoid(b.astype(jnp.float32)))
+
+
+# heads of one row a grid step of the chunk kernel takes, as the leading
+# axis of every value in it: an operation is traced ONCE for all of them
+# (a Python loop over the heads cost a ragged step 26-35 s of tracing),
+# and Mosaic issues it a head after another, so that one head's product
+# is in the MXU while the next is issued (a head at a time the kernel
+# waited out each product's latency: 17.7 ms a layer of the cell's
+# launch). Compile time grows faster than the heads: 2.9 s a kernel
+# with 8, 7.9 with 16 (AOT), for 9.3 against 9.1 ms a layer (the chip).
+CHUNK_HEADS = 8
+
+
+def chunk_kernel_serves(leaf) -> bool:
+    """Whether :func:`kda_chunk_fwd` takes this state leaf ``[layers,
+    slots, nh, dk, dv]``: on a TPU, a head's q, k and v whole lane
+    blocks of the flat token buffer, the heads whole grid steps whose
+    outputs are whole (8, 128) tiles of ``[T, nh, dv]``."""
+    nh, dk, dv = leaf.shape[2:]
+    return (jax.default_backend() == "tpu" and dk % 128 == 0
+            and dv % 128 == 0 and nh % CHUNK_HEADS == 0)
+
+
+def _bdot(a, b, contract):
+    """A float32 product a head: ``contract`` names the contracted axis
+    of each operand, axis 0 the heads."""
+    return jax.lax.dot_general(
+        a, b, (((contract[0],), (contract[1],)), ((0,), (0,))),
+        precision=_HI, preferred_element_type=jnp.float32)
+
+
+def _chunk_in_vmem(q, k, v, g, beta, st, sub):
+    """The heads of a grid step through one chunk, every operand a value
+    in VMEM, heads leading. q, k, g [H, C, dk]; v [H, C, dv]; beta
+    [H, C, 1]; ``st`` the states TRANSPOSED [H, dv, dk] (their decay
+    then scales lanes). :func:`_chunk`'s mathematics with the triangular
+    solve as matmuls: the pairs come out of ONE product transposed
+    (token j a sublane), the unit lower-triangular ``I + N`` is inverted
+    on its ``sub`` x ``sub`` diagonal blocks row by row on the VPU
+    (exact float32) and the blocks below them go by forward substitution
+    through the MXU. Returns (o [H, C, dv], st)."""
+    H, C, dk = k.shape
+    nb = C // sub
+    row = jax.lax.broadcasted_iota(jnp.int32, (1, C, 1), 1)
+    # inclusive cumulative log-decay inside each sub-block: a scan of
+    # log2(sub) shifted adds
+    Gs, step = g, 1
+    while step < sub:
+        Gs = Gs + jnp.where(row % sub >= step, pltpu.roll(Gs, step, 1), 0.0)
+        step *= 2
+    # the cumulative decay at the start of each sub-block, and at the end
+    ref = [jnp.zeros((H, 1, dk), jnp.float32)]
+    for a in range(1, nb + 1):
+        ref.append(ref[-1] + Gs[:, a * sub - 1:a * sub])
+    last = ref.pop()
+    G = Gs + jnp.concatenate(
+        [jnp.broadcast_to(r, (H, sub, dk)) for r in ref], axis=1)
+    fwd = jnp.exp(Gs)                                        # <= 1
+    # row a of the pairs' blocks against every token j up to its last:
+    # k_j exp(R_a - G_j), at most e^(-floor sub) inside block a itself
+    kh = jnp.concatenate(
+        [k[:, :(a + 1) * sub] * jnp.exp(ref[a] - G[:, :(a + 1) * sub])
+         for a in range(nb)], axis=1)
+    pt = _bdot(kh, jnp.concatenate([beta * k * fwd, q * fwd], axis=1),
+               (2, 2))                                       # [H, .., 2C]
+    eg = jnp.exp(G)
+    y = _bdot(jnp.concatenate([k * eg, q * eg], axis=1), st, (2, 2))
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, 1, 2 * C), 2)
+    wt, off = jnp.zeros((H, C, 2 * C), jnp.float32), 0
+    for a in range(nb):
+        n = (a + 1) * sub
+        piece = pt[:, off:off + n]
+        if n < C:
+            piece = jnp.concatenate(
+                [piece, jnp.zeros((H, C - n, 2 * C), jnp.float32)], axis=1)
+        wt = jnp.where(lane % C // sub == a, piece, wt)
+        off += n
+    # lanes [0, C): N^T = (diag(beta) A)^T, strictly j < i; lanes
+    # [C, 2C): the queries' pairs, j <= i
+    wt = jnp.where(row < lane % C + lane // C, wt, 0.0)
+    w = jnp.swapaxes(wt, 1, 2)                               # [H, 2C, C]
+    rhs = beta * (v - y[:, :C])
+    # (I + N_aa)^-1 of every diagonal block (block a of head h at a H +
+    # h), a row at a time: row i is e_i - sum_j N[i, j] row j, N[i, :] a
+    # sublane vector of N^T
+    dt = jnp.concatenate(
+        [wt[:, a * sub:(a + 1) * sub, a * sub:(a + 1) * sub]
+         for a in range(nb)], axis=0)
+    tri = jax.lax.broadcasted_iota(jnp.int32, (1, sub, 1), 1)
+    col = jax.lax.broadcasted_iota(jnp.int32, (1, 1, sub), 2)
+    inv = jnp.broadcast_to((tri == col).astype(jnp.float32), dt.shape)
+    for i in range(1, sub):
+        inv = jnp.where(tri == i, (col == i).astype(jnp.float32) - jnp.sum(
+            dt[:, :, i:i + 1] * inv, axis=1, keepdims=True), inv)
+    us = []
+    for a in range(nb):
+        ra = rhs[:, a * sub:(a + 1) * sub]
+        if a:
+            ra = ra - _bdot(w[:, a * sub:(a + 1) * sub, :a * sub],
+                            jnp.concatenate(us, axis=1), (2, 1))
+        us.append(_bdot(inv[a * H:(a + 1) * H], ra, (2, 1)))
+    u = jnp.concatenate(us, axis=1)
+    o = y[:, C:] + _bdot(w[:, C:], u, (2, 1))
+    st = st * jnp.exp(last) + _bdot(u, k * jnp.exp(last - G), (1, 1))
+    return o, st
+
+
+def _chunk_kernel(layer_ref, slots_ref, fresh_ref, starts_ref, counts_ref,
+                  s_ref, rate_ref, bias_ref, q_hbm, k_hbm, v_hbm, f_hbm,
+                  b_hbm, so_ref, o_hbm, qb, kb, vb, fb, bb, ob, st, sem_in,
+                  sem_out, *, floor, sub):
+    """One row's ``heads`` states through the row's tokens. The flat
+    buffer is cut into WINDOWS of ``chunk`` tokens where it lies (window
+    w: tokens w chunk .. (w + 1) chunk), and the row takes every window
+    it has a token in, the tokens of other rows masked (k = 0, g = 0,
+    beta = 0: they leave the state as it was): every copy is aligned,
+    and a row whose first token is a window's first takes exactly its
+    chunks. The states wait in VMEM (``st``, transposed) from window to
+    window."""
+    del layer_ref, slots_ref            # the index maps read them
+    heads, dv, dk = st.shape
+    chunk = qb.shape[1]
+    r, hblk = pl.program_id(0), pl.program_id(1)
+    start, count = starts_ref[r], counts_ref[r]
+    # the row's windows [w0, w1), and where window w's copies land
+    w0 = start // chunk
+    w1 = jnp.where(count > 0, (start + count - 1) // chunk + 1, w0)
+    st[...] = jnp.swapaxes(jnp.where(
+        fresh_ref[r] == 0, s_ref[...].astype(jnp.float32), 0.0), 1, 2)
+
+    def tokens_of(w):
+        return pl.ds(pl.multiple_of(w * chunk, chunk), chunk)
+
+    def lanes_of(d):
+        return pl.ds(pl.multiple_of(hblk * heads * d, heads * d), heads * d)
+
+    def copies_in(w):
+        rows, slot = tokens_of(w), (w - w0) % 2
+        return [pltpu.make_async_copy(src.at[rows, lanes_of(d)],
+                                      dst.at[slot], sem_in.at[i, slot])
+                for i, (src, dst, d) in enumerate((
+                    (q_hbm, qb, dk), (k_hbm, kb, dk), (v_hbm, vb, dv),
+                    (f_hbm, fb, dk)))] + [
+            pltpu.make_async_copy(b_hbm.at[hblk, rows], bb.at[slot],
+                                  sem_in.at[4, slot])]
+
+    def outputs_of(w):
+        return o_hbm.at[tokens_of(w), pl.ds(hblk * heads, heads)]
+
+    def copy_out(w, slot):
+        return pltpu.make_async_copy(ob.at[slot], outputs_of(w),
+                                     sem_out.at[slot])
+
+    # the first row's steps write zeros over their heads' outputs, every
+    # window of the buffer: what a token of no row keeps
+    @pl.when(r == 0)
+    def _():
+        ob[0] = jnp.zeros(ob.shape[1:], ob.dtype)
+        windows = o_hbm.shape[0] // chunk
+        jax.lax.fori_loop(
+            0, windows, lambda w, c: copy_out(w, 0).start(), None)
+        jax.lax.fori_loop(
+            0, windows, lambda w, c: copy_out(w, 0).wait(), None)
+
+    @pl.when(w1 > w0)
+    def _():
+        for c in copies_in(w0):
+            c.start()
+
+    def by_head(x, d):                  # [C, heads d] -> [heads, C, d]
+        return jnp.stack([x[:, h * d:(h + 1) * d] for h in range(heads)])
+
+    def window(w, carry):
+        slot = (w - w0) % 2
+        for c in copies_in(w):
+            c.wait()
+
+        @pl.when(w + 1 < w1)
+        def _():
+            for c in copies_in(w + 1):
+                c.start()
+
+        @pl.when(w - 2 >= w0)             # ob[slot]'s last write has left
+        def _():
+            copy_out(w - 2, slot).wait()
+
+        t = w * chunk + jax.lax.broadcasted_iota(jnp.int32, (chunk, 1), 0)
+        live = (t >= start) & (t < start + count)
+
+        # a window the row shares with its neighbours: their outputs
+        # (or the zeros of no row's tokens) come in and go back as they are
+        @pl.when((w * chunk < start) | ((w + 1) * chunk > start + count))
+        def _():
+            old = pltpu.make_async_copy(outputs_of(w), ob.at[slot],
+                                        sem_out.at[slot])
+            old.start()
+            old.wait()
+
+        o, st[...] = _chunk_in_vmem(*(
+            jnp.where(live, a, 0.0) for a in kda_inputs(
+                by_head(qb[slot], dk), by_head(kb[slot], dk),
+                by_head(vb[slot], dv), by_head(fb[slot], dk),
+                by_head(bb[slot], 1), rate_ref[...], bias_ref[...],
+                floor)), st[...], sub)
+        for h in range(heads):
+            ob[slot, :, h] = jnp.where(live, o[h], ob[slot, :, h])
+        copy_out(w, slot).start()
+        return carry
+
+    jax.lax.fori_loop(w0, w1, window, 0)
+    for back in (2, 1):
+        @pl.when(w1 - back >= w0)
+        def _():
+            copy_out(w1 - back, (w1 - back - w0) % 2).wait()
+    so_ref[...] = jnp.swapaxes(st[...], 1, 2).astype(so_ref.dtype)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("floor", "chunk", "sub", "interpret"))
+def kda_chunk_fwd(tokens, rate, dt_bias, leaf, layer, slots, fresh, starts,
+                  counts, floor, chunk=CHUNK, sub=SUB, interpret=False):
+    """:func:`kda_chunked` as ONE kernel a layer and launch, the rows'
+    states in place. ``tokens``: the flat (q, k, v, f [T, nh d], b
+    [T, nh]) as the layer made them, :func:`kda_inputs` run on a window
+    of them inside the kernel with ``rate`` [nh] and ``dt_bias``
+    [nh dk]. The grid is (row, block of ``CHUNK_HEADS`` heads); a step
+    copies the block's states in from ``leaf[layer, slots[r]]`` once
+    (zeros where ``fresh[r]``), walks the row's windows of ``chunk``
+    tokens where they lie in the flat buffer, and copies the states back
+    once (aliased): :func:`kda_state_update`'s addressing. Returns
+    (o [T, nh, dv] float32, zeros at tokens of no row; leaf). A trace
+    shows it as ``kda_chunk_fwd``. A jit of its own: the layers of a
+    program, and the program's signatures, trace the kernel once."""
+    assert chunk % sub == 0 and floor * sub > -88.0, (chunk, sub, floor)
+    q, k, v, f, b = tokens
+    T = q.shape[0]
+    R = starts.shape[0]
+    nh, dk, dv = leaf.shape[2:]
+    hb = CHUNK_HEADS
+    blocks = nh // hb
+    pad = -T % chunk
+    if pad:
+        q, k, v, f, b = (jnp.pad(a, ((0, pad), (0, 0)))
+                         for a in (q, k, v, f, b))
+    # beta's projection a head block, its heads the first of 128 lanes
+    b = jnp.pad(b.astype(jnp.float32).reshape(T + pad, blocks, hb)
+                .transpose(1, 0, 2), ((0, 0), (0, 0), (0, 128 - hb)))
+    rate = jnp.broadcast_to(
+        rate.astype(jnp.float32).reshape(nh, 1, 1), (nh, 1, dk))
+    dt_bias = dt_bias.astype(jnp.float32).reshape(nh, 1, dk)
+    state = pl.BlockSpec(
+        (None, None, hb, dk, dv),
+        lambda r, h, layer, slots, *_: (layer[0], slots[r], h, 0, 0))
+    gate = pl.BlockSpec((hb, 1, dk), lambda r, h, *_: (h, 0, 0))
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    leaf, o = pl.pallas_call(
+        functools.partial(_chunk_kernel, floor=floor, sub=sub),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5, grid=(R, blocks),
+            in_specs=[state, gate, gate] + [hbm] * 5,
+            out_specs=[state, hbm],
+            scratch_shapes=[
+                pltpu.VMEM((2, chunk, hb * dk), q.dtype),
+                pltpu.VMEM((2, chunk, hb * dk), k.dtype),
+                pltpu.VMEM((2, chunk, hb * dv), v.dtype),
+                pltpu.VMEM((2, chunk, hb * dk), f.dtype),
+                pltpu.VMEM((2, chunk, 128), jnp.float32),
+                pltpu.VMEM((2, chunk, hb, dv), jnp.float32),
+                pltpu.VMEM((hb, dv, dk), jnp.float32),
+                pltpu.SemaphoreType.DMA((5, 2)),
+                pltpu.SemaphoreType.DMA((2,))]),
+        out_shape=[jax.ShapeDtypeStruct(leaf.shape, leaf.dtype),
+                   jax.ShapeDtypeStruct((T + pad, nh, dv), jnp.float32)],
+        input_output_aliases={5: 0},
+        name="kda_chunk_fwd",
+        interpret=pltpu.InterpretParams() if interpret else False,
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), slots.astype(jnp.int32),
+      fresh.astype(jnp.int32), starts.astype(jnp.int32),
+      counts.astype(jnp.int32), leaf, rate, dt_bias, q, k, v, f, b)
+    return o[:T], leaf
 
 
 def _chunk(q, k, v, g, beta, state, sub, floor):

@@ -734,10 +734,6 @@ def _state_rows(row_ids, pos, lengths, slots, rows, one_token):
                       one_token)
 
 
-def _l2norm(x):
-    return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
-
-
 def _linear_attention_sublayer(cfg, lp, x, l, cache, rows: _StateRows,
                                use_kernel=True):
     """A linear-attention (KDA) mixer on flat tokens x [T, H]; ``l`` is
@@ -753,10 +749,12 @@ def _linear_attention_sublayer(cfg, lp, x, l, cache, rows: _StateRows,
     one-token update (scope ``kda_state``: the kernel that reads the
     slot where it lies, ``kda_state_update``, where ``use_kernel`` and
     the widths allow, else gather, ``kda_step`` and scatter), every
-    other launch through
-    the chunked form, rows of any lengths (scope ``kda_chunk``):
-    ``kernels/linear_attention``. Returns (what the mixer adds to x,
-    cache)."""
+    other launch through the chunked form, rows of any lengths (scope
+    ``kda_chunk``: under the same two conditions the kernel
+    ``kda_chunk_fwd``, which reads the projections where they lie and
+    runs the norms and gates on them in VMEM, else the XLA
+    ``kda_chunked``): ``kernels/linear_attention``. Returns (what the
+    mixer adds to x, cache)."""
     from ...ops.norms import rms_norm
     from .kernels import linear_attention as la
     T = x.shape[0]
@@ -790,9 +788,8 @@ def _linear_attention_sublayer(cfg, lp, x, l, cache, rows: _StateRows,
         """float32 (q, k, v, g, beta) of tokens [..., D], heads split"""
         q, k, v, f = (a.astype(f32).reshape(*a.shape[:-1], nh, d)
                       for a in (q, k, v, f))
-        g = cfg.linear_decay_floor * jax.nn.sigmoid(rate * (f + dt_bias))
-        return (_l2norm(q) * d ** -0.5, _l2norm(k), v, g,
-                jax.nn.sigmoid(b.astype(f32)))
+        return la.kda_inputs(q, k, v, f, b, rate, dt_bias,
+                             cfg.linear_decay_floor)
 
     # the recurrence with its state's way out of the slot (zeros for a
     # row at its first token) and back, one scope: what a roofline of it
@@ -807,6 +804,10 @@ def _linear_attention_sublayer(cfg, lp, x, l, cache, rows: _StateRows,
                               leaf[l, slots].astype(f32))   # [N, nh, d, d]
             o, state = la.kda_step(*prepare(*mixed, f, b), state)
             leaf = leaf.at[l, slots].set(state.astype(leaf.dtype))
+        elif use_kernel and la.chunk_kernel_serves(leaf):
+            o, leaf = la.kda_chunk_fwd(
+                (*mixed, f, b), rate, dt_bias, leaf, l, slots, rows.fresh,
+                rows.starts, rows.counts, cfg.linear_decay_floor)
         else:
             o, leaf = la.kda_chunked(
                 (*mixed, f, b), prepare, leaf, l, slots, rows.fresh,

@@ -271,30 +271,109 @@ def _kda_rows(lengths, nh=2, d=16, seed=0):
         np.asarray(lengths, np.int32)
 
 
-def test_the_chunked_form_is_the_one_token_form_over_a_row():
-    """Rows of 150 (three chunks, the last partial), 1, 70, 0 and 64
-    tokens from states that are not zero, decays down to the floor of -5
-    a token (64 of them would overflow ``exp(-G)``: the sub-block
-    factoring is what holds), in row blocks of two."""
-    tokens, starts, counts = _kda_rows((150, 1, 70, 0, 64, 3))
-    R = len(counts)
-    rng = np.random.default_rng(1)
-    leaf = rng.standard_normal((2, R + 1, 2, 16, 16)).astype(np.float32)
-    slots = jnp.arange(1, R + 1)
-    out, new = jax.jit(lambda *a: la.kda_chunked(
-        a[:5], lambda *t: t, a[5], 1, slots, jnp.zeros((R,), bool),
-        jnp.asarray(starts), jnp.asarray(counts), -5.0,
-        rows_a_step=2))(*tokens, leaf)
-    want_o = np.zeros_like(np.asarray(out))
+def _kda_projections(lengths, nh, d, seed=0):
+    """A linear layer's flat projections (q, k, v, f [T, nh d], b
+    [T, nh]) and its gates' (rate [nh], dt_bias [nh d]): the decay gate
+    saturates, so a good share of the tokens sit at the floor."""
+    rng = np.random.default_rng(seed)
+    T = int(np.ceil((sum(lengths) + 5) / 64) * 64)
+
+    def rnd(*s):
+        return rng.standard_normal(s).astype(np.float32)
+
+    tokens = (rnd(T, nh * d), rnd(T, nh * d), rnd(T, nh * d),
+              3 * rnd(T, nh * d), rnd(T, nh))
+    starts = np.cumsum([0] + list(lengths[:-1])).astype(np.int32)
+    return tokens, np.exp(0.3 * rnd(nh)), 0.5 * rnd(nh * d), starts, \
+        np.asarray(lengths, np.int32)
+
+
+def _prepare(nh, d, rate, bias):
+    """What the layer's ``prepare`` is to the XLA form: the flat
+    projections split by head and through ``kda_inputs``."""
+    def prepare(q, k, v, f, b):
+        q, k, v, f = (a.reshape(*a.shape[:-1], nh, d) for a in (q, k, v, f))
+        return la.kda_inputs(q, k, v, f, b, jnp.asarray(rate)[:, None],
+                             jnp.asarray(bias).reshape(nh, d), -5.0)
+    return prepare
+
+
+@pytest.mark.parametrize("form,nh,d", [("xla", 2, 16), ("kernel", 8, 128)])
+def test_the_chunked_form_is_the_one_token_form_over_a_row(form, nh, d):
+    """Rows of 150 (three chunks, the last partial), 1, 70, 0, 64 and 3
+    tokens, one after another in the flat buffer (so no row but the
+    first starts on a chunk's boundary, and neighbours share the
+    kernel's windows), from states that are not zero, decays down to the
+    floor of -5 a token (64 of them would overflow ``exp(-G)``: the
+    sub-block factoring is what holds); at scattered slots of layer 1,
+    two rows at their first token (their slots' rubbish must not show),
+    the row of no token at the null slot. Both forms against
+    ``kda_step`` token by token, and every other slot and layer as it
+    went in; the kernel (under the TPU interpreter, at the published
+    head width) against the XLA form on the same operands too. The
+    kernel's sums differ in order only (its solve is exact float32 on
+    the VPU inside a sub-block, matmuls across): the same 2e-5 holds
+    (it reads 6e-6 on a state, 3e-7 on an output)."""
+    tokens, rate, bias, starts, counts = _kda_projections(
+        (150, 1, 70, 0, 64, 3), nh, d)
+    slots = np.array([3, 1, 7, 0, 5, 8])
+    fresh = np.array([0, 1, 0, 0, 0, 1], bool)
+    leaf = np.random.default_rng(1).standard_normal(
+        (2, 9, nh, d, d)).astype(np.float32)
+    rows = (1, jnp.asarray(slots), jnp.asarray(fresh), jnp.asarray(starts),
+            jnp.asarray(counts), -5.0)
+
+    prepare = _prepare(nh, d, rate, bias)
+    xla = jax.jit(lambda *a: la.kda_chunked(a[:5], prepare, a[5], *rows,
+                                            rows_a_step=2))
+    kernel = jax.jit(lambda *a: la.kda_chunk_fwd(
+        a[:5], jnp.asarray(rate), jnp.asarray(bias), a[5], *rows,
+        interpret=True))
+    out, new = (kernel if form == "kernel" else xla)(*tokens, leaf)
+    out, new = np.asarray(out), np.asarray(new)
+    each = [np.asarray(a) for a in prepare(*tokens)]
+    want_o = np.zeros_like(out)
     for r, (s0, n) in enumerate(zip(starts, counts)):
-        state = jnp.asarray(leaf[1, r + 1][None])
+        state = jnp.asarray(np.where(fresh[r], 0, leaf[1, slots[r]])[None])
         for t in range(s0, s0 + n):
-            o, state = la.kda_step(*(a[t:t + 1] for a in tokens), state)
+            o, state = la.kda_step(*(a[t:t + 1] for a in each), state)
             want_o[t] = o[0]
-        np.testing.assert_allclose(np.asarray(new)[1, r + 1], state[0],
-                                   atol=2e-5)
-    np.testing.assert_allclose(np.asarray(out), want_o, atol=2e-5)
-    np.testing.assert_array_equal(np.asarray(new)[0], leaf[0])  # its layer
+        if n:
+            np.testing.assert_allclose(new[1, slots[r]], state[0],
+                                       atol=2e-5)
+    np.testing.assert_allclose(out, want_o, atol=2e-5)
+    others = [i for i in range(9) if i not in slots[counts > 0]]
+    np.testing.assert_array_equal(new[1, others], leaf[1, others])
+    np.testing.assert_array_equal(new[0], leaf[0])          # its layer
+    if form == "kernel":
+        xo, xnew = xla(*tokens, leaf)
+        np.testing.assert_allclose(out, np.asarray(xo), atol=2e-5)
+        np.testing.assert_allclose(new, np.asarray(xnew), atol=2e-5)
+
+
+def test_the_chunk_kernel_takes_a_buffer_of_any_length():
+    """A ragged step's token buffer is a power of two, so it can be
+    shorter than the kernel's window of 64: 40 tokens, two rows, the
+    second fresh. The kernel pads its operands to whole windows and
+    returns the buffer's own 40 rows, the XLA form's."""
+    nh, d, T = 8, 128, 40
+    tokens, rate, bias, _, _ = _kda_projections((T - 5,), nh, d)
+    tokens = tuple(a[:T] for a in tokens)
+    leaf = np.random.default_rng(3).standard_normal(
+        (1, 3, nh, d, d)).astype(np.float32)
+    rows = (0, jnp.asarray([1, 2]), jnp.asarray([False, True]),
+            jnp.asarray([0, 25]), jnp.asarray([25, 10]), -5.0)
+
+    prepare = _prepare(nh, d, rate, bias)
+    out, new = jax.jit(lambda *a: la.kda_chunk_fwd(
+        a[:5], jnp.asarray(rate), jnp.asarray(bias), a[5], *rows,
+        interpret=True))(*tokens, leaf)
+    xo, xnew = jax.jit(lambda *a: la.kda_chunked(
+        a[:5], prepare, a[5], *rows))(*tokens, leaf)
+    assert out.shape == (T, nh, d)
+    np.testing.assert_allclose(out, np.asarray(xo), atol=2e-5)
+    np.testing.assert_allclose(new, np.asarray(xnew), atol=2e-5)
+    np.testing.assert_array_equal(np.asarray(out)[35:], 0)   # no row's
 
 
 @pytest.mark.parametrize("kept", ["float32", "bfloat16"])
@@ -496,6 +575,36 @@ def test_the_engine_counts_held_rows():
     assert 0 < routed < 20 * 4 * 6
     assert reg.get("inference_state_rows_total").labels(
         program="ragged_step").value - rows0 == 2
+
+
+def test_the_engine_counts_the_steps_the_chunk_kernel_took(monkeypatch):
+    """``inference_linear_chunk_kernel_steps_total`` follows
+    ``chunk_kernel_serves`` a ragged step: 0 here (the CPU, toy widths:
+    the XLA form), one a step where it says yes; and what it asks is a
+    TPU, head widths of whole lane blocks and heads in whole steps."""
+    from deepspeed_tpu.inference.v2 import engine_v2
+    reg = get_registry()
+    eng = _engine("float32")
+    steps = reg.get("inference_linear_chunk_kernel_steps_total")
+    before = steps.value
+    eng.put([0, 1], _prompts((12, 8)))
+    assert steps.value == before
+    monkeypatch.setattr(engine_v2, "chunk_kernel_serves", lambda leaf: True)
+    eng.put([2], _prompts((9,)))
+    assert steps.value == before + 1
+    eng.flush(0), eng.flush(1), eng.flush(2)
+
+    def leaf(nh, dk, dv):
+        return jax.ShapeDtypeStruct((7, 129, nh, dk, dv), jnp.float32)
+
+    assert not la.chunk_kernel_serves(leaf(32, 128, 128))    # the CPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert la.chunk_kernel_serves(leaf(32, 128, 128))
+    assert la.chunk_kernel_serves(leaf(8, 256, 128))
+    assert la.chunk_kernel_serves(leaf(24, 128, 128))        # 3 steps
+    assert not la.chunk_kernel_serves(leaf(4, 16, 16))       # TOY's
+    assert not la.chunk_kernel_serves(leaf(12, 128, 128))    # 1.5 steps
+    assert not la.chunk_kernel_serves(leaf(4, 128, 128))     # half a tile
 
 
 # ---------------------------------------------------------------------------

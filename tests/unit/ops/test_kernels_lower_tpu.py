@@ -603,15 +603,41 @@ def test_the_decode_state_kernel_at_published_widths(tpu_sharding, kept):
     assert compiled.memory_analysis().temp_size_in_bytes < 0.1e9
 
 
-def test_the_hybrid_decode_window_compiles_with_its_state_in_place(
-        tpu_sharding):
-    """The decode window of the pattern at published widths, cut to its
-    first linear expert layer and the layers before it (3 layers, 8
-    experts held): the state kernel runs in both linear runs, the
-    grouped matmul in the expert layer, and the program's temporaries
-    hold no copy of the state leaf (32 rows x 3 layers: 0.2 GB)."""
-    from deepspeed_tpu.inference.v2.paged_model import (init_paged_kv_cache,
-                                                        paged_decode_window)
+def test_the_chunk_kernel_at_published_widths(tpu_sharding):
+    """``kda_chunk_fwd`` for the cell's ragged launch (128 rows of 128
+    tokens, bf16 projections of 32 heads of 128, a leaf of 7 layers and
+    129 slots): it compiles for the chip, runs as ONE custom call under
+    a name a trace finds, the leaf is aliased and the outputs leave in
+    the layout the layer reads (no temporary at all: under 0.1 GB)."""
+    from deepspeed_tpu.inference.v2.kernels import linear_attention as la
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=tpu_sharding)
+
+    R, T, nh, d = 128, 128 * 128, 32, 128
+    leaf = sds((7, 129, nh, d, d))
+    assert la.chunk_kernel_serves(leaf)
+    wide = sds((T, nh * d), jnp.bfloat16)
+    compiled = jax.jit(
+        lambda q, k, v, f, b, rate, bias, leaf, *rows: la.kda_chunk_fwd(
+            (q, k, v, f, b), rate, bias, leaf, *rows, -5.0),
+        donate_argnums=(7,)).lower(
+        wide, wide, wide, wide, sds((T, nh), jnp.bfloat16), sds((nh,)),
+        sds((nh * d,)), leaf, sds((), jnp.int32), sds((R,), jnp.int32),
+        sds((R,), jnp.bool_), sds((R,), jnp.int32),
+        sds((R,), jnp.int32)).compile()
+    kernels = re.findall(r"%([\w.\-]+) = [^\n]*tpu_custom_call",
+                         compiled.as_text())
+    assert len(kernels) == 1 and kernels[0].startswith("kda_chunk_fwd")
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.1e9
+
+
+def _hybrid_cut(tpu_sharding):
+    """The pattern at published widths, cut to its first linear expert
+    layer and the layers before it (3 layers, 8 experts held): the
+    configuration, and its parameters and cache (33 state slots) as
+    shapes on the chip."""
+    from deepspeed_tpu.inference.v2.paged_model import init_paged_kv_cache
     from deepspeed_tpu.models import TransformerLM
     from deepspeed_tpu.models.transformer import TransformerConfig
 
@@ -631,6 +657,51 @@ def test_the_hybrid_decode_window_compiles_with_its_state_in_place(
                                     state_slots=32)))
     assert cache["latent"].shape[0] == 0 and cache["kda_state"].shape[:2] \
         == (3, 33)
+    return cfg, params, cache
+
+
+def test_the_hybrid_ragged_step_runs_its_chunks_in_the_kernel(tpu_sharding):
+    """The ragged step of the same cut, 32 rows in 2,048 tokens: the
+    chunk kernel runs in both linear runs and the grouped matmul in the
+    expert layer; nothing under ``kda_chunk`` loops or solves a
+    triangle in XLA any more; and the launch's temporaries are no more
+    than the parent's, whose loop kept a chunk's gathered rows, pairs'
+    operands and solve beside the outputs (it read 504,550,912 B; this
+    reads 116,973,568)."""
+    from deepspeed_tpu.inference.v2.paged_model import paged_ragged_step
+
+    cfg, params, cache = _hybrid_cut(tpu_sharding)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=tpu_sharding)
+
+    T, R = 2048, 32
+    compiled = jax.jit(
+        lambda p, ids, rows, pos, ln, wb, wo, bt, li, c, ss:
+        paged_ragged_step(cfg, p, ids, rows, pos, ln, wb, wo, bt, li, c, 16,
+                          use_kernel=True, state_slots=ss),
+        donate_argnums=(9,)).lower(
+        params, i32(T), i32(T), i32(T), i32(T), i32(T), i32(T), i32(R, 16),
+        i32(R), cache, i32(R)).compile()
+    text = compiled.as_text()
+    kernels = re.findall(r"%([\w.\-]+) = [^\n]*tpu_custom_call", text)
+    assert sum(k.startswith("kda_chunk_fwd") for k in kernels) == 2, kernels
+    assert sum(bool(GMM_PATTERN.search(k)) for k in kernels) == 3, kernels
+    assert "triangular-solve" not in text and "triangular_solve" not in text
+    assert "kda_chunk/while" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes <= 504_550_912
+
+
+def test_the_hybrid_decode_window_compiles_with_its_state_in_place(
+        tpu_sharding):
+    """The decode window of the pattern at published widths, cut to its
+    first linear expert layer and the layers before it (3 layers, 8
+    experts held): the state kernel runs in both linear runs, the
+    grouped matmul in the expert layer, and the program's temporaries
+    hold no copy of the state leaf (32 rows x 3 layers: 0.2 GB)."""
+    from deepspeed_tpu.inference.v2.paged_model import paged_decode_window
+
+    cfg, params, cache = _hybrid_cut(tpu_sharding)
 
     def i32(*shape):
         return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=tpu_sharding)
