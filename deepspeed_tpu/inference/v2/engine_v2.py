@@ -53,6 +53,7 @@ from .paged_model import (init_lora_bank, init_paged_kv_cache,
 from .ragged import batch as ragged_batch
 from .ragged.blocked_allocator import NULL_BLOCK
 from .ragged.ragged_manager import DSStateManager
+from .ragged.sequence_descriptor import DSSequenceDescriptor
 from .sampling import greedy_tokens
 
 DTYPES = {"float32": jnp.float32, "float16": jnp.float16,
@@ -171,7 +172,7 @@ class InferenceEngineV2:
                 f"bias, scale or shared expert; got moe_top_k=" \
                 f"{cfg.moe_top_k}, {cfg.served_only}); serve top-k>2 " \
                 f"and the deployed expert layer at ep=1"
-        if cfg.attention == "mla":
+        if cfg.walks_runs:
             self._refuse_for_latent(config, cfg)
         if not cfg.has_state and config.state_dtype != "float32":
             raise ValueError(
@@ -243,9 +244,25 @@ class InferenceEngineV2:
         # a model with linear-attention layers keeps recurrent state a
         # sequence: a slot a tracked sequence, beside its blocks
         self._has_state = cfg.has_state
+        # a model with window-attention layers keeps their keys and
+        # values in a second pool, a RING a sequence: the window, the
+        # most tokens a sequence feeds in one step (its share of a full
+        # step, in whole blocks) and one block, so that a step's writes
+        # never land on a position its earliest token still sees
+        bs = sm.block_size
+        self.max_row_chunk = None
+        ring = 0
+        if "window" in cfg.layer_kinds:
+            self.max_row_chunk = max(
+                sm.max_ragged_batch_size // sm.max_tracked_sequences
+                // bs, 1) * bs
+            ring = min(-(-cfg.attn_window // bs) * bs
+                       + self.max_row_chunk + bs,
+                       -(-sm.max_seq_len // bs) * bs)
         self.state_manager = DSStateManager(
             sm, state_slots=sm.max_tracked_sequences
-            if self._has_state else 0)
+            if self._has_state else 0, window_ring=ring)
+        self._has_ring = bool(ring)
         # note: the fresh pool carries no sharding, while every program
         # returns the donated cache with an explicit NamedSharding — so
         # a bucket's FIRST call compiles against a different executable
@@ -258,7 +275,9 @@ class InferenceEngineV2:
             cfg, sm.num_blocks, sm.block_size, self.dtype,
             kv_quant=config.kv_quant,
             state_slots=self.state_manager.state_slots,
-            state_dtype=DTYPES[config.state_dtype])
+            state_dtype=DTYPES[config.state_dtype],
+            window_blocks=sm.max_tracked_sequences
+            * self.state_manager.ring_blocks + 1)
         # cold-block KV spill tier (ragged/spill.py): installed on the
         # state manager so prefix eviction demotes content to host RAM
         # (+ optional disk) and match_prefix restores it between steps
@@ -332,6 +351,7 @@ class InferenceEngineV2:
             "pallas:" + (LATENT if cfg.attention == "mla" else
                          kernel_variant(cfg.head_dim, cfg.kv_heads,
                                         bool(config.kv_quant)))
+            + "+window" * self._has_ring
             if use_kernel else "jnp:gather")
         topo = self.topology if ep > 1 else None
         # load_draft_model builds jits after __init__; it reuses the
@@ -350,27 +370,28 @@ class InferenceEngineV2:
         # step) take one more behind them, ``ss``: each row's state
         # slot, None for every other model
         self._decode_jit = watchdog.watch_jit(
-            "decode", lambda p, t, pos, bt, c, a, lb, aid, ss: paged_decode(
+            "decode",
+            lambda p, t, pos, bt, c, a, lb, aid, ss, wt=None: paged_decode(
                 cfg, p, t, pos, bt, c, a, sm.block_size,
                 use_kernel=use_kernel, topo=topo, lora=lb,
-                adapter_ids=aid, state_slots=ss),
+                adapter_ids=aid, state_slots=ss, window_tables=wt),
             donate_argnums=(4,))
 
-        def _decode_tok(p, t, pos, bt, c, a, lb, aid, ss):
+        def _decode_tok(p, t, pos, bt, c, a, lb, aid, ss, wt=None):
             # greedy variant for the generate() hot loop: argmax on device
             # so the per-token host transfer is [N] int32, not [N, vocab]
             # (the reference's sampler also runs device-side)
             logits, *moe, c = paged_decode(
                 cfg, p, t, pos, bt, c, a, sm.block_size,
                 use_kernel=use_kernel, topo=topo, lora=lb, adapter_ids=aid,
-                state_slots=ss)
+                state_slots=ss, window_tables=wt)
             return (greedy_tokens(logits), *moe, c)
 
         self._decode_tok_jit = watchdog.watch_jit(
             "decode_greedy", _decode_tok, donate_argnums=(4,))
 
         def _decode_sample(p, t, pos, bt, c, a, rng, seeds, gidx, temp,
-                           topp, topk, lb, aid, ss):
+                           topp, topk, lb, aid, ss, wt=None):
             # sampling variant (FastGen temperature/top-p/top-k): the
             # sampler runs device-side too, still an [N] int32 transfer.
             # Per-ROW keys (stable row seed + generated-token index) so
@@ -379,7 +400,7 @@ class InferenceEngineV2:
             logits, *moe, c = paged_decode(
                 cfg, p, t, pos, bt, c, a, sm.block_size,
                 use_kernel=use_kernel, topo=topo, lora=lb, adapter_ids=aid,
-                state_slots=ss)
+                state_slots=ss, window_tables=wt)
             keys = fold_in_rows(rng, seeds, gidx)
             return (sample_tokens_rowwise(logits, keys, temp, topp, topk),
                     *moe, c)
@@ -410,22 +431,24 @@ class InferenceEngineV2:
         def _build_fused_pair(K: int):
             greedy = watchdog.watch_jit(
                 "decode_window_greedy",
-                lambda p, t, pos, bt, c, sl, eos, alive, lb, aid, ss, _K=K:
-                paged_decode_window(
+                lambda p, t, pos, bt, c, sl, eos, alive, lb, aid, ss,
+                wt=None, _K=K: paged_decode_window(
                     cfg, p, t, pos, bt, c, sl, eos, sm.block_size,
                     _K, use_kernel=use_kernel,
                     topo=topo, lora=lb, adapter_ids=aid, alive=alive,
-                    state_slots=ss),
+                    state_slots=ss, window_tables=wt),
                 donate_argnums=(4,))
             sample = watchdog.watch_jit(
                 "decode_window_sample",
                 lambda p, t, pos, bt, c, sl, eos, alive, rng, seeds, g0, \
-                temp, topp, topk, lb, aid, ss, _K=K: paged_decode_window(
+                temp, topp, topk, lb, aid, ss, wt=None, _K=K:
+                paged_decode_window(
                     cfg, p, t, pos, bt, c, sl, eos, sm.block_size,
                     _K, rng=rng, row_seeds=seeds, gen_idx0=g0,
                     temp=temp, topp=topp, topk=topk,
                     use_kernel=use_kernel, topo=topo, lora=lb,
-                    adapter_ids=aid, alive=alive, state_slots=ss),
+                    adapter_ids=aid, alive=alive, state_slots=ss,
+                    window_tables=wt),
                 donate_argnums=(4,))
             return greedy, sample
 
@@ -462,11 +485,12 @@ class InferenceEngineV2:
             config.ragged_attention)
         self._ragged_jit = watchdog.watch_jit(
             "ragged_step",
-            lambda p, ids, rows, pos, ln, wb, wo, bt, li, c, lb, aid, ss:
-            paged_ragged_step(
+            lambda p, ids, rows, pos, ln, wb, wo, bt, li, c, lb, aid, ss,
+            wt=None: paged_ragged_step(
                 cfg, p, ids, rows, pos, ln, wb, wo, bt, li, c,
                 sm.block_size, use_kernel=use_kernel, topo=topo,
-                lora=lb, adapter_ids=aid, state_slots=ss),
+                lora=lb, adapter_ids=aid, state_slots=ss,
+                window_tables=wt),
             donate_argnums=(9,))
         # speculative verification: greedy ids for a static window of
         # fed positions from one fused continuation pass (prompt-lookup
@@ -507,6 +531,12 @@ class InferenceEngineV2:
             self._m_state_bytes.set(ds_memory.tree_bytes(
                 {k: v for k, v in self.kv_cache.items()
                  if k.startswith("kda_")}))
+            for kind in ("full", "window"):
+                self._m_pool_bytes.labels(kind=kind).set(
+                    ds_memory.tree_bytes({
+                        k: v for k, v in self.kv_cache.items()
+                        if not k.startswith("kda_")
+                        and k.endswith("_window") == (kind == "window")}))
         except Exception:  # accounting must never block serving
             pass
         log_dist(
@@ -517,13 +547,52 @@ class InferenceEngineV2:
 
     @staticmethod
     def _refuse_for_latent(config, cfg):
-        """What this engine does not do for an attention='mla' model,
-        and (``cfg.has_state``) for one whose layer pattern keeps
-        recurrent state a sequence, said at construction rather than run
-        wrong."""
+        """What this engine does not do for a model served by the walk
+        of runs: an attention='mla' model, one whose layer pattern
+        keeps recurrent state a sequence (``cfg.has_state``), and a
+        pattern over per-head attention (``cfg.layer_types``), whose
+        window layers keep a ring in a pool of their own; said at
+        construction rather than run wrong."""
+        who, refused = (InferenceEngineV2._pattern_refusals(config)
+                        if cfg.layer_types is not None
+                        else InferenceEngineV2._latent_refusals(config, cfg))
+        bad = [what for what, on in refused.items() if on]
+        if bad:
+            raise NotImplementedError(
+                who + " is served without: " + "; ".join(bad))
+
+    @staticmethod
+    def _pattern_refusals(config):
+        sm = config.state_manager
+        return ("a layer_types pattern (window and full per-head layers, a "
+                "cache of two geometries)", {
+            "tensor_parallel_size > 1 (the pattern's kernels and "
+            "stacks are written for one device)":
+                config.tensor_parallel_size > 1,
+            "expert_parallel_size > 1 (the expert layer as deployed "
+            "is served at ep = 1)": config.expert_parallel_size > 1,
+            "quant_bits (the quantiser does not know a stack a layer "
+            "kind, and the expert stack is read whole)":
+                bool(config.quant_bits),
+            "max_lora_adapters (the bank is one stack of every "
+            "layer's wq / wv; a pattern keeps a stack a kind)":
+                config.max_lora_adapters > 0,
+            "enable_prefix_caching (a shared block holds the full "
+            "layers' keys and values only: a row that skipped a "
+            "prefix would find its ring empty)":
+                sm.enable_prefix_caching,
+            "enable_kv_spill (the spill tier moves the blocks of one "
+            "geometry and no ring)": sm.enable_kv_spill,
+            "ragged_attention 'off' (the stitched prefill / continue "
+            "programs have no form for the walk of runs)":
+                config.ragged_attention == "off"})
+
+    @staticmethod
+    def _latent_refusals(config, cfg):
         sm = config.state_manager
         state = cfg.has_state
-        refused = {
+        return "attention='mla'" + (
+            " with linear-attention layers" if state else ""), {
             "tensor_parallel_size > 1 (the latent projections and the "
             "kernel are written for one device)":
                 config.tensor_parallel_size > 1,
@@ -546,12 +615,6 @@ class InferenceEngineV2:
                 config.ragged_attention == "off",
             "kv_quant (the int8 latent pool has not been served beside "
             "state leaves)": state and config.kv_quant}
-        bad = [what for what, on in refused.items() if on]
-        if bad:
-            raise NotImplementedError(
-                "attention='mla'" + (" with linear-attention layers"
-                                     if state else "")
-                + " is served without: " + "; ".join(bad))
 
     # ------------------------------------------------------------------
     # Telemetry (unified registry, telemetry/registry.py)
@@ -634,6 +697,21 @@ class InferenceEngineV2:
             "their chunked form as the kernel kda_chunk_fwd (0 for a "
             "model without such layers, and where the backend or the "
             "widths leave it to the XLA form)")
+        self._m_prefill_chunks = reg.counter(
+            "inference_prefill_chunks_total",
+            "ragged steps put() ran for a prompt set it fed in chunks (a "
+            "call that fits one step counts none)")
+        self._m_pool_bytes = reg.gauge(
+            "inference_kv_pool_bytes",
+            "bytes of the cache's key / value (or latent) leaves, scales "
+            "included, by geometry: \"full\" (a sequence holds every "
+            "position) and \"window\" (a ring a sequence: the "
+            "window-attention layers of a layer_types pattern)",
+            unit="bytes", labelnames=("kind",))
+        self._m_blocks_in_use = reg.gauge(
+            "inference_kv_blocks_in_use",
+            "blocks owned by tracked sequences or the prefix index, by "
+            "geometry", labelnames=("kind",))
         self._m_spec_drafted = reg.counter(
             "inference_spec_drafted_tokens_total",
             "speculative tokens drafted for verification")
@@ -737,6 +815,10 @@ class InferenceEngineV2:
             self._m_kv_util_peak.set(util)
         self._m_tracked.set(sm.tracked_sequences())
         self._m_state_slots.set(sm.state_slots_in_use())
+        self._m_blocks_in_use.labels(kind="full").set(
+            usable - sm.free_blocks())
+        self._m_blocks_in_use.labels(kind="window").set(
+            sm.window_blocks_in_use())
 
     # ------------------------------------------------------------------
     # Ragged mode (config_v2.ragged_attention: auto | on | off)
@@ -935,13 +1017,31 @@ class InferenceEngineV2:
         return {
             "seen_tokens": seq.seen_tokens if seq else 0,
             "free_blocks": self.state_manager.free_blocks(),
+            **({"free_window_blocks":
+                self.state_manager.window_allocator.free_blocks}
+               if self._has_ring else {}),
             "tracked_sequences": self.state_manager.tracked_sequences(),
             "max_seq_len": self.state_manager.config.max_seq_len,
         }
 
     def can_schedule(self, uids: Sequence[int],
                      lengths: Sequence[int]) -> bool:
-        total_new = 0
+        """Whether ONE step can take ``lengths[i]`` more tokens of each
+        ``uids[i]``: both pools hold them (:meth:`_can_hold`), the step
+        its token budget, and a row of a model whose window layers keep
+        a ring no more than ``max_row_chunk``."""
+        return self._can_hold(uids, lengths) and self._fits_a_step(lengths)
+
+    def _fits_a_step(self, lengths: Sequence[int]) -> bool:
+        cap = self.max_row_chunk
+        return sum(lengths) \
+            <= self.state_manager.config.max_ragged_batch_size \
+            and (cap is None or max(lengths, default=0) <= cap)
+
+    def _can_hold(self, uids: Sequence[int],
+                  lengths: Sequence[int]) -> bool:
+        sm = self.state_manager
+        total_new = ring_new = 0
         # retained prefix blocks are evictable on demand (ensure_blocks
         # evicts LRU) — counting only free blocks would spuriously
         # reject requests once the index occupies the pool
@@ -949,13 +1049,12 @@ class InferenceEngineV2:
         for uid, n in zip(uids, lengths):
             if not self.state_manager.can_schedule(uid, n):
                 return False
-            seq = self.state_manager.seqs.get(uid)
-            if seq is not None:
-                total_new += seq.blocks_needed(n, self.block_size)
-            else:
-                total_new += -(-n // self.block_size)
-        return total_new <= free and \
-            sum(lengths) <= self.state_manager.config.max_ragged_batch_size
+            seq = sm.seqs.get(uid) or DSSequenceDescriptor(uid=uid)
+            total_new += seq.blocks_needed(n, self.block_size)
+            ring_new += seq.window_blocks_needed(n, self.block_size,
+                                                 sm.ring_blocks)
+        return total_new <= free and (
+            not ring_new or ring_new <= sm.window_allocator.free_blocks)
 
     # ------------------------------------------------------------------
     # Bucketing (shared rules: utils/bucketing.py — the same helpers key
@@ -1185,10 +1284,11 @@ class InferenceEngineV2:
         init (tests); production passes the trained draft weights."""
         dcfg = model.cfg
         cfg = self.model.cfg
-        if "mla" in (cfg.attention, dcfg.attention):
+        if cfg.walks_runs or dcfg.walks_runs:
             raise NotImplementedError(
-                "draft-model speculation with an attention='mla' target "
-                "or draft: the verify pass has no latent form")
+                "draft-model speculation with an attention='mla' or "
+                "layer_types target or draft: the verify pass has no form "
+                "for the walk of runs (and none over a ring)")
         if dcfg.vocab_size != cfg.vocab_size:
             raise DraftModelMismatchError(
                 f"draft vocab_size {dcfg.vocab_size} != target "
@@ -1459,6 +1559,20 @@ class InferenceEngineV2:
         tables = tables[:, :self._pow2_bucket(used_pages, MB)]
         return N, toks, pos, tables
 
+    def _window_tables(self, uids: List[int], N: int) -> tuple:
+        """What a decode program takes behind its state slots: ``([N,
+        ring blocks] int32,)``, each row's ring in the window layers'
+        pool (the null block for padding rows), or ``()`` for a model
+        without one, whose programs are then called as they always
+        were."""
+        if not self._has_ring:
+            return ()
+        sm = self.state_manager
+        tables = np.full((N, sm.ring_blocks), NULL_BLOCK, np.int32)
+        for i, uid in enumerate(uids):
+            tables[i] = sm.window_table_for(uid)
+        return (jnp.asarray(tables),)
+
     def _state_slots(self, uids: List[int], N: int):
         """[N] int32: each row's slot of recurrent state, the null slot
         for padding rows; None for a model that keeps none."""
@@ -1492,7 +1606,8 @@ class InferenceEngineV2:
             with trace.span("step_dispatch"):
                 vals, *moe, self.kv_cache = jit_fn(
                     self.params, toks, pos, tables, self.kv_cache, active,
-                    lb, aid, self._state_slots(uids, active.shape[0]))
+                    lb, aid, self._state_slots(uids, active.shape[0]),
+                    *self._window_tables(uids, active.shape[0]))
             with trace.span("step_fetch"):
                 # blocks: the pass completes here
                 vals, moe = jax.device_get((vals, moe))
@@ -1557,9 +1672,10 @@ class InferenceEngineV2:
             temperature, top_p, top_k)
         return self._decode_common(
             uids, tokens,
-            lambda p, t, pos, bt, c, a, lb, aid, ss: self._decode_sample_jit(
+            lambda p, t, pos, bt, c, a, lb, aid, ss, *wt:
+            self._decode_sample_jit(
                 p, t, pos, bt, c, a, rng, seeds, g0, temp, topp, topk,
-                lb, aid, ss),
+                lb, aid, ss, *wt),
             lambda v, i: int(v[i]))
 
     # -- fused multi-token decode window --------------------------------
@@ -1619,7 +1735,8 @@ class InferenceEngineV2:
                     self.params, state[0], state[1], jnp.asarray(tables),
                     self.kv_cache, self._pad_i32(N, steps_left),
                     jnp.asarray(eos), state[2], *extra, lb, aid,
-                    self._state_slots(uids, N))
+                    self._state_slots(uids, N),
+                    *self._window_tables(uids, N))
                 if behind is not None:
                     self._m_windows_ahead.inc()
                 win = _Window(
@@ -1767,14 +1884,18 @@ class InferenceEngineV2:
 
     # -- ragged unified step --------------------------------------------
     def step_ragged(self, batch_uids: Sequence[int],
-                    batch_tokens: Sequence[Iterable[int]]) -> np.ndarray:
+                    batch_tokens: Sequence[Iterable[int]],
+                    chunk: Optional[tuple] = None) -> np.ndarray:
         """One compiled launch for a MIXED batch: prompt chunks,
         continuations and decode rows pack into a single
         :class:`~.ragged.batch.RaggedBatch` and run through the unified
         ragged program (paged_model.paged_ragged_step) — the dispatch
         put() previously sequenced through the prefill / continue /
         decode program families. Same contract as put(): returns
-        [len(batch_uids), vocab] last-token logits per entry."""
+        [len(batch_uids), vocab] last-token logits per entry. ``chunk``
+        = (i, n): this is step i of the n that put() runs for a prompt
+        set fed in chunks (the span's ``chunk`` / ``chunks`` attrs; the
+        tables then keep their full width, one program for all n)."""
         sm = self.state_manager
         with trace.span("ragged_pack") as packed:
             entries = [(int(uid),
@@ -1812,10 +1933,12 @@ class InferenceEngineV2:
                     seq = sm.get_or_create_sequence(uid)
                     seq.adapter = self._uid_adapter.get(int(uid))
                     seq.adapter_slot = self._adapter_slot_of(uid)
-            rb = ragged_batch.pack(entries, sm)
+            rb = ragged_batch.pack(entries, sm, full_width=chunk is not None)
         with trace.span("ragged_step", rows=len(entries),
                         tokens=rb.total_tokens,
                         uids=[u for u, _ in entries],
+                        **(dict(chunk=chunk[0], chunks=chunk[1])
+                           if chunk is not None else {}),
                         **self._trace_attrs(u for u, _ in entries)) as step:
             with trace.span("ragged_dispatch"):
                 logits, *moe, self.kv_cache = self._ragged_jit(
@@ -1829,7 +1952,9 @@ class InferenceEngineV2:
                     (jnp.asarray(rb.adapter_slots)
                      if self.lora_bank is not None else None),
                     (jnp.asarray(rb.state_slots)
-                     if self._has_state else None))
+                     if self._has_state else None),
+                    *((jnp.asarray(rb.window_tables),)
+                      if self._has_ring else ()))
             with trace.span("ragged_fetch"):
                 # blocks: the pass completes here
                 logits, moe = jax.device_get((logits, moe))
@@ -1874,9 +1999,22 @@ class InferenceEngineV2:
         for the last token of each entry. With ragged attention enabled
         (config_v2.ragged_attention) the whole batch runs as ONE unified
         ragged launch; otherwise the stitched dispatch below sequences
-        prefills, continuations and the batched decode."""
+        prefills, continuations and the batched decode.
+
+        A prompt set of more tokens than ``max_ragged_batch_size`` (or,
+        for a model whose window layers keep a ring, a row of more than
+        ``max_row_chunk``) goes in as SEVERAL ragged steps, each row's
+        tokens in consecutive chunks, the rows in lock step (a step's
+        budget shared evenly among the rows that have tokens left): one
+        program signature for all of them, and the logits returned are
+        each row's from the step its last token went in. A call that
+        fits one step is that one step, as before."""
         if self.ragged_enabled:
-            return self.step_ragged(batch_uids, batch_tokens)
+            plan = self._chunk_plan([len(np.atleast_1d(t))
+                                     for t in batch_tokens])
+            if len(plan) == 1:
+                return self.step_ragged(batch_uids, batch_tokens)
+            return self._put_chunks(batch_uids, batch_tokens, plan)
         sm = self.state_manager
         entries = [(int(uid), np.atleast_1d(np.asarray(toks, np.int64)))
                    for uid, toks in zip(batch_uids, batch_tokens)]
@@ -1916,6 +2054,51 @@ class InferenceEngineV2:
                                       + sm.config.max_tracked_sequences]
                 results.update(self._decode_batch(chunk_u, chunk_t))
         return np.stack([results[uid] for uid, _ in entries])
+
+    def _chunk_plan(self, lengths: Sequence[int]) -> List[List[int]]:
+        """How put() feeds rows of ``lengths`` tokens: a list of steps,
+        each the tokens every row feeds in it. One step where the whole
+        set fits ``max_ragged_batch_size`` and every row its
+        ``max_row_chunk``; else the rows with tokens left share each
+        step's budget evenly, in whole blocks."""
+        if self._fits_a_step(lengths):
+            return [list(lengths)]
+        budget = self.state_manager.config.max_ragged_batch_size
+        cap, bs, left, plan = self.max_row_chunk, self.block_size, \
+            list(lengths), []
+        while any(left):
+            share = budget // sum(1 for n in left if n)
+            share = max(share // bs * bs, 1)
+            take = [min(n, share, cap or share) for n in left]
+            plan.append(take)
+            left = [n - t for n, t in zip(left, take)]
+        return plan
+
+    def _put_chunks(self, batch_uids, batch_tokens, plan) -> np.ndarray:
+        """put() for a prompt set fed in ``plan``'s steps. Asks first
+        that BOTH pools hold every row's whole prompt (a step's own
+        ``can_schedule`` sees only that step), so that a call that
+        cannot finish raises before it has fed a token."""
+        rows = [np.atleast_1d(np.asarray(t, np.int64)) for t in batch_tokens]
+        uids = [int(u) for u in batch_uids]
+        if not self._can_hold(uids, [len(t) for t in rows]):
+            raise RuntimeError(
+                "batch not schedulable (KV blocks / sequence budget); "
+                "check can_schedule()/query() before put()")
+        out: Dict[int, np.ndarray] = {}
+        fed = [0] * len(rows)
+        for i, take in enumerate(plan):
+            live = [r for r, n in enumerate(take) if n]
+            logits = self.step_ragged(
+                [uids[r] for r in live],
+                [rows[r][fed[r]:fed[r] + take[r]] for r in live],
+                chunk=(i, len(plan)))
+            self._m_prefill_chunks.inc()
+            for at, r in enumerate(live):
+                fed[r] += take[r]
+                if fed[r] == len(rows[r]):
+                    out[r] = logits[at]
+        return np.stack([out[r] for r in range(len(rows))])
 
     # -- weight hot-swap (serve/weights.py) -----------------------------
     def note_weight_swap(self, seconds: float) -> None:
@@ -1981,6 +2164,51 @@ class InferenceEngineV2:
                 for name, leaf in self.kv_cache.items()
                 if name.startswith("kda_")}
 
+    def sequence_kv(self, uid: int, kind: str = "window"
+                    ) -> Dict[str, np.ndarray]:
+        """The keys and values a tracked sequence holds in the ``kind``
+        leaves ("window": its ring; "full": its blocks) of a
+        ``layer_types`` pattern, on the host, by POSITION: ``{"k", "v":
+        [layers of the kind, positions held, kv_heads * head_dim],
+        "positions": [positions held]}``, the positions ascending: every
+        one fed so far of a full layer, of a window layer the last
+        ``ring - block_size`` at most (the ring's places put back in
+        order; the oldest block is the one the next write lands on). An
+        int8 pool comes back dequantised. The read half of a snapshot,
+        beside ``sequence_state``."""
+        if self.model.cfg.layer_types is None or \
+                "k_" + kind not in self.kv_cache:
+            raise ValueError(f"sequence_kv: this model keeps no {kind!r} "
+                             f"leaves (no layer_types pattern with such "
+                             f"layers)")
+        sm, bs = self.state_manager, self.block_size
+        if not sm.known_seq(uid):
+            raise KeyError(f"sequence_kv: uid {uid} is not tracked")
+        seq = sm.seqs[uid]
+        n = seq.seen_tokens
+        if kind == "window":
+            ring = sm.ring_blocks * bs
+            first = max(0, -(-n // bs) * bs - (ring - bs))
+            blocks = np.asarray(seq.window_blocks, np.int32)
+        else:
+            ring, first = sm.max_blocks_per_seq * bs, 0
+            blocks = np.asarray(seq.blocks, np.int32)
+        pos = np.arange(first, n)
+        place = pos % ring
+        out = {"positions": pos}
+        for name in ("k", "v"):
+            leaf = np.asarray(
+                self.kv_cache[f"{name}_{kind}"][:, blocks]
+            ).astype(np.float32)              # [L, blocks, bs, F]
+            scales = self.kv_cache.get(f"{name}s_{kind}")
+            if scales is not None:
+                hd = self.model.cfg.head_dim
+                leaf = leaf * np.repeat(np.asarray(scales[:, blocks]), hd,
+                                        axis=-1)[:, :, None, :]
+            out[name] = leaf.reshape(leaf.shape[0], -1, leaf.shape[-1])[
+                :, place]
+        return out
+
     def flush(self, uid: int) -> None:
         """Release a finished sequence's KV blocks (reference flush).
         Also forgets the uid's speculative cold-streak state: uids are
@@ -2033,26 +2261,29 @@ class InferenceEngineV2:
         aid0 = (jax.ShapeDtypeStruct((), jnp.int32)
                 if self.lora_bank is not None else None)
         ssN = i32(N) if self._has_state else None
+        wtN = (i32(N, sm.ring_blocks),) if self._has_ring else ()
         programs: Dict[str, dict] = {}
         compiled = self._decode_tok_jit.lower(
             params, toks, pos, tables, cache,
-            jax.ShapeDtypeStruct((N,), jnp.bool_), lb, aidN, ssN).compile()
+            jax.ShapeDtypeStruct((N,), jnp.bool_), lb, aidN, ssN,
+            *wtN).compile()
         programs["decode_greedy"] = ds_memory.record_memory_analysis(
             "decode_greedy", compiled)
         if self.decode_window > 1:
             compiled = self._fused_greedy_jit.lower(
                 params, toks, pos, tables, cache, i32(N), i32(N),
                 jax.ShapeDtypeStruct((N,), jnp.bool_), lb, aidN,
-                ssN).compile()
+                ssN, *wtN).compile()
             programs["decode_window_greedy"] = \
                 ds_memory.record_memory_analysis("decode_window_greedy",
                                                  compiled)
-        C = self._bucket(self.config.prefill_bucket)
-        compiled = self._prefill_jit.lower(
-            params, i32(1, C), jax.ShapeDtypeStruct((), jnp.int32), cache,
-            i32(C), i32(C), lb, aid0).compile()
-        programs["prefill"] = ds_memory.record_memory_analysis(
-            "prefill", compiled)
+        if not self.model.cfg.walks_runs:   # no stitched form of the walk
+            C = self._bucket(self.config.prefill_bucket)
+            compiled = self._prefill_jit.lower(
+                params, i32(1, C), jax.ShapeDtypeStruct((), jnp.int32),
+                cache, i32(C), i32(C), lb, aid0).compile()
+            programs["prefill"] = ds_memory.record_memory_analysis(
+                "prefill", compiled)
         if self.ragged_enabled:
             # a representative mixed bucket: one prefill chunk plus a
             # decode row per batch slot, full table width (the
@@ -2065,7 +2296,7 @@ class InferenceEngineV2:
             compiled = self._ragged_jit.lower(
                 params, i32(TB), i32(TB), i32(TB), i32(TB), i32(TB),
                 i32(TB), i32(N, MB), i32(N), cache, lb, aidN,
-                ssN).compile()
+                ssN, *wtN).compile()
             programs["ragged_step"] = dict(
                 ds_memory.record_memory_analysis("ragged_step", compiled),
                 token_bucket=TB, row_bucket=N)
@@ -2113,10 +2344,11 @@ class InferenceEngineV2:
                 assert not (speculative and sampling), \
                     "speculative decoding is greedy-only (draft " \
                     "verification compares argmax)"
-                if speculative and self.model.cfg.attention == "mla":
+                if speculative and self.model.cfg.walks_runs:
                     raise NotImplementedError(
-                        "speculative decoding of an attention='mla' model: "
-                        "the verify pass has no latent form")
+                        "speculative decoding of an attention='mla' or "
+                        "layer_types model: the verify pass has no form "
+                        "for the walk of runs (and none over a ring)")
                 # each generate() call is an independent request batch: spec
                 # cold-streaks (and draft indexes) from earlier calls must not
                 # leak into this one

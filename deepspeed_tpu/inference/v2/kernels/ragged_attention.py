@@ -137,7 +137,7 @@ def kernel_variant(head_dim: int, kv_heads: int, kv_quant: bool) -> str:
 
 
 def _page_update(q_ref, k_ref, v_ref, ks, vs, j, length, acc_sc, m_sc,
-                 l_sc, *, bs, scale, kvh, group, io_dtype):
+                 l_sc, *, bs, scale, kvh, group, io_dtype, window=0):
     """One page's online-softmax update, all kv heads (shared by both
     variants so their numerics cannot diverge). k_ref/v_ref are the
     page's (1, 1, bs, kvh * hd) block as stored, a head its ``hd`` lanes
@@ -147,8 +147,13 @@ def _page_update(q_ref, k_ref, v_ref, ks, vs, j, length, acc_sc, m_sc,
     arithmetic as paged_model._kv_read's gather dequant (bit-identical
     at fp32 io; one rounding at bf16). GQA is a static Python loop (kvh
     is a compile-time constant), each head updating its own rows of the
-    flat (kvh*group, ...) scratch."""
+    flat (kvh*group, ...) scratch. ``j`` is the page's index among the
+    row's POSITIONS (``window``: positions under ``length - window`` are
+    masked too)."""
     pos = j * bs + jax.lax.broadcasted_iota(jnp.int32, (group, bs), 1)
+    seen = pos < length
+    if window:
+        seen = seen & (pos >= length - window)
     hd = q_ref.shape[-1]
     for h in range(kvh):                              # static unroll (GQA)
         rows = slice(h * group, (h + 1) * group)
@@ -162,7 +167,7 @@ def _page_update(q_ref, k_ref, v_ref, ks, vs, j, length, acc_sc, m_sc,
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         s = s * scale
-        s = jnp.where(pos < length, s, NEG_INF)
+        s = jnp.where(seen, s, NEG_INF)
         m_prev = m_sc[rows, :1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         p = jnp.exp(s - m_new)
@@ -204,23 +209,35 @@ def _scale_rows(ks_ref, vs_ref, j, kvh):
 def _pipelined_kernel(layer_ref, row_ref, len_ref, bt_ref, q_ref, k_ref,
                       v_ref, *rest, quant, n_pages, **static):
     """Grid (T, MB): token t streams page j of ITS row's table out of
-    the whole pool (index map ``(layer, bt[row[t], j])``)."""
+    the whole pool (index map ``(layer, bt[row[t], j])``). With a
+    ``window`` the table is a ring (position p at place ``(p // bs) %
+    MB``): place j holds the newest page of the token's positions that
+    is congruent to j, and is computed where that page reaches into the
+    window."""
     (ks_ref, vs_ref), rest = (rest[:2], rest[2:]) if quant \
         else ((None, None), rest)
     o_ref, acc_sc, m_sc, l_sc = rest
     t = pl.program_id(0)
     j = pl.program_id(1)
+    bs, window = static["bs"], static.get("window", 0)
 
     @pl.when(j == 0)
     def _init():
         _init_scratch(acc_sc, m_sc, l_sc)
 
     length = len_ref[t]
+    if window:
+        newest = jnp.maximum(length - 1, 0) // bs
+        page = newest - (newest - j) % n_pages
+        live = (length > 0) & (page >= 0) \
+            & ((page + 1) * bs > length - window)
+    else:
+        page, live = j, j * bs < length
 
-    @pl.when(j * static["bs"] < length)
+    @pl.when(live)
     def _body():
         ks, vs = _scale_rows(ks_ref, vs_ref, j, static["kvh"])
-        _page_update(q_ref, k_ref, v_ref, ks, vs, j, length,
+        _page_update(q_ref, k_ref, v_ref, ks, vs, page, length,
                      acc_sc, m_sc, l_sc, **static)
 
     @pl.when(j == n_pages - 1)
@@ -247,21 +264,26 @@ def tiled_geometry(head_dim: int, kv_heads: int):
     return None
 
 
-def _visible(tl_ref, t0, first, last, c, tq, reps, P):
+def _visible(tl_ref, t0, first, last, c, tq, reps, P, base=0, window=0):
     """``(reps * tq, P)``: which of chunk c's positions each query row
     of the tile may attend. Query rows are ``reps`` copies of the tile's
     tokens; a token outside [first, last] (another row's) sees nothing,
     one inside sees the positions under its own causal bound
-    (``tl_ref``, (tq, 1))."""
+    (``tl_ref``, (tq, 1)) and, with a ``window``, not under ``bound -
+    window``. ``base``: the position the row's chunk 0 starts at (0
+    without a window: the walk starts at the row's first page)."""
     tok = t0 + jax.lax.broadcasted_iota(jnp.int32, (tq, 1), 0)
     eff = jnp.where((tok >= first) & (tok <= last), tl_ref[...], 0)
     eff = jnp.concatenate([eff] * reps, axis=0)
-    return c * P + jax.lax.broadcasted_iota(
-        jnp.int32, (reps * tq, P), 1) < eff
+    pos = c * P + jax.lax.broadcasted_iota(jnp.int32, (reps * tq, P), 1)
+    if not window:
+        return pos < eff
+    pos = pos + base
+    return (pos < eff) & (pos >= eff - window)
 
 
 def _walk_rows(len_ref, bt_ref, first_ref, last_ref, lo, hi, *, t0, tq, bs,
-               cp, copies, compute, chunk_copies=None):
+               cp, copies, compute, chunk_copies=None, window=0, ring=0):
     """The walk the tiled and the latent kernel share: the tile of flat
     tokens [t0, t0 + tq) visits the rows ``lo..hi`` that own its tokens,
     a row's pages in chunks of ``cp`` up to the causal bound of the
@@ -271,32 +293,57 @@ def _walk_rows(len_ref, bt_ref, first_ref, last_ref, lo, hi, *, t0, tq, bs,
     slot)``: those a whole chunk needs besides); the next chunk, of this
     row or the next, is in flight while ``compute(first, last, c,
     slot)`` runs on this one ([first, last]: the row's tokens in the
-    tile)."""
+    tile).
+
+    ``window`` > 0 (static): a token sees only its last ``window``
+    positions, so a row's walk STARTS at the page that holds the first
+    position its earliest token in the tile sees (no page wholly below
+    that is copied or visited), chunk c is the ``cp`` pages from there,
+    and ``compute`` gets that page's first position as a fifth argument.
+    The row's table is then a ring of ``ring`` places: the page of
+    positions ``[b * bs, (b + 1) * bs)`` is ``bt_ref[r, b % ring]``
+    (a table that holds every position is a ring that never wraps)."""
     P, T = cp * bs, len_ref.shape[0]
 
     def bounds(r):
-        """Row r's tokens in this tile [first, last] and the causal bound
-        of the last of them (0: the row has no token here)."""
+        """Row r's tokens in this tile [first, last], the causal bound
+        of the last of them (0: the row has no token here) and the first
+        page of its walk."""
         r = jnp.minimum(r, first_ref.shape[0] - 1)   # hi + 1 is asked too
         first = jnp.maximum(first_ref[r], t0)
         last = jnp.minimum(last_ref[r], t0 + tq - 1)
         kv = jnp.where(first <= last, len_ref[jnp.clip(last, 0, T - 1)], 0)
-        return first, last, kv
+        if not window:
+            return first, last, kv, 0
+        low = len_ref[jnp.clip(first, 0, T - 1)] - window
+        return first, last, kv, jnp.maximum(low, 0) // bs
 
     def n_chunks(r):
-        return (bounds(r)[2] + P - 1) // P
+        _, _, kv, page0 = bounds(r)
+        if not window:
+            return (kv + P - 1) // P
+        # a row with no token here has no bound and no window either
+        return jnp.maximum(kv - page0 * bs + P - 1, 0) // P
 
     def next_row(r):
         return jax.lax.while_loop(
             lambda r: (r <= hi) & (n_chunks(r) == 0), lambda r: r + 1, r)
 
     def pages(r, c):
-        return jnp.minimum((bounds(r)[2] + bs - 1) // bs - c * cp, cp)
+        _, _, kv, page0 = bounds(r)
+        if not window:
+            return jnp.minimum((kv + bs - 1) // bs - c * cp, cp)
+        return jnp.minimum((kv + bs - 1) // bs - page0 - c * cp, cp)
 
     def each_copy(r, c, slot, act):
         """``act`` (start or wait) on every copy of chunk c of row r."""
+        page0 = bounds(r)[3] if window else 0
+
         def one(j, _):
-            for dma in copies(bt_ref[r, c * cp + j], slot, j):
+            at = c * cp + j
+            if window:
+                at = (page0 + at) % ring
+            for dma in copies(bt_ref[r, at], slot, j):
                 act(dma)
             return 0
         jax.lax.fori_loop(0, pages(r, c), one, 0)
@@ -327,8 +374,11 @@ def _walk_rows(len_ref, bt_ref, first_ref, last_ref, lo, hi, *, t0, tq, bs,
             start(nr, nc, 1 - slot)
 
         wait(r, c, slot)
-        first, last_tok, _ = bounds(r)
-        compute(first, last_tok, c, slot)
+        first, last_tok, _, page0 = bounds(r)
+        if window:
+            compute(first, last_tok, c, slot, page0 * bs)
+        else:
+            compute(first, last_tok, c, slot)
         return nr, nc, 1 - slot
 
     jax.lax.while_loop(lambda state: state[0] <= hi, step,
@@ -365,7 +415,8 @@ def _tile_update(q, k, v, visible, acc_sc, m_sc, l_sc, b, *, scale):
 
 def _tiled_kernel(layer_ref, len_ref, bt_ref, first_ref, last_ref, lo_ref,
                   hi_ref, q_ref, tl_ref, k_hbm, v_hbm, *rest, quant, bs,
-                  scale, kvh, hd, hpb, group, tq, cp, io_dtype):
+                  scale, kvh, hd, hpb, group, tq, cp, io_dtype, window=0,
+                  ring=0):
     """Grid (T / tq,): one step a tile of ``tq`` flat tokens. The tile
     walks the rows that own its tokens (``lo_ref``/``hi_ref``), a row's
     pages in chunks of ``cp`` up to the causal bound of the row's last
@@ -427,8 +478,9 @@ def _tiled_kernel(layer_ref, len_ref, bt_ref, first_ref, last_ref, lo_ref,
         return (x.astype(jnp.float32).reshape(P, bw)
                 * jnp.concatenate(rows, axis=0)).astype(io_dtype)
 
-    def compute(first, last, c, slot):
-        visible = _visible(tl_ref, t0, first, last, c, tq, rpb, P)
+    def compute(first, last, c, slot, base=0):
+        visible = _visible(tl_ref, t0, first, last, c, tq, rpb, P, base,
+                           window)
         for b in range(nblk):                                 # static
             lanes = slice(b * bw, (b + 1) * bw)
             k = k_buf[slot, :, :, lanes]
@@ -442,7 +494,8 @@ def _tiled_kernel(layer_ref, len_ref, bt_ref, first_ref, last_ref, lo_ref,
 
     _walk_rows(len_ref, bt_ref, first_ref, last_ref, lo, hi, t0=t0, tq=tq,
                bs=bs, cp=cp, copies=copies, compute=compute,
-               chunk_copies=scale_copies if quant else None)
+               chunk_copies=scale_copies if quant else None, window=window,
+               ring=ring)
 
     lane = jax.lax.broadcasted_iota(jnp.int32, (group * tq, bw), 1)
     for b in range(nblk):                                     # static
@@ -472,7 +525,7 @@ def _row_descriptors(row_ids, lengths, R, tq):
 
 
 def _tiled_call(q, k_cache, v_cache, layer, row_ids, lengths, block_tables,
-                k_scale, v_scale, interpret):
+                k_scale, v_scale, interpret, window=0):
     """Lay the operands out for :func:`_tiled_kernel` and undo it: the
     per-row descriptor (first and last flat token, from ``row_ids`` and
     ``lengths``: a row's tokens are contiguous in pack order), a tile's
@@ -486,6 +539,13 @@ def _tiled_call(q, k_cache, v_cache, layer, row_ids, lengths, block_tables,
     bw, hpb = tiled_geometry(hd, kvh)
     nblk, rpb = kvh // hpb, hpb * group
     quant = k_scale is not None
+    if window and quant:
+        raise NotImplementedError(
+            "the tiled kernel takes a window over a bf16 / float32 pool: a "
+            "chunk of its walk no longer starts at a chunk of the table, "
+            "where an int8 pool's scales are sliced (dequantise the layer "
+            "first: paged_model._per_head_attention_sublayer)")
+    ring = MB             # the table's places, before the padding below
     # a tile: a power of two of 16 to 128 tokens, at most 512 query rows
     # a lane block where that leaves 16
     tq = max(16, min(pow2_bucket(T0, 128),
@@ -538,7 +598,8 @@ def _tiled_call(q, k_cache, v_cache, layer, row_ids, lengths, block_tables,
                 pltpu.VMEM((nblk, M, 128), jnp.float32)]
     kernel = functools.partial(
         _tiled_kernel, quant=quant, bs=bs, scale=1.0 / (hd ** 0.5), kvh=kvh,
-        hd=hd, hpb=hpb, group=group, tq=tq, cp=cp, io_dtype=q.dtype)
+        hd=hd, hpb=hpb, group=group, tq=tq, cp=cp, io_dtype=q.dtype,
+        **(dict(window=window, ring=ring) if window else {}))
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -552,7 +613,8 @@ def _tiled_call(q, k_cache, v_cache, layer, row_ids, lengths, block_tables,
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=_TILED_VMEM_BYTES),
         interpret=pltpu.InterpretParams() if interpret else False,
-        name="ragged_attention_tiled",
+        name="ragged_attention_window" if window
+        else "ragged_attention_tiled",
     )(layer, lengths, block_tables, row_first, row_last, tile_lo, tile_hi,
       *operands)
     out = out.reshape(nblk, group, T, hpb, hd).transpose(2, 0, 3, 1, 4)
@@ -565,7 +627,8 @@ def ragged_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
                      block_tables: jnp.ndarray,
                      k_scale: jnp.ndarray = None,
                      v_scale: jnp.ndarray = None,
-                     variant: Optional[str] = None) -> jnp.ndarray:
+                     variant: Optional[str] = None,
+                     window: int = 0) -> jnp.ndarray:
     """Ragged paged attention (serving hot path).
 
     q [T, nh, hd] flat token buffer; k/v_cache the pool's leaves as
@@ -581,7 +644,16 @@ def ragged_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
     defaults to :func:`kernel_variant`'s static choice for the pool
     geometry; off-TPU the default is the pipelined variant in interpret
     mode, and ``variant="tiled"`` runs the tiled one under the TPU
-    interpreter, DMAs and semaphores included. Returns [T, nh, hd]."""
+    interpreter, DMAs and semaphores included. Returns [T, nh, hd].
+
+    ``window`` (static; 0: none, today's kernels bit for bit): a token
+    with bound n sees positions ``[n - window, n)`` only, and a row's
+    table is read as a RING: the page of positions ``[b * bs, (b + 1) *
+    bs)`` lies at place ``b % MB``, so a pool whose rows own ``window +
+    largest chunk + one block`` positions serves any context (a table
+    that holds every position never wraps). The tiled variant starts a
+    row's walk at its window's first page; in a trace the launch is
+    ``ragged_attention_window`` (tiled) / ``..._pipelined_window``."""
     T, nh, hd = q.shape
     bs, F = k_cache.shape[2:]
     kvh = F // hd
@@ -604,10 +676,11 @@ def ragged_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
         if tiled_geometry(hd, kvh) is None:
             raise ValueError(f"no tiled variant for {kvh} kv heads of {hd}")
         return _tiled_call(q, k_cache, v_cache, layer, row_ids, lengths,
-                           block_tables, k_scale, v_scale, interpret)
+                           block_tables, k_scale, v_scale, interpret,
+                           window)
     q4 = q.reshape(T, kvh, group, hd)
     static = dict(bs=bs, scale=1.0 / (hd ** 0.5), kvh=kvh, group=group,
-                  io_dtype=q.dtype)
+                  io_dtype=q.dtype, **({"window": window} if window else {}))
 
     # index maps see (grid indices..., layer, row_ids, lengths, block_tables)
     def tok(t, *_):
@@ -645,9 +718,46 @@ def ragged_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
             ]),
         out_shape=jax.ShapeDtypeStruct((T, kvh, group, hd), q.dtype),
         interpret=interpret,
-        name="ragged_attention_pipelined",
+        name="ragged_attention_pipelined" + "_window" * bool(window),
     )(layer, row_ids, lengths, block_tables, *operands)
     return out.reshape(T, nh, hd)
+
+
+def ragged_attention_reference(q, k_cache, v_cache, layer, row_ids, lengths,
+                               block_tables, k_scale=None, v_scale=None,
+                               window: int = 0):
+    """:func:`ragged_attention` by gathering, window and ring included:
+    each row's pages once, every token against the positions its row's
+    table holds under its own bound (and inside its window), a dense
+    masked softmax in float32. Place i of a table of MB pages holds the
+    newest position p < bound with p = i mod (MB * bs), which for a
+    table that holds every position is i itself. The ``jnp:gather``
+    path of a layer pattern's per-head layers and the kernels' parity
+    reference."""
+    T, nh, hd = q.shape
+    R, MB = block_tables.shape
+    bs, F = k_cache.shape[2:]
+    kvh = F // hd
+
+    def rows(cache, scale):
+        pages = cache[layer][block_tables].reshape(R, MB, bs, kvh, hd)
+        if scale is not None:
+            pages = (pages.astype(jnp.float32) * scale[block_tables][
+                :, :, None, :, None]).astype(q.dtype)
+        return jnp.repeat(pages.reshape(R, MB * bs, kvh, hd), nh // kvh,
+                          axis=2)[row_ids]                # [T, ctx, nh, hd]
+
+    k, v = rows(k_cache, k_scale), rows(v_cache, v_scale)
+    ctx = MB * bs
+    newest = lengths[:, None] - 1
+    pos = newest - (newest - jnp.arange(ctx)[None, :]) % ctx   # [T, ctx]
+    seen = (pos >= 0) & (lengths[:, None] > 0)
+    if window:
+        seen = seen & (pos >= lengths[:, None] - window)
+    s = jnp.einsum("thd,tchd->thc", q, k).astype(jnp.float32) / hd ** 0.5
+    p = jax.nn.softmax(jnp.where(seen[:, None, :], s, NEG_INF), axis=-1)
+    p = jnp.where(lengths[:, None, None] > 0, p, 0.0)   # padding: zeros
+    return jnp.einsum("thc,tchd->thd", p.astype(q.dtype), v)
 
 
 # ---------------------------------------------------------------------------

@@ -53,7 +53,8 @@ NEG_INF = -1e30
 
 def init_paged_kv_cache(cfg: TransformerConfig, num_blocks: int,
                         block_size: int, dtype, kv_quant: bool = False,
-                        state_slots: int = 0, state_dtype=jnp.float32
+                        state_slots: int = 0, state_dtype=jnp.float32,
+                        window_blocks: int = 0
                         ) -> Dict[str, jnp.ndarray]:
     """``kv_quant`` stores the pool int8 with PER-BLOCK (page x kv-head)
     fp32 scales — ~0.5x the bf16 bytes (scale overhead 4/(bs*hd) per
@@ -70,22 +71,43 @@ def init_paged_kv_cache(cfg: TransformerConfig, num_blocks: int,
     geometry and dtype (the module docstring says why); the int8 scales
     ``[L, nb, kv_heads]``. ``state_slots`` / ``state_dtype``: the
     recurrent state of a model with linear-attention layers
-    (``_init_latent_cache``)."""
-    assert cfg.is_causal and cfg.norm_scheme == "pre", \
+    (``_init_latent_cache``).
+
+    A leaf a layer KIND under a pattern over per-head attention
+    (``cfg.layer_types``): the full layers' ``k_full`` / ``v_full``
+    ``[L_full, nb, bs, kvh * hd]``, every position of a sequence as
+    above, and the window layers' ``k_window`` / ``v_window``
+    ``[L_window, window_blocks, bs, kvh * hd]``, a pool of its own in
+    which a sequence owns a RING: position p lies at place ``p % ring``
+    of the row's own table (``ragged/ragged_manager.py``), so a layer
+    that sees ``attn_window`` positions holds ``window + largest chunk +
+    one block`` and not the context. Each in layer order, each with its
+    int8 scales (``ks_full`` ...) under ``kv_quant``."""
+    assert cfg.is_causal and cfg.norm_scheme in ("pre", "sandwich"), \
         "paged serving requires a causal pre-LN model (the MLM/post-LN " \
         "encoder family does not decode)"
     if cfg.attention == "mla":
         return _init_latent_cache(cfg, num_blocks, block_size, dtype,
                                   kv_quant, state_slots, state_dtype)
-    shape = (cfg.num_layers, num_blocks, block_size,
-             cfg.kv_heads * cfg.head_dim)
-    if kv_quant:
-        sshape = (cfg.num_layers, num_blocks, cfg.kv_heads)
-        return {"k": jnp.zeros(shape, jnp.int8),
-                "v": jnp.zeros(shape, jnp.int8),
-                "ks": jnp.zeros(sshape, jnp.float32),
-                "vs": jnp.zeros(sshape, jnp.float32)}
-    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+
+    def leaves(layers, blocks, tag=""):
+        shape = (layers, blocks, block_size, cfg.kv_heads * cfg.head_dim)
+        if kv_quant:
+            sshape = (layers, blocks, cfg.kv_heads)
+            return {"k" + tag: jnp.zeros(shape, jnp.int8),
+                    "v" + tag: jnp.zeros(shape, jnp.int8),
+                    "ks" + tag: jnp.zeros(sshape, jnp.float32),
+                    "vs" + tag: jnp.zeros(sshape, jnp.float32)}
+        return {"k" + tag: jnp.zeros(shape, dtype),
+                "v" + tag: jnp.zeros(shape, dtype)}
+
+    if cfg.layer_types is not None:
+        kinds = cfg.layer_kinds
+        return {**(leaves(kinds.count("full"), num_blocks, "_full")
+                   if "full" in kinds else {}),
+                **(leaves(kinds.count("window"), window_blocks, "_window")
+                   if "window" in kinds else {})}
+    return leaves(cfg.num_layers, num_blocks)
 
 
 def latent_pool_row(cfg) -> int:
@@ -678,6 +700,80 @@ def _latent_attention_sublayer(cfg, lp, x, l, pool, cos, sin, row_ids,
     return o.reshape(T, nh * dv) @ lp["wo"], pool
 
 
+def _per_head_attention_sublayer(cfg, lp, x, kind, l, pool, cos, sin,
+                                 row_ids, lengths, write_blocks,
+                                 write_offsets, block_tables, use_kernel):
+    """A per-head (GQA) mixer of a layer pattern on flat tokens x
+    [T, H]: ``kind`` "full" (a token sees every position under its
+    bound) or "window" (its last ``cfg.attn_window``), ``l`` the layer's
+    index among its kind's (its leaves' leading axis). q, k, v and the
+    gate are projections of the normed input; q and k are RMS-normed a
+    head (``qk_norm``) and rotated (a full layer not, under
+    ``rope_sliding_only``); the new keys and values go to the kind's
+    leaves at ``write_blocks`` / ``write_offsets``, which for a window
+    layer are places of the row's ring; attention is
+    ``kernels/ragged_attention.ragged_attention`` over the kind's
+    tables (``window``: the walk starts at the window's first page), or
+    its gathering reference; the heads' output times sigmoid of the
+    gate, element-wise; ``wo``; the post-norm of the sandwich scheme.
+    An int8 pool is dequantised a layer at a time into a transient pool
+    of one layer (1 / L of the leaf at twice its bytes), as the latent
+    pool's is (``_latent_rows``). Returns
+    (what attention adds to x, pool)."""
+    from ...ops.norms import rms_norm
+    from .kernels.ragged_attention import (ragged_attention,
+                                           ragged_attention_reference)
+    T = x.shape[0]
+    nh, nkv, hd = cfg.num_heads, cfg.kv_heads, cfg.head_dim
+    dt = lp["wq"].dtype
+    hn = _norm(cfg, x, lp["attn_norm"]).astype(dt)
+    with jax.named_scope("qkv_proj"):
+        q = (hn @ lp["wq"]).reshape(T, nh, hd)
+        k = (hn @ lp["wk"]).reshape(T, nkv, hd)
+        v = (hn @ lp["wv"]).reshape(T, nkv, hd)
+        if cfg.qk_norm:
+            q = rms_norm(q, lp["q_norm"], cfg.norm_eps)
+            k = rms_norm(k, lp["k_norm"], cfg.norm_eps)
+        if kind == "window" or not cfg.rope_sliding_only:
+            q = _rotate(q, cos[:, None, :], sin[:, None, :])
+            k = _rotate(k, cos[:, None, :], sin[:, None, :])
+    tag = "_" + kind
+    kc, vc = pool["k" + tag], pool["v" + tag]
+    ksc, vsc = pool.get("ks" + tag), pool.get("vs" + tag)
+    kc, vc, ksc, vsc = _kv_write_pair(kc, vc, ksc, vsc, l, write_blocks,
+                                      write_offsets, k, v)
+    pool = {**pool, "k" + tag: kc, "v" + tag: vc}
+    if ksc is not None:
+        pool.update({"ks" + tag: ksc, "vs" + tag: vsc})
+    window = cfg.attn_window if kind == "window" else 0
+    with jax.named_scope("attn_kernel"):
+        if not use_kernel:
+            o = ragged_attention_reference(
+                q, kc, vc, l, row_ids, lengths, block_tables,
+                None if ksc is None else ksc[l],
+                None if vsc is None else vsc[l], window=window)
+        else:
+            at = l
+            if ksc is not None:
+                # every page of the layer through ``_kv_read``'s dequant
+                kc, vc = (_kv_read(c, sc, l, jnp.arange(c.shape[1]), nkv,
+                                   dt).reshape(1, *c.shape[1:])
+                          for c, sc in ((kc, ksc), (vc, vsc)))
+                at = jnp.int32(0)
+            o = ragged_attention(q, kc, vc, at, row_ids, lengths,
+                                 block_tables, window=window)
+    if cfg.attn_gate == "elementwise":
+        with jax.named_scope("attn_gate"):
+            o = o * jax.nn.sigmoid(
+                (hn @ lp["wg"]).astype(jnp.float32)
+            ).reshape(T, nh, hd).astype(o.dtype)
+    with jax.named_scope("out_proj"):
+        a = o.reshape(T, nh * hd) @ lp["wo"]
+        if cfg.norm_scheme == "sandwich":
+            a = _norm(cfg, a.astype(jnp.float32), lp["attn_post_norm"])
+    return a, pool
+
+
 def _moe_stats(topi, valid, num_experts, held_from=None):
     """What one expert layer routed in one launch, float32 [4]: 1 (a
     launch of an expert layer), the routed rows (valid tokens x k), the
@@ -838,8 +934,11 @@ def _layer_runs(cfg):
 
 def _pattern_step(cfg: TransformerConfig, params, ids, row_ids, pos, lengths,
                   write_blocks, write_offsets, block_tables, cache,
-                  use_kernel=True, state_slots=None, one_token=False):
-    """The whole block of an attention='mla' model on a flat token
+                  use_kernel=True, state_slots=None, one_token=False,
+                  window_tables=None):
+    """The whole block of a model that is served as RUNS of layers
+    (``cfg.walks_runs``: attention='mla', or a ``layer_types`` pattern
+    over per-head attention) on a flat token
     buffer, the one forward behind ``paged_ragged_step`` and
     ``paged_decode`` (a decode batch is the ragged layout with one token
     a row, ``one_token``): embedding, then the layers as RUNS of one
@@ -860,6 +959,17 @@ def _pattern_step(cfg: TransformerConfig, params, ids, row_ids, pos, lengths,
     ``_linear_attention_sublayer``). A run indexes the stacks it reads
     at its own layers inside the scan's body: a static slice of a stack
     handed to the scan would be a copy of it.
+
+    A pattern over PER-HEAD attention (``cfg.layer_types``) is the same
+    walk with the kinds "window" and "full"
+    (``_per_head_attention_sublayer``; stacks ``window_layers`` /
+    ``full_layers``, leaves ``k_window`` ... / ``k_full`` ...): a full
+    layer reads and writes by ``block_tables`` / ``write_blocks`` as
+    every model's pool, a window layer by ``window_tables`` [rows, ring
+    blocks], the row's ring, in which position p lies at place
+    ``p % ring`` (its write-set is that arithmetic on ``pos``). Under
+    the sandwich scheme each sub-layer's output passes a second norm
+    before it joins the stream.
 
     The stack's expert weights never ride a scan: a layer sliced out of
     them for the grouped-matmul kernel would be a copy of all its
@@ -886,8 +996,15 @@ def _pattern_step(cfg: TransformerConfig, params, ids, row_ids, pos, lengths,
     valid = lengths > 0
     lead = cfg.moe_first_dense_layers
     kinds = cfg.layer_kinds
-    pattern = cfg.linear_attn_period > 0
+    pattern = cfg.pattern
+    sandwich = cfg.norm_scheme == "sandwich"
     expert_keys = ("e_gate", "e_up", "e_down")
+    if window_tables is not None:
+        # a window layer's write-set: the ring place of each new position
+        bs = cache["k_window"].shape[2]
+        ring_blocks = window_tables.shape[1]
+        window_writes = jnp.where(
+            valid, window_tables[row_ids, (pos // bs) % ring_blocks], 0)
     rows = _state_rows(row_ids, pos, lengths, state_slots,
                        block_tables.shape[0], one_token) \
         if cfg.has_state else None
@@ -916,6 +1033,15 @@ def _pattern_step(cfg: TransformerConfig, params, ids, row_ids, pos, lengths,
                     a, pool = _linear_attention_sublayer(
                         cfg, lp, x, m0 + i, pool, rows, use_kernel)
                     x = x + a.astype(jnp.float32)
+            elif kind in ("window", "full"):
+                ring = kind == "window"
+                with jax.named_scope("attention"):
+                    a, pool = _per_head_attention_sublayer(
+                        cfg, lp, x, kind, m0 + i, pool, cos, sin, row_ids,
+                        lengths, window_writes if ring else write_blocks,
+                        write_offsets,
+                        window_tables if ring else block_tables, use_kernel)
+                    x = x + a.astype(jnp.float32)
             else:
                 with jax.named_scope("mla_attention"):
                     a, pool = _latent_attention_sublayer(
@@ -939,6 +1065,9 @@ def _pattern_step(cfg: TransformerConfig, params, ids, row_ids, pos, lengths,
                         hn = hn.astype(dtype)
                         out = (gate_act(cfg)(hn @ lp["w_gate"])
                                * (hn @ lp["w_up"])) @ lp["w_down"]
+                if sandwich:
+                    out = _norm(cfg, out.astype(jnp.float32),
+                                lp["mlp_post_norm"])
                 x = x + out.astype(jnp.float32)
             return (x, pool, stats), None
 
@@ -957,11 +1086,12 @@ def _pattern_step(cfg: TransformerConfig, params, ids, row_ids, pos, lengths,
 
 
 def _refuse_latent(cfg, program):
-    if cfg.attention == "mla":
+    if cfg.walks_runs:
         raise NotImplementedError(
-            f"{program} has no latent-attention form: an attention='mla' "
-            f"model is served through the ragged step and the decode "
-            f"programs (ragged_attention 'auto' or 'on', no speculation)")
+            f"{program} has no form for the walk of runs: an "
+            f"attention='mla' model or a layer_types pattern is served "
+            f"through the ragged step and the decode programs "
+            f"(ragged_attention 'auto' or 'on', no speculation)")
 
 
 # ---------------------------------------------------------------------------
@@ -1116,7 +1246,8 @@ def paged_decode(cfg: TransformerConfig, params, toks: jnp.ndarray,
                  pos: jnp.ndarray, block_tables: jnp.ndarray,
                  cache: Dict[str, jnp.ndarray], active: jnp.ndarray,
                  block_size: int, use_kernel: bool = True, topo=None,
-                 lora=None, adapter_ids=None, state_slots=None
+                 lora=None, adapter_ids=None, state_slots=None,
+                 window_tables=None
                  ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
     """toks/pos/active [N]; block_tables [N, MB]. One token per sequence;
     returns ([N, V] logits, cache). Inactive rows write to the null block
@@ -1126,9 +1257,11 @@ def paged_decode(cfg: TransformerConfig, params, toks: jnp.ndarray,
     returns (logits, what its expert layers routed, cache): see
     ``_pattern_step``. ``state_slots`` [N]: each row's slot of recurrent
     state, for a model whose layer pattern has linear-attention layers
-    (an inactive row reads and writes the null slot)."""
+    (an inactive row reads and writes the null slot). ``window_tables``
+    [N, ring blocks]: each row's ring in the window layers' pool, for a
+    ``layer_types`` pattern that has such layers."""
     N, MB = block_tables.shape
-    if cfg.attention == "mla":
+    if cfg.walks_runs:
         # the ragged layout with one token a row (_pattern_step)
         blk = jnp.take_along_axis(
             block_tables, (pos // block_size)[:, None], axis=1)[:, 0]
@@ -1136,7 +1269,8 @@ def paged_decode(cfg: TransformerConfig, params, toks: jnp.ndarray,
             cfg, params, toks, jnp.arange(N, dtype=jnp.int32), pos,
             jnp.where(active, pos + 1, 0), jnp.where(active, blk, 0),
             pos % block_size, block_tables, cache, use_kernel=use_kernel,
-            state_slots=state_slots, one_token=True)
+            state_slots=state_slots, one_token=True,
+            window_tables=window_tables)
         with jax.named_scope("head"):
             return _logits(cfg, params, x), stats, cache
     nh, nkv, hd = cfg.num_heads, cfg.kv_heads, cfg.head_dim
@@ -1200,7 +1334,8 @@ def paged_ragged_step(cfg: TransformerConfig, params, ids: jnp.ndarray,
                       block_tables: jnp.ndarray, last_index: jnp.ndarray,
                       cache: Dict[str, jnp.ndarray], block_size: int,
                       use_kernel: bool = True, topo=None,
-                      lora=None, adapter_ids=None, state_slots=None
+                      lora=None, adapter_ids=None, state_slots=None,
+                      window_tables=None
                       ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
     """One compiled program for a MIXED batch (the Ragged Paged
     Attention layout, kernels/ragged_attention.py): prefill chunks,
@@ -1217,7 +1352,8 @@ def paged_ragged_step(cfg: TransformerConfig, params, ids: jnp.ndarray,
     routed, cache): see ``_pattern_step``; ``state_slots`` [RB] is each
     row's slot of recurrent state where its layer pattern has
     linear-attention layers (rows packed one after another in row
-    order, as ``ragged/batch.pack`` lays them).
+    order, as ``ragged/batch.pack`` lays them); ``window_tables`` [RB,
+    ring blocks] each row's ring in the window layers' pool.
 
     The new tokens' K/V scatter into the pool inside the scanned layer
     body (padding tokens land in the null block), then every token
@@ -1228,11 +1364,11 @@ def paged_ragged_step(cfg: TransformerConfig, params, ids: jnp.ndarray,
     through attention, which is row-local by construction."""
     T = ids.shape[0]
     RB, MBw = block_tables.shape
-    if cfg.attention == "mla":
+    if cfg.walks_runs:
         x, stats, cache = _pattern_step(
             cfg, params, ids, row_ids, pos, lengths, write_blocks,
             write_offsets, block_tables, cache, use_kernel=use_kernel,
-            state_slots=state_slots)
+            state_slots=state_slots, window_tables=window_tables)
         with jax.named_scope("head"):
             return _logits(cfg, params, x[last_index]), stats, cache
     ctx = MBw * block_size
@@ -1304,7 +1440,7 @@ def paged_decode_window(cfg: TransformerConfig, params, toks: jnp.ndarray,
                         topk: jnp.ndarray = None,
                         use_kernel: bool = True, topo=None,
                         lora=None, adapter_ids=None, alive=None,
-                        state_slots=None):
+                        state_slots=None, window_tables=None):
     """Up to ``window`` decode steps entirely on device — the answer to
     the dispatch-bound per-token loop (one Python round-trip + [N] int32
     transfer PER TOKEN). One ``lax.while_loop`` runs cache write, paged
@@ -1365,7 +1501,8 @@ def paged_decode_window(cfg: TransformerConfig, params, toks: jnp.ndarray,
         logits, *routed, cache = paged_decode(
             cfg, params, toks, pos, block_tables, cache, active, block_size,
             use_kernel=use_kernel, topo=topo, lora=lora,
-            adapter_ids=adapter_ids, state_slots=state_slots)
+            adapter_ids=adapter_ids, state_slots=state_slots,
+            window_tables=window_tables)
         moe = [_merge_moe_stats(a, b) for a, b in zip(moe, routed)]
         if sampled:
             from .sampling import fold_in_rows, sample_tokens_rowwise
@@ -1385,8 +1522,8 @@ def paged_decode_window(cfg: TransformerConfig, params, toks: jnp.ndarray,
         return (state[0] < window) & jnp.any(state[3])
 
     # what the window's expert layers routed, merged over its steps: one
-    # more output where paged_decode has it (attention='mla'), none else
-    moe = [jnp.zeros((4,), jnp.float32)] * (cfg.attention == "mla")
+    # more output where paged_decode has it (the walk of runs), none else
+    moe = [jnp.zeros((4,), jnp.float32)] * cfg.walks_runs
     state = (jnp.asarray(0, jnp.int32), toks, pos,
              (steps_left > 0) & alive, alive,
              jnp.full((N, window), -1, jnp.int32), moe, cache)

@@ -30,7 +30,7 @@ prefill-bucket x decode-bucket PRODUCT of the stitched families.
 """
 
 from dataclasses import dataclass
-from typing import List, Sequence, Tuple
+from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -54,6 +54,9 @@ class RaggedBatch:
     last_index: np.ndarray        # [RB] int32 flat idx of row's last token
     adapter_slots: np.ndarray     # [RB] int32 LoRA bank slot (0 = base)
     state_slots: np.ndarray       # [RB] int32 recurrent-state slot (0 = none)
+    # [RB, ring blocks] int32: each row's ring in the window layers'
+    # pool (None for a model without one); never sliced, a ring is short
+    window_tables: Optional[np.ndarray] = None
 
     @property
     def total_tokens(self) -> int:
@@ -67,9 +70,12 @@ class RaggedBatch:
         return 1.0 - self.total_tokens / max(self.token_bucket, 1)
 
 
-def pack(entries: Sequence[Tuple[int, np.ndarray]], state_manager
-         ) -> RaggedBatch:
+def pack(entries: Sequence[Tuple[int, np.ndarray]], state_manager,
+         full_width: bool = False) -> RaggedBatch:
     """Pack ``[(uid, fed_tokens)]`` into one :class:`RaggedBatch`.
+    ``full_width``: the tables keep every page a sequence may reach
+    (one program for all the steps of a prompt fed in chunks, whose
+    tables would else widen bucket by bucket).
 
     Allocates each row's KV blocks for the tokens it will write
     (``ensure_blocks``, same contract as the stitched paths) but does
@@ -96,6 +102,8 @@ def pack(entries: Sequence[Tuple[int, np.ndarray]], state_manager
     last_index = np.zeros(RB, np.int32)
     adapter_slots = np.zeros(RB, np.int32)
     state_slots = np.zeros(RB, np.int32)
+    window_tables = np.full((RB, sm.ring_blocks), NULL_BLOCK, np.int32) \
+        if sm.ring_blocks else None
 
     cursor = 0
     used_pages = 1
@@ -118,6 +126,8 @@ def pack(entries: Sequence[Tuple[int, np.ndarray]], state_manager
         last_index[r] = cursor + n - 1
         adapter_slots[r] = getattr(seq, "adapter_slot", 0)
         state_slots[r] = seq.state_slot
+        if window_tables is not None:
+            window_tables[r] = sm.window_table_for(uid)
         used_pages = max(used_pages, len(seq.blocks))
         cursor += n
         uids.append(int(uid))
@@ -126,11 +136,12 @@ def pack(entries: Sequence[Tuple[int, np.ndarray]], state_manager
     # slice tables to the power-of-two used-page bucket (the same
     # width discipline as the stitched decode path: a short batch in a
     # full-width table would stream every null slot)
-    tables = tables[:, :pow2_bucket(used_pages, sm.max_blocks_per_seq)]
+    if not full_width:
+        tables = tables[:, :pow2_bucket(used_pages, sm.max_blocks_per_seq)]
     return RaggedBatch(uids=uids, new_lens=new_lens, token_bucket=TB,
                        row_bucket=RB, ids=ids, row_ids=row_ids,
                        positions=positions, lengths=lengths,
                        write_blocks=write_blocks,
                        write_offsets=write_offsets, block_tables=tables,
                        last_index=last_index, adapter_slots=adapter_slots,
-                       state_slots=state_slots)
+                       state_slots=state_slots, window_tables=window_tables)

@@ -74,8 +74,22 @@ def prefix_digest(tokens, block_size: int,
 
 
 class DSStateManager:
-    def __init__(self, config: DSStateManagerConfig, state_slots: int = 0):
-        """``state_slots`` > 0: the model keeps recurrent state a
+    def __init__(self, config: DSStateManagerConfig, state_slots: int = 0,
+                 window_ring: int = 0):
+        """``window_ring`` > 0: the model has window-attention layers,
+        whose keys and values live in a pool of their own in which a
+        sequence owns a RING of that many positions (whole blocks;
+        position p at place ``p % window_ring``) and not its whole
+        context: a second geometry a sequence, with its own allocator
+        (``max_tracked_sequences`` rings and the null block), its own
+        table (``window_table_for``), handed out with the blocks of the
+        first and taken back with them, and counted by ``can_schedule``.
+        The engine sizes it (``InferenceEngineV2.max_row_chunk``, the
+        most tokens a sequence feeds in ONE step): the window, that many
+        and one block, so that a step's writes never land on a position
+        its earliest token still sees.
+
+        ``state_slots`` > 0: the model keeps recurrent state a
         sequence beside its blocks (linear-attention layers), in that
         many slots of the cache's state leaves, numbered from 1 (slot 0
         is the null slot). A tracked sequence owns one from its creation
@@ -92,6 +106,13 @@ class DSStateManager:
         self._free_slots = list(range(self.state_slots, 0, -1))
         self.block_size = config.block_size
         self.allocator = BlockedAllocator(config.num_blocks)
+        if window_ring % self.block_size:
+            raise ValueError(f"a ring of {window_ring} positions is not "
+                             f"whole blocks of {self.block_size}")
+        self.ring_blocks = window_ring // self.block_size
+        self.window_allocator = BlockedAllocator(
+            config.max_tracked_sequences * self.ring_blocks + 1) \
+            if self.ring_blocks else None
         self.seqs: Dict[int, DSSequenceDescriptor] = {}
         self.max_blocks_per_seq = -(-config.max_seq_len // self.block_size)
         # cold-block spill tier (spill.py KVSpillTier, installed by the
@@ -129,6 +150,11 @@ class DSStateManager:
             "inference_kv_blocks_freed_total",
             "KV block references released (sequence flush + prefix "
             "eviction)")
+        self._m_ring_reused = reg.counter(
+            "inference_window_blocks_reused_total",
+            "blocks of a sequence's ring (window-attention layers) "
+            "written over by positions a whole ring later: what a pool "
+            "that held every position would have had to hand out")
 
     # -- prefix caching -----------------------------------------------------
     _chain = staticmethod(_chain)
@@ -291,12 +317,31 @@ class DSStateManager:
         if uid not in self.seqs and \
                 len(self.seqs) >= self.config.max_tracked_sequences:
             return False
+        if self.ring_blocks and seq.window_blocks_needed(
+                new_tokens, self.block_size, self.ring_blocks) \
+                > self.window_allocator.free_blocks:
+            return False
         return seq.blocks_needed(new_tokens, self.block_size) \
             <= self.allocator.free_blocks + self._evictable()
+
+    def window_blocks_in_use(self) -> int:
+        """Ring blocks owned by tracked sequences (0 without a ring)."""
+        return sum(len(s.window_blocks) for s in self.seqs.values())
 
     # -- allocation ---------------------------------------------------------
     def ensure_blocks(self, uid: int, new_tokens: int) -> DSSequenceDescriptor:
         seq = self.get_or_create_sequence(uid)
+        if self.ring_blocks:
+            bs, total = self.block_size, seq.seen_tokens + new_tokens
+            ring = seq.window_blocks_needed(new_tokens, bs,
+                                            self.ring_blocks)
+            if ring:
+                seq.window_blocks.extend(
+                    int(b) for b in self.window_allocator.allocate(ring))
+            # pages past the ring's size land on blocks the row holds
+            self._m_ring_reused.inc(
+                max(0, -(-total // bs) - self.ring_blocks)
+                - max(0, -(-seq.seen_tokens // bs) - self.ring_blocks))
         need = seq.blocks_needed(new_tokens, self.block_size)
         if need:
             if need > self.allocator.free_blocks:
@@ -321,6 +366,11 @@ class DSStateManager:
             raise NotImplementedError(
                 "a sequence with recurrent state cannot be adopted: a "
                 "handoff carries KV blocks and no state slot")
+        if self.ring_blocks:
+            raise NotImplementedError(
+                "a sequence of a model with window-attention layers "
+                "cannot be adopted: a handoff carries the blocks of one "
+                "geometry and no ring")
         if uid in self.seqs:
             raise ValueError(
                 f"cannot adopt uid {uid}: sequence already tracked")
@@ -357,6 +407,8 @@ class DSStateManager:
                 self._free_slots.append(seq.state_slot)
             if self.config.enable_prefix_caching:
                 self._register_prefix(seq)
+            if seq.window_blocks:
+                self.window_allocator.free(seq.window_blocks)
             self.allocator.free(seq.blocks)
             if seq.blocks:
                 self._m_freed.inc(len(seq.blocks))
@@ -365,12 +417,20 @@ class DSStateManager:
                               free=self.allocator.free_blocks)
 
     # -- device metadata ----------------------------------------------------
-    def block_table_for(self, uid: int) -> np.ndarray:
-        """[max_blocks_per_seq] int32 padded with the null block."""
-        table = np.full(self.max_blocks_per_seq, NULL_BLOCK, np.int32)
-        blocks = self.seqs[uid].blocks
+    @staticmethod
+    def _table(blocks, width: int) -> np.ndarray:
+        table = np.full(width, NULL_BLOCK, np.int32)
         table[:len(blocks)] = blocks
         return table
+
+    def block_table_for(self, uid: int) -> np.ndarray:
+        """[max_blocks_per_seq] int32 padded with the null block."""
+        return self._table(self.seqs[uid].blocks, self.max_blocks_per_seq)
+
+    def window_table_for(self, uid: int) -> np.ndarray:
+        """[ring_blocks] int32: the sequence's ring, place by place,
+        padded with the null block where it has not grown yet."""
+        return self._table(self.seqs[uid].window_blocks, self.ring_blocks)
 
     def free_blocks(self) -> int:
         return self.allocator.free_blocks

@@ -29,6 +29,20 @@ class DSSequenceDescriptor:
     # sequence and takes it back at flush, beside its blocks); 0, the
     # null slot, where the model keeps none
     state_slot: int = 0
+    # the second geometry (a model with window-attention layers): the
+    # blocks of the sequence's RING in the window layers' pool, in place
+    # order (position p lies in ``window_blocks[(p // block_size) %
+    # ring_blocks]``); they grow with the sequence up to the ring's size
+    # and are then written over
+    window_blocks: List[int] = field(default_factory=list)
+
+    def window_blocks_needed(self, new_tokens: int, block_size: int,
+                             ring_blocks: int) -> int:
+        """Ring blocks still to hand out before ``new_tokens`` more
+        positions are written (0 for a model without a ring)."""
+        total = self.seen_tokens + new_tokens
+        return max(0, min(-(-total // block_size), ring_blocks)
+                   - len(self.window_blocks))
 
     def blocks_needed(self, new_tokens: int, block_size: int) -> int:
         total = self.seen_tokens + new_tokens

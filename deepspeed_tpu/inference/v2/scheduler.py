@@ -109,6 +109,10 @@ class DynamicSplitFuseScheduler:
         # chunks align to the prefill bucket so every split hits an
         # already-compiled program size
         self.chunk = chunk or engine.config.prefill_bucket
+        # a model whose window layers keep a ring takes no more of a
+        # sequence in one step than the ring leaves room for
+        if getattr(engine, "max_row_chunk", None):
+            self.chunk = min(self.chunk, engine.max_row_chunk)
         self.clock = clock
         self._queue: List[_Request] = []     # waiting for prefill budget
         self._running: List[_Request] = []   # prefill done, decoding

@@ -35,6 +35,10 @@ from ..ops.norms import layer_norm, rms_norm
 from ..telemetry import registry as _registry
 
 
+# a source's word for a per-head layer -> the mixer kind it is served as
+PER_HEAD_KINDS = {"sliding_attention": "window", "full_attention": "full"}
+
+
 @dataclass(frozen=True)
 class TransformerConfig:
     vocab_size: int = 32000
@@ -161,6 +165,24 @@ class TransformerConfig:
     linear_conv_size: int = 4
     linear_decay_floor: float = -5.0
     attn_gate: str = "none"
+    # a per-layer pattern over PER-HEAD attention (served only; the
+    # afmoe block of Trinity-Mini): ``layer_types`` lists every layer's
+    # mixer as the source publishes it, "sliding_attention" (a token sees
+    # the last ``attn_window`` positions, itself included) or
+    # "full_attention" (all of them), under ``attention="mha"``; the
+    # cache then keeps a leaf a kind, the sliding layers' a ring
+    # (``paged_model.init_paged_kv_cache``). ``qk_norm``: q and k are
+    # RMS-normed a head over ``head_dim`` with a learned weight, before
+    # the rotation. ``attn_gate`` "elementwise": the heads' output times
+    # sigmoid of one ``num_heads * head_dim``-wide projection of the
+    # layer's input, before ``wo``. ``rope_sliding_only``: only the
+    # sliding layers rotate q and k, a full layer has no position
+    # signal. ``norm_scheme`` "sandwich" (below): a norm before each
+    # sub-layer and one more on what it adds to the stream
+    layer_types: Optional[tuple] = None
+    attn_window: int = 0
+    qk_norm: bool = False
+    rope_sliding_only: bool = False
     # the group limit of the deployed router (DeepSeek-V3 noaux_tc): the
     # experts form ``moe_n_group`` groups, a group scores the sum of its
     # best two, and the top k are chosen inside the best
@@ -186,6 +208,8 @@ class TransformerConfig:
     # residual add — original BERT; the reference kernel's
     # pre_layer_norm=False mode, ds_transformer_cuda.cpp). Post-LN has no
     # final norm: the last layer's output LayerNorm plays that role.
+    # "sandwich" (served only, with ``layer_types``): pre-norm, and a
+    # second norm on each sub-layer's output before it joins the stream
     norm_scheme: str = "pre"
     # BERT-family extras: LayerNorm over the summed embeddings
     # (bert.embeddings.LayerNorm) and the MLM prediction head transform
@@ -211,9 +235,9 @@ class TransformerConfig:
             raise ValueError(
                 f"objective must be 'causal_lm' or 'mlm', got "
                 f"{self.objective!r}")
-        if self.norm_scheme not in ("pre", "post"):
+        if self.norm_scheme not in ("pre", "post", "sandwich"):
             raise ValueError(
-                f"norm_scheme must be 'pre' or 'post', got "
+                f"norm_scheme must be 'pre', 'post' or 'sandwich', got "
                 f"{self.norm_scheme!r}")
         if self.norm_scheme == "post" and self.moe_num_experts > 0:
             raise NotImplementedError("post-LN + MoE is not supported")
@@ -245,9 +269,39 @@ class TransformerConfig:
             raise ValueError(
                 f"moe_first_dense_layers={self.moe_first_dense_layers} "
                 f"leaves no expert layer of {self.num_layers}")
-        if self.attn_gate not in ("none", "head"):
-            raise ValueError(f"attn_gate must be 'none' or 'head', got "
-                             f"{self.attn_gate!r}")
+        if self.attn_gate not in ("none", "head", "elementwise"):
+            raise ValueError(f"attn_gate must be 'none', 'head' or "
+                             f"'elementwise', got {self.attn_gate!r}")
+        if self.layer_types is not None:
+            # a list from a JSON file; the dataclass is frozen
+            object.__setattr__(self, "layer_types",
+                               tuple(self.layer_types))
+            if (len(self.layer_types) != self.num_layers
+                    or set(self.layer_types) - set(PER_HEAD_KINDS)):
+                raise ValueError(
+                    f"layer_types names a mixer a layer ({self.num_layers}"
+                    f" of {sorted(PER_HEAD_KINDS)}), got "
+                    f"{self.layer_types!r}")
+            if (self.attention != "mha" or self.linear_attn_period
+                    or self.positional != "rope" or self.norm != "rmsnorm"
+                    or self.attn_bias or not self.is_causal
+                    or self.parallel_residual or not self.is_gated_mlp
+                    or self.rotary_pct != 1.0):
+                raise NotImplementedError(
+                    "a pattern over per-head attention (layer_types) is "
+                    "the afmoe block: attention='mha', rope over the whole "
+                    "head, RMSNorm, causal, a gated MLP, no biases")
+            if "sliding_attention" in self.layer_types \
+                    and self.attn_window < 1:
+                raise ValueError("a sliding_attention layer needs "
+                                 "attn_window > 0")
+        elif self.attn_window or self.qk_norm or self.rope_sliding_only \
+                or self.norm_scheme == "sandwich" \
+                or self.attn_gate == "elementwise":
+            raise NotImplementedError(
+                "attn_window, qk_norm, rope_sliding_only, norm_scheme="
+                "'sandwich' and attn_gate='elementwise' describe the "
+                "per-head block of a layer pattern: give layer_types")
         if self.linear_attn_period < 0 or (
                 self.linear_attn_period
                 and (self.attention != "mla" or self.linear_head_dim <= 0
@@ -258,10 +312,10 @@ class TransformerConfig:
                 f"{self.linear_attn_period}) needs attention='mla' for "
                 f"the layers it leaves, linear_head_dim > 0, "
                 f"linear_conv_size >= 2 and linear_decay_floor < 0")
-        if self.attn_gate != "none" and self.attention != "mla":
+        if self.attn_gate == "head" and self.attention != "mla":
             raise NotImplementedError(
-                "attn_gate is served with attention='mla' (and the "
-                "linear layers of its pattern) only")
+                "attn_gate 'head' is served with attention='mla' (and "
+                "the linear layers of its pattern) only")
         E, G = self.moe_num_experts, self.moe_n_group
         if G < 1 or not 1 <= self.moe_topk_group <= G or (
                 G > 1 and (E % G or E // G < 2
@@ -277,18 +331,19 @@ class TransformerConfig:
                 f"moe_experts_held={self.moe_experts_held} from "
                 f"{self.moe_experts_first} lie outside {E} experts")
         if (self.moe_experts_held or self.moe_experts_first) and (
-                self.attention != "mla"
+                not self.walks_runs
                 or not 0 < self.moe_experts_held):
             raise NotImplementedError(
                 "a share of the experts (moe_experts_held > 0 from "
-                "moe_experts_first) is served by the attention='mla' "
-                "block's expert layer only")
-        if self.moe_first_dense_layers and (self.moe_num_experts == 0
-                                            or self.attention != "mla"):
+                "moe_experts_first) is served by the walk of runs' expert "
+                "layer only (attention='mla' or layer_types)")
+        if self.moe_first_dense_layers and (
+                self.moe_num_experts == 0 or not self.walks_runs):
             # the runs' scans live in paged_model._pattern_step
             raise NotImplementedError(
                 "leading dense layers (moe_first_dense_layers) are served "
-                "for an MoE model with attention='mla' only")
+                "for an MoE model with attention='mla' or a layer_types "
+                "pattern only")
         if self.moe_noisy_gate_policy is not None:
             # RSample needs an rng threaded through the scanned layer body,
             # which neither the GSPMD nor the manual-pipeline MoE branch
@@ -321,6 +376,12 @@ class TransformerConfig:
             ("linear_attn_period (linear-attention layers and their "
              "recurrent state)", self.linear_attn_period > 0),
             ("attn_gate", self.attn_gate != "none"),
+            ("layer_types (window and full per-head layers, a cache leaf "
+             "a kind, the leading dense stack)",
+             self.layer_types is not None),
+            ("qk_norm", self.qk_norm),
+            ("rope_sliding_only", self.rope_sliding_only),
+            ("norm_scheme='sandwich'", self.norm_scheme == "sandwich"),
             ("moe_n_group", self.moe_n_group > 1),
             ("moe_experts_held (a share of the experts)",
              self.experts_held < self.moe_num_experts),
@@ -350,10 +411,27 @@ class TransformerConfig:
     @property
     def layer_kinds(self) -> tuple:
         """The mixer of every layer, in order: "kda" (linear attention)
-        or ``attention``."""
+        or ``attention`` under ``linear_attn_period``; "window" or "full"
+        (per-head attention) from an explicit ``layer_types``."""
+        if self.layer_types is not None:
+            return tuple(PER_HEAD_KINDS[t] for t in self.layer_types)
         p = self.linear_attn_period
         return tuple("kda" if p and (i + 1) % p else self.attention
                      for i in range(self.num_layers))
+
+    @property
+    def walks_runs(self) -> bool:
+        """Whether the model is served by the walk of runs
+        (``paged_model._pattern_step``: a stack a mixer kind, the
+        leading dense stack, the deployed expert layer) and not by the
+        homogeneous per-head scan."""
+        return self.attention == "mla" or self.layer_types is not None
+
+    @property
+    def pattern(self) -> bool:
+        """Whether the layers' mixers differ by layer: a parameter stack
+        and a cache leaf a kind."""
+        return self.linear_attn_period > 0 or self.layer_types is not None
 
     @property
     def has_state(self) -> bool:
@@ -674,7 +752,7 @@ class TransformerLM:
         def init(key, shape, scale=std):
             return (jax.random.normal(key, shape, jnp.float32) * scale).astype(dt)
 
-        if cfg.attention == "mla":
+        if cfg.walks_runs:
             return self._init_latent_params(rng)
         layer = {
             "attn_norm": jnp.ones((L, h), dt),
@@ -773,7 +851,12 @@ class TransformerLM:
         (``linear_attn_period``) the mixers leave those two stacks for
         one of their own a kind, ``kda_layers`` and ``mla_layers``, each
         in layer order; an expert stack holds ``cfg.experts_held``
-        experts under a router of ``moe_num_experts``."""
+        experts under a router of ``moe_num_experts``. A pattern over
+        per-head attention (``layer_types``) has the same four stacks
+        with ``window_layers`` / ``full_layers`` as its mixers'
+        (``per_head``), and under the sandwich scheme a post-norm a
+        sub-layer (``attn_post_norm`` with the mixer, ``mlp_post_norm``
+        with the MLP)."""
         cfg, dt = self.cfg, jnp.float32
         h, v, nh = cfg.hidden_size, cfg.vocab_size, cfg.num_heads
         L, std = cfg.num_layers, 0.02
@@ -831,6 +914,31 @@ class TransformerLM:
                 out["wg"] = init(ks[8], (n, h, nh))
             return out
 
+        def per_head(key, n):
+            """A per-head (GQA) mixer's leaves: ``wq`` / ``wk`` / ``wv``
+            / ``wo``, the learned weights of the q and k norms a head
+            lane, the element-wise output gate's projection ``wg``."""
+            ks = jax.random.split(key, 5)
+            hd, nkv = cfg.head_dim, cfg.kv_heads
+            out = {"attn_norm": jnp.ones((n, h), dt),
+                   "wq": init(ks[0], (n, h, nh * hd)),
+                   "wk": init(ks[1], (n, h, nkv * hd)),
+                   "wv": init(ks[2], (n, h, nkv * hd)),
+                   "wo": init(ks[3], (n, nh * hd, h), out_std)}
+            if cfg.qk_norm:
+                out["q_norm"] = jnp.ones((n, hd), dt)
+                out["k_norm"] = jnp.ones((n, hd), dt)
+            if cfg.attn_gate == "elementwise":
+                out["wg"] = init(ks[4], (n, h, nh * hd))
+            if cfg.norm_scheme == "sandwich":
+                out["attn_post_norm"] = jnp.ones((n, h), dt)
+            return out
+
+        def mlp_norms(n):
+            return {"mlp_norm": jnp.ones((n, h), dt),
+                    **({"mlp_post_norm": jnp.ones((n, h), dt)}
+                       if cfg.norm_scheme == "sandwich" else {})}
+
         def dense(key, n):
             ks, ffn = jax.random.split(key, 3), cfg.intermediate_size
             return {"w_gate": init(ks[0], (n, h, ffn)),
@@ -854,23 +962,23 @@ class TransformerLM:
                   "final_norm": jnp.ones((h,), dt)}
         if not cfg.tie_embeddings:
             params["lm_head"] = init(k[5], (h, v))
-        if cfg.linear_attn_period:
+        if cfg.pattern:
             # a stack a mixer kind (``cfg.layer_kinds``), and the MLPs
             # apart: ``lead_layers`` / ``layers`` hold the norm and the
             # MLP of the leading dense and of the other layers
             kinds = cfg.layer_kinds
-            params["kda_layers"] = linear(jax.random.fold_in(rng, 7),
-                                          kinds.count("kda"))
-            if "mla" in kinds:
-                params["mla_layers"] = attention(
-                    jax.random.fold_in(rng, 8), kinds.count("mla"),
-                    mlp_norm=False)
-            params["layers"] = {"mlp_norm": jnp.ones((L - lead, h), dt),
-                                **mlp(k[2], L - lead)}
+            mixers = {"kda": (linear, 7), "window": (per_head, 9),
+                      "full": (per_head, 10),
+                      "mla": (functools.partial(attention, mlp_norm=False),
+                              8)}
+            for kind in dict.fromkeys(kinds):
+                make, fold = mixers[kind]
+                params[kind + "_layers"] = make(
+                    jax.random.fold_in(rng, fold), kinds.count(kind))
+            params["layers"] = {**mlp_norms(L - lead), **mlp(k[2], L - lead)}
             if lead:
-                params["lead_layers"] = {
-                    "mlp_norm": jnp.ones((lead, h), dt),
-                    **dense(k[4], lead)}
+                params["lead_layers"] = {**mlp_norms(lead),
+                                         **dense(k[4], lead)}
             return params
         params["layers"] = {**attention(k[1], L - lead),
                             **mlp(k[2], L - lead)}
@@ -882,7 +990,7 @@ class TransformerLM:
     # -- sharding (TP over "model", PP over "pipe"; ZeRO composes on top) --
     def param_partition_specs(self, topo) -> Dict[str, Any]:
         cfg = self.cfg
-        if cfg.attention == "mla":
+        if cfg.walks_runs:
             # served at tp = ep = 1 only (engine_v2 refuses the rest):
             # every leaf whole on its device
             shapes = jax.eval_shape(self.init_params, jax.random.PRNGKey(0))

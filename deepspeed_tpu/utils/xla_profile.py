@@ -138,6 +138,7 @@ def scope_phase(op_name: str) -> str:
 _SERVE_PHASE_OF_SCOPE = {
     "embed": "embed", "attention": "attn_proj", "mla_attention": "attn_proj",
     "qkv_proj": "attn_proj", "out_proj": "attn_proj",
+    "attn_gate": "attn_proj",
     "kv_write": "kv_write", "attn_kernel": "attn_kernel",
     "mlp": "mlp", "dense_mlp": "mlp", "moe_shared_expert": "mlp",
     "moe_router": "router", "moe_experts": "experts",

@@ -119,29 +119,42 @@ def test_leaf_spans_tile_the_call_and_reach_its_root(engine):
 
 def test_the_coarse_spans_keep_name_extent_and_attrs(engine):
     eng, prompts, _ = engine
-    _, spans = _call_spans(eng, prompts)
-    step = [s for s in spans if s["name"] == "ragged_step"]
-    windows = [s for s in spans if s["name"] == "decode_window"]
-    assert len(step) == 1 and len(windows) == -(-(NEW_TOKENS - 1) // 8)
-    assert step[0]["attrs"] == {"rows": 3, "tokens": 36, "uids": [0, 1, 2]}
-    # a span a window launched; all but a call's first were queued
-    # behind the one before (generate() launches ahead)
-    for i, w in enumerate(windows):
-        assert w["attrs"] == {"batch": 3, "window": 8, "ahead": int(i > 0),
-                              "uids": [0, 1, 2]}
     # the extent is the children's: uploads and launch, then the wait
     # (a window's: for the tokens of the window before it, so a call's
-    # first has none and its last is fetched under the root)
-    for outer, names in ((step[0], ("ragged_dispatch", "ragged_fetch")),
-                         (windows[0], ("window_assemble", "window_dispatch")),
-                         (windows[1], ("window_assemble", "window_dispatch",
-                                       "window_fetch"))):
-        inner = [s for s in spans if s["parent"] == outer["id"]]
-        assert tuple(s["name"] for s in sorted(
-            inner, key=lambda s: s["start"])) == names
-        # (a span with no wait in it is too short for that to say much)
-        assert outer is windows[0] or sum(s["duration_s"] for s in inner) \
-            >= 0.98 * outer["duration_s"]
+    # first has none and its last is fetched under the root). What a
+    # span's children leave uncovered is the host between them, which
+    # beside five other test workers is now and then a preemption: the
+    # best of three calls says whether the extent is the children's
+    uncovered = []
+    for _ in range(3):
+        _, spans = _call_spans(eng, prompts)
+        step = [s for s in spans if s["name"] == "ragged_step"]
+        windows = [s for s in spans if s["name"] == "decode_window"]
+        assert len(step) == 1 and len(windows) == -(-(NEW_TOKENS - 1) // 8)
+        assert step[0]["attrs"] == {"rows": 3, "tokens": 36,
+                                    "uids": [0, 1, 2]}
+        # a span a window launched; all but a call's first were queued
+        # behind the one before (generate() launches ahead)
+        for i, w in enumerate(windows):
+            assert w["attrs"] == {"batch": 3, "window": 8,
+                                  "ahead": int(i > 0), "uids": [0, 1, 2]}
+        worst = 0.0
+        for outer, names in (
+                (step[0], ("ragged_dispatch", "ragged_fetch")),
+                (windows[0], ("window_assemble", "window_dispatch")),
+                (windows[1], ("window_assemble", "window_dispatch",
+                              "window_fetch"))):
+            inner = [s for s in spans if s["parent"] == outer["id"]]
+            assert tuple(s["name"] for s in sorted(
+                inner, key=lambda s: s["start"])) == names
+            # (a span with no wait in it is too short to say much)
+            if outer is not windows[0]:
+                worst = max(worst, 1.0 - sum(
+                    s["duration_s"] for s in inner) / outer["duration_s"])
+        uncovered.append(worst)
+        if worst <= 0.02:
+            break
+    assert min(uncovered) <= 0.02, uncovered
     fetches = [s for s in spans if s["name"] == "window_fetch"]
     assert len(fetches) == len(windows)
     assert [s["parent"] for s in fetches] \

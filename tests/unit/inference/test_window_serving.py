@@ -1,0 +1,473 @@
+"""The ``afmoe`` block served: a layer pattern over PER-HEAD attention
+(window and full layers in one model), a window in the ragged kernels, a
+cache of two geometries (the window layers' keys and values in a ring),
+gated GQA with QK-norm, sandwich norms, a leading dense stack, and
+prompts fed in chunks, at toy widths on the CPU, against the benchmark's
+plain reference (``benchmark/reference_trinity.py``: float32, every
+position against every key, no cache, no ring, no chunks).
+
+Tolerances. A float32 engine differs from the reference by the order of
+its sums (pages of a ring, an online softmax, a grouped matmul): 2e-5 of
+the largest logit is fifty times what it reads (3e-7 to 5e-7). A kernel
+against a dense masked softmax, both float32: 2e-5 of the largest output
+(they read 3e-6 and under). An int8 pool (the cell's control) reads
+5e-2 to 1.4e-1.
+"""
+
+import json
+from pathlib import Path
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from benchmark import reference_trinity, weights_trinity
+from benchmark import run as harness
+from deepspeed_tpu.inference.v2 import InferenceEngineV2
+from deepspeed_tpu.inference.v2.config_v2 import DSStateManagerConfig
+from deepspeed_tpu.inference.v2.kernels.ragged_attention import (
+    ragged_attention, ragged_attention_reference)
+from deepspeed_tpu.inference.v2.paged_model import (_layer_runs,
+                                                    init_paged_kv_cache)
+from deepspeed_tpu.inference.v2.ragged.ragged_manager import DSStateManager
+from deepspeed_tpu.inference.v2.scheduler import DynamicSplitFuseScheduler
+from deepspeed_tpu.models import TransformerConfig, TransformerLM
+from deepspeed_tpu.telemetry import get_registry, trace
+
+REPO = Path(__file__).resolve().parents[3]
+CONFIG = json.loads(
+    (REPO / "benchmark/configs/trinity-mini.json").read_text())
+TOY = harness.merge(CONFIG["fields"], CONFIG["toy_fields"])
+WINDOW = TOY["attn_window"]                 # 16
+F32_TIGHT = 2e-5
+SEED = 5
+
+
+def _engine(dtype="float32", fields=TOY, seqs=4, budget=32, **engine):
+    """Blocks of 8, a step of ``budget`` tokens: a row's share is
+    ``budget / seqs`` (8: half the window; 128 / 4 = 32: twice it) and
+    its ring the window, that share and one block."""
+    cfg = TransformerConfig(**fields)
+    return InferenceEngineV2(TransformerLM(cfg), {
+        "dtype": dtype, "use_paged_kernel": True, "decode_window": 4,
+        **engine,
+        "state_manager": {"max_tracked_sequences": seqs,
+                          "max_ragged_batch_size": budget,
+                          "max_seq_len": 160, "block_size": 8,
+                          "num_blocks": 100}},
+        params=weights_trinity.make(fields, SEED, dtype))
+
+
+def _prompts(lengths=(50, 70, 80), seed=0):
+    rng = np.random.default_rng(seed)
+    return [rng.integers(0, TOY["vocab_size"], n) for n in lengths]
+
+
+def _params():
+    return weights_trinity.make(TOY, SEED, "float32")
+
+
+def _reference(prompt):
+    return np.asarray(reference_trinity.logits(_params(), TOY, prompt))
+
+
+def _err(got, want):
+    return float(np.abs(np.asarray(got, np.float32) - want).max()
+                 / np.abs(want).max())
+
+
+# ---------------------------------------------------------------------------
+# (a) the engine against the plain reference
+# ---------------------------------------------------------------------------
+def test_the_pattern_is_walked_as_runs_of_one_mixer_and_one_mlp():
+    cfg = TransformerConfig(**TOY)
+    assert cfg.layer_kinds == ("window", "window", "full", "window",
+                               "window")
+    assert cfg.walks_runs and cfg.pattern and not cfg.has_state
+    # dense + sliding x1; expert + sliding x1; expert + full x1;
+    # expert + sliding x2
+    assert _layer_runs(cfg) == [("window", False, 0, 1),
+                                ("window", True, 1, 1),
+                                ("full", True, 2, 1),
+                                ("window", True, 3, 2)]
+    for what in ("layer_types", "qk_norm", "attn_gate", "rope_sliding_only",
+                 "norm_scheme='sandwich'", "moe_shared_experts"):
+        assert what in cfg.served_only
+    with pytest.raises(NotImplementedError, match="served by"):
+        TransformerLM(cfg).forward_logits(
+            TransformerLM(cfg).init_params(jax.random.PRNGKey(0)),
+            jnp.zeros((1, 8), jnp.int32))
+
+
+@pytest.mark.parametrize("budget,chunk", [(32, 8), (128, 32)],
+                         ids=["chunks-under-the-window",
+                              "chunks-over-the-window"])
+def test_served_logits_match_the_references_full_forward(budget, chunk):
+    """Prompts of 3 to 5 windows (50, 70, 80 tokens at a window of 16)
+    fed in chunks smaller than the window (8) and larger than it (32),
+    then decoding 40 tokens, past two more wraps of the ring: the logits
+    ``put()`` returns and every generated token against the reference's
+    full forward on the same prefix."""
+    eng = _engine(budget=budget)
+    sm = eng.state_manager
+    assert eng.attention_impl == "pallas:pipelined+window"
+    assert eng.max_row_chunk == chunk
+    assert sm.ring_blocks * 8 == WINDOW + chunk + 8
+    prompts = _prompts()
+    before = get_registry().family_total("inference_prefill_chunks_total")
+    got = eng.put([0, 1, 2], prompts)
+    steps = -(-80 // chunk)
+    assert get_registry().family_total(
+        "inference_prefill_chunks_total") - before == steps
+    for i, p in enumerate(prompts):
+        assert _err(got[i], _reference(p)[-1]) <= F32_TIGHT, i
+    for uid in range(3):
+        eng.flush(uid)
+    outs = eng.generate(prompts, max_new_tokens=40, temperature=0.0,
+                        eos_token_id=None)
+    assert get_registry().family_total(
+        "inference_window_blocks_reused_total") > 0
+    for prompt, out in zip(prompts, outs):
+        out = np.asarray(out)
+        assert len(out) == len(prompt) + 40
+        ref = _reference(out[:-1])[len(prompt) - 1:]
+        np.testing.assert_array_equal(out[len(prompt):], ref.argmax(-1))
+
+
+def test_the_gather_path_serves_the_same_logits():
+    """``use_paged_kernel`` off: the ring and the window through the
+    gathering reference inside the same programs."""
+    eng = _engine(use_paged_kernel=False)
+    assert eng.attention_impl == "jnp:gather"
+    prompts = _prompts((50, 33))
+    got = eng.put([0, 1], prompts)
+    for i, p in enumerate(prompts):
+        assert _err(got[i], _reference(p)[-1]) <= F32_TIGHT, i
+
+
+def test_put_in_chunks_equals_one_launch_where_one_launch_fits():
+    """The same two prompts through an engine whose step holds them
+    whole (one ragged step, a ring that never wraps) and through one
+    that feeds them eight tokens a row at a time: the same logits to
+    float32 rounding, and a call that fits is ONE step, as before."""
+    prompts = _prompts((24, 17))
+    whole = _engine(budget=128, seqs=4)           # a row's share: 32
+    steps0 = get_registry().family_total("inference_ragged_steps_total")
+    chunks0 = get_registry().family_total("inference_prefill_chunks_total")
+    want = whole.put([0, 1], prompts)
+    assert get_registry().family_total(
+        "inference_ragged_steps_total") - steps0 == 1
+    assert get_registry().family_total(
+        "inference_prefill_chunks_total") == chunks0
+    fed = _engine(budget=32)                      # a row's share: 8
+    trace.clear()
+    got = fed.put([0, 1], prompts)
+    spans = [s for s in trace.export() if s["name"] == "ragged_step"]
+    assert [(s["attrs"]["chunk"], s["attrs"]["chunks"]) for s in spans] \
+        == [(0, 3), (1, 3), (2, 3)]
+    assert float(np.abs(got - want).max() / np.abs(want).max()) <= F32_TIGHT
+
+
+def test_a_mixed_step_decodes_some_rows_and_feeds_chunks_of_others():
+    """Row 0 is fed whole and decodes one token in the very step that
+    feeds rows 1 and 2 their first chunks (8 tokens each and the decode
+    row's one), and a put() of a decode token beside a long prompt runs
+    the decode row in the first chunk step only."""
+    eng = _engine()
+    a, b = _prompts((40, 30))
+    first = eng.put([0], [a])
+    tok = int(np.argmax(first[0]))
+    got = eng.put([0, 1], [[tok], b])
+    assert _err(got[0], _reference(np.append(a, tok))[-1]) <= F32_TIGHT
+    assert _err(got[1], _reference(b)[-1]) <= F32_TIGHT
+    assert eng.state_manager.seqs[0].seen_tokens == 41
+
+
+def test_the_scheduler_keeps_to_a_rows_share_of_a_step():
+    """The SplitFuse scheduler's chunk is clipped to what the ring
+    leaves room for, and its streams equal generate()'s."""
+    eng = _engine()
+    prompts = _prompts((50, 21))
+    want = eng.generate(prompts, max_new_tokens=9, temperature=0.0,
+                        eos_token_id=None)
+    sched = DynamicSplitFuseScheduler(eng, chunk=64)
+    assert sched.chunk == eng.max_row_chunk == 8
+    for uid, p in enumerate(prompts):
+        sched.submit(uid, p, max_new_tokens=9)
+    sched.run()
+    for uid, row in sched.results().items():
+        np.testing.assert_array_equal(row, np.asarray(want[uid]))
+
+
+# ---------------------------------------------------------------------------
+# (b) the kernels with a window, against a dense masked softmax
+# ---------------------------------------------------------------------------
+def _kernel_case(window=64, seed=0):
+    """Group 8 at head width 128 (32 query heads on 4 kv heads), pages
+    of 16, three rows whose tables are RINGS of ``window + 48 + 16``
+    positions: a chunk of 40 tokens at positions 200-239, a decode row
+    at 333, and a fresh chunk of 23."""
+    rng = np.random.default_rng(seed)
+    nh, kvh, hd, bs, R = 32, 4, 128, 16, 3
+    ring_blocks = (window + 48 + 16) // bs
+    nb = 1 + R * ring_blocks
+    k, v = (jnp.asarray(rng.normal(size=(2, nb, bs, kvh * hd)), jnp.float32)
+            for _ in range(2))
+    tables = jnp.arange(1, nb, dtype=jnp.int32).reshape(R, ring_blocks)
+    row_ids, lengths = [], []
+    for r, first, n in ((0, 200, 40), (1, 333, 1), (2, 0, 23)):
+        row_ids += [r] * n
+        lengths += list(range(first + 1, first + n + 1))
+    T, live = 64, len(row_ids)
+    q = jnp.asarray(rng.normal(size=(T, nh, hd)), jnp.float32)
+    pad = [0] * (T - live)
+    return (q, k, v, 1, jnp.asarray(row_ids + pad, jnp.int32),
+            jnp.asarray(lengths + pad, jnp.int32), tables), live
+
+
+def _dense_masked_softmax(q, k, v, layer, row_ids, lengths, tables, window):
+    """By hand: every position put back in order from the ring, then a
+    softmax over j <= p and j > p - window."""
+    bs, hd = k.shape[2], q.shape[-1]
+    ring = tables.shape[1] * bs
+    out = np.zeros(q.shape, np.float32)
+    for t in range(q.shape[0]):
+        n = int(lengths[t])
+        if not n:
+            continue
+        pos = np.arange(max(0, n - window), n)
+        place = pos % ring
+        pages = np.asarray(tables)[int(row_ids[t]), place // bs]
+        kk = np.asarray(k)[layer, pages, place % bs].reshape(len(pos), -1, hd)
+        vv = np.asarray(v)[layer, pages, place % bs].reshape(len(pos), -1, hd)
+        group = q.shape[1] // kk.shape[1]
+        for h in range(q.shape[1]):
+            s = kk[:, h // group] @ np.asarray(q)[t, h] / hd ** 0.5
+            p = np.exp(s - s.max())
+            out[t, h] = (p / p.sum()) @ vv[:, h // group]
+    return out
+
+
+@pytest.mark.parametrize("variant", ["tiled", "pipelined"])
+def test_the_kernel_with_a_window_against_a_dense_masked_softmax(variant):
+    """Tiled (under the TPU interpreter: DMAs, semaphores and all) and
+    pipelined, group 8: the walk over a ring from the window's first
+    page, decode rows and prompt chunks in one launch."""
+    args, live = _kernel_case()
+    want = _dense_masked_softmax(*args, window=64)
+    got = ragged_attention(*args, variant=variant, window=64)
+    assert np.abs(np.asarray(got) - want)[:live].max() \
+        <= F32_TIGHT * np.abs(want).max()
+    ref = ragged_attention_reference(*args, window=64)
+    assert np.abs(np.asarray(ref) - want)[:live].max() \
+        <= F32_TIGHT * np.abs(want).max()
+
+
+@pytest.mark.parametrize("variant", ["tiled", "pipelined"])
+def test_a_window_that_holds_the_context_is_no_window_bit_for_bit(variant):
+    """``window >= context`` over a table that holds every position
+    equals ``window=0``, today's kernel, to the last bit."""
+    (q, k, v, layer, row_ids, _, tables), live = _kernel_case()
+    lengths = []
+    for first, n in ((60, 40), (99, 1), (0, 23)):
+        lengths += list(range(first + 1, first + n + 1))
+    lengths = jnp.asarray(lengths + [0] * (64 - live), jnp.int32)
+    args = (q, k, v, layer, row_ids, lengths, tables)
+    plain = ragged_attention(*args, variant=variant)
+    wide = ragged_attention(*args, variant=variant, window=4096)
+    np.testing.assert_array_equal(np.asarray(plain)[:live],
+                                  np.asarray(wide)[:live])
+
+
+def test_the_tiled_kernel_refuses_a_window_over_an_int8_pool():
+    (q, k, v, layer, row_ids, lengths, tables), _ = _kernel_case()
+    scale = jnp.ones((k.shape[1], 4), jnp.float32)
+    with pytest.raises(NotImplementedError, match="dequantise the layer"):
+        ragged_attention(q, k.astype(jnp.int8), v.astype(jnp.int8), layer,
+                         row_ids, lengths, tables, k_scale=scale,
+                         v_scale=scale, variant="tiled", window=64)
+
+
+# ---------------------------------------------------------------------------
+# (c) the cache of two geometries and its manager
+# ---------------------------------------------------------------------------
+def test_a_leaf_a_kind_and_the_window_leaf_a_ring():
+    cfg = TransformerConfig(**TOY)
+    cache = jax.eval_shape(lambda: init_paged_kv_cache(
+        cfg, 100, 8, jnp.float32, window_blocks=17))
+    F = TOY["num_kv_heads"] * TOY["head_dim_override"]
+    assert {k: v.shape for k, v in cache.items()} == {
+        "k_full": (1, 100, 8, F), "v_full": (1, 100, 8, F),
+        "k_window": (4, 17, 8, F), "v_window": (4, 17, 8, F)}
+    quant = jax.eval_shape(lambda: init_paged_kv_cache(
+        cfg, 100, 8, jnp.float32, kv_quant=True, window_blocks=17))
+    assert quant["k_window"].dtype == jnp.int8 \
+        and quant["ks_window"].shape == (4, 17, TOY["num_kv_heads"]) \
+        and quant["vs_full"].shape == (1, 100, TOY["num_kv_heads"])
+    eng = _engine()
+    bytes_of = {kind: sum(int(np.prod(v.shape)) * 4
+                          for k, v in eng.kv_cache.items()
+                          if k.endswith("_" + kind))
+                for kind in ("full", "window")}
+    fam = get_registry().get("inference_kv_pool_bytes")
+    assert {labels[0]: s.value for labels, s in fam.series()} == bytes_of
+
+
+def test_ring_blocks_never_pass_rows_x_ring_and_both_pools_empty_after_flush():
+    eng = _engine()
+    sm = eng.state_manager
+    ring = sm.ring_blocks                          # 4 blocks of 8
+    assert sm.window_allocator.num_blocks == 4 * ring + 1
+    prompts = _prompts((50, 70, 80, 20))
+    free0 = sm.allocator.free_blocks
+    outs = eng.generate(prompts, max_new_tokens=30, temperature=0.0,
+                        eos_token_id=None, keep_sequences=True)
+    assert len(outs) == 4
+    in_use = get_registry().get("inference_kv_blocks_in_use")
+    used = {labels[0]: s.value for labels, s in in_use.series()}
+    assert sm.window_blocks_in_use() == used["window"] == 4 * ring
+    assert used["full"] == sum(-(-(len(o) - 1) // 8) for o in outs)
+    for uid, seq in sm.seqs.items():
+        assert len(seq.window_blocks) == ring, uid
+    assert eng.query(0)["free_window_blocks"] == 0
+    # the ring holds the last positions, in order, of the right tokens
+    kv = eng.sequence_kv(2)
+    n = len(outs[2]) - 1
+    assert kv["positions"][-1] == n - 1 and len(kv["positions"]) >= WINDOW
+    want_k, want_v = reference_trinity.leading_kv(_params(), TOY,
+                                                  outs[2][:-1])
+    at = kv["positions"]
+    for got, want in ((kv["k"], want_k), (kv["v"], want_v)):
+        want = np.asarray(want)[:, at]
+        assert np.linalg.norm(got[:2] - want) / np.linalg.norm(want) \
+            <= F32_TIGHT
+    full = eng.sequence_kv(2, "full")
+    assert len(full["positions"]) == n and full["k"].shape[0] == 1
+    for uid in range(4):
+        eng.flush(uid)
+    assert sm.window_blocks_in_use() == 0
+    assert sm.window_allocator.free_blocks == 4 * ring
+    assert sm.allocator.free_blocks == free0
+
+
+def test_can_schedule_counts_both_geometries():
+    """A manager whose full pool is ample and whose rings are taken
+    refuses a new sequence's tokens, and a row of more than its share of
+    a step is no single step."""
+    sm = DSStateManager(DSStateManagerConfig(
+        max_tracked_sequences=2, max_ragged_batch_size=64, max_seq_len=256,
+        num_blocks=200, block_size=8), window_ring=32)
+    assert sm.ring_blocks == 4 and sm.window_allocator.free_blocks == 8
+    sm.ensure_blocks(0, 100)
+    assert len(sm.seqs[0].window_blocks) == 4 and len(sm.seqs[0].blocks) == 13
+    assert sm.can_schedule(1, 100)
+    sm.window_allocator.allocate(3)             # someone else's
+    assert not sm.can_schedule(1, 100) and sm.can_schedule(1, 8)
+    with pytest.raises(NotImplementedError, match="no ring"):
+        sm.adopt_sequence(5, 2, 10, [0] * 10)
+    with pytest.raises(ValueError, match="whole blocks"):
+        DSStateManager(DSStateManagerConfig(block_size=8), window_ring=20)
+    eng = _engine()
+    assert eng.can_schedule([0], [8]) and not eng.can_schedule([0], [9])
+    with pytest.raises(RuntimeError, match="not schedulable"):
+        eng.put([0], [np.zeros(200, np.int64)])      # over max_seq_len
+    assert eng.state_manager.tracked_sequences() == 0
+
+
+# ---------------------------------------------------------------------------
+# (d) what is not served over two geometries refuses at construction
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("engine,why", [
+    ({"state_manager": {"enable_prefix_caching": True}},
+     "enable_prefix_caching"),
+    ({"state_manager": {"enable_prefix_caching": True,
+                        "enable_kv_spill": True}}, "enable_kv_spill"),
+    ({"max_lora_adapters": 2}, "max_lora_adapters"),
+    ({"quant_bits": 8}, "quant_bits"),
+    ({"ragged_attention": "off"}, "ragged_attention 'off'"),
+    ({"tensor_parallel_size": 2}, "tensor_parallel_size"),
+], ids=["prefix-cache", "spill", "lora", "weight-quant", "stitched", "tp"])
+def test_what_two_geometries_do_not_serve_refuses_at_construction(engine,
+                                                                  why):
+    cfg = TransformerConfig(**TOY)
+    with pytest.raises(NotImplementedError, match="layer_types") as e:
+        InferenceEngineV2(TransformerLM(cfg), {
+            "dtype": "float32", **engine}, params={})
+    assert why in str(e.value)
+
+
+def test_speculation_and_a_draft_model_are_refused_where_asked():
+    eng = _engine()
+    with pytest.raises(NotImplementedError, match="verify pass"):
+        eng.generate(_prompts((9,)), max_new_tokens=2, speculative=True)
+    with pytest.raises(NotImplementedError, match="verify pass"):
+        eng.load_draft_model(TransformerLM(TransformerConfig(**TOY)))
+    assert eng.state_manager.tracked_sequences() == 0
+
+
+@pytest.mark.parametrize("fields,error", [
+    ({"layer_types": ["full_attention"] * 4}, ValueError),
+    ({"layer_types": ["sliding_attention", "global"] + ["full_attention"] * 3},
+     ValueError),
+    ({"attn_window": 0}, ValueError),
+    ({"positional": "learned"}, NotImplementedError),
+    ({"layer_types": None}, NotImplementedError),
+], ids=["a-kind-a-layer", "an-unknown-kind", "no-window", "not-rope",
+        "parts-without-a-pattern"])
+def test_the_blocks_parts_describe_a_pattern_or_are_refused(fields, error):
+    with pytest.raises(error):
+        TransformerConfig(**{**TOY, **fields})
+
+
+# ---------------------------------------------------------------------------
+# (e) the control, and what stays as it was
+# ---------------------------------------------------------------------------
+def test_an_int8_pool_in_both_leaves_is_the_lower_precision_control():
+    """``kv_quant``: int8 keys and values in BOTH leaves (a layer
+    dequantised at a time for the kernel). The same prompts read far
+    over the float32 limit, and what a ring holds is off by percents."""
+    prompts = _prompts((50, 70))
+    eng = _engine(kv_quant=True)
+    assert eng.kv_cache["k_window"].dtype == jnp.int8 \
+        and eng.kv_cache["k_full"].dtype == jnp.int8
+    outs = eng.generate(prompts, max_new_tokens=4, temperature=0.0,
+                        eos_token_id=None, keep_sequences=True)
+    kv = eng.sequence_kv(1)
+    want_k, want_v = reference_trinity.leading_kv(_params(), TOY,
+                                                  outs[1][:-1])
+    want = np.asarray(want_v)[:, kv["positions"]]
+    assert np.linalg.norm(kv["v"][:2] - want) / np.linalg.norm(want) > 5e-3
+    for uid in range(2):
+        eng.flush(uid)
+    got = eng.put([0, 1], prompts)
+    assert max(_err(got[i], _reference(p)[-1])
+               for i, p in enumerate(prompts)) > 100 * F32_TIGHT
+
+
+def test_the_old_trees_seeded_sums_are_unchanged():
+    """The parameter trees that existed are seeded as they were (sums
+    read at commit 63bfad3, ``PRNGKey(3)``): the per-head block's one
+    stack and the latent block's two."""
+    per_head = TransformerLM(TransformerConfig(
+        vocab_size=64, hidden_size=32, intermediate_size=64, num_layers=2,
+        num_heads=4, max_seq_len=32)).init_params(jax.random.PRNGKey(3))
+    latent = TransformerLM(TransformerConfig(
+        vocab_size=64, hidden_size=32, intermediate_size=64, num_layers=3,
+        num_heads=4, max_seq_len=32, attention="mla", q_lora_rank=16,
+        kv_lora_rank=16, qk_nope_head_dim=8, qk_rope_head_dim=8,
+        v_head_dim=8, moe_num_experts=4, moe_top_k=2,
+        moe_intermediate_size=16, moe_first_dense_layers=1,
+        moe_shared_experts=1, moe_scoring="sigmoid",
+        moe_selection_bias=True)).init_params(jax.random.PRNGKey(3))
+    sums = {name: float(np.asarray(leaf, np.float64).sum())
+            for tree, tag in ((per_head, "mha"), (latent, "mla"))
+            for name, leaf in (
+                (f"{tag}/{'/'.join(str(getattr(k, 'key', k)) for k in p)}",
+                 x) for p, x in jax.tree_util.tree_leaves_with_path(tree))}
+    want = json.loads((Path(__file__).parent
+                       / "seeded_sums_63bfad3.json").read_text())
+    assert sums.keys() == want.keys()
+    for name, value in want.items():
+        assert sums[name] == value, name

@@ -528,6 +528,8 @@ def test_latent_ragged_step_compiles_with_its_experts_in_place(tpu_sharding):
     from deepspeed_tpu.inference.v2.paged_model import (init_paged_kv_cache,
                                                         paged_ragged_step)
     from deepspeed_tpu.models import TransformerLM
+    import json
+    from pathlib import Path
     from deepspeed_tpu.models.transformer import TransformerConfig
 
     fields = json.loads((Path(__file__).resolve().parents[3]
@@ -720,3 +722,108 @@ def test_the_hybrid_decode_window_compiles_with_its_state_in_place(
         kernels
     assert sum(bool(GMM_PATTERN.search(k)) for k in kernels) == 3, kernels
     assert compiled.memory_analysis().temp_size_in_bytes < 0.15e9
+
+
+# ---------------------------------------------------------------------------
+# a pattern over per-head attention (window and full layers, a cache of
+# two geometries): trinity-mini.rollout-16x8192-512's geometry
+# ---------------------------------------------------------------------------
+# (tokens, rows, the full table's pages, a ring's pages)
+WINDOW_LAUNCHES = {"prefill": (16384, 16, 544, 193),
+                   "decode": (16, 16, 544, 193)}
+WINDOW_PATTERN = re.compile(r"ragged_attention_(window|tiled)[_.0-9]*$")
+
+
+@pytest.mark.parametrize("launch", sorted(WINDOW_LAUNCHES))
+def test_window_kernel_at_the_cells_launch_shapes(tpu_sharding, launch):
+    """The tiled kernel with a window at 4 kv heads x 128 (group 8) over
+    a ring of 193 pages: it compiles for a v5e at the cell's chunk step
+    and decode step, and its name in a trace tells it from the full
+    launches (benchmark/layer_metrics/window_roofline.gen.json)."""
+    T, R, _, ring = WINDOW_LAUNCHES[launch]
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=tpu_sharding)
+
+    pool = sds((4, R * ring + 1, 16, 4 * 128), jnp.bfloat16)
+    args = [sds((T, 32, 128), jnp.bfloat16), pool, pool, sds((), jnp.int32),
+            sds((T,), jnp.int32), sds((T,), jnp.int32),
+            sds((R, ring), jnp.int32)]
+    assert kernel_variant(128, 4, False) == "tiled"
+    text = jax.jit(lambda *a: ragged_attention(*a, window=2048)).lower(
+        *args).compile().as_text()
+    kernels = re.findall(r"%([\w.\-]+) = [^\n]*tpu_custom_call", text)
+    assert len(kernels) == 1 and kernels[0].startswith(
+        "ragged_attention_window") and WINDOW_PATTERN.search(kernels[0])
+    # and without one it is the kernel it was, under the name it had
+    text = jax.jit(lambda *a: ragged_attention(*a)).lower(
+        *args).compile().as_text()
+    kernels = re.findall(r"%([\w.\-]+) = [^\n]*tpu_custom_call", text)
+    assert len(kernels) == 1 and kernels[0].startswith(
+        "ragged_attention_tiled")
+
+
+def _window_cut(tpu_sharding):
+    """The pattern at published widths, cut to [sliding + dense,
+    sliding + experts, full + experts] with 8 experts and 4,096 rows of
+    the vocabulary: the configuration, and its parameters and cache
+    (16 rows' blocks and rings) as shapes on the chip."""
+    from deepspeed_tpu.inference.v2.paged_model import init_paged_kv_cache
+    from deepspeed_tpu.models import TransformerLM
+    import json
+    from pathlib import Path
+    from deepspeed_tpu.models.transformer import TransformerConfig
+
+    fields = json.loads((Path(__file__).resolve().parents[3]
+                         / "benchmark/configs/trinity-mini.json"
+                         ).read_text())["fields"]
+    cfg = TransformerConfig(**{
+        **fields, "num_layers": 3, "layer_types": fields["layer_types"][:3],
+        "moe_num_experts": 8, "vocab_size": 4096})
+
+    def on_tpu(x, dtype=None):
+        return jax.ShapeDtypeStruct(x.shape, dtype or x.dtype,
+                                    sharding=tpu_sharding)
+
+    params = jax.tree.map(
+        lambda x: on_tpu(x, jnp.bfloat16),
+        jax.eval_shape(TransformerLM(cfg).init_params,
+                       jax.random.PRNGKey(0)))
+    cache = jax.tree.map(on_tpu, jax.eval_shape(
+        lambda: init_paged_kv_cache(cfg, 16 * 545 + 1, 16, jnp.bfloat16,
+                                    window_blocks=16 * 193 + 1)))
+    assert cache["k_full"].shape == (1, 8721, 16, 512) \
+        and cache["k_window"].shape == (2, 3089, 16, 512)
+    return cfg, params, cache
+
+
+def test_the_patterns_decode_window_compiles_with_both_pools_in_place(
+        tpu_sharding):
+    """The decode window of the per-head pattern at published widths
+    (its first three layers): the window kernel runs in the two sliding
+    runs, the full kernel in the full one, the grouped matmul in both
+    expert layers, and the program's temporaries hold no copy of either
+    pool (0.29 + 0.20 GB here)."""
+    from deepspeed_tpu.inference.v2.paged_model import paged_decode_window
+
+    cfg, params, cache = _window_cut(tpu_sharding)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=tpu_sharding)
+
+    R = 16
+    compiled = jax.jit(
+        lambda p, t, pos, bt, c, sl, eos, alive, wt: paged_decode_window(
+            cfg, p, t, pos, bt, c, sl, eos, 16, 8, use_kernel=True,
+            alive=alive, window_tables=wt), donate_argnums=(4,)).lower(
+        params, i32(R), i32(R), i32(R, 544), cache, i32(R), i32(R),
+        jax.ShapeDtypeStruct((R,), jnp.bool_, sharding=tpu_sharding),
+        i32(R, 193)).compile()
+    kernels = re.findall(r"%([\w.\-]+) = [^\n]*tpu_custom_call",
+                         compiled.as_text())
+    assert sum(k.startswith("ragged_attention_window") for k in kernels) \
+        == 2, kernels
+    assert sum(k.startswith("ragged_attention_tiled") for k in kernels) \
+        == 1, kernels
+    assert sum(bool(GMM_PATTERN.search(k)) for k in kernels) == 6, kernels
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.1e9
