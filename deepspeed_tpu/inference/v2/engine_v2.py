@@ -44,7 +44,8 @@ from ...telemetry import trace, watchdog
 from ...utils.bucketing import ceil_bucket, pow2_bucket
 from ...utils.logging import log_dist
 from .config_v2 import RaggedInferenceEngineConfig
-from .kernels.linear_attention import chunk_kernel_serves
+from .kernels.linear_attention import (chunk_kernel_serves,
+                                       conv_kernel_serves)
 from .kernels.ragged_attention import LATENT, kernel_variant
 from .paged_model import (init_lora_bank, init_paged_kv_cache,
                           paged_continue, paged_decode, paged_decode_window,
@@ -697,6 +698,13 @@ class InferenceEngineV2:
             "their chunked form as the kernel kda_chunk_fwd (0 for a "
             "model without such layers, and where the backend or the "
             "widths leave it to the XLA form)")
+        self._m_conv_kernel_steps = reg.counter(
+            "inference_linear_conv_kernel_steps_total",
+            "decode steps launched whose linear-attention layers ran "
+            "their short convolution as the kernel kda_conv_update (a "
+            "fused window counts its steps; 0 for a model without such "
+            "layers, and where the backend or the widths leave it to "
+            "the XLA form)")
         self._m_prefill_chunks = reg.counter(
             "inference_prefill_chunks_total",
             "ragged steps put() ran for a prompt set it fed in chunks (a "
@@ -1618,6 +1626,7 @@ class InferenceEngineV2:
             if self._has_state:
                 self._m_state_rows.labels(program="decode_step").inc(
                     len(uids))
+            self._note_conv_kernel_steps(1)
             self._m_decode_steps.inc()
             self._m_decode_tokens.inc(len(uids))
             self._m_decode_time.observe(dt)
@@ -1677,6 +1686,14 @@ class InferenceEngineV2:
                 p, t, pos, bt, c, a, rng, seeds, g0, temp, topp, topk,
                 lb, aid, ss, *wt),
             lambda v, i: int(v[i]))
+
+    def _note_conv_kernel_steps(self, steps: int):
+        """``steps`` decode steps went to the device: a model with
+        linear layers ran their convolution as the kernel where the
+        decode programs' own test says so."""
+        if self._has_state and self._use_kernel and conv_kernel_serves(
+                self.kv_cache["kda_conv"]):
+            self._m_conv_kernel_steps.inc(steps)
 
     # -- fused multi-token decode window --------------------------------
     def _launch_window(self, uids: List[int], tokens: Optional[List[int]],
@@ -1739,6 +1756,7 @@ class InferenceEngineV2:
                     *self._window_tables(uids, N))
                 if behind is not None:
                     self._m_windows_ahead.inc()
+                self._note_conv_kernel_steps(self.decode_window)
                 win = _Window(
                     uids=list(uids), steps_left=list(steps_left),
                     fed=behind if behind is not None else tokens,
@@ -2148,9 +2166,10 @@ class InferenceEngineV2:
 
     def sequence_state(self, uid: int) -> Dict[str, np.ndarray]:
         """The recurrent state a tracked sequence holds in its slot, on
-        the host, as the cache keeps it: ``kda_state`` ``[linear layers,
-        heads, d_k, d_v]`` and ``kda_conv`` ``[linear layers, taps - 1,
-        3 x heads x d_k]``, after every token fed so far. The read half
+        the host: ``kda_state`` ``[linear layers, heads, d_k, d_v]`` and
+        ``kda_conv`` ``[linear layers, taps - 1, 3 x heads x d_k]`` (an
+        input's channels in one row, however the leaf folds them), after
+        every token fed so far. The read half
         of a snapshot (what preemption and handoff of such a model would
         carry: ROADMAP M5)."""
         if not self._has_state:
@@ -2160,9 +2179,12 @@ class InferenceEngineV2:
         if not sm.known_seq(uid):
             raise KeyError(f"sequence_state: uid {uid} is not tracked")
         slot = sm.seqs[uid].state_slot
-        return {name: np.asarray(leaf[:, slot])
-                for name, leaf in self.kv_cache.items()
-                if name.startswith("kda_")}
+        state = {name: np.asarray(leaf[:, slot])
+                 for name, leaf in self.kv_cache.items()
+                 if name.startswith("kda_")}
+        conv = state["kda_conv"]
+        state["kda_conv"] = conv.reshape(*conv.shape[:2], -1)
+        return state
 
     def sequence_kv(self, uid: int, kind: str = "window"
                     ) -> Dict[str, np.ndarray]:

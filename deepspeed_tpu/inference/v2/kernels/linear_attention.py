@@ -16,20 +16,26 @@ value ``v``, log-decay ``g`` <= 0 a KEY CHANNEL and update strength
   ragged step's flat token buffer.
 
 Each form has a Pallas kernel that works on the state leaf where it
-lies and an XLA implementation that is its reference in the tests:
+lies and an XLA implementation that is its reference in the tests, and
+so has the one-token form of the short convolution in front of q, k
+and v:
 
-==========  ==========================  ================================
-form        kernel (a TPU, d_k and d_v  XLA (every other backend and
-            multiples of 128, heads in  width: the CPU tests, toy
-            whole grid steps)           widths)
-==========  ==========================  ================================
-one token   :func:`kda_state_update`    gather, :func:`kda_step`, scatter
-chunked     :func:`kda_chunk_fwd`       :func:`kda_chunked`
-==========  ==========================  ================================
+===========  ==========================  ===============================
+form         kernel (a TPU, d_k and d_v  XLA (every other backend and
+             multiples of 128, heads in  width: the CPU tests, toy
+             whole grid steps)           widths)
+===========  ==========================  ===============================
+one token    :func:`kda_state_update`    gather, :func:`kda_step`, scatter
+chunked      :func:`kda_chunk_fwd`       :func:`kda_chunked`
+convolution  :func:`kda_conv_update`     gather, :func:`causal_conv_step`,
+at decode    (q, k and v each whole      scatter
+             (16, 128) tiles)
+===========  ==========================  ===============================
 
-:func:`state_kernel_serves` / :func:`chunk_kernel_serves` say which
-runs, from the leaf's shape and ``jax.default_backend()`` alone: no
-option selects a form. :func:`kda_chunked` is a ``while_loop`` whose
+:func:`state_kernel_serves` / :func:`chunk_kernel_serves` /
+:func:`conv_kernel_serves` say which runs, from the leaf's shape and
+``jax.default_backend()`` alone: no option selects a form.
+:func:`kda_chunked` is a ``while_loop`` whose
 step j takes chunk j of a block of rows (gathered by ``starts`` /
 ``counts``) from their slots and back. :func:`kda_chunk_fwd` is one
 launch a layer: a grid step takes one row and 8 of its heads, the
@@ -56,7 +62,12 @@ product of either is float32 (``Precision.HIGHEST``).
 
 :func:`causal_conv_rows` / :func:`causal_conv_step` are the short
 depthwise convolution in front of q, k and v, over a row's own tokens,
-its last ``taps - 1`` inputs carried as state beside ``S``.
+its last ``taps - 1`` inputs carried as state beside ``S`` in a leaf of
+its own, an input as rows of 128 lanes (:func:`conv_leaf_shape`).
+:func:`kda_conv_update` is the one-token form as one launch a layer: a
+grid step takes one row, its slot comes in and goes back once, the
+taps are fetched once. The ragged step (a row's prompt tokens) keeps
+:func:`causal_conv_rows`.
 """
 
 import functools
@@ -607,3 +618,88 @@ def causal_conv_step(x, taps, conv_state, act=None):
     y = jnp.sum(seq * taps.astype(jnp.float32)[None], axis=1)
     y = y if act is None else act(y)
     return y.astype(x.dtype), seq[:, 1:].astype(conv_state.dtype)
+
+
+def conv_leaf_shape(layers, slots, taps, width):
+    """The shape of the convolution's state leaf: a slot's last
+    ``taps - 1`` inputs of the ``width`` channels (q, k and v side by
+    side), an input ``width / 128`` rows of 128 lanes where the width is
+    whole lane blocks (float32 tiles of (8, 128) then hold a slot with
+    no padding, where ``[taps - 1 = 3, width]`` pads 3 sublanes to 4
+    and leaves a vector register an eighth full), else one row."""
+    lanes = 128 if width % 128 == 0 else width
+    return (layers, slots, taps - 1, width // lanes, lanes)
+
+
+def conv_kernel_serves(leaf) -> bool:
+    """Whether :func:`kda_conv_update` takes this convolution leaf
+    ``[layers, slots, taps - 1, rows, lanes]`` (:func:`conv_leaf_shape`):
+    on a TPU, the channels whole lane blocks, each of q, k and v whole
+    (16, 128) tiles (what projections in bfloat16 ask; float32 ones
+    half of it)."""
+    rows, lanes = leaf.shape[3:]
+    return (jax.default_backend() == "tpu" and lanes == 128
+            and rows % (3 * 16) == 0)
+
+
+def _conv_kernel(layer_ref, slots_ref, fresh_ref, s_ref, w_ref, q_ref,
+                 k_ref, v_ref, so_ref, qo_ref, ko_ref, vo_ref):
+    """One row's token through the convolution: ``s_ref`` [K - 1, rows,
+    128] the slot's last inputs, oldest first, q, k and v one after
+    another along the rows; ``w_ref`` [K, rows, 128] the taps laid out
+    likewise; the token's projections [rows / 3, 128] each."""
+    del layer_ref, slots_ref            # the index maps read them
+    keep = fresh_ref[pl.program_id(0)] == 0
+    x = jnp.concatenate([r[...].astype(jnp.float32)
+                         for r in (q_ref, k_ref, v_ref)], axis=0)
+    seq = [jnp.where(keep, s_ref[t].astype(jnp.float32), 0.0)
+           for t in range(s_ref.shape[0])] + [x]
+    y = seq[0] * w_ref[0]
+    for t in range(1, len(seq)):
+        y = y + seq[t] * w_ref[t]
+    y = jax.nn.silu(y)
+    for t in range(s_ref.shape[0]):
+        so_ref[t] = seq[t + 1].astype(so_ref.dtype)
+    part = q_ref.shape[0]
+    for i, o_ref in enumerate((qo_ref, ko_ref, vo_ref)):
+        o_ref[...] = y[i * part:(i + 1) * part].astype(o_ref.dtype)
+
+
+def kda_conv_update(leaf, layer, slots, fresh, q, k, v, taps,
+                    interpret=False):
+    """:func:`causal_conv_step` with SiLU on the rows' slots of the
+    convolution leaf where it lies: ``leaf`` ``[layers, slots, K - 1,
+    rows, 128]`` (:func:`conv_leaf_shape`) stays whole in HBM, and a
+    grid step copies in row n's slot ``slots[n]`` at ``layer`` (both
+    prefetched scalars) and the row's new q, k and v projections [N, D]
+    (three operands, in their own type), and writes the slot's inputs
+    shifted by one with the token's own appended back to where they
+    came from (aliased) and the convolved, SiLU'd q, k and v in the
+    projections' type: a slot is read once and written once.
+    ``fresh[n]``: the row's first token, its inputs start from zeros.
+    ``taps`` [K, 3 D] (tap K - 1 meets the token itself), fetched once.
+    The sum runs in float32, oldest tap first. Returns ((q, k, v)
+    [N, D], leaf). A trace shows it as ``kda_conv_update``."""
+    N, D = q.shape
+    K1, rows, lanes = leaf.shape[2:]
+    part = pl.BlockSpec((None, rows // 3, lanes), lambda n, *_: (n, 0, 0))
+    state = pl.BlockSpec(
+        (None, None, K1, rows, lanes),
+        lambda n, layer, slots, fresh: (layer[0], slots[n], 0, 0, 0))
+    weights = pl.BlockSpec((K1 + 1, rows, lanes), lambda n, *_: (0, 0, 0))
+    leaf, *mixed = pl.pallas_call(
+        _conv_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(N,),
+            in_specs=[state, weights, part, part, part],
+            out_specs=[state, part, part, part]),
+        out_shape=[jax.ShapeDtypeStruct(leaf.shape, leaf.dtype)] + [
+            jax.ShapeDtypeStruct((N, rows // 3, lanes), a.dtype)
+            for a in (q, k, v)],
+        input_output_aliases={3: 0},
+        name="kda_conv_update", interpret=interpret,
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), slots.astype(jnp.int32),
+      fresh.astype(jnp.int32), leaf,
+      taps.astype(jnp.float32).reshape(K1 + 1, rows, lanes),
+      *(a.reshape(N, rows // 3, lanes) for a in (q, k, v)))
+    return tuple(a.reshape(N, D) for a in mixed), leaf

@@ -136,7 +136,9 @@ def _init_latent_cache(cfg, num_blocks, block_size, dtype, kv_quant,
     layers' recurrent state lies beside it, indexed by a sequence's
     state SLOT and not by block: ``kda_state`` ``[L_linear, slots + 1,
     heads, d_k, d_v]`` and ``kda_conv`` ``[L_linear, slots + 1, taps - 1,
-    3 x heads x d_k]`` (the convolution's last inputs), both in
+    3 x heads x d_k / 128, 128]`` (the convolution's last inputs, an
+    input of q, k and v as rows of 128 lanes, so that a slot is whole
+    tiles: ``linear_attention.conv_leaf_shape``), both in
     ``state_dtype`` (float32: a state rounded to bfloat16 at every token
     drifts from the recurrence). Slot 0 is the null slot, as block 0 is
     the null block: padded and masked rows read and write it.
@@ -147,12 +149,13 @@ def _init_latent_cache(cfg, num_blocks, block_size, dtype, kv_quant,
              latent_pool_row(cfg))
     state = {}
     if "kda" in kinds:
+        from .kernels.linear_attention import conv_leaf_shape
         n, d = kinds.count("kda"), cfg.linear_head_dim
         state = {"kda_state": jnp.zeros(
                      (n, state_slots + 1, cfg.num_heads, d, d), state_dtype),
-                 "kda_conv": jnp.zeros(
-                     (n, state_slots + 1, cfg.linear_conv_size - 1,
-                      3 * cfg.num_heads * d), state_dtype)}
+                 "kda_conv": jnp.zeros(conv_leaf_shape(
+                     n, state_slots + 1, cfg.linear_conv_size,
+                     3 * cfg.num_heads * d), state_dtype)}
     if kv_quant:
         # int8 rows, one float32 scale a cached position (its row's
         # absmax / 127): half the pool's bytes. A launch dequantises the
@@ -835,7 +838,11 @@ def _linear_attention_sublayer(cfg, lp, x, l, cache, rows: _StateRows,
     """A linear-attention (KDA) mixer on flat tokens x [T, H]; ``l`` is
     the layer's index among the linear layers (its state leaves'
     leading axis). q, k and v pass the short causal convolution over the
-    row's own tokens and SiLU; a head's q and k are l2-normalised (q
+    row's own tokens and SiLU (scope ``kda_conv``: a decode batch through
+    the kernel that reads and writes the slot's last inputs where they
+    lie, ``kda_conv_update``, where ``use_kernel`` and the widths allow,
+    else gather, ``causal_conv_step`` and scatter; every other launch
+    through ``causal_conv_rows``); a head's q and k are l2-normalised (q
     times d_k^-1/2); the decay a head and key channel is
     ``linear_decay_floor * sigmoid(exp(a_log) (wf x + dt_bias))`` and
     the update strength ``sigmoid(wb x)``; the heads' outputs are
@@ -859,24 +866,44 @@ def _linear_attention_sublayer(cfg, lp, x, l, cache, rows: _StateRows,
     dt = lp["wq"].dtype
     hn = _norm(cfg, x, lp["attn_norm"]).astype(dt)
     with jax.named_scope("kda_proj"):
-        qkv = [hn @ lp[w] for w in ("wq", "wk", "wv")]
+        # a decode row's q, k and v stay float32 from the matmul's sum
+        # to the recurrence. XLA's fusions kept them so (excess
+        # precision) while the convolution was theirs; a kernel between
+        # them takes what the program says, and rounded to ``dt`` before
+        # and behind it the state's error against the float32 reference
+        # read 6.0e-3 where it had read 4.3e-3 (PERF.md section 6, PR 45)
+        wide = f32 if rows.one_token else None
+        qkv = [jnp.dot(hn, lp[w], preferred_element_type=wide)
+               for w in ("wq", "wk", "wv")]
         f, b = hn @ lp["wf"], hn @ lp["wb"]
     slots = rows.slots
     with jax.named_scope("kda_conv"):
-        held = cache["kda_conv"][l, slots]                 # [R, K - 1, 3D]
-        held = jnp.where(rows.fresh[:, None, None], 0, held)
-        mixed, kept = [], []
-        for i, part in enumerate(qkv):
-            taps = lp["conv"][:, i * D:(i + 1) * D]
-            past = held[..., i * D:(i + 1) * D]
-            y, past = la.causal_conv_step(part, taps, past, jax.nn.silu) \
-                if rows.one_token else la.causal_conv_rows(
-                    part, taps, past, rows.row_ids, rows.starts,
-                    rows.counts, jax.nn.silu)
-            mixed.append(y)
-            kept.append(past)
-        cache = {**cache, "kda_conv": cache["kda_conv"].at[l, slots].set(
-            jnp.concatenate(kept, axis=-1))}
+        leaf = cache["kda_conv"]            # [L, slots, K - 1, 3D / w, w]
+        if rows.one_token and use_kernel and la.conv_kernel_serves(leaf):
+            # the kernel takes a row's projections as rows of 128 lanes.
+            # Behind the barrier that is a 2 MB copy of each; without it
+            # XLA has the matmuls make that shape, and for them copies
+            # every layer's wq, wk and wv transposed, once a launch
+            mixed, leaf = la.kda_conv_update(
+                leaf, l, slots, rows.fresh,
+                *jax.lax.optimization_barrier(qkv), lp["conv"])
+        else:
+            held = leaf[l, slots].reshape(-1, leaf.shape[2], 3 * D)
+            held = jnp.where(rows.fresh[:, None, None], 0, held)
+            mixed, kept = [], []
+            for i, part in enumerate(qkv):
+                taps = lp["conv"][:, i * D:(i + 1) * D]
+                past = held[..., i * D:(i + 1) * D]
+                y, past = la.causal_conv_step(
+                    part, taps, past, jax.nn.silu) \
+                    if rows.one_token else la.causal_conv_rows(
+                        part, taps, past, rows.row_ids, rows.starts,
+                        rows.counts, jax.nn.silu)
+                mixed.append(y)
+                kept.append(past)
+            leaf = leaf.at[l, slots].set(jnp.concatenate(
+                kept, axis=-1).reshape(-1, *leaf.shape[2:]))
+        cache = {**cache, "kda_conv": leaf}
     rate = jnp.exp(lp["a_log"].astype(f32))[:, None]        # [nh, 1]
     dt_bias = lp["dt_bias"].astype(f32).reshape(nh, d)
 

@@ -204,6 +204,37 @@ def test_a_kept_sequence_holds_the_references_state():
         eng.sequence_state(0)
 
 
+@pytest.mark.parametrize("head_dim,leaf", [
+    (16, (7, 5, 3, 1, 192)),            # TOY's: 192 channels, one row
+    (32, (7, 5, 3, 3, 128))])           # whole lane blocks: rows of 128
+def test_sequence_state_reads_the_last_inputs_whatever_the_leafs_layout(
+        head_dim, leaf):
+    """``sequence_state(uid)["kda_conv"]`` is ``[linear layers, taps - 1,
+    3 x heads x d_k]`` whether the leaf keeps an input as one row or as
+    rows of 128 lanes, and after a prompt (the ragged step's XLA form)
+    and decode steps (the one-token form) layer 0 holds the q, k and v
+    projections of the last three tokens fed, oldest first."""
+    fields = {**TOY, "linear_head_dim": head_dim}
+    eng = _engine("float32", fields=fields)
+    assert eng.kv_cache["kda_conv"].shape == leaf
+    out, = eng.generate(_prompts((9,)), max_new_tokens=5, temperature=0.0,
+                        eos_token_id=None, keep_sequences=True)
+    conv = eng.sequence_state(0)["kda_conv"]
+    D = TOY["num_heads"] * head_dim
+    assert conv.shape == (7, 3, 3 * D)
+    assert eng.sequence_state(0)["kda_state"].shape == (
+        7, TOY["num_heads"], head_dim, head_dim)
+    params = weights_ling.make(fields, SEED, "float32")
+    lp = jax.tree.map(lambda a: a[0], params["kda_layers"])
+    cfg = eng.model.cfg
+    fed = np.asarray(out[:-1][-3:])
+    hn = paged_model._norm(cfg, params["embed"][fed] * cfg.embed_scale,
+                           lp["attn_norm"])
+    want = np.concatenate([hn @ lp[w] for w in ("wq", "wk", "wv")], axis=-1)
+    assert _err(conv[0], want) <= F32_TIGHT
+    eng.flush(0)
+
+
 def test_a_call_that_raises_keeps_nothing():
     eng = _engine("float32")
     with pytest.raises(RuntimeError, match="not schedulable"):
@@ -410,6 +441,78 @@ def test_the_decode_kernel_is_the_one_token_form_in_place(kept):
     np.testing.assert_array_equal(new[0], old[0])
 
 
+# (slots, fresh) of six rows in a leaf of nine slots: the slot order is
+# never the row order
+CONV_ROWS = {
+    # two rows at their first token, one at the null slot
+    "kept_and_fresh": ([3, 1, 7, 0, 5, 8], [0, 1, 0, 0, 0, 1]),
+    # padded and masked rows share the null slot, whatever they claim
+    "null_slot_shared": ([0, 4, 0, 2, 0, 0], [0, 0, 1, 1, 0, 1]),
+    "every_row_fresh": ([8, 6, 4, 2, 1, 3], [1, 1, 1, 1, 1, 1]),
+}
+
+
+@pytest.mark.parametrize("proj,kept", [
+    ("float32", "float32"), ("bfloat16", "float32"),
+    ("bfloat16", "bfloat16")])
+@pytest.mark.parametrize("rows", sorted(CONV_ROWS))
+def test_the_conv_kernel_is_the_one_token_convolution_in_place(
+        rows, proj, kept):
+    """``kda_conv_update`` under the interpreter, 16 lane blocks a part,
+    on layer 1 of a lane-dense leaf: the convolved q, k and v and the
+    slots' shifted inputs are ``causal_conv_step``'s on what the slots
+    held (zeros for a fresh row), every other slot and layer comes back
+    as it went in. A slot's inputs are copies: equal to the bit. The
+    sum of bf16 projections is equal to the bit too once rounded; on
+    float32 ones the two programs differ by where the CPU's compiler
+    contracts a product and a sum (an ulp). Rows that share the null
+    slot see each other's writes there in a kernel that runs a row
+    after another, and not in a gather: of them, only the fresh ones'
+    outputs are defined, and the slot's newest input is one row's."""
+    rng = np.random.default_rng(0)
+    N, D, K, L, S = 6, 16 * 128, 4, 2, 9
+    slots, fresh = (np.asarray(a) for a in CONV_ROWS[rows])
+    fresh = fresh.astype(bool)
+    shape = la.conv_leaf_shape(L, S, K, 3 * D)
+    assert shape == (L, S, K - 1, 48, 128)
+    leaf = jnp.asarray(rng.standard_normal(shape), kept)
+    q, k, v = (jnp.asarray(rng.standard_normal((N, D)), proj)
+               for _ in range(3))
+    taps = jnp.asarray(rng.standard_normal((K, 3 * D)), proj)
+    mixed, new = jax.jit(lambda *a: la.kda_conv_update(*a, interpret=True))(
+        leaf, jnp.int32(1), jnp.asarray(slots), jnp.asarray(fresh),
+        q, k, v, taps)
+    assert new.dtype == leaf.dtype and new.shape == leaf.shape
+    held = jnp.where(fresh[:, None, None], 0,
+                     leaf[1, slots].reshape(N, K - 1, 3 * D))
+    defined = (slots > 0) | fresh
+    got = np.asarray(new[1, slots].reshape(N, K - 1, 3 * D), np.float32)
+    for i, (x, y) in enumerate(zip((q, k, v), mixed)):
+        part = slice(i * D, (i + 1) * D)
+        want_y, want_s = la.causal_conv_step(
+            x, taps[:, part], held[..., part], jax.nn.silu)
+        assert y.dtype == x.dtype and y.shape == x.shape
+        y, want_y = (np.asarray(a, np.float32)[defined]
+                     for a in (y, want_y))
+        if proj == "bfloat16":
+            np.testing.assert_array_equal(y, want_y)
+        else:
+            np.testing.assert_allclose(y, want_y, rtol=2e-6, atol=2e-6)
+        np.testing.assert_array_equal(
+            got[..., part][slots > 0],
+            np.asarray(want_s, np.float32)[slots > 0])
+        if (slots == 0).any():          # its newest input: one row's own
+            newest = np.asarray(new[1, 0].reshape(K - 1, 3 * D),
+                                np.float32)[-1, part]
+            assert any(np.array_equal(newest, np.asarray(x[r], np.float32))
+                       for r in np.flatnonzero(slots == 0))
+    old = np.asarray(leaf, np.float32)
+    new = np.asarray(new, np.float32)
+    others = [i for i in range(1, S) if i not in slots]
+    np.testing.assert_array_equal(new[1, others], old[1, others])
+    np.testing.assert_array_equal(new[0], old[0])
+
+
 def test_a_fresh_row_starts_from_zeros_whatever_its_slot_holds():
     tokens, starts, counts = _kda_rows((70, 20))
     tokens = tuple(jnp.asarray(a) for a in tokens)
@@ -607,6 +710,44 @@ def test_the_engine_counts_the_steps_the_chunk_kernel_took(monkeypatch):
     assert not la.chunk_kernel_serves(leaf(4, 128, 128))     # half a tile
 
 
+def test_the_engine_counts_the_steps_the_conv_kernel_took(monkeypatch):
+    """``inference_linear_conv_kernel_steps_total`` follows
+    ``conv_kernel_serves`` a decode step LAUNCHED: 0 here (the CPU, toy
+    widths: the XLA form), a window's steps a fused window and one a
+    per-token step where it says yes; and what it asks is a TPU and a
+    leaf whose channels are whole lane blocks, each of q, k and v whole
+    (16, 128) tiles."""
+    from deepspeed_tpu.inference.v2 import engine_v2
+    reg = get_registry()
+    eng = _engine("float32")            # decode_window 4
+    steps = reg.get("inference_linear_conv_kernel_steps_total")
+    before = steps.value
+    prompts = _prompts((12, 8))
+    eng.generate(prompts, max_new_tokens=9, temperature=0.0,
+                 eos_token_id=None)
+    assert steps.value == before
+    monkeypatch.setattr(engine_v2, "conv_kernel_serves", lambda leaf: True)
+    eng.generate(prompts, max_new_tokens=9, temperature=0.0,
+                 eos_token_id=None)     # 8 decode steps: two windows of 4
+    assert steps.value == before + 8
+    eng.put([0], prompts[:1])
+    eng._decode_batch_greedy([0], [1])
+    assert steps.value == before + 9
+    eng.flush(0)
+
+    def leaf(width, taps=4):
+        return jax.ShapeDtypeStruct(
+            la.conv_leaf_shape(7, 129, taps, width), jnp.float32)
+
+    assert leaf(3 * 4096).shape == (7, 129, 3, 96, 128)
+    assert not la.conv_kernel_serves(leaf(3 * 4096))         # the CPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert la.conv_kernel_serves(leaf(3 * 4096))
+    assert la.conv_kernel_serves(leaf(3 * 2048, taps=2))
+    assert not la.conv_kernel_serves(leaf(3 * 64))           # TOY's
+    assert not la.conv_kernel_serves(leaf(3 * 1024))         # half a tile
+
+
 # ---------------------------------------------------------------------------
 # (d) trees, leaves, runs
 # ---------------------------------------------------------------------------
@@ -641,7 +782,7 @@ def test_the_cache_keeps_a_leaf_a_layer_kind():
         cfg, 9, 16, jnp.bfloat16, state_slots=4))
     assert cache["latent"].shape == (1, 9, 16, 128)      # latent layers
     assert cache["kda_state"].shape == (7, 5, 4, 16, 16)  # by slot
-    assert cache["kda_conv"].shape == (7, 5, 3, 192)
+    assert cache["kda_conv"].shape == (7, 5, 3, 1, 192)   # one row: toy
     assert cache["kda_state"].dtype == cache["kda_conv"].dtype \
         == jnp.float32
     assert cache["latent"].dtype == jnp.bfloat16

@@ -634,6 +634,34 @@ def test_the_chunk_kernel_at_published_widths(tpu_sharding):
     assert compiled.memory_analysis().temp_size_in_bytes < 0.1e9
 
 
+@pytest.mark.parametrize("proj", [jnp.float32, jnp.bfloat16])
+def test_the_conv_kernel_at_published_widths(tpu_sharding, proj):
+    """``kda_conv_update`` at the cell's decode shape (128 rows, q, k and
+    v of 4,096 channels as the decode programs keep them, float32, and
+    in bf16; a float32 leaf of 7 layers and 129 slots stored
+    lane-dense): it compiles for the chip, runs as ONE custom call
+    under a name a trace finds, and the leaf is aliased (no copy of
+    0.13 GB: what the program keeps beside its arguments are the
+    projections as rows of lanes, 6 MB in and 6 MB out at most)."""
+    from deepspeed_tpu.inference.v2.kernels import linear_attention as la
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=tpu_sharding)
+
+    N, D, K = 128, 32 * 128, 4
+    leaf = sds(la.conv_leaf_shape(7, 129, K, 3 * D))
+    assert leaf.shape == (7, 129, 3, 96, 128) and la.conv_kernel_serves(leaf)
+    x = sds((N, D), proj)
+    compiled = jax.jit(la.kda_conv_update, donate_argnums=(0,)).lower(
+        leaf, sds((), jnp.int32), sds((N,), jnp.int32),
+        sds((N,), jnp.bool_), x, x, x,
+        sds((K, 3 * D), jnp.bfloat16)).compile()
+    kernels = re.findall(r"%([\w.\-]+) = [^\n]*tpu_custom_call",
+                         compiled.as_text())
+    assert len(kernels) == 1 and kernels[0].startswith("kda_conv_update")
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.02e9
+
+
 def _hybrid_cut(tpu_sharding):
     """The pattern at published widths, cut to its first linear expert
     layer and the layers before it (3 layers, 8 experts held): the
@@ -698,9 +726,13 @@ def test_the_hybrid_decode_window_compiles_with_its_state_in_place(
         tpu_sharding):
     """The decode window of the pattern at published widths, cut to its
     first linear expert layer and the layers before it (3 layers, 8
-    experts held): the state kernel runs in both linear runs, the
-    grouped matmul in the expert layer, and the program's temporaries
-    hold no copy of the state leaf (32 rows x 3 layers: 0.2 GB)."""
+    experts held): the state kernel and the convolution's kernel run in
+    both linear runs, the grouped matmul in the expert layer; nothing
+    under ``kda_conv`` gathers or scatters the slots in XLA any more or
+    copies the convolution's leaf (a copy of it may only be the move of
+    this cut's small leaf, 15 MB, into the chip's fast memory and back);
+    and the program's temporaries hold no copy of the state leaf (32
+    rows x 3 layers: 0.2 GB)."""
     from deepspeed_tpu.inference.v2.paged_model import paged_decode_window
 
     cfg, params, cache = _hybrid_cut(tpu_sharding)
@@ -716,11 +748,19 @@ def test_the_hybrid_decode_window_compiles_with_its_state_in_place(
         params, i32(R), i32(R), i32(R, 16), cache, i32(R), i32(R),
         jax.ShapeDtypeStruct((R,), jnp.bool_, sharding=tpu_sharding),
         i32(R)).compile()
-    kernels = re.findall(r"%([\w.\-]+) = [^\n]*tpu_custom_call",
-                         compiled.as_text())
+    text = compiled.as_text()
+    kernels = re.findall(r"%([\w.\-]+) = [^\n]*tpu_custom_call", text)
     assert sum(k.startswith("kda_state_update") for k in kernels) == 2, \
         kernels
+    assert sum(k.startswith("kda_conv_update") for k in kernels) == 2, \
+        kernels
     assert sum(bool(GMM_PATTERN.search(k)) for k in kernels) == 3, kernels
+    under_conv = re.findall(
+        r"= \S+ ([\w\-]+)\([^\n]*op_name=\"[^\"]*/kda_conv/", text)
+    assert under_conv and not {"gather", "scatter", "copy", "copy-start"} \
+        & set(under_conv), under_conv
+    leaf = re.escape("f32[%s]" % ",".join(map(str, cache["kda_conv"].shape)))
+    assert not re.search(r"= %s\S* (?:fusion|copy)\(" % leaf, text)
     assert compiled.memory_analysis().temp_size_in_bytes < 0.15e9
 
 
