@@ -46,7 +46,8 @@ from ...utils.logging import log_dist
 from .config_v2 import RaggedInferenceEngineConfig
 from .kernels.linear_attention import (chunk_kernel_serves,
                                        conv_kernel_serves)
-from .kernels.ragged_attention import LATENT, kernel_variant
+from .kernels.ragged_attention import (LATENT, kernel_variant,
+                                       one_token_tile_serves)
 from .paged_model import (init_lora_bank, init_paged_kv_cache,
                           paged_continue, paged_decode, paged_decode_window,
                           paged_prefill, paged_ragged_step,
@@ -705,6 +706,12 @@ class InferenceEngineV2:
             "fused window counts its steps; 0 for a model without such "
             "layers, and where the backend or the widths leave it to "
             "the XLA form)")
+        self._m_one_token_steps = reg.counter(
+            "inference_attention_one_token_steps_total",
+            "decode steps launched whose attention kernels took the "
+            "one-token form: a row's pages against that row's own query "
+            "rows (a fused window counts its steps; 0 off the TPU and "
+            "where the decode programs run no tiled or latent kernel)")
         self._m_prefill_chunks = reg.counter(
             "inference_prefill_chunks_total",
             "ragged steps put() ran for a prompt set it fed in chunks (a "
@@ -1626,7 +1633,7 @@ class InferenceEngineV2:
             if self._has_state:
                 self._m_state_rows.labels(program="decode_step").inc(
                     len(uids))
-            self._note_conv_kernel_steps(1)
+            self._note_kernel_steps(1)
             self._m_decode_steps.inc()
             self._m_decode_tokens.inc(len(uids))
             self._m_decode_time.observe(dt)
@@ -1687,13 +1694,19 @@ class InferenceEngineV2:
                 lb, aid, ss, *wt),
             lambda v, i: int(v[i]))
 
-    def _note_conv_kernel_steps(self, steps: int):
+    def _note_kernel_steps(self, steps: int):
         """``steps`` decode steps went to the device: a model with
-        linear layers ran their convolution as the kernel where the
-        decode programs' own test says so."""
-        if self._has_state and self._use_kernel and conv_kernel_serves(
-                self.kv_cache["kda_conv"]):
+        linear layers ran their convolution as the kernel, and the
+        attention kernels took their one-token form, where the decode
+        programs' own tests say so."""
+        if not self._use_kernel:
+            return
+        if self._has_state and conv_kernel_serves(self.kv_cache["kda_conv"]):
             self._m_conv_kernel_steps.inc(steps)
+        cfg = self.model.cfg
+        if one_token_tile_serves(cfg.attention == "mla", cfg.head_dim,
+                                 cfg.kv_heads):
+            self._m_one_token_steps.inc(steps)
 
     # -- fused multi-token decode window --------------------------------
     def _launch_window(self, uids: List[int], tokens: Optional[List[int]],
@@ -1756,7 +1769,7 @@ class InferenceEngineV2:
                     *self._window_tables(uids, N))
                 if behind is not None:
                     self._m_windows_ahead.inc()
-                self._note_conv_kernel_steps(self.decode_window)
+                self._note_kernel_steps(self.decode_window)
                 win = _Window(
                     uids=list(uids), steps_left=list(steps_left),
                     fed=behind if behind is not None else tokens,

@@ -11,7 +11,11 @@ serving hot path).
 Decode is the ragged kernel (``ragged_attention.py``) with one token per
 row: the token -> row map is the identity and ``lengths`` is per
 sequence. One kernel family, one variant table
-(:func:`~.ragged_attention.kernel_variant`), one set of numerics.
+(:func:`~.ragged_attention.kernel_variant`), one set of numerics. That
+every row has one token is known here when the program is traced, so
+the tiled variant is asked for its one-token form (``one_token=True``:
+a row's chunks meet that row's own query rows, not a tile of 16
+tokens' of which 15 are masked).
 """
 
 from typing import Optional
@@ -35,4 +39,4 @@ def paged_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
     return ragged_attention(
         q, k_cache, v_cache, layer, jnp.arange(q.shape[0], dtype=jnp.int32),
         lengths, block_tables, k_scale=k_scale, v_scale=v_scale,
-        variant=variant)
+        variant=variant, one_token=True)

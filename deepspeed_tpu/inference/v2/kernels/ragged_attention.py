@@ -41,7 +41,8 @@ test_kernels_lower_tpu.py`` pins against the Mosaic compiler):
 
 * ``"tiled"`` — the page walk goes per ROW. Grid ``(T / tq,)``: a step
   owns a tile of up to 128 flat tokens and walks the rows whose tokens
-  lie in it (decode: 16 rows of one token; prefill: one row a tile). A
+  lie in it (prefill: one row a tile; a mixed step: whatever rows the
+  tile's tokens belong to; decode: see the one-token form below). A
   row's pages arrive in chunks of ``_CHUNK_POSITIONS`` positions by
   ``make_async_copy`` out of its block table, double-buffered across
   chunks AND rows, up to the causal bound of the row's last token in the
@@ -57,6 +58,20 @@ test_kernels_lower_tpu.py`` pins against the Mosaic compiler):
   (:func:`_tile_update`); tokens of other rows and positions past a
   token's own bound are masked, which is all in-tile causality is.
   Serves every geometry :func:`tiled_geometry` accepts.
+  THE ONE-TOKEN FORM (``one_token=True``, static: the caller knows every
+  row has exactly one token, as ``paged_attention`` and the pattern's
+  decode step do): a grid step owns ``_ONE_TOKEN_ROWS`` rows and walks
+  them the same way, but the queries lie token-major, so a chunk goes
+  through the matrix unit against the WALKED ROW'S OWN query rows (a
+  lane block's ``hpb * group``, padded to a sublane tile) and not
+  against the tile's 16 tokens' of which 15 would be masked; the
+  float32 state is one row's, started at its first chunk and written to
+  its place in the output tile behind its last (:func:`_walk_rows`'
+  ``begin`` / ``finish``); a position is masked by the row's bound and
+  window alone (:func:`_row_visible`). The same chunks in the same order
+  on the same products: the token tile's output to the order of the
+  float32 sums. The launch keeps its name. The latent kernel takes the
+  same form over all ``nh`` heads.
 * ``"pipelined"`` (grid ``(T, MB)``, BlockSpec-indexed): one step a
   token and table slot, the page a ``(1, 1, bs, kvh * hd)`` block of
   the same stored leaf by the index map ``(layer, bt[row, j])``, its
@@ -118,6 +133,14 @@ _TILED_VMEM_BYTES = 64 * 2 ** 20
 
 # positions of a row fetched and computed at a time (tuned on the chip)
 _CHUNK_POSITIONS = 512
+
+# rows a grid step of the one-token form walks
+_ONE_TOKEN_ROWS = 16
+
+
+def _sublane_tiles(rows: int) -> int:
+    """``rows`` query rows padded to whole (8, 128) tiles and no further"""
+    return -(-rows // 8) * 8
 
 
 def _interpret() -> bool:
@@ -264,6 +287,24 @@ def tiled_geometry(head_dim: int, kv_heads: int):
     return None
 
 
+def one_token_tile_serves(latent: bool, head_dim: int, kv_heads: int) -> bool:
+    """Whether the attention launches of a model's DECODE programs take
+    the ONE-TOKEN form here: on a TPU (off it those programs run the
+    pipelined variant and the latent pool's gathering reference, which
+    have no tile to speak of), over a latent pool or a pool whose
+    geometry the tiled variant serves. That every row of such a launch
+    has one token is static (``paged_attention``,
+    ``_pattern_step(one_token=True)``) and the kernels take the form
+    wherever they are told so: every geometry the benchmark holds gained
+    by it on the chip (PERF.md section 6, PR 47: 32 heads of a latent
+    row 2.4 x, GQA group 8 at width 128 1.14 x and 1.27 x with a window,
+    two 64-wide heads a lane block 1.04 x), so none is left on the token
+    tile. What the engine's ``inference_attention_one_token_steps_total``
+    counts by."""
+    return not _interpret() and (
+        latent or tiled_geometry(head_dim, kv_heads) is not None)
+
+
 def _visible(tl_ref, t0, first, last, c, tq, reps, P, base=0, window=0):
     """``(reps * tq, P)``: which of chunk c's positions each query row
     of the tile may attend. Query rows are ``reps`` copies of the tile's
@@ -282,8 +323,20 @@ def _visible(tl_ref, t0, first, last, c, tq, reps, P, base=0, window=0):
     return (pos < eff) & (pos >= eff - window)
 
 
+def _row_visible(bound, c, rows, P, base=0, window=0):
+    """:func:`_visible` in the one-token form, ``(rows, P)``: every
+    query row is the walked row's own token, so what it may attend of
+    chunk c is the positions under that token's ``bound`` (a scalar)
+    and, with a ``window``, not under ``bound - window``."""
+    pos = base + c * P + jax.lax.broadcasted_iota(jnp.int32, (rows, P), 1)
+    if not window:
+        return pos < bound
+    return (pos < bound) & (pos >= bound - window)
+
+
 def _walk_rows(len_ref, bt_ref, first_ref, last_ref, lo, hi, *, t0, tq, bs,
-               cp, copies, compute, chunk_copies=None, window=0, ring=0):
+               cp, copies, compute, chunk_copies=None, window=0, ring=0,
+               begin=None, finish=None):
     """The walk the tiled and the latent kernel share: the tile of flat
     tokens [t0, t0 + tq) visits the rows ``lo..hi`` that own its tokens,
     a row's pages in chunks of ``cp`` up to the causal bound of the
@@ -293,7 +346,9 @@ def _walk_rows(len_ref, bt_ref, first_ref, last_ref, lo, hi, *, t0, tq, bs,
     slot)``: those a whole chunk needs besides); the next chunk, of this
     row or the next, is in flight while ``compute(first, last, c,
     slot)`` runs on this one ([first, last]: the row's tokens in the
-    tile).
+    tile). ``begin(first)`` / ``finish(first)``, where given (the
+    one-token form), run before a row's first chunk is computed and
+    behind its last.
 
     ``window`` > 0 (static): a token sees only its last ``window``
     positions, so a row's walk STARTS at the page that holds the first
@@ -375,14 +430,28 @@ def _walk_rows(len_ref, bt_ref, first_ref, last_ref, lo, hi, *, t0, tq, bs,
 
         wait(r, c, slot)
         first, last_tok, _, page0 = bounds(r)
+        if begin is not None:
+            pl.when(c == 0)(lambda: begin(first))
         if window:
             compute(first, last_tok, c, slot, page0 * bs)
         else:
             compute(first, last_tok, c, slot)
+        if finish is not None:
+            pl.when(last)(lambda: finish(first))
         return nr, nc, 1 - slot
 
     jax.lax.while_loop(lambda state: state[0] <= hi, step,
                        (r0, jnp.int32(0), jnp.int32(0)))
+
+
+def _row_hooks(one_token, finish, acc_sc, m_sc, l_sc):
+    """:func:`_walk_rows`' hooks of the one-token form (none otherwise):
+    the float32 state starts afresh at a row's first chunk, and
+    ``finish`` writes the row's output behind its last."""
+    if not one_token:
+        return {}
+    return dict(begin=lambda _: _init_scratch(acc_sc, m_sc, l_sc),
+                finish=finish)
 
 
 def _tile_update(q, k, v, visible, acc_sc, m_sc, l_sc, b, *, scale):
@@ -416,7 +485,7 @@ def _tile_update(q, k, v, visible, acc_sc, m_sc, l_sc, b, *, scale):
 def _tiled_kernel(layer_ref, len_ref, bt_ref, first_ref, last_ref, lo_ref,
                   hi_ref, q_ref, tl_ref, k_hbm, v_hbm, *rest, quant, bs,
                   scale, kvh, hd, hpb, group, tq, cp, io_dtype, window=0,
-                  ring=0):
+                  ring=0, one_token=False):
     """Grid (T / tq,): one step a tile of ``tq`` flat tokens. The tile
     walks the rows that own its tokens (``lo_ref``/``hi_ref``), a row's
     pages in chunks of ``cp`` up to the causal bound of the row's last
@@ -427,13 +496,23 @@ def _tiled_kernel(layer_ref, len_ref, bt_ref, first_ref, last_ref, lo_ref,
     ``(L, nb, bs, F)``, the layer a prefetched scalar: a page is
     ``k_hbm.at[layer, page]``, ``bs`` rows of ``F = kvh * hd`` lanes,
     whole tiles where it lies; k_buf/v_buf are (2, cp, bs, F); sem is
-    (2, 2) = slot x {k, v}, one wait a page."""
+    (2, 2) = slot x {k, v}, one wait a page.
+
+    ``one_token`` (static): every row has ONE token, and the tile is
+    ``tq`` rows walked one after another. The queries lie token-major,
+    ``(tq, nblk, rows, bw)`` (a lane block's ``hpb * group`` query rows
+    padded to whole sublane tiles), so a chunk goes through the matrix
+    unit against the walked row's own query rows and nothing else; the
+    float32 state is ONE row's, started at the row's first chunk and
+    written to the row's place in the output tile ``(tq, nblk, group,
+    bw)`` behind its last; a position is masked by the row's bound (and
+    window) alone."""
     if quant:
         ks_hbm, vs_hbm, o_ref, k_buf, v_buf, ks_buf, vs_buf, acc_sc, m_sc, \
             l_sc, sem, ssem = rest
     else:
         o_ref, k_buf, v_buf, acc_sc, m_sc, l_sc, sem = rest
-    nblk, rpb = q_ref.shape[:2]
+    nblk, rpb = q_ref.shape[1:3] if one_token else q_ref.shape[:2]
     bw = q_ref.shape[3]
     M, P = rpb * tq, cp * bs
     sw = ks_buf.shape[0] // 2 if quant else 0   # a slot of scales, words
@@ -441,7 +520,10 @@ def _tiled_kernel(layer_ref, len_ref, bt_ref, first_ref, last_ref, lo_ref,
     lo, hi = lo_ref[pl.program_id(0)], hi_ref[pl.program_id(0)]
     layer = layer_ref[0]
 
-    _init_scratch(acc_sc, m_sc, l_sc)
+    if one_token:
+        o_ref[...] = jnp.zeros_like(o_ref)     # a row that is never walked
+    else:
+        _init_scratch(acc_sc, m_sc, l_sc)
 
     @pl.when(pl.program_id(0) == 0)
     def _zero():
@@ -479,8 +561,11 @@ def _tiled_kernel(layer_ref, len_ref, bt_ref, first_ref, last_ref, lo_ref,
                 * jnp.concatenate(rows, axis=0)).astype(io_dtype)
 
     def compute(first, last, c, slot, base=0):
-        visible = _visible(tl_ref, t0, first, last, c, tq, rpb, P, base,
-                           window)
+        if one_token:
+            visible = _row_visible(len_ref[first], c, rpb, P, base, window)
+        else:
+            visible = _visible(tl_ref, t0, first, last, c, tq, rpb, P, base,
+                               window)
         for b in range(nblk):                                 # static
             lanes = slice(b * bw, (b + 1) * bw)
             k = k_buf[slot, :, :, lanes]
@@ -488,14 +573,29 @@ def _tiled_kernel(layer_ref, len_ref, bt_ref, first_ref, last_ref, lo_ref,
             if quant:
                 k = dequant(k, ks_buf, slot, b)
                 v = dequant(v, vs_buf, slot, b)
-            _tile_update(q_ref[b].reshape(M, bw), k.reshape(P, bw),
-                         v.reshape(P, bw), visible, acc_sc, m_sc, l_sc, b,
-                         scale=scale)
+            q = q_ref[first - t0, b] if one_token \
+                else q_ref[b].reshape(M, bw)
+            _tile_update(q, k.reshape(P, bw), v.reshape(P, bw), visible,
+                         acc_sc, m_sc, l_sc, b, scale=scale)
+
+    def finish(first):
+        """The walked row's output, a head's lanes from its own rows."""
+        lane = jax.lax.broadcasted_iota(jnp.int32, (group, bw), 1)
+        for b in range(nblk):                                 # static
+            l = l_sc[b, :, :1]
+            a = acc_sc[b] / jnp.where(l == 0.0, 1.0, l)
+            out = a[:group]
+            for i in range(1, hpb):
+                out = jnp.where(lane >= i * hd,
+                                a[i * group:(i + 1) * group], out)
+            o_ref[first - t0, b] = out.astype(o_ref.dtype)
 
     _walk_rows(len_ref, bt_ref, first_ref, last_ref, lo, hi, t0=t0, tq=tq,
                bs=bs, cp=cp, copies=copies, compute=compute,
                chunk_copies=scale_copies if quant else None, window=window,
-               ring=ring)
+               ring=ring, **_row_hooks(one_token, finish, acc_sc, m_sc, l_sc))
+    if one_token:
+        return
 
     lane = jax.lax.broadcasted_iota(jnp.int32, (group * tq, bw), 1)
     for b in range(nblk):                                     # static
@@ -525,12 +625,14 @@ def _row_descriptors(row_ids, lengths, R, tq):
 
 
 def _tiled_call(q, k_cache, v_cache, layer, row_ids, lengths, block_tables,
-                k_scale, v_scale, interpret, window=0):
+                k_scale, v_scale, interpret, window=0, one_token=False):
     """Lay the operands out for :func:`_tiled_kernel` and undo it: the
     per-row descriptor (first and last flat token, from ``row_ids`` and
     ``lengths``: a row's tokens are contiguous in pack order), a tile's
     first and last row, and queries as ``[block, row of the block, T,
-    bw]``. The pools go in as they are stored, whole."""
+    bw]`` (``one_token``: ``[T, block, row of the block, bw]``, a
+    token's query rows one tile). The pools go in as they are stored,
+    whole."""
     T0, nh, hd = q.shape
     bs, F = k_cache.shape[2:]
     kvh = F // hd
@@ -548,8 +650,9 @@ def _tiled_call(q, k_cache, v_cache, layer, row_ids, lengths, block_tables,
     ring = MB             # the table's places, before the padding below
     # a tile: a power of two of 16 to 128 tokens, at most 512 query rows
     # a lane block where that leaves 16
-    tq = max(16, min(pow2_bucket(T0, 128),
-                     1 << (max(512 // rpb, 1).bit_length() - 1)))
+    # (one_token: the rows a grid step walks)
+    tq = _ONE_TOKEN_ROWS if one_token else max(16, min(
+        pow2_bucket(T0, 128), 1 << (max(512 // rpb, 1).bit_length() - 1)))
     T = -(-T0 // tq) * tq
     cp = max(1, min(MB, _CHUNK_POSITIONS // bs))   # pages a chunk
     if MB % cp:
@@ -563,21 +666,35 @@ def _tiled_call(q, k_cache, v_cache, layer, row_ids, lengths, block_tables,
     row_first, row_last, tile_lo, tile_hi = _row_descriptors(
         row_ids, lengths, R, tq)
 
-    qx = q.reshape(T, nblk, hpb, group, hd).transpose(1, 2, 3, 0, 4)
+    qx = q.reshape(T, nblk, hpb, group, hd)
+    if not one_token:
+        qx = qx.transpose(1, 2, 3, 0, 4)
     if hpb > 1:       # a query row is zero outside its own head's lanes
-        own = jnp.eye(hpb, dtype=bool)[None, :, None, None, :, None]
-        qx = jnp.where(own, qx[:, :, :, :, None, :], 0)
-    qx = qx.reshape(nblk, rpb, T, bw)
+        own = jnp.eye(hpb, dtype=bool)[:, None, :, None] if one_token \
+            else jnp.eye(hpb, dtype=bool)[None, :, None, None, :, None]
+        qx = jnp.where(own, qx[..., None, :], 0)
+    if one_token:
+        # a token's query rows of a lane block, whole sublane tiles
+        M = _sublane_tiles(rpb)
+        qx = jnp.pad(qx.reshape(T, nblk, rpb, bw),
+                     ((0, 0), (0, 0), (0, M - rpb), (0, 0)))
+        q_block, o_block = (tq, nblk, M, bw), (tq, nblk, group, bw)
 
-    def tile(i, *_):
-        return (0, 0, i, 0)
+        def tile(i, *_):
+            return (i, 0, 0, 0)
+    else:
+        M = rpb * tq
+        qx = qx.reshape(nblk, rpb, T, bw)
+        q_block, o_block = (nblk, rpb, tq, bw), (nblk, group, tq, bw)
+
+        def tile(i, *_):
+            return (0, 0, i, 0)
 
     pool_spec = pl.BlockSpec(memory_space=pl.ANY)       # pool stays in HBM
-    in_specs = [pl.BlockSpec((nblk, rpb, tq, bw), tile),
+    in_specs = [pl.BlockSpec(q_block, tile),
                 pl.BlockSpec((tq, 1), lambda i, *_: (i, 0)),
                 pool_spec, pool_spec]
     operands = [qx, lengths.reshape(T, 1), k_cache, v_cache]
-    M = rpb * tq
     scratch = [pltpu.VMEM((2, cp, bs, F), k_cache.dtype),
                pltpu.VMEM((2, cp, bs, F), v_cache.dtype)]
     sems = [pltpu.SemaphoreType.DMA((2, 2))]
@@ -599,6 +716,7 @@ def _tiled_call(q, k_cache, v_cache, layer, row_ids, lengths, block_tables,
     kernel = functools.partial(
         _tiled_kernel, quant=quant, bs=bs, scale=1.0 / (hd ** 0.5), kvh=kvh,
         hd=hd, hpb=hpb, group=group, tq=tq, cp=cp, io_dtype=q.dtype,
+        one_token=one_token,
         **(dict(window=window, ring=ring) if window else {}))
     out = pl.pallas_call(
         kernel,
@@ -606,9 +724,11 @@ def _tiled_call(q, k_cache, v_cache, layer, row_ids, lengths, block_tables,
             num_scalar_prefetch=7,
             grid=(T // tq,),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((nblk, group, tq, bw), tile),
+            out_specs=pl.BlockSpec(o_block, tile),
             scratch_shapes=scratch + sems),
-        out_shape=jax.ShapeDtypeStruct((nblk, group, T, bw), q.dtype),
+        out_shape=jax.ShapeDtypeStruct(
+            (T, nblk, group, bw) if one_token else (nblk, group, T, bw),
+            q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=_TILED_VMEM_BYTES),
@@ -617,7 +737,9 @@ def _tiled_call(q, k_cache, v_cache, layer, row_ids, lengths, block_tables,
         else "ragged_attention_tiled",
     )(layer, lengths, block_tables, row_first, row_last, tile_lo, tile_hi,
       *operands)
-    out = out.reshape(nblk, group, T, hpb, hd).transpose(2, 0, 3, 1, 4)
+    out = out.reshape(T, nblk, group, hpb, hd).transpose(0, 1, 3, 2, 4) \
+        if one_token \
+        else out.reshape(nblk, group, T, hpb, hd).transpose(2, 0, 3, 1, 4)
     return out.reshape(T, nh, hd)[:T0]
 
 
@@ -628,7 +750,8 @@ def ragged_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
                      k_scale: jnp.ndarray = None,
                      v_scale: jnp.ndarray = None,
                      variant: Optional[str] = None,
-                     window: int = 0) -> jnp.ndarray:
+                     window: int = 0,
+                     one_token: bool = False) -> jnp.ndarray:
     """Ragged paged attention (serving hot path).
 
     q [T, nh, hd] flat token buffer; k/v_cache the pool's leaves as
@@ -653,7 +776,12 @@ def ragged_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
     largest chunk + one block`` positions serves any context (a table
     that holds every position never wraps). The tiled variant starts a
     row's walk at its window's first page; in a trace the launch is
-    ``ragged_attention_window`` (tiled) / ``..._pipelined_window``."""
+    ``ragged_attention_window`` (tiled) / ``..._pipelined_window``.
+
+    ``one_token`` (static): the caller's word that every row has
+    exactly one token (a decode batch). The tiled variant then takes its
+    one-token form (the same launch names, the same sums in the same
+    order); the pipelined one is a token a grid step already."""
     T, nh, hd = q.shape
     bs, F = k_cache.shape[2:]
     kvh = F // hd
@@ -677,7 +805,7 @@ def ragged_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
             raise ValueError(f"no tiled variant for {kvh} kv heads of {hd}")
         return _tiled_call(q, k_cache, v_cache, layer, row_ids, lengths,
                            block_tables, k_scale, v_scale, interpret,
-                           window)
+                           window, one_token)
     q4 = q.reshape(T, kvh, group, hd)
     static = dict(bs=bs, scale=1.0 / (hd ** 0.5), kvh=kvh, group=group,
                   io_dtype=q.dtype, **({"window": window} if window else {}))
@@ -765,7 +893,8 @@ def ragged_attention_reference(q, k_cache, v_cache, layer, row_ids, lengths,
 # ---------------------------------------------------------------------------
 def _latent_kernel(layer_ref, len_ref, bt_ref, first_ref, last_ref, lo_ref,
                    hi_ref, q_ref, tl_ref, pool_hbm, o_ref, buf, acc_sc,
-                   m_sc, l_sc, sem, *, bs, scale, dc, tq, cp):
+                   m_sc, l_sc, sem, *, bs, scale, dc, tq, cp,
+                   one_token=False):
     """Grid (T / tq,), the tiled variant's walk (:func:`_walk_rows`)
     over a latent pool ``[L, nb, bs, W]`` left whole in HBM, the layer a
     prefetched scalar. All ``nh`` heads of the tile's tokens, ``(nh *
@@ -774,14 +903,27 @@ def _latent_kernel(layer_ref, len_ref, bt_ref, first_ref, last_ref, lo_ref,
     unit together against ONE row a position: a chunk's ``(P, W)`` rows
     are the keys whole and, their first ``dc`` lanes, the values, so a
     page is read once. buf is (2, cp, bs, W); sem is (2,), one wait a
-    page."""
-    nh, W = q_ref.shape[0], q_ref.shape[2]
+    page.
+
+    ``one_token`` (static): every row has ONE token and the tile is
+    ``tq`` rows walked one after another (:func:`_tiled_kernel`): the
+    queries lie token-major ``(tq, nh, W)``, a chunk meets the walked
+    row's ``nh`` query rows alone, the float32 state is one row's and
+    goes to the row's place in the output tile ``(tq, nh, dc)`` behind
+    its last chunk."""
+    if one_token:
+        nh, W = q_ref.shape[1:]
+    else:
+        nh, W = q_ref.shape[0], q_ref.shape[2]
     M, P = nh * tq, cp * bs
     t0 = pl.program_id(0) * tq
     lo, hi = lo_ref[pl.program_id(0)], hi_ref[pl.program_id(0)]
     layer = layer_ref[0]
 
-    _init_scratch(acc_sc, m_sc, l_sc)
+    if one_token:
+        o_ref[...] = jnp.zeros_like(o_ref)     # a row that is never walked
+    else:
+        _init_scratch(acc_sc, m_sc, l_sc)
 
     @pl.when(pl.program_id(0) == 0)
     def _zero():
@@ -793,10 +935,11 @@ def _latent_kernel(layer_ref, len_ref, bt_ref, first_ref, last_ref, lo_ref,
                                       buf.at[slot, j], sem.at[slot]),)
 
     def compute(first, last, c, slot):
-        visible = _visible(tl_ref, t0, first, last, c, tq, nh, P)
+        visible = _row_visible(len_ref[first], c, nh, P) if one_token \
+            else _visible(tl_ref, t0, first, last, c, tq, nh, P)
         kv = buf[slot].reshape(P, W)
-        s = jax.lax.dot_general(q_ref[...].reshape(M, W), kv,
-                                (((1,), (1,)), ((), ())),
+        q = q_ref[first - t0] if one_token else q_ref[...].reshape(M, W)
+        s = jax.lax.dot_general(q, kv, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         s = jnp.where(visible, s, 2 * NEG_INF)      # as _tile_update masks
         m_prev = m_sc[:, :1]
@@ -811,8 +954,16 @@ def _latent_kernel(layer_ref, len_ref, bt_ref, first_ref, last_ref, lo_ref,
             preferred_element_type=jnp.float32)
         m_sc[...] = jnp.broadcast_to(m_new, m_sc.shape)
 
+    def finish(first):
+        l = l_sc[:, :1]
+        o_ref[first - t0] = (acc_sc[...] / jnp.where(l == 0.0, 1.0, l)
+                             ).astype(o_ref.dtype)
+
     _walk_rows(len_ref, bt_ref, first_ref, last_ref, lo, hi, t0=t0, tq=tq,
-               bs=bs, cp=cp, copies=copies, compute=compute)
+               bs=bs, cp=cp, copies=copies, compute=compute,
+               **_row_hooks(one_token, finish, acc_sc, m_sc, l_sc))
+    if one_token:
+        return
 
     l = l_sc[:, :1]
     o_ref[...] = (acc_sc[...] / jnp.where(l == 0.0, 1.0, l)).reshape(
@@ -837,7 +988,8 @@ def latent_attention_reference(q, pool, layer, row_ids, lengths,
 
 def latent_attention(q, pool, layer, row_ids, lengths, block_tables, *,
                      dc: int, scale: float,
-                     interpret: Optional[bool] = None):
+                     interpret: Optional[bool] = None,
+                     one_token: bool = False):
     """Ragged paged attention over a LATENT pool (attention='mla', the
     absorbed form): every head's query attends ONE cached row a
     position, whose first ``dc`` lanes are also the values.
@@ -850,7 +1002,11 @@ def latent_attention(q, pool, layer, row_ids, lengths, block_tables, *,
     space. On a TPU the kernel (``ragged_attention_latent`` in a trace);
     off it the gathering reference, and the kernel under the TPU
     interpreter (DMAs, semaphores and all) only where ``interpret`` asks
-    for it: that interpreter is several times slower than the gather."""
+    for it: that interpreter is several times slower than the gather.
+
+    ``one_token`` (static): the caller's word that every row has
+    exactly one token (a decode batch); the kernel then takes its
+    one-token form, under the same name."""
     nh, T0, W = q.shape
     if interpret is None and _interpret():
         return latent_attention_reference(q, pool, layer, row_ids, lengths,
@@ -861,9 +1017,9 @@ def latent_attention(q, pool, layer, row_ids, lengths, block_tables, *,
     bs = pool.shape[2]
     R, MB = block_tables.shape
     # a tile: a power of two of 16 to 128 tokens, 512 query rows where
-    # that leaves 16
-    tq = max(16, min(pow2_bucket(T0, 128),
-                     1 << (max(512 // nh, 1).bit_length() - 1)))
+    # that leaves 16 (one_token: the rows a grid step walks)
+    tq = _ONE_TOKEN_ROWS if one_token else max(16, min(
+        pow2_bucket(T0, 128), 1 << (max(512 // nh, 1).bit_length() - 1)))
     T = -(-T0 // tq) * tq
     cp = max(1, min(MB, _CHUNK_POSITIONS // bs))   # pages a chunk
     if MB % cp:
@@ -874,23 +1030,36 @@ def latent_attention(q, pool, layer, row_ids, lengths, block_tables, *,
         lengths = jnp.pad(lengths, (0, T - T0))
     row_first, row_last, tile_lo, tile_hi = _row_descriptors(
         row_ids, lengths, R, tq)
-    M = nh * tq
+    if one_token:
+        # token-major, a token's heads whole sublane tiles
+        M = _sublane_tiles(nh)
+        q = jnp.pad(q.transpose(1, 0, 2), ((0, 0), (0, M - nh), (0, 0)))
+        q_block, o_block, out_shape = (tq, M, W), (tq, M, dc), (T, M, dc)
+
+        def tile(i, *_):
+            return (i, 0, 0)
+    else:
+        M = nh * tq
+        q_block, o_block, out_shape = (nh, tq, W), (nh, tq, dc), (nh, T, dc)
+
+        def tile(i, *_):
+            return (0, i, 0)
     out = pl.pallas_call(
         functools.partial(_latent_kernel, bs=bs, scale=scale, dc=dc, tq=tq,
-                          cp=cp),
+                          cp=cp, one_token=one_token),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=7,
             grid=(T // tq,),
-            in_specs=[pl.BlockSpec((nh, tq, W), lambda i, *_: (0, i, 0)),
+            in_specs=[pl.BlockSpec(q_block, tile),
                       pl.BlockSpec((tq, 1), lambda i, *_: (i, 0)),
                       pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=pl.BlockSpec((nh, tq, dc), lambda i, *_: (0, i, 0)),
+            out_specs=pl.BlockSpec(o_block, tile),
             scratch_shapes=[pltpu.VMEM((2, cp, bs, W), pool.dtype),
                             pltpu.VMEM((M, dc), jnp.float32),
                             pltpu.VMEM((M, 128), jnp.float32),
                             pltpu.VMEM((M, 128), jnp.float32),
                             pltpu.SemaphoreType.DMA((2,))]),
-        out_shape=jax.ShapeDtypeStruct((nh, T, dc), q.dtype),
+        out_shape=jax.ShapeDtypeStruct(out_shape, q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=_TILED_VMEM_BYTES),
@@ -899,4 +1068,6 @@ def latent_attention(q, pool, layer, row_ids, lengths, block_tables, *,
         name="ragged_attention_latent",
     )(jnp.asarray(layer, jnp.int32).reshape(1), lengths, block_tables,
       row_first, row_last, tile_lo, tile_hi, q, lengths.reshape(T, 1), pool)
+    if one_token:
+        return out[:T0, :nh].transpose(1, 0, 2)
     return out[:, :T0]

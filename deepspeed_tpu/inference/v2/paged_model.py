@@ -41,6 +41,7 @@ where fusion cannot: the attention reads (paged_attention.py,
 ops/decode_attention.py, flash prefill).
 """
 
+import functools
 from typing import Any, Dict, NamedTuple, Tuple
 
 import jax
@@ -656,7 +657,7 @@ def _latent_rows(pool, l, dtype):
 
 def _latent_attention_sublayer(cfg, lp, x, l, pool, cos, sin, row_ids,
                                lengths, write_blocks, write_offsets,
-                               block_tables, use_kernel):
+                               block_tables, use_kernel, one_token=False):
     """Multi-head latent attention on flat tokens x [T, H], in the
     ABSORBED form for prefill and decode alike: the new tokens' rows
     (normed latent, rotated shared key part) go to layer ``l`` of the
@@ -666,7 +667,9 @@ def _latent_attention_sublayer(cfg, lp, x, l, pool, cos, sin, row_ids,
     leaves that space by the value slice (``o_lat Wv``). The same
     mathematics as expanding every cached position's keys and values
     per head, which a long prefill would do more cheaply (ROADMAP M3).
-    Returns (what attention adds to x, pool)."""
+    ``one_token``: every row has exactly one token (a decode batch),
+    which the kernel is told. Returns (what attention adds to x,
+    pool)."""
     from ...ops.norms import rms_norm
     from .kernels.ragged_attention import (latent_attention,
                                            latent_attention_reference)
@@ -693,8 +696,8 @@ def _latent_attention_sublayer(cfg, lp, x, l, pool, cos, sin, row_ids,
     scale = 1.0 / float(dn + dr) ** 0.5
     with jax.named_scope("attn_kernel"):
         rows, at = _latent_rows(pool, l, hn.dtype)
-        attend = latent_attention if use_kernel \
-            else latent_attention_reference
+        attend = functools.partial(latent_attention, one_token=one_token) \
+            if use_kernel else latent_attention_reference
         o_lat = attend(qx, rows, at, row_ids, lengths, block_tables,
                        dc=dc, scale=scale)
     o = jnp.einsum("htc,chd->thd", o_lat, wkv_b[..., dn:])
@@ -705,7 +708,8 @@ def _latent_attention_sublayer(cfg, lp, x, l, pool, cos, sin, row_ids,
 
 def _per_head_attention_sublayer(cfg, lp, x, kind, l, pool, cos, sin,
                                  row_ids, lengths, write_blocks,
-                                 write_offsets, block_tables, use_kernel):
+                                 write_offsets, block_tables, use_kernel,
+                                 one_token=False):
     """A per-head (GQA) mixer of a layer pattern on flat tokens x
     [T, H]: ``kind`` "full" (a token sees every position under its
     bound) or "window" (its last ``cfg.attn_window``), ``l`` the layer's
@@ -721,7 +725,8 @@ def _per_head_attention_sublayer(cfg, lp, x, kind, l, pool, cos, sin,
     gate, element-wise; ``wo``; the post-norm of the sandwich scheme.
     An int8 pool is dequantised a layer at a time into a transient pool
     of one layer (1 / L of the leaf at twice its bytes), as the latent
-    pool's is (``_latent_rows``). Returns
+    pool's is (``_latent_rows``). ``one_token``: every row has exactly
+    one token (a decode batch), which the kernel is told. Returns
     (what attention adds to x, pool)."""
     from ...ops.norms import rms_norm
     from .kernels.ragged_attention import (ragged_attention,
@@ -764,7 +769,8 @@ def _per_head_attention_sublayer(cfg, lp, x, kind, l, pool, cos, sin,
                           for c, sc in ((kc, ksc), (vc, vsc)))
                 at = jnp.int32(0)
             o = ragged_attention(q, kc, vc, at, row_ids, lengths,
-                                 block_tables, window=window)
+                                 block_tables, window=window,
+                                 one_token=one_token)
     if cfg.attn_gate == "elementwise":
         with jax.named_scope("attn_gate"):
             o = o * jax.nn.sigmoid(
@@ -1067,14 +1073,15 @@ def _pattern_step(cfg: TransformerConfig, params, ids, row_ids, pos, lengths,
                         cfg, lp, x, kind, m0 + i, pool, cos, sin, row_ids,
                         lengths, window_writes if ring else write_blocks,
                         write_offsets,
-                        window_tables if ring else block_tables, use_kernel)
+                        window_tables if ring else block_tables, use_kernel,
+                        one_token)
                     x = x + a.astype(jnp.float32)
             else:
                 with jax.named_scope("mla_attention"):
                     a, pool = _latent_attention_sublayer(
                         cfg, lp, x, m0 + i, pool, cos, sin, row_ids,
                         lengths, write_blocks, write_offsets, block_tables,
-                        use_kernel)
+                        use_kernel, one_token)
                     x = x + a.astype(jnp.float32)
             with jax.named_scope("mlp"):
                 hn = _norm(cfg, x, lp["mlp_norm"])

@@ -154,6 +154,35 @@ def test_latent_kernel_matches_the_gather_on_a_mixed_launch():
     assert not np.asarray(got)[:, T0:].any()        # padding: zeros
 
 
+def test_the_one_token_form_of_the_latent_kernel():
+    """A decode batch through the latent kernel's one-token form (a
+    row's chunks against that row's own ``nh`` query rows, the state one
+    row's) against ``latent_attention_reference`` and against the token
+    tile on the same inputs: rows of unequal contexts over pages out of
+    order, one that crosses a 512-position chunk, rows of length 0
+    among them and behind, more rows than the 16 one grid step walks."""
+    nh, dc, dr, L, bs, W, MB = 4, 32, 16, 3, 8, 128, 80
+    rng = np.random.default_rng(0)
+    lens = [11, 70, 1, 0, 600, 64, 512] + [23] * 11 + [0, 5]
+    R = len(lens)
+    nb = 1 + R * MB
+    pool = np.zeros((L, nb, bs, W), np.float32)
+    pool[..., :dc + dr] = rng.normal(size=(L, nb, bs, dc + dr))
+    bt = rng.permutation(np.arange(1, nb)).reshape(R, MB).astype(np.int32)
+    q = np.zeros((nh, R, W), np.float32)
+    q[..., :dc + dr] = rng.normal(size=(nh, R, dc + dr))
+    args = (jnp.asarray(q), jnp.asarray(pool), jnp.int32(1),
+            jnp.arange(R, dtype=jnp.int32), jnp.asarray(lens, jnp.int32),
+            jnp.asarray(bt))
+    want = np.asarray(latent_attention_reference(*args, dc=dc, scale=0.2))
+    tile, one = (np.asarray(latent_attention(
+        *args, dc=dc, scale=0.2, interpret=True, one_token=flag))
+        for flag in (False, True))
+    np.testing.assert_allclose(one, want, atol=2e-6)
+    np.testing.assert_allclose(one, tile, atol=2e-6)
+    assert not one[:, np.asarray(lens) == 0].any()
+
+
 def test_the_pool_row_is_padded_to_whole_lane_blocks():
     cfg = TransformerConfig(**TOY)
     assert cfg.latent_row == 48 and latent_pool_row(cfg) == 128

@@ -748,6 +748,47 @@ def test_the_engine_counts_the_steps_the_conv_kernel_took(monkeypatch):
     assert not la.conv_kernel_serves(leaf(3 * 1024))         # half a tile
 
 
+def test_the_engine_counts_the_steps_the_attention_kernels_took_one_token(
+        monkeypatch):
+    """``inference_attention_one_token_steps_total`` follows
+    ``one_token_tile_serves`` a decode step LAUNCHED: 0 here (the
+    CPU: the latent layer's decode launches are the gathering
+    reference), a window's steps a fused window and one a per-token
+    step where it says yes; and what it asks is a TPU and a latent pool
+    or a pool the tiled variant serves."""
+    import importlib
+    from deepspeed_tpu.inference.v2 import engine_v2
+    ra = importlib.import_module(      # the package exports the function
+        "deepspeed_tpu.inference.v2.kernels.ragged_attention")
+    reg = get_registry()
+    eng = _engine("float32")            # decode_window 4
+    steps = reg.get("inference_attention_one_token_steps_total")
+    before = steps.value
+    prompts = _prompts((12, 8))
+    eng.generate(prompts, max_new_tokens=9, temperature=0.0,
+                 eos_token_id=None)
+    assert steps.value == before
+    asked = []
+    monkeypatch.setattr(engine_v2, "one_token_tile_serves",
+                        lambda *a: asked.append(a) or True)
+    eng.generate(prompts, max_new_tokens=9, temperature=0.0,
+                 eos_token_id=None)     # 8 decode steps: two windows of 4
+    assert steps.value == before + 8
+    eng.put([0], prompts[:1])
+    eng._decode_batch_greedy([0], [1])
+    assert steps.value == before + 9
+    eng.flush(0)
+    cfg = eng.model.cfg                 # a latent pool
+    assert set(asked) == {(True, cfg.head_dim, cfg.kv_heads)}
+
+    assert not ra.one_token_tile_serves(True, 192, 32)       # the CPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert ra.one_token_tile_serves(True, 192, 32)
+    assert ra.one_token_tile_serves(False, 64, 32)           # OPT's
+    assert ra.one_token_tile_serves(False, 128, 4)           # GQA at 128
+    assert not ra.one_token_tile_serves(False, 96, 32)       # pipelined
+
+
 # ---------------------------------------------------------------------------
 # (d) trees, leaves, runs
 # ---------------------------------------------------------------------------

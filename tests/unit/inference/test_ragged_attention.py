@@ -208,7 +208,11 @@ def test_tiled_kernel_matches_gather_reference(stored_pool, kvh, nh, pool):
 @pytest.mark.parametrize("kvh,nh,pool", TILED_CASES)
 def test_tiled_pure_decode_is_the_decode_kernel(stored_pool, kvh, nh, pool):
     """One token a row through ``paged_attention()`` and through
-    ``ragged_attention()``: bit-equal, and both the reference's."""
+    ``ragged_attention(one_token=True)``: bit-equal (one program, the
+    one-token form), the token tile's output to the order of its sums
+    (the same chunks in the same order; float32 products add up in
+    another order where a matmul has 8 rows for 128), and all three the
+    reference's."""
     c = _tiled_case(kvh, nh, pool, seed=1)
     lens = jnp.asarray([150, 640, 78, 6, 1024, 0, 17, 513], jnp.int32)
     tables = c["tables"].at[6, :2].set(jnp.asarray([3, 1])) \
@@ -216,17 +220,56 @@ def test_tiled_pure_decode_is_the_decode_kernel(stored_pool, kvh, nh, pool):
     q = c["q"][:8]
     kw = dict(k_scale=c["ks"], v_scale=c["vs"], variant="tiled")
     kp, vp = stored_pool(c["kc"], 2, 1), stored_pool(c["vc"], 2, 1)
-    ragged = np.asarray(jax.jit(functools.partial(ragged_attention, **kw))(
-        q, kp, vp, 1, jnp.arange(8, dtype=jnp.int32), lens, tables),
-        np.float32)
+    rows = jnp.arange(8, dtype=jnp.int32)
+    tile, ragged = (np.asarray(jax.jit(functools.partial(
+        ragged_attention, one_token=one, **kw))(
+        q, kp, vp, 1, rows, lens, tables), np.float32)
+        for one in (False, True))
     decode = np.asarray(jax.jit(functools.partial(paged_attention, **kw))(
         q, kp, vp, 1, tables, lens), np.float32)
     np.testing.assert_array_equal(ragged, decode)
-    ref = _gather_reference(q, c["kc"], c["vc"],
-                            jnp.arange(8, dtype=jnp.int32), lens, tables,
+    np.testing.assert_allclose(tile, decode, rtol=0, atol=2e-6)
+    assert not decode[5].any()                       # a row of no length
+    ref = _gather_reference(q, c["kc"], c["vc"], rows, lens, tables,
                             c["ks"], c["vs"])
     tol = 2e-2 if pool == "bf16" else 2e-5
     np.testing.assert_allclose(decode, ref, rtol=tol, atol=tol)
+
+
+# hpb 2 / group 1 (OPT's: two 64-wide heads a lane block, 2 query rows
+# padded to a sublane tile) and hpb 1 / group 8 (GQA at width 128)
+ONE_TOKEN_GEOMETRIES = {"hpb2-group1": (4, 4, 64), "hpb1-group8": (16, 2, 128)}
+
+
+@pytest.mark.parametrize("geometry", sorted(ONE_TOKEN_GEOMETRIES))
+def test_the_one_token_form_is_the_token_tile_and_the_reference(geometry):
+    """A decode batch through the tiled kernel's one-token form (a row's
+    chunks against that row's own query rows) against the token tile on
+    the same inputs and against the gathering reference: rows of unequal
+    contexts over pages out of order, one that crosses a 512-position
+    chunk (and one that ends on its edge), rows of length 0 among them
+    and behind, more rows than the 16 one grid step walks."""
+    from deepspeed_tpu.inference.v2.kernels.ragged_attention import \
+        ragged_attention_reference
+    nh, kvh, hd = ONE_TOKEN_GEOMETRIES[geometry]
+    rng = np.random.default_rng(3)
+    lens = [150, 640, 78, 6, 512, 0, 17, 513] + [33] * 9 + [0, 7, 0]
+    R, MB, bs = len(lens), 48, 16
+    nb = 1 + R * MB
+    k, v = (jnp.asarray(rng.normal(size=(2, nb, bs, kvh * hd)), jnp.float32)
+            for _ in range(2))
+    tables = jnp.asarray(rng.permutation(np.arange(1, nb)).reshape(R, MB),
+                         jnp.int32)
+    args = (jnp.asarray(rng.normal(size=(R, nh, hd)), jnp.float32), k, v, 1,
+            jnp.arange(R, dtype=jnp.int32), jnp.asarray(lens, jnp.int32),
+            tables)
+    tile, one = (np.asarray(jax.jit(functools.partial(
+        ragged_attention, variant="tiled", one_token=flag))(*args))
+        for flag in (False, True))
+    want = np.asarray(ragged_attention_reference(*args))
+    np.testing.assert_allclose(one, want, rtol=0, atol=2e-6)
+    np.testing.assert_allclose(one, tile, rtol=0, atol=2e-6)
+    assert not one[np.asarray(lens) == 0].any()
 
 
 @pytest.mark.parametrize("pool", ["bf16", "int8"])

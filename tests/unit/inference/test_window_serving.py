@@ -281,6 +281,34 @@ def test_a_window_that_holds_the_context_is_no_window_bit_for_bit(variant):
                                   np.asarray(wide)[:live])
 
 
+def test_the_one_token_form_over_a_ring_that_wraps():
+    """A decode batch through the window launch's one-token form: group
+    8 at width 128, rings of 8 pages (128 positions) under a window of
+    64, contexts that have gone round the ring many times, one inside
+    its first lap, one of no length, more rows than one grid step
+    walks; against the token tile on the same inputs and the gathering
+    reference."""
+    rng = np.random.default_rng(5)
+    nh, kvh, hd, bs, ring = 16, 2, 128, 16, 8
+    lens = [333, 90, 1000, 0, 64, 65, 5000] + [777] * 11
+    R = len(lens)
+    nb = 1 + R * ring
+    k, v = (jnp.asarray(rng.normal(size=(2, nb, bs, kvh * hd)), jnp.float32)
+            for _ in range(2))
+    tables = jnp.asarray(rng.permutation(np.arange(1, nb)).reshape(R, ring),
+                         jnp.int32)
+    args = (jnp.asarray(rng.normal(size=(R, nh, hd)), jnp.float32), k, v, 1,
+            jnp.arange(R, dtype=jnp.int32), jnp.asarray(lens, jnp.int32),
+            tables)
+    tile, one = (np.asarray(ragged_attention(
+        *args, variant="tiled", window=64, one_token=flag))
+        for flag in (False, True))
+    want = np.asarray(ragged_attention_reference(*args, window=64))
+    assert np.abs(one - want).max() <= F32_TIGHT * np.abs(want).max()
+    assert np.abs(one - tile).max() <= F32_TIGHT * np.abs(want).max()
+    assert not one[3].any()
+
+
 def test_the_tiled_kernel_refuses_a_window_over_an_int8_pool():
     (q, k, v, layer, row_ids, lengths, tables), _ = _kernel_case()
     scale = jnp.ones((k.shape[1], 4), jnp.float32)

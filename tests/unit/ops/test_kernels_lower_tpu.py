@@ -17,7 +17,10 @@ drift apart:
 * the whole serving programs (ragged step, fused decode window with
   greedy and sampled picks, prefill) compile at OPT-1.3B's geometry;
 * the generation cell's two programs, at its shapes, take the pool as
-  an argument and make no copy of it or of a layer of it.
+  an argument and make no copy of it or of a layer of it;
+* the four generation cells' DECODE launches lower in the kernels'
+  one-token form under the three names their readers find, and the
+  decode windows round them relay no pool.
 
 This is the pre-check that costs no chip time: run it before
 ``chip_smoke.py``. It says nothing about speed or numerics; phase K of
@@ -128,6 +131,35 @@ def _check_rows(rows, sharding, variants):
 
 def test_gate_matches_compiler_tier1_rows(tpu_sharding):
     _check_rows(TIER1_ROWS, tpu_sharding, variants=None)
+
+
+def test_the_one_token_form_compiles_wherever_the_gate_is_tiled(
+        tpu_sharding):
+    """The decode entry (``paged_attention``: every row one token) at
+    the tier-1 rows the gate gives the tiled variant, bf16 and int8
+    pools: Mosaic takes the one-token form at every one of them (query
+    rows a lane block from 1 to 32, padded to sublane tiles; lane blocks
+    128 and 256 wide)."""
+    from deepspeed_tpu.inference.v2.kernels.paged_attention import \
+        paged_attention
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=tpu_sharding)
+
+    failures = []
+    for hd, kvh, quant in TIER1_ROWS:
+        if kernel_variant(hd, kvh, quant) != "tiled":
+            continue
+        q, kc, vc, layer, _, lens, bt, *scales = _paged_args(
+            sds, hd, kvh, quant, T=4, R=4)
+        err = _compile_error(
+            lambda q, kc, vc, layer, bt, lens, *sc: paged_attention(
+                q, kc, vc, layer, bt, lens, k_scale=sc[0] if sc else None,
+                v_scale=sc[1] if sc else None),
+            q, kc, vc, layer, bt, lens, *scales)
+        if err is not None:
+            failures.append(f"hd={hd} kvh={kvh} int8={quant}: {err[:300]}")
+    assert not failures, "\n".join(failures)
 
 
 @pytest.mark.slow   # 80 compiles; the tier-1 dozen above is its sibling
@@ -516,20 +548,14 @@ def test_a_latent_row_that_is_not_whole_lane_blocks_is_refused(tpu_sharding):
     assert err is not None and "aligned to tiling" in err, err
 
 
-def test_latent_ragged_step_compiles_with_its_experts_in_place(tpu_sharding):
-    """The whole ragged step at the published widths, depth cut to the
-    leading dense layer and ONE expert layer of 256 experts: it compiles
-    for the chip, the latent kernel runs in both stacks and the grouped
-    matmul three times under names a trace finds, and no instruction
-    holds a copy of a layer's experts (the kernel reads the stack where
-    it lies; a sliced layer would be a 1.2 GB copy a launch)."""
+def _latent_cut(tpu_sharding, blocks):
+    """The latent block at published widths, depth cut to the leading
+    dense layer and ONE expert layer of 256 experts: the configuration,
+    and its parameters and pool of ``blocks`` as shapes on the chip."""
     import json
     from pathlib import Path
-    from deepspeed_tpu.inference.v2.paged_model import (init_paged_kv_cache,
-                                                        paged_ragged_step)
+    from deepspeed_tpu.inference.v2.paged_model import init_paged_kv_cache
     from deepspeed_tpu.models import TransformerLM
-    import json
-    from pathlib import Path
     from deepspeed_tpu.models.transformer import TransformerConfig
 
     fields = json.loads((Path(__file__).resolve().parents[3]
@@ -546,7 +572,20 @@ def test_latent_ragged_step_compiles_with_its_experts_in_place(tpu_sharding):
         jax.eval_shape(TransformerLM(cfg).init_params,
                        jax.random.PRNGKey(0)))
     cache = jax.tree.map(on_tpu, jax.eval_shape(
-        lambda: init_paged_kv_cache(cfg, 129, 16, jnp.bfloat16)))
+        lambda: init_paged_kv_cache(cfg, blocks, 16, jnp.bfloat16)))
+    return cfg, params, cache
+
+
+def test_latent_ragged_step_compiles_with_its_experts_in_place(tpu_sharding):
+    """The whole ragged step at the published widths, depth cut to the
+    leading dense layer and ONE expert layer of 256 experts: it compiles
+    for the chip, the latent kernel runs in both stacks and the grouped
+    matmul three times under names a trace finds, and no instruction
+    holds a copy of a layer's experts (the kernel reads the stack where
+    it lies; a sliced layer would be a 1.2 GB copy a launch)."""
+    from deepspeed_tpu.inference.v2.paged_model import paged_ragged_step
+
+    cfg, params, cache = _latent_cut(tpu_sharding, 129)
 
     def i32(*shape):
         return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=tpu_sharding)
@@ -867,3 +906,117 @@ def test_the_patterns_decode_window_compiles_with_both_pools_in_place(
         == 1, kernels
     assert sum(bool(GMM_PATTERN.search(k)) for k in kernels) == 6, kernels
     assert compiled.memory_analysis().temp_size_in_bytes < 0.1e9
+    # the one-token form lays the queries token-major ([16, 4, 8, 128]);
+    # neither pool is relaid for it
+    assert compiled.as_text().count("bf16[16,4,8,128]") >= 3
+    _no_relayout_of(compiled.as_text(), 8721, 3089)
+
+
+# ---------------------------------------------------------------------------
+# the one-token form: the four generation cells' decode launches
+# ---------------------------------------------------------------------------
+# cell -> (kernel, rows, the pool as stored, the table's pages, window,
+# the launch's name, the queries as the kernel takes them: token-major)
+ONE_TOKEN_LAUNCHES = {
+    "joyai-llm-flash.rollout-64x256": (
+        "latent", 64, (5, 1601, 16, 640), 32, 0, "ragged_attention_latent",
+        "bf16[64,32,640]"),
+    "ling-3.0-flash.rollout-128x256": (
+        "latent", 128, (1, 3201, 16, 640), 32, 0, "ragged_attention_latent",
+        "bf16[128,32,640]"),
+    "trinity-mini.rollout-16x8192-512.full": (
+        "tiled", 16, (1, 8721, 16, 512), 544, 0, "ragged_attention_tiled",
+        "bf16[16,4,8,128]"),
+    "trinity-mini.rollout-16x8192-512.window": (
+        "tiled", 16, (4, 3089, 16, 512), 193, 2048,
+        "ragged_attention_window", "bf16[16,4,8,128]"),
+    "opt-1.3b.rollout-256": (
+        "tiled", 16, (24, 529, 16, 2048), 32, 0, "ragged_attention_tiled",
+        "bf16[16,16,8,128]"),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(ONE_TOKEN_LAUNCHES))
+def test_the_decode_launches_lower_in_the_one_token_form(tpu_sharding, cell):
+    """A decode step of each generation cell at its shapes, told that
+    every row has one token: Mosaic takes the one-token form of the
+    latent kernel (64 and 128 rows of 32 heads over a 640-lane row) and
+    of the tiled kernel (16 rows: GQA group 8 at width 128, full and
+    over a ring with a window; two 64-wide heads a lane block, their 2
+    query rows padded to one sublane tile), under the name the launch
+    had (the roofline and share readers' patterns), with the queries
+    token-major and nothing as large as a layer of the pool beside
+    it."""
+    from deepspeed_tpu.inference.v2.kernels.ragged_attention import \
+        latent_attention
+    kernel, R, pool, MB, window, name, queries = ONE_TOKEN_LAUNCHES[cell]
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=tpu_sharding)
+
+    desc = [sds((), jnp.int32), sds((R,), jnp.int32), sds((R,), jnp.int32),
+            sds((R, MB), jnp.int32)]
+    if kernel == "latent":
+        fn = lambda *a: latent_attention(       # noqa: E731
+            *a, dc=512, scale=192 ** -0.5, one_token=True)
+        args = [sds((32, R, pool[-1]), jnp.bfloat16),
+                sds(pool, jnp.bfloat16)] + desc
+    else:
+        hd = 64 if pool[-1] == 2048 else 128
+        fn = lambda *a: ragged_attention(       # noqa: E731
+            *a, window=window, one_token=True)
+        args = [sds((R, 32, hd), jnp.bfloat16), sds(pool, jnp.bfloat16),
+                sds(pool, jnp.bfloat16)] + desc
+    compiled = jax.jit(fn).lower(*args).compile()
+    calls = [line for line in compiled.as_text().splitlines()
+             if "tpu_custom_call" in line]
+    assert len(calls) == 1, calls
+    assert re.match(r"\s*%" + name + r"[_.0-9]* = ", calls[0]), calls[0][:200]
+    assert queries in calls[0], calls[0][:400]
+    layer = 2 * pool[1] * pool[2] * pool[3]
+    assert compiled.memory_analysis().temp_size_in_bytes < layer / 4
+
+
+def _no_relayout_of(text, *pools):
+    """No ``copy`` or ``transpose`` in the program makes a value of a
+    pool's or a layer's shape (the pool's blocks x 16 positions as the
+    leading or second axis): PR 37's bug."""
+    for blocks in pools:
+        made = re.findall(
+            r"= \w+\[((?:\d+,)?%d,16,[\d,]+)\]\S* ([\w\-]+)\(" % blocks,
+            text)
+        assert made, f"the pattern no longer finds the pool of {blocks}"
+        relaid = [m for m in made if m[1] in ("copy", "transpose",
+                                              "copy-start", "copy-done")]
+        assert not relaid, relaid
+
+
+def test_the_latent_decode_window_takes_the_one_token_form(tpu_sharding):
+    """The decode window of the latent block at published widths (the
+    leading dense layer and one expert layer, 64 rows): both stacks'
+    launches are the latent kernel under its name with the queries
+    token-major (a few hundred KB relaid round it), and no copy or
+    transpose of the pool's shape."""
+    from deepspeed_tpu.inference.v2.paged_model import paged_decode_window
+
+    cfg, params, cache = _latent_cut(tpu_sharding, 1601)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=tpu_sharding)
+
+    R = 64
+    compiled = jax.jit(
+        lambda p, t, pos, bt, c, sl, eos, alive: paged_decode_window(
+            cfg, p, t, pos, bt, c, sl, eos, 16, 8, use_kernel=True,
+            alive=alive), donate_argnums=(4,)).lower(
+        params, i32(R), i32(R), i32(R, 32), cache, i32(R), i32(R),
+        jax.ShapeDtypeStruct((R,), jnp.bool_, sharding=tpu_sharding)
+    ).compile()
+    text = compiled.as_text()
+    lines = [line for line in text.splitlines()
+             if "tpu_custom_call" in line and "ragged_attention" in line]
+    assert len(lines) == 2 and all(
+        LATENT_PATTERN.search(re.match(r"\s*%([\w.\-]+) =", line).group(1))
+        and "bf16[64,32,640]" in line for line in lines), lines
+    _no_relayout_of(text, 1601)
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.2e9
