@@ -13,11 +13,6 @@ Prints ONE JSON line. Usage:
   python -m deepspeed_tpu.benchmarks.serving_bench [--batch 8] [--prompt 64]
          [--new 64] [--layers 4] [--hidden 256]
 
-``--mixed`` switches to the mixed-traffic sweep: concurrent prefill +
-decode through the SplitFuse scheduler, run twice — ragged unified
-program vs stitched prefill/decode families — reporting compiled-program
-counts, steady-state recompiles (watchdog-pinned zero) and tokens/s.
-
 ``--router N`` switches to the routed fleet sweep: a shared-prefix
 workload through N in-process replicas behind the prefix-affinity
 router (``--disagg`` adds a dedicated prefill replica and the KV
@@ -364,92 +359,6 @@ def bench_paged(model, params, prompts: np.ndarray, new_tokens: int,
     }
 
 
-def bench_mixed(model, params, *, requests: int, prompt: int,
-                new_tokens: int, token_budget: int, window: int,
-                mode: str) -> dict:
-    """Mixed-traffic sweep (concurrent prefill + decode through the
-    SplitFuse scheduler) for ONE dispatch mode ('on' = ragged unified
-    program, 'off' = stitched prefill/continue/decode families).
-    Staggered submissions keep prompt chunks and running decodes in the
-    same steps — the composition the ragged program exists for. Runs in
-    an isolated registry; reports the compiled-program count of the
-    sweep, per-family compiles, steady-state recompiles (a second
-    identical wave under ``watchdog.mark_steady``) and steady-state
-    generation tokens/s."""
-    from ..inference.v2.engine_v2 import InferenceEngineV2
-    from ..inference.v2.scheduler import DynamicSplitFuseScheduler
-    from ..telemetry import (FlightRecorder, MetricsRegistry,
-                             set_recorder, set_registry, get_registry,
-                             watchdog)
-
-    prev = set_registry(MetricsRegistry())
-    prev_rec = set_recorder(FlightRecorder())
-    watchdog.reset()
-    try:
-        eng = InferenceEngineV2(model, {
-            "dtype": "bfloat16",
-            "decode_window": window,
-            "ragged_attention": mode,
-            "state_manager": {
-                "max_tracked_sequences": max(requests, 8),
-                "max_ragged_batch_size": max(4 * prompt, 512),
-                "num_blocks": 4096},
-        }, params=params)
-        sched = DynamicSplitFuseScheduler(eng, token_budget=token_budget)
-        rng = np.random.default_rng(0)
-        # variable prompt lengths around --prompt so chunk counts (and
-        # bucket shapes) vary like real traffic
-        lens = rng.integers(max(prompt // 2, 1), 2 * prompt,
-                            size=requests)
-        prompts = [list(map(int, rng.integers(0, 2047, n)))
-                   for n in lens]
-
-        def wave(base: int) -> int:
-            half = max(len(prompts) // 2, 1)
-            for i, p in enumerate(prompts[:half]):
-                sched.submit(base + i, p, new_tokens)
-            for _ in range(3):   # first wave starts decoding...
-                sched.step()
-            for i, p in enumerate(prompts[half:]):
-                sched.submit(base + 1000 + i, p, new_tokens)
-            sched.run()          # ...while the second wave prefills
-            return len(prompts) * new_tokens
-
-        # two warm waves: every bucket compiles on first touch, and a
-        # bucket first visited with the fresh (unsharded) pool pays one
-        # respecialization on its next visit — the second wave absorbs
-        # both before steady state is declared
-        wave(10_000)
-        wave(15_000)
-        reg = get_registry()
-        compiled = reg.family_total("xla_compile_events_total")
-        per_family = {v[0]: s.value for v, s in
-                      reg.get("xla_compile_events_total").series()}
-        watchdog.mark_steady(True)
-        try:
-            t0 = time.perf_counter()
-            produced = wave(20_000)
-            dt = time.perf_counter() - t0
-        finally:
-            watchdog.mark_steady(False)
-        return {
-            "mode": mode,
-            "compiled_programs": compiled,
-            "compiles_per_family": per_family,
-            "steady_state_recompiles": reg.family_total(
-                "xla_steady_state_recompiles_total"),
-            "tok_s": produced / dt,
-            "ragged_steps": reg.family_total(
-                "inference_ragged_steps_total"),
-            "ragged_tokens": reg.family_total(
-                "inference_ragged_tokens_total"),
-        }
-    finally:
-        watchdog.reset()
-        set_registry(prev)
-        set_recorder(prev_rec)
-
-
 def bench_routed(model, params, *, replicas_n: int, requests: int,
                  prompt: int, new_tokens: int, budget: int,
                  disaggregated: bool, trace_out=None,
@@ -607,48 +516,6 @@ def main_router(args) -> int:
     return 0
 
 
-def main_mixed(args) -> int:
-    """--mixed mode: the ragged-vs-stitched comparison under concurrent
-    prefill+decode traffic, one JSON line."""
-    import jax
-
-    model = build_model(args.layers, args.hidden)
-    params = model.init_params(jax.random.PRNGKey(0))
-    kw = dict(requests=args.batch, prompt=args.prompt,
-              new_tokens=args.new, token_budget=args.budget,
-              window=args.window)
-    ragged = bench_mixed(model, params, mode="on", **kw)
-    stitched = bench_mixed(model, params, mode="off", **kw)
-    print(json.dumps({
-        "metric": "serving_mixed_tokens_per_sec",
-        "backend": jax.default_backend(),
-        "requests": args.batch, "prompt": args.prompt,
-        "new_tokens": args.new, "token_budget": args.budget,
-        "decode_window": args.window,
-        "ragged_tok_s": round(ragged["tok_s"], 2),
-        "stitched_tok_s": round(stitched["tok_s"], 2),
-        "ragged_over_stitched": (
-            round(ragged["tok_s"] / stitched["tok_s"], 3)
-            if stitched["tok_s"] else None),
-        # the compiled-program story: ONE ragged family vs the stitched
-        # prefill x decode product, and the watchdog's verdict that the
-        # steady wave compiled nothing
-        "ragged_compiled_programs": ragged["compiled_programs"],
-        "stitched_compiled_programs": stitched["compiled_programs"],
-        "compiled_programs_saved": (stitched["compiled_programs"]
-                                    - ragged["compiled_programs"]),
-        "ragged_compiles_per_family": ragged["compiles_per_family"],
-        "stitched_compiles_per_family": stitched["compiles_per_family"],
-        "ragged_steady_state_recompiles":
-            ragged["steady_state_recompiles"],
-        "stitched_steady_state_recompiles":
-            stitched["steady_state_recompiles"],
-        "ragged_steps": ragged["ragged_steps"],
-        "ragged_step_tokens": ragged["ragged_tokens"],
-    }))
-    return 0
-
-
 def main_kv_spill(args) -> int:
     import jax
 
@@ -691,14 +558,8 @@ def main(argv=None) -> int:
                         "recompiles (double-warm discipline) and max "
                         "concurrent conversations at the fixed HBM "
                         "pool budget")
-    p.add_argument("--mixed", action="store_true",
-                   help="mixed-traffic mode: concurrent prefill+decode "
-                        "through the SplitFuse scheduler, ragged vs "
-                        "stitched — reports compiled-program counts, "
-                        "steady-state recompiles and tokens/s")
     p.add_argument("--budget", type=int, default=256,
-                   help="scheduler token budget per step "
-                        "(--mixed/--router)")
+                   help="scheduler token budget per step (--router)")
     p.add_argument("--router", type=int, default=0, metavar="N",
                    help="routed fleet mode: shared-prefix traffic "
                         "through N in-process replicas behind the "
@@ -725,8 +586,6 @@ def main(argv=None) -> int:
                         "row per lane, spans correlated by trace id")
     args = p.parse_args(argv)
 
-    if args.mixed:
-        return main_mixed(args)
     if args.router:
         return main_router(args)
     if args.kv_spill:

@@ -74,7 +74,11 @@ class RaggedInferenceEngineConfig:
     # model_implementations/*/; here it is one mesh axis away)
     expert_parallel_size: int = 1
     dtype: str = "bfloat16"
-    prefill_bucket: int = 64                 # prompt lengths pad to multiples
+    # no prompt pads to it (put() is the ragged step, whose buckets are
+    # powers of two): the scheduler's default prompt chunk, the bucket of
+    # the one-sequence passes (the n-gram verify, the draft's catch-up)
+    # and the prompt chunk of memory_report's representative step
+    prefill_bucket: int = 64
     use_paged_kernel: bool = True            # Pallas decode attention kernel
     # weight-only quantization (0 = off): weights rest in HBM as int8 /
     # packed int4 + per-block scales, dequantized inside the jitted
@@ -105,17 +109,6 @@ class RaggedInferenceEngineConfig:
     # bounded; per-row budgets mask shorter tails. 1 = the per-token
     # fallback path.
     decode_window: int = 8
-    # ragged paged attention (PAPERS.md arXiv:2604.15464): serve mixed
-    # prefill+decode compositions through ONE unified program per
-    # (token bucket, row bucket) instead of stitching the separate
-    # prefill/continue/decode program families.
-    #   "auto" — on wherever the ragged program can serve the model
-    #            (today: everywhere; the jnp fallback covers tp/ep,
-    #            alibi and quantized-KV configs the kernel gates off)
-    #   "on"   — force the ragged step path
-    #   "off"  — keep the stitched prefill->continue->decode dispatch
-    #            (the rollback knob; parity-tested against "on")
-    ragged_attention: str = "auto"
     # multi-tenant batched LoRA serving (0 = off): hot adapter slots in
     # the stacked device bank. Slot 0 is reserved for the base model
     # (all-zero delta — bit-exact no-op), so the bank holds
@@ -159,6 +152,12 @@ class RaggedInferenceEngineConfig:
     @classmethod
     def from_dict(cls, d: dict) -> "RaggedInferenceEngineConfig":
         d = dict(d or {})
+        if "ragged_attention" in d:
+            raise ValueError(
+                "ragged_attention is gone: every put() runs the ragged "
+                "step, and the stitched prefill / continue / decode "
+                "dispatch it selected no longer exists; drop the key "
+                f"(got {d['ragged_attention']!r})")
         sm = d.pop("state_manager", {})
         if isinstance(sm, dict):
             sm = DSStateManagerConfig(**sm)

@@ -6,19 +6,13 @@ prompts and one next-token per running sequence; the engine returns the
 next-token logits for every entry. KV lives in a blocked (paged) pool
 managed by DSStateManager; sequences are freed with ``flush``.
 
-TPU-native scheduling: with ragged attention enabled (the default,
-``config_v2.ragged_attention``) every put() — mixed prompts,
-continuations and decode rows — packs into ONE RaggedBatch and runs as
-a single unified compiled program per (token bucket, row bucket)
+TPU-native scheduling: every put() — mixed prompts, continuations and
+decode rows — packs into ONE RaggedBatch and runs as a single unified
+compiled program per (token bucket, row bucket, table-width bucket)
 (``paged_ragged_step`` + ``kernels/ragged_attention.py``), the Ragged
-Paged Attention design (PAPERS.md arXiv:2604.15464). The stitched
-families remain behind ``ragged_attention="off"``: prompts through
-``paged_prefill`` (one compiled program per prompt-length bucket),
-multi-token continuations through ONE fused ``paged_continue`` chunk
-pass, and running sequences batched into a ``paged_decode`` call padded
-to the next power-of-two bucket — the compiled-program cache plays the
-role the reference's CUDA graphs + atom builder play. Stitched mixed
-puts do the prefills/continuations first, then the fused decode batch.
+Paged Attention design (PAPERS.md arXiv:2604.15464); a prompt set over
+the step's budget goes in as several such steps. The compiled-program
+cache plays the role the reference's CUDA graphs + atom builder play.
 
 The decode hot loop itself is fused on device (``decode_window`` > 1):
 ``paged_decode_window`` runs up to K decode steps per dispatch — cache
@@ -50,8 +44,7 @@ from .kernels.ragged_attention import (LATENT, kernel_variant,
                                        one_token_tile_serves)
 from .paged_model import (init_lora_bank, init_paged_kv_cache,
                           paged_continue, paged_decode, paged_decode_window,
-                          paged_prefill, paged_ragged_step,
-                          paged_spec_decode_window)
+                          paged_ragged_step, paged_spec_decode_window)
 from .ragged import batch as ragged_batch
 from .ragged.blocked_allocator import NULL_BLOCK
 from .ragged.ragged_manager import DSStateManager
@@ -269,8 +262,8 @@ class InferenceEngineV2:
         # returns the donated cache with an explicit NamedSharding — so
         # a bucket's FIRST call compiles against a different executable
         # signature than its steady repeats (one respecialization per
-        # bucket, for the stitched families too). Warmup should replay
-        # the bucket set twice before watchdog.mark_steady(); committing
+        # bucket). Warmup should replay the bucket set twice before
+        # watchdog.mark_steady(); committing
         # the pool sharded at init was tried and destabilizes unrelated
         # XLA-CPU executables later in the process (see PR 7 notes)
         self.kv_cache = init_paged_kv_cache(
@@ -371,14 +364,6 @@ class InferenceEngineV2:
         # with recurrent state (decode, the fused window, the ragged
         # step) take one more behind them, ``ss``: each row's state
         # slot, None for every other model
-        self._decode_jit = watchdog.watch_jit(
-            "decode",
-            lambda p, t, pos, bt, c, a, lb, aid, ss, wt=None: paged_decode(
-                cfg, p, t, pos, bt, c, a, sm.block_size,
-                use_kernel=use_kernel, topo=topo, lora=lb,
-                adapter_ids=aid, state_slots=ss, window_tables=wt),
-            donate_argnums=(4,))
-
         def _decode_tok(p, t, pos, bt, c, a, lb, aid, ss, wt=None):
             # greedy variant for the generate() hot loop: argmax on device
             # so the per-token host transfer is [N] int32, not [N, vocab]
@@ -462,29 +447,17 @@ class InferenceEngineV2:
         self._fused_greedy_jit, self._fused_sample_jit = \
             self._fused_pair(self.decode_window)
         self._row_state_sharding = NamedSharding(self.mesh, P())
-        self._prefill_jit = watchdog.watch_jit(
-            "prefill", lambda p, ids, n, c, b, o, lb, aid: paged_prefill(
-                cfg, p, ids, n, c, b, o,
-                use_kernel=use_kernel, topo=topo, lora=lb,
-                adapter_ids=aid),
-            donate_argnums=(3,))
-        self._continue_jit = watchdog.watch_jit(
-            "continue",
-            lambda p, ids, s, n, c, b, o, t, lb, aid: paged_continue(
-                cfg, p, ids, s, n, c, b, o, t, sm.block_size, topo=topo,
-                lora=lb, adapter_ids=aid),
-            donate_argnums=(4,))
-        # ragged unified step (ROADMAP item 1; kernels/ragged_attention.py
-        # + ragged/batch.py): every mixed prefill+decode composition runs
+        # ragged unified step (kernels/ragged_attention.py +
+        # ragged/batch.py): every mixed prefill+decode composition runs
         # as ONE program keyed by (token bucket, row bucket, table-width
-        # bucket) — put() and the SplitFuse scheduler route here instead
-        # of sequencing the prefill/continue/decode families. The ragged
-        # kernel shares the decode kernel's gates (no alibi, tp=ep=1;
-        # int8 kv_quant pools ride the same kernels); gated-off
-        # configs serve through the jnp ragged fallback inside the same
-        # unified program.
-        self.ragged_enabled = self._resolve_ragged_mode(
-            config.ragged_attention)
+        # bucket); put() and the SplitFuse scheduler have no other way
+        # in. The ragged kernel shares the decode kernel's gates (no
+        # alibi, tp=ep=1; int8 kv_quant pools ride the same kernels);
+        # gated-off configs serve through the jnp ragged fallback inside
+        # the same unified program.
+        # read by benchmark/runners/generate.py's log line and by
+        # nothing else: it goes when that read does (ROADMAP D11)
+        self.ragged_enabled = True
         self._ragged_jit = watchdog.watch_jit(
             "ragged_step",
             lambda p, ids, rows, pos, ln, wb, wo, bt, li, c, lb, aid, ss,
@@ -584,10 +557,7 @@ class InferenceEngineV2:
             "prefix would find its ring empty)":
                 sm.enable_prefix_caching,
             "enable_kv_spill (the spill tier moves the blocks of one "
-            "geometry and no ring)": sm.enable_kv_spill,
-            "ragged_attention 'off' (the stitched prefill / continue "
-            "programs have no form for the walk of runs)":
-                config.ragged_attention == "off"})
+            "geometry and no ring)": sm.enable_kv_spill})
 
     @staticmethod
     def _latent_refusals(config, cfg):
@@ -612,9 +582,6 @@ class InferenceEngineV2:
             "enable_kv_spill (the spill tier moves k / v leaves" + (
                 " and no state slot" if state else "") + ")":
                 sm.enable_kv_spill,
-            "ragged_attention 'off' (the stitched prefill / continue "
-            "programs have no latent form)":
-                config.ragged_attention == "off",
             "kv_quant (the int8 latent pool has not been served beside "
             "state leaves)": state and config.kv_quant}
 
@@ -836,21 +803,6 @@ class InferenceEngineV2:
             sm.window_blocks_in_use())
 
     # ------------------------------------------------------------------
-    # Ragged mode (config_v2.ragged_attention: auto | on | off)
-    # ------------------------------------------------------------------
-    @staticmethod
-    def _resolve_ragged_mode(mode: str) -> bool:
-        if mode not in ("auto", "on", "off"):
-            raise ValueError(
-                f"ragged_attention must be 'auto', 'on' or 'off' "
-                f"(got {mode!r})")
-        # "auto" is on everywhere today: the unified program's jnp
-        # fallback covers every config the ragged kernel gates off
-        # (tp/ep, alibi), and quantized KV runs the kernel's quant
-        # variant — there is no unsupported case
-        return mode != "off"
-
-    # ------------------------------------------------------------------
     # Fused decode window K: per-K jit cache + live adaptation
     # ------------------------------------------------------------------
     def _fused_pair(self, window: int):
@@ -886,13 +838,6 @@ class InferenceEngineV2:
         flight.record("tunable_set", name="serving.decode_window",
                       value=window, source=source)
         return window
-
-    def set_ragged_mode(self, mode: str) -> None:
-        """Flip the ragged/stitched dispatch at runtime
-        (ServingConfig.ragged_attention routes here). Compiled programs
-        for both paths stay cached, so flipping never recompiles."""
-        self.ragged_enabled = self._resolve_ragged_mode(mode)
-        self.config.ragged_attention = mode
 
     # ------------------------------------------------------------------
     # Multi-tenant batched LoRA (config_v2.max_lora_adapters)
@@ -1076,86 +1021,55 @@ class InferenceEngineV2:
     # the RaggedBatch packer, so every layer buckets identically)
     # ------------------------------------------------------------------
     def _bucket(self, n: int) -> int:
-        """Prefill chunk-length bucket (multiple of prefill_bucket,
-        capped at the max_seq_len bucket)."""
+        """Chunk-length bucket of the one-sequence passes, the n-gram
+        verify and the draft model's catch-up (multiple of
+        prefill_bucket, capped at the max_seq_len bucket)."""
         return ceil_bucket(n, self.config.prefill_bucket,
                            cap=self.state_manager.config.max_seq_len)
 
-    def _prefill(self, uid: int, tokens: np.ndarray) -> np.ndarray:
-        sm = self.state_manager
+    def _chunk_inputs(self, uid: int, start: int, tokens: np.ndarray):
+        """``tokens`` of ONE tracked sequence from position ``start``, as
+        ``paged_continue`` takes them (the sequence's blocks hold them
+        already): ids [1, C] padded to the chunk bucket, ``start``, the
+        count, each chunk position's (block, slot) with padding on the
+        null block, and the sequence's full table."""
         n = len(tokens)
-        seq = sm.ensure_blocks(uid, n)
-        start = seq.seen_tokens
-        assert start == 0, \
-            "prompt continuation for an existing sequence must arrive " \
-            "token-by-token (chunked prefill lands with the Pallas kernel)"
-        C = self._bucket(n)
-        ids = np.zeros((1, C), np.int32)
-        ids[0, :n] = tokens
-        # chunk position -> (block, slot); padding -> null block
-        positions = np.arange(C)
-        block_idx = positions // self.block_size
-        offs = positions % self.block_size
-        table = np.full(C, NULL_BLOCK, np.int32)
-        valid = positions < n
-        table[valid] = np.asarray(seq.blocks, np.int32)[block_idx[valid]]
-        lb = self.lora_bank
-        aid = (jnp.asarray(self._adapter_slot_of(uid), jnp.int32)
-               if lb is not None else None)
-        with trace.span("prefill", uid=int(uid), tokens=int(n),
-                        **self._trace_attr(uid)):
-            logits, self.kv_cache = self._prefill_jit(
-                self.params, jnp.asarray(ids), jnp.asarray(n),
-                self.kv_cache, jnp.asarray(table), jnp.asarray(offs),
-                lb, aid)
-        flight.record("prefill", uid=int(uid), tokens=int(n))
-        seq.seen_tokens = n
-        if sm.config.enable_prefix_caching:
-            seq.token_log.extend(map(int, tokens))
-        self._m_prefill_tokens.inc(n)
-        self._update_pool_telemetry()
-        return np.asarray(logits)
-
-    def _continue(self, uid: int, tokens: np.ndarray,
-                  all_logits: int = 0) -> np.ndarray:
-        """Multi-token continuation in ONE compiled pass (replaces the
-        token-at-a-time decode loop; reference chunked prefill).
-        ``all_logits`` > 0 returns greedy ids for that many leading fed
-        positions (speculative verification, device-side argmax, [w]
-        int32 to host) instead of the last token's [V] logits."""
-        sm = self.state_manager
-        n = len(tokens)
-        seq = sm.ensure_blocks(uid, n)
-        start = seq.seen_tokens
         C = self._bucket(n)
         ids = np.zeros((1, C), np.int32)
         ids[0, :n] = tokens
         positions = start + np.arange(C)
-        block_idx = positions // self.block_size
-        offs = positions % self.block_size
         table = np.full(C, NULL_BLOCK, np.int32)
-        valid = np.arange(C) < n
-        seq_blocks = np.asarray(seq.blocks, np.int32)
-        table[valid] = seq_blocks[block_idx[valid]]
-        full_table = sm.block_table_for(uid)
-        jit_fn = (self._spec_jit(all_logits) if all_logits
-                  else self._continue_jit)
+        blocks = np.asarray(self.state_manager.seqs[uid].blocks, np.int32)
+        table[:n] = blocks[positions[:n] // self.block_size]
+        return (jnp.asarray(ids), jnp.asarray(start), jnp.asarray(n),
+                jnp.asarray(table), jnp.asarray(positions % self.block_size),
+                jnp.asarray(self.state_manager.block_table_for(uid)))
+
+    def _spec_verify(self, uid: int, tokens: np.ndarray) -> np.ndarray:
+        """Feed ``tokens`` to ONE tracked sequence in one compiled pass
+        (``paged_continue``) and return the greedy id after each fed
+        position: device-side argmax, [len(tokens)] int32 to the host.
+        The n-gram speculation's verify pass; one program a window
+        size (``spec_verify_w*``)."""
+        sm = self.state_manager
+        n = len(tokens)
+        seq = sm.ensure_blocks(uid, n)
+        start = seq.seen_tokens
         lb = self.lora_bank
         aid = (jnp.asarray(self._adapter_slot_of(uid), jnp.int32)
                if lb is not None else None)
-        with trace.span("continue", uid=int(uid), tokens=int(n),
-                        spec=bool(all_logits), **self._trace_attr(uid)):
-            logits, self.kv_cache = jit_fn(
-                self.params, jnp.asarray(ids), jnp.asarray(start),
-                jnp.asarray(n), self.kv_cache, jnp.asarray(table),
-                jnp.asarray(offs), jnp.asarray(full_table), lb, aid)
+        with trace.span("spec_verify", uid=int(uid), tokens=int(n),
+                        **self._trace_attr(uid)):
+            ids, s, fed, table, offs, full_table = \
+                self._chunk_inputs(uid, start, tokens)
+            greedy, self.kv_cache = self._spec_jit(n)(
+                self.params, ids, s, fed, self.kv_cache, table, offs,
+                full_table, lb, aid)
         seq.seen_tokens = start + n
         if sm.config.enable_prefix_caching:
             seq.token_log.extend(map(int, tokens))
-        if not all_logits:  # spec-verify feeds count via the spec counters
-            self._m_prefill_tokens.inc(n)
         self._update_pool_telemetry()
-        return np.asarray(logits)
+        return np.asarray(greedy)
 
     # -- speculative decoding (prompt-lookup) ---------------------------
     _SPEC_SCAN_WINDOW = 512   # bound the per-round host scan (the scan
@@ -1203,8 +1117,7 @@ class InferenceEngineV2:
         seq = sm.seqs[uid]
         fed = [int(cur)] + list(map(int, draft))
         start = seq.seen_tokens
-        greedy = self._continue(uid, np.asarray(fed, np.int64),
-                                all_logits=len(fed))
+        greedy = self._spec_verify(uid, np.asarray(fed, np.int64))
         emitted = [int(greedy[0])]
         accepted = 0
         for j, d in enumerate(draft):
@@ -1334,7 +1247,7 @@ class InferenceEngineV2:
         # (prefill, plain decode, n-gram rounds) before a uid's first
         # spec window
         bs = self.block_size
-        self._draft_continue_jit = watchdog.watch_jit(
+        self._draft_catchup_jit = watchdog.watch_jit(
             "draft_catchup",
             lambda p, ids, s, n, c, b, o, t: paged_continue(
                     dcfg, p, ids, s, n, c, b, o, t, bs, topo=None),
@@ -1385,24 +1298,13 @@ class InferenceEngineV2:
         if d0 >= seen:
             return
         toks = np.asarray(row[d0:seen], np.int64)
-        n = len(toks)
-        C = self._bucket(n)
-        ids = np.zeros((1, C), np.int32)
-        ids[0, :n] = toks
-        positions = d0 + np.arange(C)
-        block_idx = positions // self.block_size
-        offs = positions % self.block_size
-        table = np.full(C, NULL_BLOCK, np.int32)
-        valid = np.arange(C) < n
-        seq_blocks = np.asarray(seq.blocks, np.int32)
-        table[valid] = seq_blocks[block_idx[valid]]
-        full_table = sm.block_table_for(uid)
-        with trace.span("draft_catchup", uid=int(uid), tokens=int(n),
+        with trace.span("draft_catchup", uid=int(uid), tokens=len(toks),
                         **self._trace_attr(uid)):
-            _, self.draft_cache = self._draft_continue_jit(
-                self.draft_params, jnp.asarray(ids), jnp.asarray(d0),
-                jnp.asarray(n), self.draft_cache, jnp.asarray(table),
-                jnp.asarray(offs), jnp.asarray(full_table))
+            ids, s, n, table, offs, full_table = \
+                self._chunk_inputs(uid, d0, toks)
+            _, self.draft_cache = self._draft_catchup_jit(
+                self.draft_params, ids, s, n, self.draft_cache, table,
+                offs, full_table)
         self._draft_seen[uid] = seen
 
     def _observe_spec_rates(self) -> None:
@@ -1652,11 +1554,6 @@ class InferenceEngineV2:
                 out[uid] = extract(vals, i)
             self._update_pool_telemetry()
         return out
-
-    def _decode_batch(self, uids: List[int],
-                      tokens: List[int]) -> Dict[int, np.ndarray]:
-        return self._decode_common(uids, tokens, self._decode_jit,
-                                   lambda v, i: v[i])
 
     def _decode_batch_greedy(self, uids: List[int],
                              tokens: List[int]) -> Dict[int, int]:
@@ -1920,9 +1817,8 @@ class InferenceEngineV2:
         """One compiled launch for a MIXED batch: prompt chunks,
         continuations and decode rows pack into a single
         :class:`~.ragged.batch.RaggedBatch` and run through the unified
-        ragged program (paged_model.paged_ragged_step) — the dispatch
-        put() previously sequenced through the prefill / continue /
-        decode program families. Same contract as put(): returns
+        ragged program (paged_model.paged_ragged_step). Same contract
+        as put(): returns
         [len(batch_uids), vocab] last-token logits per entry. ``chunk``
         = (i, n): this is step i of the n that put() runs for a prompt
         set fed in chunks (the span's ``chunk`` / ``chunks`` attrs; the
@@ -1940,9 +1836,9 @@ class InferenceEngineV2:
             for i, (uid, toks) in enumerate(entries):
                 if not sm.known_seq(uid) and len(toks) > 1:
                     # prefix caching: shared full blocks shorten the row
-                    # to its unseen suffix (same as the stitched put()).
-                    # Adapter-keyed: a LoRA row's v-projection KV differs
-                    # from the base model's, so prefixes only share
+                    # to its unseen suffix. Adapter-keyed: a LoRA row's
+                    # v-projection KV differs from the base model's, so
+                    # prefixes only share
                     # within one adapter identity (the NAME — stable
                     # across replicas, unlike engine-local slot ints)
                     _, n_reused = sm.match_prefix(
@@ -2014,8 +1910,8 @@ class InferenceEngineV2:
             self._m_ragged_time.observe(dt)
             self._m_ragged_pad.set(rb.pad_fraction)
             self._m_ragged_host_syncs.inc()
-            # the family counters stay comparable across ragged/stitched:
-            # chunk tokens are prefill work wherever they run
+            # chunk tokens are prefill work: the family counter the
+            # dashboards read
             if chunk_tokens:
                 self._m_prefill_tokens.inc(chunk_tokens)
             flight.record("ragged_step", rows=len(entries),
@@ -2027,10 +1923,9 @@ class InferenceEngineV2:
     def put(self, batch_uids: Sequence[int],
             batch_tokens: Sequence[Iterable[int]]) -> np.ndarray:
         """Reference engine_v2.put: returns [len(batch_uids), vocab] logits
-        for the last token of each entry. With ragged attention enabled
-        (config_v2.ragged_attention) the whole batch runs as ONE unified
-        ragged launch; otherwise the stitched dispatch below sequences
-        prefills, continuations and the batched decode.
+        for the last token of each entry. The whole batch (prompts,
+        continuations, one-token decode rows) runs as ONE unified ragged
+        launch (:meth:`step_ragged`).
 
         A prompt set of more tokens than ``max_ragged_batch_size`` (or,
         for a model whose window layers keep a ring, a row of more than
@@ -2038,53 +1933,12 @@ class InferenceEngineV2:
         tokens in consecutive chunks, the rows in lock step (a step's
         budget shared evenly among the rows that have tokens left): one
         program signature for all of them, and the logits returned are
-        each row's from the step its last token went in. A call that
-        fits one step is that one step, as before."""
-        if self.ragged_enabled:
-            plan = self._chunk_plan([len(np.atleast_1d(t))
-                                     for t in batch_tokens])
-            if len(plan) == 1:
-                return self.step_ragged(batch_uids, batch_tokens)
-            return self._put_chunks(batch_uids, batch_tokens, plan)
-        sm = self.state_manager
-        entries = [(int(uid), np.atleast_1d(np.asarray(toks, np.int64)))
-                   for uid, toks in zip(batch_uids, batch_tokens)]
-        if not self.can_schedule([u for u, _ in entries],
-                                 [len(t) for _, t in entries]):
-            raise RuntimeError(
-                "batch not schedulable (KV blocks / sequence budget); "
-                "check can_schedule()/query() before put()")
-        results: Dict[int, np.ndarray] = {}
-        decode_uids: List[int] = []
-        decode_toks: List[int] = []
-        for i, (uid, toks) in enumerate(entries):
-            if not sm.known_seq(uid) and len(toks) > 1:
-                # prefix caching: shared full blocks make this uid a
-                # KNOWN sequence whose suffix continues below
-                # (adapter-keyed — see step_ragged)
-                _, n_reused = sm.match_prefix(
-                    uid, toks, adapter=self._uid_adapter.get(int(uid)))
-                if n_reused:
-                    toks = toks[n_reused:]
-                    entries[i] = (uid, toks)
-            known = sm.known_seq(uid) and sm.seqs[uid].seen_tokens > 0
-            if not known and len(toks) >= 1:
-                results[uid] = self._prefill(uid, toks)
-            elif len(toks) == 1:
-                decode_uids.append(uid)
-                decode_toks.append(int(toks[0]))
-            else:
-                # multi-token continuation: one fused chunked pass
-                results[uid] = self._continue(uid, toks)
-        if decode_uids:
-            for chunk_start in range(0, len(decode_uids),
-                                     sm.config.max_tracked_sequences):
-                chunk_u = decode_uids[chunk_start:chunk_start
-                                      + sm.config.max_tracked_sequences]
-                chunk_t = decode_toks[chunk_start:chunk_start
-                                      + sm.config.max_tracked_sequences]
-                results.update(self._decode_batch(chunk_u, chunk_t))
-        return np.stack([results[uid] for uid, _ in entries])
+        each row's from the step its last token went in."""
+        plan = self._chunk_plan([len(np.atleast_1d(t))
+                                 for t in batch_tokens])
+        if len(plan) == 1:
+            return self.step_ragged(batch_uids, batch_tokens)
+        return self._put_chunks(batch_uids, batch_tokens, plan)
 
     def _chunk_plan(self, lengths: Sequence[int]) -> List[List[int]]:
         """How put() feeds rows of ``lengths`` tokens: a list of steps,
@@ -2264,7 +2118,7 @@ class InferenceEngineV2:
     def memory_report(self, batch: int = 1) -> Dict[str, object]:
         """AOT compile-and-analyze the serving hot-path programs —
         per-token decode, the fused window (when ``decode_window`` > 1)
-        and one prefill chunk — at the bucket shapes a ``batch``-row
+        and one mixed ragged step — at the bucket shapes a ``batch``-row
         step uses, with the FULL block-table width (the worst-case
         program a long sequence pays). Publishes peak/argument/temp
         bytes per program and returns ``{"programs", "buffers", "flops"
@@ -2293,8 +2147,6 @@ class InferenceEngineV2:
         lb = (jax.tree.map(sds, self.lora_bank)
               if self.lora_bank is not None else None)
         aidN = i32(N) if self.lora_bank is not None else None
-        aid0 = (jax.ShapeDtypeStruct((), jnp.int32)
-                if self.lora_bank is not None else None)
         ssN = i32(N) if self._has_state else None
         wtN = (i32(N, sm.ring_blocks),) if self._has_ring else ()
         programs: Dict[str, dict] = {}
@@ -2312,29 +2164,20 @@ class InferenceEngineV2:
             programs["decode_window_greedy"] = \
                 ds_memory.record_memory_analysis("decode_window_greedy",
                                                  compiled)
-        if not self.model.cfg.walks_runs:   # no stitched form of the walk
-            C = self._bucket(self.config.prefill_bucket)
-            compiled = self._prefill_jit.lower(
-                params, i32(1, C), jax.ShapeDtypeStruct((), jnp.int32),
-                cache, i32(C), i32(C), lb, aid0).compile()
-            programs["prefill"] = ds_memory.record_memory_analysis(
-                "prefill", compiled)
-        if self.ragged_enabled:
-            # a representative mixed bucket: one prefill chunk plus a
-            # decode row per batch slot, full table width (the
-            # worst-case ragged program a long sequence pays). The
-            # analyzed bucket geometry rides along in the record so
-            # consumers (a per-token normalization) read the
-            # bucket this analysis actually compiled
-            TB = pow2_bucket(self.config.prefill_bucket + N,
-                             sm.config.max_ragged_batch_size)
-            compiled = self._ragged_jit.lower(
-                params, i32(TB), i32(TB), i32(TB), i32(TB), i32(TB),
-                i32(TB), i32(N, MB), i32(N), cache, lb, aidN,
-                ssN, *wtN).compile()
-            programs["ragged_step"] = dict(
-                ds_memory.record_memory_analysis("ragged_step", compiled),
-                token_bucket=TB, row_bucket=N)
+        # a representative mixed bucket: one prefill chunk plus a decode
+        # row per batch slot, full table width (the worst-case ragged
+        # program a long sequence pays). The analyzed bucket geometry
+        # rides along in the record so consumers (a per-token
+        # normalization) read the bucket this analysis actually compiled
+        TB = pow2_bucket(self.config.prefill_bucket + N,
+                         sm.config.max_ragged_batch_size)
+        compiled = self._ragged_jit.lower(
+            params, i32(TB), i32(TB), i32(TB), i32(TB), i32(TB),
+            i32(TB), i32(N, MB), i32(N), cache, lb, aidN,
+            ssN, *wtN).compile()
+        programs["ragged_step"] = dict(
+            ds_memory.record_memory_analysis("ragged_step", compiled),
+            token_bucket=TB, row_bucket=N)
         return {"programs": programs, "buffers": ds_memory.buffers()}
 
     # convenience: serve-style generation over the ragged engine

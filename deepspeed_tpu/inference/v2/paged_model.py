@@ -7,13 +7,20 @@ Device-side core of inference v2. Reference counterparts:
     (ragged_ops/blocked_kv_rotary/)
   * ragged embedding + logits gather (ragged_ops/ragged_embed, logits_gather)
 
-Two entry points, both pure and jit-compiled by the engine:
-  * ``paged_prefill``: one new sequence's prompt chunk [1, C] — causal
-    attention within the chunk, K/V scattered into the sequence's cache
-    blocks, returns the last-token logits.
+The entry points, all pure and jit-compiled by the engine:
+  * ``paged_ragged_step``: a MIXED batch as one flat token buffer —
+    prompt chunks, continuations and decode rows; each token's K/V
+    scattered into its row's cache blocks, attention over the row's block
+    table up to the token's own position, returns each row's last-token
+    logits. Everything ``put()`` is given runs here.
   * ``paged_decode``: one token for each of N sequences — K/V appended at
-    each sequence's next slot, attention over the sequence's block table
-    (gathered pages), returns [N, V] logits.
+    each sequence's next slot, attention over the sequence's block table,
+    returns [N, V] logits; ``paged_decode_window`` runs K such steps in
+    one device loop with the pick inside (the ``generate()`` hot loop).
+  * ``paged_continue``: several tokens of ONE tracked sequence — the
+    n-gram speculation's verify pass and the draft model's catch-up;
+    ``paged_spec_decode_window`` is the decode window with the draft
+    model's propose -> verify -> accept rounds inside.
 
 The KV pool is ``[L, num_blocks, block_size, kv_heads * head_dim]``: a
 cached position is ONE lane-dense row, its kv heads side by side (head h
@@ -26,8 +33,9 @@ as a scalar and copy a page from where it lies (kernels/ragged_attention.py).
 One stored layout for every geometry and dtype; ``_kv_write`` and
 ``_kv_read`` are the only code that knows it. Block 0
 is the null block (padding writes land there). Static shapes throughout:
-prompt lengths bucket to multiples of ``prefill_bucket`` and the decode
-batch pads to the next power-of-two bucket — each bucket compiles once
+a step's tokens, rows and table width each pad to the next power-of-two
+bucket (the one-sequence passes to multiples of ``prefill_bucket``) — each
+bucket compiles once
 (the XLA analogue of the reference's CUDA-graph'd atom sizes).
 
 Design note — why there is no dedicated rotary+KV-append kernel (reference
@@ -37,8 +45,8 @@ scatter kernels per layer. Here the rotary and the ``.at[block_ids,
 offsets].set`` cache write sit INSIDE the jitted, scanned layer body, so XLA
 fuses them into the same program as the qkv projections — the "fusion" the
 reference hand-writes is the compiler's default. The Pallas budget goes
-where fusion cannot: the attention reads (paged_attention.py,
-ops/decode_attention.py, flash prefill).
+where fusion cannot: the attention reads (kernels/ragged_attention.py,
+kernels/paged_attention.py).
 """
 
 import functools
@@ -238,7 +246,7 @@ def _lora_delta(a, b, hn, aid):
     into ``b`` at load time, so this matches the training-side fused
     semantics ``W + scale * (a @ b)`` bit-for-bit under fp32); ``hn``
     [T, h]; ``aid`` int32 [T] per row, or a scalar for single-sequence
-    chunks (prefill/continue), which skips the gather entirely. Slot 0
+    chunks (``paged_continue``), which skips the gather entirely. Slot 0
     is all-zeros — base-model rows add an exact +0.0."""
     aid = jnp.asarray(aid)
     if aid.ndim == 0:
@@ -551,7 +559,7 @@ def _kv_write_pair(kc, vc, ksc, vsc, l, blocks, offs, k, v):
 
 def _scan_layers(cfg, params, x, cache, lora, topo, attend):
     """The per-head block's layer stack, scanned, for every program that
-    runs it (prefill, continue, decode, the ragged step, the speculative
+    runs it (continue, decode, the ragged step, the speculative
     verify). What differs between them is ``attend(lp, ll, l, hn, kc,
     vc, ksc, vsc) -> (o, kc, vc, ksc, vsc)``: the program's projections
     by its own shapes (``_qkv_heads``), its write-set
@@ -1124,78 +1132,8 @@ def _refuse_latent(cfg, program):
         raise NotImplementedError(
             f"{program} has no form for the walk of runs: an "
             f"attention='mla' model or a layer_types pattern is served "
-            f"through the ragged step and the decode programs "
-            f"(ragged_attention 'auto' or 'on', no speculation)")
-
-
-# ---------------------------------------------------------------------------
-# Prefill
-# ---------------------------------------------------------------------------
-def paged_prefill(cfg: TransformerConfig, params, ids: jnp.ndarray,
-                  prompt_len: jnp.ndarray, cache: Dict[str, jnp.ndarray],
-                  block_ids: jnp.ndarray, offsets: jnp.ndarray,
-                  use_kernel: bool = True, topo=None,
-                  lora=None, adapter_ids=None
-                  ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
-    """ids [1, C] (padded prompt); prompt_len scalar; block_ids/offsets [C]
-    map chunk position -> (cache block, slot) with padding -> null block.
-    Returns (last-token logits [V], cache).
-
-    ``use_kernel`` runs the prompt's causal self-attention through the
-    Pallas flash kernel (the reference's blocked-flash prefill,
-    inference/v2/kernels/ragged_ops/blocked_flash/) — padding keys sit at
-    positions AFTER every valid query, so causal masking excludes them and
-    no explicit valid mask is needed; K/V still scatter into the cache
-    blocks in the same pass."""
-    _refuse_latent(cfg, "paged_prefill")
-    C = ids.shape[1]
-    nh, nkv, hd = cfg.num_heads, cfg.kv_heads, cfg.head_dim
-    # shape gates only: off-TPU the kernel runs in interpret mode (slow but
-    # identical math), which is what lets CPU tests cover this path
-    flash_ok = (use_kernel and C % 128 == 0 and hd % 8 == 0
-                and cfg.positional != "alibi")
-    params = _deq_nonlayer(params)
-    pos = jnp.arange(C)
-    x = _embed(cfg, params, ids[0], pos)                       # [C, H]
-    cos, sin = _rope_at(cfg, pos)                              # [C, half]
-    valid = pos < prompt_len                                   # [C]
-    causal = pos[:, None] >= pos[None, :]
-    mask = causal & valid[None, :]                             # [C, C]
-
-    def attend(lp, ll, l, hn, kc, vc, ksc, vsc):
-        q, k, v = _qkv_heads(
-            cfg, lp, hn, (C,), cos, sin,
-            lambda q, v: _lora_qv(ll, hn, adapter_ids, q, v))
-        kc, vc, ksc, vsc = _kv_write_pair(kc, vc, ksc, vsc, l, block_ids,
-                                          offsets, k, v)
-        with jax.named_scope("attn_kernel"):
-            if flash_ok:
-                from ...ops.flash_attention import flash_attention
-
-                o = flash_attention(
-                    q.transpose(1, 0, 2)[None],      # [1, nh, C, hd]
-                    k.transpose(1, 0, 2)[None],      # [1, nkv, C, hd]
-                    v.transpose(1, 0, 2)[None],
-                    causal=True)[0].transpose(1, 0, 2).reshape(C, nh * hd)
-            else:
-                kf, vf = k, v
-                if nkv != nh:
-                    kf = jnp.repeat(kf, nh // nkv, axis=1)
-                    vf = jnp.repeat(vf, nh // nkv, axis=1)
-                scores = jnp.einsum("qhd,khd->hqk", q,
-                                    kf).astype(jnp.float32)
-                scores = scores / jnp.sqrt(jnp.asarray(hd, jnp.float32))
-                if cfg.positional == "alibi":
-                    scores = scores + _alibi_row(cfg, pos)
-                scores = jnp.where(mask[None], scores, NEG_INF)
-                probs = jax.nn.softmax(scores, axis=-1).astype(hn.dtype)
-                o = jnp.einsum("hqk,khd->qhd", probs,
-                               vf).reshape(C, nh * hd)
-        return o, kc, vc, ksc, vsc
-
-    x, cache = _scan_layers(cfg, params, x, cache, lora, topo, attend)
-    return _head(cfg, params, x,
-                 lambda x: jnp.take(x, prompt_len - 1, axis=0)), cache
+            f"through the ragged step and the decode programs (no "
+            f"speculation)")
 
 
 # ---------------------------------------------------------------------------
@@ -1214,8 +1152,9 @@ def paged_continue(cfg: TransformerConfig, params, ids: jnp.ndarray,
     inference/v2/kernels/ragged_ops/atom_builder + blocked_flash): the
     chunk's K/V are scattered into the sequence's cache blocks, then every
     chunk token attends over the sequence's full block table (cached prefix
-    + the chunk itself) with causal masking — replacing the token-at-a-time
-    decode loop the engine previously ran for multi-token puts.
+    + the chunk itself) with causal masking. The engine runs it for the
+    n-gram speculation's verify pass (``greedy_window``) and the draft
+    model's catch-up; a multi-token put() is a ragged step.
 
     ids [1, C] (padded chunk); start_pos = tokens already cached; n_new =
     valid tokens in the chunk; block_ids/offsets [C] map chunk position ->
@@ -1379,10 +1318,9 @@ def paged_ragged_step(cfg: TransformerConfig, params, ids: jnp.ndarray,
     bound = pos+1; 0 for padding) and the KV write-set
     ``write_blocks``/``write_offsets`` — plus per-row ``block_tables``
     [RB, MBw] and ``last_index`` [RB] (flat index of each row's last
-    valid token). Replaces the separate paged_prefill / paged_continue /
-    paged_decode dispatches for everything the scheduler composes into a
-    step. Returns ([RB, V] last-token logits per row, cache); an
-    attention='mla' model returns (logits, what its expert layers
+    valid token): everything put() is given and everything the
+    scheduler composes into a step. Returns ([RB, V] last-token logits
+    per row, cache); an attention='mla' model returns (logits, what its expert layers
     routed, cache): see ``_pattern_step``; ``state_slots`` [RB] is each
     row's slot of recurrent state where its layer pattern has
     linear-attention layers (rows packed one after another in row

@@ -1,13 +1,10 @@
 """Ragged batch descriptor: one padded layout for mixed prefill+decode.
 
 The SplitFuse scheduler composes each step from decode rows and prompt
-chunks; previously the engine SEQUENCED those pieces through separate
-compiled-program families (``paged_prefill`` per prompt bucket, the
-fused ``paged_continue`` pass, ``paged_decode`` per batch bucket). A
-:class:`RaggedBatch` packs the same composition into ONE padded
-(token-bucket x row-bucket) layout the unified ragged program
-(``paged_model.paged_ragged_step`` + ``kernels.ragged_attention``)
-consumes in a single launch.
+chunks, and ``put()`` takes any such mix. A :class:`RaggedBatch` packs
+the composition into ONE padded (token-bucket x row-bucket) layout the
+unified ragged program (``paged_model.paged_ragged_step`` +
+``kernels.ragged_attention``) consumes in a single launch.
 
 Layout (all numpy, converted to device arrays by the engine):
 
@@ -25,8 +22,7 @@ Layout (all numpy, converted to device arrays by the engine):
 
 Both buckets come from the shared ``utils.bucketing`` helpers, so the
 compile cache holds one program per (token bucket, row bucket,
-table-width bucket) — logarithmic in every axis, replacing the
-prefill-bucket x decode-bucket PRODUCT of the stitched families.
+table-width bucket) — logarithmic in every axis.
 """
 
 from dataclasses import dataclass
@@ -78,7 +74,7 @@ def pack(entries: Sequence[Tuple[int, np.ndarray]], state_manager,
     tables would else widen bucket by bucket).
 
     Allocates each row's KV blocks for the tokens it will write
-    (``ensure_blocks``, same contract as the stitched paths) but does
+    (``ensure_blocks``) but does
     NOT advance ``seen_tokens`` — the engine commits host state only
     after the device step is dispatched, like every other path.
     """
@@ -134,7 +130,7 @@ def pack(entries: Sequence[Tuple[int, np.ndarray]], state_manager,
         new_lens.append(n)
 
     # slice tables to the power-of-two used-page bucket (the same
-    # width discipline as the stitched decode path: a short batch in a
+    # width discipline as the decode programs: a short batch in a
     # full-width table would stream every null slot)
     if not full_width:
         tables = tables[:, :pow2_bucket(used_pages, sm.max_blocks_per_seq)]

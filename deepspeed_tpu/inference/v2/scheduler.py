@@ -6,14 +6,10 @@ chunks scheduled across forward passes, short prompts composed to fill a
 target token budget, and decodes are never stalled behind a long
 prefill). The reference implements the policy in the MII serving layer on
 top of ``InferenceEngineV2.put``; here it sits directly on the TPU-native
-engine (engine_v2.py). With ragged attention enabled (the default,
-config_v2.ragged_attention) each composed step is emitted as ONE
+engine (engine_v2.py). Each composed step is emitted as ONE
 :class:`~.ragged.batch.RaggedBatch` — prompt chunks and decode rows run
 in a single unified compiled program (kernels/ragged_attention.py), so
 the scheduler never trades prefill against decode across dispatches.
-With it off, put() sequences the stitched program families: first
-prompt chunk -> paged_prefill, later chunks -> the fused paged_continue
-pass, single tokens -> the batched paged_decode.
 
 TPU-first consequence of the same "schedule a token budget, not
 sequences" insight: every (bucketed) token count is one precompiled XLA
@@ -106,8 +102,8 @@ class DynamicSplitFuseScheduler:
         sm = engine.state_manager.config
         self.token_budget = min(token_budget or sm.max_ragged_batch_size,
                                 sm.max_ragged_batch_size)
-        # chunks align to the prefill bucket so every split hits an
-        # already-compiled program size
+        # the default prompt chunk is the engine's prefill_bucket: one
+        # size for every split keeps the steps' token buckets few
         self.chunk = chunk or engine.config.prefill_bucket
         # a model whose window layers keep a ring takes no more of a
         # sequence in one step than the ring leaves room for
@@ -553,12 +549,9 @@ class DynamicSplitFuseScheduler:
             self._update_depth_gauges()
             return len(uids)
 
-        # mixed composition: with ragged attention enabled (engine
-        # default) put() emits this step as ONE RaggedBatch launch —
-        # chunks and decode rows packed into the unified ragged program
-        # (engine_v2.step_ragged) — instead of sequencing the
-        # prefill/continue/decode families, so the scheduler never
-        # trades prefill against decode across separate dispatches
+        # mixed composition: put() emits this step as ONE RaggedBatch
+        # launch — chunks and decode rows packed into the unified ragged
+        # program (engine_v2.step_ragged)
         logits = np.asarray(self.engine.put(uids, toks))
         self.steps += 1
         self._m_steps.inc()

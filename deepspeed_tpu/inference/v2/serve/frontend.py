@@ -50,11 +50,6 @@ class ServingConfig:
     chunk: Optional[int] = None             # prefill chunk size
     max_inflight: Optional[int] = None      # requests inside the scheduler
     idle_wait_s: float = 0.002
-    # 'auto' | 'on' | 'off': override the engine's ragged unified-step
-    # dispatch (config_v2.ragged_attention) for this serving runtime —
-    # 'off' is the rollback knob to the stitched prefill/decode
-    # families; None leaves the engine's own setting alone
-    ragged_attention: Optional[str] = None
     admission: AdmissionConfig = field(default_factory=AdmissionConfig)
     # active observability: flight-recorder budget, SLO burn-rate
     # monitoring, stall watchdog, KV-leak check at drain (telemetry/
@@ -219,8 +214,6 @@ class ServingEngine:
         one process row per lane."""
         self.config = config or ServingConfig()
         self.clock = clock
-        if self.config.ragged_attention is not None:
-            engine.set_ragged_mode(self.config.ragged_attention)
         self.scheduler = DynamicSplitFuseScheduler(
             engine, token_budget=self.config.token_budget,
             chunk=self.config.chunk, clock=clock)

@@ -237,9 +237,10 @@ _r(name="serving.decode_window", default=8, lo=1, hi=64, online=True,
 _r(name="serving.prefill_bucket", default=64, lo=1, hi=8192,
    cost_signal="inference_ragged_pad_fraction",
    search=(16, 32, 64, 128, 256),
-   doc="prompt lengths pad to multiples of this "
-       "(config_v2.prefill_bucket); finer buckets waste less padding "
-       "but compile more programs")
+   doc="the scheduler's default prompt chunk, and the bucket the "
+       "one-sequence passes (n-gram verify, draft catch-up) pad to "
+       "(config_v2.prefill_bucket); no prompt pads to it: put() is the "
+       "ragged step, whose buckets are powers of two")
 _r(name="serving.token_budget", default=768, lo=1, hi=1 << 16,
    cost_signal="inference_ragged_pad_fraction",
    search=(128, 256, 512, 768, 1024),

@@ -28,8 +28,7 @@ from deepspeed_tpu.models import TransformerConfig, TransformerLM
 from deepspeed_tpu.telemetry import context as trace_context
 from deepspeed_tpu.telemetry import get_registry, timeline, trace
 
-_ENGINE_SPANS = {"prefill", "continue", "decode_step", "decode_window",
-                 "ragged_step"}
+_ENGINE_SPANS = {"decode_step", "decode_window", "ragged_step"}
 
 
 @pytest.fixture(scope="module")

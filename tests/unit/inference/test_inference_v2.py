@@ -473,8 +473,8 @@ def test_moe_serving_tp_x_ep():
 
 
 def test_decode_table_sliced_to_used_pages():
-    """_decode_batch slices the block table to the power-of-two bucket of
-    pages actually in use (the decode program's cost scales with table
+    """The decode step (_assemble_decode_rows) slices the block table to
+    the power-of-two bucket of pages actually in use (the decode program's cost scales with table
     width — r05 chip capture), widening as the context grows."""
     cfg = _tiny_cfg(max_seq_len=128)  # block_size 16 -> 8 pages max
     model = TransformerLM(cfg)

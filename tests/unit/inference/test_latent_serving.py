@@ -333,7 +333,6 @@ def test_the_latent_tree_holds_the_leading_stack_apart():
     ({"tensor_parallel_size": 2}, "tensor_parallel_size"),
     ({"quant_bits": 8}, "quant_bits"),
     ({"max_lora_adapters": 2}, "max_lora_adapters"),
-    ({"ragged_attention": "off"}, "ragged_attention"),
     ({"state_manager": {"enable_prefix_caching": True}},
      "enable_prefix_caching")])
 def test_the_engine_refuses_at_construction(engine, word):
@@ -375,8 +374,8 @@ def test_a_latent_row_is_handed_to_another_engine():
     np.testing.assert_array_equal(
         np.asarray(src.kv_cache["latent"])[:, a.blocks],
         np.asarray(dst.kv_cache["latent"])[:, b.blocks])
-    np.testing.assert_array_equal(dst._decode_batch([9], [first])[9],
-                                  src._decode_batch([5], [first])[5])
+    np.testing.assert_array_equal(dst.put([9], [[first]])[0],
+                                  src.put([5], [[first]])[0])
 
 
 def test_the_expert_counters_count_valid_rows():

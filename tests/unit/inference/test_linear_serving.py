@@ -886,7 +886,6 @@ def test_the_old_trees_are_what_they_were():
     ({"expert_parallel_size": 2}, "expert-parallel"),
     ({"quant_bits": 8}, "quant_bits"),
     ({"max_lora_adapters": 2}, "max_lora_adapters"),
-    ({"ragged_attention": "off"}, "ragged_attention"),
     ({"kv_quant": True}, "kv_quant"),
     ({"state_manager": {"enable_prefix_caching": True}},
      "no recurrent state"),

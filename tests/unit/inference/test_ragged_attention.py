@@ -8,17 +8,22 @@ engine_v2.step_ragged + the SplitFuse scheduler's RaggedBatch emission):
   ``_page_update``); both variants read the pool as it is stored,
   ``[L, nb, bs, kvh * hd]`` whole with the layer a scalar, and touch no
   other layer;
-* ragged vs stitched token streams are bit-identical — greedy and
-  fixed-seed sampled — for prefill-only, decode-only and interleaved
-  batches, through put() and through the scheduler (chip-free: the
-  kernels run in interpret mode on CPU);
-* the mixed-traffic compiled-program count under ragged is strictly
-  lower than the stitched prefill+decode program count it replaces,
-  with ZERO steady-state recompiles (the watchdog pins it);
-* ``ragged_attention="off"`` reproduces the stitched dispatch exactly
-  (the CI-visible rollback guarantee).
+* put() — prefill-only, decode-only and interleaved batches — returns
+  the model's own dense float32 forward's logits, and a greedy
+  generate() stream its best tokens (chip-free: the kernels run in
+  interpret mode on CPU);
+* a row's stream does not depend on what it was packed with: a
+  fixed-seed sampled generate() row and every request of the
+  scheduler's mixed traffic equal the same request served ALONE;
+* the mixed traffic compiles 8 programs, of the families that remain
+  and no other, with ZERO steady-state recompiles (the watchdog pins
+  it), and put() has one way in: every row of it runs ``ragged_step``;
+* a configuration that still names ``ragged_attention`` (the option
+  that selected the stitched prefill / continue / decode dispatch,
+  gone with it) is refused by name.
 """
 
+import contextlib
 import functools
 
 import numpy as np
@@ -347,7 +352,7 @@ def tiny(tiny_model_128):
     return tiny_model_128
 
 
-def _engine(model, params, mode, window=1, **kw):
+def _engine(model, params, window=1, **kw):
     smc = dict(max_tracked_sequences=8, max_seq_len=128, num_blocks=65,
                block_size=16)
     smc.update(kw.pop("sm", {}))
@@ -355,65 +360,87 @@ def _engine(model, params, mode, window=1, **kw):
         model, RaggedInferenceEngineConfig(
             state_manager=DSStateManagerConfig(**smc),
             dtype="float32", prefill_bucket=16, decode_window=window,
-            ragged_attention=mode, **kw),
+            **kw),
         params=params)
+
+
+def _dense_logits(model, params, seq):
+    """The model's own dense forward over one whole sequence, float32:
+    [len(seq), vocab]. No pool, no packing, no kernel."""
+    return np.asarray(model.forward_logits(
+        params, jnp.asarray([seq], jnp.int32)))[0]
+
+
+def _assert_put_is_dense(model, params, got, seqs):
+    want = np.stack([_dense_logits(model, params, s)[-1] for s in seqs])
+    np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
+    np.testing.assert_array_equal(got.argmax(-1), want.argmax(-1))
 
 
 def test_put_parity_prefill_only(tiny):
     model, params = tiny
     prompts = [list(range(3, 17)), [2, 4, 6], list(range(40, 62))]
-    on = _engine(model, params, "on").put([1, 2, 3], prompts)
-    off = _engine(model, params, "off").put([1, 2, 3], prompts)
-    np.testing.assert_allclose(on, off, rtol=2e-4, atol=2e-4)
-    np.testing.assert_array_equal(on.argmax(-1), off.argmax(-1))
+    got = _engine(model, params).put([1, 2, 3], prompts)
+    _assert_put_is_dense(model, params, got, prompts)
 
 
 def test_put_parity_decode_only_and_interleaved(tiny):
     model, params = tiny
-    prompts = [list(range(3, 17)), [2, 4, 6]]
-    e_on = _engine(model, params, "on")
-    e_off = _engine(model, params, "off")
-    e_on.put([1, 2], prompts)
-    e_off.put([1, 2], prompts)
+    a, b = list(range(3, 17)), [2, 4, 6]
+    eng = _engine(model, params)
+    eng.put([1, 2], [a, b])
     # decode-only batch
-    d_on = e_on.put([1, 2], [[40], [41]])
-    d_off = e_off.put([1, 2], [[40], [41]])
-    np.testing.assert_allclose(d_on, d_off, rtol=2e-4, atol=2e-4)
+    got = eng.put([1, 2], [[40], [41]])
+    a, b = a + [40], b + [41]
+    _assert_put_is_dense(model, params, got, [a, b])
     # interleaved: decode + fresh prefill + continuation chunk
-    m_on = e_on.put([1, 3, 2], [[50], list(range(20, 31)), [51, 52, 53]])
-    m_off = e_off.put([1, 3, 2], [[50], list(range(20, 31)),
-                                  [51, 52, 53]])
-    np.testing.assert_allclose(m_on, m_off, rtol=2e-4, atol=2e-4)
-    np.testing.assert_array_equal(m_on.argmax(-1), m_off.argmax(-1))
+    c = list(range(20, 31))
+    got = eng.put([1, 3, 2], [[50], c, [51, 52, 53]])
+    _assert_put_is_dense(model, params, got,
+                         [a + [50], c, b + [51, 52, 53]])
 
 
 def test_generate_stream_parity_greedy_and_sampled(tiny):
-    """Bit-identical token streams, ragged vs stitched, through the full
-    generate() loop (ragged prefill put + fused decode window)."""
+    """The full generate() loop (ragged prefill put + fused decode
+    window). Greedy: every generated token is the dense forward's best
+    at its position. Sampled, fixed seed: a row's stream is the stream
+    of that request served alone by another engine (``generate()``
+    seeds a row by its place, so each request takes row 0 in turn)."""
     model, params = tiny
     prompts = [list(range(3, 17)), [2, 4, 6], [5]]
-    for kw in (dict(max_new_tokens=20),
-               dict(max_new_tokens=14, temperature=0.8, top_p=0.9,
-                    top_k=20, seed=5)):
-        a = _engine(model, params, "on", window=8).generate(prompts, **kw)
-        b = _engine(model, params, "off", window=8).generate(prompts,
-                                                             **kw)
-        for x, y in zip(a, b):
-            np.testing.assert_array_equal(x, y)
+    outs = _engine(model, params, window=8).generate(
+        prompts, max_new_tokens=20)
+    for p, out in zip(prompts, outs):
+        assert len(out) == len(p) + 20
+        ref = _dense_logits(model, params, list(map(int, out)))
+        np.testing.assert_array_equal(
+            out[len(p):], ref[len(p) - 1:-1].argmax(-1))
+    kw = dict(max_new_tokens=14, temperature=0.8, top_p=0.9, top_k=20,
+              seed=5)
+    mixed, alone = (_engine(model, params, window=8) for _ in range(2))
+    for r in range(len(prompts)):
+        order = prompts[r:] + prompts[:r]
+        np.testing.assert_array_equal(
+            mixed.generate(order, **kw)[0],
+            alone.generate(order[:1], **kw)[0])
 
 
-def _mixed_traffic(sched, prompts, base, new_tokens=10):
+def _mixed_traffic(sched, prompts, base, new_tokens=10, only=None):
     """Staggered submissions so steps interleave prompt chunks with
-    running decodes (the SplitFuse mixed-batch shape)."""
+    running decodes (the SplitFuse mixed-batch shape). ``only``: submit
+    that one request of the mix, with its uid and sampling, and no
+    other."""
+    def submit(i, uid, p, **kw):
+        if only is None or only == i:
+            sched.submit(uid, p, new_tokens, **kw)
     for i, p in enumerate(prompts[:2]):
-        sched.submit(base + i, p, new_tokens,
-                     temperature=0.7 if i == 1 else 0.0, top_p=0.9,
-                     seed=5)
+        submit(i, base + i, p, temperature=0.7 if i == 1 else 0.0,
+               top_p=0.9, seed=5)
     for _ in range(3):
         sched.step()
     for i, p in enumerate(prompts[2:]):
-        sched.submit(base + 100 + i, p, new_tokens,
-                     temperature=0.9 if i % 2 else 0.0, top_k=30, seed=9)
+        submit(2 + i, base + 100 + i, p,
+               temperature=0.9 if i % 2 else 0.0, top_k=30, seed=9)
     sched.run()
     return {uid: list(map(int, toks))
             for uid, toks in sched.results().items()}
@@ -427,23 +454,29 @@ def _mixed_prompts():
 
 @pytest.mark.parametrize("window", [1, 8])
 def test_scheduler_stream_parity_mixed_traffic(tiny, window):
-    """The scheduler emits RaggedBatch steps (ragged on) vs sequenced
-    put() dispatch (off): greedy AND fixed-seed sampled streams must be
-    bit-identical under chunked prefill + interleaved decode."""
+    """The scheduler's RaggedBatch steps under chunked prefill +
+    interleaved decode: every request's stream, greedy AND fixed-seed
+    sampled, is bit-identical to the same request (same uid, same seed)
+    served alone by another engine, whose steps hold that row only."""
     model, params = tiny
     prompts = _mixed_prompts()
-    results = {}
-    for mode in ("on", "off"):
-        eng = _engine(model, params, mode, window=window)
-        sched = DynamicSplitFuseScheduler(eng, token_budget=24, chunk=16)
-        results[mode] = _mixed_traffic(sched, prompts, 100)
-    assert results["on"] == results["off"]
+    mixed = _mixed_traffic(DynamicSplitFuseScheduler(
+        _engine(model, params, window=window), token_budget=24, chunk=16),
+        prompts, 100)
+    assert len(mixed) == len(prompts)
+    alone_engine = _engine(model, params, window=window)
+    alone = {}
+    for i in range(len(prompts)):
+        alone.update(_mixed_traffic(DynamicSplitFuseScheduler(
+            alone_engine, token_budget=24, chunk=16), prompts, 100,
+            only=i))
+    assert mixed == alone
 
 
 def _greedy_mixed_traffic(sched, prompts, base, new_tokens=10):
-    """All-greedy staggered mix (the serving_bench --mixed sweep shape):
-    steps interleave prompt chunks with running decodes, and pure-decode
-    steps take the fused-window fast path in BOTH modes."""
+    """All-greedy staggered mix: steps interleave prompt chunks with
+    running decodes, and pure-decode steps take the fused-window fast
+    path."""
     for i, p in enumerate(prompts[:2]):
         sched.submit(base + i, p, new_tokens)
     for _ in range(3):
@@ -453,107 +486,150 @@ def _greedy_mixed_traffic(sched, prompts, base, new_tokens=10):
     sched.run()
 
 
-def test_mixed_traffic_fewer_programs_zero_steady_recompiles(tiny):
-    """The acceptance criterion, chip-free: ONE ragged program family
-    serves the mixed sweep with zero steady-state recompiles, and its
-    compiled-program count is strictly lower than the stitched
-    prefill+decode program count it replaces."""
-    model, params = tiny
-    prompts = _mixed_prompts()
-    counts, steady, families = {}, {}, {}
-    for mode in ("on", "off"):
-        prev = set_registry(MetricsRegistry())
-        watchdog.reset()
-        try:
-            eng = _engine(model, params, mode, window=8)
-            sched = DynamicSplitFuseScheduler(eng, token_budget=24,
-                                              chunk=16)
-            # warm the bucket set TWICE: a bucket's first call compiles
-            # against the unsharded fresh pool, its repeats against the
-            # donated (sharded) one — the second wave absorbs that
-            # one-time respecialization for buckets the first wave
-            # visited only once (same discipline as bench/gate)
-            _greedy_mixed_traffic(sched, prompts, 100)
-            _greedy_mixed_traffic(sched, prompts, 200)
-            reg = get_registry()
-            counts[mode] = reg.family_total("xla_compile_events_total")
-            watchdog.mark_steady(True)
-            try:
-                _greedy_mixed_traffic(sched, prompts, 300)
-            finally:
-                watchdog.mark_steady(False)
-            steady[mode] = reg.family_total(
-                "xla_steady_state_recompiles_total")
-            families[mode] = {v[0] for v, _ in
-                              reg.get("xla_compile_events_total").series()}
-        finally:
-            set_registry(prev)
-            watchdog.reset()
-    assert steady["on"] == 0
-    # exact, so that a bucket either side gains shows here: 6 ragged_step
-    # + 2 decode_window_greedy, against 2 prefill + 1 continue + 4 decode
-    # + the same 2 windows
-    assert counts == {"on": 8, "off": 9}
-    # the stitched families are gone from the ragged sweep entirely
-    assert "ragged_step" in families["on"]
-    assert not families["on"] & {"prefill", "continue", "decode"}
-
-
-# ---------------------------------------------------------------------------
-# config + fallback
-# ---------------------------------------------------------------------------
-def test_off_mode_reproduces_stitched_dispatch(tiny):
-    """ragged_attention='off' must reproduce today's behavior exactly:
-    the stitched program families run (and no ragged program ever
-    compiles), and the streams match the ragged path bit-for-bit."""
-    model, params = tiny
-    prompts = [list(range(3, 17)), [2, 4, 6]]
+@contextlib.contextmanager
+def _own_registry():
+    """A registry and a watchdog of the test's own: what compiled and
+    what was counted inside is what the test ran."""
     prev = set_registry(MetricsRegistry())
     watchdog.reset()
     try:
-        eng = _engine(model, params, "off", window=8)
-        assert eng.ragged_enabled is False
-        out_off = eng.generate(prompts, max_new_tokens=12)
-        progs = {v[0] for v, _ in
-                 get_registry().get("xla_compile_events_total").series()}
-        assert "prefill" in progs
-        assert "ragged_step" not in progs
+        yield get_registry()
     finally:
         set_registry(prev)
         watchdog.reset()
-    out_on = _engine(model, params, "on", window=8).generate(
-        prompts, max_new_tokens=12)
-    for x, y in zip(out_off, out_on):
-        np.testing.assert_array_equal(x, y)
 
 
-def test_ragged_mode_validation_and_runtime_flip(tiny):
+def _compiled(reg):
+    """{program name: compile events} the watchdog has seen."""
+    fam = reg.get("xla_compile_events_total")
+    return {v[0]: int(s.value) for v, s in fam.series()} if fam else {}
+
+
+def test_mixed_traffic_fewer_programs_zero_steady_recompiles(tiny):
+    """The acceptance criterion, chip-free: ONE ragged program family
+    (and the fused window) serves the mixed sweep, 8 programs in all,
+    with zero steady-state recompiles."""
     model, params = tiny
-    with pytest.raises(ValueError):
-        _engine(model, params, "maybe")
-    eng = _engine(model, params, "auto")
-    assert eng.ragged_enabled is True     # auto == on today
-    eng.set_ragged_mode("off")
-    assert eng.ragged_enabled is False
-    eng.set_ragged_mode("on")
-    assert eng.ragged_enabled is True
-    with pytest.raises(ValueError):
-        eng.set_ragged_mode("sometimes")
+    prompts = _mixed_prompts()
+    with _own_registry() as reg:
+        eng = _engine(model, params, window=8)
+        sched = DynamicSplitFuseScheduler(eng, token_budget=24, chunk=16)
+        # warm the bucket set TWICE: a bucket's first call compiles
+        # against the unsharded fresh pool, its repeats against the
+        # donated (sharded) one — the second wave absorbs that
+        # one-time respecialization for buckets the first wave
+        # visited only once (same discipline as bench/gate)
+        _greedy_mixed_traffic(sched, prompts, 100)
+        _greedy_mixed_traffic(sched, prompts, 200)
+        count = reg.family_total("xla_compile_events_total")
+        watchdog.mark_steady(True)
+        try:
+            _greedy_mixed_traffic(sched, prompts, 300)
+        finally:
+            watchdog.mark_steady(False)
+        steady = reg.family_total("xla_steady_state_recompiles_total")
+        families = _compiled(reg)
+    assert steady == 0
+    # exact, so that a bucket gained shows here
+    assert count == 8
+    assert families == {"ragged_step": 6, "decode_window_greedy": 2}
 
 
-def test_serving_config_ragged_knob(tiny):
-    """ServingConfig.ragged_attention overrides the engine's dispatch at
-    runtime construction (the serve-level rollback knob)."""
-    from deepspeed_tpu.inference.v2.serve.frontend import (ServingConfig,
-                                                           ServingEngine)
-    from deepspeed_tpu.telemetry.anomaly import DiagnosticsConfig
-
+# ---------------------------------------------------------------------------
+# one way in: the option is gone, and so are the programs it selected
+# ---------------------------------------------------------------------------
+def test_a_config_dict_that_names_ragged_attention_is_refused(tiny):
     model, params = tiny
-    eng = _engine(model, params, "auto")
-    serving = ServingEngine(eng, ServingConfig(
-        ragged_attention="off",
-        diagnostics=DiagnosticsConfig(enabled=False)))
-    try:
-        assert eng.ragged_enabled is False
-    finally:
-        serving.diagnostics.close()
+    for value in ("off", "auto"):
+        with pytest.raises(ValueError, match="ragged_attention is gone"):
+            InferenceEngineV2(model, {"ragged_attention": value,
+                                      "dtype": "float32"}, params=params)
+
+
+def test_a_serving_config_that_names_ragged_attention_is_refused():
+    from deepspeed_tpu.inference.v2.serve.worker import _serving_config
+    with pytest.raises(TypeError, match="ragged_attention"):
+        _serving_config({"serving": {"ragged_attention": "off"}})
+
+
+PLAIN_FAMILIES = {"ragged_step", "decode_greedy", "decode_sample",
+                  "decode_window_greedy", "decode_window_sample"}
+SPEC_FAMILIES = {"spec_verify_w", "draft_catchup", "spec_decode_window"}
+
+
+def _family(program):
+    return "spec_verify_w" if program.startswith("spec_verify_w") \
+        else program
+
+
+def _engine_families(eng):
+    """The family of every watched jit the engine holds, built or
+    cached: what it COULD compile, whatever the traffic was."""
+    held = list(vars(eng).values())
+    held += [j for pair in eng._fused_jit_cache.values() for j in pair]
+    held += list(eng._continue_spec_jits.values())
+    held += list(eng._spec_window_jits.values())
+    return {_family(j.program) for j in held
+            if isinstance(j, watchdog.WatchedFunction)}
+
+
+@pytest.mark.parametrize("speculative", [False, True],
+                         ids=["plain", "speculative"])
+def test_the_engine_compiles_the_families_that_remain_and_no_other(
+        tiny, speculative):
+    """After the mixed traffic (greedy and sampled rows, all-greedy
+    rows, sampled generate(); windows of 1 and of 8) the compiled
+    programs are the five plain families.
+    With a draft model loaded, speculative generate() through both
+    draft sources adds the three speculative ones, and no ninth; the
+    engine holds no watched jit of another name either."""
+    model, params = tiny
+    prompts = _mixed_prompts()
+    with _own_registry() as reg:
+        for window in (1, 8):
+            eng = _engine(model, params, window=window)
+            sched = DynamicSplitFuseScheduler(eng, token_budget=24,
+                                              chunk=16)
+            _mixed_traffic(sched, prompts, 100)
+            _greedy_mixed_traffic(sched, prompts, 300)
+            eng.generate(prompts[:2], max_new_tokens=4, temperature=0.8,
+                         seed=3)
+        want = set(PLAIN_FAMILIES)
+        if speculative:
+            eng.load_draft_model(model, params)
+            # greedy text of this model soon cycles: n-gram drafts hit
+            rep = [[5, 9, 17, 23] * 6]
+            for mode in ("ngram", "draft"):
+                eng.generate(rep, max_new_tokens=40, speculative=True,
+                             spec_mode=mode)
+            want |= SPEC_FAMILIES
+        assert {_family(p) for p in _compiled(reg)} == want
+        assert _engine_families(eng) <= want
+
+
+@pytest.mark.parametrize("tokens", [[40], [40, 41, 42]],
+                         ids=["one_token", "continuation"])
+def test_put_of_a_known_sequence_runs_the_ragged_step(tiny, tokens):
+    """put() of one token for a KNOWN sequence and of a multi-token
+    continuation are both ONE ragged step: its span, its counters, its
+    program, and no decode step beside it."""
+    from deepspeed_tpu.telemetry import trace
+    model, params = tiny
+    prompt = list(range(3, 17))
+    with _own_registry() as reg:
+        eng = _engine(model, params)
+        eng.put([1], [prompt])
+        steps0 = reg.family_total("inference_ragged_steps_total")
+        tokens0 = reg.family_total("inference_ragged_tokens_total")
+        trace.clear()
+        got = eng.put([1], [tokens])
+        names = {s["name"] for s in trace.export()}
+        assert reg.family_total("inference_ragged_steps_total") \
+            == steps0 + 1
+        assert reg.family_total("inference_ragged_tokens_total") \
+            == tokens0 + len(tokens)
+        assert reg.family_total("inference_decode_steps_total") == 0
+        assert set(_compiled(reg)) == {"ragged_step"}
+    assert "ragged_step" in names
+    assert not names & {"prefill", "continue", "decode_step"}
+    _assert_put_is_dense(model, params, got, [prompt + tokens])

@@ -414,9 +414,8 @@ def test_can_schedule_counts_both_geometries():
                         "enable_kv_spill": True}}, "enable_kv_spill"),
     ({"max_lora_adapters": 2}, "max_lora_adapters"),
     ({"quant_bits": 8}, "quant_bits"),
-    ({"ragged_attention": "off"}, "ragged_attention 'off'"),
     ({"tensor_parallel_size": 2}, "tensor_parallel_size"),
-], ids=["prefix-cache", "spill", "lora", "weight-quant", "stitched", "tp"])
+], ids=["prefix-cache", "spill", "lora", "weight-quant", "tp"])
 def test_what_two_geometries_do_not_serve_refuses_at_construction(engine,
                                                                   why):
     cfg = TransformerConfig(**TOY)

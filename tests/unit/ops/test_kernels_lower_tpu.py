@@ -491,18 +491,6 @@ def test_the_cells_programs_read_the_pool_where_it_lies(tpu_sharding,
         "while", "tuple"}, sorted(set(made))
 
 
-@pytest.mark.slow   # the flash kernel it uses is pinned tier-1 above
-def test_opt_prefill_compiles(tpu_sharding):
-    from deepspeed_tpu.inference.v2.paged_model import paged_prefill
-    cfg, params, cache, i32 = _serving_case(tpu_sharding, False)
-    C = 256
-    err = _compile_error(
-        lambda p, ids, n, c, b, o: paged_prefill(cfg, p, ids, n, c, b, o,
-                                                 use_kernel=True),
-        params, i32(1, C), i32(), cache, i32(C), i32(C))
-    assert err is None, err
-
-
 # ---------------------------------------------------------------------------
 # the latent (MLA) pool: joyai-llm-flash.rollout-64x256's geometry
 # ---------------------------------------------------------------------------

@@ -51,7 +51,7 @@ def test_record_memory_analysis_plain_program(_fresh):
 
 
 def test_engine_memory_report_chip_free(tiny_model, _fresh):
-    """The decode/prefill programs' memory gauges populate from AOT
+    """The decode and ragged-step programs' memory gauges populate from AOT
     lowering alone — no generate() call, no device execution of the
     analyzed shapes."""
     model, params = tiny_model
@@ -64,11 +64,10 @@ def test_engine_memory_report_chip_free(tiny_model, _fresh):
         params=params)
     rep = eng.memory_report(batch=2)
     assert set(rep["programs"]) == {"decode_greedy",
-                                    "decode_window_greedy", "prefill",
-                                    "ragged_step"}
+                                    "decode_window_greedy", "ragged_step"}
     for rec in rep["programs"].values():
         assert rec["peak_bytes"] > 0
-        # every decode/prefill program references the params and pool
+        # every program references the params and pool
         assert rec["argument_size_in_bytes"] > 0
     # the engine registered its long-lived buffers at construction
     assert rep["buffers"]["kv_pool"] > 0
