@@ -38,11 +38,12 @@ from ...telemetry import trace, watchdog
 from ...utils.bucketing import ceil_bucket, pow2_bucket
 from ...utils.logging import log_dist
 from .config_v2 import RaggedInferenceEngineConfig
+from .kernels import state_space
 from .kernels.linear_attention import (chunk_kernel_serves,
                                        conv_kernel_serves)
 from .kernels.ragged_attention import (LATENT, kernel_variant,
                                        one_token_tile_serves)
-from .paged_model import (init_lora_bank, init_paged_kv_cache,
+from .paged_model import (STATE_LEAVES, init_lora_bank, init_paged_kv_cache,
                           paged_continue, paged_decode, paged_decode_window,
                           paged_ragged_step, paged_spec_decode_window)
 from .ragged import batch as ragged_batch
@@ -172,7 +173,8 @@ class InferenceEngineV2:
         if not cfg.has_state and config.state_dtype != "float32":
             raise ValueError(
                 "state_dtype is for a model that keeps recurrent state "
-                "(linear-attention layers); this one keeps none")
+                "(linear-attention or state-space layers); this one keeps "
+                "none")
         sm = config.state_manager
         if sm.max_seq_len > cfg.max_seq_len:
             sm.max_seq_len = cfg.max_seq_len
@@ -505,12 +507,12 @@ class InferenceEngineV2:
                                     ds_memory.tree_bytes(self.params))
             self._m_state_bytes.set(ds_memory.tree_bytes(
                 {k: v for k, v in self.kv_cache.items()
-                 if k.startswith("kda_")}))
+                 if k in STATE_LEAVES}))
             for kind in ("full", "window"):
                 self._m_pool_bytes.labels(kind=kind).set(
                     ds_memory.tree_bytes({
                         k: v for k, v in self.kv_cache.items()
-                        if not k.startswith("kda_")
+                        if k not in STATE_LEAVES
                         and k.endswith("_window") == (kind == "window")}))
         except Exception:  # accounting must never block serving
             pass
@@ -528,7 +530,7 @@ class InferenceEngineV2:
         pattern over per-head attention (``cfg.layer_types``), whose
         window layers keep a ring in a pool of their own; said at
         construction rather than run wrong."""
-        who, refused = (InferenceEngineV2._pattern_refusals(config)
+        who, refused = (InferenceEngineV2._pattern_refusals(config, cfg)
                         if cfg.layer_types is not None
                         else InferenceEngineV2._latent_refusals(config, cfg))
         bad = [what for what, on in refused.items() if on]
@@ -537,10 +539,14 @@ class InferenceEngineV2:
                 who + " is served without: " + "; ".join(bad))
 
     @staticmethod
-    def _pattern_refusals(config):
+    def _pattern_refusals(config, cfg):
         sm = config.state_manager
-        return ("a layer_types pattern (window and full per-head layers, a "
-                "cache of two geometries)", {
+        state = cfg.has_state
+        return ("a layer_types pattern (" + (
+            "state-space layers with a recurrent state a sequence beside "
+            "per-head attention" if state else
+            "window and full per-head layers, a cache of two geometries")
+                + ")", {
             "tensor_parallel_size > 1 (the pattern's kernels and "
             "stacks are written for one device)":
                 config.tensor_parallel_size > 1,
@@ -554,10 +560,15 @@ class InferenceEngineV2:
                 config.max_lora_adapters > 0,
             "enable_prefix_caching (a shared block holds the full "
             "layers' keys and values only: a row that skipped a "
-            "prefix would find its ring empty)":
+            "prefix would " + (
+                "start from the wrong recurrent state" if state
+                else "find its ring empty") + ")":
                 sm.enable_prefix_caching,
             "enable_kv_spill (the spill tier moves the blocks of one "
-            "geometry and no ring)": sm.enable_kv_spill})
+            "geometry and no " + ("state slot" if state else "ring") + ")":
+                sm.enable_kv_spill,
+            "kv_quant (an int8 pool has not been served beside state "
+            "leaves)": state and config.kv_quant})
 
     @staticmethod
     def _latent_refusals(config, cfg):
@@ -649,9 +660,9 @@ class InferenceEngineV2:
             "inference_tracked_sequences", "sequences with live KV state")
         self._m_state_bytes = reg.gauge(
             "inference_state_bytes",
-            "bytes of the recurrent-state leaves (linear-attention "
-            "layers: every slot of every such layer, the null slot "
-            "included); 0 for a model that keeps none", unit="bytes")
+            "bytes of the recurrent-state leaves (linear-attention or "
+            "state-space layers: every slot of every such layer, the null "
+            "slot included); 0 for a model that keeps none", unit="bytes")
         self._m_state_slots = reg.gauge(
             "inference_state_slots_in_use",
             "recurrent-state slots owned by tracked sequences")
@@ -673,6 +684,18 @@ class InferenceEngineV2:
             "fused window counts its steps; 0 for a model without such "
             "layers, and where the backend or the widths leave it to "
             "the XLA form)")
+        self._m_ssm_state_kernel_steps = reg.counter(
+            "inference_ssm_state_kernel_steps_total",
+            "decode steps launched whose state-space layers ran their "
+            "one-token update as the kernel ssm_state_update (a fused "
+            "window counts its steps; 0 for a model without such layers, "
+            "and where the backend or the widths leave it to the XLA form)")
+        self._m_ssm_scan_kernel_steps = reg.counter(
+            "inference_ssm_scan_kernel_steps_total",
+            "ragged steps launched whose state-space layers ran their "
+            "chunked form as the kernel ssm_chunk_fwd (0 for a model "
+            "without such layers, and where the backend or the widths "
+            "leave it to the XLA form)")
         self._m_one_token_steps = reg.counter(
             "inference_attention_one_token_steps_total",
             "decode steps launched whose attention kernels took the "
@@ -1598,8 +1621,12 @@ class InferenceEngineV2:
         programs' own tests say so."""
         if not self._use_kernel:
             return
-        if self._has_state and conv_kernel_serves(self.kv_cache["kda_conv"]):
+        cache = self.kv_cache
+        if "kda_conv" in cache and conv_kernel_serves(cache["kda_conv"]):
             self._m_conv_kernel_steps.inc(steps)
+        if "ssm_state" in cache \
+                and state_space.state_kernel_serves(cache["ssm_state"]):
+            self._m_ssm_state_kernel_steps.inc(steps)
         cfg = self.model.cfg
         if one_token_tile_serves(cfg.attention == "mla", cfg.head_dim,
                                  cfg.kv_heads):
@@ -1893,9 +1920,15 @@ class InferenceEngineV2:
             if self._has_state:
                 self._m_state_rows.labels(program="ragged_step").inc(
                     len(entries))
-                if self._use_kernel and chunk_kernel_serves(
-                        self.kv_cache["kda_state"]):
+                cache = self.kv_cache
+                if self._use_kernel and "kda_state" in cache \
+                        and chunk_kernel_serves(cache["kda_state"]):
                     self._m_chunk_kernel_steps.inc()
+                if self._use_kernel and "ssm_state" in cache \
+                        and state_space.chunk_kernel_serves(
+                            cache["ssm_state"],
+                            self.model.cfg.mamba_d_head):
+                    self._m_ssm_scan_kernel_steps.inc()
             log_tokens = sm.config.enable_prefix_caching
             for uid, toks in entries:
                 seq = sm.seqs[uid]
@@ -2033,24 +2066,33 @@ class InferenceEngineV2:
 
     def sequence_state(self, uid: int) -> Dict[str, np.ndarray]:
         """The recurrent state a tracked sequence holds in its slot, on
-        the host: ``kda_state`` ``[linear layers, heads, d_k, d_v]`` and
-        ``kda_conv`` ``[linear layers, taps - 1, 3 x heads x d_k]`` (an
-        input's channels in one row, however the leaf folds them), after
-        every token fed so far. The read half
-        of a snapshot (what preemption and handoff of such a model would
-        carry: ROADMAP M5)."""
+        the host, after every token fed so far: of linear-attention
+        layers ``kda_state`` ``[linear layers, heads, d_k, d_v]`` and
+        ``kda_conv`` ``[linear layers, taps - 1, 3 x heads x d_k]``; of
+        state-space layers ``ssm_state`` ``[state-space layers, heads,
+        d_head, d_state]`` and ``ssm_conv`` ``[state-space layers, taps
+        - 1, x | B | C channels]`` (an input's channels in one row and a
+        head's state by its own axes, however the leaves fold them).
+        The read half of a snapshot (what preemption and handoff of such
+        a model would carry: ROADMAP M5)."""
         if not self._has_state:
             raise ValueError("sequence_state: this model keeps no "
-                             "recurrent state (no linear-attention layer)")
+                             "recurrent state (no linear-attention or "
+                             "state-space layer)")
         sm = self.state_manager
         if not sm.known_seq(uid):
             raise KeyError(f"sequence_state: uid {uid} is not tracked")
         slot = sm.seqs[uid].state_slot
         state = {name: np.asarray(leaf[:, slot])
                  for name, leaf in self.kv_cache.items()
-                 if name.startswith("kda_")}
-        conv = state["kda_conv"]
-        state["kda_conv"] = conv.reshape(*conv.shape[:2], -1)
+                 if name in STATE_LEAVES}
+        for name in ("kda_conv", "ssm_conv"):
+            if name in state:
+                conv = state[name]
+                state[name] = conv.reshape(*conv.shape[:2], -1)
+        if "ssm_state" in state:
+            state["ssm_state"] = np.asarray(state_space.heads_of(
+                state["ssm_state"], self.model.cfg.mamba_n_heads))
         return state
 
     def sequence_kv(self, uid: int, kind: str = "window"

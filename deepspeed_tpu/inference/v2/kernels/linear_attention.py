@@ -27,9 +27,11 @@ form         kernel (a TPU, d_k and d_v  XLA (every other backend and
 ===========  ==========================  ===============================
 one token    :func:`kda_state_update`    gather, :func:`kda_step`, scatter
 chunked      :func:`kda_chunk_fwd`       :func:`kda_chunked`
-convolution  :func:`kda_conv_update`     gather, :func:`causal_conv_step`,
+convolution  :func:`conv_update`         gather, :func:`causal_conv_step`,
 at decode    (q, k and v each whole      scatter
-             (16, 128) tiles)
+             (16, 128) tiles; one part
+             of any rows, with a bias:
+             a state-space layer's)
 ===========  ==========================  ===============================
 
 :func:`state_kernel_serves` / :func:`chunk_kernel_serves` /
@@ -64,9 +66,10 @@ product of either is float32 (``Precision.HIGHEST``).
 depthwise convolution in front of q, k and v, over a row's own tokens,
 its last ``taps - 1`` inputs carried as state beside ``S`` in a leaf of
 its own, an input as rows of 128 lanes (:func:`conv_leaf_shape`).
-:func:`kda_conv_update` is the one-token form as one launch a layer: a
+:func:`conv_update` is the one-token form as one launch a layer: a
 grid step takes one row, its slot comes in and goes back once, the
-taps are fetched once. The ragged step (a row's prompt tokens) keeps
+taps (and a bias, which the Mamba-2 layers of ``state_space.py`` bring
+with their one input in place of three) are fetched once. The ragged step (a row's prompt tokens) keeps
 :func:`causal_conv_rows`.
 """
 
@@ -631,75 +634,97 @@ def conv_leaf_shape(layers, slots, taps, width):
     return (layers, slots, taps - 1, width // lanes, lanes)
 
 
-def conv_kernel_serves(leaf) -> bool:
-    """Whether :func:`kda_conv_update` takes this convolution leaf
-    ``[layers, slots, taps - 1, rows, lanes]`` (:func:`conv_leaf_shape`):
-    on a TPU, the channels whole lane blocks, each of q, k and v whole
+def conv_kernel_serves(leaf, parts=3) -> bool:
+    """Whether :func:`conv_update` takes this convolution leaf
+    ``[layers, slots, taps - 1, rows, lanes]`` (:func:`conv_leaf_shape`)
+    for an input that comes as ``parts`` arrays side by side (a linear
+    layer's q, k and v; one for a state-space layer's x, B and C): on a
+    TPU, the channels whole lane blocks, and each of several parts whole
     (16, 128) tiles (what projections in bfloat16 ask; float32 ones
-    half of it)."""
+    half of it; ONE part is the leaf's whole rows, whatever their
+    count)."""
     rows, lanes = leaf.shape[3:]
     return (jax.default_backend() == "tpu" and lanes == 128
-            and rows % (3 * 16) == 0)
+            and (parts == 1 or rows % (parts * 16) == 0))
 
 
-def _conv_kernel(layer_ref, slots_ref, fresh_ref, s_ref, w_ref, q_ref,
-                 k_ref, v_ref, so_ref, qo_ref, ko_ref, vo_ref):
+def _conv_kernel(layer_ref, slots_ref, fresh_ref, s_ref, w_ref, *refs,
+                 parts, bias):
     """One row's token through the convolution: ``s_ref`` [K - 1, rows,
-    128] the slot's last inputs, oldest first, q, k and v one after
+    128] the slot's last inputs, oldest first, the parts one after
     another along the rows; ``w_ref`` [K, rows, 128] the taps laid out
-    likewise; the token's projections [rows / 3, 128] each."""
+    likewise; ``refs``: the token's projections [rows / parts, 128]
+    each, the bias [rows, 128] where the convolution has one, then the
+    slot and the parts going out."""
     del layer_ref, slots_ref            # the index maps read them
+    ins, outs = refs[:parts], refs[parts + bias + 1:]
+    so_ref = refs[parts + bias]
     keep = fresh_ref[pl.program_id(0)] == 0
-    x = jnp.concatenate([r[...].astype(jnp.float32)
-                         for r in (q_ref, k_ref, v_ref)], axis=0)
+    x = jnp.concatenate([r[...].astype(jnp.float32) for r in ins], axis=0)
     seq = [jnp.where(keep, s_ref[t].astype(jnp.float32), 0.0)
            for t in range(s_ref.shape[0])] + [x]
     y = seq[0] * w_ref[0]
     for t in range(1, len(seq)):
         y = y + seq[t] * w_ref[t]
+    if bias:
+        y = y + refs[parts][...]
     y = jax.nn.silu(y)
     for t in range(s_ref.shape[0]):
         so_ref[t] = seq[t + 1].astype(so_ref.dtype)
-    part = q_ref.shape[0]
-    for i, o_ref in enumerate((qo_ref, ko_ref, vo_ref)):
+    part = ins[0].shape[0]
+    for i, o_ref in enumerate(outs):
         o_ref[...] = y[i * part:(i + 1) * part].astype(o_ref.dtype)
 
 
-def kda_conv_update(leaf, layer, slots, fresh, q, k, v, taps,
-                    interpret=False):
+def conv_update(leaf, layer, slots, fresh, parts, taps, bias=None,
+                name="kda_conv_update", interpret=False):
     """:func:`causal_conv_step` with SiLU on the rows' slots of the
     convolution leaf where it lies: ``leaf`` ``[layers, slots, K - 1,
     rows, 128]`` (:func:`conv_leaf_shape`) stays whole in HBM, and a
     grid step copies in row n's slot ``slots[n]`` at ``layer`` (both
-    prefetched scalars) and the row's new q, k and v projections [N, D]
-    (three operands, in their own type), and writes the slot's inputs
-    shifted by one with the token's own appended back to where they
-    came from (aliased) and the convolved, SiLU'd q, k and v in the
-    projections' type: a slot is read once and written once.
-    ``fresh[n]``: the row's first token, its inputs start from zeros.
-    ``taps`` [K, 3 D] (tap K - 1 meets the token itself), fetched once.
-    The sum runs in float32, oldest tap first. Returns ((q, k, v)
-    [N, D], leaf). A trace shows it as ``kda_conv_update``."""
-    N, D = q.shape
+    prefetched scalars) and the row's new projections ``parts`` (arrays
+    [N, D] side by side along the channels, in their own type), and
+    writes the slot's inputs shifted by one with the token's own
+    appended back to where they came from (aliased) and the convolved,
+    SiLU'd parts in the projections' type: a slot is read once and
+    written once. ``fresh[n]``: the row's first token, its inputs start
+    from zeros. ``taps`` [K, channels] (tap K - 1 meets the token
+    itself) and ``bias`` [channels] (None: none), fetched once. The sum
+    runs in float32, oldest tap first, then the bias. Returns (the
+    parts [N, D], leaf). A trace shows it as ``name``."""
+    N, D = parts[0].shape
     K1, rows, lanes = leaf.shape[2:]
-    part = pl.BlockSpec((None, rows // 3, lanes), lambda n, *_: (n, 0, 0))
+    n = len(parts)
+    part = pl.BlockSpec((None, rows // n, lanes), lambda r, *_: (r, 0, 0))
     state = pl.BlockSpec(
         (None, None, K1, rows, lanes),
-        lambda n, layer, slots, fresh: (layer[0], slots[n], 0, 0, 0))
-    weights = pl.BlockSpec((K1 + 1, rows, lanes), lambda n, *_: (0, 0, 0))
+        lambda r, layer, slots, fresh: (layer[0], slots[r], 0, 0, 0))
+    weights = pl.BlockSpec((K1 + 1, rows, lanes), lambda r, *_: (0, 0, 0))
+    offset = [] if bias is None else [
+        bias.astype(jnp.float32).reshape(rows, lanes)]
     leaf, *mixed = pl.pallas_call(
-        _conv_kernel,
+        functools.partial(_conv_kernel, parts=n, bias=len(offset)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3, grid=(N,),
-            in_specs=[state, weights, part, part, part],
-            out_specs=[state, part, part, part]),
+            in_specs=[state, weights] + [part] * n + [
+                pl.BlockSpec((rows, lanes), lambda r, *_: (0, 0))
+                for _ in offset],
+            out_specs=[state] + [part] * n),
         out_shape=[jax.ShapeDtypeStruct(leaf.shape, leaf.dtype)] + [
-            jax.ShapeDtypeStruct((N, rows // 3, lanes), a.dtype)
-            for a in (q, k, v)],
+            jax.ShapeDtypeStruct((N, rows // n, lanes), a.dtype)
+            for a in parts],
         input_output_aliases={3: 0},
-        name="kda_conv_update", interpret=interpret,
+        name=name, interpret=interpret,
     )(jnp.reshape(layer, (1,)).astype(jnp.int32), slots.astype(jnp.int32),
       fresh.astype(jnp.int32), leaf,
       taps.astype(jnp.float32).reshape(K1 + 1, rows, lanes),
-      *(a.reshape(N, rows // 3, lanes) for a in (q, k, v)))
+      *(a.reshape(N, rows // n, lanes) for a in parts), *offset)
     return tuple(a.reshape(N, D) for a in mixed), leaf
+
+
+def kda_conv_update(leaf, layer, slots, fresh, q, k, v, taps,
+                    interpret=False):
+    """:func:`conv_update` of a linear layer's q, k and v [N, D] under
+    ``taps`` [K, 3 D]: ``kda_conv_update`` in a trace."""
+    return conv_update(leaf, layer, slots, fresh, (q, k, v), taps,
+                       interpret=interpret)

@@ -115,8 +115,35 @@ def init_paged_kv_cache(cfg: TransformerConfig, num_blocks: int,
         return {**(leaves(kinds.count("full"), num_blocks, "_full")
                    if "full" in kinds else {}),
                 **(leaves(kinds.count("window"), window_blocks, "_window")
-                   if "window" in kinds else {})}
+                   if "window" in kinds else {}),
+                **_init_ssm_state(cfg, state_slots, state_dtype)}
     return leaves(cfg.num_layers, num_blocks)
+
+
+# the leaves that hold recurrent state, indexed by a sequence's slot
+STATE_LEAVES = ("kda_state", "kda_conv", "ssm_state", "ssm_conv")
+
+
+def _init_ssm_state(cfg, state_slots, state_dtype):
+    """The state-space (Mamba-2) layers' recurrent state, beside a
+    per-head pool for the first time: ``ssm_state`` ``[L_ssm, slots + 1,
+    channels / 128, d_state, 128]`` (a channel of a head a lane:
+    ``kernels/state_space.state_leaf_shape``) and ``ssm_conv``
+    ``[L_ssm, slots + 1, taps - 1, (channels + 2 d_state) / 128, 128]``
+    (the convolution's last inputs of x, B and C:
+    ``linear_attention.conv_leaf_shape``), both in ``state_dtype``,
+    slot 0 the null slot."""
+    n = cfg.layer_kinds.count("ssm")
+    if not n:
+        return {}
+    from .kernels.linear_attention import conv_leaf_shape
+    from .kernels.state_space import state_leaf_shape
+    return {"ssm_state": jnp.zeros(state_leaf_shape(
+                n, state_slots + 1, cfg.mamba_d_inner, cfg.mamba_d_state),
+                state_dtype),
+            "ssm_conv": jnp.zeros(conv_leaf_shape(
+                n, state_slots + 1, cfg.mamba_d_conv, cfg.mamba_conv_dim),
+                state_dtype)}
 
 
 def latent_pool_row(cfg) -> int:
@@ -377,8 +404,19 @@ def _moe_mlp(cfg, lp, x, topo=None):
     return out.reshape(orig_shape)
 
 
-# tokens of a launch an expert layer that holds a SHARE takes at a time
+# tokens of a launch an expert layer that holds a SHARE takes at a time:
+# 4,096 at 8 picks of 2,560 bf16 values a token (0.17 GB of sorted rows
+# a buffer), fewer in proportion where a token's picks hold more
 _SHARE_TOKENS = 4096
+_SHARE_PICKS_BYTES = 8 * 2560 * 2
+
+
+def _share_tokens(xt, k):
+    """Tokens a share's run takes, for rows ``xt`` [T, H] of k picks: a
+    power of two, ``_SHARE_TOKENS`` at most."""
+    fit = _SHARE_TOKENS * _SHARE_PICKS_BYTES \
+        // (k * xt.shape[1] * xt.dtype.itemsize)
+    return min(_SHARE_TOKENS, 1 << max(fit.bit_length() - 1, 0))
 
 
 def _held_from(cfg):
@@ -432,8 +470,8 @@ def _moe_routed(cfg, lp, xt, experts=None, stack_layer=None,
                 gmm_swiglu_experts if gmm_serves(experts) else None,
                 stack_layer=stack_layer, held_from=_held_from(cfg))
 
-        T = xt.shape[0]
-        if _held_from(cfg) is not None and T > _SHARE_TOKENS:
+        T, run = xt.shape[0], _share_tokens(xt, cfg.moe_top_k)
+        if _held_from(cfg) is not None and T > run:
             # a share's launch sorts and gathers EVERY pick's row and
             # computes the held ones (all of a token's picks may be
             # held, so no smaller buffer is safe): a launch of any
@@ -442,10 +480,10 @@ def _moe_routed(cfg, lp, xt, experts=None, stack_layer=None,
             # live (16,384 tokens x 8 picks x 2560 are 0.67 GB a
             # buffer). The last run is padded with rows of zeros, which
             # add nothing and are cut off
-            pad = -T % _SHARE_TOKENS
+            pad = -T % run
             out = jax.lax.map(lambda a: dispatch(*a), tuple(
                 jnp.pad(a, ((0, pad), (0, 0))).reshape(
-                    -1, _SHARE_TOKENS, a.shape[-1])
+                    -1, run, a.shape[-1])
                 for a in (xt, topi, topv))).reshape(T + pad, -1)[:T]
         else:
             out = dispatch(xt, topi, topv)
@@ -497,6 +535,8 @@ def _logits(cfg, params, x):
     out = (x @ head.astype(x.dtype)).astype(jnp.float32)
     if "lm_head_b" in params:
         out = out + params["lm_head_b"].astype(jnp.float32)
+    if cfg.logit_scale != 1.0:
+        out = out / cfg.logit_scale
     return out
 
 
@@ -744,13 +784,22 @@ def _per_head_attention_sublayer(cfg, lp, x, kind, l, pool, cos, sin,
     dt = lp["wq"].dtype
     hn = _norm(cfg, x, lp["attn_norm"]).astype(dt)
     with jax.named_scope("qkv_proj"):
-        q = (hn @ lp["wq"]).reshape(T, nh, hd)
+        if cfg.attn_scale:
+            # the kernels score q k^T over sqrt(hd): a model that
+            # scales its scores otherwise has the ratio in its queries,
+            # taken on the matmul's float32 sum before its one rounding
+            q = (jnp.dot(hn, lp["wq"], preferred_element_type=jnp.float32)
+                 * (cfg.attn_scale * hd ** 0.5)).astype(dt)
+        else:
+            q = hn @ lp["wq"]
+        q = q.reshape(T, nh, hd)
         k = (hn @ lp["wk"]).reshape(T, nkv, hd)
         v = (hn @ lp["wv"]).reshape(T, nkv, hd)
         if cfg.qk_norm:
             q = rms_norm(q, lp["q_norm"], cfg.norm_eps)
             k = rms_norm(k, lp["k_norm"], cfg.norm_eps)
-        if kind == "window" or not cfg.rope_sliding_only:
+        if cfg.positional == "rope" and (
+                kind == "window" or not cfg.rope_sliding_only):
             q = _rotate(q, cos[:, None, :], sin[:, None, :])
             k = _rotate(k, cos[:, None, :], sin[:, None, :])
     tag = "_" + kind
@@ -957,6 +1006,93 @@ def _linear_attention_sublayer(cfg, lp, x, l, cache, rows: _StateRows,
         return o.astype(dt).reshape(T, D) @ lp["wo"], cache
 
 
+def _state_space_sublayer(cfg, lp, x, l, cache, rows: _StateRows,
+                          use_kernel=True):
+    """A state-space (Mamba-2) mixer on flat tokens x [T, H]; ``l`` is
+    the layer's index among the state-space layers (its state leaves'
+    leading axis). One projection of the normed input gives [z | x B C |
+    dt] (scope ``ssm_proj``); x, B and C pass the short causal
+    convolution over the row's own tokens, its bias and SiLU (scope
+    ``ssm_conv``: a decode batch through the convolution's kernel on the
+    slot where it lies, ``ssm_conv_update`` in a trace, where
+    ``use_kernel`` and the widths allow, else gather,
+    ``causal_conv_step`` and scatter; every other launch through
+    ``causal_conv_rows``); ``dt = softplus(dt + dt_bias)`` and ``A =
+    -exp(a_log)`` a head; the recurrence runs in float32 from the row's
+    slot (zeros for a row at its first token) and its result goes back
+    to the slot: a decode batch through the one-token update (scope
+    ``ssm_state``: the kernel ``ssm_state_update`` or gather,
+    ``ssm_step`` and scatter), every other launch through the chunked
+    form, rows of any lengths (scope ``ssm_scan``: the kernel
+    ``ssm_chunk_fwd`` or the XLA ``ssm_chunked``):
+    ``kernels/state_space``; the skip term ``d_skip x``; the heads'
+    whole output times SiLU(z), RMS-normed (scope ``ssm_gate_norm``) and
+    projected (``ssm_out``). Returns (what the mixer adds to x, cache)."""
+    from ...ops.norms import rms_norm
+    from .kernels import linear_attention as la
+    from .kernels import state_space as ss
+    di, n = cfg.mamba_d_inner, cfg.mamba_d_state
+    dc, f32 = cfg.mamba_conv_dim, jnp.float32
+    dt_ = lp["w_in"].dtype
+    hn = _norm(cfg, x, lp["attn_norm"]).astype(dt_)
+    with jax.named_scope("ssm_proj"):
+        # a decode row's projections stay float32 from the matmul's sum
+        # to the recurrence, as a linear layer's do (PERF.md section 6,
+        # PR 45)
+        zxd = jnp.dot(hn, lp["w_in"], preferred_element_type=f32
+                      if rows.one_token else None)
+        z, xbc = zxd[:, :di], zxd[:, di:di + dc]
+        dt = jax.nn.softplus(zxd[:, di + dc:].astype(f32)
+                             + lp["dt_bias"].astype(f32))
+        a = -jnp.exp(lp["a_log"].astype(f32))
+    slots = rows.slots
+    with jax.named_scope("ssm_conv"):
+        leaf = cache["ssm_conv"]            # [L, slots, K - 1, dc / w, w]
+        bias = lp.get("conv_b")
+        if rows.one_token and use_kernel \
+                and la.conv_kernel_serves(leaf, parts=1):
+            (xbc,), leaf = la.conv_update(
+                leaf, l, slots, rows.fresh, (xbc,), lp["conv"], bias,
+                name="ssm_conv_update")
+        else:
+            def act(y):
+                return jax.nn.silu(y if bias is None
+                                   else y + bias.astype(f32))
+            held = leaf[l, slots].reshape(-1, leaf.shape[2], dc)
+            held = jnp.where(rows.fresh[:, None, None], 0, held)
+            xbc, held = la.causal_conv_step(xbc, lp["conv"], held, act) \
+                if rows.one_token else la.causal_conv_rows(
+                    xbc, lp["conv"], held, rows.row_ids, rows.starts,
+                    rows.counts, act)
+            leaf = leaf.at[l, slots].set(
+                held.reshape(-1, *leaf.shape[2:]))
+        cache = {**cache, "ssm_conv": leaf}
+    xs, b, c = xbc[:, :di], xbc[:, di:di + n], xbc[:, di + n:]
+    # the recurrence with its state's way out of the slot and back, one
+    # scope: what a roofline of it has to count
+    with jax.named_scope("ssm_state" if rows.one_token else "ssm_scan"):
+        leaf = cache["ssm_state"]
+        if rows.one_token:
+            step = ss.ssm_state_update if use_kernel \
+                and ss.state_kernel_serves(leaf) else ss.ssm_step
+            y, leaf = step(leaf, l, slots, rows.fresh, xs.astype(f32), dt,
+                           a, b.astype(f32), c.astype(f32))
+        else:
+            scan = ss.ssm_chunk_fwd if use_kernel \
+                and ss.chunk_kernel_serves(leaf, cfg.mamba_d_head) \
+                else ss.ssm_chunked
+            y, leaf = scan(leaf, l, slots, rows.fresh, rows.starts,
+                           rows.counts, xbc, dt, a)
+        cache = {**cache, "ssm_state": leaf}
+        y = y.astype(f32) + jnp.repeat(
+            lp["d_skip"].astype(f32), cfg.mamba_d_head) * xs.astype(f32)
+    with jax.named_scope("ssm_gate_norm"):
+        y = rms_norm(y * jax.nn.silu(z.astype(f32)), lp["gate_norm"],
+                     cfg.norm_eps)
+    with jax.named_scope("ssm_out"):
+        return y.astype(dt_) @ lp["w_out"], cache
+
+
 def _layer_runs(cfg):
     """The layers as maximal runs of one (mixer kind, MLP kind):
     [(kind, routed, first layer, layers)], ``kind`` one of
@@ -1039,6 +1175,14 @@ def _pattern_step(cfg: TransformerConfig, params, ids, row_ids, pos, lengths,
     kinds = cfg.layer_kinds
     pattern = cfg.pattern
     sandwich = cfg.norm_scheme == "sandwich"
+
+    def joined(x, a):
+        """The stream with what a sub-layer adds to it, times the
+        model's multiplier where it has one."""
+        a = a.astype(jnp.float32)
+        return x + (a if cfg.residual_scale == 1.0
+                    else a * cfg.residual_scale)
+
     expert_keys = ("e_gate", "e_up", "e_down")
     if window_tables is not None:
         # a window layer's write-set: the ring place of each new position
@@ -1073,7 +1217,12 @@ def _pattern_step(cfg: TransformerConfig, params, ids, row_ids, pos, lengths,
                 with jax.named_scope("linear_attention"):
                     a, pool = _linear_attention_sublayer(
                         cfg, lp, x, m0 + i, pool, rows, use_kernel)
-                    x = x + a.astype(jnp.float32)
+                    x = joined(x, a)
+            elif kind == "ssm":
+                with jax.named_scope("ssm_mixer"):
+                    a, pool = _state_space_sublayer(
+                        cfg, lp, x, m0 + i, pool, rows, use_kernel)
+                    x = joined(x, a)
             elif kind in ("window", "full"):
                 ring = kind == "window"
                 with jax.named_scope("attention"):
@@ -1083,14 +1232,14 @@ def _pattern_step(cfg: TransformerConfig, params, ids, row_ids, pos, lengths,
                         write_offsets,
                         window_tables if ring else block_tables, use_kernel,
                         one_token)
-                    x = x + a.astype(jnp.float32)
+                    x = joined(x, a)
             else:
                 with jax.named_scope("mla_attention"):
                     a, pool = _latent_attention_sublayer(
                         cfg, lp, x, m0 + i, pool, cos, sin, row_ids,
                         lengths, write_blocks, write_offsets, block_tables,
                         use_kernel, one_token)
-                    x = x + a.astype(jnp.float32)
+                    x = joined(x, a)
             with jax.named_scope("mlp"):
                 hn = _norm(cfg, x, lp["mlp_norm"])
                 if routed:
@@ -1110,7 +1259,7 @@ def _pattern_step(cfg: TransformerConfig, params, ids, row_ids, pos, lengths,
                 if sandwich:
                     out = _norm(cfg, out.astype(jnp.float32),
                                 lp["mlp_post_norm"])
-                x = x + out.astype(jnp.float32)
+                x = joined(x, out)
             return (x, pool, stats), None
 
         with jax.named_scope("layers"):

@@ -98,6 +98,11 @@ def export_sequence(engine, uid: int, trace_ctx=None) -> Dict:
     distributed trace — the trace id must cross the process boundary
     inside the handoff itself for remote replicas, not alongside it."""
     sm = engine.state_manager
+    if engine.model.cfg.has_state:
+        raise NotImplementedError(
+            "handoff moves a sequence's blocks and no state slot: a model "
+            "that keeps recurrent state a sequence (linear-attention or "
+            "state-space layers) is served without it")
     seq = sm.seqs.get(uid)
     if seq is None:
         raise ValueError(f"cannot export uid {uid}: unknown sequence")

@@ -35,8 +35,12 @@ from ..ops.norms import layer_norm, rms_norm
 from ..telemetry import registry as _registry
 
 
-# a source's word for a per-head layer -> the mixer kind it is served as
-PER_HEAD_KINDS = {"sliding_attention": "window", "full_attention": "full"}
+# a source's word for a layer -> the mixer kind it is served as: per-head
+# attention over a window or over every position ("attention": the
+# word of a source whose other layers are no attention at all), or a
+# Mamba-2 state-space mixer
+LAYER_TYPE_KINDS = {"sliding_attention": "window", "full_attention": "full",
+                    "attention": "full", "mamba": "ssm"}
 
 
 @dataclass(frozen=True)
@@ -51,7 +55,9 @@ class TransformerConfig:
     norm: str = "rmsnorm"                  # rmsnorm | layernorm
     norm_eps: float = 1e-5
     activation: str = "swiglu"     # swiglu | geglu | geglu_exact | gelu | relu
-    positional: str = "rope"               # rope | learned | alibi
+    # rope | learned | alibi | none ("none", served only, under
+    # ``layer_types``: no position signal on any layer)
+    positional: str = "rope"
     attn_bias: bool = False                # q/k/v/o projection biases (GPT-2/OPT)
     # Gemma-family knobs: q/o project to num_heads*head_dim != hidden
     # (Gemma-7B: 16x256 vs H=3072); embeddings scale by sqrt(H) at lookup
@@ -183,6 +189,35 @@ class TransformerConfig:
     attn_window: int = 0
     qk_norm: bool = False
     rope_sliding_only: bool = False
+    # a pattern of STATE-SPACE layers beside per-head attention (served
+    # only; the granitemoehybrid block): ``layer_types`` "mamba" is a
+    # Mamba-2 mixer (SSD, arXiv:2405.21060) and "attention" a full
+    # per-head layer. The mixer: one ``in_proj`` to [z | x B C | dt]
+    # (``mamba_n_heads * mamba_d_head`` | that + 2 ``mamba_d_state`` |
+    # ``mamba_n_heads``), a causal depthwise convolution of
+    # ``mamba_d_conv`` taps (with a bias where ``mamba_conv_bias``) and
+    # SiLU over x, B and C, a float32 state [mamba_d_head,
+    # mamba_d_state] a head with a SCALAR decay a head and token, B and
+    # C shared by all heads (``mamba_n_groups`` 1, the only value
+    # served), a gated RMS norm over the heads' whole output, and
+    # ``out_proj``. ``mamba_chunk_size`` is the source's tile of the
+    # chunked form: results do not depend on it but for rounding, and
+    # the programs tile by their own (kernels/state_space.py).
+    # The muP multipliers of the same block: ``attn_scale`` replaces
+    # head_dim^-1/2 on the scores (0: that default), ``residual_scale``
+    # multiplies what every sub-layer adds to the stream,
+    # ``logit_scale`` DIVIDES the logits (``embed_scale`` is above)
+    mamba_n_heads: int = 0
+    mamba_d_head: int = 0
+    mamba_d_state: int = 0
+    mamba_d_conv: int = 4
+    mamba_n_groups: int = 1
+    mamba_expand: int = 2
+    mamba_conv_bias: bool = True
+    mamba_chunk_size: int = 256
+    attn_scale: float = 0.0
+    residual_scale: float = 1.0
+    logit_scale: float = 1.0
     # the group limit of the deployed router (DeepSeek-V3 noaux_tc): the
     # experts form ``moe_n_group`` groups, a group scores the sum of its
     # best two, and the top k are chosen inside the best
@@ -277,24 +312,41 @@ class TransformerConfig:
             object.__setattr__(self, "layer_types",
                                tuple(self.layer_types))
             if (len(self.layer_types) != self.num_layers
-                    or set(self.layer_types) - set(PER_HEAD_KINDS)):
+                    or set(self.layer_types) - set(LAYER_TYPE_KINDS)):
                 raise ValueError(
                     f"layer_types names a mixer a layer ({self.num_layers}"
-                    f" of {sorted(PER_HEAD_KINDS)}), got "
+                    f" of {sorted(LAYER_TYPE_KINDS)}), got "
                     f"{self.layer_types!r}")
             if (self.attention != "mha" or self.linear_attn_period
-                    or self.positional != "rope" or self.norm != "rmsnorm"
+                    or self.positional not in ("rope", "none")
+                    or self.norm != "rmsnorm"
                     or self.attn_bias or not self.is_causal
                     or self.parallel_residual or not self.is_gated_mlp
                     or self.rotary_pct != 1.0):
                 raise NotImplementedError(
-                    "a pattern over per-head attention (layer_types) is "
-                    "the afmoe block: attention='mha', rope over the whole "
-                    "head, RMSNorm, causal, a gated MLP, no biases")
+                    "a layer_types pattern is served as attention='mha', "
+                    "rope over the whole head or no position signal "
+                    "(positional='none'), RMSNorm, causal, a gated MLP, no "
+                    "biases")
             if "sliding_attention" in self.layer_types \
                     and self.attn_window < 1:
                 raise ValueError("a sliding_attention layer needs "
                                  "attn_window > 0")
+            if "mamba" in self.layer_types and (
+                    min(self.mamba_n_heads, self.mamba_d_head,
+                        self.mamba_d_state) < 1 or self.mamba_d_conv < 2
+                    or self.mamba_n_groups != 1
+                    or self.mamba_n_heads * self.mamba_d_head
+                    != self.mamba_expand * self.hidden_size):
+                raise ValueError(
+                    f"a mamba layer needs mamba_n_heads x mamba_d_head = "
+                    f"mamba_expand x hidden_size, mamba_d_state > 0, "
+                    f"mamba_d_conv >= 2 and mamba_n_groups 1 (B and C "
+                    f"shared by all heads), got "
+                    f"{(self.mamba_n_heads, self.mamba_d_head)} against "
+                    f"{self.mamba_expand} x {self.hidden_size}, state "
+                    f"{self.mamba_d_state}, taps {self.mamba_d_conv}, "
+                    f"groups {self.mamba_n_groups}")
         elif self.attn_window or self.qk_norm or self.rope_sliding_only \
                 or self.norm_scheme == "sandwich" \
                 or self.attn_gate == "elementwise":
@@ -302,6 +354,14 @@ class TransformerConfig:
                 "attn_window, qk_norm, rope_sliding_only, norm_scheme="
                 "'sandwich' and attn_gate='elementwise' describe the "
                 "per-head block of a layer pattern: give layer_types")
+        if self.layer_types is None and (
+                self.positional == "none" or self.mamba_n_heads
+                or self.attn_scale or self.residual_scale != 1.0
+                or self.logit_scale != 1.0):
+            raise NotImplementedError(
+                "positional='none', the mamba_* sizes, attn_scale, "
+                "residual_scale and logit_scale describe the blocks of a "
+                "layer pattern: give layer_types")
         if self.linear_attn_period < 0 or (
                 self.linear_attn_period
                 and (self.attention != "mla" or self.linear_head_dim <= 0
@@ -379,6 +439,12 @@ class TransformerConfig:
             ("layer_types (window and full per-head layers, a cache leaf "
              "a kind, the leading dense stack)",
              self.layer_types is not None),
+            ("mamba layers (a Mamba-2 state-space mixer and its "
+             "recurrent state)", "ssm" in self.layer_kinds),
+            ("positional='none'", self.positional == "none"),
+            ("attn_scale", self.attn_scale != 0.0),
+            ("residual_scale", self.residual_scale != 1.0),
+            ("logit_scale", self.logit_scale != 1.0),
             ("qk_norm", self.qk_norm),
             ("rope_sliding_only", self.rope_sliding_only),
             ("norm_scheme='sandwich'", self.norm_scheme == "sandwich"),
@@ -412,9 +478,10 @@ class TransformerConfig:
     def layer_kinds(self) -> tuple:
         """The mixer of every layer, in order: "kda" (linear attention)
         or ``attention`` under ``linear_attn_period``; "window" or "full"
-        (per-head attention) from an explicit ``layer_types``."""
+        (per-head attention) or "ssm" (a Mamba-2 state-space mixer) from
+        an explicit ``layer_types``."""
         if self.layer_types is not None:
-            return tuple(PER_HEAD_KINDS[t] for t in self.layer_types)
+            return tuple(LAYER_TYPE_KINDS[t] for t in self.layer_types)
         p = self.linear_attn_period
         return tuple("kda" if p and (i + 1) % p else self.attention
                      for i in range(self.num_layers))
@@ -436,7 +503,18 @@ class TransformerConfig:
     @property
     def has_state(self) -> bool:
         """Whether a sequence owns recurrent state beside its blocks."""
-        return "kda" in self.layer_kinds
+        return bool({"kda", "ssm"} & set(self.layer_kinds))
+
+    @property
+    def mamba_d_inner(self) -> int:
+        """Channels of a Mamba-2 mixer's x, z and output."""
+        return self.mamba_n_heads * self.mamba_d_head
+
+    @property
+    def mamba_conv_dim(self) -> int:
+        """Channels the mixer's convolution runs over: x, B and C."""
+        return self.mamba_d_inner + 2 * self.mamba_n_groups \
+            * self.mamba_d_state
 
     @property
     def latent_row(self) -> int:
@@ -934,6 +1012,31 @@ class TransformerLM:
                 out["attn_post_norm"] = jnp.ones((n, h), dt)
             return out
 
+        def state_space(key, n):
+            """A Mamba-2 mixer's leaves: ``w_in`` to [z | x B C | dt],
+            the depthwise taps ``conv`` [taps, x | B | C] and their bias
+            ``conv_b``, ``dt_bias`` / ``a_log`` / ``d_skip`` a head
+            (float32 in the checkpoint), the gated norm's weight over
+            the heads' whole output, ``w_out``."""
+            ks = jax.random.split(key, 5)
+            di, dc, mh = cfg.mamba_d_inner, cfg.mamba_conv_dim, \
+                cfg.mamba_n_heads
+            step = jnp.exp(jax.random.uniform(
+                ks[3], (n, mh), dt, math.log(0.001), math.log(0.1)))
+            out = {"attn_norm": jnp.ones((n, h), dt),
+                   "w_in": init(ks[0], (n, h, di + dc + mh)),
+                   "conv": init(ks[1], (n, cfg.mamba_d_conv, dc),
+                                cfg.mamba_d_conv ** -0.5),
+                   "dt_bias": step + jnp.log(-jnp.expm1(-step)),
+                   "a_log": jnp.log(jax.random.uniform(
+                       ks[2], (n, mh), dt, 1.0, 16.0)),
+                   "d_skip": jnp.ones((n, mh), dt),
+                   "gate_norm": jnp.ones((n, di), dt),
+                   "w_out": init(ks[4], (n, di, h), out_std)}
+            if cfg.mamba_conv_bias:
+                out["conv_b"] = jnp.zeros((n, dc), dt)
+            return out
+
         def mlp_norms(n):
             return {"mlp_norm": jnp.ones((n, h), dt),
                     **({"mlp_post_norm": jnp.ones((n, h), dt)}
@@ -968,7 +1071,7 @@ class TransformerLM:
             # MLP of the leading dense and of the other layers
             kinds = cfg.layer_kinds
             mixers = {"kda": (linear, 7), "window": (per_head, 9),
-                      "full": (per_head, 10),
+                      "full": (per_head, 10), "ssm": (state_space, 11),
                       "mla": (functools.partial(attention, mlp_norm=False),
                               8)}
             for kind in dict.fromkeys(kinds):

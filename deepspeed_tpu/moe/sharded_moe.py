@@ -219,11 +219,29 @@ def ragged_swiglu_experts(expert_params, xs, group_sizes):
     return jax.lax.ragged_dot(jax.nn.silu(g) * u, wd, group_sizes)
 
 
-# the most an expert's [K, N] weight may hold for the grouped-matmul
-# kernel to take it as ONE tile (twice that is resident: the next
-# expert's arrives while this one is used)
+# the most of an expert's [K, N] weight the grouped-matmul kernel takes
+# as ONE tile (twice that is resident: the next arrives while this one
+# is used): the whole expert where it fits, else its K rows by the
+# largest whole fraction of its N columns that does (:func:`_gmm_columns`)
 _GMM_WHOLE_EXPERT_BYTES = 4 * 2 ** 20
 _GMM_ROWS = 128
+
+
+def _gmm_columns(w):
+    """Columns of an expert's [.., K, N] weight a tile of the grouped
+    matmul holds beside all K rows: N where the expert fits
+    ``_GMM_WHOLE_EXPERT_BYTES`` (every accepted width does), else N
+    over the least divisor that makes whole 128-lane blocks fit (4,096 x
+    768 in bf16 is 6.3 MB: two tiles of 384 columns; the kernel's grid
+    has the column tiles outermost, so an expert's tile still waits
+    over its consecutive row tiles and its weights stream once); None
+    where no such split exists."""
+    K, N = w.shape[-2:]
+    for d in range(1, N // 128 + 1):
+        if N % (128 * d) == 0 \
+                and K * (N // d) * w.dtype.itemsize <= _GMM_WHOLE_EXPERT_BYTES:
+            return N // d
+    return None
 
 
 def gmm_swiglu_experts(expert_params, xs, group_sizes):
@@ -246,7 +264,7 @@ def gmm_swiglu_experts(expert_params, xs, group_sizes):
 
     def mm(x, w):
         return gmm(x, w, group_sizes, preferred_element_type=x.dtype,
-                   tiling=(_GMM_ROWS, w.shape[1], w.shape[2]))
+                   tiling=(_GMM_ROWS, w.shape[1], _gmm_columns(w)))
 
     return mm(jax.nn.silu(mm(xs, wg)) * mm(xs, wu), wd)[:m]
 
@@ -254,12 +272,12 @@ def gmm_swiglu_experts(expert_params, xs, group_sizes):
 def gmm_serves(expert_params) -> bool:
     """Whether :func:`gmm_swiglu_experts` takes these experts: on a TPU,
     every weight's two widths whole 128-lane blocks, one expert's weight
-    small enough to be a tile."""
+    (or its rows by a whole fraction of its columns) small enough to be
+    a tile."""
     if jax.default_backend() != "tpu":
         return False
     return all(w.shape[-1] % 128 == 0 and w.shape[-2] % 128 == 0
-               and w.shape[-1] * w.shape[-2] * w.dtype.itemsize
-               <= _GMM_WHOLE_EXPERT_BYTES for w in expert_params)
+               and _gmm_columns(w) is not None for w in expert_params)
 
 
 def topk_routing(logits, k: int, scoring: str = "softmax", bias=None,

@@ -147,13 +147,19 @@ _SERVE_PHASE_OF_SCOPE = {
     # gate are ``linear``; the recurrence is a phase a form
     "linear_attention": "linear", "kda_proj": "linear",
     "kda_conv": "linear", "kda_out": "linear",
-    "kda_chunk": "linear_chunk", "kda_state": "linear_state"}
+    "kda_chunk": "linear_chunk", "kda_state": "linear_state",
+    # a state-space (Mamba-2) layer, likewise: its projections,
+    # convolution, gated norm are ``ssm``; the recurrence a phase a form
+    "ssm_mixer": "ssm", "ssm_proj": "ssm", "ssm_conv": "ssm",
+    "ssm_gate_norm": "ssm", "ssm_out": "ssm",
+    "ssm_scan": "ssm_scan", "ssm_state": "ssm_state"}
 _SERVE_SCOPE_WORD = re.compile(
     r"\b(" + "|".join(sorted(_SERVE_PHASE_OF_SCOPE, key=len, reverse=True))
     + r")\b")
 SERVE_PHASES = ("embed", "attn_proj", "kv_write", "attn_kernel", "mlp",
                 "router", "experts", "head", "pick", "linear",
-                "linear_chunk", "linear_state", "other")
+                "linear_chunk", "linear_state", "ssm", "ssm_scan",
+                "ssm_state", "other")
 
 
 def serve_phase(op_name: str) -> str:

@@ -321,6 +321,19 @@ PHASE_TABLE = [
      "other"),
     ("jit(decode_window_greedy)/while/body/jit(take_along_axis)/gather",
      "other"),
+    # a state-space (Mamba-2) layer: the recurrence a phase a form
+    ("jit(ragged_step)/layers/while/body/ssm_mixer/reduce_sum", "ssm"),
+    ("jit(ragged_step)/layers/while/body/ssm_mixer/ssm_proj/dot_general",
+     "ssm"),
+    ("jit(ragged_step)/layers/while/body/ssm_mixer/ssm_conv/scatter", "ssm"),
+    ("jit(ragged_step)/layers/while/body/ssm_mixer/ssm_scan/"
+     "jit(ssm_chunk_fwd)/pallas_call", "ssm_scan"),
+    ("jit(decode_window_greedy)/while/body/layers/while/body/ssm_mixer/"
+     "ssm_state/ssm_state_update/pallas_call", "ssm_state"),
+    ("jit(decode_window_greedy)/while/body/layers/while/body/ssm_mixer/"
+     "ssm_gate_norm/mul", "ssm"),
+    ("jit(decode_window_greedy)/while/body/layers/while/body/ssm_mixer/"
+     "ssm_out/dot_general", "ssm"),
     # a word inside another name is not the scope
     ("jit(ragged_step)/ragged_attention_tiled/heads/mul", "other"),
 ]
@@ -329,6 +342,47 @@ PHASE_TABLE = [
 @pytest.mark.parametrize("op_name,phase", PHASE_TABLE)
 def test_serve_phase(op_name, phase):
     assert serve_phase(op_name) == phase
+
+
+def test_the_state_space_scopes_open_in_both_programs():
+    """The toy state-space hybrid's programs carry every scope the
+    per-layer metrics read: ``ssm_mixer`` > {``ssm_proj``, ``ssm_conv``,
+    ``ssm_state`` (the decode window) or ``ssm_scan`` (the ragged step),
+    ``ssm_gate_norm``, ``ssm_out``}, beside the attention layer's."""
+    import json
+    from pathlib import Path
+    from benchmark import run as harness
+    from deepspeed_tpu.inference.v2.paged_model import (
+        init_paged_kv_cache, paged_decode_window, paged_ragged_step)
+    from deepspeed_tpu.models import TransformerLM
+    file = json.loads((Path(__file__).resolve().parents[3] / "benchmark"
+                       / "configs/granite-4.0-h-small.json").read_text())
+    cfg = TransformerConfig(**harness.merge(file["fields"],
+                                            file["toy_fields"]))
+    params = jax.eval_shape(TransformerLM(cfg).init_params,
+                            jax.random.PRNGKey(0))
+    cache = jax.eval_shape(lambda: init_paged_kv_cache(
+        cfg, 9, 16, jnp.float32, state_slots=2))
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
+    window = jax.jit(
+        lambda p, t, pos, bt, c, sl, eos, ss: paged_decode_window(
+            cfg, p, t, pos, bt, c, sl, eos, 16, 4, state_slots=ss)).lower(
+        params, i32(2), i32(2), i32(2, 4), cache, i32(2), i32(2),
+        i32(2)).as_text(debug_info=True)
+    step = jax.jit(
+        lambda p, ids, rows, pos, ln, wb, wo, bt, li, c, ss:
+        paged_ragged_step(cfg, p, ids, rows, pos, ln, wb, wo, bt, li, c, 16,
+                          state_slots=ss)).lower(
+        params, i32(16), i32(16), i32(16), i32(16), i32(16), i32(16),
+        i32(2, 4), i32(2), cache, i32(2)).as_text(debug_info=True)
+    shared = ("ssm_mixer/ssm_proj", "ssm_mixer/ssm_conv",
+              "ssm_mixer/ssm_gate_norm", "ssm_mixer/ssm_out",
+              "attention/attn_kernel", "mlp/moe_experts")
+    for text, own, other in ((window, "ssm_mixer/ssm_state", "ssm_scan"),
+                             (step, "ssm_mixer/ssm_scan", "ssm_state/")):
+        for scope in shared + (own,):
+            assert scope in text, scope
+        assert other not in text
 
 
 def _instructions(text):

@@ -1008,3 +1008,216 @@ def test_the_latent_decode_window_takes_the_one_token_form(tpu_sharding):
         and "bf16[64,32,640]" in line for line in lines), lines
     _no_relayout_of(text, 1601)
     assert compiled.memory_analysis().temp_size_in_bytes < 0.2e9
+
+
+# ---------------------------------------------------------------------------
+# state-space (Mamba-2) layers beside per-head attention:
+# granite-4.0-h-small.rollout-64x1024-256's geometry
+# ---------------------------------------------------------------------------
+def _granite_fields():
+    import json
+    from pathlib import Path
+    return json.loads((Path(__file__).resolve().parents[3] / "benchmark"
+                       / "configs/granite-4.0-h-small.json")
+                      .read_text())["fields"]
+
+
+def _custom_calls(compiled):
+    return re.findall(r"%([\w.\-]+) = [^\n]*tpu_custom_call",
+                      compiled.as_text())
+
+
+@pytest.mark.parametrize("kept", [jnp.float32, jnp.bfloat16])
+def test_the_ssm_state_kernel_at_published_widths(tpu_sharding, kept):
+    """``ssm_state_update`` at the cell's decode shape (64 rows, 128
+    heads of 64 as 64 lane blocks of channels, a state of 128, a leaf of
+    9 layers and 65 slots, float32 and the control's bfloat16): it
+    compiles for the chip, runs as ONE custom call under a name a trace
+    finds, and the leaf is aliased (no copy of 2.45 GB: what the program
+    keeps beside its arguments are B and C spread over the lanes and the
+    rows' decay, ``dt x`` and output, under 0.03 GB)."""
+    from deepspeed_tpu.inference.v2.kernels import state_space as ss
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=tpu_sharding)
+
+    N, nh, p, n = 64, 128, 64, 128
+    leaf = sds(ss.state_leaf_shape(9, 65, nh * p, n), kept)
+    assert leaf.shape == (9, 65, 64, 128, 128) \
+        and ss.state_kernel_serves(leaf)
+    compiled = jax.jit(ss.ssm_state_update, donate_argnums=(0,)).lower(
+        leaf, sds((), jnp.int32), sds((N,), jnp.int32), sds((N,), jnp.bool_),
+        sds((N, nh * p)), sds((N, nh)), sds((nh,)), sds((N, n)),
+        sds((N, n))).compile()
+    kernels = _custom_calls(compiled)
+    assert len(kernels) == 1 and kernels[0].startswith("ssm_state_update")
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.03e9
+
+
+def test_the_ssm_chunk_kernel_at_published_widths(tpu_sharding):
+    """``ssm_chunk_fwd`` for the cell's ragged launch (64 rows of 256
+    tokens, x, B and C the ONE bf16 buffer the convolution leaves, 8,448
+    wide): it compiles for the chip, runs as ONE custom call under a
+    name a trace finds, the leaf is aliased and no slice of the token
+    buffer is made (no temporary at all: under 0.05 GB)."""
+    from deepspeed_tpu.inference.v2.kernels import state_space as ss
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=tpu_sharding)
+
+    R, T, nh, p, n = 64, 64 * 256, 128, 64, 128
+    leaf = sds(ss.state_leaf_shape(9, 65, nh * p, n))
+    assert ss.chunk_kernel_serves(leaf, p)
+    assert not ss.chunk_kernel_serves(leaf, 48)
+    compiled = jax.jit(ss.ssm_chunk_fwd, donate_argnums=(0,)).lower(
+        leaf, sds((), jnp.int32), sds((R,), jnp.int32), sds((R,), jnp.bool_),
+        sds((R,), jnp.int32), sds((R,), jnp.int32),
+        sds((T, nh * p + 2 * n), jnp.bfloat16), sds((T, nh)),
+        sds((nh,))).compile()
+    kernels = _custom_calls(compiled)
+    assert len(kernels) == 1 and kernels[0].startswith("ssm_chunk_fwd")
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.05e9
+
+
+@pytest.mark.parametrize("proj", [jnp.float32, jnp.bfloat16])
+def test_the_conv_kernel_takes_a_state_space_layers_input(tpu_sharding,
+                                                          proj):
+    """``conv_update`` as the state-space layers call it: ONE input of
+    8,448 channels (66 lane blocks: not whole (16, 128) tiles, and no
+    part of it is cut), a bias, a leaf of 9 layers and 65 slots: ONE
+    custom call named ``ssm_conv_update``, the leaf aliased."""
+    from deepspeed_tpu.inference.v2.kernels import linear_attention as la
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=tpu_sharding)
+
+    N, D, K = 64, 8448, 4
+    leaf = sds(la.conv_leaf_shape(9, 65, K, D))
+    assert leaf.shape == (9, 65, 3, 66, 128)
+    assert la.conv_kernel_serves(leaf, parts=1)
+    assert not la.conv_kernel_serves(leaf)          # three parts of 22
+    compiled = jax.jit(
+        lambda leaf, layer, slots, fresh, x, taps, bias: la.conv_update(
+            leaf, layer, slots, fresh, (x,), taps, bias,
+            name="ssm_conv_update"), donate_argnums=(0,)).lower(
+        leaf, sds((), jnp.int32), sds((N,), jnp.int32), sds((N,), jnp.bool_),
+        sds((N, D), proj), sds((K, D), jnp.bfloat16),
+        sds((D,), jnp.bfloat16)).compile()
+    kernels = _custom_calls(compiled)
+    assert len(kernels) == 1 and kernels[0].startswith("ssm_conv_update")
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.02e9
+
+
+def test_an_expert_wider_than_a_tile_is_tiled_by_columns():
+    """An expert of 4,096 x 768 in bf16 is 6.3 MB, over the 4 MiB a tile
+    of the grouped matmul holds: it goes as two tiles of 384 columns
+    beside all 4,096 rows (``down``: 768 rows by 2,048 of its 4,096
+    columns), and every accepted width keeps its whole expert."""
+    from deepspeed_tpu.moe import sharded_moe as moe
+
+    def w(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+
+    assert moe._gmm_columns(w(360, 4096, 768)) == 384
+    assert moe._gmm_columns(w(360, 768, 4096)) == 2048
+    for K, N in ((2048, 768), (2560, 768), (2048, 1024)):
+        assert moe._gmm_columns(w(8, K, N)) == N
+        assert moe._gmm_columns(w(8, N, K)) == K
+    assert moe.gmm_serves((w(36, 4096, 768), w(36, 4096, 768),
+                           w(36, 768, 4096)))
+    assert not moe.gmm_serves((w(36, 4096, 100),))
+
+
+def _state_space_cut(tpu_sharding):
+    """The pattern at published widths, cut to a mamba layer, the
+    attention layer and a mamba layer (8 experts held, 4,096 rows of the
+    vocabulary): the configuration, and its parameters and cache (33
+    state slots, 129 blocks) as shapes on the chip."""
+    from deepspeed_tpu.inference.v2.paged_model import init_paged_kv_cache
+    from deepspeed_tpu.models import TransformerLM
+    from deepspeed_tpu.models.transformer import TransformerConfig
+
+    cfg = TransformerConfig(**{
+        **_granite_fields(), "num_layers": 3, "moe_experts_held": 8,
+        "layer_types": ["mamba", "attention", "mamba"], "vocab_size": 4096})
+
+    def on_tpu(x, dtype=None):
+        return jax.ShapeDtypeStruct(x.shape, dtype or x.dtype,
+                                    sharding=tpu_sharding)
+
+    params = jax.tree.map(
+        lambda x: on_tpu(x, jnp.bfloat16),
+        jax.eval_shape(TransformerLM(cfg).init_params,
+                       jax.random.PRNGKey(0)))
+    cache = jax.tree.map(on_tpu, jax.eval_shape(
+        lambda: init_paged_kv_cache(cfg, 129, 16, jnp.bfloat16,
+                                    state_slots=32)))
+    assert cache["ssm_state"].shape == (2, 33, 64, 128, 128) \
+        and cache["ssm_conv"].shape == (2, 33, 3, 66, 128) \
+        and cache["k_full"].shape == (1, 129, 16, 1024)
+    return cfg, params, cache
+
+
+def test_the_state_space_ragged_step_runs_its_scan_in_the_kernel(
+        tpu_sharding):
+    """The ragged step of the cut, 32 rows in 2,048 tokens: the chunk
+    kernel runs in both state-space runs, the tiled attention kernel in
+    the attention layer and the grouped matmul (an expert in two column
+    tiles) in every expert layer; nothing under ``ssm_scan`` loops in
+    XLA."""
+    from deepspeed_tpu.inference.v2.paged_model import paged_ragged_step
+
+    cfg, params, cache = _state_space_cut(tpu_sharding)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=tpu_sharding)
+
+    T, R = 2048, 32
+    compiled = jax.jit(
+        lambda p, ids, rows, pos, ln, wb, wo, bt, li, c, ss:
+        paged_ragged_step(cfg, p, ids, rows, pos, ln, wb, wo, bt, li, c, 16,
+                          use_kernel=True, state_slots=ss),
+        donate_argnums=(9,)).lower(
+        params, i32(T), i32(T), i32(T), i32(T), i32(T), i32(T), i32(R, 16),
+        i32(R), cache, i32(R)).compile()
+    kernels = _custom_calls(compiled)
+    assert sum(k.startswith("ssm_chunk_fwd") for k in kernels) == 2, kernels
+    assert sum(k.startswith("ragged_attention_tiled")
+               for k in kernels) == 1, kernels
+    assert sum(bool(GMM_PATTERN.search(k)) for k in kernels) == 9, kernels
+    assert "ssm_scan/while" not in compiled.as_text()
+
+
+def test_the_state_space_decode_window_compiles_with_its_state_in_place(
+        tpu_sharding):
+    """The decode window of the same cut: the state kernel and the
+    convolution's kernel run in both state-space runs, the tiled
+    attention kernel (its one-token form) and the grouped matmul beside
+    them; nothing under ``ssm_conv`` or ``ssm_state`` gathers or
+    scatters the slots in XLA, and the program's temporaries hold no
+    copy of the state leaf (32 rows x 2 layers: 0.27 GB)."""
+    from deepspeed_tpu.inference.v2.paged_model import paged_decode_window
+
+    cfg, params, cache = _state_space_cut(tpu_sharding)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=tpu_sharding)
+
+    R = 32
+    compiled = jax.jit(
+        lambda p, t, pos, bt, c, sl, eos, alive, ss: paged_decode_window(
+            cfg, p, t, pos, bt, c, sl, eos, 16, 8, use_kernel=True,
+            alive=alive, state_slots=ss), donate_argnums=(4,)).lower(
+        params, i32(R), i32(R), i32(R, 16), cache, i32(R), i32(R),
+        jax.ShapeDtypeStruct((R,), jnp.bool_, sharding=tpu_sharding),
+        i32(R)).compile()
+    text = compiled.as_text()
+    kernels = _custom_calls(compiled)
+    for name, count in (("ssm_state_update", 2), ("ssm_conv_update", 2),
+                        ("ragged_attention_tiled", 1)):
+        assert sum(k.startswith(name) for k in kernels) == count, kernels
+    assert sum(bool(GMM_PATTERN.search(k)) for k in kernels) == 9, kernels
+    under = re.findall(
+        r"= \S+ ([\w\-]+)\([^\n]*op_name=\"[^\"]*/ssm_(?:conv|state)/", text)
+    assert under and not {"gather", "scatter"} & set(under), under
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.15e9
