@@ -41,7 +41,8 @@ from .config_v2 import RaggedInferenceEngineConfig
 from .kernels import state_space
 from .kernels.linear_attention import (chunk_kernel_serves,
                                        conv_kernel_serves)
-from .kernels.ragged_attention import (LATENT, kernel_variant,
+from .kernels.ragged_attention import (LATENT, decode_positions,
+                                       kernel_variant,
                                        one_token_tile_serves)
 from .paged_model import (STATE_LEAVES, init_lora_bank, init_paged_kv_cache,
                           paged_continue, paged_decode, paged_decode_window,
@@ -702,6 +703,16 @@ class InferenceEngineV2:
             "one-token form: a row's pages against that row's own query "
             "rows (a fused window counts its steps; 0 off the TPU and "
             "where the decode programs run no tiled or latent kernel)")
+        self._m_decode_positions = reg.counter(
+            "inference_attention_decode_positions_total",
+            "cached positions under the one-token form's launches, by "
+            "kind: \"held\" (what the rows' pages hold, to whole pages; a "
+            "window layer's from its window's first page) and \"chunked\" "
+            "(what the walk's chunks hold whole); held / chunked is the "
+            "share of a chunk that is there. A position a layer, "
+            "row and step, from the contexts the manager holds at the "
+            "launch; 0 wherever inference_attention_one_token_steps_total "
+            "is", labelnames=("kind",))
         self._m_prefill_chunks = reg.counter(
             "inference_prefill_chunks_total",
             "ragged steps put() ran for a prompt set it fed in chunks (a "
@@ -1558,7 +1569,8 @@ class InferenceEngineV2:
             if self._has_state:
                 self._m_state_rows.labels(program="decode_step").inc(
                     len(uids))
-            self._note_kernel_steps(1)
+            self._note_kernel_steps(1, uids, [1] * len(uids),
+                                    tables.shape[1])
             self._m_decode_steps.inc()
             self._m_decode_tokens.inc(len(uids))
             self._m_decode_time.observe(dt)
@@ -1614,11 +1626,17 @@ class InferenceEngineV2:
                 lb, aid, ss, *wt),
             lambda v, i: int(v[i]))
 
-    def _note_kernel_steps(self, steps: int):
+    def _note_kernel_steps(self, steps: int, uids: List[int],
+                           steps_left: List[int], table_pages: int,
+                           in_flight: Optional[List[int]] = None):
         """``steps`` decode steps went to the device: a model with
         linear layers ran their convolution as the kernel, and the
         attention kernels took their one-token form, where the decode
-        programs' own tests say so."""
+        programs' own tests say so. Row i of ``uids`` takes
+        ``steps_left[i]`` of them from the position the manager holds
+        for it plus its ``in_flight`` writes (a window launched and not
+        collected), over a table of ``table_pages`` places: what the
+        positions under the attention launches are counted from."""
         if not self._use_kernel:
             return
         cache = self.kv_cache
@@ -1631,6 +1649,35 @@ class InferenceEngineV2:
         if one_token_tile_serves(cfg.attention == "mla", cfg.head_dim,
                                  cfg.kv_heads):
             self._m_one_token_steps.inc(steps)
+            self._note_decode_positions(uids, steps_left, table_pages,
+                                        in_flight)
+
+    def _note_decode_positions(self, uids, steps_left, table_pages,
+                               in_flight):
+        """The positions under the one-token form's launches of these
+        rows and steps, layer kind by layer kind
+        (``kernels/ragged_attention.decode_positions``)."""
+        sm, cfg = self.state_manager, self.model.cfg
+        start = np.asarray([sm.seqs[u].seen_tokens for u in uids], np.int64)
+        if in_flight is not None:
+            start = start + np.asarray(in_flight, np.int64)
+        step = np.arange(max(steps_left, default=0))[None, :]
+        # a row's bound at a step: the token it feeds, itself included
+        contexts = (start[:, None] + 1 + step)[
+            step < np.asarray(steps_left)[:, None]]
+        kinds = cfg.layer_kinds
+        rings = kinds.count("window")
+        held, chunked = np.asarray(decode_positions(
+            contexts, sm.block_size, table_pages, sm.config.num_blocks)) \
+            * sum(k not in ("window", "kda", "ssm") for k in kinds)
+        if rings:
+            ring = decode_positions(
+                contexts, sm.block_size, sm.ring_blocks,
+                sm.config.max_tracked_sequences * sm.ring_blocks + 1,
+                window=cfg.attn_window)
+            held, chunked = held + rings * ring[0], chunked + rings * ring[1]
+        self._m_decode_positions.labels(kind="held").inc(int(held))
+        self._m_decode_positions.labels(kind="chunked").inc(int(chunked))
 
     # -- fused multi-token decode window --------------------------------
     def _launch_window(self, uids: List[int], tokens: Optional[List[int]],
@@ -1693,7 +1740,9 @@ class InferenceEngineV2:
                     *self._window_tables(uids, N))
                 if behind is not None:
                     self._m_windows_ahead.inc()
-                self._note_kernel_steps(self.decode_window)
+                self._note_kernel_steps(
+                    self.decode_window, uids, steps_left, tables.shape[1],
+                    behind.steps_left if behind is not None else None)
                 win = _Window(
                     uids=list(uids), steps_left=list(steps_left),
                     fed=behind if behind is not None else tokens,

@@ -72,6 +72,16 @@ test_kernels_lower_tpu.py`` pins against the Mosaic compiler):
   on the same products: the token tile's output to the order of the
   float32 sums. The launch keeps its name. The latent kernel takes the
   same form over all ``nh`` heads.
+  A CHUNK'S COPIES (:func:`_walk_rows`) are started a page at a time,
+  two pages a trip of the loop, and waited for by their BYTES: one wait
+  a semaphore for a whole chunk, the powers of two in its pages for a
+  partial one (:func:`_chunk_waits`). And in the one-token form a chunk
+  meets ALL its lane blocks' query rows in one update
+  (:func:`_blocks_update`): the blocks' scores stacked, one mask, max,
+  exp and sum over them and the state read and written once, where a
+  block at a time was ``nblk`` chains of product, softmax and product
+  one behind the other, whose latency and not the matrix unit's intake
+  was what a chunk cost beside its bytes (PERF.md section 6, PR 50).
 * ``"pipelined"`` (grid ``(T, MB)``, BlockSpec-indexed): one step a
   token and table slot, the page a ``(1, 1, bs, kvh * hd)`` block of
   the same stored leaf by the index map ``(layer, bt[row, j])``, its
@@ -114,6 +124,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -136,6 +147,20 @@ _CHUNK_POSITIONS = 512
 
 # rows a grid step of the one-token form walks
 _ONE_TOKEN_ROWS = 16
+
+# pages whose copies one trip of the walk's start loop issues (a guarded
+# tail behind the whole trips); tuned on the chip: 2 read 6-10 % under 1 at
+# the latent and the 8k-context decode launches and the same where the
+# copies' bytes bound the launch, 4 read 1-6 % over 2 (PERF.md section 6,
+# PR 50)
+_START_UNROLL = 2
+
+
+def _chunk_pages(MB: int, nb: int, bs: int) -> int:
+    """Pages a chunk of the walk: ``_CHUNK_POSITIONS`` positions, and no
+    more than a row's table has places or the pool blocks (a chunk's
+    waiting descriptor names that many blocks of the pool)."""
+    return max(1, min(MB, nb, _CHUNK_POSITIONS // bs))
 
 
 def _sublane_tiles(rows: int) -> int:
@@ -305,6 +330,26 @@ def one_token_tile_serves(latent: bool, head_dim: int, kv_heads: int) -> bool:
         latent or tiled_geometry(head_dim, kv_heads) is not None)
 
 
+def decode_positions(contexts, bs: int, table_pages: int, pool_blocks: int,
+                     window: int = 0):
+    """``(held, chunked)`` of the one-token form's launches whose rows'
+    bounds are ``contexts`` (whole numbers, one a row and launch): the
+    positions their rows hold, to whole pages (with a ``window``, from
+    the window's first page), and the positions their chunks hold whole,
+    as :func:`_walk_rows` cuts them from a table of ``table_pages``
+    places over a pool of ``pool_blocks``. ``held / chunked`` is the
+    share of a chunk that is there: the copies bring ``held``, the
+    products run over ``chunked``. Host arithmetic on what the host
+    knows (numpy in, ints out): the engine's
+    ``inference_attention_decode_positions_total``."""
+    ctx = np.asarray(contexts, np.int64)
+    cp = _chunk_pages(table_pages, pool_blocks, bs)
+    pages = -(-ctx // bs)
+    if window:
+        pages = pages - np.maximum(ctx - window, 0) // bs
+    return (int(pages.sum()) * bs, int((-(-pages // cp)).sum()) * cp * bs)
+
+
 def _visible(tl_ref, t0, first, last, c, tq, reps, P, base=0, window=0):
     """``(reps * tq, P)``: which of chunk c's positions each query row
     of the tile may attend. Query rows are ``reps`` copies of the tile's
@@ -334,27 +379,60 @@ def _row_visible(bound, c, rows, P, base=0, window=0):
     return (pos < bound) & (pos >= bound - window)
 
 
+def _when(cond, fn):
+    """``fn()`` where ``cond`` holds: at once for a Python bool (the
+    tests' whole numbers), under ``pl.when`` for a traced scalar."""
+    if isinstance(cond, bool):
+        if cond:
+            fn()
+    else:
+        pl.when(cond)(fn)
+
+
+def _chunk_waits(n, cp, wait):
+    """Wait for the ``n`` pages (1..cp) whose copies a chunk started.
+    A DMA semaphore counts bytes, so ``wait(size)`` waits on ONE
+    descriptor of ``size`` pages (never started: its bytes are all it is
+    for): a whole chunk is one wait of ``cp`` pages, a partial one the
+    powers of two in ``n``, at most ``log2(cp)`` waits where a page's
+    copy used to be waited for by itself. The sizes add up to ``n``
+    exactly (a test holds that for every n), or the semaphore would be
+    left with bytes the next chunk of the slot reads as its own."""
+    def powers():
+        for i in range((cp - 1).bit_length()):
+            _when((n >> i) & 1 == 1, lambda i=i: wait(1 << i))
+    _when(n == cp, lambda: wait(cp))
+    _when(n < cp, powers)
+
+
 def _walk_rows(len_ref, bt_ref, first_ref, last_ref, lo, hi, *, t0, tq, bs,
-               cp, copies, compute, chunk_copies=None, window=0, ring=0,
-               begin=None, finish=None):
+               cp, copies, waits, compute, chunk_copies=None, window=0,
+               ring=0, begin=None, finish=None):
     """The walk the tiled and the latent kernel share: the tile of flat
     tokens [t0, t0 + tq) visits the rows ``lo..hi`` that own its tokens,
     a row's pages in chunks of ``cp`` up to the causal bound of the
     row's last token in the tile — no page past it is copied or
     visited. ``copies(page, slot, j)`` are the DMAs that bring pool page
-    ``page`` to place j of VMEM slot ``slot`` (``chunk_copies(r, c,
-    slot)``: those a whole chunk needs besides); the next chunk, of this
-    row or the next, is in flight while ``compute(first, last, c,
-    slot)`` runs on this one ([first, last]: the row's tokens in the
-    tile). ``begin(first)`` / ``finish(first)``, where given (the
-    one-token form), run before a row's first chunk is computed and
-    behind its last.
+    ``page`` to place j of VMEM slot ``slot``: a start a page, because a
+    page is where the table says. They are WAITED FOR BY THEIR BYTES:
+    ``waits(slot, size)`` are descriptors of ``size`` pages on the same
+    semaphores, one a semaphore, only ever waited on, and a chunk waits
+    on the few whose sizes add up to its pages (:func:`_chunk_waits`:
+    one for a whole chunk). ``chunk_copies(r, c, slot)`` are the copies
+    a whole chunk needs besides, started and waited for as they are.
+    The next chunk, of this row or the next, is in flight while
+    ``compute(first, last, c, slot, base)`` runs on this one ([first,
+    last]: the row's tokens in the tile; ``base``: the position the
+    row's chunk 0 starts at).
+    ``begin(first)`` / ``finish(first)``, where given (the one-token
+    form), run before a row's first chunk is computed and behind its
+    last.
 
     ``window`` > 0 (static): a token sees only its last ``window``
     positions, so a row's walk STARTS at the page that holds the first
     position its earliest token in the tile sees (no page wholly below
     that is copied or visited), chunk c is the ``cp`` pages from there,
-    and ``compute`` gets that page's first position as a fifth argument.
+    and ``base`` is that page's first position (0 without a window).
     The row's table is then a ring of ``ring`` places: the page of
     positions ``[b * bs, (b + 1) * bs)`` is ``bt_ref[r, b % ring]``
     (a table that holds every position is a ring that never wraps)."""
@@ -390,27 +468,45 @@ def _walk_rows(len_ref, bt_ref, first_ref, last_ref, lo, hi, *, t0, tq, bs,
             return jnp.minimum((kv + bs - 1) // bs - c * cp, cp)
         return jnp.minimum((kv + bs - 1) // bs - page0 - c * cp, cp)
 
-    def each_copy(r, c, slot, act):
-        """``act`` (start or wait) on every copy of chunk c of row r."""
-        page0 = bounds(r)[3] if window else 0
+    def start(r, c, slot):
+        """Start every copy of chunk c of row r: ``_START_UNROLL`` pages
+        a trip of the loop, the pages of a last, partial trip each under
+        its own guard."""
+        n = pages(r, c)
+        at0 = c * cp
+        if window:
+            # one remainder a chunk: a place is under ``ring`` again by
+            # one subtraction, since a chunk is no longer than the ring
+            at0 = (bounds(r)[3] + at0) % ring
 
-        def one(j, _):
-            at = c * cp + j
+        def one(j):
+            at = at0 + j
             if window:
-                at = (page0 + at) % ring
+                at = jnp.where(at >= ring, at - ring, at)
             for dma in copies(bt_ref[r, at], slot, j):
-                act(dma)
+                dma.start()
+
+        def trip(g, _):
+            for u in range(_START_UNROLL):                    # static
+                one(g * _START_UNROLL + u)
             return 0
-        jax.lax.fori_loop(0, pages(r, c), one, 0)
+        whole = n // _START_UNROLL
+        jax.lax.fori_loop(0, whole, trip, 0)
+        for u in range(_START_UNROLL - 1):                    # the tail
+            j = whole * _START_UNROLL + u
+            pl.when(j < n)(functools.partial(one, j))
         if chunk_copies is not None:
             for dma in chunk_copies(r, c, slot):
-                act(dma)
-
-    def start(r, c, slot):
-        each_copy(r, c, slot, lambda dma: dma.start())
+                dma.start()
 
     def wait(r, c, slot):
-        each_copy(r, c, slot, lambda dma: dma.wait())
+        def pages_of(size):
+            for dma in waits(slot, size):
+                dma.wait()
+        _chunk_waits(pages(r, c), cp, pages_of)
+        if chunk_copies is not None:
+            for dma in chunk_copies(r, c, slot):
+                dma.wait()
 
     r0 = next_row(lo)
 
@@ -432,10 +528,7 @@ def _walk_rows(len_ref, bt_ref, first_ref, last_ref, lo, hi, *, t0, tq, bs,
         first, last_tok, _, page0 = bounds(r)
         if begin is not None:
             pl.when(c == 0)(lambda: begin(first))
-        if window:
-            compute(first, last_tok, c, slot, page0 * bs)
-        else:
-            compute(first, last_tok, c, slot)
+        compute(first, last_tok, c, slot, page0 * bs)
         if finish is not None:
             pl.when(last)(lambda: finish(first))
         return nr, nc, 1 - slot
@@ -482,6 +575,41 @@ def _tile_update(q, k, v, visible, acc_sc, m_sc, l_sc, b, *, scale):
     m_sc[b] = jnp.broadcast_to(m_new, m_sc.shape[1:])
 
 
+def _blocks_update(q, k, v, visible, acc_sc, m_sc, l_sc, *, scale):
+    """:func:`_tile_update` of every lane block at once (the one-token
+    form): q a list of ``(M, bw)``, k / v lists of ``(P, bw)``, one a
+    lane block; the blocks' scores are stacked ``(nblk * M, P)`` so that
+    the mask, max, exp and sums run ONCE over all of them and the state
+    is read and written once, where a block at a time is ``nblk`` chains
+    of product, softmax and product one behind the other. The same
+    sums a row: a row's max and sum run over its own P scores and each
+    product is the block's own."""
+    nblk, M = len(q), q[0].shape[0]
+    rows = nblk * M
+
+    def flat(ref):
+        return ref[...].reshape(rows, ref.shape[-1])
+    s = jnp.concatenate([jax.lax.dot_general(
+        q[b], k[b], (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) for b in range(nblk)],
+        axis=0) * scale
+    s = jnp.where(visible, s, 2 * NEG_INF)
+    m_prev = flat(m_sc)[:, :1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    p = jnp.exp(s - m_new)
+    corr = jnp.exp(m_prev - m_new)
+    l_sc[...] = jnp.broadcast_to(
+        flat(l_sc)[:, :1] * corr + jnp.sum(p, axis=1, keepdims=True),
+        (rows, l_sc.shape[-1])).reshape(l_sc.shape)
+    p = p.astype(v[0].dtype)
+    pv = jnp.concatenate([jax.lax.dot_general(
+        p[b * M:(b + 1) * M], v[b], (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32) for b in range(nblk)], axis=0)
+    acc_sc[...] = (flat(acc_sc) * corr + pv).reshape(acc_sc.shape)
+    m_sc[...] = jnp.broadcast_to(
+        m_new, (rows, m_sc.shape[-1])).reshape(m_sc.shape)
+
+
 def _tiled_kernel(layer_ref, len_ref, bt_ref, first_ref, last_ref, lo_ref,
                   hi_ref, q_ref, tl_ref, k_hbm, v_hbm, *rest, quant, bs,
                   scale, kvh, hd, hpb, group, tq, cp, io_dtype, window=0,
@@ -496,7 +624,8 @@ def _tiled_kernel(layer_ref, len_ref, bt_ref, first_ref, last_ref, lo_ref,
     ``(L, nb, bs, F)``, the layer a prefetched scalar: a page is
     ``k_hbm.at[layer, page]``, ``bs`` rows of ``F = kvh * hd`` lanes,
     whole tiles where it lies; k_buf/v_buf are (2, cp, bs, F); sem is
-    (2, 2) = slot x {k, v}, one wait a page.
+    (2, 2) = slot x {k, v}, a start a page and a chunk's pages waited
+    for by their bytes (one wait a semaphore where the chunk is whole).
 
     ``one_token`` (static): every row has ONE token, and the tile is
     ``tq`` rows walked one after another. The queries lie token-major,
@@ -506,7 +635,8 @@ def _tiled_kernel(layer_ref, len_ref, bt_ref, first_ref, last_ref, lo_ref,
     float32 state is ONE row's, started at the row's first chunk and
     written to the row's place in the output tile ``(tq, nblk, group,
     bw)`` behind its last; a position is masked by the row's bound (and
-    window) alone."""
+    window) alone, and a chunk's lane blocks are updated all at once
+    (:func:`_blocks_update`)."""
     if quant:
         ks_hbm, vs_hbm, o_ref, k_buf, v_buf, ks_buf, vs_buf, acc_sc, m_sc, \
             l_sc, sem, ssem = rest
@@ -536,6 +666,15 @@ def _tiled_kernel(layer_ref, len_ref, bt_ref, first_ref, last_ref, lo_ref,
                 pltpu.make_async_copy(v_hbm.at[layer, page],
                                       v_buf.at[slot, j], sem.at[slot, 1]))
 
+    def waits(slot, size):
+        """``size`` pages' bytes on the slot's two semaphores (which
+        pages the descriptors name is of no account: they never start)"""
+        first = pl.ds(0, size)
+        return (pltpu.make_async_copy(k_hbm.at[layer, first],
+                                      k_buf.at[slot, first], sem.at[slot, 0]),
+                pltpu.make_async_copy(v_hbm.at[layer, first],
+                                      v_buf.at[slot, first], sem.at[slot, 1]))
+
     def scale_copies(r, c, slot):
         src = pl.ds(pl.multiple_of(
             (r * (bt_ref.shape[1] // cp) + c) * sw, sw), sw)
@@ -560,23 +699,33 @@ def _tiled_kernel(layer_ref, len_ref, bt_ref, first_ref, last_ref, lo_ref,
         return (x.astype(jnp.float32).reshape(P, bw)
                 * jnp.concatenate(rows, axis=0)).astype(io_dtype)
 
-    def compute(first, last, c, slot, base=0):
+    def block(slot, b):
+        """Lane block b of the slot's chunk: keys and values ``(P, bw)``"""
+        lanes = slice(b * bw, (b + 1) * bw)
+        k = k_buf[slot, :, :, lanes]
+        v = v_buf[slot, :, :, lanes]
+        if quant:
+            k = dequant(k, ks_buf, slot, b)
+            v = dequant(v, vs_buf, slot, b)
+        return k.reshape(P, bw), v.reshape(P, bw)
+
+    def compute(first, last, c, slot, base):
         if one_token:
-            visible = _row_visible(len_ref[first], c, rpb, P, base, window)
-        else:
-            visible = _visible(tl_ref, t0, first, last, c, tq, rpb, P, base,
-                               window)
+            # every lane block's update at once: one softmax over the
+            # stacked scores where there were nblk chains one behind the
+            # other
+            ks, vs = zip(*(block(slot, b) for b in range(nblk)))
+            _blocks_update(
+                [q_ref[first - t0, b] for b in range(nblk)], ks, vs,
+                _row_visible(len_ref[first], c, nblk * rpb, P, base, window),
+                acc_sc, m_sc, l_sc, scale=scale)
+            return
+        visible = _visible(tl_ref, t0, first, last, c, tq, rpb, P, base,
+                           window)
         for b in range(nblk):                                 # static
-            lanes = slice(b * bw, (b + 1) * bw)
-            k = k_buf[slot, :, :, lanes]
-            v = v_buf[slot, :, :, lanes]
-            if quant:
-                k = dequant(k, ks_buf, slot, b)
-                v = dequant(v, vs_buf, slot, b)
-            q = q_ref[first - t0, b] if one_token \
-                else q_ref[b].reshape(M, bw)
-            _tile_update(q, k.reshape(P, bw), v.reshape(P, bw), visible,
-                         acc_sc, m_sc, l_sc, b, scale=scale)
+            k, v = block(slot, b)
+            _tile_update(q_ref[b].reshape(M, bw), k, v, visible, acc_sc,
+                         m_sc, l_sc, b, scale=scale)
 
     def finish(first):
         """The walked row's output, a head's lanes from its own rows."""
@@ -591,7 +740,7 @@ def _tiled_kernel(layer_ref, len_ref, bt_ref, first_ref, last_ref, lo_ref,
             o_ref[first - t0, b] = out.astype(o_ref.dtype)
 
     _walk_rows(len_ref, bt_ref, first_ref, last_ref, lo, hi, t0=t0, tq=tq,
-               bs=bs, cp=cp, copies=copies, compute=compute,
+               bs=bs, cp=cp, copies=copies, waits=waits, compute=compute,
                chunk_copies=scale_copies if quant else None, window=window,
                ring=ring, **_row_hooks(one_token, finish, acc_sc, m_sc, l_sc))
     if one_token:
@@ -654,7 +803,7 @@ def _tiled_call(q, k_cache, v_cache, layer, row_ids, lengths, block_tables,
     tq = _ONE_TOKEN_ROWS if one_token else max(16, min(
         pow2_bucket(T0, 128), 1 << (max(512 // rpb, 1).bit_length() - 1)))
     T = -(-T0 // tq) * tq
-    cp = max(1, min(MB, _CHUNK_POSITIONS // bs))   # pages a chunk
+    cp = _chunk_pages(MB, k_cache.shape[1], bs)
     if MB % cp:
         block_tables = jnp.pad(block_tables, ((0, 0), (0, cp - MB % cp)))
         MB = block_tables.shape[1]
@@ -891,6 +1040,26 @@ def ragged_attention_reference(q, k_cache, v_cache, layer, row_ids, lengths,
 # ---------------------------------------------------------------------------
 # Latent (MLA) pool
 # ---------------------------------------------------------------------------
+def _latent_update(q, kv, visible, acc_sc, m_sc, l_sc, *, dc, scale):
+    """:func:`_tile_update` over a latent chunk: q ``(M, W)`` (all heads
+    of the tile's tokens, or the walked row's), kv ``(P, W)`` the keys
+    whole and, their first ``dc`` lanes, the values; the same float32
+    max, sum and accumulator and the same mask."""
+    s = jax.lax.dot_general(q, kv, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * scale
+    s = jnp.where(visible, s, 2 * NEG_INF)          # as _tile_update masks
+    m_prev = m_sc[:, :1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    p = jnp.exp(s - m_new)
+    corr = jnp.exp(m_prev - m_new)
+    l_sc[...] = jnp.broadcast_to(
+        l_sc[:, :1] * corr + jnp.sum(p, axis=1, keepdims=True), l_sc.shape)
+    acc_sc[...] = acc_sc[...] * corr + jax.lax.dot_general(
+        p.astype(kv.dtype), kv[:, :dc], (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    m_sc[...] = jnp.broadcast_to(m_new, m_sc.shape)
+
+
 def _latent_kernel(layer_ref, len_ref, bt_ref, first_ref, last_ref, lo_ref,
                    hi_ref, q_ref, tl_ref, pool_hbm, o_ref, buf, acc_sc,
                    m_sc, l_sc, sem, *, bs, scale, dc, tq, cp,
@@ -902,8 +1071,8 @@ def _latent_kernel(layer_ref, len_ref, bt_ref, first_ref, last_ref, lo_ref,
     latent's ``dc`` lanes, then its rotated part), go through the matrix
     unit together against ONE row a position: a chunk's ``(P, W)`` rows
     are the keys whole and, their first ``dc`` lanes, the values, so a
-    page is read once. buf is (2, cp, bs, W); sem is (2,), one wait a
-    page.
+    page is read once. buf is (2, cp, bs, W); sem is (2,), a start a
+    page and one wait a whole chunk (by its bytes).
 
     ``one_token`` (static): every row has ONE token and the tile is
     ``tq`` rows walked one after another (:func:`_tiled_kernel`): the
@@ -934,25 +1103,18 @@ def _latent_kernel(layer_ref, len_ref, bt_ref, first_ref, last_ref, lo_ref,
         return (pltpu.make_async_copy(pool_hbm.at[layer, page],
                                       buf.at[slot, j], sem.at[slot]),)
 
-    def compute(first, last, c, slot):
+    def waits(slot, size):
+        """``size`` pages' bytes on the slot's semaphore"""
+        first = pl.ds(0, size)
+        return (pltpu.make_async_copy(pool_hbm.at[layer, first],
+                                      buf.at[slot, first], sem.at[slot]),)
+
+    def compute(first, last, c, slot, base):
         visible = _row_visible(len_ref[first], c, nh, P) if one_token \
             else _visible(tl_ref, t0, first, last, c, tq, nh, P)
-        kv = buf[slot].reshape(P, W)
         q = q_ref[first - t0] if one_token else q_ref[...].reshape(M, W)
-        s = jax.lax.dot_general(q, kv, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        s = jnp.where(visible, s, 2 * NEG_INF)      # as _tile_update masks
-        m_prev = m_sc[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        l_sc[...] = jnp.broadcast_to(
-            l_sc[:, :1] * corr + jnp.sum(p, axis=1, keepdims=True),
-            l_sc.shape)
-        acc_sc[...] = acc_sc[...] * corr + jax.lax.dot_general(
-            p.astype(kv.dtype), kv[:, :dc], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_sc[...] = jnp.broadcast_to(m_new, m_sc.shape)
+        _latent_update(q, buf[slot].reshape(P, W), visible, acc_sc, m_sc,
+                       l_sc, dc=dc, scale=scale)
 
     def finish(first):
         l = l_sc[:, :1]
@@ -960,7 +1122,7 @@ def _latent_kernel(layer_ref, len_ref, bt_ref, first_ref, last_ref, lo_ref,
                              ).astype(o_ref.dtype)
 
     _walk_rows(len_ref, bt_ref, first_ref, last_ref, lo, hi, t0=t0, tq=tq,
-               bs=bs, cp=cp, copies=copies, compute=compute,
+               bs=bs, cp=cp, copies=copies, waits=waits, compute=compute,
                **_row_hooks(one_token, finish, acc_sc, m_sc, l_sc))
     if one_token:
         return
@@ -1021,7 +1183,7 @@ def latent_attention(q, pool, layer, row_ids, lengths, block_tables, *,
     tq = _ONE_TOKEN_ROWS if one_token else max(16, min(
         pow2_bucket(T0, 128), 1 << (max(512 // nh, 1).bit_length() - 1)))
     T = -(-T0 // tq) * tq
-    cp = max(1, min(MB, _CHUNK_POSITIONS // bs))   # pages a chunk
+    cp = _chunk_pages(MB, pool.shape[1], bs)
     if MB % cp:
         block_tables = jnp.pad(block_tables, ((0, 0), (0, cp - MB % cp)))
     if T != T0:

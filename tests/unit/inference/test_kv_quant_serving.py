@@ -135,6 +135,26 @@ def test_quant_ragged_pure_decode_matches_quant_decode_kernel(stored_pool):
 # ---------------------------------------------------------------------------
 # the stored layout against the per-head one it replaced
 # ---------------------------------------------------------------------------
+@pytest.mark.parametrize("against", ["reference", "parent"])
+def test_a_decode_launch_over_partial_chunks_of_an_int8_pool(against):
+    """PR 50 over an int8 pool (``walk_cases``' ``tiled-int8``: rows
+    whose last chunk holds 1, 2, 3, cp - 1 and cp pages, contexts that
+    end on a chunk): the pages are waited for by their bytes (an int8
+    page's), the scales' copies keep their own waits; against the gathering
+    reference's dequant at today's tolerance, and against the parent's
+    output on the same inputs to the bit."""
+    from tests.unit.inference import walk_cases
+    got = walk_cases.output("tiled-int8")
+    if against == "parent":
+        np.testing.assert_array_equal(
+            got, walk_cases.parent_output("tiled-int8"))
+        return
+    np.testing.assert_allclose(got, walk_cases.reference("tiled-int8"),
+                               rtol=0, atol=2e-5)
+    lens, _ = walk_cases.lengths("tiled-int8")
+    assert not got[lens == 0].any()
+
+
 def _per_head_write(kc, ksc, l, blocks, offs, k):
     """``paged_model._kv_write`` as it was over a ``[L, nb, bs, kvh,
     hd]`` pool (PR 36's tree), kept here as the semantics the stored

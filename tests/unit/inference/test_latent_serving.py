@@ -183,6 +183,26 @@ def test_the_one_token_form_of_the_latent_kernel():
     assert not one[:, np.asarray(lens) == 0].any()
 
 
+@pytest.mark.parametrize("against", ["reference", "parent"])
+def test_a_latent_decode_launch_over_partial_chunks(against):
+    """PR 50 over a latent pool (``walk_cases``' ``latent``: pages of 8,
+    64 a chunk; rows whose last chunk holds 1, 2, 3, 63 and 64 pages, in
+    their only chunk and in their second, contexts that end exactly on a
+    chunk): one wait a chunk by its bytes; against the gathering reference
+    at today's tolerance and against the parent's output on the same
+    inputs to the bit."""
+    from tests.unit.inference import walk_cases
+    got = walk_cases.output("latent")
+    if against == "parent":
+        np.testing.assert_array_equal(got,
+                                      walk_cases.parent_output("latent"))
+        return
+    np.testing.assert_allclose(got, walk_cases.reference("latent"),
+                               rtol=0, atol=2e-6)
+    lens, _ = walk_cases.lengths("latent")
+    assert not got[:, lens == 0].any()
+
+
 def test_the_pool_row_is_padded_to_whole_lane_blocks():
     cfg = TransformerConfig(**TOY)
     assert cfg.latent_row == 48 and latent_pool_row(cfg) == 128

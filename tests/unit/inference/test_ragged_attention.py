@@ -277,6 +277,118 @@ def test_the_one_token_form_is_the_token_tile_and_the_reference(geometry):
     assert not one[np.asarray(lens) == 0].any()
 
 
+# ---------------------------------------------------------------------------
+# PR 50: what a chunk costs beside its bytes. One wait a chunk (by bytes),
+# two starts a trip, and a decode launch's lane blocks updated at once
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("cp", [1, 2, 3, 12, 16, 32, 64])
+def test_a_chunks_waits_add_up_to_the_pages_it_started(cp):
+    """The wait decomposition itself (``_chunk_waits`` on whole
+    numbers): for every n in 1..cp the descriptors waited on hold
+    exactly n pages, as many bytes as the n copies that were started
+    put on the semaphore, ONE for a whole chunk and at most log2(cp)
+    for a partial one, each no larger than the chunk."""
+    from tests.unit.inference.walk_cases import ra
+    for n in range(1, cp + 1):
+        sizes = []
+        ra()._chunk_waits(n, cp, sizes.append)
+        assert sum(sizes) == n, (n, sizes)
+        assert len(sizes) <= max(1, (cp - 1).bit_length()), (n, sizes)
+        assert all(1 <= size <= cp for size in sizes)
+    sizes = []
+    ra()._chunk_waits(cp, cp, sizes.append)
+    assert sizes == [cp]
+
+
+WALK_CASES = ["tiled-hpb2", "tiled-group8"]
+
+
+@pytest.mark.parametrize("case", WALK_CASES)
+def test_a_decode_launch_over_partial_chunks_is_the_reference(case):
+    """Rows whose last chunk holds 1, 2, 3, cp - 1 and cp pages, in a
+    row's only chunk and in its second, contexts that end exactly on a
+    chunk (``walk_cases.contexts``), through the one-token form under
+    the TPU interpreter (which counts a wait in its descriptor's bytes,
+    as the chip does: a wrong decomposition hangs or reads a page too
+    early) against the gathering reference at today's tolerance."""
+    from tests.unit.inference import walk_cases
+    got, want = walk_cases.output(case), walk_cases.reference(case)
+    np.testing.assert_allclose(got, want, rtol=0, atol=2e-6)
+    lens, axis = walk_cases.lengths(case)
+    assert not np.take(got, np.flatnonzero(lens == 0), axis=axis).any()
+
+
+@pytest.mark.parametrize("case", WALK_CASES)
+def test_a_decode_launch_over_partial_chunks_is_the_parents(case):
+    """The same launches against what the PARENT of PR 50 returned for
+    the same inputs (a wait a page, the products over the whole chunk;
+    the committed fixture): a wait a page, a lane block at a time. The
+    same sums in the same order, so the outputs are equal to the bit
+    under the interpreter."""
+    from tests.unit.inference import walk_cases
+    np.testing.assert_array_equal(walk_cases.output(case),
+                                  walk_cases.parent_output(case))
+
+
+@pytest.mark.parametrize("case", WALK_CASES + ["tiled-int8", "window-ring"])
+def test_the_lane_blocks_at_once_are_the_blocks_one_by_one(case, monkeypatch):
+    """``_blocks_update`` (every lane block's scores stacked, one
+    softmax, the state read and written once) against the parent's
+    form kept as the test's own reference, ``_tile_update`` a lane
+    block at a time on the same chunk: a row's max and sum run over its
+    own scores and each product is its block's own either way, so the
+    outputs are equal to the bit."""
+    from tests.unit.inference import walk_cases
+    ra = walk_cases.ra()
+
+    def one_by_one(q, k, v, visible, acc_sc, m_sc, l_sc, *, scale):
+        M = q[0].shape[0]
+        for b in range(len(q)):
+            ra._tile_update(q[b], k[b], v[b], visible[:M], acc_sc, m_sc,
+                            l_sc, b, scale=scale)
+
+    monkeypatch.setattr(ra, "_blocks_update", one_by_one)
+    np.testing.assert_array_equal(walk_cases.launch(case),
+                                  walk_cases.output(case))
+
+
+@pytest.mark.parametrize("unroll", [1, 8])
+def test_the_start_loop_unrolled_starts_the_same_copies(unroll, monkeypatch):
+    """``_START_UNROLL`` pages a trip with a guarded tail (1, 2, 3, 31
+    and 32 pages: whole trips, a tail alone, both) brings the same
+    pages: a page a trip (the parent's loop) and eight a trip give the
+    two-a-trip launch's output to the bit."""
+    from tests.unit.inference import walk_cases
+    ra = walk_cases.ra()
+    monkeypatch.setattr(ra, "_START_UNROLL", unroll)
+    np.testing.assert_array_equal(walk_cases.launch("tiled-hpb2"),
+                                  walk_cases.output("tiled-hpb2"))
+
+
+@pytest.mark.parametrize("name,contexts,table,window,share", [
+    ("rollout-256", range(257, 512), 32, 0, (0.74, 0.78)),
+    ("latent, a table of 16", range(129, 257), 16, 0, (0.74, 0.80)),
+    ("latent, a table of 32", range(257, 384), 32, 0, (0.61, 0.65)),
+    ("trinity full", range(8193, 8704), 544, 0, (0.96, 0.98)),
+    ("trinity window", range(8193, 8704), 193, 2048, (0.80, 0.83)),
+])
+def test_decode_positions_held_and_chunked(name, contexts, table, window,
+                                           share):
+    """``decode_positions`` on the cells' decode contexts: held to whole
+    pages, chunked to whole chunks of the table the launch sees, held <=
+    chunked, and the share of a chunk that is there about what ISSUE 50
+    reckoned (0.75 on rollout-256; a window's 129 or 130 pages are four
+    chunks and a page or two: 0.81)."""
+    from tests.unit.inference.walk_cases import ra
+    ctx = np.asarray(list(contexts))
+    held, chunked = ra().decode_positions(ctx, 16, table, 10_000, window)
+    assert held % 16 == 0 and chunked % 16 == 0 and 0 < held <= chunked
+    if not window:
+        assert held == int((-(-ctx // 16) * 16).sum())
+    assert share[0] <= held / chunked <= share[1], held / chunked
+    assert ra().decode_positions([], 16, table, 10_000, window) == (0, 0)
+
+
 @pytest.mark.parametrize("pool", ["bf16", "int8"])
 @pytest.mark.parametrize("variant", ["tiled", "pipelined"])
 def test_a_launch_reads_its_own_layer_of_the_whole_pool(stored_pool, variant,
@@ -398,6 +510,41 @@ def test_put_parity_decode_only_and_interleaved(tiny):
     got = eng.put([1, 3, 2], [[50], c, [51, 52, 53]])
     _assert_put_is_dense(model, params, got,
                          [a + [50], c, b + [51, 52, 53]])
+
+
+@pytest.mark.parametrize("window", [1, 4], ids=["per-token", "fused"])
+def test_the_engine_counts_the_positions_under_the_decode_launches(
+        tiny, window, monkeypatch):
+    """``inference_attention_decode_positions_total`` {held, chunked}: 0
+    and 0 on the CPU (its decode programs run no kernel with a chunk),
+    and where the one-token form serves (asked of the engine here as the
+    chip would answer) the positions the rows' pages hold, a layer, row
+    and step, from the contexts the manager holds at a launch (a window
+    launched behind one in flight counts from that one's writes), held
+    <= chunked and both whole pages."""
+    from deepspeed_tpu.inference.v2 import engine_v2
+    from deepspeed_tpu.telemetry import get_registry
+    model, params = tiny
+    eng = _engine(model, params, window=window)
+    family = get_registry().get("inference_attention_decode_positions_total")
+    held, chunked = family.labels(kind="held"), family.labels(kind="chunked")
+    before = held.value, chunked.value
+    prompts = [list(range(3, 17)), [2, 4, 6], list(range(40, 62))]
+    eng.generate(prompts, max_new_tokens=9, temperature=0.0,
+                 eos_token_id=None)
+    assert (held.value, chunked.value) == before             # the CPU
+    monkeypatch.setattr(engine_v2, "one_token_tile_serves",
+                        lambda *a: True)
+    eng.generate(prompts, max_new_tokens=9, temperature=0.0,
+                 eos_token_id=None)       # 8 decode steps a row
+    # a row's bound at step s is its prompt + s + 1 (the fed token too)
+    want = model.cfg.num_layers * sum(
+        -(-(len(p) + s + 1) // 16) * 16 for p in prompts for s in range(8))
+    assert held.value - before[0] == want
+    grown = chunked.value - before[1]
+    assert grown >= want and grown % 16 == 0
+    # a table of one or two pages is one chunk: every row's is whole
+    assert grown <= model.cfg.num_layers * 3 * 8 * 32
 
 
 def test_generate_stream_parity_greedy_and_sampled(tiny):

@@ -201,6 +201,36 @@ def test_the_scheduler_keeps_to_a_rows_share_of_a_step():
         np.testing.assert_array_equal(row, np.asarray(want[uid]))
 
 
+def test_the_positions_counter_takes_a_window_layer_from_its_first_page(
+        monkeypatch):
+    """``inference_attention_decode_positions_total`` over a pattern: a
+    full layer's rows hold their whole context, a window layer's the
+    pages from its window's first (window 16 over blocks of 8: two or
+    three pages whatever the context), each kind over its own table;
+    held <= chunked, and nothing on the CPU until the engine is told the
+    one-token form serves."""
+    from deepspeed_tpu.inference.v2 import engine_v2
+    eng = _engine()
+    family = get_registry().get("inference_attention_decode_positions_total")
+    held, chunked = family.labels(kind="held"), family.labels(kind="chunked")
+    prompts = _prompts((50, 21))
+    before = held.value, chunked.value
+    eng.generate(prompts, max_new_tokens=5, temperature=0.0,
+                 eos_token_id=None)
+    assert (held.value, chunked.value) == before             # the CPU
+    monkeypatch.setattr(engine_v2, "one_token_tile_serves",
+                        lambda *a: True)
+    eng.generate(prompts, max_new_tokens=5, temperature=0.0,
+                 eos_token_id=None)       # 4 decode steps a row
+    kinds = eng.model.cfg.layer_kinds
+    bounds = [len(p) + s + 1 for p in prompts for s in range(4)]
+    full = sum(-(-n // 8) * 8 for n in bounds)
+    ring = sum((-(-n // 8) - max(n - WINDOW, 0) // 8) * 8 for n in bounds)
+    assert held.value - before[0] == \
+        kinds.count("full") * full + kinds.count("window") * ring
+    assert chunked.value - before[1] >= held.value - before[0]
+
+
 # ---------------------------------------------------------------------------
 # (b) the kernels with a window, against a dense masked softmax
 # ---------------------------------------------------------------------------
@@ -307,6 +337,27 @@ def test_the_one_token_form_over_a_ring_that_wraps():
     assert np.abs(one - want).max() <= F32_TIGHT * np.abs(want).max()
     assert np.abs(one - tile).max() <= F32_TIGHT * np.abs(want).max()
     assert not one[3].any()
+
+
+@pytest.mark.parametrize("against", ["reference", "parent"])
+def test_a_window_launch_over_partial_chunks_of_a_wrapped_ring(against):
+    """PR 50 over rings (``walk_cases``' ``window-ring``: rings of 40
+    pages under a window of 520, contexts inside the first lap whose
+    only chunk holds 1, 2, 3, 31 and 32 pages and contexts far round
+    the ring whose 33 or 34 pages are a whole chunk and a page or two):
+    a place is found by one remainder a chunk and a subtraction a page,
+    the chunk waited for by its bytes; against the gathering reference and against the parent's
+    output on the same inputs to the bit."""
+    from tests.unit.inference import walk_cases
+    got = walk_cases.output("window-ring")
+    if against == "parent":
+        np.testing.assert_array_equal(
+            got, walk_cases.parent_output("window-ring"))
+        return
+    want = walk_cases.reference("window-ring")
+    assert np.abs(got - want).max() <= F32_TIGHT * np.abs(want).max()
+    lens, _ = walk_cases.lengths("window-ring")
+    assert not got[lens == 0].any()
 
 
 def test_the_tiled_kernel_refuses_a_window_over_an_int8_pool():

@@ -921,6 +921,15 @@ ONE_TOKEN_LAUNCHES = {
     "opt-1.3b.rollout-256": (
         "tiled", 16, (24, 529, 16, 2048), 32, 0, "ragged_attention_tiled",
         "bf16[16,16,8,128]"),
+    # PR 50: the int8 pool's decode launch (the control of rollout-256: an
+    # int8 page's bytes are what its chunk waits for, the scales' copies
+    # keep their own waits) and the fifth cell's one attention layer
+    "opt-1.3b.rollout-256.int8": (
+        "tiled-int8", 16, (24, 529, 16, 2048), 32, 0,
+        "ragged_attention_tiled", "bf16[16,16,8,128]"),
+    "granite-4.0-h-small.rollout-64x1024-256": (
+        "tiled", 64, (1, 5185, 16, 1024), 80, 0, "ragged_attention_tiled",
+        "bf16[64,8,8,128]"),
 }
 
 
@@ -951,10 +960,15 @@ def test_the_decode_launches_lower_in_the_one_token_form(tpu_sharding, cell):
                 sds(pool, jnp.bfloat16)] + desc
     else:
         hd = 64 if pool[-1] == 2048 else 128
+        quant = kernel == "tiled-int8"
         fn = lambda *a: ragged_attention(       # noqa: E731
-            *a, window=window, one_token=True)
-        args = [sds((R, 32, hd), jnp.bfloat16), sds(pool, jnp.bfloat16),
-                sds(pool, jnp.bfloat16)] + desc
+            *a[:7], window=window, one_token=True,
+            **(dict(k_scale=a[7], v_scale=a[8]) if quant else {}))
+        kept = jnp.int8 if quant else jnp.bfloat16
+        args = [sds((R, 32, hd), jnp.bfloat16), sds(pool, kept),
+                sds(pool, kept)] + desc
+        if quant:
+            args += [sds((pool[1], pool[-1] // hd), jnp.float32)] * 2
     compiled = jax.jit(fn).lower(*args).compile()
     calls = [line for line in compiled.as_text().splitlines()
              if "tpu_custom_call" in line]
@@ -963,6 +977,48 @@ def test_the_decode_launches_lower_in_the_one_token_form(tpu_sharding, cell):
     assert queries in calls[0], calls[0][:400]
     layer = 2 * pool[1] * pool[2] * pool[3]
     assert compiled.memory_analysis().temp_size_in_bytes < layer / 4
+
+
+# PR 50: the prompt launches no test above holds (rollout-256's 4,096 tokens,
+# joyai's 8,192 and trinity's 16,384 with and without the window are
+# CELL_LAUNCHES, LATENT_LAUNCHES and WINDOW_LAUNCHES): (kernel, tokens, rows,
+# the pool as stored, the table's pages)
+PROMPT_LAUNCHES = {
+    "ling-3.0-flash.rollout-128x256": (
+        "latent", 16384, 128, (1, 3201, 16, 640), 8),
+    "granite-4.0-h-small.rollout-64x1024-256": (
+        "tiled", 16384, 64, (1, 5185, 16, 1024), 80),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(PROMPT_LAUNCHES))
+def test_the_prompt_launches_lower_with_a_wait_a_chunk(tpu_sharding, cell):
+    """The token tile takes PR 50's waits (the walk is one): a chunk's
+    pages waited for by their bytes on descriptors that are never
+    started, at the two cells' ragged steps of 16,384 tokens; Mosaic
+    takes them under the launches' names."""
+    from deepspeed_tpu.inference.v2.kernels.ragged_attention import \
+        latent_attention
+    kernel, T, R, pool, MB = PROMPT_LAUNCHES[cell]
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=tpu_sharding)
+
+    desc = [sds((), jnp.int32), sds((T,), jnp.int32), sds((T,), jnp.int32),
+            sds((R, MB), jnp.int32)]
+    if kernel == "latent":
+        fn = lambda *a: latent_attention(       # noqa: E731
+            *a, dc=512, scale=192 ** -0.5)
+        args = [sds((32, T, 640), jnp.bfloat16), sds(pool, jnp.bfloat16)]
+        pattern = LATENT_PATTERN
+    else:
+        fn = ragged_attention
+        args = [sds((T, 32, 128), jnp.bfloat16), sds(pool, jnp.bfloat16),
+                sds(pool, jnp.bfloat16)]
+        pattern = TRACE_PATTERN
+    text = jax.jit(fn).lower(*args, *desc).compile().as_text()
+    kernels = re.findall(r"%([\w.\-]+) = [^\n]*tpu_custom_call", text)
+    assert len(kernels) == 1 and pattern.search(kernels[0]), kernels
 
 
 def _no_relayout_of(text, *pools):
