@@ -801,6 +801,12 @@ class TransformerLM:
     # iteration (forward_hidden); everything else (embed/head/norm) stays
     # in HBM — it is touched outside the layer loop
     param_offload_keys = ("layers",)
+    # ZeRO-3 on the GSPMD path: the engine's gather-on-use for one layer's
+    # slice of params["layers"] (zero/partition.scanned_gather_on_use), or
+    # None. forward_hidden applies it inside the checkpointed scan body,
+    # the one place that does; a model that declares the attribute is one
+    # the engine may hand the function to
+    layer_param_gather = None
 
     @property
     def supports_param_offload(self) -> bool:
@@ -1382,6 +1388,18 @@ class TransformerLM:
                 lp = jax.tree.map(
                     lambda a: jax.device_put(a, jax.memory.Space.Device), lp)
                 return _inner(h, lp, cos, sin)
+
+        gather = self.layer_param_gather
+        if gather is not None:
+            # ZeRO-3 allgather-on-use, and its transpose the reduction of
+            # the layer's weight gradients into their shards. INSIDE the
+            # remat boundary for the same reason as the wrap above: what
+            # is saved for the backward is the shard, and the backward
+            # gathers again
+            inner = body
+
+            def body(h, lp, cos, sin, _inner=inner):
+                return _inner(h, gather(lp), cos, sin)
 
         if cfg.remat:
             from ..runtime.activation_checkpointing import checkpointing as ds_ckpt

@@ -386,6 +386,7 @@ class DeepSpeedTpuEngine:
             self._build_eval_step()
         else:
             self._build_train_step()
+        self._install_gather_on_use()
 
         # --- observability
         from ..utils.timer import ThroughputTimer
@@ -454,6 +455,15 @@ class DeepSpeedTpuEngine:
             "training_quant_error_feedback_norm",
             "global norm of the carried quantized-reduce error-feedback "
             "residuals after the last step")
+        reg.gauge(
+            "training_gather_on_use_leaves",
+            "leaves of one scanned layer that take ZeRO-3's gather-on-use "
+            "(0: nothing is sharded, or the step is not GSPMD's)"
+        ).set(self.gather_on_use_leaves)
+        reg.gauge(
+            "training_gather_on_use_layer_bytes",
+            "bytes one layer of those leaves holds once gathered",
+            unit="bytes").set(self.gather_on_use_layer_bytes)
         if self.grad_bucket_plan is not None:
             self._tm_bucket_bytes.set(self.grad_bucket_plan.max_bucket_bytes)
             if self.quant_reduce_state is not None:
@@ -601,6 +611,36 @@ class DeepSpeedTpuEngine:
             raise NotImplementedError(
                 "offload_param nvme composes with plain ZeRO-3 only "
                 "(no ZeRO++ / MiCS)")
+
+    def _install_gather_on_use(self):
+        """ZeRO-3's allgather-on-use, handed to a model that scans its
+        layers (zero/partition.scanned_gather_on_use says what it is and
+        which leaves take it: that is read from the plan's specs, there
+        is no option). Only the GSPMD path gets it: inside the manual
+        program's shard_map the dp axes are manual and a constraint over
+        them is an error, the 1-bit steps are manual too, and
+        offload_param wraps the scanned body itself. Assigned
+        unconditionally (the scan_unroll_hint rule), after the step
+        builders have resolved grad_overlap_mode and before anything
+        traces. The two counts are the engine's to show: a step that
+        silently stops engaging the function reads 0 here."""
+        fn, leaves, nbytes = None, 0, 0
+        handed = hasattr(self.model, "layer_param_gather")
+        if (handed and self.grad_overlap_mode == "off"
+                and not (self.onebit_mode or self.param_offload
+                         or self.param_offload_nvme)):
+            from .zero.partition import scanned_gather_on_use
+            fn, leaves, nbytes = scanned_gather_on_use(
+                self.zero_plan, self.params, "layers")
+        if handed:
+            self.model.layer_param_gather = fn
+        self.gather_on_use_leaves = leaves
+        self.gather_on_use_layer_bytes = nbytes
+        log_dist(
+            f"gather on use: {leaves} leaves a layer take it, "
+            f"{nbytes / 1e6:.1f} MB a layer gathered "
+            f"(zero stage {self.zero_stage}, grad overlap "
+            f"{self.grad_overlap_mode})", ranks=[0])
 
     def _host_param_sharding(self, param_sh):
         """Compute-param storage shardings with the model's offloadable

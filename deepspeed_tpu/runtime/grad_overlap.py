@@ -580,14 +580,18 @@ def overlap_blockers(engine, forced: bool) -> List[Tuple[str, str]]:
         if not engine.config.zero_optimization.overlap_comm:
             out.append((_SOFT, "overlap_comm is disabled"))
         if engine.zero_stage == 3:
-            # stage-3's dominant exchange is the param gathers, which the
-            # GSPMD path already hides almost completely (AOT dp8:
-            # param_gather_exposed_fraction 0.027 with 145 async chains);
-            # the manual program's explicit per-leaf gathers forfeit that
-            # scheduling and regress peak memory. Manual stage 3 stays
-            # opt-in ('bucketed') / ZeRO++-only.
-            out.append((_SOFT, "stage-3 gathers ride GSPMD's async "
-                               "collective fusion"))
+            # the manual program gathers every leaf before the model runs:
+            # for a scanned stack that is the WHOLE stack ahead of the
+            # scan (OPT-1.3B at dp 4, compiled for a v5e:2x2: all-gather
+            # bf16[96,2048,2048] ..., 13.0 GB a chip against the GSPMD
+            # step's 7.6; PERF.md section 6, PR 51), and the stacked
+            # gradients are reduce-scattered after it: ZeRO-3's memory
+            # given up and nothing left to overlap. The GSPMD step gathers
+            # a layer where the layer uses it (zero/partition.
+            # scanned_gather_on_use). Manual stage 3 stays opt-in
+            # ('bucketed') / ZeRO++-only.
+            out.append((_SOFT, "the manual program gathers the whole "
+                               "layer stack ahead of the scan"))
         for ax in ("model", "seq", "shard"):
             if topo.axis_size(ax) > 1:
                 out.append((_SOFT, f"'{ax}' mesh axis > 1"))

@@ -7,17 +7,32 @@ TPU-native re-design of the reference's ZeRO optimizers:
     (parameter partitioning with allgather-on-use and trace-based prefetch)
 
 The torch implementation is ~7,000 lines of hook machinery because eager
-execution forces manual gather/release/prefetch. Under XLA the same semantics
-are *sharding specs*: we assign each state tensor a `PartitionSpec` placing its
-ZeRO shard on the data-parallel mesh axes, and XLA's SPMD partitioner inserts
-exactly the collectives the reference issues by hand —
+execution forces manual gather/release/prefetch. Under XLA the placement of
+the STATE is a sharding spec: each state tensor gets a `PartitionSpec` that
+puts its ZeRO shard on the data-parallel mesh axes, and the SPMD partitioner
+inserts collectives that make the program correct —
 
   stage 1: optimizer state sharded  -> allgather of updated params after step
-  stage 2: + gradients sharded      -> reduce-scatter instead of all-reduce
-  stage 3: + parameters sharded     -> allgather-on-use in fwd/bwd (XLA's
+  stage 2: + gradients sharded      -> the gradients reduced into shards
+  stage 3: + parameters sharded     -> the weights gathered in fwd/bwd (XLA's
            latency-hiding scheduler overlaps these with compute, replacing the
            reference's __allgather_stream / prefetch coordinator,
            stage3.py:1151, partitioned_param_coordinator.py:256)
+
+Correct is not "the collectives the reference issues by hand". A spec says
+where a tensor lies, not where it is used, and to the partitioner a ZeRO
+shard is a tensor-parallel shard like any other: given a sharded weight as
+a matmul's operand it may move the ACTIVATIONS to the shards instead of
+the weight to the batch, whatever the bytes. It did so for the backward of
+OPT-1.3B's down projection at dp 4 (an all-gather of the cotangent over
+the global batch and an all-to-all back: 268 MB of activations a layer in
+place of a 33 MB weight; PERF.md section 6, PR 51), and it reduces the
+weight gradients as whole-leaf all-reduces sliced afterwards, not as
+reduce-scatters. So stage 3 STATES the reference's allgather-on-use and
+its reduce-scatter hook where a layer uses its weights:
+`scanned_gather_on_use` below, a function with its own transpose that the
+model applies inside its checkpointed layer body, so that no matmul of the
+body sees a sharded weight.
 
 Parameters smaller than `stage3_param_persistence_threshold` stay replicated,
 mirroring the reference's persistent-param optimization
@@ -92,6 +107,7 @@ class ZeroPlan:
     param_sharding: Any   # compute params (fwd/bwd)
     grad_sharding: Any    # accumulated gradients
     master_sharding: Any  # fp32 master weights + optimizer moments
+    base_sharding: Any    # the TP/EP placement alone: no ZeRO axis
 
     def shardings_for_opt_state(self, opt_state_template):
         """Optimizer moments mirror master-weight sharding, leaf-for-leaf."""
@@ -156,15 +172,89 @@ def build_zero_plan(topo: MeshTopology,
     opt_ns = ns(opt_specs)
 
     if stage <= 0:
-        return ZeroPlan(stage, base_ns, base_ns, base_ns)
+        return ZeroPlan(stage, base_ns, base_ns, base_ns, base_ns)
     if stage == 1:
         # grads replicated (all-reduced), optimizer state sharded
-        return ZeroPlan(stage, base_ns, base_ns, opt_ns)
+        return ZeroPlan(stage, base_ns, base_ns, opt_ns, base_ns)
     if stage == 2:
         # grads reduce-scattered into shards, params still gathered
-        return ZeroPlan(stage, base_ns, opt_ns, opt_ns)
+        return ZeroPlan(stage, base_ns, opt_ns, opt_ns, base_ns)
     # stage 3: params sharded too (modulo persistence threshold)
-    return ZeroPlan(stage, ns(param3_specs), opt_ns, opt_ns)
+    return ZeroPlan(stage, ns(param3_specs), opt_ns, opt_ns, base_ns)
+
+
+def _gather_leaf(use: NamedSharding, shard: NamedSharding):
+    """One leaf's gather-on-use: forward, the weight constrained to the
+    placement its layer computes with; backward, its cotangent to the
+    leaf's gradient shard. The scopes are the two collective phases
+    ``utils/xla_profile.scope_phase`` already has."""
+
+    @jax.custom_vjp
+    def gather(w):
+        with jax.named_scope("param_gather"):
+            return jax.lax.with_sharding_constraint(w, use)
+
+    def fwd(w):
+        return gather(w), None
+
+    def bwd(_, dw):
+        with jax.named_scope("grad_reduce"):
+            return (jax.lax.with_sharding_constraint(dw, shard),)
+
+    gather.defvjp(fwd, bwd)
+    return gather
+
+
+def scanned_gather_on_use(plan: ZeroPlan, params, key: str):
+    """Stage 3's allgather-on-use for a stack of layers that a model
+    scans: ``(fn, leaves, bytes)``. ``params`` is the compute tree
+    (arrays or shapes) and ``fn`` maps ONE layer's slice of
+    ``params[key]`` (the scan's ``xs``) to the same tree with every leaf
+    that ZeRO sharded passed through `_gather_leaf`: gathered to its base
+    spec (the plan's spec less the ZeRO axes: replicated on a pure-dp
+    mesh, the TP / EP placement kept where there is one) where the layer
+    uses it, its cotangent constrained to the leaf's gradient spec. It
+    belongs INSIDE the remat boundary of the scanned body: outside it the
+    gathered weights are the checkpoint's saved inputs. Whether a leaf
+    takes it is read from its own specs: one whose compute spec IS its
+    base spec (stages 0-2, a gather world of 1, a leaf under the
+    persistence threshold) is left as it is, and when none takes it
+    ``fn`` is None. ``leaves`` and ``bytes`` count the leaves that do and
+    what one layer of them holds gathered, over the whole mesh's view (the
+    base spec's own shards are not divided out)."""
+    if not (isinstance(plan.param_sharding, dict)
+            and key in plan.param_sharding):
+        return None, 0, 0
+
+    def one_layer(sh):
+        # the scan slices the stack's leading dimension away
+        return NamedSharding(sh.mesh, P(*tuple(sh.spec)[1:]))
+
+    def entries(sh, ndim):
+        spec = tuple(sh.spec)
+        return spec + (None,) * (ndim - len(spec))
+
+    stack, treedef = jax.tree.flatten(params[key])
+    fns, nbytes = [], 0
+    for a, param, base, grad in zip(
+            stack, *(treedef.flatten_up_to(sh[key]) for sh in (
+                plan.param_sharding, plan.base_sharding,
+                plan.grad_sharding))):
+        if entries(param, a.ndim) == entries(base, a.ndim):
+            fns.append(None)
+            continue
+        fns.append(_gather_leaf(one_layer(base), one_layer(grad)))
+        nbytes += _numel(a.shape[1:]) * np.dtype(a.dtype).itemsize
+    leaves = len(fns) - fns.count(None)
+    if not leaves:
+        return None, 0, 0
+
+    def gather(lp):
+        return treedef.unflatten(
+            [w if f is None else f(w)
+             for f, w in zip(fns, treedef.flatten_up_to(lp))])
+
+    return gather, leaves, nbytes
 
 
 def estimate_zero_memory(param_count: int, stage: int, dp: int,

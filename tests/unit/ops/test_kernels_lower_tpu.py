@@ -1277,3 +1277,93 @@ def test_the_state_space_decode_window_compiles_with_its_state_in_place(
         r"= \S+ ([\w\-]+)\([^\n]*op_name=\"[^\"]*/ssm_(?:conv|state)/", text)
     assert under and not {"gather", "scatter"} & set(under), under
     assert compiled.memory_analysis().temp_size_in_bytes < 0.15e9
+
+
+# ---------------------------------------------------------------------------
+# ZeRO-3 at dp 4: what the scanned backward moves between chips
+# ---------------------------------------------------------------------------
+ZERO3_MICRO, ZERO3_DP, ZERO3_HIDDEN, ZERO3_FFN = 2, 4, 256, 1024
+_COLLECTIVE = re.compile(
+    r"= (\w+)\[([\d,]*)\]\S* (all-to-all|all-gather)(?:-start)?\("
+    r"[^\n]*op_name=\"([^\"]*)\"")
+
+
+@pytest.fixture(scope="module")
+def zero3_backward(tpu_sharding):
+    """The benchmark's four-chip cell at toy widths (OPT's norm,
+    activation and biases; hidden 256, ffn 1024, 4 layers, micro 2 x
+    seq 256), its step compiled for the described ``v5e:2x2`` twice: as
+    the engine builds it, and with the gather-on-use withheld from the
+    model. Each: the collectives under the layers' backward as (kind,
+    dims), and what the engine counted."""
+    import json
+    from pathlib import Path
+    from deepspeed_tpu.accelerator.tpu_accelerator import (
+        COLLECTIVE_OVERLAP_COMPILER_OPTIONS)
+    from deepspeed_tpu.benchmarks.aot_scale import build_abstract_engine
+    from deepspeed_tpu.models.transformer import TransformerConfig
+    from deepspeed_tpu.runtime.activation_checkpointing import checkpointing
+
+    bench = Path(__file__).resolve().parents[3] / "benchmark"
+    fields = json.loads((bench / "configs/opt-1.3b.json").read_text())["fields"]
+    ds = json.loads((bench / "workloads/opt-1.3b.zero3-dp4.json")
+                    .read_text())["deepspeed"]
+    ds["train_micro_batch_size_per_gpu"] = ZERO3_MICRO
+    cfg = TransformerConfig(**{
+        **fields, "hidden_size": ZERO3_HIDDEN, "num_heads": 4,
+        "intermediate_size": ZERO3_FFN, "num_layers": 4, "max_seq_len": 256,
+        "vocab_size": 2048})
+    out = {}
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        try:
+            for case in ("engaged", "withheld"):
+                engine, batch = build_abstract_engine(
+                    cfg, ds, topology_name="v5e:2x2")
+                counted = (engine.gather_on_use_leaves,
+                           engine.gather_on_use_layer_bytes)
+                if case == "withheld":
+                    engine.model.layer_param_gather = None
+                text = engine.lower_train_step(
+                    batch,
+                    compiler_options=COLLECTIVE_OVERLAP_COMPILER_OPTIONS
+                ).as_text()
+                moved = [(kind, tuple(int(d) for d in dims.split(",")))
+                         for _dt, dims, kind, op in _COLLECTIVE.findall(text)
+                         if "transpose(jvp(layers))" in op]
+                out[case] = {"moved": moved, "counted": counted}
+        finally:
+            checkpointing.reset()
+    return out
+
+
+def _leads_with_the_global_batch(moved):
+    return [dims for kind, dims in moved if kind == "all-gather"
+            and dims[0] == ZERO3_MICRO * ZERO3_DP and len(dims) == 3]
+
+
+@pytest.mark.parametrize("case", ["engaged", "withheld"])
+def test_zero3_backward_gathers_the_weight_not_the_batch(zero3_backward,
+                                                         case):
+    """With the function, no matmul of the layers' backward sees a
+    sharded weight: no ``all-to-all``, no ``all-gather`` of an activation
+    over the GLOBAL batch, and ``w_down`` is gathered whole (``[ffn,
+    hidden]``). Withheld, the partitioner runs the down projection's
+    backward tensor-parallel over the ZeRO shards: the cotangent gathered
+    over the global batch and an all-to-all back, which is what makes
+    the first case mean something on the compiler that runs it."""
+    got = zero3_backward[case]
+    moved = got["moved"]
+    assert got["counted"] == (16, 2 * (4 * ZERO3_HIDDEN ** 2
+                                       + 2 * ZERO3_HIDDEN * ZERO3_FFN
+                                       + 9 * ZERO3_HIDDEN + ZERO3_FFN))
+    kinds = {kind for kind, _ in moved}
+    w_down = ("all-gather", (ZERO3_FFN, ZERO3_HIDDEN))
+    if case == "engaged":
+        assert "all-to-all" not in kinds, moved
+        assert not _leads_with_the_global_batch(moved), moved
+        assert w_down in moved, moved
+    else:
+        assert sum(kind == "all-to-all" for kind, _ in moved) == 2, moved
+        assert _leads_with_the_global_batch(moved), moved
+        assert w_down not in moved, moved
