@@ -509,6 +509,8 @@ class InferenceEngineV2:
             self._m_state_bytes.set(ds_memory.tree_bytes(
                 {k: v for k, v in self.kv_cache.items()
                  if k in STATE_LEAVES}))
+            self._m_ssm_groups.set(
+                cfg.mamba_n_groups if "ssm" in cfg.layer_kinds else 0)
             for kind in ("full", "window"):
                 self._m_pool_bytes.labels(kind=kind).set(
                     ds_memory.tree_bytes({
@@ -608,6 +610,9 @@ class InferenceEngineV2:
             return
         launches, rows, touched, share = (float(v) for v in stats)
         self._m_moe_launches.labels(program=program).inc(launches)
+        self._m_moe_form_launches.labels(
+            program=program, form=self.model.cfg.moe_expert_form).inc(
+            launches)
         self._m_moe_rows.labels(program=program).inc(rows)
         self._m_moe_touched.labels(program=program).inc(touched)
         self._m_moe_share.labels(program=program).set(share)
@@ -620,6 +625,11 @@ class InferenceEngineV2:
             "expert-layer passes run by the latent block's programs (a "
             "ragged step: one an expert layer; a decode window: one an "
             "expert layer and step)", labelnames=("program",))
+        self._m_moe_form_launches = reg.counter(
+            "moe_form_launches_total",
+            "moe_launches_total by the form of expert the pass ran: "
+            "swiglu (three matrices) or relu2 (two, a squared ReLU)",
+            labelnames=("program", "form"))
         self._m_moe_rows = reg.counter(
             "moe_routed_rows_total",
             "rows routed to experts (valid tokens x top-k), summed over "
@@ -691,6 +701,11 @@ class InferenceEngineV2:
             "one-token update as the kernel ssm_state_update (a fused "
             "window counts its steps; 0 for a model without such layers, "
             "and where the backend or the widths leave it to the XLA form)")
+        self._m_ssm_groups = reg.gauge(
+            "inference_ssm_groups",
+            "groups of B and C a token the state-space layers' programs "
+            "were built for (mamba_n_groups; 0 for a model without such "
+            "layers)")
         self._m_ssm_scan_kernel_steps = reg.counter(
             "inference_ssm_scan_kernel_steps_total",
             "ragged steps launched whose state-space layers ran their "
@@ -1642,10 +1657,10 @@ class InferenceEngineV2:
         cache = self.kv_cache
         if "kda_conv" in cache and conv_kernel_serves(cache["kda_conv"]):
             self._m_conv_kernel_steps.inc(steps)
-        if "ssm_state" in cache \
-                and state_space.state_kernel_serves(cache["ssm_state"]):
-            self._m_ssm_state_kernel_steps.inc(steps)
         cfg = self.model.cfg
+        if "ssm_state" in cache and state_space.state_kernel_serves(
+                cache["ssm_state"], cfg.mamba_n_groups):
+            self._m_ssm_state_kernel_steps.inc(steps)
         if one_token_tile_serves(cfg.attention == "mla", cfg.head_dim,
                                  cfg.kv_heads):
             self._m_one_token_steps.inc(steps)
@@ -1669,7 +1684,7 @@ class InferenceEngineV2:
         rings = kinds.count("window")
         held, chunked = np.asarray(decode_positions(
             contexts, sm.block_size, table_pages, sm.config.num_blocks)) \
-            * sum(k not in ("window", "kda", "ssm") for k in kinds)
+            * sum(k not in ("window", "kda", "ssm", "moe") for k in kinds)
         if rings:
             ring = decode_positions(
                 contexts, sm.block_size, sm.ring_blocks,
@@ -1975,8 +1990,8 @@ class InferenceEngineV2:
                     self._m_chunk_kernel_steps.inc()
                 if self._use_kernel and "ssm_state" in cache \
                         and state_space.chunk_kernel_serves(
-                            cache["ssm_state"],
-                            self.model.cfg.mamba_d_head):
+                            cache["ssm_state"], self.model.cfg.mamba_d_head,
+                            self.model.cfg.mamba_n_groups):
                     self._m_ssm_scan_kernel_steps.inc()
             log_tokens = sm.config.enable_prefix_caching
             for uid, toks in entries:

@@ -3,10 +3,17 @@ arXiv:2405.21060), in the two forms a server needs.
 
 A head keeps a float32 state ``S`` [d_head, d_state] in place of cached
 positions. A token with input ``x`` [d_head], step ``dt`` > 0, and the
-vectors ``B``, ``C`` [d_state] that ALL heads share (one group) does,
-with the head's ``A`` < 0,
+vectors ``B``, ``C`` [d_state] of the head's GROUP (``groups`` of them a
+token, consecutive heads a group: heads ``h`` with ``h // (heads /
+groups)`` equal read the same pair; one group: all heads share it)
+does, with the head's ``A`` < 0,
 
     S = exp(dt A) S + (dt x) B^T;   y = S C      (+ D x, the caller's)
+
+Every function here takes B and C as ``[.., groups * d_state]``, the
+groups side by side as the convolution leaves them, and reads the group
+count off that width: one code path, and at one group the operations it
+always made.
 
 The decay is a SCALAR a head and token, so a head's ``d_head`` channels
 are independent of each other and of which head they belong to but for
@@ -32,18 +39,21 @@ over sublanes.
   dt_s x_s`` and ``S_Q = exp(La_Q) S_0 + sum_s exp(La_Q - La_s) (dt_s
   x_s) B_s^T``: matmuls, the pairs' decay ``exp(La_t - La_s)`` <= 1 made
   pair by pair (no factor of it overflows, whatever ``dt A`` is:
-  Mamba-2 has no floor on it). ``C B^T`` is one matrix for all heads.
+  Mamba-2 has no floor on it). ``C B^T`` is one matrix a group.
   :func:`ssm_chunked` in XLA (a ``while_loop`` over chunks of a block of
   rows), and the kernel :func:`ssm_chunk_fwd`: the flat token buffer is
   cut into windows of ``Q`` tokens where it lies, a grid step takes one
-  window and a block of channel groups, walks the rows that have tokens
+  window and a block of lane blocks of channels (inside ONE group of
+  B and C), walks the rows that have tokens
   in it (their neighbours' masked), and a row's state waits in VMEM
   from window to window, out of its slot at the row's first window and
   back at its last.
 
 :func:`state_kernel_serves` / :func:`chunk_kernel_serves` say which
-runs, from the leaf's shape, the head width and
-``jax.default_backend()`` alone: no option selects a form. Every product
+runs, from the leaf's shape, the head width, the group count and
+``jax.default_backend()`` alone: no option selects a form. A kernel
+takes a lane block of 128 channels with ONE B and C, so where a lane
+block would straddle two groups the XLA forms serve. Every product
 is float32 (``Precision.HIGHEST``).
 """
 
@@ -87,11 +97,25 @@ def _channels(per_head, d_head):
     return jnp.repeat(per_head, d_head, axis=-1)
 
 
+def _spread(v, leaf):
+    """B or C [N, groups * d_state] at every channel of the leaf's
+    layout: broadcastable against a row's state [N, G, d_state, W]. One
+    group broadcasts as it is; more repeat a group's vector over its
+    channels, wherever the lane blocks' edges fall."""
+    G, n, W = leaf.shape[2:]
+    N, groups = v.shape[0], v.shape[1] // n
+    if groups == 1:
+        return v[:, None, :, None]
+    each = jnp.repeat(v.reshape(N, groups, n), G * W // groups, axis=1)
+    return jnp.swapaxes(each.reshape(N, G, W, n), -1, -2)
+
+
 def ssm_step(leaf, layer, slots, fresh, x, dt, a, b, c):
     """One token a row: x [N, C] (C = nh d_head channels), dt [N, nh]
-    (softplus taken), a [nh] < 0, b and c [N, d_state], float32; the
-    rows' states from ``leaf[layer, slots]`` (zeros where ``fresh``) and
-    back. Returns (y [N, C] float32 without the skip term, leaf)."""
+    (softplus taken), a [nh] < 0, b and c [N, groups * d_state],
+    float32; the rows' states from ``leaf[layer, slots]`` (zeros where
+    ``fresh``) and back. Returns (y [N, C] float32 without the skip
+    term, leaf)."""
     N, C = x.shape
     G, _, W = leaf.shape[2:]
     d_head = C // dt.shape[-1]
@@ -99,35 +123,54 @@ def ssm_step(leaf, layer, slots, fresh, x, dt, a, b, c):
     dtx = (_channels(dt, d_head) * x).reshape(N, G, 1, W)
     s = jnp.where(fresh[:, None, None, None], 0.0,
                   leaf[layer, slots].astype(jnp.float32))
-    s = s * decay + b[:, None, :, None] * dtx
-    y = jnp.sum(s * c[:, None, :, None], axis=2).reshape(N, C)
+    s = s * decay + _spread(b, leaf) * dtx
+    y = jnp.sum(s * _spread(c, leaf), axis=2).reshape(N, C)
     return y, leaf.at[layer, slots].set(s.astype(leaf.dtype))
 
 
-def state_kernel_serves(leaf) -> bool:
+def _blocks_a_group(blocks, step, groups):
+    """Lane blocks that read one B and C, where ``blocks`` lane blocks
+    in grid steps of ``step`` are served under ``groups`` groups: a
+    group whole lane blocks, and a grid step whole groups or a group
+    whole grid steps; else None."""
+    per = blocks // groups
+    if blocks % groups or (per % step and step % per):
+        return None
+    return per
+
+
+def state_kernel_serves(leaf, groups=1) -> bool:
     """Whether :func:`ssm_state_update` takes this state leaf
-    ``[layers, slots, groups, d_state, lanes]``: on a TPU, the channels
-    whole lane blocks, a group's state whole (8, 128) tiles, the groups
-    whole grid steps."""
+    ``[layers, slots, lane blocks, d_state, lanes]`` under ``groups``
+    groups of B and C: on a TPU, the channels whole lane blocks, a lane
+    block's state whole (8, 128) tiles, the lane blocks whole grid
+    steps, no lane block astride two groups."""
     g, n, w = leaf.shape[2:]
     return (jax.default_backend() == "tpu" and w == 128 and n % 8 == 0
             and g % min(GROUPS_A_STEP, g) == 0
-            and min(GROUPS_A_STEP, g) % 8 == 0)
+            and min(GROUPS_A_STEP, g) % 8 == 0
+            and _blocks_a_group(g, min(GROUPS_A_STEP, g), groups)
+            is not None)
 
 
 def _state_kernel(layer_ref, slots_ref, fresh_ref, s_ref, decay_ref, dtx_ref,
-                  b_ref, c_ref, so_ref, y_ref, *, groups):
-    """One row's ``groups`` channel groups through one token: a group's
-    state [d_state, 128], its channels' decay and ``dt x`` rows of 128
-    lanes, ``B`` and ``C`` spread over the lanes [d_state, 128]."""
+                  b_ref, c_ref, so_ref, y_ref, *, groups, per):
+    """One row's ``groups`` lane blocks of channels through one token: a
+    block's state [d_state, 128], its channels' decay and ``dt x`` rows
+    of 128 lanes, ``B`` and ``C`` spread over the lanes [d_state, 128],
+    one pair every ``per`` blocks where the step spans several groups
+    of them (their pairs one under another)."""
     del layer_ref, slots_ref            # the index maps read them
     keep = fresh_ref[pl.program_id(0)] == 0
+    n = s_ref.shape[1]
     b, c = b_ref[...], c_ref[...]
     for j in range(groups):
+        bj, cj = (b, c) if b.shape[0] == n else (
+            v[j // per * n:(j // per + 1) * n] for v in (b, c))
         s = jnp.where(keep, s_ref[j].astype(jnp.float32), 0.0)
-        s = s * decay_ref[j:j + 1, :] + b * dtx_ref[j:j + 1, :]
+        s = s * decay_ref[j:j + 1, :] + bj * dtx_ref[j:j + 1, :]
         so_ref[j] = s.astype(so_ref.dtype)
-        y_ref[j:j + 1, :] = jnp.sum(s * c, axis=0, keepdims=True)
+        y_ref[j:j + 1, :] = jnp.sum(s * cj, axis=0, keepdims=True)
 
 
 def ssm_state_update(leaf, layer, slots, fresh, x, dt, a, b, c,
@@ -138,21 +181,30 @@ def ssm_state_update(leaf, layer, slots, fresh, x, dt, a, b, c,
     ``layer`` (both prefetched scalars), puts them through the token and
     copies them back to where they came from (aliased): a state is read
     once and written once, where a gather, the update and a scatter
-    move it three times. A trace shows it as ``ssm_state_update``."""
+    move it three times. B and C [N, groups * d_state] go in spread
+    over the lanes, a group's pair under the last's, and a grid step
+    takes the pairs of the groups its lane blocks lie in. A trace shows
+    it as ``ssm_state_update``."""
     N, C = x.shape
     G, n, W = leaf.shape[2:]
     d_head = C // dt.shape[-1]
     gb = min(GROUPS_A_STEP, G)
+    per = _blocks_a_group(G, gb, b.shape[1] // n)
+    pairs = max(gb // per, 1)           # groups of B and C a grid step
+    span = max(gb, per)                 # lane blocks a block of pairs serves
     decay = _channels(jnp.exp(dt * a), d_head).reshape(N, G, W)
     dtx = (_channels(dt, d_head) * x).reshape(N, G, W)
-    spread = [jnp.broadcast_to(v[:, :, None], (N, n, W)) for v in (b, c)]
+    spread = [jnp.broadcast_to(v[:, :, None], (N, v.shape[1], W))
+              for v in (b, c)]
     row = pl.BlockSpec((None, gb, W), lambda r, g, *_: (r, g, 0))
-    shared = pl.BlockSpec((None, n, W), lambda r, g, *_: (r, 0, 0))
+    shared = pl.BlockSpec(
+        (None, pairs * n, W),
+        lambda r, g, *_: (r, g * gb // span if per < G else 0, 0))
     state = pl.BlockSpec(
         (None, None, gb, n, W),
         lambda r, g, layer, slots, fresh: (layer[0], slots[r], g, 0, 0))
     so, y = pl.pallas_call(
-        functools.partial(_state_kernel, groups=gb),
+        functools.partial(_state_kernel, groups=gb, per=per),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3, grid=(N, G // gb),
             in_specs=[state, row, row, shared, shared],
@@ -169,9 +221,20 @@ def ssm_state_update(leaf, layer, slots, fresh, x, dt, a, b, c,
 def _chunk(x, dt, a, b, c, state):
     """One chunk of every row, a head at a time in einsums. x [R, Q, nh,
     p]; dt [R, Q, nh] (0 at a masked token: it leaves the state as it
-    was and adds nothing); a [nh]; b, c [R, Q, n]; state [R, nh, p, n].
-    Returns (y [R, Q, nh, p], state)."""
-    Q = x.shape[1]
+    was and adds nothing); a [nh]; b, c [R, Q, groups * n]; state [R,
+    nh, p, n]. Returns (y [R, Q, nh, p], state). More groups than one:
+    the same chunk a group, over the group's heads."""
+    R, Q, nh, p = x.shape
+    groups = b.shape[-1] // state.shape[-1]
+    if groups > 1:
+        hg = nh // groups
+        y, state = jax.vmap(_chunk, in_axes=(2, 2, 0, 2, 2, 1),
+                            out_axes=(2, 1))(
+            x.reshape(R, Q, groups, hg, p), dt.reshape(R, Q, groups, hg),
+            a.reshape(groups, hg), b.reshape(R, Q, groups, -1),
+            c.reshape(R, Q, groups, -1),
+            state.reshape(R, groups, hg, p, -1))
+        return y.reshape(R, Q, nh, p), state.reshape(R, nh, p, -1)
     la = jnp.cumsum(dt * a, axis=1)                         # [R, Q, nh]
     idx = jnp.arange(Q)
     seen = (idx[:, None] >= idx[None, :])[None, :, :, None]
@@ -192,17 +255,18 @@ def _chunk(x, dt, a, b, c, state):
 def ssm_chunked(leaf, layer, slots, fresh, starts, counts, xbc, dt, a,
                 chunk=CHUNK):
     """The recurrence over the rows of a flat token buffer, from and to
-    the rows' slots of the state leaf. ``xbc`` [T, C + 2 d_state]: a
-    token's x, B and C side by side as the convolution leaves them, any
-    float type; dt [T, nh] (softplus taken); a [nh]. Row r owns the
+    the rows' slots of the state leaf. ``xbc`` [T, C + 2 groups
+    d_state]: a token's x, B and C (each all its groups) side by side as
+    the convolution leaves them, any float type (C, the channels, is the
+    leaf's); dt [T, nh] (softplus taken); a [nh]. Row r owns the
     tokens ``starts[r] .. starts[r] + counts[r]`` (``counts`` 0: none)
     and the slot ``slots[r]``: its state before its first token here
     (zeros where ``fresh[r]``), and after its last. A step of the loop
     takes one chunk of a block of rows from their slots and back.
     Returns (y [T, C] in ``xbc``'s type without the skip term, zeros at
     tokens of no row; leaf)."""
-    n = leaf.shape[3]
-    T, C = xbc.shape[0], xbc.shape[1] - 2 * n
+    T, C = xbc.shape[0], leaf.shape[2] * leaf.shape[4]
+    n = (xbc.shape[1] - C) // 2         # groups x d_state
     x, b, c = xbc[:, :C], xbc[:, C:C + n], xbc[:, C + n:]
     R, nh = starts.shape[0], dt.shape[-1]
     p, f32 = C // nh, jnp.float32
@@ -227,7 +291,8 @@ def ssm_chunked(leaf, layer, slots, fresh, starts, counts, xbc, dt, a,
                           heads_of(leaf[layer, slot].astype(f32), nh))
         y, state = _chunk(xs, dts, a.astype(f32), b[idx].astype(f32),
                           c[idx].astype(f32), state)
-        back = jnp.swapaxes(state.reshape(B, -1, leaf.shape[4], n), -1, -2)
+        back = jnp.swapaxes(
+            state.reshape(B, -1, leaf.shape[4], leaf.shape[3]), -1, -2)
         leaf = leaf.at[layer, slot].set(back.astype(leaf.dtype))
         out = out.at[jnp.where(live, idx, T)].set(
             y.reshape(B, chunk, C), mode="drop")
@@ -239,17 +304,20 @@ def ssm_chunked(leaf, layer, slots, fresh, starts, counts, xbc, dt, a,
     return out.astype(xbc.dtype), leaf
 
 
-def chunk_kernel_serves(leaf, d_head) -> bool:
+def chunk_kernel_serves(leaf, d_head, groups=1) -> bool:
     """Whether :func:`ssm_chunk_fwd` takes this state leaf ``[layers,
-    slots, groups, d_state, lanes]`` of heads ``d_head`` wide: on a TPU,
-    the channels whole lane blocks, a lane block whole heads or a head
-    whole lane blocks, ``d_state`` whole lane blocks (B and C are
-    blocks of the token buffer behind the channels', and the contracted
-    width of the window's products), the groups whole grid steps."""
+    slots, lane blocks, d_state, lanes]`` of heads ``d_head`` wide under
+    ``groups`` groups of B and C: on a TPU, the channels whole lane
+    blocks, a lane block whole heads or a head whole lane blocks,
+    ``d_state`` whole lane blocks (B and C are blocks of the token
+    buffer behind the channels', and the contracted width of the
+    window's products), the lane blocks whole grid steps, and a grid
+    step inside ONE group (its ``C B^T`` is one matrix)."""
     g, n, w = leaf.shape[2:]
     return (jax.default_backend() == "tpu" and w == 128 and n % 128 == 0
             and (128 % d_head == 0 or d_head % 128 == 0)
-            and g % CHUNK_GROUPS == 0)
+            and g % CHUNK_GROUPS == 0 and g % groups == 0
+            and g // groups % CHUNK_GROUPS == 0)
 
 
 def _dot(x, y, contract):
@@ -370,9 +438,10 @@ def ssm_chunk_fwd(leaf, layer, slots, fresh, starts, counts, xbc, dt, a,
                   chunk=CHUNK, interpret=False):
     """:func:`ssm_chunked` as ONE kernel a layer and launch, the rows'
     states in place (its arguments and results). The grid is (block of
-    ``CHUNK_GROUPS`` channel groups, window of ``chunk`` flat tokens);
-    x, B and C are blocks of the ONE ``xbc`` where it lies (the
-    channels' lane blocks, then B's and C's: no slice of it is made),
+    ``CHUNK_GROUPS`` lane blocks of channels, window of ``chunk`` flat
+    tokens); x, B and C are blocks of the ONE ``xbc`` where it lies (the
+    channels' lane blocks, then every group's B and every group's C, of
+    which a grid step takes its own group's: no slice of it is made),
     and they, dt and the output (``xbc``'s type) move by the pipeline's
     own copies, a window at a time; the leaf stays whole in HBM and a row's state is copied out of ``leaf[layer, slots[r]]`` at
     the row's first window (zeros where ``fresh[r]``) and back at its
@@ -381,8 +450,10 @@ def ssm_chunk_fwd(leaf, layer, slots, fresh, starts, counts, xbc, dt, a,
     the kernel once."""
     nh = dt.shape[-1]
     G, n, W = leaf.shape[2:]
-    T, C = xbc.shape[0], xbc.shape[1] - 2 * n
+    T, C = xbc.shape[0], G * W
+    groups = (xbc.shape[1] - C) // (2 * n)
     gb = CHUNK_GROUPS
+    per = G // groups // gb             # grid steps a group of B and C
     pad = -T % chunk
     if pad:
         xbc, dt = (jnp.pad(v, ((0, pad), (0, 0))) for v in (xbc, dt))
@@ -404,8 +475,10 @@ def ssm_chunk_fwd(leaf, layer, slots, fresh, starts, counts, xbc, dt, a,
                 pl.BlockSpec((1, lanes), lambda g, w, *_: (0, 0)),
                 pl.BlockSpec((chunk, gb * W), lambda g, w, *_: (w, g)),
                 pl.BlockSpec((chunk, lanes), lambda g, w, *_: (w, 0)),
-                pl.BlockSpec((chunk, n), lambda g, w, *_: (w, C // n)),
-                pl.BlockSpec((chunk, n), lambda g, w, *_: (w, C // n + 1)),
+                pl.BlockSpec((chunk, n), lambda g, w, *_: (
+                    w, C // n + (g // per if groups > 1 else 0))),
+                pl.BlockSpec((chunk, n), lambda g, w, *_: (
+                    w, C // n + groups + (g // per if groups > 1 else 0))),
                 hbm],
             out_specs=[hbm, pl.BlockSpec((chunk, gb * W),
                                          lambda g, w, *_: (w, g))],

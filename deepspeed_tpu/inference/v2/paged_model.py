@@ -440,8 +440,8 @@ def _moe_routed(cfg, lp, xt, experts=None, stack_layer=None,
     bf16 passes unless asked otherwise, so ``_pattern_step`` asks for
     ``HIGHEST``; the per-head path's softmax router keeps the backend's
     default (None), which is what its programs compiled to before."""
-    from ...moe.sharded_moe import (dropless_topk_dispatch, gmm_serves,
-                                    gmm_swiglu_experts, topk_routing)
+    from ...moe.sharded_moe import (dropless_topk_dispatch, expert_forms,
+                                    gmm_serves, topk_routing)
 
     with jax.named_scope("moe_router"):
         gate_w = lp["moe_gate_w"]
@@ -460,14 +460,15 @@ def _moe_routed(cfg, lp, xt, experts=None, stack_layer=None,
     xt = xt.astype(gate_w.dtype)
     with jax.named_scope("moe_experts"):
         if experts is None:
-            experts = (lp["e_gate"], lp["e_up"], lp["e_down"])
+            experts = tuple(lp[k] for k in cfg.expert_keys)
+        ragged, gmm, shared_expert = expert_forms(cfg.moe_expert_form)
         # the router scores every expert; this tree may hold a share of
         # them (cfg.moe_experts_held from cfg.moe_experts_first), and a
         # pick that is held elsewhere adds nothing here
         def dispatch(xt, topi, topv):
             return dropless_topk_dispatch(
                 xt, topi, topv, experts, cfg.experts_held,
-                gmm_swiglu_experts if gmm_serves(experts) else None,
+                gmm if gmm_serves(experts) else ragged,
                 stack_layer=stack_layer, held_from=_held_from(cfg))
 
         T, run = xt.shape[0], _share_tokens(xt, cfg.moe_top_k)
@@ -489,8 +490,10 @@ def _moe_routed(cfg, lp, xt, experts=None, stack_layer=None,
             out = dispatch(xt, topi, topv)
     if cfg.moe_shared_experts:
         with jax.named_scope("moe_shared_expert"):
-            out = out + (jax.nn.silu(xt @ lp["shared_gate"])
-                         * (xt @ lp["shared_up"])) @ lp["shared_down"]
+            # the shared expert's leaves are the routed ones' by name:
+            # e_up -> shared_up
+            out = out + shared_expert(xt, *(
+                lp[k.replace("e_", "shared_", 1)] for k in cfg.expert_keys))
     return out, topi
 
 
@@ -1026,12 +1029,16 @@ def _state_space_sublayer(cfg, lp, x, l, cache, rows: _StateRows,
     form, rows of any lengths (scope ``ssm_scan``: the kernel
     ``ssm_chunk_fwd`` or the XLA ``ssm_chunked``):
     ``kernels/state_space``; the skip term ``d_skip x``; the heads'
-    whole output times SiLU(z), RMS-normed (scope ``ssm_gate_norm``) and
-    projected (``ssm_out``). Returns (what the mixer adds to x, cache)."""
+    whole output times SiLU(z), RMS-normed a GROUP of heads
+    (``cfg.mamba_n_groups`` of them, each with its own B and C: one
+    group, the whole output) under one weight (scope ``ssm_gate_norm``)
+    and projected (``ssm_out``). Returns (what the mixer adds to x,
+    cache)."""
     from ...ops.norms import rms_norm
     from .kernels import linear_attention as la
     from .kernels import state_space as ss
-    di, n = cfg.mamba_d_inner, cfg.mamba_d_state
+    di, groups = cfg.mamba_d_inner, cfg.mamba_n_groups
+    n = groups * cfg.mamba_d_state      # B's (and C's) width a token
     dc, f32 = cfg.mamba_conv_dim, jnp.float32
     dt_ = lp["w_in"].dtype
     hn = _norm(cfg, x, lp["attn_norm"]).astype(dt_)
@@ -1074,12 +1081,12 @@ def _state_space_sublayer(cfg, lp, x, l, cache, rows: _StateRows,
         leaf = cache["ssm_state"]
         if rows.one_token:
             step = ss.ssm_state_update if use_kernel \
-                and ss.state_kernel_serves(leaf) else ss.ssm_step
+                and ss.state_kernel_serves(leaf, groups) else ss.ssm_step
             y, leaf = step(leaf, l, slots, rows.fresh, xs.astype(f32), dt,
                            a, b.astype(f32), c.astype(f32))
         else:
             scan = ss.ssm_chunk_fwd if use_kernel \
-                and ss.chunk_kernel_serves(leaf, cfg.mamba_d_head) \
+                and ss.chunk_kernel_serves(leaf, cfg.mamba_d_head, groups) \
                 else ss.ssm_chunked
             y, leaf = scan(leaf, l, slots, rows.fresh, rows.starts,
                            rows.counts, xbc, dt, a)
@@ -1087,8 +1094,12 @@ def _state_space_sublayer(cfg, lp, x, l, cache, rows: _StateRows,
         y = y.astype(f32) + jnp.repeat(
             lp["d_skip"].astype(f32), cfg.mamba_d_head) * xs.astype(f32)
     with jax.named_scope("ssm_gate_norm"):
-        y = rms_norm(y * jax.nn.silu(z.astype(f32)), lp["gate_norm"],
-                     cfg.norm_eps)
+        y, w = y * jax.nn.silu(z.astype(f32)), lp["gate_norm"]
+        if groups > 1:
+            y = rms_norm(y.reshape(-1, groups, di // groups),
+                         w.reshape(groups, -1), cfg.norm_eps).reshape(-1, di)
+        else:
+            y = rms_norm(y, w, cfg.norm_eps)
     with jax.named_scope("ssm_out"):
         return y.astype(dt_) @ lp["w_out"], cache
 
@@ -1097,11 +1108,16 @@ def _layer_runs(cfg):
     """The layers as maximal runs of one (mixer kind, MLP kind):
     [(kind, routed, first layer, layers)], ``kind`` one of
     ``cfg.layer_kinds``' and ``routed`` whether the run's MLP is the
-    expert layer. One scan a run; the pattern is static."""
+    expert layer. One scan a run; the pattern is static. Where a layer
+    is ONE sub-layer (``cfg.one_sublayer``) a run is layers of one kind,
+    a mixer's with no MLP and ``routed`` only for the kind "moe", an
+    expert layer with no mixer: sixteen alternating layers are sixteen
+    runs of one (more runs, and no unit that may lack a half)."""
     lead = cfg.moe_first_dense_layers
     runs = []
     for i, kind in enumerate(cfg.layer_kinds):
-        key = (kind, cfg.moe_num_experts > 0 and i >= lead, i < lead)
+        key = (kind, kind == "moe", False) if cfg.one_sublayer else \
+            (kind, cfg.moe_num_experts > 0 and i >= lead, i < lead)
         if runs and runs[-1][0] == key:
             runs[-1][2] += 1
         else:
@@ -1148,6 +1164,13 @@ def _pattern_step(cfg: TransformerConfig, params, ids, row_ids, pos, lengths,
     the sandwich scheme each sub-layer's output passes a second norm
     before it joins the stream.
 
+    A pattern of layers that are ONE sub-layer each (``cfg.one_sublayer``:
+    ``layer_types`` names "moe" layers) walks the same way: a run of
+    mixers reads ``<kind>_layers`` and has no MLP behind it, a run of
+    expert layers reads ``layers`` (the expert layers alone, in layer
+    order: their one norm ``mlp_norm``, router and experts) and has no
+    mixer ahead of it.
+
     The stack's expert weights never ride a scan: a layer sliced out of
     them for the grouped-matmul kernel would be a copy of all its
     experts, so the kernel takes the stack whole and the layer by where
@@ -1183,7 +1206,8 @@ def _pattern_step(cfg: TransformerConfig, params, ids, row_ids, pos, lengths,
         return x + (a if cfg.residual_scale == 1.0
                     else a * cfg.residual_scale)
 
-    expert_keys = ("e_gate", "e_up", "e_down")
+    expert_keys = cfg.expert_keys
+    single = cfg.one_sublayer
     if window_tables is not None:
         # a window layer's write-set: the ring place of each new position
         bs = cache["k_window"].shape[2]
@@ -1196,14 +1220,18 @@ def _pattern_step(cfg: TransformerConfig, params, ids, row_ids, pos, lengths,
 
     def stack(x, pool, stats, kind, routed, first, n):
         led = first < lead
-        mlps = params["lead_layers" if led else "layers"]
-        f0 = first if led else first - lead     # the run's place in mlps
+        # a layer that is one sub-layer has a mixer or an MLP, not both
+        has_mixer, has_mlp = kind != "moe", routed or not single
+        mlps = params["lead_layers" if led else "layers"] if has_mlp else {}
+        # the run's place in mlps
+        f0 = kinds[:first].count("moe") if single else \
+            first if led else first - lead
         experts = tuple(mlps[k] for k in expert_keys) if routed else None
         scanned = {k: v for k, v in mlps.items()
                    if not (routed and k in expert_keys)}
         # the run's place in its mixer's leaves, parameters and cache
         m0 = kinds[:first].count(kind) if pattern else first
-        mixers = params[kind + "_layers"] if pattern else None
+        mixers = params[kind + "_layers"] if pattern and has_mixer else {}
 
         def layer_fn(carry, inputs):
             x, pool, stats = carry
@@ -1223,6 +1251,8 @@ def _pattern_step(cfg: TransformerConfig, params, ids, row_ids, pos, lengths,
                     a, pool = _state_space_sublayer(
                         cfg, lp, x, m0 + i, pool, rows, use_kernel)
                     x = joined(x, a)
+            elif kind == "moe":
+                pass                    # no mixer ahead of the experts
             elif kind in ("window", "full"):
                 ring = kind == "window"
                 with jax.named_scope("attention"):
@@ -1240,6 +1270,8 @@ def _pattern_step(cfg: TransformerConfig, params, ids, row_ids, pos, lengths,
                         lengths, write_blocks, write_offsets, block_tables,
                         use_kernel, one_token)
                     x = joined(x, a)
+            if not has_mlp:
+                return (x, pool, stats), None
             with jax.named_scope("mlp"):
                 hn = _norm(cfg, x, lp["mlp_norm"])
                 if routed:

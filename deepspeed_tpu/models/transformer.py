@@ -35,12 +35,14 @@ from ..ops.norms import layer_norm, rms_norm
 from ..telemetry import registry as _registry
 
 
-# a source's word for a layer -> the mixer kind it is served as: per-head
+# a source's word for a layer -> the kind it is served as: per-head
 # attention over a window or over every position ("attention": the
-# word of a source whose other layers are no attention at all), or a
-# Mamba-2 state-space mixer
+# word of a source whose other layers are no attention at all), a
+# Mamba-2 state-space mixer, or "moe": an expert layer that is a layer
+# of its own. A pattern that names "moe" is one whose every layer is
+# ONE sub-layer behind ONE norm (``TransformerConfig.one_sublayer``)
 LAYER_TYPE_KINDS = {"sliding_attention": "window", "full_attention": "full",
-                    "attention": "full", "mamba": "ssm"}
+                    "attention": "full", "mamba": "ssm", "moe": "moe"}
 
 
 @dataclass(frozen=True)
@@ -193,16 +195,19 @@ class TransformerConfig:
     # only; the granitemoehybrid block): ``layer_types`` "mamba" is a
     # Mamba-2 mixer (SSD, arXiv:2405.21060) and "attention" a full
     # per-head layer. The mixer: one ``in_proj`` to [z | x B C | dt]
-    # (``mamba_n_heads * mamba_d_head`` | that + 2 ``mamba_d_state`` |
-    # ``mamba_n_heads``), a causal depthwise convolution of
+    # (``mamba_n_heads * mamba_d_head`` | that + 2 ``mamba_n_groups``
+    # ``mamba_d_state`` | ``mamba_n_heads``), a causal depthwise convolution of
     # ``mamba_d_conv`` taps (with a bias where ``mamba_conv_bias``) and
     # SiLU over x, B and C, a float32 state [mamba_d_head,
     # mamba_d_state] a head with a SCALAR decay a head and token, B and
-    # C shared by all heads (``mamba_n_groups`` 1, the only value
-    # served), a gated RMS norm over the heads' whole output, and
-    # ``out_proj``. ``mamba_chunk_size`` is the source's tile of the
-    # chunked form: results do not depend on it but for rounding, and
-    # the programs tile by their own (kernels/state_space.py).
+    # C [``mamba_n_groups``, mamba_d_state] a token, a group of
+    # consecutive heads reading one of them (heads a whole multiple of
+    # groups), a gated RMS norm over each group's channels, and
+    # ``out_proj``. The inner width is heads x head width whatever
+    # ``mamba_expand`` says (read and unused). ``mamba_chunk_size`` is
+    # the source's tile of the chunked form: results do not depend on it
+    # but for rounding, and the programs tile by their own
+    # (kernels/state_space.py).
     # The muP multipliers of the same block: ``attn_scale`` replaces
     # head_dim^-1/2 on the scores (0: that default), ``residual_scale``
     # multiplies what every sub-layer adds to the stream,
@@ -230,6 +235,12 @@ class TransformerConfig:
     # (0: all), and a pick that is held elsewhere adds nothing here
     moe_experts_held: int = 0
     moe_experts_first: int = 0
+    # an expert's form (served only, the walk of runs): "swiglu", three
+    # matrices, ``down(silu(gate x) * (up x))``; "relu2", two and a
+    # squared ReLU, ``down(relu(up x)^2)`` (the nemotron_h block; no
+    # ``e_gate`` / ``shared_gate`` leaf; ``e_up`` [.., F, H], out x in,
+    # the model's width last as ``e_down``'s), routed and shared alike
+    moe_expert_form: str = "swiglu"
 
     # training objective: "causal_lm" (next-token, causal attention) or
     # "mlm" (BERT-family masked-LM: bidirectional attention, loss at the
@@ -334,19 +345,25 @@ class TransformerConfig:
                                  "attn_window > 0")
             if "mamba" in self.layer_types and (
                     min(self.mamba_n_heads, self.mamba_d_head,
-                        self.mamba_d_state) < 1 or self.mamba_d_conv < 2
-                    or self.mamba_n_groups != 1
-                    or self.mamba_n_heads * self.mamba_d_head
-                    != self.mamba_expand * self.hidden_size):
+                        self.mamba_d_state, self.mamba_n_groups) < 1
+                    or self.mamba_d_conv < 2
+                    or self.mamba_n_heads % self.mamba_n_groups):
                 raise ValueError(
-                    f"a mamba layer needs mamba_n_heads x mamba_d_head = "
-                    f"mamba_expand x hidden_size, mamba_d_state > 0, "
-                    f"mamba_d_conv >= 2 and mamba_n_groups 1 (B and C "
-                    f"shared by all heads), got "
-                    f"{(self.mamba_n_heads, self.mamba_d_head)} against "
-                    f"{self.mamba_expand} x {self.hidden_size}, state "
+                    f"a mamba layer needs mamba_n_heads, mamba_d_head and "
+                    f"mamba_d_state > 0, mamba_d_conv >= 2 and "
+                    f"mamba_n_heads a whole multiple of mamba_n_groups "
+                    f"(a group of heads reads one B and C), got heads "
+                    f"{(self.mamba_n_heads, self.mamba_d_head)}, state "
                     f"{self.mamba_d_state}, taps {self.mamba_d_conv}, "
                     f"groups {self.mamba_n_groups}")
+            if self.one_sublayer and (
+                    self.moe_num_experts < 1 or self.moe_first_dense_layers
+                    or self.norm_scheme != "pre"):
+                raise NotImplementedError(
+                    "a layer_types pattern that names 'moe' layers (every "
+                    "layer ONE sub-layer behind one norm) is served "
+                    "pre-norm, with routed experts (moe_num_experts > 0) "
+                    "and no leading dense stack")
         elif self.attn_window or self.qk_norm or self.rope_sliding_only \
                 or self.norm_scheme == "sandwich" \
                 or self.attn_gate == "elementwise":
@@ -404,6 +421,14 @@ class TransformerConfig:
                 "leading dense layers (moe_first_dense_layers) are served "
                 "for an MoE model with attention='mla' or a layer_types "
                 "pattern only")
+        if self.moe_expert_form not in ("swiglu", "relu2") or (
+                self.moe_expert_form == "relu2"
+                and (self.layer_types is None or self.moe_num_experts < 1
+                     or self.moe_use_residual)):
+            raise NotImplementedError(
+                f"moe_expert_form is 'swiglu' or, for the routed and "
+                f"shared experts of a layer_types pattern, 'relu2'; got "
+                f"{self.moe_expert_form!r}")
         if self.moe_noisy_gate_policy is not None:
             # RSample needs an rng threaded through the scanned layer body,
             # which neither the GSPMD nor the manual-pipeline MoE branch
@@ -441,6 +466,12 @@ class TransformerConfig:
              self.layer_types is not None),
             ("mamba layers (a Mamba-2 state-space mixer and its "
              "recurrent state)", "ssm" in self.layer_kinds),
+            ("'moe' layers (a layer is one sub-layer behind one norm)",
+             self.one_sublayer),
+            ("mamba_n_groups (B and C a group of heads)",
+             "ssm" in self.layer_kinds and self.mamba_n_groups > 1),
+            ("moe_expert_form='relu2' (two-matrix experts)",
+             self.moe_expert_form != "swiglu"),
             ("positional='none'", self.positional == "none"),
             ("attn_scale", self.attn_scale != 0.0),
             ("residual_scale", self.residual_scale != 1.0),
@@ -475,11 +506,27 @@ class TransformerConfig:
         return self.moe_experts_held or self.moe_num_experts
 
     @property
+    def one_sublayer(self) -> bool:
+        """Whether every layer is ONE sub-layer behind ONE norm, ``h = h
+        + f_i(norm_i(h))``: a mixer with no MLP behind it, or an expert
+        layer (``layer_types`` "moe") with no mixer ahead of it. A
+        pattern says so by naming "moe" layers."""
+        return self.layer_types is not None and "moe" in self.layer_types
+
+    @property
+    def expert_keys(self) -> tuple:
+        """The leaves of a routed expert, in the order the grouped
+        matmuls take them."""
+        return ("e_gate", "e_up", "e_down") \
+            if self.moe_expert_form == "swiglu" else ("e_up", "e_down")
+
+    @property
     def layer_kinds(self) -> tuple:
         """The mixer of every layer, in order: "kda" (linear attention)
         or ``attention`` under ``linear_attn_period``; "window" or "full"
-        (per-head attention) or "ssm" (a Mamba-2 state-space mixer) from
-        an explicit ``layer_types``."""
+        (per-head attention), "ssm" (a Mamba-2 state-space mixer) or
+        "moe" (an expert layer that is a layer of its own:
+        ``one_sublayer``) from an explicit ``layer_types``."""
         if self.layer_types is not None:
             return tuple(LAYER_TYPE_KINDS[t] for t in self.layer_types)
         p = self.linear_attn_period
@@ -921,7 +968,8 @@ class TransformerLM:
             out["moe_gate_bias"] = jnp.zeros((L, cfg.moe_num_experts), dt)
         if fs:
             ks = jax.random.split(key, 3)
-            out["shared_gate"] = init(ks[0], (L, h, fs))
+            if cfg.moe_expert_form == "swiglu":
+                out["shared_gate"] = init(ks[0], (L, h, fs))
             out["shared_up"] = init(ks[1], (L, h, fs))
             out["shared_down"] = init(ks[2], (L, fs, h), out_std)
         return out
@@ -1059,8 +1107,13 @@ class TransformerLM:
                 cfg.expert_size
             held = cfg.experts_held
             return {"moe_gate_w": init(ks[0], (n, h, E)),
-                    "e_gate": init(ks[1], (n, held, h, f)),
-                    "e_up": init(ks[2], (n, held, h, f)),
+                    **({"e_gate": init(ks[1], (n, held, h, f))}
+                       if cfg.moe_expert_form == "swiglu" else {}),
+                    # a relu2 expert's ``up`` keeps the model's width last,
+                    # out x in (``sharded_moe.ragged_relu2_experts``)
+                    "e_up": init(ks[2], (n, held, h, f)
+                                 if cfg.moe_expert_form == "swiglu"
+                                 else (n, held, f, h)),
                     "e_down": init(ks[3], (n, held, f, h), out_std),
                     **self._init_deployed_router(ks[4], n, init, out_std)}
 
@@ -1081,9 +1134,18 @@ class TransformerLM:
                       "mla": (functools.partial(attention, mlp_norm=False),
                               8)}
             for kind in dict.fromkeys(kinds):
+                if kind == "moe":
+                    continue
                 make, fold = mixers[kind]
                 params[kind + "_layers"] = make(
                     jax.random.fold_in(rng, fold), kinds.count(kind))
+            if cfg.one_sublayer:
+                # a layer is ONE sub-layer: ``layers`` holds the expert
+                # layers alone (their norm, router and experts), in
+                # layer order, and no mixer has an MLP behind it
+                n = kinds.count("moe")
+                params["layers"] = {**mlp_norms(n), **experts(k[2], n)}
+                return params
             params["layers"] = {**mlp_norms(L - lead), **mlp(k[2], L - lead)}
             if lead:
                 params["lead_layers"] = {**mlp_norms(lead),

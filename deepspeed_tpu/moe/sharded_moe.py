@@ -219,6 +219,22 @@ def ragged_swiglu_experts(expert_params, xs, group_sizes):
     return jax.lax.ragged_dot(jax.nn.silu(g) * u, wd, group_sizes)
 
 
+def ragged_relu2_experts(expert_params, xs, group_sizes):
+    """:func:`ragged_swiglu_experts` for the two-matrix expert with a
+    squared ReLU and no gate, ``down(relu(up x)^2)`` (the nemotron_h
+    block). ``expert_params`` are (up [E, F, H], down [E, F, H]): BOTH
+    keep the model's width H as their last axis, ``up`` as a linear
+    layer's checkpoint stores it (out x in). An expert's own width F
+    need be no whole number of 128-lane blocks (1,856 is 14.5), and a
+    TPU lays an array whose last axis is not out with another axis last
+    where that saves padding: a [E, H, F] leaf would reach the grouped
+    matmul's kernel through a copy of every expert, each launch."""
+    wu, wd = expert_params
+    u = jax.nn.relu(jax.lax.ragged_dot(xs, jnp.swapaxes(wu, -1, -2),
+                                       group_sizes))
+    return jax.lax.ragged_dot(u * u, wd, group_sizes)
+
+
 # the most of an expert's [K, N] weight the grouped-matmul kernel takes
 # as ONE tile (twice that is resident: the next arrives while this one
 # is used): the whole expert where it fits, else its K rows by the
@@ -244,6 +260,14 @@ def _gmm_columns(w):
     return None
 
 
+def _whole_row_tiles(xs):
+    """``xs`` padded with rows of zeros to whole row tiles of the grouped
+    matmul (rows past the last group: not computed), and its own rows."""
+    m = xs.shape[0]
+    pad = (-m) % _GMM_ROWS
+    return (jnp.pad(xs, ((0, pad), (0, 0))) if pad else xs), m
+
+
 def gmm_swiglu_experts(expert_params, xs, group_sizes):
     """``ragged_swiglu_experts`` through the Pallas grouped matmul JAX
     ships (``jax.experimental.pallas.ops.tpu.megablox``), a row tile of
@@ -257,10 +281,7 @@ def gmm_swiglu_experts(expert_params, xs, group_sizes):
     ``gmm.N`` (the name of JAX's jitted function around them)."""
     from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
     wg, wu, wd = expert_params
-    m = xs.shape[0]
-    pad = (-m) % _GMM_ROWS          # rows past the last group: not computed
-    if pad:
-        xs = jnp.pad(xs, ((0, pad), (0, 0)))
+    xs, m = _whole_row_tiles(xs)
 
     def mm(x, w):
         return gmm(x, w, group_sizes, preferred_element_type=x.dtype,
@@ -269,14 +290,58 @@ def gmm_swiglu_experts(expert_params, xs, group_sizes):
     return mm(jax.nn.silu(mm(xs, wg)) * mm(xs, wu), wd)[:m]
 
 
+def gmm_relu2_experts(expert_params, xs, group_sizes):
+    """:func:`ragged_relu2_experts` through the same Pallas grouped
+    matmul as :func:`gmm_swiglu_experts` (a trace shows its two kernels
+    as ``gmm.N`` too), both matrices [E, F, H] tiled alike: all F rows
+    by :func:`_gmm_columns` of the H columns (1,856 x 2,688 in bf16 is
+    10 MB: three tiles of 896 columns). ``down`` is the plain product
+    over the column tiles. ``up`` is the product with the matrix
+    transposed, and its column tiles are tiles of the CONTRACTED width:
+    a row tile sums over them (the kernel's innermost grid axis), so an
+    expert's ``up`` streams once a row tile of its rows: once at a
+    decode step's few rows an expert."""
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
+    wu, wd = expert_params
+    xs, m = _whole_row_tiles(xs)
+    u = jax.nn.relu(gmm(
+        xs, wu, group_sizes, preferred_element_type=xs.dtype,
+        tiling=(_GMM_ROWS, _gmm_columns(wu), wu.shape[1]),
+        transpose_rhs=True))
+    return gmm(u * u, wd, group_sizes, preferred_element_type=xs.dtype,
+               tiling=(_GMM_ROWS, wd.shape[1], _gmm_columns(wd)))[:m]
+
+
+def _swiglu_expert(x, wg, wu, wd):
+    return (jax.nn.silu(x @ wg) * (x @ wu)) @ wd
+
+
+def _relu2_expert(x, wu, wd):
+    """One plain relu^2 expert, ``wu`` [H, F] and ``wd`` [F, H] (the
+    shared expert's leaves: its width is whole lane blocks)."""
+    u = jax.nn.relu(x @ wu)
+    return (u * u) @ wd
+
+
+def expert_forms(form):
+    """An expert's form (``TransformerConfig.moe_expert_form``) as (the
+    routed experts over sorted rows by ``ragged_dot``, the same by the
+    Pallas grouped matmul, ONE always-on expert on plain rows)."""
+    return {"swiglu": (ragged_swiglu_experts, gmm_swiglu_experts,
+                       _swiglu_expert),
+            "relu2": (ragged_relu2_experts, gmm_relu2_experts,
+                      _relu2_expert)}[form]
+
+
 def gmm_serves(expert_params) -> bool:
-    """Whether :func:`gmm_swiglu_experts` takes these experts: on a TPU,
-    every weight's two widths whole 128-lane blocks, one expert's weight
-    (or its rows by a whole fraction of its columns) small enough to be
-    a tile."""
+    """Whether the grouped-matmul kernel takes these experts: on a TPU,
+    every weight's last axis whole 128-lane blocks and the one before it
+    whole (16, 128) tiles (every accepted width: whole lane blocks too),
+    one expert's weight (or its rows by a whole fraction of its columns)
+    small enough to be a tile."""
     if jax.default_backend() != "tpu":
         return False
-    return all(w.shape[-1] % 128 == 0 and w.shape[-2] % 128 == 0
+    return all(w.shape[-1] % 128 == 0 and w.shape[-2] % 16 == 0
                and _gmm_columns(w) is not None for w in expert_params)
 
 

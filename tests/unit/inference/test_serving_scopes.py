@@ -385,6 +385,89 @@ def test_the_state_space_scopes_open_in_both_programs():
         assert other not in text
 
 
+def _scopes_under(jaxpr, found=None):
+    """Every name stack under a jaxpr, sub-jaxprs included."""
+    found = set() if found is None else found
+    for eqn in jaxpr.eqns:
+        found.add(str(eqn.source_info.name_stack))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _scopes_under(sub, found)
+    return found
+
+
+@pytest.mark.parametrize("program", ["ragged_step", "decode_window"])
+def test_a_layer_that_is_one_sub_layer_opens_its_scopes_and_no_others(
+        program):
+    """The toy of the one-sub-layer pattern (mamba | moe | attention):
+    its sixteen runs are sixteen scans, and each carries its own kind's
+    scopes alone: a mamba layer ``ssm_mixer`` > {``ssm_proj``,
+    ``ssm_conv``, ``ssm_scan`` | ``ssm_state``, ``ssm_gate_norm``,
+    ``ssm_out``} and no ``mlp``; an expert layer ``mlp`` >
+    {``moe_router``, ``moe_experts``, ``moe_shared_expert``} with NO
+    mixer scope around it; an attention layer ``attention`` > {...} with
+    no ``mlp`` behind it."""
+    import json
+    from pathlib import Path
+    from benchmark import run as harness
+    from deepspeed_tpu.inference.v2.paged_model import (
+        init_paged_kv_cache, paged_decode_window, paged_ragged_step)
+    from deepspeed_tpu.models import TransformerLM
+    file = json.loads((Path(__file__).resolve().parents[3] / "benchmark"
+                       / "configs/nemotron-3-nano-30b-a3b.json").read_text())
+    cfg = TransformerConfig(**harness.merge(file["fields"],
+                                            file["toy_fields"]))
+    params = jax.eval_shape(TransformerLM(cfg).init_params,
+                            jax.random.PRNGKey(0))
+    cache = jax.eval_shape(lambda: init_paged_kv_cache(
+        cfg, 9, 16, jnp.float32, state_slots=2))
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
+    if program == "decode_window":
+        jaxpr = jax.make_jaxpr(
+            lambda p, t, pos, bt, c, sl, eos, ss: paged_decode_window(
+                cfg, p, t, pos, bt, c, sl, eos, 16, 4, state_slots=ss))(
+            params, i32(2), i32(2), i32(2, 4), cache, i32(2), i32(2), i32(2))
+        recurrence, other = "ssm_state", "ssm_scan"
+    else:
+        jaxpr = jax.make_jaxpr(
+            lambda p, ids, rows, pos, ln, wb, wo, bt, li, c, ss:
+            paged_ragged_step(cfg, p, ids, rows, pos, ln, wb, wo, bt, li, c,
+                              16, state_slots=ss))(
+            params, i32(16), i32(16), i32(16), i32(16), i32(16), i32(16),
+            i32(2, 4), i32(2), cache, i32(2))
+        recurrence, other = "ssm_scan", "ssm_state"
+
+    def scans(jp, found):
+        for eqn in jp.eqns:
+            if eqn.primitive.name == "scan" and str(
+                    eqn.source_info.name_stack).endswith("layers"):
+                found.append(_scopes_under(eqn.params["jaxpr"].jaxpr))
+            else:
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    scans(sub, found)
+        return found
+
+    runs = scans(jaxpr.jaxpr, [])
+    assert len(runs) == 16
+    wants = {
+        "ssm": {"ssm_mixer/ssm_proj", "ssm_mixer/ssm_conv",
+                "ssm_mixer/" + recurrence, "ssm_mixer/ssm_gate_norm",
+                "ssm_mixer/ssm_out"},
+        "moe": {"mlp/moe_router", "mlp/moe_experts",
+                "mlp/moe_shared_expert"},
+        "full": {"attention/qkv_proj", "attention/kv_write",
+                 "attention/attn_kernel", "attention/out_proj"}}
+    tops = {"ssm": "ssm_mixer", "moe": "mlp", "full": "attention"}
+    for kind, scopes in zip(cfg.layer_kinds, runs):
+        text = "\n".join(sorted(scopes))
+        for scope in wants[kind]:
+            assert any(scope in s for s in scopes), (kind, scope, text)
+        for k, top in tops.items():
+            if k != kind:
+                assert not any(re.search(r"(^|/)%s(/|$)" % top, s)
+                               for s in scopes), (kind, top, text)
+        assert other + "/" not in text and not text.endswith(other)
+
+
 def _instructions(text):
     return len(re.findall(r"^\s*(?:ROOT\s+)?%?[\w.\-]+ = ", text, re.M))
 

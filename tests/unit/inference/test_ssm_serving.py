@@ -93,8 +93,12 @@ def test_the_pattern_is_written_down_as_the_source_spells_it():
         assert word in cfg.served_only, word
     assert paged_model._layer_runs(cfg) == [
         ("ssm", True, 0, 5), ("full", True, 5, 1), ("ssm", True, 6, 4)]
-    with pytest.raises(ValueError, match="mamba_n_heads x mamba_d_head"):
-        TransformerConfig(**{**TOY, "mamba_d_head": 8})
+    # the inner width is heads x head width whatever mamba_expand says
+    # (PR 52); what is held is heads a whole multiple of the groups
+    assert TransformerConfig(**{**TOY, "mamba_d_head": 8}).mamba_d_inner \
+        == 64
+    with pytest.raises(ValueError, match="multiple of mamba_n_groups"):
+        TransformerConfig(**{**TOY, "mamba_n_groups": 3})
     with pytest.raises(NotImplementedError, match="give layer_types"):
         TransformerConfig(hidden_size=64, num_heads=4, residual_scale=0.5)
 

@@ -1280,6 +1280,262 @@ def test_the_state_space_decode_window_compiles_with_its_state_in_place(
 
 
 # ---------------------------------------------------------------------------
+# layers of ONE sub-layer (Mamba-2 with B and C a group of heads | relu^2
+# experts | attention 32 / 2 x 128):
+# nemotron-3-nano-30b-a3b.rollout-128x256-384's geometry
+# ---------------------------------------------------------------------------
+def _nemotron_fields():
+    import json
+    from pathlib import Path
+    return json.loads((Path(__file__).resolve().parents[3] / "benchmark"
+                       / "configs/nemotron-3-nano-30b-a3b.json")
+                      .read_text())["fields"]
+
+
+@pytest.mark.parametrize("kept", [jnp.float32, jnp.bfloat16])
+def test_the_ssm_state_kernel_at_eight_groups(tpu_sharding, kept):
+    """``ssm_state_update`` at the cell's decode shape (128 rows, 64
+    heads of 64 as 32 lane blocks of channels, B and C [8, 128] a token,
+    a leaf of 7 layers and 129 slots, float32 and the control's
+    bfloat16): a group is four lane blocks and a grid step of sixteen
+    takes four groups' pairs; ONE custom call under the name a trace
+    finds, the leaf aliased: what the program keeps beside its
+    arguments are the rows' B and C spread over the lanes (2 x 67 MB at
+    8 groups) and their decay, ``dt x`` and output."""
+    from deepspeed_tpu.inference.v2.kernels import state_space as ss
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=tpu_sharding)
+
+    N, nh, p, n, g = 128, 64, 64, 128, 8
+    leaf = sds(ss.state_leaf_shape(7, 129, nh * p, n), kept)
+    assert leaf.shape == (7, 129, 32, 128, 128) \
+        and ss.state_kernel_serves(leaf, g)
+    compiled = jax.jit(ss.ssm_state_update, donate_argnums=(0,)).lower(
+        leaf, sds((), jnp.int32), sds((N,), jnp.int32), sds((N,), jnp.bool_),
+        sds((N, nh * p)), sds((N, nh)), sds((nh,)), sds((N, g * n)),
+        sds((N, g * n))).compile()
+    kernels = _custom_calls(compiled)
+    assert len(kernels) == 1 and kernels[0].startswith("ssm_state_update")
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.16e9
+
+
+def test_the_ssm_chunk_kernel_at_eight_groups(tpu_sharding):
+    """``ssm_chunk_fwd`` for the cell's ragged launch (128 rows of 128
+    tokens, x and the 8 groups' B and C the ONE bf16 buffer the
+    convolution leaves, 6,144 wide): a grid step (four lane blocks) is
+    one group and takes its B and C where they lie: ONE custom call, the
+    leaf aliased, no slice of the token buffer (no temporary)."""
+    from deepspeed_tpu.inference.v2.kernels import state_space as ss
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=tpu_sharding)
+
+    R, T, nh, p, n, g = 128, 128 * 128, 64, 64, 128, 8
+    leaf = sds(ss.state_leaf_shape(7, 129, nh * p, n))
+    assert ss.chunk_kernel_serves(leaf, p, g)
+    assert not ss.chunk_kernel_serves(leaf, p, 16)      # half a grid step
+    compiled = jax.jit(ss.ssm_chunk_fwd, donate_argnums=(0,)).lower(
+        leaf, sds((), jnp.int32), sds((R,), jnp.int32), sds((R,), jnp.bool_),
+        sds((R,), jnp.int32), sds((R,), jnp.int32),
+        sds((T, nh * p + 2 * g * n), jnp.bfloat16), sds((T, nh)),
+        sds((nh,))).compile()
+    kernels = _custom_calls(compiled)
+    assert len(kernels) == 1 and kernels[0].startswith("ssm_chunk_fwd")
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.05e9
+
+
+# sha256 (16 hex) of the jaxprs of granite's one-group kernel calls at its
+# published shapes, kernel bodies and index maps included, addresses struck
+# out, read on the parent commit (e16ef18) by the test below. The lowered
+# TEXT carries the kernels' source lines (Mosaic's payload embeds them), so
+# it moves with every edit of the file; the jaxpr is what is lowered
+GRANITE_KERNEL_JAXPRS = {"ssm_state_update": "a6ea15075c99f564",
+                         "ssm_chunk_fwd": "9457ed25af6cccce"}
+
+
+@pytest.mark.parametrize("kernel", sorted(GRANITE_KERNEL_JAXPRS))
+def test_granites_one_group_calls_are_the_kernels_they_were(kernel):
+    import hashlib
+    from deepspeed_tpu.inference.v2.kernels import state_space as ss
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    N, nh, p, n = 64, 128, 64, 128
+    leaf = sds(ss.state_leaf_shape(9, 65, nh * p, n))
+    if kernel == "ssm_state_update":
+        jaxpr = jax.make_jaxpr(ss.ssm_state_update)(
+            leaf, sds((), jnp.int32), sds((N,), jnp.int32),
+            sds((N,), jnp.bool_), sds((N, nh * p)), sds((N, nh)), sds((nh,)),
+            sds((N, n)), sds((N, n)))
+    else:
+        R, T = 64, 64 * 256
+        jaxpr = jax.make_jaxpr(ss.ssm_chunk_fwd)(
+            leaf, sds((), jnp.int32), sds((R,), jnp.int32),
+            sds((R,), jnp.bool_), sds((R,), jnp.int32), sds((R,), jnp.int32),
+            sds((T, nh * p + 2 * n), jnp.bfloat16), sds((T, nh)),
+            sds((nh,)))
+    text = re.sub(r"0x[0-9a-f]+", "0x", str(jaxpr))
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] \
+        == GRANITE_KERNEL_JAXPRS[kernel]
+
+
+@pytest.mark.parametrize("rows", [768, 24576])
+def test_the_relu2_grouped_matmul_at_published_widths(tpu_sharding, rows):
+    """The two-matrix expert at 64 experts of 2,688 x 1,856 a layer (7
+    layers' stack, read in place), a decode step's 768 picks and a run
+    of 4,096 prompt tokens' 24,576: an expert is 10 MB, over the 4 MiB
+    tile, and goes as three tiles of 896 of its 2,688 columns beside all
+    1,856 rows (1,856 is 14.5 lane blocks: it is never the last axis of
+    a tile, ``up`` being stored out x in); two ``gmm`` custom calls and
+    NO copy of an expert stack (4.5 GB: the first build's [.., 2,688,
+    1,856] leaf was laid out with 2,688 last by the compiler and copied
+    every launch)."""
+    from deepspeed_tpu.moe import sharded_moe as moe
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=tpu_sharding)
+
+    L, E, H, F = 7, 64, 2688, 1856
+    wu, wd = sds((L * E, F, H)), sds((L * E, F, H))
+    assert moe.gmm_serves((wu, wd))
+    assert moe._gmm_columns(wu) == moe._gmm_columns(wd) == 896
+    assert not moe.gmm_serves((sds((L * E, H, F)), wd))
+    compiled = jax.jit(moe.gmm_relu2_experts).lower(
+        (wu, wd), sds((rows, H)), sds((L * E,), jnp.int32)).compile()
+    kernels = _custom_calls(compiled)
+    assert len(kernels) == 2 and all(GMM_PATTERN.search(k) for k in kernels)
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.2e9
+
+
+@pytest.mark.parametrize("launch,T,one_token,queries", [
+    ("prompt", 16384, False, "bf16[2,16,16384,128]"),
+    ("decode", 128, True, "bf16[128,2,16,128]")])
+def test_the_tiled_kernel_at_group_sixteen(tpu_sharding, launch, T,
+                                           one_token, queries):
+    """32 query heads on 2 key / value heads of 128 (group 16: the
+    widest an accepted cell ran was 8) over a pool row of 256 lanes, 128
+    rows of 640 positions, two layers: the cell's ragged step and its
+    decode step (the one-token form), each ONE tiled launch under the
+    name the share readers find, nothing as large as a layer of the
+    pool beside it."""
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=tpu_sharding)
+
+    assert kernel_variant(128, 2, False) == "tiled"
+    pool = sds((2, 5249, 16, 256), jnp.bfloat16)
+    args = [sds((T, 32, 128), jnp.bfloat16), pool, pool, sds((), jnp.int32),
+            sds((T,), jnp.int32), sds((T,), jnp.int32),
+            sds((128, 40), jnp.int32)]
+    compiled = jax.jit(lambda *a: ragged_attention(
+        *a, one_token=one_token)).lower(*args).compile()
+    calls = [line for line in compiled.as_text().splitlines()
+             if "tpu_custom_call" in line]
+    assert len(calls) == 1, calls
+    assert re.match(r"\s*%ragged_attention_tiled[_.0-9]* = ", calls[0])
+    assert queries in calls[0], calls[0][:400]
+    layer = 2 * 5249 * 16 * 256
+    assert compiled.memory_analysis().temp_size_in_bytes < max(
+        layer / 4, 8 * T * 32 * 128)
+
+
+def _one_sublayer_cut(tpu_sharding):
+    """The pattern at published widths, cut to its first six layers
+    (mamba, experts, mamba, experts, mamba, attention; 8 experts held,
+    4,096 rows of the vocabulary): the configuration, and its parameters
+    and cache (33 state slots, 129 blocks) as shapes on the chip."""
+    from deepspeed_tpu.inference.v2.paged_model import init_paged_kv_cache
+    from deepspeed_tpu.models import TransformerLM
+    from deepspeed_tpu.models.transformer import TransformerConfig
+
+    fields = _nemotron_fields()
+    cfg = TransformerConfig(**{
+        **fields, "num_layers": 6, "moe_experts_held": 8,
+        "layer_types": fields["layer_types"][:6], "vocab_size": 4096})
+
+    def on_tpu(x, dtype=None):
+        return jax.ShapeDtypeStruct(x.shape, dtype or x.dtype,
+                                    sharding=tpu_sharding)
+
+    params = jax.tree.map(
+        lambda x: on_tpu(x, jnp.bfloat16),
+        jax.eval_shape(TransformerLM(cfg).init_params,
+                       jax.random.PRNGKey(0)))
+    cache = jax.tree.map(on_tpu, jax.eval_shape(
+        lambda: init_paged_kv_cache(cfg, 129, 16, jnp.bfloat16,
+                                    state_slots=32)))
+    assert cache["ssm_state"].shape == (3, 33, 32, 128, 128) \
+        and cache["ssm_conv"].shape == (3, 33, 3, 48, 128) \
+        and cache["k_full"].shape == (1, 129, 16, 256)
+    return cfg, params, cache
+
+
+def test_the_one_sublayer_ragged_step_runs_its_kernels(tpu_sharding):
+    """The ragged step of the cut, 32 rows in 2,048 tokens: six runs of
+    one layer; the chunk kernel in the three mamba layers, two grouped
+    matmuls in each of the two expert layers (none behind a mixer), the
+    tiled attention kernel once; nothing under ``ssm_scan`` loops in
+    XLA."""
+    from deepspeed_tpu.inference.v2.paged_model import paged_ragged_step
+
+    cfg, params, cache = _one_sublayer_cut(tpu_sharding)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=tpu_sharding)
+
+    T, R = 2048, 32
+    compiled = jax.jit(
+        lambda p, ids, rows, pos, ln, wb, wo, bt, li, c, ss:
+        paged_ragged_step(cfg, p, ids, rows, pos, ln, wb, wo, bt, li, c, 16,
+                          use_kernel=True, state_slots=ss),
+        donate_argnums=(9,)).lower(
+        params, i32(T), i32(T), i32(T), i32(T), i32(T), i32(T), i32(R, 16),
+        i32(R), cache, i32(R)).compile()
+    kernels = _custom_calls(compiled)
+    assert sum(k.startswith("ssm_chunk_fwd") for k in kernels) == 3, kernels
+    assert sum(k.startswith("ragged_attention_tiled")
+               for k in kernels) == 1, kernels
+    assert sum(bool(GMM_PATTERN.search(k)) for k in kernels) == 4, kernels
+    assert "ssm_scan/while" not in compiled.as_text()
+
+
+def test_the_one_sublayer_decode_window_compiles_with_its_state_in_place(
+        tpu_sharding):
+    """The decode window of the same cut: the state kernel and the
+    convolution's kernel in the three mamba layers, the tiled attention
+    kernel (its one-token form) and four grouped matmuls beside them;
+    nothing under ``ssm_conv`` or ``ssm_state`` gathers or scatters the
+    slots in XLA, and the program's temporaries hold no copy of the
+    state leaf (32 rows x 3 layers: 0.2 GB) or of an expert stack."""
+    from deepspeed_tpu.inference.v2.paged_model import paged_decode_window
+
+    cfg, params, cache = _one_sublayer_cut(tpu_sharding)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=tpu_sharding)
+
+    R = 32
+    compiled = jax.jit(
+        lambda p, t, pos, bt, c, sl, eos, alive, ss: paged_decode_window(
+            cfg, p, t, pos, bt, c, sl, eos, 16, 8, use_kernel=True,
+            alive=alive, state_slots=ss), donate_argnums=(4,)).lower(
+        params, i32(R), i32(R), i32(R, 16), cache, i32(R), i32(R),
+        jax.ShapeDtypeStruct((R,), jnp.bool_, sharding=tpu_sharding),
+        i32(R)).compile()
+    text = compiled.as_text()
+    kernels = _custom_calls(compiled)
+    for name, count in (("ssm_state_update", 3), ("ssm_conv_update", 3),
+                        ("ragged_attention_tiled", 1)):
+        assert sum(k.startswith(name) for k in kernels) == count, kernels
+    assert sum(bool(GMM_PATTERN.search(k)) for k in kernels) == 4, kernels
+    under = re.findall(
+        r"= \S+ ([\w\-]+)\([^\n]*op_name=\"[^\"]*/ssm_(?:conv|state)/", text)
+    assert under and not {"gather", "scatter"} & set(under), under
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.15e9
+
+
+# ---------------------------------------------------------------------------
 # ZeRO-3 at dp 4: what the scanned backward moves between chips
 # ---------------------------------------------------------------------------
 ZERO3_MICRO, ZERO3_DP, ZERO3_HIDDEN, ZERO3_FFN = 2, 4, 256, 1024
