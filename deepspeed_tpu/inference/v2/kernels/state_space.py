@@ -32,7 +32,10 @@ over sublanes.
 * one token a row (decode): :func:`ssm_step` in XLA (gather, update,
   scatter), and the kernel :func:`ssm_state_update`, which takes a row's
   state out of its slot once and puts it back once, aliased
-  (``kda_state_update``'s manner).
+  (``kda_state_update``'s manner), and a token's B and C at their own
+  size, a group a row of ``d_state`` values: it spreads a pair over the
+  lanes in VMEM, once for all the lane blocks that read it, and no copy
+  of a pair a lane ever lies in HBM.
 * a row's prompt tokens, CHUNKED: inside a chunk of ``Q`` tokens with
   ``La`` the inclusive cumulative ``dt A``,
   ``y_t = exp(La_t) C_t S_0 + sum_{s <= t} (C_t . B_s) exp(La_t - La_s)
@@ -154,23 +157,25 @@ def state_kernel_serves(leaf, groups=1) -> bool:
 
 
 def _state_kernel(layer_ref, slots_ref, fresh_ref, s_ref, decay_ref, dtx_ref,
-                  b_ref, c_ref, so_ref, y_ref, *, groups, per):
-    """One row's ``groups`` lane blocks of channels through one token: a
-    block's state [d_state, 128], its channels' decay and ``dt x`` rows
-    of 128 lanes, ``B`` and ``C`` spread over the lanes [d_state, 128],
-    one pair every ``per`` blocks where the step spans several groups
-    of them (their pairs one under another)."""
+                  b_ref, c_ref, so_ref, y_ref, b_sc, c_sc, *, per):
+    """One row's lane blocks of channels through one token: a block's
+    state [d_state, 128], its channels' decay and ``dt x`` rows of 128
+    lanes. ``b_ref`` and ``c_ref`` [pairs, d_state] are the pairs of the
+    groups this step's blocks lie in, a group a row as the convolution
+    leaves it; a group's pair is spread over the lanes ONCE, into
+    ``b_sc`` / ``c_sc`` [d_state, 128] (the row over the sublanes, then
+    one transpose), and serves its ``per`` blocks from there."""
     del layer_ref, slots_ref            # the index maps read them
     keep = fresh_ref[pl.program_id(0)] == 0
-    n = s_ref.shape[1]
-    b, c = b_ref[...], c_ref[...]
-    for j in range(groups):
-        bj, cj = (b, c) if b.shape[0] == n else (
-            v[j // per * n:(j // per + 1) * n] for v in (b, c))
-        s = jnp.where(keep, s_ref[j].astype(jnp.float32), 0.0)
-        s = s * decay_ref[j:j + 1, :] + bj * dtx_ref[j:j + 1, :]
-        so_ref[j] = s.astype(so_ref.dtype)
-        y_ref[j:j + 1, :] = jnp.sum(s * cj, axis=0, keepdims=True)
+    blocks, n, W = s_ref.shape
+    for k in range(b_ref.shape[0]):
+        for ref, sc in ((b_ref, b_sc), (c_ref, c_sc)):
+            sc[...] = jnp.broadcast_to(ref[k:k + 1, :], (W, n)).T
+        for j in range(k * per, min((k + 1) * per, blocks)):
+            s = jnp.where(keep, s_ref[j].astype(jnp.float32), 0.0)
+            s = s * decay_ref[j:j + 1, :] + b_sc[...] * dtx_ref[j:j + 1, :]
+            so_ref[j] = s.astype(so_ref.dtype)
+            y_ref[j:j + 1, :] = jnp.sum(s * c_sc[...], axis=0, keepdims=True)
 
 
 def ssm_state_update(leaf, layer, slots, fresh, x, dt, a, b, c,
@@ -181,9 +186,10 @@ def ssm_state_update(leaf, layer, slots, fresh, x, dt, a, b, c,
     ``layer`` (both prefetched scalars), puts them through the token and
     copies them back to where they came from (aliased): a state is read
     once and written once, where a gather, the update and a scatter
-    move it three times. B and C [N, groups * d_state] go in spread
-    over the lanes, a group's pair under the last's, and a grid step
-    takes the pairs of the groups its lane blocks lie in. A trace shows
+    move it three times. B and C [N, groups * d_state] go in at their
+    own size, a group a row ``[N, .., pairs, d_state]``, and a grid step
+    takes the ``pairs`` rows of the groups its lane blocks lie in (a few
+    KB): the kernel spreads a pair over the lanes in VMEM. A trace shows
     it as ``ssm_state_update``."""
     N, C = x.shape
     G, n, W = leaf.shape[2:]
@@ -194,27 +200,26 @@ def ssm_state_update(leaf, layer, slots, fresh, x, dt, a, b, c,
     span = max(gb, per)                 # lane blocks a block of pairs serves
     decay = _channels(jnp.exp(dt * a), d_head).reshape(N, G, W)
     dtx = (_channels(dt, d_head) * x).reshape(N, G, W)
-    spread = [jnp.broadcast_to(v[:, :, None], (N, v.shape[1], W))
-              for v in (b, c)]
     row = pl.BlockSpec((None, gb, W), lambda r, g, *_: (r, g, 0))
-    shared = pl.BlockSpec(
-        (None, pairs * n, W),
-        lambda r, g, *_: (r, g * gb // span if per < G else 0, 0))
+    shared = pl.BlockSpec((None, None, pairs, n),
+                          lambda r, g, *_: (r, g * gb // span, 0, 0))
     state = pl.BlockSpec(
         (None, None, gb, n, W),
         lambda r, g, layer, slots, fresh: (layer[0], slots[r], g, 0, 0))
     so, y = pl.pallas_call(
-        functools.partial(_state_kernel, groups=gb, per=per),
+        functools.partial(_state_kernel, per=per),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3, grid=(N, G // gb),
             in_specs=[state, row, row, shared, shared],
-            out_specs=[state, row]),
+            out_specs=[state, row],
+            scratch_shapes=[pltpu.VMEM((n, W), jnp.float32)] * 2),
         out_shape=[jax.ShapeDtypeStruct(leaf.shape, leaf.dtype),
                    jax.ShapeDtypeStruct((N, G, W), jnp.float32)],
         input_output_aliases={3: 0},
         name="ssm_state_update", interpret=interpret,
     )(jnp.reshape(layer, (1,)).astype(jnp.int32), slots.astype(jnp.int32),
-      fresh.astype(jnp.int32), leaf, decay, dtx, *spread)
+      fresh.astype(jnp.int32), leaf, decay, dtx,
+      *(v.reshape(N, -1, pairs, n) for v in (b, c)))
     return y.reshape(N, C), so
 
 

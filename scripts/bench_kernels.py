@@ -13,6 +13,12 @@ Beside each time, the launch's BYTES at the chip's peak (the pages its rows
 hold, once a row; the window's where there is one): what a launch cannot go
 under.
 
+``ssm_state_update`` (``kernels/state_space.py``) stands beside them at the
+two state-space cells' decode shapes, the launch as ``paged_model`` makes it
+(decay, ``dt x`` and the pairs' reshape are XLA's, in the time): the leaf is
+carried through the loop, aliased, and the bytes are the rows' states read
+once and written once.
+
 The variants take the walk apart by replacing one function of
 ``kernels/ragged_attention.py`` in this process (nothing a cell runs is
 touched, and no option of the program exists for it):
@@ -21,7 +27,8 @@ touched, and no option of the program exists for it):
 * ``copies``         the same walk with the products taken out
                      (``_tile_update`` / ``_blocks_update`` /
                      ``_latent_update`` do nothing): starts, waits and
-                     the loop
+                     the loop; of the state update, the grid's copies of
+                     the states in and back with the token taken out
 * ``page-waits``     a wait a page, as before PR 50           (--sweep)
 * ``block-by-block`` the tiled kernel's lane blocks updated one behind
                      the other, as before PR 50               (--sweep)
@@ -52,6 +59,7 @@ import numpy as np                                            # noqa: E402
 # the package exports a function under the module's name
 ra = importlib.import_module(                                 # noqa: E402
     "deepspeed_tpu.inference.v2.kernels.ragged_attention")
+from deepspeed_tpu.inference.v2.kernels import state_space as ss  # noqa: E402
 
 PEAK_BYTES_S = 819e9            # TPU v5e (benchmark/peaks.json)
 
@@ -71,8 +79,46 @@ SHAPES = {
                            nh=32, ctx=(8192, 8704), window=2048, ring=193),
     "granite-full": dict(kernel="tiled", rows=64, layers=1, kvh=8, hd=128,
                          nh=32, ctx=(1024, 1280), window=0, ring=0),
+    # the one-token state-space update: heads of ``p`` channels, ``groups``
+    # pairs of B and C a token, a state of ``n`` a channel
+    "nemotron-state": dict(kernel="ssm_state", rows=128, layers=7, nh=64,
+                           p=64, n=128, groups=8),
+    "granite-state": dict(kernel="ssm_state", rows=64, layers=9, nh=128,
+                          p=64, n=128, groups=1),
 }
 BS = 16
+
+
+def build_state(shape, rng, rehearse):
+    """One launch of ``ssm_state_update``: ``(fn(x, layer, leaf) -> (y,
+    leaf), again, x, (leaf,), layers, the states' bytes a launch,
+    reference)``; every row a slot of its own, out of order."""
+    rows, L, nh = shape["rows"], shape["layers"], shape["nh"]
+    p, n, groups = shape["p"], shape["n"], shape["groups"]
+    if rehearse:
+        rows, L, nh = 3, 2, max(16, 2 * groups)
+    key = jax.random.PRNGKey(int(rng.integers(1 << 30)))
+    f = lambda i, *s: jax.random.normal(jax.random.fold_in(key, i), s)
+    leaf = f(0, *ss.state_leaf_shape(L, rows + 1, nh * p, n))
+    x, b, c = f(1, rows, nh * p), f(2, rows, groups * n), f(3, rows,
+                                                            groups * n)
+    dt = jax.random.uniform(jax.random.fold_in(key, 4), (rows, nh),
+                            minval=0.01, maxval=1.0)
+    a = -jax.random.uniform(jax.random.fold_in(key, 5), (nh,), minval=1.0,
+                            maxval=16.0)
+    slots = jnp.asarray(rng.permutation(rows) + 1, jnp.int32)
+    fresh = jnp.zeros(rows, bool)
+
+    def fn(x, layer, leaf):
+        return ss.ssm_state_update(leaf, layer, slots, fresh, x, dt, a, b,
+                                   c, interpret=rehearse)
+
+    def ref(x, layer, leaf):
+        return ss.ssm_step(leaf, layer, slots, fresh, x, dt, a, b, c)
+
+    def again(x, y):
+        return x + y * 0
+    return fn, again, x, (leaf,), L, 2 * rows * leaf[0, 0].nbytes, ref
 
 
 def build(shape, rng, rehearse):
@@ -145,20 +191,20 @@ def build(shape, rng, rehearse):
 
 
 @contextlib.contextmanager
-def patched(**attrs):
-    """``ra``'s attributes replaced for the block; a KeyError names the
-    one this tree lacks."""
-    missing = [a for a in attrs if not hasattr(ra, a)]
+def patched(module, **attrs):
+    """``module``'s attributes replaced for the block; a KeyError names
+    the one this tree lacks."""
+    missing = [a for a in attrs if not hasattr(module, a)]
     if missing:
         raise KeyError(missing[0])
-    old = {a: getattr(ra, a) for a in attrs}
+    old = {a: getattr(module, a) for a in attrs}
     for a, new in attrs.items():
-        setattr(ra, a, new)
+        setattr(module, a, new)
     try:
         yield
     finally:
         for a, was in old.items():
-            setattr(ra, a, was)
+            setattr(module, a, was)
 
 
 def _nothing(*a, **k):
@@ -180,7 +226,17 @@ def _block_by_block(q, k, v, visible, acc_sc, m_sc, l_sc, *, scale):
                         b, scale=scale)
 
 
+def _state_copies(layer_ref, slots_ref, fresh_ref, s_ref, decay_ref, dtx_ref,
+                  b_ref, c_ref, so_ref, y_ref, *scratch, **static):
+    """:func:`ss._state_kernel` with the token taken out: a grid step's
+    states copied in and copied back as they came."""
+    so_ref[...] = s_ref[...]
+    y_ref[...] = dtx_ref[...]
+
+
 def variants(kernel, sweep):
+    if kernel == "ssm_state":
+        return {"full": {}, "copies": dict(_state_kernel=_state_copies)}
     # the products of this tree's kernels, whichever it has (the parent
     # of PR 50 ran ``_tile_update`` in both forms of the tiled kernel and
     # kept the latent kernel's products inline)
@@ -197,19 +253,30 @@ def variants(kernel, sweep):
     return out
 
 
-def time_launches(fn, again, q, pools, L, launches):
+def time_launches(fn, again, q, pools, L, launches, carried=False):
     """us a launch: ``launches`` of them in one program, best of three.
     A fresh ``jit`` a call: its cache does not see the attributes a
-    variant replaces."""
+    variant replaces. ``carried``: ``fn`` returns its pool beside its
+    output (a state leaf, updated in place), and the loop hands it on:
+    the program is given a copy of the caller's to consume."""
+    def step(i, qp):
+        q, pools = qp
+        out = fn(q, i % L, *pools)
+        out, pools = (out[0], out[1:]) if carried else (out, pools)
+        return again(q, out), pools
+
     def many(q, *pools):
-        return jax.lax.fori_loop(
-            0, launches, lambda i, q: again(q, fn(q, i % L, *pools)), q)
-    run = jax.jit(many)
-    run(q, *pools).block_until_ready()
+        return jax.lax.fori_loop(0, launches, step, (q, pools))
+    run = jax.jit(many, donate_argnums=(1,) if carried else ())
+    if carried:
+        pools = tuple(jnp.copy(p) for p in pools)
+    q, pools = run(q, *pools)
+    jax.block_until_ready(q)
     best = float("inf")
     for _ in range(3):
         t0 = time.perf_counter()
-        run(q, *pools).block_until_ready()
+        q, pools = run(q, *pools)
+        jax.block_until_ready(q)
         best = min(best, time.perf_counter() - t0)
     return best / launches * 1e6
 
@@ -233,20 +300,23 @@ def main():
     names = [n for n in args.only.split(",") if n] or list(SHAPES)
     for name in names:
         rng = np.random.default_rng(args.seed)
-        fn, again, q, pools, L, nbytes, ref = build(SHAPES[name], rng,
-                                                    args.rehearse)
+        state = SHAPES[name]["kernel"] == "ssm_state"
+        fn, again, q, pools, L, nbytes, ref = (
+            build_state if state else build)(SHAPES[name], rng, args.rehearse)
         row = {"shape": name, "bytes": nbytes,
                "bytes_us": round(nbytes / PEAK_BYTES_S * 1e6, 2)}
         if args.check:
-            got = np.asarray(jax.jit(fn)(q, L - 1, *pools), np.float32)
-            want = np.asarray(jax.jit(ref)(q, L - 1, *pools), np.float32)
-            row["max_err"] = float(np.abs(got - want).max())
+            got, want = (jax.tree.leaves(jax.jit(f)(q, L - 1, *pools))
+                         for f in (fn, ref))
+            row["max_err"] = max(float(jnp.abs(g - w).max())
+                                 for g, w in zip(got, want))
         for label, attrs in variants(SHAPES[name]["kernel"],
                                      args.sweep).items():
             try:
-                with patched(**attrs):
+                with patched(ss if state else ra, **attrs):
                     row[label] = round(time_launches(
-                        fn, again, q, pools, L, args.launches), 2)
+                        fn, again, q, pools, L, args.launches,
+                        carried=state), 2)
             except KeyError as missing:
                 row[label] = f"no {missing.args[0]} in this tree"
         print(json.dumps(row), flush=True)

@@ -334,16 +334,9 @@ def test_chunk_kernel_is_the_token_scan_under_groups(groups, nh, p, lengths,
     _against_the_scan(case, y, leaf, nh, groups)
 
 
-@pytest.mark.parametrize("groups,nh,p,n", [
-    (1, 4, 16, 32), (2, 4, 16, 32), (8, 8, 16, 32),     # ssm_step alone
-    (1, 16, 64, 128), (2, 16, 64, 128), (8, 16, 64, 128), (4, 64, 64, 128)])
-def test_one_token_forms_are_the_token_scan_under_groups(groups, nh, p, n):
-    """``ssm_step`` and, where the channels are whole lane blocks, the
-    kernel ``ssm_state_update`` (interpreted) on three rows' slots: one
-    token of the scan with each head reading ITS group's B and C (a grid
-    step of 8 or 16 lane blocks spans several groups; at 64 heads a
-    group is 8 lane blocks and a grid step two groups, the published
-    geometry's kind), the other slots and the other layer untouched."""
+def _one_token_case(groups, nh, p, n):
+    """(leaf, layer, slots, fresh, x, dt, a, b, c) of three rows, the
+    middle one fresh between two kept ones."""
     rng = np.random.default_rng(1)
     C, N = nh * p, 3
     f = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)
@@ -351,12 +344,42 @@ def test_one_token_forms_are_the_token_scan_under_groups(groups, nh, p, n):
     x, b, c = f(N, C), f(N, groups * n), f(N, groups * n)
     dt = jnp.asarray(rng.uniform(0.01, 1.0, (N, nh)), jnp.float32)
     a = -jnp.asarray(rng.uniform(1, 16, (nh,)), jnp.float32)
-    slots, fresh = jnp.asarray([2, 4, 1]), jnp.asarray([False, True, False])
+    return (leaf0, jnp.int32(1), jnp.asarray([2, 4, 1]),
+            jnp.asarray([False, True, False]), x, dt, a, b, c)
+
+
+@pytest.mark.parametrize("groups,nh,p,n", [
+    (1, 4, 16, 32), (2, 4, 16, 32), (8, 8, 16, 32),     # ssm_step alone
+    # 8 lane blocks, fewer than a grid step's 16: one step a row
+    (1, 16, 64, 128), (2, 16, 64, 128), (8, 16, 64, 128),
+    (4, 64, 64, 128),       # 32 lane blocks: a grid step two groups
+    (8, 64, 64, 128),       # the published geometry: a grid step four
+    (1, 128, 64, 128),      # 64 lane blocks: a group four grid steps
+    (2, 128, 64, 128)])     # 64 lane blocks: a group two grid steps
+def test_one_token_forms_are_the_token_scan_under_groups(groups, nh, p, n):
+    """``ssm_step`` and, where the channels are whole lane blocks, the
+    kernel ``ssm_state_update`` (interpreted) on three rows' slots, a
+    fresh row between two kept ones: one token of the scan with each
+    head reading ITS group's B and C, the other slots and the other
+    layer untouched. The kernel takes the pairs a group a row and
+    spreads them in VMEM, so the cases are the block shapes that makes
+    delicate: a grid step that spans several groups (8 groups over 8
+    and over 32 lane blocks), a group that spans several grid steps (1
+    and 2 groups over 64), fewer lane blocks than a step's 16. Its
+    state and ``y`` are ``ssm_step``'s too (the same float32 operations
+    on the same values, a compiled product and sum rounding once where
+    the eager ones round twice), and the leaf goes in aliased to the
+    leaf that comes out."""
+    args = _one_token_case(groups, nh, p, n)
+    leaf0, _, slots, fresh, x, dt, a, b, c = args
+    C, N = nh * p, x.shape[0]
     forms = [ss.ssm_step] + [
         lambda *args: ss.ssm_state_update(*args, interpret=True)
     ] * (C % 1024 == 0)
+    out = []
     for form in forms:
-        y, leaf = form(leaf0, jnp.int32(1), slots, fresh, x, dt, a, b, c)
+        y, leaf = form(*args)
+        out.append((y, leaf))
         for r in range(N):
             s0 = jnp.where(fresh[r], 0.0, ss.heads_of(leaf0[1, slots[r]], nh))
             s1, want = _scan(x[r:r + 1].reshape(1, nh, p), dt[r:r + 1], a,
@@ -366,6 +389,12 @@ def test_one_token_forms_are_the_token_scan_under_groups(groups, nh, p, n):
                         np.asarray(s1)) <= F32_TIGHT
         np.testing.assert_array_equal(leaf[0], leaf0[0])
         np.testing.assert_array_equal(leaf[1, 3], leaf0[1, 3])
+    if len(out) == 2:
+        for step, kernel in zip(*out):
+            assert _err(kernel, np.asarray(step)) <= F32_TIGHT
+        (call,) = [e for e in jax.make_jaxpr(forms[1])(*args).eqns
+                   if e.primitive.name == "pallas_call"]
+        assert call.params["input_output_aliases"] == ((3, 0),)
 
 
 def test_the_kernels_say_no_where_a_lane_block_would_straddle_groups(

@@ -320,11 +320,9 @@ def test_chunk_kernel_is_the_token_scan(nh, p, lengths, T, chunk):
     _against_the_scan(case, y, leaf, nh)
 
 
-@pytest.mark.parametrize("nh,p,n", [(4, 16, 32), (16, 64, 128)])
-def test_one_token_forms_are_the_token_scan(nh, p, n):
-    """``ssm_step`` and the kernel ``ssm_state_update`` (interpreted) on
-    three rows' slots: one token of the scan, the other slots and the
-    other layer untouched."""
+def _one_token_case(nh, p, n):
+    """(leaf, layer, slots, fresh, x, dt, a, b, c) of three rows, the
+    middle one fresh between two kept ones."""
     rng = np.random.default_rng(1)
     C, N = nh * p, 3
     f = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)
@@ -332,12 +330,33 @@ def test_one_token_forms_are_the_token_scan(nh, p, n):
     x, b, c = f(N, C), f(N, n), f(N, n)
     dt = jnp.asarray(rng.uniform(0.01, 1.0, (N, nh)), jnp.float32)
     a = -jnp.asarray(rng.uniform(1, 16, (nh,)), jnp.float32)
-    slots, fresh = jnp.asarray([2, 4, 1]), jnp.asarray([False, True, False])
+    return (leaf0, jnp.int32(1), jnp.asarray([2, 4, 1]),
+            jnp.asarray([False, True, False]), x, dt, a, b, c)
+
+
+@pytest.mark.parametrize("nh,p,n", [
+    (4, 16, 32),            # half a lane block: ssm_step alone
+    (16, 64, 128),          # 8 lane blocks, fewer than a grid step's 16
+    (32, 64, 128),          # 16: one whole grid step a row
+    (128, 64, 128)])        # the cell's 64: the ONE group four grid steps
+def test_one_token_forms_are_the_token_scan(nh, p, n):
+    """``ssm_step`` and the kernel ``ssm_state_update`` (interpreted) on
+    three rows' slots, a fresh row between two kept ones: one token of
+    the scan, the other slots and the other layer untouched. The kernel
+    takes B and C as ONE row of ``d_state`` a token and spreads it in
+    VMEM every grid step of the row: its state and
+    ``y`` are ``ssm_step``'s too, and the leaf goes in aliased to the
+    leaf that comes out."""
+    args = _one_token_case(nh, p, n)
+    leaf0, _, slots, fresh, x, dt, a, b, c = args
+    C, N = nh * p, x.shape[0]
     forms = [ss.ssm_step] + [
         lambda *args: ss.ssm_state_update(*args, interpret=True)
     ] * (C % 128 == 0)
+    out = []
     for form in forms:
-        y, leaf = form(leaf0, jnp.int32(1), slots, fresh, x, dt, a, b, c)
+        y, leaf = form(*args)
+        out.append((y, leaf))
         for r in range(N):
             s0 = jnp.where(fresh[r], 0.0, ss.heads_of(leaf0[1, slots[r]], nh))
             s1, want = _scan(x[r:r + 1].reshape(1, nh, p), dt[r:r + 1], a,
@@ -347,6 +366,12 @@ def test_one_token_forms_are_the_token_scan(nh, p, n):
                         np.asarray(s1)) <= F32_TIGHT
         np.testing.assert_array_equal(leaf[0], leaf0[0])
         np.testing.assert_array_equal(leaf[1, 3], leaf0[1, 3])
+    if len(out) == 2:
+        for step, kernel in zip(*out):
+            assert _err(kernel, np.asarray(step)) <= F32_TIGHT
+        (call,) = [e for e in jax.make_jaxpr(forms[1])(*args).eqns
+                   if e.primitive.name == "pallas_call"]
+        assert call.params["input_output_aliases"] == ((3, 0),)
 
 
 def test_conv_kernel_takes_one_input_and_a_bias():

@@ -1089,9 +1089,13 @@ def test_the_ssm_state_kernel_at_published_widths(tpu_sharding, kept):
     heads of 64 as 64 lane blocks of channels, a state of 128, a leaf of
     9 layers and 65 slots, float32 and the control's bfloat16): it
     compiles for the chip, runs as ONE custom call under a name a trace
-    finds, and the leaf is aliased (no copy of 2.45 GB: what the program
-    keeps beside its arguments are B and C spread over the lanes and the
-    rows' decay, ``dt x`` and output, under 0.03 GB)."""
+    finds, and the leaf is aliased (no copy of 2.45 GB). B and C go in
+    as the token's own 128 values, ``[64, 1, 1, 128]``: nothing of the
+    program is one of them spread over the lanes (``[64, 128, 128]``,
+    4.2 MB each until PR 53), and what it keeps beside its arguments
+    are the rows' decay, ``dt x`` and output, 2 MB each, which the
+    compiler holds outside HBM's temporaries (it reads 0 here, as it
+    did with the two spread pairs: under 0.01 GB holds both out)."""
     from deepspeed_tpu.inference.v2.kernels import state_space as ss
 
     def sds(shape, dtype=jnp.float32):
@@ -1107,7 +1111,9 @@ def test_the_ssm_state_kernel_at_published_widths(tpu_sharding, kept):
         sds((N, n))).compile()
     kernels = _custom_calls(compiled)
     assert len(kernels) == 1 and kernels[0].startswith("ssm_state_update")
-    assert compiled.memory_analysis().temp_size_in_bytes < 0.03e9
+    assert f"f32[{N},1,1,{n}]" in compiled.as_text()
+    assert f"f32[{N},{n},128]" not in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.01e9
 
 
 def test_the_ssm_chunk_kernel_at_published_widths(tpu_sharding):
@@ -1298,10 +1304,14 @@ def test_the_ssm_state_kernel_at_eight_groups(tpu_sharding, kept):
     heads of 64 as 32 lane blocks of channels, B and C [8, 128] a token,
     a leaf of 7 layers and 129 slots, float32 and the control's
     bfloat16): a group is four lane blocks and a grid step of sixteen
-    takes four groups' pairs; ONE custom call under the name a trace
-    finds, the leaf aliased: what the program keeps beside its
-    arguments are the rows' B and C spread over the lanes (2 x 67 MB at
-    8 groups) and their decay, ``dt x`` and output."""
+    takes four groups' pairs, four rows of 128 values each, ``[128, 2,
+    4, 128]``; ONE custom call under the name a trace finds, the leaf
+    aliased. Nothing of the program is B or C spread over the lanes
+    (``[128, 1024, 128]``: 2 x 67 MB a layer and step until PR 53, of
+    which the compiler read 0.0675 GB of temporaries here): what it
+    keeps beside its arguments are the rows' decay, ``dt x`` and output
+    and B and C at their own size (0.5 MB each), and it reads 0; under
+    0.03 GB."""
     from deepspeed_tpu.inference.v2.kernels import state_space as ss
 
     def sds(shape, dtype=jnp.float32):
@@ -1317,7 +1327,9 @@ def test_the_ssm_state_kernel_at_eight_groups(tpu_sharding, kept):
         sds((N, g * n))).compile()
     kernels = _custom_calls(compiled)
     assert len(kernels) == 1 and kernels[0].startswith("ssm_state_update")
-    assert compiled.memory_analysis().temp_size_in_bytes < 0.16e9
+    assert f"f32[{N},2,4,{n}]" in compiled.as_text()
+    assert f"f32[{N},{g * n},128]" not in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.03e9
 
 
 def test_the_ssm_chunk_kernel_at_eight_groups(tpu_sharding):
@@ -1347,10 +1359,13 @@ def test_the_ssm_chunk_kernel_at_eight_groups(tpu_sharding):
 
 # sha256 (16 hex) of the jaxprs of granite's one-group kernel calls at its
 # published shapes, kernel bodies and index maps included, addresses struck
-# out, read on the parent commit (e16ef18) by the test below. The lowered
-# TEXT carries the kernels' source lines (Mosaic's payload embeds them), so
-# it moves with every edit of the file; the jaxpr is what is lowered
-GRANITE_KERNEL_JAXPRS = {"ssm_state_update": "a6ea15075c99f564",
+# out, read by the test below: the chunk kernel's on PR 52's parent commit
+# (e16ef18), the one-token kernel's on PR 53's tree, which changed it on
+# purpose (B and C a row a group, spread in VMEM; a6ea15075c99f564 before).
+# The lowered TEXT carries the kernels' source lines (Mosaic's payload
+# embeds them), so it moves with every edit of the file; the jaxpr is what
+# is lowered
+GRANITE_KERNEL_JAXPRS = {"ssm_state_update": "0c608753769a2afb",
                          "ssm_chunk_fwd": "9457ed25af6cccce"}
 
 
