@@ -618,7 +618,8 @@ class InferenceEngineV2:
         self._m_moe_share.labels(program=program).set(share)
 
     def _init_telemetry(self):
-        from ...telemetry import get_registry
+        from ...telemetry import collector, get_registry
+        collector.install_gc_hook()
         reg = get_registry()
         self._m_moe_launches = reg.counter(
             "moe_launches_total",
@@ -1744,15 +1745,22 @@ class InferenceEngineV2:
                 jit_fn = (self._fused_greedy_jit if sampling is None
                           else self._fused_sample_jit)
             with trace.span("window_dispatch"):
-                state = behind.state if behind is not None else \
-                    jax.device_put((toks, pos, np.ones(N, bool)),
-                                   self._row_state_sharding)
-                out, state, *moe, self.kv_cache = jit_fn(
-                    self.params, state[0], state[1], jnp.asarray(tables),
-                    self.kv_cache, self._pad_i32(N, steps_left),
-                    jnp.asarray(eos), state[2], *extra, lb, aid,
-                    self._state_slots(uids, N),
-                    *self._window_tables(uids, N))
+                # a launch's two parts as leaves: the host arrays handed
+                # to the device, then the jit call alone
+                with trace.span("window_upload"):
+                    state = behind.state if behind is not None else \
+                        jax.device_put((toks, pos, np.ones(N, bool)),
+                                       self._row_state_sharding)
+                    tables_in, left_in, eos_in = (
+                        jnp.asarray(tables), self._pad_i32(N, steps_left),
+                        jnp.asarray(eos))
+                    slots_in = (self._state_slots(uids, N),
+                                *self._window_tables(uids, N))
+                with trace.span("window_call", program=jit_fn.program):
+                    out, state, *moe, self.kv_cache = jit_fn(
+                        self.params, state[0], state[1], tables_in,
+                        self.kv_cache, left_in, eos_in, state[2], *extra,
+                        lb, aid, *slots_in)
                 if behind is not None:
                     self._m_windows_ahead.inc()
                 self._note_kernel_steps(
@@ -1959,20 +1967,25 @@ class InferenceEngineV2:
                            if chunk is not None else {}),
                         **self._trace_attrs(u for u, _ in entries)) as step:
             with trace.span("ragged_dispatch"):
-                logits, *moe, self.kv_cache = self._ragged_jit(
-                    self.params, jnp.asarray(rb.ids),
-                    jnp.asarray(rb.row_ids), jnp.asarray(rb.positions),
-                    jnp.asarray(rb.lengths), jnp.asarray(rb.write_blocks),
-                    jnp.asarray(rb.write_offsets),
-                    jnp.asarray(rb.block_tables),
-                    jnp.asarray(rb.last_index), self.kv_cache,
-                    self.lora_bank,
-                    (jnp.asarray(rb.adapter_slots)
-                     if self.lora_bank is not None else None),
-                    (jnp.asarray(rb.state_slots)
-                     if self._has_state else None),
-                    *((jnp.asarray(rb.window_tables),)
-                      if self._has_ring else ()))
+                # a launch's two parts as leaves: the host arrays handed
+                # to the device, then the jit call alone
+                with trace.span("ragged_upload"):
+                    packed_in = [jnp.asarray(a) for a in (
+                        rb.ids, rb.row_ids, rb.positions, rb.lengths,
+                        rb.write_blocks, rb.write_offsets, rb.block_tables,
+                        rb.last_index)]
+                    slots_in = (
+                        (jnp.asarray(rb.adapter_slots)
+                         if self.lora_bank is not None else None),
+                        (jnp.asarray(rb.state_slots)
+                         if self._has_state else None),
+                        *((jnp.asarray(rb.window_tables),)
+                          if self._has_ring else ()))
+                with trace.span("ragged_call",
+                                program=self._ragged_jit.program):
+                    logits, *moe, self.kv_cache = self._ragged_jit(
+                        self.params, *packed_in, self.kv_cache,
+                        self.lora_bank, *slots_in)
             with trace.span("ragged_fetch"):
                 # blocks: the pass completes here
                 logits, moe = jax.device_get((logits, moe))
@@ -2075,12 +2088,19 @@ class InferenceEngineV2:
                 [uids[r] for r in live],
                 [rows[r][fed[r]:fed[r] + take[r]] for r in live],
                 chunk=(i, len(plan)))
-            self._m_prefill_chunks.inc()
-            for at, r in enumerate(live):
-                fed[r] += take[r]
-                if fed[r] == len(rows[r]):
-                    out[r] = logits[at]
-        return np.stack([out[r] for r in range(len(rows))])
+            # put()'s own work behind a chunk step, a leaf like the
+            # step's: the rows' counts, and behind the last step the
+            # rows' logits stacked (128 rows of a 65,536-row head are
+            # 33 MB: tens of ms in which the device has nothing queued)
+            with trace.span("put_chunk"):
+                self._m_prefill_chunks.inc()
+                for at, r in enumerate(live):
+                    fed[r] += take[r]
+                    if fed[r] == len(rows[r]):
+                        out[r] = logits[at]
+                if i == len(plan) - 1:
+                    stacked = np.stack([out[r] for r in range(len(rows))])
+        return stacked
 
     # -- weight hot-swap (serve/weights.py) -----------------------------
     def note_weight_swap(self, seconds: float) -> None:

@@ -414,7 +414,7 @@ class DeepSpeedTpuEngine:
         engine: training-step series + the TelemetryBridge that flushes
         registry scalars through MonitorMaster at the configured cadence
         (``telemetry.flush_interval``)."""
-        from ..telemetry import get_registry, trace
+        from ..telemetry import collector, get_registry, trace
         tcfg = self.config.telemetry
         self.telemetry_enabled = bool(tcfg.enabled)
         self.telemetry = get_registry()
@@ -424,6 +424,7 @@ class DeepSpeedTpuEngine:
             return
         if tcfg.xla_annotations:
             trace.enable_xla_annotations(True)
+        collector.install_gc_hook()
         reg = self.telemetry
         self._tm_loss = reg.gauge("training_loss", "last train_batch loss")
         self._tm_gnorm = reg.gauge("training_grad_norm",

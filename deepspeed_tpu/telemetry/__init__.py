@@ -24,8 +24,8 @@ from .registry import (Counter, Gauge, Histogram, MetricsRegistry,
                        get_registry, render_federated, scoped_registry,
                        set_registry)
 from .bridge import TelemetryBridge
-from . import anomaly, context, memory, postmortem, recorder, timeline, \
-    trace, watchdog
+from . import anomaly, collector, context, memory, postmortem, recorder, \
+    timeline, trace, watchdog
 from .anomaly import DiagnosticsConfig
 from .context import TraceContext
 from .recorder import FlightRecorder, get_recorder, set_recorder
@@ -34,7 +34,8 @@ __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry",
     "get_registry", "set_registry", "scoped_registry",
     "render_federated", "TelemetryBridge", "trace", "timeline",
-    "watchdog", "memory", "recorder", "anomaly", "postmortem", "context",
+    "watchdog", "memory", "collector", "recorder", "anomaly", "postmortem",
+    "context",
     "TraceContext", "DiagnosticsConfig", "FlightRecorder",
     "get_recorder", "set_recorder",
 ]

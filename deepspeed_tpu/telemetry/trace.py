@@ -135,6 +135,19 @@ def span(name: str, lane: Optional[str] = None, **attrs):
             _buffer.append(rec)
 
 
+def _retroactive(name, start, duration_s, track, lane, attrs) -> Dict:
+    rec = {"name": name, "start": float(start),
+           "duration_s": float(duration_s), "depth": 0, "id": next(_ids),
+           "parent": None,
+           "track": track if track is not None else current_track()}
+    ln = lane if lane is not None else current_lane()
+    if ln is not None:
+        rec["lane"] = ln
+    if attrs:
+        rec["attrs"] = attrs
+    return rec
+
+
 def record(name: str, start: float, duration_s: float,
            track: Optional[str] = None, lane: Optional[str] = None,
            **attrs) -> None:
@@ -145,17 +158,25 @@ def record(name: str, start: float, duration_s: float,
     decode phase between first token and finish). Retroactive spans are
     top-level (no parent) on ``track`` (default: the calling thread's
     track) in fleet lane ``lane`` (default: the thread's lane)."""
-    rec = {"name": name, "start": float(start),
-           "duration_s": float(duration_s), "depth": 0, "id": next(_ids),
-           "parent": None,
-           "track": track if track is not None else current_track()}
-    ln = lane if lane is not None else current_lane()
-    if ln is not None:
-        rec["lane"] = ln
-    if attrs:
-        rec["attrs"] = attrs
+    rec = _retroactive(name, start, duration_s, track, lane, attrs)
     with _lock:
         _buffer.append(rec)
+
+
+def record_nowait(name: str, start: float, duration_s: float,
+                  **attrs) -> bool:
+    """:func:`record` for a caller that may interrupt this module on its
+    own thread (a ``gc.callbacks`` hook runs wherever a collection
+    falls, inside ``export()``'s copy of the ring too): where the lock
+    is taken the span is dropped and False returned, never waited for."""
+    rec = _retroactive(name, start, duration_s, None, None, attrs)
+    if not _lock.acquire(blocking=False):
+        return False
+    try:
+        _buffer.append(rec)
+    finally:
+        _lock.release()
+    return True
 
 
 def export(name: Optional[str] = None) -> List[Dict]:

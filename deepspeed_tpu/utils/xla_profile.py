@@ -28,7 +28,9 @@ stream): is ZeRO communication overlapped with compute?
    same for a serving program (``telemetry.memory.scopes("ragged_step")``
    and the decode programs'; the scopes of inference/v2/paged_model.py):
    embedding, attention projections, the pool write, the attention
-   kernel, MLP, router, experts, head, pick.
+   kernel, MLP, router, experts, head, pick. A phase folds several
+   scopes; ``serve_scope(op_name)`` is the innermost scope itself, and
+   ``scope_seconds(rows)`` sums a traced call by program and scope.
 """
 
 import re
@@ -162,15 +164,46 @@ SERVE_PHASES = ("embed", "attn_proj", "kv_write", "attn_kernel", "mlp",
                 "ssm_state", "other")
 
 
+def serve_scope(op_name: str) -> str:
+    """The innermost scope word of ``_SERVE_PHASE_OF_SCOPE`` an
+    ``op_name`` holds (``layers/ssm_mixer/ssm_conv/scatter`` is
+    ``ssm_conv``, ``layers/mlp/moe_router/dot_general`` is
+    ``moe_router``); ``other`` for a path with none (``layers`` alone:
+    the scan's own slicing and counting)."""
+    words = _SERVE_SCOPE_WORD.findall(op_name)
+    return words[-1] if words else "other"
+
+
 def serve_phase(op_name: str) -> str:
     """The phase of a serving program an ``op_name`` belongs to, one of
-    ``SERVE_PHASES``: the innermost scope word the path holds
-    (``layers/attention/kv_write/scatter`` is ``kv_write``,
-    ``layers/mlp/moe_router/dot_general`` is ``router``); ``other`` for a
-    path with none (``layers`` alone: the scan's own slicing and
-    counting)."""
-    words = _SERVE_SCOPE_WORD.findall(op_name)
-    return _SERVE_PHASE_OF_SCOPE[words[-1]] if words else "other"
+    ``SERVE_PHASES``: that of its innermost scope (:func:`serve_scope`;
+    ``layers/attention/kv_write/scatter`` is ``kv_write``); ``other``
+    for a path with none."""
+    return _SERVE_PHASE_OF_SCOPE.get(serve_scope(op_name), "other")
+
+
+def scope_seconds(rows) -> Dict[tuple, float]:
+    """``{(program family, scope): self seconds}`` of a traced call.
+    A family is what a program's launches are summed under: the prompt
+    path's one program by its name (``ragged_step``), every decode
+    program (``decode_window_greedy``, ``decode_window_sample``,
+    ``decode_tok``, ...) as ``decode``, any other by its name, and a
+    launch of no named program as ``other``.
+    ``rows`` are ``(program, instruction, op_name, seconds)``: the
+    program whose launch held the operation (``watch_jit``'s name, None
+    outside any), the instruction's name (two programs may both have a
+    ``fusion.147``: the rows stay apart by program), the ``op_name``
+    THAT program's scope map gives the instruction (None where no map
+    knows it, or two signatures' maps disagree: scope ``other``) and the
+    operation's self time. Every row lands in exactly one cell, so the
+    cells of a family add up to the family's device time."""
+    out: Dict[tuple, float] = {}
+    for program, _, op_name, seconds in rows:
+        family = "other" if not program else \
+            "decode" if program.startswith("decode") else program
+        key = (family, serve_scope(op_name) if op_name else "other")
+        out[key] = out.get(key, 0.0) + seconds
+    return out
 
 
 # async-pair HLO opcodes emitted by the latency-hiding scheduler

@@ -586,19 +586,31 @@ def test_launch_ahead_spans_read_as_one_window_each(engines):
     for a, b in zip(windows, windows[1:]):      # disjoint, in order
         assert a["start"] + a["duration_s"] <= b["start"]
     root, = (s for s in ring if s["name"] == "generate")
+    # a dispatch span holds its two parts, the upload and the call
+    parts = {"ragged_upload": "ragged_dispatch",
+             "ragged_call": "ragged_dispatch",
+             "window_upload": "window_dispatch",
+             "window_call": "window_dispatch"}
+    holders = ("generate", "decode_window", "ragged_step",
+               "ragged_dispatch", "window_dispatch")
     for s in ring:
         if s is root:
             continue
         parent = by_id[s["parent"]]
-        assert parent["name"] in ("generate", "decode_window",
-                                  "ragged_step"), (s["name"], parent)
+        if s["name"] in parts:
+            assert parent["name"] == parts[s["name"]]
+        else:
+            assert parent["name"] in holders[:3], (s["name"], parent)
         if s["name"] in ("decode_window", "ragged_step"):
             assert parent is root
+        elif s["name"] in holders:
+            assert sorted(c["name"] for c in ring
+                          if c["parent"] == s["id"]) == sorted(
+                n for n, p in parts.items() if p == s["name"])
         else:                                   # a leaf: nothing under it
             assert not any(c["parent"] == s["id"] for c in ring)
     # leaves of one thread do not overlap
-    leaves = sorted((s for s in ring if s["name"] not in
-                     ("generate", "decode_window", "ragged_step")),
+    leaves = sorted((s for s in ring if s["name"] not in holders),
                     key=lambda s: s["start"])
     for a, b in zip(leaves, leaves[1:]):
         assert a["start"] + a["duration_s"] <= b["start"]

@@ -23,7 +23,9 @@ from deepspeed_tpu.inference.v2.config_v2 import DSStateManagerConfig
 from deepspeed_tpu.models import TransformerConfig, TransformerLM
 from deepspeed_tpu.telemetry import memory, trace
 from deepspeed_tpu.telemetry.watchdog import WatchedFunction
-from deepspeed_tpu.utils.xla_profile import SERVE_PHASES, serve_phase
+from deepspeed_tpu.utils import xla_profile
+from deepspeed_tpu.utils.xla_profile import (SERVE_PHASES, scope_seconds,
+                                             serve_phase, serve_scope)
 
 REPO = Path(__file__).resolve().parents[3]
 JOYAI = json.loads(
@@ -32,10 +34,14 @@ TOY_LATENT = harness.merge(JOYAI["fields"], JOYAI["toy_fields"])
 NEW_TOKENS = 20
 
 # the leaves of a generate() call: what docs/TELEMETRY.md lists
-LEAVES = {"gen_admit", "ragged_pack", "ragged_dispatch", "ragged_fetch",
-          "ragged_bookkeeping", "gen_first_token", "gen_schedule",
-          "window_assemble", "window_dispatch", "window_fetch",
-          "window_bookkeeping", "gen_flush"}
+# (a dispatch span's leaves are its two parts: the upload and the call)
+LEAVES = {"gen_admit", "ragged_pack", "ragged_upload", "ragged_call",
+          "ragged_fetch", "ragged_bookkeeping", "gen_first_token",
+          "gen_schedule", "window_assemble", "window_upload",
+          "window_call", "window_fetch", "window_bookkeeping", "gen_flush"}
+# the leaves that say which program they launched
+CALLS = {"ragged_call": {"ragged_step"},
+         "window_call": {"decode_window_greedy", "decode_window_sample"}}
 PER_TOKEN_LEAVES = {"step_assemble", "step_dispatch", "step_fetch",
                     "step_bookkeeping"}
 # the scope words a program of each block must hold an instruction of
@@ -109,7 +115,12 @@ def test_leaf_spans_tile_the_call_and_reach_its_root(engine):
         assert at is root, s
     leaves = [s for s in spans if s["name"] in LEAVES]
     assert {s["name"] for s in leaves} == LEAVES
-    assert all("attrs" not in s for s in leaves)
+    for s in leaves:
+        if s["name"] in CALLS:
+            assert set(s["attrs"]) == {"program"}
+            assert s["attrs"]["program"] in CALLS[s["name"]]
+        else:
+            assert "attrs" not in s
     # a leaf holds no span: what the leaves cover is counted once
     assert not {s["parent"] for s in spans} & {s["id"] for s in leaves}
     covered = sum(s["duration_s"] for s in leaves)
@@ -178,6 +189,58 @@ def test_the_per_token_path_has_the_same_leaves(tiny_model_128):
               if s["name"] in LEAVES | PER_TOKEN_LEAVES]
     assert sum(s["duration_s"] for s in leaves) \
         >= 0.99 * root["duration_s"]
+
+
+@pytest.fixture(scope="module")
+def dispatches(tiny_model_128):
+    """The spans of one warm greedy call and of one warm sampled call of
+    ONE per-head engine (a module's: an engine a test is too many
+    resident executables a worker): ``{id: span}``."""
+    memory.reset()
+    eng = _per_head(tiny_model_128)
+    prompts = _prompts(eng.model.cfg.vocab_size)
+    spans = _call_spans(eng, prompts)[1] \
+        + _call_spans(eng, prompts, temperature=0.8, seed=3)[1]
+    return {s["id"]: s for s in spans}
+
+
+@pytest.mark.parametrize("outer,upload,call", [
+    ("ragged_dispatch", "ragged_upload", "ragged_call"),
+    ("window_dispatch", "window_upload", "window_call")])
+def test_a_dispatch_holds_its_upload_and_its_call(dispatches, outer,
+                                                  upload, call):
+    """Every dispatch span holds exactly its two parts, in that order,
+    inside its own extent: the gap metrics lay idle time over either the
+    parent or the children, never both."""
+    parents = [s for s in dispatches.values() if s["name"] == outer]
+    assert len(parents) >= 2
+    for p in parents:
+        inner = sorted((s for s in dispatches.values()
+                        if s["parent"] == p["id"]),
+                       key=lambda s: s["start"])
+        assert [s["name"] for s in inner] == [upload, call]
+        assert inner[0]["start"] >= p["start"]
+        assert inner[0]["start"] + inner[0]["duration_s"] \
+            <= inner[1]["start"]
+        assert inner[1]["start"] + inner[1]["duration_s"] \
+            <= p["start"] + p["duration_s"]
+        assert "attrs" not in inner[0]
+        assert not [s for s in dispatches.values()
+                    if s["parent"] in (inner[0]["id"], inner[1]["id"])]
+
+
+def test_a_call_span_names_the_program_that_ran(dispatches):
+    """``watch_jit``'s name, which is the launch's in a device trace
+    (``jit_<program>``): greedy windows then sampled ones, and the one
+    ragged step of either call."""
+    by_start = sorted(dispatches.values(), key=lambda s: s["start"])
+    ragged = [s["attrs"] for s in by_start if s["name"] == "ragged_call"]
+    assert ragged == [{"program": "ragged_step"}] * 2
+    windows = [s["attrs"]["program"] for s in by_start
+               if s["name"] == "window_call"]
+    n = -(-(NEW_TOKENS - 1) // 8)
+    assert windows == ["decode_window_greedy"] * n \
+        + ["decode_window_sample"] * n
 
 
 def test_a_span_hands_out_its_record_and_marks_a_mirrored_one():
@@ -342,6 +405,69 @@ PHASE_TABLE = [
 @pytest.mark.parametrize("op_name,phase", PHASE_TABLE)
 def test_serve_phase(op_name, phase):
     assert serve_phase(op_name) == phase
+
+
+@pytest.mark.parametrize("word", sorted(xla_profile._SERVE_PHASE_OF_SCOPE))
+def test_serve_scope_reads_every_word_of_the_table(word):
+    """The innermost word is the scope, whatever stands round it, and
+    its phase is the table's: one regex, one table."""
+    path = f"jit(ragged_step)/layers/while/body/{word}/dot_general"
+    assert serve_scope(path) == word
+    assert serve_phase(path) == xla_profile._SERVE_PHASE_OF_SCOPE[word]
+    assert serve_phase(path) in SERVE_PHASES
+    # inside another scope it wins; as part of another name it is none
+    assert serve_scope(f"jit(x)/layers/attention/{word}/mul") == word
+    assert serve_scope(f"jit(x)/my_{word}_kernel/mul") == "other"
+
+
+@pytest.mark.parametrize("op_name,scope", [
+    ("jit(ragged_step)/layers/while/body/ssm_mixer/reduce_sum",
+     "ssm_mixer"),
+    ("jit(ragged_step)/layers/while/body/ssm_mixer/ssm_conv/scatter",
+     "ssm_conv"),
+    ("jit(ragged_step)/layers/while/body/ssm_mixer/ssm_out/dot_general",
+     "ssm_out"),
+    ("jit(ragged_step)/layers/while/body/mlp/moe_router/top_k",
+     "moe_router"),
+    ("jit(ragged_step)/layers/while/body/attention/kv_write/scatter",
+     "kv_write"),
+    ("jit(decode_window_greedy)/while/body/layers/while/body/dynamic_slice",
+     "other"),
+    ("", "other")])
+def test_serve_scope_is_the_innermost_word(op_name, scope):
+    assert serve_scope(op_name) == scope
+
+
+def test_scope_seconds_keeps_two_programs_instructions_apart():
+    """``fusion.1`` is the ragged step's MLP matmul AND the decode
+    window's query projection: the sum is by the launch's program, the
+    decode programs one family, and what no map knows is ``other``."""
+    rows = [
+        ("ragged_step", "fusion.1",
+         "jit(ragged_step)/layers/while/body/mlp/dot_general", 3.0),
+        ("ragged_step", "fusion.2", "jit(ragged_step)/head/dot_general",
+         4.0),
+        ("ragged_step", "fusion.7", None, 0.5),
+        ("decode_window_greedy", "fusion.1",
+         "jit(decode_window_greedy)/while/body/layers/while/body/"
+         "attention/qkv_proj/dot_general", 8.0),
+        ("decode_window_sample", "fusion.1",
+         "jit(decode_window_sample)/while/body/layers/while/body/"
+         "attention/qkv_proj/dot_general", 1.0),
+        ("decode_window_greedy", "copy.9", None, 1.0),
+        (None, "fusion.1", None, 0.25),
+        ("draft_catchup", "fusion.3", "jit(draft_catchup)/embed/gather",
+         0.125)]
+    got = scope_seconds(rows)
+    assert got == {("ragged_step", "mlp"): 3.0,
+                   ("ragged_step", "head"): 4.0,
+                   ("ragged_step", "other"): 0.5,
+                   ("decode", "qkv_proj"): 9.0,
+                   ("decode", "other"): 1.0,
+                   ("other", "other"): 0.25,
+                   ("draft_catchup", "embed"): 0.125}
+    assert sum(got.values()) == sum(r[-1] for r in rows)
+    assert scope_seconds([]) == {}
 
 
 def test_the_state_space_scopes_open_in_both_programs():

@@ -170,6 +170,45 @@ def test_put_in_chunks_equals_one_launch_where_one_launch_fits():
     assert float(np.abs(got - want).max() / np.abs(want).max()) <= F32_TIGHT
 
 
+@pytest.fixture(scope="module")
+def chunked_call():
+    """The ring of one ``generate()`` whose prompts go in in three chunk
+    steps (one engine for the cases below)."""
+    eng = _engine(budget=32)                      # a row's share: 8
+    prompts = _prompts((24, 17))
+    eng.generate(prompts, max_new_tokens=2, temperature=0.0,
+                 eos_token_id=None)                # compiles
+    trace.clear()
+    eng.generate(prompts, max_new_tokens=2, temperature=0.0,
+                 eos_token_id=None)
+    return sorted(trace.export(), key=lambda s: s["start"])
+
+
+@pytest.mark.parametrize("what", ["one a chunk step", "a leaf of the call",
+                                  "behind the step's bookkeeping"])
+def test_put_chunk_is_puts_own_work_behind_a_chunk_step(chunked_call, what):
+    """``_put_chunks`` folds a step's logits into its rows (and stacks
+    them behind the last step) under a leaf of its own, ``put_chunk``:
+    no part of a chunked ``put()`` runs outside a leaf."""
+    ring = chunked_call
+    chunks = [s for s in ring if s["name"] == "put_chunk"]
+    root, = (s for s in ring if s["name"] == "generate")
+    if what == "one a chunk step":
+        steps = [s for s in ring if s["name"] == "ragged_step"]
+        assert len(chunks) == len(steps) == 3
+        assert [s["attrs"]["chunk"] for s in steps] == [0, 1, 2]
+    elif what == "a leaf of the call":
+        for s in chunks:
+            assert s["parent"] == root["id"] and "attrs" not in s
+            assert not any(c["parent"] == s["id"] for c in ring)
+    else:
+        names = [s["name"] for s in ring if s["parent"] == root["id"]]
+        at = [i for i, n in enumerate(names) if n == "put_chunk"]
+        assert [names[i - 1] for i in at] == ["ragged_bookkeeping"] * 3
+        assert names[at[-1] + 1] == "gen_first_token"
+        assert [names[i + 1] for i in at[:-1]] == ["ragged_pack"] * 2
+
+
 def test_a_mixed_step_decodes_some_rows_and_feeds_chunks_of_others():
     """Row 0 is fed whole and decodes one token in the very step that
     feeds rows 1 and 2 their first chunks (8 tokens each and the decode
