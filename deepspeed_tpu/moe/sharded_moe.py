@@ -392,8 +392,22 @@ def dropless_topk_dispatch(xt, topi, topv, expert_params, num_experts: int,
                            held_from=None):
     """Sorted-token grouped-GEMM core shared by the training dropless MoE
     and the v2 serving path (_moe_mlp): route every (token, choice) row to
-    its expert with one argsort + `jax.lax.ragged_dot`, unsort, and weight
-    by the gate value. xt: [T, H]; topi/topv: [T, k]. Returns [T, H].
+    its expert with one argsort + `jax.lax.ragged_dot`, bring the rows
+    back, and weight by the gate value. xt: [T, H]; topi/topv: [T, k].
+    Returns [T, H].
+
+    The rows are keyed PICK-major: row ``j * T + t`` is (token t, pick
+    j), so that the experts' output comes back from expert order as k
+    dense ``[T, H]`` slabs, ``[k, T, H]``, by ONE row gather through the
+    inverse permutation, and the mask, the weights and the sum over the
+    picks are one fusion over them. The token-major form this replaced
+    scattered the rows into a zero-filled ``[T * k, H]`` and summed over
+    ``[T, k, H]``: a TPU scatters rows one after another where it
+    gathers them abreast, and the tiled layout pads a second-minor axis
+    of k = 10 to 16, so that the "reshape" was a copy of 1.6x the bytes
+    (granite's prompt, ms a call, PR 54's trace: the unsort's scatters
+    613.7 where the gather INTO expert order of the same 168 MB a run is
+    73.9; the reshapes 219.2).
 
     ``stack_layer`` (a traced scalar): ``expert_params`` are a whole
     scanned stack's, [L, E, ...], and the layer is chosen by WHERE its
@@ -408,15 +422,16 @@ def dropless_topk_dispatch(xt, topi, topv, expert_params, num_experts: int,
     adds nothing."""
     T, H = xt.shape
     k = topi.shape[-1]
-    idx = topi.reshape(-1)                       # [T*k], token-major
+    idx = topi.T.reshape(-1)                     # [k*T], pick-major
     if held_from is not None:
         idx = idx - held_from
         held = (idx >= 0) & (idx < num_experts)
         idx = jnp.where(held, idx, num_experts)
     order = jnp.argsort(idx)                     # stable
-    xs = xt[order // k]                          # row t*k+j <-> (token t, j)
+    xs = xt[order % T]                           # row j*T+t <-> (token t, j)
     # (a pick held elsewhere has index num_experts: counted in no group)
-    group_sizes = jnp.bincount(idx, length=num_experts).astype(jnp.int32)
+    group_sizes = jnp.sum(idx[:, None] == jnp.arange(num_experts),
+                          axis=0, dtype=jnp.int32)
     if stack_layer is not None:
         L = expert_params[0].shape[0]
         group_sizes = jax.lax.dynamic_update_slice(
@@ -425,13 +440,19 @@ def dropless_topk_dispatch(xt, topi, topv, expert_params, num_experts: int,
         expert_params = tuple(w.reshape(-1, *w.shape[2:])
                               for w in expert_params)
     fn = ragged_expert_fn or ragged_swiglu_experts
-    ys = fn(expert_params, xs, group_sizes)      # [T*k, H]
-    ys = jnp.zeros_like(ys).at[order].set(ys)    # unsort
+    ys = fn(expert_params, xs, group_sizes)      # [k*T, H], expert order
+    # where each pick-major row lies in expert order: the inverse of a
+    # permutation is its argsort (a sort of k*T int32 is a quarter of the
+    # time of their scatter on a TPU). The rows are a permutation's, so
+    # the gather's transpose is a plain row scatter
+    rows = ys.at[jnp.argsort(order)].get(
+        unique_indices=True, mode="promise_in_bounds").reshape(k, T, H)
     if held_from is not None:
-        # rows past the last group hold whatever the grouped matmul left
-        ys = jnp.where(held[:, None], ys, 0)
-    return jnp.sum(ys.reshape(T, k, H) * topv[..., None].astype(ys.dtype),
-                   axis=1)
+        # rows past the last group hold whatever the grouped matmul
+        # left, finite or not: a zero weight would not do
+        rows = jnp.where(held.reshape(k, T, 1), rows, 0)
+    # products in the experts' type; jnp.sum adds them up in float32
+    return jnp.sum(rows * topv.T[..., None].astype(ys.dtype), axis=0)
 
 
 def moe_layer_dropless(x, gate_w, expert_params, ragged_expert_fn=None,

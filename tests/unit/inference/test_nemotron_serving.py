@@ -593,18 +593,21 @@ def test_speculation_handoff_and_the_other_forwards_refuse_by_name():
 # (e) the four older pattern configurations' programs are the jaxprs they were
 # ---------------------------------------------------------------------------
 # sha256 (16 hex) of the program's jaxpr text at the configuration's toy
-# widths, object addresses struck out, read on the parent commit (e16ef18)
-# by this function: the walk, the state-space forms and the expert dispatch
-# make for them the operations they made
+# widths, object addresses struck out, read by this function: on PR 52's
+# parent commit (e16ef18) first, so that the walk, the state-space forms
+# and the expert dispatch made for them the operations they had made; read
+# again in PR 55, whose pick-major ``dropless_topk_dispatch`` is in all
+# eight (with the parent's dispatch laid over that tree the eight digests
+# were PR 52's to the digit: nothing else had moved)
 PARENT_JAXPRS = {
-    ("granite-4.0-h-small", "ragged_step"): "a09ed8239ea857ed",
-    ("granite-4.0-h-small", "decode_window"): "469fc5352375a01e",
-    ("trinity-mini", "ragged_step"): "3f6494a7971bafe0",
-    ("trinity-mini", "decode_window"): "e33c51d368065209",
-    ("ling-3.0-flash", "ragged_step"): "7e408de4c1ef00b5",
-    ("ling-3.0-flash", "decode_window"): "2e158f42ec636a58",
-    ("joyai-llm-flash", "ragged_step"): "f75d8bcb388fb2ec",
-    ("joyai-llm-flash", "decode_window"): "64212c3071c1291f",
+    ("granite-4.0-h-small", "ragged_step"): "5de0c03281f18034",
+    ("granite-4.0-h-small", "decode_window"): "5c6d2d136a7a4be5",
+    ("trinity-mini", "ragged_step"): "a1d9b31452d67d2c",
+    ("trinity-mini", "decode_window"): "6fac32732400fc53",
+    ("ling-3.0-flash", "ragged_step"): "1cbe3a214edb6fd4",
+    ("ling-3.0-flash", "decode_window"): "4c56d08286393d76",
+    ("joyai-llm-flash", "ragged_step"): "3c076d8f7d440e1c",
+    ("joyai-llm-flash", "decode_window"): "f29cfc684a88e169",
 }
 
 

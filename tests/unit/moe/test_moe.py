@@ -449,3 +449,131 @@ def test_pp_x_ep_trains_with_aux_loss():
     _, l_pp = _ppep_run(_ppep_cfg(0.01), pp=2, micro=4, batch=batch)
     assert np.isfinite(l_pp).all() and l_pp[-1] < l_pp[0]
     np.testing.assert_allclose(l_pp, l_ep, rtol=2e-3, atol=1e-2)
+
+
+# ---------------------------------------------------------------------------
+# the sorted dispatch (dropless_topk_dispatch) against a loop over experts
+# ---------------------------------------------------------------------------
+def _loop_over_experts(xt, topi, topv, ws, first=0):
+    """``for e: mask, expert(x), weight, add`` over the experts ``ws``
+    hold, the router's ``first`` ..: the plain form of the dispatch."""
+    from deepspeed_tpu.moe.sharded_moe import _swiglu_expert
+    out = jnp.zeros_like(xt)
+    for e in range(ws[0].shape[0]):
+        weight = jnp.sum(jnp.where(topi == first + e, topv, 0), axis=-1)
+        out = out + _swiglu_expert(xt, *(w[e] for w in ws)) \
+            * weight[:, None]
+    return out
+
+
+def _poisoned(expert_params, xs, group_sizes):
+    """The routed experts, with every row past the last group NaN: what
+    a grouped matmul may leave where it computed nothing."""
+    from deepspeed_tpu.moe.sharded_moe import ragged_swiglu_experts
+    ys = ragged_swiglu_experts(expert_params, xs, group_sizes)
+    computed = jnp.arange(ys.shape[0]) < jnp.sum(group_sizes)
+    return jnp.where(computed[:, None], ys, jnp.nan)
+
+
+# experts the router scores, of which the tree holds [first, first + held):
+# a token's k picks lie among ``picked``
+_WHOLE = dict(E=12, first=0, held=12, picked=(0, 12))
+DISPATCH_CASES = {
+    **{f"k{k}-T{T}": dict(_WHOLE, k=k, T=T)
+       for k in (1, 2, 8, 10) for T in (13, 1)},
+    "k8-T40": dict(_WHOLE, k=8, T=40),
+    "held-all": dict(E=12, first=6, held=6, picked=(6, 12), k=2, T=13),
+    "held-none": dict(E=12, first=6, held=6, picked=(0, 6), k=2, T=13),
+    "held-mixed": dict(E=12, first=6, held=6, picked=(0, 12), k=10, T=13),
+    "held-mixed-first": dict(E=12, first=0, held=4, picked=(0, 12), k=8,
+                             T=1),
+    "stack-layer": dict(_WHOLE, k=2, T=13, layer=1),
+    "stack-layer-held": dict(E=12, first=4, held=4, picked=(0, 12), k=10,
+                             T=13, layer=2),
+}
+
+
+@pytest.mark.parametrize("case", sorted(DISPATCH_CASES))
+def test_the_dispatch_is_a_loop_over_experts(case):
+    """Pick-major keys, the gather back and the fused combine give what
+    a loop over the experts gives, and its gradient in the rows, the
+    weights of the picks and the experts: at every k the callers have,
+    at a T that is no multiple of a tile's rows and at one token; as a
+    share (``held_from``) where a token's picks are all held, none, or
+    some, with the rows no expert computed poisoned (they must add
+    nothing, not NaN x 0); as one layer of a stack (``stack_layer``)."""
+    from deepspeed_tpu.moe.sharded_moe import dropless_topk_dispatch
+    c = DISPATCH_CASES[case]
+    T, k, E, first, held = c["T"], c["k"], c["E"], c["first"], c["held"]
+    H, F, L = 16, 24, 3
+    rng = np.random.default_rng(sorted(DISPATCH_CASES).index(case))
+    f = lambda *s: jnp.asarray(rng.normal(size=s) / s[-2] ** 0.5,
+                               jnp.float32)
+    xt = f(T, H) * H ** 0.5
+    stack = (f(L, held, H, F), f(L, held, H, F), f(L, held, F, H))
+    lo, hi = c["picked"]
+    topi = jnp.asarray(np.stack([rng.choice(np.arange(lo, hi), k,
+                                            replace=False)
+                                 for _ in range(T)]), jnp.int32)
+    topv = jnp.asarray(rng.uniform(0.1, 1.0, (T, k)), jnp.float32)
+    layer = c.get("layer")
+    share = None if held == E else first
+
+    def got(xt, topv, stack):
+        ws = stack if layer is not None else tuple(w[0] for w in stack)
+        return dropless_topk_dispatch(
+            xt, topi, topv, ws, held, _poisoned, held_from=share,
+            stack_layer=None if layer is None else jnp.int32(layer))
+
+    def want(xt, topv, stack):
+        return _loop_over_experts(
+            xt, topi, topv, tuple(w[layer or 0] for w in stack), first)
+
+    with jax.default_matmul_precision("highest"):
+        out = got(xt, topv, stack)
+        assert out.shape == (T, H) and np.isfinite(np.asarray(out)).all()
+        np.testing.assert_allclose(out, want(xt, topv, stack), atol=2e-5)
+        if case == "held-none":
+            assert not np.asarray(out).any()
+        probe = f(T, H)
+        grads = [jax.grad(lambda *a: jnp.sum(fn(*a) * probe),
+                          argnums=(0, 1, 2))(xt, topv, stack)
+                 for fn in (got, want)]
+    for a, b in zip(*(jax.tree.leaves(g) for g in grads)):
+        assert np.isfinite(np.asarray(a)).all()
+        np.testing.assert_allclose(a, b, atol=5e-5)
+
+
+def test_the_dispatch_moves_no_rows_by_scatter_or_through_a_padded_axis():
+    """The shape of the program, not its speed, at granite's run of
+    2,048 tokens x 10 picks x 4,096: the rows come back by ONE gather of
+    ``[T * k, H]``; nothing is scattered, neither rows into a ``[T * k,
+    H]`` (a TPU scatters rows one after another: 613.7 ms of granite's
+    prompt, PR 54's trace) nor ones into the experts' counts
+    (``bincount``: 51.6 ms); and no array is ``[T, k, H]`` (the tiled
+    layout pads the k = 10 to 16: a relayout copy of 1.6x the bytes,
+    219.2 ms)."""
+    from deepspeed_tpu.moe.sharded_moe import dropless_topk_dispatch
+    T, H, F, E, k = 2048, 4096, 768, 36, 10
+    sds = jax.ShapeDtypeStruct
+    jaxpr = jax.make_jaxpr(
+        lambda xt, topi, topv, *ws: dropless_topk_dispatch(
+            xt, topi, topv, ws, E, held_from=0))(
+        sds((T, H), jnp.bfloat16), sds((T, k), jnp.int32),
+        sds((T, k), jnp.float32), sds((E, H, F), jnp.bfloat16),
+        sds((E, H, F), jnp.bfloat16), sds((E, F, H), jnp.bfloat16))
+
+    def eqns(jaxpr):
+        for eqn in jaxpr.eqns:
+            yield eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from eqns(sub)
+
+    seen = list(eqns(jaxpr.jaxpr))
+    assert sum(e.primitive.name == "gather"
+               and e.outvars[0].aval.shape == (T * k, H)
+               and e.invars[0].aval.shape == (T * k, H) for e in seen) == 1
+    for e in seen:
+        assert not e.primitive.name.startswith("scatter"), e
+        assert (T, k, H) not in [v.aval.shape for v in (*e.invars,
+                                                        *e.outvars)], e
