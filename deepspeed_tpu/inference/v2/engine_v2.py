@@ -38,7 +38,7 @@ from ...telemetry import trace, watchdog
 from ...utils.bucketing import ceil_bucket, pow2_bucket
 from ...utils.logging import log_dist
 from .config_v2 import RaggedInferenceEngineConfig
-from .kernels import state_space
+from .kernels import power_retention, state_space
 from .kernels.linear_attention import (chunk_kernel_serves,
                                        conv_kernel_serves)
 from .kernels.ragged_attention import (LATENT, decode_positions,
@@ -50,7 +50,6 @@ from .paged_model import (STATE_LEAVES, init_lora_bank, init_paged_kv_cache,
 from .ragged import batch as ragged_batch
 from .ragged.blocked_allocator import NULL_BLOCK
 from .ragged.ragged_manager import DSStateManager
-from .ragged.sequence_descriptor import DSSequenceDescriptor
 from .sampling import greedy_tokens
 
 DTYPES = {"float32": jnp.float32, "float16": jnp.float16,
@@ -174,8 +173,8 @@ class InferenceEngineV2:
         if not cfg.has_state and config.state_dtype != "float32":
             raise ValueError(
                 "state_dtype is for a model that keeps recurrent state "
-                "(linear-attention or state-space layers); this one keeps "
-                "none")
+                "(linear-attention, state-space or power-retention "
+                "layers); this one keeps none")
         sm = config.state_manager
         if sm.max_seq_len > cfg.max_seq_len:
             sm.max_seq_len = cfg.max_seq_len
@@ -257,9 +256,13 @@ class InferenceEngineV2:
             ring = min(-(-cfg.attn_window // bs) * bs
                        + self.max_row_chunk + bs,
                        -(-sm.max_seq_len // bs) * bs)
+        # a model whose layers ALL keep a state caches no position: its
+        # sequences own a slot and no block, and the cache is the state
+        # leaves alone (``DSStateManager``'s ``paged``)
         self.state_manager = DSStateManager(
             sm, state_slots=sm.max_tracked_sequences
-            if self._has_state else 0, window_ring=ring)
+            if self._has_state else 0, window_ring=ring,
+            paged=cfg.caches_positions)
         self._has_ring = bool(ring)
         # note: the fresh pool carries no sharding, while every program
         # returns the donated cache with an explicit NamedSharding — so
@@ -346,9 +349,10 @@ class InferenceEngineV2:
         # a latent pool (attention='mla') has one kernel, whatever its
         # widths: kernels/ragged_attention.latent_attention
         self.attention_impl = (
-            "pallas:" + (LATENT if cfg.attention == "mla" else
-                         kernel_variant(cfg.head_dim, cfg.kv_heads,
-                                        bool(config.kv_quant)))
+            "none:no-layer-caches-positions" if not cfg.caches_positions
+            else "pallas:" + (LATENT if cfg.attention == "mla" else
+                              kernel_variant(cfg.head_dim, cfg.kv_heads,
+                                             bool(config.kv_quant)))
             + "+window" * self._has_ring
             if use_kernel else "jnp:gather")
         topo = self.topology if ep > 1 else None
@@ -546,6 +550,10 @@ class InferenceEngineV2:
         sm = config.state_manager
         state = cfg.has_state
         return ("a layer_types pattern (" + (
+            "power-retention layers with a power-kernel state a key/value "
+            "head and sequence" + ("" if cfg.caches_positions else
+                                   ", no position cached at all")
+            if "retention" in cfg.layer_kinds else
             "state-space layers with a recurrent state a sequence beside "
             "per-head attention" if state else
             "window and full per-head layers, a cache of two geometries")
@@ -702,6 +710,18 @@ class InferenceEngineV2:
             "one-token update as the kernel ssm_state_update (a fused "
             "window counts its steps; 0 for a model without such layers, "
             "and where the backend or the widths leave it to the XLA form)")
+        self._m_retention_state_kernel_steps = reg.counter(
+            "inference_retention_state_kernel_steps_total",
+            "decode steps whose power-retention layers ran their "
+            "one-token update as the kernel retention_state_update (a "
+            "fused window counts its steps; 0 for a model without such "
+            "layers and where the XLA twin runs: off a TPU, heads not 128 "
+            "wide)")
+        self._m_retention_chunk_kernel_launches = reg.counter(
+            "inference_retention_chunk_kernel_launches_total",
+            "ragged steps whose power-retention layers ran their chunked "
+            "form as the kernel retention_chunk_fwd (0 for a model "
+            "without such layers and where the XLA twin runs)")
         self._m_ssm_groups = reg.gauge(
             "inference_ssm_groups",
             "groups of B and C a token the state-space layers' programs "
@@ -838,8 +858,10 @@ class InferenceEngineV2:
 
     def _update_pool_telemetry(self):
         sm = self.state_manager
-        usable = max(sm.config.num_blocks - 1, 1)  # block 0 is the null
-        util = (usable - sm.free_blocks()) / usable
+        # block 0 is the null; a model that caches no position has no
+        # other (``DSStateManager``'s ``paged``), and reads 0 throughout
+        usable = max(sm.allocator.num_blocks - 1, 1)
+        util = (sm.allocator.num_blocks - 1 - sm.free_blocks()) / usable
         self._m_kv_util.set(util)
         # the live gauge reads 0 between requests (flush returns blocks),
         # so pool-pressure tuning needs the high-water mark too
@@ -848,7 +870,7 @@ class InferenceEngineV2:
         self._m_tracked.set(sm.tracked_sequences())
         self._m_state_slots.set(sm.state_slots_in_use())
         self._m_blocks_in_use.labels(kind="full").set(
-            usable - sm.free_blocks())
+            sm.allocator.num_blocks - 1 - sm.free_blocks())
         self._m_blocks_in_use.labels(kind="window").set(
             sm.window_blocks_in_use())
 
@@ -1059,7 +1081,7 @@ class InferenceEngineV2:
         for uid, n in zip(uids, lengths):
             if not self.state_manager.can_schedule(uid, n):
                 return False
-            seq = sm.seqs.get(uid) or DSSequenceDescriptor(uid=uid)
+            seq = sm.descriptor(uid)
             total_new += seq.blocks_needed(n, self.block_size)
             ring_new += seq.window_blocks_needed(n, self.block_size,
                                                  sm.ring_blocks)
@@ -1662,8 +1684,11 @@ class InferenceEngineV2:
         if "ssm_state" in cache and state_space.state_kernel_serves(
                 cache["ssm_state"], cfg.mamba_n_groups):
             self._m_ssm_state_kernel_steps.inc(steps)
-        if one_token_tile_serves(cfg.attention == "mla", cfg.head_dim,
-                                 cfg.kv_heads):
+        if "retention_state" in cache and power_retention.\
+                state_kernel_serves(cache["retention_state"]):
+            self._m_retention_state_kernel_steps.inc(steps)
+        if cfg.caches_positions and one_token_tile_serves(
+                cfg.attention == "mla", cfg.head_dim, cfg.kv_heads):
             self._m_one_token_steps.inc(steps)
             self._note_decode_positions(uids, steps_left, table_pages,
                                         in_flight)
@@ -1685,7 +1710,8 @@ class InferenceEngineV2:
         rings = kinds.count("window")
         held, chunked = np.asarray(decode_positions(
             contexts, sm.block_size, table_pages, sm.config.num_blocks)) \
-            * sum(k not in ("window", "kda", "ssm", "moe") for k in kinds)
+            * sum(k not in ("window", "kda", "ssm", "retention", "moe")
+                  for k in kinds)
         if rings:
             ring = decode_positions(
                 contexts, sm.block_size, sm.ring_blocks,
@@ -2006,6 +2032,10 @@ class InferenceEngineV2:
                             cache["ssm_state"], self.model.cfg.mamba_d_head,
                             self.model.cfg.mamba_n_groups):
                     self._m_ssm_scan_kernel_steps.inc()
+                if self._use_kernel and "retention_state" in cache \
+                        and power_retention.chunk_kernel_serves(
+                            cache["retention_state"]):
+                    self._m_retention_chunk_kernel_launches.inc()
             log_tokens = sm.config.enable_prefix_caching
             for uid, toks in entries:
                 seq = sm.seqs[uid]
@@ -2156,13 +2186,19 @@ class InferenceEngineV2:
         state-space layers ``ssm_state`` ``[state-space layers, heads,
         d_head, d_state]`` and ``ssm_conv`` ``[state-space layers, taps
         - 1, x | B | C channels]`` (an input's channels in one row and a
-        head's state by its own axes, however the leaves fold them).
+        head's state by its own axes, however the leaves fold them); of
+        power-retention layers ``retention_state`` ``[retention layers,
+        kv_heads, head_dim (head_dim + 1) / 2, head_dim]`` and
+        ``retention_norm`` ``[retention layers, kv_heads, head_dim
+        (head_dim + 1) / 2]``: a key/value head's state and normaliser
+        against phi in the order a <= b (the mechanism's 8,256 rows at
+        head_dim 128, whatever the leaf keeps twice).
         The read half of a snapshot (what preemption and handoff of such
         a model would carry: ROADMAP M5)."""
         if not self._has_state:
             raise ValueError("sequence_state: this model keeps no "
-                             "recurrent state (no linear-attention or "
-                             "state-space layer)")
+                             "recurrent state (no linear-attention, "
+                             "state-space or power-retention layer)")
         sm = self.state_manager
         if not sm.known_seq(uid):
             raise KeyError(f"sequence_state: uid {uid} is not tracked")
@@ -2177,6 +2213,11 @@ class InferenceEngineV2:
         if "ssm_state" in state:
             state["ssm_state"] = np.asarray(state_space.heads_of(
                 state["ssm_state"], self.model.cfg.mamba_n_heads))
+        for name, canonical in (
+                ("retention_state", power_retention.canonical_state),
+                ("retention_norm", power_retention.canonical_norm)):
+            if name in state:
+                state[name] = canonical(state[name])
         return state
 
     def sequence_kv(self, uid: int, kind: str = "window"

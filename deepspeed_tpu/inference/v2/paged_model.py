@@ -116,12 +116,35 @@ def init_paged_kv_cache(cfg: TransformerConfig, num_blocks: int,
                    if "full" in kinds else {}),
                 **(leaves(kinds.count("window"), window_blocks, "_window")
                    if "window" in kinds else {}),
-                **_init_ssm_state(cfg, state_slots, state_dtype)}
+                **_init_ssm_state(cfg, state_slots, state_dtype),
+                **_init_retention_state(cfg, state_slots, state_dtype)}
     return leaves(cfg.num_layers, num_blocks)
 
 
 # the leaves that hold recurrent state, indexed by a sequence's slot
-STATE_LEAVES = ("kda_state", "kda_conv", "ssm_state", "ssm_conv")
+STATE_LEAVES = ("kda_state", "kda_conv", "ssm_state", "ssm_conv",
+                "retention_state", "retention_norm")
+
+
+def _init_retention_state(cfg, state_slots, state_dtype):
+    """The power-retention layers' recurrent state: ``retention_state``
+    ``[L_retention, slots + 1, kv_heads, head_dim / 2 + 1, head_dim,
+    head_dim]`` (a key/value head's state against the symmetric second
+    power of its key, laid out by circular distance:
+    ``kernels/power_retention``; 8,320 x 128 float32 a head at the
+    published width, of which 8,256 are the mechanism's and 64 are kept
+    twice) and ``retention_norm`` ``[L_retention, slots + 1, kv_heads,
+    rows, head_dim]`` (the normaliser's, phi's rows rounded up to whole
+    sublane tiles: 72 for 65), both in
+    ``state_dtype``, slot 0 the null slot. A model of such layers alone
+    has no other leaf: nothing of it is paged."""
+    n = cfg.layer_kinds.count("retention")
+    if not n:
+        return {}
+    from .kernels.power_retention import leaf_shapes
+    state, norm = leaf_shapes(n, state_slots + 1, cfg.kv_heads, cfg.head_dim)
+    return {"retention_state": jnp.zeros(state, state_dtype),
+            "retention_norm": jnp.zeros(norm, state_dtype)}
 
 
 def _init_ssm_state(cfg, state_slots, state_dtype):
@@ -1104,6 +1127,63 @@ def _state_space_sublayer(cfg, lp, x, l, cache, rows: _StateRows,
         return y.astype(dt_) @ lp["w_out"], cache
 
 
+def _power_retention_sublayer(cfg, lp, x, l, cache, cos, sin,
+                              rows: _StateRows, use_kernel=True):
+    """A power-retention mixer on flat tokens x [T, H]; ``l`` is the
+    layer's index among the retention layers (its state leaves' leading
+    axis). q, k and v are the per-head block's projections of the normed
+    input (scope ``qkv_proj``): q and k RMS-normed a head (``qk_norm``)
+    and rotated, and beside them the decay's log, ``log sigmoid(w_decay
+    x + b_decay)``, one scalar a key/value head. They stay float32 from
+    the matmuls' sums to the recurrence: phi squares them, and a
+    rounding of q or k is twice that of the pair's weight. The
+    recurrence runs in float32 from the row's slot (zeros for a row at
+    its first token) and its result goes back to the slot, the state of
+    ONE key/value head read by its whole group of query heads: a decode
+    batch through the one-token update (scope ``retention_state``: the
+    kernel ``retention_state_update`` where ``use_kernel`` and the
+    widths allow, else gather, ``retention_step`` and scatter), every
+    other launch through the chunked form, rows of any lengths (scope
+    ``retention_chunk``: the kernel ``retention_chunk_fwd`` or the XLA
+    ``retention_chunked``): ``kernels/power_retention``. No position is
+    cached and no block table read. Returns (what the mixer adds to x,
+    cache)."""
+    from ...ops.norms import rms_norm
+    from .kernels import power_retention as pr
+    T = x.shape[0]
+    nh, nkv, hd = cfg.num_heads, cfg.kv_heads, cfg.head_dim
+    f32, dt = jnp.float32, lp["wq"].dtype
+    hn = _norm(cfg, x, lp["attn_norm"]).astype(dt)
+    with jax.named_scope("qkv_proj"):
+        q, k, v, g = (jnp.dot(hn, lp[w], preferred_element_type=f32)
+                      for w in ("wq", "wk", "wv", "w_decay"))
+        q, k = q.reshape(T, nh, hd), k.reshape(T, nkv, hd)
+        g = jax.nn.log_sigmoid(g + lp["b_decay"].astype(f32))
+        if cfg.qk_norm:
+            q = rms_norm(q, lp["q_norm"].astype(f32), cfg.norm_eps)
+            k = rms_norm(k, lp["k_norm"].astype(f32), cfg.norm_eps)
+        q = _rotate(q, cos[:, None, :], sin[:, None, :])
+        k = _rotate(k, cos[:, None, :], sin[:, None, :])
+    # the recurrence with its state's way out of the slot and back, one
+    # scope: what a roofline of it has to count
+    with jax.named_scope("retention_state" if rows.one_token
+                         else "retention_chunk"):
+        state, norm = cache["retention_state"], cache["retention_norm"]
+        at = (state, norm, l, rows.slots, rows.fresh)
+        tokens = (q, k, v.reshape(T, nkv, hd), g, cfg.retention_eps)
+        if rows.one_token:
+            step = pr.retention_state_update if use_kernel \
+                and pr.state_kernel_serves(state) else pr.retention_step
+            o, state, norm = step(*at, *tokens)
+        else:
+            scan = pr.retention_chunk_fwd if use_kernel \
+                and pr.chunk_kernel_serves(state) else pr.retention_chunked
+            o, state, norm = scan(*at, rows.starts, rows.counts, *tokens)
+        cache = {**cache, "retention_state": state, "retention_norm": norm}
+    with jax.named_scope("out_proj"):
+        return o.astype(dt).reshape(T, nh * hd) @ lp["wo"], cache
+
+
 def _layer_runs(cfg):
     """The layers as maximal runs of one (mixer kind, MLP kind):
     [(kind, routed, first layer, layers)], ``kind`` one of
@@ -1250,6 +1330,12 @@ def _pattern_step(cfg: TransformerConfig, params, ids, row_ids, pos, lengths,
                 with jax.named_scope("ssm_mixer"):
                     a, pool = _state_space_sublayer(
                         cfg, lp, x, m0 + i, pool, rows, use_kernel)
+                    x = joined(x, a)
+            elif kind == "retention":
+                with jax.named_scope("attention"):
+                    a, pool = _power_retention_sublayer(
+                        cfg, lp, x, m0 + i, pool, cos, sin, rows,
+                        use_kernel)
                     x = joined(x, a)
             elif kind == "moe":
                 pass                    # no mixer ahead of the experts
@@ -1416,9 +1502,11 @@ def paged_decode(cfg: TransformerConfig, params, toks: jnp.ndarray,
     ``layer_types`` pattern that has such layers."""
     N, MB = block_tables.shape
     if cfg.walks_runs:
-        # the ragged layout with one token a row (_pattern_step)
+        # the ragged layout with one token a row (_pattern_step); a
+        # model that caches no position writes no block
         blk = jnp.take_along_axis(
-            block_tables, (pos // block_size)[:, None], axis=1)[:, 0]
+            block_tables, (pos // block_size)[:, None], axis=1)[:, 0] \
+            if cfg.caches_positions else jnp.zeros_like(pos)
         x, stats, cache = _pattern_step(
             cfg, params, toks, jnp.arange(N, dtype=jnp.int32), pos,
             jnp.where(active, pos + 1, 0), jnp.where(active, blk, 0),

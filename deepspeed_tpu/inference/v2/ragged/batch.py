@@ -116,7 +116,8 @@ def pack(entries: Sequence[Tuple[int, np.ndarray]], state_manager,
         row_ids[sl] = r
         positions[sl] = pos
         lengths[sl] = pos + 1
-        write_blocks[sl] = seq_blocks[pos // bs]
+        if sm.paged:                # else: every write on the null block
+            write_blocks[sl] = seq_blocks[pos // bs]
         write_offsets[sl] = pos % bs
         tables[r, :len(seq.blocks)] = seq_blocks
         last_index[r] = cursor + n - 1

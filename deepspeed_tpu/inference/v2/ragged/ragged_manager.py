@@ -75,8 +75,17 @@ def prefix_digest(tokens, block_size: int,
 
 class DSStateManager:
     def __init__(self, config: DSStateManagerConfig, state_slots: int = 0,
-                 window_ring: int = 0):
-        """``window_ring`` > 0: the model has window-attention layers,
+                 window_ring: int = 0, paged: bool = True):
+        """``paged`` False: the model caches no position at all (every
+        layer keeps a state a sequence, ``state_slots`` of them). A
+        sequence then owns its slot and NO block: the allocator holds
+        the null block and one it never hands out, a block table is one
+        null entry wide, ``num_blocks`` and ``block_size`` size nothing,
+        and what bounds the batch is ``max_tracked_sequences``, the
+        slots; ``max_seq_len`` still bounds a sequence's length (its
+        positions feed the rotation).
+
+        ``window_ring`` > 0: the model has window-attention layers,
         whose keys and values live in a pool of their own in which a
         sequence owns a RING of that many positions (whole blocks;
         position p at place ``p % window_ring``) and not its whole
@@ -105,7 +114,12 @@ class DSStateManager:
                 f"{config.max_tracked_sequences} sequences tracked")
         self._free_slots = list(range(self.state_slots, 0, -1))
         self.block_size = config.block_size
-        self.allocator = BlockedAllocator(config.num_blocks)
+        self.paged = bool(paged)
+        if not self.paged and not self.state_slots:
+            raise ValueError("a model that caches no position keeps its "
+                             "sequences in state slots: state_slots > 0")
+        self.allocator = BlockedAllocator(
+            config.num_blocks if self.paged else 2)
         if window_ring % self.block_size:
             raise ValueError(f"a ring of {window_ring} positions is not "
                              f"whole blocks of {self.block_size}")
@@ -114,7 +128,8 @@ class DSStateManager:
             config.max_tracked_sequences * self.ring_blocks + 1) \
             if self.ring_blocks else None
         self.seqs: Dict[int, DSSequenceDescriptor] = {}
-        self.max_blocks_per_seq = -(-config.max_seq_len // self.block_size)
+        self.max_blocks_per_seq = -(-config.max_seq_len // self.block_size) \
+            if self.paged else 1
         # cold-block spill tier (spill.py KVSpillTier, installed by the
         # engine when enable_kv_spill is on): eviction demotes a retained
         # block's CONTENT to host RAM/disk instead of discarding it, and
@@ -304,14 +319,20 @@ class DSStateManager:
                     f"{self.config.max_tracked_sequences} reached")
             self.seqs[uid] = DSSequenceDescriptor(
                 uid=uid, state_slot=self._free_slots.pop()
-                if self.state_slots else 0)
+                if self.state_slots else 0, paged=self.paged)
         return self.seqs[uid]
+
+    def descriptor(self, uid: int) -> DSSequenceDescriptor:
+        """The tracked sequence ``uid``, or what it would start as (for
+        a question about a sequence not yet created)."""
+        return self.seqs.get(uid) or DSSequenceDescriptor(
+            uid=uid, paged=self.paged)
 
     def state_slots_in_use(self) -> int:
         return self.state_slots - len(self._free_slots)
 
     def can_schedule(self, uid: int, new_tokens: int) -> bool:
-        seq = self.seqs.get(uid) or DSSequenceDescriptor(uid=uid)
+        seq = self.descriptor(uid)
         if seq.seen_tokens + new_tokens > self.config.max_seq_len:
             return False
         if uid not in self.seqs and \

@@ -35,6 +35,10 @@ class DSSequenceDescriptor:
     # ring_blocks]``); they grow with the sequence up to the ring's size
     # and are then written over
     window_blocks: List[int] = field(default_factory=list)
+    # False for a model that caches no position (its layers all keep a
+    # state a sequence): the sequence then owns its state slot and NO
+    # block, however long it grows
+    paged: bool = True
 
     def window_blocks_needed(self, new_tokens: int, block_size: int,
                              ring_blocks: int) -> int:
@@ -45,6 +49,8 @@ class DSSequenceDescriptor:
                    - len(self.window_blocks))
 
     def blocks_needed(self, new_tokens: int, block_size: int) -> int:
+        if not self.paged:
+            return 0
         total = self.seen_tokens + new_tokens
         have = len(self.blocks)
         need = -(-total // block_size)  # ceil

@@ -499,7 +499,8 @@ class DynamicSplitFuseScheduler:
                 # request writes prompt + max(new-1, 0) KV slots total
                 total = len(head.prompt) + max(head.max_new_tokens - 1, 0)
                 need = -(-total // bs)
-                if need > sm.config.num_blocks - 1:  # block 0 is the null
+                # (a model that caches no position needs no block)
+                if sm.paged and need > sm.config.num_blocks - 1:  # 0: null
                     raise RuntimeError(
                         f"request uid={head.uid} cannot be scheduled: "
                         f"{len(head.prompt)}+{head.max_new_tokens} tokens "

@@ -38,11 +38,17 @@ from ..telemetry import registry as _registry
 # a source's word for a layer -> the kind it is served as: per-head
 # attention over a window or over every position ("attention": the
 # word of a source whose other layers are no attention at all), a
-# Mamba-2 state-space mixer, or "moe": an expert layer that is a layer
-# of its own. A pattern that names "moe" is one whose every layer is
-# ONE sub-layer behind ONE norm (``TransformerConfig.one_sublayer``)
+# Mamba-2 state-space mixer, a power-retention mixer (a fixed-size
+# power-kernel state a key/value head in place of cached positions), or
+# "moe": an expert layer that is a layer of its own. A pattern that
+# names "moe" is one whose every layer is ONE sub-layer behind ONE norm
+# (``TransformerConfig.one_sublayer``)
 LAYER_TYPE_KINDS = {"sliding_attention": "window", "full_attention": "full",
-                    "attention": "full", "mamba": "ssm", "moe": "moe"}
+                    "attention": "full", "mamba": "ssm", "moe": "moe",
+                    "power_retention": "retention"}
+# the kinds that cache positions in blocks; the others keep a state a
+# sequence, or nothing
+PAGED_KINDS = ("mha", "mla", "window", "full")
 
 
 @dataclass(frozen=True)
@@ -223,6 +229,19 @@ class TransformerConfig:
     attn_scale: float = 0.0
     residual_scale: float = 1.0
     logit_scale: float = 1.0
+    # POWER RETENTION layers (served only; ``layer_types``
+    # "power_retention"; arXiv:2507.04239, degree 2): the per-head
+    # block's projections, ``qk_norm`` and rotation as they are, with
+    # the softmax's exp(q.k) replaced by (q.k / sqrt(head_dim))^2 times
+    # a cumulative decay whose log is ``log sigmoid(w_decay x +
+    # b_decay)``, one scalar a key/value head and token, and the
+    # weights' sum (+ ``retention_eps``) as normaliser. A key/value head
+    # keeps a float32 state over the symmetric second power of its key
+    # (head_dim (head_dim + 1) / 2 products x head_dim) in place of
+    # cached positions, which its whole GROUP of query heads reads
+    # (kernels/power_retention.py); a model of such layers alone caches
+    # no position at all
+    retention_eps: float = 1e-6
     # the group limit of the deployed router (DeepSeek-V3 noaux_tc): the
     # experts form ``moe_n_group`` groups, a group scores the sum of its
     # best two, and the top k are chosen inside the best
@@ -343,6 +362,15 @@ class TransformerConfig:
                     and self.attn_window < 1:
                 raise ValueError("a sliding_attention layer needs "
                                  "attn_window > 0")
+            if "power_retention" in self.layer_types and (
+                    self.head_dim % 2 or self.positional != "rope"
+                    or self.retention_eps <= 0 or self.attn_scale
+                    or self.attn_gate != "none"):
+                raise ValueError(
+                    "a power_retention layer needs an even head_dim "
+                    "(phi is laid out by circular distance), "
+                    "positional='rope', retention_eps > 0, and neither "
+                    "attn_scale nor attn_gate")
             if "mamba" in self.layer_types and (
                     min(self.mamba_n_heads, self.mamba_d_head,
                         self.mamba_d_state, self.mamba_n_groups) < 1
@@ -466,6 +494,8 @@ class TransformerConfig:
              self.layer_types is not None),
             ("mamba layers (a Mamba-2 state-space mixer and its "
              "recurrent state)", "ssm" in self.layer_kinds),
+            ("power_retention layers (a power-kernel state a key/value "
+             "head)", "retention" in self.layer_kinds),
             ("'moe' layers (a layer is one sub-layer behind one norm)",
              self.one_sublayer),
             ("mamba_n_groups (B and C a group of heads)",
@@ -524,9 +554,10 @@ class TransformerConfig:
     def layer_kinds(self) -> tuple:
         """The mixer of every layer, in order: "kda" (linear attention)
         or ``attention`` under ``linear_attn_period``; "window" or "full"
-        (per-head attention), "ssm" (a Mamba-2 state-space mixer) or
-        "moe" (an expert layer that is a layer of its own:
-        ``one_sublayer``) from an explicit ``layer_types``."""
+        (per-head attention), "ssm" (a Mamba-2 state-space mixer),
+        "retention" (a power-retention mixer) or "moe" (an expert layer
+        that is a layer of its own: ``one_sublayer``) from an explicit
+        ``layer_types``."""
         if self.layer_types is not None:
             return tuple(LAYER_TYPE_KINDS[t] for t in self.layer_types)
         p = self.linear_attn_period
@@ -550,7 +581,14 @@ class TransformerConfig:
     @property
     def has_state(self) -> bool:
         """Whether a sequence owns recurrent state beside its blocks."""
-        return bool({"kda", "ssm"} & set(self.layer_kinds))
+        return bool({"kda", "ssm", "retention"} & set(self.layer_kinds))
+
+    @property
+    def caches_positions(self) -> bool:
+        """Whether any layer caches positions in blocks. A model of
+        state-keeping layers alone has no pool: a sequence owns a state
+        slot and no block."""
+        return bool(set(PAGED_KINDS) & set(self.layer_kinds))
 
     @property
     def mamba_d_inner(self) -> int:
@@ -1066,6 +1104,19 @@ class TransformerLM:
                 out["attn_post_norm"] = jnp.ones((n, h), dt)
             return out
 
+        def retention(key, n):
+            """A power-retention mixer's leaves: the per-head mixer's,
+            and the decay gate's projection ``w_decay`` to one scalar a
+            key/value head with its bias ``b_decay`` (float32 in the
+            checkpoint), drawn so that ``log sigmoid`` spans soft and
+            hard decays."""
+            out = per_head(key, n)
+            ks = jax.random.split(jax.random.fold_in(key, 5), 2)
+            out["w_decay"] = init(ks[0], (n, h, cfg.kv_heads))
+            out["b_decay"] = jax.random.uniform(
+                ks[1], (n, cfg.kv_heads), dt, -3.0, 5.0)
+            return out
+
         def state_space(key, n):
             """A Mamba-2 mixer's leaves: ``w_in`` to [z | x B C | dt],
             the depthwise taps ``conv`` [taps, x | B | C] and their bias
@@ -1131,6 +1182,7 @@ class TransformerLM:
             kinds = cfg.layer_kinds
             mixers = {"kda": (linear, 7), "window": (per_head, 9),
                       "full": (per_head, 10), "ssm": (state_space, 11),
+                      "retention": (retention, 12),
                       "mla": (functools.partial(attention, mlp_norm=False),
                               8)}
             for kind in dict.fromkeys(kinds):

@@ -154,14 +154,21 @@ _SERVE_PHASE_OF_SCOPE = {
     # convolution, gated norm are ``ssm``; the recurrence a phase a form
     "ssm_mixer": "ssm", "ssm_proj": "ssm", "ssm_conv": "ssm",
     "ssm_gate_norm": "ssm", "ssm_out": "ssm",
-    "ssm_scan": "ssm_scan", "ssm_state": "ssm_state"}
+    "ssm_scan": "ssm_scan", "ssm_state": "ssm_state",
+    # a power-retention layer stands under ``attention`` as every
+    # per-head mixer (its projections, head norms, rotation and decay
+    # gate are ``qkv_proj`` / ``out_proj``: attn_proj); the mixer's core,
+    # the state's way out of its slot, the update and its way back, is a
+    # phase a form
+    "retention_chunk": "retention_chunk",
+    "retention_state": "retention_state"}
 _SERVE_SCOPE_WORD = re.compile(
     r"\b(" + "|".join(sorted(_SERVE_PHASE_OF_SCOPE, key=len, reverse=True))
     + r")\b")
 SERVE_PHASES = ("embed", "attn_proj", "kv_write", "attn_kernel", "mlp",
                 "router", "experts", "head", "pick", "linear",
                 "linear_chunk", "linear_state", "ssm", "ssm_scan",
-                "ssm_state", "other")
+                "ssm_state", "retention_chunk", "retention_state", "other")
 
 
 def serve_scope(op_name: str) -> str:

@@ -19,6 +19,15 @@ two state-space cells' decode shapes, the launch as ``paged_model`` makes it
 carried through the loop, aliased, and the bytes are the rows' states read
 once and written once.
 
+The two power-retention kernels (``kernels/power_retention.py``) stand
+there too at their cell's shapes: ``retention_state_update`` for a decode
+step's 16 rows (a row's 8 key/value heads of 65 x 128 x 128 float32 read once
+and written once, five query heads reading each) and ``retention_chunk_fwd``
+for a prompt launch's 16 rows of 512 tokens (bytes: the launch's q, k, v, gate
+and output and the rows' states once each way; ``copies`` there is the
+kernel with its distances' loop taken out: the windows' copies, the decay
+and the attention form inside a chunk).
+
 The variants take the walk apart by replacing one function of
 ``kernels/ragged_attention.py`` in this process (nothing a cell runs is
 touched, and no option of the program exists for it):
@@ -60,6 +69,11 @@ import numpy as np                                            # noqa: E402
 ra = importlib.import_module(                                 # noqa: E402
     "deepspeed_tpu.inference.v2.kernels.ragged_attention")
 from deepspeed_tpu.inference.v2.kernels import state_space as ss  # noqa: E402
+try:                            # a tree before the kind: its shapes skip
+    from deepspeed_tpu.inference.v2.kernels import (      # noqa: E402
+        power_retention as pr)
+except ImportError:
+    pr = None
 
 PEAK_BYTES_S = 819e9            # TPU v5e (benchmark/peaks.json)
 
@@ -85,8 +99,84 @@ SHAPES = {
                            p=64, n=128, groups=8),
     "granite-state": dict(kernel="ssm_state", rows=64, layers=9, nh=128,
                           p=64, n=128, groups=1),
+    # power retention: ``kvh`` states of [hd / 2 + 1, hd, hd] a row, read
+    # by ``nh`` query heads; ``tokens`` a row of the prompt launch. Two
+    # layers of the cell's eight: a launch is one layer's, and --check
+    # holds three copies of the leaf (0.58 GB a layer)
+    "brumby-state": dict(kernel="retention_state", rows=16, layers=2,
+                         nh=40, kvh=8, hd=128),
+    "brumby-chunk": dict(kernel="retention_chunk", rows=16, layers=2,
+                         nh=40, kvh=8, hd=128, tokens=512),
 }
 BS = 16
+EPS = 1e-6
+
+
+def build_retention(shape, rng, rehearse):
+    """One launch of a power-retention kernel, as ``build_state`` gives
+    it: ``(fn(q, layer, state, norm) -> (o, state, norm), again, q,
+    (state, norm), layers, bytes a launch, reference)``."""
+    rows, L, nh, kvh, hd = (shape[k] for k in
+                            ("rows", "layers", "nh", "kvh", "hd"))
+    chunked = shape["kernel"] == "retention_chunk"
+    per = shape.get("tokens", 1)
+    if rehearse:
+        rows, L, nh, kvh, hd, per = 3, 2, 4, 2, 16, 20 if chunked else 1
+    T = rows * per
+    key = jax.random.PRNGKey(int(rng.integers(1 << 30)))
+    f = lambda i, *s: jax.random.normal(jax.random.fold_in(key, i), s)
+    shapes = pr.leaf_shapes(L, rows + 1, kvh, hd)
+    @jax.jit
+    def held():
+        """A state as tokens leave it: sums of phi(k) v^T, and a
+        normaliser > 0."""
+        pk = pr.phi(f(0, L, rows + 1, kvh, hd))
+        return (pk[..., None, :] * f(1, L, rows + 1, kvh, 1, hd, 1),
+                jnp.pad(jnp.abs(pk) + 0.1, [(0, 0)] * 3 + [
+                    (0, shapes[1][-2] - pk.shape[-2]), (0, 0)]))
+
+    state, norm = held()
+    assert state.shape == shapes[0] and norm.shape == shapes[1]
+    q, k, v = f(2, T, nh, hd), f(3, T, kvh, hd), f(4, T, kvh, hd)
+    g = -jax.random.uniform(jax.random.fold_in(key, 5), (T, kvh),
+                            minval=0.01, maxval=3.0)
+    slots = jnp.asarray(rng.permutation(rows) + 1, jnp.int32)
+    fresh = jnp.zeros(rows, bool)
+    starts = jnp.arange(rows, dtype=jnp.int32) * per
+    counts = jnp.full((rows,), per, jnp.int32)
+    if chunked:
+        kw = dict(chunk=16) if rehearse else {}
+
+        # the function under its own jit: that jit's cache would hand a
+        # variant the trace of the one before it
+        chunk_fwd = getattr(pr.retention_chunk_fwd, "__wrapped__",
+                            pr.retention_chunk_fwd)
+
+        def fn(q, layer, state, norm):
+            return chunk_fwd(
+                state, norm, layer, slots, fresh, starts, counts, q, k, v,
+                g, EPS, interpret=rehearse, **kw)
+
+        def ref(q, layer, state, norm):
+            return pr.retention_chunked(
+                state, norm, layer, slots, fresh, starts, counts, q, k, v,
+                g, EPS, **kw)
+        moved = 4 * T * hd * (2 * nh + 2 * kvh + kvh / hd)
+    else:
+        def fn(q, layer, state, norm):
+            return pr.retention_state_update(
+                state, norm, layer, slots, fresh, q, k, v, g, EPS,
+                interpret=rehearse)
+
+        def ref(q, layer, state, norm):
+            return pr.retention_step(state, norm, layer, slots, fresh, q,
+                                     k, v, g, EPS)
+        moved = 0
+
+    def again(q, o):
+        return q + o * 0
+    held = rows * (state[0, 0].nbytes + norm[0, 0].nbytes)
+    return fn, again, q, (state, norm), L, int(2 * held + moved), ref
 
 
 def build_state(shape, rng, rehearse):
@@ -234,9 +324,34 @@ def _state_copies(layer_ref, slots_ref, fresh_ref, s_ref, decay_ref, dtx_ref,
     y_ref[...] = dtx_ref[...]
 
 
+def _retention_copies(layer_ref, slots_ref, fresh_ref, s_ref, z_ref, q_ref,
+                      k_ref, v_ref, dec_ref, so_ref, zo_ref, o_ref, *scratch,
+                      **static):
+    """:func:`pr._state_kernel` with the token taken out."""
+    so_ref[...] = s_ref[...]
+    zo_ref[...] = z_ref[...]
+    o_ref[...] = q_ref[...]
+
+
+def _no_distances(lo, hi, body, init, **kw):
+    """``fori_loop`` that skips the loop over phi's distances (the one
+    whose carry is the int 0) and runs every other as it is."""
+    if isinstance(init, int) and init == 0 and isinstance(lo, int):
+        return init
+    return _FORI(lo, hi, body, init, **kw)
+
+
+_FORI = jax.lax.fori_loop
+
+
 def variants(kernel, sweep):
     if kernel == "ssm_state":
         return {"full": {}, "copies": dict(_state_kernel=_state_copies)}
+    if kernel == "retention_state":
+        return {"full": {},
+                "copies": dict(_state_kernel=_retention_copies)}
+    if kernel == "retention_chunk":
+        return {"full": {}, "copies": dict(_distances_loop=_no_distances)}
     # the products of this tree's kernels, whichever it has (the parent
     # of PR 50 ran ``_tile_update`` in both forms of the tiled kernel and
     # kept the latent kernel's products inline)
@@ -300,9 +415,16 @@ def main():
     names = [n for n in args.only.split(",") if n] or list(SHAPES)
     for name in names:
         rng = np.random.default_rng(args.seed)
-        state = SHAPES[name]["kernel"] == "ssm_state"
+        kernel = SHAPES[name]["kernel"]
+        retention = kernel.startswith("retention")
+        if retention and pr is None:
+            print(json.dumps({"shape": name, "skipped": "no "
+                              "kernels/power_retention.py in this tree"}))
+            continue
+        state = kernel == "ssm_state" or retention
         fn, again, q, pools, L, nbytes, ref = (
-            build_state if state else build)(SHAPES[name], rng, args.rehearse)
+            build_retention if retention else build_state if state
+            else build)(SHAPES[name], rng, args.rehearse)
         row = {"shape": name, "bytes": nbytes,
                "bytes_us": round(nbytes / PEAK_BYTES_S * 1e6, 2)}
         if args.check:
@@ -310,10 +432,14 @@ def main():
                          for f in (fn, ref))
             row["max_err"] = max(float(jnp.abs(g - w).max())
                                  for g, w in zip(got, want))
+            row["rel_err"] = max(
+                float(jnp.linalg.norm(g - w) / jnp.linalg.norm(w))
+                for g, w in zip(got, want))
         for label, attrs in variants(SHAPES[name]["kernel"],
                                      args.sweep).items():
             try:
-                with patched(ss if state else ra, **attrs):
+                with patched(pr if retention else ss if state else ra,
+                             **attrs):
                     row[label] = round(time_launches(
                         fn, again, q, pools, L, args.launches,
                         carried=state), 2)

@@ -1551,6 +1551,169 @@ def test_the_one_sublayer_decode_window_compiles_with_its_state_in_place(
 
 
 # ---------------------------------------------------------------------------
+# power-retention layers (a state of 65 x 128 x 128 a key/value head, five
+# query heads a group, no position cached):
+# brumby-14b-base.rollout-16x2048-256's geometry
+# ---------------------------------------------------------------------------
+def _brumby_fields():
+    import json
+    from pathlib import Path
+    return json.loads((Path(__file__).resolve().parents[3] / "benchmark"
+                       / "configs/brumby-14b-base.json").read_text())["fields"]
+
+
+def _retention_leaves(sds, kept=jnp.float32, layers=8, slots=17):
+    from deepspeed_tpu.inference.v2.kernels import power_retention as pr
+    state, norm = pr.leaf_shapes(layers, slots, 8, 128)
+    assert state == (layers, slots, 8, 65, 128, 128) \
+        and norm == (layers, slots, 8, 72, 128)
+    return sds(state, kept), sds(norm, kept)
+
+
+@pytest.mark.parametrize("kept", [jnp.float32, jnp.bfloat16])
+def test_the_retention_state_kernel_at_published_widths(tpu_sharding, kept):
+    """``retention_state_update`` at the cell's decode shape (16 rows,
+    40 query heads on 8 key/value heads of 128, leaves of 8 layers and
+    17 slots, float32 and the control's bfloat16): it compiles for the
+    chip, runs as ONE custom call under a name a trace finds, and both
+    leaves are aliased (no copy of 4.67 GB: no temporary at all)."""
+    from deepspeed_tpu.inference.v2.kernels import power_retention as pr
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=tpu_sharding)
+
+    N, nh, nkv, hd = 16, 40, 8, 128
+    state, norm = _retention_leaves(sds, kept)
+    assert pr.state_kernel_serves(state) and pr.chunk_kernel_serves(state)
+    assert not pr.state_kernel_serves(sds((2, 5, 2, 9, 16, 16)))
+    compiled = jax.jit(lambda *a: pr.retention_state_update(*a, 1e-6),
+                       donate_argnums=(0, 1)).lower(
+        state, norm, sds((), jnp.int32), sds((N,), jnp.int32),
+        sds((N,), jnp.bool_), sds((N, nh, hd)), sds((N, nkv, hd)),
+        sds((N, nkv, hd)), sds((N, nkv))).compile()
+    kernels = _custom_calls(compiled)
+    assert len(kernels) == 1 \
+        and kernels[0].startswith("retention_state_update")
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.01e9
+
+
+@pytest.mark.parametrize("kept", [jnp.float32, jnp.bfloat16])
+def test_the_retention_chunk_kernel_at_published_widths(tpu_sharding, kept):
+    """``retention_chunk_fwd`` for the cell's ragged launch (16 rows of
+    512 tokens, q, k and v float32 as the projections leave them; leaves
+    in float32 and in the control's bfloat16, which the first build did
+    not lower: a distance's row of a bfloat16 normaliser): it
+    compiles for the chip, runs as ONE custom call under a name a trace
+    finds, both leaves are aliased, and what it keeps beside its
+    arguments is the output and the gate on every lane (0.17 + 0.03
+    GB)."""
+    from deepspeed_tpu.inference.v2.kernels import power_retention as pr
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=tpu_sharding)
+
+    R, T, nh, nkv, hd = 16, 8192, 40, 8, 128
+    state, norm = _retention_leaves(sds, kept)
+    compiled = jax.jit(lambda *a: pr.retention_chunk_fwd(*a, 1e-6),
+                       donate_argnums=(0, 1)).lower(
+        state, norm, sds((), jnp.int32), sds((R,), jnp.int32),
+        sds((R,), jnp.bool_), sds((R,), jnp.int32), sds((R,), jnp.int32),
+        sds((T, nh, hd)), sds((T, nkv, hd)), sds((T, nkv, hd)),
+        sds((T, nkv))).compile()
+    kernels = _custom_calls(compiled)
+    assert len(kernels) == 1 and kernels[0].startswith("retention_chunk_fwd")
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.3e9
+
+
+def _retention_cut(tpu_sharding):
+    """The block at published widths, cut to two layers and 4,096 rows
+    of the vocabulary: the configuration, and its parameters and cache
+    (16 state slots, NO pool) as shapes on the chip."""
+    from deepspeed_tpu.inference.v2.paged_model import init_paged_kv_cache
+    from deepspeed_tpu.models import TransformerLM
+    from deepspeed_tpu.models.transformer import TransformerConfig
+
+    cfg = TransformerConfig(**{
+        **_brumby_fields(), "num_layers": 2, "vocab_size": 4096,
+        "layer_types": ["power_retention"] * 2})
+
+    def on_tpu(x, dtype=None):
+        return jax.ShapeDtypeStruct(x.shape, dtype or x.dtype,
+                                    sharding=tpu_sharding)
+
+    params = jax.tree.map(
+        lambda x: on_tpu(x, jnp.bfloat16),
+        jax.eval_shape(TransformerLM(cfg).init_params,
+                       jax.random.PRNGKey(0)))
+    cache = jax.tree.map(on_tpu, jax.eval_shape(
+        lambda: init_paged_kv_cache(cfg, 2, 16, jnp.bfloat16,
+                                    state_slots=16)))
+    assert set(cache) == {"retention_state", "retention_norm"}
+    assert cache["retention_state"].shape == (2, 17, 8, 65, 128, 128) \
+        and cache["retention_state"].dtype == jnp.float32
+    return cfg, params, cache
+
+
+def test_the_retention_ragged_step_runs_its_chunks_in_the_kernel(
+        tpu_sharding):
+    """The ragged step of the cut, 16 rows in 2,048 tokens, a block
+    table ONE null entry wide: the chunk kernel runs in the one run of
+    layers, no attention kernel does, and nothing under
+    ``retention_chunk`` loops in XLA."""
+    from deepspeed_tpu.inference.v2.paged_model import paged_ragged_step
+
+    cfg, params, cache = _retention_cut(tpu_sharding)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=tpu_sharding)
+
+    T, R = 2048, 16
+    compiled = jax.jit(
+        lambda p, ids, rows, pos, ln, wb, wo, bt, li, c, ss:
+        paged_ragged_step(cfg, p, ids, rows, pos, ln, wb, wo, bt, li, c, 16,
+                          use_kernel=True, state_slots=ss),
+        donate_argnums=(9,)).lower(
+        params, i32(T), i32(T), i32(T), i32(T), i32(T), i32(T), i32(R, 1),
+        i32(R), cache, i32(R)).compile()
+    kernels = _custom_calls(compiled)
+    assert len(kernels) == 1 and kernels[0].startswith(
+        "retention_chunk_fwd"), kernels
+    assert "retention_chunk/while" not in compiled.as_text()
+
+
+def test_the_retention_decode_window_compiles_with_its_state_in_place(
+        tpu_sharding):
+    """The decode window of the same cut: the state kernel runs in the
+    one run of layers and nothing else is a kernel; nothing under
+    ``retention_state`` gathers or scatters the slots in XLA, and the
+    program's temporaries hold no copy of a state leaf (16 rows x 2
+    layers: 1.1 GB)."""
+    from deepspeed_tpu.inference.v2.paged_model import paged_decode_window
+
+    cfg, params, cache = _retention_cut(tpu_sharding)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=tpu_sharding)
+
+    R = 16
+    compiled = jax.jit(
+        lambda p, t, pos, bt, c, sl, eos, alive, ss: paged_decode_window(
+            cfg, p, t, pos, bt, c, sl, eos, 16, 8, use_kernel=True,
+            alive=alive, state_slots=ss), donate_argnums=(4,)).lower(
+        params, i32(R), i32(R), i32(R, 1), cache, i32(R), i32(R),
+        jax.ShapeDtypeStruct((R,), jnp.bool_, sharding=tpu_sharding),
+        i32(R)).compile()
+    text = compiled.as_text()
+    kernels = _custom_calls(compiled)
+    assert len(kernels) == 1 and kernels[0].startswith(
+        "retention_state_update"), kernels
+    under = re.findall(
+        r"= \S+ ([\w\-]+)\([^\n]*op_name=\"[^\"]*/retention_state/", text)
+    assert under and not {"gather", "scatter"} & set(under), under
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.3e9
+
+
+# ---------------------------------------------------------------------------
 # ZeRO-3 at dp 4: what the scanned backward moves between chips
 # ---------------------------------------------------------------------------
 ZERO3_MICRO, ZERO3_DP, ZERO3_HIDDEN, ZERO3_FFN = 2, 4, 256, 1024
