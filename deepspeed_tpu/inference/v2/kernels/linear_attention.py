@@ -67,22 +67,30 @@ depthwise convolution in front of q, k and v, over a row's own tokens,
 its last ``taps - 1`` inputs carried as state beside ``S`` in a leaf of
 its own, an input as rows of 128 lanes (:func:`conv_leaf_shape`).
 :func:`conv_update` is the one-token form as one launch a layer: a
-grid step takes one row, its slot comes in and goes back once, the
-taps (and a bias, which the Mamba-2 layers of ``state_space.py`` bring
-with their one input in place of three) are fetched once. The ragged step (a row's prompt tokens) keeps
-:func:`causal_conv_rows`.
+grid step takes ``SLOTS_A_STEP`` rows, a row's slot comes in and goes
+back once by the kernel's own copies, several in flight, the taps
+(and a bias, which the Mamba-2 layers of ``state_space.py`` bring with
+their one input in place of three) are fetched once. The ragged step
+(a row's prompt tokens) keeps :func:`causal_conv_rows`.
 """
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .slot_leaf import hbm_out, in_hbm
+
 CHUNK = 64
 SUB = 16
 ROWS_A_STEP = 32
+# rows whose slots a grid step of the convolution's one-token kernel has
+# in flight each way; 4 and 16 read the same on the chip, 2 a fifth more
+# and 1 (the blocked pipeline's) three quarters more (PERF.md, PR 57)
+SLOTS_A_STEP = 8
 _HI = jax.lax.Precision.HIGHEST
 
 
@@ -134,8 +142,9 @@ def _state_kernel(layer_ref, slots_ref, fresh_ref, s_ref, eg_ref, k_ref,
 def kda_state_update(leaf, layer, slots, fresh, q, k, v, g, beta,
                      interpret=False):
     """:func:`kda_step` on the rows' slots of the state leaf where it
-    lies: ``leaf`` ``[layers, slots, nh, dk, dv]`` stays whole in HBM,
-    and a grid step copies in ``HEADS_A_STEP`` heads of row n's slot
+    lies: ``leaf`` ``[layers, slots, nh, dk, dv]`` stays whole in HBM
+    (coloured so, as :func:`conv_update`'s), and a grid step copies in
+    ``HEADS_A_STEP`` heads of row n's slot
     ``slots[n]`` at ``layer`` (both prefetched scalars), puts them
     through the token and copies them back to where they came from
     (aliased): a state is read once and written once, where a gather,
@@ -157,13 +166,14 @@ def kda_state_update(leaf, layer, slots, fresh, q, k, v, g, beta,
     state = pl.BlockSpec(
         (None, None, hb, dk, dv),
         lambda n, h, layer, slots, fresh: (layer[0], slots[n], h, 0, 0))
+    leaf = in_hbm(leaf, interpret)
     so, o = pl.pallas_call(
         functools.partial(_state_kernel, heads=hb),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3, grid=(N, blocks),
             in_specs=[state, col, col, col, col, row],
             out_specs=[state, row]),
-        out_shape=[jax.ShapeDtypeStruct(leaf.shape, leaf.dtype),
+        out_shape=[hbm_out(leaf, interpret),
                    jax.ShapeDtypeStruct((N, nh, dv), jnp.float32)],
         input_output_aliases={3: 0},
         name="kda_state_update", interpret=interpret,
@@ -648,43 +658,117 @@ def conv_kernel_serves(leaf, parts=3) -> bool:
             and (parts == 1 or rows % (parts * 16) == 0))
 
 
-def _conv_kernel(layer_ref, slots_ref, fresh_ref, s_ref, w_ref, *refs,
-                 parts, bias):
-    """One row's token through the convolution: ``s_ref`` [K - 1, rows,
-    128] the slot's last inputs, oldest first, the parts one after
-    another along the rows; ``w_ref`` [K, rows, 128] the taps laid out
-    likewise; ``refs``: the token's projections [rows / parts, 128]
-    each, the bias [rows, 128] where the convolution has one, then the
-    slot and the parts going out."""
-    del layer_ref, slots_ref            # the index maps read them
-    ins, outs = refs[:parts], refs[parts + bias + 1:]
-    so_ref = refs[parts + bias]
-    keep = fresh_ref[pl.program_id(0)] == 0
-    x = jnp.concatenate([r[...].astype(jnp.float32) for r in ins], axis=0)
-    seq = [jnp.where(keep, s_ref[t].astype(jnp.float32), 0.0)
-           for t in range(s_ref.shape[0])] + [x]
+def _conv_token(held, x, w_ref, b_ref):
+    """One row's token: ``held`` the slot's last inputs [rows, 128]
+    float32, oldest first, ``x`` the token's own; the taps ``w_ref``
+    [K, rows, 128], the bias ``b_ref`` [rows, 128] or None. The sum runs
+    oldest tap first, then the bias, then SiLU."""
+    seq = held + [x]
     y = seq[0] * w_ref[0]
     for t in range(1, len(seq)):
         y = y + seq[t] * w_ref[t]
-    if bias:
-        y = y + refs[parts][...]
-    y = jax.nn.silu(y)
-    for t in range(s_ref.shape[0]):
-        so_ref[t] = seq[t + 1].astype(so_ref.dtype)
-    part = ins[0].shape[0]
-    for i, o_ref in enumerate(outs):
-        o_ref[...] = y[i * part:(i + 1) * part].astype(o_ref.dtype)
+    if b_ref is not None:
+        y = y + b_ref[...]
+    return jax.nn.silu(y)
+
+
+def _conv_kernel(layer_ref, slots_ref, fresh_ref, leaf_in, w_ref, *refs,
+                 parts, bias, steps):
+    """``R`` rows' tokens through the convolution a grid step. A row's
+    slot [K - 1, rows, 128] (its last inputs, oldest first, the parts
+    one after another along the rows) is copied out of ``leaf_in`` at
+    ``[layer, slots[row]]`` and, shifted by the token, back to the same
+    place of ``leaf_out`` (the same buffer) by the kernel's own copies:
+    a step starts the NEXT step's ``R`` copies in before it waits for
+    its own, and waits for a buffer's copies back only two steps later,
+    so up to ``3 R`` copies of a slot are in flight, where the blocked
+    pipeline had one each way and took 0.35 us of its own a row.
+    ``refs``: the tokens' projections [R, rows / parts, 128] each, the
+    bias [rows, 128] where the convolution has one, ``leaf_out``, the
+    parts going out, two buffers of ``R`` slots each way and their
+    semaphores. Two rows on ONE slot (rows that are not alive share the
+    null slot) race on it, which nothing reads."""
+    ins = refs[:parts]
+    b_ref = refs[parts] if bias else None
+    leaf_out = refs[parts + bias]
+    outs = refs[parts + bias + 1:2 * parts + bias + 1]
+    held_in, held_out, sem_in, sem_out = refs[2 * parts + bias + 1:]
+    R, K1 = held_in.shape[1:3]
+    g = pl.program_id(0)
+    buf = g % 2
+
+    def load(step, b, i):
+        return pltpu.make_async_copy(
+            leaf_in.at[layer_ref[0], slots_ref[step * R + i]],
+            held_in.at[b, i], sem_in.at[b, i])
+
+    def store(step, b, i):
+        return pltpu.make_async_copy(
+            held_out.at[b, i],
+            leaf_out.at[layer_ref[0], slots_ref[step * R + i]],
+            sem_out.at[b, i])
+
+    def rows(body):
+        # a loop, not R copies of the body: a program lowers the kernel
+        # once a call site, and eight rows unrolled took eight times as
+        # long to lower as one (set-up, not speed: PERF.md, PR 57)
+        jax.lax.fori_loop(0, R, lambda i, _: body(i), None)
+
+    @pl.when(g == 0)
+    def _():
+        rows(lambda i: load(0, 0, i).start())
+
+    if steps > 1:
+        @pl.when(g + 1 < steps)
+        def _():
+            rows(lambda i: load(g + 1, 1 - buf, i).start())
+
+        @pl.when(g >= 2)
+        def _():
+            rows(lambda i: store(g - 2, buf, i).wait())
+
+    part = ins[0].shape[1]
+
+    def token(i):
+        load(g, buf, i).wait()
+        keep = fresh_ref[g * R + i] == 0
+        x = jnp.concatenate([r[i].astype(jnp.float32) for r in ins], axis=0)
+        held = [jnp.where(keep, held_in[buf, i, t].astype(jnp.float32), 0.0)
+                for t in range(K1)]
+        y = _conv_token(held, x, w_ref, b_ref)
+        for t, kept in enumerate(held[1:] + [x]):
+            held_out[buf, i, t] = kept.astype(held_out.dtype)
+        store(g, buf, i).start()
+        for j, o_ref in enumerate(outs):
+            o_ref[i] = y[j * part:(j + 1) * part].astype(o_ref.dtype)
+
+    rows(token)
+
+    @pl.when(g == steps - 1)
+    def _():
+        rows(lambda i: store(g, buf, i).wait())
+        if steps > 1:
+            rows(lambda i: store(g - 1, 1 - buf, i).wait())
 
 
 def conv_update(leaf, layer, slots, fresh, parts, taps, bias=None,
                 name="kda_conv_update", interpret=False):
     """:func:`causal_conv_step` with SiLU on the rows' slots of the
     convolution leaf where it lies: ``leaf`` ``[layers, slots, K - 1,
-    rows, 128]`` (:func:`conv_leaf_shape`) stays whole in HBM, and a
-    grid step copies in row n's slot ``slots[n]`` at ``layer`` (both
-    prefetched scalars) and the row's new projections ``parts`` (arrays
-    [N, D] side by side along the channels, in their own type), and
-    writes the slot's inputs shifted by one with the token's own
+    rows, 128]`` (:func:`conv_leaf_shape`) stays whole in HBM (COLOURED
+    so, operand and aliased output, where the kernel is compiled for a
+    TPU: ``slot_leaf``; left to the compiler, a leaf that fits the
+    chip's fast memory was carried there whole and back round every
+    launch, and ``pl.ANY`` or ``pltpu.HBM`` on the ``BlockSpec`` did not
+    stop it: they say where the kernel finds its operand, not where the
+    program keeps it; on a TPU the caller donates the leaf or carries
+    it in a loop, ``slot_leaf``'s last paragraph), and a grid step
+    copies in the slots ``slots[n]`` at ``layer`` (both prefetched
+    scalars) of ``SLOTS_A_STEP`` rows (of its largest divisor that
+    divides the rows' count) side by side, a step ahead
+    (:func:`_conv_kernel`), takes the rows' new projections ``parts``
+    (arrays [N, D] side by side along the channels, in their own type),
+    and writes each slot's inputs shifted by one with the token's own
     appended back to where they came from (aliased) and the convolved,
     SiLU'd parts in the projections' type: a slot is read once and
     written once. ``fresh[n]``: the row's first token, its inputs start
@@ -695,26 +779,33 @@ def conv_update(leaf, layer, slots, fresh, parts, taps, bias=None,
     N, D = parts[0].shape
     K1, rows, lanes = leaf.shape[2:]
     n = len(parts)
-    part = pl.BlockSpec((None, rows // n, lanes), lambda r, *_: (r, 0, 0))
-    state = pl.BlockSpec(
-        (None, None, K1, rows, lanes),
-        lambda r, layer, slots, fresh: (layer[0], slots[r], 0, 0, 0))
-    weights = pl.BlockSpec((K1 + 1, rows, lanes), lambda r, *_: (0, 0, 0))
+    R = math.gcd(N, SLOTS_A_STEP)
+    part = pl.BlockSpec((R, rows // n, lanes), lambda g, *_: (g, 0, 0))
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    weights = pl.BlockSpec((K1 + 1, rows, lanes), lambda g, *_: (0, 0, 0))
     offset = [] if bias is None else [
         bias.astype(jnp.float32).reshape(rows, lanes)]
+    leaf = in_hbm(leaf, interpret)
     leaf, *mixed = pl.pallas_call(
-        functools.partial(_conv_kernel, parts=n, bias=len(offset)),
+        functools.partial(_conv_kernel, parts=n, bias=len(offset),
+                          steps=N // R),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3, grid=(N,),
-            in_specs=[state, weights] + [part] * n + [
-                pl.BlockSpec((rows, lanes), lambda r, *_: (0, 0))
+            num_scalar_prefetch=3, grid=(N // R,),
+            in_specs=[hbm, weights] + [part] * n + [
+                pl.BlockSpec((rows, lanes), lambda g, *_: (0, 0))
                 for _ in offset],
-            out_specs=[state] + [part] * n),
-        out_shape=[jax.ShapeDtypeStruct(leaf.shape, leaf.dtype)] + [
+            out_specs=[hbm] + [part] * n,
+            scratch_shapes=[
+                pltpu.VMEM((2, R, K1, rows, lanes), leaf.dtype)] * 2 + [
+                pltpu.SemaphoreType.DMA((2, R))] * 2),
+        out_shape=[hbm_out(leaf, interpret)] + [
             jax.ShapeDtypeStruct((N, rows // n, lanes), a.dtype)
             for a in parts],
         input_output_aliases={3: 0},
-        name=name, interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        name=name,
+        interpret=pltpu.InterpretParams() if interpret else False,
     )(jnp.reshape(layer, (1,)).astype(jnp.int32), slots.astype(jnp.int32),
       fresh.astype(jnp.int32), leaf,
       taps.astype(jnp.float32).reshape(K1 + 1, rows, lanes),

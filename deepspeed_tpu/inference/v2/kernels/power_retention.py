@@ -62,6 +62,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .slot_leaf import hbm_out, in_hbm
+
 CHUNK = 128
 # value channels of a head's state a pass of the decode kernel's inner
 # loop takes: 32 rows x 5 query heads are 20 accumulator registers
@@ -246,7 +248,11 @@ def _state_kernel(layer_ref, slots_ref, fresh_ref, s_ref, z_ref, q_ref,
 def retention_state_update(state, norm, layer, slots, fresh, q, k, v, g,
                            eps, interpret=False):
     """:func:`retention_step` on the rows' slots of the leaves where
-    they lie: both stay whole in HBM, and a grid step copies in ONE
+    they lie: both stay whole in HBM (coloured so, ``slot_leaf``:
+    left to the compiler, the normaliser's leaf, 40 MB at the cell's
+    shape, was carried into the chip's fast memory in four slices ahead
+    of every launch and copied back behind it), and a grid step copies
+    in ONE
     key/value head of row n's slot ``slots[n]`` at ``layer`` (prefetched
     scalars), puts it through the token for the head's whole group of
     queries and copies it back to where it came from (aliased): a state
@@ -265,6 +271,7 @@ def retention_state_update(state, norm, layer, slots, fresh, q, k, v, g,
         (None, None, None, Dp, hd),
         lambda n, j, layer, slots, fresh: (layer[0], slots[n], j, 0, 0))
     lanes = (N, nkv, 1, hd)
+    state, norm = in_hbm(state, interpret), in_hbm(norm, interpret)
     state, norm, o = pl.pallas_call(
         functools.partial(_state_kernel, eps=eps, stripe=min(STRIPE, hd)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -274,8 +281,7 @@ def retention_state_update(state, norm, layer, slots, fresh, q, k, v, g,
             scratch_shapes=[pltpu.VMEM((Dp, hd), _F32),
                             pltpu.VMEM((G, Dp, hd), _F32),
                             pltpu.VMEM((G, hd, hd), _F32)]),
-        out_shape=[jax.ShapeDtypeStruct(state.shape, state.dtype),
-                   jax.ShapeDtypeStruct(norm.shape, norm.dtype),
+        out_shape=[hbm_out(state, interpret), hbm_out(norm, interpret),
                    jax.ShapeDtypeStruct((N, nkv, G, hd), _F32)],
         input_output_aliases={3: 0, 4: 1},
         compiler_params=pltpu.CompilerParams(

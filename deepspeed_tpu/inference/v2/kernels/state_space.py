@@ -67,6 +67,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .slot_leaf import hbm_out, in_hbm
+
 CHUNK = 256
 _HI = jax.lax.Precision.HIGHEST
 # channel groups (of 128 lanes) of one row a decode grid step takes: 16
@@ -181,7 +183,9 @@ def _state_kernel(layer_ref, slots_ref, fresh_ref, s_ref, decay_ref, dtx_ref,
 def ssm_state_update(leaf, layer, slots, fresh, x, dt, a, b, c,
                      interpret=False):
     """:func:`ssm_step` on the rows' slots of the state leaf where it
-    lies: ``leaf`` stays whole in HBM, and a grid step copies in
+    lies: ``leaf`` stays whole in HBM (coloured so, ``slot_leaf``:
+    the one rule of every slot leaf a kernel updates in place, whether
+    or not it could fit anywhere else), and a grid step copies in
     ``GROUPS_A_STEP`` channel groups of row n's slot ``slots[n]`` at
     ``layer`` (both prefetched scalars), puts them through the token and
     copies them back to where they came from (aliased): a state is read
@@ -206,6 +210,7 @@ def ssm_state_update(leaf, layer, slots, fresh, x, dt, a, b, c,
     state = pl.BlockSpec(
         (None, None, gb, n, W),
         lambda r, g, layer, slots, fresh: (layer[0], slots[r], g, 0, 0))
+    leaf = in_hbm(leaf, interpret)
     so, y = pl.pallas_call(
         functools.partial(_state_kernel, per=per),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -213,7 +218,7 @@ def ssm_state_update(leaf, layer, slots, fresh, x, dt, a, b, c,
             in_specs=[state, row, row, shared, shared],
             out_specs=[state, row],
             scratch_shapes=[pltpu.VMEM((n, W), jnp.float32)] * 2),
-        out_shape=[jax.ShapeDtypeStruct(leaf.shape, leaf.dtype),
+        out_shape=[hbm_out(leaf, interpret),
                    jax.ShapeDtypeStruct((N, G, W), jnp.float32)],
         input_output_aliases={3: 0},
         name="ssm_state_update", interpret=interpret,

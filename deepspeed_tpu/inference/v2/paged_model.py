@@ -929,9 +929,12 @@ def _linear_attention_sublayer(cfg, lp, x, l, cache, rows: _StateRows,
     leading axis). q, k and v pass the short causal convolution over the
     row's own tokens and SiLU (scope ``kda_conv``: a decode batch through
     the kernel that reads and writes the slot's last inputs where they
-    lie, ``kda_conv_update``, where ``use_kernel`` and the widths allow,
-    else gather, ``causal_conv_step`` and scatter; every other launch
-    through ``causal_conv_rows``); a head's q and k are l2-normalised (q
+    lie, ``kda_conv_update``, where ``use_kernel`` and the widths allow
+    (the kernel COLOURS the leaf HBM, ``kernels/slot_leaf``, which is
+    what keeps it where it lies between launches: ``pl.ANY`` on its
+    operand would not), else gather, ``causal_conv_step`` and scatter;
+    every other launch through ``causal_conv_rows``); a head's q and k
+    are l2-normalised (q
     times d_k^-1/2); the decay a head and key channel is
     ``linear_decay_floor * sigmoid(exp(a_log) (wf x + dt_bias))`` and
     the update strength ``sigmoid(wb x)``; the heads' outputs are
@@ -1041,7 +1044,11 @@ def _state_space_sublayer(cfg, lp, x, l, cache, rows: _StateRows,
     convolution over the row's own tokens, its bias and SiLU (scope
     ``ssm_conv``: a decode batch through the convolution's kernel on the
     slot where it lies, ``ssm_conv_update`` in a trace, where
-    ``use_kernel`` and the widths allow, else gather,
+    ``use_kernel`` and the widths allow (the kernel colours the leaf
+    HBM, ``kernels/slot_leaf``: until PR 57 the compiler carried its
+    59-67 MB into the chip's fast memory and back round every launch of
+    a decode step, and neither ``pl.ANY`` nor ``pltpu.HBM`` on the
+    kernel's ``BlockSpec`` stops that), else gather,
     ``causal_conv_step`` and scatter; every other launch through
     ``causal_conv_rows``); ``dt = softplus(dt + dt_bias)`` and ``A =
     -exp(a_log)`` a head; the recurrence runs in float32 from the row's
@@ -1142,7 +1149,10 @@ def _power_retention_sublayer(cfg, lp, x, l, cache, cos, sin,
     ONE key/value head read by its whole group of query heads: a decode
     batch through the one-token update (scope ``retention_state``: the
     kernel ``retention_state_update`` where ``use_kernel`` and the
-    widths allow, else gather, ``retention_step`` and scatter), every
+    widths allow, both leaves coloured HBM by the kernel
+    (``kernels/slot_leaf``: the normaliser's 40 MB were carried into
+    fast memory and back round every launch), else gather,
+    ``retention_step`` and scatter), every
     other launch through the chunked form, rows of any lengths (scope
     ``retention_chunk``: the kernel ``retention_chunk_fwd`` or the XLA
     ``retention_chunked``): ``kernels/power_retention``. No position is

@@ -28,6 +28,16 @@ and output and the rows' states once each way; ``copies`` there is the
 kernel with its distances' loop taken out: the windows' copies, the decay
 and the attention form inside a chunk).
 
+The convolution's one-token kernel (``conv_update`` of
+``kernels/linear_attention.py``: ``ssm_conv_update`` with one input and a
+bias at nemotron's and granite's decode shapes, ``kda_conv_update`` with q,
+k and v at ling's) stands there as well, the leaf carried through the loop,
+bytes the rows' slots once each way: the kernel's own time FROM HBM, which
+is what a decode program pays since PR 57 (the kernel colours its leaf HBM:
+``kernels/slot_leaf.py``; on a tree before it the loop's leaf, where it
+fits, lies in the chip's fast memory from launch to launch and the time is
+the kernel's from THERE).
+
 The variants take the walk apart by replacing one function of
 ``kernels/ragged_attention.py`` in this process (nothing a cell runs is
 touched, and no option of the program exists for it):
@@ -69,6 +79,8 @@ import numpy as np                                            # noqa: E402
 ra = importlib.import_module(                                 # noqa: E402
     "deepspeed_tpu.inference.v2.kernels.ragged_attention")
 from deepspeed_tpu.inference.v2.kernels import state_space as ss  # noqa: E402
+from deepspeed_tpu.inference.v2.kernels import (          # noqa: E402
+    linear_attention as la)
 try:                            # a tree before the kind: its shapes skip
     from deepspeed_tpu.inference.v2.kernels import (      # noqa: E402
         power_retention as pr)
@@ -107,6 +119,14 @@ SHAPES = {
                          nh=40, kvh=8, hd=128),
     "brumby-chunk": dict(kernel="retention_chunk", rows=16, layers=2,
                          nh=40, kvh=8, hd=128, tokens=512),
+    # the convolution's one-token kernel: ``width`` channels in ``parts``
+    # arrays side by side, four taps
+    "nemotron-conv": dict(kernel="conv", rows=128, layers=7, width=6144,
+                          parts=1, bias=True, name="ssm_conv_update"),
+    "granite-conv": dict(kernel="conv", rows=64, layers=9, width=8448,
+                         parts=1, bias=True, name="ssm_conv_update"),
+    "ling-conv": dict(kernel="conv", rows=128, layers=7, width=12288,
+                      parts=3, bias=False, name="kda_conv_update"),
 }
 BS = 16
 EPS = 1e-6
@@ -177,6 +197,46 @@ def build_retention(shape, rng, rehearse):
         return q + o * 0
     held = rows * (state[0, 0].nbytes + norm[0, 0].nbytes)
     return fn, again, q, (state, norm), L, int(2 * held + moved), ref
+
+
+def build_conv(shape, rng, rehearse):
+    """One launch of ``conv_update`` as the decode programs make it (the
+    projections float32): ``(fn(x, layer, leaf) -> (the first part out,
+    leaf), again, x, (leaf,), layers, the slots' bytes a launch,
+    reference)``; every row a slot of its own, out of order."""
+    rows, L, width, n = (shape[k] for k in
+                         ("rows", "layers", "width", "parts"))
+    K = 4
+    if rehearse:
+        rows, L, width = 3, 2, 256 * n
+    key = jax.random.PRNGKey(int(rng.integers(1 << 30)))
+    f = lambda i, *s: jax.random.normal(jax.random.fold_in(key, i), s)
+    leaf = f(0, *la.conv_leaf_shape(L, rows + 1, K, width))
+    x, *others = (f(1 + i, rows, width // n) for i in range(n))
+    taps = f(5, K, width).astype(jnp.bfloat16)
+    bias = f(6, width).astype(jnp.bfloat16) if shape["bias"] else None
+    slots = jnp.asarray(rng.permutation(rows) + 1, jnp.int32)
+    fresh = jnp.zeros(rows, bool)
+
+    def fn(x, layer, leaf):
+        mixed, leaf = la.conv_update(
+            leaf, layer, slots, fresh, (x, *others), taps, bias,
+            name=shape["name"], interpret=rehearse)
+        return mixed[0], leaf
+
+    def ref(x, layer, leaf):
+        def act(y):
+            return jax.nn.silu(y if bias is None
+                               else y + bias.astype(jnp.float32))
+        held = leaf[layer, slots].reshape(rows, K - 1, width)
+        y, held = la.causal_conv_step(
+            jnp.concatenate((x, *others), axis=-1), taps, held, act)
+        return y[:, :width // n], leaf.at[layer, slots].set(
+            held.reshape(rows, *leaf.shape[2:]))
+
+    def again(x, y):
+        return x + y * 0
+    return fn, again, x, (leaf,), L, 2 * rows * leaf[0, 0].nbytes, ref
 
 
 def build_state(shape, rng, rehearse):
@@ -341,12 +401,20 @@ def _no_distances(lo, hi, body, init, **kw):
     return _FORI(lo, hi, body, init, **kw)
 
 
+def _conv_copies(held, x, w_ref, b_ref):
+    """:func:`la._conv_token` with the sum taken out: the kernel's copies
+    of the slots and the shift by the token."""
+    return x
+
+
 _FORI = jax.lax.fori_loop
 
 
 def variants(kernel, sweep):
     if kernel == "ssm_state":
         return {"full": {}, "copies": dict(_state_kernel=_state_copies)}
+    if kernel == "conv":
+        return {"full": {}, "copies": dict(_conv_token=_conv_copies)}
     if kernel == "retention_state":
         return {"full": {},
                 "copies": dict(_state_kernel=_retention_copies)}
@@ -416,30 +484,37 @@ def main():
     for name in names:
         rng = np.random.default_rng(args.seed)
         kernel = SHAPES[name]["kernel"]
-        retention = kernel.startswith("retention")
-        if retention and pr is None:
+        if kernel.startswith("retention") and pr is None:
             print(json.dumps({"shape": name, "skipped": "no "
                               "kernels/power_retention.py in this tree"}))
             continue
-        state = kernel == "ssm_state" or retention
-        fn, again, q, pools, L, nbytes, ref = (
-            build_retention if retention else build_state if state
-            else build)(SHAPES[name], rng, args.rehearse)
+        # the kernels that update a state leaf in place (their builder,
+        # the module a variant patches): the loop carries the leaf
+        in_place = {"ssm_state": (build_state, ss), "conv": (build_conv, la),
+                    "retention_state": (build_retention, pr),
+                    "retention_chunk": (build_retention, pr)}
+        state = kernel in in_place
+        builder, module = in_place.get(kernel, (build, ra))
+        fn, again, q, pools, L, nbytes, ref = builder(
+            SHAPES[name], rng, args.rehearse)
         row = {"shape": name, "bytes": nbytes,
                "bytes_us": round(nbytes / PEAK_BYTES_S * 1e6, 2)}
         if args.check:
-            got, want = (jax.tree.leaves(jax.jit(f)(q, L - 1, *pools))
-                         for f in (fn, ref))
+            # a leaf updated in place goes in donated, as the engine's
+            # cache does: a kernel that colours its leaf HBM cannot take
+            # a copy the compiler made (kernels/slot_leaf.py)
+            given = tuple(range(2, 2 + len(pools))) if state else ()
+            got, want = (jax.tree.leaves(jax.jit(f, donate_argnums=given)(
+                q, L - 1, *(jnp.copy(p) if state else p for p in pools)))
+                for f in (fn, ref))
             row["max_err"] = max(float(jnp.abs(g - w).max())
                                  for g, w in zip(got, want))
             row["rel_err"] = max(
                 float(jnp.linalg.norm(g - w) / jnp.linalg.norm(w))
                 for g, w in zip(got, want))
-        for label, attrs in variants(SHAPES[name]["kernel"],
-                                     args.sweep).items():
+        for label, attrs in variants(kernel, args.sweep).items():
             try:
-                with patched(pr if retention else ss if state else ra,
-                             **attrs):
+                with patched(module, **attrs):
                     row[label] = round(time_launches(
                         fn, again, q, pools, L, args.launches,
                         carried=state), 2)

@@ -466,9 +466,12 @@ def test_the_conv_kernel_is_the_one_token_convolution_in_place(
     sum of bf16 projections is equal to the bit too once rounded; on
     float32 ones the two programs differ by where the CPU's compiler
     contracts a product and a sum (an ulp). Rows that share the null
-    slot see each other's writes there in a kernel that runs a row
-    after another, and not in a gather: of them, only the fresh ones'
-    outputs are defined, and the slot's newest input is one row's."""
+    slot see each other's writes there in a kernel that copies the
+    rows' slots itself, and not in a gather: of them, only the fresh
+    ones' outputs are defined, and the slot's newest input is one row's
+    (under the interpreter, where a copy is whole; on a chip the copies
+    of several rows are in flight side by side and may interleave on
+    the null slot, which nothing reads)."""
     rng = np.random.default_rng(0)
     N, D, K, L, S = 6, 16 * 128, 4, 2, 9
     slots, fresh = (np.asarray(a) for a in CONV_ROWS[rows])

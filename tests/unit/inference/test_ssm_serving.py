@@ -374,17 +374,23 @@ def test_one_token_forms_are_the_token_scan(nh, p, n):
         assert call.params["input_output_aliases"] == ((3, 0),)
 
 
-def test_conv_kernel_takes_one_input_and_a_bias():
+@pytest.mark.parametrize("N", [3, 8, 12, 24])
+def test_conv_kernel_takes_one_input_and_a_bias(N):
     """The convolution's kernel as the state-space layers use it: ONE
     input of 66 lane blocks (8,448 channels: x, B and C), a bias, SiLU,
-    under the interpreter against ``causal_conv_step``."""
+    under the interpreter against ``causal_conv_step``; the rows' slots
+    1, 8, 4 and 8 a grid step (``SLOTS_A_STEP``'s largest divisor that
+    divides the rows), in three steps, one, three and three: the first
+    step's copies in, a next step's started ahead, a buffer's copies
+    back waited for two steps on and at the end."""
     rng = np.random.default_rng(2)
-    N, D, K = 3, 66 * 128, 4
+    D, K, S = 66 * 128, 4, 2 * N
     f = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)
-    leaf0 = f(*la.conv_leaf_shape(2, 5, K, D))
-    assert leaf0.shape == (2, 5, 3, 66, 128)
+    leaf0 = f(*la.conv_leaf_shape(2, S, K, D))
+    assert leaf0.shape == (2, S, 3, 66, 128)
     x, taps, bias = f(N, D).astype(jnp.bfloat16), f(K, D), f(D)
-    slots, fresh = jnp.asarray([2, 4, 1]), jnp.asarray([False, True, False])
+    slots = jnp.asarray(rng.permutation(S - 1)[:N] + 1)
+    fresh = jnp.asarray(rng.random(N) < 0.4)
     (y,), leaf = la.conv_update(leaf0, jnp.int32(1), slots, fresh, (x,),
                                 taps, bias, name="ssm_conv_update",
                                 interpret=True)
@@ -395,6 +401,8 @@ def test_conv_kernel_takes_one_input_and_a_bias():
     np.testing.assert_allclose(np.asarray(y, np.float32),
                                np.asarray(want, np.float32), atol=2e-2)
     np.testing.assert_array_equal(leaf[1, slots].reshape(N, K - 1, D), kept)
+    others = np.setdiff1d(np.arange(S), np.asarray(slots))
+    np.testing.assert_array_equal(leaf[1, others], leaf0[1, others])
     np.testing.assert_array_equal(leaf[0], leaf0[0])
 
 

@@ -86,6 +86,33 @@ def _compile_error(fn, *args):
     return None
 
 
+# what makes a second copy of a value, or carries it somewhere else
+_MOVES = ("copy", "copy-start", "copy-done", "slice-start", "slice-done",
+          "fusion", "transpose")
+
+
+def _leaves_stay_in_hbm(text, cache, *names):
+    """The compiled program ``text`` keeps the cache leaves ``names``
+    where they lie: no value of a leaf's shape is laid in the chip's fast
+    memory (``S(1)`` in its layout: at a custom call's operand or result,
+    or anywhere else), and no instruction whose result (or, of an
+    asynchronous start, whose operand) has the leaf's shape copies,
+    slices or rewrites it: PR 57's bug, the compiler carrying a whole
+    leaf into fast memory and back round a kernel that moves the rows'
+    slots of one layer."""
+    for name in names:
+        leaf = cache[name]
+        shape = "%s[%s]" % ({"float32": "f32", "bfloat16": "bf16"}[
+            str(leaf.dtype)], ",".join(map(str, leaf.shape)))
+        assert shape in text, f"the program no longer holds {name} {shape}"
+        fast = re.findall(re.escape(shape) + r"\{[^}]*S\(1\)", text)
+        assert not fast, f"{name} {shape} lies in fast memory {len(fast)} x"
+        moved = [m.group(2) for m in re.finditer(
+            r"^\s*(?:ROOT )?%[\w.\-]+ = ([^\n]*?) ([\w\-]+)\(", text, re.M)
+            if shape in m.group(1) and m.group(2) in _MOVES]
+        assert not moved, f"{name} {shape} is moved by {moved}"
+
+
 def _paged_args(sds, hd, kvh, quant, T=8, R=4, MB=8, nb=64, layers=2):
     """``ragged_attention``'s operands: queries, the two pool leaves as
     stored (``[L, nb, 16, kvh * hd]``), the layer, the descriptors, and
@@ -756,8 +783,9 @@ def test_the_hybrid_decode_window_compiles_with_its_state_in_place(
     experts held): the state kernel and the convolution's kernel run in
     both linear runs, the grouped matmul in the expert layer; nothing
     under ``kda_conv`` gathers or scatters the slots in XLA any more or
-    copies the convolution's leaf (a copy of it may only be the move of
-    this cut's small leaf, 15 MB, into the chip's fast memory and back);
+    copies the convolution's leaf, and neither leaf is laid in the
+    chip's fast memory (this cut's small convolution leaf, 15 MB, was
+    moved there and back round every launch until PR 57 coloured it);
     and the program's temporaries hold no copy of the state leaf (32
     rows x 3 layers: 0.2 GB)."""
     from deepspeed_tpu.inference.v2.paged_model import paged_decode_window
@@ -784,10 +812,9 @@ def test_the_hybrid_decode_window_compiles_with_its_state_in_place(
     assert sum(bool(GMM_PATTERN.search(k)) for k in kernels) == 3, kernels
     under_conv = re.findall(
         r"= \S+ ([\w\-]+)\([^\n]*op_name=\"[^\"]*/kda_conv/", text)
-    assert under_conv and not {"gather", "scatter", "copy", "copy-start"} \
-        & set(under_conv), under_conv
-    leaf = re.escape("f32[%s]" % ",".join(map(str, cache["kda_conv"].shape)))
-    assert not re.search(r"= %s\S* (?:fusion|copy)\(" % leaf, text)
+    assert under_conv and not {"gather", "scatter", "copy", "copy-start",
+                               "copy-done"} & set(under_conv), under_conv
+    _leaves_stay_in_hbm(text, cache, "kda_conv", "kda_state")
     assert compiled.memory_analysis().temp_size_in_bytes < 0.15e9
 
 
@@ -1256,8 +1283,15 @@ def test_the_state_space_decode_window_compiles_with_its_state_in_place(
     convolution's kernel run in both state-space runs, the tiled
     attention kernel (its one-token form) and the grouped matmul beside
     them; nothing under ``ssm_conv`` or ``ssm_state`` gathers or
-    scatters the slots in XLA, and the program's temporaries hold no
-    copy of the state leaf (32 rows x 2 layers: 0.27 GB)."""
+    scatters the slots in XLA, no kernel takes a leaf from the chip's
+    fast memory, and the program's temporaries hold no copy of the
+    state leaf (32 rows x 2 layers: 0.27 GB). What the colour does NOT
+    rule (PERF.md section 7): in this program of three layers the
+    compiler keeps the WINDOW's carry of the small convolution leaf
+    (6.7 MB) in fast memory from step to step and moves it out and back
+    once a step round the coloured launches; at the configuration's own
+    depth it does so at no row count from 4 to 128 (the cells' case
+    below holds 64)."""
     from deepspeed_tpu.inference.v2.paged_model import paged_decode_window
 
     cfg, params, cache = _state_space_cut(tpu_sharding)
@@ -1282,6 +1316,11 @@ def test_the_state_space_decode_window_compiles_with_its_state_in_place(
     under = re.findall(
         r"= \S+ ([\w\-]+)\([^\n]*op_name=\"[^\"]*/ssm_(?:conv|state)/", text)
     assert under and not {"gather", "scatter"} & set(under), under
+    _leaves_stay_in_hbm(text, cache, "ssm_state")
+    conv = "f32[%s]" % ",".join(map(str, cache["ssm_conv"].shape))
+    assert not [line for line in text.splitlines()
+                if "tpu_custom_call" in line
+                and re.search(re.escape(conv) + r"\{[^}]*S\(1\)", line)]
     assert compiled.memory_analysis().temp_size_in_bytes < 0.15e9
 
 
@@ -1360,12 +1399,15 @@ def test_the_ssm_chunk_kernel_at_eight_groups(tpu_sharding):
 # sha256 (16 hex) of the jaxprs of granite's one-group kernel calls at its
 # published shapes, kernel bodies and index maps included, addresses struck
 # out, read by the test below: the chunk kernel's on PR 52's parent commit
-# (e16ef18), the one-token kernel's on PR 53's tree, which changed it on
-# purpose (B and C a row a group, spread in VMEM; a6ea15075c99f564 before).
+# (e16ef18), the one-token kernel's on PR 57's tree, which coloured its
+# leaf HBM and changed nothing else (0c608753769a2afb before, PR 53's, which
+# changed it on purpose: B and C a row a group, spread in VMEM; the two
+# jaxprs differ by the ``with_memory_space_constraint`` equation ahead of
+# the call and ``float32<hbm>`` in its ``out_avals``, variables' names apart).
 # The lowered TEXT carries the kernels' source lines (Mosaic's payload
 # embeds them), so it moves with every edit of the file; the jaxpr is what
 # is lowered
-GRANITE_KERNEL_JAXPRS = {"ssm_state_update": "0c608753769a2afb",
+GRANITE_KERNEL_JAXPRS = {"ssm_state_update": "bec48aa2c196eb88",
                          "ssm_chunk_fwd": "9457ed25af6cccce"}
 
 
@@ -1521,8 +1563,11 @@ def test_the_one_sublayer_decode_window_compiles_with_its_state_in_place(
     convolution's kernel in the three mamba layers, the tiled attention
     kernel (its one-token form) and four grouped matmuls beside them;
     nothing under ``ssm_conv`` or ``ssm_state`` gathers or scatters the
-    slots in XLA, and the program's temporaries hold no copy of the
-    state leaf (32 rows x 3 layers: 0.2 GB) or of an expert stack."""
+    slots in XLA or copies a leaf (``copy`` under ``ssm_conv`` was
+    allowed until PR 57: the compiler's move of the convolution's leaf
+    into fast memory and back), neither leaf is laid in the chip's fast
+    memory, and the program's temporaries hold no copy of the state
+    leaf (32 rows x 3 layers: 0.2 GB) or of an expert stack."""
     from deepspeed_tpu.inference.v2.paged_model import paged_decode_window
 
     cfg, params, cache = _one_sublayer_cut(tpu_sharding)
@@ -1546,7 +1591,9 @@ def test_the_one_sublayer_decode_window_compiles_with_its_state_in_place(
     assert sum(bool(GMM_PATTERN.search(k)) for k in kernels) == 4, kernels
     under = re.findall(
         r"= \S+ ([\w\-]+)\([^\n]*op_name=\"[^\"]*/ssm_(?:conv|state)/", text)
-    assert under and not {"gather", "scatter"} & set(under), under
+    assert under and not {"gather", "scatter", "copy", "copy-start",
+                          "copy-done"} & set(under), under
+    _leaves_stay_in_hbm(text, cache, "ssm_conv", "ssm_state")
     assert compiled.memory_analysis().temp_size_in_bytes < 0.15e9
 
 
@@ -1685,9 +1732,10 @@ def test_the_retention_decode_window_compiles_with_its_state_in_place(
         tpu_sharding):
     """The decode window of the same cut: the state kernel runs in the
     one run of layers and nothing else is a kernel; nothing under
-    ``retention_state`` gathers or scatters the slots in XLA, and the
-    program's temporaries hold no copy of a state leaf (16 rows x 2
-    layers: 1.1 GB)."""
+    ``retention_state`` gathers or scatters the slots in XLA, neither
+    leaf is laid in the chip's fast memory or copied, and the program's
+    temporaries hold no copy of a state leaf (16 rows x 2 layers: 1.1
+    GB)."""
     from deepspeed_tpu.inference.v2.paged_model import paged_decode_window
 
     cfg, params, cache = _retention_cut(tpu_sharding)
@@ -1710,7 +1758,94 @@ def test_the_retention_decode_window_compiles_with_its_state_in_place(
     under = re.findall(
         r"= \S+ ([\w\-]+)\([^\n]*op_name=\"[^\"]*/retention_state/", text)
     assert under and not {"gather", "scatter"} & set(under), under
+    _leaves_stay_in_hbm(text, cache, "retention_state", "retention_norm")
     assert compiled.memory_analysis().temp_size_in_bytes < 0.3e9
+
+
+# ---------------------------------------------------------------------------
+# the recurrent cells' decode windows at their OWN cache shapes: a slot
+# leaf that a kernel updates in place stays in HBM (PR 57)
+# ---------------------------------------------------------------------------
+# cell -> (the leaves its one-token kernels update in place, their custom
+# calls a decode step: nemotron's seven mamba layers are seven runs of one,
+# granite's nine two scanned runs, ling's seven three, brumby's eight one)
+IN_PLACE_CELLS = {
+    "nemotron-3-nano-30b-a3b.rollout-128x256-384": (
+        ("ssm_conv", "ssm_state"),
+        {"ssm_conv_update": 7, "ssm_state_update": 7}),
+    "granite-4.0-h-small.rollout-64x1024-256": (
+        ("ssm_conv", "ssm_state"),
+        {"ssm_conv_update": 2, "ssm_state_update": 2}),
+    "brumby-14b-base.rollout-16x2048-256": (
+        ("retention_norm", "retention_state"),
+        {"retention_state_update": 1}),
+    "ling-3.0-flash.rollout-128x256": (
+        ("kda_conv", "kda_state"),
+        {"kda_conv_update": 3, "kda_state_update": 3}),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(IN_PLACE_CELLS))
+def test_a_cells_decode_window_keeps_its_slot_leaves_in_hbm(tpu_sharding,
+                                                            cell):
+    """The decode window of a cell with recurrent layers, at the
+    configuration's ``fields`` and the cell's own rows, state slots,
+    blocks and table width (``benchmark/workloads/<cell>.json``; the
+    parameters and the cache as shapes): it compiles for the chip, every
+    one-token kernel runs under its name, and NO leaf that one of them
+    updates in place is laid in fast memory or copied whole. Until PR 57
+    the compiler carried nemotron's convolution leaf ``[7, 129, 3, 48,
+    128]`` (66.6 MB) into fast memory and back round six of its seven
+    launches a step, granite's ``[9, 65, 3, 66, 128]`` (59.3 MB) round
+    both of its call sites, and brumby's normaliser ``[8, 17, 8, 72,
+    128]`` (40.1 MB) round every launch; ling's ``[7, 129, 3, 96, 128]``
+    (133 MB) never fitted, and the case holds that the colour lost
+    nothing there."""
+    import json
+    from pathlib import Path
+
+    from deepspeed_tpu.inference.v2.paged_model import (
+        init_paged_kv_cache, paged_decode_window)
+    from deepspeed_tpu.models import TransformerLM
+    from deepspeed_tpu.models.transformer import TransformerConfig
+
+    bench = Path(__file__).resolve().parents[3] / "benchmark"
+    work = json.loads((bench / "workloads" / f"{cell}.json").read_text())
+    sm = work["engine"]["state_manager"]
+    cfg = TransformerConfig(**json.loads(
+        (bench / "configs" / f"{work['config']}.json").read_text())["fields"])
+    rows, bs = sm["max_tracked_sequences"], sm["block_size"]
+    # a model that caches no position has a table one null entry wide
+    pages = -(-sm["max_seq_len"] // bs) if cfg.caches_positions else 1
+
+    def on_tpu(x, dtype=None):
+        return jax.ShapeDtypeStruct(x.shape, dtype or x.dtype,
+                                    sharding=tpu_sharding)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=tpu_sharding)
+
+    params = jax.tree.map(
+        lambda x: on_tpu(x, jnp.bfloat16),
+        jax.eval_shape(TransformerLM(cfg).init_params,
+                       jax.random.PRNGKey(0)))
+    cache = jax.tree.map(on_tpu, jax.eval_shape(
+        lambda: init_paged_kv_cache(cfg, sm["num_blocks"], bs, jnp.bfloat16,
+                                    state_slots=rows)))
+    leaves, launches = IN_PLACE_CELLS[cell]
+    assert all(cache[k].shape[1] == rows + 1 for k in leaves)
+    compiled = jax.jit(
+        lambda p, t, pos, bt, c, sl, eos, alive, ss: paged_decode_window(
+            cfg, p, t, pos, bt, c, sl, eos, bs, 8, use_kernel=True,
+            alive=alive, state_slots=ss), donate_argnums=(4,)).lower(
+        params, i32(rows), i32(rows), i32(rows, pages), cache, i32(rows),
+        i32(rows),
+        jax.ShapeDtypeStruct((rows,), jnp.bool_, sharding=tpu_sharding),
+        i32(rows)).compile()
+    kernels = _custom_calls(compiled)
+    for name, count in launches.items():
+        assert sum(k.startswith(name) for k in kernels) == count, kernels
+    _leaves_stay_in_hbm(compiled.as_text(), cache, *leaves)
 
 
 # ---------------------------------------------------------------------------
