@@ -449,22 +449,20 @@ def _held_from(cfg):
         if cfg.experts_held < cfg.moe_num_experts else None
 
 
-def _moe_routed(cfg, lp, xt, experts=None, stack_layer=None,
-                router_precision=None):
-    """The ep = 1 expert layer on flat tokens ``xt`` [T, H]: (what the
-    routed and the shared experts add, in the experts' type; the chosen
-    experts [T, k]). ``experts`` with ``stack_layer``: the scanned
-    stack's expert weights whole and this layer's index in it
-    (``sharded_moe.dropless_topk_dispatch``); else ``lp``'s own.
+def _moe_route(cfg, lp, xt, router_precision=None):
+    """The ROUTING half of the ep = 1 expert layer on flat tokens ``xt``
+    [T, H], what the router reads: (the chosen experts [T, k], their
+    weights [T, k]). ``xt`` is the experts' own normed input, or, under
+    ``cfg.moe_router_ahead``, the MIXER's (``_pattern_step`` norms the
+    stream once and hands it to the router and the mixer both).
     ``xt`` may be float32 beside bf16 experts: the router reads it as it
-    is, the experts its rounding. ``router_precision`` is the router
-    matmul's: the latent block's published router is float32 (``s =
-    sigmoid(x Wr)`` in float32) and on a TPU a float32 matmul runs in
-    bf16 passes unless asked otherwise, so ``_pattern_step`` asks for
-    ``HIGHEST``; the per-head path's softmax router keeps the backend's
-    default (None), which is what its programs compiled to before."""
-    from ...moe.sharded_moe import (dropless_topk_dispatch, expert_forms,
-                                    gmm_serves, topk_routing)
+    is. ``router_precision`` is the router matmul's: the latent block's
+    published router is float32 (``s = sigmoid(x Wr)`` in float32) and
+    on a TPU a float32 matmul runs in bf16 passes unless asked
+    otherwise, so ``_pattern_step`` asks for ``HIGHEST``; the per-head
+    path's softmax router keeps the backend's default (None), which is
+    what its programs compiled to before."""
+    from ...moe.sharded_moe import topk_routing
 
     with jax.named_scope("moe_router"):
         gate_w = lp["moe_gate_w"]
@@ -476,11 +474,24 @@ def _moe_routed(cfg, lp, xt, experts=None, stack_layer=None,
         limit = dict(n_group=cfg.moe_n_group,
                      topk_group=cfg.moe_topk_group) \
             if cfg.moe_n_group > 1 else {}
-        topi, topv = topk_routing(
+        return topk_routing(
             logits, cfg.moe_top_k, cfg.moe_scoring,
             lp["moe_gate_bias"] if cfg.moe_selection_bias else None,
             cfg.moe_norm_topk, cfg.moe_routed_scale, **limit)
-    xt = xt.astype(gate_w.dtype)
+
+
+def _moe_experts(cfg, lp, xt, topi, topv, experts=None, stack_layer=None):
+    """The EXPERTS half: what the routed and the shared experts add for
+    flat tokens ``xt`` [T, H] routed as ``topi`` / ``topv``
+    (:func:`_moe_route`, of this tensor or of another), in the experts'
+    type (they read ``xt``'s rounding). ``experts`` with
+    ``stack_layer``: the scanned stack's expert weights whole and this
+    layer's index in it (``sharded_moe.dropless_topk_dispatch``); else
+    ``lp``'s own."""
+    from ...moe.sharded_moe import (dropless_topk_dispatch, expert_forms,
+                                    gmm_serves)
+
+    xt = xt.astype(lp["moe_gate_w"].dtype)
     with jax.named_scope("moe_experts"):
         if experts is None:
             experts = tuple(lp[k] for k in cfg.expert_keys)
@@ -517,7 +528,17 @@ def _moe_routed(cfg, lp, xt, experts=None, stack_layer=None,
             # e_up -> shared_up
             out = out + shared_expert(xt, *(
                 lp[k.replace("e_", "shared_", 1)] for k in cfg.expert_keys))
-    return out, topi
+    return out
+
+
+def _moe_routed(cfg, lp, xt, experts=None, stack_layer=None,
+                router_precision=None):
+    """The expert layer whose router reads the experts' own input: the
+    two halves on one tensor, (what the experts add, the chosen experts
+    [T, k])."""
+    topi, topv = _moe_route(cfg, lp, xt, router_precision)
+    return _moe_experts(cfg, lp, xt, topi, topv, experts,
+                        stack_layer), topi
 
 
 def _deq_nonlayer(params):
@@ -783,7 +804,7 @@ def _latent_attention_sublayer(cfg, lp, x, l, pool, cos, sin, row_ids,
 def _per_head_attention_sublayer(cfg, lp, x, kind, l, pool, cos, sin,
                                  row_ids, lengths, write_blocks,
                                  write_offsets, block_tables, use_kernel,
-                                 one_token=False):
+                                 one_token=False, hn=None):
     """A per-head (GQA) mixer of a layer pattern on flat tokens x
     [T, H]: ``kind`` "full" (a token sees every position under its
     bound) or "window" (its last ``cfg.attn_window``), ``l`` the layer's
@@ -800,15 +821,19 @@ def _per_head_attention_sublayer(cfg, lp, x, kind, l, pool, cos, sin,
     An int8 pool is dequantised a layer at a time into a transient pool
     of one layer (1 / L of the leaf at twice its bytes), as the latent
     pool's is (``_latent_rows``). ``one_token``: every row has exactly
-    one token (a decode batch), which the kernel is told. Returns
-    (what attention adds to x, pool)."""
+    one token (a decode batch), which the kernel is told. ``hn``:
+    ``norm(x, attn_norm)`` where the caller has made it already (a
+    router ahead of the mixer reads it too). Returns (what attention
+    adds to x, pool)."""
     from ...ops.norms import rms_norm
     from .kernels.ragged_attention import (ragged_attention,
                                            ragged_attention_reference)
     T = x.shape[0]
     nh, nkv, hd = cfg.num_heads, cfg.kv_heads, cfg.head_dim
     dt = lp["wq"].dtype
-    hn = _norm(cfg, x, lp["attn_norm"]).astype(dt)
+    if hn is None:
+        hn = _norm(cfg, x, lp["attn_norm"])
+    hn = hn.astype(dt)
     with jax.named_scope("qkv_proj"):
         if cfg.attn_scale:
             # the kernels score q k^T over sqrt(hd): a model that
@@ -1331,6 +1356,15 @@ def _pattern_step(cfg: TransformerConfig, params, ids, row_ids, pos, lengths,
                       **jax.tree.map(lambda a: a[f0 + i], scanned)}
             else:
                 lp, i = inputs
+            picks = mixer_in = None
+            if routed and cfg.moe_router_ahead:
+                # the router reads the MIXER's normed input, ahead of
+                # it: ONE norm, the mixer's own (scope ``attention``),
+                # handed to both
+                with jax.named_scope("attention"):
+                    mixer_in = _norm(cfg, x, lp["attn_norm"])
+                picks = _moe_route(cfg, lp, mixer_in,
+                                   jax.lax.Precision.HIGHEST)
             if kind == "kda":
                 with jax.named_scope("linear_attention"):
                     a, pool = _linear_attention_sublayer(
@@ -1357,7 +1391,7 @@ def _pattern_step(cfg: TransformerConfig, params, ids, row_ids, pos, lengths,
                         lengths, window_writes if ring else write_blocks,
                         write_offsets,
                         window_tables if ring else block_tables, use_kernel,
-                        one_token)
+                        one_token, mixer_in)
                     x = joined(x, a)
             else:
                 with jax.named_scope("mla_attention"):
@@ -1371,9 +1405,12 @@ def _pattern_step(cfg: TransformerConfig, params, ids, row_ids, pos, lengths,
             with jax.named_scope("mlp"):
                 hn = _norm(cfg, x, lp["mlp_norm"])
                 if routed:
-                    out, topi = _moe_routed(
-                        cfg, lp, hn, experts, f0 + i,
-                        router_precision=jax.lax.Precision.HIGHEST)
+                    at = f0 + i         # the layer's place in ``experts``
+                    if picks is None:   # the router behind the mixer
+                        picks = _moe_route(cfg, lp, hn,
+                                           jax.lax.Precision.HIGHEST)
+                    topi, topv = picks
+                    out = _moe_experts(cfg, lp, hn, topi, topv, experts, at)
                     with jax.named_scope("moe_router"):
                         stats = _merge_moe_stats(stats, _moe_stats(
                             topi, valid, cfg.experts_held,

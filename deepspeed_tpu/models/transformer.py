@@ -258,8 +258,18 @@ class TransformerConfig:
     # matrices, ``down(silu(gate x) * (up x))``; "relu2", two and a
     # squared ReLU, ``down(relu(up x)^2)`` (the nemotron_h block; no
     # ``e_gate`` / ``shared_gate`` leaf; ``e_up`` [.., F, H], out x in,
-    # the model's width last as ``e_down``'s), routed and shared alike
+    # the model's width last as ``e_down``'s), routed and shared alike;
+    # "reglu", SwiGLU's three matrices and leaves with a ReLU gate,
+    # ``down(relu(gate x) * (up x))`` (the SmallThinker block)
     moe_expert_form: str = "swiglu"
+    # where the router reads (served only, a ``layer_types`` pattern of
+    # window and full per-head layers): False, the experts' own normed
+    # input, behind the mixer; True, the MIXER's normed input
+    # (``attn_norm``), ahead of attention, so that a layer's experts are
+    # known before its attention runs (the SmallThinker block); the
+    # experts still run on the stream behind the mixer, under
+    # ``mlp_norm``
+    moe_router_ahead: bool = False
 
     # training objective: "causal_lm" (next-token, causal attention) or
     # "mlm" (BERT-family masked-LM: bidirectional attention, loss at the
@@ -449,14 +459,22 @@ class TransformerConfig:
                 "leading dense layers (moe_first_dense_layers) are served "
                 "for an MoE model with attention='mla' or a layer_types "
                 "pattern only")
-        if self.moe_expert_form not in ("swiglu", "relu2") or (
-                self.moe_expert_form == "relu2"
+        if self.moe_expert_form not in ("swiglu", "relu2", "reglu") or (
+                self.moe_expert_form != "swiglu"
                 and (self.layer_types is None or self.moe_num_experts < 1
                      or self.moe_use_residual)):
             raise NotImplementedError(
                 f"moe_expert_form is 'swiglu' or, for the routed and "
-                f"shared experts of a layer_types pattern, 'relu2'; got "
-                f"{self.moe_expert_form!r}")
+                f"shared experts of a layer_types pattern, 'relu2' or "
+                f"'reglu'; got {self.moe_expert_form!r}")
+        if self.moe_router_ahead and (
+                self.layer_types is None or self.moe_num_experts < 1
+                or set(self.layer_kinds) - {"window", "full"}):
+            raise NotImplementedError(
+                "moe_router_ahead (the router reads the mixer's normed "
+                "input, ahead of attention) is served for the routed "
+                "experts of a layer_types pattern whose every mixer is "
+                "per-head attention (sliding_attention / full_attention)")
         if self.moe_noisy_gate_policy is not None:
             # RSample needs an rng threaded through the scanned layer body,
             # which neither the GSPMD nor the manual-pipeline MoE branch
@@ -501,7 +519,11 @@ class TransformerConfig:
             ("mamba_n_groups (B and C a group of heads)",
              "ssm" in self.layer_kinds and self.mamba_n_groups > 1),
             ("moe_expert_form='relu2' (two-matrix experts)",
-             self.moe_expert_form != "swiglu"),
+             self.moe_expert_form == "relu2"),
+            ("moe_expert_form='reglu' (a ReLU gate on the three-matrix "
+             "experts)", self.moe_expert_form == "reglu"),
+            ("moe_router_ahead (the router reads the mixer's normed "
+             "input)", self.moe_router_ahead),
             ("positional='none'", self.positional == "none"),
             ("attn_scale", self.attn_scale != 0.0),
             ("residual_scale", self.residual_scale != 1.0),
@@ -547,8 +569,8 @@ class TransformerConfig:
     def expert_keys(self) -> tuple:
         """The leaves of a routed expert, in the order the grouped
         matmuls take them."""
-        return ("e_gate", "e_up", "e_down") \
-            if self.moe_expert_form == "swiglu" else ("e_up", "e_down")
+        return ("e_up", "e_down") if self.moe_expert_form == "relu2" \
+            else ("e_gate", "e_up", "e_down")
 
     @property
     def layer_kinds(self) -> tuple:
@@ -1006,7 +1028,7 @@ class TransformerLM:
             out["moe_gate_bias"] = jnp.zeros((L, cfg.moe_num_experts), dt)
         if fs:
             ks = jax.random.split(key, 3)
-            if cfg.moe_expert_form == "swiglu":
+            if cfg.moe_expert_form != "relu2":
                 out["shared_gate"] = init(ks[0], (L, h, fs))
             out["shared_up"] = init(ks[1], (L, h, fs))
             out["shared_down"] = init(ks[2], (L, fs, h), out_std)
@@ -1159,11 +1181,11 @@ class TransformerLM:
             held = cfg.experts_held
             return {"moe_gate_w": init(ks[0], (n, h, E)),
                     **({"e_gate": init(ks[1], (n, held, h, f))}
-                       if cfg.moe_expert_form == "swiglu" else {}),
+                       if cfg.moe_expert_form != "relu2" else {}),
                     # a relu2 expert's ``up`` keeps the model's width last,
                     # out x in (``sharded_moe.ragged_relu2_experts``)
                     "e_up": init(ks[2], (n, held, h, f)
-                                 if cfg.moe_expert_form == "swiglu"
+                                 if cfg.moe_expert_form != "relu2"
                                  else (n, held, f, h)),
                     "e_down": init(ks[3], (n, held, f, h), out_std),
                     **self._init_deployed_router(ks[4], n, init, out_std)}

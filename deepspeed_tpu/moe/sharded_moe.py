@@ -14,6 +14,7 @@ reference's drop_tokens=True mode — which is also the only mode that maps
 well onto XLA; dropless variants need ragged kernels (future ragged_dot path).
 """
 
+import functools
 import math
 from typing import Optional, Tuple
 
@@ -205,18 +206,21 @@ def moe_layer_manual(x, gate_w, expert_params_local, expert_fn,
     return out.reshape(B, S, H), aux.astype(jnp.float32)
 
 
-def ragged_swiglu_experts(expert_params, xs, group_sizes):
+def ragged_swiglu_experts(expert_params, xs, group_sizes, gate=jax.nn.silu):
     """SwiGLU expert stack as grouped GEMMs over token groups.
 
     The TPU-native equivalent of the reference's CUTLASS MoE grouped GEMM
     (inference/v2/kernels/cutlass_ops/moe_gemm): `jax.lax.ragged_dot` tiles
     the per-expert segments onto the MXU without materializing the [E, C, H]
     capacity tensor. xs: [T, H] tokens SORTED by expert; group_sizes: [E].
+    ``gate``: the gate's function, ``down(gate(gate_w x) * (up x))``: SiLU,
+    or ReLU for the "reglu" form (:func:`expert_forms`); the three-matrix
+    expert is one body whatever gates it.
     """
     wg, wu, wd = expert_params                                 # [E, H, F] ...
     g = jax.lax.ragged_dot(xs, wg, group_sizes)
     u = jax.lax.ragged_dot(xs, wu, group_sizes)
-    return jax.lax.ragged_dot(jax.nn.silu(g) * u, wd, group_sizes)
+    return jax.lax.ragged_dot(gate(g) * u, wd, group_sizes)
 
 
 def ragged_relu2_experts(expert_params, xs, group_sizes):
@@ -268,10 +272,11 @@ def _whole_row_tiles(xs):
     return (jnp.pad(xs, ((0, pad), (0, 0))) if pad else xs), m
 
 
-def gmm_swiglu_experts(expert_params, xs, group_sizes):
-    """``ragged_swiglu_experts`` through the Pallas grouped matmul JAX
-    ships (``jax.experimental.pallas.ops.tpu.megablox``), a row tile of
-    128 against an expert's WHOLE [K, N] weight: a launch then streams
+def gmm_swiglu_experts(expert_params, xs, group_sizes, gate=jax.nn.silu):
+    """``ragged_swiglu_experts`` (its ``gate`` too) through the Pallas
+    grouped matmul JAX ships
+    (``jax.experimental.pallas.ops.tpu.megablox``), a row tile of 128
+    against an expert's WHOLE [K, N] weight: a launch then streams
     each touched expert's weights once and nothing else, where XLA's own
     ``ragged_dot`` kernel took 2.5 times as long at 256 experts of
     2048 x 768 and two rows an expert (9.03 ms against 3.66 a layer; at
@@ -287,7 +292,7 @@ def gmm_swiglu_experts(expert_params, xs, group_sizes):
         return gmm(x, w, group_sizes, preferred_element_type=x.dtype,
                    tiling=(_GMM_ROWS, w.shape[1], _gmm_columns(w)))
 
-    return mm(jax.nn.silu(mm(xs, wg)) * mm(xs, wu), wd)[:m]
+    return mm(gate(mm(xs, wg)) * mm(xs, wu), wd)[:m]
 
 
 def gmm_relu2_experts(expert_params, xs, group_sizes):
@@ -312,8 +317,8 @@ def gmm_relu2_experts(expert_params, xs, group_sizes):
                tiling=(_GMM_ROWS, wd.shape[1], _gmm_columns(wd)))[:m]
 
 
-def _swiglu_expert(x, wg, wu, wd):
-    return (jax.nn.silu(x @ wg) * (x @ wu)) @ wd
+def _swiglu_expert(x, wg, wu, wd, gate=jax.nn.silu):
+    return (gate(x @ wg) * (x @ wu)) @ wd
 
 
 def _relu2_expert(x, wu, wd):
@@ -326,9 +331,13 @@ def _relu2_expert(x, wu, wd):
 def expert_forms(form):
     """An expert's form (``TransformerConfig.moe_expert_form``) as (the
     routed experts over sorted rows by ``ragged_dot``, the same by the
-    Pallas grouped matmul, ONE always-on expert on plain rows)."""
-    return {"swiglu": (ragged_swiglu_experts, gmm_swiglu_experts,
-                       _swiglu_expert),
+    Pallas grouped matmul, ONE always-on expert on plain rows). "reglu"
+    is the three-matrix form's one body with a ReLU for its gate."""
+    gated = (ragged_swiglu_experts, gmm_swiglu_experts, _swiglu_expert)
+    if form == "reglu":
+        return tuple(functools.partial(fn, gate=jax.nn.relu)
+                     for fn in gated)
+    return {"swiglu": gated,
             "relu2": (ragged_relu2_experts, gmm_relu2_experts,
                       _relu2_expert)}[form]
 

@@ -3,8 +3,9 @@
     chiprun -- python scripts/bench_kernels.py [--only NAME] [--sweep]
         [--check] [--launches 200]
 
-The decode launches of the five generation cells (``ragged_attention_tiled``
-/ ``_window`` / ``_latent`` in their one-token form, at each cell's rows,
+The decode launches of the six generation cells that cache positions
+(``ragged_attention_tiled`` / ``_window`` / ``_latent`` in their one-token
+form, at each cell's rows,
 pool, table and contexts), alone, over a random pool whose pages are out of
 order: a ``fori_loop`` of ``--launches`` launches in one jitted program, the
 next launch's queries made from the last one's output, timed on the host
@@ -103,6 +104,15 @@ SHAPES = {
                          nh=32, ctx=(8192, 8704), window=0, ring=0),
     "trinity-window": dict(kernel="tiled", rows=16, layers=4, kvh=4, hd=128,
                            nh=32, ctx=(8192, 8704), window=2048, ring=193),
+    # a query group of SEVEN (28 on 4), beside trinity's eight: the full
+    # layers' launch at the same contexts, the window layers' at 4,096
+    # over rings of 321 pages
+    "smallthinker-full": dict(kernel="tiled", rows=16, layers=2, kvh=4,
+                              hd=128, nh=28, ctx=(8192, 8704), window=0,
+                              ring=0),
+    "smallthinker-window": dict(kernel="tiled", rows=16, layers=6, kvh=4,
+                                hd=128, nh=28, ctx=(8192, 8704), window=4096,
+                                ring=321),
     "granite-full": dict(kernel="tiled", rows=64, layers=1, kvh=8, hd=128,
                          nh=32, ctx=(1024, 1280), window=0, ring=0),
     # the one-token state-space update: heads of ``p`` channels, ``groups``

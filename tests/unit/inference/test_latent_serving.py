@@ -266,7 +266,7 @@ def _fault(name):
     from deepspeed_tpu.inference.v2 import paged_model
     from deepspeed_tpu.moe import sharded_moe
     real_routing = sharded_moe.topk_routing
-    real_routed = paged_model._moe_routed
+    real_experts = paged_model._moe_experts
 
     def bf16_router(logits, *a, **kw):
         return real_routing(logits.astype(jnp.bfloat16)
@@ -278,13 +278,13 @@ def _fault(name):
         return topi, topv / jnp.sum(topv, -1, keepdims=True) * scale
 
     def no_shared(cfg, lp, xt, *a, **kw):
-        return real_routed(dataclasses.replace(cfg, moe_shared_experts=0),
-                           lp, xt, *a, **kw)
+        return real_experts(dataclasses.replace(cfg, moe_shared_experts=0),
+                            lp, xt, *a, **kw)
 
     patch = {"bf16_router": (sharded_moe, "topk_routing", bf16_router),
              "bias_in_weights": (sharded_moe, "topk_routing",
                                  bias_in_weights),
-             "no_shared_expert": (paged_model, "_moe_routed",
+             "no_shared_expert": (paged_model, "_moe_experts",
                                   no_shared)}[name]
     return patch
 

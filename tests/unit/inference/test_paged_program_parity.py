@@ -190,3 +190,90 @@ def test_the_window_hands_on_its_rows_state(ran):
     assert (nxt[0] == -1).all() and (nxt[1, :2] >= 0).all()
     assert got["window_next_alive"] == [0.0, 1.0]
     assert got["window_next_pos"] == [15 + fed, 5 + 4]
+
+
+# ---------------------------------------------------------------------------
+# PR 58 split the expert layer into a routing half and an experts half
+# (``paged_model._moe_route`` / ``_moe_experts``), so that a router can
+# read another tensor than the experts run on. Every sparse configuration
+# of the benchmark goes through the split, and for the five whose router
+# stays BEHIND the mixer it stands where it stood: every ``moe_router``
+# equation of their two programs at toy widths (``configs/<name>.json``'s
+# ``fields`` under its ``toy_fields``) lies under ``mlp``, one router
+# matmul a run of expert layers, none ahead of a mixer. (That the split
+# moved nothing at all in them was read once, as equal jaxpr hashes on
+# the parent and the change: PERF.md section 6, PR 58.)
+# ---------------------------------------------------------------------------
+ROUTER_BEHIND = ["joyai-llm-flash", "ling-3.0-flash", "trinity-mini",
+                 "granite-4.0-h-small", "nemotron-3-nano-30b-a3b"]
+MIXERS = ("attention", "mla_attention", "linear_attention", "ssm_mixer")
+
+
+def walk_jaxprs(name):
+    """(the decode window's, the ragged step's) jaxpr of configuration
+    ``name`` at toy widths."""
+    from benchmark import run as harness
+    file = json.loads((Path(__file__).resolve().parents[3] / "benchmark"
+                       / "configs" / f"{name}.json").read_text())
+    cfg = TransformerConfig(**harness.merge(file["fields"],
+                                            file["toy_fields"]))
+    params = jax.eval_shape(TransformerLM(cfg).init_params,
+                            jax.random.PRNGKey(0))
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
+    kw, more = {}, {}
+    if cfg.has_state:
+        kw["state_slots"], more["state_slots"] = 3, i32(2)
+    if "window" in cfg.layer_kinds:
+        kw["window_blocks"], more["window_tables"] = 9, i32(2, 4)
+    cache = jax.eval_shape(lambda: pm.init_paged_kv_cache(
+        cfg, 9, 16, jnp.float32, **kw))
+    window = jax.make_jaxpr(
+        lambda p, t, pos, bt, c, sl, eos, *a: pm.paged_decode_window(
+            cfg, p, t, pos, bt, c, sl, eos, 16, 4, **dict(zip(more, a))))(
+        params, i32(2), i32(2), i32(2, 4), cache, i32(2), i32(2),
+        *more.values())
+    step = jax.make_jaxpr(
+        lambda p, ids, rows, pos, ln, wb, wo, bt, li, c, *a:
+        pm.paged_ragged_step(cfg, p, ids, rows, pos, ln, wb, wo, bt, li, c,
+                             16, **dict(zip(more, a))))(
+        params, i32(16), i32(16), i32(16), i32(16), i32(16), i32(16),
+        i32(2, 4), i32(2), cache, *more.values())
+    return window, step
+
+
+def layer_bodies(jaxpr):
+    """[(scope, primitive)] of every ``layers`` scan's body, in order."""
+    found = []
+
+    def walk(jp):
+        for eqn in jp.eqns:
+            if eqn.primitive.name == "scan" and str(
+                    eqn.source_info.name_stack).endswith("layers"):
+                found.append([
+                    (str(e.source_info.name_stack), e.primitive.name)
+                    for e in eqn.params["jaxpr"].jaxpr.eqns])
+            else:
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    walk(sub)
+    walk(jaxpr.jaxpr)
+    return found
+
+
+@pytest.mark.parametrize("name", ROUTER_BEHIND)
+def test_the_sparse_configurations_router_stays_behind_the_mixer(name):
+    for program in walk_jaxprs(name):
+        routed = 0
+        for body in layer_bodies(program):
+            router = [(i, s, p) for i, (s, p) in enumerate(body)
+                      if "moe_router" in s.split("/")]
+            if not router:
+                continue            # a run of layers with no expert
+            routed += 1
+            for _, s, _ in router:
+                scopes = s.split("/")
+                assert "mlp" in scopes[:scopes.index("moe_router")], s
+            assert sum(p == "dot_general" for _, _, p in router) == 1
+            mixer = [i for i, (s, _) in enumerate(body)
+                     if set(s.split("/")) & set(MIXERS)]
+            assert not mixer or mixer[-1] < router[0][0]
+        assert routed >= 1

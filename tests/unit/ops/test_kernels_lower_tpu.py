@@ -1936,3 +1936,157 @@ def test_zero3_backward_gathers_the_weight_not_the_batch(zero3_backward,
         assert sum(kind == "all-to-all" for kind, _ in moved) == 2, moved
         assert _leads_with_the_global_batch(moved), moved
         assert w_down not in moved, moved
+
+
+# ---------------------------------------------------------------------------
+# PR 58: a query group of SEVEN (28 heads on 4 of 128), a window of 4,096
+# over rings of 321 pages, the router ahead of the mixer:
+# smallthinker-21ba3b-instruct.rollout-16x8192-512's geometry
+# ---------------------------------------------------------------------------
+# launch -> (tokens, the pool as stored, the table's pages, window, one
+# token a row, the launch's name)
+GROUP7_LAUNCHES = {
+    "prefill.full": (16384, (2, 8721, 16, 512), 544, 0, False,
+                     "ragged_attention_tiled"),
+    "prefill.window": (16384, (6, 5137, 16, 512), 321, 4096, False,
+                       "ragged_attention_window"),
+    "decode.full": (16, (2, 8721, 16, 512), 544, 0, True,
+                    "ragged_attention_tiled"),
+    "decode.window": (16, (6, 5137, 16, 512), 321, 4096, True,
+                      "ragged_attention_window"),
+}
+
+
+@pytest.mark.parametrize("launch", sorted(GROUP7_LAUNCHES))
+def test_the_tiled_kernel_at_group_seven(tpu_sharding, launch):
+    """Every per-head geometry served before is a power of two; here a
+    kv head's SEVEN query heads. Mosaic takes the token tile (an output
+    block ``(tq, nblk, 7, 128)``) and the one-token form (a lane block's
+    seven query rows padded to one sublane tile of eight: the queries
+    ``[16, 4, 8, 128]``, the output ``[16, 4, 7, 128]``), full and over
+    a ring with the window, under the names the roofline's reader finds,
+    with nothing as large as a layer of the pool beside the call. The
+    kernel needed no change for it."""
+    T, pool, MB, window, one_token, name = GROUP7_LAUNCHES[launch]
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=tpu_sharding)
+
+    assert kernel_variant(128, 4, False) == "tiled"
+    args = [sds((T, 28, 128), jnp.bfloat16), sds(pool, jnp.bfloat16),
+            sds(pool, jnp.bfloat16), sds((), jnp.int32),
+            sds((T,), jnp.int32), sds((T,), jnp.int32),
+            sds((16, MB), jnp.int32)]
+    compiled = jax.jit(lambda *a: ragged_attention(
+        *a, window=window, one_token=one_token)).lower(*args).compile()
+    calls = [line for line in compiled.as_text().splitlines()
+             if "tpu_custom_call" in line]
+    assert len(calls) == 1, calls
+    assert re.match(r"\s*%" + name + r"[_.0-9]* = ", calls[0]), calls[0][:200]
+    assert WINDOW_PATTERN.search(
+        re.match(r"\s*%([\w.\-]+) = ", calls[0]).group(1))
+    if one_token:
+        assert "= bf16[16,4,7,128]" in calls[0] \
+            and "bf16[16,4,8,128]" in calls[0], calls[0][:600]
+    layer = 2 * pool[1] * pool[2] * pool[3]
+    assert compiled.memory_analysis().temp_size_in_bytes < layer / 4
+
+
+@pytest.fixture(scope="module")
+def group7_programs(tpu_sharding):
+    """The cell's two programs at published widths, cut to two layers
+    (published layers 3-4: sliding, FULL) with 8 experts and 4,096 rows
+    of the vocabulary, over the cell's pools (16 rows' blocks and rings
+    of 321 pages), compiled under the chip's flags."""
+    import json
+    from pathlib import Path
+    from deepspeed_tpu.accelerator.tpu_accelerator import \
+        COLLECTIVE_OVERLAP_COMPILER_OPTIONS
+    from deepspeed_tpu.inference.v2.paged_model import (
+        init_paged_kv_cache, paged_decode_window, paged_ragged_step)
+    from deepspeed_tpu.models import TransformerLM
+    from deepspeed_tpu.models.transformer import TransformerConfig
+
+    fields = json.loads((
+        Path(__file__).resolve().parents[3] / "benchmark/configs"
+        / "smallthinker-21ba3b-instruct.json").read_text())["fields"]
+    cfg = TransformerConfig(**{
+        **fields, "num_layers": 2, "layer_types": fields["layer_types"][2:4],
+        "moe_num_experts": 8, "vocab_size": 4096})
+    assert cfg.layer_kinds == ("window", "full") and cfg.moe_router_ahead
+
+    def on_tpu(x, dtype=None):
+        return jax.ShapeDtypeStruct(x.shape, dtype or x.dtype,
+                                    sharding=tpu_sharding)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=tpu_sharding)
+
+    params = jax.tree.map(
+        lambda x: on_tpu(x, jnp.bfloat16),
+        jax.eval_shape(TransformerLM(cfg).init_params,
+                       jax.random.PRNGKey(0)))
+    cache = jax.tree.map(on_tpu, jax.eval_shape(
+        lambda: init_paged_kv_cache(cfg, 16 * 545 + 1, 16, jnp.bfloat16,
+                                    window_blocks=16 * 321 + 1)))
+    assert cache["k_full"].shape == (1, 8721, 16, 512) \
+        and cache["k_window"].shape == (1, 5137, 16, 512)
+    R, T = 16, 16384
+    programs = {
+        "decode_window": (jax.jit(
+            lambda p, t, pos, bt, c, sl, eos, alive, wt: paged_decode_window(
+                cfg, p, t, pos, bt, c, sl, eos, 16, 8, use_kernel=True,
+                alive=alive, window_tables=wt), donate_argnums=(4,)), (
+            params, i32(R), i32(R), i32(R, 544), cache, i32(R), i32(R),
+            jax.ShapeDtypeStruct((R,), jnp.bool_, sharding=tpu_sharding),
+            i32(R, 321))),
+        "ragged_step": (jax.jit(
+            lambda p, ids, rows, pos, ln, wb, wo, bt, li, c, wt:
+            paged_ragged_step(cfg, p, ids, rows, pos, ln, wb, wo, bt, li, c,
+                              16, use_kernel=True, window_tables=wt),
+            donate_argnums=(9,)), (
+            params, i32(T), i32(T), i32(T), i32(T), i32(T), i32(T),
+            i32(R, 544), i32(R), cache, i32(R, 321)))}
+    pool = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(cache))
+    # ``_trace_for_tpu`` answers for the target a test at a time; a
+    # module's fixture is set up outside it
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(jax, "default_backend", lambda: "tpu")
+        return pool, {
+            name: fn.lower(*args).compile(
+                compiler_options=dict(COLLECTIVE_OVERLAP_COMPILER_OPTIONS))
+            for name, (fn, args) in programs.items()}
+
+
+@pytest.mark.parametrize("program", ["decode_window", "ragged_step"])
+def test_the_router_ahead_programs_compile_with_both_pools_in_place(
+        group7_programs, program):
+    """The window kernel runs in the sliding layer, the full kernel in
+    the full one, the grouped matmul three times a layer (the ReGLU
+    experts are the accepted ``gmm``), the donated pools are aliased to
+    the result, no instruction copies or relays a pool or a layer of
+    one, and the program's temporaries hold no copy of either (the
+    decode window's are under 0.1 GB; the 16,384-token step's 1.36 GB
+    here are its rows' float32 stream and six sorted picks a token, what
+    the whole cell's are: 1.33 GB by the same analysis at eight layers,
+    64 experts and the whole vocabulary, beside 9.52 GB of arguments:
+    PERF.md section 4). The router's operations stand under
+    ``moe_router`` and under no ``mlp``, where the router behind
+    stands."""
+    pool, compiled = group7_programs
+    compiled = compiled[program]
+    text = compiled.as_text()
+    kernels = re.findall(r"%([\w.\-]+) = [^\n]*tpu_custom_call", text)
+    assert sum(k.startswith("ragged_attention_window") for k in kernels) \
+        == 1, kernels
+    assert sum(k.startswith("ragged_attention_tiled") for k in kernels) \
+        == 1, kernels
+    assert sum(bool(GMM_PATTERN.search(k)) for k in kernels) == 6, kernels
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool, mem
+    assert mem.temp_size_in_bytes < (0.1e9 if program == "decode_window"
+                                     else 1.5e9), mem
+    _no_relayout_of(text, 8721, 5137)
+    routed = set(re.findall(r'op_name="[^"]*?((?:mlp/)?moe_router/'
+                            r'(?:dot_general|top_k))"', text))
+    assert routed and not any(r.startswith("mlp/") for r in routed), routed
