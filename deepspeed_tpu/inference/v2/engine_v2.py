@@ -43,7 +43,8 @@ from .kernels.linear_attention import (chunk_kernel_serves,
                                        conv_kernel_serves)
 from .kernels.ragged_attention import (LATENT, decode_positions,
                                        kernel_variant,
-                                       one_token_tile_serves)
+                                       one_token_tile_serves, prompt_chunks,
+                                       token_tile, token_tile_serves)
 from .paged_model import (STATE_LEAVES, init_lora_bank, init_paged_kv_cache,
                           paged_continue, paged_decode, paged_decode_window,
                           paged_ragged_step, paged_spec_decode_window)
@@ -749,6 +750,17 @@ class InferenceEngineV2:
             "row and step, from the contexts the manager holds at the "
             "launch; 0 wherever inference_attention_one_token_steps_total "
             "is", labelnames=("kind",))
+        self._m_prompt_chunks = reg.counter(
+            "inference_attention_prompt_chunks_total",
+            "chunk visits of the token tile's launches (a ragged step's "
+            "attention where the tiled kernel serves), by kind: \"whole\" "
+            "(a tile of one row's tokens that all see every position of "
+            "the chunk) and \"masked\" (an edge of a bound or a window, a "
+            "tile of several rows or of a row's tail). A visit a layer, "
+            "tile, row and chunk of 512 positions: what a launch's time "
+            "goes by, and what a larger tile halves; from the rows' "
+            "tokens and contexts at the launch, no device read; 0 off "
+            "the TPU and for a latent pool", labelnames=("kind",))
         self._m_prefill_chunks = reg.counter(
             "inference_prefill_chunks_total",
             "ragged steps put() ran for a prompt set it fed in chunks (a "
@@ -1698,7 +1710,7 @@ class InferenceEngineV2:
         """The positions under the one-token form's launches of these
         rows and steps, layer kind by layer kind
         (``kernels/ragged_attention.decode_positions``)."""
-        sm, cfg = self.state_manager, self.model.cfg
+        sm = self.state_manager
         start = np.asarray([sm.seqs[u].seen_tokens for u in uids], np.int64)
         if in_flight is not None:
             start = start + np.asarray(in_flight, np.int64)
@@ -1706,20 +1718,50 @@ class InferenceEngineV2:
         # a row's bound at a step: the token it feeds, itself included
         contexts = (start[:, None] + 1 + step)[
             step < np.asarray(steps_left)[:, None]]
+        held, chunked = self._over_attention_layers(
+            decode_positions, table_pages, contexts)
+        self._m_decode_positions.labels(kind="held").inc(held)
+        self._m_decode_positions.labels(kind="chunked").inc(chunked)
+
+    def _over_attention_layers(self, count, table_pages, *rows, **kw):
+        """``count(*rows, block size, a table's pages, its pool's
+        blocks, window=, **kw)`` -> a pair, summed over the layers that
+        cache positions: the full ones over a table of ``table_pages``
+        places of the pool, the window ones over their rings."""
+        sm, cfg = self.state_manager, self.model.cfg
         kinds = cfg.layer_kinds
         rings = kinds.count("window")
-        held, chunked = np.asarray(decode_positions(
-            contexts, sm.block_size, table_pages, sm.config.num_blocks)) \
+        a, b = np.asarray(count(
+            *rows, sm.block_size, table_pages, sm.config.num_blocks, **kw)) \
             * sum(k not in ("window", "kda", "ssm", "retention", "moe")
                   for k in kinds)
         if rings:
-            ring = decode_positions(
-                contexts, sm.block_size, sm.ring_blocks,
+            ring = count(
+                *rows, sm.block_size, sm.ring_blocks,
                 sm.config.max_tracked_sequences * sm.ring_blocks + 1,
-                window=cfg.attn_window)
-            held, chunked = held + rings * ring[0], chunked + rings * ring[1]
-        self._m_decode_positions.labels(kind="held").inc(int(held))
-        self._m_decode_positions.labels(kind="chunked").inc(int(chunked))
+                window=cfg.attn_window, **kw)
+            a, b = a + rings * ring[0], b + rings * ring[1]
+        return int(a), int(b)
+
+    def _note_prompt_chunks(self, entries, rb):
+        """The chunk visits of a ragged step's attention launches, from
+        the tokens its rows feed and the contexts they end at
+        (``kernels/ragged_attention.prompt_chunks``), where the token
+        tile serves them."""
+        cfg = self.model.cfg
+        if not (self._use_kernel and cfg.caches_positions
+                and cfg.attention != "mla"
+                and token_tile_serves(cfg.head_dim, cfg.kv_heads)):
+            return
+        seqs = self.state_manager.seqs
+        new = [len(toks) for _, toks in entries]
+        whole, masked = self._over_attention_layers(
+            prompt_chunks, rb.block_tables.shape[1], new,
+            [seqs[uid].seen_tokens + n for (uid, _), n in zip(entries, new)],
+            tq=token_tile(rb.token_bucket, cfg.num_heads, cfg.head_dim,
+                          cfg.kv_heads))
+        self._m_prompt_chunks.labels(kind="whole").inc(whole)
+        self._m_prompt_chunks.labels(kind="masked").inc(masked)
 
     # -- fused multi-token decode window --------------------------------
     def _launch_window(self, uids: List[int], tokens: Optional[List[int]],
@@ -2020,6 +2062,7 @@ class InferenceEngineV2:
             # the logits' arrival (the two spans' own durations)
             dt = packed["duration_s"] + step["duration_s"]
             self._note_moe("ragged_step", *moe)
+            self._note_prompt_chunks(entries, rb)
             if self._has_state:
                 self._m_state_rows.labels(program="ragged_step").inc(
                     len(entries))

@@ -42,7 +42,14 @@ test_kernels_lower_tpu.py`` pins against the Mosaic compiler):
 * ``"tiled"`` — the page walk goes per ROW. Grid ``(T / tq,)``: a step
   owns a tile of up to 128 flat tokens and walks the rows whose tokens
   lie in it (prefill: one row a tile; a mixed step: whatever rows the
-  tile's tokens belong to; decode: see the one-token form below). A
+  tile's tokens belong to; decode: see the one-token form below). THE
+  TILE (:func:`_token_tile`) is a power of two of 16 to 128 tokens, at
+  most 1,024 query rows a lane block (tokens x heads of the block x GQA
+  group), read off the launch's bucket and the pool's geometry alone:
+  every tile re-reads its row's context and pays for its own zeroed
+  state, first chunk and normalise, and every chunk visit for its 64
+  copy starts and the state's read and write, whatever the tile holds,
+  so half the tiles are half of all that for the same products. A
   row's pages arrive in chunks of ``_CHUNK_POSITIONS`` positions by
   ``make_async_copy`` out of its block table, double-buffered across
   chunks AND rows, up to the causal bound of the row's last token in the
@@ -57,6 +64,15 @@ test_kernels_lower_tpu.py`` pins against the Mosaic compiler):
   together on the pool's bf16 with float32 accumulation
   (:func:`_tile_update`); tokens of other rows and positions past a
   token's own bound are masked, which is all in-tile causality is.
+  THE UPDATE IS TRANSPOSED: a chunk's scores are ``k q^T``, a position a
+  sublane and a query row a LANE, and the float32 state lies the same
+  way (max and sum ``(1, rows)``, the accumulator ``v^T p``, turned back
+  once a tile), so that a row's max and sum run down the vector
+  registers elementwise; along the lanes each was a cross-lane
+  reduction a register of eight rows, a chunk and a lane block, and the
+  two were half of a prompt launch at the 8k-context cells (PERF.md
+  section 6, PR 59, which also measured that an update with no mask for
+  the chunks a tile sees whole gains nothing: every chunk is masked).
   Serves every geometry :func:`tiled_geometry` accepts.
   THE ONE-TOKEN FORM (``one_token=True``, static: the caller knows every
   row has exactly one token, as ``paged_attention`` and the pattern's
@@ -350,6 +366,83 @@ def decode_positions(contexts, bs: int, table_pages: int, pool_blocks: int,
     return (int(pages.sum()) * bs, int((-(-pages // cp)).sum()) * cp * bs)
 
 
+def _token_tile(tokens: int, rpb: int) -> int:
+    """Tokens a grid step of the token tile owns, from the launch's
+    token bucket and a lane block's query rows a token (``rpb`` = heads a
+    block x GQA group): a power of two of 16 to 128, at most 1,024 query
+    rows a lane block where that leaves 16, and no more than the launch
+    has. A tile pays its own fixed part (the state zeroed, a first chunk
+    nothing hides, the normalise) and a chunk visit its starts and its
+    state's read and write whatever the tile holds, and a row's keys are
+    read once a TILE: 1,024 rows halve all of it where 512 left the
+    matrix unit a quarter used (PERF.md section 6, PR 59)."""
+    return max(16, min(pow2_bucket(tokens, 128),
+                       1 << (max(1024 // rpb, 1).bit_length() - 1)))
+
+
+def token_tile(tokens: int, heads: int, head_dim: int, kv_heads: int) -> int:
+    """:func:`_token_tile` of a launch of ``tokens`` (its bucket) by a
+    model's widths, for a host that counts what the launch walks
+    (:func:`prompt_chunks`)."""
+    return _token_tile(tokens, tiled_geometry(head_dim, kv_heads)[1]
+                       * (heads // kv_heads))
+
+
+def token_tile_serves(head_dim: int, kv_heads: int) -> bool:
+    """Whether a ragged step's attention launches are the token tile's
+    here: on a TPU (off it the step runs the pipelined variant, a token
+    a grid step), over a pool whose geometry the tiled variant serves.
+    What the engine's ``inference_attention_prompt_chunks_total`` counts
+    by."""
+    return not _interpret() and tiled_geometry(head_dim, kv_heads) is not None
+
+
+def prompt_chunks(new, contexts, bs: int, table_pages: int, pool_blocks: int,
+                  window: int = 0, tq: int = 128):
+    """``(whole, masked)``: the chunk visits of ONE token-tile launch
+    whose rows feed ``new`` tokens each (packed in order) that end at
+    ``contexts`` (the last token's bound), in tiles of ``tq``, as
+    :func:`_walk_rows` cuts the chunks from a table of ``table_pages``
+    places over a pool of ``pool_blocks``: a visit is ``whole`` where
+    the tile holds ``tq`` tokens of one row that all see every position
+    of the chunk (its last under the lowest bound and, with a
+    ``window``, its first not under the highest bound's window),
+    ``masked`` otherwise (an edge of the bound or the window, a tile of
+    several rows or of a row's tail). The visits are what a launch's
+    time goes by (a chunk's copies started and its state read and
+    written whatever the tile holds) and what a larger tile halves; the
+    kernel masks every one: an update with no mask for the whole ones
+    read 0.5-6 % SLOWER on the chip, not faster (PERF.md section 6,
+    PR 59).
+    Host arithmetic on what the host knows (numpy in, ints out): the
+    engine's ``inference_attention_prompt_chunks_total``."""
+    new = np.asarray(new, np.int64)
+    ctx = np.asarray(contexts, np.int64)[new > 0]
+    new = new[new > 0]
+    if not new.size:
+        return 0, 0
+    P = _chunk_pages(table_pages, pool_blocks, bs) * bs
+    end = np.cumsum(new)                    # a row's tokens: [tok0, end)
+    tok0 = end - new
+    tiles = (end - 1) // tq - tok0 // tq + 1        # a row's tokens lie in
+    # one entry a (row, tile) pair: the row's tokens in the tile
+    row = np.repeat(np.arange(new.size), tiles)
+    tile = np.arange(row.size) - np.repeat(np.cumsum(tiles) - tiles, tiles) \
+        + (tok0 // tq)[row]
+    first = np.maximum(tile * tq, tok0[row])
+    last = np.minimum(tile * tq + tq - 1, end[row] - 1)
+    bound0 = ctx[row] - end[row] + 1         # a token's bound less its index
+    lo, hi = bound0 + first, bound0 + last
+    base = np.maximum(lo - window, 0) // bs * bs if window else 0
+    chunks = -(-(hi - base) // P)
+    # chunk c is whole for c in [c0, c1]: its end under the lowest bound,
+    # its start inside the highest bound's window
+    c1 = np.minimum((lo - base) // P, chunks) - 1
+    c0 = np.maximum(-(-(hi - window - base) // P), 0) if window else 0
+    whole = np.where(last - first + 1 == tq, np.maximum(c1 - c0 + 1, 0), 0)
+    return int(whole.sum()), int((chunks - whole).sum())
+
+
 def _visible(tl_ref, t0, first, last, c, tq, reps, P, base=0, window=0):
     """``(reps * tq, P)``: which of chunk c's positions each query row
     of the tile may attend. Query rows are ``reps`` copies of the tile's
@@ -377,6 +470,22 @@ def _row_visible(bound, c, rows, P, base=0, window=0):
     if not window:
         return pos < bound
     return (pos < bound) & (pos >= bound - window)
+
+
+def _tile_visible(tb_ref, t0, first, last, c, tq, P, base=0, window=0):
+    """:func:`_visible` as the token tile reads it, ``(P, Mp)``: chunk
+    c's positions down the sublanes, the tile's query rows along the
+    lanes (row ``m`` is token ``m % tq`` of the tile; ``tb_ref``, ``(1,
+    1, Mp)``, holds each row's causal bound, 0 for a row that pads the
+    lanes). A token outside [first, last] sees nothing."""
+    col = jax.lax.broadcasted_iota(jnp.int32, tb_ref.shape[1:], 1)
+    tok = t0 + (col & (tq - 1))
+    eff = jnp.where((tok >= first) & (tok <= last), tb_ref[0], 0)
+    pos = base + c * P + jax.lax.broadcasted_iota(
+        jnp.int32, (P, eff.shape[1]), 0)
+    if not window:
+        return pos < eff
+    return (pos < eff) & (pos >= eff - window)
 
 
 def _when(cond, fn):
@@ -548,31 +657,37 @@ def _row_hooks(one_token, finish, acc_sc, m_sc, l_sc):
 
 
 def _tile_update(q, k, v, visible, acc_sc, m_sc, l_sc, b, *, scale):
-    """One lane block's online-softmax update over one KV chunk: q
-    ``(M, bw)`` (the rows of the block's query heads, each zero outside
-    its own kv head's lanes), k/v ``(P, bw)`` as stored (bf16 on the
-    chip: the products are exact in the float32 accumulator), visible
-    ``(M, P)`` which positions each row may attend (none for a token of
-    another row).
+    """One lane block's online-softmax update over one KV chunk, the
+    token tile's: q ``(Mp, bw)`` (the rows of the block's query heads,
+    each zero outside its own kv head's lanes), k/v ``(P, bw)`` as
+    stored (bf16 on the chip: the products are exact in the float32
+    accumulator), visible ``(P, Mp)`` which positions each query row may
+    attend (none for a token of another row).
+    TRANSPOSED: the scores are ``k q^T``, ``(P, Mp)``, a query row a
+    LANE and a position a sublane, so that a row's max and sum run DOWN
+    the vector registers, elementwise, and its statistics ``(1, Mp)``
+    spread over the scores by a sublane broadcast; along the lanes each
+    was a cross-lane reduction a register of eight rows, and the two
+    were half a prompt launch's time (PERF.md section 6, PR 59). The
+    state is transposed with them: max and sum ``(1, Mp)``, the
+    accumulator ``v^T p``, ``(bw, Mp)``.
     Max, sum and accumulator stay float32; p is rounded to the pool's
     dtype for the second product, as ops/flash_attention.py does. A
     masked score is 2 * NEG_INF under a running max that starts at
     NEG_INF, so a row with nothing to attend yet adds exp(NEG_INF) = 0
     and keeps its state."""
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+    s = jax.lax.dot_general(k, q, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
     s = jnp.where(visible, s, 2 * NEG_INF)
-    m_prev = m_sc[b, :, :1]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    m_prev = m_sc[b]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
     p = jnp.exp(s - m_new)
     corr = jnp.exp(m_prev - m_new)
-    l_sc[b] = jnp.broadcast_to(
-        l_sc[b, :, :1] * corr + jnp.sum(p, axis=1, keepdims=True),
-        l_sc.shape[1:])
+    l_sc[b] = l_sc[b] * corr + jnp.sum(p, axis=0, keepdims=True)
     acc_sc[b] = acc_sc[b] * corr + jax.lax.dot_general(
-        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        v, p.astype(v.dtype), (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
-    m_sc[b] = jnp.broadcast_to(m_new, m_sc.shape[1:])
+    m_sc[b] = m_new
 
 
 def _blocks_update(q, k, v, visible, acc_sc, m_sc, l_sc, *, scale):
@@ -626,6 +741,10 @@ def _tiled_kernel(layer_ref, len_ref, bt_ref, first_ref, last_ref, lo_ref,
     whole tiles where it lies; k_buf/v_buf are (2, cp, bs, F); sem is
     (2, 2) = slot x {k, v}, a start a page and a chunk's pages waited
     for by their bytes (one wait a semaphore where the chunk is whole).
+    The token tile's float32 state is TRANSPOSED, a query row a lane
+    (:func:`_tile_update`): acc_sc ``(nblk, bw, Mp)``, m_sc / l_sc
+    ``(nblk, 1, Mp)``, ``Mp`` the tile's ``rpb * tq`` query rows a lane
+    block to whole lane tiles, and tl_ref ``(1, 1, Mp)`` their bounds.
 
     ``one_token`` (static): every row has ONE token, and the tile is
     ``tq`` rows walked one after another. The queries lie token-major,
@@ -645,6 +764,7 @@ def _tiled_kernel(layer_ref, len_ref, bt_ref, first_ref, last_ref, lo_ref,
     nblk, rpb = q_ref.shape[1:3] if one_token else q_ref.shape[:2]
     bw = q_ref.shape[3]
     M, P = rpb * tq, cp * bs
+    Mp = acc_sc.shape[-1]       # the token tile: M to whole lane tiles
     sw = ks_buf.shape[0] // 2 if quant else 0   # a slot of scales, words
     t0 = pl.program_id(0) * tq
     lo, hi = lo_ref[pl.program_id(0)], hi_ref[pl.program_id(0)]
@@ -720,12 +840,16 @@ def _tiled_kernel(layer_ref, len_ref, bt_ref, first_ref, last_ref, lo_ref,
                 _row_visible(len_ref[first], c, nblk * rpb, P, base, window),
                 acc_sc, m_sc, l_sc, scale=scale)
             return
-        visible = _visible(tl_ref, t0, first, last, c, tq, rpb, P, base,
-                           window)
+        visible = _tile_visible(tl_ref, t0, first, last, c, tq, P, base,
+                                window)
         for b in range(nblk):                                 # static
             k, v = block(slot, b)
-            _tile_update(q_ref[b].reshape(M, bw), k, v, visible, acc_sc,
-                         m_sc, l_sc, b, scale=scale)
+            q = q_ref[b].reshape(M, bw)
+            if Mp != M:        # a small launch: zero rows to whole lanes
+                q = jnp.concatenate(
+                    [q, jnp.zeros((Mp - M, bw), q.dtype)], axis=0)
+            _tile_update(q, k, v, visible, acc_sc, m_sc, l_sc, b,
+                         scale=scale)
 
     def finish(first):
         """The walked row's output, a head's lanes from its own rows."""
@@ -748,8 +872,9 @@ def _tiled_kernel(layer_ref, len_ref, bt_ref, first_ref, last_ref, lo_ref,
 
     lane = jax.lax.broadcasted_iota(jnp.int32, (group * tq, bw), 1)
     for b in range(nblk):                                     # static
-        l = l_sc[b, :, :1]
-        a = (acc_sc[b] / jnp.where(l == 0.0, 1.0, l)).reshape(
+        l = l_sc[b]
+        # the state is transposed (:func:`_tile_update`): back, once a tile
+        a = (acc_sc[b] / jnp.where(l == 0.0, 1.0, l)).T[:M].reshape(
             hpb, group * tq, bw)
         out = a[0]
         for i in range(1, hpb):        # head i's lanes from head i's rows
@@ -797,11 +922,8 @@ def _tiled_call(q, k_cache, v_cache, layer, row_ids, lengths, block_tables,
             "where an int8 pool's scales are sliced (dequantise the layer "
             "first: paged_model._per_head_attention_sublayer)")
     ring = MB             # the table's places, before the padding below
-    # a tile: a power of two of 16 to 128 tokens, at most 512 query rows
-    # a lane block where that leaves 16
-    # (one_token: the rows a grid step walks)
-    tq = _ONE_TOKEN_ROWS if one_token else max(16, min(
-        pow2_bucket(T0, 128), 1 << (max(512 // rpb, 1).bit_length() - 1)))
+    # a tile: :func:`_token_tile` (one_token: the rows a grid step walks)
+    tq = _ONE_TOKEN_ROWS if one_token else _token_tile(T0, rpb)
     T = -(-T0 // tq) * tq
     cp = _chunk_pages(MB, k_cache.shape[1], bs)
     if MB % cp:
@@ -831,19 +953,34 @@ def _tiled_call(q, k_cache, v_cache, layer, row_ids, lengths, block_tables,
 
         def tile(i, *_):
             return (i, 0, 0, 0)
+        bounds = lengths.reshape(T, 1)
+        bounds_spec = pl.BlockSpec((tq, 1), lambda i, *_: (i, 0))
+        state = [pltpu.VMEM((nblk, M, bw), jnp.float32),
+                 pltpu.VMEM((nblk, M, 128), jnp.float32),
+                 pltpu.VMEM((nblk, M, 128), jnp.float32)]
     else:
+        # a tile's query rows lie along the LANES of its scores and state
+        # (:func:`_tile_update`): their bounds a row a tile, padded with
+        # rows that see nothing to whole lane tiles
         M = rpb * tq
+        Mp = -(-M // 128) * 128
         qx = qx.reshape(nblk, rpb, T, bw)
         q_block, o_block = (nblk, rpb, tq, bw), (nblk, group, tq, bw)
 
         def tile(i, *_):
             return (0, 0, i, 0)
+        bounds = jnp.pad(jnp.broadcast_to(
+            lengths.reshape(T // tq, 1, tq), (T // tq, rpb, tq)).reshape(
+            T // tq, 1, M), ((0, 0), (0, 0), (0, Mp - M)))
+        bounds_spec = pl.BlockSpec((1, 1, Mp), lambda i, *_: (i, 0, 0))
+        state = [pltpu.VMEM((nblk, bw, Mp), jnp.float32),
+                 pltpu.VMEM((nblk, 1, Mp), jnp.float32),
+                 pltpu.VMEM((nblk, 1, Mp), jnp.float32)]
 
     pool_spec = pl.BlockSpec(memory_space=pl.ANY)       # pool stays in HBM
-    in_specs = [pl.BlockSpec(q_block, tile),
-                pl.BlockSpec((tq, 1), lambda i, *_: (i, 0)),
-                pool_spec, pool_spec]
-    operands = [qx, lengths.reshape(T, 1), k_cache, v_cache]
+    in_specs = [pl.BlockSpec(q_block, tile), bounds_spec, pool_spec,
+                pool_spec]
+    operands = [qx, bounds, k_cache, v_cache]
     scratch = [pltpu.VMEM((2, cp, bs, F), k_cache.dtype),
                pltpu.VMEM((2, cp, bs, F), v_cache.dtype)]
     sems = [pltpu.SemaphoreType.DMA((2, 2))]
@@ -859,9 +996,7 @@ def _tiled_call(q, k_cache, v_cache, layer, row_ids, lengths, block_tables,
                             .reshape(-1))
         scratch += [pltpu.SMEM((2 * w,), jnp.float32)] * 2
         sems.append(pltpu.SemaphoreType.DMA((2, 2)))
-    scratch += [pltpu.VMEM((nblk, M, bw), jnp.float32),
-                pltpu.VMEM((nblk, M, 128), jnp.float32),
-                pltpu.VMEM((nblk, M, 128), jnp.float32)]
+    scratch += state
     kernel = functools.partial(
         _tiled_kernel, quant=quant, bs=bs, scale=1.0 / (hd ** 0.5), kvh=kvh,
         hd=hd, hpb=hpb, group=group, tq=tq, cp=cp, io_dtype=q.dtype,

@@ -50,10 +50,32 @@ touched, and no option of the program exists for it):
                      the loop; of the state update, the grid's copies of
                      the states in and back with the token taken out
 * ``page-waits``     a wait a page, as before PR 50           (--sweep)
-* ``block-by-block`` the tiled kernel's lane blocks updated one behind
-                     the other, as before PR 50               (--sweep)
 * ``unroll-N``       ``_START_UNROLL`` = N (1, 4): the start loop's trips
                                                               (--sweep)
+
+THE PROMPT'S LAUNCHES of the two 8k-context cells stand beside the decode
+ones (PR 59): ``trinity-prompt-full`` / ``-window`` and ``smallthinker-prompt-
+full`` / ``-window``, the token tile's launch of a chunk step: 16 rows x 1,024
+new tokens that end at contexts 1,024, 4,096 and 8,192 (``--contexts``; a row
+of the output each), over the cell's table (544 pages, or the window's ring).
+Beside the bytes stand the launch's OPERATIONS at the matrix unit's peak
+(``benchmark/arith_window.py``'s count: what the ledger's ``window_roofline
+.gen`` divides by); a tenth of ``--launches`` a program (a launch is 5-20 ms);
+``--check`` holds 32 sampled tokens to the gathering reference (every token's
+gathered context would be terabytes). Their variants (all but the first
+timing only: wrong sums):
+
+* ``copies``         as above (``_tile_update`` does nothing)
+* ``no-mask``        no chunk's mask built, nothing selected
+* ``no-copies``      no page copied or waited for: the walk's loops and the
+                     products over whatever the slots hold     (--sweep)
+* ``no-exp`` / ``no-reduce`` / ``no-pv``   the update without its
+                     exponentials, without its rows' max and sum, without
+                     its second product                        (--sweep)
+* ``halves``         the update as two products over 256 positions each
+                                                               (--sweep)
+* ``tq-N``           the tile N tokens (``_token_tile``), alone and with each
+                     of the others                             (--sweep)
 
 A tree that lacks a function a variant replaces (an older commit) skips that
 variant and says so. ``--check`` holds one launch of each shape to the
@@ -75,6 +97,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import jax                                                    # noqa: E402
 import jax.numpy as jnp                                       # noqa: E402
 import numpy as np                                            # noqa: E402
+from jax.experimental.pallas import tpu as pltpu              # noqa: E402
 
 # the package exports a function under the module's name
 ra = importlib.import_module(                                 # noqa: E402
@@ -88,7 +111,10 @@ try:                            # a tree before the kind: its shapes skip
 except ImportError:
     pr = None
 
+from benchmark import arith_window                            # noqa: E402
+
 PEAK_BYTES_S = 819e9            # TPU v5e (benchmark/peaks.json)
+PEAK_FLOPS_S = 197e12
 
 # name -> kernel, rows, layers, kv heads (tiled) , head width / pool row,
 # query heads, contexts [lo, hi), window, the ring's pages (0: a table that
@@ -115,6 +141,23 @@ SHAPES = {
                                 ring=321),
     "granite-full": dict(kernel="tiled", rows=64, layers=1, kvh=8, hd=128,
                          nh=32, ctx=(1024, 1280), window=0, ring=0),
+    # the token tile's launch of a chunk step of the two 8k cells: ``new``
+    # tokens a row that end at each of ``ends`` (a row of the output each),
+    # over the cell's table of 544 pages or the window's ring
+    "trinity-prompt-full": dict(kernel="prompt", rows=16, new=1024, kvh=4,
+                                hd=128, nh=32, ends=(1024, 4096, 8192),
+                                window=0, ring=0),
+    "trinity-prompt-window": dict(kernel="prompt", rows=16, new=1024, kvh=4,
+                                  hd=128, nh=32, ends=(1024, 4096, 8192),
+                                  window=2048, ring=193),
+    "smallthinker-prompt-full": dict(kernel="prompt", rows=16, new=1024,
+                                     kvh=4, hd=128, nh=28,
+                                     ends=(1024, 4096, 8192), window=0,
+                                     ring=0),
+    "smallthinker-prompt-window": dict(kernel="prompt", rows=16, new=1024,
+                                       kvh=4, hd=128, nh=28,
+                                       ends=(1024, 4096, 8192), window=4096,
+                                       ring=321),
     # the one-token state-space update: heads of ``p`` channels, ``groups``
     # pairs of B and C a token, a state of ``n`` a channel
     "nemotron-state": dict(kernel="ssm_state", rows=128, layers=7, nh=64,
@@ -350,6 +393,54 @@ def build(shape, rng, rehearse):
     return fn, again, q, pools, L, nbytes, ref
 
 
+def build_prompt(shape, end, rng, rehearse):
+    """One launch of the TOKEN TILE, a chunk step's: every row feeds
+    ``new`` tokens that end at context ``end``. As :func:`build` returns,
+    with the launch's operations behind its bytes and a reference over
+    the sampled tokens ``ref.tokens`` alone."""
+    rows, new, kvh, hd, nh = (shape[k] for k in
+                              ("rows", "new", "kvh", "hd", "nh"))
+    window, ring, table = shape["window"], shape["ring"], 544
+    if rehearse:
+        rows, new, end, table = 2, 160, 160 + 96 * (end > 1024), 48
+        if window:
+            window, ring = 128, 19
+    MB = ring or table
+    nb = 1 + rows * MB
+    T = rows * new
+    tables = jnp.asarray(rng.permutation(np.arange(1, nb)).reshape(rows, MB),
+                         jnp.int32)
+    row_ids = jnp.repeat(jnp.arange(rows, dtype=jnp.int32), new)
+    lengths = jnp.tile(jnp.arange(end - new + 1, end + 1, dtype=jnp.int32),
+                       rows)
+    key = jax.random.PRNGKey(int(rng.integers(1 << 30)))
+    dtype = jnp.float32 if rehearse else jnp.bfloat16
+    k = jax.random.normal(key, (1, nb, BS, kvh * hd), dtype)
+    v = jax.random.normal(jax.random.fold_in(key, 1), k.shape, dtype)
+    q = jax.random.normal(jax.random.fold_in(key, 2), (T, nh, hd), dtype)
+
+    def fn(q, layer, k, v):
+        return ra.ragged_attention(q, k, v, layer, row_ids, lengths, tables,
+                                   window=window, variant="tiled")
+
+    sample = jnp.asarray(np.sort(rng.choice(T, 32, replace=False)))
+
+    def ref(q, layer, k, v):
+        return jnp.concatenate([ra.ragged_attention_reference(
+            q[at], k, v, layer, row_ids[at], lengths[at], tables,
+            window=window) for at in sample.reshape(4, 8)])
+    ref.tokens = sample
+
+    def again(q, out):
+        return q + out * 0
+    fields = dict(num_heads=nh, num_kv_heads=kvh, head_dim_override=hd)
+    launch = [(new, end)] * rows
+    return (fn, again, q, (k, v), 1,
+            (arith_window.launch_bytes(fields, launch, window,
+                                       k.dtype.itemsize),
+             arith_window.launch_flops(fields, launch, window)), ref)
+
+
 @contextlib.contextmanager
 def patched(module, **attrs):
     """``module``'s attributes replaced for the block; a KeyError names
@@ -377,13 +468,57 @@ def _page_waits(n, cp, wait):
     jax.lax.fori_loop(0, n, lambda j, _: (wait(1), 0)[1], 0)
 
 
-def _block_by_block(q, k, v, visible, acc_sc, m_sc, l_sc, *, scale):
-    """:func:`ra._blocks_update` as the one-token form computed before
-    PR 50: ``_tile_update`` a lane block at a time."""
-    M = q[0].shape[0]
-    for b in range(len(q)):
-        ra._tile_update(q[b], k[b], v[b], visible[:M], acc_sc, m_sc, l_sc,
-                        b, scale=scale)
+def _update_without(mask=True, exp=True, reduce=True, pv=True, split=1):
+    """:func:`ra._tile_update` (the token tile's, transposed) with a part
+    taken out (wrong sums: timing only): the ``mask`` never built nor
+    selected by, the exponential of the scores (``exp``), the rows' max
+    and sum (``reduce``), the second product (``pv``); or whole, as
+    ``split`` products over as many parts of the chunk's positions, one
+    behind the other."""
+    def update(q, k, v, visible, acc_sc, m_sc, l_sc, b, *, scale):
+        P = k.shape[0] // split
+        for h in range(split):                                # static
+            at = slice(h * P, (h + 1) * P)
+            s = jax.lax.dot_general(
+                k[at], q, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            if mask:
+                s = jnp.where(visible[at], s, 2 * ra.NEG_INF)
+            m_prev = m_sc[b]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True)) \
+                if reduce else m_prev
+            p = jnp.exp(s - m_new) if exp else s - m_new
+            corr = jnp.exp(m_prev - m_new)
+            l_sc[b] = l_sc[b] * corr + (jnp.sum(p, axis=0, keepdims=True)
+                                        if reduce else p[:1])
+            acc_sc[b] = acc_sc[b] * corr + (jax.lax.dot_general(
+                v[at], p.astype(v.dtype), (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+                if pv else p[:acc_sc.shape[1]])
+            m_sc[b] = m_new
+    return update
+
+
+class _NoCopy:
+    """A copy that is never made: started and waited for in no time."""
+
+    def start(self):
+        pass
+
+    wait = start
+
+
+class _NoCopies:
+    """``pltpu`` with every ``make_async_copy`` a :class:`_NoCopy`: the
+    walk's loops and the products over whatever the slots hold."""
+
+    def __getattr__(self, name):
+        return getattr(pltpu, name)
+
+    @staticmethod
+    def make_async_copy(*a, **k):
+        return _NoCopy()
+
 
 
 def _state_copies(layer_ref, slots_ref, fresh_ref, s_ref, decay_ref, dtx_ref,
@@ -430,6 +565,23 @@ def variants(kernel, sweep):
                 "copies": dict(_state_kernel=_retention_copies)}
     if kernel == "retention_chunk":
         return {"full": {}, "copies": dict(_distances_loop=_no_distances)}
+    if kernel == "prompt":
+        parts = {"": {}, "copies": dict(_tile_update=_nothing),
+                 "no-mask": dict(_tile_update=_update_without(mask=False)),
+                 "no-copies": dict(pltpu=_NoCopies()),
+                 "no-exp": dict(_tile_update=_update_without(exp=False)),
+                 "no-reduce": dict(
+                     _tile_update=_update_without(reduce=False)),
+                 "no-pv": dict(_tile_update=_update_without(pv=False)),
+                 "halves": dict(_tile_update=_update_without(split=2))}
+        out = {"full": {}, "copies": parts["copies"],
+               "no-mask": parts["no-mask"]}
+        if sweep:
+            for n in (64, 128, 256):
+                for label, attrs in parts.items():
+                    out[f"tq-{n}" + f"+{label}" * bool(label)] = dict(
+                        attrs, _token_tile=lambda tokens, rpb, n=n: n)
+        return out
     # the products of this tree's kernels, whichever it has (the parent
     # of PR 50 ran ``_tile_update`` in both forms of the tiled kernel and
     # kept the latent kernel's products inline)
@@ -439,8 +591,6 @@ def variants(kernel, sweep):
                                   if hasattr(ra, a)} or {products[0]: None}}
     if sweep:
         out["page-waits"] = dict(_chunk_waits=_page_waits)
-        if kernel != "latent":
-            out["block-by-block"] = dict(_blocks_update=_block_by_block)
         for u in (1, 4):
             out[f"unroll-{u}"] = dict(_START_UNROLL=u)
     return out
@@ -480,6 +630,10 @@ def main():
     ap.add_argument("--sweep", action="store_true")
     ap.add_argument("--check", action="store_true")
     ap.add_argument("--launches", type=int, default=200)
+    ap.add_argument("--variants", default="", help="comma-separated: the "
+                    "variants to time (all of a kernel's)")
+    ap.add_argument("--contexts", default="", help="comma-separated: the "
+                    "prompt shapes' contexts to run (all of a shape's)")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--rehearse", action="store_true")
     args = ap.parse_args()
@@ -491,7 +645,13 @@ def main():
         sys.exit(f"needs a TPU, found {jax.default_backend()!r} "
                  "(--rehearse runs toy sizes on the CPU)")
     names = [n for n in args.only.split(",") if n] or list(SHAPES)
-    for name in names:
+    wanted = [int(c) for c in args.contexts.split(",") if c]
+    chosen = [v for v in args.variants.split(",") if v]
+    # a prompt shape is a row of the output a context
+    cases = [(n, end) for n in names
+             for end in (SHAPES[n].get("ends") or (None,))
+             if end is None or not wanted or end in wanted]
+    for name, end in cases:
         rng = np.random.default_rng(args.seed)
         kernel = SHAPES[name]["kernel"]
         if kernel.startswith("retention") and pr is None:
@@ -505,10 +665,18 @@ def main():
                     "retention_chunk": (build_retention, pr)}
         state = kernel in in_place
         builder, module = in_place.get(kernel, (build, ra))
-        fn, again, q, pools, L, nbytes, ref = builder(
-            SHAPES[name], rng, args.rehearse)
-        row = {"shape": name, "bytes": nbytes,
-               "bytes_us": round(nbytes / PEAK_BYTES_S * 1e6, 2)}
+        if kernel == "prompt":
+            fn, again, q, pools, L, (nbytes, flops), ref = build_prompt(
+                SHAPES[name], end, rng, args.rehearse)
+            row = {"shape": name, "context": end, "flops": flops,
+                   "flops_us": round(flops / PEAK_FLOPS_S * 1e6, 2)}
+            launches = max(2, args.launches // 10)
+        else:
+            fn, again, q, pools, L, nbytes, ref = builder(
+                SHAPES[name], rng, args.rehearse)
+            row, launches = {"shape": name}, args.launches
+        row.update({"bytes": nbytes,
+                    "bytes_us": round(nbytes / PEAK_BYTES_S * 1e6, 2)})
         if args.check:
             # a leaf updated in place goes in donated, as the engine's
             # cache does: a kernel that colours its leaf HBM cannot take
@@ -517,19 +685,26 @@ def main():
             got, want = (jax.tree.leaves(jax.jit(f, donate_argnums=given)(
                 q, L - 1, *(jnp.copy(p) if state else p for p in pools)))
                 for f in (fn, ref))
+            if kernel == "prompt":
+                got = [got[0][ref.tokens]]
             row["max_err"] = max(float(jnp.abs(g - w).max())
                                  for g, w in zip(got, want))
             row["rel_err"] = max(
                 float(jnp.linalg.norm(g - w) / jnp.linalg.norm(w))
                 for g, w in zip(got, want))
         for label, attrs in variants(kernel, args.sweep).items():
+            if chosen and label not in chosen:
+                continue
             try:
                 with patched(module, **attrs):
                     row[label] = round(time_launches(
-                        fn, again, q, pools, L, args.launches,
+                        fn, again, q, pools, L, launches,
                         carried=state), 2)
             except KeyError as missing:
                 row[label] = f"no {missing.args[0]} in this tree"
+            except Exception as refused:      # the compiler's, at a size
+                row[label] = f"{type(refused).__name__}: " \
+                    f"{str(refused)[:200]}"
         print(json.dumps(row), flush=True)
     if args.rehearse:
         print("REHEARSAL (cpu)")

@@ -233,7 +233,9 @@ def test_tiled_pure_decode_is_the_decode_kernel(stored_pool, kvh, nh, pool):
     decode = np.asarray(jax.jit(functools.partial(paged_attention, **kw))(
         q, kp, vp, 1, tables, lens), np.float32)
     np.testing.assert_array_equal(ragged, decode)
-    np.testing.assert_allclose(tile, decode, rtol=0, atol=2e-6)
+    # (a bf16 output: the other order may round one value the other way)
+    np.testing.assert_allclose(tile, decode, atol=2e-6,
+                               rtol=2 ** -7 if pool == "bf16" else 0)
     assert not decode[5].any()                       # a row of no length
     ref = _gather_reference(q, c["kc"], c["vc"], rows, lens, tables,
                             c["ks"], c["vs"])
@@ -334,18 +336,36 @@ def test_a_decode_launch_over_partial_chunks_is_the_parents(case):
 def test_the_lane_blocks_at_once_are_the_blocks_one_by_one(case, monkeypatch):
     """``_blocks_update`` (every lane block's scores stacked, one
     softmax, the state read and written once) against the parent's
-    form kept as the test's own reference, ``_tile_update`` a lane
-    block at a time on the same chunk: a row's max and sum run over its
+    form kept as the test's own reference, a lane block at a time on
+    the same chunk: a row's max and sum run over its
     own scores and each product is its block's own either way, so the
     outputs are equal to the bit."""
     from tests.unit.inference import walk_cases
     ra = walk_cases.ra()
 
+    def block_update(q, k, v, visible, acc_sc, m_sc, l_sc, b, *, scale):
+        """A lane block's update as the parent of PR 50 made it (the
+        token tile's own until PR 59 transposed that one)."""
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        s = jnp.where(visible, s, 2 * ra.NEG_INF)
+        m_prev = m_sc[b, :, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        l_sc[b] = jnp.broadcast_to(
+            l_sc[b, :, :1] * corr + jnp.sum(p, axis=1, keepdims=True),
+            l_sc.shape[1:])
+        acc_sc[b] = acc_sc[b] * corr + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_sc[b] = jnp.broadcast_to(m_new, m_sc.shape[1:])
+
     def one_by_one(q, k, v, visible, acc_sc, m_sc, l_sc, *, scale):
         M = q[0].shape[0]
         for b in range(len(q)):
-            ra._tile_update(q[b], k[b], v[b], visible[:M], acc_sc, m_sc,
-                            l_sc, b, scale=scale)
+            block_update(q[b], k[b], v[b], visible[:M], acc_sc, m_sc, l_sc,
+                         b, scale=scale)
 
     monkeypatch.setattr(ra, "_blocks_update", one_by_one)
     np.testing.assert_array_equal(walk_cases.launch(case),
@@ -387,6 +407,165 @@ def test_decode_positions_held_and_chunked(name, contexts, table, window,
         assert held == int((-(-ctx // 16) * 16).sum())
     assert share[0] <= held / chunked <= share[1], held / chunked
     assert ra().decode_positions([], 16, table, 10_000, window) == (0, 0)
+
+
+# ---------------------------------------------------------------------------
+# the token tile (PR 59): up to 1,024 query rows a lane block
+# ---------------------------------------------------------------------------
+from tests.unit.inference.walk_cases import PROMPT_CASES  # noqa: E402
+
+
+def _prompt_geometry(case):
+    """``(token tile, table pages, pool blocks, window)`` of a case's
+    launch, as the kernel reads them off its operands."""
+    from tests.unit.inference import walk_cases
+    c = PROMPT_CASES[case]
+    (q, k, *_, tables), _, _ = walk_cases.build_prompt(case)
+    return (walk_cases.ra().token_tile(q.shape[0], c["nh"], c["hd"],
+                                       c["kvh"]),
+            tables.shape[1], k.shape[1], c.get("window", 0))
+
+
+@pytest.mark.parametrize("case,tile", [
+    ("hpb2-bf16", 128), ("hpb2-int8", 128), ("group4-bf16", 128),
+    ("group7-float32", 128), ("group8-bf16", 128), ("group8-int8", 128),
+    ("group16-float32", 64), ("window-wraps", 128),
+    ("window-first-not-last", 128), ("short", 64)])
+def test_the_token_tile_is_the_reference_at_the_new_tile_sizes(case, tile):
+    """The token tile under the TPU interpreter against the gathering
+    reference where a tile holds up to 1,024 query rows a lane block
+    (two 64-wide heads a block, groups of 4, 7, 8 and 16; bf16 and int8
+    pools; a window whose ring wraps inside a tile's walk; a chunk whole
+    for a tile's first token and not its last; a tile of two rows; a
+    prompt shorter than a tile)."""
+    from tests.unit.inference import walk_cases
+    assert _prompt_geometry(case)[0] == tile
+    got, want = walk_cases.prompt_output(case), \
+        walk_cases.prompt_reference(case)
+    assert np.isfinite(got).all()
+    c = PROMPT_CASES[case]
+    # bf16: the output's own rounding and p's before p.v; float32 (the
+    # int8 pools are served in it): the order of the sums alone
+    tol = 2e-2 if c.get("dtype") == "bfloat16" \
+        else 2e-5 if c.get("int8") else 2e-6
+    np.testing.assert_allclose(got, want, rtol=tol if tol > 2e-6 else 0,
+                               atol=tol)
+    fed = sum(new for new, _ in walk_cases.prompt_rows(case))
+    assert not got[fed:].any()                           # the padding
+
+
+def _brute_chunks(ra, rows, bs, table_pages, pool_blocks, window, tq):
+    """``prompt_chunks`` by visiting every (tile, row, chunk) as
+    ``_walk_rows`` does and asking ``_visible`` itself whether every
+    query row of the tile sees every position of the chunk."""
+    P = ra._chunk_pages(table_pages, pool_blocks, bs) * bs
+    ids = [r for r, (new, _) in enumerate(rows) for _ in range(new)]
+    bounds = [b for new, ctx in rows for b in range(ctx - new + 1, ctx + 1)]
+    pad = -len(ids) % tq
+    ids, bounds = np.asarray(ids + [-1] * pad), np.asarray(bounds + [0] * pad)
+    whole = masked = 0
+    for t0 in range(0, len(ids), tq):
+        mine = ids[t0:t0 + tq]
+        for r in sorted(set(mine[mine >= 0])):
+            at = np.flatnonzero(mine == r) + t0
+            first, last = int(at[0]), int(at[-1])
+            base = max(int(bounds[first]) - window, 0) // bs * bs \
+                if window else 0
+            for c in range(-(-(int(bounds[last]) - base) // P)):
+                seen = ra._visible(
+                    jnp.asarray(bounds[t0:t0 + tq], jnp.int32)[:, None], t0,
+                    first, last, c, tq, 1, P, base, window)
+                if bool(seen.all()):
+                    whole += 1
+                else:
+                    masked += 1
+    return whole, masked
+
+
+@pytest.mark.parametrize("case", sorted(PROMPT_CASES))
+def test_prompt_chunks_counts_what_visible_shows(case):
+    """The host's ``prompt_chunks`` (the engine's
+    ``inference_attention_prompt_chunks_total``) against a brute-force
+    count over ``_visible`` of the same launch: the chunk visits a tile
+    sees whole and the others, tile by tile and row by row."""
+    from tests.unit.inference import walk_cases
+    ra = walk_cases.ra()
+    rows, bs = walk_cases.prompt_rows(case), walk_cases.PROMPT_BS
+    tq, MB, nb, window = _prompt_geometry(case)
+    got = ra.prompt_chunks([n for n, _ in rows], [c for _, c in rows], bs,
+                           MB, nb, window, tq)
+    assert got == _brute_chunks(ra, rows, bs, MB, nb, window, tq)
+    if case in ("short", "window-first-not-last"):
+        assert got[0] == 0 and got[1] > 0
+    else:
+        assert got[0] > 0 and got[1] > 0
+    assert ra.prompt_chunks([], [], bs, MB, nb, window, tq) == (0, 0)
+    # a decode launch through the token tile: no chunk is whole
+    assert ra.prompt_chunks([1] * 5, [700, 40, 513, 9, 1024], bs, MB, nb,
+                            window, tq)[0] == 0
+
+
+@pytest.mark.parametrize("tokens,rpb,tile", [
+    (16384, 8, 128), (16384, 7, 128), (16384, 16, 64), (16384, 4, 128),
+    (4096, 2, 128), (16, 8, 16), (40, 8, 64), (16384, 64, 16),
+    (16384, 2048, 16)])
+def test_the_tile_follows_the_query_rows_a_lane_block(tokens, rpb, tile):
+    """``_token_tile``: a power of two of 16 to 128 tokens, at most
+    1,024 query rows a lane block where that leaves 16, no more than the
+    launch's bucket: the two 8k cells' groups of 8 and 7 and granite's 4
+    at 128, nemotron's 16 at 64, OPT's two heads a block at 128, a
+    launch of 16 tokens at 16."""
+    from tests.unit.inference.walk_cases import ra
+    assert ra()._token_tile(tokens, rpb) == tile
+
+
+# what the launches that are NOT the token tile are made of: the jaxprs of
+# the one-token form's and the latent kernel's launches of ``walk_cases``
+# were read on the parent of PR 59 (d747e90) and on the change with their
+# addresses struck out and are the same text (PERF.md section 6, PR 59);
+# these counts are that text's, so that a change to the token tile that
+# reaches the walk they share shows here
+OTHER_LAUNCHES = {
+    "tiled-hpb2": dict(dot_general=4, exp=2, select_n=10, cond=15,
+                       dma_start=12, dma_wait=12),
+    "tiled-group8": dict(dot_general=4, exp=2, select_n=8, cond=15,
+                         dma_start=12, dma_wait=12),
+    "tiled-int8": dict(dot_general=4, exp=2, select_n=13, cond=15,
+                       dma_start=16, dma_wait=14),
+    "window-ring": dict(dot_general=4, exp=2, select_n=10, cond=15,
+                        dma_start=12, dma_wait=12),
+    "latent": dict(dot_general=2, exp=2, select_n=8, cond=16, dma_start=6,
+                   dma_wait=7),
+}
+
+
+@pytest.mark.parametrize("case", sorted(OTHER_LAUNCHES))
+def test_the_one_token_and_latent_launches_are_made_of_what_they_were(case):
+    """The one-token form and the latent kernel are not the token
+    tile's: their launches hold the products, exponentials, selects,
+    branches and copies the parent's held, and the token tile's own
+    launch of the same geometry one masked update a lane block and
+    chunk, as it did (no second body beside it)."""
+    import re
+    from tests.unit.inference import walk_cases
+    ra = walk_cases.ra()
+    args, kw, _ = walk_cases.build(case)
+    latent = walk_cases.CASES[case]["kernel"] == "latent"
+    fn = ra.latent_attention if latent else ra.ragged_attention
+
+    def count(text):
+        return {w: len(re.findall(rf"\b{w}\b", text))
+                for w in OTHER_LAUNCHES[case]}
+    text = str(jax.make_jaxpr(functools.partial(fn, **kw))(*args))
+    assert count(text) == OTHER_LAUNCHES[case]
+    if not latent:
+        tile = count(str(jax.make_jaxpr(functools.partial(
+            fn, **dict(kw, one_token=False)))(*args)))
+        # a lane block at a time where the one-token form stacks them
+        blocks = walk_cases.CASES[case]["kvh"] * walk_cases.CASES[case][
+            "hd"] // 128
+        assert tile["dot_general"] == 2 * blocks
+        assert tile["exp"] == 2 * blocks
 
 
 @pytest.mark.parametrize("pool", ["bf16", "int8"])
@@ -545,6 +724,35 @@ def test_the_engine_counts_the_positions_under_the_decode_launches(
     assert grown >= want and grown % 16 == 0
     # a table of one or two pages is one chunk: every row's is whole
     assert grown <= model.cfg.num_layers * 3 * 8 * 32
+
+
+def test_the_engine_counts_the_chunk_visits_of_its_ragged_steps(
+        tiny, monkeypatch):
+    """``inference_attention_prompt_chunks_total`` {whole, masked}: 0 on
+    the CPU (its ragged step runs the pipelined variant: no tile), and
+    where the token tile serves (asked of the engine here as the chip
+    would answer) a visit a layer and (tile, row) for rows whose
+    contexts fit one chunk: three prompts in one tile of the step's
+    bucket are three visits a layer, none of them whole (a tile of
+    several rows), and a continuation of one row one more."""
+    from deepspeed_tpu.inference.v2 import engine_v2
+    model, params = tiny
+    eng = _engine(model, params)
+    family = get_registry().get("inference_attention_prompt_chunks_total")
+    whole, masked = family.labels(kind="whole"), family.labels(kind="masked")
+    before = whole.value, masked.value
+    prompts = [list(range(3, 17)), [2, 4, 6], list(range(40, 62))]
+    eng.put([1, 2, 3], prompts)
+    assert (whole.value, masked.value) == before             # the CPU
+    # (the toy widths are no geometry of the tiled variant: the tile is
+    # told too)
+    monkeypatch.setattr(engine_v2, "token_tile_serves", lambda *a: True)
+    monkeypatch.setattr(engine_v2, "token_tile", lambda tokens, *a: tokens)
+    eng.put([4, 5, 6], prompts)
+    L = model.cfg.num_layers
+    assert (whole.value, masked.value) == (before[0], before[1] + 3 * L)
+    eng.put([5], [[7, 8, 9]])
+    assert (whole.value, masked.value) == (before[0], before[1] + 4 * L)
 
 
 def test_generate_stream_parity_greedy_and_sampled(tiny):

@@ -11,6 +11,11 @@ returned for the same inputs:
         <this repo>/tests/unit/inference/walk_cases.py <out.npz>
 
 wrote ``fixtures/walk_parent_outputs_pr50.npz`` (float32, 220 KB).
+
+PR 59 adds the TOKEN TILE's launches beside them (``PROMPT_CASES``): rows
+that feed many tokens, so that a tile of up to 128 of them sees most of a
+row's chunks whole (no mask), some by an edge, and some with another row's
+tokens beside its own.
 """
 
 import functools
@@ -36,6 +41,113 @@ CASES = {
     "latent": dict(kernel="latent", nh=4, dc=32, dr=16, W=128, bs=8,
                    pages=64),
 }
+
+
+# the rows of a prompt launch, ``(new tokens, the context they end at)``:
+# 172 tokens behind 1,128 (a tile of 128 whose first two chunks are whole
+# and whose third is an edge; at 64 a tile, a chunk that the tile's LAST
+# token sees whole and its first does not; a tail of 44 tokens), 100 tokens
+# behind 500 that start in the first row's last tile (a tile of two rows,
+# and of three: never whole), a prompt of 40
+_ROWS = ((172, 1300), (100, 600), (40, 40))
+# name -> geometry (a lane block's query rows a token: 2, 4, 7, 8, 16: the
+# tile is 128 tokens, and 64 at sixteen), the pool kept and served in
+# ``dtype``, the launch's token bucket
+PROMPT_CASES = {
+    "hpb2-bf16": dict(nh=4, kvh=4, hd=64, dtype="bfloat16"),
+    "hpb2-int8": dict(nh=4, kvh=4, hd=64, int8=True),
+    "group4-bf16": dict(nh=8, kvh=2, hd=128, dtype="bfloat16"),
+    "group7-float32": dict(nh=7, kvh=1, hd=128),
+    "group8-bf16": dict(nh=16, kvh=2, hd=128, dtype="bfloat16"),
+    "group8-int8": dict(nh=16, kvh=2, hd=128, int8=True),
+    "group16-float32": dict(nh=16, kvh=1, hd=128),
+    # a window of 1,300 over rings of 50 pages: the first row's walk starts
+    # at page 45 of its positions and wraps to place 0 inside its first
+    # tile's walk; a tile's second chunk is whole (inside every token's
+    # window), its first and last are edges
+    "window-wraps": dict(nh=16, kvh=2, hd=128, window=1300, ring=50,
+                         rows=((256, 3000), (100, 900)), bucket=384),
+    # the first token's window starts ON a page (1,801 - 713 = 34 x 32), so
+    # the walk's first chunk is whole for the tile's first token and not
+    # for its last: masked
+    "window-first-not-last": dict(nh=16, kvh=2, hd=128, window=713, ring=30,
+                                  rows=((200, 2000),), bucket=256),
+    # a prompt shorter than one tile, alone in its launch
+    "short": dict(nh=16, kvh=2, hd=128, rows=((40, 40),), bucket=64),
+}
+
+
+PROMPT_BS = 32            # 16 pages a chunk of 512 positions
+
+
+def prompt_rows(case):
+    return PROMPT_CASES[case].get("rows", _ROWS)
+
+
+@functools.lru_cache(maxsize=None)
+def build_prompt(case):
+    """``(args, kw, reference kw)`` of the case's token-tile launch, as
+    :func:`build` gives a decode launch's: the rows' tokens packed in
+    order and padded to the bucket, pages out of order."""
+    import jax.numpy as jnp
+    c = PROMPT_CASES[case]
+    rng = np.random.default_rng(100 + sorted(PROMPT_CASES).index(case))
+    rows, bs = prompt_rows(case), PROMPT_BS
+    R = len(rows)
+    MB = c.get("ring") or -(-max(ctx for _, ctx in rows) // bs)
+    nb = 1 + R * MB
+    T = c.get("bucket", 384)
+    ids = [r for r, (new, _) in enumerate(rows) for _ in range(new)]
+    bounds = [b for new, ctx in rows for b in range(ctx - new + 1, ctx + 1)]
+    pad = T - len(ids)
+    tables = jnp.asarray(rng.permutation(np.arange(1, nb)).reshape(R, MB),
+                         jnp.int32)
+    desc = (1, jnp.asarray(ids + [0] * pad, jnp.int32),
+            jnp.asarray(bounds + [0] * pad, jnp.int32), tables)
+    F = c["kvh"] * c["hd"]
+    io = getattr(jnp, c.get("dtype", "float32"))
+    q = jnp.asarray(rng.normal(size=(T, c["nh"], c["hd"])), io)
+    kw = {}
+    if c.get("int8"):
+        k, v = (jnp.asarray(rng.integers(-127, 128, (2, nb, bs, F)),
+                            jnp.int8) for _ in range(2))
+        kw = dict(k_scale=jnp.asarray(
+            rng.uniform(0.005, 0.03, (nb, c["kvh"])), jnp.float32),
+            v_scale=jnp.asarray(
+            rng.uniform(0.005, 0.03, (nb, c["kvh"])), jnp.float32))
+    else:
+        k, v = (jnp.asarray(rng.normal(size=(2, nb, bs, F)), io)
+                for _ in range(2))
+    if c.get("window"):
+        kw["window"] = c["window"]
+    return (q, k, v) + desc, dict(kw, variant="tiled"), kw
+
+
+def prompt_launch(case):
+    """The case through the token tile of the importable tree."""
+    import jax
+    args, kw, _ = build_prompt(case)
+    return np.asarray(jax.jit(functools.partial(
+        ra().ragged_attention, **kw))(*args), np.float32)
+
+
+@functools.lru_cache(maxsize=None)
+def prompt_output(case):
+    return prompt_launch(case)
+
+
+def prompt_reference(case):
+    """The gathering reference, eight tokens at a time: every token's
+    gathered context at once is gigabytes."""
+    import jax
+    (q, k, v, layer, ids, bounds, tables), _, kw = build_prompt(case)
+
+    def some(at):
+        return ra().ragged_attention_reference(
+            q[at], k, v, layer, ids[at], bounds[at], tables, **kw)
+    out = jax.jit(lambda: jax.lax.map(
+        some, np.arange(q.shape[0]).reshape(-1, 8)))()
+    return np.asarray(out, np.float32).reshape(q.shape)
 
 
 def ra():

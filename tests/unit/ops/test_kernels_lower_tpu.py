@@ -1009,12 +1009,37 @@ def test_the_decode_launches_lower_in_the_one_token_form(tpu_sharding, cell):
 # PR 50: the prompt launches no test above holds (rollout-256's 4,096 tokens,
 # joyai's 8,192 and trinity's 16,384 with and without the window are
 # CELL_LAUNCHES, LATENT_LAUNCHES and WINDOW_LAUNCHES): (kernel, tokens, rows,
-# the pool as stored, the table's pages)
+# the pool as stored, the table's pages, query heads, window, the token tile
+# by the queries' block). PR 59 grows them to the five per-head cells' ragged
+# steps at the tile each now takes: up to 1,024 query rows a lane block
+# (``_token_tile``), inside ``_TILED_VMEM_BYTES``
 PROMPT_LAUNCHES = {
     "ling-3.0-flash.rollout-128x256": (
-        "latent", 16384, 128, (1, 3201, 16, 640), 8),
+        "latent", 16384, 128, (1, 3201, 16, 640), 8, 32, 0, None),
     "granite-4.0-h-small.rollout-64x1024-256": (
-        "tiled", 16384, 64, (1, 5185, 16, 1024), 80),
+        "tiled", 16384, 64, (1, 5185, 16, 1024), 80, 32, 0,
+        "bf16[8,4,16384,128]"),
+    "opt-1.3b.rollout-256": (
+        "tiled", 4096, 16, (24, 529, 16, 2048), 16, 32, 0,
+        "bf16[16,2,4096,128]"),
+    "opt-1.3b.rollout-256.int8": (
+        "tiled-int8", 4096, 16, (24, 529, 16, 2048), 16, 32, 0,
+        "bf16[16,2,4096,128]"),
+    "nemotron-3-nano-30b-a3b.rollout-128x256-384": (
+        "tiled", 16384, 128, (2, 5249, 16, 256), 40, 32, 0,
+        "bf16[2,16,16384,128]"),
+    "trinity-mini.rollout-16x8192-512.full": (
+        "tiled", 16384, 16, (1, 8721, 16, 512), 544, 32, 0,
+        "bf16[4,8,16384,128]"),
+    "trinity-mini.rollout-16x8192-512.window": (
+        "tiled", 16384, 16, (4, 3089, 16, 512), 193, 32, 2048,
+        "bf16[4,8,16384,128]"),
+    "smallthinker-21ba3b-instruct.rollout-16x8192-512.full": (
+        "tiled", 16384, 16, (2, 8721, 16, 512), 544, 28, 0,
+        "bf16[4,7,16384,128]"),
+    "smallthinker-21ba3b-instruct.rollout-16x8192-512.window": (
+        "tiled", 16384, 16, (6, 5137, 16, 512), 321, 28, 4096,
+        "bf16[4,7,16384,128]"),
 }
 
 
@@ -1022,11 +1047,14 @@ PROMPT_LAUNCHES = {
 def test_the_prompt_launches_lower_with_a_wait_a_chunk(tpu_sharding, cell):
     """The token tile takes PR 50's waits (the walk is one): a chunk's
     pages waited for by their bytes on descriptors that are never
-    started, at the two cells' ragged steps of 16,384 tokens; Mosaic
-    takes them under the launches' names."""
+    started, at the cells' ragged steps of 16,384 (OPT: 4,096) tokens;
+    Mosaic takes them under the launches' names, at the tile PR 59 gives
+    each (a lane block's query rows a token 2, 4, 7, 8 and 16; a window
+    over a ring; an int8 pool), inside the fast memory the launch is
+    granted."""
     from deepspeed_tpu.inference.v2.kernels.ragged_attention import \
         latent_attention
-    kernel, T, R, pool, MB = PROMPT_LAUNCHES[cell]
+    kernel, T, R, pool, MB, nh, window, queries = PROMPT_LAUNCHES[cell]
 
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=tpu_sharding)
@@ -1039,13 +1067,26 @@ def test_the_prompt_launches_lower_with_a_wait_a_chunk(tpu_sharding, cell):
         args = [sds((32, T, 640), jnp.bfloat16), sds(pool, jnp.bfloat16)]
         pattern = LATENT_PATTERN
     else:
-        fn = ragged_attention
-        args = [sds((T, 32, 128), jnp.bfloat16), sds(pool, jnp.bfloat16),
-                sds(pool, jnp.bfloat16)]
+        hd = 64 if pool[-1] == 2048 else 128
+        quant = kernel == "tiled-int8"
+        fn = lambda *a: ragged_attention(       # noqa: E731
+            *a[:7], window=window,
+            **(dict(k_scale=a[7], v_scale=a[8]) if quant else {}))
+        kept = jnp.int8 if quant else jnp.bfloat16
+        args = [sds((T, nh, hd), jnp.bfloat16), sds(pool, kept),
+                sds(pool, kept)]
+        if quant:
+            desc += [sds((pool[1], pool[-1] // hd), jnp.float32)] * 2
         pattern = TRACE_PATTERN
     text = jax.jit(fn).lower(*args, *desc).compile().as_text()
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
     kernels = re.findall(r"%([\w.\-]+) = [^\n]*tpu_custom_call", text)
     assert len(kernels) == 1 and pattern.search(kernels[0]), kernels
+    assert bool(window) == kernels[0].startswith("ragged_attention_window")
+    if queries:
+        # the queries as the token tile takes them: [lane block, query
+        # row of the block, token, 128]
+        assert queries in calls[0], calls[0][:400]
 
 
 def _no_relayout_of(text, *pools):
