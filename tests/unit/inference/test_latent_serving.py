@@ -1,23 +1,14 @@
-"""The DeepSeek-V3 block served: a latent (MLA) pool and its kernel, a
-leading dense stack before the scanned expert stack, and the expert
-layer as deployed (sigmoid scores, a selection-only bias, the chosen
-weights normalised and scaled, a shared expert), at toy widths on the
-CPU, against the benchmark's plain reference
-(``benchmark/reference_joyai.py``: float32, non-absorbed, no cache).
-
-Tolerances. A float32 engine differs from the reference by the order of
-its sums and by the absorbed form: 2e-5 of the largest logit is twenty
-times what it reads (9e-7). A bf16 engine rounds every
-activation to 8 bits: the OPT block's toy limit, 4e-2, on a seed whose
-routing the rounding does not flip (with two experts of eight a token, a
-flipped choice swaps half the routed output, and one seed in twelve
-flips one at the compared position; PERF.md section 4). A fault is held
-to 3e-2 and more: over a thousand times the float32 limit.
+"""The DeepSeek-V3 block's own: the latent kernel against the gather path,
+the pool's row, the router as deployed (sigmoid scores, a selection-only
+bias, the chosen weights normalised and scaled), the trees (a leading
+dense stack held apart), a latent row handed to another engine, and the
+expert counters. What every served block is held to (the engine against
+the plain reference ``benchmark/reference_joyai.py``: float32,
+non-absorbed, no cache; the int8 control, the three faults in the expert
+layer, the refusals) is the contract's
+(``test_served_block_contract.py``), on this block's row of
+``served_blocks.py``, where the limits are justified.
 """
-
-import dataclasses
-import json
-from pathlib import Path
 
 import numpy as np
 import pytest
@@ -25,9 +16,6 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from benchmark import reference_joyai, weights_joyai
-from benchmark import run as harness
-from deepspeed_tpu.inference.v2 import InferenceEngineV2
 from deepspeed_tpu.inference.v2.kernels.ragged_attention import (
     latent_attention, latent_attention_reference)
 from deepspeed_tpu.inference.v2.paged_model import (init_paged_kv_cache,
@@ -35,90 +23,16 @@ from deepspeed_tpu.inference.v2.paged_model import (init_paged_kv_cache,
 from deepspeed_tpu.models import TransformerConfig, TransformerLM
 from deepspeed_tpu.moe.sharded_moe import topk_routing
 from deepspeed_tpu.telemetry import get_registry
+from tests.unit.inference import served_block_contract as contract
+from tests.unit.inference import served_blocks as sb
 
-REPO = Path(__file__).resolve().parents[3]
-CONFIG = json.loads(
-    (REPO / "benchmark/configs/joyai-llm-flash.json").read_text())
-TOY = harness.merge(CONFIG["fields"], CONFIG["toy_fields"])
-F32_TIGHT, BF16_LIMIT, A_FAULT = 2e-5, 4e-2, 3e-2
-SEED = 7
-
-
-def _engine(dtype="float32", fields=TOY, params=None, **engine):
-    cfg = TransformerConfig(**fields)
-    if params is None:
-        params = weights_joyai.make(fields, SEED, dtype)
-    return InferenceEngineV2(TransformerLM(cfg), {
-        "dtype": dtype, "use_paged_kernel": True, **engine,
-        "state_manager": {"max_tracked_sequences": 4,
-                          "max_ragged_batch_size": 64, "max_seq_len": 256,
-                          "block_size": 16, "num_blocks": 40}}, params=params)
-
-
-def _prompts(n=3, length=16):
-    rng = np.random.default_rng(0)
-    return [rng.integers(0, TOY["vocab_size"], length) for _ in range(n)]
-
-
-def _reference_last(fields, dtype, prompts, params=None):
-    if params is None:
-        params = weights_joyai.make(fields, SEED, dtype)
-    return np.stack([np.asarray(reference_joyai.logits(params, fields, p)[-1])
-                     for p in prompts])
-
-
-def _err(got, want):
-    return float(np.abs(np.asarray(got, np.float32) - want).max()
-                 / np.abs(want).max())
+BLOCK = sb.BLOCKS["joyai-llm-flash"]
+globals().update(contract.clauses(BLOCK))     # the contract's cases of this row
+CONFIG, TOY, weights_joyai = BLOCK.config, BLOCK.toy, BLOCK.weights
 
 
 # ---------------------------------------------------------------------------
-# (a) the engine against the plain reference
-# ---------------------------------------------------------------------------
-@pytest.mark.parametrize("dtype,limit", [("float32", F32_TIGHT),
-                                         ("bfloat16", BF16_LIMIT)])
-def test_put_logits_match_the_reference(dtype, limit):
-    eng = _engine(dtype)
-    assert eng.attention_impl == "pallas:latent"
-    prompts = _prompts()
-    got = eng.put([0, 1, 2], prompts)
-    assert _err(got, _reference_last(TOY, dtype, prompts)) <= limit
-
-
-def test_decode_through_the_latent_pool_matches_the_reference():
-    """The ragged step writes the prompt's rows, then decode windows
-    read and extend them: at EVERY generated position the engine's token
-    is the reference's best on the same prefix (float32: no near-tie is
-    within rounding), so a row, a rope position or a page read wrong
-    shows. Rows of different lengths, one ending mid-page."""
-    eng = _engine("float32")
-    rng = np.random.default_rng(1)
-    prompts = [rng.integers(0, TOY["vocab_size"], n) for n in (16, 21, 9)]
-    outs = eng.generate(prompts, max_new_tokens=20, temperature=0.0,
-                        eos_token_id=None)
-    params = weights_joyai.make(TOY, SEED, "float32")
-    for prompt, out in zip(prompts, outs):
-        out = np.asarray(out)
-        assert len(out) == len(prompt) + 20
-        ref = np.asarray(reference_joyai.logits(params, TOY, out[:-1]))
-        want = ref[len(prompt) - 1:].argmax(-1)
-        np.testing.assert_array_equal(out[len(prompt):], want)
-
-
-def test_the_int8_latent_pool_is_the_lower_precision_control():
-    """``kv_quant`` stores the rows in 8 bits against each row's largest
-    value; the benchmark's weights carry outlier channels in the latent,
-    so it reads far over the float32 limit."""
-    prompts = _prompts()
-    want = _reference_last(TOY, "float32", prompts)
-    eng = _engine("float32", kv_quant=True)
-    assert eng.kv_cache["latent"].dtype == jnp.int8
-    err = _err(eng.put([0, 1, 2], prompts), want)
-    assert err > 500 * F32_TIGHT, err
-
-
-# ---------------------------------------------------------------------------
-# (b) the kernel against the gather path
+# (a) the kernel against the gather path
 # ---------------------------------------------------------------------------
 def test_latent_kernel_matches_the_gather_on_a_mixed_launch():
     """A prefill chunk from an empty row, a continuation, and two decode
@@ -215,7 +129,7 @@ def test_the_pool_row_is_padded_to_whole_lane_blocks():
 
 
 # ---------------------------------------------------------------------------
-# (c) the router
+# (b) the router
 # ---------------------------------------------------------------------------
 def test_the_bias_chooses_and_does_not_weigh():
     logits = jnp.asarray([[2.0, 1.0, 0.5, -1.0]])
@@ -260,55 +174,8 @@ def test_the_old_routing_is_what_it_was():
                                rtol=1e-6)
 
 
-def _fault(name):
-    """The toy engine's float32 put() logits with one fault in the
-    program's expert layer, against the sound reference."""
-    from deepspeed_tpu.inference.v2 import paged_model
-    from deepspeed_tpu.moe import sharded_moe
-    real_routing = sharded_moe.topk_routing
-    real_experts = paged_model._moe_experts
-
-    def bf16_router(logits, *a, **kw):
-        return real_routing(logits.astype(jnp.bfloat16)
-                            .astype(jnp.float32), *a, **kw)
-
-    def bias_in_weights(logits, k, scoring, bias, normalize, scale):
-        scores = jax.nn.sigmoid(logits) + bias
-        topv, topi = jax.lax.top_k(scores, k)
-        return topi, topv / jnp.sum(topv, -1, keepdims=True) * scale
-
-    def no_shared(cfg, lp, xt, *a, **kw):
-        return real_experts(dataclasses.replace(cfg, moe_shared_experts=0),
-                            lp, xt, *a, **kw)
-
-    patch = {"bf16_router": (sharded_moe, "topk_routing", bf16_router),
-             "bias_in_weights": (sharded_moe, "topk_routing",
-                                 bias_in_weights),
-             "no_shared_expert": (paged_model, "_moe_experts",
-                                  no_shared)}[name]
-    return patch
-
-
-@pytest.mark.parametrize("fault,at_least", [
-    ("bf16_router", 3 * F32_TIGHT), ("bias_in_weights", 50 * F32_TIGHT),
-    ("no_shared_expert", A_FAULT)])
-def test_a_fault_in_the_expert_layer_fails_logit_err(monkeypatch, fault,
-                                                     at_least):
-    """A router computed in bf16 (its logits rounded to 8 bits: the
-    sigmoid's weights move in their third digit; it reads 8e-5), a
-    bias of spread 0.02 leaking into weights of 1.25 (it reads 2.5e-3) and
-    a missing shared expert each read over the float32 limit, the two
-    faults of kind by a hundred times and more."""
-    prompts = _prompts()
-    want = _reference_last(TOY, "float32", prompts)
-    module, attr, fn = _fault(fault)
-    monkeypatch.setattr(module, attr, fn)
-    err = _err(_engine("float32").put([0, 1, 2], prompts), want)
-    assert err > at_least > F32_TIGHT, (fault, err)
-
-
 # ---------------------------------------------------------------------------
-# (d) what was there is what it was
+# (c) what was there is what it was
 # ---------------------------------------------------------------------------
 def test_the_old_moe_tree_and_defaults_are_unchanged():
     cfg = TransformerConfig(vocab_size=64, hidden_size=32,
@@ -347,44 +214,27 @@ def test_the_latent_tree_holds_the_leading_stack_apart():
 
 
 # ---------------------------------------------------------------------------
-# what the engine refuses, and what it counts
+# (d) what else is refused, a handoff, and what the engine counts
 # ---------------------------------------------------------------------------
-@pytest.mark.parametrize("engine,word", [
-    ({"tensor_parallel_size": 2}, "tensor_parallel_size"),
-    ({"quant_bits": 8}, "quant_bits"),
-    ({"max_lora_adapters": 2}, "max_lora_adapters"),
-    ({"state_manager": {"enable_prefix_caching": True}},
-     "enable_prefix_caching")])
-def test_the_engine_refuses_at_construction(engine, word):
-    cfg = TransformerConfig(**TOY)
-    with pytest.raises(NotImplementedError, match=word):
-        InferenceEngineV2(TransformerLM(cfg), {"dtype": "float32", **engine})
-
-
 def test_speculation_training_and_the_v1_engine_are_refused():
+    """(Speculation and the other forward: the contract's.)"""
     cfg = TransformerConfig(**TOY)
-    eng = _engine("float32")
-    with pytest.raises(NotImplementedError, match="speculative"):
-        eng.generate(_prompts(1), max_new_tokens=2, speculative=True)
-    model = TransformerLM(cfg)
-    with pytest.raises(NotImplementedError, match="served by"):
-        model.forward_hidden({}, jnp.zeros((1, 4), jnp.int32))
     with pytest.raises(NotImplementedError, match="served by"):
         cfg.refuse_served_only("the trainer")
     with pytest.raises(NotImplementedError, match="leading dense"):
         TransformerConfig(**{**TOY, "attention": "mha"})
 
 
-def test_a_latent_row_is_handed_to_another_engine():
+def test_a_latent_row_is_handed_to_another_engine(lend):
     """The pool is block pools alone (what the experts routed is an
     output of the programs, not a leaf of the cache), so the handoff
     that gathers every leaf along its block axis moves latent rows as it
     moves keys and values: the other engine decodes what this one would
     have."""
     from deepspeed_tpu.inference.v2.serve import handoff
-    params = weights_joyai.make(TOY, SEED, "float32")
-    src, dst = _engine(params=params), _engine(params=params)
-    prompt = _prompts(1, 21)                       # ends mid-page
+    src = lend()
+    dst = sb.engine(BLOCK)      # its own: ANOTHER engine's pool
+    prompt = sb.prompts(BLOCK, (21,))              # ends mid-page
     first = int(np.argmax(src.put([5], prompt)[0]))
     pack = handoff.deserialize(handoff.serialize(
         handoff.export_sequence(src, 5)))
@@ -396,9 +246,10 @@ def test_a_latent_row_is_handed_to_another_engine():
         np.asarray(dst.kv_cache["latent"])[:, b.blocks])
     np.testing.assert_array_equal(dst.put([9], [[first]])[0],
                                   src.put([5], [[first]])[0])
+    src.flush(5)
 
 
-def test_the_expert_counters_count_valid_rows():
+def test_the_expert_counters_count_valid_rows(lend):
     reg = get_registry()
 
     def read():
@@ -407,10 +258,12 @@ def test_the_expert_counters_count_valid_rows():
                              "moe_experts_touched_total")
                 for prog in ("ragged_step",)}
 
-    eng = _engine("float32")
+    eng = lend()
     before = read()
-    eng.put([0, 1, 2], _prompts(3, 5))        # 15 tokens in a bucket of 16+
+    eng.put([0, 1, 2], sb.prompts(BLOCK, (5, 5, 5)))  # 15 in a bucket of 16
     after = read()
+    for uid in range(3):
+        eng.flush(uid)
     passes = after["moe_launches_total"] - before["moe_launches_total"]
     rows = after["moe_routed_rows_total"] - before["moe_routed_rows_total"]
     touched = (after["moe_experts_touched_total"]

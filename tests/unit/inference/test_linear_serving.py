@@ -1,23 +1,16 @@
-"""The ``bailing_hybrid`` block served: linear-attention (KDA) layers
-with a recurrent state a sequence beside one latent layer a period, a
-per-layer pattern of parameter stacks and cache leaves, a group-limited
-router and an expert layer that holds a SHARE of the experts, at toy
-widths on the CPU, against the benchmark's plain reference
-(``benchmark/reference_ling.py``: float32, a token at a time through the
-recurrence, no cache).
+"""The ``bailing_hybrid`` block's own: a row that continues from its slot,
+``sequence_state`` whatever the leaf's layout, slots freed and reused,
+the two forms of the KDA recurrence and the convolution with their
+kernels, the router's group limit and the shares of the experts, what
+the engine counts, and the trees, leaves and runs of a per-layer
+pattern. What every served block is held to (the engine against the
+plain reference ``benchmark/reference_ling.py``, its control, its
+refusals) is the contract's (``test_served_block_contract.py``), on this
+block's row of ``served_blocks.py``, where the limits are justified.
 
-Tolerances. A float32 engine differs from the reference by the order of
-its sums, the absorbed latent form and the chunked form of the
-recurrence (a triangular solve a chunk in place of 64 rank-one updates):
-2e-5 of the largest logit is twenty times what it reads (4e-7 to 7e-7).
-A bf16 engine rounds every activation to 8 bits: the other blocks' toy
-limit, 4e-2, on a seed whose routing the rounding does not flip (it
-reads 3e-3 to 1e-2). Two forms of one recurrence, both float32: 2e-5 of
-the largest output (they read 2e-6).
+Two forms of one recurrence, both float32: 2e-5 of the largest output
+(they read 2e-6).
 """
-
-import json
-from pathlib import Path
 
 import numpy as np
 import pytest
@@ -25,8 +18,6 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from benchmark import reference_ling, weights_ling
-from benchmark import run as harness
 from deepspeed_tpu.inference.v2 import InferenceEngineV2, paged_model
 from deepspeed_tpu.inference.v2.kernels import linear_attention as la
 from deepspeed_tpu.inference.v2.paged_model import init_paged_kv_cache
@@ -35,196 +26,61 @@ from deepspeed_tpu.inference.v2.config_v2 import DSStateManagerConfig
 from deepspeed_tpu.models import TransformerConfig, TransformerLM
 from deepspeed_tpu.moe.sharded_moe import topk_routing
 from deepspeed_tpu.telemetry import get_registry
+from tests.unit.inference import served_block_contract as contract
+from tests.unit.inference import served_blocks as sb
+from tests.unit.inference.served_blocks import F32 as F32_TIGHT, err as _err
 
-REPO = Path(__file__).resolve().parents[3]
-CONFIG = json.loads(
-    (REPO / "benchmark/configs/ling-3.0-flash.json").read_text())
-TOY = harness.merge(CONFIG["fields"], CONFIG["toy_fields"])
-F32_TIGHT, BF16_LIMIT = 2e-5, 4e-2
-SEED = 5
-
-
-def _engine(dtype="float32", fields=TOY, seqs=4, **engine):
-    cfg = TransformerConfig(**fields)
-    return InferenceEngineV2(TransformerLM(cfg), {
-        "dtype": dtype, "use_paged_kernel": True, "decode_window": 4,
-        **engine,
-        "state_manager": {"max_tracked_sequences": seqs,
-                          "max_ragged_batch_size": 256, "max_seq_len": 256,
-                          "block_size": 16, "num_blocks": 60}},
-        params=weights_ling.make(fields, SEED, dtype))
-
-
-def _prompts(lengths=(20, 70, 5), seed=0):
-    rng = np.random.default_rng(seed)
-    return [rng.integers(0, TOY["vocab_size"], n) for n in lengths]
-
-
-def _reference(prompt, fields=TOY):
-    return np.asarray(reference_ling.logits(
-        weights_ling.make(fields, SEED, "float32"), fields, prompt))
-
-
-def _err(got, want):
-    return float(np.abs(np.asarray(got, np.float32) - want).max()
-                 / np.abs(want).max())
+BLOCK = sb.BLOCKS["ling-3.0-flash"]
+globals().update(contract.clauses(BLOCK))     # the contract's cases of this row
+CONFIG, TOY, SEED = BLOCK.config, BLOCK.toy, BLOCK.seed
+reference_ling, weights_ling = BLOCK.reference, BLOCK.weights
 
 
 # ---------------------------------------------------------------------------
-# (a) the engine against the plain reference
+# (a) rows, slots and what a sequence keeps
 # ---------------------------------------------------------------------------
-@pytest.mark.parametrize("dtype,limit", [("float32", F32_TIGHT),
-                                         ("bfloat16", BF16_LIMIT)])
-def test_put_logits_match_the_reference(dtype, limit):
-    """Rows of 20, 70 (two chunks of the chunked form) and 5 tokens in
-    one ragged step."""
-    eng = _engine(dtype)
-    assert eng.attention_impl == "pallas:latent" and eng._has_state
-    prompts = _prompts()
-    got = eng.put([0, 1, 2], prompts)
-    for i, p in enumerate(prompts):
-        assert _err(got[i], _reference(p)[-1]) <= limit, i
-
-
-@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-def test_decode_through_pool_and_state_matches_the_reference(dtype):
-    """The ragged step leaves each row's state in its slot and its
-    latent rows in the pool; decode windows of 4 (launched one behind
-    the other: the state rides the cache) read and extend both. float32:
-    at EVERY generated position the engine's token is the reference's
-    best on the same prefix, so a state, a slot or a conv tap read wrong
-    shows. bf16: the served token's reference logit lies within the
-    bf16 limit of the best."""
-    eng = _engine(dtype)
-    prompts = _prompts()
-    outs = eng.generate(prompts, max_new_tokens=13, temperature=0.0,
-                        eos_token_id=None)
-    assert get_registry().family_total(
-        "inference_decode_windows_ahead_total") > 0
-    for prompt, out in zip(prompts, outs):
-        out = np.asarray(out)
-        assert len(out) == len(prompt) + 13
-        ref = _reference(out[:-1])[len(prompt) - 1:]
-        if dtype == "float32":
-            np.testing.assert_array_equal(out[len(prompt):], ref.argmax(-1))
-        else:
-            served = ref[np.arange(len(ref)), out[len(prompt):]]
-            gap = (ref.max(-1) - served) / np.abs(ref).max(-1)
-            assert gap.max() <= BF16_LIMIT
-
-
-def test_a_state_kept_in_bfloat16_is_the_lower_precision_control():
-    """``state_dtype`` bfloat16 rounds the state at every token: a
-    float32 engine with it reads far over the float32 limit after a few
-    dozen tokens, and the state leaves are half the bytes."""
-    prompts = _prompts((70,))
-    want = _reference(prompts[0])[-1]
-    sound, control = _engine("float32"), \
-        _engine("float32", state_dtype="bfloat16")
-    assert control.kv_cache["kda_state"].dtype == jnp.bfloat16
-    assert sound.kv_cache["kda_state"].dtype == jnp.float32
-    err = _err(control.put([0], prompts)[0], want)
-    assert err > 20 * F32_TIGHT, err
-    assert _err(sound.put([0], prompts)[0], want) <= F32_TIGHT
-
-
-def test_rows_in_one_step_are_the_rows_served_alone():
-    """Rows of unequal lengths in one ragged step, then a MIXED step (a
-    new prompt beside the first rows' decode tokens), give each row what
-    it gets served alone: rows mix nowhere, not in the convolution (a
-    row's taps stop at its first token), not in the chunks."""
-    prompts = _prompts((33, 64, 7))
-    late = _prompts((41,), seed=3)[0]
-    nxt = [11, 22, 33]
-    eng = _engine("float32")
-    first = eng.put([0, 1, 2], prompts)
-    mixed = eng.put([0, 1, 2, 3], [[t] for t in nxt] + [late])
-    for i, p in enumerate(prompts):
-        alone = _engine("float32")
-        a = alone.put([7], [p])
-        assert _err(first[i], np.asarray(a[0])) <= F32_TIGHT
-        b = alone.put([7], [[nxt[i]]])          # continues from its slot
-        assert _err(mixed[i], np.asarray(b[0])) <= F32_TIGHT
-    alone = _engine("float32")
-    assert _err(mixed[3], np.asarray(alone.put([9], [late])[0])) \
-        <= F32_TIGHT
-    # and against the reference: the decode token's logits
-    want = _reference(np.append(prompts[1], nxt[1]))[-1]
-    assert _err(mixed[1], want) <= F32_TIGHT
-
-
-def test_a_row_continues_from_its_slot():
+def test_a_row_continues_from_its_slot(lend):
     """A prompt fed in two put()s of 50 and 37 tokens (the second starts
     mid-chunk from the slot's state and the slot's last three conv
     inputs) is the prompt fed at once."""
-    prompt = _prompts((87,))[0]
-    eng = _engine("float32")
+    prompt = sb.prompts(BLOCK, (87,))[0]
+    want = sb.reference(BLOCK, prompt)[-1]
+    eng = lend()
     eng.put([4], [prompt[:50]])
     got = eng.put([4], [prompt[50:]])
-    assert _err(got[0], _reference(prompt)[-1]) <= F32_TIGHT
+    assert _err(got[0], want) <= F32_TIGHT
+    eng.flush(4)
     # one token at a time from the third on: the ragged step's rows of one
-    eng = _engine("float32")
-    eng.put([4], [prompt[:3]])
-    eng.put([4], [prompt[3:4]])
-    eng.put([4], [prompt[4:6]])
-    got = eng.put([4], [prompt[6:]])
-    assert _err(got[0], _reference(prompt)[-1]) <= F32_TIGHT
-
-
-def test_a_kept_sequence_holds_the_references_state():
-    """``generate(keep_sequences=True)`` leaves its rows tracked, every
-    token but the last fed; ``sequence_state`` reads a row's slot: the
-    leading linear layers' states are the reference's after the same
-    tokens, and the row goes on from there through ``put()``."""
-    eng = _engine("float32")
-    prompts = _prompts((20, 70))
-    outs = eng.generate(prompts, max_new_tokens=9, temperature=0.0,
-                        eos_token_id=None, keep_sequences=True)
-    assert eng.state_manager.state_slots_in_use() == 2
-    params = weights_ling.make(TOY, SEED, "float32")
-    for uid, out in enumerate(outs):
-        assert eng.query(uid)["seen_tokens"] == len(out) - 1
-        state = eng.sequence_state(uid)
-        assert state["kda_state"].shape == (7, 4, 16, 16)
-        assert state["kda_conv"].shape == (7, 3, 3 * 4 * 16)
-        want = np.asarray(reference_ling.leading_states(
-            params, TOY, out[:-1]))
-        assert want.shape == (3, 4, 16, 16)     # layers 0, 1 and 2
-        assert _err(state["kda_state"][:3], want) <= F32_TIGHT
-        # one token more than was fed: a different state
-        assert _err(state["kda_state"][:3], np.asarray(
-            reference_ling.leading_states(params, TOY, out))) > 1e-3
-    # the row continues from its slot with the token that was not fed
-    nxt = eng.put([0], [outs[0][-1:]])
-    assert _err(nxt[0], _reference(outs[0])[-1]) <= F32_TIGHT
-    for uid in (0, 1):
-        eng.flush(uid)
-    assert eng.state_manager.state_slots_in_use() == 0
-    with pytest.raises(KeyError, match="not tracked"):
-        eng.sequence_state(0)
+    eng.put([5], [prompt[:3]])
+    eng.put([5], [prompt[3:4]])
+    eng.put([5], [prompt[4:6]])
+    got = eng.put([5], [prompt[6:]])
+    assert _err(got[0], want) <= F32_TIGHT
+    eng.flush(5)
 
 
 @pytest.mark.parametrize("head_dim,leaf", [
     (16, (7, 5, 3, 1, 192)),            # TOY's: 192 channels, one row
     (32, (7, 5, 3, 3, 128))])           # whole lane blocks: rows of 128
 def test_sequence_state_reads_the_last_inputs_whatever_the_leafs_layout(
-        head_dim, leaf):
+        lend, head_dim, leaf):
     """``sequence_state(uid)["kda_conv"]`` is ``[linear layers, taps - 1,
     3 x heads x d_k]`` whether the leaf keeps an input as one row or as
     rows of 128 lanes, and after a prompt (the ragged step's XLA form)
     and decode steps (the one-token form) layer 0 holds the q, k and v
     projections of the last three tokens fed, oldest first."""
-    fields = {**TOY, "linear_head_dim": head_dim}
-    eng = _engine("float32", fields=fields)
+    fields = {"linear_head_dim": head_dim}
+    eng = lend(fields=fields)
     assert eng.kv_cache["kda_conv"].shape == leaf
-    out, = eng.generate(_prompts((9,)), max_new_tokens=5, temperature=0.0,
-                        eos_token_id=None, keep_sequences=True)
+    out, = eng.generate(sb.prompts(BLOCK, (9,)), max_new_tokens=5,
+                        temperature=0.0, eos_token_id=None,
+                        keep_sequences=True)
     conv = eng.sequence_state(0)["kda_conv"]
     D = TOY["num_heads"] * head_dim
     assert conv.shape == (7, 3, 3 * D)
     assert eng.sequence_state(0)["kda_state"].shape == (
         7, TOY["num_heads"], head_dim, head_dim)
-    params = weights_ling.make(fields, SEED, "float32")
+    params = sb.params(BLOCK, fields)
     lp = jax.tree.map(lambda a: a[0], params["kda_layers"])
     cfg = eng.model.cfg
     fed = np.asarray(out[:-1][-3:])
@@ -235,20 +91,21 @@ def test_sequence_state_reads_the_last_inputs_whatever_the_leafs_layout(
     eng.flush(0)
 
 
-def test_a_call_that_raises_keeps_nothing():
-    eng = _engine("float32")
+def test_a_call_that_raises_keeps_nothing(lend):
+    eng = lend()
     with pytest.raises(RuntimeError, match="not schedulable"):
-        eng.generate(_prompts((250,)), max_new_tokens=20, temperature=0.0,
-                     eos_token_id=None, keep_sequences=True)
+        eng.generate(sb.prompts(BLOCK, (250,)), max_new_tokens=20,
+                     temperature=0.0, eos_token_id=None, keep_sequences=True)
     assert eng.state_manager.state_slots_in_use() == 0
 
 
 def test_slots_are_freed_and_reused_without_leaking_state():
     """Two tracked sequences at most: a slot changes hands at flush and
     is NOT cleared; its next owner's first token starts from zeros."""
-    eng = _engine("float32", seqs=2)
+    # its own: slots nobody has held, and the gauge is the last one built's
+    eng = sb.engine(BLOCK, seqs=2)
     sm = eng.state_manager
-    a, b, c = _prompts((40, 25, 31), seed=9)
+    a, b, c = sb.prompts(BLOCK, (40, 25, 31), seed=9)
     eng.put([0, 1], [a, b])
     slots = {sm.seqs[u].state_slot for u in (0, 1)}
     assert slots == {1, 2} and sm.state_slots_in_use() == 2
@@ -260,7 +117,7 @@ def test_slots_are_freed_and_reused_without_leaking_state():
     assert np.abs(dirty[:, 1:]).max() > 0       # what the old rows left
     got = eng.put([2], [c])
     assert sm.seqs[2].state_slot in slots
-    assert _err(got[0], _reference(c)[-1]) <= F32_TIGHT
+    assert _err(got[0], sb.reference(BLOCK, c)[-1]) <= F32_TIGHT
     eng.flush(1), eng.flush(2)
     assert sm.state_slots_in_use() == 0
     reg = get_registry()
@@ -270,9 +127,9 @@ def test_slots_are_freed_and_reused_without_leaking_state():
         if k.startswith("kda_"))
 
 
-def test_the_generation_loop_reuses_slots_across_calls():
-    eng = _engine("float32")
-    prompts = _prompts((9, 17))
+def test_the_generation_loop_reuses_slots_across_calls(lend):
+    eng = lend()
+    prompts = sb.prompts(BLOCK, (9, 17))
     first = eng.generate(prompts, max_new_tokens=6, temperature=0.0,
                          eos_token_id=None)
     again = eng.generate(prompts, max_new_tokens=6, temperature=0.0,
@@ -667,36 +524,38 @@ def test_the_share_is_counted_over_held_experts_only():
     assert list(whole[:3]) == [1.0, 8.0, 7.0]
 
 
-def test_the_engine_counts_held_rows():
+def test_the_engine_counts_held_rows(lend):
     reg = get_registry()
-    eng = _engine("float32")            # registers the families
+    eng = lend()                        # registers the families
     before = reg.get("moe_routed_rows_total").labels(
         program="ragged_step").value
     rows0 = reg.get("inference_state_rows_total").labels(
         program="ragged_step").value
-    eng.put([0, 1], _prompts((12, 8)))
+    eng.put([0, 1], sb.prompts(BLOCK, (12, 8)))
     routed = reg.get("moe_routed_rows_total").labels(
         program="ragged_step").value - before
     # 20 tokens x 4 picks x 6 expert layers, of which a share is held
     assert 0 < routed < 20 * 4 * 6
     assert reg.get("inference_state_rows_total").labels(
         program="ragged_step").value - rows0 == 2
+    eng.flush(0), eng.flush(1)
 
 
-def test_the_engine_counts_the_steps_the_chunk_kernel_took(monkeypatch):
+def test_the_engine_counts_the_steps_the_chunk_kernel_took(lend,
+                                                           monkeypatch):
     """``inference_linear_chunk_kernel_steps_total`` follows
     ``chunk_kernel_serves`` a ragged step: 0 here (the CPU, toy widths:
     the XLA form), one a step where it says yes; and what it asks is a
     TPU, head widths of whole lane blocks and heads in whole steps."""
     from deepspeed_tpu.inference.v2 import engine_v2
     reg = get_registry()
-    eng = _engine("float32")
+    eng = lend()
     steps = reg.get("inference_linear_chunk_kernel_steps_total")
     before = steps.value
-    eng.put([0, 1], _prompts((12, 8)))
+    eng.put([0, 1], sb.prompts(BLOCK, (12, 8)))
     assert steps.value == before
     monkeypatch.setattr(engine_v2, "chunk_kernel_serves", lambda leaf: True)
-    eng.put([2], _prompts((9,)))
+    eng.put([2], sb.prompts(BLOCK, (9,)))
     assert steps.value == before + 1
     eng.flush(0), eng.flush(1), eng.flush(2)
 
@@ -713,7 +572,7 @@ def test_the_engine_counts_the_steps_the_chunk_kernel_took(monkeypatch):
     assert not la.chunk_kernel_serves(leaf(4, 128, 128))     # half a tile
 
 
-def test_the_engine_counts_the_steps_the_conv_kernel_took(monkeypatch):
+def test_the_engine_counts_the_steps_the_conv_kernel_took(lend, monkeypatch):
     """``inference_linear_conv_kernel_steps_total`` follows
     ``conv_kernel_serves`` a decode step LAUNCHED: 0 here (the CPU, toy
     widths: the XLA form), a window's steps a fused window and one a
@@ -722,10 +581,10 @@ def test_the_engine_counts_the_steps_the_conv_kernel_took(monkeypatch):
     (16, 128) tiles."""
     from deepspeed_tpu.inference.v2 import engine_v2
     reg = get_registry()
-    eng = _engine("float32")            # decode_window 4
+    eng = lend()                        # decode_window 4
     steps = reg.get("inference_linear_conv_kernel_steps_total")
     before = steps.value
-    prompts = _prompts((12, 8))
+    prompts = sb.prompts(BLOCK, (12, 8))
     eng.generate(prompts, max_new_tokens=9, temperature=0.0,
                  eos_token_id=None)
     assert steps.value == before
@@ -752,7 +611,7 @@ def test_the_engine_counts_the_steps_the_conv_kernel_took(monkeypatch):
 
 
 def test_the_engine_counts_the_steps_the_attention_kernels_took_one_token(
-        monkeypatch):
+        lend, monkeypatch):
     """``inference_attention_one_token_steps_total`` follows
     ``one_token_tile_serves`` a decode step LAUNCHED: 0 here (the
     CPU: the latent layer's decode launches are the gathering
@@ -764,10 +623,10 @@ def test_the_engine_counts_the_steps_the_attention_kernels_took_one_token(
     ra = importlib.import_module(      # the package exports the function
         "deepspeed_tpu.inference.v2.kernels.ragged_attention")
     reg = get_registry()
-    eng = _engine("float32")            # decode_window 4
+    eng = lend()                        # decode_window 4
     steps = reg.get("inference_attention_one_token_steps_total")
     before = steps.value
-    prompts = _prompts((12, 8))
+    prompts = sb.prompts(BLOCK, (12, 8))
     eng.generate(prompts, max_new_tokens=9, temperature=0.0,
                  eos_token_id=None)
     assert steps.value == before
@@ -849,10 +708,7 @@ def test_the_published_pattern_and_sizes():
 
 
 def test_the_old_trees_are_what_they_were():
-    joyai = json.loads((REPO / "benchmark/configs/joyai-llm-flash.json")
-                       .read_text())
-    cfg = TransformerConfig(**harness.merge(joyai["fields"],
-                                            joyai["toy_fields"]))
+    cfg = TransformerConfig(**sb.BLOCKS["joyai-llm-flash"].toy)
     assert not cfg.has_state and cfg.layer_kinds == ("mla",) * 3
     assert paged_model._layer_runs(cfg) == [("mla", False, 0, 1),
                                             ("mla", True, 1, 2)]
@@ -884,33 +740,10 @@ def test_the_old_trees_are_what_they_were():
 # ---------------------------------------------------------------------------
 # what is refused
 # ---------------------------------------------------------------------------
-@pytest.mark.parametrize("engine,word", [
-    ({"tensor_parallel_size": 2}, "tensor_parallel_size"),
-    ({"expert_parallel_size": 2}, "expert-parallel"),
-    ({"quant_bits": 8}, "quant_bits"),
-    ({"max_lora_adapters": 2}, "max_lora_adapters"),
-    ({"kv_quant": True}, "kv_quant"),
-    ({"state_manager": {"enable_prefix_caching": True}},
-     "no recurrent state"),
-    ({"state_manager": {"enable_prefix_caching": True,
-                        "enable_kv_spill": True}}, "no state slot")])
-def test_the_engine_refuses_at_construction(engine, word):
-    cfg = TransformerConfig(**TOY)
-    with pytest.raises((NotImplementedError, AssertionError), match=word):
-        InferenceEngineV2(TransformerLM(cfg), {"dtype": "float32", **engine})
-
-
-def test_what_else_is_refused():
-    cfg = TransformerConfig(**TOY)
-    eng = _engine("float32")
-    with pytest.raises(NotImplementedError, match="speculative"):
-        eng.generate(_prompts((4,)), max_new_tokens=2, speculative=True)
-    with pytest.raises(NotImplementedError, match="draft"):
-        eng.load_draft_model(TransformerLM(cfg))
+def test_what_else_is_refused(lend):
+    """(Speculation, a draft model and the other forward: the contract's.)"""
     with pytest.raises(NotImplementedError, match="state slot"):
-        eng.state_manager.adopt_sequence(5, 1, 3, [1, 2, 3])
-    with pytest.raises(NotImplementedError, match="linear_attn_period"):
-        TransformerLM(cfg).forward_hidden({}, jnp.zeros((1, 4), jnp.int32))
+        lend().state_manager.adopt_sequence(5, 1, 3, [1, 2, 3])
     with pytest.raises(ValueError, match="state_dtype"):
         InferenceEngineV2(TransformerLM(TransformerConfig(
             vocab_size=64, hidden_size=32, intermediate_size=64,

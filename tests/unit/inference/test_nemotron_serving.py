@@ -1,29 +1,21 @@
-"""The ``nemotron_h`` block served: layers of ONE sub-layer behind one norm
-(a Mamba-2 mixer whose B and C are a GROUP of heads' | an expert layer of
-two-matrix relu^2 experts that holds a SHARE of them | per-head attention
-without positions), state slots AND a per-head pool in one cache, at toy
-widths on the CPU, against the benchmark's plain reference
-(``benchmark/reference_nemotron.py``: float32, a token at a time through
-the recurrence, no cache).
+"""The ``nemotron_h`` block's own: its pattern as the source spells it
+(layers of ONE sub-layer behind one norm), patterns that start with
+experts and end with a mixer, the recurrence under GROUPS of heads and
+its kernels against the token scan, the group-wise gated norm, the
+relu^2 dispatch, the router and the shares of the experts, and the four
+older pattern configurations' programs as the jaxprs they were. What
+every served block is held to (the engine against the plain reference
+``benchmark/reference_nemotron.py``, its controls, its refusals) is the
+contract's (``test_served_block_contract.py``), on this block's row of
+``served_blocks.py``, where the limits are justified.
 
-Tolerances. A float32 engine differs from the reference by the order of
-its sums and the chunked form of the recurrence (matmuls over a chunk's
-pairs in place of rank-one updates a token): 2e-5 of the largest logit
-is the other blocks' float32 limit and over five times what it reads
-(3e-6; the state 6e-7). A state kept in bfloat16 and a dropped shared
-expert each read over five times it (the test below shows both). The
-toy's hard top-4 of 16 under a bf16 engine swaps an expert for its
-runner-up against the float32 reference (0.05 to 0.7 of the largest
-logit on the granite toy, whose router is this one's size), which is
-why the cell's rehearsal runs a float32 engine; no bf16 engine is held
-here. Two forms of one recurrence, both float32: 2e-5 of the largest
-output (they read 2e-6).
+Two forms of one recurrence, both float32: 2e-5 of the largest output
+(they read 2e-6).
 """
 
 import hashlib
 import json
 import re
-from pathlib import Path
 
 import numpy as np
 import pytest
@@ -31,60 +23,30 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from benchmark import reference_nemotron, weights_nemotron
-from benchmark import run as harness
-from deepspeed_tpu.inference.v2 import InferenceEngineV2, paged_model
+from deepspeed_tpu.inference.v2 import paged_model
 from deepspeed_tpu.inference.v2.kernels import state_space as ss
 from deepspeed_tpu.models import TransformerConfig, TransformerLM
 from deepspeed_tpu.moe import sharded_moe
 from deepspeed_tpu.telemetry import get_registry
+from tests.unit.inference import served_block_contract as contract
+from tests.unit.inference import served_blocks as sb
+from tests.unit.inference import state_space_cases as cases
+from tests.unit.inference.served_blocks import (F32 as F32_TIGHT, REPO,
+                                                err as _err)
 
-REPO = Path(__file__).resolve().parents[3]
-CONFIG = json.loads(
-    (REPO / "benchmark/configs/nemotron-3-nano-30b-a3b.json").read_text())
-TOY = harness.merge(CONFIG["fields"], CONFIG["toy_fields"])
-F32_TIGHT = 2e-5
-SEED = 5
-
-
-def _engine(fields=TOY, seqs=4, budget=256, **engine):
-    cfg = TransformerConfig(**fields)
-    return InferenceEngineV2(TransformerLM(cfg), {
-        "dtype": "float32", "use_paged_kernel": True, "decode_window": 4,
-        **engine,
-        "state_manager": {"max_tracked_sequences": seqs,
-                          "max_ragged_batch_size": budget,
-                          "max_seq_len": 256, "block_size": 16,
-                          "num_blocks": 60}},
-        params=weights_nemotron.make(fields, SEED, "float32"))
-
-
-def _prompts(lengths=(20, 70, 5), seed=0):
-    rng = np.random.default_rng(seed)
-    return [rng.integers(0, TOY["vocab_size"], n) for n in lengths]
-
-
-def _params(fields=TOY):
-    return weights_nemotron.make(fields, SEED, "float32")
-
-
-def _reference(prompt, fields=TOY):
-    return np.asarray(reference_nemotron.logits(_params(fields), fields,
-                                                prompt))
-
-
-def _err(got, want):
-    return float(np.abs(np.asarray(got, np.float32) - want).max()
-                 / np.abs(want).max())
+BLOCK = sb.BLOCKS["nemotron-3-nano-30b-a3b"]
+globals().update(contract.clauses(BLOCK))     # the contract's cases of this row
+CONFIG, TOY = BLOCK.config, BLOCK.toy
+reference_nemotron, weights_nemotron = BLOCK.reference, BLOCK.weights
 
 
 def _cut(pattern):
-    """The toy with another pattern of as many layers."""
-    return {**TOY, "layer_types": list(pattern), "num_layers": len(pattern)}
+    """Another pattern of as many layers, laid on the toy."""
+    return {"layer_types": list(pattern), "num_layers": len(pattern)}
 
 
 # ---------------------------------------------------------------------------
-# (a) the configuration, and the engine against the plain reference
+# (a) the configuration, and other patterns against the plain reference
 # ---------------------------------------------------------------------------
 def test_the_pattern_is_written_down_as_the_source_spells_it():
     """The first sixteen characters of ``hybrid_override_pattern``,
@@ -130,6 +92,13 @@ def test_the_pattern_is_written_down_as_the_source_spells_it():
         == jax.tree.map(lambda a: a.shape, shapes)
 
 
+def test_the_toys_cache_keeps_a_leaf_a_kind_by_its_layers(lend):
+    """Seven mixers and two attention layers of the toy's sixteen."""
+    eng = lend()
+    assert eng.kv_cache["ssm_state"].shape[0] == 7 \
+        and eng.kv_cache["k_full"].shape[0] == 2
+
+
 @pytest.mark.parametrize("fields,word", [
     ({"mamba_n_groups": 3}, "whole multiple of mamba_n_groups"),
     ({"mamba_n_groups": 0}, "whole multiple of mamba_n_groups"),
@@ -142,110 +111,51 @@ def test_configurations_the_block_does_not_describe_are_refused(fields, word):
         TransformerConfig(**{**TOY, **fields})
 
 
-def test_put_logits_match_the_reference_in_one_step_and_in_chunks():
-    """Rows of 20, 70 and 5 tokens in one ragged step; the same rows
-    with a step's budget of 32 tokens (a row's prompt in chunks, its
-    state carried in its slot and its keys in the pool)."""
-    prompts = _prompts()
-    reg = get_registry()
-    for budget in (256, 32):
-        eng = _engine(budget=budget)
-        assert set(eng.kv_cache) == {"k_full", "v_full", "ssm_state",
-                                     "ssm_conv"}
-        assert eng.kv_cache["ssm_state"].shape[0] == 7 \
-            and eng.kv_cache["k_full"].shape[0] == 2
-        assert reg.get("inference_ssm_groups").value == 2
-        before = reg.family_total("inference_prefill_chunks_total")
-        got = eng.put([0, 1, 2], prompts)
-        chunks = reg.family_total("inference_prefill_chunks_total") - before
-        # a put() that fits one step counts no chunk; 95 tokens under a
-        # budget of 32 (a row's share 8) go in as eight steps
-        assert chunks == (0 if budget == 256 else 8), chunks
-        for i, p in enumerate(prompts):
-            assert _err(got[i], _reference(p)[-1]) <= F32_TIGHT, (budget, i)
-
-
-def test_decode_through_slot_and_pool_matches_the_reference():
-    """The ragged step leaves each row's state in its slot and its keys
-    and values in the pool; decode windows of 4 read and extend both. At
-    EVERY generated position the engine's token is the reference's best
-    on the same prefix and the slot then holds the reference's state
-    (layer 0, ahead of every routed expert, and the next two), so a
-    state, a group, a slot, a conv tap or a page read wrong shows."""
-    eng = _engine()
-    prompts = _prompts((37, 20, 70))
-    reg = get_registry()
-    before = {form: reg.get("moe_form_launches_total").labels(
-        program="decode_window", form=form).value
-        for form in ("relu2", "swiglu")}
-    outs = eng.generate(prompts, max_new_tokens=13, temperature=0.0,
-                        eos_token_id=None, keep_sequences=True)
-    after = {form: reg.get("moe_form_launches_total").labels(
-        program="decode_window", form=form).value
-        for form in ("relu2", "swiglu")}
-    # 12 decode steps x 7 expert layers ran the relu2 form, none the other
-    assert after["relu2"] - before["relu2"] == 12 * 7
-    assert after["swiglu"] == before["swiglu"]
-    assert eng.state_manager.state_slots_in_use() == 3
-    for uid, (prompt, out) in enumerate(zip(prompts, outs)):
-        out = np.asarray(out)
-        assert len(out) == len(prompt) + 13
-        ref = _reference(out[:-1])[len(prompt) - 1:]
-        np.testing.assert_array_equal(out[len(prompt):], ref.argmax(-1))
-        state = eng.sequence_state(uid)
-        assert state["ssm_state"].shape == (7, 8, 16, 32)
-        assert state["ssm_conv"].shape == (7, 3, 8 * 16 + 2 * 2 * 32)
-        want = np.asarray(reference_nemotron.leading_states(
-            _params(), TOY, out[:-1], layers=3))
-        for layer in range(3):
-            err = np.linalg.norm(state["ssm_state"][layer] - want[layer]) \
-                / np.linalg.norm(want[layer])
-            assert err <= F32_TIGHT, (uid, layer, err)
-        eng.flush(uid)
-    assert eng.state_manager.state_slots_in_use() == 0
-
-
-def test_the_tolerance_is_tight_enough_for_its_controls():
-    """A state kept in bfloat16 (``state_dtype``, the cell's control)
-    and a shared expert that is dropped each fail the float32 limit that
-    the engine as it stands passes."""
-    prompts = _prompts((70,))
-    want = _reference(prompts[0])[-1]
-    assert _err(_engine().put([0], prompts)[0], want) <= F32_TIGHT
-    control = _engine(state_dtype="bfloat16", budget=32)
-    assert control.kv_cache["ssm_state"].dtype == jnp.bfloat16
-    # the state is rounded where a launch hands it on: three chunks
-    assert _err(control.put([0], prompts)[0], want) > 5 * F32_TIGHT
-    dropped = _engine()
-    dropped.params["layers"]["shared_down"] = jnp.zeros_like(
-        dropped.params["layers"]["shared_down"])
-    assert _err(dropped.put([0], prompts)[0], want) > 5 * F32_TIGHT
-
-
 @pytest.mark.parametrize("pattern", [
     ("moe", "mamba", "attention", "moe", "mamba"),      # ends: a lone mixer
     ("moe", "moe", "mamba", "mamba", "attention", "attention", "moe")])
-def test_patterns_that_start_with_experts_and_end_with_a_mixer(pattern):
+def test_patterns_that_start_with_experts_and_end_with_a_mixer(lend, pattern):
     """A pattern whose first layer is an expert layer (no mixer ahead of
     it, and no state-space layer ahead of every routed expert), runs of
-    two of a kind, and a last layer that is a lone mixer."""
+    two of a kind, and a last layer that is a lone mixer; the cache keeps
+    a leaf a kind by ITS layers, and the decode windows count the relu^2
+    form a step an expert layer and never the other."""
     fields = _cut(pattern)
-    cfg = TransformerConfig(**fields)
+    cfg = TransformerConfig(**{**TOY, **fields})
+    kinds = cfg.layer_kinds
     runs = paged_model._layer_runs(cfg)
     assert sum(n for *_, n in runs) == len(pattern)
-    assert [k for k, *_ in runs] == [k for i, k in enumerate(
-        cfg.layer_kinds) if i == 0 or cfg.layer_kinds[i - 1] != k]
-    eng = _engine(fields, budget=32)
-    prompts = _prompts((40, 9))
-    got = eng.put([0, 1], prompts)
+    assert [k for k, *_ in runs] == [k for i, k in enumerate(kinds)
+                                     if i == 0 or kinds[i - 1] != k]
+    eng = lend(fields=fields, budget=32)
+    reg = get_registry()
+    assert eng.kv_cache["ssm_state"].shape[0] == kinds.count("ssm") \
+        and eng.kv_cache["k_full"].shape[0] == kinds.count("full")
+    assert reg.get("inference_ssm_groups").value == 2
+    prompts = sb.prompts(BLOCK, (40, 9))
+    uids = sb.uids(2)
+    got = eng.put(uids, prompts)
     for i, p in enumerate(prompts):
-        assert _err(got[i], _reference(p, fields)[-1]) <= F32_TIGHT, i
-    for uid in (0, 1):
+        assert _err(got[i], sb.reference(BLOCK, p, fields)[-1]) \
+            <= F32_TIGHT, i
+    for uid in uids:
         eng.flush(uid)
+
+    def launches():
+        return {form: reg.get("moe_form_launches_total").labels(
+            program="decode_window", form=form).value
+            for form in ("relu2", "swiglu")}
+
+    before = launches()
     outs = eng.generate(prompts, max_new_tokens=6, temperature=0.0,
-                        eos_token_id=None)
+                        eos_token_id=None, uids=sb.uids(2))
+    after = launches()
+    # 5 decode steps x the pattern's expert layers ran the relu2 form
+    assert after["relu2"] - before["relu2"] == 5 * kinds.count("moe")
+    assert after["swiglu"] == before["swiglu"]
     for prompt, out in zip(prompts, outs):
-        ref = _reference(np.asarray(out)[:-1], fields)[len(prompt) - 1:]
+        ref = sb.reference(BLOCK, np.asarray(out)[:-1],
+                           fields)[len(prompt) - 1:]
         np.testing.assert_array_equal(np.asarray(out)[len(prompt):],
                                       ref.argmax(-1))
 
@@ -253,61 +163,6 @@ def test_patterns_that_start_with_experts_and_end_with_a_mixer(pattern):
 # ---------------------------------------------------------------------------
 # (b) the two forms of the recurrence under groups, and their kernels
 # ---------------------------------------------------------------------------
-def _scan(x, dt, a, b, c, s0, groups):
-    """The recurrence a token at a time: x [T, nh, p], dt [T, nh], b and
-    c [T, groups * n], s0 [nh, p, n]; head h reads group h // (nh /
-    groups)."""
-    nh = x.shape[1]
-    of = jnp.arange(nh) // (nh // groups)
-
-    def token(s, t):
-        xt, dtt, bt, ct = t
-        bt, ct = (v.reshape(groups, -1)[of] for v in (bt, ct))   # [nh, n]
-        s = jnp.exp(dtt * a)[:, None, None] * s \
-            + (dtt[:, None] * xt)[:, :, None] * bt[:, None, :]
-        return s, jnp.einsum("hpn,hn->hp", s, ct)
-    return jax.lax.scan(token, s0, (x, dt, b, c))
-
-
-def _case(nh, p, n, groups, lengths, T, seed=0, slots=6):
-    rng = np.random.default_rng(seed)
-    C = nh * p
-    f = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)
-    dt = jnp.asarray(np.exp(rng.uniform(np.log(1e-3), np.log(2.0),
-                                        (T, nh))), jnp.float32)
-    counts = jnp.asarray(lengths, jnp.int32)
-    return dict(
-        leaf=f(*ss.state_leaf_shape(2, slots, C, n)), layer=jnp.int32(1),
-        slots=jnp.asarray([i % (slots - 1) + 1 if n_ else 0
-                           for i, n_ in enumerate(lengths)], jnp.int32),
-        fresh=jnp.asarray([i % 2 == 0 for i in range(len(lengths))]),
-        starts=jnp.cumsum(counts) - counts, counts=counts,
-        xbc=f(T, C + 2 * groups * n), dt=dt,
-        a=-jnp.asarray(rng.uniform(1, 16, (nh,)), jnp.float32))
-
-
-def _against_the_scan(case, y, leaf, nh, groups):
-    n = case["leaf"].shape[3]
-    C = case["xbc"].shape[1] - 2 * groups * n
-    x, b, c = (case["xbc"][:, :C], case["xbc"][:, C:C + groups * n],
-               case["xbc"][:, C + groups * n:])
-    with jax.default_matmul_precision("highest"):
-        for r, n_ in enumerate(np.asarray(case["counts"])):
-            if not n_:
-                continue
-            at = slice(int(case["starts"][r]), int(case["starts"][r]) + n_)
-            slot = case["slots"][r]
-            s0 = jnp.where(case["fresh"][r], 0.0,
-                           ss.heads_of(case["leaf"][1, slot], nh))
-            s1, want = _scan(x[at].reshape(n_, nh, -1), case["dt"][at],
-                             case["a"], b[at], c[at], s0, groups)
-            assert _err(y[at], np.asarray(want).reshape(n_, -1)) \
-                <= F32_TIGHT, r
-            assert _err(ss.heads_of(leaf[1, slot], nh),
-                        np.asarray(s1)) <= F32_TIGHT, r
-    np.testing.assert_array_equal(leaf[0], case["leaf"][0])
-
-
 @pytest.mark.parametrize("groups", [1, 2, 8])
 @pytest.mark.parametrize("nh,p,n", [(8, 16, 32), (16, 8, 16)])
 def test_chunked_form_is_the_token_scan_at_any_group_count(nh, p, n, groups):
@@ -315,9 +170,9 @@ def test_chunked_form_is_the_token_scan_at_any_group_count(nh, p, n, groups):
     slots, through ``ssm_chunked`` with B and C a group of heads: each
     row's outputs and final state are the token scan's. (At these widths
     a lane block holds every group: the XLA forms serve that.)"""
-    case = _case(nh, p, n, groups, (5, 0, 37, 16, 1), 62)
+    case = cases.case(nh, p, n, (5, 0, 37, 16, 1), 62, groups)
     y, leaf = ss.ssm_chunked(**case, chunk=16)
-    _against_the_scan(case, y, leaf, nh, groups)
+    cases.against_the_scan(case, y, leaf, nh, groups)
 
 
 @pytest.mark.parametrize("groups,nh,p,lengths,T,chunk", [
@@ -329,23 +184,9 @@ def test_chunk_kernel_is_the_token_scan_under_groups(groups, nh, p, lengths,
     """``ssm_chunk_fwd`` under the TPU interpreter: a grid step (four
     lane blocks, 512 channels) takes ITS group's B and C out of the one
     token buffer."""
-    case = _case(nh, p, 128, groups, lengths, T)
+    case = cases.case(nh, p, 128, lengths, T, groups)
     y, leaf = ss.ssm_chunk_fwd(**case, chunk=chunk, interpret=True)
-    _against_the_scan(case, y, leaf, nh, groups)
-
-
-def _one_token_case(groups, nh, p, n):
-    """(leaf, layer, slots, fresh, x, dt, a, b, c) of three rows, the
-    middle one fresh between two kept ones."""
-    rng = np.random.default_rng(1)
-    C, N = nh * p, 3
-    f = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)
-    leaf0 = f(*ss.state_leaf_shape(2, 5, C, n))
-    x, b, c = f(N, C), f(N, groups * n), f(N, groups * n)
-    dt = jnp.asarray(rng.uniform(0.01, 1.0, (N, nh)), jnp.float32)
-    a = -jnp.asarray(rng.uniform(1, 16, (nh,)), jnp.float32)
-    return (leaf0, jnp.int32(1), jnp.asarray([2, 4, 1]),
-            jnp.asarray([False, True, False]), x, dt, a, b, c)
+    cases.against_the_scan(case, y, leaf, nh, groups)
 
 
 @pytest.mark.parametrize("groups,nh,p,n", [
@@ -357,44 +198,14 @@ def _one_token_case(groups, nh, p, n):
     (1, 128, 64, 128),      # 64 lane blocks: a group four grid steps
     (2, 128, 64, 128)])     # 64 lane blocks: a group two grid steps
 def test_one_token_forms_are_the_token_scan_under_groups(groups, nh, p, n):
-    """``ssm_step`` and, where the channels are whole lane blocks, the
-    kernel ``ssm_state_update`` (interpreted) on three rows' slots, a
-    fresh row between two kept ones: one token of the scan with each
-    head reading ITS group's B and C, the other slots and the other
-    layer untouched. The kernel takes the pairs a group a row and
-    spreads them in VMEM, so the cases are the block shapes that makes
-    delicate: a grid step that spans several groups (8 groups over 8
-    and over 32 lane blocks), a group that spans several grid steps (1
-    and 2 groups over 64), fewer lane blocks than a step's 16. Its
-    state and ``y`` are ``ssm_step``'s too (the same float32 operations
-    on the same values, a compiled product and sum rounding once where
-    the eager ones round twice), and the leaf goes in aliased to the
-    leaf that comes out."""
-    args = _one_token_case(groups, nh, p, n)
-    leaf0, _, slots, fresh, x, dt, a, b, c = args
-    C, N = nh * p, x.shape[0]
-    forms = [ss.ssm_step] + [
-        lambda *args: ss.ssm_state_update(*args, interpret=True)
-    ] * (C % 1024 == 0)
-    out = []
-    for form in forms:
-        y, leaf = form(*args)
-        out.append((y, leaf))
-        for r in range(N):
-            s0 = jnp.where(fresh[r], 0.0, ss.heads_of(leaf0[1, slots[r]], nh))
-            s1, want = _scan(x[r:r + 1].reshape(1, nh, p), dt[r:r + 1], a,
-                             b[r:r + 1], c[r:r + 1], s0, groups)
-            assert _err(y[r], np.asarray(want).reshape(C)) <= F32_TIGHT
-            assert _err(ss.heads_of(leaf[1, slots[r]], nh),
-                        np.asarray(s1)) <= F32_TIGHT
-        np.testing.assert_array_equal(leaf[0], leaf0[0])
-        np.testing.assert_array_equal(leaf[1, 3], leaf0[1, 3])
-    if len(out) == 2:
-        for step, kernel in zip(*out):
-            assert _err(kernel, np.asarray(step)) <= F32_TIGHT
-        (call,) = [e for e in jax.make_jaxpr(forms[1])(*args).eqns
-                   if e.primitive.name == "pallas_call"]
-        assert call.params["input_output_aliases"] == ((3, 0),)
+    """``cases.one_token_forms`` with B and C a group of heads', the
+    kernel where the channels are whole (8, 128) tiles. The kernel takes
+    the pairs a group a row and spreads them in VMEM, so the cases are
+    the block shapes that makes delicate: a grid step that spans several
+    groups (8 groups over 8 and over 32 lane blocks), a group that spans
+    several grid steps (1 and 2 groups over 64), fewer lane blocks than
+    a step's 16."""
+    cases.one_token_forms(nh, p, n, groups, whole=1024)
 
 
 def test_the_kernels_say_no_where_a_lane_block_would_straddle_groups(
@@ -416,14 +227,15 @@ def test_the_kernels_say_no_where_a_lane_block_would_straddle_groups(
     assert not ss.state_kernel_serves(leaf, 3)
 
 
-def test_the_gated_norm_is_a_groups():
+def test_the_gated_norm_is_a_groups(lend):
     """``y * silu(z)`` normed over each GROUP's channels under one
     weight of all the channels: a pattern of one mamba layer at two
     groups against the reference's mixer, whose group-wise norm is not
     the norm over all the channels."""
-    fields = _cut(("mamba", "moe"))
-    params = _params(fields)
-    prompt = _prompts((24,))[0]
+    cut = _cut(("mamba", "moe"))
+    fields = {**TOY, **cut}
+    params = sb.params(BLOCK, cut)
+    prompt = sb.prompts(BLOCK, (24,))[0]
     lp = jax.tree.map(lambda a: a[0], params["ssm_layers"])
     x = params["embed"][prompt]
     w = np.asarray(lp["gate_norm"])
@@ -439,8 +251,11 @@ def test_the_gated_norm_is_a_groups():
         hidden, _ = reference_nemotron._layers(params, fields, prompt,
                                                layers=1)
     np.testing.assert_allclose(hidden, x + want, atol=1e-5)
-    assert _err(_engine(fields).put([0], [prompt])[0],
-                _reference(prompt, fields)[-1]) <= F32_TIGHT
+    uid, = sb.uids(1)
+    eng = lend(fields=cut)
+    assert _err(eng.put([uid], [prompt])[0],
+                sb.reference(BLOCK, prompt, cut)[-1]) <= F32_TIGHT
+    eng.flush(uid)
 
 
 # ---------------------------------------------------------------------------
@@ -510,83 +325,9 @@ def test_the_router_is_the_references():
 
 
 def test_the_shares_add_up_to_the_uncut_layer():
-    """The guide's test of a cut in experts: the routed output of the
-    share that holds experts 0 .. E/2 - 1 plus that of the share that
-    holds the rest, the shared expert counted once, is the uncut
-    reference's expert layer; and the program's expert layer on either
-    share is that share's reference."""
-    E = TOY["moe_num_experts"]
-    whole = {**TOY, "moe_experts_held": E}
-    stack = _expert_stack(whole)
-    rng = np.random.default_rng(4)
-    x = jnp.asarray(rng.normal(size=(24, TOY["hidden_size"])), jnp.float32)
-    experts, half = ("e_up", "e_down"), E // 2
-
-    def share(first):
-        cut = {k: v[:, first:first + half] if k in experts else v
-               for k, v in stack.items()}
-        return cut, {**TOY, "moe_experts_held": half,
-                     "moe_experts_first": first}
-
-    with jax.default_matmul_precision("highest"):
-        routed, shared = reference_nemotron.expert_layer(x, stack, 3, whole)
-        parts = []
-        for first in (0, half):
-            cut, fields = share(first)
-            r, s = reference_nemotron.expert_layer(x, cut, 3, fields)
-            np.testing.assert_allclose(s, shared, atol=1e-6)
-            parts.append(r)
-            cfg = TransformerConfig(**fields)
-            lp = {k: v[3] for k, v in cut.items()}
-            hn = paged_model._norm(cfg, x, lp["mlp_norm"])
-            got, _ = paged_model._moe_routed(
-                cfg, lp, hn, router_precision=jax.lax.Precision.HIGHEST)
-            np.testing.assert_allclose(
-                got, r + s, atol=F32_TIGHT * float(jnp.abs(r + s).max()))
-        assert float(jnp.abs(parts[0]).max()) > 0 \
-            and float(jnp.abs(parts[1]).max()) > 0
-        np.testing.assert_allclose(
-            parts[0] + parts[1], routed,
-            atol=F32_TIGHT * float(jnp.abs(routed).max()))
-
-
-# ---------------------------------------------------------------------------
-# (d) what is not served is refused, each by the new descriptions' names
-# ---------------------------------------------------------------------------
-@pytest.mark.parametrize("engine,word", [
-    ({"tensor_parallel_size": 2}, "tensor_parallel_size"),
-    ({"max_lora_adapters": 2}, "max_lora_adapters"),
-    ({"kv_quant": True}, "kv_quant"),
-    ({"state_manager": {"enable_prefix_caching": True}},
-     "wrong recurrent state")])
-def test_refusals_at_construction(engine, word):
-    cfg = TransformerConfig(**TOY)
-    with pytest.raises(NotImplementedError,
-                       match="state-space layers.*" + word):
-        InferenceEngineV2(TransformerLM(cfg), {"dtype": "float32", **engine})
-
-
-def test_speculation_handoff_and_the_other_forwards_refuse_by_name():
-    eng = _engine()
-    prompts = _prompts((12,))
-    with pytest.raises(NotImplementedError, match="verify pass"):
-        eng.generate(prompts, max_new_tokens=2, speculative=True)
-    eng.put([7], prompts)
-    from deepspeed_tpu.inference.v2.serve import handoff
-    with pytest.raises(NotImplementedError, match="no state slot"):
-        handoff.export_sequence(eng, 7)
-    model = TransformerLM(TransformerConfig(**TOY))
-    params = model.init_params(jax.random.PRNGKey(0))
-    batch = {"input_ids": jnp.zeros((1, 8), jnp.int32)}
-    names = ("'moe' layers", "mamba_n_groups", "moe_expert_form='relu2'")
-    for call in (lambda: model.apply(params, batch),
-                 lambda: model.forward_hidden(params, batch["input_ids"]),
-                 lambda: model.forward_cached(params, batch["input_ids"],
-                                              None, 0)):
-        with pytest.raises(NotImplementedError) as e:
-            call()
-        for name in names:
-            assert name in str(e.value), (name, str(e.value))
+    """``cases.shares_add_up`` on a stack of all 16 experts."""
+    stack = _expert_stack({**TOY, "moe_experts_held": TOY["moe_num_experts"]})
+    cases.shares_add_up(BLOCK, stack, ("e_up", "e_down"))
 
 
 # ---------------------------------------------------------------------------

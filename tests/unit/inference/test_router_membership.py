@@ -137,15 +137,19 @@ def test_death_and_drain_compose_without_double_requeue(model_and_params):
                                           stall_check_interval_s=0.02))
         replicas = [Replica("m0", eng0, cfg),
                     Replica("m1", eng1, _serving_config())]
+        # the deadlines leave a HEALTHY replica the slack a host with six
+        # busy test workers needs: m1's loop may stall a second or two
+        # there, and at a timeout of 1.0 s it was declared dead beside
+        # m0 (PR 54's take-up run); the wedge outlasts every deadline
         router = ReplicaRouter(
             replicas, RouterConfig(placement="round_robin",
-                                   heartbeat_timeout_s=1.0,
+                                   heartbeat_timeout_s=4.0,
                                    monitor_interval_s=0.0))
         await router.start()
         real_step = replicas[0].serving.scheduler.step
 
         def wedged_step():
-            release.wait(timeout=20.0)
+            release.wait(timeout=120.0)
             return real_step()
 
         replicas[0].serving.scheduler.step = wedged_step
@@ -156,7 +160,7 @@ def test_death_and_drain_compose_without_double_requeue(model_and_params):
         reg = get_registry()
         rq0 = reg.family_total("router_requeued_total")
         import time as _time
-        deadline = _time.monotonic() + 10.0
+        deadline = _time.monotonic() + 60.0
         died = []
         while not died and _time.monotonic() < deadline:
             await asyncio.sleep(0.05)

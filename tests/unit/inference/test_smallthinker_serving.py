@@ -1,25 +1,20 @@
-"""The SmallThinker block served: an expert layer whose ROUTER READS THE
-MIXER'S NORMED INPUT, ahead of attention (``moe_router_ahead``), ReGLU
-experts (``moe_expert_form`` "reglu": SwiGLU's three matrices under a
-ReLU gate), a query group of SEVEN (28 heads on 4; 14 on 2 at toy
-widths) in window (rotated) and full (no position signal) layers 3 : 1,
-the ring and the pool in each launch, at toy widths on the CPU, against
-the benchmark's plain reference
-(``benchmark/reference_smallthinker.py``: float32, every position
-against every key, no cache, no ring, no chunks).
+"""The SmallThinker block's own: the source's lists and the pattern as
+runs, a ROUTER THAT READS THE MIXER'S NORMED INPUT (what it reads, how it
+weighs, where its scope stands in the programs), the ReGLU experts'
+body, a query group of SEVEN in the ragged kernels, the ring, the int8
+control on what a ring holds, and what does not serve the block. What
+every served block is held to (the engine against the plain reference
+``benchmark/reference_smallthinker.py``: every position against every
+key, no cache, no ring, no chunks; the five programs that are another
+block's on the same weights) is the contract's
+(``test_served_block_contract.py``), on this block's row of
+``served_blocks.py``, where the limits are justified.
 
-Tolerances. A float32 engine differs from the reference by the order of
-its sums (pages of a ring, an online softmax, a grouped matmul): 2e-5 of
-the largest logit is twenty times what it reads (9e-7 to 1.2e-6). A bf16
-engine reads 1e-2 and over; a router behind the mixer, a SiLU gate, a
-rotated full layer and an unrotated window layer each read 1e-2 and over
-on the same weights. A kernel against the gathering reference, both
-float32: 2e-5 of the largest output.
+A kernel against the gathering reference, both float32: 2e-5 of the
+largest output.
 """
 
 import dataclasses
-import json
-from pathlib import Path
 
 import numpy as np
 import pytest
@@ -27,9 +22,6 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from benchmark import reference_smallthinker as reference
-from benchmark import run as harness
-from benchmark import weights_smallthinker as weights
 from deepspeed_tpu.inference.v2 import InferenceEngineV2
 from deepspeed_tpu.inference.v2 import paged_model
 from deepspeed_tpu.inference.v2.kernels.ragged_attention import (
@@ -38,60 +30,19 @@ from deepspeed_tpu.inference.v2.paged_model import (_layer_runs,
                                                     init_paged_kv_cache)
 from deepspeed_tpu.models import TransformerConfig, TransformerLM
 from deepspeed_tpu.moe import sharded_moe
-from deepspeed_tpu.telemetry import get_registry
+from tests.unit.inference import served_block_contract as contract
+from tests.unit.inference import served_blocks as sb
+from tests.unit.inference.served_blocks import F32 as F32_TIGHT
 
-REPO = Path(__file__).resolve().parents[3]
-CONFIG = json.loads((REPO / "benchmark/configs/"
-                     "smallthinker-21ba3b-instruct.json").read_text())
-TOY = harness.merge(CONFIG["fields"], CONFIG["toy_fields"])
+BLOCK = sb.BLOCKS["smallthinker-21ba3b-instruct"]
+globals().update(contract.clauses(BLOCK))     # the contract's cases of this row
+CONFIG, TOY = BLOCK.config, BLOCK.toy
+reference, weights = BLOCK.reference, BLOCK.weights
 WINDOW = TOY["attn_window"]                 # 16
-F32_TIGHT = 2e-5
-A_FAULT = 500 * F32_TIGHT                   # 1e-2
-SEED = 5
-
-
-def _engine(dtype="float32", fields=TOY, seqs=4, budget=32, **engine):
-    """Blocks of 8, a step of ``budget`` tokens: a row's share is
-    ``budget / seqs`` (8: half the window) and its ring the window, that
-    share and one block. The weights are the toy's whatever ``fields``
-    asks of the program (a fault laid on the SAME leaves)."""
-    cfg = TransformerConfig(**fields)
-    return InferenceEngineV2(TransformerLM(cfg), {
-        "dtype": dtype, "use_paged_kernel": True, "decode_window": 4,
-        **engine,
-        "state_manager": {"max_tracked_sequences": seqs,
-                          "max_ragged_batch_size": budget,
-                          "max_seq_len": 160, "block_size": 8,
-                          "num_blocks": 100}},
-        params=weights.make(TOY, SEED, dtype))
-
-
-def _prompts(lengths=(50, 70, 80), seed=0):
-    rng = np.random.default_rng(seed)
-    return [rng.integers(0, TOY["vocab_size"], n) for n in lengths]
-
-
-def _params():
-    return weights.make(TOY, SEED, "float32")
-
-
-def _reference(prompt):
-    return np.asarray(reference.logits(_params(), TOY, prompt))
-
-
-def _err(got, want):
-    return float(np.abs(np.asarray(got, np.float32) - want).max()
-                 / np.abs(want).max())
-
-
-def _put_err(eng, prompts):
-    got = eng.put(list(range(len(prompts))), prompts)
-    return max(_err(got[i], _reference(p)[-1])
-               for i, p in enumerate(prompts))
 
 
 # ---------------------------------------------------------------------------
-# (a) the configuration, and the engine against the plain reference
+# (a) the configuration
 # ---------------------------------------------------------------------------
 def test_the_source_lists_agree_and_the_pattern_is_walked_as_runs():
     """``rope_layout`` equals ``sliding_window_layout`` entry for entry
@@ -124,59 +75,12 @@ def test_the_source_lists_agree_and_the_pattern_is_walked_as_runs():
                 "window_layers", "full_layers", "layers")}
 
 
-def test_served_logits_match_the_references_full_forward():
-    """Prompts of 3 to 5 windows (50, 70, 80 tokens at a window of 16)
-    fed in chunks of 8, then decoding 40 tokens, past two more wraps of
-    the ring: the logits ``put()`` returns and every generated token
-    against the reference's full forward on the same prefix."""
-    eng = _engine()
-    assert eng.attention_impl == "pallas:pipelined+window"
-    assert eng.max_row_chunk == 8
-    assert eng.state_manager.ring_blocks * 8 == WINDOW + 8 + 8
-    prompts = _prompts()
-    assert _put_err(eng, prompts) <= F32_TIGHT
-    for uid in range(3):
-        eng.flush(uid)
-    outs = eng.generate(prompts, max_new_tokens=40, temperature=0.0,
-                        eos_token_id=None)
-    assert get_registry().family_total(
-        "inference_window_blocks_reused_total") > 0
-    for prompt, out in zip(prompts, outs):
-        out = np.asarray(out)
-        assert len(out) == len(prompt) + 40
-        ref = _reference(out[:-1])[len(prompt) - 1:]
-        np.testing.assert_array_equal(out[len(prompt):], ref.argmax(-1))
-
-
-def test_the_gather_path_serves_the_same_logits():
-    eng = _engine(use_paged_kernel=False)
-    assert eng.attention_impl == "jnp:gather"
-    assert _put_err(eng, _prompts((50, 33))) <= F32_TIGHT
-
-
-@pytest.mark.parametrize("fault,fields,engine", [
-    ("a bf16 engine", {}, {"dtype": "bfloat16"}),
-    ("the router behind the mixer", {"moe_router_ahead": False}, {}),
-    ("a SiLU gate", {"moe_expert_form": "swiglu"}, {}),
-    ("a rotated full layer", {"rope_sliding_only": False}, {}),
-    ("an unrotated window layer", {"positional": "none",
-                                   "rope_sliding_only": False}, {}),
-])
-def test_what_the_block_is_not_fails_the_float32_limit(fault, fields,
-                                                       engine):
-    """The same weights through a program that is another block's, and
-    through this one in bf16: each reads over a hundred times the
-    float32 limit."""
-    eng = _engine(**{"fields": {**TOY, **fields}, **engine})
-    assert _put_err(eng, _prompts((50, 70))) > A_FAULT, fault
-
-
 # ---------------------------------------------------------------------------
 # (b) the router: what it reads, and how it weighs
 # ---------------------------------------------------------------------------
 def _first_layer():
     """(the first layer's leaves in float32, a stream [37, H])."""
-    params = _params()
+    params = sb.params(BLOCK)
     lp = {**jax.tree.map(lambda a: a[0], params["window_layers"]),
           **jax.tree.map(lambda a: a[0], params["layers"])}
     x = jnp.asarray(np.random.default_rng(1).standard_normal(
@@ -404,17 +308,17 @@ def test_a_group_of_seven_against_the_gathering_reference(variant,
 # ---------------------------------------------------------------------------
 # (d) the ring
 # ---------------------------------------------------------------------------
-def test_the_ring_wraps_and_holds_the_right_positions():
+def test_the_ring_wraps_and_holds_the_right_positions(lend):
     """Prompts of more than two windows (50, 70, 80, 20 at a window of
     16), 30 decode steps: every row's ring is full, holds the LAST
     positions in order, and its keys and values are the reference's at
     those positions (the first layer's, ahead of every routed expert);
     the full leaves hold every position; both pools empty after
     flush."""
-    eng = _engine()
+    eng = lend()
     sm = eng.state_manager
     ring = sm.ring_blocks
-    prompts = _prompts((50, 70, 80, 20))
+    prompts = sb.prompts(BLOCK, (50, 70, 80, 20))
     free0 = sm.allocator.free_blocks
     outs = eng.generate(prompts, max_new_tokens=30, temperature=0.0,
                         eos_token_id=None, keep_sequences=True)
@@ -426,7 +330,8 @@ def test_the_ring_wraps_and_holds_the_right_positions():
         assert at[-1] == n - 1 and len(at) >= WINDOW \
             and (np.diff(at) == 1).all() and n > 2 * WINDOW + 30
         assert kv["k"].shape[0] == 6
-        want_k, want_v = reference.leading_kv(_params(), TOY, outs[row][:-1])
+        want_k, want_v = reference.leading_kv(sb.params(BLOCK), TOY,
+                                              outs[row][:-1])
         for got, want in ((kv["k"], want_k), (kv["v"], want_v)):
             want = np.asarray(want)[:, at]
             assert want.shape[0] == 1
@@ -440,23 +345,26 @@ def test_the_ring_wraps_and_holds_the_right_positions():
     assert sm.allocator.free_blocks == free0
 
 
-def test_an_int8_pool_in_both_leaves_is_the_lower_precision_control():
-    prompts = _prompts((50, 70))
-    eng = _engine(kv_quant=True)
+def test_an_int8_pool_in_both_leaves_is_the_lower_precision_control(lend):
+    prompts = sb.prompts(BLOCK, (50, 70))
+    eng = lend(kv_quant=True)
     assert eng.kv_cache["k_window"].dtype == jnp.int8 \
         and eng.kv_cache["k_full"].dtype == jnp.int8
     outs = eng.generate(prompts, max_new_tokens=4, temperature=0.0,
                         eos_token_id=None, keep_sequences=True)
     kv = eng.sequence_kv(1)
-    _, want_v = reference.leading_kv(_params(), TOY, outs[1][:-1])
+    _, want_v = reference.leading_kv(sb.params(BLOCK), TOY, outs[1][:-1])
     want = np.asarray(want_v)[:, kv["positions"]]
     assert np.linalg.norm(kv["v"][:1] - want) / np.linalg.norm(want) > 5e-3
+    for uid in range(2):
+        eng.flush(uid)
 
 
 # ---------------------------------------------------------------------------
 # (e) what does not serve the block says so
 # ---------------------------------------------------------------------------
 def test_the_refusals_by_their_lines():
+    """(Speculation: the contract's.)"""
     from deepspeed_tpu.inference.engine import InferenceEngine
     cfg = TransformerConfig(**TOY)
     for what in ("layer_types", "rope_sliding_only",
@@ -481,10 +389,6 @@ def test_the_refusals_by_their_lines():
                              r".*at ep=1"):
         InferenceEngineV2(model, {"dtype": "float32",
                                   "expert_parallel_size": 2}, params={})
-    eng = _engine()
-    with pytest.raises(NotImplementedError, match="verify pass"):
-        eng.generate(_prompts((9,)), max_new_tokens=2, speculative=True)
-    assert eng.state_manager.tracked_sequences() == 0
 
 
 @pytest.mark.parametrize("fields,line", [
@@ -503,9 +407,7 @@ def test_the_new_fields_are_refused_where_nothing_serves_them(fields, line):
 
 
 def test_the_router_is_not_put_ahead_of_a_mixer_that_is_not_per_head():
-    nemotron = json.loads((REPO / "benchmark/configs/"
-                           "granite-4.0-h-small.json").read_text())
-    fields = harness.merge(nemotron["fields"], nemotron["toy_fields"])
+    fields = sb.BLOCKS["granite-4.0-h-small"].toy
     TransformerConfig(**fields)
     with pytest.raises(NotImplementedError, match="per-head attention"):
         TransformerConfig(**{**fields, "moe_router_ahead": True})

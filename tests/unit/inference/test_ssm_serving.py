@@ -1,30 +1,14 @@
-"""The ``granitemoehybrid`` block served: Mamba-2 state-space layers with
-a recurrent state a sequence beside one per-head attention layer a
-period (state slots AND a per-head pool in one cache), no position
-signal, the muP multipliers, and an expert layer of top-k by softmax
-over the chosen logits that holds a SHARE of the experts, at toy widths
-on the CPU, against the benchmark's plain reference
-(``benchmark/reference_granite.py``: float32, a token at a time through
-the recurrence, no cache).
+"""The ``granitemoehybrid`` block's own: its pattern as the source spells
+it, the two forms of the Mamba-2 recurrence and their kernels against the
+token scan, the router's two routes and the shares of the experts. What
+every served block is held to (the engine against the plain reference
+``benchmark/reference_granite.py``, its controls, its refusals) is the
+contract's (``test_served_block_contract.py``), on this block's row of
+``served_blocks.py``, where the limits are justified.
 
-Tolerances. A float32 engine differs from the reference by the order of
-its sums and the chunked form of the recurrence (matmuls over a chunk's
-pairs in place of rank-one updates a token): 2e-5 of the largest logit
-is the other blocks' float32 limit and over ten times what it reads
-(1e-6). A state kept in bfloat16 and a router that scores in bfloat16
-each read over five times it. A bf16 engine rounds every activation to 8
-bits, and at a hidden width of 64 under twenty sub-layers that is 3e-2
-to 1.2e-1 of the largest logit on seeds 1-5 even where no expert can be
-swapped; with the toy's hard top-4 of 16 a swap happens on every seed
-read (0.05 to 0.7). So the bf16 engine is held on a router that cannot
-flip (top-16 of 16: ``NO_FLIP``), where seed 5 reads 4.2e-2 (logits)
-and 7e-3 (served tokens' gap): limits 1e-1 and the other blocks' 4e-2.
 Two forms of one recurrence, both float32: 2e-5 of the largest output
 (they read 2e-6).
 """
-
-import json
-from pathlib import Path
 
 import numpy as np
 import pytest
@@ -32,57 +16,23 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from benchmark import reference_granite, weights_granite
-from benchmark import run as harness
-from deepspeed_tpu.inference.v2 import InferenceEngineV2, paged_model
+from deepspeed_tpu.inference.v2 import paged_model
 from deepspeed_tpu.inference.v2.kernels import linear_attention as la
 from deepspeed_tpu.inference.v2.kernels import state_space as ss
-from deepspeed_tpu.models import TransformerConfig, TransformerLM
+from deepspeed_tpu.models import TransformerConfig
 from deepspeed_tpu.moe.sharded_moe import topk_routing
-from deepspeed_tpu.telemetry import get_registry
+from tests.unit.inference import served_block_contract as contract
+from tests.unit.inference import served_blocks as sb
+from tests.unit.inference import state_space_cases as cases
 
-REPO = Path(__file__).resolve().parents[3]
-CONFIG = json.loads(
-    (REPO / "benchmark/configs/granite-4.0-h-small.json").read_text())
-TOY = harness.merge(CONFIG["fields"], CONFIG["toy_fields"])
-NO_FLIP = {**TOY, "moe_top_k": TOY["moe_num_experts"]}
-F32_TIGHT, BF16_LOGITS, BF16_LIMIT = 2e-5, 1e-1, 4e-2
-SEED = 5
-
-
-def _engine(dtype="float32", fields=TOY, seqs=4, budget=256, **engine):
-    cfg = TransformerConfig(**fields)
-    return InferenceEngineV2(TransformerLM(cfg), {
-        "dtype": dtype, "use_paged_kernel": True, "decode_window": 4,
-        **engine,
-        "state_manager": {"max_tracked_sequences": seqs,
-                          "max_ragged_batch_size": budget,
-                          "max_seq_len": 256, "block_size": 16,
-                          "num_blocks": 60}},
-        params=weights_granite.make(fields, SEED, dtype))
-
-
-def _prompts(lengths=(20, 70, 5), seed=0):
-    rng = np.random.default_rng(seed)
-    return [rng.integers(0, TOY["vocab_size"], n) for n in lengths]
-
-
-def _params(fields=TOY):
-    return weights_granite.make(fields, SEED, "float32")
-
-
-def _reference(prompt, fields=TOY):
-    return np.asarray(reference_granite.logits(_params(fields), fields,
-                                               prompt))
-
-
-def _err(got, want):
-    return float(np.abs(np.asarray(got, np.float32) - want).max()
-                 / np.abs(want).max())
+BLOCK = sb.BLOCKS["granite-4.0-h-small"]
+globals().update(contract.clauses(BLOCK))     # the contract's cases of this row
+TOY = BLOCK.toy
+reference_granite = BLOCK.reference
 
 
 # ---------------------------------------------------------------------------
-# (a) the configuration, and the engine against the plain reference
+# (a) the configuration
 # ---------------------------------------------------------------------------
 def test_the_pattern_is_written_down_as_the_source_spells_it():
     cfg = TransformerConfig(**TOY)
@@ -103,207 +53,17 @@ def test_the_pattern_is_written_down_as_the_source_spells_it():
         TransformerConfig(hidden_size=64, num_heads=4, residual_scale=0.5)
 
 
-@pytest.mark.parametrize("dtype,fields,limit", [
-    ("float32", TOY, F32_TIGHT), ("bfloat16", NO_FLIP, BF16_LOGITS)])
-def test_put_logits_match_the_reference(dtype, fields, limit):
-    """Rows of 20, 70 and 5 tokens in one ragged step."""
-    eng = _engine(dtype, fields)
-    assert eng.attention_impl == "pallas:pipelined" and eng._has_state
-    assert set(eng.kv_cache) == {"k_full", "v_full", "ssm_state",
-                                 "ssm_conv"}
-    prompts = _prompts()
-    got = eng.put([0, 1, 2], prompts)
-    for i, p in enumerate(prompts):
-        assert _err(got[i], _reference(p, fields)[-1]) <= limit, i
-
-
-@pytest.mark.parametrize("dtype,fields", [("float32", TOY),
-                                          ("bfloat16", NO_FLIP)])
-def test_decode_through_slot_and_pool_matches_the_reference(dtype, fields):
-    """The ragged step leaves each row's state in its slot and its keys
-    and values in the pool; decode windows of 4 (launched one behind the
-    other: the state rides the cache) read and extend both. float32: at
-    EVERY generated position the engine's token is the reference's best
-    on the same prefix, so a state, a slot, a conv tap or a page read
-    wrong shows. bf16: the served token's reference logit lies within
-    the bf16 limit of the best."""
-    eng = _engine(dtype, fields)
-    prompts = _prompts()
-    outs = eng.generate(prompts, max_new_tokens=13, temperature=0.0,
-                        eos_token_id=None)
-    assert get_registry().family_total(
-        "inference_decode_windows_ahead_total") > 0
-    assert eng.state_manager.state_slots_in_use() == 0
-    for prompt, out in zip(prompts, outs):
-        out = np.asarray(out)
-        assert len(out) == len(prompt) + 13
-        ref = _reference(out[:-1], fields)[len(prompt) - 1:]
-        if dtype == "float32":
-            np.testing.assert_array_equal(out[len(prompt):], ref.argmax(-1))
-        else:
-            served = ref[np.arange(len(ref)), out[len(prompt):]]
-            gap = (ref.max(-1) - served) / np.abs(ref).max(-1)
-            assert gap.max() <= BF16_LIMIT
-
-
-def test_the_tolerance_is_tight_enough_for_its_controls(monkeypatch):
-    """A state kept in bfloat16 (``state_dtype``, the cell's control)
-    and a router that scores in bfloat16 each fail the float32 limit
-    that the engine as it stands passes."""
-    prompts = _prompts((70,))
-    want = _reference(prompts[0])[-1]
-    sound = _engine("float32")
-    control = _engine("float32", state_dtype="bfloat16", budget=32)
-    assert control.kv_cache["ssm_state"].dtype == jnp.bfloat16
-    assert sound.kv_cache["ssm_state"].dtype == jnp.float32
-    assert _err(sound.put([0], prompts)[0], want) <= F32_TIGHT
-    # the state is rounded where a launch hands it on: three chunks
-    assert _err(control.put([0], prompts)[0], want) > 5 * F32_TIGHT
-    from deepspeed_tpu.moe import sharded_moe
-    real = sharded_moe.topk_routing
-    monkeypatch.setattr(
-        sharded_moe, "topk_routing", lambda logits, *a, **k: real(
-            logits.astype(jnp.bfloat16).astype(jnp.float32), *a, **k))
-    rough = _engine("float32")
-    assert _err(rough.put([0], prompts)[0], want) > 5 * F32_TIGHT
-
-
-def test_rows_in_one_step_are_the_rows_served_alone():
-    """Rows of unequal lengths packed in one ragged step, then a MIXED
-    step (a new prompt beside the first rows' decode tokens), give each
-    row what it gets served alone: rows mix nowhere, not in the
-    convolution, not in the scan's windows, not in the pool."""
-    prompts = _prompts((33, 64, 7))
-    late = _prompts((41,), seed=3)[0]
-    nxt = [11, 22, 33]
-    eng = _engine("float32")
-    first = eng.put([0, 1, 2], prompts)
-    mixed = eng.put([0, 1, 2, 3], [[t] for t in nxt] + [late])
-    for i, p in enumerate(prompts):
-        alone = _engine("float32")
-        np.testing.assert_allclose(alone.put([9], [p])[0], first[i],
-                                   rtol=0, atol=2e-6)
-        np.testing.assert_allclose(alone.put([9], [[nxt[i]]])[0], mixed[i],
-                                   rtol=0, atol=2e-6)
-    assert _err(mixed[3], _reference(late)[-1]) <= F32_TIGHT
-
-
-def test_a_prompt_in_four_put_chunks_is_the_prompt_in_one():
-    """``put()`` feeds a prompt set over its step's budget in chunks, a
-    row continuing from its slot and its blocks: four steps of 16 tokens
-    a row give the logits of one step, and the state after them is the
-    one step's."""
-    prompts = _prompts((64, 64))
-    whole, parts = _engine("float32"), _engine("float32", budget=32)
-    reg = get_registry()
-    before = reg.family_total("inference_prefill_chunks_total")
-    got = parts.put([0, 1], prompts)
-    assert reg.family_total("inference_prefill_chunks_total") - before == 4
-    want = whole.put([0, 1], prompts)
-    np.testing.assert_allclose(got, want, rtol=0,
-                               atol=F32_TIGHT * np.abs(want).max())
-    for uid in (0, 1):
-        a, b = parts.sequence_state(uid), whole.sequence_state(uid)
-        for name in ("ssm_state", "ssm_conv"):
-            np.testing.assert_allclose(
-                a[name], b[name], rtol=0,
-                atol=F32_TIGHT * np.abs(b[name]).max())
-
-
-def test_the_state_after_n_tokens_is_the_references():
-    """``generate(keep_sequences=True)`` leaves every token but the last
-    fed: the slot then holds the reference's state after them, in the
-    layer ahead of every routed expert and in the next ones, and the
-    convolution's leaf the layer's last three inputs."""
-    eng = _engine("float32")
-    prompts = _prompts((37, 20))
-    outs = eng.generate(prompts, max_new_tokens=9, temperature=0.0,
-                        eos_token_id=None, keep_sequences=True)
-    assert eng.state_manager.state_slots_in_use() == 2
-    gauge = get_registry().get("inference_state_bytes").value
-    assert gauge == sum(v.nbytes for k, v in eng.kv_cache.items()
-                        if k.startswith("ssm_"))
-    for uid, out in enumerate(outs):
-        state = eng.sequence_state(uid)
-        assert state["ssm_state"].shape == (9, 8, 16, 32)
-        assert state["ssm_conv"].shape == (9, 3, 8 * 16 + 2 * 32)
-        want = np.asarray(reference_granite.leading_states(
-            _params(), TOY, np.asarray(out)[:-1], layers=3))
-        for layer in range(3):
-            err = np.linalg.norm(state["ssm_state"][layer] - want[layer]) \
-                / np.linalg.norm(want[layer])
-            assert err <= F32_TIGHT, (uid, layer, err)
-        eng.flush(uid)
-    assert eng.state_manager.state_slots_in_use() == 0
-
-
 # ---------------------------------------------------------------------------
 # (b) the two forms of the recurrence, and their kernels
 # ---------------------------------------------------------------------------
-def _scan(x, dt, a, b, c, s0):
-    """The recurrence a token at a time: x [T, nh, p], dt [T, nh], b and
-    c [T, n], s0 [nh, p, n]."""
-    def token(s, t):
-        xt, dtt, bt, ct = t
-        s = jnp.exp(dtt * a)[:, None, None] * s \
-            + (dtt[:, None] * xt)[:, :, None] * bt[None, None, :]
-        return s, jnp.einsum("hpn,n->hp", s, ct)
-    return jax.lax.scan(token, s0, (x, dt, b, c))
-
-
-def _case(nh, p, n, lengths, T, seed=0, slots=6):
-    rng = np.random.default_rng(seed)
-    C = nh * p
-    f = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)
-    # steps from slow to one that forgets within a token (dt A = -30)
-    dt = jnp.asarray(np.exp(rng.uniform(np.log(1e-3), np.log(2.0),
-                                        (T, nh))), jnp.float32)
-    counts = jnp.asarray(lengths, jnp.int32)
-    return dict(
-        leaf=f(*ss.state_leaf_shape(2, slots, C, n)), layer=jnp.int32(1),
-        slots=jnp.asarray([i % (slots - 1) + 1 if n_ else 0
-                           for i, n_ in enumerate(lengths)], jnp.int32),
-        fresh=jnp.asarray([i % 2 == 0 for i in range(len(lengths))]),
-        starts=jnp.cumsum(counts) - counts, counts=counts,
-        xbc=f(T, C + 2 * n), dt=dt,
-        a=-jnp.asarray(rng.uniform(1, 16, (nh,)), jnp.float32))
-
-
-def _against_the_scan(case, y, leaf, nh):
-    n = case["leaf"].shape[3]
-    C = case["xbc"].shape[1] - 2 * n
-    case = {**case, "x": case["xbc"][:, :C], "b": case["xbc"][:, C:C + n],
-            "c": case["xbc"][:, C + n:]}
-    with jax.default_matmul_precision("highest"):
-        for r, n_ in enumerate(np.asarray(case["counts"])):
-            if not n_:
-                continue
-            at = slice(int(case["starts"][r]), int(case["starts"][r]) + n_)
-            slot = case["slots"][r]
-            s0 = ss.heads_of(case["leaf"][1, slot], nh)
-            s0 = jnp.where(case["fresh"][r], 0.0, s0)
-            s1, want = _scan(case["x"][at].reshape(n_, nh, -1),
-                             case["dt"][at], case["a"], case["b"][at],
-                             case["c"][at], s0)
-            assert _err(y[at], np.asarray(want).reshape(n_, -1)) \
-                <= F32_TIGHT, r
-            assert _err(ss.heads_of(leaf[1, slot], nh),
-                        np.asarray(s1)) <= F32_TIGHT, r
-    used = np.zeros(len(y), bool)
-    for s, n_ in zip(np.asarray(case["starts"]), np.asarray(case["counts"])):
-        used[s:s + n_] = True
-    assert not np.asarray(y)[~used].any()       # tokens of no row: zeros
-    np.testing.assert_array_equal(leaf[0], case["leaf"][0])
-
-
 @pytest.mark.parametrize("nh,p,n,chunk", [(4, 16, 32, 16), (2, 64, 128, 32)])
 def test_chunked_form_is_the_token_scan(nh, p, n, chunk):
     """Rows of 5, 0, 37, 16 and 1 tokens, fresh and continued from their
     slots, through ``ssm_chunked``: each row's outputs and final state
     are the token scan's."""
-    case = _case(nh, p, n, (5, 0, 37, 16, 1), 62)
+    case = cases.case(nh, p, n, (5, 0, 37, 16, 1), 62)
     y, leaf = ss.ssm_chunked(**case, chunk=chunk)
-    _against_the_scan(case, y, leaf, nh)
+    cases.against_the_scan(case, y, leaf, nh)
 
 
 @pytest.mark.parametrize("nh,p,lengths,T,chunk", [
@@ -315,23 +75,9 @@ def test_chunk_kernel_is_the_token_scan(nh, p, lengths, T, chunk):
     """``ssm_chunk_fwd`` under the TPU interpreter (its DMAs and
     semaphores included): rows that start and end inside windows, share
     a window, span several, fresh and continued."""
-    case = _case(nh, p, 128, lengths, T)
+    case = cases.case(nh, p, 128, lengths, T)
     y, leaf = ss.ssm_chunk_fwd(**case, chunk=chunk, interpret=True)
-    _against_the_scan(case, y, leaf, nh)
-
-
-def _one_token_case(nh, p, n):
-    """(leaf, layer, slots, fresh, x, dt, a, b, c) of three rows, the
-    middle one fresh between two kept ones."""
-    rng = np.random.default_rng(1)
-    C, N = nh * p, 3
-    f = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)
-    leaf0 = f(*ss.state_leaf_shape(2, 5, C, n))
-    x, b, c = f(N, C), f(N, n), f(N, n)
-    dt = jnp.asarray(rng.uniform(0.01, 1.0, (N, nh)), jnp.float32)
-    a = -jnp.asarray(rng.uniform(1, 16, (nh,)), jnp.float32)
-    return (leaf0, jnp.int32(1), jnp.asarray([2, 4, 1]),
-            jnp.asarray([False, True, False]), x, dt, a, b, c)
+    cases.against_the_scan(case, y, leaf, nh)
 
 
 @pytest.mark.parametrize("nh,p,n", [
@@ -340,38 +86,11 @@ def _one_token_case(nh, p, n):
     (32, 64, 128),          # 16: one whole grid step a row
     (128, 64, 128)])        # the cell's 64: the ONE group four grid steps
 def test_one_token_forms_are_the_token_scan(nh, p, n):
-    """``ssm_step`` and the kernel ``ssm_state_update`` (interpreted) on
-    three rows' slots, a fresh row between two kept ones: one token of
-    the scan, the other slots and the other layer untouched. The kernel
-    takes B and C as ONE row of ``d_state`` a token and spreads it in
-    VMEM every grid step of the row: its state and
-    ``y`` are ``ssm_step``'s too, and the leaf goes in aliased to the
-    leaf that comes out."""
-    args = _one_token_case(nh, p, n)
-    leaf0, _, slots, fresh, x, dt, a, b, c = args
-    C, N = nh * p, x.shape[0]
-    forms = [ss.ssm_step] + [
-        lambda *args: ss.ssm_state_update(*args, interpret=True)
-    ] * (C % 128 == 0)
-    out = []
-    for form in forms:
-        y, leaf = form(*args)
-        out.append((y, leaf))
-        for r in range(N):
-            s0 = jnp.where(fresh[r], 0.0, ss.heads_of(leaf0[1, slots[r]], nh))
-            s1, want = _scan(x[r:r + 1].reshape(1, nh, p), dt[r:r + 1], a,
-                             b[r:r + 1], c[r:r + 1], s0)
-            assert _err(y[r], np.asarray(want).reshape(C)) <= F32_TIGHT
-            assert _err(ss.heads_of(leaf[1, slots[r]], nh),
-                        np.asarray(s1)) <= F32_TIGHT
-        np.testing.assert_array_equal(leaf[0], leaf0[0])
-        np.testing.assert_array_equal(leaf[1, 3], leaf0[1, 3])
-    if len(out) == 2:
-        for step, kernel in zip(*out):
-            assert _err(kernel, np.asarray(step)) <= F32_TIGHT
-        (call,) = [e for e in jax.make_jaxpr(forms[1])(*args).eqns
-                   if e.primitive.name == "pallas_call"]
-        assert call.params["input_output_aliases"] == ((3, 0),)
+    """``ssm_step`` and the kernel ``ssm_state_update`` (interpreted)
+    against one token of the scan (``cases.one_token_forms``). The
+    kernel takes B and C as ONE row of ``d_state`` a token and spreads
+    it in VMEM every grid step of the row."""
+    cases.one_token_forms(nh, p, n)
 
 
 @pytest.mark.parametrize("N", [3, 8, 12, 24])
@@ -424,81 +143,12 @@ def test_softmax_over_the_chosen_is_the_renormalised_softmax_over_all():
 
 
 def test_the_shares_add_up_to_the_uncut_layer():
-    """The guide's test of a cut in experts: the routed output of the
-    share that holds the first half of the experts plus that of the
-    share that holds the second half, the shared expert counted once,
-    is the uncut reference's expert layer; and the program's expert
-    layer on either share is that share's reference."""
-    E = TOY["moe_num_experts"]
-    whole = {**TOY, "moe_experts_held": E}
-    shapes = weights_granite.shapes(whole)
+    """``cases.shares_add_up`` on a stack of all 16 experts."""
+    shapes = BLOCK.weights.shapes({**TOY,
+                                   "moe_experts_held": TOY["moe_num_experts"]})
     rng = np.random.default_rng(4)
     stack = {k: jnp.asarray(rng.normal(size=s) / s[-2] ** 0.5 if len(s) > 2
                             else 1.0 + 0.1 * rng.normal(size=s),
                             jnp.float32)
              for k, (s, _) in shapes["layers"].items()}
-    x = jnp.asarray(rng.normal(size=(24, TOY["hidden_size"])), jnp.float32)
-    experts = ("e_gate", "e_up", "e_down")
-    half = E // 2
-
-    def share(first):
-        cut = {k: v[:, first:first + half] if k in experts else v
-               for k, v in stack.items()}
-        fields = {**TOY, "moe_experts_held": half,
-                  "moe_experts_first": first}
-        return cut, fields
-
-    with jax.default_matmul_precision("highest"):
-        routed, shared = reference_granite.expert_layer(x, stack, 3, whole)
-        parts = []
-        for first in (0, half):
-            cut, fields = share(first)
-            r, s = reference_granite.expert_layer(x, cut, 3, fields)
-            np.testing.assert_allclose(s, shared, atol=1e-6)
-            parts.append(r)
-            cfg = TransformerConfig(**fields)
-            lp = {k: v[3] for k, v in cut.items()}
-            hn = paged_model._norm(cfg, x, lp["mlp_norm"])
-            got, _ = paged_model._moe_routed(
-                cfg, lp, hn, router_precision=jax.lax.Precision.HIGHEST)
-            np.testing.assert_allclose(
-                got, r + s, atol=F32_TIGHT * float(jnp.abs(r + s).max()))
-        assert float(jnp.abs(parts[0]).max()) > 0 \
-            and float(jnp.abs(parts[1]).max()) > 0
-        np.testing.assert_allclose(
-            parts[0] + parts[1], routed,
-            atol=F32_TIGHT * float(jnp.abs(routed).max()))
-
-
-# ---------------------------------------------------------------------------
-# (d) what is not served with this state is refused by name
-# ---------------------------------------------------------------------------
-@pytest.mark.parametrize("engine,word", [
-    ({"tensor_parallel_size": 2}, "tensor_parallel_size"),
-    ({"max_lora_adapters": 2}, "max_lora_adapters"),
-    ({"kv_quant": True}, "kv_quant"),
-    ({"quant_bits": 8}, "quant_bits"),
-    ({"state_manager": {"enable_prefix_caching": True}},
-     "wrong recurrent state"),
-    ({"state_manager": {"enable_prefix_caching": True,
-                        "enable_kv_spill": True}}, "state slot")])
-def test_refusals_at_construction(engine, word):
-    cfg = TransformerConfig(**TOY)
-    with pytest.raises(NotImplementedError,
-                       match="state-space layers.*" + word):
-        InferenceEngineV2(TransformerLM(cfg), {"dtype": "float32", **engine})
-
-
-def test_speculation_handoff_and_the_other_forwards_refuse():
-    eng = _engine("float32")
-    prompts = _prompts((12,))
-    with pytest.raises(NotImplementedError, match="verify pass"):
-        eng.generate(prompts, max_new_tokens=2, speculative=True)
-    eng.put([7], prompts)
-    from deepspeed_tpu.inference.v2.serve import handoff
-    with pytest.raises(NotImplementedError, match="no state slot"):
-        handoff.export_sequence(eng, 7)
-    model = TransformerLM(TransformerConfig(**TOY))
-    with pytest.raises(NotImplementedError, match="mamba layers"):
-        model.apply(model.init_params(jax.random.PRNGKey(0)),
-                    {"input_ids": jnp.zeros((1, 8), jnp.int32)})
+    cases.shares_add_up(BLOCK, stack, ("e_gate", "e_up", "e_down"))

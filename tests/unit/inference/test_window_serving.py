@@ -1,17 +1,18 @@
-"""The ``afmoe`` block served: a layer pattern over PER-HEAD attention
-(window and full layers in one model), a window in the ragged kernels, a
-cache of two geometries (the window layers' keys and values in a ring),
-gated GQA with QK-norm, sandwich norms, a leading dense stack, and
-prompts fed in chunks, at toy widths on the CPU, against the benchmark's
-plain reference (``benchmark/reference_trinity.py``: float32, every
-position against every key, no cache, no ring, no chunks).
+"""The ``afmoe`` block's own: its pattern walked as runs, ``put()``'s chunk
+steps under leaves of their own, a mixed step, the scheduler's share of
+a step, the positions counter over a pattern, the ragged kernels with a
+WINDOW against a dense masked softmax, the cache of two geometries (the
+window layers' keys and values in a ring) and its manager, the int8
+control on what a ring holds, and the old trees' seeds. What every
+served block is held to (the engine against the plain reference
+``benchmark/reference_trinity.py``: every position against every key, no
+cache, no ring, no chunks; its refusals) is the contract's
+(``test_served_block_contract.py``), on this block's row of
+``served_blocks.py``, where the limits are justified.
 
-Tolerances. A float32 engine differs from the reference by the order of
-its sums (pages of a ring, an online softmax, a grouped matmul): 2e-5 of
-the largest logit is fifty times what it reads (3e-7 to 5e-7). A kernel
-against a dense masked softmax, both float32: 2e-5 of the largest output
-(they read 3e-6 and under). An int8 pool (the cell's control) reads
-5e-2 to 1.4e-1.
+A kernel against a dense masked softmax, both float32: 2e-5 of the
+largest output (they read 3e-6 and under). An int8 pool (the cell's
+control) reads 5e-2 to 1.4e-1.
 """
 
 import json
@@ -23,9 +24,6 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from benchmark import reference_trinity, weights_trinity
-from benchmark import run as harness
-from deepspeed_tpu.inference.v2 import InferenceEngineV2
 from deepspeed_tpu.inference.v2.config_v2 import DSStateManagerConfig
 from deepspeed_tpu.inference.v2.kernels.ragged_attention import (
     ragged_attention, ragged_attention_reference)
@@ -35,51 +33,20 @@ from deepspeed_tpu.inference.v2.ragged.ragged_manager import DSStateManager
 from deepspeed_tpu.inference.v2.scheduler import DynamicSplitFuseScheduler
 from deepspeed_tpu.models import TransformerConfig, TransformerLM
 from deepspeed_tpu.telemetry import get_registry, trace
+from tests.unit.inference import served_block_contract as contract
+from tests.unit.inference import served_blocks as sb
+from tests.unit.inference.served_blocks import F32 as F32_TIGHT, err as _err
 
-REPO = Path(__file__).resolve().parents[3]
-CONFIG = json.loads(
-    (REPO / "benchmark/configs/trinity-mini.json").read_text())
-TOY = harness.merge(CONFIG["fields"], CONFIG["toy_fields"])
+BLOCK = sb.BLOCKS["trinity-mini"]
+globals().update(contract.clauses(BLOCK))     # the contract's cases of this row
+TOY, reference_trinity = BLOCK.toy, BLOCK.reference
 WINDOW = TOY["attn_window"]                 # 16
-F32_TIGHT = 2e-5
-SEED = 5
-
-
-def _engine(dtype="float32", fields=TOY, seqs=4, budget=32, **engine):
-    """Blocks of 8, a step of ``budget`` tokens: a row's share is
-    ``budget / seqs`` (8: half the window; 128 / 4 = 32: twice it) and
-    its ring the window, that share and one block."""
-    cfg = TransformerConfig(**fields)
-    return InferenceEngineV2(TransformerLM(cfg), {
-        "dtype": dtype, "use_paged_kernel": True, "decode_window": 4,
-        **engine,
-        "state_manager": {"max_tracked_sequences": seqs,
-                          "max_ragged_batch_size": budget,
-                          "max_seq_len": 160, "block_size": 8,
-                          "num_blocks": 100}},
-        params=weights_trinity.make(fields, SEED, dtype))
-
-
-def _prompts(lengths=(50, 70, 80), seed=0):
-    rng = np.random.default_rng(seed)
-    return [rng.integers(0, TOY["vocab_size"], n) for n in lengths]
-
-
-def _params():
-    return weights_trinity.make(TOY, SEED, "float32")
-
-
-def _reference(prompt):
-    return np.asarray(reference_trinity.logits(_params(), TOY, prompt))
-
-
-def _err(got, want):
-    return float(np.abs(np.asarray(got, np.float32) - want).max()
-                 / np.abs(want).max())
 
 
 # ---------------------------------------------------------------------------
-# (a) the engine against the plain reference
+# (a) the pattern, and the steps of a call (blocks of 8: a row's share of a
+# step of 32 tokens is 8, half the window; its ring the window, that share
+# and one block)
 # ---------------------------------------------------------------------------
 def test_the_pattern_is_walked_as_runs_of_one_mixer_and_one_mlp():
     cfg = TransformerConfig(**TOY)
@@ -101,81 +68,12 @@ def test_the_pattern_is_walked_as_runs_of_one_mixer_and_one_mlp():
             jnp.zeros((1, 8), jnp.int32))
 
 
-@pytest.mark.parametrize("budget,chunk", [(32, 8), (128, 32)],
-                         ids=["chunks-under-the-window",
-                              "chunks-over-the-window"])
-def test_served_logits_match_the_references_full_forward(budget, chunk):
-    """Prompts of 3 to 5 windows (50, 70, 80 tokens at a window of 16)
-    fed in chunks smaller than the window (8) and larger than it (32),
-    then decoding 40 tokens, past two more wraps of the ring: the logits
-    ``put()`` returns and every generated token against the reference's
-    full forward on the same prefix."""
-    eng = _engine(budget=budget)
-    sm = eng.state_manager
-    assert eng.attention_impl == "pallas:pipelined+window"
-    assert eng.max_row_chunk == chunk
-    assert sm.ring_blocks * 8 == WINDOW + chunk + 8
-    prompts = _prompts()
-    before = get_registry().family_total("inference_prefill_chunks_total")
-    got = eng.put([0, 1, 2], prompts)
-    steps = -(-80 // chunk)
-    assert get_registry().family_total(
-        "inference_prefill_chunks_total") - before == steps
-    for i, p in enumerate(prompts):
-        assert _err(got[i], _reference(p)[-1]) <= F32_TIGHT, i
-    for uid in range(3):
-        eng.flush(uid)
-    outs = eng.generate(prompts, max_new_tokens=40, temperature=0.0,
-                        eos_token_id=None)
-    assert get_registry().family_total(
-        "inference_window_blocks_reused_total") > 0
-    for prompt, out in zip(prompts, outs):
-        out = np.asarray(out)
-        assert len(out) == len(prompt) + 40
-        ref = _reference(out[:-1])[len(prompt) - 1:]
-        np.testing.assert_array_equal(out[len(prompt):], ref.argmax(-1))
-
-
-def test_the_gather_path_serves_the_same_logits():
-    """``use_paged_kernel`` off: the ring and the window through the
-    gathering reference inside the same programs."""
-    eng = _engine(use_paged_kernel=False)
-    assert eng.attention_impl == "jnp:gather"
-    prompts = _prompts((50, 33))
-    got = eng.put([0, 1], prompts)
-    for i, p in enumerate(prompts):
-        assert _err(got[i], _reference(p)[-1]) <= F32_TIGHT, i
-
-
-def test_put_in_chunks_equals_one_launch_where_one_launch_fits():
-    """The same two prompts through an engine whose step holds them
-    whole (one ragged step, a ring that never wraps) and through one
-    that feeds them eight tokens a row at a time: the same logits to
-    float32 rounding, and a call that fits is ONE step, as before."""
-    prompts = _prompts((24, 17))
-    whole = _engine(budget=128, seqs=4)           # a row's share: 32
-    steps0 = get_registry().family_total("inference_ragged_steps_total")
-    chunks0 = get_registry().family_total("inference_prefill_chunks_total")
-    want = whole.put([0, 1], prompts)
-    assert get_registry().family_total(
-        "inference_ragged_steps_total") - steps0 == 1
-    assert get_registry().family_total(
-        "inference_prefill_chunks_total") == chunks0
-    fed = _engine(budget=32)                      # a row's share: 8
-    trace.clear()
-    got = fed.put([0, 1], prompts)
-    spans = [s for s in trace.export() if s["name"] == "ragged_step"]
-    assert [(s["attrs"]["chunk"], s["attrs"]["chunks"]) for s in spans] \
-        == [(0, 3), (1, 3), (2, 3)]
-    assert float(np.abs(got - want).max() / np.abs(want).max()) <= F32_TIGHT
-
-
 @pytest.fixture(scope="module")
-def chunked_call():
+def chunked_call(served):
     """The ring of one ``generate()`` whose prompts go in in three chunk
     steps (one engine for the cases below)."""
-    eng = _engine(budget=32)                      # a row's share: 8
-    prompts = _prompts((24, 17))
+    eng = served.lend()                           # a row's share: 8
+    prompts = sb.prompts(BLOCK, (24, 17))
     eng.generate(prompts, max_new_tokens=2, temperature=0.0,
                  eos_token_id=None)                # compiles
     trace.clear()
@@ -196,7 +94,8 @@ def test_put_chunk_is_puts_own_work_behind_a_chunk_step(chunked_call, what):
     if what == "one a chunk step":
         steps = [s for s in ring if s["name"] == "ragged_step"]
         assert len(chunks) == len(steps) == 3
-        assert [s["attrs"]["chunk"] for s in steps] == [0, 1, 2]
+        assert [(s["attrs"]["chunk"], s["attrs"]["chunks"])
+                for s in steps] == [(0, 3), (1, 3), (2, 3)]
     elif what == "a leaf of the call":
         for s in chunks:
             assert s["parent"] == root["id"] and "attrs" not in s
@@ -209,26 +108,28 @@ def test_put_chunk_is_puts_own_work_behind_a_chunk_step(chunked_call, what):
         assert [names[i + 1] for i in at[:-1]] == ["ragged_pack"] * 2
 
 
-def test_a_mixed_step_decodes_some_rows_and_feeds_chunks_of_others():
+def test_a_mixed_step_decodes_some_rows_and_feeds_chunks_of_others(lend):
     """Row 0 is fed whole and decodes one token in the very step that
     feeds rows 1 and 2 their first chunks (8 tokens each and the decode
     row's one), and a put() of a decode token beside a long prompt runs
     the decode row in the first chunk step only."""
-    eng = _engine()
-    a, b = _prompts((40, 30))
+    eng = lend()
+    a, b = sb.prompts(BLOCK, (40, 30))
     first = eng.put([0], [a])
     tok = int(np.argmax(first[0]))
     got = eng.put([0, 1], [[tok], b])
-    assert _err(got[0], _reference(np.append(a, tok))[-1]) <= F32_TIGHT
-    assert _err(got[1], _reference(b)[-1]) <= F32_TIGHT
+    assert _err(got[0], sb.reference(BLOCK, np.append(a, tok))[-1]) \
+        <= F32_TIGHT
+    assert _err(got[1], sb.reference(BLOCK, b)[-1]) <= F32_TIGHT
     assert eng.state_manager.seqs[0].seen_tokens == 41
+    eng.flush(0), eng.flush(1)
 
 
-def test_the_scheduler_keeps_to_a_rows_share_of_a_step():
+def test_the_scheduler_keeps_to_a_rows_share_of_a_step(lend):
     """The SplitFuse scheduler's chunk is clipped to what the ring
     leaves room for, and its streams equal generate()'s."""
-    eng = _engine()
-    prompts = _prompts((50, 21))
+    eng = lend()
+    prompts = sb.prompts(BLOCK, (50, 21))
     want = eng.generate(prompts, max_new_tokens=9, temperature=0.0,
                         eos_token_id=None)
     sched = DynamicSplitFuseScheduler(eng, chunk=64)
@@ -241,7 +142,7 @@ def test_the_scheduler_keeps_to_a_rows_share_of_a_step():
 
 
 def test_the_positions_counter_takes_a_window_layer_from_its_first_page(
-        monkeypatch):
+        lend, monkeypatch):
     """``inference_attention_decode_positions_total`` over a pattern: a
     full layer's rows hold their whole context, a window layer's the
     pages from its window's first (window 16 over blocks of 8: two or
@@ -249,10 +150,10 @@ def test_the_positions_counter_takes_a_window_layer_from_its_first_page(
     held <= chunked, and nothing on the CPU until the engine is told the
     one-token form serves."""
     from deepspeed_tpu.inference.v2 import engine_v2
-    eng = _engine()
+    eng = lend()
     family = get_registry().get("inference_attention_decode_positions_total")
     held, chunked = family.labels(kind="held"), family.labels(kind="chunked")
-    prompts = _prompts((50, 21))
+    prompts = sb.prompts(BLOCK, (50, 21))
     before = held.value, chunked.value
     eng.generate(prompts, max_new_tokens=5, temperature=0.0,
                  eos_token_id=None)
@@ -424,7 +325,7 @@ def test_a_leaf_a_kind_and_the_window_leaf_a_ring():
     assert quant["k_window"].dtype == jnp.int8 \
         and quant["ks_window"].shape == (4, 17, TOY["num_kv_heads"]) \
         and quant["vs_full"].shape == (1, 100, TOY["num_kv_heads"])
-    eng = _engine()
+    eng = sb.engine(BLOCK)      # its own: the gauge is the last one built's
     bytes_of = {kind: sum(int(np.prod(v.shape)) * 4
                           for k, v in eng.kv_cache.items()
                           if k.endswith("_" + kind))
@@ -433,16 +334,21 @@ def test_a_leaf_a_kind_and_the_window_leaf_a_ring():
     assert {labels[0]: s.value for labels, s in fam.series()} == bytes_of
 
 
-def test_ring_blocks_never_pass_rows_x_ring_and_both_pools_empty_after_flush():
-    eng = _engine()
+def test_ring_blocks_never_pass_rows_x_ring_and_both_pools_empty_after_flush(
+        lend):
+    eng = lend()
     sm = eng.state_manager
     ring = sm.ring_blocks                          # 4 blocks of 8
     assert sm.window_allocator.num_blocks == 4 * ring + 1
-    prompts = _prompts((50, 70, 80, 20))
+    prompts = sb.prompts(BLOCK, (50, 70, 80, 20))
     free0 = sm.allocator.free_blocks
+    reused = get_registry().family_total(
+        "inference_window_blocks_reused_total")
     outs = eng.generate(prompts, max_new_tokens=30, temperature=0.0,
                         eos_token_id=None, keep_sequences=True)
     assert len(outs) == 4
+    assert get_registry().family_total(
+        "inference_window_blocks_reused_total") > reused
     in_use = get_registry().get("inference_kv_blocks_in_use")
     used = {labels[0]: s.value for labels, s in in_use.series()}
     assert sm.window_blocks_in_use() == used["window"] == 4 * ring
@@ -454,7 +360,7 @@ def test_ring_blocks_never_pass_rows_x_ring_and_both_pools_empty_after_flush():
     kv = eng.sequence_kv(2)
     n = len(outs[2]) - 1
     assert kv["positions"][-1] == n - 1 and len(kv["positions"]) >= WINDOW
-    want_k, want_v = reference_trinity.leading_kv(_params(), TOY,
+    want_k, want_v = reference_trinity.leading_kv(sb.params(BLOCK), TOY,
                                                   outs[2][:-1])
     at = kv["positions"]
     for got, want in ((kv["k"], want_k), (kv["v"], want_v)):
@@ -470,7 +376,7 @@ def test_ring_blocks_never_pass_rows_x_ring_and_both_pools_empty_after_flush():
     assert sm.allocator.free_blocks == free0
 
 
-def test_can_schedule_counts_both_geometries():
+def test_can_schedule_counts_both_geometries(lend):
     """A manager whose full pool is ample and whose rings are taken
     refuses a new sequence's tokens, and a row of more than its share of
     a step is no single step."""
@@ -487,7 +393,7 @@ def test_can_schedule_counts_both_geometries():
         sm.adopt_sequence(5, 2, 10, [0] * 10)
     with pytest.raises(ValueError, match="whole blocks"):
         DSStateManager(DSStateManagerConfig(block_size=8), window_ring=20)
-    eng = _engine()
+    eng = lend()
     assert eng.can_schedule([0], [8]) and not eng.can_schedule([0], [9])
     with pytest.raises(RuntimeError, match="not schedulable"):
         eng.put([0], [np.zeros(200, np.int64)])      # over max_seq_len
@@ -495,35 +401,8 @@ def test_can_schedule_counts_both_geometries():
 
 
 # ---------------------------------------------------------------------------
-# (d) what is not served over two geometries refuses at construction
+# (d) what the block's parts do not describe
 # ---------------------------------------------------------------------------
-@pytest.mark.parametrize("engine,why", [
-    ({"state_manager": {"enable_prefix_caching": True}},
-     "enable_prefix_caching"),
-    ({"state_manager": {"enable_prefix_caching": True,
-                        "enable_kv_spill": True}}, "enable_kv_spill"),
-    ({"max_lora_adapters": 2}, "max_lora_adapters"),
-    ({"quant_bits": 8}, "quant_bits"),
-    ({"tensor_parallel_size": 2}, "tensor_parallel_size"),
-], ids=["prefix-cache", "spill", "lora", "weight-quant", "tp"])
-def test_what_two_geometries_do_not_serve_refuses_at_construction(engine,
-                                                                  why):
-    cfg = TransformerConfig(**TOY)
-    with pytest.raises(NotImplementedError, match="layer_types") as e:
-        InferenceEngineV2(TransformerLM(cfg), {
-            "dtype": "float32", **engine}, params={})
-    assert why in str(e.value)
-
-
-def test_speculation_and_a_draft_model_are_refused_where_asked():
-    eng = _engine()
-    with pytest.raises(NotImplementedError, match="verify pass"):
-        eng.generate(_prompts((9,)), max_new_tokens=2, speculative=True)
-    with pytest.raises(NotImplementedError, match="verify pass"):
-        eng.load_draft_model(TransformerLM(TransformerConfig(**TOY)))
-    assert eng.state_manager.tracked_sequences() == 0
-
-
 @pytest.mark.parametrize("fields,error", [
     ({"layer_types": ["full_attention"] * 4}, ValueError),
     ({"layer_types": ["sliding_attention", "global"] + ["full_attention"] * 3},
@@ -541,26 +420,28 @@ def test_the_blocks_parts_describe_a_pattern_or_are_refused(fields, error):
 # ---------------------------------------------------------------------------
 # (e) the control, and what stays as it was
 # ---------------------------------------------------------------------------
-def test_an_int8_pool_in_both_leaves_is_the_lower_precision_control():
+def test_an_int8_pool_in_both_leaves_is_the_lower_precision_control(lend):
     """``kv_quant``: int8 keys and values in BOTH leaves (a layer
     dequantised at a time for the kernel). The same prompts read far
     over the float32 limit, and what a ring holds is off by percents."""
-    prompts = _prompts((50, 70))
-    eng = _engine(kv_quant=True)
+    prompts = sb.prompts(BLOCK, (50, 70))
+    eng = lend(kv_quant=True)
     assert eng.kv_cache["k_window"].dtype == jnp.int8 \
         and eng.kv_cache["k_full"].dtype == jnp.int8
     outs = eng.generate(prompts, max_new_tokens=4, temperature=0.0,
                         eos_token_id=None, keep_sequences=True)
     kv = eng.sequence_kv(1)
-    want_k, want_v = reference_trinity.leading_kv(_params(), TOY,
+    want_k, want_v = reference_trinity.leading_kv(sb.params(BLOCK), TOY,
                                                   outs[1][:-1])
     want = np.asarray(want_v)[:, kv["positions"]]
     assert np.linalg.norm(kv["v"][:2] - want) / np.linalg.norm(want) > 5e-3
     for uid in range(2):
         eng.flush(uid)
     got = eng.put([0, 1], prompts)
-    assert max(_err(got[i], _reference(p)[-1])
+    assert max(_err(got[i], sb.reference(BLOCK, p)[-1])
                for i, p in enumerate(prompts)) > 100 * F32_TIGHT
+    for uid in range(2):
+        eng.flush(uid)
 
 
 def test_the_old_trees_seeded_sums_are_unchanged():

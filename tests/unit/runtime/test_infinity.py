@@ -74,7 +74,12 @@ def test_infinity_loss_parity_and_files(tmp_path):
             assert engine.params is None
             ev = float(engine.eval_batch(batch=_batch(cfg, 99)))
             assert np.isfinite(ev)
-    np.testing.assert_allclose(losses["inf"], losses["std"], atol=2e-3)
+    # the streamed path's bf16 sums depend on how the host's threads fall:
+    # twelve runs side by side on a busy host (PR 60) read three outcomes,
+    # 8.9e-5 (eight runs), 9.4e-4 (three) and 2.06e-3 (one: over the old
+    # 2e-3, the failure ROADMAP D3 named); a step moves the loss by 2e-2,
+    # so a lost update still reads over three times this limit
+    np.testing.assert_allclose(losses["inf"], losses["std"], atol=5e-3)
 
 
 @pytest.mark.slow  # tier-1 sibling: test_infinity_loss_parity_and_files (same streamed update; nvme tier = dir-backed host path)
