@@ -46,8 +46,9 @@ from .kernels.ragged_attention import (LATENT, decode_positions,
                                        one_token_tile_serves, prompt_chunks,
                                        token_tile, token_tile_serves)
 from .paged_model import (STATE_LEAVES, init_lora_bank, init_paged_kv_cache,
-                          paged_continue, paged_decode, paged_decode_window,
-                          paged_ragged_step, paged_spec_decode_window)
+                          moe_rows_form, paged_continue, paged_decode,
+                          paged_decode_window, paged_ragged_step,
+                          paged_spec_decode_window)
 from .ragged import batch as ragged_batch
 from .ragged.blocked_allocator import NULL_BLOCK
 from .ragged.ragged_manager import DSStateManager
@@ -611,13 +612,19 @@ class InferenceEngineV2:
     # ------------------------------------------------------------------
     # Telemetry (unified registry, telemetry/registry.py)
     # ------------------------------------------------------------------
-    def _note_moe(self, program: str, stats=None) -> None:
+    def _note_moe(self, program: str, tokens: int, stats=None) -> None:
         """What the launch's expert layers routed (the latent programs'
         middle output, ``paged_model._pattern_step``, fetched with the
-        launch's own result) into the registry's counters."""
+        launch's own result) into the registry's counters. ``tokens``:
+        the flat tokens an expert layer of the program takes, which say
+        how its routed rows came back from expert order
+        (``paged_model.moe_rows_form``)."""
         if stats is None:
             return
         launches, rows, touched, share = (float(v) for v in stats)
+        self._m_moe_combined.labels(
+            program=program, form=moe_rows_form(
+                self.model.cfg, tokens, self.dtype)).inc(rows)
         self._m_moe_launches.labels(program=program).inc(launches)
         self._m_moe_form_launches.labels(
             program=program, form=self.model.cfg.moe_expert_form).inc(
@@ -644,6 +651,13 @@ class InferenceEngineV2:
             "moe_routed_rows_total",
             "rows routed to experts (valid tokens x top-k), summed over "
             "expert layers and launches", labelnames=("program",))
+        self._m_moe_combined = reg.counter(
+            "moe_rows_combined_total",
+            "moe_routed_rows_total by the form that brought the rows back "
+            "from expert order, chosen from the launch's shape: kernel "
+            "(moe_rows_whole + moe_rows_combine: a share's prompt launch "
+            "on a TPU) or gather (XLA's: every other launch)",
+            labelnames=("program", "form"))
         self._m_moe_touched = reg.counter(
             "moe_experts_touched_total",
             "distinct experts with at least one row, summed over expert "
@@ -1615,7 +1629,7 @@ class InferenceEngineV2:
         with trace.span("step_bookkeeping"):
             dt = step["duration_s"]
             self._m_host_syncs.inc()
-            self._note_moe("decode_step", *moe)
+            self._note_moe("decode_step", active.shape[0], *moe)
             if self._has_state:
                 self._m_state_rows.labels(program="decode_step").inc(
                     len(uids))
@@ -1858,7 +1872,7 @@ class InferenceEngineV2:
         with trace.span("window_bookkeeping"):
             win.out = win.moe = win.state = None
             self._m_host_syncs.inc()
-            self._note_moe("decode_window", *moe)
+            self._note_moe("decode_window", out.shape[0], *moe)
             if self._has_state:
                 self._m_state_rows.labels(program="decode_window").inc(
                     sum(win.steps_left))
@@ -2061,7 +2075,7 @@ class InferenceEngineV2:
             # inference_ragged_step_seconds: the pack and the launch, to
             # the logits' arrival (the two spans' own durations)
             dt = packed["duration_s"] + step["duration_s"]
-            self._note_moe("ragged_step", *moe)
+            self._note_moe("ragged_step", len(rb.ids), *moe)
             self._note_prompt_chunks(entries, rb)
             if self._has_state:
                 self._m_state_rows.labels(program="ragged_step").inc(

@@ -449,6 +449,26 @@ def _held_from(cfg):
         if cfg.experts_held < cfg.moe_num_experts else None
 
 
+def moe_rows_form(cfg, tokens: int, dtype) -> str:
+    """How an expert layer's launch of ``tokens`` flat tokens brings its
+    routed rows back from expert order: ``"kernel"``
+    (``kernels/expert_combine.rows_combine``) or ``"gather"`` (XLA's
+    lines in ``dropless_topk_dispatch``), from the shape of ONE dispatch
+    (a share's launch over a run goes through in runs), the experts'
+    type and whether the tree holds a share of them. What
+    ``_moe_experts`` traces and what the engine counts
+    (``moe_rows_combined_total``)."""
+    from .kernels.expert_combine import rows_combine_serves
+
+    share = _held_from(cfg) is not None
+    if share:
+        tokens = min(tokens, _share_tokens(
+            jax.ShapeDtypeStruct((tokens, cfg.hidden_size), dtype),
+            cfg.moe_top_k))
+    return "kernel" if rows_combine_serves(
+        tokens * cfg.moe_top_k, cfg.hidden_size, dtype, share) else "gather"
+
+
 def _moe_route(cfg, lp, xt, router_precision=None):
     """The ROUTING half of the ep = 1 expert layer on flat tokens ``xt``
     [T, H], what the router reads: (the chosen experts [T, k], their
@@ -490,6 +510,7 @@ def _moe_experts(cfg, lp, xt, topi, topv, experts=None, stack_layer=None):
     ``lp``'s own."""
     from ...moe.sharded_moe import (dropless_topk_dispatch, expert_forms,
                                     gmm_serves)
+    from .kernels.expert_combine import rows_combine
 
     xt = xt.astype(lp["moe_gate_w"].dtype)
     with jax.named_scope("moe_experts"):
@@ -499,11 +520,16 @@ def _moe_experts(cfg, lp, xt, topi, topv, experts=None, stack_layer=None):
         # the router scores every expert; this tree may hold a share of
         # them (cfg.moe_experts_held from cfg.moe_experts_first), and a
         # pick that is held elsewhere adds nothing here
+        # the rows come back through the kernels where the launch is
+        # one they serve (a share's prompt launch), else by XLA's gather
+        kernel = moe_rows_form(cfg, xt.shape[0], xt.dtype) == "kernel"
+
         def dispatch(xt, topi, topv):
             return dropless_topk_dispatch(
                 xt, topi, topv, experts, cfg.experts_held,
                 gmm if gmm_serves(experts) else ragged,
-                stack_layer=stack_layer, held_from=_held_from(cfg))
+                stack_layer=stack_layer, held_from=_held_from(cfg),
+                rows_combine=rows_combine if kernel else None)
 
         T, run = xt.shape[0], _share_tokens(xt, cfg.moe_top_k)
         if _held_from(cfg) is not None and T > run:

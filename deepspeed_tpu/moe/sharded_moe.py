@@ -398,7 +398,7 @@ def topk_routing(logits, k: int, scoring: str = "softmax", bias=None,
 
 def dropless_topk_dispatch(xt, topi, topv, expert_params, num_experts: int,
                            ragged_expert_fn=None, stack_layer=None,
-                           held_from=None):
+                           held_from=None, rows_combine=None):
     """Sorted-token grouped-GEMM core shared by the training dropless MoE
     and the v2 serving path (_moe_mlp): route every (token, choice) row to
     its expert with one argsort + `jax.lax.ragged_dot`, bring the rows
@@ -418,6 +418,21 @@ def dropless_topk_dispatch(xt, topi, topv, expert_params, num_experts: int,
     613.7 where the gather INTO expert order of the same 168 MB a run is
     73.9; the reshapes 219.2).
 
+    The rows come back in one of TWO FORMS. The gather above is XLA's
+    and is what every caller that hands nothing gets: the training side,
+    which differentiates the dispatch, and a decode step, whose few
+    hundred rows XLA gathers out of fast memory in microseconds.
+    ``rows_combine`` (``(ys, inv, held, topv, rows_held) -> [T, H]``:
+    ``inference/v2/kernels/expert_combine.rows_combine``) takes the
+    experts' output where it lies in HBM and does the gather, the mask,
+    the weights and the sum itself, products in float32; the serving
+    path hands it for the launches whose SHAPE it serves
+    (``expert_combine.rows_combine_serves``: on a TPU, a share
+    (``held_from``) of bfloat16 rows of whole lane blocks, an even count
+    of at least ``MIN_ROWS``: a share's prompt launch, whose rows held
+    elsewhere are then neither relaid nor copied), the way it hands the
+    grouped matmul in place of ``ragged_dot``. No option chooses.
+
     ``stack_layer`` (a traced scalar): ``expert_params`` are a whole
     scanned stack's, [L, E, ...], and the layer is chosen by WHERE its
     groups lie among L * E (every other group is empty), so that the
@@ -432,6 +447,7 @@ def dropless_topk_dispatch(xt, topi, topv, expert_params, num_experts: int,
     T, H = xt.shape
     k = topi.shape[-1]
     idx = topi.T.reshape(-1)                     # [k*T], pick-major
+    held = None
     if held_from is not None:
         idx = idx - held_from
         held = (idx >= 0) & (idx < num_experts)
@@ -454,9 +470,21 @@ def dropless_topk_dispatch(xt, topi, topv, expert_params, num_experts: int,
     # permutation is its argsort (a sort of k*T int32 is a quarter of the
     # time of their scatter on a TPU). The rows are a permutation's, so
     # the gather's transpose is a plain row scatter
-    rows = ys.at[jnp.argsort(order)].get(
-        unique_indices=True, mode="promise_in_bounds").reshape(k, T, H)
-    if held_from is not None:
+    inv = jnp.argsort(order)
+    if rows_combine is not None:
+        return rows_combine(ys, inv, held, topv, jnp.sum(group_sizes))
+    return gather_rows_combine(ys, inv, held, topv)
+
+
+def gather_rows_combine(ys, inv, held, topv):
+    """The experts' output ``ys`` [k T, H] (expert order) back as [T, H]
+    by XLA: one row gather through ``inv`` [k T] (where each pick-major
+    row lies) to ``[k, T, H]``, the picks ``held`` [k T] elsewhere (None:
+    none) masked, weighted by ``topv`` [T, k] and summed."""
+    T, k = topv.shape
+    rows = ys.at[inv].get(
+        unique_indices=True, mode="promise_in_bounds").reshape(k, T, -1)
+    if held is not None:
         # rows past the last group hold whatever the grouped matmul
         # left, finite or not: a zero weight would not do
         rows = jnp.where(held.reshape(k, T, 1), rows, 0)

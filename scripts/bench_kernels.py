@@ -39,6 +39,24 @@ is what a decode program pays since PR 57 (the kernel colours its leaf HBM:
 fits, lies in the chip's fast memory from launch to launch and the time is
 the kernel's from THERE).
 
+THE EXPERTS' DISPATCH (PR 61), ``dispatch-rows``: how the routed rows come
+back from expert order (``moe/sharded_moe.dropless_topk_dispatch``'s last
+lines), alone, at the sparse cells' launches: granite's, nemotron's and
+ling's runs (a share: half to three quarters of the picks held elsewhere,
+the rows past the last group NaN), smallthinker's and trinity-mini's chunk
+steps, and the three shares' decode steps. ``xla`` is the gather and the
+fusion every caller had before PR 61 (``gather_rows_combine``), ``kernel``
+``kernels/expert_combine.rows_combine`` (two launches), ``whole`` its first
+launch alone (the pairs made one piece each), ``copies`` the two with the
+arithmetic taken out, ``no-skip`` with the picks held elsewhere relaid and
+copied too (``--sweep``: ``starts-N`` / ``sums-N``, the copies started and
+the tokens summed a trip of the kernel's loops). Beside the time: the rows, the picks held here (``copy_starts``)
+and the bytes no form can go under (the held rows read once, the result
+written once). The experts' output is carried through the loop and a row of
+it touched a launch, so that no launch's work is the loop's invariant.
+``expert_combine.MIN_ROWS`` is read off this table: the row count from which
+``kernel`` is under ``xla``.
+
 The variants take the walk apart by replacing one function of
 ``kernels/ragged_attention.py`` in this process (nothing a cell runs is
 touched, and no option of the program exists for it):
@@ -86,6 +104,7 @@ says the script runs and nothing about time.
 
 import argparse
 import contextlib
+import functools
 import importlib
 import json
 import os
@@ -103,6 +122,11 @@ from jax.experimental.pallas import tpu as pltpu              # noqa: E402
 ra = importlib.import_module(                                 # noqa: E402
     "deepspeed_tpu.inference.v2.kernels.ragged_attention")
 from deepspeed_tpu.inference.v2.kernels import state_space as ss  # noqa: E402
+try:                            # a tree before PR 61: its shapes skip
+    from deepspeed_tpu.inference.v2.kernels import (      # noqa: E402
+        expert_combine as ec)
+except ImportError:
+    ec = None
 from deepspeed_tpu.inference.v2.kernels import (          # noqa: E402
     linear_attention as la)
 try:                            # a tree before the kind: its shapes skip
@@ -180,9 +204,77 @@ SHAPES = {
                          parts=1, bias=True, name="ssm_conv_update"),
     "ling-conv": dict(kernel="conv", rows=128, layers=7, width=12288,
                       parts=3, bias=False, name="kda_conv_update"),
+    # how the routed rows come back from expert order: ``tokens`` of k
+    # picks over ``experts`` of which the first ``held`` are held here
+    "granite-dispatch": dict(kernel="dispatch", tokens=2048, k=10, H=4096,
+                             experts=72, held=36),
+    "smallthinker-dispatch": dict(kernel="dispatch", tokens=16384, k=6,
+                                  H=2560, experts=64, held=64),
+    "trinity-dispatch": dict(kernel="dispatch", tokens=16384, k=8, H=2048,
+                             experts=128, held=128),
+    "nemotron-dispatch": dict(kernel="dispatch", tokens=4096, k=6, H=2688,
+                              experts=128, held=64),
+    "ling-dispatch": dict(kernel="dispatch", tokens=4096, k=8, H=2560,
+                          experts=512, held=128),
+    "granite-dispatch-decode": dict(kernel="dispatch", tokens=64, k=10,
+                                    H=4096, experts=72, held=36),
+    "nemotron-dispatch-decode": dict(kernel="dispatch", tokens=128, k=6,
+                                     H=2688, experts=128, held=64),
+    "ling-dispatch-decode": dict(kernel="dispatch", tokens=128, k=8,
+                                 H=2560, experts=512, held=128),
 }
+# the family's name stands for its shapes under --only
+FAMILIES = {"dispatch-rows": [n for n, v in SHAPES.items()
+                              if v["kernel"] == "dispatch"]}
 BS = 16
 EPS = 1e-6
+
+
+def build_dispatch(shape, rng, rehearse):
+    """One dispatch's way back: ``(fn(topv, _, ys) -> (out, ys), again,
+    topv, (ys,), 1, bytes a launch, reference)``. Every token picks k
+    distinct experts at random; ``ys`` is in expert order as the stable
+    sort of the pick-major keys leaves it, its rows past the last group
+    NaN. ``fn.rows`` / ``fn.held``: the dispatch's rows and those held
+    here."""
+    from deepspeed_tpu.moe.sharded_moe import gather_rows_combine
+    T, k, H, E, here = (shape[n] for n in
+                        ("tokens", "k", "H", "experts", "held"))
+    if rehearse:
+        T, H = 24, 256
+    topi = np.argsort(rng.random((T, E)), axis=1)[:, :k]
+    idx = topi.T.reshape(-1)
+    held = idx < here
+    order = np.argsort(np.where(held, idx, here), kind="stable")
+    inv = jnp.asarray(np.argsort(order), jnp.int32)
+    n_held = int(held.sum())
+    held = None if here == E else jnp.asarray(held)
+    key = jax.random.PRNGKey(int(rng.integers(1 << 30)))
+    ys = jax.random.normal(key, (k * T, H), jnp.bfloat16)
+    ys = jnp.where(jnp.arange(k * T)[:, None] < n_held, ys, jnp.nan)
+    topv = jax.random.uniform(jax.random.fold_in(key, 1), (T, k))
+
+    def touched(out, ys):
+        # the loop carries ``ys`` and a launch rewrites one value of it
+        return out, ys.at[0, 0].add((out[0, 0] * 0).astype(ys.dtype))
+
+    def fn(topv, _, ys):
+        return touched(ec.rows_combine(
+            ys, inv, held, topv, jnp.int32(n_held), interpret=rehearse), ys)
+
+    def ref(topv, _, ys):
+        rows = ys.astype(jnp.float32)[inv].reshape(k, T, H)
+        if held is not None:
+            rows = jnp.where(held.reshape(k, T, 1), rows, 0)
+        return touched(jnp.sum(rows * topv.T[..., None], axis=0).astype(
+            ys.dtype), ys)
+
+    def again(topv, out):
+        return topv + out[:1, :k].astype(jnp.float32) * 0
+    fn.rows, fn.held = k * T, n_held
+    fn.xla = lambda ys, inv, held, topv, rows_held, interpret=False: \
+        gather_rows_combine(ys, inv, held, topv)
+    return fn, again, topv, (ys,), 1, 2 * H * (n_held + T), ref
 
 
 def build_retention(shape, rng, rehearse):
@@ -555,7 +647,32 @@ def _conv_copies(held, x, w_ref, b_ref):
 _FORI = jax.lax.fori_loop
 
 
-def variants(kernel, sweep):
+def _whole_alone(ys, inv, held, topv, rows_held=None, interpret=False):
+    """:func:`ec.rows_combine`'s first launch alone."""
+    return ec.rows_whole(ys, rows_held, interpret)[0].astype(jnp.float32)
+
+
+def variants(kernel, sweep, fn=None):
+    if kernel == "dispatch":
+        # the function under its own jit: that jit's cache would hand a
+        # variant the trace of the one before it
+        full = ec._rows_combine
+        out = {"xla": dict(rows_combine=fn.xla),
+               "kernel": dict(rows_combine=full),
+               "whole": dict(rows_combine=_whole_alone),
+               "copies": dict(rows_combine=functools.partial(
+                   full, arithmetic=False)),
+               "no-skip": dict(rows_combine=functools.partial(
+                   full, skip=False))}
+        if sweep:
+            for n in (8, 16):
+                out[f"starts-{n}"] = dict(_STARTS=n, rows_combine=full)
+                out[f"starts-{n}+copies"] = dict(
+                    _STARTS=n, rows_combine=functools.partial(
+                        full, arithmetic=False))
+            for n in (2, 4):
+                out[f"sums-{n}"] = dict(_SUMS=n, rows_combine=full)
+        return out
     if kernel == "ssm_state":
         return {"full": {}, "copies": dict(_state_kernel=_state_copies)}
     if kernel == "conv":
@@ -644,7 +761,8 @@ def main():
     elif jax.default_backend() != "tpu":
         sys.exit(f"needs a TPU, found {jax.default_backend()!r} "
                  "(--rehearse runs toy sizes on the CPU)")
-    names = [n for n in args.only.split(",") if n] or list(SHAPES)
+    names = [m for n in args.only.split(",") if n
+             for m in FAMILIES.get(n, [n])] or list(SHAPES)
     wanted = [int(c) for c in args.contexts.split(",") if c]
     chosen = [v for v in args.variants.split(",") if v]
     # a prompt shape is a row of the output a context
@@ -658,11 +776,16 @@ def main():
             print(json.dumps({"shape": name, "skipped": "no "
                               "kernels/power_retention.py in this tree"}))
             continue
+        if kernel == "dispatch" and ec is None:
+            print(json.dumps({"shape": name, "skipped": "no "
+                              "kernels/expert_combine.py in this tree"}))
+            continue
         # the kernels that update a state leaf in place (their builder,
         # the module a variant patches): the loop carries the leaf
         in_place = {"ssm_state": (build_state, ss), "conv": (build_conv, la),
                     "retention_state": (build_retention, pr),
-                    "retention_chunk": (build_retention, pr)}
+                    "retention_chunk": (build_retention, pr),
+                    "dispatch": (build_dispatch, ec)}
         state = kernel in in_place
         builder, module = in_place.get(kernel, (build, ra))
         if kernel == "prompt":
@@ -675,6 +798,8 @@ def main():
             fn, again, q, pools, L, nbytes, ref = builder(
                 SHAPES[name], rng, args.rehearse)
             row, launches = {"shape": name}, args.launches
+            if kernel == "dispatch":
+                row.update(rows=fn.rows, copy_starts=fn.held)
         row.update({"bytes": nbytes,
                     "bytes_us": round(nbytes / PEAK_BYTES_S * 1e6, 2)})
         if args.check:
@@ -687,12 +812,14 @@ def main():
                 for f in (fn, ref))
             if kernel == "prompt":
                 got = [got[0][ref.tokens]]
+            if kernel == "dispatch":     # the carried ys holds NaN rows
+                got, want = got[:1], want[:1]
             row["max_err"] = max(float(jnp.abs(g - w).max())
                                  for g, w in zip(got, want))
             row["rel_err"] = max(
                 float(jnp.linalg.norm(g - w) / jnp.linalg.norm(w))
                 for g, w in zip(got, want))
-        for label, attrs in variants(kernel, args.sweep).items():
+        for label, attrs in variants(kernel, args.sweep, fn).items():
             if chosen and label not in chosen:
                 continue
             try:

@@ -16,7 +16,8 @@ scope) that the ``prefill_*_ms.gen`` metrics read
 (``deepspeed_tpu.utils.xla_profile.scope_seconds``), then the call's
 idle gaps between launches, each by the leaf span the host was in
 (``gap_*_ms.gen``'s arithmetic, gap by gap), what the garbage
-collector did (``process_gc_*``, the ``gc_pause`` spans), then the cell's
+collector did (``process_gc_*``, the ``gc_pause`` spans), which form brought
+the routed rows back (``moe_rows_combined_total``), then the cell's
 per-layer metrics as ``benchmark/run.py`` would print them. A trace names
 an operation by its HLO instruction, and only the process that compiled
 the programs can say what scope an instruction was traced under
@@ -194,6 +195,20 @@ def collector_summary():
                if longest else ""))
 
 
+def rows_forms_summary():
+    """Which form brought the expert layers' routed rows back from
+    expert order, by program, over the whole process
+    (``moe_rows_combined_total{program, form}``: ``kernel`` is
+    ``kernels/expert_combine.py``'s two launches, ``gather`` XLA's)."""
+    from deepspeed_tpu.telemetry import get_registry
+    combined = get_registry().get("moe_rows_combined_total")
+    if combined is None or not combined.series():
+        return "routed rows by form: no expert layer ran"
+    return "routed rows by form: " + ", ".join(
+        f"{program} {form} {int(c.value)}"
+        for (program, form), c in sorted(combined.series()))
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
@@ -247,6 +262,7 @@ def main(argv=None):
     print(render(listed, sums, args.program, args.top), flush=True)
     print(render_gaps(idle_gaps(ev)), flush=True)
     print(collector_summary(), flush=True)
+    print(rows_forms_summary(), flush=True)
     manifest = json.loads((REPO / "BENCHMARK.json").read_text())
     metrics = bench.layer_metrics(
         bench.reported_by(manifest, args.workload, "per_layer"), ev,

@@ -99,6 +99,47 @@ def test_the_toys_cache_keeps_a_leaf_a_kind_by_its_layers(lend):
         and eng.kv_cache["k_full"].shape[0] == 2
 
 
+def test_the_routed_rows_are_counted_by_the_form_that_brought_them_back(
+        lend, monkeypatch):
+    """``moe_rows_combined_total{program, form}``: what a launch routed,
+    under the form its static shape selects
+    (``paged_model.moe_rows_form``, no device read). Off the TPU that is
+    the gather for every program: a ``put()`` raises it by what
+    ``moe_routed_rows_total`` rises; where the shape says kernel (a TPU's
+    share of bfloat16 rows, a prompt's launch) the rows count there."""
+    from deepspeed_tpu.inference.v2 import engine_v2
+    eng = lend()
+    reg = get_registry()
+
+    def read(name, **labels):
+        return reg.get(name).labels(**labels).value
+
+    def combined(program):
+        return {form: read("moe_rows_combined_total", program=program,
+                           form=form) for form in ("kernel", "gather")}
+
+    before = combined("ragged_step")
+    routed = read("moe_routed_rows_total", program="ragged_step")
+    uid, = sb.uids(1)
+    eng.put([uid], sb.prompts(BLOCK, (9,)))
+    eng.flush(uid)
+    routed = read("moe_routed_rows_total", program="ragged_step") - routed
+    after = combined("ragged_step")
+    assert routed > 0 and after["gather"] - before["gather"] == routed
+    assert after["kernel"] == before["kernel"]
+    monkeypatch.setattr(
+        engine_v2, "moe_rows_form",
+        lambda cfg, tokens, dtype: "kernel" if tokens >= 2048 else "gather")
+    stats = np.asarray([7.0, 1000.0, 8.0, 0.5], np.float32)
+    for program, tokens, form in (("ragged_step", 16384, "kernel"),
+                                  ("decode_window", 128, "gather")):
+        was = combined(program)
+        eng._note_moe(program, tokens, stats)
+        now = combined(program)
+        assert {f: now[f] - was[f] for f in now} == {
+            form: 1000.0, {"kernel": "gather", "gather": "kernel"}[form]: 0.0}
+
+
 @pytest.mark.parametrize("fields,word", [
     ({"mamba_n_groups": 3}, "whole multiple of mamba_n_groups"),
     ({"mamba_n_groups": 0}, "whole multiple of mamba_n_groups"),

@@ -616,6 +616,8 @@ def test_latent_ragged_step_compiles_with_its_experts_in_place(tpu_sharding):
     kernels = re.findall(r"%([\w.\-]+) = [^\n]*tpu_custom_call", text)
     assert sum(bool(LATENT_PATTERN.search(k)) for k in kernels) == 2, kernels
     assert sum(bool(GMM_PATTERN.search(k)) for k in kernels) == 3, kernels
+    # every expert is held: the rows come back by XLA's gather
+    assert not any(k.startswith("moe_rows") for k in kernels), kernels
     made = re.findall(r"%[\w.\-]+ = bf16\[(?:1,)?256,(?:2048,768|768,2048)\]"
                       r"\S* (\w[\w\-]*)\(", text)
     assert set(made) <= {"parameter", "bitcast", "get-tuple-element"}, made
@@ -771,6 +773,8 @@ def test_the_hybrid_ragged_step_runs_its_chunks_in_the_kernel(tpu_sharding):
     kernels = re.findall(r"%([\w.\-]+) = [^\n]*tpu_custom_call", text)
     assert sum(k.startswith("kda_chunk_fwd") for k in kernels) == 2, kernels
     assert sum(bool(GMM_PATTERN.search(k)) for k in kernels) == 3, kernels
+    assert sum(k.startswith("moe_rows_combine") for k in kernels) == 1, \
+        kernels
     assert "triangular-solve" not in text and "triangular_solve" not in text
     assert "kda_chunk/while" not in text
     assert compiled.memory_analysis().temp_size_in_bytes <= 504_550_912
@@ -1256,6 +1260,46 @@ def test_an_expert_wider_than_a_tile_is_tiled_by_columns():
     assert moe.gmm_serves((w(36, 4096, 768), w(36, 4096, 768),
                            w(36, 768, 4096)))
     assert not moe.gmm_serves((w(36, 4096, 100),))
+
+
+# a prompt's dispatch of the four sparse cells whose widths differ:
+# tokens (a share's run, or the whole step), picks, width, a share
+DISPATCH_RUNS = {"granite": (2048, 10, 4096, True),
+                 "smallthinker": (16384, 6, 2560, False),
+                 "trinity-mini": (16384, 8, 2048, False),
+                 "nemotron": (4096, 6, 2688, True)}
+
+
+@pytest.mark.parametrize("cell", sorted(DISPATCH_RUNS))
+def test_the_rows_come_back_through_the_kernels_at_the_cells_runs(
+        tpu_sharding, cell):
+    """``expert_combine.rows_combine`` at a cell's run (granite's 2,048
+    tokens x 10 picks x 4,096 with half the picks held elsewhere; a
+    width of 20 and of 21 lane blocks; the two cells that hold every
+    expert, whose launches the rule leaves to the gather, as the bench
+    times them): it compiles for the chip as two
+    custom calls under names that are not the grouped matmul's, and what
+    the program makes besides its result is the pairs, not a gathered
+    ``[k, T, H]`` beside them."""
+    from deepspeed_tpu.inference.v2.kernels import expert_combine as ec
+
+    T, k, H, share = DISPATCH_RUNS[cell]
+    assert ec.rows_combine_serves(k * T, H, jnp.bfloat16, share) == share
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=tpu_sharding)
+
+    compiled = jax.jit(lambda ys, inv, held, topv, n: ec.rows_combine(
+        ys, inv, held if share else None, topv, n if share else None)).lower(
+        sds((k * T, H), jnp.bfloat16), sds((k * T,), jnp.int32),
+        sds((k * T,), jnp.bool_), sds((T, k), jnp.float32),
+        sds((), jnp.int32)).compile()
+    kernels = _custom_calls(compiled)
+    assert [n.rstrip(".0123456789") for n in kernels] == [
+        "moe_rows_whole", "moe_rows_combine"], kernels
+    assert not any(GMM_PATTERN.search(n) for n in kernels)
+    pairs = k * T // 2 * -(-H // 1024) * 1024 * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < pairs + 2 ** 20
 
 
 def _state_space_cut(tpu_sharding):
