@@ -516,7 +516,7 @@ class InferenceEngineV2:
                 {k: v for k, v in self.kv_cache.items()
                  if k in STATE_LEAVES}))
             self._m_ssm_groups.set(
-                cfg.mamba_n_groups if "ssm" in cfg.layer_kinds else 0)
+                cfg.mamba_n_groups if cfg.leaf_places("ssm") else 0)
             for kind in ("full", "window"):
                 self._m_pool_bytes.labels(kind=kind).set(
                     ds_memory.tree_bytes({

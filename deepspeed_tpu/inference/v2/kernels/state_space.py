@@ -71,8 +71,9 @@ from .slot_leaf import hbm_out, in_hbm
 
 CHUNK = 256
 _HI = jax.lax.Precision.HIGHEST
-# channel groups (of 128 lanes) of one row a decode grid step takes: 16
-# states of [128, 128] float32 are 1 MB in and 1 MB out, double-buffered 4
+# channel groups (of 128 lanes) of one row a decode grid step takes at
+# most: 16 states of [128, 128] float32 are 1 MB in and 1 MB out,
+# double-buffered 4 (``_blocks_a_step``: fewer where a state is larger)
 GROUPS_A_STEP = 16
 # channel groups a grid step of the chunk kernel takes
 CHUNK_GROUPS = 4
@@ -133,6 +134,16 @@ def ssm_step(leaf, layer, slots, fresh, x, dt, a, b, c):
     return y, leaf.at[layer, slots].set(s.astype(leaf.dtype))
 
 
+def _blocks_a_step(blocks, d_state):
+    """Lane blocks of one row a decode grid step takes, by the leaf's
+    shape: ``GROUPS_A_STEP`` of them at a state of 128 a channel and
+    under, and as many as keep a step's states at that size (1 MB in
+    float32) above it: 8 at a state of 256, whose 16 would be 8 MB
+    double-buffered in and out against a scoped default of 16."""
+    return min(GROUPS_A_STEP, blocks,
+               max(GROUPS_A_STEP * 128 // d_state, 1))
+
+
 def _blocks_a_group(blocks, step, groups):
     """Lane blocks that read one B and C, where ``blocks`` lane blocks
     in grid steps of ``step`` are served under ``groups`` groups: a
@@ -151,11 +162,10 @@ def state_kernel_serves(leaf, groups=1) -> bool:
     block's state whole (8, 128) tiles, the lane blocks whole grid
     steps, no lane block astride two groups."""
     g, n, w = leaf.shape[2:]
+    gb = _blocks_a_step(g, n)
     return (jax.default_backend() == "tpu" and w == 128 and n % 8 == 0
-            and g % min(GROUPS_A_STEP, g) == 0
-            and min(GROUPS_A_STEP, g) % 8 == 0
-            and _blocks_a_group(g, min(GROUPS_A_STEP, g), groups)
-            is not None)
+            and g % gb == 0 and gb % 8 == 0
+            and _blocks_a_group(g, gb, groups) is not None)
 
 
 def _state_kernel(layer_ref, slots_ref, fresh_ref, s_ref, decay_ref, dtx_ref,
@@ -187,7 +197,8 @@ def ssm_state_update(leaf, layer, slots, fresh, x, dt, a, b, c,
     the one rule of every slot leaf a kernel updates in place, whether
     or not it could fit anywhere else), and a grid step copies in
     ``GROUPS_A_STEP`` channel groups of row n's slot ``slots[n]`` at
-    ``layer`` (both prefetched scalars), puts them through the token and
+    ``layer`` (both prefetched scalars; ``_blocks_a_step`` of them: 16
+    at a state of 128, 8 at 256), puts them through the token and
     copies them back to where they came from (aliased): a state is read
     once and written once, where a gather, the update and a scatter
     move it three times. B and C [N, groups * d_state] go in at their
@@ -198,7 +209,7 @@ def ssm_state_update(leaf, layer, slots, fresh, x, dt, a, b, c,
     N, C = x.shape
     G, n, W = leaf.shape[2:]
     d_head = C // dt.shape[-1]
-    gb = min(GROUPS_A_STEP, G)
+    gb = _blocks_a_step(G, n)
     per = _blocks_a_group(G, gb, b.shape[1] // n)
     pairs = max(gb // per, 1)           # groups of B and C a grid step
     span = max(gb, per)                 # lane blocks a block of pairs serves
@@ -418,7 +429,11 @@ def _chunk_kernel(layer_ref, slots_ref, fresh_ref, starts_ref, counts_ref,
                     parts = (jnp.exp(col), jnp.exp(last),
                              dtx * jnp.exp(last - col))
                     if mine is None:
-                        grow, keep, left = parts
+                        # a head a lane block: its scalars over the lanes
+                        # here, one axis at a time (Mosaic spreads no
+                        # [1, 1] over sublanes and lanes at once)
+                        grow, _, left = parts
+                        keep = jnp.exp(jnp.broadcast_to(last, (1, W)))
                     else:
                         grow, keep, left = (
                             jnp.where(mine, v, acc) for v, acc in

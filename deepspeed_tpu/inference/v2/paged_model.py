@@ -112,8 +112,10 @@ def init_paged_kv_cache(cfg: TransformerConfig, num_blocks: int,
 
     if cfg.layer_types is not None:
         kinds = cfg.layer_kinds
-        return {**(leaves(kinds.count("full"), num_blocks, "_full")
-                   if "full" in kinds else {}),
+        # a two-mixer layer owns a place in the full pool AND one in
+        # the state-space leaves (``cfg.leaf_places``)
+        full = cfg.leaf_places("full")
+        return {**(leaves(full, num_blocks, "_full") if full else {}),
                 **(leaves(kinds.count("window"), window_blocks, "_window")
                    if "window" in kinds else {}),
                 **_init_ssm_state(cfg, state_slots, state_dtype),
@@ -148,15 +150,16 @@ def _init_retention_state(cfg, state_slots, state_dtype):
 
 
 def _init_ssm_state(cfg, state_slots, state_dtype):
-    """The state-space (Mamba-2) layers' recurrent state, beside a
-    per-head pool for the first time: ``ssm_state`` ``[L_ssm, slots + 1,
+    """The state-space (Mamba-2) layers' recurrent state (and the
+    two-mixer layers', whose attention half has a place in the full pool
+    as well), beside a per-head pool: ``ssm_state`` ``[L_ssm, slots + 1,
     channels / 128, d_state, 128]`` (a channel of a head a lane:
     ``kernels/state_space.state_leaf_shape``) and ``ssm_conv``
     ``[L_ssm, slots + 1, taps - 1, (channels + 2 d_state) / 128, 128]``
     (the convolution's last inputs of x, B and C:
     ``linear_attention.conv_leaf_shape``), both in ``state_dtype``,
     slot 0 the null slot."""
-    n = cfg.layer_kinds.count("ssm")
+    n = cfg.leaf_places("ssm")
     if not n:
         return {}
     from .kernels.linear_attention import conv_leaf_shape
@@ -849,8 +852,13 @@ def _per_head_attention_sublayer(cfg, lp, x, kind, l, pool, cos, sin,
     pool's is (``_latent_rows``). ``one_token``: every row has exactly
     one token (a decode batch), which the kernel is told. ``hn``:
     ``norm(x, attn_norm)`` where the caller has made it already (a
-    router ahead of the mixer reads it too). Returns (what attention
-    adds to x, pool)."""
+    router ahead of the mixer reads it too, and so does the other half
+    of a two-mixer layer). The block's multipliers, where it has them:
+    ``cfg.attn_in_scale`` on the normed input (taken on each
+    projection's float32 sum, which is linear in it) and
+    ``cfg.key_scale`` on the keys, ahead of the rotation, so that the
+    pool holds the keys the source caches. Returns (what attention adds
+    to x, pool)."""
     from ...ops.norms import rms_norm
     from .kernels.ragged_attention import (ragged_attention,
                                            ragged_attention_reference)
@@ -860,18 +868,22 @@ def _per_head_attention_sublayer(cfg, lp, x, kind, l, pool, cos, sin,
     if hn is None:
         hn = _norm(cfg, x, lp["attn_norm"])
     hn = hn.astype(dt)
+
+    def proj(w, scale):
+        # a multiplier is taken on the matmul's float32 sum before its
+        # one rounding
+        return hn @ lp[w] if scale == 1.0 else (jnp.dot(
+            hn, lp[w], preferred_element_type=jnp.float32) * scale).astype(dt)
+
+    s_in = cfg.attn_in_scale
     with jax.named_scope("qkv_proj"):
-        if cfg.attn_scale:
-            # the kernels score q k^T over sqrt(hd): a model that
-            # scales its scores otherwise has the ratio in its queries,
-            # taken on the matmul's float32 sum before its one rounding
-            q = (jnp.dot(hn, lp["wq"], preferred_element_type=jnp.float32)
-                 * (cfg.attn_scale * hd ** 0.5)).astype(dt)
-        else:
-            q = hn @ lp["wq"]
+        # the kernels score q k^T over sqrt(hd): a model that scales
+        # its scores otherwise has the ratio in its queries
+        q = proj("wq", s_in * (cfg.attn_scale * hd ** 0.5
+                               if cfg.attn_scale else 1.0))
         q = q.reshape(T, nh, hd)
-        k = (hn @ lp["wk"]).reshape(T, nkv, hd)
-        v = (hn @ lp["wv"]).reshape(T, nkv, hd)
+        k = proj("wk", s_in * cfg.key_scale).reshape(T, nkv, hd)
+        v = proj("wv", s_in).reshape(T, nkv, hd)
         if cfg.qk_norm:
             q = rms_norm(q, lp["q_norm"], cfg.norm_eps)
             k = rms_norm(k, lp["k_norm"], cfg.norm_eps)
@@ -908,7 +920,7 @@ def _per_head_attention_sublayer(cfg, lp, x, kind, l, pool, cos, sin,
     if cfg.attn_gate == "elementwise":
         with jax.named_scope("attn_gate"):
             o = o * jax.nn.sigmoid(
-                (hn @ lp["wg"]).astype(jnp.float32)
+                proj("wg", s_in).astype(jnp.float32)
             ).reshape(T, nh, hd).astype(o.dtype)
     with jax.named_scope("out_proj"):
         a = o.reshape(T, nh * hd) @ lp["wo"]
@@ -1087,7 +1099,7 @@ def _linear_attention_sublayer(cfg, lp, x, l, cache, rows: _StateRows,
 
 
 def _state_space_sublayer(cfg, lp, x, l, cache, rows: _StateRows,
-                          use_kernel=True):
+                          use_kernel=True, hn=None):
     """A state-space (Mamba-2) mixer on flat tokens x [T, H]; ``l`` is
     the layer's index among the state-space layers (its state leaves'
     leading axis). One projection of the normed input gives [z | x B C |
@@ -1113,8 +1125,12 @@ def _state_space_sublayer(cfg, lp, x, l, cache, rows: _StateRows,
     whole output times SiLU(z), RMS-normed a GROUP of heads
     (``cfg.mamba_n_groups`` of them, each with its own B and C: one
     group, the whole output) under one weight (scope ``ssm_gate_norm``)
-    and projected (``ssm_out``). Returns (what the mixer adds to x,
-    cache)."""
+    and projected (``ssm_out``). ``hn``: ``norm(x, attn_norm)`` where
+    the caller has made it already (the other half of a two-mixer layer
+    reads it too). The block's multipliers, where it has them
+    (``cfg.ssm_proj_scales``: the input's and the five segments' of the
+    projection, one vector on its float32 sum). Returns (what the mixer
+    adds to x, cache)."""
     from ...ops.norms import rms_norm
     from .kernels import linear_attention as la
     from .kernels import state_space as ss
@@ -1122,13 +1138,21 @@ def _state_space_sublayer(cfg, lp, x, l, cache, rows: _StateRows,
     n = groups * cfg.mamba_d_state      # B's (and C's) width a token
     dc, f32 = cfg.mamba_conv_dim, jnp.float32
     dt_ = lp["w_in"].dtype
-    hn = _norm(cfg, x, lp["attn_norm"]).astype(dt_)
+    if hn is None:
+        hn = _norm(cfg, x, lp["attn_norm"])
+    hn = hn.astype(dt_)
+    scales = cfg.ssm_proj_scales
     with jax.named_scope("ssm_proj"):
         # a decode row's projections stay float32 from the matmul's sum
         # to the recurrence, as a linear layer's do (PERF.md section 6,
         # PR 45)
         zxd = jnp.dot(hn, lp["w_in"], preferred_element_type=f32
-                      if rows.one_token else None)
+                      if rows.one_token or scales else None)
+        if scales:
+            zxd = zxd * jnp.concatenate(
+                [jnp.full((w,), s, f32) for w, s in scales])
+            if not rows.one_token:
+                zxd = zxd.astype(dt_)
         z, xbc = zxd[:, :di], zxd[:, di:di + dc]
         dt = jax.nn.softplus(zxd[:, di + dc:].astype(f32)
                              + lp["dt_bias"].astype(f32))
@@ -1305,6 +1329,16 @@ def _pattern_step(cfg: TransformerConfig, params, ids, row_ids, pos, lengths,
     the sandwich scheme each sub-layer's output passes a second norm
     before it joins the stream.
 
+    A layer whose mixer is TWO mixers (kind "hybrid", ``layer_types``
+    "mamba_attention") norms the stream ONCE and hands the result to a
+    state-space half, which reads and writes the row's SLOT, and to a
+    full per-head half, which reads and writes the row's BLOCKS; what
+    they return is scaled and summed into the stream in float32 (scope
+    ``hybrid_mixer`` round both, ``hybrid_join`` round the sum). Such a
+    layer owns a place in the full pool and in the state-space leaves,
+    so a run finds its place a leaf FAMILY (``cfg.leaf_places``), not a
+    kind.
+
     A pattern of layers that are ONE sub-layer each (``cfg.one_sublayer``:
     ``layer_types`` names "moe" layers) walks the same way: a run of
     mixers reads ``<kind>_layers`` and has no MLP behind it, a run of
@@ -1340,12 +1374,12 @@ def _pattern_step(cfg: TransformerConfig, params, ids, row_ids, pos, lengths,
     pattern = cfg.pattern
     sandwich = cfg.norm_scheme == "sandwich"
 
-    def joined(x, a):
+    def joined(x, a, scale=1.0):
         """The stream with what a sub-layer adds to it, times the
-        model's multiplier where it has one."""
-        a = a.astype(jnp.float32)
-        return x + (a if cfg.residual_scale == 1.0
-                    else a * cfg.residual_scale)
+        model's multiplier and the sub-layer's own (``scale``) where
+        they are not 1."""
+        a, scale = a.astype(jnp.float32), scale * cfg.residual_scale
+        return x + (a if scale == 1.0 else a * scale)
 
     expert_keys = cfg.expert_keys
     single = cfg.one_sublayer
@@ -1370,8 +1404,12 @@ def _pattern_step(cfg: TransformerConfig, params, ids, row_ids, pos, lengths,
         experts = tuple(mlps[k] for k in expert_keys) if routed else None
         scanned = {k: v for k, v in mlps.items()
                    if not (routed and k in expert_keys)}
-        # the run's place in its mixer's leaves, parameters and cache
+        # the run's place in its mixer's parameters, and in the cache
+        # leaves it owns a place in: a kind's own, but the full pool and
+        # the state-space leaves, which a two-mixer layer shares with
+        # the full and the state-space layers (``cfg.leaf_places``)
         m0 = kinds[:first].count(kind) if pattern else first
+        p0, s0 = (cfg.leaf_places(f, first) for f in ("full", "ssm"))
         mixers = params[kind + "_layers"] if pattern and has_mixer else {}
 
         def layer_fn(carry, inputs):
@@ -1399,8 +1437,28 @@ def _pattern_step(cfg: TransformerConfig, params, ids, row_ids, pos, lengths,
             elif kind == "ssm":
                 with jax.named_scope("ssm_mixer"):
                     a, pool = _state_space_sublayer(
-                        cfg, lp, x, m0 + i, pool, rows, use_kernel)
-                    x = joined(x, a)
+                        cfg, lp, x, s0 + i, pool, rows, use_kernel)
+                    x = joined(x, a, cfg.ssm_out_scale)
+            elif kind == "hybrid":
+                # TWO mixers on ONE norm, summed: the state-space half
+                # by the row's slot, the attention half by its block
+                # table. The norm and the join stand under ``attention``
+                # as every per-head layer's do, so the table of scopes
+                # (``xla_profile``) reads them without a word of its own
+                with jax.named_scope("hybrid_mixer"):
+                    with jax.named_scope("attention"):
+                        hn = _norm(cfg, x, lp["attn_norm"])
+                    with jax.named_scope("ssm_mixer"):
+                        m, pool = _state_space_sublayer(
+                            cfg, lp, x, s0 + i, pool, rows, use_kernel, hn)
+                    with jax.named_scope("attention"):
+                        a, pool = _per_head_attention_sublayer(
+                            cfg, lp, x, "full", p0 + i, pool, cos, sin,
+                            row_ids, lengths, write_blocks, write_offsets,
+                            block_tables, use_kernel, one_token, hn)
+                        with jax.named_scope("hybrid_join"):
+                            x = joined(joined(x, m, cfg.ssm_out_scale), a,
+                                       cfg.attn_out_scale)
             elif kind == "retention":
                 with jax.named_scope("attention"):
                     a, pool = _power_retention_sublayer(
@@ -1413,12 +1471,13 @@ def _pattern_step(cfg: TransformerConfig, params, ids, row_ids, pos, lengths,
                 ring = kind == "window"
                 with jax.named_scope("attention"):
                     a, pool = _per_head_attention_sublayer(
-                        cfg, lp, x, kind, m0 + i, pool, cos, sin, row_ids,
-                        lengths, window_writes if ring else write_blocks,
+                        cfg, lp, x, kind, (m0 if ring else p0) + i, pool,
+                        cos, sin, row_ids, lengths,
+                        window_writes if ring else write_blocks,
                         write_offsets,
                         window_tables if ring else block_tables, use_kernel,
                         one_token, mixer_in)
-                    x = joined(x, a)
+                    x = joined(x, a, cfg.attn_out_scale)
             else:
                 with jax.named_scope("mla_attention"):
                     a, pool = _latent_attention_sublayer(
@@ -1445,12 +1504,17 @@ def _pattern_step(cfg: TransformerConfig, params, ids, row_ids, pos, lengths,
                     from ...models.transformer import gate_act
                     with jax.named_scope("dense_mlp"):
                         hn = hn.astype(dtype)
-                        out = (gate_act(cfg)(hn @ lp["w_gate"])
+                        g = hn @ lp["w_gate"]
+                        if cfg.mlp_gate_scale != 1.0:
+                            g = (g.astype(jnp.float32)
+                                 * cfg.mlp_gate_scale).astype(dtype)
+                        out = (gate_act(cfg)(g)
                                * (hn @ lp["w_up"])) @ lp["w_down"]
                 if sandwich:
                     out = _norm(cfg, out.astype(jnp.float32),
                                 lp["mlp_post_norm"])
-                x = joined(x, out)
+                # (1 beside an expert layer: the config refuses the rest)
+                x = joined(x, out, cfg.mlp_down_scale)
             return (x, pool, stats), None
 
         with jax.named_scope("layers"):

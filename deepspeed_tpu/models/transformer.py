@@ -42,13 +42,19 @@ from ..telemetry import registry as _registry
 # power-kernel state a key/value head in place of cached positions), or
 # "moe": an expert layer that is a layer of its own. A pattern that
 # names "moe" is one whose every layer is ONE sub-layer behind ONE norm
-# (``TransformerConfig.one_sublayer``)
+# (``TransformerConfig.one_sublayer``). "mamba_attention": a layer whose
+# mixer is TWO mixers on ONE norm, a Mamba-2 mixer beside full per-head
+# attention, their outputs summed (kind "hybrid")
 LAYER_TYPE_KINDS = {"sliding_attention": "window", "full_attention": "full",
                     "attention": "full", "mamba": "ssm", "moe": "moe",
-                    "power_retention": "retention"}
+                    "power_retention": "retention",
+                    "mamba_attention": "hybrid"}
 # the kinds that cache positions in blocks; the others keep a state a
-# sequence, or nothing
-PAGED_KINDS = ("mha", "mla", "window", "full")
+# sequence, or nothing ("hybrid" does both)
+PAGED_KINDS = ("mha", "mla", "window", "full", "hybrid")
+# the kinds that own a place in a family of cache leaves: a "hybrid"
+# layer has one in the full pool AND one in the state-space leaves
+LEAF_OWNERS = {"full": ("full", "hybrid"), "ssm": ("ssm", "hybrid")}
 
 
 @dataclass(frozen=True)
@@ -229,6 +235,33 @@ class TransformerConfig:
     attn_scale: float = 0.0
     residual_scale: float = 1.0
     logit_scale: float = 1.0
+    # a layer whose mixer is TWO mixers on one norm (served only;
+    # ``layer_types`` "mamba_attention", the falcon_h1 block): the
+    # Mamba-2 mixer above and full per-head attention both read ONE
+    # ``attn_norm`` of the stream and their outputs are summed into it,
+    # ahead of the layer's MLP. The muP multipliers of that block, each a
+    # plain scalar where it stands in the source (1: none), read by every
+    # per-head, state-space and dense-MLP sub-layer of a pattern:
+    # ``attn_in_scale`` on the attention's normed input, ``key_scale``
+    # on its keys ahead of the rotation, ``attn_out_scale`` on what it
+    # adds; ``ssm_in_scale`` on the state-space mixer's normed input,
+    # ``ssm_z_scale`` / ``ssm_x_scale`` / ``ssm_b_scale`` /
+    # ``ssm_c_scale`` / ``ssm_dt_scale`` on the five segments of its
+    # projection's output, ``ssm_out_scale`` on what it adds;
+    # ``mlp_gate_scale`` on the dense MLP's gate ahead of its
+    # activation, ``mlp_down_scale`` on what the MLP adds
+    attn_in_scale: float = 1.0
+    key_scale: float = 1.0
+    attn_out_scale: float = 1.0
+    ssm_in_scale: float = 1.0
+    ssm_z_scale: float = 1.0
+    ssm_x_scale: float = 1.0
+    ssm_b_scale: float = 1.0
+    ssm_c_scale: float = 1.0
+    ssm_dt_scale: float = 1.0
+    ssm_out_scale: float = 1.0
+    mlp_gate_scale: float = 1.0
+    mlp_down_scale: float = 1.0
     # POWER RETENTION layers (served only; ``layer_types``
     # "power_retention"; arXiv:2507.04239, degree 2): the per-head
     # block's projections, ``qk_norm`` and rotation as they are, with
@@ -381,7 +414,14 @@ class TransformerConfig:
                     "(phi is laid out by circular distance), "
                     "positional='rope', retention_eps > 0, and neither "
                     "attn_scale nor attn_gate")
-            if "mamba" in self.layer_types and (
+            if "power_retention" in self.layer_types and (
+                    self.attn_in_scale != 1.0 or self.key_scale != 1.0
+                    or self.attn_out_scale != 1.0):
+                raise NotImplementedError(
+                    "attn_in_scale, key_scale and attn_out_scale are read "
+                    "by the per-head attention layers, not by "
+                    "power_retention ones")
+            if {"mamba", "mamba_attention"} & set(self.layer_types) and (
                     min(self.mamba_n_heads, self.mamba_d_head,
                         self.mamba_d_state, self.mamba_n_groups) < 1
                     or self.mamba_d_conv < 2
@@ -394,6 +434,21 @@ class TransformerConfig:
                     f"{(self.mamba_n_heads, self.mamba_d_head)}, state "
                     f"{self.mamba_d_state}, taps {self.mamba_d_conv}, "
                     f"groups {self.mamba_n_groups}")
+            if "mamba_attention" in self.layer_types and (
+                    min(self.num_heads, self.kv_heads, self.head_dim) < 1
+                    or self.norm_scheme != "pre" or self.one_sublayer):
+                raise ValueError(
+                    f"a mamba_attention layer (two mixers on one norm) "
+                    f"needs the mamba_* sizes and per-head attention "
+                    f"sizes (num_heads, num_kv_heads, head_dim > 0), "
+                    f"pre-norm, and an MLP behind it (no 'moe' layers); "
+                    f"got heads {(self.num_heads, self.kv_heads)} of "
+                    f"{self.head_dim}, norm_scheme {self.norm_scheme!r}")
+            if (self.mlp_gate_scale != 1.0 or self.mlp_down_scale != 1.0) \
+                    and self.moe_num_experts:
+                raise NotImplementedError(
+                    "mlp_gate_scale and mlp_down_scale are read by a "
+                    "pattern's dense MLP, not by an expert layer")
             if self.one_sublayer and (
                     self.moe_num_experts < 1 or self.moe_first_dense_layers
                     or self.norm_scheme != "pre"):
@@ -412,11 +467,12 @@ class TransformerConfig:
         if self.layer_types is None and (
                 self.positional == "none" or self.mamba_n_heads
                 or self.attn_scale or self.residual_scale != 1.0
-                or self.logit_scale != 1.0):
+                or self.logit_scale != 1.0 or self.sublayer_scales):
             raise NotImplementedError(
                 "positional='none', the mamba_* sizes, attn_scale, "
-                "residual_scale and logit_scale describe the blocks of a "
-                "layer pattern: give layer_types")
+                "residual_scale, logit_scale and the sub-layers' "
+                "multipliers (attn_in_scale ... mlp_down_scale) describe "
+                "the blocks of a layer pattern: give layer_types")
         if self.linear_attn_period < 0 or (
                 self.linear_attn_period
                 and (self.attention != "mla" or self.linear_head_dim <= 0
@@ -512,12 +568,18 @@ class TransformerConfig:
              self.layer_types is not None),
             ("mamba layers (a Mamba-2 state-space mixer and its "
              "recurrent state)", "ssm" in self.layer_kinds),
+            ("mamba_attention layers (two mixers on one norm: a Mamba-2 "
+             "mixer beside per-head attention, summed)",
+             "hybrid" in self.layer_kinds),
+            ("the sub-layers' multipliers ("
+             + ", ".join(self.sublayer_scales) + ")",
+             bool(self.sublayer_scales)),
             ("power_retention layers (a power-kernel state a key/value "
              "head)", "retention" in self.layer_kinds),
             ("'moe' layers (a layer is one sub-layer behind one norm)",
              self.one_sublayer),
             ("mamba_n_groups (B and C a group of heads)",
-             "ssm" in self.layer_kinds and self.mamba_n_groups > 1),
+             self.leaf_places("ssm") > 0 and self.mamba_n_groups > 1),
             ("moe_expert_form='relu2' (two-matrix experts)",
              self.moe_expert_form == "relu2"),
             ("moe_expert_form='reglu' (a ReLU gate on the three-matrix "
@@ -577,9 +639,10 @@ class TransformerConfig:
         """The mixer of every layer, in order: "kda" (linear attention)
         or ``attention`` under ``linear_attn_period``; "window" or "full"
         (per-head attention), "ssm" (a Mamba-2 state-space mixer),
-        "retention" (a power-retention mixer) or "moe" (an expert layer
-        that is a layer of its own: ``one_sublayer``) from an explicit
-        ``layer_types``."""
+        "retention" (a power-retention mixer), "hybrid" (a Mamba-2
+        mixer AND full per-head attention on one norm, summed) or "moe"
+        (an expert layer that is a layer of its own: ``one_sublayer``)
+        from an explicit ``layer_types``."""
         if self.layer_types is not None:
             return tuple(LAYER_TYPE_KINDS[t] for t in self.layer_types)
         p = self.linear_attn_period
@@ -603,7 +666,41 @@ class TransformerConfig:
     @property
     def has_state(self) -> bool:
         """Whether a sequence owns recurrent state beside its blocks."""
-        return bool({"kda", "ssm", "retention"} & set(self.layer_kinds))
+        return bool({"kda", "ssm", "retention", "hybrid"}
+                    & set(self.layer_kinds))
+
+    def leaf_places(self, family: str, upto: Optional[int] = None) -> int:
+        """How many of the first ``upto`` layers (None: all) own a place
+        in the cache leaves of ``family``: "full" (``k_full`` ...: the
+        full per-head layers and the two-mixer layers) or "ssm"
+        (``ssm_state`` / ``ssm_conv``: the state-space layers and the
+        two-mixer layers); any other kind's leaves are its own. With
+        ``upto`` a layer's index this is the layer's place along its
+        leaves' leading axis."""
+        owners = LEAF_OWNERS.get(family, (family,))
+        return sum(k in owners for k in self.layer_kinds[:upto])
+
+    @property
+    def sublayer_scales(self) -> tuple:
+        """The sub-layers' multipliers that are not 1, by name."""
+        return tuple(n for n in (
+            "attn_in_scale", "key_scale", "attn_out_scale", "ssm_in_scale",
+            "ssm_z_scale", "ssm_x_scale", "ssm_b_scale", "ssm_c_scale",
+            "ssm_dt_scale", "ssm_out_scale", "mlp_gate_scale",
+            "mlp_down_scale") if getattr(self, n) != 1.0)
+
+    @property
+    def ssm_proj_scales(self) -> Optional[tuple]:
+        """What a state-space mixer's projection [z | x | B | C | dt]
+        is multiplied by, a (width, scalar) a segment with the input's
+        multiplier folded in (the projection is linear); None where
+        every one is 1."""
+        n = self.mamba_n_groups * self.mamba_d_state
+        segs = tuple((w, s * self.ssm_in_scale) for w, s in (
+            (self.mamba_d_inner, self.ssm_z_scale),
+            (self.mamba_d_inner, self.ssm_x_scale), (n, self.ssm_b_scale),
+            (n, self.ssm_c_scale), (self.mamba_n_heads, self.ssm_dt_scale)))
+        return segs if any(s != 1.0 for _, s in segs) else None
 
     @property
     def caches_positions(self) -> bool:
@@ -1048,7 +1145,8 @@ class TransformerLM:
         with ``window_layers`` / ``full_layers`` as its mixers'
         (``per_head``), and under the sandwich scheme a post-norm a
         sub-layer (``attn_post_norm`` with the mixer, ``mlp_post_norm``
-        with the MLP)."""
+        with the MLP). A two-mixer layer's stack (``hybrid_layers``)
+        holds both halves' leaves and one ``attn_norm``."""
         cfg, dt = self.cfg, jnp.float32
         h, v, nh = cfg.hidden_size, cfg.vocab_size, cfg.num_heads
         L, std = cfg.num_layers, 0.02
@@ -1164,6 +1262,12 @@ class TransformerLM:
                 out["conv_b"] = jnp.zeros((n, dc), dt)
             return out
 
+        def hybrid(key, n):
+            """A two-mixer layer's leaves: both halves' under the names
+            the halves read, and ONE ``attn_norm`` for both."""
+            return {**state_space(jax.random.fold_in(key, 1), n),
+                    **per_head(key, n)}
+
         def mlp_norms(n):
             return {"mlp_norm": jnp.ones((n, h), dt),
                     **({"mlp_post_norm": jnp.ones((n, h), dt)}
@@ -1204,7 +1308,7 @@ class TransformerLM:
             kinds = cfg.layer_kinds
             mixers = {"kda": (linear, 7), "window": (per_head, 9),
                       "full": (per_head, 10), "ssm": (state_space, 11),
-                      "retention": (retention, 12),
+                      "retention": (retention, 12), "hybrid": (hybrid, 13),
                       "mla": (functools.partial(attention, mlp_norm=False),
                               8)}
             for kind in dict.fromkeys(kinds):

@@ -384,6 +384,73 @@ def _drop_shared_down(eng):
     eng.params = {**eng.params, "layers": layers}
 
 
+def _a_norm_a_half(monkeypatch):
+    """The state-space half of a two-mixer layer norms the stream
+    itself, under a weight of its own (the shared one times 1.5)."""
+    from deepspeed_tpu.inference.v2 import paged_model
+    real = paged_model._state_space_sublayer
+    monkeypatch.setattr(
+        paged_model, "_state_space_sublayer",
+        lambda cfg, lp, x, l, cache, rows, use_kernel=True, hn=None: real(
+            cfg, {**lp, "attn_norm": lp["attn_norm"] * 1.5}, x, l, cache,
+            rows, use_kernel))
+
+
+def _gate_behind_the_norm(monkeypatch):
+    """SiLU(z) multiplies the state-space mixer's output BEHIND its
+    grouped norm: inside the sub-layer the SiLU of z (the one of the
+    inner width) reads one, and the norm that follows takes the gate on
+    its result."""
+    from deepspeed_tpu.inference.v2 import paged_model
+    from deepspeed_tpu.ops import norms
+    real, silu, norm = (paged_model._state_space_sublayer, jax.nn.silu,
+                        norms.rms_norm)
+    held = {}
+
+    def sublayer(cfg, *a, **k):
+        held["width"] = cfg.mamba_d_inner
+        try:
+            return real(cfg, *a, **k)
+        finally:
+            held.clear()
+
+    def gate(z):
+        if z.ndim != 2 or z.shape[-1] != held.get("width"):
+            return silu(z)
+        held["gate"] = silu(z)
+        return jnp.ones_like(z)
+
+    def normed(x, w, eps):
+        gated = held.pop("gate")
+        return (norm(x, w, eps).reshape(gated.shape) * gated).reshape(x.shape)
+    monkeypatch.setattr(paged_model, "_state_space_sublayer", sublayer)
+    monkeypatch.setattr(jax.nn, "silu", gate)
+    monkeypatch.setattr(
+        norms, "rms_norm", lambda x, w, eps: normed(x, w, eps)
+        if "gate" in held else norm(x, w, eps))
+
+
+def _swap_groups(eng):
+    """Head h reads the OTHER group's B and C: the two groups' columns
+    of the projection, the taps and their bias change places, in a tree
+    of the engine's own."""
+    cfg = eng.model.cfg
+    di, n = cfg.mamba_d_inner, cfg.mamba_d_state
+    assert cfg.mamba_n_groups == 2
+
+    def swapped(leaf, first):
+        # [.., B group 0 | B group 1 | C group 0 | C group 1, ..]
+        b0, b1, c0, c1 = (leaf[..., first + i * n:first + (i + 1) * n]
+                          for i in range(4))
+        return jnp.concatenate([leaf[..., :first], b1, b0, c1, c0,
+                                leaf[..., first + 4 * n:]], axis=-1)
+    stack = dict(eng.params["hybrid_layers"])
+    stack["w_in"] = swapped(stack["w_in"], 2 * di)
+    stack["conv"] = swapped(stack["conv"], di)
+    stack["conv_b"] = swapped(stack["conv_b"], di)
+    eng.params = {**eng.params, "hybrid_layers": stack}
+
+
 _STATE_REFUSALS = (
     ({"tensor_parallel_size": 2}, "tensor_parallel_size"),
     ({"max_lora_adapters": 2}, "max_lora_adapters"),
@@ -567,6 +634,41 @@ _ROWS = (
                 ("an-unrotated-window-layer",
                  {"program": {"positional": "none",
                               "rope_sliding_only": False}})))),
+    # falcon_h1: EVERY layer two mixers on one norm (a Mamba-2 mixer with
+    # B and C in two groups beside rotated per-head attention, five
+    # queries a key/value head), summed under multipliers, ahead of a
+    # dense MLP. float32 reads 4e-7 (logits, state, keys); bf16 4e-3 to
+    # 6e-3 of the largest logit (no router to flip). The toy keeps what
+    # is new: 2 groups, d_state 32 != d_head 16, d_ssm 96 != 2 x 64,
+    # every multiplier != 1 and all different. A dropped multiplier, the
+    # gate behind the norm, a second norm for one half, a head reading
+    # the other group and a state kept in bfloat16 each read over five
+    # times the limit.
+    Block(
+        "falcon-h1-34b-instruct", seed=5, manager=_POOL,
+        impl="pallas:pipelined",
+        leaves=frozenset({"k_full", "v_full", "ssm_state", "ssm_conv"}),
+        put=(Case("one-step", chunks=0),
+             Case("two-steps", {"budget": 32}, lengths=(24, 24), chunks=2),
+             Case("five-steps", {"budget": 32}, lengths=(100, 24), chunks=5),
+             Case("bfloat16", {"dtype": "bfloat16"}, BF16_LOGITS)),
+        decode=(Case("float32"), Case("bfloat16", {"dtype": "bfloat16"})),
+        alone=Alone(),
+        chunked=Chunked((100, 24), {}, {"budget": 32}, 5),
+        kept=Kept({"ssm_state": (4, 6, 16, 32),
+                   "ssm_conv": (4, 3, 6 * 16 + 2 * 2 * 32)},
+                  lambda state: state["ssm_state"][:3], _leading(layers=3)),
+        controls=(
+            Control("bf16-state", {**_BF16_STATE, "budget": 32}, sound={},
+                    leaf=("ssm_state", "bfloat16")),
+            Control("a-dropped-multiplier",
+                    {"program": {"ssm_out_scale": 1.0}}),
+            Control("the-gate-behind-the-norm", patch=_gate_behind_the_norm),
+            Control("a-norm-a-half", patch=_a_norm_a_half),
+            Control("the-other-group", mutate=_swap_groups)),
+        refusals=Refusals("state-space layers", _STATE_REFUSALS),
+        refuses=Refuses(handoff="no state slot", forwards=("apply",),
+                        words=("mamba_attention layers",))),
     # brumby: power-retention layers in a model that caches no position.
     # float32 reads 4e-7; a state kept in bfloat16 reads 1e-3 and more of
     # the state after a prompt in three chunk steps and eight one-token

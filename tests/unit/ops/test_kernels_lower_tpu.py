@@ -2175,3 +2175,161 @@ def test_the_router_ahead_programs_compile_with_both_pools_in_place(
     routed = set(re.findall(r'op_name="[^"]*?((?:mlp/)?moe_router/'
                             r'(?:dot_general|top_k))"', text))
     assert routed and not any(r.startswith("mlp/") for r in routed), routed
+
+
+# ---------------------------------------------------------------------------
+# a layer of TWO mixers on one norm (Mamba-2 with a state of 256 in two
+# groups and heads of 128, beside GQA 20 / 4 x 128):
+# falcon-h1-34b-instruct.rollout-64x1024-512's geometry
+# ---------------------------------------------------------------------------
+def _falcon_fields():
+    import json
+    from pathlib import Path
+    return json.loads((Path(__file__).resolve().parents[3] / "benchmark"
+                       / "configs/falcon-h1-34b-instruct.json")
+                      .read_text())["fields"]
+
+
+@pytest.mark.parametrize("kernel", ["ssm_state_update", "ssm_chunk_fwd",
+                                    "ssm_conv_update"])
+def test_the_state_space_kernels_at_a_state_of_256_in_two_groups(
+        tpu_sharding, kernel):
+    """The three kernels at the cell's shapes: a leaf of 4 layers and 65
+    slots of 32 lane blocks of a state of 256 (a decode grid step takes
+    8 of them, 1 MB, where 16 would be 8 MB double-buffered in and out),
+    64 rows a token each and 64 rows of 256 prompt tokens with x, B and
+    C one bf16 buffer 5,120 wide (a head IS a lane block: its decay is
+    spread over the lanes one axis at a time, which Mosaic asks), and
+    the convolution's 40 lane blocks: each compiles for the chip as ONE
+    custom call under the name a trace finds, its leaf aliased, with no
+    temporary to speak of."""
+    from deepspeed_tpu.inference.v2.kernels import linear_attention as la
+    from deepspeed_tpu.inference.v2.kernels import state_space as ss
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=tpu_sharding)
+
+    f = _falcon_fields()
+    nh, p, n, g = (f[k] for k in ("mamba_n_heads", "mamba_d_head",
+                                  "mamba_d_state", "mamba_n_groups"))
+    assert (nh, p, n, g) == (32, 128, 256, 2)
+    N, D, K = 64, nh * p + 2 * g * n, f["mamba_d_conv"]
+    leaf = sds(ss.state_leaf_shape(4, 65, nh * p, n))
+    assert leaf.shape == (4, 65, 32, 256, 128)
+    assert ss.state_kernel_serves(leaf, g) \
+        and ss.chunk_kernel_serves(leaf, p, g)
+    rows = (sds((), jnp.int32), sds((N,), jnp.int32), sds((N,), jnp.bool_))
+    if kernel == "ssm_state_update":
+        compiled = jax.jit(ss.ssm_state_update, donate_argnums=(0,)).lower(
+            leaf, *rows, sds((N, nh * p)), sds((N, nh)), sds((nh,)),
+            sds((N, g * n)), sds((N, g * n))).compile()
+        # B and C go in a group a row, at their own size
+        assert f"f32[{N},2,1,{n}]" in compiled.as_text()
+    elif kernel == "ssm_chunk_fwd":
+        compiled = jax.jit(ss.ssm_chunk_fwd, donate_argnums=(0,)).lower(
+            leaf, *rows, sds((N,), jnp.int32), sds((N,), jnp.int32),
+            sds((N * 256, D), jnp.bfloat16), sds((N * 256, nh)),
+            sds((nh,))).compile()
+    else:
+        conv = sds(la.conv_leaf_shape(4, 65, K, D))
+        assert conv.shape == (4, 65, 3, 40, 128) \
+            and la.conv_kernel_serves(conv, parts=1)
+        compiled = jax.jit(
+            lambda leaf, layer, slots, fresh, x, taps, bias: la.conv_update(
+                leaf, layer, slots, fresh, (x,), taps, bias,
+                name="ssm_conv_update"), donate_argnums=(0,)).lower(
+            conv, *rows, sds((N, D)), sds((K, D), jnp.bfloat16),
+            sds((D,), jnp.bfloat16)).compile()
+    kernels = _custom_calls(compiled)
+    assert len(kernels) == 1 and kernels[0].startswith(kernel), kernels
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.05e9
+
+
+def _two_mixer_cut(tpu_sharding):
+    """The block at published widths, cut to two layers and 4,096 rows
+    of the vocabulary: the configuration, and its parameters and cache
+    (32 state slots, 193 blocks) as shapes on the chip."""
+    from deepspeed_tpu.inference.v2.paged_model import init_paged_kv_cache
+    from deepspeed_tpu.models import TransformerLM
+    from deepspeed_tpu.models.transformer import TransformerConfig
+
+    cfg = TransformerConfig(**{
+        **_falcon_fields(), "num_layers": 2, "vocab_size": 4096,
+        "layer_types": ["mamba_attention"] * 2})
+
+    def on_tpu(x, dtype=None):
+        return jax.ShapeDtypeStruct(x.shape, dtype or x.dtype,
+                                    sharding=tpu_sharding)
+
+    params = jax.tree.map(
+        lambda x: on_tpu(x, jnp.bfloat16),
+        jax.eval_shape(TransformerLM(cfg).init_params,
+                       jax.random.PRNGKey(0)))
+    cache = jax.tree.map(on_tpu, jax.eval_shape(
+        lambda: init_paged_kv_cache(cfg, 193, 16, jnp.bfloat16,
+                                    state_slots=32)))
+    # every layer has a place in BOTH families of leaves
+    assert cache["ssm_state"].shape == (2, 33, 32, 256, 128) \
+        and cache["ssm_conv"].shape == (2, 33, 3, 40, 128) \
+        and cache["k_full"].shape == cache["v_full"].shape \
+        == (2, 193, 16, 512)
+    return cfg, params, cache
+
+
+@pytest.mark.parametrize("program", ["ragged_step", "decode_window"])
+def test_the_two_mixer_programs_run_both_halves_in_their_kernels(
+        tpu_sharding, program):
+    """The ragged step (32 rows in 2,048 tokens) and the decode window
+    of the cut: ONE run of two layers, so each kernel stands once in the
+    scan's body: the chunk kernel and the tiled attention kernel in the
+    prompt's launch, the state kernel, the convolution's and the tiled
+    kernel's one-token form in the window; nothing under ``ssm_scan``
+    loops in XLA and nothing of the window under ``ssm_conv`` /
+    ``ssm_state`` gathers or scatters the slots (the prompt's
+    convolution is ``causal_conv_rows``, XLA's, as in every state-space
+    block); the state leaf stays where it lies; both
+    halves' operations and the join stand under ``hybrid_mixer``."""
+    from deepspeed_tpu.inference.v2.paged_model import (paged_decode_window,
+                                                        paged_ragged_step)
+
+    cfg, params, cache = _two_mixer_cut(tpu_sharding)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=tpu_sharding)
+
+    T, R = 2048, 32
+    if program == "ragged_step":
+        compiled = jax.jit(
+            lambda p, ids, rows, pos, ln, wb, wo, bt, li, c, ss:
+            paged_ragged_step(cfg, p, ids, rows, pos, ln, wb, wo, bt, li, c,
+                              16, use_kernel=True, state_slots=ss),
+            donate_argnums=(9,)).lower(
+            params, i32(T), i32(T), i32(T), i32(T), i32(T), i32(T),
+            i32(R, 16), i32(R), cache, i32(R)).compile()
+        want = ("ssm_chunk_fwd", "ragged_attention_tiled")
+    else:
+        compiled = jax.jit(
+            lambda p, t, pos, bt, c, sl, eos, alive, ss: paged_decode_window(
+                cfg, p, t, pos, bt, c, sl, eos, 16, 8, use_kernel=True,
+                alive=alive, state_slots=ss), donate_argnums=(4,)).lower(
+            params, i32(R), i32(R), i32(R, 16), cache, i32(R), i32(R),
+            jax.ShapeDtypeStruct((R,), jnp.bool_, sharding=tpu_sharding),
+            i32(R)).compile()
+        want = ("ssm_state_update", "ssm_conv_update",
+                "ragged_attention_tiled")
+    text = compiled.as_text()
+    kernels = _custom_calls(compiled)
+    assert sorted(re.sub(r"\.\d+$", "", k) for k in kernels) \
+        == sorted(want), kernels
+    assert "ssm_scan/while" not in text
+    if program == "decode_window":
+        under = re.findall(
+            r"= \S+ ([\w\-]+)\([^\n]*op_name=\"[^\"]*/ssm_(?:conv|state)/",
+            text)
+        assert under and not {"gather", "scatter"} & set(under), under
+    _leaves_stay_in_hbm(text, cache, "ssm_state")
+    paths = set(re.findall(r'op_name="([^"]*)"', text))
+    for scope in ("ssm_mixer/ssm_proj", "ssm_mixer/ssm_out",
+                  "attention/qkv_proj", "attention/attn_kernel",
+                  "attention/out_proj", "attention/hybrid_join"):
+        assert any(f"/hybrid_mixer/{scope}/" in p for p in paths), scope
