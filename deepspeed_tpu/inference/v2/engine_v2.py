@@ -52,7 +52,7 @@ from .paged_model import (STATE_LEAVES, init_lora_bank, init_paged_kv_cache,
 from .ragged import batch as ragged_batch
 from .ragged.blocked_allocator import NULL_BLOCK
 from .ragged.ragged_manager import DSStateManager
-from .sampling import greedy_tokens
+from .sampling import fold_in_rows, greedy_tokens, sample_tokens_rowwise
 
 DTYPES = {"float32": jnp.float32, "float16": jnp.float16,
           "bfloat16": jnp.bfloat16}
@@ -147,6 +147,21 @@ class _Window:
     moe: list
     state: tuple            # (token, position, alive), on the device
     last: Optional[Dict[int, int]] = None
+
+
+@dataclasses.dataclass
+class _RaggedStep:
+    """A ragged step that was launched: its outputs, still on the device,
+    and what noting them takes once they are fetched."""
+    rows: int               # the entries it ran (the logits' valid rows)
+    valid: int              # the tokens they fed
+    tokens: int             # its token bucket: what _note_moe reads a form by
+    t0: float               # perf_counter at the start of its pack
+    logits: object          # [row bucket, vocab] float32, on the device
+    moe: list
+    # a chunked put(): (row of the step, row of the call) of the rows
+    # whose LAST token went in here, the only rows whose logits are read
+    ended: tuple = ()
 
 
 class InferenceEngineV2:
@@ -476,6 +491,16 @@ class InferenceEngineV2:
                 lora=lb, adapter_ids=aid, state_slots=ss,
                 window_tables=wt),
             donate_argnums=(9,))
+        # generate()'s pick of the first token over the prompt's logits
+        # where they are, [row bucket, vocab] on the device: programs of
+        # their own, so that the ragged step's stays what put() runs
+        self._first_greedy_jit = watchdog.watch_jit(
+            "first_token_greedy", lambda logits: greedy_tokens(logits))
+        self._first_sample_jit = watchdog.watch_jit(
+            "first_token_sample",
+            lambda logits, rng, seeds, g0, temp, topp, topk:
+            sample_tokens_rowwise(logits, fold_in_rows(rng, seeds, g0),
+                                  temp, topp, topk))
         # speculative verification: greedy ids for a static window of
         # fed positions from one fused continuation pass (prompt-lookup
         # decoding); one compiled program per window size
@@ -855,14 +880,23 @@ class InferenceEngineV2:
             "ragged rows carrying a single decode token")
         self._m_ragged_time = reg.histogram(
             "inference_ragged_step_seconds",
-            "unified ragged step wall time", unit="s")
+            "unified ragged step wall time, from the start of its pack "
+            "to the host's seeing it end (under launch-ahead that spans "
+            "the wait for the step before it)", unit="s")
         self._m_ragged_pad = reg.gauge(
             "inference_ragged_pad_fraction",
             "padding waste of the last ragged step's token bucket")
         self._m_ragged_host_syncs = reg.counter(
             "inference_ragged_host_syncs_total",
-            "device->host transfers made by unified ragged steps (one "
-            "per step)")
+            "unified ragged steps the host waited for with nothing "
+            "queued behind them (one a put(), a step_ragged() and a "
+            "generate() call: the chunk steps before the last are "
+            "launched ahead)")
+        self._m_logits_fetched = reg.counter(
+            "inference_ragged_logits_fetched_bytes_total",
+            "bytes of [row bucket, vocab] logits brought to the host by "
+            "put() and step_ragged() (generate() picks its first token "
+            "on the device and fetches none)", unit="bytes")
         self._m_kv_quant_saved = reg.gauge(
             "inference_kv_pool_quant_bytes_saved",
             "HBM the int8 KV pool frees vs the same pool at the serving "
@@ -2004,8 +2038,24 @@ class InferenceEngineV2:
         = (i, n): this is step i of the n that put() runs for a prompt
         set fed in chunks (the span's ``chunk`` / ``chunks`` attrs; the
         tables then keep their full width, one program for all n)."""
+        step = self._launch_ragged(batch_uids, batch_tokens, chunk)
+        return self._logits_to_host([step], step.logits, step.rows)
+
+    def _launch_ragged(self, batch_uids, batch_tokens,
+                       chunk: Optional[tuple] = None,
+                       behind: Optional[_RaggedStep] = None) -> _RaggedStep:
+        """:meth:`step_ragged` less its fetch: the step packed, launched
+        and booked on the host, its logits and expert counters left on
+        the device (:meth:`_collect_ragged` fetches what a caller reads).
+        ``behind`` is the step launched before this one and not waited
+        for yet: the host waits for it once THIS one is queued (launch,
+        THEN wait, one step deep: the device goes from one to the next
+        on its own, and no more than two steps' buffers are asked for at
+        a time). Nothing the next pack reads comes from the device:
+        positions, blocks, slots and rings are the manager's."""
         sm = self.state_manager
-        with trace.span("ragged_pack") as packed:
+        t0 = time.perf_counter()
+        with trace.span("ragged_pack"):
             entries = [(int(uid),
                         np.atleast_1d(np.asarray(toks, np.int64)))
                        for uid, toks in zip(batch_uids, batch_tokens)]
@@ -2047,7 +2097,7 @@ class InferenceEngineV2:
                         uids=[u for u, _ in entries],
                         **(dict(chunk=chunk[0], chunks=chunk[1])
                            if chunk is not None else {}),
-                        **self._trace_attrs(u for u, _ in entries)) as step:
+                        **self._trace_attrs(u for u, _ in entries)):
             with trace.span("ragged_dispatch"):
                 # a launch's two parts as leaves: the host arrays handed
                 # to the device, then the jit call alone
@@ -2069,13 +2119,14 @@ class InferenceEngineV2:
                         self.params, *packed_in, self.kv_cache,
                         self.lora_bank, *slots_in)
             with trace.span("ragged_fetch"):
-                # blocks: the pass completes here
-                logits, moe = jax.device_get((logits, moe))
+                # the wait for the step BEFORE this one, which ends
+                # while this one is queued behind it (no transfer: what
+                # a caller reads of a step, _collect_ragged fetches)
+                if behind is not None:
+                    jax.block_until_ready(behind.logits)
         with trace.span("ragged_bookkeeping"):
-            # inference_ragged_step_seconds: the pack and the launch, to
-            # the logits' arrival (the two spans' own durations)
-            dt = packed["duration_s"] + step["duration_s"]
-            self._note_moe("ragged_step", len(rb.ids), *moe)
+            if behind is not None:
+                self._ragged_ended(behind)
             self._note_prompt_chunks(entries, rb)
             if self._has_state:
                 self._m_state_rows.labels(program="ragged_step").inc(
@@ -2104,18 +2155,53 @@ class InferenceEngineV2:
             self._m_ragged_tokens.inc(rb.total_tokens)
             self._m_ragged_prefill_rows.inc(len(entries) - decode_rows)
             self._m_ragged_decode_rows.inc(decode_rows)
-            self._m_ragged_time.observe(dt)
             self._m_ragged_pad.set(rb.pad_fraction)
-            self._m_ragged_host_syncs.inc()
             # chunk tokens are prefill work: the family counter the
             # dashboards read
             if chunk_tokens:
                 self._m_prefill_tokens.inc(chunk_tokens)
-            flight.record("ragged_step", rows=len(entries),
-                          tokens=rb.total_tokens, bucket=rb.token_bucket,
-                          dur_s=round(dt, 5))
             self._update_pool_telemetry()
-        return logits[:len(entries)]
+        return _RaggedStep(rows=len(entries), valid=rb.total_tokens,
+                           tokens=rb.token_bucket, t0=t0, logits=logits,
+                           moe=moe)
+
+    def _ragged_ended(self, step: _RaggedStep) -> None:
+        """The host has seen ``step`` end (its wait returned).
+        inference_ragged_step_seconds: from the start of its pack to
+        here, as a window's is from its launch to its tokens (under
+        launch-ahead that spans the wait for the step before it)."""
+        dt = time.perf_counter() - step.t0
+        self._m_ragged_time.observe(dt)
+        flight.record("ragged_step", rows=step.rows, tokens=step.valid,
+                      bucket=step.tokens, dur_s=round(dt, 5))
+
+    def _collect_ragged(self, steps: List[_RaggedStep], result):
+        """Wait for launched ``steps`` (the last is the only one not
+        waited for yet): ONE transfer of ``result``, a device array that
+        depends on the last step (a put()'s logits, generate()'s first
+        tokens), and of every step's expert counters, which are noted a
+        step at a time as a step that fetched its own would have.
+        Returns ``result`` on the host. The caller's span is the leaf."""
+        result, moes = jax.device_get((result, [s.moe for s in steps]))
+        # the one ragged step of these that the host waited for with
+        # nothing queued behind it
+        self._m_ragged_host_syncs.inc()
+        self._ragged_ended(steps[-1])
+        for step, moe in zip(steps, moes):
+            self._note_moe("ragged_step", step.tokens, *moe)
+            step.logits = step.moe = None
+        return result
+
+    def _logits_to_host(self, steps: List[_RaggedStep], logits,
+                        rows: int) -> np.ndarray:
+        """put()'s and step_ragged()'s fetch: the ``[row bucket, vocab]``
+        device ``logits`` behind ``steps`` on the host, the first
+        ``rows`` of them."""
+        with trace.span("ragged_fetch"):
+            # blocks: the pass completes here
+            logits = self._collect_ragged(steps, logits)
+            self._m_logits_fetched.inc(logits.nbytes)
+        return logits[:rows]
 
     def put(self, batch_uids: Sequence[int],
             batch_tokens: Sequence[Iterable[int]]) -> np.ndarray:
@@ -2131,10 +2217,19 @@ class InferenceEngineV2:
         budget shared evenly among the rows that have tokens left): one
         program signature for all of them, and the logits returned are
         each row's from the step its last token went in."""
+        steps, logits = self._put(batch_uids, batch_tokens)
+        return self._logits_to_host(steps, logits, len(batch_uids))
+
+    def _put(self, batch_uids, batch_tokens):
+        """:meth:`put` less its fetch, which is all generate() runs of
+        it: ``(steps, logits)``, the launched steps (the last not waited
+        for) and the rows' logits ``[row bucket, vocab]`` on the device,
+        row i the i-th entry's."""
         plan = self._chunk_plan([len(np.atleast_1d(t))
                                  for t in batch_tokens])
         if len(plan) == 1:
-            return self.step_ragged(batch_uids, batch_tokens)
+            step = self._launch_ragged(batch_uids, batch_tokens)
+            return [step], step.logits
         return self._put_chunks(batch_uids, batch_tokens, plan)
 
     def _chunk_plan(self, lengths: Sequence[int]) -> List[List[int]]:
@@ -2156,8 +2251,9 @@ class InferenceEngineV2:
             left = [n - t for n, t in zip(left, take)]
         return plan
 
-    def _put_chunks(self, batch_uids, batch_tokens, plan) -> np.ndarray:
-        """put() for a prompt set fed in ``plan``'s steps. Asks first
+    def _put_chunks(self, batch_uids, batch_tokens, plan):
+        """:meth:`_put` for a prompt set fed in ``plan``'s steps, each
+        launched without waiting for the one before. Asks first
         that BOTH pools hold every row's whole prompt (a step's own
         ``can_schedule`` sees only that step), so that a call that
         cannot finish raises before it has fed a token."""
@@ -2167,27 +2263,50 @@ class InferenceEngineV2:
             raise RuntimeError(
                 "batch not schedulable (KV blocks / sequence budget); "
                 "check can_schedule()/query() before put()")
-        out: Dict[int, np.ndarray] = {}
+        steps: List[_RaggedStep] = []
         fed = [0] * len(rows)
         for i, take in enumerate(plan):
             live = [r for r, n in enumerate(take) if n]
-            logits = self.step_ragged(
+            step = self._launch_ragged(
                 [uids[r] for r in live],
                 [rows[r][fed[r]:fed[r] + take[r]] for r in live],
-                chunk=(i, len(plan)))
+                chunk=(i, len(plan)), behind=steps[-1] if steps else None)
             # put()'s own work behind a chunk step, a leaf like the
-            # step's: the rows' counts, and behind the last step the
-            # rows' logits stacked (128 rows of a 65,536-row head are
-            # 33 MB: tens of ms in which the device has nothing queued)
+            # step's: the rows' counts and which of them ended here, and
+            # behind the last step the ended rows' logits brought
+            # together, on the device
             with trace.span("put_chunk"):
                 self._m_prefill_chunks.inc()
-                for at, r in enumerate(live):
+                if steps and not steps[-1].ended:
+                    # the step before was waited for inside this one's
+                    # launch, and nobody reads its logits
+                    steps[-1].logits = None
+                for r in live:
                     fed[r] += take[r]
-                    if fed[r] == len(rows[r]):
-                        out[r] = logits[at]
+                step.ended = tuple((at, r) for at, r in enumerate(live)
+                                   if fed[r] == len(rows[r]))
+                steps.append(step)
                 if i == len(plan) - 1:
-                    stacked = np.stack([out[r] for r in range(len(rows))])
-        return stacked
+                    logits = self._ended_rows(steps, len(rows))
+        return steps, logits
+
+    def _ended_rows(self, steps: List[_RaggedStep], rows: int):
+        """The logits of a chunked put()'s ``rows`` rows, each from the
+        step its last token went in, ``[row bucket, vocab]`` on the
+        device. Rows of one length end together in the last step, whose
+        logits are theirs as they stand; rows that end in different
+        steps are gathered there, on the device."""
+        kept = [s for s in steps if s.ended]
+        if len(kept) == 1 and kept[0].ended == tuple(
+                (r, r) for r in range(rows)):
+            return kept[0].logits
+        place = np.zeros(self._decode_bucket(rows), np.int32)
+        base = 0
+        for step in kept:
+            for at, r in step.ended:
+                place[r] = base + at
+            base += step.logits.shape[0]
+        return jnp.concatenate([s.logits for s in kept])[jnp.asarray(place)]
 
     # -- weight hot-swap (serve/weights.py) -----------------------------
     def note_weight_swap(self, seconds: float) -> None:
@@ -2428,9 +2547,11 @@ class InferenceEngineV2:
 
         A call is one ``generate`` span and no part of it runs outside a
         leaf span under it (``train_batch``'s rule; docs/TELEMETRY.md,
-        "Span tracing"): ``gen_admit`` to the ``put()`` call, ``put()``'s
-        own leaves, ``gen_first_token`` (the host's pick over ``put()``'s
-        logits), then a decode window at a time ``gen_schedule`` (from
+        "Span tracing"): ``gen_admit`` to the prompt's first launch,
+        ``put()``'s own leaves less its fetch, ``gen_first_token`` (the
+        pick launched over the logits where they are, and its ``[N]``
+        tokens' arrival: the wait for the prompt's last step), then a
+        decode window at a time ``gen_schedule`` (from
         the last window's return to the next one's call) and the
         window's own leaves, and ``gen_flush``. The leaves carry no
         attrs: what a call was is on the root and on ``ragged_step`` /
@@ -2493,33 +2614,31 @@ class InferenceEngineV2:
                 base_rng = jax.random.PRNGKey(seed) if sampling else None
             t_start = time.perf_counter()
             served = False
-            # prompts go through put() (prefill); the continuation loop
-            # then stays in token space — argmax/sampler runs on device
-            # and only [N] int32s cross to host per step (put()'s [N,
+            # prompts go through put()'s launches (prefill) and every
+            # pick runs on the device: the call stays in token space,
+            # only [N] int32s cross to the host a step (put()'s [N,
             # vocab] logits are the API for external schedulers, not the
             # hot loop)
             try:
-                logits = self.put(uids, prompts)
+                steps, logits = self._put(uids, prompts)
                 with trace.span("gen_first_token"):
-                    self._m_ttft.observe(time.perf_counter() - t_start)
+                    # queued behind the last chunk step; its tokens'
+                    # arrival is the wait for that step
                     if sampling:
-                        from .sampling import (fold_in_rows,
-                                               sample_tokens_rowwise)
                         # per-row keys (stable row seed + generated-token
                         # index): a row's stream depends only on its own
                         # draw history, so the per-token and fused-window
                         # paths sample the exact same tokens for a given
                         # seed
-                        keys = fold_in_rows(
-                            base_rng, jnp.arange(len(uids), dtype=jnp.int32),
-                            jnp.zeros(len(uids), jnp.int32))
-                        first = np.asarray(sample_tokens_rowwise(
-                            jnp.asarray(logits), keys,
-                            jnp.full((len(uids),), temperature, jnp.float32),
-                            jnp.full((len(uids),), top_p, jnp.float32),
-                            jnp.full((len(uids),), top_k, jnp.int32)))
+                        first = self._first_sample_jit(
+                            logits, base_rng, *self._sampling_arrays(
+                                logits.shape[0], list(range(len(uids))),
+                                [0] * len(uids), temperature, top_p, top_k))
                     else:
-                        first = np.argmax(logits, axis=-1)
+                        first = self._first_greedy_jit(logits)
+                    del logits      # the steps' own handle goes below
+                    first = self._collect_ragged(steps, first)
+                    self._m_ttft.observe(time.perf_counter() - t_start)
                     # what came back and is not in ``outs`` yet: the
                     # prefill's pick here, then a window's tokens or a
                     # step's one (the last of a row is the next one fed)

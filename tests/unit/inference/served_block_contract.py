@@ -15,6 +15,7 @@ and every engine comes back empty.
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from deepspeed_tpu.inference.v2 import InferenceEngineV2
@@ -201,6 +202,109 @@ def test_a_prompt_in_put_chunks_is_the_prompt_in_one(served, lend, chunked):
     for eng, us in ((parts, a), (whole, b)):
         for uid in us:
             eng.flush(uid)
+
+
+_SAMPLED = dict(temperature=0.8, top_p=0.9, top_k=0, seed=5)
+
+
+def _host_pick(logits, sampled):
+    """The first token as the host picked it over ``put()``'s logits
+    until generate() stopped fetching them: ``np.argmax``, or the
+    sampler's rows under the keys of a row's first draw."""
+    if not sampled:
+        return np.argmax(logits, axis=-1)
+    from deepspeed_tpu.inference.v2.sampling import (fold_in_rows,
+                                                     sample_tokens_rowwise)
+    n = len(logits)
+    keys = fold_in_rows(jax.random.PRNGKey(_SAMPLED["seed"]),
+                        jnp.arange(n, dtype=jnp.int32),
+                        jnp.zeros(n, jnp.int32))
+    return np.asarray(sample_tokens_rowwise(
+        jnp.asarray(logits), keys,
+        *(jnp.full((n,), _SAMPLED[k], t) for k, t in (
+            ("temperature", jnp.float32), ("top_p", jnp.float32),
+            ("top_k", jnp.int32)))))
+
+
+@clause("handed_on", lambda row: row.handed_on)
+def test_generate_hands_on_tokens_and_put_fetches_its_logits(
+        served, lend, monkeypatch, handed_on):
+    """Inside ``generate()`` the prompt's steps hand on device arrays
+    and ``[N]`` tokens: nothing ``[rows, vocab]`` wide reaches the host
+    (the counter of fetched logits stands, and no array that
+    ``jax.device_get`` returns is that large), the host waits for ONE
+    ragged step however many chunk steps the prompts take (the others
+    are launched ahead), and the first token is the one the host picked
+    over ``put()``'s logits, greedy and sampled on one seed, for prompts
+    fed in one step, in chunk steps, and in chunk steps that rows of
+    unequal length end in apart. ``put()`` and ``step_ragged()`` keep
+    their contract: the same logits, fetched once, counted."""
+    row = served.row
+    eng = lend(**handed_on.spec)
+    prompts = sb.prompts(row, handed_on.lengths)
+    steps = max(handed_on.chunks, 1)
+    vocab = row.toy["vocab_size"]
+    pick = _SAMPLED if handed_on.sampled else {"temperature": 0.0}
+    names = ("inference_ragged_steps_total",
+             "inference_ragged_host_syncs_total",
+             "inference_ragged_logits_fetched_bytes_total",
+             "inference_prefill_chunks_total")
+    fetched = []
+    device_get = jax.device_get
+
+    def spy(tree):
+        out = device_get(tree)
+        fetched.extend(np.size(x) for x in jax.tree.leaves(out))
+        return out
+
+    before = _total(*names)
+    with monkeypatch.context() as patched:
+        patched.setattr(jax, "device_get", spy)
+        outs = eng.generate(prompts, max_new_tokens=handed_on.new,
+                            eos_token_id=None, uids=sb.uids(len(prompts)),
+                            **pick)
+    after = _total(*names)
+    assert [b - a for a, b in zip(before, after)] \
+        == [steps, 1, 0, handed_on.chunks]
+    assert fetched and max(fetched) < len(prompts) * vocab, fetched
+    uids = sb.uids(len(prompts))
+    logits = eng.put(uids, prompts)
+    for uid in uids:
+        eng.flush(uid)
+    assert logits.shape == (len(prompts), vocab)
+    assert [b - a for a, b in zip(after, _total(*names))] \
+        == [steps, 1, eng._decode_bucket(len(prompts)) * vocab * 4,
+            handed_on.chunks]
+    np.testing.assert_array_equal(
+        [out[len(p)] for out, p in zip(outs, prompts)],
+        _host_pick(logits, handed_on.sampled))
+    if steps == 1:
+        # the one step of a put() is step_ragged(), to the bit
+        again = eng.step_ragged(uids, prompts)
+        for uid in uids:
+            eng.flush(uid)
+        np.testing.assert_array_equal(again, logits)
+
+
+@clause("pinned", lambda row: [row.pinned] * bool(row.pinned))
+def test_the_programs_a_call_launches_are_the_pinned_ones(served, lend,
+                                                          pinned):
+    """The ragged step and the decode windows lower to what the row
+    pins, and the first token's pick is a program of its own beside
+    them: what the host does between launches changes no launch."""
+    row = served.row
+    eng = lend(**pinned.spec)
+    prompts = sb.prompts(row, pinned.lengths)
+
+    def call():
+        for pick in ({"temperature": 0.0}, _SAMPLED):
+            eng.generate(prompts, max_new_tokens=5, eos_token_id=None,
+                         uids=sb.uids(len(prompts)), **pick)
+
+    got = sb.lowered(eng, call)
+    assert {"first_token_greedy", "first_token_sample"} <= set(got)
+    assert {name: got.get(name) for name in pinned.programs} \
+        == pinned.programs
 
 
 def _kept_err(eng, row, uid, tokens):

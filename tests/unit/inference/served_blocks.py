@@ -83,6 +83,7 @@ class Case:
     row_chunk: Optional[int] = None  # a row's share of a step; its ring is
     #                                 the window, that share and one block
     rises: tuple = ()               # counters the case must move
+    sampled: bool = False           # generate() samples (a seed of its own)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -116,6 +117,17 @@ class Chunked:
     whole: dict = dataclasses.field(default_factory=dict)
     parts: dict = dataclasses.field(default_factory=lambda: {"budget": 32})
     chunks: int = 8
+
+
+@dataclasses.dataclass(frozen=True)
+class Pinned:
+    """The programs a greedy and a sampled ``generate()`` of ``lengths``
+    on ``lend(**spec)`` launch, by the hash of what each lowers to
+    (``lowered``): a PR of the host loop leaves them as they are, one
+    that means to change a program pins what it made of it."""
+    spec: dict
+    lengths: tuple
+    programs: dict
 
 
 @dataclasses.dataclass(frozen=True)
@@ -167,6 +179,8 @@ class Block:
     decode: tuple = ()
     alone: Optional[Alone] = None
     chunked: Optional[Chunked] = None
+    handed_on: tuple = ()
+    pinned: Optional[Pinned] = None
     kept: Optional[Kept] = None
     controls: tuple = ()
     refusals: Optional[Refusals] = None
@@ -282,6 +296,27 @@ def _in_use(eng):
             "state slots": sm.state_slots_in_use(),
             "ring blocks": sm.window_blocks_in_use(),
             "free blocks": sm.free_blocks()}
+
+
+def lowered(eng, call):
+    """``{program: [a hash a signature]}`` of the programs ``call()``
+    launches on ``eng``: sha256 (16 hex digits) of the text each watched
+    jit lowers to (StableHLO, no locations) for the shapes it was
+    launched with (the benchmark's recorder, ``runners/generate.py``),
+    sorted. What a PR that must not touch a program pins it by; the
+    engine's jits are put back."""
+    import hashlib
+    from benchmark.runners.generate import Programs
+    programs = Programs(eng)
+    try:
+        call()
+    finally:
+        programs.release()
+    out = {}
+    for fn, shapes in programs.seen.values():
+        out.setdefault(fn.program, []).append(hashlib.sha256(
+            fn.lower(*shapes).as_text().encode()).hexdigest()[:16])
+    return {program: sorted(hashes) for program, hashes in out.items()}
 
 
 class Lender:
@@ -451,6 +486,26 @@ def _swap_groups(eng):
     eng.params = {**eng.params, "hybrid_layers": stack}
 
 
+def _handed_on(*forms):
+    """A greedy and a sampled case of each ``(id, spec, lengths, chunk
+    steps)``: what ``generate()`` hands from the prompt's steps to its
+    first window, on engines the row's other cases build anyway."""
+    return tuple(
+        Case(f"{name}-{pick}", spec, lengths=lengths, new=5, chunks=chunks,
+             sampled=pick == "sampled")
+        for name, spec, lengths, chunks in forms
+        for pick in ("greedy", "sampled"))
+
+
+# two rows of a step of 32 tokens in blocks of 16: 16 each a step, and
+# the step to itself once the other row has ended
+_IN_STEPS_OF_32 = (("in-chunks", {"budget": 32}, (48, 48), 3),
+                   ("rows-end-apart", {"budget": 32}, (40, 20), 3))
+# a ring's share of a step is 8 tokens a row
+_IN_SHARES_OF_8 = (("one-step", {}, (5, 7, 6), 0),
+                   ("in-chunks", {}, (24, 24), 3),
+                   ("rows-end-apart", {}, (24, 12), 3))
+
 _STATE_REFUSALS = (
     ({"tensor_parallel_size": 2}, "tensor_parallel_size"),
     ({"max_lora_adapters": 2}, "max_lora_adapters"),
@@ -486,6 +541,7 @@ _ROWS = (
         put=(Case("float32"),
              Case("bfloat16", {"dtype": "bfloat16"}, BF16_GAP)),
         decode=(Case("float32", lengths=(16, 21, 9), prompt_seed=1, new=20),),
+        handed_on=_handed_on(("one-step", {}, None, 0)),
         controls=(
             Control("int8-pool", {"kv_quant": True}, 500,
                     leaf=("latent", "int8")),
@@ -515,6 +571,7 @@ _ROWS = (
         put=(Case("float32"), Case("bfloat16", _NO_FLIP, BF16_LOGITS)),
         decode=(Case("float32"), Case("bfloat16", _NO_FLIP)),
         alone=Alone(tol=2e-6, relative=False), chunked=Chunked(),
+        handed_on=_handed_on(("one-step", {}, None, 0), *_IN_STEPS_OF_32),
         kept=Kept({"ssm_state": (9, 8, 16, 32),
                    "ssm_conv": (9, 3, 8 * 16 + 2 * 32)},
                   lambda state: state["ssm_state"][:3], _leading(layers=3)),
@@ -535,7 +592,7 @@ _ROWS = (
         put=(Case("float32"),
              Case("bfloat16", {"dtype": "bfloat16"}, BF16_GAP)),
         decode=(Case("float32"), Case("bfloat16", {"dtype": "bfloat16"})),
-        alone=Alone(),
+        alone=Alone(), handed_on=_handed_on(("one-step", {}, None, 0)),
         kept=Kept({"kda_state": (7, 4, 16, 16), "kda_conv": (7, 3, 192)},
                   lambda state: state["kda_state"][:3], _leading()),
         controls=(Control("bf16-state", _BF16_STATE, 20, sound={},
@@ -567,6 +624,7 @@ _ROWS = (
         put=(Case("one-step", chunks=0),
              Case("in-chunks", {"budget": 32}, chunks=8)),
         decode=(Case("float32"),),
+        handed_on=_handed_on(("one-step", {}, None, 0), *_IN_STEPS_OF_32),
         kept=Kept({"ssm_state": (7, 8, 16, 32),
                    "ssm_conv": (7, 3, 8 * 16 + 2 * 2 * 32)},
                   lambda state: state["ssm_state"][:3], _leading(layers=3)),
@@ -599,6 +657,13 @@ _ROWS = (
                 Case("chunks-over-the-window", {"budget": 128}, new=40,
                      row_chunk=32, rises=_REUSED)),
         chunked=Chunked((24, 17), {"budget": 128}, {}, 3),
+        handed_on=_handed_on(*_IN_SHARES_OF_8),
+        # PR 62's programs, which PR 63 (the prompt's steps launched
+        # ahead, the first token picked on the device) left to the hash
+        pinned=Pinned({}, (24, 12), {
+            "ragged_step": ["116a86210638417b", "690592fec2b62d61"],
+            "decode_window_greedy": ["ff68bf23ff9f8730"],
+            "decode_window_sample": ["820da7320059d02b"]}),
         refusals=Refusals("layer_types", (
             ({"state_manager": {"enable_prefix_caching": True}},
              "enable_prefix_caching"),
@@ -623,6 +688,7 @@ _ROWS = (
              Case("gather", {"use_paged_kernel": False}, lengths=(50, 33),
                   chunks=7, impl="jnp:gather")),
         decode=(Case("float32", new=40, row_chunk=8, rises=_REUSED),),
+        handed_on=_handed_on(*_IN_SHARES_OF_8),
         controls=tuple(
             Control(name, spec, 500) for name, spec in (
                 ("a-bf16-engine", {"dtype": "bfloat16"}),
@@ -655,6 +721,15 @@ _ROWS = (
         decode=(Case("float32"), Case("bfloat16", {"dtype": "bfloat16"})),
         alone=Alone(),
         chunked=Chunked((100, 24), {}, {"budget": 32}, 5),
+        handed_on=_handed_on(
+            ("one-step", {}, None, 0),
+            ("in-chunks", {"budget": 32}, (24, 24), 2),
+            ("rows-end-apart", {"budget": 32}, (100, 24), 5)),
+        # PR 62's programs, left to the hash by PR 63 (trinity's row)
+        pinned=Pinned({"budget": 32}, (24, 24), {
+            "ragged_step": ["d5312dcbe7fd7d87", "ff0eaf2422fe89c5"],
+            "decode_window_greedy": ["1309402e8177d3dc"],
+            "decode_window_sample": ["a5856130719c3751"]}),
         kept=Kept({"ssm_state": (4, 6, 16, 32),
                    "ssm_conv": (4, 3, 6 * 16 + 2 * 2 * 32)},
                   lambda state: state["ssm_state"][:3], _leading(layers=3)),
@@ -682,6 +757,7 @@ _ROWS = (
         put=(Case("float32"), Case("bfloat16", _SEED_11, BF16_LOGITS)),
         decode=(Case("float32"), Case("bfloat16", _SEED_11)),
         alone=Alone(), chunked=Chunked(),
+        handed_on=_handed_on(("one-step", {}, None, 0), *_IN_STEPS_OF_32),
         kept=Kept({"retention_state": (2, 2, 136, 16),
                    "retention_norm": (2, 2, 136)},
                   _retention_held, _retention_wanted),

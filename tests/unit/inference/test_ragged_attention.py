@@ -907,7 +907,8 @@ def test_a_serving_config_that_names_ragged_attention_is_refused():
         _serving_config({"serving": {"ragged_attention": "off"}})
 
 
-PLAIN_FAMILIES = {"ragged_step", "decode_greedy", "decode_sample",
+PLAIN_FAMILIES = {"ragged_step", "first_token_greedy", "first_token_sample",
+                  "decode_greedy", "decode_sample",
                   "decode_window_greedy", "decode_window_sample"}
 SPEC_FAMILIES = {"spec_verify_w", "draft_catchup", "spec_decode_window"}
 
@@ -933,10 +934,11 @@ def _engine_families(eng):
 def test_the_engine_compiles_the_families_that_remain_and_no_other(
         tiny, speculative):
     """After the mixed traffic (greedy and sampled rows, all-greedy
-    rows, sampled generate(); windows of 1 and of 8) the compiled
-    programs are the five plain families.
+    rows, a sampled and a greedy generate(); windows of 1 and of 8) the
+    programs are the seven plain families (generate()'s pick of the
+    first token is two of them: the ragged step's stays put()'s).
     With a draft model loaded, speculative generate() through both
-    draft sources adds the three speculative ones, and no ninth; the
+    draft sources adds the three speculative ones, and no eleventh; the
     engine holds no watched jit of another name either."""
     model, params = tiny
     prompts = _mixed_prompts()
@@ -949,6 +951,7 @@ def test_the_engine_compiles_the_families_that_remain_and_no_other(
             _greedy_mixed_traffic(sched, prompts, 300)
             eng.generate(prompts[:2], max_new_tokens=4, temperature=0.8,
                          seed=3)
+            eng.generate(prompts[:2], max_new_tokens=4)
         want = set(PLAIN_FAMILIES)
         if speculative:
             eng.load_draft_model(model, params)

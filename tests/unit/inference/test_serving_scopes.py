@@ -131,8 +131,9 @@ def test_leaf_spans_tile_the_call_and_reach_its_root(engine):
 def test_the_coarse_spans_keep_name_extent_and_attrs(engine):
     eng, prompts, _ = engine
     # the extent is the children's: uploads and launch, then the wait
-    # (a window's: for the tokens of the window before it, so a call's
-    # first has none and its last is fetched under the root). What a
+    # (for what was launched BEFORE: the tokens of the window before a
+    # window, the chunk step before a ragged step; so a call's first
+    # has none and its last is fetched under the root). What a
     # span's children leave uncovered is the host between them, which
     # beside five other test workers is now and then a preemption: the
     # best of three calls says whether the extent is the children's
@@ -158,8 +159,10 @@ def test_the_coarse_spans_keep_name_extent_and_attrs(engine):
             inner = [s for s in spans if s["parent"] == outer["id"]]
             assert tuple(s["name"] for s in sorted(
                 inner, key=lambda s: s["start"])) == names
-            # (a span with no wait in it is too short to say much)
-            if outer is not windows[0]:
+            # (a span with no wait in it is too short to say much: a
+            # call's first window, and its one ragged step, whose wait
+            # is the first tokens' arrival under gen_first_token)
+            if outer is windows[1]:
                 worst = max(worst, 1.0 - sum(
                     s["duration_s"] for s in inner) / outer["duration_s"])
         uncovered.append(worst)
@@ -266,12 +269,13 @@ def test_every_serving_jit_is_named_for_its_program(engine):
     watched = {name: fn for name, fn in vars(eng).items()
                if isinstance(fn, WatchedFunction)}
     assert {fn.program for fn in watched.values()} == {
-        "ragged_step", "decode_greedy", "decode_sample",
+        "ragged_step", "first_token_greedy", "first_token_sample",
+        "decode_greedy", "decode_sample",
         "decode_window_greedy", "decode_window_sample"}
     for fn in watched.values():
         assert fn.__wrapped__.__name__ == fn.program, fn
     eng.generate(prompts, max_new_tokens=NEW_TOKENS)
-    ran = {"ragged_step", "decode_window_greedy"}
+    ran = {"ragged_step", "first_token_greedy", "decode_window_greedy"}
     assert ran <= set(memory._executables)
     for program in ran:
         head = memory._executables[program]().as_text().splitlines()[0]

@@ -68,12 +68,8 @@ def test_the_pattern_is_walked_as_runs_of_one_mixer_and_one_mlp():
             jnp.zeros((1, 8), jnp.int32))
 
 
-@pytest.fixture(scope="module")
-def chunked_call(served):
-    """The ring of one ``generate()`` whose prompts go in in three chunk
-    steps (one engine for the cases below)."""
-    eng = served.lend()                           # a row's share: 8
-    prompts = sb.prompts(BLOCK, (24, 17))
+def _ring_of_a_call(eng, lengths):
+    prompts = sb.prompts(BLOCK, lengths)
     eng.generate(prompts, max_new_tokens=2, temperature=0.0,
                  eos_token_id=None)                # compiles
     trace.clear()
@@ -82,12 +78,27 @@ def chunked_call(served):
     return sorted(trace.export(), key=lambda s: s["start"])
 
 
+@pytest.fixture(scope="module")
+def chunked_call(served):
+    """The ring of one ``generate()`` whose prompts go in in three chunk
+    steps (one engine for the cases below)."""
+    return _ring_of_a_call(served.lend(), (24, 17))   # a row's share: 8
+
+
+@pytest.fixture(scope="module")
+def one_step_call(served):
+    """The ring of one ``generate()`` whose prompts fit one step."""
+    return _ring_of_a_call(served.lend(), (5, 7, 6))
+
+
 @pytest.mark.parametrize("what", ["one a chunk step", "a leaf of the call",
                                   "behind the step's bookkeeping"])
 def test_put_chunk_is_puts_own_work_behind_a_chunk_step(chunked_call, what):
-    """``_put_chunks`` folds a step's logits into its rows (and stacks
-    them behind the last step) under a leaf of its own, ``put_chunk``:
-    no part of a chunked ``put()`` runs outside a leaf."""
+    """``_put_chunks`` notes which rows a step ended (and behind the
+    last step brings their logits together, on the device) under a leaf
+    of its own, ``put_chunk``: no part of a chunked ``put()`` runs
+    outside a leaf, and the next step's pack follows at once: nothing
+    waits for the step just launched."""
     ring = chunked_call
     chunks = [s for s in ring if s["name"] == "put_chunk"]
     root, = (s for s in ring if s["name"] == "generate")
@@ -106,6 +117,33 @@ def test_put_chunk_is_puts_own_work_behind_a_chunk_step(chunked_call, what):
         assert [names[i - 1] for i in at] == ["ragged_bookkeeping"] * 3
         assert names[at[-1] + 1] == "gen_first_token"
         assert [names[i + 1] for i in at[:-1]] == ["ragged_pack"] * 2
+
+
+@pytest.mark.parametrize("metric", [
+    "gap_host_ms.gen", "gap_launch_ms.gen", "gap_upload_ms.gen",
+    "gap_call_ms.gen", "gap_fetch_ms.gen"])
+@pytest.mark.parametrize("call", ["chunked", "one step"])
+def test_every_leaf_the_gap_metrics_read_is_still_emitted(
+        chunked_call, one_step_call, call, metric):
+    """A call whose prompt steps are launched ahead, and one whose
+    prompts fit one step, still emit every leaf a ``gap_*`` metric's
+    file lists but the per-token path's ``step_*`` (one that is gone
+    reads null there, not 0.0), and each step's wait stands where the
+    readers look for it: ``ragged_fetch`` inside the ``ragged_step``
+    span of the step launched behind it (empty in a call's first), the
+    last step's under ``gen_first_token``, where the first tokens
+    arrive."""
+    ring = chunked_call if call == "chunked" else one_step_call
+    spec = json.loads((sb.REPO / "benchmark/layer_metrics"
+                       / f"{metric}.json").read_text())["params"]
+    listed = {name for key in ("spans", "host_spans", "launch_spans")
+              for name in spec.get(key, ()) if not name.startswith("step_")}
+    assert listed and listed <= {s["name"] for s in ring}
+    steps = [s for s in ring if s["name"] == "ragged_step"]
+    assert len(steps) == (3 if call == "chunked" else 1)
+    for step in steps:
+        inner = [s["name"] for s in ring if s["parent"] == step["id"]]
+        assert inner == ["ragged_dispatch", "ragged_fetch"]
 
 
 def test_a_mixed_step_decodes_some_rows_and_feeds_chunks_of_others(lend):
