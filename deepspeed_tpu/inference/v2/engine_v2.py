@@ -46,9 +46,9 @@ from .kernels.ragged_attention import (LATENT, decode_positions,
                                        one_token_tile_serves, prompt_chunks,
                                        token_tile, token_tile_serves)
 from .paged_model import (STATE_LEAVES, init_lora_bank, init_paged_kv_cache,
-                          moe_rows_form, paged_continue, paged_decode,
-                          paged_decode_window, paged_ragged_step,
-                          paged_spec_decode_window)
+                          moe_rows_form, moe_share_runs, paged_continue,
+                          paged_decode, paged_decode_window,
+                          paged_ragged_step, paged_spec_decode_window)
 from .ragged import batch as ragged_batch
 from .ragged.blocked_allocator import NULL_BLOCK
 from .ragged.ragged_manager import DSStateManager
@@ -651,6 +651,9 @@ class InferenceEngineV2:
             program=program, form=moe_rows_form(
                 self.model.cfg, tokens, self.dtype)).inc(rows)
         self._m_moe_launches.labels(program=program).inc(launches)
+        self._m_moe_share_runs.labels(program=program).inc(
+            launches * moe_share_runs(self.model.cfg, tokens,
+                                      self.dtype)[0])
         self._m_moe_form_launches.labels(
             program=program, form=self.model.cfg.moe_expert_form).inc(
             launches)
@@ -683,6 +686,14 @@ class InferenceEngineV2:
             "(moe_rows_whole + moe_rows_combine: a share's prompt launch "
             "on a TPU) or gather (XLA's: every other launch)",
             labelnames=("program", "form"))
+        self._m_moe_share_runs = reg.counter(
+            "moe_share_runs_total",
+            "runs of tokens a share's launches went through the experts "
+            "in (an expert-layer pass over a run: paged_model."
+            "moe_share_runs), from the launch's shape; over "
+            "moe_launches_total the runs a pass: 0 where a pass is one "
+            "dispatch (every expert held, a decode step)",
+            labelnames=("program",))
         self._m_moe_touched = reg.counter(
             "moe_experts_touched_total",
             "distinct experts with at least one row, summed over expert "

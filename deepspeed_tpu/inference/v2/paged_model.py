@@ -430,19 +430,46 @@ def _moe_mlp(cfg, lp, x, topo=None):
     return out.reshape(orig_shape)
 
 
-# tokens of a launch an expert layer that holds a SHARE takes at a time:
-# 4,096 at 8 picks of 2,560 bf16 values a token (0.17 GB of sorted rows
-# a buffer), fewer in proportion where a token's picks hold more
+# A share's prompt launch goes through its experts in RUNS of tokens
+# (:func:`_moe_experts`), and a run is sized by what the grouped matmul
+# needs. Every run streams every held expert's weights anew, and an
+# expert's rows make 2 operations a weight value: in bf16 the rows an
+# expert ARE the run's operations a weight byte, and the chip's ridge is
+# 197 TFLOP/s over 819 GB/s = 240. The kernel does not hide the stream
+# behind the products: on the chip a run-dispatch's three ``gmm``
+# launches take 1.07 ms + 0.617 us a token at granite's share
+# (``scripts/bench_kernels.py --only share-gmm``, PERF.md section 6,
+# PR 64), and 1.07 ms is what 241 rows an expert cost: a run of r ridges
+# runs at r / (r + 1) of what rows alone would take (half at the 285
+# rows bytes alone gave granite; a row tile of 128 is also visited whole
+# for every group it spans, which few rows an expert pay most). So a
+# run's expected rows a held expert (tokens x k / the router's experts)
+# reach ``_SHARE_ROWS``, about four ridges (80 %), inside
+# ``_SHARE_RUN_BYTES`` of sorted rows a buffer and never under
+# ``_SHARE_TOKENS``. The bytes are the largest power of two at which
+# the three share cells' ragged steps fit their chip with 0.5 GB to
+# spare by the compiler's memory analysis (the rows, their products and
+# the kernels' relaid copy are live together: granite 15.42 GB of 16 at
+# 0.67 GB, 15.94 at twice that).
+_SHARE_ROWS = 1024
 _SHARE_TOKENS = 4096
-_SHARE_PICKS_BYTES = 8 * 2560 * 2
+_SHARE_RUN_BYTES = 4 * 4096 * 8 * 2560 * 2
 
 
-def _share_tokens(xt, k):
-    """Tokens a share's run takes, for rows ``xt`` [T, H] of k picks: a
-    power of two, ``_SHARE_TOKENS`` at most."""
-    fit = _SHARE_TOKENS * _SHARE_PICKS_BYTES \
-        // (k * xt.shape[1] * xt.dtype.itemsize)
-    return min(_SHARE_TOKENS, 1 << max(fit.bit_length() - 1, 0))
+def _share_tokens(cfg, dtype):
+    """Tokens a share's run takes, for rows of ``cfg.hidden_size``
+    values of ``dtype`` and ``cfg.moe_top_k`` picks over the router's
+    ``cfg.moe_num_experts``: the power of two that gives a held expert
+    ``_SHARE_ROWS`` rows, ``_SHARE_TOKENS`` at least, the largest that
+    fits ``_SHARE_RUN_BYTES`` at most (granite's 10 picks of 4,096 over
+    72 experts: 8,192 tokens, the bytes to the byte; nemotron's and
+    ling's launches of 16,384 whole)."""
+    k = cfg.moe_top_k
+    fit = _SHARE_RUN_BYTES // (k * cfg.hidden_size
+                               * jnp.dtype(dtype).itemsize)
+    want = -(-_SHARE_ROWS * cfg.moe_num_experts // k)
+    return min(1 << max(fit.bit_length() - 1, 0),
+               max(_SHARE_TOKENS, 1 << (want - 1).bit_length()))
 
 
 def _held_from(cfg):
@@ -452,24 +479,31 @@ def _held_from(cfg):
         if cfg.experts_held < cfg.moe_num_experts else None
 
 
+def moe_share_runs(cfg, tokens: int, dtype):
+    """How an expert layer's launch of ``tokens`` flat tokens goes
+    through its experts: (the runs, the tokens of ONE dispatch). A tree
+    that holds a share sends a launch over a run
+    (:func:`_share_tokens`) through in runs; every other launch is one
+    dispatch of its own tokens, (0, ``tokens``). What ``_moe_experts``
+    traces and what the engine counts (``moe_share_runs_total``)."""
+    run = tokens if _held_from(cfg) is None else _share_tokens(cfg, dtype)
+    return (-(-tokens // run), run) if tokens > run else (0, tokens)
+
+
 def moe_rows_form(cfg, tokens: int, dtype) -> str:
     """How an expert layer's launch of ``tokens`` flat tokens brings its
     routed rows back from expert order: ``"kernel"``
     (``kernels/expert_combine.rows_combine``) or ``"gather"`` (XLA's
     lines in ``dropless_topk_dispatch``), from the shape of ONE dispatch
-    (a share's launch over a run goes through in runs), the experts'
-    type and whether the tree holds a share of them. What
-    ``_moe_experts`` traces and what the engine counts
-    (``moe_rows_combined_total``)."""
+    (:func:`moe_share_runs`), the experts' type and whether the tree
+    holds a share of them. What ``_moe_experts`` traces and what the
+    engine counts (``moe_rows_combined_total``)."""
     from .kernels.expert_combine import rows_combine_serves
 
-    share = _held_from(cfg) is not None
-    if share:
-        tokens = min(tokens, _share_tokens(
-            jax.ShapeDtypeStruct((tokens, cfg.hidden_size), dtype),
-            cfg.moe_top_k))
+    _, tokens = moe_share_runs(cfg, tokens, dtype)
     return "kernel" if rows_combine_serves(
-        tokens * cfg.moe_top_k, cfg.hidden_size, dtype, share) else "gather"
+        tokens * cfg.moe_top_k, cfg.hidden_size, dtype,
+        _held_from(cfg) is not None) else "gather"
 
 
 def _moe_route(cfg, lp, xt, router_precision=None):
@@ -534,8 +568,9 @@ def _moe_experts(cfg, lp, xt, topi, topv, experts=None, stack_layer=None):
                 stack_layer=stack_layer, held_from=_held_from(cfg),
                 rows_combine=rows_combine if kernel else None)
 
-        T, run = xt.shape[0], _share_tokens(xt, cfg.moe_top_k)
-        if _held_from(cfg) is not None and T > run:
+        T = xt.shape[0]
+        runs, run = moe_share_runs(cfg, T, xt.dtype)
+        if runs:
             # a share's launch sorts and gathers EVERY pick's row and
             # computes the held ones (all of a token's picks may be
             # held, so no smaller buffer is safe): a launch of any
@@ -544,7 +579,7 @@ def _moe_experts(cfg, lp, xt, topi, topv, experts=None, stack_layer=None):
             # live (16,384 tokens x 8 picks x 2560 are 0.67 GB a
             # buffer). The last run is padded with rows of zeros, which
             # add nothing and are cut off
-            pad = -T % run
+            pad = runs * run - T
             out = jax.lax.map(lambda a: dispatch(*a), tuple(
                 jnp.pad(a, ((0, pad), (0, 0))).reshape(
                     -1, run, a.shape[-1])

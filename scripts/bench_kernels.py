@@ -57,6 +57,27 @@ it touched a launch, so that no launch's work is the loop's invariant.
 ``expert_combine.MIN_ROWS`` is read off this table: the row count from which
 ``kernel`` is under ``xla``.
 
+A SHARE'S RUN THROUGH THE GROUPED MATMUL (PR 64), ``share-gmm``: one
+run-dispatch's ``gmm`` launches alone (``moe/sharded_moe.gmm_swiglu_experts``
+/ ``gmm_relu2_experts``, as ``dropless_topk_dispatch`` calls them) at the
+three share cells' experts: granite's (72 experts, 36 held, 10 picks of
+4,096 x 768), nemotron's (128, 64 held, 6 picks, relu2 of 2,688 x 1,856) and
+ling's (512, 128 held, 8 picks of 2,560 x 768), the weights a scanned STACK's
+(``layers`` x held groups of which one layer's are non-empty, the layer turning
+launch by launch), every token's picks distinct and random, the rows sorted
+as the dispatch sorts them. A row of the output a RUN of 2,048, 4,096, 8,192
+and 16,384 tokens (``--contexts``); ``tree_run`` is what this tree's
+``paged_model._share_tokens`` gives the cell's launch. Beside the time: us a
+TOKEN (what a longer run is for), the mean rows a held expert, the row tiles
+the kernel visits (one a group a tile it spans) over the tiles the rows fill,
+the touched experts' bytes and the operations at the chip's peaks.
+``--sweep`` adds tilings the program does not run: ``k-tiles`` (an expert's
+weight cut along the CONTRACTED width in place of its columns, so the rows
+pass once a matrix), ``rows-256`` (the row tile; 512 is over the
+kernel's fast memory) and ``aligned`` (every expert's rows from a whole row
+tile on, the groups padded to whole tiles: no tile spans two groups).
+``--rehearse`` runs a toy share through ``ragged_*_experts`` on the CPU.
+
 The variants take the walk apart by replacing one function of
 ``kernels/ragged_attention.py`` in this process (nothing a cell runs is
 touched, and no option of the program exists for it):
@@ -110,6 +131,7 @@ import json
 import os
 import sys
 import time
+import types
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
@@ -134,6 +156,8 @@ try:                            # a tree before the kind: its shapes skip
         power_retention as pr)
 except ImportError:
     pr = None
+
+from deepspeed_tpu.moe import sharded_moe as sm               # noqa: E402
 
 from benchmark import arith_window                            # noqa: E402
 
@@ -222,10 +246,25 @@ SHAPES = {
                                      H=2688, experts=128, held=64),
     "ling-dispatch-decode": dict(kernel="dispatch", tokens=128, k=8,
                                  H=2560, experts=512, held=128),
+    # a share's run through the grouped matmul: tokens of k picks over
+    # ``experts`` of which the first ``held`` are held here, an expert of
+    # ``form`` H x F, the stack ``layers`` expert layers deep; ``launch``
+    # the cell's ragged step (tokens)
+    "granite-share-gmm": dict(kernel="share_gmm", k=10, H=4096, F=768,
+                              experts=72, held=36, layers=10, form="swiglu",
+                              launch=16384, ends=(2048, 4096, 8192, 16384)),
+    "nemotron-share-gmm": dict(kernel="share_gmm", k=6, H=2688, F=1856,
+                               experts=128, held=64, layers=7, form="relu2",
+                               launch=16384, ends=(2048, 4096, 8192, 16384)),
+    "ling-share-gmm": dict(kernel="share_gmm", k=8, H=2560, F=768,
+                           experts=512, held=128, layers=6, form="swiglu",
+                           launch=16384, ends=(2048, 4096, 8192, 16384)),
 }
 # the family's name stands for its shapes under --only
 FAMILIES = {"dispatch-rows": [n for n, v in SHAPES.items()
-                              if v["kernel"] == "dispatch"]}
+                              if v["kernel"] == "dispatch"],
+            "share-gmm": [n for n, v in SHAPES.items()
+                          if v["kernel"] == "share_gmm"]}
 BS = 16
 EPS = 1e-6
 
@@ -275,6 +314,94 @@ def build_dispatch(shape, rng, rehearse):
     fn.xla = lambda ys, inv, held, topv, rows_held, interpret=False: \
         gather_rows_combine(ys, inv, held, topv)
     return fn, again, topv, (ys,), 1, 2 * H * (n_held + T), ref
+
+
+def build_share_gmm(shape, run, rng, rehearse):
+    """One run-dispatch's grouped matmuls over a stack's groups: ``(fn(xs,
+    layer, *weights) -> ys, again, xs, weights, layers, (bytes, flops) a
+    dispatch, reference)``. ``run`` tokens pick k distinct experts each at
+    random; ``xs`` holds their rows in expert order, the picks held
+    elsewhere behind every group (``dropless_topk_dispatch``'s order).
+    ``fn.rows`` / ``fn.held`` / ``fn.an_expert``: the dispatch's rows,
+    those held here and their mean a held expert;
+    ``fn.visits`` / ``fn.tiles``: the row tiles the kernel visits (one a
+    group a tile it spans) and the tiles the held rows fill;
+    ``fn.tree_run``: this tree's run at the cell's launch."""
+    from deepspeed_tpu.inference.v2 import paged_model
+    k, H, F, E, here, L, form = (shape[n] for n in (
+        "k", "H", "F", "experts", "held", "layers", "form"))
+    launch = shape["launch"]
+    if rehearse:
+        k, H, F, E, here, L, run, launch = 4, 256, 128, 16, 8, 2, run // 32, 512
+    topi = np.argsort(rng.random((run, E)), axis=1)[:, :k]
+    idx = topi.T.reshape(-1)
+    sizes = np.bincount(idx[idx < here], minlength=here).astype(np.int32)
+    n_held = int(sizes.sum())
+    ends = np.cumsum(sizes)
+    at = sizes > 0
+    visits = int(((ends[at] - 1) // sm._GMM_ROWS
+                  - (ends[at] - sizes[at]) // sm._GMM_ROWS + 1).sum())
+    dtype = jnp.float32 if rehearse else jnp.bfloat16
+    key = jax.random.PRNGKey(int(rng.integers(1 << 30)))
+    xs = jax.random.normal(key, (k * run, H), dtype)
+    # one layer's experts at random, the stack that layer scaled layer by
+    # layer: a stack's worth of random bits would be gigabytes beside it
+    shapes = ((H, F), (H, F), (F, H)) if form != "relu2" \
+        else ((F, H), (F, H))
+    scale = 1 + jnp.arange(L, dtype=jnp.float32)[:, None, None, None] / L
+    weights = tuple(jax.jit(lambda i, s=s: (
+        jax.random.normal(jax.random.fold_in(key, i), (1, here, *s))
+        * (0.5 * s[0] ** -0.5) * scale).astype(dtype))(i)
+        for i, s in enumerate(shapes, 1))
+    sizes = jnp.asarray(sizes)
+
+    def through(form_fn, xs, layer, weights, sizes=sizes):
+        """The rows through one layer of the stack, chosen as
+        ``dropless_topk_dispatch(stack_layer=)`` chooses it: by where its
+        groups lie among layers x held."""
+        return form_fn(
+            tuple(w.reshape(-1, *w.shape[2:]) for w in weights), xs,
+            jax.lax.dynamic_update_slice(
+                jnp.zeros((L * here,), jnp.int32), sizes, (layer * here,)))
+
+    def launch_of(sizes):
+        def fn(xs, layer, *weights):
+            # looked up when traced: a variant replaces the module's
+            ragged, gmm, _ = sm.expert_forms(form)
+            return through(ragged if rehearse else gmm, xs, layer, weights,
+                           sizes)
+        return fn
+
+    fn = launch_of(sizes)
+    # every expert's rows from a whole row tile on: the groups padded to
+    # whole tiles (rows of the buffer that no pick owns; timing only: the
+    # rows stay where they lay), no tile spans two; its launch wants
+    # ``pad`` rows more
+    whole = -(-sizes // sm._GMM_ROWS) * sm._GMM_ROWS
+    fn.aligned = launch_of(whole)
+    fn.aligned.visits = int(whole.sum()) // sm._GMM_ROWS
+    fn.aligned.pad = here * sm._GMM_ROWS
+
+    def ref(xs, layer, *weights):
+        return through(sm.expert_forms(form)[0], xs, layer, weights)
+
+    def again(xs, ys):
+        # the loop carries the rows and a launch rewrites one of them
+        return xs.at[0].add(ys[0] * 0)
+    try:
+        tree_run = min(launch, paged_model._share_tokens(
+            types.SimpleNamespace(hidden_size=H, moe_top_k=k,
+                                  moe_num_experts=E), dtype))
+    except (TypeError, AttributeError):     # before PR 64: bytes alone
+        tree_run = min(launch, paged_model._share_tokens(
+            jax.ShapeDtypeStruct((launch, H), dtype), k))
+    fn.rows, fn.held, fn.tree_run = k * run, n_held, tree_run
+    fn.visits, fn.tiles = visits, round(n_held / sm._GMM_ROWS, 1)
+    fn.tokens, fn.an_expert = run, round(n_held / here, 1)
+    item = jnp.dtype(dtype).itemsize
+    nbytes = item * (int(at.sum()) * len(shapes) * H * F + 2 * n_held * H)
+    return (fn, again, xs, weights, L,
+            (nbytes, 2 * n_held * H * F * len(shapes)), ref)
 
 
 def build_retention(shape, rng, rehearse):
@@ -652,7 +779,30 @@ def _whole_alone(ys, inv, held, topv, rows_held=None, interpret=False):
     return ec.rows_whole(ys, rows_held, interpret)[0].astype(jnp.float32)
 
 
+def _gmm_k_tiles(expert_params, xs, group_sizes, gate=jax.nn.silu):
+    """:func:`sm.gmm_swiglu_experts` with an expert's weight cut along the
+    CONTRACTED width where the program cuts its columns (the same bytes a
+    tile): the rows pass once a matrix and an expert's tiles turn a row
+    tile."""
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
+    xs, m = sm._whole_row_tiles(xs)
+
+    def mm(x, w):
+        K, N = w.shape[1:]
+        return gmm(x, w, group_sizes, preferred_element_type=x.dtype,
+                   tiling=(sm._GMM_ROWS, K * sm._gmm_columns(w) // N, N))
+    wg, wu, wd = expert_params
+    return mm(gate(mm(xs, wg)) * mm(xs, wu), wd)[:m]
+
+
 def variants(kernel, sweep, fn=None):
+    if kernel == "share_gmm":
+        out = {"full": {}}
+        if sweep:
+            out["k-tiles"] = dict(gmm_swiglu_experts=_gmm_k_tiles)
+            out["aligned"] = {}
+            out["rows-256"] = dict(_GMM_ROWS=256)
+        return out
     if kernel == "dispatch":
         # the function under its own jit: that jit's cache would hand a
         # variant the trace of the one before it
@@ -713,12 +863,15 @@ def variants(kernel, sweep, fn=None):
     return out
 
 
-def time_launches(fn, again, q, pools, L, launches, carried=False):
+def time_launches(fn, again, q, pools, L, launches, carried=False,
+                  kept=False):
     """us a launch: ``launches`` of them in one program, best of three.
     A fresh ``jit`` a call: its cache does not see the attributes a
     variant replaces. ``carried``: ``fn`` returns its pool beside its
     output (a state leaf, updated in place), and the loop hands it on:
-    the program is given a copy of the caller's to consume."""
+    the program is given a copy of the caller's to consume. ``kept``:
+    the pools are gigabytes of weights, which the program reads and does
+    not return (a returned pool is a copy of it)."""
     def step(i, qp):
         q, pools = qp
         out = fn(q, i % L, *pools)
@@ -726,8 +879,13 @@ def time_launches(fn, again, q, pools, L, launches, carried=False):
         return again(q, out), pools
 
     def many(q, *pools):
+        if kept:
+            return jax.lax.fori_loop(
+                0, launches, lambda i, q: step(i, (q, pools))[0], q)
         return jax.lax.fori_loop(0, launches, step, (q, pools))
     run = jax.jit(many, donate_argnums=(1,) if carried else ())
+    if kept:
+        run = lambda q, *pools, run=run: (run(q, *pools), pools)
     if carried:
         pools = tuple(jnp.copy(p) for p in pools)
     q, pools = run(q, *pools)
@@ -787,12 +945,19 @@ def main():
                     "retention_chunk": (build_retention, pr),
                     "dispatch": (build_dispatch, ec)}
         state = kernel in in_place
-        builder, module = in_place.get(kernel, (build, ra))
-        if kernel == "prompt":
-            fn, again, q, pools, L, (nbytes, flops), ref = build_prompt(
+        builder, module = in_place.get(
+            kernel, (build, sm if kernel == "share_gmm" else ra))
+        if kernel in ("prompt", "share_gmm"):
+            fn, again, q, pools, L, (nbytes, flops), ref = (
+                build_prompt if kernel == "prompt" else build_share_gmm)(
                 SHAPES[name], end, rng, args.rehearse)
-            row = {"shape": name, "context": end, "flops": flops,
-                   "flops_us": round(flops / PEAK_FLOPS_S * 1e6, 2)}
+            row = {"shape": name, "context": end} if kernel == "prompt" \
+                else {"shape": name, "run": fn.tokens,
+                      "tree_run": fn.tree_run, "rows": fn.rows,
+                      "held": fn.held, "rows_an_expert": fn.an_expert,
+                      "visits": fn.visits, "tiles": fn.tiles}
+            row.update(flops=flops,
+                       flops_us=round(flops / PEAK_FLOPS_S * 1e6, 2))
             launches = max(2, args.launches // 10)
         else:
             fn, again, q, pools, L, nbytes, ref = builder(
@@ -814,6 +979,8 @@ def main():
                 got = [got[0][ref.tokens]]
             if kernel == "dispatch":     # the carried ys holds NaN rows
                 got, want = got[:1], want[:1]
+            if kernel == "share_gmm":    # no group computes the rows behind
+                got, want = ([a[0][:fn.held]] for a in (got, want))
             row["max_err"] = max(float(jnp.abs(g - w).max())
                                  for g, w in zip(got, want))
             row["rel_err"] = max(
@@ -823,16 +990,27 @@ def main():
             if chosen and label not in chosen:
                 continue
             try:
+                # (``aligned`` is another launch over other rows)
+                timed, rows = (fn.aligned, jnp.pad(
+                    q, ((0, fn.aligned.pad), (0, 0)))) \
+                    if label == "aligned" else (fn, q)
                 with patched(module, **attrs):
                     row[label] = round(time_launches(
-                        fn, again, q, pools, L, launches,
-                        carried=state), 2)
+                        timed, again, rows, pools, L, launches,
+                        carried=state, kept=kernel == "share_gmm"), 2)
+                if label == "aligned":
+                    row["aligned_visits"] = timed.visits
+                if kernel == "share_gmm":
+                    row[f"{label}_us_a_token"] = round(
+                        row[label] / fn.tokens, 4)
             except KeyError as missing:
                 row[label] = f"no {missing.args[0]} in this tree"
             except Exception as refused:      # the compiler's, at a size
                 row[label] = f"{type(refused).__name__}: " \
                     f"{str(refused)[:200]}"
         print(json.dumps(row), flush=True)
+        # the next shape's weights want the room these hold
+        del fn, again, q, pools, ref
     if args.rehearse:
         print("REHEARSAL (cpu)")
 

@@ -17,7 +17,8 @@ scope) that the ``prefill_*_ms.gen`` metrics read
 idle gaps between launches, each by the leaf span the host was in
 (``gap_*_ms.gen``'s arithmetic, gap by gap), what the garbage
 collector did (``process_gc_*``, the ``gc_pause`` spans), which form brought
-the routed rows back (``moe_rows_combined_total``), then the cell's
+the routed rows back (``moe_rows_combined_total``) and the runs a pass of
+a share's launches (``moe_share_runs_total``), then the cell's
 per-layer metrics as ``benchmark/run.py`` would print them. A trace names
 an operation by its HLO instruction, and only the process that compiled
 the programs can say what scope an instruction was traced under
@@ -209,6 +210,22 @@ def rows_forms_summary():
         for (program, form), c in sorted(combined.series()))
 
 
+def share_runs_summary():
+    """The runs of tokens a share's launches went through the experts
+    in, by program, over the expert-layer passes
+    (``moe_share_runs_total`` over ``moe_launches_total``: the runs a
+    pass; 0 where a pass is one dispatch)."""
+    from deepspeed_tpu.telemetry import get_registry
+    runs = get_registry().get("moe_share_runs_total")
+    passes = get_registry().get("moe_launches_total")
+    if runs is None or passes is None or not passes.series():
+        return "runs a pass: no expert layer ran, or a tree before the count"
+    return "runs a pass: " + ", ".join(
+        f"{program} {int(runs.labels(program=program).value)} / "
+        f"{int(c.value)} = {runs.labels(program=program).value / c.value:g}"
+        for (program,), c in sorted(passes.series()) if c.value)
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
@@ -263,6 +280,7 @@ def main(argv=None):
     print(render_gaps(idle_gaps(ev)), flush=True)
     print(collector_summary(), flush=True)
     print(rows_forms_summary(), flush=True)
+    print(share_runs_summary(), flush=True)
     manifest = json.loads((REPO / "BENCHMARK.json").read_text())
     metrics = bench.layer_metrics(
         bench.reported_by(manifest, args.workload, "per_layer"), ev,

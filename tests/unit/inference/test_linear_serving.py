@@ -508,6 +508,8 @@ def test_a_share_takes_a_launch_of_any_length_in_runs(monkeypatch):
         (21, TOY["hidden_size"])), jnp.float32)
     at_once, picks = paged_model._moe_routed(cfg, lp, x)
     monkeypatch.setattr(paged_model, "_SHARE_TOKENS", 8)
+    monkeypatch.setattr(paged_model, "_SHARE_ROWS", 1)   # the floor decides
+    assert paged_model.moe_share_runs(cfg, 21, jnp.float32) == (3, 8)
     in_runs, picks_runs = paged_model._moe_routed(cfg, lp, x)
     np.testing.assert_array_equal(picks, picks_runs)
     np.testing.assert_allclose(in_runs, at_once, atol=1e-6)

@@ -136,7 +136,8 @@ def test_which_launches_take_the_kernels():
 
 def test_the_serving_path_hands_the_kernels_by_shape(monkeypatch):
     """``paged_model.moe_rows_form``: the three shares' ragged steps
-    (runs of 2,048 or 4,096 tokens) take the kernels on a TPU; their
+    (a run of 8,192 tokens, or the step's 16,384 whole) take the kernels
+    on a TPU; their
     decode steps, the cells that hold every expert, a float32 engine
     and every launch elsewhere the gather."""
     import json

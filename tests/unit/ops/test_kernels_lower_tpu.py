@@ -1302,6 +1302,48 @@ def test_the_rows_come_back_through_the_kernels_at_the_cells_runs(
     assert compiled.memory_analysis().temp_size_in_bytes < pairs + 2 ** 20
 
 
+def test_granites_run_lowers_with_its_five_kernels(tpu_sharding):
+    """One run-dispatch of granite's share at the run PR 64 gives it
+    (``paged_model.moe_share_runs``: 8,192 tokens x 10 picks, 1,137 rows
+    an expert, where bytes alone gave 2,048): ``dropless_topk_dispatch``
+    over the stack's 360 groups compiles for the chip as three ``gmm``
+    custom calls (an expert in two column tiles, as it was) and the two
+    kernels that bring the rows back, and what it makes besides its
+    result stays under the sorted rows, their products and the relaid
+    pairs (0.67 GB each at most)."""
+    from deepspeed_tpu.inference.v2 import paged_model
+    from deepspeed_tpu.inference.v2.kernels import expert_combine as ec
+    from deepspeed_tpu.models.transformer import TransformerConfig
+    from deepspeed_tpu.moe import sharded_moe as moe
+
+    cfg = TransformerConfig(**_granite_fields())
+    runs, T = paged_model.moe_share_runs(cfg, 16384, jnp.bfloat16)
+    assert (runs, T) == (2, 8192)
+    assert paged_model.moe_rows_form(cfg, 16384, jnp.bfloat16) == "kernel"
+    k, H, F, E, L = cfg.moe_top_k, cfg.hidden_size, \
+        cfg.moe_intermediate_size, cfg.experts_held, 10
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=tpu_sharding)
+
+    experts = (sds((L, E, H, F)), sds((L, E, H, F)), sds((L, E, F, H)))
+    assert moe.gmm_serves(experts)
+    compiled = jax.jit(lambda xt, topi, topv, experts, layer:
+                       moe.dropless_topk_dispatch(
+                           xt, topi, topv, experts, E,
+                           moe.gmm_swiglu_experts, stack_layer=layer,
+                           held_from=0, rows_combine=ec.rows_combine)).lower(
+        sds((T, H)), sds((T, k), jnp.int32), sds((T, k), jnp.float32),
+        experts, sds((), jnp.int32)).compile()
+    kernels = _custom_calls(compiled)
+    assert sum(bool(GMM_PATTERN.search(n)) for n in kernels) == 3, kernels
+    assert sorted(n.rstrip(".0123456789") for n in kernels
+                  if not GMM_PATTERN.search(n)) == [
+        "moe_rows_combine", "moe_rows_whole"], kernels
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < 4 * paged_model._SHARE_RUN_BYTES
+
+
 def _state_space_cut(tpu_sharding):
     """The pattern at published widths, cut to a mamba layer, the
     attention layer and a mamba layer (8 experts held, 4,096 rows of the
