@@ -31,7 +31,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ...models.transformer import TransformerConfig
+from ...models.transformer import PAGED_KINDS, TransformerConfig
 from ...telemetry import memory as ds_memory
 from ...telemetry import recorder as flight
 from ...telemetry import trace, watchdog
@@ -190,8 +190,8 @@ class InferenceEngineV2:
         if not cfg.has_state and config.state_dtype != "float32":
             raise ValueError(
                 "state_dtype is for a model that keeps recurrent state "
-                "(linear-attention, state-space or power-retention "
-                "layers); this one keeps none")
+                "(linear-attention, state-space, power-retention or "
+                "short-convolution layers); this one keeps none")
         sm = config.state_manager
         if sm.max_seq_len > cfg.max_seq_len:
             sm.max_seq_len = cfg.max_seq_len
@@ -258,6 +258,7 @@ class InferenceEngineV2:
         # a model with linear-attention layers keeps recurrent state a
         # sequence: a slot a tracked sequence, beside its blocks
         self._has_state = cfg.has_state
+        self._conv_layers = cfg.leaf_places("conv")
         # a model with window-attention layers keeps their keys and
         # values in a second pool, a RING a sequence: the window, the
         # most tokens a sequence feeds in one step (its share of a full
@@ -581,6 +582,9 @@ class InferenceEngineV2:
             "head and sequence" + ("" if cfg.caches_positions else
                                    ", no position cached at all")
             if "retention" in cfg.layer_kinds else
+            "short-convolution layers with a row's last inputs a "
+            "sequence beside per-head attention"
+            if "conv" in cfg.layer_kinds else
             "state-space layers with a recurrent state a sequence beside "
             "per-head attention" if state else
             "window and full per-head layers, a cache of two geometries")
@@ -731,9 +735,10 @@ class InferenceEngineV2:
             "inference_tracked_sequences", "sequences with live KV state")
         self._m_state_bytes = reg.gauge(
             "inference_state_bytes",
-            "bytes of the recurrent-state leaves (linear-attention or "
-            "state-space layers: every slot of every such layer, the null "
-            "slot included); 0 for a model that keeps none", unit="bytes")
+            "bytes of the recurrent-state leaves (linear-attention, "
+            "state-space, power-retention or short-convolution layers: "
+            "every slot of every such layer, the null slot included); 0 "
+            "for a model that keeps none", unit="bytes")
         self._m_state_slots = reg.gauge(
             "inference_state_slots_in_use",
             "recurrent-state slots owned by tracked sequences")
@@ -742,6 +747,12 @@ class InferenceEngineV2:
             "rows whose recurrent state a launch read and wrote, by "
             "program (a fused window counts a row once a step it may "
             "take)", labelnames=("program",))
+        self._m_conv_tokens = reg.counter(
+            "inference_conv_state_tokens_total",
+            "tokens that passed through a short-convolution layer's "
+            "state, by program: launches x conv layers x tokens, from "
+            "the host's own shapes (0 for a model without such layers)",
+            labelnames=("program",))
         self._m_chunk_kernel_steps = reg.counter(
             "inference_linear_chunk_kernel_steps_total",
             "ragged steps launched whose linear-attention layers ran "
@@ -1675,9 +1686,7 @@ class InferenceEngineV2:
             dt = step["duration_s"]
             self._m_host_syncs.inc()
             self._note_moe("decode_step", active.shape[0], *moe)
-            if self._has_state:
-                self._m_state_rows.labels(program="decode_step").inc(
-                    len(uids))
+            self._note_state_rows("decode_step", len(uids), len(uids))
             self._note_kernel_steps(1, uids, [1] * len(uids),
                                     tables.shape[1])
             self._m_decode_steps.inc()
@@ -1734,6 +1743,18 @@ class InferenceEngineV2:
                 p, t, pos, bt, c, a, rng, seeds, g0, temp, topp, topk,
                 lb, aid, ss, *wt),
             lambda v, i: int(v[i]))
+
+    def _note_state_rows(self, program: str, rows: int, tokens: int):
+        """A launch of ``program`` read and wrote the recurrent state of
+        ``rows`` rows (a window: a row once a step it may take) and fed
+        ``tokens`` tokens, each of which passed through every
+        short-convolution layer's state."""
+        if not self._has_state:
+            return
+        self._m_state_rows.labels(program=program).inc(rows)
+        if self._conv_layers:
+            self._m_conv_tokens.labels(program=program).inc(
+                self._conv_layers * tokens)
 
     def _note_kernel_steps(self, steps: int, uids: List[int],
                            steps_left: List[int], table_pages: int,
@@ -1792,8 +1813,7 @@ class InferenceEngineV2:
         rings = kinds.count("window")
         a, b = np.asarray(count(
             *rows, sm.block_size, table_pages, sm.config.num_blocks, **kw)) \
-            * sum(k not in ("window", "kda", "ssm", "retention", "moe")
-                  for k in kinds)
+            * sum(k in PAGED_KINDS and k != "window" for k in kinds)
         if rings:
             ring = count(
                 *rows, sm.block_size, sm.ring_blocks,
@@ -1918,9 +1938,8 @@ class InferenceEngineV2:
             win.out = win.moe = win.state = None
             self._m_host_syncs.inc()
             self._note_moe("decode_window", out.shape[0], *moe)
-            if self._has_state:
-                self._m_state_rows.labels(program="decode_window").inc(
-                    sum(win.steps_left))
+            self._note_state_rows("decode_window", sum(win.steps_left),
+                                  sum(win.steps_left))
             log_tokens = sm.config.enable_prefix_caching
             emitted: Dict[int, List[int]] = {}
             win.last = {}
@@ -2139,9 +2158,9 @@ class InferenceEngineV2:
             if behind is not None:
                 self._ragged_ended(behind)
             self._note_prompt_chunks(entries, rb)
+            self._note_state_rows("ragged_step", len(entries),
+                                  rb.total_tokens)
             if self._has_state:
-                self._m_state_rows.labels(program="ragged_step").inc(
-                    len(entries))
                 cache = self.kv_cache
                 if self._use_kernel and "kda_state" in cache \
                         and chunk_kernel_serves(cache["kda_state"]):
@@ -2379,13 +2398,16 @@ class InferenceEngineV2:
         ``retention_norm`` ``[retention layers, kv_heads, head_dim
         (head_dim + 1) / 2]``: a key/value head's state and normaliser
         against phi in the order a <= b (the mechanism's 8,256 rows at
-        head_dim 128, whatever the leaf keeps twice).
+        head_dim 128, whatever the leaf keeps twice); of
+        short-convolution layers ``conv_state`` ``[conv layers, taps -
+        1, hidden]``: the row's last gated inputs, oldest first.
         The read half of a snapshot (what preemption and handoff of such
         a model would carry: ROADMAP M5)."""
         if not self._has_state:
             raise ValueError("sequence_state: this model keeps no "
                              "recurrent state (no linear-attention, "
-                             "state-space or power-retention layer)")
+                             "state-space, power-retention or "
+                             "short-convolution layer)")
         sm = self.state_manager
         if not sm.known_seq(uid):
             raise KeyError(f"sequence_state: uid {uid} is not tracked")
@@ -2393,7 +2415,7 @@ class InferenceEngineV2:
         state = {name: np.asarray(leaf[:, slot])
                  for name, leaf in self.kv_cache.items()
                  if name in STATE_LEAVES}
-        for name in ("kda_conv", "ssm_conv"):
+        for name in ("kda_conv", "ssm_conv", "conv_state"):
             if name in state:
                 conv = state[name]
                 state[name] = conv.reshape(*conv.shape[:2], -1)

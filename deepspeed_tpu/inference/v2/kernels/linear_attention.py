@@ -658,22 +658,22 @@ def conv_kernel_serves(leaf, parts=3) -> bool:
             and (parts == 1 or rows % (parts * 16) == 0))
 
 
-def _conv_token(held, x, w_ref, b_ref):
+def _conv_token(held, x, w_ref, b_ref, act="silu"):
     """One row's token: ``held`` the slot's last inputs [rows, 128]
     float32, oldest first, ``x`` the token's own; the taps ``w_ref``
     [K, rows, 128], the bias ``b_ref`` [rows, 128] or None. The sum runs
-    oldest tap first, then the bias, then SiLU."""
+    oldest tap first, then the bias, then ``act`` ("silu" or "none")."""
     seq = held + [x]
     y = seq[0] * w_ref[0]
     for t in range(1, len(seq)):
         y = y + seq[t] * w_ref[t]
     if b_ref is not None:
         y = y + b_ref[...]
-    return jax.nn.silu(y)
+    return jax.nn.silu(y) if act == "silu" else y
 
 
 def _conv_kernel(layer_ref, slots_ref, fresh_ref, leaf_in, w_ref, *refs,
-                 parts, bias, steps):
+                 parts, bias, steps, act="silu"):
     """``R`` rows' tokens through the convolution a grid step. A row's
     slot [K - 1, rows, 128] (its last inputs, oldest first, the parts
     one after another along the rows) is copied out of ``leaf_in`` at
@@ -735,7 +735,7 @@ def _conv_kernel(layer_ref, slots_ref, fresh_ref, leaf_in, w_ref, *refs,
         x = jnp.concatenate([r[i].astype(jnp.float32) for r in ins], axis=0)
         held = [jnp.where(keep, held_in[buf, i, t].astype(jnp.float32), 0.0)
                 for t in range(K1)]
-        y = _conv_token(held, x, w_ref, b_ref)
+        y = _conv_token(held, x, w_ref, b_ref, act)
         for t, kept in enumerate(held[1:] + [x]):
             held_out[buf, i, t] = kept.astype(held_out.dtype)
         store(g, buf, i).start()
@@ -752,8 +752,10 @@ def _conv_kernel(layer_ref, slots_ref, fresh_ref, leaf_in, w_ref, *refs,
 
 
 def conv_update(leaf, layer, slots, fresh, parts, taps, bias=None,
-                name="kda_conv_update", interpret=False):
-    """:func:`causal_conv_step` with SiLU on the rows' slots of the
+                name="kda_conv_update", interpret=False, act="silu"):
+    """:func:`causal_conv_step` with ``act`` ("silu", or "none": a
+    convolution that is a mixer's whole core and carries no activation;
+    static) on the rows' slots of the
     convolution leaf where it lies: ``leaf`` ``[layers, slots, K - 1,
     rows, 128]`` (:func:`conv_leaf_shape`) stays whole in HBM (COLOURED
     so, operand and aliased output, where the kernel is compiled for a
@@ -770,7 +772,7 @@ def conv_update(leaf, layer, slots, fresh, parts, taps, bias=None,
     (arrays [N, D] side by side along the channels, in their own type),
     and writes each slot's inputs shifted by one with the token's own
     appended back to where they came from (aliased) and the convolved,
-    SiLU'd parts in the projections' type: a slot is read once and
+    activated parts in the projections' type: a slot is read once and
     written once. ``fresh[n]``: the row's first token, its inputs start
     from zeros. ``taps`` [K, channels] (tap K - 1 meets the token
     itself) and ``bias`` [channels] (None: none), fetched once. The sum
@@ -788,7 +790,7 @@ def conv_update(leaf, layer, slots, fresh, parts, taps, bias=None,
     leaf = in_hbm(leaf, interpret)
     leaf, *mixed = pl.pallas_call(
         functools.partial(_conv_kernel, parts=n, bias=len(offset),
-                          steps=N // R),
+                          steps=N // R, act=act),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3, grid=(N // R,),
             in_specs=[hbm, weights] + [part] * n + [

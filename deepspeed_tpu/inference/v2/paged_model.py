@@ -119,13 +119,29 @@ def init_paged_kv_cache(cfg: TransformerConfig, num_blocks: int,
                 **(leaves(kinds.count("window"), window_blocks, "_window")
                    if "window" in kinds else {}),
                 **_init_ssm_state(cfg, state_slots, state_dtype),
-                **_init_retention_state(cfg, state_slots, state_dtype)}
+                **_init_retention_state(cfg, state_slots, state_dtype),
+                **_init_conv_state(cfg, state_slots, state_dtype)}
     return leaves(cfg.num_layers, num_blocks)
 
 
 # the leaves that hold recurrent state, indexed by a sequence's slot
 STATE_LEAVES = ("kda_state", "kda_conv", "ssm_state", "ssm_conv",
-                "retention_state", "retention_norm")
+                "retention_state", "retention_norm", "conv_state")
+
+
+def _init_conv_state(cfg, state_slots, state_dtype):
+    """The short-convolution layers' whole state: ``conv_state``
+    ``[L_conv, slots + 1, taps - 1, hidden / 128, 128]`` (a row's last
+    gated inputs ``B * u``, oldest first:
+    ``linear_attention.conv_leaf_shape``) in ``state_dtype``, slot 0 the
+    null slot. The kind has no other leaf: no matrix state and no
+    position."""
+    n = cfg.leaf_places("conv")
+    if not n:
+        return {}
+    from .kernels.linear_attention import conv_leaf_shape
+    return {"conv_state": jnp.zeros(conv_leaf_shape(
+        n, state_slots + 1, cfg.conv_taps, cfg.hidden_size), state_dtype)}
 
 
 def _init_retention_state(cfg, state_slots, state_dtype):
@@ -534,7 +550,8 @@ def _moe_route(cfg, lp, xt, router_precision=None):
         return topk_routing(
             logits, cfg.moe_top_k, cfg.moe_scoring,
             lp["moe_gate_bias"] if cfg.moe_selection_bias else None,
-            cfg.moe_norm_topk, cfg.moe_routed_scale, **limit)
+            cfg.moe_norm_topk, cfg.moe_routed_scale,
+            norm_eps=cfg.moe_norm_topk_eps, **limit)
 
 
 def _moe_experts(cfg, lp, xt, topi, topv, experts=None, stack_layer=None):
@@ -1304,6 +1321,59 @@ def _power_retention_sublayer(cfg, lp, x, l, cache, cos, sin,
         return o.astype(dt).reshape(T, nh * hd) @ lp["wo"], cache
 
 
+def _short_conv_sublayer(cfg, lp, x, l, cache, rows: _StateRows,
+                         use_kernel=True):
+    """A doubly gated short convolution, a layer's WHOLE mixer, on flat
+    tokens x [T, H]; ``l`` is the layer's index among the conv layers
+    (its leaf's leading axis). One projection of the normed input gives
+    [B | C | u] (scope ``conv_proj``); ``g = B * u``, the input gate; a
+    causal depthwise convolution of ``cfg.conv_taps`` taps over the
+    row's own ``g``, zeros before its start, and no activation on it;
+    ``C *`` the result, the output gate (scope ``conv_gate`` holds both
+    gates, the taps and the state's way out of its slot and back); one
+    projection back (scope ``conv_out``). The row's state is its last ``taps - 1`` values of
+    ``g``, oldest first, in its slot of ``conv_state``: a decode batch
+    through the convolution's kernel on the slot where it lies
+    (``short_conv_update`` in a trace: ``linear_attention.conv_update``
+    without its activation, the gates in the fusions round it)
+    where ``use_kernel`` and the width allow, else gather,
+    ``causal_conv_step`` and scatter; every other launch through
+    ``causal_conv_rows``, rows of any lengths, a row of fewer tokens
+    than taps included. A decode row's projection stays float32 from
+    the matmul's sum to the output gate, as every state-keeping
+    layer's. Returns (what the mixer adds to x, cache)."""
+    from .kernels import linear_attention as la
+    H, f32 = cfg.hidden_size, jnp.float32
+    dt = lp["w_in"].dtype
+    hn = _norm(cfg, x, lp["attn_norm"]).astype(dt)
+    with jax.named_scope("conv_proj"):
+        bcu = jnp.dot(hn, lp["w_in"], preferred_element_type=f32
+                      if rows.one_token else None)
+    slots = rows.slots
+    with jax.named_scope("conv_gate"):
+        b, c, u = bcu[:, :H], bcu[:, H:2 * H], bcu[:, 2 * H:]
+        g = b * u
+        leaf = cache["conv_state"]          # [L, slots, K - 1, H / w, w]
+        if rows.one_token and use_kernel \
+                and la.conv_kernel_serves(leaf, parts=1):
+            (y,), leaf = la.conv_update(
+                leaf, l, slots, rows.fresh, (g,), lp["conv"],
+                act="none", name="short_conv_update")
+        else:
+            held = leaf[l, slots].reshape(-1, leaf.shape[2], H)
+            held = jnp.where(rows.fresh[:, None, None], 0, held)
+            y, held = la.causal_conv_step(g, lp["conv"], held) \
+                if rows.one_token else la.causal_conv_rows(
+                    g, lp["conv"], held, rows.row_ids, rows.starts,
+                    rows.counts)
+            leaf = leaf.at[l, slots].set(
+                held.reshape(-1, *leaf.shape[2:]))
+        cache = {**cache, "conv_state": leaf}
+        y = (c * y).astype(dt)
+    with jax.named_scope("conv_out"):
+        return y @ lp["w_out"], cache
+
+
 def _layer_runs(cfg):
     """The layers as maximal runs of one (mixer kind, MLP kind):
     [(kind, routed, first layer, layers)], ``kind`` one of
@@ -1494,6 +1564,11 @@ def _pattern_step(cfg: TransformerConfig, params, ids, row_ids, pos, lengths,
                         with jax.named_scope("hybrid_join"):
                             x = joined(joined(x, m, cfg.ssm_out_scale), a,
                                        cfg.attn_out_scale)
+            elif kind == "conv":
+                with jax.named_scope("short_conv"):
+                    a, pool = _short_conv_sublayer(
+                        cfg, lp, x, m0 + i, pool, rows, use_kernel)
+                    x = joined(x, a)
             elif kind == "retention":
                 with jax.named_scope("attention"):
                     a, pool = _power_retention_sublayer(

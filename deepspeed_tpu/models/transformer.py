@@ -44,17 +44,23 @@ from ..telemetry import registry as _registry
 # names "moe" is one whose every layer is ONE sub-layer behind ONE norm
 # (``TransformerConfig.one_sublayer``). "mamba_attention": a layer whose
 # mixer is TWO mixers on ONE norm, a Mamba-2 mixer beside full per-head
-# attention, their outputs summed (kind "hybrid")
+# attention, their outputs summed (kind "hybrid"). "conv": a layer whose
+# WHOLE mixer is a doubly gated short convolution (kind "conv"): no
+# matrix state and no positions, a row's memory is its last taps - 1
+# gated inputs
 LAYER_TYPE_KINDS = {"sliding_attention": "window", "full_attention": "full",
                     "attention": "full", "mamba": "ssm", "moe": "moe",
                     "power_retention": "retention",
-                    "mamba_attention": "hybrid"}
+                    "mamba_attention": "hybrid", "conv": "conv"}
 # the kinds that cache positions in blocks; the others keep a state a
 # sequence, or nothing ("hybrid" does both)
 PAGED_KINDS = ("mha", "mla", "window", "full", "hybrid")
 # the kinds that own a place in a family of cache leaves: a "hybrid"
 # layer has one in the full pool AND one in the state-space leaves
-LEAF_OWNERS = {"full": ("full", "hybrid"), "ssm": ("ssm", "hybrid")}
+LEAF_OWNERS = {"full": ("full", "hybrid"), "ssm": ("ssm", "hybrid"),
+               "conv": ("conv",)}
+# the kinds whose layers keep a state a sequence, in a slot
+STATE_KINDS = ("kda", "ssm", "retention", "hybrid", "conv")
 
 
 @dataclass(frozen=True)
@@ -153,6 +159,10 @@ class TransformerConfig:
     moe_selection_bias: bool = False
     moe_norm_topk: bool = True
     moe_routed_scale: float = 1.0
+    # what guards the sum of the chosen SIGMOID scores where they are
+    # normalised (a softmax's chosen never sum to 0): DeepSeek-V3's
+    # 1e-20, the lfm2_moe block's 1e-6
+    moe_norm_topk_eps: float = 1e-20
     # attention kind: "mha" (per-head keys and values; GQA/MQA by
     # num_kv_heads) or "mla" (multi-head latent attention, served only:
     # queries through a q_lora_rank bottleneck, keys and values expanded
@@ -275,6 +285,17 @@ class TransformerConfig:
     # (kernels/power_retention.py); a model of such layers alone caches
     # no position at all
     retention_eps: float = 1e-6
+    # a layer whose WHOLE mixer is a doubly gated SHORT CONVOLUTION
+    # (served only; ``layer_types`` "conv", the lfm2 block): one
+    # projection of the normed input to [B | C | u], each ``hidden_size``
+    # wide; ``g = B * u``; a causal depthwise convolution of
+    # ``conv_taps`` taps a channel over a row's own ``g`` (zeros before
+    # its start), NO activation; ``C *`` the result; one projection
+    # back. A row's whole state is its last ``conv_taps - 1`` gated
+    # inputs ``g``. ``conv_bias``: the source's switch for a bias on the
+    # two projections and the taps; read, and refused where true
+    conv_taps: int = 0
+    conv_bias: bool = False
     # the group limit of the deployed router (DeepSeek-V3 noaux_tc): the
     # experts form ``moe_n_group`` groups, a group scores the sum of its
     # best two, and the top k are chosen inside the best
@@ -434,6 +455,15 @@ class TransformerConfig:
                     f"{(self.mamba_n_heads, self.mamba_d_head)}, state "
                     f"{self.mamba_d_state}, taps {self.mamba_d_conv}, "
                     f"groups {self.mamba_n_groups}")
+            if "conv" in self.layer_types and (
+                    self.conv_taps < 2 or self.conv_bias
+                    or self.one_sublayer):
+                raise ValueError(
+                    f"a conv layer (a doubly gated short convolution as "
+                    f"the whole mixer) needs conv_taps >= 2, no bias "
+                    f"(conv_bias=False) and an MLP behind it (no 'moe' "
+                    f"layers); got taps {self.conv_taps}, conv_bias "
+                    f"{self.conv_bias}")
             if "mamba_attention" in self.layer_types and (
                     min(self.num_heads, self.kv_heads, self.head_dim) < 1
                     or self.norm_scheme != "pre" or self.one_sublayer):
@@ -464,6 +494,13 @@ class TransformerConfig:
                 "attn_window, qk_norm, rope_sliding_only, norm_scheme="
                 "'sandwich' and attn_gate='elementwise' describe the "
                 "per-head block of a layer pattern: give layer_types")
+        if self.conv_taps and "conv" not in (self.layer_types or ()):
+            raise NotImplementedError(
+                "conv_taps describes the conv layers of a layer pattern: "
+                "give layer_types that names one")
+        if self.moe_norm_topk_eps <= 0:
+            raise ValueError(f"moe_norm_topk_eps must be > 0, got "
+                             f"{self.moe_norm_topk_eps}")
         if self.layer_types is None and (
                 self.positional == "none" or self.mamba_n_heads
                 or self.attn_scale or self.residual_scale != 1.0
@@ -576,6 +613,9 @@ class TransformerConfig:
              bool(self.sublayer_scales)),
             ("power_retention layers (a power-kernel state a key/value "
              "head)", "retention" in self.layer_kinds),
+            ("conv layers (a doubly gated short convolution as the whole "
+             "mixer, a row's last inputs its state)",
+             "conv" in self.layer_kinds),
             ("'moe' layers (a layer is one sub-layer behind one norm)",
              self.one_sublayer),
             ("mamba_n_groups (B and C a group of heads)",
@@ -600,7 +640,9 @@ class TransformerConfig:
             ("moe_selection_bias", self.moe_selection_bias),
             ("moe_shared_experts", self.moe_shared_experts > 0),
             ("moe_routed_scale", self.moe_routed_scale != 1.0),
-            ("moe_norm_topk=False", not self.moe_norm_topk)) if on]
+            ("moe_norm_topk=False", not self.moe_norm_topk),
+            ("moe_norm_topk_eps", self.moe_norm_topk_eps != 1e-20))
+            if on]
         return ", ".join(what) or None
 
     def refuse_served_only(self, who: str):
@@ -666,15 +708,15 @@ class TransformerConfig:
     @property
     def has_state(self) -> bool:
         """Whether a sequence owns recurrent state beside its blocks."""
-        return bool({"kda", "ssm", "retention", "hybrid"}
-                    & set(self.layer_kinds))
+        return bool(set(STATE_KINDS) & set(self.layer_kinds))
 
     def leaf_places(self, family: str, upto: Optional[int] = None) -> int:
         """How many of the first ``upto`` layers (None: all) own a place
         in the cache leaves of ``family``: "full" (``k_full`` ...: the
         full per-head layers and the two-mixer layers) or "ssm"
         (``ssm_state`` / ``ssm_conv``: the state-space layers and the
-        two-mixer layers); any other kind's leaves are its own. With
+        two-mixer layers) or "conv" (``conv_state``: the short-convolution
+        layers); any other kind's leaves are its own. With
         ``upto`` a layer's index this is the layer's place along its
         leaves' leading axis."""
         owners = LEAF_OWNERS.get(family, (family,))
@@ -1146,7 +1188,9 @@ class TransformerLM:
         (``per_head``), and under the sandwich scheme a post-norm a
         sub-layer (``attn_post_norm`` with the mixer, ``mlp_post_norm``
         with the MLP). A two-mixer layer's stack (``hybrid_layers``)
-        holds both halves' leaves and one ``attn_norm``."""
+        holds both halves' leaves and one ``attn_norm``; a
+        short-convolution layer's (``conv_layers``) its two projections
+        and taps."""
         cfg, dt = self.cfg, jnp.float32
         h, v, nh = cfg.hidden_size, cfg.vocab_size, cfg.num_heads
         L, std = cfg.num_layers, 0.02
@@ -1268,6 +1312,17 @@ class TransformerLM:
             return {**state_space(jax.random.fold_in(key, 1), n),
                     **per_head(key, n)}
 
+        def short_conv(key, n):
+            """A short-convolution mixer's leaves: ``w_in`` to [B | C |
+            u], the depthwise taps ``conv`` [taps, H] (tap taps - 1
+            meets the token itself), ``w_out``."""
+            ks = jax.random.split(key, 3)
+            return {"attn_norm": jnp.ones((n, h), dt),
+                    "w_in": init(ks[0], (n, h, 3 * h)),
+                    "conv": init(ks[1], (n, cfg.conv_taps, h),
+                                 cfg.conv_taps ** -0.5),
+                    "w_out": init(ks[2], (n, h, h), out_std)}
+
         def mlp_norms(n):
             return {"mlp_norm": jnp.ones((n, h), dt),
                     **({"mlp_post_norm": jnp.ones((n, h), dt)}
@@ -1309,6 +1364,7 @@ class TransformerLM:
             mixers = {"kda": (linear, 7), "window": (per_head, 9),
                       "full": (per_head, 10), "ssm": (state_space, 11),
                       "retention": (retention, 12), "hybrid": (hybrid, 13),
+                      "conv": (short_conv, 14),
                       "mla": (functools.partial(attention, mlp_norm=False),
                               8)}
             for kind in dict.fromkeys(kinds):

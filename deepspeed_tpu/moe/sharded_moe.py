@@ -356,7 +356,8 @@ def gmm_serves(expert_params) -> bool:
 
 def topk_routing(logits, k: int, scoring: str = "softmax", bias=None,
                  normalize: bool = True, scale: float = 1.0,
-                 n_group: int = 1, topk_group: int = 1):
+                 n_group: int = 1, topk_group: int = 1,
+                 norm_eps: float = 1e-20):
     """Router logits [T, E] (float32) -> (chosen experts [T, k], their
     weights [T, k]), as served. ``scoring`` makes a logit a score
     (softmax over the experts, or an independent sigmoid); ``bias`` [E]
@@ -367,8 +368,9 @@ def topk_routing(logits, k: int, scoring: str = "softmax", bias=None,
     included), and the top k are chosen inside the best ``topk_group``
     groups (``n_group`` 1: one group, every expert stands). The k > 1
     chosen weights are normalised over the chosen set where
-    ``normalize`` (top-1 keeps its raw score: top1gating's g1), then
-    scaled."""
+    ``normalize`` (top-1 keeps its raw score: top1gating's g1), a
+    sigmoid's sum guarded by ``norm_eps`` (the published blocks' own:
+    DeepSeek-V3's 1e-20, lfm2_moe's 1e-6), then scaled."""
     scores = (jax.nn.softmax(logits, axis=-1) if scoring == "softmax"
               else jax.nn.sigmoid(logits))
     plain = bias is None and n_group == 1    # the scores choose as they are
@@ -390,7 +392,7 @@ def topk_routing(logits, k: int, scoring: str = "softmax", bias=None,
     if k > 1 and normalize:
         total = jnp.sum(topv, axis=-1, keepdims=True)
         # the published code's guard; a softmax's chosen never sum to 0
-        topv = topv / (total + 1e-20 if scoring == "sigmoid" else total)
+        topv = topv / (total + norm_eps if scoring == "sigmoid" else total)
     if scale != 1.0:
         topv = topv * scale
     return topi, topv

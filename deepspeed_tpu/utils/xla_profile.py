@@ -161,14 +161,20 @@ _SERVE_PHASE_OF_SCOPE = {
     # the state's way out of its slot, the update and its way back, is a
     # phase a form
     "retention_chunk": "retention_chunk",
-    "retention_state": "retention_state"}
+    "retention_state": "retention_state",
+    # a short-convolution layer: its norm and residual add
+    # (``short_conv``), its two projections and, between them, both
+    # gates, the taps and the state's way out of its slot and back
+    "short_conv": "short_conv", "conv_proj": "short_conv",
+    "conv_gate": "short_conv", "conv_out": "short_conv"}
 _SERVE_SCOPE_WORD = re.compile(
     r"\b(" + "|".join(sorted(_SERVE_PHASE_OF_SCOPE, key=len, reverse=True))
     + r")\b")
 SERVE_PHASES = ("embed", "attn_proj", "kv_write", "attn_kernel", "mlp",
                 "router", "experts", "head", "pick", "linear",
                 "linear_chunk", "linear_state", "ssm", "ssm_scan",
-                "ssm_state", "retention_chunk", "retention_state", "other")
+                "ssm_state", "retention_chunk", "retention_state",
+                "short_conv", "other")
 
 
 def serve_scope(op_name: str) -> str:

@@ -397,7 +397,7 @@ def _bias_in_weights(monkeypatch):
     """The selection bias leaks into the chosen weights."""
     from deepspeed_tpu.moe import sharded_moe
 
-    def routing(logits, k, scoring, bias, normalize, scale):
+    def routing(logits, k, scoring, bias, normalize, scale, **_):
         topv, topi = jax.lax.top_k(jax.nn.sigmoid(logits) + bias, k)
         return topi, topv / jnp.sum(topv, -1, keepdims=True) * scale
     monkeypatch.setattr(sharded_moe, "topk_routing", routing)
@@ -484,6 +484,66 @@ def _swap_groups(eng):
     stack["conv"] = swapped(stack["conv"], di)
     stack["conv_b"] = swapped(stack["conv_b"], di)
     eng.params = {**eng.params, "hybrid_layers": stack}
+
+
+def _short_conv_but_for(monkeypatch, *swaps):
+    """``_short_conv_sublayer`` as it stands but for ``swaps``, (text,
+    what stands there instead), each text once in its source."""
+    import inspect
+    from deepspeed_tpu.inference.v2 import paged_model
+    source = inspect.getsource(paged_model._short_conv_sublayer)
+    for text, instead in swaps:
+        assert source.count(text) == 1, text
+        source = source.replace(text, instead)
+    scope = dict(vars(paged_model))
+    exec(source, scope)
+    monkeypatch.setattr(paged_model, "_short_conv_sublayer",
+                        scope["_short_conv_sublayer"])
+
+
+def _no_output_gate(monkeypatch):
+    """A short-convolution mixer's output gate ``C *`` dropped."""
+    _short_conv_but_for(monkeypatch, ("y = (c * y).astype(dt)",
+                                      "y = y.astype(dt)"))
+
+
+def _silu_on_the_taps(monkeypatch):
+    """SiLU left on the taps, as a short convolution ahead of a bigger
+    mixer carries it: in the kernel and in both plain forms."""
+    _short_conv_but_for(
+        monkeypatch, ('act="none"', 'act="silu"'),
+        ('lp["conv"], held)', 'lp["conv"], held, jax.nn.silu)'),
+        ("rows.counts)", "rows.counts, jax.nn.silu)"))
+
+
+def _x_first(eng):
+    """A short-convolution mixer's projection read as [x | B | C], the
+    state-space mixers' order: the three column blocks of ``w_in``
+    rolled by one, in a tree of the engine's own."""
+    stack = dict(eng.params["conv_layers"])
+    h = eng.model.cfg.hidden_size
+    stack["w_in"] = jnp.roll(stack["w_in"], h, axis=-1)
+    eng.params = {**eng.params, "conv_layers": stack}
+
+
+def _head_norms_behind_the_rotation(monkeypatch):
+    """q and k are rotated first and RMS-normed a head after: the head
+    norms hand their input on as it is and remember their weight, and
+    the rotation norms what it has turned."""
+    from deepspeed_tpu.inference.v2 import paged_model
+    from deepspeed_tpu.ops import norms
+    norm, rotate = norms.rms_norm, paged_model._rotate
+    held = []
+
+    def head_norm(x, w, eps):
+        if x.ndim != 3:
+            return norm(x, w, eps)
+        held.append((w, eps))
+        return x
+    monkeypatch.setattr(norms, "rms_norm", head_norm)
+    monkeypatch.setattr(
+        paged_model, "_rotate",
+        lambda x, cos, sin: norm(rotate(x, cos, sin), *held.pop(0)))
 
 
 def _handed_on(*forms):
@@ -768,6 +828,43 @@ _ROWS = (
                           _STATE_REFUSALS),
         refuses=Refuses(handoff="no state slot", forwards=("apply",),
                         words=("power_retention layers",))),
+    # lfm2_moe: a layer whose WHOLE mixer is a doubly gated 3-tap
+    # convolution (a row's state: its last two gated inputs) beside one
+    # rotated GQA layer with head norms, two leading dense layers whose
+    # mixers are conv, then experts under sigmoid scores and a
+    # selection-only bias. float32 reads 3e-7 to 7e-7 (logits, state,
+    # keys). The toy's hard top-2 of 8 under bf16 swaps an expert
+    # (granite's router), so the bf16 engine is held on a router that
+    # cannot flip (top-8 of 8). SiLU left on the taps, the output gate
+    # dropped, the projection read x first, the bias weighing and the
+    # head norms behind the rotation each read over five times the
+    # limit. A state kept in bfloat16 is NOT a control here: the state is
+    # a copy of two gated inputs, not a sum over tokens, and reads 2e-3
+    # of itself whatever the length.
+    Block(
+        "lfm2-8b-a1b", seed=5, manager=_POOL, impl="pallas:pipelined",
+        leaves=frozenset({"k_full", "v_full", "conv_state"}),
+        put=(Case("one-step", chunks=0),
+             Case("two-steps", {"budget": 32}, lengths=(24, 24), chunks=2),
+             Case("five-steps", {"budget": 32}, lengths=(100, 24), chunks=5),
+             Case("bfloat16", {"dtype": "bfloat16",
+                               "fields": {"moe_top_k": 8}}, BF16_LOGITS)),
+        decode=(Case("float32"),),
+        alone=Alone(),
+        chunked=Chunked((100, 24), {}, {"budget": 32}, 5),
+        handed_on=_handed_on(("one-step", {}, None, 0)),
+        kept=Kept({"conv_state": (5, 2, 128)},
+                  lambda state: state["conv_state"][:2], _leading(layers=2)),
+        controls=(
+            Control("silu-on-the-taps", patch=_silu_on_the_taps),
+            Control("the-output-gate-dropped", patch=_no_output_gate),
+            Control("x-first", mutate=_x_first),
+            Control("the-bias-weighs", patch=_bias_in_weights),
+            Control("the-head-norms-behind-the-rotation",
+                    patch=_head_norms_behind_the_rotation)),
+        refusals=Refusals("short-convolution layers", _STATE_REFUSALS),
+        refuses=Refuses(handoff="no state slot", forwards=("apply",),
+                        words=("conv layers",))),
 )
 
 

@@ -718,6 +718,97 @@ def test_the_conv_kernel_at_published_widths(tpu_sharding, proj):
     assert compiled.memory_analysis().temp_size_in_bytes < 0.02e9
 
 
+@pytest.mark.parametrize("act,proj", [
+    ("none", jnp.float32), ("none", jnp.bfloat16), ("silu", jnp.float32)])
+def test_the_short_conv_kernel_at_published_widths(tpu_sharding, act, proj):
+    """``conv_update`` as a short-convolution MIXER's core at
+    lfm2-8b-a1b.rollout-256x512-512's decode shape: ONE part of 2,048
+    channels (no q | k | v to keep apart), 3 taps, 256 rows, no
+    activation (and with SiLU, the default the other blocks trace), a
+    float32 leaf of 11 layers and 257 slots: it compiles for the chip,
+    runs as ONE custom call under the name a trace finds, and the leaf
+    is aliased (no copy of its 46 MB)."""
+    from deepspeed_tpu.inference.v2.kernels import linear_attention as la
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=tpu_sharding)
+
+    N, D, K = 256, 2048, 3
+    leaf = sds(la.conv_leaf_shape(11, 257, K, D))
+    assert leaf.shape == (11, 257, 2, 16, 128)
+    assert la.conv_kernel_serves(leaf, parts=1)
+    compiled = jax.jit(
+        lambda leaf, l, slots, fresh, g, taps: la.conv_update(
+            leaf, l, slots, fresh, (g,), taps, act=act,
+            name="short_conv_update"), donate_argnums=(0,)).lower(
+        leaf, sds((), jnp.int32), sds((N,), jnp.int32),
+        sds((N,), jnp.bool_), sds((N, D), proj),
+        sds((K, D), jnp.bfloat16)).compile()
+    kernels = re.findall(r"%([\w.\-]+) = [^\n]*tpu_custom_call",
+                         compiled.as_text())
+    assert len(kernels) == 1 and kernels[0].startswith("short_conv_update")
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.01e9
+
+
+def test_the_short_conv_decode_window_at_256_rows(tpu_sharding):
+    """The decode window of the lfm2_moe block at published widths, cut
+    to its two leading dense layers, its first attention layer and one
+    conv expert layer (4 layers, every expert held), at the cell's 256
+    rows over a table of 64 pages: the convolution's kernel runs in both
+    conv runs with no gather or scatter of the slots in XLA beside it,
+    the grouped matmul in both expert layers, the one-token attention
+    kernel once; the conv leaf stays where it lies."""
+    from deepspeed_tpu.inference.v2.paged_model import (init_paged_kv_cache,
+                                                        paged_decode_window)
+    from deepspeed_tpu.models import TransformerLM
+    from deepspeed_tpu.models.transformer import TransformerConfig
+
+    import json
+    from pathlib import Path
+    fields = json.loads((Path(__file__).resolve().parents[3]
+                         / "benchmark/configs/lfm2-8b-a1b.json"
+                         ).read_text())["fields"]
+    cfg = TransformerConfig(**{**fields, "num_layers": 4, "vocab_size": 4096,
+                               "layer_types": fields["layer_types"][:4]})
+
+    def on_tpu(x, dtype=None):
+        return jax.ShapeDtypeStruct(x.shape, dtype or x.dtype,
+                                    sharding=tpu_sharding)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=tpu_sharding)
+
+    R = 256
+    params = jax.tree.map(
+        lambda x: on_tpu(x, jnp.bfloat16),
+        jax.eval_shape(TransformerLM(cfg).init_params,
+                       jax.random.PRNGKey(0)))
+    cache = jax.tree.map(on_tpu, jax.eval_shape(
+        lambda: init_paged_kv_cache(cfg, 16641, 16, jnp.bfloat16,
+                                    state_slots=R)))
+    assert cache["conv_state"].shape == (3, 257, 2, 16, 128)
+    compiled = jax.jit(
+        lambda p, t, pos, bt, c, sl, eos, alive, ss: paged_decode_window(
+            cfg, p, t, pos, bt, c, sl, eos, 16, 8, use_kernel=True,
+            alive=alive, state_slots=ss), donate_argnums=(4,)).lower(
+        params, i32(R), i32(R), i32(R, 64), cache, i32(R), i32(R),
+        jax.ShapeDtypeStruct((R,), jnp.bool_, sharding=tpu_sharding),
+        i32(R)).compile()
+    text = compiled.as_text()
+    kernels = re.findall(r"%([\w.\-]+) = [^\n]*tpu_custom_call", text)
+    assert sum(k.startswith("short_conv_update") for k in kernels) == 2, \
+        kernels
+    assert sum(bool(GMM_PATTERN.search(k)) for k in kernels) == 6, kernels
+    assert sum(k.startswith("ragged_attention_tiled") for k in kernels) \
+        == 1, kernels
+    under_gate = re.findall(
+        r"= \S+ ([\w\-]+)\([^\n]*op_name=\"[^\"]*/conv_gate/", text)
+    assert under_gate and not {"gather", "scatter", "copy-start",
+                               "copy-done"} & set(under_gate), under_gate
+    _leaves_stay_in_hbm(text, cache, "conv_state")
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.15e9
+
+
 def _hybrid_cut(tpu_sharding):
     """The pattern at published widths, cut to its first linear expert
     layer and the layers before it (3 layers, 8 experts held): the
