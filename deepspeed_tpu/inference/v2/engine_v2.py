@@ -42,8 +42,9 @@ from .kernels import power_retention, state_space
 from .kernels.linear_attention import (chunk_kernel_serves,
                                        conv_kernel_serves)
 from .kernels.ragged_attention import (LATENT, decode_positions,
-                                       kernel_variant,
-                                       one_token_tile_serves, prompt_chunks,
+                                       decode_walks, kernel_variant,
+                                       launch_copies, one_token_tile_serves,
+                                       prompt_chunks, prompt_walks,
                                        token_tile, token_tile_serves)
 from .paged_model import (STATE_LEAVES, init_lora_bank, init_paged_kv_cache,
                           moe_rows_form, moe_share_runs, paged_continue,
@@ -822,6 +823,24 @@ class InferenceEngineV2:
             "goes by, and what a larger tile halves; from the rows' "
             "tokens and contexts at the launch, no device read; 0 off "
             "the TPU and for a latent pool", labelnames=("kind",))
+        self._m_copy_pages = reg.counter(
+            "inference_attention_copy_pages_total",
+            "pages a leaf's copies brought under the tiled attention "
+            "kernel's launches (the one-token form's and the token "
+            "tile's): a page a layer, walk and place, by the host's own "
+            "block tables at the launch; 0 off the TPU and for a latent "
+            "pool")
+        self._m_copy_descriptors = reg.counter(
+            "inference_attention_copy_descriptors_total",
+            "copies a leaf's pages were started as under the same "
+            "launches, by the cut the kernel makes of the table "
+            "(kernels/ragged_attention.copy_counts): a run of pages on "
+            "consecutive blocks a few descriptors, a page that lies alone "
+            "one. pages / descriptors is 1.0 over a pool with no two "
+            "neighbouring places on neighbouring blocks (and wherever "
+            "the launches are handed no runs: a pool whose page is 32 KB "
+            "a leaf and more) and a chunk's 32 where every chunk lies "
+            "together")
         self._m_prefill_chunks = reg.counter(
             "inference_prefill_chunks_total",
             "ragged steps put() ran for a prompt set it fed in chunks (a "
@@ -1657,8 +1676,10 @@ class InferenceEngineV2:
             uids, tokens, [1] * len(uids))
         active = np.zeros(N, bool)
         active[:len(uids)] = True
+        # (the tables twice: the device's, and the host's own for what
+        # is counted behind the launch)
         return (jnp.asarray(toks), jnp.asarray(pos), jnp.asarray(tables),
-                jnp.asarray(active))
+                jnp.asarray(active), tables)
 
     def _decode_common(self, uids: List[int], tokens: List[int], jit_fn,
                        extract) -> Dict[int, object]:
@@ -1667,8 +1688,8 @@ class InferenceEngineV2:
                         uids=[int(u) for u in uids],
                         **self._trace_attrs(uids)) as step:
             with trace.span("step_assemble"):
-                toks, pos, tables, active = self._build_decode_inputs(
-                    uids, tokens)
+                toks, pos, tables, active, host_tables = \
+                    self._build_decode_inputs(uids, tokens)
                 lb = self.lora_bank
                 aid = (self._pad_i32(active.shape[0],
                                      [self._adapter_slot_of(u)
@@ -1688,7 +1709,7 @@ class InferenceEngineV2:
             self._note_moe("decode_step", active.shape[0], *moe)
             self._note_state_rows("decode_step", len(uids), len(uids))
             self._note_kernel_steps(1, uids, [1] * len(uids),
-                                    tables.shape[1])
+                                    host_tables)
             self._m_decode_steps.inc()
             self._m_decode_tokens.inc(len(uids))
             self._m_decode_time.observe(dt)
@@ -1757,7 +1778,7 @@ class InferenceEngineV2:
                 self._conv_layers * tokens)
 
     def _note_kernel_steps(self, steps: int, uids: List[int],
-                           steps_left: List[int], table_pages: int,
+                           steps_left: List[int], tables: np.ndarray,
                            in_flight: Optional[List[int]] = None):
         """``steps`` decode steps went to the device: a model with
         linear layers ran their convolution as the kernel, and the
@@ -1765,8 +1786,9 @@ class InferenceEngineV2:
         programs' own tests say so. Row i of ``uids`` takes
         ``steps_left[i]`` of them from the position the manager holds
         for it plus its ``in_flight`` writes (a window launched and not
-        collected), over a table of ``table_pages`` places: what the
-        positions under the attention launches are counted from."""
+        collected), over ``tables`` (the launch's, row i's at i): what
+        the positions and the copies under the attention launches are
+        counted from."""
         if not self._use_kernel:
             return
         cache = self.kv_cache
@@ -1782,26 +1804,57 @@ class InferenceEngineV2:
         if cfg.caches_positions and one_token_tile_serves(
                 cfg.attention == "mla", cfg.head_dim, cfg.kv_heads):
             self._m_one_token_steps.inc(steps)
-            self._note_decode_positions(uids, steps_left, table_pages,
-                                        in_flight)
+            self._note_decode_positions(uids, steps_left, tables, in_flight)
 
-    def _note_decode_positions(self, uids, steps_left, table_pages,
-                               in_flight):
+    def _note_decode_positions(self, uids, steps_left, tables, in_flight):
         """The positions under the one-token form's launches of these
         rows and steps, layer kind by layer kind
-        (``kernels/ragged_attention.decode_positions``)."""
+        (``kernels/ragged_attention.decode_positions``), and the copies
+        that bring them (:meth:`_note_copies`)."""
         sm = self.state_manager
         start = np.asarray([sm.seqs[u].seen_tokens for u in uids], np.int64)
         if in_flight is not None:
             start = start + np.asarray(in_flight, np.int64)
         step = np.arange(max(steps_left, default=0))[None, :]
         # a row's bound at a step: the token it feeds, itself included
-        contexts = (start[:, None] + 1 + step)[
-            step < np.asarray(steps_left)[:, None]]
+        taken = step < np.asarray(steps_left)[:, None]
+        contexts = (start[:, None] + 1 + step)[taken]
         held, chunked = self._over_attention_layers(
-            decode_positions, table_pages, contexts)
+            decode_positions, tables.shape[1], contexts)
         self._m_decode_positions.labels(kind="held").inc(held)
         self._m_decode_positions.labels(kind="chunked").inc(chunked)
+        if self.model.cfg.attention != "mla":
+            rows = np.nonzero(taken)[0]
+            self._note_copies(
+                uids, tables,
+                lambda bs, window: (rows, *decode_walks(contexts, bs,
+                                                        window)))
+
+    def _note_copies(self, uids, tables, walks):
+        """The pages a leaf's copies brought under the tiled kernel's
+        launches of these walks and the descriptors they were started
+        as, layer kind by layer kind
+        (``kernels/ragged_attention.launch_copies``): ``walks(block
+        size, window) -> (rows, first page, pages)``, a walk of row
+        ``rows[i]`` of ``tables`` (row j is ``uids[j]``'s; a window
+        layer's walks go over the rows' rings). numpy on what the host
+        holds, behind a launch that is already queued."""
+        sm = self.state_manager
+        rings = np.stack([sm.window_table_for(u) for u in uids]) \
+            if self._has_ring else None
+
+        def count(bs, table_pages, pool_blocks, window=0):
+            cache = self.kv_cache
+            leaf = cache["k_window"] if window \
+                else cache.get("k_full", cache.get("k"))
+            return launch_copies(
+                rings if window else tables, *walks(bs, window), bs,
+                pool_blocks, bs * leaf.shape[-1] * leaf.dtype.itemsize,
+                table_pages if window else 0)
+        pages, descriptors = self._over_attention_layers(
+            count, tables.shape[1])
+        self._m_copy_pages.inc(pages)
+        self._m_copy_descriptors.inc(descriptors)
 
     def _over_attention_layers(self, count, table_pages, *rows, **kw):
         """``count(*rows, block size, a table's pages, its pool's
@@ -1825,7 +1878,8 @@ class InferenceEngineV2:
     def _note_prompt_chunks(self, entries, rb):
         """The chunk visits of a ragged step's attention launches, from
         the tokens its rows feed and the contexts they end at
-        (``kernels/ragged_attention.prompt_chunks``), where the token
+        (``kernels/ragged_attention.prompt_chunks``), and the copies
+        that bring their pages (:meth:`_note_copies`), where the token
         tile serves them."""
         cfg = self.model.cfg
         if not (self._use_kernel and cfg.caches_positions
@@ -1834,13 +1888,17 @@ class InferenceEngineV2:
             return
         seqs = self.state_manager.seqs
         new = [len(toks) for _, toks in entries]
+        contexts = [seqs[uid].seen_tokens + n
+                    for (uid, _), n in zip(entries, new)]
+        tq = token_tile(rb.token_bucket, cfg.num_heads, cfg.head_dim,
+                        cfg.kv_heads)
         whole, masked = self._over_attention_layers(
-            prompt_chunks, rb.block_tables.shape[1], new,
-            [seqs[uid].seen_tokens + n for (uid, _), n in zip(entries, new)],
-            tq=token_tile(rb.token_bucket, cfg.num_heads, cfg.head_dim,
-                          cfg.kv_heads))
+            prompt_chunks, rb.block_tables.shape[1], new, contexts, tq=tq)
         self._m_prompt_chunks.labels(kind="whole").inc(whole)
         self._m_prompt_chunks.labels(kind="masked").inc(masked)
+        self._note_copies(
+            [uid for uid, _ in entries], rb.block_tables,
+            lambda bs, window: prompt_walks(new, contexts, bs, window, tq))
 
     # -- fused multi-token decode window --------------------------------
     def _launch_window(self, uids: List[int], tokens: Optional[List[int]],
@@ -1911,7 +1969,7 @@ class InferenceEngineV2:
                 if behind is not None:
                     self._m_windows_ahead.inc()
                 self._note_kernel_steps(
-                    self.decode_window, uids, steps_left, tables.shape[1],
+                    self.decode_window, uids, steps_left, tables,
                     behind.steps_left if behind is not None else None)
                 win = _Window(
                     uids=list(uids), steps_left=list(steps_left),

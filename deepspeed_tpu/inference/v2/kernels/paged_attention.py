@@ -30,13 +30,16 @@ def paged_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
                     lengths: jnp.ndarray,
                     k_scale: jnp.ndarray = None,
                     v_scale: jnp.ndarray = None,
-                    variant: Optional[str] = None) -> jnp.ndarray:
+                    variant: Optional[str] = None,
+                    runs: jnp.ndarray = None) -> jnp.ndarray:
     """q [N, nh, hd]; k/v_cache the pool's leaves whole,
     [L, nb, bs, kvh * hd], and the ``layer`` to attend; block_tables
     [N, MB] int32; lengths [N] (valid tokens incl. the current one);
     ``k_scale``/``v_scale`` [nb, kvh], the layer's, for the int8
-    ``kv_quant`` pool. Returns [N, nh, hd]."""
+    ``kv_quant`` pool; ``runs``: how the tables lie
+    (``ragged_attention.table_runs``), where the caller has made it.
+    Returns [N, nh, hd]."""
     return ragged_attention(
         q, k_cache, v_cache, layer, jnp.arange(q.shape[0], dtype=jnp.int32),
         lengths, block_tables, k_scale=k_scale, v_scale=v_scale,
-        variant=variant, one_token=True)
+        variant=variant, one_token=True, runs=runs)

@@ -88,8 +88,17 @@ test_kernels_lower_tpu.py`` pins against the Mosaic compiler):
   on the same products: the token tile's output to the order of the
   float32 sums. The launch keeps its name. The latent kernel takes the
   same form over all ``nh`` heads.
-  A CHUNK'S COPIES (:func:`_walk_rows`) are started a page at a time,
-  two pages a trip of the loop, and waited for by their BYTES: one wait
+  A CHUNK'S COPIES (:func:`_walk_rows`) are started A RUN OF PAGES A
+  DESCRIPTOR where the row's table says its places lie on consecutive
+  blocks (:func:`table_runs`, made once a program by the caller and
+  prefetched beside the table: one copy ``pool[layer, b : b + 32]`` for
+  a chunk that lies together, the sizes in a shorter run's binary
+  digits), and a page at a time, two pages a trip of the loop, where
+  they lie alone or the launch is handed no runs: a page of 16
+  positions is 16 KB a leaf at 4 kv heads of 128, and 64 starts a chunk
+  in one instruction stream with the products were a quarter of a
+  decode launch at 8k contexts (PERF.md section 6, PR 67). They are
+  waited for by their BYTES: one wait
   a semaphore for a whole chunk, the powers of two in its pages for a
   partial one (:func:`_chunk_waits`). And in the one-token form a chunk
   meets ALL its lane blocks' query rows in one update
@@ -171,12 +180,135 @@ _ONE_TOKEN_ROWS = 16
 # PR 50)
 _START_UNROLL = 2
 
+# the pages a run must hold to be copied as one (:func:`table_runs`): a
+# shorter run's pages are started one by one, because a segment of the walk
+# costs a lookup and a trip of a loop, which a start or two saved do not pay
+# for; read on the chip (PERF.md section 6, PR 67)
+_RUN_PAGES = 8
+
+# a page of a leaf must be UNDER this many bytes for the launches over its
+# pool to be handed their tables' runs (:func:`runs_serve`); read on the chip
+_RUN_PAGE_BYTES = 32 * 1024
+
 
 def _chunk_pages(MB: int, nb: int, bs: int) -> int:
     """Pages a chunk of the walk: ``_CHUNK_POSITIONS`` positions, and no
     more than a row's table has places or the pool blocks (a chunk's
     waiting descriptor names that many blocks of the pool)."""
     return max(1, min(MB, nb, _CHUNK_POSITIONS // bs))
+
+
+def table_runs(block_tables, cp: int):
+    """How a row's table LIES, place by place: ``[R, MB]`` int32 beside
+    ``block_tables`` ``[R, MB]``, read off the table and nothing else.
+    Places ``p`` and ``p + 1`` are LINKED where the second's block is the
+    first's plus one. At place ``p``:
+
+    * ``+s`` (2 or more): a RUN starts here, ``s`` places whose blocks are
+      ``b .. b + s - 1``, which one copy ``pool[layer, b : b + s]`` brings
+      (:func:`_walk_rows` cuts it into a few descriptors of static sizes);
+    * ``-t`` (1 or more): the next ``t`` places are each linked to nothing
+      behind them, and are copied a page at a time.
+
+    A run of under ``_RUN_PAGES`` pages in all is no run: its links are
+    struck out first and its pages lie alone (what is left of a LONGER run
+    behind a chunk's first place or ahead of its end is still a run,
+    however short).
+
+    Both count no further than the table's last place (a ring's run never
+    wraps) and are capped a little over ``cp``: the walk takes ``min(., the
+    chunk's pages left)``, which is how a run ends at a chunk's end. A
+    place's entry holds for a walk that STARTS there (a chunk's first
+    page, or the place behind the run or the stretch before), so a
+    run's last page reads as a lone one: it is, for a chunk that starts
+    on it. The null block links to nothing that matters: padding is
+    zeros, and ``0, 0`` is no run.
+
+    ``numpy`` in, ``numpy`` out (the host's counter,
+    :func:`copy_counts`); traced, a few shifted selects (the count of
+    equal neighbours by doubling: ``log2(cp)`` steps, no scan and no
+    cumulative minimum, which lowers to half a megabyte of TPU code a
+    table). The serving programs make it ONCE, ahead of the layers
+    (``paged_model.paged_ragged_step`` / ``paged_decode_window``), and
+    hand it to every attention launch as a prefetched scalar array."""
+    xp = np if isinstance(block_tables, np.ndarray) else jnp
+    R, MB = block_tables.shape
+
+    def shifted(a, d):
+        """``a`` ``d`` places further on (``d`` < 0: further back), zeros
+        past the table's ends"""
+        if abs(d) >= MB:
+            return xp.zeros_like(a)
+        pad = xp.zeros((R, abs(d)), a.dtype)
+        return xp.concatenate([a[:, d:], pad] if d > 0
+                              else [pad, a[:, :d]], axis=1)
+
+    def counted(a, step, cap):
+        """At each place, the ones of ``a`` from there on without a gap
+        (``step`` -1: from there back), capped at ``cap`` or a little
+        over: by doubling"""
+        d = 1
+        while d < cap:
+            a = a + xp.where(a == d, shifted(a, step * d), 0)
+            d *= 2
+        return a
+
+    bt = block_tables.astype(xp.int32)
+    last = xp.arange(MB, dtype=xp.int32)[None, :] == MB - 1
+    link = (shifted(bt, 1) == bt + 1) & ~last
+    # a run of under ``_RUN_PAGES`` pages is no run: its pages lie alone
+    links = link.astype(xp.int32)
+    link = link & (counted(links, 1, _RUN_PAGES) + counted(
+        links, -1, _RUN_PAGES) >= _RUN_PAGES)
+    # places from p on whose link is p's own, less one
+    same = counted(((shifted(link, 1) == link) & ~last).astype(xp.int32),
+                   1, cp)
+    return xp.where(link, same + 2, -(same + 1)).astype(xp.int32)
+
+
+def _run_sizes(cp: int):
+    """The static sizes (pages) of a run's descriptors, largest first:
+    the powers of two up to ``cp``. A run of ``s`` pages (``s <= cp``)
+    is started as the sizes in ``s``'s binary digits, each at the pages
+    the larger ones leave (:func:`_run_cut`): one descriptor for a whole
+    chunk of 32, at most five for any other length."""
+    return tuple(1 << i for i in reversed(range(cp.bit_length())))
+
+
+def _run_cut(s: int, cp: int):
+    """``[(offset, size)]``: the descriptors :func:`_walk_rows` starts for
+    a run of ``s`` pages, whole numbers in and out (the kernel makes the
+    same cut on a traced ``s``; the host's counter and a test read it
+    here). The sizes add up to ``s`` for every ``s`` in ``0..cp``."""
+    return [(s & ~(2 * size - 1), size) for size in _run_sizes(cp)
+            if s & size]
+
+
+def runs_serve(page_bytes: int) -> bool:
+    """Whether a launch over a pool whose page is ``page_bytes`` a leaf is
+    handed its tables' runs (static: shapes alone). Where a page's copy is
+    32 KB and more its bytes already take as long as its start, the launch
+    stands on its bytes and a run's one start gains nothing, while the
+    lookup a chunk is not free: read on the chip at the benchmark's
+    decode launches (PERF.md section 6, PR 67: pages of 8 and 16 KB gain
+    20 to 32 % over a pool that lies together, granite's 32 KB 0.7 %,
+    OPT-1.3B's 64 KB lose 1 %). Such a launch is the program it was."""
+    return page_bytes < _RUN_PAGE_BYTES
+
+
+def launch_runs(block_tables, k_cache, head_dim: int):
+    """:func:`table_runs` of ``block_tables`` for the launches that walk
+    the pool ``k_cache`` ``[L, nb, bs, kvh * head_dim]`` by them, or None
+    where such a launch does not read it (static): off the TPU and
+    wherever the pipelined variant serves, which copy a page a grid
+    step, and over a pool whose pages are too large to gain by it
+    (:func:`runs_serve`)."""
+    nb, bs, F = k_cache.shape[1:]
+    if _interpret() or tiled_geometry(head_dim, F // head_dim) is None \
+            or not runs_serve(bs * F * k_cache.dtype.itemsize):
+        return None
+    return table_runs(block_tables, _chunk_pages(block_tables.shape[1], nb,
+                                                 bs))
 
 
 def _sublane_tiles(rows: int) -> int:
@@ -358,12 +490,105 @@ def decode_positions(contexts, bs: int, table_pages: int, pool_blocks: int,
     products run over ``chunked``. Host arithmetic on what the host
     knows (numpy in, ints out): the engine's
     ``inference_attention_decode_positions_total``."""
-    ctx = np.asarray(contexts, np.int64)
     cp = _chunk_pages(table_pages, pool_blocks, bs)
-    pages = -(-ctx // bs)
-    if window:
-        pages = pages - np.maximum(ctx - window, 0) // bs
+    _, pages = decode_walks(contexts, bs, window)
     return (int(pages.sum()) * bs, int((-(-pages // cp)).sum()) * cp * bs)
+
+
+def _ones(x):
+    """The ones in each whole number's binary digits (numpy)"""
+    x = np.asarray(x, np.int64)
+    return sum((x >> i) & 1 for i in range(max(int(x.max(initial=0)), 1)
+                                           .bit_length()))
+
+
+def copy_counts(tables, rows, first, pages, cp: int, ring: int = 0,
+                runs: bool = True):
+    """``(pages, descriptors)``: what ONE leaf's copies of these walks
+    bring and in how many starts, by the cut :func:`_walk_rows` makes.
+    Walk i is row ``rows[i]``'s of ``tables`` (the host's, ``[R, MB]``
+    numpy): ``pages[i]`` pages from the row's page ``first[i]`` on, in
+    chunks of ``cp`` from there, a page at place ``page % ring`` (``ring``
+    0: at its own place). ``runs`` False: the launch was handed no runs,
+    a start a page. A chunk's descriptors are, of each run of linked
+    places it holds (clipped to the chunk and to the ring's last place),
+    the ones in its length's binary digits (:func:`_run_cut`), and one a
+    page that lies alone: read off prefix sums over the table, no loop
+    over pages or chunks (a test holds it to the kernel's own cut, walked
+    chunk by chunk). Host arithmetic on what the host holds (numpy in,
+    ints out): the engine's ``inference_attention_copy_pages_total`` /
+    ``..._descriptors_total``; ``pages / descriptors`` is 1.0 over a pool
+    with no two neighbouring places on neighbouring blocks and ``cp``
+    where every chunk lies together."""
+    rows, first, pages = (np.asarray(a, np.int64) for a in
+                          (rows, first, pages))
+    keep = pages > 0
+    rows, first, pages = rows[keep], first[keep], pages[keep]
+    total = int(pages.sum())
+    if not runs or not total:
+        return total, total
+    tables = np.asarray(tables)
+    R, MB = tables.shape
+    W = ring or MB
+    link = np.zeros((R, W + 1), bool)           # place p to p + 1
+    link[:, :W] = table_runs(
+        np.ascontiguousarray(tables[:, :W]), cp) > 0
+    before = np.zeros((R, W + 1), bool)         # place p - 1 to p
+    before[:, 1:] = link[:, :-1]
+    at = np.arange(W + 1)[None, :]
+    # the run a place lies in: where it starts and ends (exclusive)
+    start = np.maximum.accumulate(
+        np.where(link & ~before, at, -1), axis=1)
+    end = np.minimum.accumulate(
+        np.where(~link, at, W)[:, ::-1], axis=1)[:, ::-1] + 1
+    in_run = link | before
+    # prefix sums: the lone pages, and every run's descriptors at its start
+    lone = np.zeros((R, W + 1), np.int64)
+    lone[:, 1:] = np.cumsum(~in_run[:, :W], axis=1)
+    whole = np.zeros((R, W + 1), np.int64)
+    whole[:, 1:] = np.cumsum(
+        np.where(link & ~before, _ones(end - at), 0)[:, :W], axis=1)
+
+    def pieces(r, a, b):
+        """Descriptors of places [a, b) of row r: no wrap, b - a <= cp"""
+        n = lone[r, b] - lone[r, a] + whole[r, b] - whole[r, a]
+        # the run a lies in, where a is not its first page
+        head = in_run[r, a] & (start[r, a] < a)
+        n = n + np.where(head, _ones(np.minimum(end[r, a], b) - a), 0)
+        # a run that starts in [a, b) and ends past b
+        z = b - 1
+        tail = in_run[r, z] & (end[r, z] > b) & (start[r, z] >= a)
+        n = n + np.where(tail, _ones(b - start[r, z])
+                         - _ones(end[r, z] - start[r, z]), 0)
+        return int(n.sum())
+
+    # a lane a (walk, chunk)
+    chunks = -(-pages // cp)
+    walk = np.repeat(np.arange(pages.size), chunks)
+    c = np.arange(walk.size) - np.repeat(np.cumsum(chunks) - chunks, chunks)
+    r = rows[walk]
+    a = first[walk] + c * cp
+    n = np.minimum(pages[walk] - c * cp, cp)
+    if not ring:
+        return total, pieces(r, a, a + n)
+    a = a % ring
+    b = a + n
+    over = b > ring                     # a run ends at the ring's last place
+    return total, pieces(r, a, np.minimum(b, ring)) + pieces(
+        r[over], np.zeros_like(a[over]), b[over] - ring)
+
+
+def launch_copies(tables, rows, first, pages, bs: int, pool_blocks: int,
+                  page_bytes: int, ring: int = 0):
+    """:func:`copy_counts` of the walks as a launch over ``tables`` (its
+    pool ``pool_blocks`` pages of ``bs`` positions, ``page_bytes`` a
+    leaf) makes them: the chunk it cuts from the table's width, and the
+    table's runs where such a launch is handed them
+    (:func:`runs_serve`)."""
+    MB = ring or np.shape(tables)[1]
+    return copy_counts(tables, rows, first, pages,
+                       _chunk_pages(MB, pool_blocks, bs), ring,
+                       runs=runs_serve(page_bytes))
 
 
 def _token_tile(tokens: int, rpb: int) -> int:
@@ -416,31 +641,62 @@ def prompt_chunks(new, contexts, bs: int, table_pages: int, pool_blocks: int,
     PR 59).
     Host arithmetic on what the host knows (numpy in, ints out): the
     engine's ``inference_attention_prompt_chunks_total``."""
-    new = np.asarray(new, np.int64)
-    ctx = np.asarray(contexts, np.int64)[new > 0]
-    new = new[new > 0]
-    if not new.size:
+    _, lo, hi, held = _tile_rows(new, contexts, tq)
+    if not lo.size:
         return 0, 0
     P = _chunk_pages(table_pages, pool_blocks, bs) * bs
-    end = np.cumsum(new)                    # a row's tokens: [tok0, end)
-    tok0 = end - new
-    tiles = (end - 1) // tq - tok0 // tq + 1        # a row's tokens lie in
-    # one entry a (row, tile) pair: the row's tokens in the tile
-    row = np.repeat(np.arange(new.size), tiles)
-    tile = np.arange(row.size) - np.repeat(np.cumsum(tiles) - tiles, tiles) \
-        + (tok0 // tq)[row]
-    first = np.maximum(tile * tq, tok0[row])
-    last = np.minimum(tile * tq + tq - 1, end[row] - 1)
-    bound0 = ctx[row] - end[row] + 1         # a token's bound less its index
-    lo, hi = bound0 + first, bound0 + last
     base = np.maximum(lo - window, 0) // bs * bs if window else 0
     chunks = -(-(hi - base) // P)
     # chunk c is whole for c in [c0, c1]: its end under the lowest bound,
     # its start inside the highest bound's window
     c1 = np.minimum((lo - base) // P, chunks) - 1
     c0 = np.maximum(-(-(hi - window - base) // P), 0) if window else 0
-    whole = np.where(last - first + 1 == tq, np.maximum(c1 - c0 + 1, 0), 0)
+    whole = np.where(held == tq, np.maximum(c1 - c0 + 1, 0), 0)
     return int(whole.sum()), int((chunks - whole).sum())
+
+
+def _tile_rows(new, contexts, tq: int):
+    """One entry a (row, tile) pair of a token-tile launch whose rows feed
+    ``new`` tokens each (packed in order) that end at ``contexts``, in
+    tiles of ``tq``: ``(row, lo, hi, held)``, the row (its index in
+    ``new``), the bounds of the first and the last of its tokens in the
+    tile, and how many of them the tile holds. What :func:`_walk_rows`'
+    ``bounds`` reads, on the host (numpy)."""
+    new = np.asarray(new, np.int64)
+    fed = np.flatnonzero(new > 0)
+    ctx, new = np.asarray(contexts, np.int64)[fed], new[fed]
+    end = np.cumsum(new)                    # a row's tokens: [tok0, end)
+    tok0 = end - new
+    tiles = (end - 1) // tq - tok0 // tq + 1        # a row's tokens lie in
+    row = np.repeat(np.arange(new.size), tiles)
+    tile = np.arange(row.size) - np.repeat(np.cumsum(tiles) - tiles, tiles) \
+        + (tok0 // tq)[row]
+    first = np.maximum(tile * tq, tok0[row])
+    last = np.minimum(tile * tq + tq - 1, end[row] - 1)
+    bound0 = ctx[row] - end[row] + 1         # a token's bound less its index
+    return fed[row], bound0 + first, bound0 + last, last - first + 1
+
+
+def prompt_walks(new, contexts, bs: int, window: int = 0, tq: int = 128):
+    """``(rows, first page, pages)`` of ONE token-tile launch's walks, one
+    a (row, tile) pair (:func:`_tile_rows`): the tile walks the row's
+    pages from the one that holds the first position its earliest token
+    sees (page 0 without a ``window``) to the one that holds its last
+    token's bound. What :func:`copy_counts` takes."""
+    row, lo, hi, _ = _tile_rows(new, contexts, tq)
+    first = np.maximum(lo - window, 0) // bs if window \
+        else np.zeros_like(lo)
+    return row, first, -(-hi // bs) - first
+
+
+def decode_walks(contexts, bs: int, window: int = 0):
+    """``(first page, pages)`` of the one-token form's walks of rows whose
+    bounds are ``contexts`` (one a row and launch), as
+    :func:`decode_positions` counts their positions."""
+    ctx = np.asarray(contexts, np.int64)
+    first = np.maximum(ctx - window, 0) // bs if window \
+        else np.zeros_like(ctx)
+    return first, -(-ctx // bs) - first
 
 
 def _visible(tl_ref, t0, first, last, c, tq, reps, P, base=0, window=0):
@@ -516,7 +772,8 @@ def _chunk_waits(n, cp, wait):
 
 def _walk_rows(len_ref, bt_ref, first_ref, last_ref, lo, hi, *, t0, tq, bs,
                cp, copies, waits, compute, chunk_copies=None, window=0,
-               ring=0, begin=None, finish=None):
+               ring=0, begin=None, finish=None, runs_ref=None,
+               run_copies=None):
     """The walk the tiled and the latent kernel share: the tile of flat
     tokens [t0, t0 + tq) visits the rows ``lo..hi`` that own its tokens,
     a row's pages in chunks of ``cp`` up to the causal bound of the
@@ -544,7 +801,22 @@ def _walk_rows(len_ref, bt_ref, first_ref, last_ref, lo, hi, *, t0, tq, bs,
     and ``base`` is that page's first position (0 without a window).
     The row's table is then a ring of ``ring`` places: the page of
     positions ``[b * bs, (b + 1) * bs)`` is ``bt_ref[r, b % ring]``
-    (a table that holds every position is a ring that never wraps)."""
+    (a table that holds every position is a ring that never wraps).
+
+    ``runs_ref`` (:func:`table_runs` of the table, beside it; None: every
+    page is started by itself, the walk as it always was): a chunk's
+    copies are started A RUN OF PAGES A DESCRIPTOR. A run of ``s`` pages
+    on blocks ``b .. b + s - 1`` is started as the few ``run_copies(b +
+    off, slot, j + off, size)`` of static sizes that add up to it
+    (:func:`_run_cut`: one for a chunk that lies together), a stretch of
+    pages that lie alone a page at a time as ever, two a trip. ONE lookup
+    at the chunk's first place says which it is for the whole chunk,
+    nearly always; a chunk that holds several segments (and every chunk
+    of a ring, which may turn its last place) is walked segment by
+    segment, a lookup each. A segment ends at the chunk's end and at the
+    ring's last place. The bytes that land in a slot, their places and
+    the waits are the same, so ``compute`` sees the same chunk to the
+    last bit."""
     P, T = cp * bs, len_ref.shape[0]
 
     def bounds(r):
@@ -588,22 +860,75 @@ def _walk_rows(len_ref, bt_ref, first_ref, last_ref, lo, hi, *, t0, tq, bs,
             # one subtraction, since a chunk is no longer than the ring
             at0 = (bounds(r)[3] + at0) % ring
 
-        def one(j):
+        def each(count, one):
+            """``one(i)`` for i under ``count``: ``_START_UNROLL`` a trip of
+            the loop, a last, partial trip's each under its own guard"""
+            def trip(g, _):
+                for u in range(_START_UNROLL):                # static
+                    one(g * _START_UNROLL + u)
+                return 0
+            whole = count // _START_UNROLL
+            jax.lax.fori_loop(0, whole, trip, 0)
+            for u in range(_START_UNROLL - 1):                # the tail
+                i = whole * _START_UNROLL + u
+                pl.when(i < count)(functools.partial(one, i))
+
+        def place(j):
             at = at0 + j
             if window:
                 at = jnp.where(at >= ring, at - ring, at)
-            for dma in copies(bt_ref[r, at], slot, j):
+            return at
+
+        def one(j):
+            for dma in copies(bt_ref[r, place(j)], slot, j):
                 dma.start()
 
-        def trip(g, _):
-            for u in range(_START_UNROLL):                    # static
-                one(g * _START_UNROLL + u)
-            return 0
-        whole = n // _START_UNROLL
-        jax.lax.fori_loop(0, whole, trip, 0)
-        for u in range(_START_UNROLL - 1):                    # the tail
-            j = whole * _START_UNROLL + u
-            pl.when(j < n)(functools.partial(one, j))
+        def lone(j0, at, count):
+            """``count`` pages a copy each: places ``at ..`` of the table
+            (no wrap among them) to the slot's ``j0 ..``"""
+            def page(i):
+                for dma in copies(bt_ref[r, at + i], slot, j0 + i):
+                    dma.start()
+            each(count, page)
+
+        def run(j0, block, count):
+            """``count`` pages that lie together from ``block``, as the
+            sizes in ``count``'s binary digits (:func:`_run_cut`)"""
+            for size in _run_sizes(cp):                       # static
+                def some(size=size):
+                    off = count & ~(2 * size - 1)
+                    for dma in run_copies(block + off, slot, j0 + off, size):
+                        dma.start()
+                pl.when(count & size != 0)(some)
+
+        def segment(j):
+            at = place(j)
+            lies = runs_ref[r, at]
+            count = jnp.minimum(jnp.abs(lies), n - j)
+            pl.when(lies > 0)(lambda: run(j, bt_ref[r, at], count))
+            pl.when(lies < 0)(lambda: lone(j, at, count))
+            return j + count
+
+        def segments():
+            jax.lax.while_loop(lambda j: j < n, segment, jnp.int32(0))
+
+        if runs_ref is None:
+            each(n, one)
+        elif window:
+            # a chunk may turn the ring's last place: segment by segment
+            # (a fast path ahead of the loop read 3.6 % SLOWER over a pool
+            # with no run in it, where this reads the parent's time)
+            segments()
+        else:
+            # one lookup says how the whole chunk lies, nearly always:
+            # alone, page by page exactly as ever (2 % over the parent's
+            # launch on a pool with no run in it, where the loop alone
+            # reads 6 %), or together, one run; else segment by segment
+            lies = runs_ref[r, at0]
+            whole_lone, whole_run = lies <= -n, lies >= n
+            pl.when(whole_lone)(lambda: each(n, one))
+            pl.when(whole_run)(lambda: run(0, bt_ref[r, at0], n))
+            pl.when(~(whole_lone | whole_run))(segments)
         if chunk_copies is not None:
             for dma in chunk_copies(r, c, slot):
                 dma.start()
@@ -728,7 +1053,7 @@ def _blocks_update(q, k, v, visible, acc_sc, m_sc, l_sc, *, scale):
 def _tiled_kernel(layer_ref, len_ref, bt_ref, first_ref, last_ref, lo_ref,
                   hi_ref, q_ref, tl_ref, k_hbm, v_hbm, *rest, quant, bs,
                   scale, kvh, hd, hpb, group, tq, cp, io_dtype, window=0,
-                  ring=0, one_token=False):
+                  ring=0, one_token=False, runs_ref=None):
     """Grid (T / tq,): one step a tile of ``tq`` flat tokens. The tile
     walks the rows that own its tokens (``lo_ref``/``hi_ref``), a row's
     pages in chunks of ``cp`` up to the causal bound of the row's last
@@ -755,7 +1080,12 @@ def _tiled_kernel(layer_ref, len_ref, bt_ref, first_ref, last_ref, lo_ref,
     written to the row's place in the output tile ``(tq, nblk, group,
     bw)`` behind its last; a position is masked by the row's bound (and
     window) alone, and a chunk's lane blocks are updated all at once
-    (:func:`_blocks_update`)."""
+    (:func:`_blocks_update`).
+
+    ``runs_ref`` (:func:`table_runs`, prefetched beside the table; None:
+    a start a page): pages that lie on consecutive blocks arrive a run a
+    descriptor, ``k_hbm.at[layer, b : b + size]`` to the slot's places
+    ``j : j + size`` (:func:`_walk_rows`)."""
     if quant:
         ks_hbm, vs_hbm, o_ref, k_buf, v_buf, ks_buf, vs_buf, acc_sc, m_sc, \
             l_sc, sem, ssem = rest
@@ -786,14 +1116,18 @@ def _tiled_kernel(layer_ref, len_ref, bt_ref, first_ref, last_ref, lo_ref,
                 pltpu.make_async_copy(v_hbm.at[layer, page],
                                       v_buf.at[slot, j], sem.at[slot, 1]))
 
+    def run_copies(block, slot, j, size):
+        """``size`` (static) pages on consecutive blocks from ``block``"""
+        src, dst = pl.ds(block, size), pl.ds(j, size)
+        return (pltpu.make_async_copy(k_hbm.at[layer, src],
+                                      k_buf.at[slot, dst], sem.at[slot, 0]),
+                pltpu.make_async_copy(v_hbm.at[layer, src],
+                                      v_buf.at[slot, dst], sem.at[slot, 1]))
+
     def waits(slot, size):
         """``size`` pages' bytes on the slot's two semaphores (which
         pages the descriptors name is of no account: they never start)"""
-        first = pl.ds(0, size)
-        return (pltpu.make_async_copy(k_hbm.at[layer, first],
-                                      k_buf.at[slot, first], sem.at[slot, 0]),
-                pltpu.make_async_copy(v_hbm.at[layer, first],
-                                      v_buf.at[slot, first], sem.at[slot, 1]))
+        return run_copies(0, slot, 0, size)
 
     def scale_copies(r, c, slot):
         src = pl.ds(pl.multiple_of(
@@ -866,7 +1200,8 @@ def _tiled_kernel(layer_ref, len_ref, bt_ref, first_ref, last_ref, lo_ref,
     _walk_rows(len_ref, bt_ref, first_ref, last_ref, lo, hi, t0=t0, tq=tq,
                bs=bs, cp=cp, copies=copies, waits=waits, compute=compute,
                chunk_copies=scale_copies if quant else None, window=window,
-               ring=ring, **_row_hooks(one_token, finish, acc_sc, m_sc, l_sc))
+               ring=ring, runs_ref=runs_ref, run_copies=run_copies,
+               **_row_hooks(one_token, finish, acc_sc, m_sc, l_sc))
     if one_token:
         return
 
@@ -880,6 +1215,14 @@ def _tiled_kernel(layer_ref, len_ref, bt_ref, first_ref, last_ref, lo_ref,
         for i in range(1, hpb):        # head i's lanes from head i's rows
             out = jnp.where(lane >= i * hd, a[i], out)
         o_ref[b] = out.reshape(group, tq, bw).astype(o_ref.dtype)
+
+
+def _tiled_kernel_runs(layer_ref, len_ref, bt_ref, first_ref, last_ref,
+                       lo_ref, hi_ref, runs_ref, *rest, **static):
+    """:func:`_tiled_kernel` with the table's runs prefetched behind the
+    other scalars"""
+    _tiled_kernel(layer_ref, len_ref, bt_ref, first_ref, last_ref, lo_ref,
+                  hi_ref, *rest, runs_ref=runs_ref, **static)
 
 
 def _row_descriptors(row_ids, lengths, R, tq):
@@ -899,14 +1242,16 @@ def _row_descriptors(row_ids, lengths, R, tq):
 
 
 def _tiled_call(q, k_cache, v_cache, layer, row_ids, lengths, block_tables,
-                k_scale, v_scale, interpret, window=0, one_token=False):
+                k_scale, v_scale, interpret, window=0, one_token=False,
+                runs=None):
     """Lay the operands out for :func:`_tiled_kernel` and undo it: the
     per-row descriptor (first and last flat token, from ``row_ids`` and
     ``lengths``: a row's tokens are contiguous in pack order), a tile's
     first and last row, and queries as ``[block, row of the block, T,
     bw]`` (``one_token``: ``[T, block, row of the block, bw]``, a
     token's query rows one tile). The pools go in as they are stored,
-    whole."""
+    whole. ``runs`` (:func:`table_runs` of ``block_tables``, or None)
+    rides beside the table, one more prefetched scalar array."""
     T0, nh, hd = q.shape
     bs, F = k_cache.shape[2:]
     kvh = F // hd
@@ -928,6 +1273,11 @@ def _tiled_call(q, k_cache, v_cache, layer, row_ids, lengths, block_tables,
     cp = _chunk_pages(MB, k_cache.shape[1], bs)
     if MB % cp:
         block_tables = jnp.pad(block_tables, ((0, 0), (0, cp - MB % cp)))
+        if runs is not None:
+            # (a padding place lies alone: an entry of 0 would be a
+            # segment of no pages, and a walk that never ends)
+            runs = jnp.pad(runs, ((0, 0), (0, cp - MB % cp)),
+                           constant_values=-1)
         MB = block_tables.shape[1]
     if T != T0:
         q = jnp.pad(q, ((0, T - T0), (0, 0), (0, 0)))
@@ -997,15 +1347,19 @@ def _tiled_call(q, k_cache, v_cache, layer, row_ids, lengths, block_tables,
         scratch += [pltpu.SMEM((2 * w,), jnp.float32)] * 2
         sems.append(pltpu.SemaphoreType.DMA((2, 2)))
     scratch += state
+    tables = (block_tables, row_first, row_last, tile_lo, tile_hi)
+    if runs is not None:
+        tables += (runs.astype(jnp.int32),)
     kernel = functools.partial(
-        _tiled_kernel, quant=quant, bs=bs, scale=1.0 / (hd ** 0.5), kvh=kvh,
+        _tiled_kernel if runs is None else _tiled_kernel_runs,
+        quant=quant, bs=bs, scale=1.0 / (hd ** 0.5), kvh=kvh,
         hd=hd, hpb=hpb, group=group, tq=tq, cp=cp, io_dtype=q.dtype,
         one_token=one_token,
         **(dict(window=window, ring=ring) if window else {}))
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=7,
+            num_scalar_prefetch=2 + len(tables),
             grid=(T // tq,),
             in_specs=in_specs,
             out_specs=pl.BlockSpec(o_block, tile),
@@ -1019,8 +1373,7 @@ def _tiled_call(q, k_cache, v_cache, layer, row_ids, lengths, block_tables,
         interpret=pltpu.InterpretParams() if interpret else False,
         name="ragged_attention_window" if window
         else "ragged_attention_tiled",
-    )(layer, lengths, block_tables, row_first, row_last, tile_lo, tile_hi,
-      *operands)
+    )(layer, lengths, *tables, *operands)
     out = out.reshape(T, nblk, group, hpb, hd).transpose(0, 1, 3, 2, 4) \
         if one_token \
         else out.reshape(nblk, group, T, hpb, hd).transpose(2, 0, 3, 1, 4)
@@ -1035,7 +1388,8 @@ def ragged_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
                      v_scale: jnp.ndarray = None,
                      variant: Optional[str] = None,
                      window: int = 0,
-                     one_token: bool = False) -> jnp.ndarray:
+                     one_token: bool = False,
+                     runs: jnp.ndarray = None) -> jnp.ndarray:
     """Ragged paged attention (serving hot path).
 
     q [T, nh, hd] flat token buffer; k/v_cache the pool's leaves as
@@ -1065,7 +1419,14 @@ def ragged_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
     ``one_token`` (static): the caller's word that every row has
     exactly one token (a decode batch). The tiled variant then takes its
     one-token form (the same launch names, the same sums in the same
-    order); the pipelined one is a token a grid step already."""
+    order); the pipelined one is a token a grid step already.
+
+    ``runs`` (:func:`table_runs` of ``block_tables``; None: the launch
+    as it always was): how the rows' tables lie, made once a program by
+    the caller. The tiled variant then starts a chunk's copies a run of
+    pages a descriptor (:func:`_walk_rows`): the same bytes to the same
+    places, the same output to the last bit. The pipelined variant
+    copies a page a grid step and does not read it."""
     T, nh, hd = q.shape
     bs, F = k_cache.shape[2:]
     kvh = F // hd
@@ -1089,7 +1450,7 @@ def ragged_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
             raise ValueError(f"no tiled variant for {kvh} kv heads of {hd}")
         return _tiled_call(q, k_cache, v_cache, layer, row_ids, lengths,
                            block_tables, k_scale, v_scale, interpret,
-                           window, one_token)
+                           window, one_token, runs)
     q4 = q.reshape(T, kvh, group, hd)
     static = dict(bs=bs, scale=1.0 / (hd ** 0.5), kvh=kvh, group=group,
                   io_dtype=q.dtype, **({"window": window} if window else {}))

@@ -885,7 +885,7 @@ def _latent_attention_sublayer(cfg, lp, x, l, pool, cos, sin, row_ids,
 def _per_head_attention_sublayer(cfg, lp, x, kind, l, pool, cos, sin,
                                  row_ids, lengths, write_blocks,
                                  write_offsets, block_tables, use_kernel,
-                                 one_token=False, hn=None):
+                                 one_token=False, hn=None, runs=None):
     """A per-head (GQA) mixer of a layer pattern on flat tokens x
     [T, H]: ``kind`` "full" (a token sees every position under its
     bound) or "window" (its last ``cfg.attn_window``), ``l`` the layer's
@@ -909,8 +909,9 @@ def _per_head_attention_sublayer(cfg, lp, x, kind, l, pool, cos, sin,
     ``cfg.attn_in_scale`` on the normed input (taken on each
     projection's float32 sum, which is linear in it) and
     ``cfg.key_scale`` on the keys, ahead of the rotation, so that the
-    pool holds the keys the source caches. Returns (what attention adds
-    to x, pool)."""
+    pool holds the keys the source caches. ``runs``: how the kind's
+    tables lie, where the program has made it (``_table_runs``).
+    Returns (what attention adds to x, pool)."""
     from ...ops.norms import rms_norm
     from .kernels.ragged_attention import (ragged_attention,
                                            ragged_attention_reference)
@@ -968,7 +969,7 @@ def _per_head_attention_sublayer(cfg, lp, x, kind, l, pool, cos, sin,
                 at = jnp.int32(0)
             o = ragged_attention(q, kc, vc, at, row_ids, lengths,
                                  block_tables, window=window,
-                                 one_token=one_token)
+                                 one_token=one_token, runs=runs)
     if cfg.attn_gate == "elementwise":
         with jax.named_scope("attn_gate"):
             o = o * jax.nn.sigmoid(
@@ -1374,6 +1375,37 @@ def _short_conv_sublayer(cfg, lp, x, l, cache, rows: _StateRows,
         return y @ lp["w_out"], cache
 
 
+def _table_runs(cfg, cache, block_tables, window_tables, use_kernel):
+    """``(how block_tables lie, how window_tables lie)``
+    (``kernels/ragged_attention.table_runs``), each None where the
+    attention launches over that table do not read it
+    (``launch_runs``: off the TPU, a geometry the pipelined variant
+    serves, pages of 32 KB a leaf and more) or the model has no such
+    table. A function
+    of the tables alone, so a program makes it ONCE, here, under the
+    scope ``table_runs`` ahead of every layer, and every attention
+    launch takes it beside its table as one more prefetched scalar
+    array; a decode window makes it once for all its steps (a table
+    does not change on the device)."""
+    from .kernels.ragged_attention import launch_runs
+    if not (use_kernel and cfg.caches_positions) or cfg.attention == "mla":
+        return None, None
+    with jax.named_scope("table_runs"):
+        full = cache.get("k_full", cache.get("k"))
+        # behind a barrier: made whole HERE, where the program starts.
+        # Left to the scheduler, the full table's few selects landed
+        # between two runs of layers and a 16,384-token step kept one
+        # more [tokens, hidden] float32 buffer alive round them (+0.17 GB
+        # of temporaries by the compiler's analysis, PR 67)
+        runs = (None if full is None else launch_runs(
+                    block_tables, full, cfg.head_dim),
+                None if window_tables is None else launch_runs(
+                    window_tables, cache["k_window"], cfg.head_dim))
+        # (a program that is handed none is the text it was)
+        return runs if all(r is None for r in runs) \
+            else jax.lax.optimization_barrier(runs)
+
+
 def _layer_runs(cfg):
     """The layers as maximal runs of one (mixer kind, MLP kind):
     [(kind, routed, first layer, layers)], ``kind`` one of
@@ -1398,7 +1430,7 @@ def _layer_runs(cfg):
 def _pattern_step(cfg: TransformerConfig, params, ids, row_ids, pos, lengths,
                   write_blocks, write_offsets, block_tables, cache,
                   use_kernel=True, state_slots=None, one_token=False,
-                  window_tables=None):
+                  window_tables=None, table_runs=(None, None)):
     """The whole block of a model that is served as RUNS of layers
     (``cfg.walks_runs``: attention='mla', or a ``layer_types`` pattern
     over per-head attention) on a flat token
@@ -1432,7 +1464,9 @@ def _pattern_step(cfg: TransformerConfig, params, ids, row_ids, pos, lengths,
     blocks], the row's ring, in which position p lies at place
     ``p % ring`` (its write-set is that arithmetic on ``pos``). Under
     the sandwich scheme each sub-layer's output passes a second norm
-    before it joins the stream.
+    before it joins the stream. ``table_runs``: how the two kinds'
+    tables lie (``_table_runs``, made by the program ahead of this
+    walk), handed to each kind's launches.
 
     A layer whose mixer is TWO mixers (kind "hybrid", ``layer_types``
     "mamba_attention") norms the stream ONCE and hands the result to a
@@ -1560,7 +1594,8 @@ def _pattern_step(cfg: TransformerConfig, params, ids, row_ids, pos, lengths,
                         a, pool = _per_head_attention_sublayer(
                             cfg, lp, x, "full", p0 + i, pool, cos, sin,
                             row_ids, lengths, write_blocks, write_offsets,
-                            block_tables, use_kernel, one_token, hn)
+                            block_tables, use_kernel, one_token, hn,
+                            table_runs[0])
                         with jax.named_scope("hybrid_join"):
                             x = joined(joined(x, m, cfg.ssm_out_scale), a,
                                        cfg.attn_out_scale)
@@ -1586,7 +1621,7 @@ def _pattern_step(cfg: TransformerConfig, params, ids, row_ids, pos, lengths,
                         window_writes if ring else write_blocks,
                         write_offsets,
                         window_tables if ring else block_tables, use_kernel,
-                        one_token, mixer_in)
+                        one_token, mixer_in, table_runs[ring])
                     x = joined(x, a, cfg.attn_out_scale)
             else:
                 with jax.named_scope("mla_attention"):
@@ -1734,7 +1769,7 @@ def paged_decode(cfg: TransformerConfig, params, toks: jnp.ndarray,
                  cache: Dict[str, jnp.ndarray], active: jnp.ndarray,
                  block_size: int, use_kernel: bool = True, topo=None,
                  lora=None, adapter_ids=None, state_slots=None,
-                 window_tables=None
+                 window_tables=None, table_runs=None
                  ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
     """toks/pos/active [N]; block_tables [N, MB]. One token per sequence;
     returns ([N, V] logits, cache). Inactive rows write to the null block
@@ -1746,8 +1781,13 @@ def paged_decode(cfg: TransformerConfig, params, toks: jnp.ndarray,
     state, for a model whose layer pattern has linear-attention layers
     (an inactive row reads and writes the null slot). ``window_tables``
     [N, ring blocks]: each row's ring in the window layers' pool, for a
-    ``layer_types`` pattern that has such layers."""
+    ``layer_types`` pattern that has such layers. ``table_runs``: how
+    the tables lie (``_table_runs``), where a decode window has made it
+    for all its steps; made here otherwise."""
     N, MB = block_tables.shape
+    if table_runs is None:
+        table_runs = _table_runs(cfg, cache, block_tables, window_tables,
+                                 use_kernel)
     if cfg.walks_runs:
         # the ragged layout with one token a row (_pattern_step); a
         # model that caches no position writes no block
@@ -1759,7 +1799,7 @@ def paged_decode(cfg: TransformerConfig, params, toks: jnp.ndarray,
             jnp.where(active, pos + 1, 0), jnp.where(active, blk, 0),
             pos % block_size, block_tables, cache, use_kernel=use_kernel,
             state_slots=state_slots, one_token=True,
-            window_tables=window_tables)
+            window_tables=window_tables, table_runs=table_runs)
         with jax.named_scope("head"):
             return _logits(cfg, params, x), stats, cache
     nh, nkv, hd = cfg.num_heads, cfg.kv_heads, cfg.head_dim
@@ -1786,8 +1826,8 @@ def paged_decode(cfg: TransformerConfig, params, toks: jnp.ndarray,
                 o = paged_attention(
                     q, kc, vc, l, block_tables, pos + 1,
                     k_scale=None if ksc is None else ksc[l],
-                    v_scale=None if vsc is None else vsc[l]
-                ).reshape(N, nh * hd)
+                    v_scale=None if vsc is None else vsc[l],
+                    runs=table_runs[0]).reshape(N, nh * hd)
             else:
                 # gather this sequence's pages:
                 # [N, MB, bs, nkv, hd] -> [N, ctx, ..]
@@ -1852,11 +1892,14 @@ def paged_ragged_step(cfg: TransformerConfig, params, ids: jnp.ndarray,
     through attention, which is row-local by construction."""
     T = ids.shape[0]
     RB, MBw = block_tables.shape
+    table_runs = _table_runs(cfg, cache, block_tables, window_tables,
+                             use_kernel)
     if cfg.walks_runs:
         x, stats, cache = _pattern_step(
             cfg, params, ids, row_ids, pos, lengths, write_blocks,
             write_offsets, block_tables, cache, use_kernel=use_kernel,
-            state_slots=state_slots, window_tables=window_tables)
+            state_slots=state_slots, window_tables=window_tables,
+            table_runs=table_runs)
         with jax.named_scope("head"):
             return _logits(cfg, params, x[last_index]), stats, cache
     ctx = MBw * block_size
@@ -1884,8 +1927,8 @@ def paged_ragged_step(cfg: TransformerConfig, params, ids: jnp.ndarray,
                 o = ragged_attention(
                     q, kc, vc, l, row_ids, lengths, block_tables,
                     k_scale=None if ksc is None else ksc[l],
-                    v_scale=None if vsc is None else vsc[l]
-                ).reshape(T, nh * hd)
+                    v_scale=None if vsc is None else vsc[l],
+                    runs=table_runs[0]).reshape(T, nh * hd)
             else:
                 # gather each ROW's pages once, indirect per token: the
                 # materializing fallback (parity reference +
@@ -1983,6 +2026,9 @@ def paged_decode_window(cfg: TransformerConfig, params, toks: jnp.ndarray,
     sampled = rng is not None
     if alive is None:
         alive = jnp.ones((N,), bool)
+    # how the tables lie: once a window, they do not change inside it
+    table_runs = _table_runs(cfg, cache, block_tables, window_tables,
+                             use_kernel)
 
     def body(state):
         s, toks, pos, active, alive, out, moe, cache = state
@@ -1990,7 +2036,7 @@ def paged_decode_window(cfg: TransformerConfig, params, toks: jnp.ndarray,
             cfg, params, toks, pos, block_tables, cache, active, block_size,
             use_kernel=use_kernel, topo=topo, lora=lora,
             adapter_ids=adapter_ids, state_slots=state_slots,
-            window_tables=window_tables)
+            window_tables=window_tables, table_runs=table_runs)
         moe = [_merge_moe_stats(a, b) for a, b in zip(moe, routed)]
         if sampled:
             from .sampling import fold_in_rows, sample_tokens_rowwise

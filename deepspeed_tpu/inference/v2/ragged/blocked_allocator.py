@@ -2,10 +2,20 @@
 
 Reference: inference/v2/ragged/blocked_allocator.py (BlockedAllocator): a
 fixed pool of KV-cache blocks handed out to sequences and returned on
-flush. Host-side (numpy int free list); block 0 is reserved as the NULL
-block that padded token slots write into, so scatters never need masking.
+flush. Host-side (a heap of free block numbers); block 0 is reserved as
+the NULL block that padded token slots write into, so scatters never need
+masking.
+
+``allocate(n)`` hands out the ``n`` LOWEST free blocks, ascending,
+whatever order they were freed in: the blocks of one call lie together
+wherever the pool has the room, in a server's hundredth call as in its
+first, and the attention kernels copy a run of a row's pages that lie on
+consecutive blocks with one descriptor
+(``kernels/ragged_attention.table_runs``). Nothing else may be read
+into WHICH block a caller gets.
 """
 
+import heapq
 from typing import Iterable, List
 
 import numpy as np
@@ -22,8 +32,8 @@ class BlockedAllocator:
         if num_blocks < 2:
             raise ValueError("need at least 2 blocks (one is the null block)")
         self.num_blocks = num_blocks
-        # LIFO free list; block 0 reserved
-        self._free: List[int] = list(range(num_blocks - 1, 0, -1))
+        # a heap: the lowest free block first; block 0 reserved
+        self._free: List[int] = list(range(1, num_blocks))
         self._refs: dict = {}
         # bumped on every allocate/share/free: lets callers memoize
         # refcount-derived aggregates (DSStateManager._evictable)
@@ -52,7 +62,7 @@ class BlockedAllocator:
             raise RuntimeError(
                 f"KV cache exhausted: requested {n} blocks, "
                 f"{len(self._free)} free")
-        out = [self._free.pop() for _ in range(n)]
+        out = [heapq.heappop(self._free) for _ in range(n)]
         for b in out:
             self._refs[b] = 1
             self._touch[b] = self.version
@@ -84,7 +94,7 @@ class BlockedAllocator:
             if refs == 1:
                 del self._refs[b]
                 self._touch.pop(b, None)
-                self._free.append(b)
+                heapq.heappush(self._free, b)
             else:
                 self._refs[b] = refs - 1
         self.version += 1

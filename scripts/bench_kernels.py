@@ -1,18 +1,35 @@
 """What a decode launch of the attention walk costs, part by part, on the chip.
 
     chiprun -- python scripts/bench_kernels.py [--only NAME] [--sweep]
-        [--check] [--launches 200]
+        [--check] [--launches 200] [--pool together|shuffled]
+        [--runs gate|always|never]
 
-The decode launches of the six generation cells that cache positions
+The decode launches of the generation cells that cache positions
 (``ragged_attention_tiled`` / ``_window`` / ``_latent`` in their one-token
 form, at each cell's rows,
-pool, table and contexts), alone, over a random pool whose pages are out of
-order: a ``fori_loop`` of ``--launches`` launches in one jitted program, the
+pool, table and contexts), alone, over a random pool: a ``fori_loop`` of
+``--launches`` launches in one jitted program, the
 next launch's queries made from the last one's output, timed on the host
 clock round ``block_until_ready``; microseconds a launch, the best of three.
 Beside each time, the launch's BYTES at the chip's peak (the pages its rows
 hold, once a row; the window's where there is one): what a launch cannot go
 under.
+
+``--pool`` says how the rows' tables lie (PR 67): ``shuffled`` (the default,
+and what every number before PR 67 was read over) hands each place a block of
+a permutation, no two neighbours together, the pool of a server long in
+service; ``together`` hands a row its places' blocks in ascending order, what
+``BlockedAllocator`` gives a ``generate()`` call's rows; ``steps-N`` lays a
+row's blocks in runs of N pages with the rows' runs interleaved, what chunk
+steps that feed every row N pages each leave behind (``lfm2``'s 4, nemotron's
+8, falcon's 16), and ``--run-pages`` reads the least run (``_RUN_PAGES``)
+otherwise than the tree does. A tiled launch is
+handed the table's runs (``ragged_attention.launch_runs``) as the serving
+programs hand them: where ``runs_serve`` says so (``--runs gate``), whatever
+the table's width (``always``: how the gate is read) or not at all (``never``:
+the launch as it was before PR 67); the row's ``runs`` says which it got, and
+``pages_a_descriptor`` what ``copy_counts`` counts under the launch. The
+latent kernel is handed none.
 
 ``ssm_state_update`` (``kernels/state_space.py``) stands beside them at the
 two state-space cells' decode shapes, the launch as ``paged_model`` makes it
@@ -189,6 +206,14 @@ SHAPES = {
                                 ring=321),
     "granite-full": dict(kernel="tiled", rows=64, layers=1, kvh=8, hd=128,
                          nh=32, ctx=(1024, 1280), window=0, ring=0),
+    # PR 67: the other cells' decode launches, where the table's runs are
+    # weighed against a table's width (``ragged_attention.runs_serve``)
+    "falcon-full": dict(kernel="tiled", rows=64, layers=4, kvh=4, hd=128,
+                        nh=20, ctx=(1024, 1536), window=0, ring=0),
+    "lfm2-full": dict(kernel="tiled", rows=256, layers=3, kvh=8, hd=64,
+                      nh=32, ctx=(512, 1024), window=0, ring=0),
+    "nemotron-full": dict(kernel="tiled", rows=128, layers=2, kvh=2, hd=128,
+                          nh=32, ctx=(256, 640), window=0, ring=0),
     # the token tile's launch of a chunk step of the two 8k cells: ``new``
     # tokens a row that end at each of ``ends`` (a row of the output each),
     # over the cell's table of 544 pages or the window's ring
@@ -543,11 +568,14 @@ def build_state(shape, rng, rehearse):
     return fn, again, x, (leaf,), L, 2 * rows * leaf[0, 0].nbytes, ref
 
 
-def build(shape, rng, rehearse):
+def build(shape, rng, rehearse, pool="shuffled", runs="gate"):
     """One launch: ``(fn(q, layer, *pools) -> out, again(q, out) -> the
     next launch's q, q, the pools (arguments of the jitted program: a
     closed-over pool would be a constant of gigabytes in it), layers,
-    bytes a launch, reference(q, layer, *pools))``."""
+    bytes a launch, reference(q, layer, *pools))``. ``pool``: how the
+    tables lie (``together``: a row's blocks ascending); ``runs``: whether
+    a tiled launch is handed its table's runs (``fn.runs`` says whether it
+    was, ``fn.pages_a_descriptor`` what the host's counter reads)."""
     rows, lo, hi = shape["rows"], *shape["ctx"]
     if rehearse:
         rows, lo, hi = 4, max(lo // 16, 20), max(hi // 16, 40)
@@ -559,10 +587,21 @@ def build(shape, rng, rehearse):
     held = -(-lens // BS)                                   # pages a row
     MB = ring or int(-(-hi // BS))
     nb = 1 + rows * MB
-    tables = np.zeros((rows, MB), np.int32)
-    tables[:] = rng.permutation(np.arange(1, nb)).reshape(rows, MB)
+    blocks = np.arange(1, nb)
+    if pool.startswith("steps-"):
+        # a row's blocks in runs of N pages, the rows' runs interleaved:
+        # what chunk steps that feed every row N pages each lay down
+        n = int(pool.split("-")[1])
+        host_tables = np.zeros((rows, MB), np.int32)
+        for at in range(0, MB, n):
+            width = min(n, MB - at)
+            host_tables[:, at:at + width] = 1 + at * rows + (
+                np.arange(rows)[:, None] * width + np.arange(width))
+    else:
+        host_tables = (rng.permutation(blocks) if pool == "shuffled"
+                       else blocks).reshape(rows, MB).astype(np.int32)
     lengths = jnp.asarray(lens, jnp.int32)
-    tables = jnp.asarray(tables)
+    tables = jnp.asarray(host_tables)
     row_ids = jnp.arange(rows, dtype=jnp.int32)
     key = jax.random.PRNGKey(int(rng.integers(1 << 30)))
     dtype = jnp.float32 if rehearse else jnp.bfloat16
@@ -594,10 +633,26 @@ def build(shape, rng, rehearse):
         q = jax.random.normal(jax.random.fold_in(key, 2), (rows, nh, hd),
                               dtype)
 
+        # how the tables lie, made once as the serving programs make it
+        # (a tree before PR 67 has no such thing, and is handed none)
+        lie = None
+        if hasattr(ra, "table_runs") and runs != "never":
+            lie = ra.launch_runs(tables, k, hd) if runs == "gate" \
+                else ra.table_runs(tables, ra._chunk_pages(MB, nb, BS))
+        kw = {} if lie is None else dict(runs=lie)
+
         def fn(q, layer, k, v):
             return ra.ragged_attention(
                 q, k, v, layer, row_ids, lengths, tables, window=window,
-                one_token=True, variant="tiled")
+                one_token=True, variant="tiled", **kw)
+        fn.runs = lie is not None
+        if hasattr(ra, "copy_counts"):
+            first = np.maximum(lens - window, 0) // BS if window \
+                else np.zeros_like(held)
+            pages, descs = ra.copy_counts(
+                host_tables, np.arange(rows), first, held - first,
+                ra._chunk_pages(MB, nb, BS), ring, runs=fn.runs)
+            fn.pages_a_descriptor = round(pages / max(descs, 1), 2)
 
         def ref(q, layer, k, v):
             return ra.ragged_attention_reference(
@@ -911,6 +966,16 @@ def main():
                     "prompt shapes' contexts to run (all of a shape's)")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--rehearse", action="store_true")
+    ap.add_argument("--pool", default="shuffled", help="how the decode "
+                    "launches' tables lie: shuffled (no two neighbours "
+                    "together), together (a row's blocks ascending) or "
+                    "steps-N (runs of N pages, the rows' interleaved)")
+    ap.add_argument("--run-pages", type=int, default=0, help="read the "
+                    "least run as this many pages (ragged_attention."
+                    "_RUN_PAGES; 0: the tree's)")
+    ap.add_argument("--runs", choices=("gate", "always", "never"),
+                    default="gate", help="hand a tiled decode launch its "
+                    "table's runs where runs_serve says so, always, never")
     args = ap.parse_args()
     if args.rehearse:
         print("REHEARSAL (cpu): toy sizes under the interpreter, no time "
@@ -919,6 +984,8 @@ def main():
     elif jax.default_backend() != "tpu":
         sys.exit(f"needs a TPU, found {jax.default_backend()!r} "
                  "(--rehearse runs toy sizes on the CPU)")
+    if args.run_pages:
+        ra._RUN_PAGES = args.run_pages
     names = [m for n in args.only.split(",") if n
              for m in FAMILIES.get(n, [n])] or list(SHAPES)
     wanted = [int(c) for c in args.contexts.split(",") if c]
@@ -961,8 +1028,14 @@ def main():
             launches = max(2, args.launches // 10)
         else:
             fn, again, q, pools, L, nbytes, ref = builder(
-                SHAPES[name], rng, args.rehearse)
+                SHAPES[name], rng, args.rehearse, **(
+                    dict(pool=args.pool, runs=args.runs)
+                    if builder is build else {}))
             row, launches = {"shape": name}, args.launches
+            if builder is build:
+                row.update(pool=args.pool, **{
+                    k: getattr(fn, k) for k in ("runs", "pages_a_descriptor")
+                    if hasattr(fn, k)})
             if kernel == "dispatch":
                 row.update(rows=fn.rows, copy_starts=fn.held)
         row.update({"bytes": nbytes,

@@ -32,8 +32,12 @@ def test_blocked_allocator():
     assert alloc.free_blocks == 7  # block 0 reserved
     a = alloc.allocate(3)
     assert len(set(a)) == 3 and NULL_BLOCK not in a
-    alloc.free(a)
+    alloc.free(a[::-1])
     assert alloc.free_blocks == 7
+    # whatever order they came back in, the lowest go out first, ascending
+    # (tests/unit/inference/test_blocks_in_order.py holds the property)
+    assert list(alloc.allocate(4)) == [1, 2, 3, 4]
+    alloc.free([2, 4, 1, 3])
     with pytest.raises(RuntimeError, match="exhausted"):
         alloc.allocate(8)
     with pytest.raises(ValueError):

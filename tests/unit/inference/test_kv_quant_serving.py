@@ -327,10 +327,12 @@ def test_quant_kernel_actually_runs_not_the_fallback(tiny, monkeypatch):
     seen = {}
     orig = rk.ragged_attention
 
-    def spy(q, kc, vc, layer, rows, lens, bt, k_scale=None, v_scale=None):
+    def spy(q, kc, vc, layer, rows, lens, bt, k_scale=None, v_scale=None,
+            runs=None):
         seen["called"] = True
         seen["scales"] = k_scale is not None
         seen["whole"] = kc.shape == eng.kv_cache["k"].shape
+        assert runs is None     # off the TPU a launch is handed no runs
         return orig(q, kc, vc, layer, rows, lens, bt, k_scale=k_scale,
                     v_scale=v_scale)
 

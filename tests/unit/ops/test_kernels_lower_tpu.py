@@ -1020,6 +1020,63 @@ def test_the_patterns_decode_window_compiles_with_both_pools_in_place(
     # neither pool is relaid for it
     assert compiled.as_text().count("bf16[16,4,8,128]") >= 3
     _no_relayout_of(compiled.as_text(), 8721, 3089)
+    # PR 67: how the two tables lie is made ONCE a window, ahead of the
+    # loop of steps and of every layer
+    assert _table_runs_made(compiled.as_text()) == 2
+
+
+def _table_runs_made(text):
+    """How many tables' runs the compiled program makes
+    (``paged_model._table_runs``, scope ``table_runs``): each instruction
+    of it stands outside every layer's scope, every ``attention`` /
+    ``attn_proj`` word and the decode window's loop, and the count is of
+    the selects that close ``ragged_attention.table_runs``, one a
+    table."""
+    made = re.findall(
+        r"^\s*(?:ROOT )?%([\w.\-]+) = ([^\n]*?) ([\w\-]+)\([^\n]*"
+        r'op_name="([^"]*table_runs[^"]*)"', text, re.M)
+    assert made, "no instruction under the scope table_runs"
+    for _, _, _, scope in made:
+        assert not re.search(
+            r"layers|attention|attn_|qkv_proj|out_proj|while|body|scan",
+            scope), scope
+    # a table's runs end in ONE select (``where(link, run, -stretch)``),
+    # which the compiler leaves a fusion of its own
+    return sum(op == "fusion" and scope.endswith("select_n")
+               for _, _, op, scope in made)
+
+
+def test_the_patterns_ragged_step_makes_its_tables_runs_once(tpu_sharding):
+    """The ragged step of the per-head pattern at published widths (its
+    first three layers, 2,048 tokens of 16 rows): the two tables' runs
+    are made once each, under the scope ``table_runs`` and no layer's
+    (``prefill_attn_proj_ms.gen`` reads what it read), and a model whose
+    pages are 64 KB (OPT-1.3B) makes none: its programs are the
+    parent's."""
+    from deepspeed_tpu.inference.v2.paged_model import paged_ragged_step
+
+    cfg, params, cache = _window_cut(tpu_sharding)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=tpu_sharding)
+
+    T, R = 2048, 16
+    text = jax.jit(
+        lambda p, ids, rows, pos, ln, wb, wo, bt, li, c, wt:
+        paged_ragged_step(cfg, p, ids, rows, pos, ln, wb, wo, bt, li, c, 16,
+                          use_kernel=True, window_tables=wt),
+        donate_argnums=(9,)).lower(
+        params, i32(T), i32(T), i32(T), i32(T), i32(T), i32(T), i32(R, 544),
+        i32(R), cache, i32(R, 193)).compile().as_text()
+    assert _table_runs_made(text) == 2
+    cfg, params, cache, i32 = _serving_case(tpu_sharding, False)
+    text = jax.jit(
+        lambda p, ids, rows, pos, ln, wb, wo, bt, li, c: paged_ragged_step(
+            cfg, p, ids, rows, pos, ln, wb, wo, bt, li, c, 16,
+            use_kernel=True)).lower(
+        params, i32(64), i32(64), i32(64), i32(64), i32(64), i32(64),
+        i32(8, 16), i32(8), cache).compile().as_text()
+    assert "table_runs" not in text
 
 
 # ---------------------------------------------------------------------------
