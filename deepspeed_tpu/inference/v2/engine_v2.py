@@ -268,7 +268,7 @@ class InferenceEngineV2:
         bs = sm.block_size
         self.max_row_chunk = None
         ring = 0
-        if "window" in cfg.layer_kinds:
+        if {"window", "mla_window"} & set(cfg.layer_kinds):
             self.max_row_chunk = max(
                 sm.max_ragged_batch_size // sm.max_tracked_sequences
                 // bs, 1) * bs
@@ -372,7 +372,7 @@ class InferenceEngineV2:
             else "pallas:" + (LATENT if cfg.attention == "mla" else
                               kernel_variant(cfg.head_dim, cfg.kv_heads,
                                              bool(config.kv_quant)))
-            + "+window" * self._has_ring
+            + "+window" * self._has_ring + "+indexed" * bool(cfg.index_topk)
             if use_kernel else "jnp:gather")
         topo = self.topology if ep > 1 else None
         # load_draft_model builds jits after __init__; it reuses the
@@ -544,6 +544,7 @@ class InferenceEngineV2:
                  if k in STATE_LEAVES}))
             self._m_ssm_groups.set(
                 cfg.mamba_n_groups if cfg.leaf_places("ssm") else 0)
+            # (the indexer's keys lie on the full tables: "full")
             for kind in ("full", "window"):
                 self._m_pool_bytes.labels(kind=kind).set(
                     ds_memory.tree_bytes({
@@ -568,6 +569,7 @@ class InferenceEngineV2:
         construction rather than run wrong."""
         who, refused = (InferenceEngineV2._pattern_refusals(config, cfg)
                         if cfg.layer_types is not None
+                        and cfg.attention != "mla"
                         else InferenceEngineV2._latent_refusals(config, cfg))
         bad = [what for what, on in refused.items() if on]
         if bad:
@@ -617,6 +619,8 @@ class InferenceEngineV2:
     def _latent_refusals(config, cfg):
         sm = config.state_manager
         state = cfg.has_state
+        if cfg.layer_types is not None:
+            return InferenceEngineV2._latent_pattern_refusals(config, cfg)
         return "attention='mla'" + (
             " with linear-attention layers" if state else ""), {
             "tensor_parallel_size > 1 (the latent projections and the "
@@ -638,6 +642,39 @@ class InferenceEngineV2:
                 sm.enable_kv_spill,
             "kv_quant (the int8 latent pool has not been served beside "
             "state leaves)": state and config.kv_quant}
+
+    @staticmethod
+    def _latent_pattern_refusals(config, cfg):
+        """A ``layer_types`` pattern over latent attention: two latent
+        kinds, the second's rows a ring, and (``cfg.index_topk``) the
+        indexer's keys beside the first's."""
+        sm = config.state_manager
+        picks = bool(cfg.index_topk)
+        return ("a layer_types pattern over latent attention (full "
+                "latent layers" + (" that read the positions an indexer "
+                                   "picks" if picks else "")
+                + " beside latent layers over a ring)"), {
+            "tensor_parallel_size > 1 (the two kinds' projections, the "
+            "indexer and the selected read are written for one device; "
+            "the index keys and the ring are not sharded)":
+                config.tensor_parallel_size > 1,
+            "expert_parallel_size > 1 (the expert layer as deployed is "
+            "served at ep = 1)": config.expert_parallel_size > 1,
+            "quant_bits (the quantiser does not know a stack a latent "
+            "kind, and the expert stack is read whole)":
+                bool(config.quant_bits),
+            "max_lora_adapters (LoRA targets wq / wv, which the latent "
+            "projections replace)": config.max_lora_adapters > 0,
+            "enable_prefix_caching (a shared block holds the full "
+            "layers' rows" + (" and index keys" if picks else "")
+            + " only: a row that skipped a prefix would find its ring "
+            "empty)": sm.enable_prefix_caching,
+            "enable_kv_spill (the spill tier moves the blocks of one "
+            "geometry: no ring" + (" and no index key" if picks else "")
+            + ")": sm.enable_kv_spill,
+            "kv_quant (an int8 latent pool has no form beside a ring"
+            + (" or under a selection: a selected row is gathered as it "
+               "is stored" if picks else "") + ")": config.kv_quant}
 
     # ------------------------------------------------------------------
     # Telemetry (unified registry, telemetry/registry.py)
@@ -812,6 +849,30 @@ class InferenceEngineV2:
             "row and step, from the contexts the manager holds at the "
             "launch; 0 wherever inference_attention_one_token_steps_total "
             "is", labelnames=("kind",))
+        self._m_index_queries = reg.counter(
+            "inference_index_queries_total",
+            "query tokens of the full latent layers of a model whose "
+            "indexer picks what they read (index_topk): one a token and "
+            "full layer, by program", labelnames=("program",))
+        self._m_index_attended = reg.counter(
+            "inference_index_positions_attended_total",
+            "cached positions those queries ATTENDED: min(the token's "
+            "bound, index_topk) a query, whatever read them",
+            labelnames=("program",))
+        self._m_index_read = reg.counter(
+            "inference_index_positions_read_total",
+            "cached positions whose rows those queries' attention READ: "
+            "what it attended in a decode step (a token gathers the rows "
+            "it picked), the token's whole bound in a prompt's launch "
+            "(the row's pages once, the picks a mask) and under tables "
+            "of no more than index_topk positions (the dense launch); "
+            "over inference_index_queries_total the mean positions read "
+            "a query", labelnames=("program",))
+        self._m_index_scored = reg.counter(
+            "inference_index_positions_scored_total",
+            "cached positions the indexer scored for those queries (a "
+            "token's bound, under the launches that select; 0 under the "
+            "dense ones)", labelnames=("program",))
         self._m_prompt_chunks = reg.counter(
             "inference_attention_prompt_chunks_total",
             "chunk visits of the token tile's launches (a ragged step's "
@@ -1789,6 +1850,10 @@ class InferenceEngineV2:
         collected), over ``tables`` (the launch's, row i's at i): what
         the positions and the copies under the attention launches are
         counted from."""
+        if self.model.cfg.index_topk:
+            self._note_index_reads(
+                "decode", self._decode_bounds(uids, steps_left, in_flight)[0],
+                tables.shape[1])
         if not self._use_kernel:
             return
         cache = self.kv_cache
@@ -1806,19 +1871,55 @@ class InferenceEngineV2:
             self._m_one_token_steps.inc(steps)
             self._note_decode_positions(uids, steps_left, tables, in_flight)
 
-    def _note_decode_positions(self, uids, steps_left, tables, in_flight):
-        """The positions under the one-token form's launches of these
-        rows and steps, layer kind by layer kind
-        (``kernels/ragged_attention.decode_positions``), and the copies
-        that bring them (:meth:`_note_copies`)."""
+    def _decode_bounds(self, uids, steps_left, in_flight):
+        """``(bounds, taken)`` of the decode steps rows ``uids`` take:
+        a row's bound at a step (the token it feeds, itself included)
+        for every (row, step) it takes, ``taken`` [rows, steps] which
+        those are; from the position the manager holds for the row plus
+        its ``in_flight`` writes."""
         sm = self.state_manager
         start = np.asarray([sm.seqs[u].seen_tokens for u in uids], np.int64)
         if in_flight is not None:
             start = start + np.asarray(in_flight, np.int64)
         step = np.arange(max(steps_left, default=0))[None, :]
-        # a row's bound at a step: the token it feeds, itself included
         taken = step < np.asarray(steps_left)[:, None]
-        contexts = (start[:, None] + 1 + step)[taken]
+        return (start[:, None] + 1 + step)[taken], taken
+
+    def _note_index_reads(self, program: str, bounds, table_pages: int):
+        """What the full latent layers of a model with an indexer did
+        under a launch of ``program`` whose query tokens' causal bounds
+        are ``bounds`` (a whole number a token: its own position
+        included) over tables of ``table_pages`` places: a query a token
+        and full layer; the positions it ATTENDED, ``min(bound,
+        index_topk)``; the positions whose rows its attention READ: as
+        many in a decode step (its tokens gather what they picked), the
+        whole bound in a prompt's launch (the row's pages once, the
+        picks a mask) and under tables of no more than ``index_topk``
+        positions (the dense launch); and the positions the indexer
+        scored for it, its bound where the launch selected at all. Host
+        arithmetic on what the manager holds."""
+        cfg = self.model.cfg
+        bounds = np.asarray(bounds, np.int64)
+        layers = cfg.layer_kinds.count("mla")
+        picked = table_pages * self.block_size > cfg.index_topk
+        attended = int(np.minimum(bounds, cfg.index_topk).sum())
+        self._m_index_queries.labels(program=program).inc(
+            layers * bounds.size)
+        self._m_index_attended.labels(program=program).inc(
+            layers * attended)
+        self._m_index_read.labels(program=program).inc(layers * (
+            attended if picked and program == "decode"
+            else int(bounds.sum())))
+        if picked:
+            self._m_index_scored.labels(program=program).inc(
+                layers * int(bounds.sum()))
+
+    def _note_decode_positions(self, uids, steps_left, tables, in_flight):
+        """The positions under the one-token form's launches of these
+        rows and steps, layer kind by layer kind
+        (``kernels/ragged_attention.decode_positions``), and the copies
+        that bring them (:meth:`_note_copies`)."""
+        contexts, taken = self._decode_bounds(uids, steps_left, in_flight)
         held, chunked = self._over_attention_layers(
             decode_positions, tables.shape[1], contexts)
         self._m_decode_positions.labels(kind="held").inc(held)
@@ -1863,10 +1964,17 @@ class InferenceEngineV2:
         places of the pool, the window ones over their rings."""
         sm, cfg = self.state_manager, self.model.cfg
         kinds = cfg.layer_kinds
-        rings = kinds.count("window")
+        ringed = ("window", "mla_window")
+        rings = sum(k in ringed for k in kinds)
+        # (a full latent layer whose indexer picks what it reads walks
+        # no chunk of a table that holds more than it picks: its reads
+        # are counted by ``_note_index_reads``)
+        picked = cfg.index_topk and \
+            table_pages * sm.block_size > cfg.index_topk
         a, b = np.asarray(count(
             *rows, sm.block_size, table_pages, sm.config.num_blocks, **kw)) \
-            * sum(k in PAGED_KINDS and k != "window" for k in kinds)
+            * sum(k in PAGED_KINDS and k not in ringed
+                  and not (picked and k == "mla") for k in kinds)
         if rings:
             ring = count(
                 *rows, sm.block_size, sm.ring_blocks,
@@ -2216,6 +2324,11 @@ class InferenceEngineV2:
             if behind is not None:
                 self._ragged_ended(behind)
             self._note_prompt_chunks(entries, rb)
+            if self.model.cfg.index_topk:
+                seqs = self.state_manager.seqs
+                self._note_index_reads("ragged_step", np.concatenate([
+                    seqs[uid].seen_tokens + 1 + np.arange(len(toks))
+                    for uid, toks in entries]), rb.block_tables.shape[1])
             self._note_state_rows("ragged_step", len(entries),
                                   rb.total_tokens)
             if self._has_state:
@@ -2499,8 +2612,15 @@ class InferenceEngineV2:
         order; the oldest block is the one the next write lands on). An
         int8 pool comes back dequantised. The read half of a snapshot,
         beside ``sequence_state``."""
-        if self.model.cfg.layer_types is None or \
-                "k_" + kind not in self.kv_cache:
+        latent = self.model.cfg.attention == "mla"
+        # a pattern over LATENT attention: a full layer's rows and, with
+        # an indexer, its index keys ({"latent", "index_k"}); the window
+        # layers' ring of rows ({"latent_window"}), by their leaves' names
+        leaves = [n for n in ({"full": ("latent", "index_k"),
+                               "window": ("latent_window",)}[kind]
+                              if latent else ("k_" + kind, "v_" + kind))
+                  if n in self.kv_cache]
+        if self.model.cfg.layer_types is None or not leaves:
             raise ValueError(f"sequence_kv: this model keeps no {kind!r} "
                              f"leaves (no layer_types pattern with such "
                              f"layers)")
@@ -2519,9 +2639,10 @@ class InferenceEngineV2:
         pos = np.arange(first, n)
         place = pos % ring
         out = {"positions": pos}
-        for name in ("k", "v"):
+        for leaf_name in leaves:
+            name = leaf_name if latent else leaf_name[0]
             leaf = np.asarray(
-                self.kv_cache[f"{name}_{kind}"][:, blocks]
+                self.kv_cache[leaf_name][:, blocks]
             ).astype(np.float32)              # [L, blocks, bs, F]
             scales = self.kv_cache.get(f"{name}s_{kind}")
             if scales is not None:

@@ -1557,9 +1557,8 @@ def _latent_update(q, kv, visible, acc_sc, m_sc, l_sc, *, dc, scale):
 
 
 def _latent_kernel(layer_ref, len_ref, bt_ref, first_ref, last_ref, lo_ref,
-                   hi_ref, q_ref, tl_ref, pool_hbm, o_ref, buf, acc_sc,
-                   m_sc, l_sc, sem, *, bs, scale, dc, tq, cp,
-                   one_token=False):
+                   hi_ref, q_ref, tl_ref, pool_hbm, *rest, bs, scale, dc,
+                   tq, cp, one_token=False, window=0, ring=0, picked=False):
     """Grid (T / tq,), the tiled variant's walk (:func:`_walk_rows`)
     over a latent pool ``[L, nb, bs, W]`` left whole in HBM, the layer a
     prefetched scalar. All ``nh`` heads of the tile's tokens, ``(nh *
@@ -1575,7 +1574,22 @@ def _latent_kernel(layer_ref, len_ref, bt_ref, first_ref, last_ref, lo_ref,
     queries lie token-major ``(tq, nh, W)``, a chunk meets the walked
     row's ``nh`` query rows alone, the float32 state is one row's and
     goes to the row's place in the output tile ``(tq, nh, dc)`` behind
-    its last chunk."""
+    its last chunk.
+
+    ``window`` / ``ring`` (static): a token sees its last ``window``
+    positions and the row's table is a ring of ``ring`` places, as the
+    tiled kernel's (:func:`_walk_rows`).
+
+    ``picked`` (static; the token tile's form only): one more operand
+    left in HBM, ``[T, positions]`` flags (> 0: the token PICKED the
+    position; every head reads the same), a chunk's ``(tq, P)`` of
+    which rides in beside its pages (``pbuf`` (2, tq, P), ``psem``
+    (2,)) and is laid on the causal mask: a token attends the positions
+    under its bound that it picked and no other."""
+    if picked:
+        picked_hbm, o_ref, buf, acc_sc, m_sc, l_sc, sem, pbuf, psem = rest
+    else:
+        o_ref, buf, acc_sc, m_sc, l_sc, sem = rest
     if one_token:
         nh, W = q_ref.shape[1:]
     else:
@@ -1606,11 +1620,24 @@ def _latent_kernel(layer_ref, len_ref, bt_ref, first_ref, last_ref, lo_ref,
                                       buf.at[slot, first], sem.at[slot]),)
 
     def compute(first, last, c, slot, base):
-        visible = _row_visible(len_ref[first], c, nh, P) if one_token \
-            else _visible(tl_ref, t0, first, last, c, tq, nh, P)
+        visible = _row_visible(len_ref[first], c, nh, P, base, window) \
+            if one_token \
+            else _visible(tl_ref, t0, first, last, c, tq, nh, P, base, window)
+        if picked:
+            # the tile's query rows are ``nh`` copies of its tokens
+            # (compared in float32: the chip compares no bf16)
+            visible = visible & (jnp.concatenate(
+                [pbuf[slot].astype(jnp.float32)] * nh, axis=0) > 0)
         q = q_ref[first - t0] if one_token else q_ref[...].reshape(M, W)
         _latent_update(q, buf[slot].reshape(P, W), visible, acc_sc, m_sc,
                        l_sc, dc=dc, scale=scale)
+
+    def picked_copies(r, c, slot):
+        """The tile's tokens' flags for chunk c's positions"""
+        return (pltpu.make_async_copy(
+            picked_hbm.at[pl.ds(pl.multiple_of(t0, tq), tq),
+                          pl.ds(pl.multiple_of(c * P, P), P)],
+            pbuf.at[slot], psem.at[slot]),)
 
     def finish(first):
         l = l_sc[:, :1]
@@ -1619,6 +1646,8 @@ def _latent_kernel(layer_ref, len_ref, bt_ref, first_ref, last_ref, lo_ref,
 
     _walk_rows(len_ref, bt_ref, first_ref, last_ref, lo, hi, t0=t0, tq=tq,
                bs=bs, cp=cp, copies=copies, waits=waits, compute=compute,
+               **(dict(window=window, ring=ring) if window else {}),
+               **(dict(chunk_copies=picked_copies) if picked else {}),
                **_row_hooks(one_token, finish, acc_sc, m_sc, l_sc))
     if one_token:
         return
@@ -1629,16 +1658,30 @@ def _latent_kernel(layer_ref, len_ref, bt_ref, first_ref, last_ref, lo_ref,
 
 
 def latent_attention_reference(q, pool, layer, row_ids, lengths,
-                               block_tables, *, dc: int, scale: float):
+                               block_tables, *, dc: int, scale: float,
+                               window: int = 0, picked=None):
     """:func:`latent_attention` by gathering: each row's pages once,
     every token against its row's positions under its own bound, the
     softmax in float32. The ``jnp:gather`` path of a latent pool and the
-    kernel's parity reference."""
+    kernel's parity reference. ``window``: a token sees its last
+    ``window`` positions and the table is the row's ring, place i the
+    newest position p under the bound with p = i mod the ring
+    (:func:`ragged_attention_reference`). ``picked`` ``[T, MB * bs]``
+    (> 0: the token picked the position): a token attends what it
+    picked of what its bound shows."""
     R, MB = block_tables.shape
     bs, W = pool.shape[2:]
     rows = pool[layer][block_tables].reshape(R, MB * bs, W)[row_ids]
     s = jnp.einsum("htw,tcw->htc", q, rows).astype(jnp.float32) * scale
-    seen = jnp.arange(MB * bs)[None, :] < lengths[:, None]       # [T, ctx]
+    if window:
+        ctx = MB * bs
+        newest = lengths[:, None] - 1
+        pos = newest - (newest - jnp.arange(ctx)[None, :]) % ctx
+        seen = (pos >= 0) & (pos >= lengths[:, None] - window)
+    else:
+        seen = jnp.arange(MB * bs)[None, :] < lengths[:, None]   # [T, ctx]
+    if picked is not None:      # [T, ctx]: what each token picked
+        seen = seen & (picked > 0)
     p = jax.nn.softmax(jnp.where(seen[None], s, NEG_INF), axis=-1)
     p = jnp.where(lengths[None, :, None] > 0, p, 0.0)   # padding: zeros
     return jnp.einsum("htc,tcd->htd", p.astype(q.dtype), rows[..., :dc])
@@ -1647,7 +1690,8 @@ def latent_attention_reference(q, pool, layer, row_ids, lengths,
 def latent_attention(q, pool, layer, row_ids, lengths, block_tables, *,
                      dc: int, scale: float,
                      interpret: Optional[bool] = None,
-                     one_token: bool = False):
+                     one_token: bool = False, window: int = 0,
+                     picked=None):
     """Ragged paged attention over a LATENT pool (attention='mla', the
     absorbed form): every head's query attends ONE cached row a
     position, whose first ``dc`` lanes are also the values.
@@ -1664,16 +1708,32 @@ def latent_attention(q, pool, layer, row_ids, lengths, block_tables, *,
 
     ``one_token`` (static): the caller's word that every row has
     exactly one token (a decode batch); the kernel then takes its
-    one-token form, under the same name."""
+    one-token form, under the same name.
+
+    ``window`` > 0 (static): a token sees its last ``window`` positions
+    and ``block_tables`` is each row's RING in a pool of its own
+    (``ragged_attention_latent_window`` in a trace), as
+    :func:`ragged_attention` takes a window over per-head pools.
+
+    ``picked`` ``[T, MB * bs]`` (any dtype; > 0: the token PICKED the
+    position): a token attends only what it picked of the positions
+    under its bound, every head the same (a full latent layer whose
+    indexer selects; ``ragged_attention_latent_picked`` in a trace). The
+    token tile's form only."""
     nh, T0, W = q.shape
     if interpret is None and _interpret():
-        return latent_attention_reference(q, pool, layer, row_ids, lengths,
-                                          block_tables, dc=dc, scale=scale)
+        return latent_attention_reference(
+            q, pool, layer, row_ids, lengths, block_tables, dc=dc,
+            scale=scale, **({"window": window} if window else {}),
+            **({} if picked is None else {"picked": picked}))
+    assert picked is None or not (one_token or window), \
+        "picked positions ride the token tile's form, without a window"
     row_ids = row_ids.astype(jnp.int32)
     lengths = lengths.astype(jnp.int32)
     block_tables = block_tables.astype(jnp.int32)
     bs = pool.shape[2]
     R, MB = block_tables.shape
+    ring = MB             # the table's places, before the padding below
     # a tile: a power of two of 16 to 128 tokens, 512 query rows where
     # that leaves 16 (one_token: the rows a grid step walks)
     tq = _ONE_TOKEN_ROWS if one_token else max(16, min(
@@ -1686,6 +1746,18 @@ def latent_attention(q, pool, layer, row_ids, lengths, block_tables, *,
         q = jnp.pad(q, ((0, 0), (0, T - T0), (0, 0)))
         row_ids = jnp.pad(row_ids, (0, T - T0))
         lengths = jnp.pad(lengths, (0, T - T0))
+    extra_in, extra_scratch, extra_args = [], [], []
+    if picked is not None:
+        # flags a token and position of the (padded) table, in the
+        # pool's type: a chunk's (tq, P) of them is whole tiles
+        P = cp * bs
+        picked = (picked > 0).astype(pool.dtype)
+        picked = jnp.pad(picked, ((0, T - T0), (
+            0, block_tables.shape[1] * bs - picked.shape[1])))
+        extra_in = [pl.BlockSpec(memory_space=pl.ANY)]
+        extra_scratch = [pltpu.VMEM((2, tq, P), pool.dtype),
+                         pltpu.SemaphoreType.DMA((2,))]
+        extra_args = [picked]
     row_first, row_last, tile_lo, tile_hi = _row_descriptors(
         row_ids, lengths, R, tq)
     if one_token:
@@ -1704,28 +1776,34 @@ def latent_attention(q, pool, layer, row_ids, lengths, block_tables, *,
             return (0, i, 0)
     out = pl.pallas_call(
         functools.partial(_latent_kernel, bs=bs, scale=scale, dc=dc, tq=tq,
-                          cp=cp, one_token=one_token),
+                          cp=cp, one_token=one_token,
+                          **(dict(window=window, ring=ring) if window
+                             else {}),
+                          **(dict(picked=True) if extra_args else {})),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=7,
             grid=(T // tq,),
             in_specs=[pl.BlockSpec(q_block, tile),
                       pl.BlockSpec((tq, 1), lambda i, *_: (i, 0)),
-                      pl.BlockSpec(memory_space=pl.ANY)],
+                      pl.BlockSpec(memory_space=pl.ANY)] + extra_in,
             out_specs=pl.BlockSpec(o_block, tile),
             scratch_shapes=[pltpu.VMEM((2, cp, bs, W), pool.dtype),
                             pltpu.VMEM((M, dc), jnp.float32),
                             pltpu.VMEM((M, 128), jnp.float32),
                             pltpu.VMEM((M, 128), jnp.float32),
-                            pltpu.SemaphoreType.DMA((2,))]),
+                            pltpu.SemaphoreType.DMA((2,))]
+            + extra_scratch),
         out_shape=jax.ShapeDtypeStruct(out_shape, q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=_TILED_VMEM_BYTES),
         interpret=pltpu.InterpretParams() if interpret or _interpret()
         else False,
-        name="ragged_attention_latent",
+        name="ragged_attention_latent" + "_window" * bool(window)
+        + "_picked" * bool(extra_args),
     )(jnp.asarray(layer, jnp.int32).reshape(1), lengths, block_tables,
-      row_first, row_last, tile_lo, tile_hi, q, lengths.reshape(T, 1), pool)
+      row_first, row_last, tile_lo, tile_hi, q, lengths.reshape(T, 1), pool,
+      *extra_args)
     if one_token:
         return out[:T0, :nh].transpose(1, 0, 2)
     return out[:, :T0]

@@ -97,7 +97,8 @@ def init_paged_kv_cache(cfg: TransformerConfig, num_blocks: int,
         "encoder family does not decode)"
     if cfg.attention == "mla":
         return _init_latent_cache(cfg, num_blocks, block_size, dtype,
-                                  kv_quant, state_slots, state_dtype)
+                                  kv_quant, state_slots, state_dtype,
+                                  window_blocks)
 
     def leaves(layers, blocks, tag=""):
         shape = (layers, blocks, block_size, cfg.kv_heads * cfg.head_dim)
@@ -188,14 +189,23 @@ def _init_ssm_state(cfg, state_slots, state_dtype):
                 state_dtype)}
 
 
-def latent_pool_row(cfg) -> int:
-    """Lanes of one position of the latent pool: ``cfg.latent_row``
-    rounded up to whole 128-lane blocks, the rest zeros."""
-    return -(-cfg.latent_row // 128) * 128
+def latent_pool_row(cfg, kind: str = "mla") -> int:
+    """Lanes of one position of a latent kind's pool: its row
+    (``cfg.latent_kind``) rounded up to whole 128-lane blocks, the rest
+    zeros."""
+    return -(-cfg.latent_kind(kind).row // 128) * 128
+
+
+# a latent kind's leaf: the full layers' by block table, the window
+# layers' a ring in a pool of their own; and the scope round its layers
+LATENT_LEAVES = {"mla": "latent", "mla_window": "latent_window"}
+LATENT_SCOPES = {"mla": "mla_attention",
+                 "mla_window": "mla_window_attention"}
 
 
 def _init_latent_cache(cfg, num_blocks, block_size, dtype, kv_quant,
-                       state_slots=0, state_dtype=jnp.float32):
+                       state_slots=0, state_dtype=jnp.float32,
+                       window_blocks=0):
     """The pool of an attention='mla' model: ONE leaf, ``latent``
     ``[L, nb, bs, kv_lora_rank + qk_rope_head_dim]``: a cached position
     holds a layer's normed latent and, behind it, the rotated key part
@@ -221,11 +231,31 @@ def _init_latent_cache(cfg, num_blocks, block_size, dtype, kv_quant,
     drifts from the recurrence). Slot 0 is the null slot, as block 0 is
     the null block: padded and masked rows read and write it.
 
+    Under a pattern over latent attention (``cfg.layer_types``) a leaf
+    a latent KIND: ``latent`` holds the full layers' rows, every
+    position of a sequence by its block table; ``latent_window``
+    ``[L_window, window_blocks, bs, its own row]`` the window layers', a
+    pool of its own in which a sequence owns a RING (position p at
+    place ``p % ring`` of the row's own table, as the per-head window
+    leaves: ``init_paged_kv_cache``); and, where an indexer picks what a
+    full layer reads, ``index_k`` ``[L_full, nb, bs, index_head_dim]``:
+    the indexer's ONE key a position, beside the row it may select and
+    on the same tables.
+
     ``kv_quant``: see below."""
     kinds = cfg.layer_kinds
     shape = (kinds.count("mla"), num_blocks, block_size,
              latent_pool_row(cfg))
     state = {}
+    if "mla_window" in kinds:
+        state["latent_window"] = jnp.zeros(
+            (kinds.count("mla_window"), window_blocks, block_size,
+             latent_pool_row(cfg, "mla_window")), dtype)
+    if cfg.index_topk:
+        state["index_k"] = jnp.zeros(
+            (*shape[:3], cfg.index_head_dim), dtype)
+    assert not (kv_quant and state), \
+        "an int8 latent pool has no form beside a ring or an indexer"
     if "kda" in kinds:
         from .kernels.linear_attention import conv_leaf_shape
         n, d = kinds.count("kda"), cfg.linear_head_dim
@@ -358,12 +388,18 @@ def _norm(cfg, x, w, b=None):
     return layer_norm(x, w, b, cfg.norm_eps)
 
 
-def _rope_at(cfg: TransformerConfig, pos: jnp.ndarray):
+def _rope_at(cfg: TransformerConfig, pos: jnp.ndarray, kind=None):
     """cos/sin tables at integer positions `pos` [...]-> [..., half]
-    (half = rotating dims / 2; partial rotary leaves the tail alone)."""
+    (half = rotating dims / 2; partial rotary leaves the tail alone).
+    ``kind``: a latent mixer kind with a theta and a rotating width of
+    its own (``cfg.latent_kind``)."""
     from ...models.transformer import rotary_dims
     half = rotary_dims(cfg) // 2
-    freqs = 1.0 / (cfg.rope_theta
+    theta = cfg.rope_theta
+    if kind is not None:
+        lk = cfg.latent_kind(kind)
+        half, theta = lk.rope // 2, lk.theta
+    freqs = 1.0 / (theta
                    ** (jnp.arange(0, half, dtype=jnp.float32) / half))
     angles = pos.astype(jnp.float32)[..., None] * freqs
     return jnp.cos(angles), jnp.sin(angles)
@@ -800,15 +836,16 @@ def _rotate_pairs(x, cos, sin, interleave):
                            axis=-1).astype(x.dtype)
 
 
-def _latent_write(pool, l, blocks, offs, row):
-    """The new tokens' rows [T, row] into layer ``l`` of the pool
-    (``{"latent"[, "latent_scale"]}``), padded with zeros to the pool's
+def _latent_write(pool, l, blocks, offs, row, leaf="latent"):
+    """The new tokens' rows [T, row] into layer ``l`` of the pool's
+    ``leaf`` (``{"latent"[, "latent_scale"]}``; a pattern's
+    ``latent_window`` and ``index_k``), padded with zeros to the leaf's
     lanes; into an int8 pool against each row's own absmax."""
-    lat = pool["latent"]
+    lat = pool[leaf]
     row = jnp.pad(row, ((0, 0), (0, lat.shape[-1] - row.shape[-1])))
     if "latent_scale" not in pool:
         return {**pool,
-                "latent": lat.at[l, blocks, offs].set(row.astype(lat.dtype))}
+                leaf: lat.at[l, blocks, offs].set(row.astype(lat.dtype))}
     rf = row.astype(jnp.float32)
     scale = jnp.max(jnp.abs(rf), axis=-1) / 127.0
     q = jnp.round(rf / jnp.where(scale > 0, scale, 1.0)[:, None])
@@ -818,22 +855,280 @@ def _latent_write(pool, l, blocks, offs, row):
             .set(scale)}
 
 
-def _latent_rows(pool, l, dtype):
+def _latent_rows(pool, l, dtype, leaf="latent"):
     """(pool, layer) for the attention of layer ``l``: the pool whole
     and ``l``, or, of an int8 pool, that ONE layer dequantised through
     the serving dtype as a pool of one layer (a transient 1 / L of the
     pool at twice its bytes; an in-kernel dequant would save the pass:
     ROADMAP M3)."""
     if "latent_scale" not in pool:
-        return pool["latent"], l
+        return pool[leaf], l
     rows = (pool["latent"][l].astype(jnp.float32)
             * pool["latent_scale"][l][..., None]).astype(dtype)
     return rows[None], jnp.int32(0)
 
 
+# tokens a tile of the indexed read, and cached positions a chunk of a
+# tile's indexer scores: a tile's scores a head are ``[tokens, heads,
+# chunk]`` float32 before the heads are summed (256 x 64 x 4,096: 268 MB
+# where XLA makes them at all), a tile's scores ``[tokens, positions]``
+# (256 x 32,768 float32: 34 MB) and its flags as many bf16; the score
+# matrix of a launch is never whole
+_INDEX_TILE = 256
+_INDEX_CHUNK = 4096
+
+
+def _index_scores(qi, wi, keys):
+    """The indexer's score of every query token against every cached
+    key: ``I[t, s] = sum_j w[t, j] * relu(q[t, j] . k[s])``. qi
+    ``[t, heads, d]``, wi ``[t, heads]`` float32 (the head's weight with
+    both scales in it), keys ``[c, d]`` -> ``[t, c]`` float32, in chunks
+    of ``_INDEX_CHUNK`` keys where there are more."""
+    def chunk(k):
+        s = jnp.einsum("tjd,cd->tjc", qi, k,
+                       preferred_element_type=jnp.float32)
+        return jnp.einsum("tjc,tj->tc", jax.nn.relu(s), wi)
+    c = keys.shape[0]
+    if c <= _INDEX_CHUNK:
+        return chunk(keys)
+    n = -(-c // _INDEX_CHUNK)
+    keys = jnp.pad(keys, ((0, n * _INDEX_CHUNK - c), (0, 0)))
+    out = jax.lax.map(chunk, keys.reshape(n, _INDEX_CHUNK, -1))
+    return out.transpose(1, 0, 2).reshape(qi.shape[0], -1)[:, :c]
+
+
+def _token_scores(qi, wi, keys, l, row_ids, lengths, block_tables,
+                  one_token):
+    """``(scores [t, ctx] float32, seen [t, ctx])``: the indexer's score
+    of each of a tile's query tokens against every position its row's
+    table holds, and which of them lie under the token's causal bound
+    ``lengths``. qi ``[t, heads, d]`` and wi ``[t, heads]`` as
+    :func:`_index_scores` takes them, keys the ``index_k`` leaf whole,
+    ``l`` the layer's place in it. A tile's tokens may belong to several
+    rows (a ragged launch): each row's keys are gathered through its
+    block table and scored against the tile, and a token keeps its own
+    row's scores; ``one_token``: every token is its own row, scored in
+    one batched product."""
+    R, MB = block_tables.shape
+    ctx = MB * keys.shape[2]
+    seen = jnp.arange(ctx)[None, :] < lengths[:, None]
+    with jax.named_scope("indexer"):
+        if one_token:
+            kg = keys[l, block_tables[row_ids]].reshape(len(row_ids), ctx, -1)
+            s = jnp.einsum("tjd,tcd->tjc", qi, kg,
+                           preferred_element_type=jnp.float32)
+            return jnp.einsum("tjc,tj->tc", jax.nn.relu(s), wi), seen
+        live = lengths > 0
+        lo = jnp.min(jnp.where(live, row_ids, R))
+        hi = jnp.max(jnp.where(live, row_ids, -1))
+
+        def row(r, acc):
+            kr = keys[l, block_tables[r]].reshape(ctx, -1)
+            return jnp.where((row_ids == r)[:, None],
+                             _index_scores(qi, wi, kr), acc)
+        return jax.lax.fori_loop(
+            lo, hi + 1, row, jnp.zeros((qi.shape[0], ctx), jnp.float32)), seen
+
+
+def index_select(qi, wi, keys, l, row_ids, lengths, block_tables, topk,
+                 one_token=False):
+    """What the indexer picks for each of a tile's query tokens, as
+    positions: ``(idx [t, topk] int32, ok [t, topk] bool)``, the
+    ``topk`` positions of the token's row with the largest scores
+    (:func:`_token_scores`) among those under its causal bound (ties to
+    the lower position, ``jax.lax.top_k``'s order), and which of them
+    are positions at all: every one under the bound while the bound is
+    no larger than ``topk``, when the rest of ``idx`` is filler. The
+    form a decode step takes: its few tokens GATHER what they picked
+    (:func:`_selected_latent_attention`)."""
+    scores, seen = _token_scores(qi, wi, keys, l, row_ids, lengths,
+                                 block_tables, one_token)
+    with jax.named_scope("index_select"):
+        _, idx = jax.lax.top_k(jnp.where(seen, scores, -jnp.inf), topk)
+        return idx, idx < lengths[:, None]
+
+
+def index_mask(qi, wi, keys, l, row_ids, lengths, block_tables, topk):
+    """:func:`index_select` as FLAGS, ``[t, ctx]`` bool: the same set a
+    token (the ``topk`` largest scores under its bound, ties to the
+    lower position; every position under the bound while it is no
+    larger than ``topk``), found without sorting: the ``topk``-th
+    largest score a token by 32 counting passes over the scores' bits
+    (an order-keeping map of float32 to uint32), then every position
+    over it and, of the positions AT it, the first ones in position
+    order that fill the set. The form a prompt's launch takes: its many
+    tokens read their row's pages once and mask
+    (``latent_attention(picked=)``)."""
+    scores, seen = _token_scores(qi, wi, keys, l, row_ids, lengths,
+                                 block_tables, False)
+    with jax.named_scope("index_select"):
+        u = jax.lax.bitcast_convert_type(scores, jnp.uint32)
+        u = jnp.where(u >> 31 == 1, ~u, u | jnp.uint32(1 << 31))
+        u = jnp.where(seen, u, jnp.uint32(0))   # under every seen score
+
+        def bit(i, kth):
+            cand = kth | (jnp.uint32(1 << 31) >> i.astype(jnp.uint32))
+            enough = jnp.sum(u >= cand[:, None], axis=-1,
+                             dtype=jnp.int32) >= topk
+            return jnp.where(enough, cand, kth)
+        kth = jax.lax.fori_loop(
+            0, 32, bit, jnp.zeros((u.shape[0],), jnp.uint32))[:, None]
+        over = u > kth
+        at = (u == kth) & seen
+        room = topk - jnp.sum(over, axis=-1, dtype=jnp.int32)
+        return over | (at & (_running_count(at) <= room[:, None]))
+
+
+def _running_count(flags):
+    """``cumsum(flags, axis=-1)`` of bool ``[t, c]`` as int32, made of
+    two products with a triangle of ones (128 positions a block, then
+    the blocks): exact (0 / 1 in bf16, sums in float32, under 2 ** 24),
+    a few percent of ``jnp.cumsum``'s time on the chip, and traced under
+    the caller's scope (``cumsum``'s own lowering drops it, and its time
+    fell under no scope of a trace: PERF.md section 6, PR 68)."""
+    t, c = flags.shape
+    n = -(-c // 128)
+    x = jnp.pad(flags, ((0, 0), (0, n * 128 - c))).astype(jnp.bfloat16)
+    x = x.reshape(t, n, 128)
+
+    def upto(k):        # [k, k]: 1 where row <= column
+        return (jnp.arange(k)[:, None] <= jnp.arange(k)[None, :])
+
+    inside = jnp.einsum("tbk,kj->tbj", x, upto(128).astype(jnp.bfloat16),
+                        preferred_element_type=jnp.float32)
+    blocks = inside[..., -1]                        # a block's own count
+    ahead = jnp.einsum("tb,bj->tj", blocks,
+                       (jnp.arange(n)[:, None] < jnp.arange(n)[None, :]
+                        ).astype(jnp.float32),
+                       precision=jax.lax.Precision.HIGHEST)
+    return (inside + ahead[..., None]).astype(jnp.int32).reshape(
+        t, n * 128)[:, :c]
+
+
+def _selected_latent_attention(qx, lat, l, idx, ok, row_ids, block_tables,
+                               *, dc, scale):
+    """The absorbed latent attention of a tile's tokens over the
+    positions each one SELECTED: qx ``[nh, t, W]``, ``idx`` / ``ok``
+    ``[t, k]`` (:func:`index_select`), ``lat`` the latent leaf whole. A
+    token's ``k`` rows are gathered through its row's block table (a row
+    is one gather, whatever page it lies on) and every head attends
+    them, their first ``dc`` lanes the values; the softmax in float32
+    over the positions that are ``ok``. Returns ``[nh, t, dc]``."""
+    bs = lat.shape[2]
+    rows = lat[l, block_tables[row_ids[:, None], idx // bs], idx % bs]
+    s = jnp.einsum("htw,tcw->htc", qx, rows,
+                   preferred_element_type=jnp.float32) * scale
+    p = jax.nn.softmax(jnp.where(ok[None], s, NEG_INF), axis=-1)
+    p = jnp.where(ok[None, :, :1], p, 0.0)              # padding: zeros
+    return jnp.einsum("htc,tcd->htd", p.astype(qx.dtype), rows[..., :dc])
+
+
+def _indexed_latent_attention(qx, qi, wi, pool, l, row_ids, lengths,
+                              block_tables, *, dc, scale, topk, one_token,
+                              use_kernel):
+    """A full latent layer's read where the tables hold more positions
+    than ``topk``, a tile of ``_INDEX_TILE`` tokens at a time: the
+    indexer's scores and selection, then the attention over what was
+    selected and nothing else. A DECODE step's tokens (``one_token``)
+    gather the rows they picked (:func:`index_select`,
+    :func:`_selected_latent_attention`): ``topk`` rows a token, whatever
+    the context. A PROMPT's launch reads its rows' pages once through
+    the latent kernel with each token's picks laid on the causal mask
+    (:func:`index_mask`, ``latent_attention(picked=)``): the same sets
+    and the same sums, and cheaper on the chip wherever it was read (a
+    gathered row costs ~15 ns whatever its bytes, 2,048 of them a token
+    and layer: PERF.md section 6, PR 68). qx ``[nh, T, W]`` -> ``[nh, T,
+    dc]``."""
+    from .kernels.ragged_attention import (latent_attention,
+                                           latent_attention_reference)
+    nh, T0, W = qx.shape
+    tt = min(_INDEX_TILE, T0)
+    T = -(-T0 // tt) * tt
+    if T != T0:
+        qx = jnp.pad(qx, ((0, 0), (0, T - T0), (0, 0)))
+        qi = jnp.pad(qi, ((0, T - T0), (0, 0), (0, 0)))
+        wi, row_ids, lengths = (jnp.pad(a, ((0, T - T0),) + ((0, 0),) * (
+            a.ndim - 1)) for a in (wi, row_ids, lengths))
+    attend = latent_attention if use_kernel else latent_attention_reference
+
+    def tile(args):
+        qx, qi, wi, rows, bounds = args
+        if one_token:
+            idx, ok = index_select(qi, wi, pool["index_k"], l, rows, bounds,
+                                   block_tables, topk, one_token)
+            with jax.named_scope("attn_kernel"):
+                return _selected_latent_attention(
+                    qx, pool["latent"], l, idx, ok, rows, block_tables,
+                    dc=dc, scale=scale)
+        picked = index_mask(qi, wi, pool["index_k"], l, rows, bounds,
+                            block_tables, topk)
+        with jax.named_scope("attn_kernel"):
+            return attend(qx, pool["latent"], l, rows, bounds, block_tables,
+                          dc=dc, scale=scale, picked=picked)
+
+    n = T // tt
+    if n == 1:          # (a decode step's few tokens: no loop round one)
+        return tile((qx, qi, wi, row_ids, lengths))[:, :T0]
+    out = jax.lax.map(tile, (
+        qx.reshape(nh, n, tt, W).transpose(1, 0, 2, 3),
+        qi.reshape(n, tt, *qi.shape[1:]), wi.reshape(n, tt, -1),
+        row_ids.reshape(n, tt), lengths.reshape(n, tt)))
+    return out.transpose(1, 0, 2, 3).reshape(nh, T, dc)[:, :T0]
+
+
+def _query_latent(cfg, lp, hn, lk):
+    """c^q: the query's normed latent, rescaled where the block says"""
+    from ...ops.norms import rms_norm
+    cq = rms_norm(hn @ lp["wq_a"], lp["q_norm"], cfg.norm_eps)
+    if cfg.mla_lora_rescale:
+        cq = cq * jnp.asarray((cfg.hidden_size / lk.q_rank) ** 0.5, cq.dtype)
+    return cq
+
+
+def _index_queries(cfg, lp, hn, cq, cos, sin):
+    """The indexer's side of a query token: its heads' queries from the
+    query's latent, their first lanes rotated with the halves paired,
+    ``[T, heads, d]``, and a weight a head from the layer's normed
+    input with both scales in it, ``[T, heads]`` float32."""
+    ih = cfg.index_n_heads
+    qi = _rotate((cq @ lp["index_wq"]).reshape(hn.shape[0], ih, -1),
+                 cos[:, None], sin[:, None])
+    wi = (hn @ lp["index_ww"]).astype(jnp.float32) * (
+        ih ** -0.5 * cfg.index_head_dim ** -0.5)
+    return qi, wi
+
+
+def index_picks(cfg, lp, x, pos, keys, l, block_table, one_token=False):
+    """What the indexer of ONE full latent layer picks for query tokens
+    at positions ``pos`` [n] of one sequence, given the layer's input
+    ``x`` [n, H] there: the program's own query path (norm, the query's
+    latent, the indexer's queries and weights, in the weights' type) and
+    its own selection against the index keys the sequence CACHED
+    (``keys``: the ``index_k`` leaf; ``l`` the layer's place in it;
+    ``block_table`` [MB] the sequence's), in the form a prompt's launch
+    takes (:func:`index_mask`) or, ``one_token``, a decode step's
+    (:func:`index_select`). Returns flags ``[n, MB * block size]`` bool:
+    row i is what the token at ``pos[i]`` reads. The read half of a check on the selection (the
+    benchmark's ``generate_sparse`` runner, the serving tests); no
+    serving program calls it."""
+    hn = _norm(cfg, x, lp["attn_norm"]).astype(lp["wq_a"].dtype)
+    cos, sin = _rope_at(cfg, pos)
+    qi, wi = _index_queries(cfg, lp, hn, _query_latent(
+        cfg, lp, hn, cfg.latent_kind()), cos, sin)
+    rows, table = jnp.zeros_like(pos), block_table[None]
+    if not one_token:
+        return index_mask(qi, wi, keys, l, rows, pos + 1, table,
+                          cfg.index_topk)
+    idx, ok = index_select(qi, wi, keys, l, rows, pos + 1, table,
+                           cfg.index_topk)
+    flags = jnp.zeros((len(pos), table.shape[1] * keys.shape[2]), bool)
+    return flags.at[jnp.arange(len(pos))[:, None], idx].max(ok)
+
+
 def _latent_attention_sublayer(cfg, lp, x, l, pool, cos, sin, row_ids,
                                lengths, write_blocks, write_offsets,
-                               block_tables, use_kernel, one_token=False):
+                               block_tables, use_kernel, one_token=False,
+                               kind="mla"):
     """Multi-head latent attention on flat tokens x [T, H], in the
     ABSORBED form for prefill and decode alike: the new tokens' rows
     (normed latent, rotated shared key part) go to layer ``l`` of the
@@ -844,38 +1139,77 @@ def _latent_attention_sublayer(cfg, lp, x, l, pool, cos, sin, row_ids,
     mathematics as expanding every cached position's keys and values
     per head, which a long prefill would do more cheaply (ROADMAP M3).
     ``one_token``: every row has exactly one token (a decode batch),
-    which the kernel is told. Returns (what attention adds to x,
-    pool)."""
-    from ...ops.norms import rms_norm
+    which the kernel is told.
+
+    ``kind`` (a pattern over latent attention): whose sizes and whose
+    leaf (``cfg.latent_kind``, ``LATENT_LEAVES``). "mla_window" sees
+    its last ``attn_window`` positions: ``write_blocks`` and
+    ``block_tables`` are then places of the row's ring, and the kernel
+    is told the window. A "mla" layer with an INDEXER
+    (``cfg.index_topk``) writes the indexer's key of every new token
+    beside its row (``index_k``) and, where the tables hold more than
+    ``index_topk`` positions, attends only the positions the indexer
+    picks a query token (:func:`_indexed_latent_attention`: a decode
+    step gathers them, a prompt's launch masks the rest); where they
+    hold no more, every position under a token's bound is picked and
+    the launch is the dense one. ``cfg.mla_lora_rescale``: each normed
+    latent times sqrt(hidden / its rank). Returns (what attention adds
+    to x, pool)."""
+    from ...ops.norms import layer_norm, rms_norm
     from .kernels.ragged_attention import (latent_attention,
                                            latent_attention_reference)
     T = x.shape[0]
-    nh, dc = cfg.num_heads, cfg.kv_lora_rank
-    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    lk = cfg.latent_kind(kind)
+    leaf = LATENT_LEAVES[kind]
+    nh, dc = lk.heads, lk.kv_rank
+    dn, dr, dv = lk.nope, lk.rope, lk.v
     hn = _norm(cfg, x, lp["attn_norm"]).astype(lp["wkv_a"].dtype)
-    q = (rms_norm(hn @ lp["wq_a"], lp["q_norm"], cfg.norm_eps) @ lp["wq_b"]
-         if cfg.q_lora_rank else hn @ lp["wq"])
+    if lk.q_rank:
+        cq = _query_latent(cfg, lp, hn, lk)
+        q = cq @ lp["wq_b"]
+    else:
+        q = hn @ lp["wq"]
     q = q.reshape(T, nh, dn + dr)
     kv = hn @ lp["wkv_a"]                                   # [T, dc + dr]
     ckv = rms_norm(kv[:, :dc], lp["kv_norm"], cfg.norm_eps)
+    if cfg.mla_lora_rescale:
+        ckv = ckv * jnp.asarray((cfg.hidden_size / dc) ** 0.5, ckv.dtype)
     k_rope = _rotate_pairs(kv[:, dc:], cos, sin, cfg.rope_interleave)
     q_rope = _rotate_pairs(q[..., dn:], cos[:, None], sin[:, None],
                            cfg.rope_interleave)
-    W = pool["latent"].shape[-1]
+    W = pool[leaf].shape[-1]
     with jax.named_scope("kv_write"):
         pool = _latent_write(pool, l, write_blocks, write_offsets,
-                             jnp.concatenate([ckv, k_rope], axis=-1))
+                             jnp.concatenate([ckv, k_rope], axis=-1), leaf)
+        if lk.topk:
+            # the indexer's key of each new token: a LayerNorm (weight
+            # and bias) of one projection, its first lanes rotated with
+            # the halves paired
+            ki = layer_norm(hn @ lp["index_wk"], lp["index_k_norm"],
+                            lp["index_k_bias"], cfg.norm_eps)
+            pool = _latent_write(pool, l, write_blocks, write_offsets,
+                                 _rotate(ki, cos, sin), "index_k")
     wkv_b = lp["wkv_b"].reshape(dc, nh, dn + dv)
     q_lat = jnp.einsum("thd,chd->htc", q[..., :dn], wkv_b[..., :dn])
     qx = jnp.concatenate([q_lat, q_rope.transpose(1, 0, 2)], axis=-1)
     qx = jnp.pad(qx, ((0, 0), (0, 0), (0, W - dc - dr)))    # [nh, T, W]
     scale = 1.0 / float(dn + dr) ** 0.5
-    with jax.named_scope("attn_kernel"):
-        rows, at = _latent_rows(pool, l, hn.dtype)
-        attend = functools.partial(latent_attention, one_token=one_token) \
-            if use_kernel else latent_attention_reference
-        o_lat = attend(qx, rows, at, row_ids, lengths, block_tables,
-                       dc=dc, scale=scale)
+    if lk.topk and block_tables.shape[1] * pool[leaf].shape[2] > lk.topk:
+        with jax.named_scope("indexer"):
+            qi, wi = _index_queries(cfg, lp, hn, cq, cos, sin)
+        o_lat = _indexed_latent_attention(
+            qx, qi, wi, pool, l, row_ids, lengths, block_tables, dc=dc,
+            scale=scale, topk=lk.topk, one_token=one_token,
+            use_kernel=use_kernel)
+    else:
+        with jax.named_scope("attn_kernel"):
+            rows, at = _latent_rows(pool, l, hn.dtype, leaf)
+            window = {"window": lk.window} if lk.window else {}
+            attend = functools.partial(latent_attention, one_token=one_token,
+                                       **window) if use_kernel \
+                else functools.partial(latent_attention_reference, **window)
+            o_lat = attend(qx, rows, at, row_ids, lengths, block_tables,
+                           dc=dc, scale=scale)
     o = jnp.einsum("htc,chd->thd", o_lat, wkv_b[..., dn:])
     if cfg.attn_gate == "head":
         o = o * jax.nn.sigmoid(hn @ lp["wg"])[..., None].astype(o.dtype)
@@ -1522,9 +1856,13 @@ def _pattern_step(cfg: TransformerConfig, params, ids, row_ids, pos, lengths,
 
     expert_keys = cfg.expert_keys
     single = cfg.one_sublayer
+    latent_ring = "mla_window" in kinds
+    if latent_ring:
+        # the second latent kind rotates by a theta of its own
+        ring_cos, ring_sin = _rope_at(cfg, pos, "mla_window")
     if window_tables is not None:
         # a window layer's write-set: the ring place of each new position
-        bs = cache["k_window"].shape[2]
+        bs = cache["latent_window" if latent_ring else "k_window"].shape[2]
         ring_blocks = window_tables.shape[1]
         window_writes = jnp.where(
             valid, window_tables[row_ids, (pos // bs) % ring_blocks], 0)
@@ -1553,7 +1891,17 @@ def _pattern_step(cfg: TransformerConfig, params, ids, row_ids, pos, lengths,
 
         def layer_fn(carry, inputs):
             x, pool, stats = carry
-            if pattern:
+            if pattern and kind in LATENT_SCOPES and cfg.layer_types:
+                # (a pattern over latent attention reads a layer's
+                # weights out of the stacks under the sub-layer's own
+                # scope: a decode step's slices are a tenth of its time)
+                i = inputs
+                with jax.named_scope(LATENT_SCOPES[kind]):
+                    lp = jax.tree.map(lambda a: a[m0 + i], mixers)
+                with jax.named_scope("mlp"):
+                    lp = {**lp,
+                          **jax.tree.map(lambda a: a[f0 + i], scanned)}
+            elif pattern:
                 i = inputs
                 lp = {**jax.tree.map(lambda a: a[m0 + i], mixers),
                       **jax.tree.map(lambda a: a[f0 + i], scanned)}
@@ -1623,6 +1971,17 @@ def _pattern_step(cfg: TransformerConfig, params, ids, row_ids, pos, lengths,
                         window_tables if ring else block_tables, use_kernel,
                         one_token, mixer_in, table_runs[ring])
                     x = joined(x, a, cfg.attn_out_scale)
+            elif kind == "mla_window":
+                # the second latent kind: its own sizes, a window over
+                # the row's ring (scope ``mla_window_attention``, so the
+                # two kinds' launches are told apart under
+                # ``attn_kernel``)
+                with jax.named_scope(LATENT_SCOPES[kind]):
+                    a, pool = _latent_attention_sublayer(
+                        cfg, lp, x, m0 + i, pool, ring_cos, ring_sin,
+                        row_ids, lengths, window_writes, write_offsets,
+                        window_tables, use_kernel, one_token, kind)
+                    x = joined(x, a)
             else:
                 with jax.named_scope("mla_attention"):
                     a, pool = _latent_attention_sublayer(

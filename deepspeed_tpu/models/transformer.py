@@ -24,7 +24,7 @@ TPU-first design:
 import functools
 import math
 from dataclasses import dataclass
-from typing import Any, Dict, Optional
+from typing import Any, Dict, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -52,15 +52,38 @@ LAYER_TYPE_KINDS = {"sliding_attention": "window", "full_attention": "full",
                     "attention": "full", "mamba": "ssm", "moe": "moe",
                     "power_retention": "retention",
                     "mamba_attention": "hybrid", "conv": "conv"}
+# what ``layer_types`` names under attention="mla" (the dots3_note
+# block): a full LATENT layer, which reads the positions its indexer
+# picks, and a second latent kind with sizes of its own over a window
+LATENT_TYPE_KINDS = {"full_attention": "mla",
+                     "sliding_attention": "mla_window"}
 # the kinds that cache positions in blocks; the others keep a state a
 # sequence, or nothing ("hybrid" does both)
-PAGED_KINDS = ("mha", "mla", "window", "full", "hybrid")
+PAGED_KINDS = ("mha", "mla", "mla_window", "window", "full", "hybrid")
 # the kinds that own a place in a family of cache leaves: a "hybrid"
 # layer has one in the full pool AND one in the state-space leaves
 LEAF_OWNERS = {"full": ("full", "hybrid"), "ssm": ("ssm", "hybrid"),
                "conv": ("conv",)}
 # the kinds whose layers keep a state a sequence, in a slot
 STATE_KINDS = ("kda", "ssm", "retention", "hybrid", "conv")
+
+
+class LatentKind(NamedTuple):
+    """A latent mixer kind's sizes (``TransformerConfig.latent_kind``)."""
+    heads: int
+    q_rank: int
+    kv_rank: int
+    nope: int
+    rope: int
+    v: int
+    theta: float
+    window: int
+    topk: int
+
+    @property
+    def row(self) -> int:
+        """What one cached position holds: latent and rotated key part"""
+        return self.kv_rank + self.rope
 
 
 @dataclass(frozen=True)
@@ -195,6 +218,31 @@ class TransformerConfig:
     linear_conv_size: int = 4
     linear_decay_floor: float = -5.0
     attn_gate: str = "none"
+    # a per-layer pattern over LATENT attention (served only; the
+    # dots3_note block): ``layer_types`` under ``attention="mla"`` names
+    # "full_attention" (the latent attention above) and
+    # "sliding_attention", a SECOND latent kind whose head count, ranks,
+    # head widths and theta are its own (``swa_*``; its gate one scalar
+    # a head of ITS heads) and which sees its last ``attn_window``
+    # positions, kept as a ring of latent rows. ``index_*``: a full
+    # layer reads only the ``index_topk`` positions its indexer picks a
+    # query token (DeepSeek-V3.2's lightning indexer: ``index_n_heads``
+    # heads of ``index_head_dim`` from the query's latent against one
+    # LayerNormed key a position, ReLU, a learned weight a head; every
+    # position while the context is no longer than ``index_topk``).
+    # ``mla_lora_rescale``: each normed latent times sqrt(hidden_size /
+    # its rank)
+    swa_num_heads: int = 0
+    swa_q_lora_rank: int = 0
+    swa_kv_lora_rank: int = 0
+    swa_qk_nope_head_dim: int = 0
+    swa_qk_rope_head_dim: int = 0
+    swa_v_head_dim: int = 0
+    swa_rope_theta: float = 0.0
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
+    mla_lora_rescale: bool = False
     # a per-layer pattern over PER-HEAD attention (served only; the
     # afmoe block of Trinity-Mini): ``layer_types`` lists every layer's
     # mixer as the source publishes it, "sliding_attention" (a token sees
@@ -405,13 +453,16 @@ class TransformerConfig:
             # a list from a JSON file; the dataclass is frozen
             object.__setattr__(self, "layer_types",
                                tuple(self.layer_types))
+            names = self._type_kinds
             if (len(self.layer_types) != self.num_layers
-                    or set(self.layer_types) - set(LAYER_TYPE_KINDS)):
+                    or set(self.layer_types) - set(names)):
                 raise ValueError(
                     f"layer_types names a mixer a layer ({self.num_layers}"
-                    f" of {sorted(LAYER_TYPE_KINDS)}), got "
+                    f" of {sorted(names)}), got "
                     f"{self.layer_types!r}")
-            if (self.attention != "mha" or self.linear_attn_period
+            if self.attention == "mla":
+                self._check_latent_pattern()
+            elif (self.attention != "mha" or self.linear_attn_period
                     or self.positional not in ("rope", "none")
                     or self.norm != "rmsnorm"
                     or self.attn_bias or not self.is_causal
@@ -487,6 +538,16 @@ class TransformerConfig:
                     "layer ONE sub-layer behind one norm) is served "
                     "pre-norm, with routed experts (moe_num_experts > 0) "
                     "and no leading dense stack")
+            if self.attention == "mla" and (
+                    self.qk_norm or self.rope_sliding_only
+                    or self.norm_scheme != "pre" or self.one_sublayer
+                    or self.attn_gate == "elementwise"
+                    or self.positional != "rope"):
+                raise NotImplementedError(
+                    "qk_norm, rope_sliding_only, norm_scheme, 'moe' "
+                    "layers, positional='none' and attn_gate="
+                    "'elementwise' describe a pattern over PER-HEAD "
+                    "attention, not one over latent attention")
         elif self.attn_window or self.qk_norm or self.rope_sliding_only \
                 or self.norm_scheme == "sandwich" \
                 or self.attn_gate == "elementwise":
@@ -494,6 +555,14 @@ class TransformerConfig:
                 "attn_window, qk_norm, rope_sliding_only, norm_scheme="
                 "'sandwich' and attn_gate='elementwise' describe the "
                 "per-head block of a layer pattern: give layer_types")
+        if (self.layer_types is None or self.attention != "mla") and (
+                self.swa_num_heads or self.swa_kv_lora_rank
+                or self.index_topk or self.index_n_heads
+                or self.mla_lora_rescale):
+            raise NotImplementedError(
+                "the swa_* sizes, the index_* sizes and mla_lora_rescale "
+                "describe a layer_types pattern over latent attention: "
+                "give attention='mla' and layer_types")
         if self.conv_taps and "conv" not in (self.layer_types or ()):
             raise NotImplementedError(
                 "conv_taps describes the conv layers of a layer pattern: "
@@ -579,6 +648,48 @@ class TransformerConfig:
                 f"supports it); got {self.moe_noisy_gate_policy!r}")
 
     @property
+    def _type_kinds(self) -> dict:
+        """What a word of ``layer_types`` names: a latent kind under
+        attention='mla', a kind of ``LAYER_TYPE_KINDS`` otherwise."""
+        return LATENT_TYPE_KINDS if self.attention == "mla" \
+            else LAYER_TYPE_KINDS
+
+    def _check_latent_pattern(self):
+        """``layer_types`` under attention='mla': what the two latent
+        kinds need said."""
+        if self.linear_attn_period:
+            raise NotImplementedError(
+                "a layer_types pattern over latent attention and "
+                "linear_attn_period are two patterns: give one")
+        if "sliding_attention" in self.layer_types:
+            swa = (self.swa_num_heads, self.swa_kv_lora_rank,
+                   self.swa_qk_nope_head_dim, self.swa_qk_rope_head_dim,
+                   self.swa_v_head_dim)
+            if min(swa) <= 0 or self.swa_qk_rope_head_dim % 2 \
+                    or self.swa_q_lora_rank < 0 or self.swa_rope_theta <= 0 \
+                    or self.attn_window < 1:
+                raise ValueError(
+                    f"a sliding_attention layer over latent attention "
+                    f"needs attn_window > 0, swa_rope_theta > 0 and its "
+                    f"own swa_num_heads, swa_kv_lora_rank, "
+                    f"swa_qk_nope_head_dim, an even swa_qk_rope_head_dim "
+                    f"and swa_v_head_dim (swa_q_lora_rank, or 0), got "
+                    f"window {self.attn_window}, theta "
+                    f"{self.swa_rope_theta}, sizes "
+                    f"{(self.swa_q_lora_rank, *swa)}")
+        index = (self.index_n_heads, self.index_head_dim, self.index_topk)
+        if any(index) and (
+                min(index) <= 0 or not self.q_lora_rank
+                or self.index_head_dim < self.qk_rope_head_dim
+                or "full_attention" not in self.layer_types):
+            raise ValueError(
+                f"an indexer needs index_n_heads, index_head_dim (no "
+                f"less than qk_rope_head_dim, whose lanes rotate) and "
+                f"index_topk > 0, a full_attention layer to select for "
+                f"and q_lora_rank > 0 (its queries are projections of "
+                f"the query's latent), got {index}")
+
+    @property
     def is_causal(self) -> bool:
         return self.objective == "causal_lm"
 
@@ -626,6 +737,9 @@ class TransformerConfig:
              "experts)", self.moe_expert_form == "reglu"),
             ("moe_router_ahead (the router reads the mixer's normed "
              "input)", self.moe_router_ahead),
+            ("a second latent kind over a window (swa_*) and an indexer "
+             "that picks what a full latent layer reads (index_*)",
+             self.attention == "mla" and self.layer_types is not None),
             ("positional='none'", self.positional == "none"),
             ("attn_scale", self.attn_scale != 0.0),
             ("residual_scale", self.residual_scale != 1.0),
@@ -686,7 +800,7 @@ class TransformerConfig:
         (an expert layer that is a layer of its own: ``one_sublayer``)
         from an explicit ``layer_types``."""
         if self.layer_types is not None:
-            return tuple(LAYER_TYPE_KINDS[t] for t in self.layer_types)
+            return tuple(self._type_kinds[t] for t in self.layer_types)
         p = self.linear_attn_period
         return tuple("kda" if p and (i + 1) % p else self.attention
                      for i in range(self.num_layers))
@@ -766,7 +880,23 @@ class TransformerConfig:
     def latent_row(self) -> int:
         """What one cached position holds a layer under attention='mla':
         the normed latent and the rotated shared key part."""
-        return self.kv_lora_rank + self.qk_rope_head_dim
+        return self.latent_kind().row
+
+    def latent_kind(self, kind: str = "mla") -> "LatentKind":
+        """The sizes of a latent mixer kind: "mla" (the model's own
+        ``num_heads``, ranks and head widths; ``topk`` where an indexer
+        picks what it reads) or "mla_window" (the ``swa_*`` sizes over
+        the last ``attn_window`` positions)."""
+        if kind == "mla_window":
+            return LatentKind(
+                self.swa_num_heads, self.swa_q_lora_rank,
+                self.swa_kv_lora_rank, self.swa_qk_nope_head_dim,
+                self.swa_qk_rope_head_dim, self.swa_v_head_dim,
+                self.swa_rope_theta, self.attn_window, 0)
+        return LatentKind(
+            self.num_heads, self.q_lora_rank, self.kv_lora_rank,
+            self.qk_nope_head_dim, self.qk_rope_head_dim, self.v_head_dim,
+            self.rope_theta, 0, self.index_topk)
 
     @property
     def is_gated_mlp(self) -> bool:
@@ -1200,24 +1330,39 @@ class TransformerLM:
             return (jax.random.normal(key, shape, jnp.float32)
                     * scale).astype(dt)
 
-        def attention(key, n, mlp_norm=True):
+        def attention(key, n, mlp_norm=True, kind="mla"):
             # five keys as before the gate came: the leaves that were
-            # there are seeded as they were; the gate folds one in
+            # there are seeded as they were; the gate folds one in, the
+            # indexer three more. ``kind``: whose sizes
+            # (``cfg.latent_kind``)
             ks = jax.random.split(key, 5)
-            qk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
-            query = {"wq_a": init(ks[0], (n, h, cfg.q_lora_rank)),
-                     "q_norm": jnp.ones((n, cfg.q_lora_rank), dt),
-                     "wq_b": init(ks[1], (n, cfg.q_lora_rank, nh * qk))} \
-                if cfg.q_lora_rank else {"wq": init(ks[0], (n, h, nh * qk))}
+            lk = cfg.latent_kind(kind)
+            nh, qk = lk.heads, lk.nope + lk.rope
+            query = {"wq_a": init(ks[0], (n, h, lk.q_rank)),
+                     "q_norm": jnp.ones((n, lk.q_rank), dt),
+                     "wq_b": init(ks[1], (n, lk.q_rank, nh * qk))} \
+                if lk.q_rank else {"wq": init(ks[0], (n, h, nh * qk))}
             out = {
                 "attn_norm": jnp.ones((n, h), dt), **query,
-                "wkv_a": init(ks[2], (n, h, cfg.latent_row)),
-                "kv_norm": jnp.ones((n, cfg.kv_lora_rank), dt),
-                "wkv_b": init(ks[3], (n, cfg.kv_lora_rank, nh * (
-                    cfg.qk_nope_head_dim + cfg.v_head_dim))),
-                "wo": init(ks[4], (n, nh * cfg.v_head_dim, h), out_std)}
+                "wkv_a": init(ks[2], (n, h, lk.row)),
+                "kv_norm": jnp.ones((n, lk.kv_rank), dt),
+                "wkv_b": init(ks[3], (n, lk.kv_rank, nh * (
+                    lk.nope + lk.v))),
+                "wo": init(ks[4], (n, nh * lk.v, h), out_std)}
             if cfg.attn_gate == "head":
                 out["wg"] = init(jax.random.fold_in(key, 5), (n, h, nh))
+            if lk.topk:
+                # the indexer: its heads' queries from the query's
+                # latent, ONE key a position behind a LayerNorm (weight
+                # and bias), a weight a head from the layer's input
+                ik = jax.random.split(jax.random.fold_in(key, 6), 3)
+                ih, idim = cfg.index_n_heads, cfg.index_head_dim
+                out.update(
+                    index_wq=init(ik[0], (n, lk.q_rank, ih * idim)),
+                    index_wk=init(ik[1], (n, h, idim)),
+                    index_k_norm=jnp.ones((n, idim), dt),
+                    index_k_bias=jnp.zeros((n, idim), dt),
+                    index_ww=init(ik[2], (n, h, ih)))
             if mlp_norm:
                 out["mlp_norm"] = jnp.ones((n, h), dt)
             return out
@@ -1366,7 +1511,9 @@ class TransformerLM:
                       "retention": (retention, 12), "hybrid": (hybrid, 13),
                       "conv": (short_conv, 14),
                       "mla": (functools.partial(attention, mlp_norm=False),
-                              8)}
+                              8),
+                      "mla_window": (functools.partial(
+                          attention, mlp_norm=False, kind="mla_window"), 15)}
             for kind in dict.fromkeys(kinds):
                 if kind == "moe":
                     continue

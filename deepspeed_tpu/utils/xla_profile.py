@@ -166,7 +166,16 @@ _SERVE_PHASE_OF_SCOPE = {
     # (``short_conv``), its two projections and, between them, both
     # gates, the taps and the state's way out of its slot and back
     "short_conv": "short_conv", "conv_proj": "short_conv",
-    "conv_gate": "short_conv", "conv_out": "short_conv"}
+    "conv_gate": "short_conv", "conv_out": "short_conv",
+    # a pattern over latent attention: the second latent kind's
+    # projections, norms and residual add stand under a scope of their
+    # own (``mla_window_attention``, as ``mla_attention`` the first's),
+    # so that the two kinds' ``attn_kernel`` are told apart by their
+    # paths; a full layer's indexer (its queries and weights, and its
+    # scores against a row's cached keys) and the selection (the top
+    # ``index_topk`` of a token's scores) are a phase each
+    "mla_window_attention": "attn_proj",
+    "indexer": "indexer", "index_select": "index_select"}
 _SERVE_SCOPE_WORD = re.compile(
     r"\b(" + "|".join(sorted(_SERVE_PHASE_OF_SCOPE, key=len, reverse=True))
     + r")\b")
@@ -174,7 +183,7 @@ SERVE_PHASES = ("embed", "attn_proj", "kv_write", "attn_kernel", "mlp",
                 "router", "experts", "head", "pick", "linear",
                 "linear_chunk", "linear_state", "ssm", "ssm_scan",
                 "ssm_state", "retention_chunk", "retention_state",
-                "short_conv", "other")
+                "short_conv", "indexer", "index_select", "other")
 
 
 def serve_scope(op_name: str) -> str:

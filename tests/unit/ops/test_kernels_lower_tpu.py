@@ -563,6 +563,119 @@ def test_a_latent_row_that_is_not_whole_lane_blocks_is_refused(tpu_sharding):
     assert err is not None and "aligned to tiling" in err, err
 
 
+# dots3-note-prev.rollout-8x32768-256: (heads, lanes, values, window,
+# tokens, rows, table places, pool blocks, layers) of its launches. The
+# full kind's launches (dense under tables of no more than index_topk
+# positions, the picks a mask on the kernel's under wider ones): 128
+# heads against a 640-lane row, a chunk step of 8 x 1,024; the window
+# kind's: 64 heads against a 1,152-lane row over a ring of 98 places, a
+# chunk step and 8 one-token rows
+DOTS3_LAUNCHES = {
+    "full-chunk": (128, 640, 512, 0, 8192, 8, 128, 16521, 2),
+    # a tile of 256 tokens of a chunk step with their picks as flags,
+    # under a table of 32,768 positions
+    "full-chunk-picked": (128, 640, 512, 0, 256, 8, 2048, 16521, 2),
+    "full-decode-under-topk": (128, 640, 512, 0, 8, 8, 128, 16521, 2),
+    "ring-chunk": (64, 1152, 1024, 513, 8192, 8, 98, 785, 3),
+    "ring-decode": (64, 1152, 1024, 513, 8, 8, 98, 785, 3)}
+
+
+@pytest.mark.parametrize("launch", sorted(DOTS3_LAUNCHES))
+def test_the_two_latent_kinds_at_the_long_context_cells_shapes(
+        tpu_sharding, launch):
+    """Both latent kinds of ``dots3-note-prev`` at the cell's shapes:
+    the kernel compiles, the window kind's under a name of its own (a
+    trace tells the two apart, and ``latent_share.gen``'s pattern does
+    not take the ring's launches for the dense ones)."""
+    from deepspeed_tpu.inference.v2.kernels.ragged_attention import \
+        latent_attention
+    nh, row, dc, window, T, R, MB, nb, layers = DOTS3_LAUNCHES[launch]
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=tpu_sharding)
+    args = (sds((nh, T, row), jnp.bfloat16),
+            sds((layers, nb, 16, row), jnp.bfloat16), sds((), jnp.int32),
+            sds((T,), jnp.int32), sds((T,), jnp.int32),
+            sds((R, MB), jnp.int32))
+    picks = launch.endswith("picked")
+    if picks:
+        args += (sds((T, MB * 16), jnp.bool_),)
+    text = jax.jit(lambda *a: latent_attention(
+        *a[:6], dc=dc, scale=256 ** -0.5, one_token=T == R, window=window,
+        picked=a[6] if picks else None)).lower(*args).compile().as_text()
+    kernels = re.findall(r"%([\w.\-]+) = [^\n]*tpu_custom_call", text)
+    assert len(kernels) == 1, kernels
+    if picks:
+        assert kernels[0].startswith("ragged_attention_latent_picked")
+        assert not LATENT_PATTERN.search(kernels[0])
+    elif window:
+        assert kernels[0].startswith("ragged_attention_latent_window")
+        assert not LATENT_PATTERN.search(kernels[0])
+    else:
+        assert LATENT_PATTERN.search(kernels[0]), kernels
+
+
+def _dots3_cell(tpu_sharding):
+    """The cell's configuration, tree and cache as shapes on the chip."""
+    import json
+    from pathlib import Path
+    from deepspeed_tpu.inference.v2.paged_model import init_paged_kv_cache
+    from deepspeed_tpu.models import TransformerLM
+    from deepspeed_tpu.models.transformer import TransformerConfig
+
+    root = Path(__file__).resolve().parents[3] / "benchmark"
+    cfg = TransformerConfig(**json.loads(
+        (root / "configs/dots3-note-prev.json").read_text())["fields"])
+    sm = json.loads((root / "workloads/dots3-note-prev.rollout-8x32768-256"
+                     ".json").read_text())["engine"]["state_manager"]
+
+    def on_tpu(x, dtype=None):
+        return jax.ShapeDtypeStruct(x.shape, dtype or x.dtype,
+                                    sharding=tpu_sharding)
+    params = jax.tree.map(
+        lambda x: on_tpu(x, jnp.bfloat16),
+        jax.eval_shape(TransformerLM(cfg).init_params,
+                       jax.random.PRNGKey(0)))
+    cache = jax.tree.map(on_tpu, jax.eval_shape(
+        lambda: init_paged_kv_cache(
+            cfg, sm["num_blocks"], 16, jnp.bfloat16,
+            window_blocks=8 * 98 + 1)))
+    return cfg, params, cache
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("table", [128, 2048])
+def test_the_long_context_cells_chunk_step_fits_the_chip(tpu_sharding,
+                                                         table):
+    """The whole 8,192-token chunk step of ``dots3-note-prev``'s cell at
+    published widths, under a table of 2,048 positions (the dense
+    launch) and of 32,768 (the indexer, the selection and the picks laid
+    on the latent kernel's mask): it compiles for the chip inside the
+    15.0 GB of the cell's rule, each under its kernel's own name."""
+    from deepspeed_tpu.inference.v2.paged_model import paged_ragged_step
+    cfg, params, cache = _dots3_cell(tpu_sharding)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=tpu_sharding)
+    T, R = 8192, 8
+    compiled = jax.jit(
+        lambda p, ids, rows, pos, ln, wb, wo, bt, li, c, wt:
+        paged_ragged_step(cfg, p, ids, rows, pos, ln, wb, wo, bt, li, c, 16,
+                          use_kernel=True, window_tables=wt),
+        donate_argnums=(9,)).lower(
+        params, i32(T), i32(T), i32(T), i32(T), i32(T), i32(T),
+        i32(R, table), i32(R), cache, i32(R, 98)).compile()
+    m = compiled.memory_analysis()
+    assert m.argument_size_in_bytes + m.temp_size_in_bytes < 15.0e9
+    kernels = re.findall(r"%([\w.\-]+) = [^\n]*tpu_custom_call",
+                         compiled.as_text())
+    assert any(k.startswith("ragged_attention_latent_window")
+               for k in kernels), kernels
+    assert any(LATENT_PATTERN.search(k) for k in kernels) == (table == 128)
+    assert any(k.startswith("ragged_attention_latent_picked")
+               for k in kernels) == (table == 2048)
+
+
 def _latent_cut(tpu_sharding, blocks):
     """The latent block at published widths, depth cut to the leading
     dense layer and ONE expert layer of 256 experts: the configuration,
