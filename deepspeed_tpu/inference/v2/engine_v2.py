@@ -46,7 +46,8 @@ from .kernels.ragged_attention import (LATENT, decode_positions,
                                        launch_copies, one_token_tile_serves,
                                        prompt_chunks, prompt_walks,
                                        token_tile, token_tile_serves)
-from .paged_model import (STATE_LEAVES, init_lora_bank, init_paged_kv_cache,
+from .paged_model import (STATE_LEAVES, index_prompt_form, init_lora_bank,
+                          init_paged_kv_cache,
                           moe_rows_form, moe_share_runs, paged_continue,
                           paged_decode, paged_decode_window,
                           paged_ragged_step, paged_spec_decode_window)
@@ -864,10 +865,23 @@ class InferenceEngineV2:
             "cached positions whose rows those queries' attention READ: "
             "what it attended in a decode step (a token gathers the rows "
             "it picked), the token's whole bound in a prompt's launch "
-            "(the row's pages once, the picks a mask) and under tables "
-            "of no more than index_topk positions (the dense launch); "
+            "(the picks a mask over the row's pages read once, or over "
+            "the per-head keys and values made of them: "
+            "inference_index_prompt_launches_total says which) and under "
+            "tables of no more than index_topk positions (the dense "
+            "launch); "
             "over inference_index_queries_total the mean positions read "
             "a query", labelnames=("program",))
+        self._m_index_prompt_launches = reg.counter(
+            "inference_index_prompt_launches_total",
+            "ragged steps' launches of a full latent layer that SELECT "
+            "(tables wider than index_topk), a launch a full layer, by "
+            "the form the launch's static shapes gave it "
+            "(paged_model.index_prompt_form): \"expanded\" (a row's "
+            "cached latent rows made per-head keys and values once, a "
+            "per-head kernel under the picks' mask) or \"absorbed\" "
+            "(the masked latent kernel: a launch of few tokens a row)",
+            labelnames=("form",))
         self._m_index_scored = reg.counter(
             "inference_index_positions_scored_total",
             "cached positions the indexer scored for those queries (a "
@@ -2329,6 +2343,13 @@ class InferenceEngineV2:
                 self._note_index_reads("ragged_step", np.concatenate([
                     seqs[uid].seen_tokens + 1 + np.arange(len(toks))
                     for uid, toks in entries]), rb.block_tables.shape[1])
+                form = index_prompt_form(
+                    rb.token_bucket, rb.row_bucket,
+                    rb.block_tables.shape[1] * self.block_size,
+                    self.model.cfg.index_topk)
+                if form:
+                    self._m_index_prompt_launches.labels(form=form).inc(
+                        self.model.cfg.layer_kinds.count("mla"))
             self._note_state_rows("ragged_step", len(entries),
                                   rb.total_tokens)
             if self._has_state:

@@ -145,6 +145,7 @@ the serving programs hold no slice, reshape or copy of a layer.
 """
 
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -1719,7 +1720,11 @@ def latent_attention(q, pool, layer, row_ids, lengths, block_tables, *,
     position): a token attends only what it picked of the positions
     under its bound, every head the same (a full latent layer whose
     indexer selects; ``ragged_attention_latent_picked`` in a trace). The
-    token tile's form only."""
+    token tile's form only. Who still calls it: a selecting prompt
+    launch of FEW tokens a row (``paged_model.index_prompt_form``:
+    "absorbed", a ragged step's tail) and the parity tests; a launch of
+    many tokens a row attends per-head keys and values instead
+    (:func:`picked_heads_attention`)."""
     nh, T0, W = q.shape
     if interpret is None and _interpret():
         return latent_attention_reference(
@@ -1807,3 +1812,265 @@ def latent_attention(q, pool, layer, row_ids, lengths, block_tables, *,
     if one_token:
         return out[:T0, :nh].transpose(1, 0, 2)
     return out[:, :T0]
+
+
+# ---------------------------------------------------------------------------
+# Latent attention in the EXPANDED form, under a selection
+# ---------------------------------------------------------------------------
+def _expand_kernel(rows_ref, pieces_ref, lat_ref, w_ref, k_ref, v_ref, *,
+                   dc, dn):
+    """Grid (heads / hb, live pieces): one piece of ``E`` latent rows
+    ``(E, W)`` times ``hb`` heads' up-projections ``(dc, hb x (dn +
+    dv))``, summed in float32, a head's keys ``(E, dn)`` and values
+    ``(E, dv)`` written to its own slab."""
+    hb = k_ref.shape[2]
+    dv = v_ref.shape[-1]
+    y = jnp.dot(lat_ref[0, 0][:, :dc], w_ref[0],
+                preferred_element_type=jnp.float32)
+    for j in range(hb):                                       # static
+        at = j * (dn + dv)
+        k_ref[0, 0, j] = y[:, at:at + dn].astype(k_ref.dtype)
+        v_ref[0, 0, j] = y[:, at + dn:at + dn + dv].astype(v_ref.dtype)
+
+
+def expand_latent_rows_reference(lat, w, reach=None, *, dc: int, dn: int):
+    """:func:`expand_latent_rows` as one einsum over every piece."""
+    y = jnp.einsum("rpcd,dhn->rphcn", lat[..., :dc], w)
+    return y[..., :dn], y[..., dn:]
+
+
+def expand_latent_rows(lat, w, reach, *, dc: int, dn: int,
+                       interpret: Optional[bool] = None,
+                       heads_a_step: int = 4):
+    """Rows' cached latent rows turned into per-head keys and values
+    (the EXPANDED form's operands): lat ``[R, C, E, W]`` (a row's
+    positions in ``C`` pieces of ``E``; the first ``dc`` lanes ``c^kv``),
+    w ``[dc, nh, dn + dv]`` (``W^UK | W^UV`` a head), reach ``[R]`` the
+    positions a row's tokens reach. Returns k ``[R, C, nh, E, dn]`` and
+    v ``[R, C, nh, E, dv]`` in the latent's type, products summed in
+    float32. On a TPU the kernel ``latent_rows_expand``: its grid runs
+    over the pieces under ``reach`` alone (a dynamic bound), so a table
+    far wider than its rows costs nothing and a piece past a row's reach
+    is NEVER WRITTEN (nothing may read it: :func:`picked_heads_attention`
+    stops at its tiles' bounds). Off it one einsum over everything, and
+    the kernel under the TPU interpreter only where ``interpret`` asks
+    for it."""
+    R, C, E, W = lat.shape
+    nh, dv = w.shape[1], w.shape[2] - dn
+    if interpret is None and _interpret():
+        return expand_latent_rows_reference(lat, w, dc=dc, dn=dn)
+    hb = math.gcd(heads_a_step, nh)
+    under = (jnp.arange(C, dtype=jnp.int32)[None, :] * E
+             < reach[:, None]).reshape(R * C)
+    live = jnp.argsort(~under, stable=True).astype(jnp.int32)
+
+    def out_block(h, s, rows_ref, pieces_ref):
+        return (rows_ref[s], pieces_ref[s], h, 0, 0)
+
+    return pl.pallas_call(
+        functools.partial(_expand_kernel, dc=dc, dn=dn),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(nh // hb, jnp.maximum(jnp.sum(under, dtype=jnp.int32), 1)),
+            in_specs=[
+                pl.BlockSpec((1, 1, E, W), lambda h, s, rows_ref, pieces_ref:
+                             (rows_ref[s], pieces_ref[s], 0, 0)),
+                pl.BlockSpec((1, dc, hb * (dn + dv)),
+                             lambda h, s, *_: (h, 0, 0))],
+            out_specs=[pl.BlockSpec((1, 1, hb, E, dn), out_block),
+                       pl.BlockSpec((1, 1, hb, E, dv), out_block)]),
+        out_shape=[jax.ShapeDtypeStruct((R, C, nh, E, dn), lat.dtype),
+                   jax.ShapeDtypeStruct((R, C, nh, E, dv), lat.dtype)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_TILED_VMEM_BYTES),
+        interpret=pltpu.InterpretParams() if interpret or _interpret()
+        else False,
+        name="latent_rows_expand",
+    )(live // C, live % C, lat,
+      w.reshape(dc, nh // hb, hb * (dn + dv)).transpose(1, 0, 2))
+
+
+def _picked_heads_kernel(rows_ref, chunks_ref, q_ref, k_ref, kr_ref, v_ref,
+                         p_ref, len_ref, o_ref, acc_sc, m_sc, l_sc, *, scale,
+                         P):
+    """Grid (tiles, heads / hs, chunks): a tile of ``tq`` tokens of ONE
+    row (``rows_ref[i]``) against that row's per-head keys (``k_ref``
+    ``(P, dn)`` a head beside the row's shared rotated part ``kr_ref``
+    ``(P, dr)``, laid side by side here: one product of ``dn + dr``) and
+    values ``(P, dv)``, ``hs`` heads a step, a chunk of ``P`` positions
+    at a time, BlockSpec-pipelined from contiguous temporaries. The
+    update is the token tile's, TRANSPOSED (:func:`_tile_update`: a
+    token a lane, scores ``k q^T`` ``(P, tq)``, max and sum ``(1,
+    tq)``, the accumulator ``v^T p`` ``(dv, tq)``, all float32), one a
+    head, under ONE mask a step: the causal bound (``len_ref`` ``(1,
+    tq)``) and the tile's flags for the chunk (``p_ref`` ``(tq / tt, P,
+    tt)``, > 0: picked; every head reads the same). ``chunks_ref[i]``: the
+    chunks under the tile's largest bound; a step past them computes
+    nothing and its blocks' indices stand still, so nothing is copied
+    for it (the grid's chunk axis is dynamic: the chunks under the
+    LAUNCH's largest bound)."""
+    i, c = pl.program_id(0), pl.program_id(2)
+    hs, tq = q_ref.shape[:2]
+    dv = v_ref.shape[-1]
+
+    @pl.when(c == 0)
+    def _begin():
+        _init_scratch(acc_sc, m_sc, l_sc)
+
+    @pl.when(c < chunks_ref[i])
+    def _chunk():
+        pos = c * P + jax.lax.broadcasted_iota(jnp.int32, (P, tq), 0)
+        # (compared in float32: the chip compares neither bf16 nor int8)
+        picked = jnp.concatenate([p_ref[j] for j in range(p_ref.shape[0])],
+                                 axis=1)
+        visible = (pos < len_ref[...]) & (picked.astype(jnp.float32) > 0)
+        for h in range(hs):                                   # static
+            _tile_update(
+                q_ref[h], jnp.concatenate([k_ref[0, 0, h], kr_ref[0, 0]],
+                                          axis=1), v_ref[0, 0, h], visible,
+                acc_sc, m_sc, l_sc, h, scale=scale)
+
+    @pl.when(c == pl.num_programs(2) - 1)
+    def _finish():
+        for h in range(hs):                                   # static
+            l = l_sc[h]
+            o_ref[:, h * dv:(h + 1) * dv] = (
+                acc_sc[h] / jnp.where(l == 0.0, 1.0, l)).T.astype(o_ref.dtype)
+
+
+def picked_heads_attention_reference(q, k, k_rope, v, picked_t, tile_rows,
+                                     lengths, *, scale: float, tq: int):
+    """:func:`picked_heads_attention` in jnp: a tile's row's keys and
+    values gathered whole, the softmax in float32 over the positions
+    under a token's bound that it picked. Toy sizes and parity."""
+    nh, N, dk = q.shape
+    R, C, _, E, dn = k.shape
+    ctx, n = C * E, N // tq
+    k, v = (a.transpose(0, 2, 1, 3, 4).reshape(R, nh, ctx, -1)
+            for a in (k, v))
+    q = q.reshape(nh, n, tq, dk)
+    s = (jnp.einsum("hitd,ihcd->hitc", q[..., :dn], k[tile_rows])
+         + jnp.einsum("hitd,icd->hitc", q[..., dn:],
+                      k_rope.reshape(R, ctx, -1)[tile_rows])
+         ).astype(jnp.float32) * scale
+    picked = picked_t.transpose(0, 2, 1).reshape(N, -1)[:, :ctx]
+    picked = jnp.pad(picked, ((0, 0), (0, ctx - picked.shape[1])))
+    seen = (jnp.arange(ctx)[None, :] < lengths[:, None]) & (picked > 0)
+    p = jax.nn.softmax(jnp.where(seen.reshape(n, tq, ctx)[None], s, NEG_INF),
+                       axis=-1)
+    p = jnp.where(jnp.any(seen, axis=-1).reshape(n, tq)[None, ..., None],
+                  p, 0.0)                       # nothing to attend: zeros
+    o = jnp.einsum("hitc,ihcd->ithd", p.astype(q.dtype), v[tile_rows])
+    return o.reshape(N, nh * v.shape[-1])
+
+
+# positions a chunk of :func:`picked_heads_attention`: the caller's keys
+# and values hold whole chunks of them: 6 % under 512 positions on the
+# chip (PERF.md section 6, PR 69: 12.79 -> 12.01 ms at 1,024 tokens x
+# 16,384 positions). Eight heads a step bring 11.70, and 18 MB of a
+# step's blocks: inside the program's loop over groups of heads XLA
+# fuses the loop's output into the call and holds the call to 16 MB
+# whatever ``vmem_limit_bytes`` says, so a step keeps four
+PICKED_CHUNK = 1024
+
+
+def picked_heads_tile(tokens: int, rows: int) -> int:
+    """Tokens a tile of :func:`picked_heads_attention` by the launch's
+    static shapes: a power of two of 128 to 512, no more than a row's
+    even share of the launch (a row's keys and values are read once a
+    TILE and head)."""
+    return max(128, min(512, pow2_bucket(max(tokens // max(rows, 1), 1),
+                                         512)))
+
+
+def picked_heads_attention(q, k, k_rope, v, picked_t, tile_rows, lengths, *,
+                           scale: float, tq: int,
+                           interpret: Optional[bool] = None,
+                           heads_a_step: int = 4,
+                           chunk: int = PICKED_CHUNK):
+    """Latent attention in the EXPANDED form under a selection: tokens
+    packed in tiles of ``tq``, every tile the tokens of ONE row, attend
+    head by head that row's per-head keys and values, which the caller
+    made of the row's latent rows once for the launch
+    (``paged_model._expanded_index_attention``).
+
+    q ``[nh, N, dn + dr]`` (N whole tiles; ``q_nope | q_rope``); a
+    row's ``ctx = C x E`` positions lie in ``C`` pieces of ``E`` as the
+    caller made them: k ``[R, C, nh, E, dn]`` (``c^kv W^UK``), k_rope
+    ``[R, C, E, dr]`` (the rotated part every head shares), v ``[R, C,
+    nh, E, dv]`` (``c^kv W^UV``); picked_t ``[N / tt, positions, tt]``
+    (int8 or the queries' type; > 0: the token PICKED the position: the
+    flags of ``tt`` tokens at a time, TRANSPOSED, a token a lane, as the
+    caller's loop over tiles of tokens leaves them; ``tt`` divides
+    ``tq``; positions past the array's are picked by none), tile_rows
+    ``[N / tq]`` each tile's row, lengths ``[N]`` each token's causal
+    bound (0: no token); a piece whole chunks of ``chunk`` positions (or
+    one chunk). A token attends
+    the positions under its bound that it picked; nothing past the
+    launch's largest bound is read (the grid's chunk axis ends there).
+    Returns ``[N, nh * dv]``, token-major. On a TPU the kernel
+    (``ragged_attention_latent_picked_heads`` in a trace); off it the
+    jnp reference, and the kernel under the TPU interpreter only where
+    ``interpret`` asks for it."""
+    if interpret is None and _interpret():
+        return picked_heads_attention_reference(
+            q, k, k_rope, v, picked_t, tile_rows, lengths, scale=scale,
+            tq=tq)
+    nh, N, dk = q.shape
+    R, C, _, E, dn = k.shape
+    dv = v.shape[-1]
+    n = N // tq
+    hs = math.gcd(heads_a_step, nh)
+    P = min(chunk, E)
+    tt = picked_t.shape[-1]
+    assert N == n * tq and E % P == 0 and tq % 128 == 0 and tq % tt == 0, \
+        (N, tq, tt, E, P)
+    per = E // P                                    # chunks a piece
+    lengths = lengths.astype(jnp.int32)
+    chunks = -(-jnp.max(lengths.reshape(n, tq), axis=1) // P)
+
+    def at(i, c, chunks_ref):
+        """the chunk a step reads: the last one under the tile's bound
+        where the step is past it (the same block: no copy)"""
+        return jnp.maximum(jnp.minimum(c, chunks_ref[i] - 1), 0)
+
+    def head_block(i, h, c, rows_ref, chunks_ref):
+        c = at(i, c, chunks_ref)
+        return (rows_ref[i], c // per, h, c % per, 0)
+
+    def rope_block(i, h, c, rows_ref, chunks_ref):
+        c = at(i, c, chunks_ref)
+        return (rows_ref[i], c // per, c % per, 0)
+
+    return pl.pallas_call(
+        functools.partial(_picked_heads_kernel, scale=scale, P=P),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            # (the chunks under the launch's largest bound: a dynamic
+            # bound, so a table far wider than its rows costs no step)
+            grid=(n, nh // hs, jnp.maximum(jnp.max(chunks), 1)),
+            in_specs=[
+                pl.BlockSpec((hs, tq, dk), lambda i, h, c, *_: (h, i, 0)),
+                pl.BlockSpec((1, 1, hs, P, dn), head_block),
+                pl.BlockSpec((1, 1, P, dk - dn), rope_block),
+                pl.BlockSpec((1, 1, hs, P, dv), head_block),
+                pl.BlockSpec((tq // tt, P, tt),
+                             lambda i, h, c, rows_ref, chunks_ref:
+                             (i, at(i, c, chunks_ref), 0)),
+                pl.BlockSpec((1, tq), lambda i, h, c, *_: (0, i))],
+            out_specs=pl.BlockSpec((tq, hs * dv),
+                                   lambda i, h, c, *_: (i, h)),
+            scratch_shapes=[pltpu.VMEM((hs, dv, tq), jnp.float32),
+                            pltpu.VMEM((hs, 1, tq), jnp.float32),
+                            pltpu.VMEM((hs, 1, tq), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((N, nh * dv), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=_TILED_VMEM_BYTES),
+        interpret=pltpu.InterpretParams() if interpret or _interpret()
+        else False,
+        name="ragged_attention_latent_picked_heads",
+    )(tile_rows.astype(jnp.int32), chunks, q, k, k_rope, v,
+      picked_t.astype(jnp.int8) if picked_t.dtype == jnp.bool_
+      else picked_t, lengths.reshape(1, N))

@@ -50,6 +50,7 @@ kernels/paged_attention.py).
 """
 
 import functools
+import math
 from typing import Any, Dict, NamedTuple, Tuple
 
 import jax
@@ -957,8 +958,9 @@ def index_mask(qi, wi, keys, l, row_ids, lengths, block_tables, topk):
     (an order-keeping map of float32 to uint32), then every position
     over it and, of the positions AT it, the first ones in position
     order that fill the set. The form a prompt's launch takes: its many
-    tokens read their row's pages once and mask
-    (``latent_attention(picked=)``)."""
+    tokens read their row's positions once and mask
+    (``picked_heads_attention`` over per-head keys and values, or
+    ``latent_attention(picked=)`` over the latent rows)."""
     scores, seen = _token_scores(qi, wi, keys, l, row_ids, lengths,
                                  block_tables, False)
     with jax.named_scope("index_select"):
@@ -1026,19 +1028,22 @@ def _selected_latent_attention(qx, lat, l, idx, ok, row_ids, block_tables,
 def _indexed_latent_attention(qx, qi, wi, pool, l, row_ids, lengths,
                               block_tables, *, dc, scale, topk, one_token,
                               use_kernel):
-    """A full latent layer's read where the tables hold more positions
-    than ``topk``, a tile of ``_INDEX_TILE`` tokens at a time: the
-    indexer's scores and selection, then the attention over what was
-    selected and nothing else. A DECODE step's tokens (``one_token``)
-    gather the rows they picked (:func:`index_select`,
+    """A full latent layer's read in the ABSORBED form where the tables
+    hold more positions than ``topk``, a tile of ``_INDEX_TILE`` tokens
+    at a time: the indexer's scores and selection, then the attention
+    over what was selected and nothing else. A DECODE step's tokens
+    (``one_token``) gather the rows they picked (:func:`index_select`,
     :func:`_selected_latent_attention`): ``topk`` rows a token, whatever
-    the context. A PROMPT's launch reads its rows' pages once through
-    the latent kernel with each token's picks laid on the causal mask
-    (:func:`index_mask`, ``latent_attention(picked=)``): the same sets
-    and the same sums, and cheaper on the chip wherever it was read (a
+    the context. A PROMPT's launch of few tokens a row (a ragged step's
+    tail; :func:`index_prompt_form` says "absorbed") reads its rows'
+    pages once through the latent kernel with each token's picks laid
+    on the causal mask (:func:`index_mask`,
+    ``latent_attention(picked=)``): the same sets and the same sums,
+    and cheaper on the chip than gathering wherever it was read (a
     gathered row costs ~15 ns whatever its bytes, 2,048 of them a token
-    and layer: PERF.md section 6, PR 68). qx ``[nh, T, W]`` -> ``[nh, T,
-    dc]``."""
+    and layer: PERF.md section 6, PR 68). A launch of many tokens a row
+    does not come here (:func:`_expanded_index_attention`). qx ``[nh,
+    T, W]`` -> ``[nh, T, dc]``."""
     from .kernels.ragged_attention import (latent_attention,
                                            latent_attention_reference)
     nh, T0, W = qx.shape
@@ -1074,6 +1079,165 @@ def _indexed_latent_attention(qx, qi, wi, pool, l, row_ids, lengths,
         qi.reshape(n, tt, *qi.shape[1:]), wi.reshape(n, tt, -1),
         row_ids.reshape(n, tt), lengths.reshape(n, tt)))
     return out.transpose(1, 0, 2, 3).reshape(nh, T, dc)[:, :T0]
+
+
+# a prompt launch of a full latent layer that selects takes the EXPANDED
+# form where the launch has at least this many tokens a table row: making
+# a row's keys and values costs rows x positions x rank x heads x (nope +
+# v) products, and saves tokens x positions x heads x (the absorbed
+# form's products a position less the expanded form's); at dots3's widths
+# they meet at ~170 tokens a row (PERF.md section 6, PR 69)
+_EXPAND_TOKENS_A_ROW = 256
+# bytes of keys and values made at once: a group of heads (a power of
+# two, four at least) whose rows x the TABLE's positions x heads x (nope
+# + v) stay inside (8 heads at the cell's 4 rows x 34,816: 0.57 GB; 4 at
+# 8 rows)
+_EXPAND_BYTES = 0.6e9
+# positions a piece of a row's keys and values: a piece past what the
+# row's tokens reach is not made
+_EXPAND_PIECE = 2048
+
+
+def index_prompt_form(tokens: int, rows: int, positions: int, topk: int):
+    """How a PROMPT's launch of a full latent layer with an indexer
+    reads, by the launch's static shapes (its token bucket, its tables'
+    rows and the positions they hold): None where the tables hold no
+    more than ``topk`` positions (nothing selects: the dense launch),
+    "expanded" where the launch brings ``_EXPAND_TOKENS_A_ROW`` tokens a
+    row or more (:func:`_expanded_index_attention`), else "absorbed"
+    (the masked latent kernel). What the engine's counter
+    ``inference_index_prompt_launches_total`` is told, too."""
+    if not topk or positions <= topk:
+        return None
+    return "expanded" if tokens >= _EXPAND_TOKENS_A_ROW * rows \
+        else "absorbed"
+
+
+def _row_tiles(row_ids, lengths, R, tq):
+    """A launch's tokens packed in tiles of ``tq`` that each hold ONE
+    row's tokens (a row's tokens are contiguous in pack order; ``lengths``
+    0: no token): ``T / tq + R`` tiles (static: a row's last tile may be
+    part full), ``src`` ``[tiles * tq]`` the flat token a packed place
+    holds (``T``: none), ``slot`` ``[T]`` a token's packed place (past
+    the end: no token) and ``tile_rows`` ``[tiles]`` each tile's row."""
+    T = row_ids.shape[0]
+    n = -(-T // tq) + R
+    live = lengths > 0
+    tok = jnp.arange(T, dtype=jnp.int32)
+    mine = (row_ids[None, :] == jnp.arange(R)[:, None]) & live[None, :]
+    first = jnp.min(jnp.where(mine, tok, T), axis=1)
+    tiles = -(-jnp.sum(mine, axis=1, dtype=jnp.int32) // tq)
+    base = jnp.cumsum(tiles) - tiles
+    j = tok - first[row_ids]
+    slot = jnp.where(live, (base[row_ids] + j // tq) * tq + j % tq, n * tq)
+    src = jnp.full((n * tq,), T, jnp.int32).at[slot].set(tok, mode="drop")
+    tile_rows = jnp.zeros((n,), jnp.int32).at[slot // tq].max(
+        row_ids.astype(jnp.int32), mode="drop")
+    return src, slot, tile_rows
+
+
+def _expanded_picked_attention(q, lat, wkv_b, picked_t, tile_rows, bounds,
+                               reach, *, dc, dn, scale, tq, use_kernel):
+    """Packed tokens (:func:`_row_tiles`) attend their rows' positions
+    in the EXPANDED form, a group of heads at a time (``_EXPAND_BYTES``):
+    the rows' latent rows ``lat`` ``[R, C, E, W]`` (a row's positions in
+    ``C`` pieces of ``E``) become the group's per-head keys (``c^kv
+    W^UK`` beside the shared rotated part) and values (``c^kv W^UV``)
+    ONCE, a temporary of one group, a piece at a time and only the
+    pieces under ``reach`` ``[R]`` (the positions a row's tokens reach:
+    a chunk step's table is as wide as a row can grow, and a row that
+    has no token in the launch is not expanded at all:
+    ``kernels/ragged_attention.expand_latent_rows``); every tile then
+    attends its row's (``picked_heads_attention``). q ``[nh, N, dn + dr]``, wkv_b ``[dc, nh,
+    dn + dv]``, picked_t ``[N / tt, positions, tt]``, bounds ``[N]``.
+    Returns ``[N, nh, dv]``."""
+    from .kernels.ragged_attention import (
+        expand_latent_rows, expand_latent_rows_reference,
+        picked_heads_attention, picked_heads_attention_reference)
+    nh, N, dk = q.shape
+    dr = dk - dn
+    dv = wkv_b.shape[-1] - dn
+    R, C, E, W = lat.shape
+    a_head = R * C * E * (dn + dv) * lat.dtype.itemsize
+    hg = math.gcd(nh, max(4, 1 << max(int(_EXPAND_BYTES // a_head), 1
+                                      ).bit_length() - 1))
+    k_rope = lat[..., dc:dc + dr]
+    expand, attend = (expand_latent_rows, picked_heads_attention) \
+        if use_kernel else (expand_latent_rows_reference,
+                            picked_heads_attention_reference)
+
+    def group(args):
+        q, w = args                         # [hg, N, dk], [dc, hg, dn + dv]
+        k, v = expand(lat, w, reach, dc=dc, dn=dn)
+        return attend(q, k, k_rope, v, picked_t, tile_rows, bounds,
+                      scale=scale, tq=tq)
+
+    if hg == nh:
+        return group((q, wkv_b)).reshape(N, nh, dv)
+    out = jax.lax.map(group, (
+        q.reshape(nh // hg, hg, N, dk),
+        wkv_b.reshape(dc, nh // hg, hg, dn + dv).transpose(1, 0, 2, 3)))
+    return out.reshape(nh // hg, N, hg, dv).transpose(1, 0, 2, 3).reshape(
+        N, nh, dv)
+
+
+def _expanded_index_attention(q, qi, wi, pool, l, wkv_b, row_ids, lengths,
+                              block_tables, *, dc, dn, scale, topk,
+                              use_kernel):
+    """A PROMPT launch's read of a full latent layer that selects, in
+    the EXPANDED form (:func:`index_prompt_form`): the launch's tokens
+    packed in tiles of one row each (:func:`_row_tiles`), the same sets
+    (:func:`index_mask`, ``_INDEX_TILE`` packed tokens at a time, left
+    transposed as the kernel reads them: no pass over the launch's flags
+    between the two), then every token attends what it picked head by
+    head, ``q_nope |
+    q_rope`` against the per-head keys and values its row's cached
+    latent rows are turned into once for the launch and layer
+    (:func:`_expanded_picked_attention`): a third of the absorbed form's
+    products a position, and neither ``q_nope Wk^T`` nor ``o_lat Wv``.
+    The pool keeps latent rows: the expansion is a temporary. q ``[T,
+    nh, dn + dr]`` (the rotated part rotated) -> ``[T, nh, dv]``."""
+    from .kernels.ragged_attention import PICKED_CHUNK, picked_heads_tile
+    T, (R, MB) = q.shape[0], block_tables.shape
+    bs, W = pool["latent"].shape[2:]
+    with jax.named_scope("attn_kernel"):
+        tq = picked_heads_tile(T, R)
+        src, slot, tile_rows = _row_tiles(row_ids, lengths, R, tq)
+        at = jnp.minimum(src, T - 1)
+        bounds = jnp.where(src < T, lengths[at], 0).astype(jnp.int32)
+    tt = min(_INDEX_TILE, tq)
+    n = src.shape[0] // tt
+
+    def tile(args):
+        """a tile's flags, a token a lane; a tile that holds no token
+        (a row's last tile may be its only one) scores nothing"""
+        qi, wi, row, bounds = args
+        return jax.lax.cond(
+            bounds[0] > 0,
+            lambda: index_mask(qi, wi, pool["index_k"], l,
+                               jnp.full((tt,), row), bounds, block_tables,
+                               topk).T.astype(jnp.int8),
+            lambda: jnp.zeros((MB * bs, tt), jnp.int8))
+
+    picked_t = jax.lax.map(tile, (
+        qi[at].reshape(n, tt, *qi.shape[1:]), wi[at].reshape(n, tt, -1),
+        jnp.repeat(tile_rows, tq // tt), bounds.reshape(n, tt)))
+    with jax.named_scope("attn_kernel"):
+        # a row's positions in pieces of _EXPAND_PIECE, whole chunks of
+        # the kernel's (a table at its full width is no power of two):
+        # null pages behind, under no token's bound
+        ctx = MB * bs
+        E = next((e for e in (_EXPAND_PIECE, PICKED_CHUNK) if ctx > e), ctx)
+        tables = jnp.pad(block_tables, ((0, 0), (0, -MB % (E // bs))))
+        lat = pool["latent"][l, tables].reshape(R, -1, E, W)
+        reach = jnp.zeros((R,), jnp.int32).at[row_ids].max(
+            lengths.astype(jnp.int32))
+        out = _expanded_picked_attention(
+            q[at].transpose(1, 0, 2), lat, wkv_b, picked_t, tile_rows,
+            bounds, reach, dc=dc, dn=dn, scale=scale, tq=tq,
+            use_kernel=use_kernel)
+        return jnp.where((slot < src.shape[0])[:, None, None],
+                         out[jnp.minimum(slot, src.shape[0] - 1)], 0)
 
 
 def _query_latent(cfg, lp, hn, lk):
@@ -1129,17 +1293,23 @@ def _latent_attention_sublayer(cfg, lp, x, l, pool, cos, sin, row_ids,
                                lengths, write_blocks, write_offsets,
                                block_tables, use_kernel, one_token=False,
                                kind="mla"):
-    """Multi-head latent attention on flat tokens x [T, H], in the
-    ABSORBED form for prefill and decode alike: the new tokens' rows
-    (normed latent, rotated shared key part) go to layer ``l`` of the
-    pool, a head's query is carried into the latent's space by its slice
-    of ``wkv_b`` (``q_nope Wk^T``), attends the rows there
+    """Multi-head latent attention on flat tokens x [T, H]: the new
+    tokens' rows (normed latent, rotated shared key part) go to layer
+    ``l`` of the pool, and the tokens attend the pool's rows in one of
+    two forms of the same mathematics. ABSORBED (every decode step,
+    every layer without an indexer, a selecting prompt launch of few
+    tokens a row): a head's query is carried into the latent's space by
+    its slice of ``wkv_b`` (``q_nope Wk^T``), attends the rows there
     (``kernels/ragged_attention.latent_attention``) and its output
-    leaves that space by the value slice (``o_lat Wv``). The same
-    mathematics as expanding every cached position's keys and values
-    per head, which a long prefill would do more cheaply (ROADMAP M3).
-    ``one_token``: every row has exactly one token (a decode batch),
-    which the kernel is told.
+    leaves that space by the value slice (``o_lat Wv``). EXPANDED (a
+    selecting prompt launch of ``_EXPAND_TOKENS_A_ROW`` tokens a row or
+    more, :func:`index_prompt_form`): every cached position's keys and
+    values are made a head, once for the launch, and the query needs
+    neither product (:func:`_expanded_index_attention`): a third of the
+    absorbed form's products a position. A long prefill of a layer
+    WITHOUT an indexer still takes the absorbed form (ROADMAP M3: no
+    cell feeds one). ``one_token``: every row has exactly one token (a
+    decode batch), which the kernel is told.
 
     ``kind`` (a pattern over latent attention): whose sizes and whose
     leaf (``cfg.latent_kind``, ``LATENT_LEAVES``). "mla_window" sees
@@ -1149,10 +1319,12 @@ def _latent_attention_sublayer(cfg, lp, x, l, pool, cos, sin, row_ids,
     (``cfg.index_topk``) writes the indexer's key of every new token
     beside its row (``index_k``) and, where the tables hold more than
     ``index_topk`` positions, attends only the positions the indexer
-    picks a query token (:func:`_indexed_latent_attention`: a decode
-    step gathers them, a prompt's launch masks the rest); where they
-    hold no more, every position under a token's bound is picked and
-    the launch is the dense one. ``cfg.mla_lora_rescale``: each normed
+    picks a query token (a decode step gathers them, a prompt's launch
+    masks the rest: :func:`_indexed_latent_attention` over the latent
+    rows or :func:`_expanded_index_attention` over per-head keys and
+    values, by the launch's shapes); where they hold no more, every
+    position under a token's bound is picked and the launch is the
+    dense one. ``cfg.mla_lora_rescale``: each normed
     latent times sqrt(hidden / its rank). Returns (what attention adds
     to x, pool)."""
     from ...ops.norms import layer_norm, rms_norm
@@ -1190,18 +1362,32 @@ def _latent_attention_sublayer(cfg, lp, x, l, pool, cos, sin, row_ids,
             pool = _latent_write(pool, l, write_blocks, write_offsets,
                                  _rotate(ki, cos, sin), "index_k")
     wkv_b = lp["wkv_b"].reshape(dc, nh, dn + dv)
-    q_lat = jnp.einsum("thd,chd->htc", q[..., :dn], wkv_b[..., :dn])
-    qx = jnp.concatenate([q_lat, q_rope.transpose(1, 0, 2)], axis=-1)
-    qx = jnp.pad(qx, ((0, 0), (0, 0), (0, W - dc - dr)))    # [nh, T, W]
     scale = 1.0 / float(dn + dr) ** 0.5
-    if lk.topk and block_tables.shape[1] * pool[leaf].shape[2] > lk.topk:
+
+    def absorbed_query():
+        """a head's query carried into the latent's space, ``[nh, T, W]``"""
+        q_lat = jnp.einsum("thd,chd->htc", q[..., :dn], wkv_b[..., :dn])
+        qx = jnp.concatenate([q_lat, q_rope.transpose(1, 0, 2)], axis=-1)
+        return jnp.pad(qx, ((0, 0), (0, 0), (0, W - dc - dr)))
+
+    positions = block_tables.shape[1] * pool[leaf].shape[2]
+    form = index_prompt_form(T, block_tables.shape[0], positions, lk.topk)
+    if form:
         with jax.named_scope("indexer"):
             qi, wi = _index_queries(cfg, lp, hn, cq, cos, sin)
+    if form == "expanded":      # (never a decode step: one token a row)
+        o = _expanded_index_attention(
+            jnp.concatenate([q[..., :dn], q_rope], axis=-1), qi, wi, pool,
+            l, wkv_b, row_ids, lengths, block_tables, dc=dc, dn=dn,
+            scale=scale, topk=lk.topk, use_kernel=use_kernel)
+    elif form:
         o_lat = _indexed_latent_attention(
-            qx, qi, wi, pool, l, row_ids, lengths, block_tables, dc=dc,
-            scale=scale, topk=lk.topk, one_token=one_token,
-            use_kernel=use_kernel)
+            absorbed_query(), qi, wi, pool, l, row_ids, lengths,
+            block_tables, dc=dc, scale=scale, topk=lk.topk,
+            one_token=one_token, use_kernel=use_kernel)
+        o = jnp.einsum("htc,chd->thd", o_lat, wkv_b[..., dn:])
     else:
+        qx = absorbed_query()
         with jax.named_scope("attn_kernel"):
             rows, at = _latent_rows(pool, l, hn.dtype, leaf)
             window = {"window": lk.window} if lk.window else {}
@@ -1210,7 +1396,7 @@ def _latent_attention_sublayer(cfg, lp, x, l, pool, cos, sin, row_ids,
                 else functools.partial(latent_attention_reference, **window)
             o_lat = attend(qx, rows, at, row_ids, lengths, block_tables,
                            dc=dc, scale=scale)
-    o = jnp.einsum("htc,chd->thd", o_lat, wkv_b[..., dn:])
+        o = jnp.einsum("htc,chd->thd", o_lat, wkv_b[..., dn:])
     if cfg.attn_gate == "head":
         o = o * jax.nn.sigmoid(hn @ lp["wg"])[..., None].astype(o.dtype)
     return o.reshape(T, nh * dv) @ lp["wo"], pool

@@ -133,6 +133,40 @@ timing only: wrong sums):
 * ``tq-N``           the tile N tokens (``_token_tile``), alone and with each
                      of the others                             (--sweep)
 
+A SELECTING PROMPT LAUNCH OF A FULL LATENT LAYER (PR 69), ``dots3-picked``:
+1,024 tokens (and, ``dots3-picked-256`` / ``-128``, 256 and 128: either side of
+the rule's threshold, at context 8,192) of ONE row of ``dots3-note-prev`` (128 heads, ranks 512 + 64,
+``nope`` / ``v`` 128) that end at contexts 4,096, 8,192 and 16,384
+(``--contexts``) under a table of 34,816 (the cell's chunk steps ride tables
+at their full width), each token's picks ~2,048 of the positions under its
+bound, at random. The forms stand side by side as variants of one launch:
+
+* ``absorbed``        the masked latent kernel as the program ran it before
+                      PR 69, with the two projections round it (``q_nope
+                      Wk^T`` ahead, ``o_lat Wv`` behind)
+* ``absorbed-kernel`` ``latent_attention(picked=)`` alone
+* ``expanded``        the row's keys and values made a group of heads at a
+                      time (``latent_rows_expand``, the pieces under the
+                      row's reach alone) and the per-head kernel over them
+                      (``paged_model._expanded_picked_attention``): what the
+                      program runs now
+* ``kernel``          ``picked_heads_attention`` alone, over keys and values
+                      made ahead of the loop
+* ``no-mask`` / ``no-reduce``   that kernel without its mask (the picks'
+                      flags and the bound: neither built nor selected by),
+                      without its max and sum (``_tile_update`` replaced as
+                      the prompt shapes' variants replace it; timing only:
+                      wrong sums): what the softmax costs a score at 320
+                      products
+* ``tq-N`` / ``hs-N`` / ``chunk-N`` / ``heads-N``   the kernel's tile, its
+                      heads a step, its chunk (joined by ``+``: both); the
+                      heads expanded at once                   (--sweep)
+
+``flops`` is the EXPANDED form's count with a 192-wide score as the two
+128-wide passes the matrix unit makes of it, the row's expansion in it;
+``--check`` holds ``expanded`` to ``absorbed`` through the gathering
+reference on 32 sampled tokens.
+
 A tree that lacks a function a variant replaces (an older commit) skips that
 variant and says so. ``--check`` holds one launch of each shape to the
 gathering reference. Like the other chip scripts it exits non-zero without a
@@ -175,6 +209,7 @@ except ImportError:
     pr = None
 
 from deepspeed_tpu.moe import sharded_moe as sm               # noqa: E402
+from deepspeed_tpu.inference.v2 import paged_model as pm      # noqa: E402
 
 from benchmark import arith_window                            # noqa: E402
 
@@ -284,9 +319,26 @@ SHAPES = {
     "ling-share-gmm": dict(kernel="share_gmm", k=8, H=2560, F=768,
                            experts=512, held=128, layers=6, form="swiglu",
                            launch=16384, ends=(2048, 4096, 8192, 16384)),
+    # a selecting prompt launch of a full latent layer (PR 69): ``new``
+    # tokens of one row that end at each of ``ends``, ``topk`` picks a token
+    # under a table of ``table`` positions (the cell's chunk steps ride
+    # tables at their full width, 33,024, to whole pieces of 2,048)
+    "dots3-picked": dict(kernel="picked", new=1024, nh=128, dc=512, dr=64,
+                         dn=128, dv=128, W=640, topk=2048, table=34816,
+                         ends=(4096, 8192, 16384)),
+    # the same launch at fewer tokens a row: either side of the rule's
+    # threshold (``paged_model._EXPAND_TOKENS_A_ROW``)
+    "dots3-picked-256": dict(kernel="picked", new=256, nh=128, dc=512, dr=64,
+                             dn=128, dv=128, W=640, topk=2048, table=34816,
+                             ends=(8192,)),
+    "dots3-picked-128": dict(kernel="picked", new=128, nh=128, dc=512, dr=64,
+                             dn=128, dv=128, W=640, topk=2048, table=34816,
+                             ends=(8192,)),
 }
 # the family's name stands for its shapes under --only
-FAMILIES = {"dispatch-rows": [n for n, v in SHAPES.items()
+FAMILIES = {"dots3-picked": [n for n, v in SHAPES.items()
+                             if v["kernel"] == "picked"],
+            "dispatch-rows": [n for n, v in SHAPES.items()
                               if v["kernel"] == "dispatch"],
             "share-gmm": [n for n, v in SHAPES.items()
                           if v["kernel"] == "share_gmm"]}
@@ -715,6 +767,120 @@ def build_prompt(shape, end, rng, rehearse):
              arith_window.launch_flops(fields, launch, window)), ref)
 
 
+def build_picked(shape, end, rng, rehearse):
+    """One selecting prompt launch of a full latent layer: ``new``
+    tokens of one row that end at context ``end``, every form of it
+    behind ``fn.form`` (the variants set it; a fresh jit a timing). As
+    :func:`build_prompt` returns; the pools are the latent pool, the
+    up-projection, the flags and, for ``kernel`` and its levers, the
+    row's keys and values made ahead."""
+    new, nh, dc, dr, dn, dv, W, topk, ctx = (shape[k] for k in (
+        "new", "nh", "dc", "dr", "dn", "dv", "W", "topk", "table"))
+    if rehearse:
+        new, nh, dc, dr, dn, dv, W, topk, ctx, end = \
+            128, 4, 32, 16, 16, 16, 128, 64, 512, 256 + 128 * (end > 4096)
+    MB, T = ctx // BS, new
+    tables = jnp.asarray(rng.permutation(np.arange(1, MB + 1))[None],
+                         jnp.int32)
+    row_ids = jnp.zeros((T,), jnp.int32)
+    lengths = jnp.arange(end - new + 1, end + 1, dtype=jnp.int32)
+    key = jax.random.PRNGKey(int(rng.integers(1 << 30)))
+    dtype = jnp.float32 if rehearse else jnp.bfloat16
+    pool = jnp.pad(jax.random.normal(key, (1, MB + 1, BS, dc + dr), dtype),
+                   ((0, 0),) * 3 + ((0, W - dc - dr),))
+    wkv_b = (jax.random.normal(jax.random.fold_in(key, 1),
+                               (dc, nh, dn + dv), jnp.float32)
+             * dc ** -0.5).astype(dtype)
+    q = jax.random.normal(jax.random.fold_in(key, 2), (T, nh, dn + dr), dtype)
+    seen = jnp.arange(ctx)[None, :] < lengths[:, None]
+    picked = seen & (jax.random.uniform(jax.random.fold_in(key, 3), (T, ctx))
+                     < topk / lengths[:, None])
+    picked = picked.at[:, 0].set(True)
+    scale = float(dn + dr) ** -0.5
+    tq = ra.picked_heads_tile(T, 1)
+    # a row's positions in pieces, as the program lays them
+    E = pm._EXPAND_PIECE if ctx > pm._EXPAND_PIECE else ctx
+    pieces = (1, ctx // E, E, W)
+    lat = pool[0][tables].reshape(pieces)
+    k, v = ra.expand_latent_rows_reference(lat, wkv_b, dc=dc, dn=dn)
+    tile_rows = jnp.zeros((T // tq,), jnp.int32)
+    reach = jnp.full((1,), end, jnp.int32)
+
+    def absorbed(q, layer, pool, wkv_b, picked, attend, at=slice(None)):
+        q_lat = jnp.einsum("thd,chd->htc", q[..., :dn], wkv_b[..., :dn])
+        qx = jnp.pad(jnp.concatenate(
+            [q_lat, q[..., dn:].transpose(1, 0, 2)], -1),
+            ((0, 0), (0, 0), (0, W - dc - dr)))
+        o_lat = attend(qx, pool, layer, row_ids[at], lengths[at], tables,
+                       dc=dc, scale=scale, picked=picked)
+        return jnp.einsum("htc,chd->thd", o_lat, wkv_b[..., dn:])
+
+    def fn(q, layer, pool, wkv_b, picked, k, v):
+        form = fn.form
+        if form == "absorbed":
+            return absorbed(q, layer, pool, wkv_b, picked,
+                            ra.latent_attention)
+        if form == "absorbed-kernel":
+            qx = jnp.pad(q.transpose(1, 0, 2), ((0, 0), (0, 0),
+                                                (0, W - dn - dr)))
+            return ra.latent_attention(
+                qx, pool, layer, row_ids, lengths, tables, dc=dc,
+                scale=scale, picked=picked)[..., :dv].transpose(1, 0, 2)
+        # the flags a tile of tokens, a token a lane, as the program's
+        # loop over tiles leaves them
+        tt = min(pm._INDEX_TILE, tq)
+        picked_t = picked.reshape(T // tt, tt, ctx).transpose(
+            0, 2, 1).astype(jnp.int8)
+        if form.startswith(("expanded", "heads-")):
+            # (heads-N: the bytes that give a group of N heads)
+            room = int(form.split("-")[1]) * ctx * (dn + dv) \
+                * q.dtype.itemsize if form.startswith("heads-") \
+                else pm._EXPAND_BYTES
+            with patched(pm, _EXPAND_BYTES=room):
+                return pm._expanded_picked_attention(
+                    q.transpose(1, 0, 2), pool[layer][tables].reshape(
+                        pieces), wkv_b, picked_t, tile_rows, lengths, reach,
+                    dc=dc, dn=dn, scale=scale, tq=tq,
+                    use_kernel=not rehearse)
+        opts, t, update = {}, tq, ra._tile_update
+        for part in form.split("+"):        # "hs-8+chunk-1024": both
+            name, _, n = part.partition("-")
+            if part == "no-mask":           # the token tile's own levers
+                update = _update_without(mask=False)
+            elif part == "no-reduce":
+                update = _update_without(reduce=False)
+            elif name == "tq":
+                t = int(n)
+            elif name in ("hs", "chunk"):
+                opts[dict(hs="heads_a_step", chunk="chunk")[name]] = int(n)
+        with patched(ra, _tile_update=update):
+            return ra.picked_heads_attention(
+                q.transpose(1, 0, 2), k, pool[layer][tables].reshape(
+                    pieces)[..., dc:dc + dr], v, picked_t,
+                jnp.zeros((T // t,), jnp.int32), lengths, scale=scale, tq=t,
+                interpret=True if rehearse else None, **opts
+            ).reshape(T, nh, dv)
+    fn.form = "expanded"
+
+    sample = jnp.asarray(np.sort(rng.choice(T, 32, replace=False)))
+
+    def ref(q, layer, pool, wkv_b, picked, k, v):
+        return absorbed(q[sample], layer, pool, wkv_b, picked[sample],
+                        ra.latent_attention_reference, sample)
+    ref.tokens = sample
+
+    def again(q, out):
+        return q + out[..., :1] * 0
+    # the expanded form's operations, a 192-wide score as two passes of
+    # 128, and the row's expansion
+    attended = int(np.asarray(lengths, np.int64).sum())
+    lanes = -(-(dn + dr) // 128) * 128 + dv
+    flops = 2 * nh * (attended * lanes + end * dc * (dn + dv))
+    nbytes = (MB * BS * W + 2 * T * nh * (dn + dr)) * pool.dtype.itemsize
+    return (fn, again, q, (pool, wkv_b, picked, k, v), 1, (nbytes, flops),
+            ref)
+
+
 @contextlib.contextmanager
 def patched(module, **attrs):
     """``module``'s attributes replaced for the block; a KeyError names
@@ -887,6 +1053,14 @@ def variants(kernel, sweep, fn=None):
                 "copies": dict(_state_kernel=_retention_copies)}
     if kernel == "retention_chunk":
         return {"full": {}, "copies": dict(_distances_loop=_no_distances)}
+    if kernel == "picked":
+        forms = ["absorbed", "absorbed-kernel", "expanded", "kernel",
+                 "no-mask", "no-reduce"]
+        if sweep:
+            forms += ["tq-256", "tq-1024", "hs-2", "hs-8", "chunk-512",
+                      "chunk-2048", "hs-8+chunk-512", "tq-1024+hs-8",
+                      "heads-4", "heads-16"]
+        return {f: dict(form=f) for f in forms}
     if kernel == "prompt":
         parts = {"": {}, "copies": dict(_tile_update=_nothing),
                  "no-mask": dict(_tile_update=_update_without(mask=False)),
@@ -1014,11 +1188,14 @@ def main():
         state = kernel in in_place
         builder, module = in_place.get(
             kernel, (build, sm if kernel == "share_gmm" else ra))
-        if kernel in ("prompt", "share_gmm"):
-            fn, again, q, pools, L, (nbytes, flops), ref = (
-                build_prompt if kernel == "prompt" else build_share_gmm)(
+        if kernel in ("prompt", "share_gmm", "picked"):
+            fn, again, q, pools, L, (nbytes, flops), ref = dict(
+                prompt=build_prompt, share_gmm=build_share_gmm,
+                picked=build_picked)[kernel](
                 SHAPES[name], end, rng, args.rehearse)
-            row = {"shape": name, "context": end} if kernel == "prompt" \
+            if kernel == "picked":
+                module = fn         # a variant is a form of the launch
+            row = {"shape": name, "context": end} if kernel != "share_gmm" \
                 else {"shape": name, "run": fn.tokens,
                       "tree_run": fn.tree_run, "rows": fn.rows,
                       "held": fn.held, "rows_an_expert": fn.an_expert,
@@ -1048,7 +1225,7 @@ def main():
             got, want = (jax.tree.leaves(jax.jit(f, donate_argnums=given)(
                 q, L - 1, *(jnp.copy(p) if state else p for p in pools)))
                 for f in (fn, ref))
-            if kernel == "prompt":
+            if kernel in ("prompt", "picked"):
                 got = [got[0][ref.tokens]]
             if kernel == "dispatch":     # the carried ys holds NaN rows
                 got, want = got[:1], want[:1]
@@ -1070,7 +1247,8 @@ def main():
                 with patched(module, **attrs):
                     row[label] = round(time_launches(
                         timed, again, rows, pools, L, launches,
-                        carried=state, kept=kernel == "share_gmm"), 2)
+                        carried=state,
+                        kept=kernel in ("share_gmm", "picked")), 2)
                 if label == "aligned":
                     row["aligned_visits"] = timed.visits
                 if kernel == "share_gmm":

@@ -615,6 +615,42 @@ def test_the_two_latent_kinds_at_the_long_context_cells_shapes(
         assert LATENT_PATTERN.search(kernels[0]), kernels
 
 
+@pytest.mark.parametrize("tokens,rows,positions,room", [
+    (4096, 4, 34816, 1.6e9), (8192, 8, 34816, 2.1e9)])
+def test_the_expanded_picked_launch_at_the_long_context_cells_shapes(
+        tpu_sharding, tokens, rows, positions, room):
+    """A selecting prompt launch of ``dots3-note-prev``'s full layers in
+    the EXPANDED form (PR 69) at the cell's chunk step (4 rows x 1,024
+    tokens under tables at their full width, 33,024 positions in 17
+    pieces of 2,048) and at the largest the engine is sized for: the
+    rows' keys and values a group of heads at a time
+    (``latent_rows_expand``) and the per-head kernel over them compile
+    for the chip, each under its own name, the temporaries inside 1.6 GB
+    (2.1 at the largest: four heads at a time there)."""
+    from deepspeed_tpu.inference.v2 import paged_model as pm
+    from deepspeed_tpu.inference.v2.kernels.ragged_attention import \
+        picked_heads_tile
+    assert pm.index_prompt_form(tokens, rows, positions, 2048) == "expanded"
+    tq = picked_heads_tile(tokens, rows)
+    N = tokens + rows * tq
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=tpu_sharding)
+    compiled = jax.jit(lambda *a: pm._expanded_picked_attention(
+        *a, dc=512, dn=128, scale=192 ** -0.5, tq=tq, use_kernel=True)
+    ).lower(sds((128, N, 192)),
+            sds((rows, positions // pm._EXPAND_PIECE, pm._EXPAND_PIECE, 640)),
+            sds((512, 128, 256)), sds((N // 256, 33024, 256), jnp.int8),
+            sds((N // tq,), jnp.int32), sds((N,), jnp.int32),
+            sds((rows,), jnp.int32)).compile()
+    kernels = re.findall(r"%([\w.\-]+) = [^\n]*tpu_custom_call",
+                         compiled.as_text())
+    assert sorted(k.split(".")[0] for k in kernels) == [
+        "latent_rows_expand", "ragged_attention_latent_picked_heads"], kernels
+    assert not any(LATENT_PATTERN.search(k) for k in kernels)
+    assert compiled.memory_analysis().temp_size_in_bytes < room
+
+
 def _dots3_cell(tpu_sharding):
     """The cell's configuration, tree and cache as shapes on the chip."""
     import json
@@ -650,8 +686,10 @@ def test_the_long_context_cells_chunk_step_fits_the_chip(tpu_sharding,
     """The whole 8,192-token chunk step of ``dots3-note-prev``'s cell at
     published widths, under a table of 2,048 positions (the dense
     launch) and of 32,768 (the indexer, the selection and the picks laid
-    on the latent kernel's mask): it compiles for the chip inside the
-    15.0 GB of the cell's rule, each under its kernel's own name."""
+    on the mask of the per-head kernel over the rows' expanded keys and
+    values, PR 69: 12.79 GB by the compiler): it compiles for the chip
+    inside the 15.0 GB of the cell's rule, each under its kernel's own
+    name."""
     from deepspeed_tpu.inference.v2.paged_model import paged_ragged_step
     cfg, params, cache = _dots3_cell(tpu_sharding)
 
