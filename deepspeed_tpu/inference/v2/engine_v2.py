@@ -707,6 +707,12 @@ class InferenceEngineV2:
     def _init_telemetry(self):
         from ...telemetry import collector, get_registry
         collector.install_gc_hook()
+        # the calling thread's CPU, run-queue wait, switches and faults
+        # over every launch span and generate() call, and the judgement
+        # of a closed call's leaves (a ``host_stall``), a kind of leaf
+        # being its name under these attrs of its own and its launch's
+        self._host = collector.HostThread(
+            "generate", kind_attrs=("program", "ahead", "chunk"))
         reg = get_registry()
         self._m_moe_launches = reg.counter(
             "moe_launches_total",
@@ -2041,7 +2047,7 @@ class InferenceEngineV2:
         inside ``behind`` is masked by the state it hands on."""
         sm = self.state_manager
         with contextlib.ExitStack() as stack:
-            stack.enter_context(trace.span(
+            stack.enter_context(self._host.launch(
                 "decode_window", batch=len(uids), window=self.decode_window,
                 ahead=int(behind is not None), uids=[int(u) for u in uids],
                 **self._trace_attrs(uids)))
@@ -2302,12 +2308,12 @@ class InferenceEngineV2:
                     seq.adapter = self._uid_adapter.get(int(uid))
                     seq.adapter_slot = self._adapter_slot_of(uid)
             rb = ragged_batch.pack(entries, sm, full_width=chunk is not None)
-        with trace.span("ragged_step", rows=len(entries),
-                        tokens=rb.total_tokens,
-                        uids=[u for u, _ in entries],
-                        **(dict(chunk=chunk[0], chunks=chunk[1])
-                           if chunk is not None else {}),
-                        **self._trace_attrs(u for u, _ in entries)):
+        with self._host.launch(
+                "ragged_step", rows=len(entries), tokens=rb.total_tokens,
+                uids=[u for u, _ in entries],
+                **(dict(chunk=chunk[0], chunks=chunk[1])
+                   if chunk is not None else {}),
+                **self._trace_attrs(u for u, _ in entries)):
             with trace.span("ragged_dispatch"):
                 # a launch's two parts as leaves: the host arrays handed
                 # to the device, then the jit call alone
@@ -2790,7 +2796,8 @@ class InferenceEngineV2:
         attrs: what a call was is on the root and on ``ragged_step`` /
         ``decode_window``."""
         with trace.span("generate", rows=len(prompts),
-                        max_new_tokens=int(max_new_tokens)):
+                        max_new_tokens=int(max_new_tokens)) as root, \
+                self._host.call(root):
             with trace.span("gen_admit"):
                 uids = (list(uids) if uids is not None
                         else list(range(len(prompts))))
@@ -3003,4 +3010,6 @@ class InferenceEngineV2:
                         for uid in uids:
                             self.flush(uid)
                     rows = [np.asarray(o) for o in outs]
+        # behind the root: the call's leaves against their medians
+        self._host.judge()
         return rows

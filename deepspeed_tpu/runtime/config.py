@@ -332,9 +332,11 @@ from ..telemetry.anomaly import DiagnosticsConfig  # noqa: E402
 @dataclass
 class TelemetryConfig:
     """Unified telemetry layer (telemetry/registry.py + bridge.py).
-    ``enabled`` gates the TRAINING engine's registry series, the bridge
-    that flushes registry scalars into the monitor backends, and the
-    span->XLA-annotation mirroring; inference/serving instrumentation
+    ``enabled`` gates the TRAINING engine's registry series, its samples
+    of the calling thread and the judgement of a batch's spans
+    (telemetry/collector.HostThread), the bridge that flushes registry
+    scalars into the monitor backends, and the span->XLA-annotation
+    mirroring; inference/serving instrumentation
     records unconditionally (allocation-free hot path)."""
 
     enabled: bool = True

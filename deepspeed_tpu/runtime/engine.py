@@ -419,12 +419,16 @@ class DeepSpeedTpuEngine:
         self.telemetry_enabled = bool(tcfg.enabled)
         self.telemetry = get_registry()
         self.telemetry_bridge = None
+        self._host = None
         if not self.telemetry_enabled:
             self._init_diagnostics()   # attributes must exist either way
             return
         if tcfg.xla_annotations:
             trace.enable_xla_annotations(True)
         collector.install_gc_hook()
+        # the calling thread's usage over train_step and a batch, and the
+        # judgement of a batch's leaf spans (a ``host_stall``)
+        self._host = collector.HostThread("train")
         reg = self.telemetry
         self._tm_loss = reg.gauge("training_loss", "last train_batch loss")
         self._tm_gnorm = reg.gauge("training_grad_norm",
@@ -1896,6 +1900,20 @@ class DeepSpeedTpuEngine:
                     "dict of named fields; seqlen truncation is SKIPPED — "
                     "feed dict batches (or disable the curriculum block)")
         from ..telemetry import trace
+        host = self._host       # None with ``telemetry.enabled`` false
+        if host is None:
+            return self._train_spans(batch, trace.span)
+        with host.call():
+            loss = self._train_spans(batch, host.launch)
+        # behind the batch's spans: its leaves against their medians
+        host.judge()
+        return loss
+
+    def _train_spans(self, batch, launch) -> float:
+        """A batch's three top-level spans; ``launch`` opens the one
+        around the device step (``HostThread.launch``: the span between
+        two samples of the calling thread)."""
+        from ..telemetry import trace
         # step-phase spans (timeline.py): data sharding, the async device
         # dispatch, and the host sync that blocks on the compiled step —
         # the host-side split of a training step's wall time
@@ -1909,7 +1927,7 @@ class DeepSpeedTpuEngine:
             stall.beat("train_step")
             stall.set_active("train_step", True)
         self.tput_timer.start()
-        with trace.span("train_step", step=self.global_steps):
+        with launch("train_step", step=self.global_steps):
             with trace.span("train_device_dispatch"):
                 if self.param_offload_nvme:
                     metrics = self._train_batch_infinity(dev_batch)

@@ -5,7 +5,10 @@ depth) into a bounded deque — overhead is two ``perf_counter`` calls and
 one locked append, so the serving hot path can stay instrumented in
 production. ``with trace.span(...) as sp:`` hands the record itself:
 ``sp["duration_s"]`` is there once the block has closed, so a histogram
-beside a span observes the span's own duration and not a second clock.
+beside a span observes the span's own duration and not a second clock;
+``sp["attrs"] = {...}`` inside the block adds attrs that are known only
+at its close (the host thread's usage in a launch:
+:mod:`deepspeed_tpu.telemetry.collector`).
 ``export()`` drains a copy for offline analysis; ``durations(name)``
 feeds assertions and benchmarks.
 
@@ -56,6 +59,7 @@ _buffer: deque = deque(maxlen=_DEFAULT_CAPACITY)
 _xla_annotations = False
 _local = threading.local()
 _ids = itertools.count(1)
+_appended = 0       # spans put on the ring since the process began
 
 
 def enable_xla_annotations(on: bool = True) -> None:
@@ -127,12 +131,19 @@ def span(name: str, lane: Optional[str] = None, **attrs):
         ln = lane if lane is not None else current_lane()
         if ln is not None:
             rec["lane"] = ln
-        if attrs:
-            rec["attrs"] = attrs
-        # under _lock: export() snapshots the deque while other threads
-        # record, and set_capacity() swaps the buffer out entirely
-        with _lock:
-            _buffer.append(rec)
+        if attrs or "attrs" in rec:
+            # what the block set on its record, over what it opened with
+            rec["attrs"] = {**attrs, **rec.get("attrs", {})}
+        _append(rec)
+
+
+def _append(rec: Dict) -> None:
+    # under _lock: export() snapshots the deque while other threads
+    # record, and set_capacity() swaps the buffer out entirely
+    global _appended
+    with _lock:
+        _buffer.append(rec)
+        _appended += 1
 
 
 def _retroactive(name, start, duration_s, track, lane, attrs) -> Dict:
@@ -158,9 +169,7 @@ def record(name: str, start: float, duration_s: float,
     decode phase between first token and finish). Retroactive spans are
     top-level (no parent) on ``track`` (default: the calling thread's
     track) in fleet lane ``lane`` (default: the thread's lane)."""
-    rec = _retroactive(name, start, duration_s, track, lane, attrs)
-    with _lock:
-        _buffer.append(rec)
+    _append(_retroactive(name, start, duration_s, track, lane, attrs))
 
 
 def record_nowait(name: str, start: float, duration_s: float,
@@ -169,14 +178,41 @@ def record_nowait(name: str, start: float, duration_s: float,
     own thread (a ``gc.callbacks`` hook runs wherever a collection
     falls, inside ``export()``'s copy of the ring too): where the lock
     is taken the span is dropped and False returned, never waited for."""
+    global _appended
     rec = _retroactive(name, start, duration_s, None, None, attrs)
     if not _lock.acquire(blocking=False):
         return False
     try:
         _buffer.append(rec)
+        _appended += 1
     finally:
         _lock.release()
     return True
+
+
+def current_span_id() -> Optional[int]:
+    """The id of the span this thread is inside (None at top level):
+    what a span opened now would carry as ``parent``."""
+    return getattr(_local, "span_id", None)
+
+
+def mark() -> int:
+    """A place on the ring: :func:`since` gives what was recorded after
+    it. The count of spans recorded so far, whatever the ring has
+    dropped or :func:`clear` taken since."""
+    return _appended
+
+
+def since(mark: int) -> List[Dict]:
+    """The spans recorded since :func:`mark` returned ``mark`` (oldest
+    first; those the ring still holds): the tail alone is copied, so a
+    caller that reads one call's few hundred spans does not pay for the
+    ring's sixteen thousand."""
+    with _lock:
+        n = min(_appended - mark, len(_buffer))
+        tail = list(itertools.islice(reversed(_buffer), n))
+    tail.reverse()
+    return tail
 
 
 def export(name: Optional[str] = None) -> List[Dict]:

@@ -16,9 +16,11 @@ scope) that the ``prefill_*_ms.gen`` metrics read
 (``deepspeed_tpu.utils.xla_profile.scope_seconds``), then the call's
 idle gaps between launches, each by the leaf span the host was in
 (``gap_*_ms.gen``'s arithmetic, gap by gap), what the garbage
-collector did (``process_gc_*``, the ``gc_pause`` spans), which form brought
-the routed rows back (``moe_rows_combined_total``) and the runs a pass of
-a share's launches (``moe_share_runs_total``), then the cell's
+collector did (``process_gc_*``, the ``gc_pause`` spans), the call's
+host-thread totals and its ``host_stall`` records (leaf, cause, evidence)
+against the gaps they overlap (``telemetry/collector.py``), which form
+brought the routed rows back (``moe_rows_combined_total``) and the runs a
+pass of a share's launches (``moe_share_runs_total``), then the cell's
 per-layer metrics as ``benchmark/run.py`` would print them. A trace names
 an operation by its HLO instruction, and only the process that compiled
 the programs can say what scope an instruction was traced under
@@ -196,6 +198,70 @@ def collector_summary():
                if longest else ""))
 
 
+def host_thread_summary(ev):
+    """The traced call's host thread (``telemetry/collector.py``): what
+    its root says the calling thread used (CPU, run-queue wait,
+    switches, faults), the launches' share of it, and every
+    ``host_stall`` record that fell in the call: leaf, cause and
+    evidence, against the device's idle gaps it overlaps (the ring and
+    the device trace share a clock through the annotated spans). A
+    stall says what the host did; the gap beside it what that cost."""
+    from benchmark import tracing
+    from benchmark.readers.gen_gap_time import (clock_offset,
+                                                idle_between_launches)
+    from deepspeed_tpu.telemetry import trace
+    ring = trace.export()
+    roots = [s for s in ring if s["name"] == "generate"
+             and s.get("annotated")] or \
+        [s for s in ring if s["name"] == "generate"][-1:]
+    if not roots or "cpu_s" not in roots[0].get("attrs", {}):
+        return "host thread: this program samples no launch"
+    root = roots[0]
+    lo, hi = root["start"], root["start"] + root["duration_s"]
+    used = root["attrs"]
+    launches = [s for s in ring if s["parent"] == root["id"]
+                and "cpu_s" in s.get("attrs", {})]
+
+    def ms(v):
+        return "none" if v is None else f"{1e3 * v:.3f}"
+
+    waits = [s["attrs"]["runq_s"] for s in launches]
+    lines = [
+        f"host thread, the traced call ({root['duration_s']:.3f} s): CPU "
+        f"{ms(used['cpu_s'])} ms, run-queue wait {ms(used['runq_s'])} ms, "
+        f"switches {used['nvcsw']} voluntary / {used['nivcsw']} "
+        f"involuntary, major faults {used['majflt']}; in its "
+        f"{len(launches)} launch spans CPU "
+        f"{ms(sum(s['attrs']['cpu_s'] for s in launches))} ms, run-queue "
+        f"wait {ms(None if None in waits else sum(waits))} ms"]
+    stalls = [s for s in ring if s["name"] == "host_stall"]
+    mine = [s for s in stalls if lo <= s["start"] < hi]
+    lines.append(f"host_stall records: {len(stalls)} in the ring "
+                 f"({1e3 * sum(s['duration_s'] for s in stalls):.1f} ms), "
+                 f"{len(mine)} in the traced call")
+    offset = clock_offset(ev.host_spans(), ring)
+    planes = tracing.device_planes(ev.events)
+    gaps = idle_between_launches(ev.events, planes[0]) \
+        if offset is not None and planes else None
+    for s in mine:
+        a = s["attrs"]
+        at, end = s["start"], s["start"] + s["duration_s"]
+        idle = "the clocks do not pair"
+        if gaps is not None:
+            under = sum(max(0.0, min(end + offset, e) - max(at + offset, b))
+                        for b, e in gaps)
+            idle = f"{1e3 * under:.3f} ms of the device's idle gaps under it"
+        numbers = " ".join(f"{k}={a[k]}" for k in (
+            "cpu_s", "runq_s", "nvcsw", "nivcsw", "majflt", "gc_s",
+            "compiles"))
+        lines.append(
+            f"  +{1e3 * (at - lo):9.1f} ms  {a['leaf']}"
+            f"{' (' + a['program'] + ')' if a.get('program') else ''} "
+            f"{1e3 * s['duration_s']:.1f} ms over {1e3 * a['expected_s']:.1f}"
+            f": {a['cause']} [{a['where']}: {numbers}]; {idle}")
+    return "\n".join(lines)
+
+
 def rows_forms_summary():
     """Which form brought the expert layers' routed rows back from
     expert order, by program, over the whole process
@@ -279,6 +345,7 @@ def main(argv=None):
     print(render(listed, sums, args.program, args.top), flush=True)
     print(render_gaps(idle_gaps(ev)), flush=True)
     print(collector_summary(), flush=True)
+    print(host_thread_summary(ev), flush=True)
     print(rows_forms_summary(), flush=True)
     print(share_runs_summary(), flush=True)
     manifest = json.loads((REPO / "BENCHMARK.json").read_text())

@@ -272,7 +272,12 @@ def test_train_batch_runs_inside_five_spans():
     by_name = {s["name"]: s for s in ring}
     step, book = by_name["train_step"], by_name["train_bookkeeping"]
     assert book["start"] >= step["start"] + step["duration_s"]
-    assert book["depth"] == 0 and book["attrs"] == step["attrs"]
+    # the same step; train_step, a launch span, also carries the host
+    # thread's usage (telemetry/collector.py)
+    assert book["depth"] == 0 and book["attrs"] == {
+        "step": step["attrs"]["step"]}
+    assert {"cpu_s", "runq_s", "nvcsw", "nivcsw", "majflt"} \
+        == set(step["attrs"]) - {"step"}
     # with train_data, the three cover train_batch end to end
     assert by_name["train_data"]["start"] <= step["start"]
     assert engine._last_metrics["loss"] > 0

@@ -257,3 +257,123 @@ def test_train_telemetry_disabled_records_nothing(fresh_registry):
              "y": rng.standard_normal((1, gm, 16)).astype("f4")}
     engine.train_batch(batch=batch)
     assert fresh_registry.get("training_steps_total") is None
+
+
+# -- the host thread over launches and calls (telemetry/collector.py) -------
+_USAGE = {"cpu_s", "runq_s", "nvcsw", "nivcsw", "majflt"}
+
+
+def _sound_floor(monkeypatch):
+    """The judgement on, and deaf to the sandbox's neighbours: a leaf
+    has to run half a second over its median (a cold first call's
+    compiles do, and must be judged by nothing)."""
+    from deepspeed_tpu.telemetry import anomaly, collector, trace
+    monkeypatch.setattr(collector, "STALL_MIN_S", 0.5)
+    anomaly.reset()
+    trace.clear()
+
+
+def test_generate_samples_its_launches_and_ten_sound_calls_hold_no_stall(
+        tiny_model, fresh_registry, monkeypatch):
+    from deepspeed_tpu.telemetry import anomaly, trace
+    _sound_floor(monkeypatch)
+    model, params = tiny_model
+    eng = _engine(model, params)
+    rng = np.random.default_rng(0)
+    for _ in range(10):
+        prompts = [list(map(int, rng.integers(1, 127, n))) for n in (20, 7)]
+        eng.generate(prompts, max_new_tokens=24)
+    ring = trace.export()
+    by_name = {name: [s for s in ring if s["name"] == name]
+               for name in ("generate", "ragged_step", "decode_window")}
+    assert [len(v) for v in by_name.values()] == [10, 10, 30]
+    for spans in by_name.values():
+        for s in spans:
+            assert _USAGE <= set(s["attrs"]), s
+            # (the kernel splits a thread's time into user and system
+            # by the tick: their sum over an interval is out by one)
+            assert 0 <= s["attrs"]["cpu_s"] <= s["duration_s"] + 1e-2
+    # a root's CPU is its segments': the launches' and what lay between
+    for root in by_name["generate"]:
+        inside = sum(s["attrs"]["cpu_s"]
+                     for name in ("ragged_step", "decode_window")
+                     for s in by_name[name] if s["parent"] == root["id"])
+        assert inside <= root["attrs"]["cpu_s"] + 1e-6
+        assert root["attrs"]["rows"] == 2       # what it opened with stays
+    cpu = fresh_registry.get("host_thread_cpu_seconds_total")
+    total = sum(s.value for _, s in cpu.series())
+    assert total == pytest.approx(
+        sum(r["attrs"]["cpu_s"] for r in by_name["generate"]), rel=1e-6)
+    # a leaf carries none: two samples a launch, none a leaf
+    assert all("cpu_s" not in s.get("attrs", {}) for s in ring
+               if s["name"] in ("window_fetch", "window_call",
+                                "gen_schedule", "ragged_call"))
+    assert [s for s in ring if s["name"] == "host_stall"] == []
+    assert [v for v in anomaly.recent() if v["kind"] == "host_stall"] == []
+    assert fresh_registry.family_total("host_stalls_total") == 0
+    # put() alone: its launch carries the attrs and counts as a launch
+    eng.put([7], [prompts[0]])
+    eng.flush(7)
+    assert _USAGE <= set(trace.export("ragged_step")[-1]["attrs"])
+
+
+def test_train_batch_samples_its_step_and_ten_sound_steps_hold_no_stall(
+        fresh_registry, monkeypatch):
+    from deepspeed_tpu.telemetry import anomaly, trace
+    from tests.unit.simple_model import SimpleModel, base_config
+    _sound_floor(monkeypatch)
+    engine, _, _, _ = deepspeed_tpu.initialize(
+        model=SimpleModel(hidden_dim=16), config=base_config(micro=2,
+                                                             lr=1e-2))
+    gm = engine.micro_batch_size * engine.ds_config.dp_world_size
+    rng = np.random.default_rng(0)
+    batch = {"x": rng.standard_normal((1, gm, 16)).astype("f4"),
+             "y": rng.standard_normal((1, gm, 16)).astype("f4")}
+    for _ in range(10):
+        engine.train_batch(batch=batch)
+    steps = trace.export("train_step")
+    assert len(steps) == 10
+    for s in steps:
+        assert _USAGE <= set(s["attrs"]) and "step" in s["attrs"]
+        assert s["parent"] is None          # no root was put over it
+    cpu = fresh_registry.get("host_thread_cpu_seconds_total")
+    assert cpu.labels(path="train", where="launch").value == pytest.approx(
+        sum(s["attrs"]["cpu_s"] for s in steps))
+    assert cpu.labels(path="train", where="between").value > 0
+    assert trace.export("host_stall") == []
+    assert [v for v in anomaly.recent() if v["kind"] == "host_stall"] == []
+    # every leaf of a batch has a history of its own kind
+    assert {k[0] for k in engine._host._kept} == {
+        "train_data", "train_device_dispatch", "train_host_sync",
+        "train_bookkeeping"}
+    assert all(len(v) == 10 for v in engine._host._kept.values())
+
+
+def test_train_telemetry_disabled_samples_and_judges_nothing(
+        fresh_registry, monkeypatch):
+    """``telemetry: {enabled: false}`` means what it meant: the spans
+    are there, and no sample, series or judgement of the host thread."""
+    from deepspeed_tpu.telemetry import anomaly, collector, trace
+    from tests.unit.simple_model import SimpleModel, base_config
+    monkeypatch.setattr(collector, "STALL_MIN_S", 0.0)
+    monkeypatch.setattr(collector, "STALL_OVER", 0.0)
+    anomaly.reset()
+    trace.clear()
+    cfg = base_config(micro=2, lr=1e-2)
+    cfg["telemetry"] = {"enabled": False}
+    engine, _, _, _ = deepspeed_tpu.initialize(
+        model=SimpleModel(hidden_dim=16), config=cfg)
+    assert engine._host is None
+    gm = engine.micro_batch_size * engine.ds_config.dp_world_size
+    rng = np.random.default_rng(0)
+    batch = {"x": rng.standard_normal((1, gm, 16)).astype("f4"),
+             "y": rng.standard_normal((1, gm, 16)).astype("f4")}
+    for _ in range(10):
+        engine.train_batch(batch=batch)
+    steps = trace.export("train_step")
+    assert len(steps) == 10
+    assert all(set(s["attrs"]) == {"step"} for s in steps)
+    assert fresh_registry.get("host_thread_cpu_seconds_total") is None
+    assert fresh_registry.get("host_stalls_total") is None
+    assert trace.export("host_stall") == []
+    assert [v for v in anomaly.recent() if v["kind"] == "host_stall"] == []
