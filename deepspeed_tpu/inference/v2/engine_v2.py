@@ -46,7 +46,8 @@ from .kernels.ragged_attention import (LATENT, decode_positions,
                                        launch_copies, one_token_tile_serves,
                                        prompt_chunks, prompt_walks,
                                        token_tile, token_tile_serves)
-from .paged_model import (STATE_LEAVES, index_prompt_form, init_lora_bank,
+from .paged_model import (STATE_LEAVES, index_positions_swept,
+                          index_prompt_form, init_lora_bank,
                           init_paged_kv_cache,
                           moe_rows_form, moe_share_runs, paged_continue,
                           paged_decode, paged_decode_window,
@@ -890,9 +891,20 @@ class InferenceEngineV2:
             labelnames=("form",))
         self._m_index_scored = reg.counter(
             "inference_index_positions_scored_total",
-            "cached positions the indexer scored for those queries (a "
-            "token's bound, under the launches that select; 0 under the "
-            "dense ones)", labelnames=("program",))
+            "cached positions the model's equations have the indexer "
+            "score for those queries: a token's bound, under the launches "
+            "that select (0 under the dense ones). The LEAST a program "
+            "can score, not what it did: "
+            "inference_index_positions_swept_total", labelnames=("program",))
+        self._m_index_swept = reg.counter(
+            "inference_index_positions_swept_total",
+            "cached positions the indexer's products COVERED for those "
+            "queries: a token's tile scores whole chunks of 4,096 "
+            "positions as far as the tile's largest bound reaches "
+            "(paged_model.index_positions_swept: from the rows' tokens "
+            "and contexts at the launch, no device read); over "
+            "inference_index_positions_scored_total the over-scoring, 1 "
+            "at the least", labelnames=("program",))
         self._m_prompt_chunks = reg.counter(
             "inference_attention_prompt_chunks_total",
             "chunk visits of the token tile's launches (a ragged step's "
@@ -1871,9 +1883,12 @@ class InferenceEngineV2:
         the positions and the copies under the attention launches are
         counted from."""
         if self.model.cfg.index_topk:
+            bounds, taken = self._decode_bounds(uids, steps_left, in_flight)
+            rows, step = np.nonzero(taken)
             self._note_index_reads(
-                "decode", self._decode_bounds(uids, steps_left, in_flight)[0],
-                tables.shape[1])
+                "decode", [(rows[step == s], bounds[step == s])
+                           for s in range(taken.shape[1])], tables.shape,
+                tables.shape[0])
         if not self._use_kernel:
             return
         cache = self.kv_cache
@@ -1905,34 +1920,50 @@ class InferenceEngineV2:
         taken = step < np.asarray(steps_left)[:, None]
         return (start[:, None] + 1 + step)[taken], taken
 
-    def _note_index_reads(self, program: str, bounds, table_pages: int):
+    def _note_index_reads(self, program: str, launches, tables,
+                          tokens: int):
         """What the full latent layers of a model with an indexer did
-        under a launch of ``program`` whose query tokens' causal bounds
-        are ``bounds`` (a whole number a token: its own position
-        included) over tables of ``table_pages`` places: a query a token
-        and full layer; the positions it ATTENDED, ``min(bound,
-        index_topk)``; the positions whose rows its attention READ: as
-        many in a decode step (its tokens gather what they picked), the
-        whole bound in a prompt's launch (the row's pages once, the
-        picks a mask) and under tables of no more than ``index_topk``
-        positions (the dense launch); and the positions the indexer
-        scored for it, its bound where the launch selected at all. Host
-        arithmetic on what the manager holds."""
+        under ``launches`` of ``program`` (a ragged step; each step of a
+        decode window), each ``(row_ids, bounds)`` of its query tokens
+        in pack order: the table row a token belongs to and its causal
+        bound (a whole number: its own position included), over tables
+        of shape ``tables`` and a token bucket of ``tokens`` (a decode
+        step: a token a table row). A query a token and full layer; the
+        positions it ATTENDED, ``min(bound, index_topk)``; the positions
+        whose rows its attention READ: as many in a decode step (its
+        tokens gather what they picked), the whole bound in a prompt's
+        launch (the row's pages once, the picks a mask) and under tables
+        of no more than ``index_topk`` positions (the dense launch);
+        and, where the launch selected at all, the positions the
+        equations have the indexer score for it (its bound), the
+        positions the program's products covered
+        (``paged_model.index_positions_swept``) and the form a prompt's
+        launch took. Host arithmetic on what the manager holds."""
         cfg = self.model.cfg
-        bounds = np.asarray(bounds, np.int64)
+        table_rows, table_pages = tables
+        ctx = table_pages * self.block_size
+        form = index_prompt_form(tokens, table_rows, ctx, cfg.index_topk)
+        if form and program == "decode":
+            form = "decode"
+        bounds = np.concatenate([b for _, b in launches]).astype(np.int64)
         layers = cfg.layer_kinds.count("mla")
-        picked = table_pages * self.block_size > cfg.index_topk
         attended = int(np.minimum(bounds, cfg.index_topk).sum())
         self._m_index_queries.labels(program=program).inc(
             layers * bounds.size)
         self._m_index_attended.labels(program=program).inc(
             layers * attended)
         self._m_index_read.labels(program=program).inc(layers * (
-            attended if picked and program == "decode"
-            else int(bounds.sum())))
-        if picked:
-            self._m_index_scored.labels(program=program).inc(
-                layers * int(bounds.sum()))
+            attended if form == "decode" else int(bounds.sum())))
+        if not form:
+            return
+        self._m_index_scored.labels(program=program).inc(
+            layers * int(bounds.sum()))
+        self._m_index_swept.labels(program=program).inc(layers * sum(
+            index_positions_swept(form, rows, b, tokens, table_rows, ctx)
+            for rows, b in launches if len(b)))
+        if program != "decode":
+            self._m_index_prompt_launches.labels(form=form).inc(
+                layers * len(launches))
 
     def _note_decode_positions(self, uids, steps_left, tables, in_flight):
         """The positions under the one-token form's launches of these
@@ -2346,16 +2377,13 @@ class InferenceEngineV2:
             self._note_prompt_chunks(entries, rb)
             if self.model.cfg.index_topk:
                 seqs = self.state_manager.seqs
-                self._note_index_reads("ragged_step", np.concatenate([
-                    seqs[uid].seen_tokens + 1 + np.arange(len(toks))
-                    for uid, toks in entries]), rb.block_tables.shape[1])
-                form = index_prompt_form(
-                    rb.token_bucket, rb.row_bucket,
-                    rb.block_tables.shape[1] * self.block_size,
-                    self.model.cfg.index_topk)
-                if form:
-                    self._m_index_prompt_launches.labels(form=form).inc(
-                        self.model.cfg.layer_kinds.count("mla"))
+                self._note_index_reads("ragged_step", [(
+                    np.repeat(np.arange(len(entries)),
+                              [len(toks) for _, toks in entries]),
+                    np.concatenate([
+                        seqs[uid].seen_tokens + 1 + np.arange(len(toks))
+                        for uid, toks in entries]))],
+                    rb.block_tables.shape, rb.token_bucket)
             self._note_state_rows("ragged_step", len(entries),
                                   rb.total_tokens)
             if self._has_state:

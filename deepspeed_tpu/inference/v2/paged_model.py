@@ -55,6 +55,7 @@ from typing import Any, Dict, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ...models.transformer import TransformerConfig, out_proj, qkv_proj
 
@@ -870,65 +871,121 @@ def _latent_rows(pool, l, dtype, leaf="latent"):
 
 
 # tokens a tile of the indexed read, and cached positions a chunk of a
-# tile's indexer scores: a tile's scores a head are ``[tokens, heads,
-# chunk]`` float32 before the heads are summed (256 x 64 x 4,096: 268 MB
-# where XLA makes them at all), a tile's scores ``[tokens, positions]``
-# (256 x 32,768 float32: 34 MB) and its flags as many bf16; the score
-# matrix of a launch is never whole
+# tile's indexer scores. A tile scores the chunks its largest bound
+# reaches and no further (a chunk step's table is as wide as a row can
+# grow, 33,024 positions, whatever the context). A chunk is ``[tokens,
+# heads, chunk]`` products that XLA fuses with ``relu``, the weights and
+# the sum over heads: 256 x 64 x 4,096 x 128 x 2 = 1.7e10 operations, 94 us
+# on a v5e, 92 % of the matrix unit's peak (PERF.md section 5, PR 71); the
+# per-head products never lie in HBM. What a tile keeps is the chunk's
+# ``[tokens, chunk]`` float32 (4 MB) laid into its scores ``[tokens,
+# positions]`` (34 MB) and its flags, as many bf16; the score matrix of a
+# launch is never whole. 4,096: the fusion is at its rate there, and a
+# finer chunk would save at most the last chunk's half a tile (a few
+# percent of a long row's products) for more trips, gathers and writes
 _INDEX_TILE = 256
 _INDEX_CHUNK = 4096
-
-
-def _index_scores(qi, wi, keys):
-    """The indexer's score of every query token against every cached
-    key: ``I[t, s] = sum_j w[t, j] * relu(q[t, j] . k[s])``. qi
-    ``[t, heads, d]``, wi ``[t, heads]`` float32 (the head's weight with
-    both scales in it), keys ``[c, d]`` -> ``[t, c]`` float32, in chunks
-    of ``_INDEX_CHUNK`` keys where there are more."""
-    def chunk(k):
-        s = jnp.einsum("tjd,cd->tjc", qi, k,
-                       preferred_element_type=jnp.float32)
-        return jnp.einsum("tjc,tj->tc", jax.nn.relu(s), wi)
-    c = keys.shape[0]
-    if c <= _INDEX_CHUNK:
-        return chunk(keys)
-    n = -(-c // _INDEX_CHUNK)
-    keys = jnp.pad(keys, ((0, n * _INDEX_CHUNK - c), (0, 0)))
-    out = jax.lax.map(chunk, keys.reshape(n, _INDEX_CHUNK, -1))
-    return out.transpose(1, 0, 2).reshape(qi.shape[0], -1)[:, :c]
 
 
 def _token_scores(qi, wi, keys, l, row_ids, lengths, block_tables,
                   one_token):
     """``(scores [t, ctx] float32, seen [t, ctx])``: the indexer's score
-    of each of a tile's query tokens against every position its row's
-    table holds, and which of them lie under the token's causal bound
-    ``lengths``. qi ``[t, heads, d]`` and wi ``[t, heads]`` as
-    :func:`_index_scores` takes them, keys the ``index_k`` leaf whole,
-    ``l`` the layer's place in it. A tile's tokens may belong to several
-    rows (a ragged launch): each row's keys are gathered through its
-    block table and scored against the tile, and a token keeps its own
-    row's scores; ``one_token``: every token is its own row, scored in
-    one batched product."""
+    of each of a tile's query tokens against the positions its row's
+    table holds, ``I[t, s] = sum_j w[t, j] * relu(q[t, j] . k[s])``, and
+    which of them lie under the token's causal bound ``lengths``. qi
+    ``[t, heads, d]``, wi ``[t, heads]`` float32 (the head's weight with
+    both scales in it), keys the ``index_k`` leaf whole, ``l`` the
+    layer's place in it. Scored are the chunks of ``_INDEX_CHUNK``
+    positions under the tile's REACH (its largest bound, a traced value:
+    a loop of as many trips), each chunk's keys gathered through its
+    pages of the table; what lies past them is zeros that no token sees.
+    A table of no whole number of chunks lays its last chunk against its
+    end (positions scored twice read the same). A tile's tokens may
+    belong to several rows (a ragged launch): each row's keys are scored
+    against the tile, and a token keeps its own row's scores;
+    ``one_token``: every token is its own row, scored in one batched
+    product a chunk."""
     R, MB = block_tables.shape
-    ctx = MB * keys.shape[2]
+    bs = keys.shape[2]
+    ctx = MB * bs
+    chunk = min(_INDEX_CHUNK, ctx)
+    pages = chunk // bs
     seen = jnp.arange(ctx)[None, :] < lengths[:, None]
-    with jax.named_scope("indexer"):
-        if one_token:
-            kg = keys[l, block_tables[row_ids]].reshape(len(row_ids), ctx, -1)
-            s = jnp.einsum("tjd,tcd->tjc", qi, kg,
-                           preferred_element_type=jnp.float32)
-            return jnp.einsum("tjc,tj->tc", jax.nn.relu(s), wi), seen
-        live = lengths > 0
-        lo = jnp.min(jnp.where(live, row_ids, R))
-        hi = jnp.max(jnp.where(live, row_ids, -1))
+    live = lengths > 0
+    lo = jnp.min(jnp.where(live, row_ids, R))
+    hi = jnp.max(jnp.where(live, row_ids, -1))
 
-        def row(r, acc):
-            kr = keys[l, block_tables[r]].reshape(ctx, -1)
-            return jnp.where((row_ids == r)[:, None],
-                             _index_scores(qi, wi, kr), acc)
+    def scores(product, k):
+        s = jnp.einsum(product, qi, k, preferred_element_type=jnp.float32)
+        return jnp.einsum("tjc,tj->tc", jax.nn.relu(s), wi)
+
+    def one(c, acc):
+        first = jnp.minimum(c * pages, MB - pages)
+        if one_token:
+            at = jax.lax.dynamic_slice_in_dim(block_tables[row_ids], first,
+                                              pages, axis=1)
+            s = scores("tjd,tcd->tjc",
+                       keys[l, at].reshape(len(row_ids), chunk, -1))
+        else:
+            def row(r, s):
+                at = jax.lax.dynamic_slice(block_tables, (r, first),
+                                           (1, pages))[0]
+                return jnp.where(
+                    (row_ids == r)[:, None],
+                    scores("tjd,cd->tjc", keys[l, at].reshape(chunk, -1)), s)
+            s = jax.lax.fori_loop(
+                lo, hi + 1, row, jnp.zeros((qi.shape[0], chunk), jnp.float32))
+        return jax.lax.dynamic_update_slice_in_dim(acc, s, first * bs, axis=1)
+
+    with jax.named_scope("indexer"):
         return jax.lax.fori_loop(
-            lo, hi + 1, row, jnp.zeros((qi.shape[0], ctx), jnp.float32)), seen
+            0, index_chunks(jnp.max(lengths), ctx), one,
+            jnp.zeros((qi.shape[0], ctx), jnp.float32)), seen
+
+
+def index_chunks(reach, ctx: int):
+    """Chunks of the indexer's scores under a tile's ``reach`` (its
+    largest bound; a traced or a host's whole number) over tables of
+    ``ctx`` positions: whole chunks of ``min(_INDEX_CHUNK, ctx)``."""
+    return -(-reach // min(_INDEX_CHUNK, ctx))
+
+
+def index_positions_swept(form, row_ids, bounds, tokens: int,
+                          table_rows: int, ctx: int) -> int:
+    """Cached positions the indexer's PRODUCTS cover for the query tokens
+    of one launch (a ragged step, or one step of a decode window), a
+    full layer: a token's count is the whole chunks under its TILE's
+    reach (:func:`_token_scores`), times the rows the tile's tokens
+    belong to where a tile is scored against each (the absorbed form).
+    Over the bounds' sum (the least the equations ask: a query scores
+    what lies under its bound) it is the over-scoring. ``form``:
+    :func:`index_prompt_form`'s answer for the launch's static shapes
+    (``tokens`` its token bucket, ``table_rows`` and ``ctx`` its tables'
+    rows and positions), or "decode"; ``row_ids`` / ``bounds`` ``[n]``
+    the launch's tokens in pack order (numpy; a row's tokens together,
+    rows ascending). "expanded" packs a row's tokens in tiles of their
+    own (:func:`_row_tiles`), "absorbed" tiles the launch as it lies, a
+    decode step its table rows. Host arithmetic, no device read: what the
+    engine's ``inference_index_positions_swept_total`` is told."""
+    from .kernels.ragged_attention import picked_heads_tile
+    row_ids, bounds = np.asarray(row_ids), np.asarray(bounds, np.int64)
+    chunk = min(_INDEX_CHUNK, ctx)
+
+    place = np.arange(len(bounds))
+    if form == "expanded":
+        tt = min(_INDEX_TILE, picked_heads_tile(tokens, table_rows))
+        tile_of = row_ids * len(place) + (
+            place - np.searchsorted(row_ids, row_ids)) // tt
+    else:
+        tile_of = (row_ids if form == "decode" else place) // min(
+            _INDEX_TILE, tokens)
+    total = 0
+    for t in np.unique(tile_of):
+        at = np.flatnonzero(tile_of == t)
+        rows = int(np.ptp(row_ids[at])) + 1 if form == "absorbed" else 1
+        total += len(at) * rows * chunk * int(
+            index_chunks(bounds[at].max(), ctx))
+    return total
 
 
 def index_select(qi, wi, keys, l, row_ids, lengths, block_tables, topk,

@@ -167,6 +167,25 @@ bound, at random. The forms stand side by side as variants of one launch:
 ``--check`` holds ``expanded`` to ``absorbed`` through the gathering
 reference on 32 sampled tokens.
 
+A TILE OF THE INDEXER of such a launch (PR 71), ``dots3-indexer``: 256 tokens
+of one row whose largest bound (the tile's REACH) is 4,096, 8,192, 16,384 and
+33,024 (``--contexts``) under a table of 33,024 positions, 64 heads of 128:
+
+* ``scores``          ``paged_model._token_scores``: the chunks of 4,096 keys
+                      the reach asks for, gathered through the table, the
+                      products with ``relu``, the weights and the sum over
+                      heads behind them
+* ``flags``           ``paged_model.index_mask`` turned as the program's tile
+                      leaves it (int8, a token a lane): the scores and the
+                      2,048 picks a token found by counting
+
+``flops`` is what the model's equations ask (a token's bound), ``swept_flops``
+what the tree's products cover (whole chunks under the reach; the whole table
+in a tree from before PR 71) and ``scores_peak_share`` / ``flags_peak_share``
+that over the time at the matrix unit's peak: the fusion's rate, read and not
+inferred. ``--check`` holds the scores under every bound to one full-width
+product.
+
 A tree that lacks a function a variant replaces (an older commit) skips that
 variant and says so. ``--check`` holds one launch of each shape to the
 gathering reference. Like the other chip scripts it exits non-zero without a
@@ -334,6 +353,11 @@ SHAPES = {
     "dots3-picked-128": dict(kernel="picked", new=128, nh=128, dc=512, dr=64,
                              dn=128, dv=128, W=640, topk=2048, table=34816,
                              ends=(8192,)),
+    # a tile of the indexer of that launch (PR 71): ``new`` tokens of one
+    # row whose largest bound is each of ``ends``
+    "dots3-indexer": dict(kernel="indexer", new=256, heads=64, d=128,
+                          topk=2048, table=33024,
+                          ends=(4096, 8192, 16384, 33024)),
 }
 # the family's name stands for its shapes under --only
 FAMILIES = {"dots3-picked": [n for n, v in SHAPES.items()
@@ -881,6 +905,56 @@ def build_picked(shape, end, rng, rehearse):
             ref)
 
 
+def build_indexer(shape, end, rng, rehearse):
+    """One tile of the indexer of a selecting prompt launch: ``new``
+    tokens of one row whose bounds end at ``end`` (the tile's reach)
+    over the row's index keys, ``fn.form`` "scores" or "flags" (the
+    variants set it). As :func:`build_picked` returns; the operations
+    are ``(the equations', the tree's products')``."""
+    new, heads, d, topk, ctx = (shape[k] for k in (
+        "new", "heads", "d", "topk", "table"))
+    if rehearse:
+        new, heads, d, topk, ctx, end = 16, 2, 8, 8, 512, min(end // 64, 512)
+    MB = ctx // BS
+    tables = jnp.asarray(rng.permutation(np.arange(1, MB + 1))[None],
+                         jnp.int32)
+    row_ids = jnp.zeros((new,), jnp.int32)
+    lengths = jnp.arange(end - new + 1, end + 1, dtype=jnp.int32)
+    key = jax.random.PRNGKey(int(rng.integers(1 << 30)))
+    dtype = jnp.float32 if rehearse else jnp.bfloat16
+    keys = jax.random.normal(key, (1, MB + 1, BS, d), dtype)
+    qi = jax.random.normal(jax.random.fold_in(key, 1), (new, heads, d), dtype)
+    wi = jax.random.normal(jax.random.fold_in(key, 2), (new, heads),
+                           jnp.float32) * (heads * d) ** -0.5
+    seen = jnp.arange(ctx)[None, :] < lengths[:, None]
+
+    def fn(qi, layer, keys, wi):
+        if fn.form == "flags":
+            return pm.index_mask(qi, wi, keys, layer, row_ids, lengths,
+                                 tables, topk).T.astype(jnp.int8)
+        scores = pm._token_scores(qi, wi, keys, layer, row_ids, lengths,
+                                  tables, False)[0]
+        return jnp.where(seen, scores, 0) if fn.form == "check" else scores
+    fn.form = "scores"
+
+    def ref(qi, layer, keys, wi):
+        s = jnp.einsum("tjd,cd->tjc", qi, keys[layer][tables[0]].reshape(
+            ctx, d), preferred_element_type=jnp.float32)
+        return jnp.where(seen, jnp.einsum("tjc,tj->tc", jax.nn.relu(s), wi),
+                         0)
+
+    def again(qi, out):
+        return qi + (out[0, 0].astype(jnp.float32) * 0).astype(qi.dtype)
+    chunk = min(pm._INDEX_CHUNK, ctx)
+    chunks = -(-end // chunk) if hasattr(pm, "index_chunks") \
+        else -(-ctx // chunk)
+    a_position = 2 * heads * d
+    flops = (int(np.asarray(lengths, np.int64).sum()) * a_position,
+             new * chunks * chunk * a_position)
+    nbytes = end * d * keys.dtype.itemsize + new * end * 4
+    return fn, again, qi, (keys, wi), 1, (nbytes, flops), ref
+
+
 @contextlib.contextmanager
 def patched(module, **attrs):
     """``module``'s attributes replaced for the block; a KeyError names
@@ -1061,6 +1135,8 @@ def variants(kernel, sweep, fn=None):
                       "chunk-2048", "hs-8+chunk-512", "tq-1024+hs-8",
                       "heads-4", "heads-16"]
         return {f: dict(form=f) for f in forms}
+    if kernel == "indexer":
+        return {f: dict(form=f) for f in ("scores", "flags")}
     if kernel == "prompt":
         parts = {"": {}, "copies": dict(_tile_update=_nothing),
                  "no-mask": dict(_tile_update=_update_without(mask=False)),
@@ -1188,12 +1264,15 @@ def main():
         state = kernel in in_place
         builder, module = in_place.get(
             kernel, (build, sm if kernel == "share_gmm" else ra))
-        if kernel in ("prompt", "share_gmm", "picked"):
+        if kernel in ("prompt", "share_gmm", "picked", "indexer"):
             fn, again, q, pools, L, (nbytes, flops), ref = dict(
                 prompt=build_prompt, share_gmm=build_share_gmm,
-                picked=build_picked)[kernel](
+                picked=build_picked, indexer=build_indexer)[kernel](
                 SHAPES[name], end, rng, args.rehearse)
-            if kernel == "picked":
+            swept = None
+            if kernel == "indexer":
+                flops, swept = flops
+            if kernel in ("picked", "indexer"):
                 module = fn         # a variant is a form of the launch
             row = {"shape": name, "context": end} if kernel != "share_gmm" \
                 else {"shape": name, "run": fn.tokens,
@@ -1202,6 +1281,9 @@ def main():
                       "visits": fn.visits, "tiles": fn.tiles}
             row.update(flops=flops,
                        flops_us=round(flops / PEAK_FLOPS_S * 1e6, 2))
+            if swept:
+                row.update(swept_flops=swept, swept_us=round(
+                    swept / PEAK_FLOPS_S * 1e6, 2))
             launches = max(2, args.launches // 10)
         else:
             fn, again, q, pools, L, nbytes, ref = builder(
@@ -1222,6 +1304,8 @@ def main():
             # cache does: a kernel that colours its leaf HBM cannot take
             # a copy the compiler made (kernels/slot_leaf.py)
             given = tuple(range(2, 2 + len(pools))) if state else ()
+            if kernel == "indexer":     # the scores under the bounds
+                fn.form = "check"
             got, want = (jax.tree.leaves(jax.jit(f, donate_argnums=given)(
                 q, L - 1, *(jnp.copy(p) if state else p for p in pools)))
                 for f in (fn, ref))
@@ -1248,7 +1332,11 @@ def main():
                     row[label] = round(time_launches(
                         timed, again, rows, pools, L, launches,
                         carried=state,
-                        kept=kernel in ("share_gmm", "picked")), 2)
+                        kept=kernel in ("share_gmm", "picked", "indexer")),
+                        2)
+                if swept:
+                    row[f"{label}_peak_share"] = round(
+                        row["swept_us"] / row[label], 4)
                 if label == "aligned":
                     row["aligned_visits"] = timed.visits
                 if kernel == "share_gmm":

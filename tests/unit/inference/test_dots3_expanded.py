@@ -328,3 +328,263 @@ def test_chunks_in_the_expanded_form_then_decode_is_the_reference():
     gap = (at.max(-1) - at[np.arange(len(at)), out[len(prompt):]]) \
         / np.abs(at).max(-1)
     assert gap.max() <= F32
+
+
+# ---------------------------------------------------------------------------
+# (f) the indexer scores a tile's keys as far as its bounds reach (PR 71)
+# ---------------------------------------------------------------------------
+CHUNK = 32          # four pages: toy tables hold several chunks
+
+
+def _whole_scores(qi, wi, keys):
+    """The indexer's scores over a row's keys at their full width, as
+    the program made them before it stopped at a tile's reach: chunks of
+    ``CHUNK`` keys, the last padded with zeros."""
+    def chunk(k):
+        s = jnp.einsum("tjd,cd->tjc", qi, k,
+                       preferred_element_type=jnp.float32)
+        return jnp.einsum("tjc,tj->tc", jax.nn.relu(s), wi)
+    c = keys.shape[0]
+    n = -(-c // CHUNK)
+    keys = jnp.pad(keys, ((0, n * CHUNK - c), (0, 0)))
+    out = jax.lax.map(chunk, keys.reshape(n, CHUNK, -1))
+    return out.transpose(1, 0, 2).reshape(qi.shape[0], -1)[:, :c]
+
+
+def _tile(bounds, rows=None, places=17, tables=2, seed=3, values=None):
+    """A tile of query tokens over ``tables`` rows' index keys, 17 pages
+    = 136 positions a row: four chunks of 32 and a quarter of a fifth,
+    so the last chunk lies against the table's end. ``values``: the
+    keys' first lane takes whole numbers of that many values (scores
+    that tie); else normal draws."""
+    rng = np.random.default_rng(seed)
+    t, heads, d = len(bounds), 3, 8
+    nb = 1 + tables * places
+    keys = rng.normal(size=(2, nb, BS, d)).astype(np.float32)
+    qi = rng.normal(size=(t, heads, d)).astype(np.float32)
+    wi = rng.normal(size=(t, heads)).astype(np.float32)
+    if values:
+        keys[:] = 0
+        keys[..., 0] = rng.integers(0, values, (2, nb, BS))
+        qi[:] = 0
+        qi[:, :, 0] = 1.0
+        wi = np.ones_like(wi)
+    bt = rng.permutation(np.arange(1, nb)).reshape(tables, places)
+    rows = np.zeros(t, np.int32) if rows is None else np.asarray(rows)
+    return dict(qi=jnp.asarray(qi), wi=jnp.asarray(wi),
+                keys=jnp.asarray(keys), l=jnp.int32(1),
+                rows=jnp.asarray(rows, jnp.int32),
+                bounds=jnp.asarray(bounds, jnp.int32),
+                bt=jnp.asarray(bt, jnp.int32))
+
+
+def _args(c, keys=None):
+    return (c["qi"], c["wi"], c["keys"] if keys is None else keys, c["l"],
+            c["rows"], c["bounds"], c["bt"])
+
+
+def _sorted_selection(scores, bounds, topk):
+    """The ``topk`` largest scores under each token's bound, ties to the
+    lower position, by a stable sort: position sets."""
+    out = []
+    for s, b in zip(np.asarray(scores), np.asarray(bounds)):
+        order = np.argsort(-s[:b], kind="stable")[:topk]
+        out.append(sorted(order.tolist()))
+    return out
+
+
+@pytest.mark.parametrize("reach", [20, 32, 33, 64, 65, 136])
+def test_scores_under_a_tiles_reach_are_the_whole_tables(monkeypatch, reach):
+    """A tile whose largest bound reaches one chunk, exactly a chunk's
+    edge, an edge + 1 and the whole table: every position under a
+    token's bound reads bit for bit what the full-width product gave it
+    (a chunk's product is the same computation), the chunks past the
+    reach are zeros nobody made, and those under it are all there."""
+    monkeypatch.setattr(paged_model, "_INDEX_CHUNK", CHUNK)
+    c = _tile(np.arange(reach - 7, reach + 1))
+    got, seen = (np.asarray(a) for a in paged_model._token_scores(
+        *_args(c), one_token=False))
+    want = np.asarray(_whole_scores(
+        c["qi"], c["wi"], c["keys"][1, c["bt"][0]].reshape(17 * BS, -1)))
+    assert seen.sum(-1).tolist() == list(range(reach - 7, reach + 1))
+    np.testing.assert_array_equal(got[seen], want[seen])
+    chunks = int(paged_model.index_chunks(reach, 136))
+    assert chunks == -(-reach // CHUNK)
+    made = min(chunks * CHUNK, 136)
+    np.testing.assert_array_equal(got[:, :made], want[:, :made])
+    assert (got[:, made:] == 0).all()
+    # a decode step's batched product stops at the same edge
+    rows = _tile([reach, max(reach - 40, 1), 0], rows=[0, 1, 0])
+    one, seen = (np.asarray(a) for a in paged_model._token_scores(
+        *_args(rows), one_token=True))
+    for i, r in enumerate([0, 1]):
+        want = np.asarray(_whole_scores(
+            rows["qi"][i:i + 1], rows["wi"][i:i + 1],
+            rows["keys"][1, rows["bt"][r]].reshape(17 * BS, -1)))[0]
+        np.testing.assert_allclose(one[i][seen[i]], want[seen[i]],
+                                   rtol=1e-6, atol=1e-6)
+    assert (one[:, made:] == 0).all() and not seen[2].any()
+
+
+@pytest.mark.parametrize("poison", [np.nan, np.inf])
+@pytest.mark.parametrize("reach", [20, 32, 70])
+def test_keys_past_the_reach_are_never_read(monkeypatch, reach, poison):
+    """Every cached key at or past the tile's reach poisoned (NaN,
+    +inf): the flags of a prompt's launch and the positions of a decode
+    step are what the clean keys gave; past the reach's last chunk not
+    even a score was made."""
+    monkeypatch.setattr(paged_model, "_INDEX_CHUNK", CHUNK)
+    topk = 16
+    c = _tile([reach, reach - 9, 5, 0], rows=[0, 0, 0, 0])
+    flat = np.array(c["keys"])
+    at = np.asarray(c["bt"][0]).repeat(BS)[reach:], \
+        np.tile(np.arange(BS), 17)[reach:]
+    flat[:, at[0], at[1]] = poison
+    bad = jnp.asarray(flat)
+    clean = np.asarray(paged_model.index_mask(*_args(c), topk))
+    np.testing.assert_array_equal(
+        np.asarray(paged_model.index_mask(*_args(c, bad), topk)), clean)
+    assert clean.sum(-1).tolist() == [min(b, topk)
+                                      for b in (reach, reach - 9, 5, 0)]
+    for one_token in (False, True):
+        idx, ok = paged_model.index_select(*_args(c), topk, one_token)
+        idx2, ok2 = paged_model.index_select(*_args(c, bad), topk, one_token)
+        np.testing.assert_array_equal(np.asarray(ok), np.asarray(ok2))
+        np.testing.assert_array_equal(np.asarray(idx)[np.asarray(ok)],
+                                      np.asarray(idx2)[np.asarray(ok2)])
+    scores = np.asarray(paged_model._token_scores(
+        *_args(c, bad), one_token=False)[0])
+    assert np.isfinite(scores[:, -(-reach // CHUNK) * CHUNK:]).all()
+
+
+@pytest.mark.parametrize("case", ["two_rows", "ties_over_an_edge",
+                                  "ties_over_the_last_chunk"])
+def test_the_flags_under_a_reach_are_the_sorted_selection(monkeypatch, case):
+    """``index_mask`` against a stable sort of the full-width scores: a
+    tile of two rows of different bounds (the absorbed form: each row's
+    keys scored as far as the TILE reaches, a token keeps its own
+    row's), and scores of three values whose ``topk``-th ties straddle a
+    chunk's edge, or the edge of the last chunk, which lies against the
+    table's end."""
+    monkeypatch.setattr(paged_model, "_INDEX_CHUNK", CHUNK)
+    topk = 24
+    c = {"two_rows": lambda: _tile([100, 101, 30, 31, 32, 0],
+                                   rows=[0, 0, 1, 1, 1, 0]),
+         "ties_over_an_edge": lambda: _tile([70, 64, 65, 33], values=3),
+         "ties_over_the_last_chunk": lambda: _tile([136, 130, 129, 97],
+                                                   values=3)}[case]()
+    flags = np.asarray(paged_model.index_mask(*_args(c), topk))
+    for i, (r, b) in enumerate(zip(np.asarray(c["rows"]),
+                                   np.asarray(c["bounds"]))):
+        whole = np.asarray(_whole_scores(
+            c["qi"][i:i + 1], c["wi"][i:i + 1],
+            c["keys"][1, c["bt"][r]].reshape(17 * BS, -1)))
+        want = _sorted_selection(whole, [b], topk)[0]
+        assert np.flatnonzero(flags[i]).tolist() == want, (case, i)
+        assert len(want) == min(b, topk)
+    if case != "two_rows":
+        # the ties are there: the topk-th score is shared past the set
+        s = np.asarray(_whole_scores(
+            c["qi"][:1], c["wi"][:1],
+            c["keys"][1, c["bt"][0]].reshape(17 * BS, -1)))[0]
+        b = int(c["bounds"][0])
+        kth = np.sort(s[:b])[-topk]
+        assert (s[:b] == kth).sum() > (s[:b] >= kth).sum() - topk + 1
+
+
+def _tiles_swept(form, row_ids, bounds, tokens, table_rows, ctx):
+    """The count by brute force over the tiles the PROGRAM makes: the
+    expanded form's packing (``_row_tiles``), the others' tiles of the
+    launch as it lies; a token in a tile counts the whole chunks under
+    the tile's largest bound."""
+    chunk = min(paged_model._INDEX_CHUNK, ctx)
+    pad = tokens - len(bounds)
+    rows_p = np.pad(row_ids, (0, pad)).astype(np.int32)
+    bounds_p = np.pad(bounds, (0, pad)).astype(np.int32)
+    if form == "expanded":
+        tq = ra.picked_heads_tile(tokens, table_rows)
+        src = np.asarray(paged_model._row_tiles(
+            jnp.asarray(rows_p), jnp.asarray(bounds_p), table_rows, tq)[0])
+        tt = min(paged_model._INDEX_TILE, tq)
+        tiles = [t[t < tokens] for t in src.reshape(-1, tt)]
+    else:
+        tt = min(paged_model._INDEX_TILE, tokens)
+        tiles = [np.arange(i, min(i + tt, tokens))
+                 for i in range(0, tokens, tt)]
+    total = 0
+    for at in tiles:
+        live = at[bounds_p[at] > 0]
+        if not len(live):
+            continue
+        scored = sum(1 for c in range(-(-ctx // chunk))
+                     if c * chunk < bounds_p[live].max())
+        each = len(set(rows_p[live])) if form == "absorbed" else 1
+        total += len(live) * each * scored * chunk
+    return total
+
+
+@pytest.mark.parametrize("form,counts,cached,tokens,table_rows", [
+    ("expanded", [300, 0, 5, 130], [40, 0, 0, 90], 512, 4),
+    ("expanded", [256], [100], 256, 1),
+    ("expanded", [1024], [0], 1024, 1),
+    ("absorbed", [20, 7, 30], [100, 0, 60], 64, 4),
+    ("absorbed", [300, 40], [0, 200], 512, 4),
+    ("decode", [1, 1, 1], [135, 3, 64], 4, 4),
+])
+def test_the_positions_swept_by_the_tiles(monkeypatch, form, counts, cached,
+                                          tokens, table_rows):
+    """``index_positions_swept`` (host arithmetic on the rows' tokens
+    and contexts) against the count over the program's own tiles; never
+    under the bounds' sum, which is the least the equations ask."""
+    monkeypatch.setattr(paged_model, "_INDEX_CHUNK", CHUNK)
+    ctx = 1400
+    row_ids = np.repeat(np.arange(len(counts)), counts)
+    bounds = np.concatenate([c + 1 + np.arange(n)
+                             for c, n in zip(cached, counts)])
+    got = paged_model.index_positions_swept(form, row_ids, bounds, tokens,
+                                            table_rows, ctx)
+    assert got == _tiles_swept(form, row_ids, bounds, tokens, table_rows,
+                               ctx)
+    assert got >= bounds.sum()
+
+
+def test_bounds_that_reach_the_last_chunk_sweep_the_table(monkeypatch):
+    """Every tile's largest bound in the table's last chunk: the
+    products cover the table's width in whole chunks for every token,
+    as they did for every tile before the reach bounded them."""
+    monkeypatch.setattr(paged_model, "_INDEX_CHUNK", CHUNK)
+    ctx, n = 136, 300
+    bounds = np.full(n, 130)
+    assert paged_model.index_positions_swept(
+        "expanded", np.zeros(n, int), bounds, 512, 1, ctx) \
+        == n * 5 * CHUNK
+    assert paged_model.index_positions_swept(
+        "decode", np.arange(4), bounds[:4], 4, 4, ctx) == 4 * 5 * CHUNK
+
+
+def test_the_counter_of_the_positions_swept():
+    """What ONE call adds: a launch that selects sweeps whole chunks
+    (here one: the toy tables hold fewer positions than a chunk) for
+    every query and full layer, its decode steps too, and a launch under
+    ``index_topk`` positions sweeps and scores nothing."""
+    def fed(eng, n, new):
+        fam = {k: get_registry().get(f"inference_index_positions_{k}_total")
+               for k in ("swept", "scored")}
+        before = {(k, p): f.labels(program=p).value for k, f in fam.items()
+                  for p in ("ragged_step", "decode")}
+        eng.generate([np.random.default_rng(3).integers(
+            0, TOY["vocab_size"], n)], max_new_tokens=new, temperature=0.0,
+            eos_token_id=None)
+        return {k: f.labels(program=k[1]).value - before[k]
+                for k in before for f in (fam[k[0]],)}
+    layers, n, new = 2, 300, 4
+    added = fed(build(state_manager=ONE_ROW), n, new)
+    ctx = 384                       # a row's table: max_seq_len
+    assert added["swept", "ragged_step"] == layers * n * ctx
+    assert added["scored", "ragged_step"] \
+        == layers * np.arange(1, n + 1).sum()
+    assert added["swept", "decode"] == layers * (new - 1) * ctx
+    assert added["scored", "decode"] \
+        == layers * (n + 1 + np.arange(new - 1)).sum()
+    short = fed(build(state_manager=ONE_ROW), 24, 1)
+    assert set(short.values()) == {0}
